@@ -2,7 +2,7 @@
 PY ?= python
 
 .PHONY: all native test test-native test-native-tsan bench bench-native \
-        bench-host dryrun engine clean
+        bench-host dryrun smoke engine clean
 
 all: native test
 
@@ -34,6 +34,10 @@ bench-host:
 
 dryrun:
 	$(PY) __graft_entry__.py 8
+
+# needs the chip (exits 2 without one): chiprun -- make smoke
+smoke:
+	$(PY) chip_smoke.py
 
 engine:
 	$(PY) tools/build_engine.py --model resnet50 --uint8 \

@@ -1,0 +1,592 @@
+#!/usr/bin/env python
+"""Does tpulab's main path still start on the chip?
+
+One command, one process holding the chip, real widths, a few requests::
+
+    python chip_smoke.py            # on a TPU host (here: chiprun -- python chip_smoke.py)
+
+Phases, in order; any phase that raises fails the run (exit 1):
+
+1. device   — ``jax.devices()[0].platform == "tpu"`` or exit 2 before
+              anything is built; Pallas interpret mode must be off.
+   native   — the C++ host core is built from ``cpp/`` into a temporary
+              directory and loaded from there (nothing git would not
+              commit is used, nothing is left in the tree).
+2. rn50     — ``build_model("resnet50", max_batch_size=128, uint8)`` →
+              ``InferenceManager`` → ``serve(batching=True)`` →
+              ``RemoteInferenceManager`` over localhost gRPC at b=1, 8, 128;
+              logits must match a direct ``runner.infer`` of the same input.
+3. lm       — the paged LM (hidden 2048, 16Q/4KV heads of 128, bf16,
+              page 16, vocab 50304, depth cut) behind the Generate RPC,
+              concurrent streams under each dispatch plan the engine has.
+4. kernels  — the ragged and flash Pallas kernels compiled by Mosaic
+              (``interpret=False``, custom call present in the lowered
+              program) against the XLA gather / dense-softmax paths.
+5. multichip— with more than one device: one RN50 replica per chip through
+              ``MultiDeviceDispatcher`` and the LM on a ``{"model": N}``
+              mesh against the single-device logits.
+
+The last line of stdout is ``{"ok": true, "device": {...}}`` and the exit
+code 0 only when every phase passed on a TPU.  The gRPC clients are threads
+of this process: nothing else ever needs the chip.
+
+``--rehearse-cpu`` walks the same code at toy sizes on the CPU backend, for
+debugging control flow before spending chip time.  It labels every line,
+prints no result line and exits 3: a rehearsal is never a pass.
+"""
+
+from __future__ import annotations
+
+import argparse
+import faulthandler
+import json
+import os
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+from dataclasses import dataclass, field
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+# the run leaves nothing in the tree, not even bytecode caches
+sys.dont_write_bytecode = True
+
+EXIT_NO_CHIP = 2
+EXIT_REHEARSAL = 3
+#: the run must end well inside the driver's 1200 s; past this every
+#: thread's stack goes to stderr and the process exits 1
+DEADLINE_S = 1100
+
+#: attention outputs of a bf16 kernel against the XLA reference on the same
+#: bf16 inputs (f32 accumulation both sides; bf16 rounds at ~4e-3 relative)
+ATTN_TOL = 2e-2
+#: logits of the same request through two programs (different reduction
+#: order, bf16 activations), relative to the largest logit
+LOGIT_RTOL = 5e-2
+
+
+@dataclass
+class Sizes:
+    """What each phase builds: the real widths, or the rehearsal's toys."""
+    rn50_kwargs: dict = field(default_factory=lambda: dict(
+        max_batch_size=128))
+    rn50_buckets: tuple = (1, 2, 4, 8, 16, 32, 64, 128)
+    rn50_batches: tuple = (1, 8, 1, 8, 128)
+    # the dense GQA + RoPE + RMSNorm + SwiGLU block the engine serves, at
+    # ROADMAP R1's attention geometry; depth is the only cut
+    lm: dict = field(default_factory=lambda: dict(
+        vocab=50304, d_model=2048, n_heads=16, n_kv_heads=4, n_layers=4,
+        d_ff=5632))
+    lm_max_len: int = 512
+    lm_page_size: int = 16
+    lm_prefill_chunk: int = 128
+    lm_prompt_lens: tuple = (8, 50, 300)   # one longer than prefill_chunk
+    lm_steps: int = 24
+    flash_t: int = 512
+
+
+REHEARSAL_SIZES = Sizes(
+    rn50_kwargs=dict(max_batch_size=8, image_size=32, num_classes=16),
+    rn50_buckets=(1, 2, 4, 8),
+    rn50_batches=(1, 8, 1),
+    lm=dict(vocab=256, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
+            d_ff=128),
+    lm_max_len=96, lm_page_size=8, lm_prefill_chunk=16,
+    lm_prompt_lens=(5, 12, 40), lm_steps=6, flash_t=32)
+
+
+class Smoke:
+    def __init__(self, rehearsal: bool):
+        self.rehearsal = rehearsal
+        self.sizes = REHEARSAL_SIZES if rehearsal else Sizes()
+        self.stamp = ""
+
+    def say(self, msg: str) -> None:
+        tag = "REHEARSAL(cpu, not a pass) " if self.rehearsal else ""
+        print(f"{tag}{msg} | {self.stamp}", flush=True)
+
+    def run(self, name: str, phase) -> None:
+        t0 = time.monotonic()
+        detail = phase(self)
+        self.say(f"phase {name}: ok ({time.monotonic() - t0:.0f}s) {detail}")
+
+    def rn50(self, **overrides):
+        """The RN50 servable at this run's sizes (uint8 images in)."""
+        import numpy as np
+
+        from tpulab.models import build_model
+        return build_model("resnet50", **dict(
+            self.sizes.rn50_kwargs, input_dtype=np.uint8, **overrides))
+
+
+# -- phase 1: device + native host core --------------------------------------
+def build_native_core(tmp: str) -> str:
+    """cmake + ninja ``cpp/`` into ``tmp``; returns the library path.  The
+    children are compilers: they never touch JAX or the chip."""
+    for cmd in (["cmake", "-S", os.path.join(REPO, "cpp"), "-B", tmp,
+                 "-G", "Ninja"],
+                ["ninja", "-C", tmp, "tpulab_native"]):
+        subprocess.run(cmd, check=True, stdout=subprocess.DEVNULL,
+                       timeout=300)
+    return os.path.join(tmp, "libtpulab_native.so")
+
+
+# -- phase 2: RN50 through the serving entry points --------------------------
+def phase_rn50(smoke: Smoke) -> str:
+    import numpy as np
+
+    import tpulab
+
+    sz = smoke.sizes
+    model = smoke.rn50()
+    spec, out_spec = model.inputs[0], model.outputs[0]
+    manager = tpulab.InferenceManager(max_exec_concurrency=4)
+    remote = None
+    try:
+        manager.register_model("rn50", model)
+        manager.update_resources()
+        compiled = sorted(manager.compiled("rn50").executables)
+        if compiled != list(sz.rn50_buckets):
+            raise AssertionError(f"compiled buckets {compiled}, expected "
+                                 f"{list(sz.rn50_buckets)}")
+        manager.serve(port=0, batching=True)
+        remote = tpulab.RemoteInferenceManager(
+            f"localhost:{manager.server.bound_port}")
+        served = remote.get_models()
+        if "rn50" not in served:
+            raise AssertionError(f"server lists {sorted(served)}")
+        rrunner = remote.infer_runner("rn50")
+        lrunner = manager.infer_runner("rn50")
+        rng = np.random.default_rng(0)
+        worst = 0.0
+        for b in sz.rn50_batches:
+            x = rng.integers(0, 256, (b, *spec.shape)).astype(spec.np_dtype)
+            got = rrunner.infer(**{spec.name: x}).result(timeout=300)
+            want = lrunner.infer(**{spec.name: x}).result(timeout=300)
+            g, w = got[out_spec.name], want[out_spec.name]
+            if g.shape != (b, *out_spec.shape) or g.dtype != w.dtype:
+                raise AssertionError(
+                    f"b={b}: got {g.shape} {g.dtype}, direct runner gave "
+                    f"{w.shape} {w.dtype}")
+            if not np.isfinite(g).all():
+                raise AssertionError(f"b={b}: non-finite logits over gRPC")
+            # the batching server may run the request in another bucket
+            # than the direct runner: bf16 conv reduction order differs
+            err = float(np.abs(g.astype(np.float32) - w.astype(np.float32))
+                        .max() / max(1.0, float(np.abs(w).max())))
+            worst = max(worst, err)
+            if err > LOGIT_RTOL:
+                raise AssertionError(
+                    f"b={b}: gRPC logits differ from the direct runner by "
+                    f"{err:.3g} (limit {LOGIT_RTOL})")
+        return (f"buckets={compiled} grpc_batches={list(sz.rn50_batches)} "
+                f"max_rel_err_vs_direct={worst:.2g}")
+    finally:
+        if remote is not None:
+            remote.close()
+        manager.shutdown()
+
+
+# -- phase 3: the paged LM through the Generate RPC --------------------------
+#: every dispatch plan the engine can select (ContinuousBatcher options)
+LM_PLANS = (
+    ("legacy_split_gather", dict(ragged=False, use_kernel=False,
+                                 prefill_flash=False)),
+    ("ragged_gather", dict(ragged=True, use_kernel=False,
+                           prefill_flash=False)),
+    # these two un-chunked: the long prompt rides the widest mixed round
+    # (RAGGED_CHUNK_CAP) through the kernel, its whole bucket through flash
+    ("ragged_kernel", dict(ragged=True, use_kernel=True,
+                           prefill_flash=False, prefill_chunk=None)),
+    ("flash_prefill", dict(ragged=False, use_kernel=False,
+                           prefill_flash=True, prefill_chunk=None)),
+)
+
+
+def lm_params(smoke: Smoke):
+    import jax
+    import jax.numpy as jnp
+
+    from tpulab.models.transformer import init_transformer_params
+    params = init_transformer_params(seed=0, ffn="swiglu",
+                                     tie_embeddings=False, **smoke.sizes.lm)
+    return jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), params)
+
+
+def lm_engine(smoke: Smoke, params, mesh=None, **plan):
+    import jax.numpy as jnp
+
+    from tpulab.engine.paged import ContinuousBatcher
+    sz = smoke.sizes
+    kw = dict(prefill_chunk=sz.lm_prefill_chunk)
+    kw.update(plan)
+    return ContinuousBatcher(
+        params, n_heads=sz.lm["n_heads"], n_layers=sz.lm["n_layers"],
+        n_kv_heads=sz.lm["n_kv_heads"], lanes=4, max_len=sz.lm_max_len,
+        page_size=sz.lm_page_size, compute_dtype=jnp.bfloat16,
+        rope_theta=10000.0, mesh=mesh, **kw)
+
+
+def stream_generations(smoke: Smoke, remote, model_name: str) -> int:
+    """Concurrent streamed generations against one served engine: mixed
+    prompt lengths, the last one seeded and device-sampled.  Returns the
+    number of tokens streamed; raises on a wrong count, an id out of
+    range, or a stream that does not end."""
+    import numpy as np
+
+    from tpulab.rpc.infer_service import GenerateStreamClient
+    sz = smoke.sizes
+    vocab, steps = sz.lm["vocab"], sz.lm_steps
+    rng = np.random.default_rng(1)
+    jobs = [(rng.integers(0, vocab, (n,)).astype(np.int32), {})
+            for n in sz.lm_prompt_lens]
+    jobs.append((jobs[0][0], dict(temperature=0.8, seed=7,
+                                  device_sampling=True)))
+    results: list = [None] * len(jobs)
+
+    def one(i: int) -> None:
+        prompt, kw = jobs[i]
+        try:
+            client = GenerateStreamClient(remote, model_name)
+            results[i] = list(client.generate(prompt, steps, timeout=600,
+                                              **kw))
+        except BaseException as e:  # noqa: BLE001 - re-raised by the caller
+            results[i] = e
+
+    threads = [threading.Thread(target=one, args=(i,), daemon=True)
+               for i in range(len(jobs))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=900)
+    for i, (t, res) in enumerate(zip(threads, results)):
+        if t.is_alive():
+            raise AssertionError(f"{model_name}: stream {i} did not end")
+        if isinstance(res, BaseException):
+            raise res
+        if len(res) != steps:
+            raise AssertionError(
+                f"{model_name}: stream {i} gave {len(res)} tokens, "
+                f"asked for {steps}")
+        if not all(0 <= tok < vocab for tok in res):
+            raise AssertionError(f"{model_name}: stream {i} token id out "
+                                 f"of [0, {vocab})")
+    return steps * len(jobs)
+
+
+def phase_lm(smoke: Smoke) -> str:
+    import tpulab
+
+    params = lm_params(smoke)
+    engines = {}
+    manager = tpulab.InferenceManager(max_exec_concurrency=1)
+    remote = None
+    try:
+        for name, plan in LM_PLANS:
+            cb = engines[name] = lm_engine(smoke, params, **plan)
+            picked = dict(ragged=cb.ragged, use_kernel=cb.use_kernel,
+                          prefill_flash=cb.prefill_flash)
+            asked = {k: plan[k] for k in picked}
+            if picked != asked:
+                raise AssertionError(f"plan {name}: asked {asked}, engine "
+                                     f"selected {picked}")
+        manager.serve(port=0, generation_engines=engines)
+        remote = tpulab.RemoteInferenceManager(
+            f"localhost:{manager.server.bound_port}")
+        report = []
+        for name, _ in LM_PLANS:
+            n = stream_generations(smoke, remote, name)
+            cb = engines[name]
+            report.append(f"{name}: {n} tokens, dispatches="
+                          f"{dict(cb.dispatch_kinds)} prefills="
+                          f"{cb.prefill_dispatches}")
+        if engines["ragged_gather"].dispatch_kinds["mixed"] == 0:
+            raise AssertionError("ragged plan ran no mixed round")
+        if engines["legacy_split_gather"].prefill_dispatches == 0:
+            raise AssertionError("legacy plan ran no prefill dispatch")
+        return "; ".join(report)
+    finally:
+        if remote is not None:
+            remote.close()
+        manager.shutdown()
+        for cb in engines.values():
+            cb.shutdown()
+
+
+# -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------
+def check_mosaic(smoke: Smoke, name: str, fn, *args) -> None:
+    """On the chip the lowered program must hold the Mosaic custom call;
+    in the rehearsal (interpret mode) it must not."""
+    import jax
+    has = "tpu_custom_call" in jax.jit(fn).lower(*args).as_text()
+    if has == smoke.rehearsal:
+        raise AssertionError(
+            f"{name}: Mosaic custom call {'present' if has else 'absent'} "
+            f"in the lowered program")
+
+
+def ragged_case(smoke: Smoke, name: str, q_lens, kv_lens, m: int,
+                dtype=None, g_pages=None, nbuf=None, heads=None,
+                tol: float = ATTN_TOL) -> float:
+    """ragged_paged_attention vs the XLA gather path on one segment mix at
+    the LM's head geometry.  Returns the max abs error over valid rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.paged import _gather_attend
+    from tpulab.ops.ragged_attention import ragged_paged_attention
+    sz = smoke.sizes
+    dtype = dtype or jnp.bfloat16
+    h, hkv = heads or (sz.lm["n_heads"], sz.lm["n_kv_heads"])
+    d = sz.lm["d_model"] // sz.lm["n_heads"]
+    ps = sz.lm_page_size
+    q_lens = np.asarray(q_lens, np.int32)
+    kv_lens = np.asarray(kv_lens, np.int32)
+    b = len(q_lens)
+    mp = -(-int(kv_lens.max()) // ps)
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((b, m, h, d)), dtype)
+    pool = jnp.asarray(
+        rng.standard_normal((b * mp + 1, 2, ps, hkv, d)), dtype)
+    tables = (1 + np.arange(b * mp, dtype=np.int32)).reshape(b, mp)
+    interpret = smoke.rehearsal
+
+    def kernel(q, pool):
+        return ragged_paged_attention(q, pool, tables, q_lens, kv_lens,
+                                      interpret=interpret, g_pages=g_pages,
+                                      nbuf=nbuf)
+
+    check_mosaic(smoke, name, kernel, q, pool)
+    got = np.asarray(jax.block_until_ready(kernel(q, pool)), np.float32)
+    pos = (kv_lens - q_lens)[:, None] + np.arange(m)[None, :]
+    want = np.asarray(_gather_attend(
+        q, pool[:, 0], pool[:, 1], jnp.asarray(tables), jnp.asarray(pos),
+        jnp.float32), np.float32).reshape(b, m, h, d)
+    valid = np.arange(m)[None, :] < q_lens[:, None]
+    if not np.isfinite(got[valid]).all():
+        raise AssertionError(f"{name}: non-finite kernel output")
+    err = float(np.abs(got - want)[valid].max())
+    if err > tol:
+        raise AssertionError(f"{name}: kernel vs XLA gather max abs error "
+                             f"{err:.3g} > {tol}")
+    return err
+
+
+def phase_kernels(smoke: Smoke) -> str:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.paged import ContinuousBatcher
+    from tpulab.models.transformer import causal_attention
+    from tpulab.ops.flash_attention import flash_attention
+    sz = smoke.sizes
+    ps, top = sz.lm_page_size, sz.lm_max_len
+    # the widest segment the engine dispatches un-chunked
+    chunk = min(ContinuousBatcher.RAGGED_CHUNK_CAP, top)
+    errs = {}
+    # decode (q_len = 1), K+1 verify (one lane inactive), and a mixed
+    # round: a full prompt chunk beside decode lanes
+    errs["decode"] = ragged_case(
+        smoke, "ragged decode", [1, 1, 1, 1], [3, ps + 1, top, top // 2 + 5],
+        m=1)
+    errs["verify"] = ragged_case(
+        smoke, "ragged verify", [5, 5, 3, 0], [9, top // 4 + 5, top, 0], m=5)
+    errs["mixed"] = ragged_case(
+        smoke, "ragged mixed", [chunk, 1, chunk // 2 + 5, 1],
+        [chunk, top // 2, top // 2 + 9, top], m=chunk)
+    # more blocks than pipeline slots, f32 so the tolerance is tight: an
+    # async page DMA racing the slot about to be read shows only on
+    # hardware (interpret-mode DMAs are synchronous)
+    errs["refill_f32"] = ragged_case(
+        smoke, "ragged slot refill", [1, 1], [16 * ps - 1, 8 * ps + 2], m=1,
+        dtype=jnp.float32, g_pages=2, nbuf=3, heads=(2, 2), tol=2e-3)
+
+    t, h = sz.flash_t, sz.lm["n_heads"]
+    d = sz.lm["d_model"] // h
+    rng = np.random.default_rng(1)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, t, h, d)), jnp.bfloat16)
+               for _ in range(3))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=smoke.rehearsal)
+
+    check_mosaic(smoke, "flash", flash, q, k, v)
+    got = np.asarray(jax.block_until_ready(flash(q, k, v)), np.float32)
+    want = np.asarray(causal_attention(q, k, v), np.float32)
+    errs["flash"] = float(np.abs(got - want).max())
+    if not np.isfinite(got).all() or errs["flash"] > ATTN_TOL:
+        raise AssertionError(f"flash vs dense softmax max abs error "
+                             f"{errs['flash']:.3g} > {ATTN_TOL}")
+    shown = " ".join(f"{k}={v:.2g}" for k, v in errs.items())
+    return f"max_abs_err_vs_xla: {shown} (tol {ATTN_TOL}, f32 case 2e-3)"
+
+
+# -- phase 5: more than one device -------------------------------------------
+def prefill_logits(cb, prompt):
+    """Last-position logits of ``prompt`` from the engine's own jitted
+    prefill program (its shardings included), over a scratch page pool."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    pool = cb.pool
+    kv = jax.device_put(jnp.zeros(pool._shape, pool.dtype), pool.placement)
+    t = len(prompt)
+    t_pad = 1 << (t - 1).bit_length()
+    tokens = np.zeros((1, t_pad), np.int32)
+    tokens[0, :t] = prompt
+    tables = np.zeros((cb.max_pages,), np.int32)
+    n = -(-t // cb.page_size)
+    tables[:n] = 1 + np.arange(n)
+    logits, _ = cb._prefill(cb.params, kv, jnp.asarray(tables),
+                            jnp.asarray(tokens), jnp.int32(t))
+    return np.asarray(logits, np.float32)
+
+
+def phase_multichip(smoke: Smoke) -> str:
+    import jax
+    import numpy as np
+
+    import tpulab
+    from tpulab.parallel.dispatch import MultiDeviceDispatcher
+    from tpulab.parallel.mesh import make_mesh
+
+    devices = jax.devices()
+    n = len(devices)
+    if n < 2:
+        return "multichip: not run (1 device)"
+    sz = smoke.sizes
+
+    # one RN50 replica per chip (one host weight set, a copy placed on
+    # each): every chip serves and holds its own weights
+    model = smoke.rn50(max_batch_size=8, batch_buckets=[1, 8])
+    spec, out_spec = model.inputs[0], model.outputs[0]
+    weight_bytes = model.weights_size_in_bytes()
+    disp = MultiDeviceDispatcher.create(lambda: model, "rn50",
+                                        devices=devices)
+    try:
+        rng = np.random.default_rng(0)
+        x = rng.integers(0, 256, (8, *spec.shape)).astype(spec.np_dtype)
+        futs = [disp.infer("rn50", **{spec.name: x}) for _ in range(2 * n)]
+        outs = [f.result(timeout=300)[out_spec.name] for f in futs]
+        if list(disp.served) != [2] * n:
+            raise AssertionError(f"requests per device {disp.served}, "
+                                 f"expected {[2] * n}")
+        for o in outs[1:]:
+            # same weights, same program, another chip: near bit-equal
+            if not np.allclose(o, outs[0], rtol=1e-3, atol=1e-3):
+                raise AssertionError("RN50 replicas disagree across devices")
+        held = []
+        for d in devices:
+            stats = d.memory_stats() if not smoke.rehearsal else None
+            held.append(stats["bytes_in_use"] if stats else None)
+        if not smoke.rehearsal and min(held) < weight_bytes:
+            raise AssertionError(
+                f"a device holds {min(held)} bytes, less than one RN50 "
+                f"weight copy ({weight_bytes}): {held}")
+    finally:
+        disp.shutdown()
+
+    # the LM tensor-parallel over every chip, against the single-device run
+    params = lm_params(smoke)
+    mesh = make_mesh({"model": n}, devices)
+    plan = dict(ragged=False, use_kernel=False, prefill_flash=False)
+    single = lm_engine(smoke, params, **plan)
+    sharded = lm_engine(smoke, params, mesh=mesh, **plan)
+    # and the ragged kernel under shard_map, each chip walking its own
+    # KV heads' pages
+    sharded_kernel = lm_engine(smoke, params, mesh=mesh, ragged=True,
+                               use_kernel=True, prefill_flash=False)
+    manager = tpulab.InferenceManager(max_exec_concurrency=1)
+    remote = None
+    try:
+        prompt = np.random.default_rng(2).integers(
+            0, sz.lm["vocab"], (sz.lm_prompt_lens[1],)).astype(np.int32)
+        a, b = prefill_logits(single, prompt), prefill_logits(sharded, prompt)
+        err = float(np.abs(a - b).max() / max(1.0, float(np.abs(a).max())))
+        if not np.isfinite(b).all() or err > LOGIT_RTOL:
+            raise AssertionError(
+                f"{{'model': {n}}} prefill logits differ from single-device "
+                f"by {err:.3g} (limit {LOGIT_RTOL})")
+        manager.serve(port=0, generation_engines={
+            "lm_mesh": sharded, "lm_mesh_kernel": sharded_kernel})
+        remote = tpulab.RemoteInferenceManager(
+            f"localhost:{manager.server.bound_port}")
+        tokens = (stream_generations(smoke, remote, "lm_mesh")
+                  + stream_generations(smoke, remote, "lm_mesh_kernel"))
+    finally:
+        if remote is not None:
+            remote.close()
+        manager.shutdown()
+        for cb in (single, sharded, sharded_kernel):
+            cb.shutdown()
+    return (f"multichip: {n} devices; rn50 served={list(disp.served)} "
+            f"bytes_in_use={held} (weights {weight_bytes}); lm mesh "
+            f"{{'model': {n}}} logits rel err {err:.2g}, {tokens} tokens "
+            "streamed (gather and ragged-kernel plans)")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="toy sizes on the CPU backend; never a pass "
+                         f"(exit {EXIT_REHEARSAL}, no result line)")
+    args = ap.parse_args(argv)
+    faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
+
+    if args.rehearse_cpu:
+        # mesh code needs devices; must precede the first backend use
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=2").strip()
+    import jax
+
+    dev = jax.devices()[0]
+    want = "cpu" if args.rehearse_cpu else "tpu"
+    if dev.platform != want:
+        print(f"chip_smoke: no accelerator: jax found platform="
+              f"{dev.platform!r} ({dev.device_kind}), need {want!r}",
+              file=sys.stderr)
+        return EXIT_NO_CHIP
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    smoke = Smoke(args.rehearse_cpu)
+
+    with tempfile.TemporaryDirectory(prefix="tpulab-native-") as tmp:
+        lib = build_native_core(tmp)
+        os.environ["TPULAB_NATIVE_LIB"] = lib
+        from tpulab import native
+        from tpulab.tpu.platform import pallas_interpret
+        if native.loaded_path() != lib:
+            raise AssertionError(f"native core loaded from "
+                                 f"{native.loaded_path()!r}, built {lib!r}")
+        if pallas_interpret() != args.rehearse_cpu:
+            raise AssertionError(
+                f"pallas_interpret() is {pallas_interpret()} on "
+                f"{dev.platform}")
+        smoke.stamp = (f"platform={device['platform']} "
+                       f"device_kind={device['kind']!r} "
+                       f"devices={device['count']} jax={jax.__version__} "
+                       f"native_core={str(native.enabled()).lower()}")
+        smoke.say(f"phase device: ok native core {native.version()} built "
+                  f"from cpp/, pallas_interpret={pallas_interpret()}")
+        smoke.run("rn50", phase_rn50)
+        smoke.run("lm", phase_lm)
+        smoke.run("kernels", phase_kernels)
+        smoke.run("multichip", phase_multichip)
+
+    if args.rehearse_cpu:
+        smoke.say("rehearsal finished; run without --rehearse-cpu on the "
+                  "chip for a result")
+        return EXIT_REHEARSAL
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,23 +1,33 @@
 #!/usr/bin/env bash
 # N server processes + round-robin client (reference examples/98: N processes
 # sharing a V100 via CUDA MPS + envoy).  TPU note: chips are not MPS-shared —
-# on a pod VM each process binds its own chip (TPU_VISIBLE_DEVICES); on a
-# single-chip host this script still demonstrates the N-replica topology.
+# a chip belongs to ONE process, so each replica binds its own
+# (TPU_VISIBLE_DEVICES) and N may not exceed the host's chips (CHIPS).
 #
-#   ./98_multiprocess.sh 2 resnet50
+#   CHIPS=4 ./98_multiprocess.sh 2 resnet50
+#   EXTRA_ARGS=--cpu ./98_multiprocess.sh 2        # no chip needed
 set -euo pipefail
 N=${1:-2}
 MODEL=${2:-mnist}
 BASE_PORT=${BASE_PORT:-51000}
 EXTRA_ARGS=${EXTRA_ARGS:-}   # e.g. EXTRA_ARGS=--cpu for hermetic runs
+CHIPS=${CHIPS:-1}            # chips on this host
 PIDS=()
+
+if [[ "$EXTRA_ARGS" != *--cpu* && "$N" -gt "$CHIPS" ]]; then
+  echo "asked for $N chip-holding replicas with CHIPS=$CHIPS: a chip belongs" \
+       "to one process and the extra replicas would hang. Set CHIPS=<chips" \
+       "on this host> or EXTRA_ARGS=--cpu." >&2
+  exit 2
+fi
 
 cleanup() { kill "${PIDS[@]}" 2>/dev/null || true; }
 trap cleanup EXIT
 
 for i in $(seq 0 $((N-1))); do
   PORT=$((BASE_PORT + i))
-  TPU_VISIBLE_DEVICES=$i python "$(dirname "$0")/02_inference_service.py" \
+  TPU_VISIBLE_DEVICES=$i TPU_CHIPS_PER_PROCESS_BOUNDS=1,1,1 \
+  TPU_PROCESS_BOUNDS=1,1,1 python "$(dirname "$0")/02_inference_service.py" \
       --model "$MODEL" --port "$PORT" --metrics-port $((9100 + i)) \
       $EXTRA_ARGS &
   PIDS+=($!)

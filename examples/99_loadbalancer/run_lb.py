@@ -16,6 +16,10 @@ and prints per-config throughput + p50 latency and the per-request
 overhead vs direct.  Run:
 
     python examples/99_loadbalancer/run_lb.py --replicas 2 -n 200 --cpu
+
+Without ``--cpu`` every replica serves on a chip, and a chip belongs to one
+process: name the chips (``--chips 0,1``), one per replica.  This parent
+never touches JAX, so it holds none.
 """
 
 from __future__ import annotations
@@ -57,14 +61,16 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def start_replicas(n: int, cpu: bool) -> list:
+def start_replicas(n: int, cpu: bool, chips: list) -> list:
+    from tpulab.fleet.process import chip_env
     env = {**os.environ, "PYTHONPATH": REPO}
     args = [sys.executable, "-c", _REPLICA_WORKER] + (["--cpu"] if cpu else [])
     procs = []
-    for _ in range(n):
+    for i in range(n):
         procs.append(subprocess.Popen(
             args, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=subprocess.PIPE, text=True, env=env))
+            stderr=subprocess.PIPE, text=True,
+            env=env if cpu else {**env, **chip_env(chips[i])}))
     ports = []
     for p in procs:
         line = p.stdout.readline()
@@ -139,6 +145,9 @@ def main() -> int:
     ap.add_argument("-n", type=int, default=200)
     ap.add_argument("--depth", type=int, default=8)
     ap.add_argument("--cpu", action="store_true")
+    ap.add_argument("--chips", default="",
+                    help="without --cpu: comma-separated local chip "
+                         "indices, one per replica (e.g. 0,1)")
     ap.add_argument("--json", action="store_true",
                     help="emit one JSON line instead of the table")
     ap.add_argument("--metrics-port", type=int, default=0,
@@ -147,12 +156,17 @@ def main() -> int:
                          "client-side series the deploy dashboard's "
                          "replica panels read")
     args = ap.parse_args()
+    chips = [int(c) for c in args.chips.split(",") if c.strip()]
+    if not args.cpu and len(chips) < args.replicas:
+        ap.error(f"{args.replicas} chip-holding replicas need --chips with "
+                 f"{args.replicas} chip indices (got {chips}); a chip "
+                 "belongs to one process — or pass --cpu")
 
     sys.path.insert(0, REPO)
     from tpulab.rpc.infer_service import RemoteInferenceManager
     from tpulab.rpc.replica import ReplicaSet
 
-    replicas = start_replicas(args.replicas, args.cpu)
+    replicas = start_replicas(args.replicas, args.cpu, chips)
     ports = [pt for _, pt in replicas]
     results: dict[str, dict] = {}
     envoy_proc = None

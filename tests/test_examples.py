@@ -216,6 +216,13 @@ def test_99_run_lb_driver():
     env = {"PYTHONPATH": REPO, "PATH": "/usr/bin:/bin",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
            "HOME": "/tmp"}
+    # chip-holding replicas need a chip each, named up front: asking for
+    # more than --chips lists fails before anything is spawned
+    out = subprocess.run(
+        [sys.executable, f"{REPO}/examples/99_loadbalancer/run_lb.py",
+         "--replicas", "2", "--chips", "0"],
+        capture_output=True, text=True, timeout=60, env=env)
+    assert out.returncode == 2 and "one process" in out.stderr, out.stderr
     out = subprocess.run(
         [sys.executable, f"{REPO}/examples/99_loadbalancer/run_lb.py",
          "--replicas", "2", "-n", "40", "--cpu", "--json"],

@@ -696,6 +696,8 @@ def test_subprocess_fleet_kill_resume_respawn_and_scaledown():
         # os._exit()s the replica mid-stream (exit code 86)
         victim = prov.spawn(extra_env={"TPULAB_CHAOS": "rpc.stream=kill@4"})
         survivor = prov.spawn()
+        # the handshake names the platform: a CPU fleet reads as one
+        assert prov.platform_of(survivor) == "cpu"
         rs = GenerationReplicaSet([victim, survivor], "lm")
         sup = FleetSupervisor(rs, prov, respawn_backoff_s=0.1,
                               probe_timeout_s=5.0)
@@ -757,3 +759,28 @@ def test_subprocess_fleet_kill_resume_respawn_and_scaledown():
                 closer()
             except Exception:
                 pass
+
+
+def test_native_replicas_are_bound_one_per_chip(monkeypatch):
+    """A --native-platform replica holds a chip, and a chip belongs to
+    one process: the provider needs the chip list up front, binds each
+    child to its own, and refuses the spawn that would have no chip
+    (instead of starting a child that hangs on a held one)."""
+    with pytest.raises(ValueError, match="chips="):
+        SubprocessReplicaProvider(replica_args=("--native-platform",))
+
+    prov = SubprocessReplicaProvider(replica_args=("--native-platform",),
+                                     chips=(0, 1))
+    bound = []
+
+    def fake_spawn_once(extra_env, chip):
+        bound.append(chip)
+        return f"127.0.0.1:{9000 + chip}"
+
+    monkeypatch.setattr(prov, "_spawn_once", fake_spawn_once)
+    assert prov.spawn() and prov.spawn()
+    assert bound == [0, 1]
+    with pytest.raises(RuntimeError, match="no free chip"):
+        prov.spawn()
+    assert bound == [0, 1]            # nothing was started for it
+

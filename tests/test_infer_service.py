@@ -53,6 +53,30 @@ def test_remote_concurrent_requests(serving):
     assert all(o["Plus214_Output_0"].shape == (1, 10) for o in outs)
 
 
+def test_remote_request_larger_than_grpc_default_limit():
+    """Tensors ride inside the messages: a full batch of images is far
+    past gRPC's default 4 MiB per message (b=128 of 224x224x3 uint8 is
+    19 MB), in both directions — server and channel lift the limit."""
+    from tpulab.engine.model import IOSpec, Model
+    mgr = tpulab.InferenceManager(max_exec_concurrency=1)
+    mgr.register_model("echo", Model(
+        "echo", lambda p, x: {"out": x["img"]}, {},
+        [IOSpec("img", (224, 224, 3), np.uint8)],
+        [IOSpec("out", (224, 224, 3), np.uint8)],
+        max_batch_size=32, batch_buckets=[32]))
+    mgr.update_resources()
+    mgr.serve(port=0, batching=True)
+    remote = RemoteInferenceManager(f"localhost:{mgr.server.bound_port}")
+    try:
+        x = np.random.default_rng(0).integers(
+            0, 256, (32, 224, 224, 3)).astype(np.uint8)      # 4.8 MB
+        out = remote.infer_runner("echo").infer(img=x).result(timeout=120)
+        assert (out["out"] == x).all()
+    finally:
+        remote.close()
+        mgr.shutdown()
+
+
 def test_remote_unknown_model(serving):
     _mgr, remote = serving
     with pytest.raises(KeyError):

@@ -646,20 +646,25 @@ def test_prefill_flash_matches_dense(lm):
     assert outs[True] == outs[False]
 
 
-def test_prefill_flash_degrades_on_compile_failure(lm):
-    """A per-bucket flash rejection must degrade the batcher to dense
-    prefill (requests succeed), not fail serving."""
+def test_prefill_flash_compile_failure_is_an_error(lm):
+    """A Mosaic refusal of the flash prefill is an error the requester
+    sees — the batcher never swaps in the dense path behind its back —
+    and serving goes on for the next request."""
     cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=1, max_len=32,
                            page_size=8, compute_dtype=jnp.float32,
                            prefill_flash=True)
     try:
+        real = cb._prefill
+
         def boom(*a, **k):
             raise RuntimeError("Mosaic rejected this bucket")
-        cb._prefill = boom  # next prefill trips the degrade path
+        cb._prefill = boom
         p = np.random.default_rng(1).integers(0, 64, (6,), np.int32)
-        out = cb.submit(p, 4).result(timeout=120)
-        assert len(out) == 4
-        assert cb.prefill_flash is False  # permanently degraded, once
+        with pytest.raises(RuntimeError, match="Mosaic rejected"):
+            cb.submit(p, 4).result(timeout=120)
+        assert cb.prefill_flash is True and cb._prefill is boom
+        cb._prefill = real
+        assert len(cb.submit(p, 4).result(timeout=120)) == 4
     finally:
         cb.shutdown()
 

@@ -1,7 +1,5 @@
 """Tool smoke tests (reference examples/12_ConfigGenerator +
-examples/ONNX build.py pipelines) and the round-evidence capture policy
-(tools/bench_capture.py, tools/hw_validate.py) — the machinery whose
-failure modes previously only showed up at round boundaries."""
+examples/ONNX build.py pipelines)."""
 
 import json
 import os
@@ -207,111 +205,3 @@ def test_gen_inference_pb2_schema_drift_and_roundtrip():
     assert fr.status.code == pb.NOT_FOUND
     assert pb.NOT_FOUND == 7
     assert pb.FetchKVResponse().kv_shipment == b""
-
-
-# -- capture policy (stubbed attempts; no device needed) ----------------------
-def _bc(monkeypatch, recs):
-    import importlib
-
-    import tools.bench_capture as bc
-    importlib.reload(bc)
-    clock = {"t": 0.0}
-    monkeypatch.setattr(bc.time, "monotonic", lambda: clock["t"])
-    monkeypatch.setattr(bc.time, "sleep",
-                        lambda s: clock.__setitem__("t", clock["t"] + s))
-    calls = {"n": 0}
-
-    def fake_attempt(deadline, round_no=0):
-        clock["t"] += 1800.0  # a real attempt takes ~30 min
-        r = recs[min(calls["n"], len(recs) - 1)]
-        calls["n"] += 1
-        return dict(r)
-
-    monkeypatch.setattr(bc, "attempt", fake_attempt)
-    monkeypatch.setattr(bc, "device_alive", lambda deadline_s=150.0: True)
-    return bc, calls
-
-
-def test_bench_capture_prefers_complete_over_partial(tmp_path, monkeypatch):
-    """A watchdog-cut (TIMEOUT) record persists best-partial-wins and the
-    loop keeps retrying until a COMPLETE run replaces it."""
-    recs = [
-        {"value": 900.0, "device": "TPU (TIMEOUT during phase 'x')",
-         "details": {}},
-        {"value": 150.0, "device": "TPU (TIMEOUT during phase 'y')",
-         "details": {}},
-        {"value": 120.0, "device": "TPU v5", "details": {}},
-    ]
-    bc, calls = _bc(monkeypatch, recs)
-    out = str(tmp_path / "cap.json")
-    monkeypatch.setattr(sys, "argv", ["bc", "--round", "9", "--out", out,
-                                      "--max-hours", "11"])
-    assert bc.main() == 0
-    assert calls["n"] == 3  # partials retried, complete run exits
-    rec = json.load(open(out))
-    assert rec["value"] == 120.0 and rec["round"] == 9
-    assert "TIMEOUT" not in rec["device"]
-
-
-def test_bench_capture_partial_only_round_keeps_best(tmp_path, monkeypatch):
-    """If only partials land all round: exit 0 with the BEST partial on
-    disk (a worse late cut must not erase better evidence)."""
-    recs = [
-        {"value": 900.0, "device": "TPU (TIMEOUT during phase 'x')",
-         "details": {}},
-        {"value": 150.0, "device": "TPU (TIMEOUT during phase 'y')",
-         "details": {}},
-    ]
-    bc, _ = _bc(monkeypatch, recs)
-    out = str(tmp_path / "cap.json")
-    monkeypatch.setattr(sys, "argv", ["bc", "--round", "9", "--out", out,
-                                      "--max-hours", "2"])
-    assert bc.main() == 0
-    assert json.load(open(out))["value"] == 900.0
-
-
-def test_hw_validate_waits_for_complete_capture(tmp_path, monkeypatch):
-    """The hardware suite must not contend with bench_capture: it runs
-    only once the capture record is COMPLETE (not a partial)."""
-    import importlib
-
-    import tools.hw_validate as hv
-    importlib.reload(hv)
-    clock = {"t": 0.0}
-    monkeypatch.setattr(hv.time, "monotonic", lambda: clock["t"])
-    monkeypatch.setattr(hv.time, "sleep",
-                        lambda s: clock.__setitem__("t", clock["t"] + s))
-    monkeypatch.setattr(hv, "REPO", str(tmp_path))
-    os.makedirs(tmp_path / "docs")
-    capture = tmp_path / "docs" / "BENCH_EARLY_r09.json"
-    runs = {"n": 0}
-
-    class P:
-        returncode = 0
-        stdout = "4 passed"
-        stderr = ""
-
-    def fake_run(cmd, **kw):
-        if cmd[0] == "pgrep":  # bench_capture process probe: "running"
-            return type("R", (), {"returncode": 0})()
-        runs["n"] += 1
-        assert kw["env"]["TPULAB_HW_TESTS"] == "1"
-        return P()
-
-    monkeypatch.setattr(hv.subprocess, "run", fake_run)
-    import tools.bench_capture as bc
-    monkeypatch.setattr(bc, "device_alive", lambda deadline_s=150.0: True)
-
-    # partial record + capture process alive -> never runs, exits 1
-    capture.write_text(json.dumps(
-        {"value": 5.0, "device": "TPU (TIMEOUT during phase 'x')"}))
-    monkeypatch.setattr(sys, "argv", ["hv", "--round", "9",
-                                      "--max-hours", "0.5",
-                                      "--poll-s", "300"])
-    assert hv.main() == 1 and runs["n"] == 0
-
-    # complete record -> suite runs once, transcript written, exit 0
-    capture.write_text(json.dumps({"value": 5.0, "device": "TPU v5"}))
-    clock["t"] = 0.0
-    assert hv.main() == 0 and runs["n"] == 1
-    assert "4 passed" in (tmp_path / "docs" / "HWTESTS_r09.txt").read_text()

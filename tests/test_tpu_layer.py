@@ -29,17 +29,29 @@ def test_device_info():
     assert len(DeviceInfo.cpu_affinity()) >= 1
 
 
-def test_peak_flops_table():
-    # CPU backend: unknown kind -> None (MFU rows are skipped, not wrong)
+def test_peak_flops_table(monkeypatch):
+    # CPU backend: no entry -> None (MFU rows are skipped, not wrong)
     assert DeviceInfo.peak_flops("bf16") is None
-    # table lookup order: 'v5 lite' must match before bare 'v5' (v5p)
-    kinds = {m: p for m, p in DeviceInfo._PEAK_FLOPS}
-    assert kinds["v5 lite"]["bf16"] == 197e12
-    assert kinds["v5"]["bf16"] == 459e12
-    markers = [m for m, _ in DeviceInfo._PEAK_FLOPS]
-    assert markers.index("v5 lite") < markers.index("v5")
-    # int8 generations double where the hardware does
-    assert kinds["v5 lite"]["int8"] == 2 * kinds["v5 lite"]["bf16"]
+
+    class _Dev:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    from tpulab.tpu import device_info as di
+    # exact device_kind keys: the string a v5e chip reports resolves...
+    monkeypatch.setattr(di.plat, "local_device", lambda i=0: _Dev("TPU v5 lite"))
+    assert DeviceInfo.peak_flops("bf16") == 197e12
+    assert DeviceInfo.peak_flops("int8") == 2 * 197e12
+    # ...and is never confused with v5p by substring
+    monkeypatch.setattr(di.plat, "local_device", lambda i=0: _Dev("TPU v5"))
+    assert DeviceInfo.peak_flops("bf16") == 459e12
+    # an unknown TPU kind is an error, not a default
+    monkeypatch.setattr(di.plat, "local_device",
+                        lambda i=0: _Dev("TPU v5 experimental"))
+    with pytest.raises(KeyError, match="TPU v5 experimental"):
+        DeviceInfo.peak_flops("bf16")
 
 
 def test_tpu_memory_types():

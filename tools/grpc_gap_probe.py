@@ -56,7 +56,9 @@ def main() -> None:
     ap.add_argument("--client-port", type=int, default=None,
                     help="internal: run as siege client against PORT")
     args = ap.parse_args()
-    if args.cpu:
+    if args.cpu or args.client_port is not None:
+        # the siege client child must never touch the chip its parent
+        # (the server) holds
         from tpulab.tpu.platform import force_cpu
         force_cpu(1)
     if args.client_port is not None:

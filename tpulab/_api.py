@@ -157,7 +157,7 @@ class InferenceManager(_EngineManager):
         res.draining = True
         t0 = _time.monotonic()
         deadline = t0 + max(timeout, settle_s)
-        while _time.monotonic() < deadline:
+        while _time.monotonic() < deadline and self._server is not None:
             settled = _time.monotonic() - t0 >= settle_s
             if settled and res.inflight_requests == 0:
                 return True

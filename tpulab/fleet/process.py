@@ -23,6 +23,16 @@ Lifecycle contracts:
 - **retire** = SIGTERM → ``term_grace_s`` wait → SIGKILL, then reap.
   Exit codes are retained (``exit_code``) so the supervisor can tell a
   graceful 0 from a chaos kill (``chaos.KILL_EXIT_CODE``).
+
+One process for each chip: replicas run on a 1-device CPU platform unless
+``replica_args`` carry ``--native-platform``.  A native replica holds a
+chip for its whole life and a chip belongs to one process, so the
+provider then needs ``chips`` — the local chip indices it may hand out —
+binds each child to one (``TPU_VISIBLE_DEVICES``), and refuses a spawn
+when none is free instead of starting a child that would hang on a held
+chip.  The parent itself must never touch a JAX backend (importing
+``tpulab.fleet`` / ``tpulab.rpc`` does not).  Every replica names its
+platform in the ``PORT`` handshake (:meth:`platform_of`).
 """
 
 from __future__ import annotations
@@ -35,25 +45,36 @@ import subprocess
 import sys
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 from tpulab.fleet.autoscaler import ReplicaProvider, spawn_with_retry
 
 log = logging.getLogger("tpulab.fleet")
 
-__all__ = ["SubprocessReplicaProvider"]
+__all__ = ["SubprocessReplicaProvider", "chip_env"]
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+def chip_env(chip: int) -> Dict[str, str]:
+    """Environment that binds one child process to one local chip (a
+    single-process, single-chip topology), for launchers whose children
+    each serve on their own chip."""
+    return {"TPU_VISIBLE_DEVICES": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 class _Replica:
     """One spawned process + its cached Status client."""
 
-    __slots__ = ("proc", "client", "address")
+    __slots__ = ("proc", "client", "address", "platform", "chip")
 
-    def __init__(self, proc, client, address: str):
+    def __init__(self, proc, client, address: str, platform: str,
+                 chip: Optional[int]):
         self.proc, self.client, self.address = proc, client, address
+        self.platform, self.chip = platform, chip
 
 
 class SubprocessReplicaProvider(ReplicaProvider):
@@ -67,9 +88,16 @@ class SubprocessReplicaProvider(ReplicaProvider):
                  ready_timeout_s: float = 180.0,
                  term_grace_s: float = 5.0,
                  env: Optional[Dict[str, str]] = None,
-                 python: Optional[str] = None):
+                 python: Optional[str] = None,
+                 chips: Sequence[int] = ()):
         self._model = model
         self._replica_args = tuple(replica_args)
+        self._native = "--native-platform" in self._replica_args
+        if self._native and not chips:
+            raise ValueError(
+                "--native-platform replicas each hold a chip: pass chips= "
+                "(the local chip indices this provider may hand out)")
+        self._free_chips = list(chips)
         self._ready_timeout_s = float(ready_timeout_s)
         self._term_grace_s = float(term_grace_s)
         self._env = dict(env or {})
@@ -80,13 +108,31 @@ class SubprocessReplicaProvider(ReplicaProvider):
 
     # -- spawn ---------------------------------------------------------------
     def spawn(self, extra_env: Optional[Dict[str, str]] = None) -> str:
-        return spawn_with_retry(lambda: self._spawn_once(extra_env),
-                                backoff_s=0.25)
+        chip = None
+        if self._native:
+            with self._lock:
+                if not self._free_chips:
+                    raise RuntimeError(
+                        "no free chip for another --native-platform replica "
+                        f"({len(self._replicas)} live, each holding one): a "
+                        "chip belongs to one process")
+                chip = self._free_chips.pop(0)
+        try:
+            return spawn_with_retry(
+                lambda: self._spawn_once(extra_env, chip), backoff_s=0.25)
+        except BaseException:
+            if chip is not None:
+                with self._lock:
+                    self._free_chips.append(chip)
+            raise
 
-    def _spawn_once(self, extra_env: Optional[Dict[str, str]]) -> str:
+    def _spawn_once(self, extra_env: Optional[Dict[str, str]],
+                    chip: Optional[int]) -> str:
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (_REPO, env.get("PYTHONPATH")) if p)
+        if chip is not None:
+            env.update(chip_env(chip))
         env.update(self._env)
         env.update(extra_env or {})
         cmd = [self._python, "-m", "tpulab.fleet.replica_main",
@@ -96,21 +142,28 @@ class SubprocessReplicaProvider(ReplicaProvider):
                                 env=env)
         deadline = time.monotonic() + self._ready_timeout_s
         try:
-            port = self._read_port(proc, deadline)
+            port, platform = self._read_port(proc, deadline)
+            if self._native and platform == "cpu":
+                raise RuntimeError(
+                    "--native-platform replica came up on the CPU platform")
             addr = f"127.0.0.1:{port}"
             client = self._gate_ready(proc, addr, deadline)
         except Exception:
             self._reap(proc)
             raise
         with self._lock:
-            self._replicas[addr] = _Replica(proc, client, addr)
-        log.info("fleet spawn: replica %s up (pid %d)", addr, proc.pid)
+            self._replicas[addr] = _Replica(proc, client, addr, platform,
+                                            chip)
+        log.info("fleet spawn: replica %s up (pid %d, platform %s%s)", addr,
+                 proc.pid, platform,
+                 "" if chip is None else f", chip {chip}")
         return addr
 
     @staticmethod
-    def _read_port(proc, deadline: float) -> int:
-        """Wait for the child's ``PORT <n>`` line (the only thing it
-        prints on stdout) without ever blocking past the deadline."""
+    def _read_port(proc, deadline: float):
+        """Wait for the child's ``PORT <n> platform=<p>`` line (the only
+        thing it prints on stdout) without ever blocking past the
+        deadline.  Returns ``(port, platform)``."""
         buf = ""
         fd = proc.stdout
         while time.monotonic() < deadline:
@@ -125,7 +178,8 @@ class SubprocessReplicaProvider(ReplicaProvider):
                 continue
             buf += chunk
             if chunk.startswith("PORT "):
-                return int(chunk.split()[1])
+                fields = chunk.split()
+                return int(fields[1]), fields[2].partition("=")[2]
         raise TimeoutError(f"replica never printed PORT (stdout={buf!r})")
 
     def _gate_ready(self, proc, addr: str, deadline: float):
@@ -193,6 +247,8 @@ class SubprocessReplicaProvider(ReplicaProvider):
         self._reap_streams(proc)
         with self._lock:
             self._exit_codes[address] = proc.returncode
+            if rep.chip is not None:
+                self._free_chips.append(rep.chip)
         try:
             rep.client.close()
         except Exception:  # pragma: no cover - teardown best-effort
@@ -217,6 +273,13 @@ class SubprocessReplicaProvider(ReplicaProvider):
             if rep is not None:
                 return rep.proc.poll()
             return self._exit_codes.get(address)
+
+    def platform_of(self, address: str) -> Optional[str]:
+        """The JAX platform the replica reported in its handshake
+        (``"cpu"`` unless it was spawned ``--native-platform``)."""
+        with self._lock:
+            rep = self._replicas.get(address)
+        return None if rep is None else rep.platform
 
     def pid_of(self, address: str) -> Optional[int]:
         with self._lock:

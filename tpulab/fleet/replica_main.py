@@ -5,8 +5,9 @@ tpulab.fleet.replica_main``) once per replica: a paged
 :class:`~tpulab.engine.paged.ContinuousBatcher` behind the full gRPC
 service, fixed-seed weights so every replica in the fleet is bit-exact
 interchangeable (the property resume-from-delivered failover rides on),
-``PORT <n>`` printed on stdout once the server is bound, then a quiet
-main loop until a signal arrives.  Promoted from
+``PORT <n> platform=<jax platform>`` printed on stdout once the server
+is bound (so nobody reads a CPU fleet as a chip fleet), then a quiet main
+loop until a signal arrives.  Promoted from
 ``tests/helpers_lm_server.py`` — the test helper stays (dense engine,
 trace autosave); this is the production-shaped variant the provider
 owns.
@@ -44,7 +45,8 @@ def build_parser() -> argparse.ArgumentParser:
         prog="python -m tpulab.fleet.replica_main",
         description="one tpulab fleet replica (module docstring)")
     ap.add_argument("--port", type=int, default=0,
-                    help="gRPC port (0 = ephemeral; printed as 'PORT <n>')")
+                    help="gRPC port (0 = ephemeral; printed as "
+                         "'PORT <n> platform=<p>')")
     ap.add_argument("--model-name", default="lm")
     ap.add_argument("--role", default="unified",
                     choices=("unified", "prefill", "decode"))
@@ -65,9 +67,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "only across identical weights)")
     ap.add_argument("--no-prefix-cache", action="store_true")
     ap.add_argument("--native-platform", action="store_true",
-                    help="serve on the native accelerator instead of "
-                         "forcing a 1-device CPU platform (the default "
-                         "keeps spawn cheap for tests/laptops)")
+                    help="serve on JAX's default backend (the chip this "
+                         "process can see) instead of forcing a 1-device "
+                         "CPU platform; a chip belongs to one process, so "
+                         "the launcher must bind each replica to its own "
+                         "(SubprocessReplicaProvider chips=)")
     ap.add_argument("--drain-timeout-s", type=float, default=120.0,
                     help="SIGUSR1 drain budget")
     ap.add_argument("--drain-settle-s", type=float, default=0.2,
@@ -169,6 +173,7 @@ def main(argv=None) -> int:
 
     stop = threading.Event()
     draining = threading.Event()
+    background = []          # our helper threads, joined before exit
 
     if trace_rec is not None or flight_rec is not None:
         # periodic autosave (the helpers_lm_server discipline): a
@@ -177,8 +182,9 @@ def main(argv=None) -> int:
             while not stop.wait(max(0.05, args.autosave_s)):
                 dump_evidence()
 
-        threading.Thread(target=autosave, name="replica-evidence",
-                         daemon=True).start()
+        background.append(threading.Thread(
+            target=autosave, name="replica-evidence", daemon=True))
+        background[-1].start()
 
     def start_drain(*_sig) -> None:
         # preStop: idempotent, asynchronous — the signal handler must
@@ -192,8 +198,9 @@ def main(argv=None) -> int:
                       settle_s=args.drain_settle_s)
             dump_evidence()  # drained = quiesced: a consistent capture
 
-        threading.Thread(target=run_drain, name="replica-drain",
-                         daemon=True).start()
+        background.append(threading.Thread(
+            target=run_drain, name="replica-drain", daemon=True))
+        background[-1].start()
 
     def request_stop(*_sig) -> None:
         stop.set()
@@ -202,7 +209,9 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGTERM, request_stop)
     signal.signal(signal.SIGINT, request_stop)
 
-    print(f"PORT {mgr.server.bound_port}", flush=True)
+    import jax
+    print(f"PORT {mgr.server.bound_port} "
+          f"platform={jax.devices()[0].platform}", flush=True)
     while not stop.wait(0.2):
         pass
 
@@ -219,6 +228,11 @@ def main(argv=None) -> int:
             closer()
         except Exception:
             pass
+    # no thread of ours may outlive main: a daemon thread the interpreter
+    # kills while finalizing aborts the process (rc -6, not the graceful 0
+    # the supervisor reads).  Both end within a poll of stop/shutdown.
+    for t in background:
+        t.join(timeout=5.0)
     return 0
 
 

@@ -9,9 +9,14 @@ trtlab/core); ours lives in ``cpp/`` as ``libtpulab_native.so`` with a C API
   with the Python framework (descriptors, trackers, make_allocator) while the
   allocation math runs native
 - :class:`NativeTokenPool` — futex-backed blocking token pool
-- :func:`available` — feature gate; everything degrades to the pure-Python
-  implementations when the library is absent (build with:
-  ``cmake -S cpp -B cpp/build -G Ninja && ninja -C cpp/build``)
+- :func:`available` / :func:`enabled` — the feature gate.  The library is
+  taken from ``TPULAB_NATIVE_LIB`` if set, else from ``cpp/build/`` (build
+  with ``cmake -S cpp -B cpp/build -G Ninja && ninja -C cpp/build``;
+  git-ignored, so a clean checkout has none).  Without it the engine's
+  pools and allocators are the pure-Python implementations: a different
+  host program, so anything that reports a result also reports
+  :func:`loaded_path` (``chip_smoke.py`` builds its own copy and prints
+  ``native_core=true|false``).
 """
 
 from __future__ import annotations
@@ -25,6 +30,7 @@ from tpulab.memory.memory_type import HostMemory, MemoryType
 
 _ffi = None
 _lib = None
+_path: Optional[str] = None
 
 _CDEF = """
 typedef struct tpl_arena tpl_arena;
@@ -74,7 +80,7 @@ def _candidate_paths():
 
 
 def _load():
-    global _ffi, _lib
+    global _ffi, _lib, _path
     if _lib is not None:
         return True
     try:
@@ -89,7 +95,7 @@ def _load():
                 lib = ffi.dlopen(path)
             except OSError:
                 continue
-            _ffi, _lib = ffi, lib
+            _ffi, _lib, _path = ffi, lib, path
             return True
     return False
 
@@ -102,6 +108,11 @@ def enabled() -> bool:
     """Built AND not disabled via ``TPULAB_NO_NATIVE=1`` (the A/B knob the
     engine's pool/staging selection honors)."""
     return os.environ.get("TPULAB_NO_NATIVE") != "1" and available()
+
+
+def loaded_path() -> Optional[str]:
+    """The file the native core was loaded from; None = pure Python."""
+    return _path if _load() else None
 
 
 def version() -> Optional[str]:

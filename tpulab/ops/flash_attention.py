@@ -99,9 +99,7 @@ def _flash_bhd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q,), jnp.float32),      # running normalizer
             pltpu.VMEM((block_q, d), jnp.float32),    # running numerator
         ],
-        # CompilerParams was TPUCompilerParams on older jax (0.4.x)
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -208,8 +206,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
         raise ValueError(f"seq len {t} must divide block sizes "
                          f"({block_q}, {block_k})")
     if interpret is None:
-        from tpulab.tpu.platform import is_tpu
-        interpret = not is_tpu()
+        from tpulab.tpu.platform import pallas_interpret
+        interpret = pallas_interpret()
 
     def to_bhd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)

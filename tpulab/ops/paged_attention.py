@@ -289,8 +289,8 @@ def paged_decode_attention(q, kv_pool, tables, lengths,
     Returns (B, Hq, D).
     """
     if interpret is None:
-        from tpulab.tpu.platform import is_tpu
-        interpret = not is_tpu()
+        from tpulab.tpu.platform import pallas_interpret
+        interpret = pallas_interpret()
     return _paged_attn(q, kv_pool, tables.astype(jnp.int32),
                        lengths.astype(jnp.int32), interpret,
                        g_pages=g_pages, nbuf=nbuf)

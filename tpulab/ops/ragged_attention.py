@@ -55,6 +55,77 @@ from tpulab.ops.paged_attention import _block_geometry
 
 _NEG = -1e30
 
+_LANES, _SUBLANES = 128, 8         # one f32 vector register / tile
+#: Mosaic's default scoped-VMEM limit; a kernel that needs more asks for it
+_VMEM_SCOPED_DEFAULT = 16 << 20
+#: the most this kernel asks for (a v5e core has 128 MiB of VMEM, and the
+#: XLA fusions around the call keep their own share)
+_VMEM_REQUEST_MAX = 96 << 20
+
+
+def _plan(m: int, h: int, hkv: int, d: int, page_size: int, max_pages: int,
+          q_dtype, kv_dtype, g_pages: int | None = None,
+          nbuf: int | None = None) -> tuple[int, int, int]:
+    """``(g_pages, nbuf, vmem_bytes)``: the block geometry (auto unless
+    pinned) and the VMEM one grid step then holds, from the kernel's own
+    shapes: the page pipeline, the double-buffered q/o blocks, the f32
+    copies, the per-head (max, normalizer, accumulator) carry — live
+    twice across a loop step, its (M, 1) columns padded to a full
+    128-lane tile — and the score tiles.  Mosaic's own temporaries come
+    on top: the caller leaves headroom."""
+    def pad(n, to):
+        return -(-n // to) * to
+    q_item, kv_item = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
+    auto_g, auto_nbuf = _block_geometry(page_size, max_pages, hkv * d,
+                                        kv_item)
+    g_pages, nbuf = g_pages or auto_g, nbuf or auto_nbuf
+    m = pad(m, _SUBLANES)
+    gs = g_pages * page_size
+    kv_buf = nbuf * 2 * gs * hkv * d * kv_item
+    q_o_blocks = 2 * 2 * m * h * d * q_item
+    q_f32 = m * h * d * 4
+    carry = 2 * h * m * (pad(d, _LANES) + 2 * _LANES) * 4
+    kv_f32 = 2 * gs * hkv * d * 4
+    scores = 3 * m * pad(gs, _LANES) * 4
+    return g_pages, nbuf, (kv_buf + q_o_blocks + q_f32 + carry + kv_f32
+                           + scores)
+
+
+def kernel_geometry_error(q_len: int, n_heads: int, n_kv_heads: int,
+                          head_dim: int, page_size: int, max_pages: int,
+                          q_dtype, kv_dtype, g_pages: int | None = None,
+                          nbuf: int | None = None) -> str | None:
+    """Why Mosaic cannot have the ragged kernel at this geometry, or None.
+
+    The rule that selects and rejects the kernel — from shapes alone, so
+    a caller learns it at construction and a real compile error is never
+    caught to mean "use the other path".  Under a mesh pass the PER-SHARD
+    head counts.  Constraints (each seen on a v5e, jax 0.9.0):
+
+    - a page is DMA'd into a slice of the VMEM pipeline buffer, and
+      Mosaic refuses a slice that is not whole tiles: the page row
+      ``n_kv_heads * head_dim`` must be a multiple of 128 lanes and
+      ``page_size`` a multiple of 8 sublanes;
+    - one grid step's VMEM (:func:`_plan`) must fit the most the kernel
+      may request; ``q_len`` (the widest segment) and the heads per shard
+      drive it.
+    """
+    row = n_kv_heads * head_dim
+    if row % _LANES:
+        return (f"page row n_kv_heads*head_dim = {n_kv_heads}*{head_dim} = "
+                f"{row} is not a multiple of {_LANES} lanes")
+    if page_size % _SUBLANES:
+        return (f"page_size {page_size} is not a multiple of {_SUBLANES} "
+                "sublanes")
+    need = _plan(q_len, n_heads, n_kv_heads, head_dim, page_size, max_pages,
+                 q_dtype, kv_dtype, g_pages, nbuf)[2]
+    if need > _VMEM_REQUEST_MAX:
+        return (f"kernel VMEM {need >> 20} MiB for q_len={q_len}, "
+                f"{n_heads} q heads x {head_dim} exceeds the "
+                f"{_VMEM_REQUEST_MAX >> 20} MiB it may request (shorten "
+                "the segment: prefill_chunk, or shard the heads)")
+    return None
+
 
 def _ragged_attn_kernel(tables_ref, qlens_ref, kvlens_ref, q_ref,
                         kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
@@ -195,14 +266,17 @@ def _ragged_attn(q, kv_pool, tables, q_lens, kv_lens, interpret: bool,
     if h % hkv:
         raise ValueError(f"q heads {h} not divisible by kv heads {hkv}")
     max_pages = tables.shape[1]
+    if not interpret:
+        err = kernel_geometry_error(m, h, hkv, d, page_size, max_pages,
+                                    q.dtype, kv_pool.dtype, g_pages, nbuf)
+        if err:
+            raise ValueError(f"ragged_paged_attention: {err}")
     # stage pages as (2, S, Hkv*D) fused K/V blocks (contiguous reshape;
     # one DMA per page), queries as (B, M, H*D)
     q2 = q.reshape(b, m, h * d)
     kvp = kv_pool.reshape(n_pages, 2, page_size, hkv * d)
-    auto_g, auto_nbuf = _block_geometry(page_size, max_pages, hkv * d,
-                                        jnp.dtype(kv_pool.dtype).itemsize)
-    g_pages = g_pages or auto_g
-    nbuf = nbuf or auto_nbuf
+    g_pages, nbuf, need = _plan(m, h, hkv, d, page_size, max_pages, q.dtype,
+                                kv_pool.dtype, g_pages, nbuf)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,           # tables (flat), q_lens, kv_lens
         grid=(b,),
@@ -232,6 +306,11 @@ def _ragged_attn(q, kv_pool, tables, q_lens, kv_lens, interpret: bool,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, m, h * d), q.dtype),
+        # wide segments (a 256-token chunk over 16 heads) pass the 16 MiB
+        # default; ask for what the shapes need, with headroom for
+        # Mosaic's own temporaries
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
+            max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
         interpret=interpret,
     )(tables.reshape(-1), q_lens, kv_lens, q2, kvp)
     return out.reshape(b, m, h, d)
@@ -268,8 +347,8 @@ def ragged_paged_attention(q, kv_pool, tables, q_lens, kv_lens,
     Returns (B, M, Hq, D).
     """
     if interpret is None:
-        from tpulab.tpu.platform import is_tpu
-        interpret = not is_tpu()
+        from tpulab.tpu.platform import pallas_interpret
+        interpret = pallas_interpret()
     tables = tables.astype(jnp.int32)
     q_lens = q_lens.astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
@@ -278,7 +357,6 @@ def ragged_paged_attention(q, kv_pool, tables, q_lens, kv_lens,
                             interpret, g_pages=g_pages, nbuf=nbuf)
     from jax.sharding import PartitionSpec as P
 
-    from tpulab.parallel.sharding import shard_map
     n_model = dict(mesh.shape)[model_axis]
     h, hkv = q.shape[2], kv_pool.shape[3]
     if h % n_model or hkv % n_model:
@@ -288,11 +366,11 @@ def ragged_paged_attention(q, kv_pool, tables, q_lens, kv_lens,
             "shards on the heads dim")
     body = functools.partial(_ragged_attn, interpret=interpret,
                              g_pages=g_pages, nbuf=nbuf)
-    return shard_map(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(None, None, model_axis, None),
                   P(None, None, None, model_axis, None),
                   P(None, None), P(None), P(None)),
         out_specs=P(None, None, model_axis, None),
-        check_rep=False,   # pallas_call has no shard_map replication rule
+        check_vma=False,   # pallas_call has no shard_map replication rule
     )(q, kv_pool, tables, q_lens, kv_lens)

@@ -27,6 +27,8 @@ class MultiDeviceDispatcher:
         self._policy = policy
         self._rr = itertools.cycle(range(len(self._managers)))
         self._inflight = [0] * len(self._managers)
+        #: requests routed to each device so far (cf. ReplicaSet.served)
+        self.served = [0] * len(self._managers)
         self._lock = threading.Lock()
 
     @classmethod
@@ -62,6 +64,7 @@ class MultiDeviceDispatcher:
         i = self._pick()
         with self._lock:
             self._inflight[i] += 1
+            self.served[i] += 1
         fut = self._managers[i].infer_runner(model_name).infer(**arrays)
 
         def _done(_f):

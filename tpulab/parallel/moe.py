@@ -89,9 +89,8 @@ def make_expert_parallel_ffn(mesh: Mesh, axis_name: str = "model",
         return jax.lax.dynamic_slice_in_dim(full, e0, n_local, axis=1)
 
     def ffn(sharded_params, x):
-        from tpulab.parallel.sharding import shard_map
-        return shard_map(local_ffn, mesh=mesh,
-                         in_specs=(param_specs, P()),
-                         out_specs=P())(sharded_params, x)
+        return jax.shard_map(local_ffn, mesh=mesh,
+                             in_specs=(param_specs, P()),
+                             out_specs=P())(sharded_params, x)
 
     return ffn, shard_params

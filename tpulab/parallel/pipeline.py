@@ -68,10 +68,7 @@ def make_pipeline(mesh: Mesh, stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray
             return (state, collected), None
 
         def vary(v):  # carries vary over the pipeline axis (cond typing)
-            # pcast only exists on newer jax (the varying-type system);
-            # older shard_map has no vary tracking — identity is correct
-            pcast = getattr(jax.lax, "pcast", None)
-            return pcast(v, axis_name, to="varying") if pcast else v
+            return jax.lax.pcast(v, axis_name, to="varying")
 
         init = (vary(jnp.zeros((mb, d), x.dtype)), vary(jnp.zeros_like(x)))
         (_, collected), _ = jax.lax.scan(step, init,
@@ -82,8 +79,7 @@ def make_pipeline(mesh: Mesh, stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray
         return jax.lax.psum(mine, axis_name)
 
     def pipeline(sharded_params, x):
-        from tpulab.parallel.sharding import shard_map
-        return shard_map(
+        return jax.shard_map(
             local_pipeline, mesh=mesh,
             in_specs=(jax.tree_util.tree_map(lambda _: param_spec,
                                              sharded_params), P()),

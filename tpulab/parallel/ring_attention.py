@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpulab.parallel.sharding import shard_map
 
 _NEG = -1e30
 
@@ -87,11 +86,9 @@ def _ring_attn_local(q, k, v, axis_name: str, causal: bool):
         return (k_nxt, v_nxt, m, l, acc), None
 
     # mark the accumulators as varying over the mesh axis so both cond
-    # branches (skip vs attend) carry the same manual-axes type (pcast
-    # only exists on newer jax; older shard_map has no vary tracking)
+    # branches (skip vs attend) carry the same manual-axes type
     def vary(x):
-        pcast = getattr(jax.lax, "pcast", None)
-        return pcast(x, axis_name, to="varying") if pcast else x
+        return jax.lax.pcast(x, axis_name, to="varying")
 
     init = (k, v,
             vary(jnp.full((b, h, t_q), _NEG, jnp.float32)),  # running max
@@ -112,8 +109,8 @@ def ring_attention(mesh: Mesh, axis_name: str = "model", causal: bool = True):
 
     def attn(q, k, v):
         body = partial(_ring_attn_local, axis_name=axis_name, causal=causal)
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec)(q, k, v)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec)(q, k, v)
     return attn
 
 
@@ -145,6 +142,6 @@ def ulysses_attention(mesh: Mesh, axis_name: str = "model",
             raise ValueError(f"heads {q.shape[2]} not divisible by axis "
                              f"{axis_name}={mesh.shape[axis_name]}")
         body = partial(_ulysses_local, axis_name=axis_name, causal=causal)
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec)(q, k, v)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec)(q, k, v)
     return attn

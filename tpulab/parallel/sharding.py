@@ -11,23 +11,7 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
-import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map(body, mesh: Mesh, in_specs, out_specs,
-              check_rep: bool = True):
-    """``jax.shard_map`` across jax versions: the top-level name only
-    exists on newer jax; older versions (this image ships 0.4.x) carry
-    it as ``jax.experimental.shard_map.shard_map``.  ``check_rep=False``
-    disables the replication-rule checker — required for bodies that
-    contain ops without one (``pallas_call``: the ragged paged-attention
-    kernel shards through here)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_rep)
 
 
 def named_sharding(mesh: Mesh, *spec) -> NamedSharding:

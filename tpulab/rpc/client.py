@@ -26,6 +26,13 @@ from tpulab.core.async_compute import SharedPackagedTask
 
 _WRITES_DONE = object()
 
+#: tensors ride inside the messages: one b=128 batch of 224x224x3 uint8
+#: images is 19 MB, and gRPC's default limit is 4 MiB per message in each
+#: direction.  Servers and channels lift it; the service bounds a request
+#: by the model's max_batch_size instead.
+MESSAGE_SIZE_OPTIONS = (("grpc.max_receive_message_length", -1),
+                        ("grpc.max_send_message_length", -1))
+
 
 def jittered_backoff_s(retry_after_ms: int, attempt: int = 0,
                        floor_s: float = 0.05, cap_s: float = 30.0,
@@ -52,7 +59,8 @@ class ClientExecutor:
                  options: Optional[list] = None):
         self.target = target
         self._channels: List[grpc.Channel] = [
-            grpc.insecure_channel(target, options=options)
+            grpc.insecure_channel(
+                target, options=list(MESSAGE_SIZE_OPTIONS) + (options or []))
             for _ in range(max(1, channels))]
         self._rr = itertools.cycle(range(len(self._channels)))
 

@@ -30,6 +30,7 @@ import grpc
 
 from tpulab.core.dispatcher import AsyncDispatcher, Dispatcher
 from tpulab.core.resources import Resources
+from tpulab.rpc.client import MESSAGE_SIZE_OPTIONS
 from tpulab.rpc.context import BatchingContext, Context, StreamingContext
 from tpulab.rpc.executor import Executor, FiberExecutor
 
@@ -202,7 +203,8 @@ class Server:
         pool = ex.build_worker_pool()
         self._worker_pool = pool
         self._server = grpc.server(
-            pool, maximum_concurrent_rpcs=ex.max_concurrency)
+            pool, maximum_concurrent_rpcs=ex.max_concurrency,
+            options=MESSAGE_SIZE_OPTIONS)
         for service in self._services:
             handlers = {}
             for rpc in service.rpcs.values():
@@ -297,7 +299,8 @@ class Server:
 
             async def boot():
                 server = grpc.aio.server(
-                    maximum_concurrent_rpcs=self.executor.max_concurrency)
+                    maximum_concurrent_rpcs=self.executor.max_concurrency,
+                    options=MESSAGE_SIZE_OPTIONS)
                 for service in self._services:
                     handlers = {}
                     for rpc in service.rpcs.values():

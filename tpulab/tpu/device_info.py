@@ -62,37 +62,45 @@ class DeviceInfo:
             stats.get("peak_bytes_in_use"),
         )
 
-    # Public per-chip peak dense-matmul throughput (FLOP/s), keyed by
-    # PjRt device_kind substring.  Sources: cloud.google.com/tpu/docs
+    # Public per-chip peak dense-matmul throughput (FLOP/s), keyed by the
+    # exact PjRt ``device_kind`` string (the same keys jax's own
+    # pallas/mosaic/tpu_info.py matches on; a v5e chip reports
+    # "TPU v5 lite").  Sources: cloud.google.com/tpu/docs
     # system-architecture tables (bf16 peak; int8 where the generation
     # has an int8 MXU mode).  The reference exposes NVML power/clocks
     # (device_info.cc) — libtpu exposes no power/duty-cycle query via
     # PjRt, so the compute-capability table + HBM stats are the TPU
     # telemetry surface (see docs/PARITY.md).
-    _PEAK_FLOPS = (
-        ("v6", {"bf16": 918e12, "int8": 1836e12}),
-        ("v5 lite", {"bf16": 197e12, "int8": 394e12}),
-        ("v5e", {"bf16": 197e12, "int8": 394e12}),
-        ("v5", {"bf16": 459e12, "int8": 918e12}),   # v5p (after lite/e)
-        ("v4", {"bf16": 275e12, "int8": 275e12}),
-        ("v3", {"bf16": 123e12, "int8": 123e12}),
-        ("v2", {"bf16": 46e12, "int8": 46e12}),
-    )
+    _V5E = {"bf16": 197e12, "int8": 394e12}
+    _V5P = {"bf16": 459e12, "int8": 918e12}
+    _V6E = {"bf16": 918e12, "int8": 1836e12}
+    _PEAK_FLOPS = {
+        "TPU v6 lite": _V6E, "TPU v6e": _V6E,
+        "TPU v5 lite": _V5E, "TPU v5e": _V5E,
+        "TPU v5": _V5P, "TPU v5p": _V5P,
+        "TPU v4": {"bf16": 275e12, "int8": 275e12},
+        "TPU v3": {"bf16": 123e12, "int8": 123e12},
+        "TPU v2": {"bf16": 46e12, "int8": 46e12},
+    }
 
     @staticmethod
     def peak_flops(dtype: str = "bf16", index: int = 0) -> Optional[float]:
-        """Per-chip peak FLOP/s for ``dtype`` ('bf16'|'int8'), or None
-        when the device kind is unknown (e.g. CPU backends) — the MFU
+        """Per-chip peak FLOP/s for ``dtype`` ('bf16'|'int8') — the MFU
         denominator (fp32 matmuls route through the MXU at bf16-class
         rates under XLA's default precision, so bf16 is the honest
-        denominator for fp32 models too)."""
-        kind = DeviceInfo.device_kind(index).lower()
-        if "tpu" not in kind:
+        denominator for fp32 models too).  None off TPU (a CPU has no
+        entry and no MFU row); a TPU whose ``device_kind`` is not in the
+        table raises — a guessed peak is a wrong utilization."""
+        dev = plat.local_device(index)
+        if dev.platform != "tpu":
             return None
-        for marker, peaks in DeviceInfo._PEAK_FLOPS:
-            if marker in kind:
-                return peaks.get(dtype, peaks["bf16"])
-        return None
+        peaks = DeviceInfo._PEAK_FLOPS.get(dev.device_kind)
+        if peaks is None:
+            raise KeyError(
+                f"no peak-FLOP/s entry for TPU device_kind "
+                f"{dev.device_kind!r}; add it to DeviceInfo._PEAK_FLOPS "
+                f"with its source (known: {sorted(DeviceInfo._PEAK_FLOPS)})")
+        return peaks.get(dtype, peaks["bf16"])
 
     @staticmethod
     def alignment() -> int:

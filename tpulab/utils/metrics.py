@@ -793,8 +793,7 @@ class GenerationMetrics:
             self._advance(self.prefix_misses, "misses", pc.misses)
 
 
-#: swap latency buckets (seconds): device<->host page copies — sub-ms on
-#: direct-attached hosts through tens of ms on relayed PjRt links
+#: swap latency buckets (seconds): device<->host page copies
 SWAP_BUCKETS = (.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5,
                 1., 2.5)
 

@@ -5,7 +5,7 @@ k8s liveness — SURVEY §5 'no in-process retry/failover').  tpulab keeps that
 deployment posture (k8s probes hit the Health RPC) but adds the in-process
 detector those probes need on TPU: a periodic *canary dispatch* (tiny compiled
 program) that catches wedged runtimes — the failure mode where the process is
-alive but the device/tunnel no longer completes work.
+alive but the device no longer completes work.
 
 ``DeviceWatchdog`` flips ``healthy`` when canaries stop completing within
 their deadline; the Health RPC reports it, so k8s/envoy rotate the replica
