@@ -13,7 +13,10 @@ stdin and reads one a line from stdout:
 ``window``    build every payload of a plan, say ``ready``, wait for ``go``,
               say ``opened`` when the measured window starts (at once, or
               after a closed loop's ramp), ``closed`` the moment it ends,
-              drain, say ``done`` with the samples
+              drain, say ``done`` with the samples.  With ``tail_s`` > 0
+              (``--trace 2``) the same traffic goes on after ``closed``
+              until the parent says ``stop`` (or ``tail_s`` have passed),
+              and only then drains: the parent traces that tail
 ``quit``      close the channels and exit
 
 Times are ``time.monotonic()``, which on Linux is one clock for every
@@ -157,17 +160,24 @@ class Client:
                           cmd["vocab"]), q["steps"])
             for q in cmd["plan"]["requests"]]
 
-    async def run_window(self, cmd: dict, payloads: list, say) -> dict:
+    async def run_window(self, cmd: dict, payloads: list, say,
+                         stopped=None) -> dict:
+        """``stopped(tail_s)`` is awaited after ``closed`` where the command
+        has ``tail_s``: it returns when the parent says stop, or after that
+        many seconds."""
         plan = cmd["plan"]
         seconds = plan["seconds"]
+        tail_s = float(cmd.get("tail_s", 0))
         recs: list = []
+        tail_recs: list = []    # an open loop's arrivals after the window
         tasks: set = set()
 
-        async def start(i: int, due: float = None, on_first=None) -> None:
+        async def start(i: int, due: float = None, on_first=None,
+                        into: list = recs) -> None:
             q = plan["requests"][i]
             rec = {"index": q["index"], "due": due,
                    "steps": q.get("steps"), "vocab": cmd.get("vocab")}
-            recs.append(rec)
+            into.append(rec)
             # no "end": the call was still in flight when it was cancelled
             await self.generate_stream(payloads[i], rec, on_first=on_first)
             rec["end"] = time.monotonic()
@@ -189,8 +199,33 @@ class Client:
                 task.add_done_callback(tasks.discard)
             await asyncio.sleep(max(0.0, t_end - time.monotonic()))
             say({"event": "closed", "t_start": t0, "t_end": t_end})
-            if tasks:     # bounded drain, outside the window
-                await asyncio.wait(set(tasks), timeout=plan["drain_s"])
+            window_tasks = set(tasks)
+            if tail_s:
+                # the same arrivals again, a window later, at the same
+                # rate; they are no requests of the window (no due time,
+                # a list of their own)
+                over = asyncio.ensure_future(stopped(tail_s))
+                for i, q in enumerate(plan["requests"]):
+                    delay = t_end + q["due_s"] - time.monotonic()
+                    if delay > 0:
+                        await asyncio.wait({over}, timeout=delay)
+                    if over.done():
+                        break
+                    task = asyncio.ensure_future(start(i, into=tail_recs))
+                    tasks.add(task)
+                    task.add_done_callback(tasks.discard)
+                await over
+            drained_at = t_end + plan["drain_s"]
+            window_tasks = {t for t in window_tasks if not t.done()}
+            if window_tasks:     # bounded drain, outside the window
+                await asyncio.wait(window_tasks, timeout=max(
+                    0.0, drained_at - time.monotonic()))
+            for rec in recs:
+                # a tail longer than the drain: what ended after the
+                # drain's end was cancelled there without a tail
+                if rec.get("end", 0.0) > drained_at:
+                    rec["ok"] = False
+                    del rec["end"]
         else:
             n = len(plan["requests"])
             cursor = iter(range(1 << 62))
@@ -225,9 +260,12 @@ class Client:
                 except asyncio.TimeoutError:
                     pass
             t0 = time.monotonic()
-            t_end = close_at[0] = opened(t0)
+            t_end = opened(t0)
+            close_at[0] = t_end + tail_s   # the callers replay on in a tail
             await asyncio.sleep(max(0.0, t_end - time.monotonic()))
             say({"event": "closed", "t_start": t0, "t_end": t_end})
+            if tail_s:
+                await stopped(tail_s)
         unfinished = {t for t in tasks if not t.done()}
         for task in unfinished:
             task.cancel()
@@ -237,7 +275,9 @@ class Client:
                 "times", "in_range")
         return {"t_start": t0, "t_end": t_end, "mode": plan["mode"],
                 "requests": [{k: r[k] for k in keep if k in r}
-                             for r in recs]}
+                             for r in recs],
+                "tail_requests": [{k: r[k] for k in keep if k in r}
+                                  for r in tail_recs]}
 
 
 async def main() -> int:
@@ -248,19 +288,37 @@ async def main() -> int:
         sys.stdout.write(json.dumps(obj) + "\n")
         sys.stdout.flush()
 
+    reading: list = []      # the one read of stdin in flight, if any
+
+    def next_line():
+        if not reading:
+            reading.append(loop.run_in_executor(None, sys.stdin.readline))
+        return reading[0]
+
     async def read() -> dict:
-        line = await loop.run_in_executor(None, sys.stdin.readline)
+        line = await next_line()
+        reading.clear()
         if not line:                # the parent is gone
             return {"op": "quit"}
         return json.loads(line)
 
+    async def stopped_within(seconds: float) -> None:
+        """The parent's next command (``stop``), or ``seconds``.  A read
+        that timed out stays in flight for the main loop."""
+        done, _ = await asyncio.wait({next_line()}, timeout=seconds)
+        if done:
+            early.append(await read())
+    early: list = []        # what the parent said during a tail
+
     try:
         while True:
-            cmd = await read()
+            cmd = early.pop(0) if early else await read()
             op = cmd["op"]
             try:
                 if op == "quit":
                     return 0
+                if op == "stop":    # the tail it ends is over already
+                    continue
                 if op == "connect":
                     await client.connect(cmd["port"], cmd.get("channels", 1))
                     say({"ok": True})
@@ -272,9 +330,8 @@ async def main() -> int:
                     go = await read()
                     if go["op"] != "go":
                         return 0
-                    say({"event": "done",
-                         "result": await client.run_window(cmd, payloads,
-                                                           say)})
+                    say({"event": "done", "result": await client.run_window(
+                        cmd, payloads, say, stopped_within)})
                 else:
                     say({"error": f"unknown op {op!r}"})
             except Exception as e:  # noqa: BLE001 - reported to the parent, which fails the run

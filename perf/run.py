@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """tpulab's benchmark: one cell, one run, one JSON line.
 
-    python3 perf/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 perf/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 One process a run.  This process holds the chip: it builds the model from
 the seed, starts the program's own gRPC server in-process, holds the served
@@ -9,9 +9,20 @@ path to the plain reference, warms up, and then drives the client process
 (``perf/loadgen/client.py``, which never imports JAX) through a window of
 exactly ``--seconds``.  Progress goes to earlier lines; the last line of
 stdout is the result (``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, traced, ``breakdown``).  ``--trace 0`` reports the cell's
-end-to-end metrics with the profiler off; ``--trace 1`` profiles a slice of
-the window and reports its per-layer metrics.
+``device`` and, traced, ``breakdown``).
+
+``--trace 0``  the cell's end-to-end metrics, the profiler off.
+``--trace 1``  profiles a slice inside the window (Python tracer on) and
+               reports the per-layer metrics only: a run of its own, whose
+               window is no measurement.
+``--trace 2``  ``--trace 0`` to the letter until the window has closed and
+               its numbers are taken; then, in the same process and on the
+               same traffic (the client keeps it up), starts and stops the
+               profiler once for nothing, traces ``TRACE_SECONDS`` with the
+               program's own switch (``tpulab.utils.tracing``), and reports
+               both kinds of metric on one line: end-to-end numbers and
+               counter deltas from the window, trace and sampler readings
+               from the traced tail.
 
 A cell needs a TPU with at least its ``chips``: anything else exits non-zero
 before it measures.  There is no CPU fallback.  (``--allow-cpu`` exists for
@@ -56,6 +67,9 @@ EXIT_NO_CHIP = 2
 DEADLINE_S, EXIT_GRACE_S = 1150, 60
 #: the traced slice of the window: starts this far in, lasts this long
 TRACE_START_S, TRACE_SECONDS = 2.0, 3.0
+#: --trace 2: how long the client keeps the traffic up after the window if
+#: this process never says stop (it says so as soon as the trace is taken)
+TRACE_TAIL_MAX_S = 60.0
 GAUGE_PERIOD_S = 0.05
 
 
@@ -124,18 +138,41 @@ def trace_slice(trace_dir: str, t_start: float, span: list) -> None:
     """Profile ``TRACE_SECONDS`` of the window, starting ``TRACE_START_S``
     after ``t_start``, inside one host span the reducer clips to; ``span``
     gets the span's own start and end on this process's monotonic clock."""
-    import jax
-
-    from harness.trace_reduce import WINDOW_SPAN
     time.sleep(max(0.0, t_start + TRACE_START_S - time.monotonic()))
-    jax.profiler.start_trace(trace_dir)
+    hold_window_span(trace_dir, span, python_tracer=True)
+
+
+def hold_window_span(trace_dir: str, span: list, **start_kw) -> None:
+    """One capture through the program's switch, ``TRACE_SECONDS`` long,
+    all of it inside the host span the reducer clips to."""
+    from harness.trace_reduce import WINDOW_SPAN
+    from tpulab.utils import tracing
+    tracing.start(trace_dir, **start_kw)
     try:
-        with jax.profiler.TraceAnnotation(WINDOW_SPAN):
+        with tracing.annotate(WINDOW_SPAN):
             t0 = time.monotonic()
             time.sleep(TRACE_SECONDS)
             span[:] = [t0, time.monotonic()]
     finally:
-        jax.profiler.stop_trace()
+        tracing.stop()
+
+
+def trace_tail(trace_dir: str, adapter, span: list) -> list:
+    """``--trace 2``, after the window has closed: start and stop the
+    profiler once and throw that away (the first start's cost falls into
+    no number), then trace ``TRACE_SECONDS`` of the traffic the client
+    keeps up, sampling the gauges meanwhile; returns the samples."""
+    from tpulab.utils import tracing
+    tracing.start(trace_dir + ".first")
+    tracing.stop()
+    shutil.rmtree(trace_dir + ".first", ignore_errors=True)
+    sampler = GaugeSampler(adapter)
+    sampler.start()
+    try:
+        hold_window_span(trace_dir, span)
+    finally:
+        sampler.stop()
+    return sampler.samples
 
 
 def device_block(devices, chips: int) -> dict:
@@ -163,7 +200,11 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0,
+                    help="0: end-to-end metrics, profiler off; 1: per-layer "
+                    "metrics from a slice profiled inside the window; 2: as "
+                    "0, then a traced tail of the same traffic in the same "
+                    "run, and both kinds of metric")
     ap.add_argument("--benchmark", default=None, help=argparse.SUPPRESS)
     ap.add_argument("--allow-cpu", action="store_true",
                     help=argparse.SUPPRESS)
@@ -224,6 +265,7 @@ def main(argv=None) -> int:
         adapter.warm_up(client)
         say(f"warm-up done ({compiles.total - n0} compilations in it)")
         client.send({"op": "window", "plan": plan, "seed": args.seed,
+                     "tail_s": TRACE_TAIL_MAX_S if args.trace == 2 else 0,
                      **adapter.window_args()})
         client.expect("ready")
 
@@ -235,7 +277,7 @@ def main(argv=None) -> int:
         host_span: list = []
         trace_dir = os.path.join(CACHE_DIR, "trace", cell.name)
         compiles.start()
-        if args.trace:
+        if args.trace == 1:
             sampler = GaugeSampler(adapter)
             sampler.start()
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -247,12 +289,29 @@ def main(argv=None) -> int:
         setup_s = closed["t_start"] - T_PROCESS_START
         compiles.stop()
         after = adapter.counters()
+        gauges = []
         if sampler is not None:
             sampler.stop()
+            gauges = sampler.samples
         say(f"window closed after {closed['t_end'] - closed['t_start']:.3f} s"
             f"; draining")
+        if args.trace == 2:
+            # the window's numbers are taken: everything from here on is
+            # the traced tail, on the traffic the client keeps up
+            in_window = compiles.in_window
+            compiles.start()
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            try:
+                gauges = trace_tail(trace_dir, adapter, host_span)
+            finally:
+                client.send({"op": "stop"})
+            compiles.stop()
+            say(f"traced tail: {host_span[1] - host_span[0]:.3f} s, "
+                f"{host_span[0] - closed['t_end']:.3f} s after the window; "
+                f"compilations: {in_window} in the window, "
+                f"{compiles.in_window - in_window} in the tail")
         result = client.expect("done")["result"]
-        if args.trace:
+        if args.trace == 1:
             tracer.join(timeout=120)
         device = device_block(devices, cell.chips)
     finally:
@@ -260,6 +319,9 @@ def main(argv=None) -> int:
         adapter.shutdown()
 
     win = reduce_window(result)
+    # an open loop's arrivals after the window: no requests of the window,
+    # but their tokens are the traced tail's
+    win["records"] = win["records"] + result.get("tail_requests", [])
     say(f"attempted {win['attempted']}, failed {win['failed']} "
         f"(completed but wrong: {win['invalid']}), completed "
         f"{len(win['completed'])}" + (f"; errors: {win['errors']}"
@@ -272,12 +334,18 @@ def main(argv=None) -> int:
         kinds = {k: v - before["dispatch"]["kinds"][k]
                  for k, v in after["dispatch"]["kinds"].items()}
         say(f"scheduler counters over the window: {moved} kinds={kinds}")
+        if "stages" in after["dispatch"]:
+            split = {k: v["s"] - before["dispatch"]["stages"][k]["s"]
+                     for k, v in after["dispatch"]["stages"].items()}
+            say("scheduler stages over the window, seconds: " + ", ".join(
+                f"{k} {v:.3f}" for k, v in split.items())
+                + f"; sum {sum(split.values()):.3f} of {win['seconds']:.3f}")
     if compiles.in_window:
         say(f"WARNING: {compiles.in_window} compilation(s) inside the "
             "measured window: a shape was not warmed up")
     ctx = {"window": win, "cell": cell, "plan": plan, "setup_s": setup_s,
            "counters_before": before, "counters_after": after,
-           "gauges": sampler.samples if sampler else [],
+           "gauges": gauges,
            "compiles_in_window": compiles.in_window, "trace": None, "say": say}
     out = {"correct": bool(reference_ok and win["invalid"] == 0
                            and win["attempted"] > 0),
@@ -290,9 +358,17 @@ def main(argv=None) -> int:
             if not rehearsal:       # a CPU trace has no TPU plane
                 raise
             trace = None
+        if args.trace == 2:
+            shutil.rmtree(trace_dir, ignore_errors=True)
     if args.trace and trace is not None:
         ctx["trace"] = trace
         trace["host_span"] = host_span
+        lo, hi = host_span
+        in_span = sum(1 for r in win["records"] if not r.get("error")
+                      for t in r.get("times", ()) if lo <= t <= hi)
+        say(f"traced slice: {in_span} tokens reached the client in "
+            f"{hi - lo:.3f} s ({in_span / (hi - lo):.1f} a second, the "
+            "profiler on)")
         say(f"trace: window {trace['window_s']:.3f} s, device busy "
             f"{trace['busy_s']:.3f} s; programs: " + ", ".join(
                 f"{k} x{v['count']} {v['total_s']:.3f}s"
@@ -302,12 +378,13 @@ def main(argv=None) -> int:
         device["window_s"] = trace["window_s"]
         out["breakdown"] = {"device_ops": trace["device_ops"],
                             "idle_gaps": trace["idle_gaps"]}
+    out["metrics"] = {}
+    if args.trace != 1:
+        out["metrics"].update(read_metrics(cell, "e2e_metrics",
+                                           cell.end_to_end, ctx))
     if args.trace:
-        out["metrics"] = read_metrics(cell, "layer_metrics", cell.per_layer,
-                                      ctx)
-    else:
-        out["metrics"] = read_metrics(cell, "e2e_metrics", cell.end_to_end,
-                                      ctx)
+        out["metrics"].update(read_metrics(cell, "layer_metrics",
+                                           cell.per_layer, ctx))
     out["device"] = device
     if rehearsal:
         out["rehearsal"] = "CPU run of a test cell: not a measurement"

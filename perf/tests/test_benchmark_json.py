@@ -19,7 +19,9 @@ WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj).*size|_dim$|"
 
 def test_keys_and_limits():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+                          "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    assert BENCH["trace_in_run"] is True
     assert BENCH["command"][:2] == ["python3", "perf/run.py"]
     assert BENCH["paths"] == ["perf"]
     assert isinstance(BENCH["run_seconds"], int)

@@ -1,22 +1,280 @@
-"""Tracing/StageTimer tests."""
+"""Tracing tests: the profiler switch, scheduler stages, program names,
+request-lifecycle traces."""
+
+import os
+import threading
+import time
 
 import numpy as np
 import pytest
 
-from tpulab.utils.tracing import StageTimer, annotate
+from tpulab.utils import tracing
+from tpulab.utils.tracing import annotate
 
 REPO = __file__.rsplit("/tests/", 1)[0]
 
 
-def test_stage_timer_splits():
+def _tiny_engine(**kw):
     import jax.numpy as jnp
-    t = StageTimer()
-    with t.stage("a"):
-        x = jnp.ones((64, 64)) @ jnp.ones((64, 64))
-    with t.stage("b", sync_on=x):
-        y = x * 2
-    assert set(t.stages_ms) == {"a", "b"}
-    assert t.total_ms > 0
+
+    from tpulab.engine.paged import ContinuousBatcher
+    from tpulab.models.transformer import init_transformer_params
+    params = init_transformer_params(vocab=64, d_model=32, n_heads=2,
+                                     n_layers=2, d_ff=64)
+    kw.setdefault("lanes", 2)
+    return ContinuousBatcher(params, n_heads=2, n_layers=2, max_len=64,
+                             page_size=8, compute_dtype=jnp.float32, **kw)
+
+
+def _captures(log_dir):
+    base = os.path.join(log_dir, "plugins", "profile")
+    return [os.path.join(root, f) for root, _d, files in os.walk(base)
+            for f in files if f.endswith(".xplane.pb")]
+
+
+@pytest.fixture
+def switch_closed():
+    """The switch is process-wide: leave it closed whatever a test did."""
+    assert not tracing.active()
+    yield
+    tracing.stop()
+
+
+def test_switch_starts_and_stops_twice(tmp_path, switch_closed):
+    """start/stop any number of times in one process, from any thread;
+    every capture leaves its own trace directory; stop is idempotent."""
+    import jax.numpy as jnp
+    dirs = [str(tmp_path / "a"), str(tmp_path / "b")]
+    assert tracing.start(dirs[0]) == dirs[0] and tracing.active()
+    (jnp.ones((8, 8)) * 2).block_until_ready()
+    assert tracing.stop() == dirs[0] and not tracing.active()
+    assert tracing.stop() is None                  # nothing open: a no-op
+    other = threading.Thread(target=tracing.start, args=(dirs[1],))
+    other.start()
+    other.join(timeout=30)
+    assert tracing.active()
+    with annotate("test-region", trace_id="abc"):
+        (jnp.ones((8, 8)) * 3).block_until_ready()
+    assert tracing.stop() == dirs[1]
+    for d in dirs:
+        assert _captures(d), f"no capture under {d}"
+
+
+def test_switch_second_start_raises_and_first_closes(tmp_path,
+                                                     switch_closed):
+    first, second = str(tmp_path / "first"), str(tmp_path / "second")
+    tracing.start(first)
+    with pytest.raises(tracing.ProfilerBusy, match="already open"):
+        tracing.start(second)
+    with pytest.raises(tracing.ProfilerBusy):
+        with tracing.trace(second):
+            pass
+    assert tracing.active()                 # the first capture still stands
+    assert tracing.stop() == first
+    assert _captures(first) and not os.path.exists(second)
+    with tracing.trace(second, python_tracer=True):   # and a next one runs
+        pass
+    assert _captures(second)
+
+
+def test_arm_profile_goes_through_switch(tmp_path, switch_closed):
+    """The Debug RPC's profile_ticks and any other owner share the one
+    switch: armed while a capture is open -> the switch's error, no JAX
+    crash; armed alone -> the capture lands and the switch closes."""
+    cb = _tiny_engine()
+    try:
+        tracing.start(str(tmp_path / "other"))
+        with pytest.raises(tracing.ProfilerBusy):
+            cb.arm_profile(2)
+        assert cb.debug_state()["profile_armed"] is False
+        tracing.stop()
+        prof_dir = cb.arm_profile(2, str(tmp_path / "ticks"))
+        assert len(cb.submit(np.arange(4, dtype=np.int32), 6)
+                   .result(timeout=120)) == 6
+        deadline = time.monotonic() + 30
+        while cb._profile is not None and time.monotonic() < deadline:
+            cb.submit(np.arange(4, dtype=np.int32), 2).result(timeout=120)
+        assert cb._profile is None and not tracing.active()
+        assert _captures(prof_dir)
+    finally:
+        cb.shutdown()
+
+
+def test_stages_cover_the_scheduler_thread():
+    """debug_state()["dispatch"]["stages"]: all seven, monotone, disjoint
+    (their seconds never exceed the scheduler thread's wall time) and
+    covering (at least four fifths of it)."""
+    from tpulab.engine.paged import ContinuousBatcher
+    t_before = time.perf_counter()
+    cb = _tiny_engine(lanes=2)
+    t_running = time.perf_counter()
+    try:
+        first = cb.debug_state()["dispatch"]["stages"]
+        assert tuple(first) == ContinuousBatcher.STAGES == (
+            "admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
+        seen = []
+        futs = [cb.submit(np.arange(3 + i, dtype=np.int32), 9,
+                          on_token=lambda tok, i: seen.append(tok))
+                for i in range(5)]
+        for f in futs:
+            assert len(f.result(timeout=120)) == 9
+        mid = cb.debug_state()["dispatch"]["stages"]
+        time.sleep(0.2)                     # the scheduler idles, waiting
+    finally:
+        cb.shutdown()
+    t_after = time.perf_counter()
+    assert not cb._thread.is_alive() and len(seen) == 45
+    last = cb.debug_state()["dispatch"]["stages"]
+    for name in ContinuousBatcher.STAGES:
+        assert first[name]["s"] <= mid[name]["s"] <= last[name]["s"]
+        assert first[name]["n"] <= mid[name]["n"] <= last[name]["n"]
+        assert last[name]["n"] > 0 and last[name]["s"] > 0, name
+    total = sum(v["s"] for v in last.values())
+    assert total <= t_after - t_before
+    assert total >= 0.8 * (t_after - t_running)
+
+
+def test_wait_counters_match_the_requests():
+    """One lane, requests one after the other, nine tokens each: the
+    first from the prefill, the other eight in one K=8 block."""
+    cb = _tiny_engine(lanes=1, decode_block=8, ragged=False)
+    n = 3
+    try:
+        for i in range(n):
+            assert len(cb.submit(np.arange(4 + i, dtype=np.int32), 9)
+                       .result(timeout=120)) == 9
+        d = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    assert d["queue_waits"] == d["ttfts"] == d["first_decode_waits"] == n
+    assert d["kinds"]["decode"] == n and d["decode_block_steps"] == 8 * n
+    assert 0 < d["queue_wait_s"] <= d["ttft_s"]
+    assert d["first_decode_wait_s"] > 0
+    # single ticks count one step each; a one-token request never waits
+    # for a second token
+    cb = _tiny_engine(lanes=1, decode_block=1, ragged=True)
+    try:
+        cb.submit(np.arange(5, dtype=np.int32), 4).result(timeout=120)
+        cb.submit(np.arange(5, dtype=np.int32), 1).result(timeout=120)
+        d = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    assert d["queue_waits"] == d["ttfts"] == 2
+    assert d["first_decode_waits"] == 1
+    assert d["decode_block_steps"] == d["kinds"]["decode"] == 3
+
+
+def test_tokens_identical_with_profiler_on_and_off(tmp_path, switch_closed):
+    """The profiler and the stage spans observe, never steer."""
+    from tpulab.engine.paged import SamplingParams
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 64, (5 + i,), np.int32) for i in range(4)]
+
+    def run():
+        cb = _tiny_engine(lanes=2)
+        try:
+            futs = [cb.submit(p, 10, sampling=SamplingParams(
+                temperature=0.7 * (i % 2), seed=i, device=True))
+                for i, p in enumerate(prompts)]
+            return [f.result(timeout=120) for f in futs]
+        finally:
+            cb.shutdown()
+
+    off = run()
+    with tracing.trace(str(tmp_path / "on")):
+        on = run()
+    assert on == off
+    assert _captures(str(tmp_path / "on"))
+
+
+def _lower_args(cb, kind):
+    """The jitted step of ``kind`` and arguments of the engine's own
+    shapes to lower it with."""
+    import jax.numpy as jnp
+    b, mp = cb.lanes, cb.max_pages
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    lane = (i32(b, mp), i32(b), i32(b), jnp.zeros((b,), bool))
+    samp = (jnp.zeros((b,), jnp.float32), jnp.zeros((b, 2), jnp.uint32))
+    one = (i32(mp), i32(1, 8))
+    head = (cb.params, cb.pool.kv)
+    return {
+        "paged_decode_block_k2": lambda: (
+            cb._block_fn(2), head + lane + samp + (i32(b), i32(b, 1))),
+        "paged_decode_block_k8": lambda: (
+            cb._block_fn(8), head + lane + samp + (i32(b), i32(b, 1))),
+        "paged_decode_step": lambda: (cb._step, head + lane),
+        "paged_decode_step_sampled": lambda: (
+            cb._step_sampled, head + lane + samp),
+        "paged_mixed_step": lambda: (
+            cb._mixed, head + (i32(b, mp), i32(b, 8), i32(b), i32(b)) + samp),
+        "paged_prefill": lambda: (
+            cb._prefill, head + one + (jnp.int32(5),)),
+        "paged_extend": lambda: (
+            cb._extend, head + one + (jnp.int32(8), jnp.int32(13))),
+        "paged_speculative_block_k2": lambda: (
+            cb._spec_block_fn(2),
+            (cb.params, cb._spec["params"], cb.pool.kv, lane[0], i32(b, mp))
+            + lane[1:] + samp + (i32(b), i32(b, 1))),
+    }[kind]()
+
+
+@pytest.mark.parametrize("kind", [
+    "paged_decode_block_k2", "paged_decode_block_k8", "paged_decode_step",
+    "paged_decode_step_sampled", "paged_mixed_step", "paged_prefill",
+    "paged_extend", "paged_speculative_block_k2"])
+def test_step_programs_carry_stable_names(kind):
+    """Every step program is built in ContinuousBatcher._jit, which names
+    it after its function (+ the block size the partial binds): a trace's
+    XLA Modules line shows ``jit_<kind>``, never ``jit__unknown``."""
+    from tpulab.models.transformer import init_transformer_params
+    draft = init_transformer_params(vocab=64, d_model=32, n_heads=2,
+                                    n_layers=1, d_ff=64, seed=1)
+    cb = _tiny_engine(draft_params=draft, draft_n_layers=1)
+    try:
+        fn, args = _lower_args(cb, kind)
+        text = fn.lower(*args).as_text()
+    finally:
+        cb.shutdown()
+    assert f"module @jit_{kind} " in text.splitlines()[0]
+
+
+def _pallas_names(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_names(inner)
+
+
+@pytest.mark.parametrize("kernel", [
+    "ragged_paged_attention", "flash_attention_fwd",
+    "paged_decode_attention"])
+def test_pallas_kernels_carry_names(kernel):
+    """``name=`` on the three pallas_calls (interpret mode here): the
+    name a device trace shows the kernel under."""
+    import jax
+    import jax.numpy as jnp
+    kv = jnp.ones((5, 2, 8, 2, 32))
+    tables = jnp.zeros((2, 4), jnp.int32)
+    lens = jnp.array([3, 9], jnp.int32)
+    if kernel == "flash_attention_fwd":
+        from tpulab.ops.flash_attention import flash_attention
+        fn, q = (lambda q: flash_attention(q, q, q, causal=True)), \
+            jnp.ones((1, 128, 2, 32))
+    elif kernel == "paged_decode_attention":
+        from tpulab.ops.paged_attention import paged_decode_attention
+        fn, q = (lambda q: paged_decode_attention(q, kv, tables, lens)), \
+            jnp.ones((2, 2, 32))
+    else:
+        from tpulab.ops.ragged_attention import ragged_paged_attention
+        fn, q = (lambda q: ragged_paged_attention(
+            q, kv, tables, jnp.array([1, 2], jnp.int32), lens)), \
+            jnp.ones((2, 2, 2, 32))
+    assert list(_pallas_names(jax.make_jaxpr(fn)(q).jaxpr)) == [kernel]
+    assert kernel in jax.jit(fn).lower(q).as_text(debug_info=True)
 
 
 def test_annotate_runs():

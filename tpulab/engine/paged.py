@@ -37,6 +37,8 @@ import numpy as np
 
 from tpulab import chaos
 from tpulab.core.deadline import Deadline, DeadlineExceeded
+from tpulab.utils import tracing
+from tpulab.utils.tracing import stage
 
 
 class PagedKVPool:
@@ -1512,6 +1514,20 @@ class ContinuousBatcher:
         #: prefill+decode rounds
         self.dispatch_kinds: Dict[str, int] = {"decode": 0, "verify": 0,
                                                "mixed": 0}
+        #: sum of K over plain decode dispatches (K-blocks and single
+        #: ticks): over ``dispatch_kinds["decode"]`` it is the mean block
+        self.decode_block_steps = 0
+        #: where a scheduler pass goes (docs/OBSERVABILITY.md "Debugz"):
+        #: disjoint stages of the scheduler thread, each a ``sched.<stage>``
+        #: span in a profiler capture and seconds + entries here
+        self._stages = tracing.StageClock(self.STAGES, prefix="sched.")
+        #: request waits, summed where the observers above see them
+        #: (seconds, count): submit -> prefill start, submit -> first
+        #: token, first token -> second token (what a newly admitted lane
+        #: waits for the running chain)
+        self.queue_wait_s, self.queue_waits = 0.0, 0
+        self.ttft_s, self.ttfts = 0.0, 0
+        self.first_decode_wait_s, self.first_decode_waits = 0.0, 0
         if prefill_flash is None:
             # auto: pallas flash attention for the FULL-PROMPT forward on
             # TPU (O(T*block) VMEM instead of a dense (T, T) score
@@ -1686,6 +1702,9 @@ class ContinuousBatcher:
                                         daemon=True)
         self._thread.start()
 
+    #: the stages of a scheduler pass, in the order a pass takes them
+    STAGES = ("admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
+
     def _jit(self, fn, donate, in_sh, out_sh):
         """``jax.jit`` with explicit in/out shardings under a mesh — the
         partitioner then inserts the collectives (psum after row-parallel
@@ -1710,13 +1729,19 @@ class ContinuousBatcher:
         headroom math never saw."""
         import jax
 
+        base = getattr(fn, "func", fn)
+        if fn is not base:
+            # a bare partial is ``jit__unknown`` in a trace: name the
+            # program after its function (+ the block size it binds)
+            k = fn.keywords.get("k")
+            fn.__name__ = base.__name__ + (f"_k{k}" if k is not None else "")
+
         def build():
             if self.mesh is None:
                 return jax.jit(fn, donate_argnums=donate)
             return jax.jit(fn, donate_argnums=donate,
                            in_shardings=in_sh, out_shardings=out_sh)
 
-        base = getattr(fn, "func", fn)
         try:
             key = (base.__module__, base.__qualname__,
                    getattr(fn, "args", ()),
@@ -2112,12 +2137,17 @@ class ContinuousBatcher:
 
     # -- debugz (tpulab.obs.debugz) -----------------------------------------
     def arm_profile(self, ticks: int, log_dir: Optional[str] = None) -> str:
-        """Arm ``jax.profiler`` around the next ``ticks`` scheduler ticks
-        (the Debug RPC's ``profile_ticks``).  The capture starts at the
-        next pass the scheduler runs and stops after ``ticks`` passes;
-        returns the trace directory (``tensorboard --logdir`` it)."""
+        """Arm the process's profiler switch (``tracing.start``) around the
+        next ``ticks`` scheduler ticks (the Debug RPC's ``profile_ticks``).
+        The capture starts at the next pass the scheduler runs and stops
+        after ``ticks`` passes; returns the trace directory
+        (``tensorboard --logdir`` it).  Raises ``tracing.ProfilerBusy``
+        while another owner's capture is open."""
         if int(ticks) < 1:
             raise ValueError("profile_ticks must be >= 1")
+        if tracing.active():
+            raise tracing.ProfilerBusy(
+                "a profiler capture is already open in this process")
         if log_dir is None:
             import tempfile
             log_dir = tempfile.mkdtemp(prefix="tpulab-profile-")
@@ -2135,19 +2165,24 @@ class ContinuousBatcher:
         prof = self._profile
         if prof is None:
             return
-        import jax
         if done:
             if prof["active"]:
-                jax.profiler.stop_trace()
+                tracing.stop()
             self._profile = None
             return
         if not prof["active"]:
-            jax.profiler.start_trace(prof["dir"])
+            try:
+                tracing.start(prof["dir"])
+            except tracing.ProfilerBusy:
+                # another owner opened a capture since arm_profile: theirs
+                # stands, this one is dropped (debugz shows it disarmed)
+                self._profile = None
+                return
             prof["active"] = True
             return  # the NEXT ticks are captured; arming pass is free
         prof["remaining"] -= 1
         if prof["remaining"] <= 0:
-            jax.profiler.stop_trace()
+            tracing.stop()
             self._profile = None
 
     def debug_state(self) -> Dict[str, Any]:
@@ -2212,6 +2247,14 @@ class ContinuousBatcher:
                          "use_kernel": self.use_kernel,
                          "ragged_dispatches": self.ragged_dispatches,
                          "kinds": dict(self.dispatch_kinds),
+                         "decode_block_steps": self.decode_block_steps,
+                         "stages": self._stages.stages(),
+                         "queue_wait_s": self.queue_wait_s,
+                         "queue_waits": self.queue_waits,
+                         "ttft_s": self.ttft_s,
+                         "ttfts": self.ttfts,
+                         "first_decode_wait_s": self.first_decode_wait_s,
+                         "first_decode_waits": self.first_decode_waits,
                          "preemptions": self.preemptions,
                          "batch_preemptions": self.batch_preemptions,
                          "completed_requests": self.completed_requests,
@@ -2588,12 +2631,14 @@ class ContinuousBatcher:
 
     def _run(self) -> None:
         import jax.numpy as jnp
+        st = self._stages
         while True:
-            with self._cv:
+            with stage(st, "admit"), self._cv:
                 while (not self._shutdown and not self._queue
                        and not any(self._active)
                        and not self._hbm_reclaim_bytes):
-                    self._cv.wait()
+                    with stage(st, "idle"):
+                        self._cv.wait()
                 if self._shutdown and not self._queue and not any(self._active):
                     self._profile_step(done=True)  # close an open capture
                     return
@@ -2658,7 +2703,7 @@ class ContinuousBatcher:
                 if prefilled:
                     # a steps==1 request can complete at prefill
                     done_reqs = []
-                    with self._cv:
+                    with stage(st, "admit"), self._cv:
                         for lane, req in enumerate(self._active):
                             if (req is not None and not req.pending_prompt
                                     and req.finished()):
@@ -2666,12 +2711,7 @@ class ContinuousBatcher:
                                 done_reqs.append(req)
                         self._admit_locked()
                         snapshot = list(self._active)
-                    for req in done_reqs:
-                        if not req.future.done():
-                            self._flight_complete(req)
-                            req.future.set_result(self._result_of(req))
-                            self.completed_requests += 1
-                            self._note_complete(req)
+                    self._deliver((), done_reqs)
                 progressed = self._tick(snapshot, jnp) or prefilled
                 if self.hbm is not None:
                     # KV-burst side of the economy: queued/starved demand
@@ -2695,7 +2735,7 @@ class ContinuousBatcher:
                                 self._hbm_break_hoard_locked()
                     # every lane starved (pool pressure): back off instead
                     # of hot-spinning until pages free up
-                    with self._cv:
+                    with stage(st, "idle"), self._cv:
                         self._cv.wait(timeout=0.01)
                 else:
                     self._hbm_starved_passes = 0
@@ -2723,147 +2763,167 @@ class ContinuousBatcher:
         with ``prefill_chunk`` long tails run in page-aligned chunks.
         Returns False (retry later) when the pool can't yet supply the
         prompt's pages."""
-        if req.cancelled or req.length != 0:  # swept / already started
-            return False
-        t = len(req.pending_prompt)
-        if req.kv_handle is not None:
-            # recompute-free resume: swap the preemption snapshot back in
-            # instead of re-prefilling.  True = restored (zero prefill
-            # dispatches); False = page-starved (handle kept, retry next
-            # pass); None = swap degraded (handle consumed, fall through
-            # to the exact re-prefill below — today's path)
-            swapped = self._try_swap_in(req, t, lane)
-            if swapped is not None:
-                return swapped
-        prompt = np.asarray(req.pending_prompt, np.int32)
-        shared: List[int] = []
-        digests: List[bytes] = []
-        if self.prefix_cache is not None:
-            shared, digests = self.prefix_cache.lookup(prompt, self.page_size)
-        # page layout: shared prefix pages first, then private pages (the
-        # admission page + extras) for the tail/write region
-        private = req.pages
-        req.pages = shared + private
-        needed = (t + self.page_size - 1) // self.page_size
-        while len(req.pages) < needed:
-            page = self._alloc_page()
-            if page is None:
-                # page pressure: release partial holdings before retrying —
-                # two starved prefills must not hold-and-wait each other
-                self.pool.release_pages(req.pages)
-                req.pages = []
+        st = self._stages
+        with stage(st, "plan"):
+            if req.cancelled or req.length != 0:  # swept / already started
                 return False
-            req.pages.append(page)
-        start = len(shared) * self.page_size
-        tables = np.zeros((self.max_pages,), np.int32)
-        tables[:len(req.pages)] = req.pages
-        tables_j = jnp.asarray(tables)
-        # pages secured: the queue wait ends HERE (first prefill only — a
-        # preemption resume re-prefills but already left the queue once)
-        t_pf0 = _time.perf_counter()
-        if req.t_prefill0 is None:
-            req.t_prefill0 = t_pf0
-            self._span("queue_wait", lane, req.t_submit,
-                       t_pf0 - req.t_submit, req)
-            if self.metrics is not None:
-                self.metrics.observe_queue_wait(t_pf0 - req.t_submit)
-        # chaos: prefill fault site — an error here rides the scheduler's
-        # recovery path (fail actives + pool reset), a delay is a slow
-        # prefill under deadline pressure
-        chaos.trip("engine.prefill")
-        self.prefill_dispatches += 1
-        if start == 0 and (self.prefill_chunk is None
-                           or t <= self.prefill_chunk):
-            t_pad = 1 << (t - 1).bit_length()  # pow2 bucket: small jit cache
-            tokens = np.zeros((1, t_pad), np.int32)
-            tokens[0, :t] = prompt
-            last_logits, self.pool.kv = self._prefill(
-                self.params, self.pool.kv, tables_j,
-                jnp.asarray(tokens), jnp.int32(t))
-        else:
-            # tail (and/or chunked) prefill against resident context
-            chunk = self.prefill_chunk or (t - start)
-            last_logits = None
-            while start < t:
-                m = min(chunk, t - start)
-                m_pad = 1 << (m - 1).bit_length()
-                tokens = np.zeros((1, m_pad), np.int32)
-                tokens[0, :m] = prompt[start:start + m]
-                last_logits, self.pool.kv = self._extend(
+            t = len(req.pending_prompt)
+            if req.kv_handle is not None:
+                # recompute-free resume: swap the preemption snapshot back in
+                # instead of re-prefilling.  True = restored (zero prefill
+                # dispatches); False = page-starved (handle kept, retry next
+                # pass); None = swap degraded (handle consumed, fall through
+                # to the exact re-prefill below — today's path)
+                swapped = self._try_swap_in(req, t, lane)
+                if swapped is not None:
+                    return swapped
+            prompt = np.asarray(req.pending_prompt, np.int32)
+            shared: List[int] = []
+            digests: List[bytes] = []
+            if self.prefix_cache is not None:
+                shared, digests = self.prefix_cache.lookup(prompt,
+                                                           self.page_size)
+            # page layout: shared prefix pages first, then private pages (the
+            # admission page + extras) for the tail/write region
+            private = req.pages
+            req.pages = shared + private
+            needed = (t + self.page_size - 1) // self.page_size
+            while len(req.pages) < needed:
+                page = self._alloc_page()
+                if page is None:
+                    # page pressure: release partial holdings before
+                    # retrying — two starved prefills must not hold-and-wait
+                    # each other
+                    self.pool.release_pages(req.pages)
+                    req.pages = []
+                    return False
+                req.pages.append(page)
+            start = len(shared) * self.page_size
+        with stage(st, "dispatch"):
+            tables = np.zeros((self.max_pages,), np.int32)
+            tables[:len(req.pages)] = req.pages
+            tables_j = jnp.asarray(tables)
+            # pages secured: the queue wait ends HERE (first prefill only
+            # — a preemption resume re-prefills but already left the queue
+            # once)
+            t_pf0 = _time.perf_counter()
+            if req.t_prefill0 is None:
+                req.t_prefill0 = t_pf0
+                self._span("queue_wait", lane, req.t_submit,
+                           t_pf0 - req.t_submit, req)
+                self.queue_wait_s += t_pf0 - req.t_submit
+                self.queue_waits += 1
+                if self.metrics is not None:
+                    self.metrics.observe_queue_wait(t_pf0 - req.t_submit)
+            # chaos: prefill fault site — an error here rides the
+            # scheduler's recovery path (fail actives + pool reset), a delay
+            # is a slow prefill under deadline pressure
+            chaos.trip("engine.prefill")
+            self.prefill_dispatches += 1
+            if start == 0 and (self.prefill_chunk is None
+                               or t <= self.prefill_chunk):
+                t_pad = 1 << (t - 1).bit_length()  # pow2: small jit cache
+                tokens = np.zeros((1, t_pad), np.int32)
+                tokens[0, :t] = prompt
+                last_logits, self.pool.kv = self._prefill(
                     self.params, self.pool.kv, tables_j,
-                    jnp.asarray(tokens), jnp.int32(start),
-                    jnp.int32(start + m))
-                start += m
-        req.length = t
-        req.pending_prompt = []
-        self._fl_pages(req)
-        was_resumed = req.resumed
-        if was_resumed:
-            # preemption resume: the fed tail ends at tokens_out[-2]; the
-            # last emitted token was picked before eviction — discard these
-            # logits, consume no PRNG state, just continue decoding
-            req.resumed = False
-        else:
-            sp = req.sampling
-            if sp.device and sp.temperature > 0.0:
-                # first token rides the SAME (seed, position) stream as the
-                # decode ticks (position t-1 = the last prompt token's
-                # query; decode ticks start at position t) — one request is
-                # one reproducible stream end to end.  The prefill logits
-                # row is fetched once per request; per-TICK logits are
-                # never fetched for device-sampled lanes.
-                import jax.numpy as _j
-                tok = int(np.asarray(_device_sample_token(
-                    _j.asarray(last_logits, _j.float32),
-                    _j.float32(sp.temperature),
-                    _j.asarray([sp.seed & 0xFFFFFFFF,
-                                (sp.seed >> 32) & 0xFFFFFFFF], _j.uint32),
-                    _j.int32(t - 1))))
+                    jnp.asarray(tokens), jnp.int32(t))
             else:
-                tok = sp.pick(np.asarray(last_logits))
-            req.tokens_out.append(tok)
-            self.tokens_generated += 1
-            lp = None
-            if req.want_logprobs:
-                # same f32 device log_softmax as paged_decode_step: one
-                # request's logprob stream is one precision end to end
-                import jax as _jax
-                import jax.numpy as _j
-                lp = float(np.asarray(_jax.nn.log_softmax(
-                    _j.asarray(last_logits, _j.float32))[tok]))
-                req.logprobs_out.append(lp)
-            self._emit(req, tok, 0, lp)
-        # prefill span closes after the first-token pick (the pick's logits
-        # fetch is the fence that makes the device time real); decode
-        # chunks start from here
-        t_pf1 = _time.perf_counter()
-        self._span("prefill", lane, t_pf0, t_pf1 - t_pf0, req,
-                   prompt_tokens=t, cached_pages=len(shared))
-        req.chunk_t0 = t_pf1
-        req.chunk_start = len(req.tokens_out)
-        if not was_resumed:
-            req.t_first = t_pf1
-            req.t_last = t_pf1
-            if self.metrics is not None:
-                self.metrics.observe_ttft(t_pf1 - req.t_submit)
-        if self.prefix_cache is not None and not was_resumed:
-            # count each logical request once (resume prefills re-walk
-            # already-counted pages) and publish only first-prefill pages:
-            # full prompt pages are immutable from here on (decode writes
-            # at positions >= t), while a resume's tail pages hold
-            # generated tokens unique to this request — not worth caching
-            self.prefix_cache.count_lookup(len(shared), len(digests))
-            self.prefix_cache.insert(digests, req.pages[:len(digests)])
-        dt = t_pf1 - t_pf0
-        if dt > 0:
-            # rolling prefill throughput — the fabric cost gate's
-            # recompute-time estimate (see kv_publish in __init__)
-            inst = t / dt
-            self.prefill_ewma_tok_s = (
-                inst if self.prefill_ewma_tok_s == 0.0
-                else 0.7 * self.prefill_ewma_tok_s + 0.3 * inst)
-        if self.kv_publish and not was_resumed and req.export_digest is None:
-            self._fab_publish(req, prompt, t, last_logits)
+                # tail (and/or chunked) prefill against resident context
+                chunk = self.prefill_chunk or (t - start)
+                last_logits = None
+                while start < t:
+                    m = min(chunk, t - start)
+                    m_pad = 1 << (m - 1).bit_length()
+                    tokens = np.zeros((1, m_pad), np.int32)
+                    tokens[0, :m] = prompt[start:start + m]
+                    last_logits, self.pool.kv = self._extend(
+                        self.params, self.pool.kv, tables_j,
+                        jnp.asarray(tokens), jnp.int32(start),
+                        jnp.int32(start + m))
+                    start += m
+        with stage(st, "commit"):
+            req.length = t
+            req.pending_prompt = []
+            self._fl_pages(req)
+            was_resumed = req.resumed
+            if was_resumed:
+                # preemption resume: the fed tail ends at tokens_out[-2];
+                # the last emitted token was picked before eviction —
+                # discard these logits, consume no PRNG state, just
+                # continue decoding
+                req.resumed = False
+            else:
+                sp = req.sampling
+                with stage(st, "fetch"):
+                    if sp.device and sp.temperature > 0.0:
+                        # first token rides the SAME (seed, position) stream
+                        # as the decode ticks (position t-1 = the last
+                        # prompt token's query; decode ticks start at
+                        # position t) — one request is one reproducible
+                        # stream end to end.  The prefill logits row is
+                        # fetched once per request; per-TICK logits are
+                        # never fetched for device-sampled lanes.
+                        import jax.numpy as _j
+                        tok = int(np.asarray(_device_sample_token(
+                            _j.asarray(last_logits, _j.float32),
+                            _j.float32(sp.temperature),
+                            _j.asarray([sp.seed & 0xFFFFFFFF,
+                                        (sp.seed >> 32) & 0xFFFFFFFF],
+                                       _j.uint32),
+                            _j.int32(t - 1))))
+                    else:
+                        tok = sp.pick(np.asarray(last_logits))
+                    lp = None
+                    if req.want_logprobs:
+                        # same f32 device log_softmax as paged_decode_step:
+                        # one request's logprob stream is one precision end
+                        # to end
+                        import jax as _jax
+                        import jax.numpy as _j
+                        lp = float(np.asarray(_jax.nn.log_softmax(
+                            _j.asarray(last_logits, _j.float32))[tok]))
+                req.tokens_out.append(tok)
+                self.tokens_generated += 1
+                if req.want_logprobs:
+                    req.logprobs_out.append(lp)
+                with stage(st, "emit"):
+                    self._emit(req, tok, 0, lp)
+            # prefill span closes after the first-token pick (the pick's
+            # logits fetch is the fence that makes the device time real);
+            # decode chunks start from here
+            t_pf1 = _time.perf_counter()
+            self._span("prefill", lane, t_pf0, t_pf1 - t_pf0, req,
+                       prompt_tokens=t, cached_pages=len(shared))
+            req.chunk_t0 = t_pf1
+            req.chunk_start = len(req.tokens_out)
+            if not was_resumed:
+                req.t_first = t_pf1
+                req.t_last = t_pf1
+                self.ttft_s += t_pf1 - req.t_submit
+                self.ttfts += 1
+                if self.metrics is not None:
+                    self.metrics.observe_ttft(t_pf1 - req.t_submit)
+            if self.prefix_cache is not None and not was_resumed:
+                # count each logical request once (resume prefills re-walk
+                # already-counted pages) and publish only first-prefill
+                # pages: full prompt pages are immutable from here on
+                # (decode writes at positions >= t), while a resume's tail
+                # pages hold generated tokens unique to this request — not
+                # worth caching
+                self.prefix_cache.count_lookup(len(shared), len(digests))
+                self.prefix_cache.insert(digests, req.pages[:len(digests)])
+            dt = t_pf1 - t_pf0
+            if dt > 0:
+                # rolling prefill throughput — the fabric cost gate's
+                # recompute-time estimate (see kv_publish in __init__)
+                inst = t / dt
+                self.prefill_ewma_tok_s = (
+                    inst if self.prefill_ewma_tok_s == 0.0
+                    else 0.7 * self.prefill_ewma_tok_s + 0.3 * inst)
+            if (self.kv_publish and not was_resumed
+                    and req.export_digest is None):
+                self._fab_publish(req, prompt, t, last_logits)
         return True
 
     #: published fabric snapshots kept addressable (digest -> handle);
@@ -3007,6 +3067,8 @@ class ContinuousBatcher:
             req.t_prefill0 = req.pf_t0
             self._span("queue_wait", lane, req.t_submit,
                        req.pf_t0 - req.t_submit, req)
+            self.queue_wait_s += req.pf_t0 - req.t_submit
+            self.queue_waits += 1
             if self.metrics is not None:
                 self.metrics.observe_queue_wait(req.pf_t0 - req.t_submit)
         # chaos: same prefill fault site + semantics as _do_prefill (one
@@ -3024,129 +3086,133 @@ class ContinuousBatcher:
         separate prefill program, no per-lane logits fetch).  With no
         pending prompts this is a no-op and the K-block decode path
         owns the tick.  Returns True when any lane made progress."""
-        progressed = False
-        segs: List = []                     # (lane, req)
-        for lane, req in enumerate(snapshot):
-            if req is None or not req.pending_prompt or req.cancelled:
-                continue
-            if req.kv_handle is not None:
-                swapped = self._try_swap_in(req, len(req.pending_prompt),
-                                            lane)
-                if swapped is True:
-                    progressed = True
-                    continue
-                if swapped is False:
-                    continue         # page-starved: snapshot kept
-            if not req.pf_started and not self._ragged_prefill_start(
-                    req, lane):
-                continue             # page-starved: retry next pass
-            segs.append((lane, req))
-        if not segs:
-            return progressed
-        # decode lanes join the round only when no dispatched-ahead
-        # block is in flight (its device carry covers those lanes)
-        decode_parts: List = []
-        if self._pending_block is None:
+        st = self._stages
+        with stage(st, "plan"):
+            progressed = False
+            segs: List = []                     # (lane, req)
             for lane, req in enumerate(snapshot):
-                if (req is None or req.pending_prompt or req.cancelled
-                        or not req.tokens_out):
+                if req is None or not req.pending_prompt or req.cancelled:
                     continue
-                need = req.length // self.page_size + 1
-                new: List[int] = []
-                while len(req.pages) < need:
-                    page = self._alloc_page()
-                    if page is None:
-                        break
-                    req.pages.append(page)
-                    new.append(page)
-                if len(req.pages) < need:
-                    for _ in new:    # starved: return the partial take
-                        self.pool.release_pages([req.pages.pop()])
-                    continue
-                decode_parts.append((lane, req))
-        cap = min(self.prefill_chunk or self.RAGGED_CHUNK_CAP,
-                  self.RAGGED_CHUNK_CAP)
-        chunks: Dict[int, int] = {}
-        m_max = 1
-        for lane, req in segs:
-            c = min(len(req.pending_prompt), cap)
-            chunks[lane] = c
-            m_max = max(m_max, c)
-        m_pad = 1 << (m_max - 1).bit_length()   # pow2 bucket: small jits
-        b = self.lanes
-        tables = np.zeros((b, self.max_pages), np.int32)
-        seq = np.zeros((b, m_pad), np.int32)
-        q_lens = np.zeros((b,), np.int32)
-        kv_lens = np.zeros((b,), np.int32)
-        temps = np.zeros((b,), np.float32)
-        seeds = np.zeros((b, 2), np.uint32)
-        host_lanes: List[int] = []
-        lane_reqs: Dict[int, _PagedRequest] = {}
-        for lane, req in segs:
-            c = chunks[lane]
-            lane_reqs[lane] = req
-            seq[lane, :c] = req.pending_prompt[:c]
-            q_lens[lane] = c
-            kv_lens[lane] = req.length + c
-            tables[lane, :len(req.pages)] = req.pages
-            sp = req.sampling
-            if c == len(req.pending_prompt) and not req.resumed \
-                    and sp.temperature > 0.0:
-                # final chunk: this round's pick IS the first token
-                if sp.device:
-                    temps[lane] = sp.temperature
-                    seeds[lane] = (sp.seed & 0xFFFFFFFF,
-                                   (sp.seed >> 32) & 0xFFFFFFFF)
-                else:
-                    host_lanes.append(lane)
-        for lane, req in decode_parts:
-            lane_reqs[lane] = req
-            seq[lane, 0] = req.tokens_out[-1]
-            q_lens[lane] = 1
-            kv_lens[lane] = req.length + 1
-            tables[lane, :len(req.pages)] = req.pages
-            sp = req.sampling
-            if sp.temperature > 0.0:
-                if sp.device:
-                    temps[lane] = sp.temperature
-                    seeds[lane] = (sp.seed & 0xFFFFFFFF,
-                                   (sp.seed >> 32) & 0xFFFFFFFF)
-                else:
-                    host_lanes.append(lane)
-        if decode_parts:
-            # decode lanes advance one tick this round — same fault site
-            chaos.trip("engine.step")
-        t0 = _time.perf_counter()
-        nt_dev, lp_dev, last_dev, self.pool.kv = self._mixed(
-            self.params, self.pool.kv, jnp.asarray(tables),
-            jnp.asarray(seq), jnp.asarray(q_lens), jnp.asarray(kv_lens),
-            jnp.asarray(temps), jnp.asarray(seeds))
-        self.decode_dispatches += 1
-        self._note_dispatch("mixed")
-        next_tokens = np.asarray(nt_dev, np.int32).copy()
-        logprobs_arr = np.asarray(lp_dev, np.float32).copy()
-        self.decode_host_syncs += 1
-        if host_lanes:
-            # fetch ONLY the host-sampled rows (same shape discipline —
-            # and PRNG rule — as _tick_single)
-            rows = np.asarray(
-                last_dev[jnp.asarray(np.asarray(host_lanes, np.int32))])
+                if req.kv_handle is not None:
+                    swapped = self._try_swap_in(req, len(req.pending_prompt),
+                                                lane)
+                    if swapped is True:
+                        progressed = True
+                        continue
+                    if swapped is False:
+                        continue         # page-starved: snapshot kept
+                if not req.pf_started and not self._ragged_prefill_start(
+                        req, lane):
+                    continue             # page-starved: retry next pass
+                segs.append((lane, req))
+            if not segs:
+                return progressed
+            # decode lanes join the round only when no dispatched-ahead
+            # block is in flight (its device carry covers those lanes)
+            decode_parts: List = []
+            if self._pending_block is None:
+                for lane, req in enumerate(snapshot):
+                    if (req is None or req.pending_prompt or req.cancelled
+                            or not req.tokens_out):
+                        continue
+                    need = req.length // self.page_size + 1
+                    new: List[int] = []
+                    while len(req.pages) < need:
+                        page = self._alloc_page()
+                        if page is None:
+                            break
+                        req.pages.append(page)
+                        new.append(page)
+                    if len(req.pages) < need:
+                        for _ in new:    # starved: return the partial take
+                            self.pool.release_pages([req.pages.pop()])
+                        continue
+                    decode_parts.append((lane, req))
+        with stage(st, "dispatch"):
+            cap = min(self.prefill_chunk or self.RAGGED_CHUNK_CAP,
+                      self.RAGGED_CHUNK_CAP)
+            chunks: Dict[int, int] = {}
+            m_max = 1
+            for lane, req in segs:
+                c = min(len(req.pending_prompt), cap)
+                chunks[lane] = c
+                m_max = max(m_max, c)
+            m_pad = 1 << (m_max - 1).bit_length()   # pow2 bucket: small jits
+            b = self.lanes
+            tables = np.zeros((b, self.max_pages), np.int32)
+            seq = np.zeros((b, m_pad), np.int32)
+            q_lens = np.zeros((b,), np.int32)
+            kv_lens = np.zeros((b,), np.int32)
+            temps = np.zeros((b,), np.float32)
+            seeds = np.zeros((b, 2), np.uint32)
+            host_lanes: List[int] = []
+            lane_reqs: Dict[int, _PagedRequest] = {}
+            for lane, req in segs:
+                c = chunks[lane]
+                lane_reqs[lane] = req
+                seq[lane, :c] = req.pending_prompt[:c]
+                q_lens[lane] = c
+                kv_lens[lane] = req.length + c
+                tables[lane, :len(req.pages)] = req.pages
+                sp = req.sampling
+                if c == len(req.pending_prompt) and not req.resumed \
+                        and sp.temperature > 0.0:
+                    # final chunk: this round's pick IS the first token
+                    if sp.device:
+                        temps[lane] = sp.temperature
+                        seeds[lane] = (sp.seed & 0xFFFFFFFF,
+                                       (sp.seed >> 32) & 0xFFFFFFFF)
+                    else:
+                        host_lanes.append(lane)
+            for lane, req in decode_parts:
+                lane_reqs[lane] = req
+                seq[lane, 0] = req.tokens_out[-1]
+                q_lens[lane] = 1
+                kv_lens[lane] = req.length + 1
+                tables[lane, :len(req.pages)] = req.pages
+                sp = req.sampling
+                if sp.temperature > 0.0:
+                    if sp.device:
+                        temps[lane] = sp.temperature
+                        seeds[lane] = (sp.seed & 0xFFFFFFFF,
+                                       (sp.seed >> 32) & 0xFFFFFFFF)
+                    else:
+                        host_lanes.append(lane)
+            if decode_parts:
+                # decode lanes advance one tick this round — same fault site
+                chaos.trip("engine.step")
+            t0 = _time.perf_counter()
+            nt_dev, lp_dev, last_dev, self.pool.kv = self._mixed(
+                self.params, self.pool.kv, jnp.asarray(tables),
+                jnp.asarray(seq), jnp.asarray(q_lens), jnp.asarray(kv_lens),
+                jnp.asarray(temps), jnp.asarray(seeds))
+            self.decode_dispatches += 1
+            self._note_dispatch("mixed")
+        with stage(st, "fetch"):
+            next_tokens = np.asarray(nt_dev, np.int32).copy()
+            logprobs_arr = np.asarray(lp_dev, np.float32).copy()
             self.decode_host_syncs += 1
-            for i, lane in enumerate(host_lanes):
-                req = lane_reqs[lane]
-                next_tokens[lane] = req.sampling.pick(rows[i])
-                if req.want_logprobs:
-                    row = rows[i].astype(np.float32)
-                    row = row - row.max()
-                    logprobs_arr[lane] = float(
-                        row[next_tokens[lane]]
-                        - np.log(np.exp(row).sum()))
+            if host_lanes:
+                # fetch ONLY the host-sampled rows (same shape discipline —
+                # and PRNG rule — as _tick_single)
+                rows = np.asarray(
+                    last_dev[jnp.asarray(np.asarray(host_lanes, np.int32))])
+                self.decode_host_syncs += 1
+                for i, lane in enumerate(host_lanes):
+                    req = lane_reqs[lane]
+                    next_tokens[lane] = req.sampling.pick(rows[i])
+                    if req.want_logprobs:
+                        row = rows[i].astype(np.float32)
+                        row = row - row.max()
+                        logprobs_arr[lane] = float(
+                            row[next_tokens[lane]]
+                            - np.log(np.exp(row).sum()))
         now = _time.perf_counter()
         self._step_ewma_s = (0.8 * self._step_ewma_s + 0.2 * (now - t0)
                              if self._step_ewma_s else now - t0)
         emits: List = []
         completed: List = []
-        with self._cv:
+        with stage(st, "commit"), self._cv:
             for lane, req in segs:
                 if self._active[lane] is not req or req.cancelled:
                     continue
@@ -3181,6 +3247,8 @@ class ContinuousBatcher:
                 if not was_resumed:
                     req.t_first = now
                     req.t_last = now
+                    self.ttft_s += now - req.t_submit
+                    self.ttfts += 1
                     if self.metrics is not None:
                         self.metrics.observe_ttft(now - req.t_submit)
                 if self.prefix_cache is not None and not was_resumed:
@@ -3193,6 +3261,7 @@ class ContinuousBatcher:
                 if self._active[lane] is not req or req.cancelled:
                     continue
                 self._probe_countdown_locked(req)
+                self._note_second_token(req, now)
                 req.length += 1
                 tok = int(next_tokens[lane])
                 req.tokens_out.append(tok)
@@ -3215,16 +3284,9 @@ class ContinuousBatcher:
                 if done:
                     self._release_lane_locked(lane, req)
                     completed.append(req)
-            self._admit_locked()
-        # user callbacks and future resolution OUTSIDE the scheduler lock
-        for req, tok, i, lp in emits:
-            self._emit(req, tok, i, lp)
-        for req in completed:
-            if not req.future.done():
-                self._flight_complete(req)
-                req.future.set_result(self._result_of(req))
-                self.completed_requests += 1
-                self._note_complete(req)
+            with stage(st, "admit"):
+                self._admit_locked()
+        self._deliver(emits, completed)
         return progressed or bool(segs)
 
     def _discard_handle(self, req: _PagedRequest) -> None:
@@ -3252,6 +3314,27 @@ class ContinuousBatcher:
                 import logging
                 logging.getLogger("tpulab.engine").exception(
                     "on_token hook failed")
+
+    def _deliver(self, emits, completed) -> None:
+        """User callbacks and future resolution, OUTSIDE the scheduler
+        lock: a slow consumer must not head-of-line-block other lanes."""
+        with stage(self._stages, "emit"):
+            for req, tok, i, lp in emits:
+                self._emit(req, tok, i, lp)
+            for req in completed:
+                if not req.future.done():
+                    self._flight_complete(req)
+                    req.future.set_result(self._result_of(req))
+                    self.completed_requests += 1
+                    self._note_complete(req)
+
+    def _note_second_token(self, req: _PagedRequest, now: float) -> None:
+        """Called as a request's second token is committed: its wait
+        since the first is what a newly admitted lane waits for the
+        running chain (dispatch-ahead) before it gets a decode step."""
+        if len(req.tokens_out) == 1 and req.t_first is not None:
+            self.first_decode_wait_s += now - req.t_first
+            self.first_decode_waits += 1
 
     def _note_dispatch(self, kind: str) -> None:
         """Dispatch-kind accounting (the ragged plan's three descriptor
@@ -3531,28 +3614,35 @@ class ContinuousBatcher:
         if one is in flight, else plan + dispatch + consume.  Returns True
         when any lane made progress, False when every decode lane is
         starved (pool pressure) or idle."""
+        st = self._stages
         if self._pending_block is not None:
             stash, self._pending_block = self._pending_block, None
             return self._consume_block(stash, jnp)
-        plan = self._plan_decode(snapshot)
+        with stage(st, "plan"):
+            plan = self._plan_decode(snapshot)
         if plan is None:
             return False
         if plan["mode"] == "spec":
-            stash = self._dispatch_spec_block(plan["parts"], plan["k"], jnp)
+            with stage(st, "dispatch"):
+                stash = self._dispatch_spec_block(plan["parts"], plan["k"],
+                                                  jnp)
             if stash is not None:
                 return self._consume_spec_block(stash, jnp)
             # verify trip (chaos) pre-dispatch: the lanes just degraded to
             # plain — re-plan this tick as a plain block (their target
             # reservations are already in place)
-            lanes = [(lane, req) for lane, req, _nt, _nd in plan["parts"]]
-            k, parts = self._reserve_block_pages(
-                lanes, self._pick_block_k(lanes))
+            with stage(st, "plan"):
+                lanes = [(lane, req)
+                         for lane, req, _nt, _nd in plan["parts"]]
+                k, parts = self._reserve_block_pages(
+                    lanes, self._pick_block_k(lanes))
             if not parts:
                 return False
             plan = {"k": k, "parts": parts, "mode": "plain"}
         if plan["k"] == 1:
             return self._tick_single(plan["parts"], jnp)
-        stash = self._dispatch_block(plan["parts"], plan["k"], jnp)
+        with stage(st, "dispatch"):
+            stash = self._dispatch_block(plan["parts"], plan["k"], jnp)
         return self._consume_block(stash, jnp)
 
     def _dispatch_block(self, parts, k: int, jnp, carry=None,
@@ -3613,6 +3703,7 @@ class ContinuousBatcher:
             jnp.asarray(active), jnp.asarray(temps), jnp.asarray(seeds),
             jnp.asarray(rem), jnp.asarray(stops))
         self.decode_dispatches += 1
+        self.decode_block_steps += k
         self._note_dispatch("decode")
         return {"k": k, "lane_reqs": lane_reqs, "dev": (toks, lps, ems),
                 "carry": (len_f, tok_f, live_f, rem_f),
@@ -3623,10 +3714,12 @@ class ContinuousBatcher:
         lane) and unpack it through the per-token emit/trace/metrics
         path; may dispatch the NEXT block before running the emit
         callbacks (overlapping device compute with host-side emit)."""
+        st = self._stages
         k = stash["k"]
-        toks = np.asarray(stash["dev"][0], np.int32)
-        lps = np.asarray(stash["dev"][1], np.float32)
-        ems = np.asarray(stash["dev"][2], bool)
+        with stage(st, "fetch"):
+            toks = np.asarray(stash["dev"][0], np.int32)
+            lps = np.asarray(stash["dev"][1], np.float32)
+            ems = np.asarray(stash["dev"][2], bool)
         self.decode_host_syncs += 1
         now = _time.perf_counter()  # post-fetch: device work is done
         self._step_ewma_s = (
@@ -3636,7 +3729,7 @@ class ContinuousBatcher:
         completed: List = []
         clean = True        # every dispatched lane is still this request's
         emitted_total = 0
-        with self._cv:
+        with stage(st, "commit"), self._cv:
             for lane, req in stash["lane_reqs"].items():
                 if self._active[lane] is not req or req.cancelled:
                     # released (cancel/deadline sweep) or preempted since
@@ -3654,6 +3747,7 @@ class ContinuousBatcher:
                 # (the burst shape is documented in docs/PERFORMANCE.md)
                 dt = (now - req.t_last) / n if req.t_last is not None \
                     else None
+                self._note_second_token(req, now)
                 for j in range(n):
                     tok = int(toks[lane, j])
                     req.length += 1
@@ -3671,7 +3765,8 @@ class ContinuousBatcher:
                 if req.finished():
                     self._release_lane_locked(lane, req)
                     completed.append(req)
-            self._admit_locked()
+            with stage(st, "admit"):
+                self._admit_locked()
         if self.trace is not None and emitted_total:
             self.trace.add_counter("decode_block", now,
                                    tokens=emitted_total, k=k)
@@ -3687,30 +3782,27 @@ class ContinuousBatcher:
                 and self._pending_block is None and not self._shutdown
                 and not self._hbm_reclaim_bytes):
             lanes_now = list(stash["lane_reqs"].items())
-            # a lane that just re-armed speculation (a probe countdown
-            # expiring above) must flow back through _plan_decode — a
-            # plain chain-ahead here would starve the probe forever
-            spec_next = (self._spec is not None
-                         and all(self._spec_eligible(r)
-                                 for _, r in lanes_now))
-            if not spec_next and self._pick_block_k(lanes_now) == k:
-                k2, parts2 = self._reserve_block_pages(lanes_now, k)
-                if k2 == k and len(parts2) == len(lanes_now):
+            parts2 = None
+            with stage(st, "plan"):
+                # a lane that just re-armed speculation (a probe countdown
+                # expiring above) must flow back through _plan_decode — a
+                # plain chain-ahead here would starve the probe forever
+                spec_next = (self._spec is not None
+                             and all(self._spec_eligible(r)
+                                     for _, r in lanes_now))
+                if not spec_next and self._pick_block_k(lanes_now) == k:
+                    k2, parts2 = self._reserve_block_pages(lanes_now, k)
+                    if k2 != k or len(parts2) != len(lanes_now):
+                        # pages stay reserved on the lanes for the next
+                        # regular plan (bounded hoard: <= one block per
+                        # lane)
+                        parts2 = None
+            if parts2 is not None:
+                with stage(st, "dispatch"):
                     self._pending_block = self._dispatch_block(
                         parts2, k, jnp, carry=stash["carry"],
                         host=stash["host"])
-                # else: pages stay reserved on the lanes for the next
-                # regular plan (bounded hoard: <= one block per lane)
-        # user callbacks and future resolution OUTSIDE the scheduler lock:
-        # a slow consumer must not head-of-line-block other lanes
-        for req, tok, i, lp in emits:
-            self._emit(req, tok, i, lp)
-        for req in completed:
-            if not req.future.done():
-                self._flight_complete(req)
-                req.future.set_result(self._result_of(req))
-                self.completed_requests += 1
-                self._note_complete(req)
+        self._deliver(emits, completed)
         return True
 
     # -- speculative decode dispatch -----------------------------------------
@@ -3833,12 +3925,14 @@ class ContinuousBatcher:
         Drafted-but-rejected proposals are counted (``spec_tokens_*``)
         but never emitted and never enter ``tokens_generated`` — so
         tokens-per-dispatch telemetry reflects accepted tokens only."""
+        st = self._stages
         k = stash["k"]
-        toks = np.asarray(stash["dev"][0], np.int32)
-        lps = np.asarray(stash["dev"][1], np.float32)
-        ems = np.asarray(stash["dev"][2], bool)
-        drafted = np.asarray(stash["dev"][3], np.int32)
-        accepted = np.asarray(stash["dev"][4], np.int32)
+        with stage(st, "fetch"):
+            toks = np.asarray(stash["dev"][0], np.int32)
+            lps = np.asarray(stash["dev"][1], np.float32)
+            ems = np.asarray(stash["dev"][2], bool)
+            drafted = np.asarray(stash["dev"][3], np.int32)
+            accepted = np.asarray(stash["dev"][4], np.int32)
         self.decode_host_syncs += 1
         now = _time.perf_counter()
         self._step_ewma_s = (
@@ -3848,7 +3942,7 @@ class ContinuousBatcher:
         completed: List = []
         emitted_total = 0
         accepted_total = 0
-        with self._cv:
+        with stage(st, "commit"), self._cv:
             for lane, req in stash["lane_reqs"].items():
                 if self._active[lane] is not req or req.cancelled:
                     continue  # released since dispatch: block discarded
@@ -3874,6 +3968,7 @@ class ContinuousBatcher:
                 emitted_total += n
                 dt = (now - req.t_last) / n if req.t_last is not None \
                     else None
+                self._note_second_token(req, now)
                 for j in range(n):
                     tok = int(toks[lane, j])
                     req.length += 1
@@ -3896,103 +3991,101 @@ class ContinuousBatcher:
                 if req.finished():
                     self._release_lane_locked(lane, req)
                     completed.append(req)
-            self._admit_locked()
+            with stage(st, "admit"):
+                self._admit_locked()
         if self.trace is not None and emitted_total:
             self.trace.add_counter("decode_block", now,
                                    tokens=emitted_total, k=k,
                                    accepted=accepted_total)
-        # user callbacks and future resolution OUTSIDE the scheduler lock
-        for req, tok, i, lp in emits:
-            self._emit(req, tok, i, lp)
-        for req in completed:
-            if not req.future.done():
-                self._flight_complete(req)
-                req.future.set_result(self._result_of(req))
-                self.completed_requests += 1
-                self._note_complete(req)
+        self._deliver(emits, completed)
         return True
 
     def _tick_single(self, parts, jnp) -> bool:
         """K=1 decode tick (host-sampled lanes present, or decode_block=1):
         one dispatch + one fetch per token, the pre-block behavior."""
-        b = self.lanes
-        tables = np.zeros((b, self.max_pages), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        tokens = np.zeros((b,), np.int32)
-        active = np.zeros((b,), bool)
-        # device-sampled lanes carry their temperature into the step (the
-        # tick then fetches only (B,)-sized arrays for them); host-sampled
-        # (top_k/top_p) lanes keep temp 0 on device and pick from fetched
-        # logits rows
-        temps = np.zeros((b,), np.float32)
-        seeds = np.zeros((b, 2), np.uint32)   # (lo, hi) words
-        host_lanes = []
-        want_logp = False
-        lane_reqs = {}
-        for lane, req, _new in parts:
-            lane_reqs[lane] = req
-            tokens[lane] = req.tokens_out[-1]
-            tables[lane, :len(req.pages)] = req.pages
-            lengths[lane] = req.length
-            active[lane] = True
-            want_logp |= req.want_logprobs
-            sp = req.sampling
-            if sp.temperature > 0.0:
-                if sp.device:
-                    temps[lane] = sp.temperature
-                    seeds[lane] = (sp.seed & 0xFFFFFFFF,
-                                   (sp.seed >> 32) & 0xFFFFFFFF)
-                else:
-                    host_lanes.append(lane)
-        # chaos: decode-tick fault site — an error fails the in-flight
-        # requests and resets the pool (the scheduler's recovery path); a
-        # delay makes every lane's step slow (deadline-storm scenarios)
-        chaos.trip("engine.step")
-        t0 = _time.perf_counter()
-        logprobs_arr = None
-        if temps.any() or want_logp:
-            tok_dev, logp_dev, logits, self.pool.kv = self._step_sampled(
-                self.params, self.pool.kv,
-                jnp.asarray(tables), jnp.asarray(lengths),
-                jnp.asarray(tokens), jnp.asarray(active),
-                jnp.asarray(temps), jnp.asarray(seeds))
-            # greedy + device-sampled lanes: ONLY (B,)-sized arrays cross
-            # the link (token ids + chosen-token logprobs)
+        st = self._stages
+        with stage(st, "dispatch"):
+            b = self.lanes
+            tables = np.zeros((b, self.max_pages), np.int32)
+            lengths = np.zeros((b,), np.int32)
+            tokens = np.zeros((b,), np.int32)
+            active = np.zeros((b,), bool)
+            # device-sampled lanes carry their temperature into the step (the
+            # tick then fetches only (B,)-sized arrays for them); host-sampled
+            # (top_k/top_p) lanes keep temp 0 on device and pick from fetched
+            # logits rows
+            temps = np.zeros((b,), np.float32)
+            seeds = np.zeros((b, 2), np.uint32)   # (lo, hi) words
+            host_lanes = []
+            want_logp = False
+            lane_reqs = {}
+            for lane, req, _new in parts:
+                lane_reqs[lane] = req
+                tokens[lane] = req.tokens_out[-1]
+                tables[lane, :len(req.pages)] = req.pages
+                lengths[lane] = req.length
+                active[lane] = True
+                want_logp |= req.want_logprobs
+                sp = req.sampling
+                if sp.temperature > 0.0:
+                    if sp.device:
+                        temps[lane] = sp.temperature
+                        seeds[lane] = (sp.seed & 0xFFFFFFFF,
+                                       (sp.seed >> 32) & 0xFFFFFFFF)
+                    else:
+                        host_lanes.append(lane)
+            # chaos: decode-tick fault site — an error fails the in-flight
+            # requests and resets the pool (the scheduler's recovery path); a
+            # delay makes every lane's step slow (deadline-storm scenarios)
+            chaos.trip("engine.step")
+            t0 = _time.perf_counter()
+            logprobs_arr = logp_dev = None
+            if temps.any() or want_logp:
+                tok_dev, logp_dev, logits, self.pool.kv = self._step_sampled(
+                    self.params, self.pool.kv,
+                    jnp.asarray(tables), jnp.asarray(lengths),
+                    jnp.asarray(tokens), jnp.asarray(active),
+                    jnp.asarray(temps), jnp.asarray(seeds))
+            else:
+                # neither device sampling nor logprobs this tick: the plain
+                # step (no temps/seeds traced) — greedy stays one device
+                # argmax
+                logits, self.pool.kv = self._step(
+                    self.params, self.pool.kv,
+                    jnp.asarray(tables), jnp.asarray(lengths),
+                    jnp.asarray(tokens), jnp.asarray(active))
+                tok_dev = logits.argmax(-1)
+            self.decode_dispatches += 1
+            self.decode_block_steps += 1
+            self._note_dispatch("decode")
+        with stage(st, "fetch"):
+            # greedy + device-sampled lanes: ONLY (B,)-sized arrays cross the
+            # link (token ids + chosen-token logprobs)
             next_tokens = np.asarray(tok_dev, np.int32).copy()
-            logprobs_arr = np.asarray(logp_dev, np.float32).copy()
-        else:
-            # neither device sampling nor logprobs this tick: the plain
-            # step (no temps/seeds traced) — greedy stays one device
-            # argmax
-            logits, self.pool.kv = self._step(
-                self.params, self.pool.kv,
-                jnp.asarray(tables), jnp.asarray(lengths),
-                jnp.asarray(tokens), jnp.asarray(active))
-            next_tokens = np.asarray(logits.argmax(-1), np.int32).copy()
-        self.decode_dispatches += 1
-        self._note_dispatch("decode")
-        self.decode_host_syncs += 1
-        if host_lanes:
-            # fetch ONLY the host-sampled rows: gather them device-side,
-            # then one (n_host, vocab) transfer — not the full
-            # (lanes, vocab) matrix when a single lane host-samples.
-            # Only active host-sampled lanes consume PRNG state: a
-            # page-starved or pending-prefill lane must not perturb a
-            # seeded request's token sequence (per-request reproducibility)
-            rows = np.asarray(
-                logits[jnp.asarray(np.asarray(host_lanes, np.int32))])
+            if logp_dev is not None:
+                logprobs_arr = np.asarray(logp_dev, np.float32).copy()
             self.decode_host_syncs += 1
-            for i, lane in enumerate(host_lanes):
-                next_tokens[lane] = lane_reqs[lane].sampling.pick(rows[i])
-                if logprobs_arr is not None:
-                    # f32 log-sum-exp: the same precision class as the
-                    # device log_softmax used for prefill and for
-                    # device-sampled lanes — one request, one precision
-                    row = rows[i].astype(np.float32)
-                    row = row - row.max()
-                    logprobs_arr[lane] = float(
-                        row[next_tokens[lane]]
-                        - np.log(np.exp(row).sum()))
+            if host_lanes:
+                # fetch ONLY the host-sampled rows: gather them device-side,
+                # then one (n_host, vocab) transfer — not the full
+                # (lanes, vocab) matrix when a single lane host-samples.
+                # Only active host-sampled lanes consume PRNG state: a
+                # page-starved or pending-prefill lane must not perturb a
+                # seeded request's token sequence (per-request reproducibility)
+                rows = np.asarray(
+                    logits[jnp.asarray(np.asarray(host_lanes, np.int32))])
+                self.decode_host_syncs += 1
+                for i, lane in enumerate(host_lanes):
+                    next_tokens[lane] = lane_reqs[lane].sampling.pick(rows[i])
+                    if logprobs_arr is not None:
+                        # f32 log-sum-exp: the same precision class as the
+                        # device log_softmax used for prefill and for
+                        # device-sampled lanes — one request, one precision
+                        row = rows[i].astype(np.float32)
+                        row = row - row.max()
+                        logprobs_arr[lane] = float(
+                            row[next_tokens[lane]]
+                            - np.log(np.exp(row).sum()))
 
         emits: List = []
         completed: List = []
@@ -4000,11 +4093,12 @@ class ContinuousBatcher:
         #                             done, so per-lane deltas are real
         self._step_ewma_s = (0.8 * self._step_ewma_s + 0.2 * (now - t0)
                              if self._step_ewma_s else now - t0)
-        with self._cv:
+        with stage(st, "commit"), self._cv:
             for lane, req in lane_reqs.items():
                 if req.cancelled:
                     continue  # the _run sweep releases it next round
                 self._probe_countdown_locked(req)
+                self._note_second_token(req, now)
                 req.length += 1
                 req.tokens_out.append(int(next_tokens[lane]))
                 self.tokens_generated += 1
@@ -4027,17 +4121,9 @@ class ContinuousBatcher:
                 if done:
                     self._release_lane_locked(lane, req)
                     completed.append(req)
-            self._admit_locked()
-        # user callbacks and future resolution OUTSIDE the scheduler lock:
-        # a slow consumer must not head-of-line-block other lanes
-        for req, tok, i, lp in emits:
-            self._emit(req, tok, i, lp)
-        for req in completed:
-            if not req.future.done():
-                self._flight_complete(req)
-                req.future.set_result(self._result_of(req))
-                self.completed_requests += 1
-                self._note_complete(req)
+            with stage(st, "admit"):
+                self._admit_locked()
+        self._deliver(emits, completed)
         return True
 
     @staticmethod

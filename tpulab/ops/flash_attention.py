@@ -102,6 +102,7 @@ def _flash_bhd(q, k, v, causal: bool, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 

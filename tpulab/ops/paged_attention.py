@@ -266,6 +266,7 @@ def _paged_attn(q, kv_pool, tables, lengths, interpret: bool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(tables.reshape(-1), lengths, q2, kvp)
     return out.reshape(b, h, d)
 

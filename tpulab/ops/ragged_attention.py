@@ -312,6 +312,7 @@ def _ragged_attn(q, kv_pool, tables, q_lens, kv_lens, interpret: bool,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
             max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(tables.reshape(-1), q_lens, kv_lens, q2, kvp)
     return out.reshape(b, m, h, d)
 

@@ -23,7 +23,7 @@ from tpulab.rpc.context import Context, StreamingContext
 from tpulab.rpc.executor import Executor
 from tpulab.rpc.protos import inference_pb2 as pb
 from tpulab.rpc.server import AsyncService, Server
-from tpulab.utils.tracing import TraceContext
+from tpulab.utils.tracing import TraceContext, annotate
 
 log = logging.getLogger("tpulab.rpc")
 
@@ -1342,6 +1342,10 @@ class GenerateContext(StreamingContext):
             self._note_resume(engine, request)
         stalled = [False]     # chaos rpc.stream drop: emit path wedged
         stream_fault = []     # chaos rpc.stream error: mid-stream fault
+        tc = TraceContext.of_request(request, self.grpc_context)
+        # this request's spans in a profiler capture (rpc.admit here,
+        # rpc.write on the scheduler thread, inside its sched.emit)
+        span_kw = {"trace_id": tc.trace_id} if tc is not None else {}
 
         def on_token(tok, i, logprob=None):
             if finished[0] or stalled[0] or stream_fault:
@@ -1354,9 +1358,10 @@ class GenerateContext(StreamingContext):
             except chaos.ChaosError as e:
                 stream_fault.append(e)
                 return
-            self.write(pb.GenerateResponse(
-                token=tok, index=resume_ofs + i,
-                logprob=0.0 if logprob is None else float(logprob)))
+            with annotate("rpc.write", **span_kw):
+                self.write(pb.GenerateResponse(
+                    token=tok, index=resume_ofs + i,
+                    logprob=0.0 if logprob is None else float(logprob)))
 
         fut = None
         res = self.get_resources(InferResources)
@@ -1379,93 +1384,94 @@ class GenerateContext(StreamingContext):
             flight_kw = {"flight_owner": "rpc",
                          "tenant": tenant_of_request(request,
                                                      self.grpc_context)}
-        tc = TraceContext.of_request(request, self.grpc_context)
         try:
-            sampling = self._sampling_of(request)
-            kw = dict(flight_kw)
-            if deadline is not None:
-                # the batcher's tick sweep enforces it (lane/pages free
-                # before the next step); only passed when present so
-                # wrapped/test engines without the kwarg keep working
-                kw["deadline"] = deadline
-            if tc is not None:
-                # same gating: only traced requests carry the kwarg
-                kw["trace_id"] = tc.trace_id
-            if request.request_class == "batch":
-                # offline batch lane: the engine ranks this lane below
-                # every online request and preempts it first.  Gated so
-                # wrapped/test engines without the kwarg keep working.
-                kw["request_class"] = "batch"
-            if request.kv_shipment and not request.return_logprobs:
-                # shipped-KV admit: import into the local host tier and
-                # promote through the restore path — zero prefill
-                # dispatches.  ANY failure (corrupt wire, geometry
-                # mismatch, budget refusal, host-sampled lane) leaves
-                # fut None and the plain submit below prefills locally:
-                # same tokens, never a stuck request.
-                res2 = self.get_resources(InferResources)
-                shipper = res2.shipper_for(engine)
-                ship = (shipper.import_shipment(bytes(request.kv_shipment))
-                        if shipper is not None else None)
-                if ship is not None:
-                    try:
-                        fut = engine.submit_shipped(
-                            np.asarray(request.prompt, np.int32),
-                            request.steps, ship.first_token, ship.handle,
-                            on_token=on_token, sampling=sampling,
-                            priority=request.priority,
-                            stop_tokens=list(request.stop_tokens), **kw)
-                    except ValueError as e:
-                        shipper.discard(ship)
-                        log.warning("shipped-KV admit rejected, degrading "
-                                    "to local prefill: %s", e)
-            if (fut is None and res.kvfabric is not None
-                    and not request.kv_shipment
-                    and not request.return_logprobs and not resume_ofs):
-                # fleet KV fabric (tpulab.kvfabric, docs/SERVING.md
-                # "Fleet KV fabric"): a routed-astray request whose
-                # digest homes on another replica PULLS the finished
-                # prefill from there and admits it through the same
-                # shipped-KV path — zero local prefill dispatches, bit-
-                # exact tokens.  pull() returning None (not eligible,
-                # cost-gated, single-flight timeout, chaos, NOT_FOUND,
-                # corrupt wire, budget refusal) means the plain submit
-                # below prefills locally: the fabric only ever SAVES
-                # work.
-                shipper = res.shipper_for(engine)
-                if shipper is not None:
-                    t_pull0 = _time.perf_counter()
-                    pulled = res.kvfabric.pull(
-                        np.asarray(request.prompt, np.int32), sampling,
-                        engine, shipper, model_name=request.model_name)
-                    if pulled is not None:
+            with annotate("rpc.admit", **span_kw):
+                sampling = self._sampling_of(request)
+                kw = dict(flight_kw)
+                if deadline is not None:
+                    # the batcher's tick sweep enforces it (lane/pages free
+                    # before the next step); only passed when present so
+                    # wrapped/test engines without the kwarg keep working
+                    kw["deadline"] = deadline
+                if tc is not None:
+                    # same gating: only traced requests carry the kwarg
+                    kw["trace_id"] = tc.trace_id
+                if request.request_class == "batch":
+                    # offline batch lane: the engine ranks this lane below
+                    # every online request and preempts it first.  Gated so
+                    # wrapped/test engines without the kwarg keep working.
+                    kw["request_class"] = "batch"
+                if request.kv_shipment and not request.return_logprobs:
+                    # shipped-KV admit: import into the local host tier and
+                    # promote through the restore path — zero prefill
+                    # dispatches.  ANY failure (corrupt wire, geometry
+                    # mismatch, budget refusal, host-sampled lane) leaves
+                    # fut None and the plain submit below prefills locally:
+                    # same tokens, never a stuck request.
+                    res2 = self.get_resources(InferResources)
+                    shipper = res2.shipper_for(engine)
+                    ship = (shipper.import_shipment(
+                        bytes(request.kv_shipment))
+                            if shipper is not None else None)
+                    if ship is not None:
                         try:
                             fut = engine.submit_shipped(
                                 np.asarray(request.prompt, np.int32),
-                                request.steps, pulled.first_token,
-                                pulled.handle, on_token=on_token,
-                                sampling=sampling,
+                                request.steps, ship.first_token, ship.handle,
+                                on_token=on_token, sampling=sampling,
                                 priority=request.priority,
-                                stop_tokens=list(request.stop_tokens),
-                                **kw)
-                            self._fl_note(kv_pull={
-                                "bytes": pulled.nbytes,
-                                "tokens_saved": pulled.length,
-                                "coalesced": pulled.coalesced,
-                                "wait_s": round(
-                                    _time.perf_counter() - t_pull0, 6)})
+                                stop_tokens=list(request.stop_tokens), **kw)
                         except ValueError as e:
-                            shipper.manager.discard(pulled.handle)
-                            res.kvfabric.note_degrade(pulled)
-                            log.warning("fabric-pull admit rejected, "
-                                        "degrading to local prefill: %s", e)
-            if fut is None:
-                fut = engine.submit(np.asarray(request.prompt, np.int32),
-                                    steps_eff, on_token=on_token,
+                            shipper.discard(ship)
+                            log.warning("shipped-KV admit rejected, degrading "
+                                        "to local prefill: %s", e)
+                if (fut is None and res.kvfabric is not None
+                        and not request.kv_shipment
+                        and not request.return_logprobs and not resume_ofs):
+                    # fleet KV fabric (tpulab.kvfabric, docs/SERVING.md
+                    # "Fleet KV fabric"): a routed-astray request whose
+                    # digest homes on another replica PULLS the finished
+                    # prefill from there and admits it through the same
+                    # shipped-KV path — zero local prefill dispatches, bit-
+                    # exact tokens.  pull() returning None (not eligible,
+                    # cost-gated, single-flight timeout, chaos, NOT_FOUND,
+                    # corrupt wire, budget refusal) means the plain submit
+                    # below prefills locally: the fabric only ever SAVES
+                    # work.
+                    shipper = res.shipper_for(engine)
+                    if shipper is not None:
+                        t_pull0 = _time.perf_counter()
+                        pulled = res.kvfabric.pull(
+                            np.asarray(request.prompt, np.int32), sampling,
+                            engine, shipper, model_name=request.model_name)
+                        if pulled is not None:
+                            try:
+                                fut = engine.submit_shipped(
+                                    np.asarray(request.prompt, np.int32),
+                                    request.steps, pulled.first_token,
+                                    pulled.handle, on_token=on_token,
                                     sampling=sampling,
                                     priority=request.priority,
                                     stop_tokens=list(request.stop_tokens),
-                                    logprobs=request.return_logprobs, **kw)
+                                    **kw)
+                                self._fl_note(kv_pull={
+                                    "bytes": pulled.nbytes,
+                                    "tokens_saved": pulled.length,
+                                    "coalesced": pulled.coalesced,
+                                    "wait_s": round(
+                                        _time.perf_counter() - t_pull0, 6)})
+                            except ValueError as e:
+                                shipper.manager.discard(pulled.handle)
+                                res.kvfabric.note_degrade(pulled)
+                                log.warning("fabric-pull admit rejected, "
+                                            "degrading to local prefill: %s", e)
+                if fut is None:
+                    fut = engine.submit(np.asarray(request.prompt, np.int32),
+                                        steps_eff, on_token=on_token,
+                                        sampling=sampling,
+                                        priority=request.priority,
+                                        stop_tokens=list(request.stop_tokens),
+                                        logprobs=request.return_logprobs, **kw)
             lease_deadline = _time.monotonic() + self.SESSION_LEASE_TIMEOUT_S
             while True:
                 try:

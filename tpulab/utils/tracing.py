@@ -2,11 +2,15 @@
 per-stage cudaEvent timing + Walltime + InferBench metrics; the TPU
 equivalent adds the XLA profiler).
 
-- :func:`trace` / :func:`annotate` — wrap jax.profiler: capture a
-  TensorBoard-loadable trace of the serving hot path, with named regions
-  (the nvtx-range analog the reference lacked).
-- :class:`StageTimer` — the TimedBenchmarkWorkspace pattern as a reusable
-  context: named stage durations with blocking sync at boundaries.
+- :func:`start` / :func:`stop` / :func:`active` — the process's ONE
+  profiler switch: the only code in ``tpulab/`` that calls
+  ``jax.profiler.start_trace`` / ``stop_trace`` (the Debug RPC's
+  ``profile_ticks``, :func:`trace` and the benchmark all go through it).
+- :func:`annotate` / :func:`stage` / :class:`StageClock` — named regions
+  in the profiler's trace, on the device trace's clock (the nvtx-range
+  analog the reference lacked); :func:`stage` also adds the region's
+  host-clock seconds to an accumulator its caller owns (the scheduler's
+  ``debug_state()["dispatch"]["stages"]``).
 - :class:`TraceContext` / :class:`ChromeTraceRecorder` /
   :func:`merge_chrome_traces` — request-scoped distributed tracing: the
   client mints a trace id, carries it over gRPC (request field + metadata),
@@ -79,71 +83,146 @@ class TraceContext:
         return f"TraceContext({self.trace_id})"
 
 
+class ProfilerBusy(RuntimeError):
+    """:func:`start` while a capture is open: the profiler is one per
+    process, so the second owner is told so here instead of failing
+    inside JAX."""
+
+
+_switch_lock = threading.Lock()
+_switch_dir: Optional[str] = None   # log_dir of the open capture
+
+
+def start(log_dir: str, python_tracer: bool = False) -> str:
+    """Open a profiler capture into ``log_dir`` (TensorBoard-loadable;
+    ``<log_dir>/plugins/profile/<time>/*.xplane.pb``).  Thread-safe, any
+    number of captures a process, one at a time: a second ``start`` while
+    one is open raises :class:`ProfilerBusy` and leaves the first intact.
+
+    With the Python tracer off (the default) the host planes hold the
+    program's own spans (:func:`annotate`, :func:`stage`) and JAX's
+    runtime events only: a smaller trace and a host slowed less.
+    ``python_tracer=True`` adds every Python call, for a person
+    debugging."""
+    global _switch_dir
+    import jax
+    with _switch_lock:
+        if _switch_dir is not None:
+            raise ProfilerBusy(
+                f"a profiler capture into {_switch_dir!r} is already open "
+                "in this process (one at a time: tracing.stop() it first)")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 1 if python_tracer else 0
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        _switch_dir = str(log_dir)
+    return _switch_dir
+
+
+def stop() -> Optional[str]:
+    """Close the open capture and write it; returns its ``log_dir``, or
+    None when none was open (idempotent)."""
+    global _switch_dir
+    import jax
+    with _switch_lock:
+        if _switch_dir is None:
+            return None
+        log_dir, _switch_dir = _switch_dir, None
+        jax.profiler.stop_trace()
+    return log_dir
+
+
+def active() -> bool:
+    """Is a capture open (by whichever owner)?"""
+    return _switch_dir is not None
+
+
 @contextlib.contextmanager
-def trace(log_dir: str = "/tmp/tpulab-trace"):
-    """Capture an XLA profiler trace around a block::
+def trace(log_dir: str = "/tmp/tpulab-trace", **start_kw):
+    """:func:`start` / :func:`stop` around a block::
 
         with tracing.trace("/tmp/trace"):
             runner.infer(**arrays).result()
         # -> tensorboard --logdir /tmp/trace
     """
-    import jax
-    jax.profiler.start_trace(log_dir)
+    start(log_dir, **start_kw)
     try:
         yield log_dir
     finally:
-        jax.profiler.stop_trace()
+        stop()
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region inside a trace (nvtx-range analog)."""
+def annotate(name: str, **kw):
+    """Named region inside a trace (nvtx-range analog): a
+    ``jax.profiler.TraceAnnotation``, i.e. an atomic check while no
+    capture is open and an event on the device trace's clock while one
+    is.  ``kw`` (e.g. ``trace_id=``) become the event's stats."""
     import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    return jax.profiler.TraceAnnotation(name, **kw)
 
 
-class StageTimer:
-    """Named stage timing (the reference's cudaEvent H2D/compute/D2H split,
-    generalized).  JAX dispatch is async, so each stage that launches device
-    work MUST name a ``sync_on`` target — otherwise the stage records only
-    dispatch time and its device time bleeds into the next stage::
+class StageClock:
+    """Seconds and entries per named stage, for ONE thread (the
+    scheduler's).  ``stages()`` is monotone; the stages never overlap (a
+    nested :func:`stage` pauses the one around it) and leave no hole (an
+    outermost stage lasts until the next one begins: what the thread
+    loses between two ``with`` blocks, e.g. the interpreter lock to the
+    threads it has just handed work, belongs to the stage that ended),
+    so their seconds sum to the thread's time since its first stage."""
 
-        t = StageTimer()
-        holder = {}
-        with t.stage("h2d"):
-            holder["dev"] = copy_to_device(host)
-        t.sync("h2d", holder["dev"])              # or stage(..., sync_on=...)
-        with t.stage("compute", sync_on_fn=lambda: out):
-            out = compiled(holder["dev"])
-        t.stages_ms  # {"h2d": ..., "compute": ...}
-    """
+    def __init__(self, names: Iterable[str], prefix: str = ""):
+        self.prefix = prefix
+        self.seconds: Dict[str, float] = {n: 0.0 for n in names}
+        self.entries: Dict[str, int] = {n: 0 for n in names}
+        self._open: list = []          # the stack of open _Stage
+        self._ended: Optional[tuple] = None   # (name, when): last outermost
 
-    def __init__(self):
-        self.stages_ms: Dict[str, float] = {}
+    def stages(self) -> Dict[str, Dict[str, float]]:
+        return {n: {"s": s, "n": self.entries[n]}
+                for n, s in self.seconds.items()}
 
-    @contextlib.contextmanager
-    def stage(self, name: str, sync_on=None, sync_on_fn=None):
-        t0 = time.perf_counter()
-        yield
-        target = sync_on_fn() if sync_on_fn is not None else sync_on
-        if target is not None:
-            import jax
-            jax.block_until_ready(target)
-        self.stages_ms[name] = self.stages_ms.get(name, 0.0) + \
-            (time.perf_counter() - t0) * 1e3
 
-    def sync(self, name: str, target) -> None:
-        """Fold a late device sync into an already-recorded stage."""
-        import jax
-        t0 = time.perf_counter()
-        jax.block_until_ready(target)
-        self.stages_ms[name] = self.stages_ms.get(name, 0.0) + \
-            (time.perf_counter() - t0) * 1e3
+class _Stage:
+    __slots__ = ("clock", "name", "t0", "span")
 
-    @property
-    def total_ms(self) -> float:
-        return sum(self.stages_ms.values())
+    def __init__(self, clock: StageClock, name: str):
+        self.clock, self.name = clock, name
+        self.span = annotate(clock.prefix + name)
+
+    def __enter__(self):
+        clock = self.clock
+        self.span.__enter__()
+        now = time.perf_counter()
+        if clock._open:                # pause the stage around this one
+            outer = clock._open[-1]
+            clock.seconds[outer.name] += now - outer.t0
+        elif clock._ended is not None:  # the last one lasted until now
+            name, when = clock._ended
+            clock.seconds[name] += now - when
+        clock._open.append(self)
+        self.t0 = now
+        return self
+
+    def __exit__(self, *exc):
+        clock = self.clock
+        now = time.perf_counter()
+        clock.seconds[self.name] += now - self.t0
+        clock.entries[self.name] += 1
+        clock._open.pop()
+        if clock._open:
+            clock._open[-1].t0 = now   # resume it
+        else:
+            clock._ended = (self.name, now)
+        self.span.__exit__(*exc)
+        return False
+
+
+def stage(clock: StageClock, name: str) -> _Stage:
+    """``with stage(clock, "dispatch"):`` — one span
+    ``<clock.prefix>dispatch`` in the profiler's trace (when a capture is
+    open) plus the block's ``perf_counter`` seconds and one entry on
+    ``clock``.  Always on: two clock reads and an inactive-TraceMe check
+    per use."""
+    return _Stage(clock, name)
 
 
 class ChromeTraceRecorder:
