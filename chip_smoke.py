@@ -330,7 +330,9 @@ def ragged_case(smoke: Smoke, name: str, q_lens, kv_lens, m: int,
                 dtype=None, g_pages=None, nbuf=None, heads=None,
                 tol: float = ATTN_TOL) -> float:
     """ragged_paged_attention vs the XLA gather path on one segment mix at
-    the LM's head geometry.  Returns the max abs error over valid rows."""
+    the LM's head geometry, on the second layer of a two-layer page store
+    (the kernel indexes the layer itself: a wrong offset reads the first).
+    Returns the max abs error over valid rows."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -349,12 +351,12 @@ def ragged_case(smoke: Smoke, name: str, q_lens, kv_lens, m: int,
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, m, h, d)), dtype)
     pool = jnp.asarray(
-        rng.standard_normal((b * mp + 1, 2, ps, hkv, d)), dtype)
+        rng.standard_normal((2, b * mp + 1, 2, ps, hkv * d)), dtype)
     tables = (1 + np.arange(b * mp, dtype=np.int32)).reshape(b, mp)
     interpret = smoke.rehearsal
 
     def kernel(q, pool):
-        return ragged_paged_attention(q, pool, tables, q_lens, kv_lens,
+        return ragged_paged_attention(q, pool, 1, tables, q_lens, kv_lens,
                                       interpret=interpret, g_pages=g_pages,
                                       nbuf=nbuf)
 
@@ -362,7 +364,8 @@ def ragged_case(smoke: Smoke, name: str, q_lens, kv_lens, m: int,
     got = np.asarray(jax.block_until_ready(kernel(q, pool)), np.float32)
     pos = (kv_lens - q_lens)[:, None] + np.arange(m)[None, :]
     want = np.asarray(_gather_attend(
-        q, pool[:, 0], pool[:, 1], jnp.asarray(tables), jnp.asarray(pos),
+        q, pool[1, :, 0], pool[1, :, 1], jnp.asarray(tables),
+        jnp.asarray(pos),
         jnp.float32), np.float32).reshape(b, m, h, d)
     valid = np.arange(m)[None, :] < q_lens[:, None]
     if not np.isfinite(got[valid]).all():

@@ -47,8 +47,8 @@ def test_kernel_refuses_excluded_geometry_before_mosaic():
     excludes, the kernel raises ValueError naming the constraint instead
     of handing Mosaic a program it will refuse."""
     q = jnp.zeros((1, 1, 4, 16), jnp.float32)
-    pool = jnp.zeros((3, 2, 8, 4, 16), jnp.float32)   # row 4*16 = 64 lanes
-    args = (np.zeros((1, 2), np.int32), np.ones((1,), np.int32),
+    pool = jnp.zeros((1, 3, 2, 8, 4 * 16), jnp.float32)   # row of 64 lanes
+    args = (0, np.zeros((1, 2), np.int32), np.ones((1,), np.int32),
             np.ones((1,), np.int32))
     with pytest.raises(ValueError, match="not a multiple of 128 lanes"):
         ragged_paged_attention(q, pool, *args, interpret=False)

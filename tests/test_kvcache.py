@@ -10,7 +10,7 @@ import pytest
 
 from tpulab import chaos
 from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool,
-                                 SamplingParams)
+                                 SamplingParams, kv_rows_view)
 from tpulab.kvcache import HostKVStore, KVOffloadManager
 from tpulab.models.transformer import init_transformer_params, make_generate_fn
 
@@ -92,7 +92,7 @@ def test_swap_out_in_roundtrip_bit_exact():
         src = [pool.allocate_page() for _ in range(3)]
         data = np.random.default_rng(1).standard_normal(
             (2, 3, 2, 4, 2, 8)).astype(np.float32)
-        pool.kv = pool.kv.at[:, np.asarray(src)].set(data)
+        pool.kv = pool.kv.at[:, np.asarray(src)].set(kv_rows_view(data))
         h = mgr.swap_out(src, length=12, kv=pool.kv)
         assert h is not None
         assert h.wait(10)                     # write-behind landed
@@ -102,7 +102,7 @@ def test_swap_out_in_roundtrip_bit_exact():
         assert new_kv is not None
         pool.kv = new_kv
         np.testing.assert_array_equal(
-            np.asarray(pool.kv[:, np.asarray(dst)]), data)
+            np.asarray(pool.kv[:, np.asarray(dst)]), kv_rows_view(data))
         assert mgr.swap_outs == 1 and mgr.swap_ins == 1
         assert mgr.recompute_tokens_saved == 12
         assert len(mgr.store) == 0            # one-shot: restore pops

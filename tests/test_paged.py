@@ -258,8 +258,9 @@ def test_gqa_paged_matches_dense_generation():
                            max_len=64, page_size=8,
                            compute_dtype=jnp.float32, n_kv_heads=2)
     try:
-        # pool stores the compact KV form: heads axis == n_kv_heads
-        assert cb.pool.kv.shape[4] == 2
+        # pool stores the compact KV form: a row is n_kv_heads heads
+        assert cb.pool.n_kv_heads == 2
+        assert cb.pool.kv.shape[4] == 2 * cb.pool.head_dim
         prompts = [np.random.default_rng(s).integers(0, 64, (4 + s,),
                                                      np.int32)
                    for s in range(3)]
@@ -556,8 +557,8 @@ def test_kv_cache_quantization_fp8(lm):
 
     # numerics: one decode tick over identical KV content, fp8 vs f32 pool
     rng = np.random.default_rng(0)
-    # fused pool shape: (n_layers, n_pages, 2, page_size, n_heads, head_dim)
-    kv32 = jnp.asarray(rng.uniform(-1, 1, (2, 4, 2, 8, 2, 16)), jnp.float32)
+    # fused pool shape: (n_layers, n_pages, 2, page_size, n_heads * head_dim)
+    kv32 = jnp.asarray(rng.uniform(-1, 1, (2, 4, 2, 8, 2 * 16)), jnp.float32)
     tables = jnp.asarray([[1, 2]], jnp.int32)
     lengths = jnp.asarray([12], jnp.int32)
     tokens = jnp.asarray([3], jnp.int32)

@@ -89,16 +89,16 @@ def test_transformer_param_shardings_full_rule_tree():
 
 
 def test_kv_pool_sharding_spec():
-    """Page payloads shard on the KV-heads dim (axis 4 of the fused
-    (L, P, 2, S, Hkv, D) layout); page tables are host arrays and never
-    see this spec."""
+    """Page payloads shard on the row of KV heads (axis 4 of the fused
+    (L, P, 2, S, Hkv*D) layout: contiguous head groups); page tables are
+    host arrays and never see this spec."""
     from tpulab.parallel import kv_pool_sharding
     mesh = make_mesh({"model": 2})
     assert kv_pool_sharding(mesh).spec == P(None, None, None, None,
-                                            "model", None)
+                                            "model")
     mesh2 = make_mesh({"tp": 2})
     assert kv_pool_sharding(mesh2, model_axis="tp").spec == \
-        P(None, None, None, None, "tp", None)
+        P(None, None, None, None, "tp")
 
 
 # -------------------------------------------------------------- attention ---

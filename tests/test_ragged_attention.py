@@ -25,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpulab.engine.paged import ContinuousBatcher, SamplingParams
+from tpulab.engine.paged import (ContinuousBatcher, SamplingParams,
+                                 _gather_attend, kv_rows_view)
 from tpulab.models.transformer import (early_exit_draft,
                                        init_transformer_params)
 from tpulab.ops.ragged_attention import ragged_paged_attention
@@ -54,6 +55,13 @@ def _reference(q, k_pool, v_pool, tables, q_lens, kv_lens):
                 p /= p.sum()
                 out[bb, j, hh] = p @ v_ctx[:pos + 1, hk]
     return out
+
+
+def _pool(k_pool, v_pool, dtype=None):
+    """One layer's K and V pages ``(P, S, Hkv, D)`` as a page store of
+    one layer in the engine's layout, ``(1, P, 2, S, Hkv*D)``."""
+    kv = kv_rows_view(jnp.stack([k_pool, v_pool], axis=1))[None]
+    return kv.astype(dtype) if dtype is not None else kv
 
 
 def _shape_case(name, page_size):
@@ -105,7 +113,7 @@ def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n):
     mesh = (make_mesh({"model": mesh_n}, jax.devices()[:mesh_n])
             if mesh_n else None)
     got = ragged_paged_attention(
-        q.astype(dt), jnp.stack([k_pool, v_pool], axis=1).astype(dt),
+        q.astype(dt), _pool(k_pool, v_pool, dt), 0,
         tables, jnp.asarray(q_lens, jnp.int32),
         jnp.asarray(kv_lens, jnp.int32), mesh=mesh)
     want = _reference(np.asarray(q), np.asarray(k_pool),
@@ -132,7 +140,7 @@ def test_kernel_long_walk_exceeds_pipeline_depth():
     q_lens = jnp.asarray([3], jnp.int32)
     kv_lens = jnp.asarray([ps * mp - 1], jnp.int32)
     got = ragged_paged_attention(
-        q, jnp.stack([k_pool, v_pool], axis=1), tables, q_lens, kv_lens,
+        q, _pool(k_pool, v_pool), 0, tables, q_lens, kv_lens,
         g_pages=1, nbuf=2)  # pin the multi-block pipeline regime
     want = _reference(np.asarray(q), np.asarray(k_pool),
                       np.asarray(v_pool), np.asarray(tables), [3],
@@ -144,11 +152,77 @@ def test_kernel_long_walk_exceeds_pipeline_depth():
 def test_kernel_rejects_unsplittable_heads_under_mesh():
     mesh = make_mesh({"model": 2}, jax.devices()[:2])
     q = jnp.zeros((1, 1, 3, 16), jnp.float32)
-    kvp = jnp.zeros((2, 2, 4, 3, 16), jnp.float32)
+    kvp = jnp.zeros((1, 2, 2, 4, 3 * 16), jnp.float32)
     with pytest.raises(ValueError, match="divide the mesh"):
-        ragged_paged_attention(q, kvp, jnp.zeros((1, 1), jnp.int32),
+        ragged_paged_attention(q, kvp, 0, jnp.zeros((1, 1), jnp.int32),
                                jnp.ones((1,), jnp.int32),
                                jnp.ones((1,), jnp.int32), mesh=mesh)
+
+
+@pytest.mark.parametrize("case", ["decode", "mixed", "mesh4"])
+def test_kernel_walks_the_layer_it_is_given(case):
+    """The kernel on the WHOLE pool at a layer index > 0 of a three-layer
+    pool whose layers hold different contents, against the gather path on
+    that layer: a wrong layer offset in the page DMAs reads another
+    layer's pages.  Decode (q_lens 1), a mixed round, and the sharded
+    walk on four virtual devices."""
+    ps, mp, d = 8, 3, 16
+    hq, hkv = (8, 4) if case == "mesh4" else (4, 2)
+    q_lens, kv_lens, m = {
+        "decode": ([1, 1, 1, 0], [2 * ps + 1, ps, 3, 0], 1),
+        "mixed": ([1, ps + 2, 5, 0], [2 * ps, 2 * ps + 2, ps + 5, 0],
+                  2 * ps),
+        "mesh4": ([1, ps + 2, 5, 1], [2 * ps, 2 * ps + 2, ps + 5, 3],
+                  2 * ps),
+    }[case]
+    b = len(q_lens)
+    pages = b * mp + 1
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    q = jax.random.normal(ks[0], (b, m, hq, d), jnp.float32)
+    # every layer its own values, so no two layers can be mistaken
+    pool = jax.random.normal(ks[1], (3, pages, 2, ps, hkv * d), jnp.float32)
+    tables = jnp.asarray(np.arange(1, b * mp + 1).reshape(b, mp), jnp.int32)
+    q_lens = jnp.asarray(q_lens, jnp.int32)
+    kv_lens = jnp.asarray(kv_lens, jnp.int32)
+    pos = (kv_lens - q_lens)[:, None] + jnp.arange(m)[None, :]
+    mesh = (make_mesh({"model": 4}, jax.devices()[:4])
+            if case == "mesh4" else None)
+    valid = np.arange(m)[None, :] < np.asarray(q_lens)[:, None]
+    outs = {}
+    for layer in (1, 2):
+        got = np.asarray(ragged_paged_attention(
+            q, pool, layer, tables, q_lens, kv_lens, mesh=mesh))
+        want = np.asarray(_gather_attend(
+            q, pool[layer, :, 0], pool[layer, :, 1], tables, pos,
+            jnp.float32)).reshape(b, m, hq, d)
+        np.testing.assert_allclose(got[valid], want[valid],
+                                   rtol=2e-5, atol=2e-5)
+        outs[layer] = got[valid]
+    assert np.abs(outs[1] - outs[2]).max() > 1e-2   # the layers do differ
+
+
+def test_kernel_takes_a_traced_layer():
+    """The layer rides the scalar prefetch: one compiled kernel serves
+    every layer, also from inside a ``lax.scan`` over layers."""
+    ps, mp, d, h = 8, 2, 16, 2
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    q = jax.random.normal(ks[0], (2, 1, h, d), jnp.float32)
+    pool = jax.random.normal(ks[1], (3, 2 * mp + 1, 2, ps, h * d),
+                             jnp.float32)
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    q_lens = jnp.ones((2,), jnp.int32)
+    kv_lens = jnp.asarray([ps + 3, 5], jnp.int32)
+
+    def walk(_, layer):
+        return None, ragged_paged_attention(q, pool, layer, tables, q_lens,
+                                            kv_lens)
+
+    _, scanned = jax.jit(lambda: jax.lax.scan(walk, None, jnp.arange(3)))()
+    for layer in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(scanned[layer]),
+            np.asarray(ragged_paged_attention(q, pool, layer, tables,
+                                              q_lens, kv_lens)))
 
 
 # ------------------------------------------------------------ engine ----

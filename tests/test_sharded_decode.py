@@ -24,7 +24,8 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tpulab.engine.paged import ContinuousBatcher, PagedKVPool, SamplingParams
+from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool,
+                                 SamplingParams, kv_rows_view)
 from tpulab.models.transformer import (early_exit_draft,
                                        init_transformer_params,
                                        make_generate_fn)
@@ -68,7 +69,7 @@ def test_pool_and_params_are_actually_sharded(lm):
     try:
         assert cb.pool.kv_sharding is not None
         assert cb.pool.kv.sharding.spec == P(None, None, None, None,
-                                             "model", None)
+                                             "model")
         assert cb.pool.n_shards == 2
         assert cb.pool.hbm_bytes_per_shard == cb.pool.hbm_bytes // 2
         assert cb.params["layer0"]["wqkv"].sharding.spec == P(None, "model")
@@ -259,15 +260,15 @@ def test_sharded_swap_payload_is_mesh_portable(lm):
     mgr_a = KVOffloadManager(pool_a, store=store)
     mgr_b = KVOffloadManager(pool_b, store=store)
     page_a = pool_a.allocate_page()
-    pool_a.kv = pool_a.kv.at[:, page_a].set(jnp.asarray(payload[:, 0]))
+    rows = kv_rows_view(payload[:, 0])      # the device keeps rows
+    pool_a.kv = pool_a.kv.at[:, page_a].set(jnp.asarray(rows))
     h = mgr_a.swap_out([page_a], 8, pool_a.kv)
     assert h is not None
     mgr_a.drain()
     page_b = pool_b.allocate_page()
     new_kv = mgr_b.restore(h, [page_b], pool_b.kv)
     assert new_kv is not None
-    np.testing.assert_array_equal(
-        np.asarray(new_kv[:, page_b]), payload[:, 0])
+    np.testing.assert_array_equal(np.asarray(new_kv[:, page_b]), rows)
     assert mgr_a._placement_key() != mgr_b._placement_key()
 
 

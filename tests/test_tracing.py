@@ -271,7 +271,8 @@ def test_pallas_kernels_carry_names(kernel):
     else:
         from tpulab.ops.ragged_attention import ragged_paged_attention
         fn, q = (lambda q: ragged_paged_attention(
-            q, kv, tables, jnp.array([1, 2], jnp.int32), lens)), \
+            q, kv.reshape(1, 5, 2, 8, 2 * 32), 0, tables,
+            jnp.array([1, 2], jnp.int32), lens)), \
             jnp.ones((2, 2, 2, 32))
     assert list(_pallas_names(jax.make_jaxpr(fn)(q).jaxpr)) == [kernel]
     assert kernel in jax.jit(fn).lower(q).as_text(debug_info=True)

@@ -146,7 +146,8 @@ class KVShipper:
         """The reject-don't-corrupt gate: the shipment's layout must
         match the local pool axis for axis (page count excepted)."""
         pool = self.manager.pool
-        local = tuple(pool.kv.shape)       # (L, P, 2, S, Hkv, D)
+        # a shipment is pages as the host holds them, heads apart
+        local = pool.host_shape(pool.n_pages)   # (L, P, 2, S, Hkv, D)
         if arr.ndim != len(local):
             raise WireFormatError(
                 f"shipment rank {arr.ndim} != pool rank {len(local)}")

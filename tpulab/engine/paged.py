@@ -41,8 +41,36 @@ from tpulab.utils import tracing
 from tpulab.utils.tracing import stage
 
 
+def kv_page_shape(page_size: int, n_kv_heads: int, head_dim: int) -> tuple:
+    """``(2, S, Hkv*D)``: one layer's share of one page as the device
+    keeps it — the ONE definition of the page payload.
+
+    FUSED: a page's K rows (``[0]``) and V rows (``[1]``) are adjacent in
+    HBM, so the ragged kernel fetches both with one DMA per page (the walk
+    is DMA-issue-bound; fusing halves the issue count).  A row is one
+    position's KV heads side by side, ``Hkv*D`` wide: the shape the kernel
+    DMAs into VMEM, so the page store goes into the ``pallas_call`` as it
+    is and no step reshapes or slices it first (on a TPU merging
+    ``(Hkv, D)`` into one minor dimension changes the tiled layout: a copy
+    of a whole layer of the pool per layer per step).  The bytes are those
+    of ``(2, S, Hkv, D)`` row-major, which is what the host-side formats
+    (host tier, disagg wire, fabric) hold: ``PagedKVPool.host_shape``."""
+    return (2, page_size, n_kv_heads * head_dim)
+
+
+def kv_rows_view(pages):
+    """``(..., Hkv, D)`` heads as the ``(..., Hkv*D)`` rows the page store
+    takes (numpy or jax; the same bytes in the same order)."""
+    return pages.reshape(pages.shape[:-2] + (-1,))
+
+
 class PagedKVPool:
-    """Global paged K/V storage + free-page accounting (host side)."""
+    """Global paged K/V storage + free-page accounting (host side).
+
+    The device array ``kv`` is ``(L, P) + kv_page_shape(S, Hkv, D)`` =
+    ``(n_layers, n_pages, 2, page_size, n_kv_heads * head_dim)``: stored
+    as the ragged kernel reads it.  Under a ``mesh`` the row shards on
+    the model axis (contiguous head groups)."""
 
     def __init__(self, n_pages: int, page_size: int, n_layers: int,
                  n_heads: int, head_dim: int, dtype=None, device=None,
@@ -56,8 +84,8 @@ class PagedKVPool:
         self.page_size = page_size
         self.n_layers = n_layers
         # sharded serving: with a ``mesh`` the page *payloads* shard over
-        # the ``model`` axis on the KV-heads dim (each shard holds its own
-        # heads' K/V, matching the column-parallel wqkv that writes them)
+        # the ``model`` axis on the row of KV heads (each shard holds its
+        # own heads' K/V, matching the column-parallel wqkv that writes them)
         # while the page *tables* — host-side int32 id maps — stay
         # replicated: one logical page id still names one logical page.
         self.mesh = mesh
@@ -70,19 +98,18 @@ class PagedKVPool:
             if n_heads % n_model:
                 raise ValueError(
                     f"pool KV heads ({n_heads}) not divisible by the mesh "
-                    f"model axis ({n_model}) — page payloads shard on the "
-                    "KV-heads dim")
+                    f"model axis ({n_model}) — page payloads shard on "
+                    "whole KV heads")
             self.kv_sharding = kv_pool_sharding(mesh)
             self.device = (device if device is not None
                            else mesh.devices.flat[0])
         else:
             self.device = (device if device is not None
                            else plat.local_device(0))
-        # FUSED page layout: a page's K rows ([..., 0, :, :, :]) and V rows
-        # ([..., 1, :, :, :]) are adjacent in HBM, so the pallas decode
-        # kernel fetches both with ONE DMA per page (the walk is
-        # DMA-issue-bound; fusing halves the issue count)
-        self._shape = (n_layers, n_pages, 2, page_size, n_heads, head_dim)
+        self.n_kv_heads = n_heads
+        self.head_dim = head_dim
+        self._shape = (n_layers, n_pages) + kv_page_shape(
+            page_size, n_heads, head_dim)
         self._dtype = dtype
         # the KV page store is an HBM block owned by the device allocator
         # framework (tracked bytes; reference cuda_allocators device memory);
@@ -126,6 +153,14 @@ class PagedKVPool:
         otherwise."""
         return self.kv_sharding if self.kv_sharding is not None \
             else self.device
+
+    def host_shape(self, n_pages: int) -> tuple:
+        """``(L, n, 2, S, Hkv, D)``: ``n_pages`` pages as the host-side
+        formats hold them (host tier, disagg wire, fabric) — heads apart,
+        the bytes of the device's rows: the view for code that wants
+        heads is a reshape to this."""
+        return (self.n_layers, n_pages, 2, self.page_size,
+                self.n_kv_heads, self.head_dim)
 
     @property
     def n_shards(self) -> int:
@@ -278,12 +313,24 @@ class PagedKVPool:
         return k
 
 
+def _scatter_kv(kv_pool, layer, page_idx, slot_idx, knew, vnew):
+    """Write new K/V ``(..., Hkv, D)`` at ``(page_idx, slot_idx)`` (both
+    shaped ``(...)``) of ``layer``, as rows of the page store: a reshape
+    of the new rows, never of the pool.  Callers route what must not land
+    to the reserved scratch page 0."""
+    knew = kv_rows_view(knew.astype(kv_pool.dtype))
+    vnew = kv_rows_view(vnew.astype(kv_pool.dtype))
+    kv_pool = kv_pool.at[layer, page_idx, 0, slot_idx].set(knew)
+    return kv_pool.at[layer, page_idx, 1, slot_idx].set(vnew)
+
+
 def _gather_attend(q, k_layer, v_layer, tables, qpos, compute_dtype):
     """Dense-gather paged attention (the XLA fallback math, single source
     of truth for decode ticks and extend/chunked prefill).
 
-    q (B, M, H, D) query tokens; k_layer/v_layer (P, S, Hkv, D) one
-    layer's pools; tables (B, MP) page ids; qpos (B, M) global position
+    q (B, M, H, D) query tokens; k_layer/v_layer (P, S, Hkv*D) one
+    layer's K and V rows (XLA fuses the slice of the pool into the
+    gather); tables (B, MP) page ids; qpos (B, M) global position
     of each query token (visibility: context j attends iff j <= qpos).
     Returns (B, M, H*D).
     """
@@ -316,7 +363,8 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
                       mesh=None):
     """One batched decode tick over the paged pool.
 
-    Shapes: kv_pool (L, P, 2, S, Hkv, D) fused page store (axis 2 = K/V),
+    Shapes: kv_pool (L, P, 2, S, Hkv*D) fused page store (axis 2 = K/V,
+    :func:`kv_page_shape`),
     tables (B, MP) int32 page ids (padded rows repeat page 0),
     lengths (B,) current position per lane, tokens (B,), active (B,) bool.
     Returns (logits (B, vocab), kv_pool) — the pool donated by the caller.
@@ -356,15 +404,13 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
             # per-lane positions: each lane decodes at its own length
             q = apply_rope(q, lengths[:, None], rope_theta)
             knew = apply_rope(knew, lengths[:, None], rope_theta)
-        knew = knew[:, 0].astype(kv_pool.dtype)      # (B, Hkv, D)
-        vnew = vnew[:, 0].astype(kv_pool.dtype)
         # scatter the new K/V into their pages; inactive/padded lanes are
         # routed to the RESERVED scratch page 0 so they can never clobber
         # a live lane's pages
         safe_page = jnp.where(active, page_idx, 0)
         safe_slot = jnp.where(active, slot_idx, 0)
-        kv_pool = kv_pool.at[layer, safe_page, 0, safe_slot].set(knew)
-        kv_pool = kv_pool.at[layer, safe_page, 1, safe_slot].set(vnew)
+        kv_pool = _scatter_kv(kv_pool, layer, safe_page, safe_slot,
+                              knew[:, 0], vnew[:, 0])       # (B, Hkv, D)
         if use_kernel:
             # pallas ragged kernel at the q=1 decode shape: walks block
             # tables page-by-page, no dense gather materialization; fused
@@ -373,7 +419,7 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
             from tpulab.ops.ragged_attention import ragged_paged_attention
             gk, nk = kernel_geometry or (None, None)
             attn = ragged_paged_attention(
-                q, kv_pool[layer], tables,
+                q, kv_pool, layer, tables,
                 jnp.ones_like(lengths), lengths + 1,
                 mesh=mesh, g_pages=gk, nbuf=nk,
             ).astype(compute_dtype).reshape(b, 1, d_model)
@@ -557,17 +603,15 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
         if rope_theta:
             q = apply_rope(q, pos, rope_theta)
             knew = apply_rope(knew, pos, rope_theta)
-        kv_pool = kv_pool.at[layer, page_idx, 0, slot_idx].set(
-            knew.astype(kv_pool.dtype))
-        kv_pool = kv_pool.at[layer, page_idx, 1, slot_idx].set(
-            vnew.astype(kv_pool.dtype))
+        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
+                              knew, vnew)
         if use_kernel:
             # pallas ragged walk over the block tables (one program for
             # every segment mix; sharded on KV-heads under a mesh)
             from tpulab.ops.ragged_attention import ragged_paged_attention
             gk, nk = kernel_geometry or (None, None)
             attn = ragged_paged_attention(
-                q, kv_pool[layer], tables, q_lens, kv_lens,
+                q, kv_pool, layer, tables, q_lens, kv_lens,
                 mesh=mesh, g_pages=gk, nbuf=nk,
             ).astype(compute_dtype).reshape(b, m, d_model)
         else:
@@ -791,10 +835,8 @@ def paged_prefill(params, kv_pool, tables, tokens, valid_len,
     page_idx = jnp.where(valid, tables[pos // page_size], 0)  # scratch if pad
     slot_idx = jnp.where(valid, pos % page_size, 0)
     for layer, (k, v) in enumerate(kvs):
-        kv_pool = kv_pool.at[layer, page_idx, 0, slot_idx].set(
-            k[0].astype(kv_pool.dtype))
-        kv_pool = kv_pool.at[layer, page_idx, 1, slot_idx].set(
-            v[0].astype(kv_pool.dtype))
+        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
+                              k[0], v[0])
     last = logits[0, valid_len - 1]
     return last, kv_pool
 
@@ -844,10 +886,8 @@ def paged_extend(params, kv_pool, tables, tokens, start, valid_total,
         if rope_theta:
             q = apply_rope(q, pos, rope_theta)
             knew = apply_rope(knew, pos, rope_theta)
-        kv_pool = kv_pool.at[layer, page_idx, 0, slot_idx].set(
-            knew[0].astype(kv_pool.dtype))
-        kv_pool = kv_pool.at[layer, page_idx, 1, slot_idx].set(
-            vnew[0].astype(kv_pool.dtype))
+        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
+                              knew[0], vnew[0])
         # gather-after-scatter: context = cached prefix + this tail
         attn = _gather_attend(q, kv_pool[layer, :, 0], kv_pool[layer, :, 1],
                               tables[None], pos[None], compute_dtype)

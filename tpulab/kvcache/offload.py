@@ -122,7 +122,7 @@ class KVOffloadManager:
             self._owns_transfer = False
         self._transfer = transfer
         self.metrics = metrics
-        # per-page payload size: pool store is (L, P, 2, S, Hkv, D); one
+        # per-page payload size: pool store is (L, P, 2, S, Hkv*D); one
         # page carries every layer's K+V rows for its S slots
         shape = tuple(pool.kv.shape)
         self.page_nbytes = int(np.prod(shape) // shape[1]
@@ -186,11 +186,21 @@ class KVOffloadManager:
             self._scatter_fns[key] = fn
         return fn
 
-    def _payload_placement(self):
-        """device_put target for restore/promote payloads — the pool's
-        NamedSharding under a mesh (the import RE-SHARDS host bytes onto
-        the local topology), the pool device otherwise."""
-        return getattr(self.pool, "placement", self.pool.device)
+    def _host_view(self, fetched) -> np.ndarray:
+        """A fetched gather of the pool's rows ``(L, n, 2, S, Hkv*D)`` as
+        the host tier and the wire hold it: ``(L, n, 2, S, Hkv, D)``, the
+        same bytes."""
+        arr = np.asarray(fetched)
+        return arr.reshape(self.pool.host_shape(arr.shape[1]))
+
+    def _device_put(self, arr: np.ndarray):
+        """A host snapshot back on the device, as rows, for the scatter:
+        on the pool's NamedSharding under a mesh (the import RE-SHARDS
+        host bytes onto the local topology), the pool device otherwise."""
+        import jax
+
+        from tpulab.engine.paged import kv_rows_view
+        return jax.device_put(kv_rows_view(arr), self.pool.placement)
 
     # -- lane swap (preemption) ----------------------------------------------
     def swap_out(self, pages: List[int], length: int, kv,
@@ -235,7 +245,7 @@ class KVOffloadManager:
         tier (the future itself is dropped afterwards, so the only host
         copy is the budgeted one)."""
         try:
-            arr = np.asarray(fut.result())[:, :n]  # strip pow2 padding
+            arr = self._host_view(fut.result())[:, :n]  # strip pow2 padding
             stored = self.store.put(handle.key, arr)
         except Exception:  # noqa: BLE001 - collector thread must live
             handle._state = _FAILED
@@ -277,8 +287,6 @@ class KVOffloadManager:
         returns None with ``kv`` untouched.  A failure in the scatter
         itself propagates — the donated buffer is gone and the scheduler's
         pool-reset recovery path must run, same as any failed step."""
-        import jax
-
         t0 = _time.perf_counter()
         try:
             if chaos.trip("kvcache.swap") == "drop":
@@ -301,7 +309,7 @@ class KVOffloadManager:
                 pad = np.broadcast_to(
                     zero, (arr.shape[0], idx.shape[0] - n) + arr.shape[2:])
                 arr = np.concatenate([arr, pad], axis=1)
-            data = jax.device_put(arr, self._payload_placement())
+            data = self._device_put(arr)
         except Exception as e:  # noqa: BLE001 - pre-dispatch: degrade
             self.swap_failures += 1
             self.store.remove(handle.key)
@@ -379,7 +387,8 @@ class KVOffloadManager:
 
         def land(f):
             try:
-                if self.store.put(("px", digest), np.asarray(f.result())):
+                if self.store.put(("px", digest),
+                                  self._host_view(f.result())):
                     self.demotions += 1
                     self.swap_out_bytes += self.page_nbytes
                     if self.metrics is not None:
@@ -402,8 +411,6 @@ class KVOffloadManager:
         """Upload a demoted prefix page into ``page``.  Returns the new
         donated pool buffer, or None (miss/failure — caller releases the
         page and recomputes, today's path)."""
-        import jax
-
         t0 = _time.perf_counter()
         try:
             if chaos.trip("kvcache.swap") == "drop":
@@ -411,7 +418,7 @@ class KVOffloadManager:
             arr = self.store.pop(("px", digest))
             if arr is None:
                 return None
-            data = jax.device_put(arr, self._payload_placement())
+            data = self._device_put(arr)
         except Exception as e:  # noqa: BLE001 - pre-dispatch: degrade
             self.swap_failures += 1
             log.warning("prefix promotion degraded to recompute: %s: %s",

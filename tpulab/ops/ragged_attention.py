@@ -31,9 +31,16 @@ accumulator) carried per head through the block walk.  GQA stages pages
 in the compact ``Hkv`` form (the bandwidth win) and slices each query
 head's KV block statically in VMEM.
 
+The pool goes in whole, ``(L, P, 2, S, Hkv*D)`` as
+:class:`~tpulab.engine.paged.PagedKVPool` keeps it, with the layer as one
+more scalar-prefetch word: the page DMAs read ``kv_pool[layer, page]``,
+and nothing slices or reshapes the pool ahead of the call (XLA cannot
+fuse into a ``pallas_call`` operand: either was a copy of a whole layer
+of the pool per call).
+
 Sharded serving: ``mesh=`` wraps the kernel in ``shard_map`` over the
-KV-heads dim — each model-axis shard walks the SAME replicated block
-tables but DMAs only its own heads' page payloads (matching
+KV heads — each model-axis shard walks the SAME replicated block
+tables but DMAs only its own heads' share of each page row (matching
 ``kv_pool_sharding``) and attends its own query heads, so the kernel
 composes with the tensor-parallel engine instead of being rejected at
 construction.  ``interpret=True`` (automatic off TPU) runs the same
@@ -127,12 +134,13 @@ def kernel_geometry_error(q_len: int, n_heads: int, n_kv_heads: int,
     return None
 
 
-def _ragged_attn_kernel(tables_ref, qlens_ref, kvlens_ref, q_ref,
+def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
                         kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
                         max_pages: int, n_heads: int, head_dim: int,
                         n_kv_heads: int, m_q: int, sm_scale: float,
                         precision, g_pages: int, nbuf: int):
     lane = pl.program_id(0)
+    layer = layer_ref[0]                      # which layer's pages to walk
     qn = qlens_ref[lane]                      # valid query rows this lane
     kvn = kvlens_ref[lane]                    # context length incl. segment
     # last visible position; inactive lanes (kvn == 0) clamp to walking
@@ -172,7 +180,7 @@ def _ragged_attn_kernel(tables_ref, qlens_ref, kvlens_ref, q_ref,
             def _start(gg=gg, p_idx=p_idx):
                 page = tables_ref[lane * max_pages + p_idx]
                 pltpu.make_async_copy(
-                    kvpool_ref.at[page],
+                    kvpool_ref.at[layer, page],
                     kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
                     sem.at[slot, gg]).start()
 
@@ -184,7 +192,7 @@ def _ragged_attn_kernel(tables_ref, qlens_ref, kvlens_ref, q_ref,
             def _wait(gg=gg, p_idx=p_idx):
                 page = tables_ref[lane * max_pages + p_idx]
                 pltpu.make_async_copy(
-                    kvpool_ref.at[page],
+                    kvpool_ref.at[layer, page],
                     kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
                     sem.at[slot, gg]).wait()
 
@@ -258,11 +266,14 @@ def _ragged_attn_kernel(tables_ref, qlens_ref, kvlens_ref, q_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "g_pages", "nbuf"))
-def _ragged_attn(q, kv_pool, tables, q_lens, kv_lens, interpret: bool,
+def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
                  g_pages: int | None = None, nbuf: int | None = None):
     b, m, h, d = q.shape
-    n_pages, page_size, hkv = (kv_pool.shape[0], kv_pool.shape[2],
-                               kv_pool.shape[3])
+    page_size, row = kv_pool.shape[3], kv_pool.shape[4]
+    hkv = row // d
+    if hkv * d != row:
+        raise ValueError(f"page row {row} is not a whole number of heads "
+                         f"of {d}")
     if h % hkv:
         raise ValueError(f"q heads {h} not divisible by kv heads {hkv}")
     max_pages = tables.shape[1]
@@ -271,14 +282,14 @@ def _ragged_attn(q, kv_pool, tables, q_lens, kv_lens, interpret: bool,
                                     q.dtype, kv_pool.dtype, g_pages, nbuf)
         if err:
             raise ValueError(f"ragged_paged_attention: {err}")
-    # stage pages as (2, S, Hkv*D) fused K/V blocks (contiguous reshape;
-    # one DMA per page), queries as (B, M, H*D)
+    # the pool goes in as it is stored — a reshape or a slice of it ahead
+    # of the call would be a copy of a layer of the pool on every call (a
+    # pallas_call operand is not fused into); queries as (B, M, H*D)
     q2 = q.reshape(b, m, h * d)
-    kvp = kv_pool.reshape(n_pages, 2, page_size, hkv * d)
     g_pages, nbuf, need = _plan(m, h, hkv, d, page_size, max_pages, q.dtype,
                                 kv_pool.dtype, g_pages, nbuf)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,           # tables (flat), q_lens, kv_lens
+        num_scalar_prefetch=4,    # layer, tables (flat), q_lens, kv_lens
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, m, h * d), lambda lane, *_: (lane, 0, 0)),
@@ -287,8 +298,7 @@ def _ragged_attn(q, kv_pool, tables, q_lens, kv_lens, interpret: bool,
         out_specs=pl.BlockSpec((1, m, h * d),
                                lambda lane, *_: (lane, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((nbuf, 2, g_pages * page_size, hkv * d),
-                       kv_pool.dtype),
+            pltpu.VMEM((nbuf, 2, g_pages * page_size, row), kv_pool.dtype),
             pltpu.SemaphoreType.DMA((nbuf, g_pages)),  # one DMA per page
         ],
     )
@@ -313,26 +323,33 @@ def _ragged_attn(q, kv_pool, tables, q_lens, kv_lens, interpret: bool,
             max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(tables.reshape(-1), q_lens, kv_lens, q2, kvp)
+    )(layer, tables.reshape(-1), q_lens, kv_lens, q2, kv_pool)
     return out.reshape(b, m, h, d)
 
 
-def ragged_paged_attention(q, kv_pool, tables, q_lens, kv_lens,
+def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
                            mesh=None, model_axis: str = "model",
                            interpret: bool | None = None,
                            g_pages: int | None = None,
                            nbuf: int | None = None):
     """Ragged paged attention over per-lane ``(query_len, kv_len)``
-    segments (MHA or grouped-query).
+    segments (MHA or grouped-query), on one layer of the page store.
 
     q (B, M, Hq, D) — up to M query tokens per lane, left-packed: lane
     b's valid queries are ``q[b, :q_lens[b]]``, query j sitting at
     global position ``kv_lens[b] - q_lens[b] + j`` and attending every
     context position <= its own (the gather-after-scatter contract: the
     segment's K/V are already resident in the pool);
-    kv_pool (P, 2, S, Hkv, D) — one layer's page pool in the FUSED
-    layout (axis 1 = K/V adjacent in HBM, one DMA per page; Hkv < Hq
-    selects GQA);
+    kv_pool (L, P, 2, S, Hkv*D) — the WHOLE page store as
+    :class:`~tpulab.engine.paged.PagedKVPool` keeps it (axis 2 = K/V
+    adjacent in HBM, one DMA per page; a row is the KV heads side by
+    side, ``Hkv = row // D``, and ``Hkv < Hq`` selects GQA).  The kernel
+    reads pages straight out of it: never hand it ``kv_pool[layer]`` or
+    a reshape, which XLA would materialise as a copy of a layer of the
+    pool on every call;
+    layer — which layer's pages to walk (int, or a traced int32 scalar:
+    it rides the scalar prefetch, the page DMAs read
+    ``kv_pool[layer, page]``);
     tables (B, MP) int32 page ids (padded rows point at scratch page 0);
     q_lens (B,) int32 — segment length per lane (0 = inactive: output
     rows are garbage the caller must mask);
@@ -340,26 +357,28 @@ def ragged_paged_attention(q, kv_pool, tables, q_lens, kv_lens,
     (NOTE: a count, not the last position — ``q_lens == 1,
     kv_lens == position + 1`` is the single-query decode shape).
 
-    ``mesh=`` shards the walk over the KV-heads dim via ``shard_map``
-    (page payloads per :func:`tpulab.parallel.sharding.kv_pool_sharding`,
-    q/output on the heads dim, tables/lengths replicated) so the kernel
-    compiles inside the engine's tensor-parallel jits.
+    ``mesh=`` shards the walk over the KV heads via ``shard_map`` (page
+    rows per :func:`tpulab.parallel.sharding.kv_pool_sharding`:
+    contiguous head groups of the row; q/output on the heads dim,
+    tables/lengths/layer replicated) so the kernel compiles inside the
+    engine's tensor-parallel jits.
     ``g_pages``/``nbuf`` override the auto block geometry.
     Returns (B, M, Hq, D).
     """
     if interpret is None:
         from tpulab.tpu.platform import pallas_interpret
         interpret = pallas_interpret()
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     tables = tables.astype(jnp.int32)
     q_lens = q_lens.astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
     if mesh is None:
-        return _ragged_attn(q, kv_pool, tables, q_lens, kv_lens,
+        return _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens,
                             interpret, g_pages=g_pages, nbuf=nbuf)
     from jax.sharding import PartitionSpec as P
 
     n_model = dict(mesh.shape)[model_axis]
-    h, hkv = q.shape[2], kv_pool.shape[3]
+    h, hkv = q.shape[2], kv_pool.shape[4] // q.shape[3]
     if h % n_model or hkv % n_model:
         raise ValueError(
             f"query heads ({h}) and KV heads ({hkv}) must divide the "
@@ -370,8 +389,8 @@ def ragged_paged_attention(q, kv_pool, tables, q_lens, kv_lens,
     return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, model_axis, None),
-                  P(None, None, None, model_axis, None),
+                  P(None, None, None, None, model_axis), P(None),
                   P(None, None), P(None), P(None)),
         out_specs=P(None, None, model_axis, None),
         check_vma=False,   # pallas_call has no shard_map replication rule
-    )(q, kv_pool, tables, q_lens, kv_lens)
+    )(q, kv_pool, layer, tables, q_lens, kv_lens)

@@ -28,15 +28,17 @@ def shard_batch(mesh: Mesh, axis: str = "data") -> NamedSharding:
 
 
 def kv_pool_sharding(mesh: Mesh, model_axis: str = "model") -> NamedSharding:
-    """Paged-KV page-store sharding: the pool's fused layout is
-    ``(n_layers, n_pages, 2, page_size, n_kv_heads, head_dim)`` and the
-    page *payloads* shard over the model axis on the KV-heads dim (axis
-    4) — matching the column-parallel ``wqkv`` that produces them, so a
+    """Paged-KV page-store sharding: the pool is
+    ``(n_layers, n_pages, 2, page_size, n_kv_heads * head_dim)``
+    (:func:`tpulab.engine.paged.kv_page_shape`) and the page *payloads*
+    shard over the model axis on the row (axis 4): the KV heads lie side
+    by side in it, so a shard holds a contiguous group of whole heads —
+    matching the column-parallel ``wqkv`` that produces them, so a
     sharded decode step scatters/gathers its own heads with no
     resharding.  Page *tables* (host-side int32 id maps) stay
     replicated.  The same spec places swap payloads
-    ``(n_layers, n, 2, page_size, n_kv_heads, head_dim)``."""
-    return NamedSharding(mesh, P(None, None, None, None, model_axis, None))
+    ``(n_layers, n, 2, page_size, n_kv_heads * head_dim)``."""
+    return NamedSharding(mesh, P(None, None, None, None, model_axis))
 
 
 def transformer_param_shardings(params: Dict[str, Any], mesh: Mesh,
