@@ -19,6 +19,11 @@ Phases, in order; any phase that raises fails the run (exit 1):
 3. lm       — the paged LM (hidden 2048, 16Q/4KV heads of 128, bf16,
               page 16, vocab 50304, depth cut) behind the Generate RPC,
               concurrent streams under each dispatch plan the engine has.
+   latent   — a two-layer MLA + expert model (GLM-4.7-Flash's widths, one
+              dense and one expert layer, 64 experts top-4 + shared) on a
+              latent page store: a mixed round and a decode step through
+              the latent ragged kernel against the XLA gather, the logit
+              error printed.
 4. kernels  — the ragged and flash Pallas kernels compiled by Mosaic
               (``interpret=False``, custom call present in the lowered
               program) against the XLA gather / dense-softmax paths.
@@ -78,6 +83,17 @@ class Sizes:
     lm: dict = field(default_factory=lambda: dict(
         vocab=50304, d_model=2048, n_heads=16, n_kv_heads=4, n_layers=4,
         d_ff=5632))
+    # GLM-4.7-Flash's published widths (perf/configs/glm47flash-l8.json);
+    # depth and vocabulary are the cuts
+    glm: dict = field(default_factory=lambda: dict(
+        hidden_size=2048, intermediate_size=10240, num_attention_heads=20,
+        num_hidden_layers=2, first_k_dense_replace=1, q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+        v_head_dim=256, n_routed_experts=64, num_experts_per_tok=4,
+        n_shared_experts=1, moe_intermediate_size=1536,
+        routed_scaling_factor=1.8, norm_topk_prob=True, rms_norm_eps=1e-5,
+        rope_theta=1e6, vocab_size=50304))
+    glm_chunk: int = 256
     lm_max_len: int = 512
     lm_page_size: int = 16
     lm_prefill_chunk: int = 128
@@ -92,6 +108,14 @@ REHEARSAL_SIZES = Sizes(
     rn50_batches=(1, 8, 1),
     lm=dict(vocab=256, d_model=64, n_heads=4, n_kv_heads=2, n_layers=2,
             d_ff=128),
+    glm=dict(hidden_size=64, intermediate_size=96, num_attention_heads=4,
+             num_hidden_layers=2, first_k_dense_replace=1, q_lora_rank=24,
+             kv_lora_rank=32, qk_nope_head_dim=12, qk_rope_head_dim=8,
+             v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2,
+             n_shared_experts=1, moe_intermediate_size=48,
+             routed_scaling_factor=1.8, norm_topk_prob=True,
+             rms_norm_eps=1e-5, rope_theta=1e6, vocab_size=256),
+    glm_chunk=16,
     lm_max_len=96, lm_page_size=8, lm_prefill_chunk=16,
     lm_prompt_lens=(5, 12, 40), lm_steps=6, flash_t=32)
 
@@ -312,6 +336,80 @@ def phase_lm(smoke: Smoke) -> str:
         manager.shutdown()
         for cb in engines.values():
             cb.shutdown()
+
+
+# -- phase 3b: a latent cache entry and expert layers --------------------------
+def phase_latent(smoke: Smoke) -> str:
+    """One mixed round (a prompt chunk beside decode lanes) and one decode
+    step of a two-layer MLA + expert model over a latent page store, by the
+    latent ragged kernel and by the XLA gather, on the same inputs."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.paged import (PagedKVPool, paged_decode_step,
+                                     paged_mixed_step)
+    from tpulab.models.spec import glm4_moe_lite_spec, init_params
+    sz = smoke.sizes
+    cfg, chunk, page = sz.glm, sz.glm_chunk, sz.lm_page_size
+    spec = glm4_moe_lite_spec(cfg)
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        init_params(spec, cfg["vocab_size"], cfg["intermediate_size"]))
+    lanes, mp = 4, 3 * chunk // page
+    rng = np.random.default_rng(2)
+    tables = np.zeros((lanes, mp), np.int32)
+    tables[:3] = 1 + np.arange(3 * mp).reshape(3, mp)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    kw = dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
+              compute_dtype=jnp.bfloat16, spec=spec)
+    # lane 0: the second chunk of a prompt; lanes 1, 2 decode; lane 3 idle
+    fill = (i32(rng.integers(0, cfg["vocab_size"], (lanes, chunk))),
+            i32([chunk, chunk - 3, chunk // 2, 0]))
+    seq = i32(rng.integers(0, cfg["vocab_size"], (lanes, chunk)))
+    q_lens = i32([chunk, 1, 1, 0])
+    kv_lens = i32([2 * chunk, chunk - 2, chunk // 2 + 1, 0])
+    temps, seeds = jnp.zeros((lanes,), jnp.float32), jnp.zeros(
+        (lanes, 2), jnp.uint32)
+    out = {}
+    for name, uk in (("gather", False), ("kernel", True)):
+        pool = PagedKVPool(3 * mp + 1, page, spec.n_layers, 0, 0,
+                           jnp.bfloat16, latent_width=spec.latent_width)
+        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **kw),
+                        donate_argnums=(1,))
+        step = jax.jit(partial(paged_decode_step, use_kernel=uk, **kw),
+                       donate_argnums=(1,))
+        if uk:
+            check_mosaic(smoke, "latent mixed round", partial(
+                paged_mixed_step, use_kernel=True, **kw), params, pool.kv,
+                i32(tables), seq, q_lens, kv_lens, temps, seeds)
+        _, _, _, kv, _ = mixed(params, pool.kv, i32(tables), *fill, fill[1],
+                               temps, seeds)
+        _, _, last, kv, moe = mixed(params, kv, i32(tables), seq, q_lens,
+                                    kv_lens, temps, seeds)
+        logits, kv, _ = step(params, kv, i32(tables), kv_lens,
+                             i32([5, 6, 7, 0]),
+                             jnp.asarray([True, True, True, False]))
+        out[name] = (np.asarray(last, np.float32)[:3],
+                     np.asarray(logits, np.float32)[:3], np.asarray(moe))
+        pool.close()
+    report = []
+    for i, what in enumerate(("mixed round", "decode step")):
+        ref, got = out["gather"][i], out["kernel"][i]
+        err = float(np.abs(got - ref).max())
+        scale = float(np.abs(ref).max())
+        if not np.isfinite(got).all() or err > LOGIT_RTOL * scale:
+            raise AssertionError(f"latent {what}: kernel logits {err:.4g} "
+                                 f"from the gather's (largest {scale:.4g})")
+        report.append(f"{what} logit err {err:.4g} of {scale:.4g}")
+    counts = out["kernel"][2][:, :spec.n_experts]
+    if counts.sum() != spec.top_k * (chunk + 2):
+        raise AssertionError(f"expert assignments {counts.sum()} != top-"
+                             f"{spec.top_k} x {chunk + 2} valid rows")
+    return "; ".join(report) + (f"; {int((counts > 0).sum())} of "
+                                f"{spec.n_experts} experts hit")
 
 
 # -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------
@@ -580,6 +678,7 @@ def main(argv=None) -> int:
                   f"from cpp/, pallas_interpret={pallas_interpret()}")
         smoke.run("rn50", phase_rn50)
         smoke.run("lm", phase_lm)
+        smoke.run("latent", phase_latent)
         smoke.run("kernels", phase_kernels)
         smoke.run("multichip", phase_multichip)
 

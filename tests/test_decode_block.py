@@ -293,3 +293,29 @@ def test_block_deadline_expiry_within_one_block(lm):
         assert cb.pool.free_pages == cb.pool.n_pages - 1
     finally:
         cb.shutdown()
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["legacy", "ragged"])
+def test_a_lane_admitted_beside_a_running_chain_is_not_starved(lm, ragged):
+    """Blocks are dispatched ahead from the device-resident carry for as
+    long as the lane set is stable; a request admitted meanwhile is in no
+    block of that chain.  It must get its steps as soon as its prompt is
+    in, not when a lane of the chain completes: here the only other lane
+    runs 160 steps, and the late request's 4 tokens must not wait for
+    them."""
+    done = []
+    cb = _batcher(lm, 8, max_len=256, ragged=ragged)
+    try:
+        started = _time.monotonic()
+        long_run = cb.submit([3, 14, 15, 9, 2], 160)
+        long_run.add_done_callback(lambda f: done.append("long"))
+        while cb.tokens_generated < 20:          # the chain is running
+            assert _time.monotonic() - started < 120
+            _time.sleep(0.001)
+        late = cb.submit([2, 7, 1, 8], 4)
+        late.add_done_callback(lambda f: done.append("late"))
+        assert len(late.result(timeout=120)) == 4
+        assert len(long_run.result(timeout=120)) == 160
+        assert done == ["late", "long"]
+    finally:
+        cb.shutdown()

@@ -205,3 +205,97 @@ def test_pool_round_trips_are_bit_exact():
     finally:
         mgr.close()
         pool.close()
+
+
+# ------------------------------------------------- the latent entry kind ----
+
+#: taken with this test's own ``_latent_pages`` when the latent
+#: cache-entry kind was added (PR 28), the same on both plans
+LATENT_GOLDEN = {
+    "shape": [2, 7, 1, 4, 128],
+    "sha256": "f1f9e06a2f7610302480fab2055203ebaa3bc2688bd5b32146efe3f0ab730cc0",
+}
+
+
+def _exact_latent_lm():
+    """:func:`_exact_lm` for MLA: every latent column selects ONE +-1
+    feature times a power of two (sums of their squares are exact in any
+    order, so the latent's RMSNorm is too), and ``wo``/``w2`` are zero so
+    every layer sees the embedding."""
+    from tpulab.models.spec import ModelSpec, init_params
+    spec = ModelSpec(n_layers=2, d_model=16, n_heads=2, attention="mla",
+                     q_lora_rank=8, kv_lora_rank=12, qk_nope_head_dim=6,
+                     qk_rope_head_dim=4, v_head_dim=8, rms_eps=1e-5,
+                     rope_theta=10000.0)
+    p = init_params(spec, vocab=16, d_ff=16)
+    code = (np.arange(16)[:, None] * 40503 + 12345) >> np.arange(16)
+    p["embed"] = jnp.asarray(1.0 - 2.0 * (code & 1), jnp.float32)
+    for layer in range(2):
+        w = np.zeros((16, spec.latent_width), np.float32)
+        for j in range(spec.latent_width):
+            w[(5 * j + 3 * layer) % 16, j] = 2.0 ** ((j + 7 * layer) % 9 - 4)
+        lp = p[f"layer{layer}"]
+        lp["wkv_a"] = jnp.asarray(w)
+        lp["wo"] = jnp.zeros_like(lp["wo"])
+        lp["w2"] = jnp.zeros_like(lp["w2"])
+    return spec, p
+
+
+def _latent_pages(use_kernel):
+    spec, params = _exact_latent_lm()
+    cb = ContinuousBatcher(params, spec.n_heads, spec.n_layers, spec=spec,
+                           lanes=1, max_len=32, page_size=4, n_pages=8,
+                           compute_dtype=jnp.float32, use_kernel=use_kernel)
+    try:
+        assert cb.pool.kv.dtype == jnp.float32
+        prompt = np.asarray([3, 14, 1, 5, 9, 2, 6, 11, 8, 7, 13], np.int32)
+        cb.submit(prompt, 1).result(timeout=120)
+        # page 0 is scratch; the stream's pages are the three highest ids
+        return spec, np.asarray(cb.pool.kv)[:, 1:]
+    finally:
+        cb.shutdown()
+
+
+def test_latent_page_shape_is_one_padded_row_a_token():
+    from tpulab.engine.paged import latent_page_shape
+    assert latent_page_shape(16, 576) == (1, 16, 640)
+    assert latent_page_shape(8, 40) == (1, 8, 128)
+    assert latent_page_shape(16, 512) == (1, 16, 512)
+    pool = PagedKVPool(5, 16, 8, 0, 0, jnp.bfloat16, latent_width=576)
+    assert pool.kv.shape == (8, 5, 1, 16, 640)
+    assert pool.entry_kind == "latent"
+    # 8 layers x 640 values x 2 B; K/V of 20 heads of 256 would be 163,840
+    assert pool.bytes_per_token == 10240
+    assert PagedKVPool(5, 16, 8, 20, 256, jnp.bfloat16
+                       ).bytes_per_token == 163840
+
+
+@pytest.mark.parametrize("plan", ["ragged_gather", "ragged_kernel"])
+def test_latent_pages_hold_the_row_once(plan):
+    """A prompt written through the engine leaves ``[c_kv ; k_rope ;
+    zeros]`` a position a layer: normalised latent, RoPE'd shared key, the
+    pad; nothing stored twice, nothing expanded."""
+    spec, pages = _latent_pages(use_kernel=(plan == "ragged_kernel"))
+    assert list(pages.shape) == LATENT_GOLDEN["shape"]
+    used = pages[:, -3:]                      # 11 tokens: 4 + 4 + 3 slots
+    assert not pages[:, :-3].any()            # no other page was written
+    rows = used[:, ::-1].reshape(2, 12, 128)  # pages pop from the top
+    assert not rows[:, 11].any()              # nor the slot past the prompt
+    rows = rows[:, :11]
+    assert not rows[..., spec.latent_width:].any()     # the pad stays zero
+    c_kv, k_r = rows[..., :12], rows[..., 12:16]
+    # RMSNorm of the latent: unit mean square, signs and ratios kept
+    np.testing.assert_allclose((c_kv ** 2).mean(-1), 1.0, rtol=1e-4)
+    ratio = np.abs(c_kv[0, :, 1] / c_kv[0, :, 0])
+    np.testing.assert_allclose(ratio, 2.0, rtol=1e-6)  # columns 2^-3, 2^-4
+    # RoPE turns the shared key by position and keeps its length; position
+    # 0 is not turned: +-2^((j) % 9 - 4) * rsqrt(1 + eps) for j = 12..15
+    np.testing.assert_allclose(
+        np.abs(k_r[0, 0]), 2.0 ** (np.arange(12, 16) % 9 - 4)
+        / np.sqrt(1 + 1e-5), rtol=1e-6)
+    norms = (k_r[0] ** 2).reshape(11, 2, 2).sum(axis=1)
+    np.testing.assert_allclose(norms, np.broadcast_to(norms[:1], norms.shape),
+                               rtol=1e-5)
+    got = hashlib.sha256(np.asarray(pages, jnp.bfloat16).tobytes()
+                         ).hexdigest()
+    assert got == LATENT_GOLDEN["sha256"]

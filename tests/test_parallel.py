@@ -260,12 +260,12 @@ def test_expert_parallel_moe_matches_dense():
 
 
 def test_moe_top1_routing():
-    from tpulab.parallel.moe import init_moe_params, moe_ffn, _gates
+    from tpulab.parallel.moe import init_moe_params, moe_ffn, route
     params = init_moe_params(d_model=16, d_ff=32, n_experts=4, seed=2)
     x = jax.random.normal(jax.random.PRNGKey(3), (8, 16), jnp.float32)
-    g = _gates(params, x, top_k=1)
-    assert np.allclose(np.asarray(g).sum(-1), 1.0, atol=1e-6)
-    assert ((np.asarray(g) > 0).sum(-1) == 1).all()  # exactly one expert
+    idx, w = route(params["router"], x, top_k=1)
+    assert idx.shape == (8, 1)                        # exactly one expert
+    assert np.allclose(np.asarray(w).sum(-1), 1.0, atol=1e-6)
     y = moe_ffn(params, x, top_k=1)
     assert y.shape == x.shape
 
@@ -312,13 +312,13 @@ def test_pipeline_single_microbatch():
 
 def test_moe_tied_logits_exact_k():
     """Uniform router logits (padding tokens) still select exactly k."""
-    from tpulab.parallel.moe import init_moe_params, _gates
+    from tpulab.parallel.moe import init_moe_params, route
     params = init_moe_params(d_model=16, d_ff=32, n_experts=4, seed=0)
     zeros = jnp.zeros((3, 16), jnp.float32)   # tied logits everywhere
-    g1 = _gates(params, zeros, top_k=1)
-    assert ((np.asarray(g1) > 0).sum(-1) == 1).all()
-    g2 = _gates(params, zeros, top_k=2)
-    assert ((np.asarray(g2) > 0).sum(-1) == 2).all()
+    for k in (1, 2):
+        idx, w = route(params["router"], zeros, top_k=k)
+        assert all(len(set(row)) == k for row in np.asarray(idx).tolist())
+        assert (np.asarray(w) > 0).all()
 
 
 def test_pipeline_rejects_stage_mesh_mismatch():

@@ -58,6 +58,19 @@ def kv_page_shape(page_size: int, n_kv_heads: int, head_dim: int) -> tuple:
     return (2, page_size, n_kv_heads * head_dim)
 
 
+def latent_page_shape(page_size: int, latent_width: int) -> tuple:
+    """``(1, S, row)``: one layer's share of one page of the *latent*
+    cache-entry kind (multi-head latent attention) — the ONE definition of
+    it.  A position leaves one row ``[c_kv ; k_rope]`` (after norm and
+    RoPE), once: it is the key of every query head and its first
+    ``kv_lora_rank`` columns are the value, so there is no second half
+    (axis 2 is 1 where a K/V page has 2; a program tells the entry kind
+    from it).  ``row`` is ``latent_width`` padded with zeros to whole
+    128-lane tiles: what the device's tiled layout occupies anyway, and
+    what a page DMA into VMEM needs."""
+    return (1, page_size, -(-latent_width // 128) * 128)
+
+
 def kv_rows_view(pages):
     """``(..., Hkv, D)`` heads as the ``(..., Hkv*D)`` rows the page store
     takes (numpy or jax; the same bytes in the same order)."""
@@ -70,11 +83,17 @@ class PagedKVPool:
     The device array ``kv`` is ``(L, P) + kv_page_shape(S, Hkv, D)`` =
     ``(n_layers, n_pages, 2, page_size, n_kv_heads * head_dim)``: stored
     as the ragged kernel reads it.  Under a ``mesh`` the row shards on
-    the model axis (contiguous head groups)."""
+    the model axis (contiguous head groups).
+
+    ``latent_width`` > 0 selects the latent cache-entry kind instead:
+    ``(L, P) + latent_page_shape(S, latent_width)``, one row a token a
+    layer (``n_heads``/``head_dim`` are then unused: pass 0).  The host
+    tier, the wire and the fabric do not carry it (``host_shape``
+    raises)."""
 
     def __init__(self, n_pages: int, page_size: int, n_layers: int,
                  n_heads: int, head_dim: int, dtype=None, device=None,
-                 allocator=None, mesh=None):
+                 allocator=None, mesh=None, latent_width: int = 0):
         import jax.numpy as jnp
         from tpulab.tpu import platform as plat
         from tpulab.tpu.allocators import make_tpu_allocator
@@ -108,8 +127,15 @@ class PagedKVPool:
                            else plat.local_device(0))
         self.n_kv_heads = n_heads
         self.head_dim = head_dim
-        self._shape = (n_layers, n_pages) + kv_page_shape(
-            page_size, n_heads, head_dim)
+        #: "kv" (K and V rows) or "latent" (one row a token)
+        self.entry_kind = "latent" if latent_width else "kv"
+        if latent_width and mesh is not None:
+            raise NotImplementedError(
+                "mesh=: a latent page store is not sharded (every head "
+                "reads the whole row)")
+        self._shape = (n_layers, n_pages) + (
+            latent_page_shape(page_size, latent_width) if latent_width
+            else kv_page_shape(page_size, n_heads, head_dim))
         self._dtype = dtype
         # the KV page store is an HBM block owned by the device allocator
         # framework (tracked bytes; reference cuda_allocators device memory);
@@ -159,6 +185,10 @@ class PagedKVPool:
         formats hold them (host tier, disagg wire, fabric) — heads apart,
         the bytes of the device's rows: the view for code that wants
         heads is a reshape to this."""
+        if self.entry_kind != "kv":
+            raise NotImplementedError(
+                "the host-side formats (host tier, disagg wire, fabric) "
+                "hold K/V pages only, not the latent cache-entry kind")
         return (self.n_layers, n_pages, 2, self.page_size,
                 self.n_kv_heads, self.head_dim)
 
@@ -204,6 +234,13 @@ class PagedKVPool:
         """Tracked HBM bytes one logical page costs (every layer's K+V
         rows for its slots) — the ledger/admission conversion factor."""
         return self.hbm_bytes // max(1, self.n_pages)
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Page-store bytes one cached token occupies, all layers: what
+        the cache-entry kind costs (a latent row against K and V of every
+        KV head)."""
+        return self.page_nbytes // self.page_size
 
     @property
     def free_pages(self) -> int:
@@ -353,6 +390,195 @@ def _gather_attend(q, k_layer, v_layer, tables, qpos, compute_dtype):
                       v_ctx.astype(compute_dtype)).reshape(b, m, h * d)
 
 
+def _scatter_latent(kv_pool, layer, page_idx, slot_idx, rows):
+    """Write latent rows ``(..., W)`` at ``(page_idx, slot_idx)`` of
+    ``layer`` of a latent page store, zero-padded to the page row."""
+    import jax.numpy as jnp
+    pad = [(0, 0)] * (rows.ndim - 1) + [(0, kv_pool.shape[4] - rows.shape[-1])]
+    return kv_pool.at[layer, page_idx, 0, slot_idx].set(
+        jnp.pad(rows.astype(kv_pool.dtype), pad))
+
+
+def _gather_attend_latent(q, c_layer, tables, qpos, v_width, sm_scale,
+                          compute_dtype):
+    """:func:`_gather_attend` for latent pages (absorbed MLA): q (B, M, H,
+    W) against one shared key row a position, c_layer (P, S, row >= W);
+    the value is the first ``v_width`` columns of the same rows.  Returns
+    (B, M, H, v_width)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, mp = tables.shape
+    page_size = c_layer.shape[1]
+    ctx = c_layer[tables].reshape(b, mp * page_size, -1)
+    scores = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32),
+                        ctx[..., :q.shape[-1]].astype(jnp.float32)) * sm_scale
+    j = jnp.arange(mp * page_size)
+    mask = j[None, None, :] <= qpos[:, :, None]          # (B, M, K)
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(compute_dtype)
+    return jnp.einsum("bhqk,bkc->bqhc", probs,
+                      ctx[..., :v_width].astype(compute_dtype))
+
+
+def _step_spec(spec, d_model: int, n_heads: int, n_layers: int, n_kv_heads,
+               rope_theta):
+    """The spec a step function runs: the caller's, or the dense decoder's
+    from the arguments the step functions always took."""
+    from tpulab.models.spec import dense_spec
+    return spec or dense_spec(d_model, n_heads, n_layers, n_kv_heads,
+                              rope_theta)
+
+
+def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
+                   compute_dtype):
+    """Multi-head latent attention of one layer in the absorbed form, on a
+    latent page store: ``(attn (B, M, H * v_head_dim), kv_pool)``.  The
+    row ``[c_kv ; k_rope]`` (after norm and RoPE) is scattered once; the
+    key up-projection moves into the query, the value up-projection
+    behind the weighted latent sum."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _rmsnorm, apply_rope, qmat
+
+    with jax.named_scope("mla_attention"):
+        eps = spec.rms_eps
+        b, m = h.shape[:2]
+        nope, rope = spec.qk_nope_head_dim, spec.qk_rope_head_dim
+        scale = 1.0 / np.sqrt(spec.qk_head_dim)
+        cq = _rmsnorm(h @ qmat(p["wq_a"], compute_dtype),
+                      p["q_norm"]["scale"], eps)
+        q = (cq @ qmat(p["wq_b"], compute_dtype)).reshape(
+            b, m, spec.n_heads, nope + rope)
+        kva = h @ qmat(p["wkv_a"], compute_dtype)
+        ckv = _rmsnorm(kva[..., :spec.kv_lora_rank], p["kv_norm"]["scale"],
+                       eps)
+        kr = apply_rope(kva[..., None, spec.kv_lora_rank:], pos,
+                        spec.rope_theta)[..., 0, :]
+        qr = apply_rope(q[..., nope:], pos, spec.rope_theta)
+        rows = jnp.concatenate([ckv, kr], axis=-1)           # (B, M, W)
+        kv_pool = _scatter_latent(
+            kv_pool, layer, page_idx, slot_idx,
+            rows.reshape(page_idx.shape + rows.shape[-1:]))
+        qa = jnp.concatenate(
+            [jnp.einsum("bmhn,hnc->bmhc", q[..., :nope],
+                        qmat(p["w_uk"], compute_dtype)), qr], axis=-1)
+        if seg["use_kernel"]:
+            from tpulab.ops.ragged_attention import ragged_latent_attention
+            lat = ragged_latent_attention(
+                qa, kv_pool, layer, seg["tables"], seg["q_lens"],
+                seg["kv_lens"], v_width=spec.kv_lora_rank, sm_scale=scale)
+        else:
+            lat = _gather_attend_latent(
+                qa, kv_pool[layer, :, 0], seg["tables"], pos,
+                spec.kv_lora_rank, scale, compute_dtype)
+        attn = jnp.einsum("bmhc,hcv->bmhv", lat.astype(compute_dtype),
+                          qmat(p["w_uv"], compute_dtype))
+        return attn.reshape(b, m, -1), kv_pool
+
+
+def _ffn_block(spec, p, layer, x, valid, compute_dtype):
+    """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
+    experts plus the shared expert.  Returns ``(x, stats)``, ``stats``
+    the expert layer's ``(E + 2,)`` counters or None."""
+    import jax
+    from tpulab.models.transformer import _dense_ffn, _rmsnorm
+
+    h = _rmsnorm(x, p["ln2"]["scale"], spec.rms_eps)
+    if spec.layer_kinds[layer] != "moe":
+        return x + _dense_ffn(p, h, compute_dtype).astype(x.dtype), None
+    from tpulab.parallel.moe import routed_ffn
+    b, m = x.shape[:2]
+    y, stats = routed_ffn(p["moe"], h.reshape(b * m, -1), spec.top_k,
+                          compute_dtype, router="sigmoid_bias", act="swiglu",
+                          scale=spec.routed_scale, norm=spec.norm_topk,
+                          valid=valid.reshape(-1))
+    with jax.named_scope("moe_shared"):
+        shared = _dense_ffn(p["shared"], h, compute_dtype)
+    return x + (y.reshape(b, m, -1) + shared).astype(x.dtype), stats
+
+
+def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
+                 seg, compute_dtype):
+    """ONE decoder layer over paged state, for every model and every step
+    function: norm, projections (+RoPE), the new rows scattered into the
+    lane's pages, attention over the block table (gather-after-scatter,
+    global causality), output projection, norm, FFN; residuals around both
+    halves.
+
+    x (B, M, D) at positions ``pos`` (B, M); ``page_idx``/``slot_idx`` are
+    the write targets, shaped (B, M) — or (B,) in a decode step, whose one
+    row a lane is then written without the M axis; rows that must not land
+    go to scratch page 0.  ``seg`` is the dispatch's segment description,
+    the same for every layer: ``tables`` (B, MP), ``q_lens``/``kv_lens``
+    (B,), and the attention path (``use_kernel``: the Pallas ragged kernel
+    of the cache-entry kind, else the XLA gather; ``kernel_geometry``,
+    ``mesh``).  ``valid`` (B, M) bool masks the expert counters only.
+    Returns ``(x, kv_pool, stats)``: ``stats`` is the expert layer's
+    ``(E + 2,)`` int32 counters
+    (:func:`tpulab.parallel.moe.routing_stats`) or None on a dense layer.
+
+    Kept short, the K/V kernel called from here and the rest in functions
+    of their own: on the v5e host, tracing a kernel body costs more with
+    every Python frame between the step function and the ``pallas_call``
+    (PR 28, my chip runs: a kernel's trace took 0.63 s a program with the
+    parent's frames, 0.87-0.97 s behind one more, 1.42 s behind four more
+    and a helper inside the kernel; the dense cell's set-up grew 10 %,
+    96 -> 106 s, until the count was the parent's again).
+    """
+    from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
+                                           split_qkv)
+
+    h = _rmsnorm(x, p["ln1"]["scale"], spec.rms_eps)
+    if spec.attention == "mla":
+        attn, kv_pool = _mla_attention(spec, p, layer, h, pos, kv_pool,
+                                       page_idx, slot_idx, seg,
+                                       compute_dtype)
+    else:
+        b, m = x.shape[:2]
+        q, knew, vnew = split_qkv(h @ qmat(p["wqkv"], compute_dtype), b, m,
+                                  spec.n_heads, spec.n_kv_heads,
+                                  spec.head_dim)
+        if spec.rope_theta:
+            q = apply_rope(q, pos, spec.rope_theta)
+            knew = apply_rope(knew, pos, spec.rope_theta)
+        tail = knew.shape[2:]
+        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
+                              knew.reshape(page_idx.shape + tail),
+                              vnew.reshape(page_idx.shape + tail))
+        if seg["use_kernel"]:
+            # pallas ragged kernel: walks block tables page-by-page, no
+            # dense gather materialization; fused pages = 1 DMA/page;
+            # under a mesh the walk shards on the KV-heads dim via
+            # shard_map (tpulab.ops.ragged_attention)
+            from tpulab.ops import ragged_attention as ra
+            gk, nk = seg["kernel_geometry"] or (None, None)
+            if seg["mesh"] is None:
+                # the jitted entry itself, not ``ragged_paged_attention``
+                # around it: one Python frame fewer above the kernel
+                # (the docstring says what a frame costs)
+                import jax.numpy as jnp
+                from tpulab.tpu.platform import pallas_interpret
+                attn = ra._ragged_attn(
+                    q, kv_pool, jnp.asarray(layer, jnp.int32).reshape(1),
+                    seg["tables"], seg["q_lens"], seg["kv_lens"],
+                    pallas_interpret(), g_pages=gk, nbuf=nk)
+            else:
+                attn = ra.ragged_paged_attention(
+                    q, kv_pool, layer, seg["tables"], seg["q_lens"],
+                    seg["kv_lens"], mesh=seg["mesh"], g_pages=gk, nbuf=nk)
+            attn = attn.astype(compute_dtype).reshape(b, m, -1)
+        else:
+            # XLA fallback: gather pages densely then mask
+            attn = _gather_attend(q, kv_pool[layer, :, 0],
+                                  kv_pool[layer, :, 1], seg["tables"], pos,
+                                  compute_dtype)
+    x, stats = _ffn_block(spec, p, layer,
+                          x + attn @ qmat(p["wo"], compute_dtype), valid,
+                          compute_dtype)
+    return x, kv_pool, stats
+
+
 def paged_decode_step(params, kv_pool, tables, lengths, tokens,
                       active, n_heads: int, n_layers: int,
                       compute_dtype, use_kernel: bool = False,
@@ -360,7 +586,7 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
                       rope_theta: Optional[float] = None,
                       temps=None, seeds=None,
                       kernel_geometry: Optional[tuple] = None,
-                      mesh=None):
+                      mesh=None, spec=None):
     """One batched decode tick over the paged pool.
 
     Shapes: kv_pool (L, P, 2, S, Hkv*D) fused page store (axis 2 = K/V,
@@ -381,70 +607,49 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
     arrays (no per-tick (B, vocab) logits transfer).
     """
     import jax.numpy as jnp
-    from tpulab.models.transformer import (_dense_ffn, _lm_head, _rmsnorm,
-                                           apply_rope, qmat, split_qkv)
+    from tpulab.models.transformer import _lm_head, _rmsnorm
 
-    n_kv = n_kv_heads or n_heads
     b = tokens.shape[0]
     page_size = kv_pool.shape[3]
     emb = params["embed"].astype(compute_dtype)
     x = emb[tokens][:, None, :]
-    d_model = x.shape[-1]
-    head_dim = d_model // n_heads
-    # write target per lane: page id + slot for position `lengths`
+    spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
+    # write target per lane: page id + slot for position `lengths`;
+    # inactive/padded lanes are routed to the RESERVED scratch page 0 so
+    # they can never clobber a live lane's pages
     page_idx = tables[jnp.arange(b), lengths // page_size]      # (B,)
-    slot_idx = lengths % page_size                              # (B,)
+    safe_page = jnp.where(active, page_idx, 0)
+    safe_slot = jnp.where(active, lengths % page_size, 0)
+    # the ragged kernel at the q=1 decode shape; per-lane positions: each
+    # lane decodes at its own length
+    pos = lengths[:, None]
+    seg = dict(tables=tables, q_lens=jnp.ones_like(lengths),
+               kv_lens=lengths + 1, use_kernel=use_kernel,
+               kernel_geometry=kernel_geometry, mesh=mesh)
+    moe_stats = []
+    for layer in range(spec.n_layers):
+        x, kv_pool, stats = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos, active[:, None],
+            kv_pool, safe_page, safe_slot, seg, compute_dtype)
+        if stats is not None:
+            moe_stats.append(stats)
 
-    for layer in range(n_layers):
-        p = params[f"layer{layer}"]
-        h = _rmsnorm(x, p["ln1"]["scale"])
-        qkv = h @ qmat(p["wqkv"], compute_dtype)
-        q, knew, vnew = split_qkv(qkv, b, 1, n_heads, n_kv, head_dim)
-        if rope_theta:
-            # per-lane positions: each lane decodes at its own length
-            q = apply_rope(q, lengths[:, None], rope_theta)
-            knew = apply_rope(knew, lengths[:, None], rope_theta)
-        # scatter the new K/V into their pages; inactive/padded lanes are
-        # routed to the RESERVED scratch page 0 so they can never clobber
-        # a live lane's pages
-        safe_page = jnp.where(active, page_idx, 0)
-        safe_slot = jnp.where(active, slot_idx, 0)
-        kv_pool = _scatter_kv(kv_pool, layer, safe_page, safe_slot,
-                              knew[:, 0], vnew[:, 0])       # (B, Hkv, D)
-        if use_kernel:
-            # pallas ragged kernel at the q=1 decode shape: walks block
-            # tables page-by-page, no dense gather materialization; fused
-            # pages = 1 DMA/page; under a mesh the walk shards on the
-            # KV-heads dim via shard_map (tpulab.ops.ragged_attention)
-            from tpulab.ops.ragged_attention import ragged_paged_attention
-            gk, nk = kernel_geometry or (None, None)
-            attn = ragged_paged_attention(
-                q, kv_pool, layer, tables,
-                jnp.ones_like(lengths), lengths + 1,
-                mesh=mesh, g_pages=gk, nbuf=nk,
-            ).astype(compute_dtype).reshape(b, 1, d_model)
-        else:
-            # XLA fallback: gather pages densely then mask
-            attn = _gather_attend(q, kv_pool[layer, :, 0],
-                                  kv_pool[layer, :, 1], tables,
-                                  lengths[:, None], compute_dtype)
-        x = x + attn @ qmat(p["wo"], compute_dtype)
-        h2 = _rmsnorm(x, p["ln2"]["scale"])
-        x = x + _dense_ffn(p, h2, compute_dtype).astype(x.dtype)
-
-    x = _rmsnorm(x, params["final_norm"]["scale"])
+    x = _rmsnorm(x, params["final_norm"]["scale"], spec.rms_eps)
     logits = _lm_head(params, x[:, 0])
     # inactive lanes emit neutral logits (argmax 0) — callers mask on active
     logits = jnp.where(active[:, None], logits, 0.0)
+    # an expert model's counters ride behind the pool (one small array)
+    moe = (jnp.stack(moe_stats),) if moe_stats else ()
     if temps is None:
-        return logits, kv_pool
+        return (logits, kv_pool) + moe
     import jax
     next_tokens = jax.vmap(_device_sample_token)(
         logits, temps, seeds.astype(jnp.uint32), lengths)
     logp_rows = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     logprobs = jnp.take_along_axis(logp_rows, next_tokens[:, None],
                                    axis=-1)[:, 0]
-    return next_tokens, logprobs, logits, kv_pool
+    return (next_tokens, logprobs, logits, kv_pool) + moe
 
 
 def paged_decode_step_sampled(params, kv_pool, tables, lengths, tokens,
@@ -463,7 +668,7 @@ def paged_decode_block(params, kv_pool, tables, lengths, tokens, active,
                        n_kv_heads: Optional[int] = None,
                        rope_theta: Optional[float] = None,
                        kernel_geometry: Optional[tuple] = None,
-                       mesh=None):
+                       mesh=None, spec=None):
     """K fused decode ticks in ONE dispatch: ``lax.scan`` over
     :func:`paged_decode_step`, sampling every step on device.
 
@@ -490,8 +695,10 @@ def paged_decode_block(params, kv_pool, tables, lengths, tokens, active,
 
     Returns ``(tokens (B, K) i32, logprobs (B, K) f32, emitted (B, K)
     bool, lengths (B,), last_tokens (B,), live (B,), steps_rem (B,),
-    kv_pool)`` — the trailing five are the carried state *after* the
-    block, returned as device arrays so a follow-up block can be
+    kv_pool)`` — and, for a ``spec`` with expert layers, their counters
+    ``(n_moe, E + 2)`` summed over the K steps as one more element;
+    ``lengths`` .. ``steps_rem`` and the pool are the carried state
+    *after* the block, returned as device arrays so a follow-up block can be
     dispatched without a host round trip (dispatch-ahead overlap).
     ``emitted[b]`` is a prefix mask: lane b's valid tokens are
     ``tokens[b, :emitted[b].sum()]``.
@@ -501,26 +708,27 @@ def paged_decode_block(params, kv_pool, tables, lengths, tokens, active,
 
     def body(carry, _):
         kv, lens, toks, live, rem = carry
-        nt, lp, _logits, kv = paged_decode_step(
+        nt, lp, _logits, kv, *moe = paged_decode_step(
             params, kv, tables, lens, toks, live,
             n_heads=n_heads, n_layers=n_layers,
             compute_dtype=compute_dtype, use_kernel=use_kernel,
             n_kv_heads=n_kv_heads, rope_theta=rope_theta,
             temps=temps, seeds=seeds, kernel_geometry=kernel_geometry,
-            mesh=mesh)
+            mesh=mesh, spec=spec)
         emitted = live
         nt = jnp.where(live, nt, toks)           # dead lanes hold position
         lens = lens + emitted.astype(jnp.int32)
         rem = rem - emitted.astype(jnp.int32)
         hit_stop = (nt[:, None] == stop_ids).any(axis=1)
         live = live & (rem > 0) & ~hit_stop
-        return (kv, lens, nt, live, rem), (nt, lp, emitted)
+        return (kv, lens, nt, live, rem), (nt, lp, emitted, *moe)
 
     init = (kv_pool, lengths, tokens, active, steps_rem)
-    (kv_pool, lengths, tokens, live, steps_rem), (toks, lps, ems) = \
+    (kv_pool, lengths, tokens, live, steps_rem), (toks, lps, ems, *moe) = \
         jax.lax.scan(body, init, None, length=k)
+    # an expert model's counters, summed over the block's steps
     return (toks.T, lps.T, ems.T, lengths, tokens, live, steps_rem,
-            kv_pool)
+            kv_pool) + tuple(m.sum(axis=0) for m in moe)
 
 
 def _device_sample_token(row, temp, seed2, pos):
@@ -548,7 +756,7 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
                          rope_theta: Optional[float] = None,
                          mesh=None,
                          kernel_geometry: Optional[tuple] = None,
-                         last_only: bool = False):
+                         last_only: bool = False, spec=None):
     """One fused multi-token forward over ragged per-lane segments — the
     single program shape behind the ragged dispatch plan (ROADMAP item
     2, "Ragged Paged Attention" in PAPERS.md).
@@ -571,19 +779,19 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
     each lane's LAST valid position only and returns ``(logits (B,
     vocab), kv_pool)``; otherwise ``(logits (B, M, vocab), kv_pool)``
     with invalid positions' logits garbage the caller must not consume.
-    The fused pool is donated by the caller either way.
+    The fused pool is donated by the caller either way.  A ``spec`` with
+    expert layers appends their counters ``(n_moe, E + 2)`` (valid
+    positions only) as a third element.
     """
     import jax.numpy as jnp
-    from tpulab.models.transformer import (_dense_ffn, _lm_head, _rmsnorm,
-                                           apply_rope, qmat, split_qkv)
+    from tpulab.models.transformer import _lm_head, _rmsnorm
 
-    n_kv = n_kv_heads or n_heads
     b, m = seq.shape
     page_size = kv_pool.shape[3]
     emb = params["embed"].astype(compute_dtype)
     x = emb[seq]                                      # (B, M, D)
-    d_model = x.shape[-1]
-    head_dim = d_model // n_heads
+    spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
     valid = jnp.arange(m)[None, :] < q_lens[:, None]  # (B, M)
     pos = (kv_lens - q_lens)[:, None] + jnp.arange(m)[None, :]
     # invalid positions' page index may run past the table width — XLA
@@ -594,46 +802,29 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
                              jnp.clip(pos // page_size, 0,
                                       tables.shape[1] - 1), axis=1), 0)
     slot_idx = jnp.where(valid, pos % page_size, 0)
-
-    for layer in range(n_layers):
-        p = params[f"layer{layer}"]
-        h = _rmsnorm(x, p["ln1"]["scale"])
-        qkv = h @ qmat(p["wqkv"], compute_dtype)
-        q, knew, vnew = split_qkv(qkv, b, m, n_heads, n_kv, head_dim)
-        if rope_theta:
-            q = apply_rope(q, pos, rope_theta)
-            knew = apply_rope(knew, pos, rope_theta)
-        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
-                              knew, vnew)
-        if use_kernel:
-            # pallas ragged walk over the block tables (one program for
-            # every segment mix; sharded on KV-heads under a mesh)
-            from tpulab.ops.ragged_attention import ragged_paged_attention
-            gk, nk = kernel_geometry or (None, None)
-            attn = ragged_paged_attention(
-                q, kv_pool, layer, tables, q_lens, kv_lens,
-                mesh=mesh, g_pages=gk, nbuf=nk,
-            ).astype(compute_dtype).reshape(b, m, d_model)
-        else:
-            # gather-after-scatter: token m sees cached context + the
-            # segment's own writes up to its position (global causality)
-            attn = _gather_attend(q, kv_pool[layer, :, 0],
-                                  kv_pool[layer, :, 1],
-                                  tables, pos, compute_dtype)
-        x = x + attn @ qmat(p["wo"], compute_dtype)
-        h2 = _rmsnorm(x, p["ln2"]["scale"])
-        x = x + _dense_ffn(p, h2, compute_dtype).astype(x.dtype)
+    # gather-after-scatter: token m sees cached context + the segment's
+    # own writes up to its position (global causality); one program for
+    # every segment mix
+    seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+               use_kernel=use_kernel, kernel_geometry=kernel_geometry,
+               mesh=mesh)
+    moe_stats = []
+    for layer in range(spec.n_layers):
+        x, kv_pool, stats = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos, valid, kv_pool,
+            page_idx, slot_idx, seg, compute_dtype)
+        if stats is not None:
+            moe_stats.append(stats)
+    moe = (jnp.stack(moe_stats),) if moe_stats else ()
 
     if last_only:
         # only each lane's last valid token seeds a pick — run the
         # vocab-sized head over ONE row per lane (paged_extend's trick,
         # batched)
-        xl = jnp.take_along_axis(
+        x = jnp.take_along_axis(
             x, jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-        xl = _rmsnorm(xl, params["final_norm"]["scale"])
-        return _lm_head(params, xl), kv_pool
-    x = _rmsnorm(x, params["final_norm"]["scale"])
-    return _lm_head(params, x), kv_pool
+    x = _rmsnorm(x, params["final_norm"]["scale"], spec.rms_eps)
+    return (_lm_head(params, x), kv_pool) + moe
 
 
 def paged_mixed_step(params, kv_pool, tables, seq, q_lens, kv_lens,
@@ -642,7 +833,7 @@ def paged_mixed_step(params, kv_pool, tables, seq, q_lens, kv_lens,
                      n_kv_heads: Optional[int] = None,
                      rope_theta: Optional[float] = None,
                      mesh=None,
-                     kernel_geometry: Optional[tuple] = None):
+                     kernel_geometry: Optional[tuple] = None, spec=None):
     """One mixed prefill+decode round: a ragged forward over per-lane
     segments plus each lane's next-token pick, in ONE dispatch.
 
@@ -659,24 +850,25 @@ def paged_mixed_step(params, kv_pool, tables, seq, q_lens, kv_lens,
 
     Returns ``(next_tokens (B,) i32, logprobs (B,) f32, last_logits
     (B, vocab), kv_pool)`` — ``last_logits`` stays device-resident
-    unless a host-sampled lane fetches its row.
+    unless a host-sampled lane fetches its row — and the expert layers'
+    counters behind the pool where ``spec`` has any.
     """
     import jax
     import jax.numpy as jnp
 
-    last, kv_pool = paged_ragged_forward(
+    last, kv_pool, *moe = paged_ragged_forward(
         params, kv_pool, tables, seq, q_lens, kv_lens,
         n_heads=n_heads, n_layers=n_layers, compute_dtype=compute_dtype,
         use_kernel=use_kernel, n_kv_heads=n_kv_heads,
         rope_theta=rope_theta, mesh=mesh,
-        kernel_geometry=kernel_geometry, last_only=True)
+        kernel_geometry=kernel_geometry, last_only=True, spec=spec)
     pos_last = jnp.maximum(kv_lens - 1, 0)
     next_tokens = jax.vmap(_device_sample_token)(
         last, temps, seeds.astype(jnp.uint32), pos_last)
     logp_rows = jax.nn.log_softmax(last.astype(jnp.float32), axis=-1)
     logprobs = jnp.take_along_axis(logp_rows, next_tokens[:, None],
                                    axis=-1)[:, 0]
-    return next_tokens, logprobs, last, kv_pool
+    return (next_tokens, logprobs, last, kv_pool, *moe)
 
 
 def paged_speculative_block(params, draft_params, kv_pool, tables,
@@ -863,37 +1055,24 @@ def paged_extend(params, kv_pool, tables, tokens, start, valid_total,
     valid token (vocab,), kv_pool) — the fused pool donated by the caller.
     """
     import jax.numpy as jnp
-    from tpulab.models.transformer import (_dense_ffn, _lm_head, _rmsnorm,
-                                           apply_rope, qmat, split_qkv)
+    from tpulab.models.transformer import _lm_head, _rmsnorm
 
-    n_kv = n_kv_heads or n_heads
     page_size = kv_pool.shape[3]
     m_pad = tokens.shape[1]
     emb = params["embed"].astype(compute_dtype)
     x = emb[tokens]                                   # (1, M_pad, D)
-    d_model = x.shape[-1]
-    head_dim = d_model // n_heads
+    spec = _step_spec(None, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
     pos = start + jnp.arange(m_pad)                   # global positions
     valid = pos < valid_total
     page_idx = jnp.where(valid, tables[pos // page_size], 0)  # pad -> scratch
     slot_idx = jnp.where(valid, pos % page_size, 0)
-
+    # gather-after-scatter: context = cached prefix + this tail
+    seg = dict(tables=tables[None], use_kernel=False)
     for layer in range(n_layers):
-        p = params[f"layer{layer}"]
-        h = _rmsnorm(x, p["ln1"]["scale"])
-        qkv = h @ qmat(p["wqkv"], compute_dtype)
-        q, knew, vnew = split_qkv(qkv, 1, m_pad, n_heads, n_kv, head_dim)
-        if rope_theta:
-            q = apply_rope(q, pos, rope_theta)
-            knew = apply_rope(knew, pos, rope_theta)
-        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
-                              knew[0], vnew[0])
-        # gather-after-scatter: context = cached prefix + this tail
-        attn = _gather_attend(q, kv_pool[layer, :, 0], kv_pool[layer, :, 1],
-                              tables[None], pos[None], compute_dtype)
-        x = x + attn @ qmat(p["wo"], compute_dtype)
-        h2 = _rmsnorm(x, p["ln2"]["scale"])
-        x = x + _dense_ffn(p, h2, compute_dtype).astype(x.dtype)
+        x, kv_pool, _ = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos[None], valid[None],
+            kv_pool, page_idx, slot_idx, seg, compute_dtype)
 
     # only the last valid token's logits are ever consumed — run the
     # vocab-sized head over ONE row, not all M_pad rows
@@ -1299,6 +1478,17 @@ class ContinuousBatcher:
     ragged kernel family.  Tokens are bit-exact vs the legacy split
     dispatch (``use_kernel=False``, the escape hatch), mesh on or off.
 
+    Model spec (``spec=``, tpulab.models.spec): a ``ModelSpec`` names the
+    attention kind, the layer kinds and the cache-entry kind; without one
+    the engine serves the dense decoder of ``n_heads``/``n_kv_heads``.
+    Multi-head latent attention keeps ONE latent row a token a layer in
+    the page store and runs in the absorbed form (gather or the latent
+    ragged kernel); expert layers run the routed FFN of
+    tpulab.parallel.moe and count assignments (``debug_state()["moe"]``).
+    Such a spec is served on the ragged plan only; the options that plan
+    or that cache entry does not carry are refused at construction, by
+    name.
+
     Tiered KV (``kv_offload=``, tpulab.kvcache): preemption swaps the
     victim's KV pages to a budgeted host-RAM tier (async, write-behind)
     and resume swaps them back with ZERO prefill dispatches; prefix-cache
@@ -1344,11 +1534,45 @@ class ContinuousBatcher:
                  spec_accept_floor: float = 0.35,
                  mesh=None, hbm=None, flight=None,
                  ragged: Optional[bool] = None,
-                 kv_publish: bool = False):
+                 kv_publish: bool = False,
+                 spec=None):
         import jax
         import jax.numpy as jnp
 
         compute_dtype = compute_dtype or jnp.bfloat16
+        #: tpulab.models.spec.ModelSpec: None serves the dense decoder of
+        #: ``n_heads``/``n_kv_heads``/``rope_theta`` with today's constants;
+        #: a spec with a latent cache or expert layers is served on the
+        #: ragged plan alone, and the options that plan or that cache-entry
+        #: kind does not carry yet are refused here, by name
+        self.model_spec = spec
+        special = spec is not None and (spec.cache_entry != "kv"
+                                        or spec.moe_layers)
+        if special:
+            refused = {
+                "ragged=False (the legacy split plan)": ragged is False,
+                "draft_params (speculative blocks)": draft_params is not None,
+                "mesh": mesh is not None
+                or getattr(pool, "mesh", None) is not None,
+                "kv_offload": kv_offload not in (None, False),
+                "kv_publish": bool(kv_publish),
+                "prefix_cache": bool(prefix_cache),
+                "kv_dtype other than the compute dtype":
+                    kv_dtype is not None
+                    and jnp.dtype(kv_dtype) != jnp.dtype(compute_dtype),
+                "hbm (the elastic page store)": hbm is not None,
+            }
+            bad = [name for name, on in refused.items() if on]
+            if bad:
+                raise NotImplementedError(
+                    "a model with a latent cache or expert layers is served "
+                    "on the ragged plan only; not supported with it: "
+                    + ", ".join(bad))
+            if (spec.n_heads, spec.n_layers) != (n_heads, n_layers):
+                raise ValueError(
+                    f"spec (n_heads {spec.n_heads}, n_layers {spec.n_layers})"
+                    f" disagrees with n_heads={n_heads}, n_layers={n_layers}")
+            ragged = True
         # KV-cache quantization: pages may store a NARROWER dtype than the
         # compute path (e.g. kv_dtype=jnp.float8_e4m3fn under bf16 compute
         # halves KV HBM *and* decode bandwidth — the decode tick is
@@ -1368,7 +1592,7 @@ class ContinuousBatcher:
             prefill_chunk -= prefill_chunk % page_size
         self.prefill_chunk = prefill_chunk
         from tpulab.models.transformer import weight_shape
-        d_model = weight_shape(params["layer0"]["wqkv"])[0]
+        d_model = int(weight_shape(params["embed"])[1])
         #: id-validation bound (public: the Generate RPC checks it too)
         self.vocab = int(weight_shape(params["embed"])[0])
         # +1: page 0 is the reserved scratch page.  GQA pools store the
@@ -1379,9 +1603,15 @@ class ContinuousBatcher:
             raise ValueError(
                 f"kv_dtype={jnp.dtype(kv_dtype).name} conflicts with the "
                 f"provided pool's dtype {jnp.dtype(pool.dtype).name}")
+        latent = (spec.latent_width
+                  if spec is not None and spec.cache_entry == "latent" else 0)
+        if pool is not None and (pool.entry_kind == "latent") != bool(latent):
+            raise ValueError(f"the provided pool holds {pool.entry_kind!r} "
+                             "entries, the model another kind")
         self.pool = pool or PagedKVPool(
             n_pages or self.max_pages * lanes + 1, page_size, n_layers,
-            n_kv, d_model // n_heads, kv_dtype, device, mesh=mesh)
+            0 if latent else n_kv, 0 if latent else d_model // n_heads,
+            kv_dtype, device, mesh=mesh, latent_width=latent)
         if pool is not None and mesh is not None and pool.mesh is not mesh:
             raise ValueError("provided pool was built on a different mesh "
                              "than the batcher's")
@@ -1460,11 +1690,17 @@ class ContinuousBatcher:
             program is the one that must build) and the widest segment a
             dispatch can carry: a mixed round's pow2 chunk bucket under
             the ragged plan, a K+1 verify otherwise."""
-            from tpulab.ops.ragged_attention import kernel_geometry_error
+            from tpulab.ops.ragged_attention import (kernel_geometry_error,
+                                                     latent_geometry_error)
             cap = min(self.prefill_chunk or self.RAGGED_CHUNK_CAP,
                       self.RAGGED_CHUNK_CAP)
             widest = (1 << (cap - 1).bit_length() if ragged is not False
                       else self.BLOCK_K_MENU[-1] + 1)
+            if latent:
+                return latent_geometry_error(
+                    widest, n_heads, self.pool.kv.shape[4],
+                    spec.kv_lora_rank, self.pool.page_size, self.max_pages,
+                    compute_dtype, self.pool.dtype)
             return kernel_geometry_error(
                 widest, n_heads // n_shards, n_kv // n_shards,
                 d_model // n_heads, self.pool.page_size, self.max_pages,
@@ -1507,6 +1743,18 @@ class ContinuousBatcher:
                              use_kernel=self.use_kernel,
                              n_kv_heads=n_kv, rope_theta=rope_theta,
                              mesh=self.mesh)
+        if spec is not None:
+            # only where a spec was given: a dense engine's programs keep
+            # the key they always had in the jit memo
+            self._step_kw["spec"] = spec
+        #: expert layers' counters (``debug_state()["moe"]``), summed on
+        #: the host from the small array every dispatch of an expert model
+        #: returns and the scheduler fetches WITH the dispatch's tokens
+        self._moe_assignments = (
+            np.zeros((len(spec.moe_layers), spec.n_experts), np.int64)
+            if spec is not None and spec.moe_layers else None)
+        self.moe_decode_steps = 0    # decode steps that had a live lane
+        self.moe_experts_hit = 0     # over those steps and expert layers
         rep, psh = self._rep, self._param_sh
         kvsh = self.pool.kv_sharding
         self._step = self._jit(
@@ -2272,6 +2520,8 @@ class ContinuousBatcher:
                      "free_pages": pool.free_pages,
                      "page_size": pool.page_size,
                      "page_nbytes": pool.page_nbytes,
+                     "entry_kind": pool.entry_kind,
+                     "bytes_per_token": pool.bytes_per_token,
                      "hbm_bytes": pool.hbm_bytes,
                      "n_shards": pool.n_shards,
                      "elastic": self.hbm is not None,
@@ -2309,6 +2559,14 @@ class ContinuousBatcher:
                            "acceptance": round(self.spec_acceptance, 4),
                            "probes": self.spec_probes,
                            "probe_recoveries": self.spec_probe_recoveries}
+        if self._moe_assignments is not None:
+            out["moe"] = {
+                "expert_layers": list(self.model_spec.moe_layers),
+                # cumulative (row, expert) assignments, [expert layer][expert]
+                "assignments": self._moe_assignments.tolist(),
+                "decode_steps": self.moe_decode_steps,
+                # summed over decode steps and expert layers
+                "experts_hit": self.moe_experts_hit}
         pc = self.prefix_cache
         if pc is not None:
             out["prefix_cache"] = {"entries": len(pc), "hits": pc.hits,
@@ -3222,7 +3480,7 @@ class ContinuousBatcher:
                 # decode lanes advance one tick this round — same fault site
                 chaos.trip("engine.step")
             t0 = _time.perf_counter()
-            nt_dev, lp_dev, last_dev, self.pool.kv = self._mixed(
+            nt_dev, lp_dev, last_dev, self.pool.kv, *moe = self._mixed(
                 self.params, self.pool.kv, jnp.asarray(tables),
                 jnp.asarray(seq), jnp.asarray(q_lens), jnp.asarray(kv_lens),
                 jnp.asarray(temps), jnp.asarray(seeds))
@@ -3232,6 +3490,7 @@ class ContinuousBatcher:
             next_tokens = np.asarray(nt_dev, np.int32).copy()
             logprobs_arr = np.asarray(lp_dev, np.float32).copy()
             self.decode_host_syncs += 1
+            self._note_moe(moe, decode=False)
             if host_lanes:
                 # fetch ONLY the host-sampled rows (same shape discipline —
                 # and PRNG rule — as _tick_single)
@@ -3375,6 +3634,22 @@ class ContinuousBatcher:
         if len(req.tokens_out) == 1 and req.t_first is not None:
             self.first_decode_wait_s += now - req.t_first
             self.first_decode_waits += 1
+
+    def _note_moe(self, moe, decode: bool) -> None:
+        """Add a dispatch's expert counters (``[(n_moe, E + 2)]`` from the
+        step program, or ``[]`` for a model without expert layers) to the
+        totals.  Called where the dispatch's tokens were just fetched: the
+        array is ready with them, so this is no further wait.  Mixed
+        rounds count assignments only; decode dispatches also the steps
+        that had a live lane and the experts those steps hit."""
+        if not moe:
+            return
+        stats = np.asarray(moe[0], np.int64)
+        n = self._moe_assignments.shape[1]
+        self._moe_assignments += stats[:, :n]
+        if decode:
+            self.moe_experts_hit += int(stats[:, n].sum())
+            self.moe_decode_steps += int(stats[0, n + 1])
 
     def _note_dispatch(self, kind: str) -> None:
         """Dispatch-kind accounting (the ragged plan's three descriptor
@@ -3737,7 +4012,7 @@ class ContinuousBatcher:
             chaos.trip("engine.step")
         t0 = _time.perf_counter()
         (toks, lps, ems, len_f, tok_f, live_f, rem_f,
-         self.pool.kv) = self._block_fn(k)(
+         self.pool.kv, *moe) = self._block_fn(k)(
             self.params, self.pool.kv, jnp.asarray(tables),
             jnp.asarray(lengths), jnp.asarray(tokens),
             jnp.asarray(active), jnp.asarray(temps), jnp.asarray(seeds),
@@ -3746,7 +4021,7 @@ class ContinuousBatcher:
         self.decode_block_steps += k
         self._note_dispatch("decode")
         return {"k": k, "lane_reqs": lane_reqs, "dev": (toks, lps, ems),
-                "carry": (len_f, tok_f, live_f, rem_f),
+                "moe": moe, "carry": (len_f, tok_f, live_f, rem_f),
                 "host": (temps, seeds, stops), "t0": t0}
 
     def _consume_block(self, stash, jnp) -> bool:
@@ -3760,6 +4035,7 @@ class ContinuousBatcher:
             toks = np.asarray(stash["dev"][0], np.int32)
             lps = np.asarray(stash["dev"][1], np.float32)
             ems = np.asarray(stash["dev"][2], bool)
+            self._note_moe(stash["moe"], decode=True)
         self.decode_host_syncs += 1
         now = _time.perf_counter()  # post-fetch: device work is done
         self._step_ewma_s = (
@@ -3807,18 +4083,26 @@ class ContinuousBatcher:
                     completed.append(req)
             with stage(st, "admit"):
                 self._admit_locked()
+            # a lane that finished its prompt while this chain ran (its
+            # first token is out) is in no block of the chain: chaining
+            # ahead would leave it without a step until a lane of the
+            # chain completes, hundreds of steps at long outputs
+            joiner = any(
+                r is not None and lane not in stash["lane_reqs"]
+                and not r.pending_prompt and r.tokens_out and not r.cancelled
+                for lane, r in enumerate(self._active))
         if self.trace is not None and emitted_total:
             self.trace.add_counter("decode_block", now,
                                    tokens=emitted_total, k=k)
         # dispatch-ahead: with the lane set stable (nothing finished, no
         # cancel/preempt observed) and the SAME adaptive K still the right
-        # choice, enqueue block N+1 from the device-resident carry BEFORE
-        # running block N's callbacks — the next block computes while the
-        # host emits.  Correctness never depends on this: a request
+        # choice, and no other lane waiting to join, enqueue block N+1
+        # from the device-resident carry BEFORE running block N's
+        # callbacks — the next block computes while the host emits.  Correctness never depends on this: a request
         # released between dispatch and consume has its block discarded
         # above, and its stale device writes only touch positions a new
         # page owner rewrites before reading.
-        if (clean and not completed and k > 1
+        if (clean and not completed and not joiner and k > 1
                 and self._pending_block is None and not self._shutdown
                 and not self._hbm_reclaim_bytes):
             lanes_now = list(stash["lane_reqs"].items())
@@ -4081,7 +4365,8 @@ class ContinuousBatcher:
             t0 = _time.perf_counter()
             logprobs_arr = logp_dev = None
             if temps.any() or want_logp:
-                tok_dev, logp_dev, logits, self.pool.kv = self._step_sampled(
+                (tok_dev, logp_dev, logits, self.pool.kv,
+                 *moe) = self._step_sampled(
                     self.params, self.pool.kv,
                     jnp.asarray(tables), jnp.asarray(lengths),
                     jnp.asarray(tokens), jnp.asarray(active),
@@ -4090,7 +4375,7 @@ class ContinuousBatcher:
                 # neither device sampling nor logprobs this tick: the plain
                 # step (no temps/seeds traced) — greedy stays one device
                 # argmax
-                logits, self.pool.kv = self._step(
+                logits, self.pool.kv, *moe = self._step(
                     self.params, self.pool.kv,
                     jnp.asarray(tables), jnp.asarray(lengths),
                     jnp.asarray(tokens), jnp.asarray(active))
@@ -4105,6 +4390,7 @@ class ContinuousBatcher:
             if logp_dev is not None:
                 logprobs_arr = np.asarray(logp_dev, np.float32).copy()
             self.decode_host_syncs += 1
+            self._note_moe(moe, decode=True)
             if host_lanes:
                 # fetch ONLY the host-sampled rows: gather them device-side,
                 # then one (n_host, vocab) transfer — not the full

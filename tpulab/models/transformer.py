@@ -122,9 +122,11 @@ def weight_shape(w):
             else w).shape
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps: float = 1e-6):
+    """``eps`` is a model parameter (``ModelSpec.rms_eps``); the default is
+    the constant the dense decoder has always been served with."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
+    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
 def dense_attention(q, k, v, causal: bool = True):

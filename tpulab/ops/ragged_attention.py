@@ -134,6 +134,59 @@ def kernel_geometry_error(q_len: int, n_heads: int, n_kv_heads: int,
     return None
 
 
+def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
+               page_size: int, max_pages: int, g_pages: int, nbuf: int,
+               n_blocks: int):
+    """The walk over one lane's block table that every kernel of the family
+    shares, whatever a page holds (K and V rows, or latent rows): starts
+    the pipeline's prologue and returns ``(start_block, wait_block,
+    block_live)``.  A block is ``g_pages`` page DMAs from
+    ``kvpool_ref[layer, page]`` into slot ``slot`` of ``kv_buf`` (dest
+    strip static, source page id dynamic); every started DMA is waited
+    exactly once; pages past ``length`` are neither fetched nor waited."""
+    def page_live(p):
+        return p * page_size <= length
+
+    # written out twice, not through a shared helper: every Python frame
+    # between the kernel and a primitive shows in its trace time
+    def start_block(j, slot):
+        for gg in range(g_pages):
+            p_idx = j * g_pages + gg
+
+            @pl.when(jnp.logical_and(p_idx < max_pages, page_live(p_idx)))
+            def _start(gg=gg, p_idx=p_idx):
+                page = tables_ref[lane * max_pages + p_idx]
+                pltpu.make_async_copy(
+                    kvpool_ref.at[layer, page],
+                    kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
+                    sem.at[slot, gg]).start()
+
+    def wait_block(j, slot):
+        for gg in range(g_pages):
+            p_idx = j * g_pages + gg
+
+            @pl.when(jnp.logical_and(p_idx < max_pages, page_live(p_idx)))
+            def _wait(gg=gg, p_idx=p_idx):
+                page = tables_ref[lane * max_pages + p_idx]
+                pltpu.make_async_copy(
+                    kvpool_ref.at[layer, page],
+                    kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
+                    sem.at[slot, gg]).wait()
+
+    def block_live(j):
+        return page_live(j * g_pages)  # first page live <=> any page live
+
+    # same deep prefetch pipeline as the single-query kernel (N-stage
+    # slot rotation)
+    start_block(0, 0)  # block 0's first page is always live (length >= 0)
+    for jj in range(1, nbuf - 1):
+        if jj < n_blocks:
+            @pl.when(block_live(jj))
+            def _prologue(jj=jj):
+                start_block(jj, jj)
+    return start_block, wait_block, block_live
+
+
 def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
                         kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
                         max_pages: int, n_heads: int, head_dim: int,
@@ -167,46 +220,10 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST)
 
-    def page_live(p):
-        return p * page_size <= length
-
-    # one block = g_pages fused-page DMAs issued back-to-back into the
-    # slot's per-page strips (dest strip static, source page id dynamic)
-    def start_block(j, slot):
-        for gg in range(g_pages):
-            p_idx = j * g_pages + gg
-
-            @pl.when(jnp.logical_and(p_idx < max_pages, page_live(p_idx)))
-            def _start(gg=gg, p_idx=p_idx):
-                page = tables_ref[lane * max_pages + p_idx]
-                pltpu.make_async_copy(
-                    kvpool_ref.at[layer, page],
-                    kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
-                    sem.at[slot, gg]).start()
-
-    def wait_block(j, slot):
-        for gg in range(g_pages):
-            p_idx = j * g_pages + gg
-
-            @pl.when(jnp.logical_and(p_idx < max_pages, page_live(p_idx)))
-            def _wait(gg=gg, p_idx=p_idx):
-                page = tables_ref[lane * max_pages + p_idx]
-                pltpu.make_async_copy(
-                    kvpool_ref.at[layer, page],
-                    kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
-                    sem.at[slot, gg]).wait()
-
-    def block_live(j):
-        return page_live(j * g_pages)  # first page live <=> any page live
-
-    # same deep prefetch pipeline as the single-query kernel (N-stage
-    # slot rotation; every started DMA is waited exactly once)
-    start_block(0, 0)  # block 0's first page is always live (length >= 0)
-    for jj in range(1, nbuf - 1):
-        if jj < n_blocks:
-            @pl.when(block_live(jj))
-            def _prologue(jj=jj):
-                start_block(jj, jj)
+    start_block, wait_block, block_live = _page_walk(
+        tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+        page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+        nbuf=nbuf, n_blocks=n_blocks)
 
     # per-query-row positions/validity are loop-invariant
     qrow = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 0)
@@ -394,3 +411,217 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
         out_specs=P(None, None, model_axis, None),
         check_vma=False,   # pallas_call has no shard_map replication rule
     )(q, kv_pool, layer, tables, q_lens, kv_lens)
+
+
+# ---------------------------------------------------------------------------
+# latent pages (multi-head latent attention, absorbed form)
+# ---------------------------------------------------------------------------
+
+#: most query rows one grid step of the latent kernel holds: a lane's heads
+#: stack into rows, and a 256-token chunk of 20 heads is 5,120 of them.
+#: On the v5e (PR 28, one layer of a 256-token mixed round, 8 lanes) 256
+#: rows ran 5.44 ms, 512 5.74, 1280 5.96, and Mosaic compiled them in 1.3,
+#: 1.9 and 5.8 s a call site (a step program has one a layer)
+_LATENT_TILE_ROWS = 256
+
+
+def _latent_tiling(m: int, h: int) -> tuple[int, int]:
+    """``(heads_per_tile, rows_per_tile)``: the most whole heads whose
+    ``m`` rows each fit ``_LATENT_TILE_ROWS`` (at least one), rows padded
+    to a packed bf16 tile."""
+    hb = max((c for c in range(1, h + 1)
+              if h % c == 0 and c * m <= _LATENT_TILE_ROWS), default=1)
+    return hb, -(-hb * m // 16) * 16
+
+
+def _latent_plan(m: int, h: int, row: int, v_width: int, page_size: int,
+                 max_pages: int, q_dtype, kv_dtype,
+                 g_pages: int | None = None,
+                 nbuf: int | None = None) -> tuple[int, int, int]:
+    """:func:`_plan` for the latent kernel: one carry for all the rows of
+    a tile, the staged block read once as keys and once as values."""
+    q_item, kv_item = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
+    auto_g, auto_nbuf = _block_geometry(page_size, max_pages, row, kv_item)
+    g_pages, nbuf = g_pages or auto_g, nbuf or auto_nbuf
+    r = _latent_tiling(m, h)[1]
+    gs = g_pages * page_size
+    return g_pages, nbuf, (
+        nbuf * gs * row * kv_item + 2 * r * (row + v_width) * q_item
+        + r * row * 4 + 2 * r * (v_width + 2 * _LANES) * 4
+        + 2 * gs * row * 4 + 3 * r * -(-gs // _LANES) * _LANES * 4)
+
+
+def latent_geometry_error(q_len: int, n_heads: int, row: int, v_width: int,
+                          page_size: int, max_pages: int, q_dtype,
+                          kv_dtype) -> str | None:
+    """:func:`kernel_geometry_error` for latent pages: whole-tile page
+    DMAs, whole-tile value columns, and a grid step that fits VMEM."""
+    if row % _LANES or v_width % _LANES:
+        return (f"latent row {row} and its value width {v_width} must be "
+                f"multiples of {_LANES} lanes")
+    if page_size % _SUBLANES:
+        return (f"page_size {page_size} is not a multiple of {_SUBLANES} "
+                "sublanes")
+    need = _latent_plan(q_len, n_heads, row, v_width, page_size, max_pages,
+                        q_dtype, kv_dtype)[2]
+    if need > _VMEM_REQUEST_MAX:
+        return (f"kernel VMEM {need >> 20} MiB for q_len={q_len} exceeds the "
+                f"{_VMEM_REQUEST_MAX >> 20} MiB it may request")
+    return None
+
+
+def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
+                        kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
+                        max_pages: int, m_q: int, rows: int, v_width: int,
+                        sm_scale: float, precision, g_pages: int, nbuf: int):
+    """One lane's tile of stacked heads against the lane's latent pages.
+
+    ``q_ref (1, rows, W)``: row ``r`` is query token ``r % m_q`` of some
+    head — every head attends the SAME ``W``-wide key row (absorbed MLA),
+    so the heads of a lane are rows of one dot, and the value is the first
+    ``v_width`` columns of the same staged row."""
+    lane = pl.program_id(0)
+    layer = layer_ref[0]
+    qn = qlens_ref[lane]
+    kvn = kvlens_ref[lane]
+    length = jnp.maximum(kvn, 1) - 1     # see _ragged_attn_kernel
+    start = kvn - qn
+    gs = g_pages * page_size
+    n_blocks = (max_pages + g_pages - 1) // g_pages
+
+    q = q_ref[0].astype(jnp.float32) * sm_scale          # (R, W)
+    dot_qk = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+    dot_pv = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+
+    start_block, wait_block, block_live = _page_walk(
+        tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+        page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+        nbuf=nbuf, n_blocks=n_blocks)
+
+    qrow = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 1)
+    # token index of a stacked row: heads are m_q rows apart
+    qtok = (qrow & (m_q - 1) if m_q & (m_q - 1) == 0
+            else jax.lax.rem(qrow, m_q))
+    qpos = start + qtok
+    row_valid = qtok < qn
+    vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
+
+    def body(j, carry):
+        def attend(carry):
+            m_c, l_c, acc_c = carry
+            slot = jax.lax.rem(j, nbuf)
+            wait_block(j, slot)
+
+            @pl.when(jnp.logical_and(j + nbuf - 1 < n_blocks,
+                                     block_live(j + nbuf - 1)))
+            def _prefetch():
+                start_block(j + nbuf - 1,
+                            jax.lax.rem(j + nbuf - 1, nbuf))
+
+            blk = kv_buf[slot, 0].astype(jnp.float32)    # (G*S, W)
+            # rows of dead/unfetched pages hold stale VMEM (possibly NaN)
+            # and ride a 0-weighted sum as values: zero them
+            blk = jnp.where(j * gs + vrow <= length, blk, 0.0)
+            mask = jnp.logical_and(j * gs + col <= qpos, row_valid)
+            s = jnp.where(mask, dot_qk(q, blk), _NEG)    # (R, G*S)
+            m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_c - m_new)
+            p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+            return (m_new, l_c * alpha + p.sum(axis=1, keepdims=True),
+                    acc_c * alpha + dot_pv(p, blk[:, :v_width]))
+
+        return jax.lax.cond(block_live(j), attend, lambda c: c, carry)
+
+    init = (jnp.full((rows, 1), _NEG, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, v_width), jnp.float32))
+    _m, l_c, acc_c = jax.lax.fori_loop(0, n_blocks, body, init)
+    o_ref[0] = (acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("v_width", "sm_scale",
+                                             "interpret"))
+def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
+                 sm_scale: float, interpret: bool):
+    b, m, h, w = q.shape
+    page_size, row = kv_pool.shape[3], kv_pool.shape[4]
+    max_pages = tables.shape[1]
+    if kv_pool.shape[2] != 1 or w > row:
+        raise ValueError(f"latent attention needs a latent page store "
+                         f"(L, P, 1, S, row >= {w}); got {kv_pool.shape}")
+    if not interpret:
+        err = latent_geometry_error(m, h, row, v_width, page_size, max_pages,
+                                    q.dtype, kv_pool.dtype)
+        if err:
+            raise ValueError(f"ragged_latent_attention: {err}")
+    hb, rows = _latent_tiling(m, h)
+    n_tiles = h // hb
+    # heads stack into rows, head-major, a tile of whole heads; query
+    # columns padded to the (zero-padded) page row
+    qs = q.transpose(0, 2, 1, 3).reshape(b, n_tiles, hb * m, w)
+    qs = jnp.pad(qs, ((0, 0), (0, 0), (0, rows - hb * m), (0, row - w)))
+    g_pages, nbuf, need = _latent_plan(m, h, row, v_width, page_size,
+                                       max_pages, q.dtype, kv_pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,    # layer, tables (flat), q_lens, kv_lens
+        grid=(b, n_tiles),
+        in_specs=[
+            pl.BlockSpec((1, rows, row), lambda lane, t, *_: (lane, t, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # page store stays in HBM
+        ],
+        out_specs=pl.BlockSpec((1, rows, v_width),
+                               lambda lane, t, *_: (lane, t, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((nbuf, 1, g_pages * page_size, row), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((nbuf, g_pages)),
+        ],
+    )
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.dtype(kv_pool.dtype).itemsize >= 4
+                 else jax.lax.Precision.DEFAULT)
+    kernel = functools.partial(
+        _latent_attn_kernel, page_size=page_size, max_pages=max_pages,
+        m_q=m, rows=rows, v_width=v_width, sm_scale=sm_scale,
+        precision=precision, g_pages=g_pages, nbuf=nbuf)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_tiles * rows, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
+            max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
+        interpret=interpret,
+        name="ragged_latent_attention",
+    )(layer, tables.reshape(-1), q_lens, kv_lens,
+      qs.reshape(b, n_tiles * rows, row), kv_pool)
+    out = out.reshape(b, n_tiles, rows, v_width)[:, :, :hb * m]
+    return out.reshape(b, h, m, v_width).transpose(0, 2, 1, 3)
+
+
+def ragged_latent_attention(q, kv_pool, layer, tables, q_lens, kv_lens, *,
+                            v_width: int, sm_scale: float,
+                            interpret: bool | None = None):
+    """Ragged paged attention over *latent* pages: every query head of a
+    lane attends one shared key row a position, and the value is the first
+    ``v_width`` columns of the same row (MLA in the absorbed form).
+
+    q (B, M, H, W) — absorbed queries ``[q_nope W_uk ; q_rope]``, segments
+    as in :func:`ragged_paged_attention`;
+    kv_pool (L, P, 1, S, row) — the latent page store, ``row >= W`` (the
+    row padded with zeros to whole lanes);
+    ``sm_scale`` — the softmax scale of the *published* head width
+    (``1 / sqrt(qk_nope + qk_rope)``), not of ``W``.
+    Same block tables, ``layer`` word, ``q_lens``/``kv_lens`` contract and
+    page walk as the K/V kernel.  Returns (B, M, H, v_width)."""
+    if interpret is None:
+        from tpulab.tpu.platform import pallas_interpret
+        interpret = pallas_interpret()
+    return _latent_attn(q, kv_pool, jnp.asarray(layer, jnp.int32).reshape(1),
+                        tables.astype(jnp.int32), q_lens.astype(jnp.int32),
+                        kv_lens.astype(jnp.int32), v_width=int(v_width),
+                        sm_scale=float(sm_scale), interpret=interpret)

@@ -1,14 +1,30 @@
-"""Mixture-of-experts FFN + expert parallelism.
+"""Mixture-of-experts FFN: routing, the grouped expert product, expert
+parallelism.
 
-Completes the parallelism alphabet (dp/tp/sp covered elsewhere): experts
-partition across a mesh axis, each device computes its local experts'
-contribution for the token stream, and a ``psum`` over the expert axis
-combines — exact MoE (no capacity truncation), communication = one psum
-riding ICI.  (The token-dropping all_to_all dispatch variant is the
-throughput optimization on top; this form is the correctness baseline and
-the right shape for small expert counts.)
+ONE expert FFN serves every caller: the paged engine's expert layers
+(:func:`routed_ffn` from ``tpulab.engine.paged._layer_block``), the dense
+MoE transformer of the dry run (:func:`moe_ffn`) and the expert-parallel
+form (:func:`make_expert_parallel_ffn`).  It is *exact*: no capacity, no
+dropped token, whatever the routing.
 
-Router: top-k softmax gating, renormalized over the selected experts.
+Routing (:func:`route`), two router kinds:
+
+``"softmax"``       top-k of the router logits, softmax over the chosen k;
+``"sigmoid_bias"``  DeepSeek-V3 / GLM-4.x ``noaux_tc``: scores ``s =
+                    sigmoid(x W_g)`` in float32; the k experts are chosen
+                    by ``s + bias`` (``e_score_correction_bias``), weighted
+                    by ``s`` itself (without the bias), normalised over the
+                    chosen k (``+ 1e-20``) and scaled.
+
+The expert product (:func:`expert_ffn`) follows rows x top-k, not rows x
+experts: the (row, expert) assignments are sorted by expert and each
+projection is ONE grouped matrix product over the sorted rows
+(``jax.lax.ragged_dot`` with the group sizes), so an expert that no row
+chose costs no FLOPs.  It is told which experts it holds (``first``, and
+the leading axis of the weights): assignments to other experts contribute
+nothing — on one device it holds them all; under
+:func:`make_expert_parallel_ffn` each shard holds a contiguous range and a
+``psum`` over the expert axis combines.
 """
 
 from __future__ import annotations
@@ -31,29 +47,101 @@ def init_moe_params(d_model: int = 64, d_ff: int = 128, n_experts: int = 8,
     }
 
 
-def _gates(params, x, top_k: int):
-    """(N, D) tokens -> (N, E) gate weights: softmax over exactly the top-k
-    router logits (lax.top_k breaks ties deterministically — tied/uniform
-    logits still activate exactly k experts)."""
-    logits = x.astype(jnp.float32) @ params["router"].astype(jnp.float32)
-    n_experts = logits.shape[-1]
-    if top_k >= n_experts:
-        return jax.nn.softmax(logits, axis=-1)
-    vals, idx = jax.lax.top_k(logits, top_k)            # (N, k)
-    weights = jax.nn.softmax(vals, axis=-1)             # renormalized over k
-    onehot = jax.nn.one_hot(idx, n_experts, dtype=weights.dtype)  # (N, k, E)
-    return jnp.einsum("nk,nke->ne", weights, onehot)
+def route(router_w, x, top_k: int, kind: str = "softmax", bias=None,
+          scale: float = 1.0, norm: bool = True):
+    """(N, D) rows -> ``(idx (N, k) int32, weights (N, k) float32)``, in
+    float32 whatever the rows' dtype (``lax.top_k`` breaks ties
+    deterministically: tied scores still choose exactly k experts)."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        top_k = min(top_k, logits.shape[-1])
+        if kind == "softmax":
+            vals, idx = jax.lax.top_k(logits, top_k)
+            return idx, jax.nn.softmax(vals, axis=-1)
+        if kind != "sigmoid_bias":
+            raise ValueError(f"unknown router kind {kind!r}")
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=1)
+        if norm:
+            w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        return idx, w * scale
+
+
+def routing_stats(idx, n_experts: int, valid=None):
+    """``(E + 2,)`` int32 counters of one routing: assignments per expert,
+    then the number of experts with at least one row, then 1 if any row
+    was valid (so that sums over steps count the steps that had work).
+    ``valid (N,)`` masks rows that carry no token."""
+    hot = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32).sum(axis=1)
+    if valid is not None:
+        hot = hot * valid.astype(jnp.int32)[:, None]
+    counts = hot.sum(axis=0)
+    hit = (counts > 0).sum()
+    return jnp.concatenate([counts, hit[None], (hit > 0)[None]]
+                           ).astype(jnp.int32)
+
+
+def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
+               compute_dtype=jnp.float32, first=0):
+    """Exact expert FFN of the assignments ``(idx, weights)`` (N, k) over
+    the experts held here: ``w_in (E_here, D, F or 2F)``, ``w_out (E_here,
+    F, D)`` are experts ``first .. first + E_here`` of the layer
+    (``first`` may be traced).  ``act="swiglu"``: ``w_in`` is ``[gate |
+    up]`` and the hidden is ``silu(gate) * up``; ``"gelu"``: ``gelu(x
+    w_in)``.  Returns (N, D) float32: the weighted sum of each row's
+    chosen experts that live here."""
+    with jax.named_scope("moe_experts"):
+        n, k = idx.shape
+        n_here = w_in.shape[0]
+        local = idx.reshape(-1) - first
+        here = (local >= 0) & (local < n_here)
+        # assignments sorted by expert; those of experts held elsewhere go
+        # last, past every group, and are weighted 0
+        key = jnp.where(here, local, n_here)
+        order = jnp.argsort(key)
+        sizes = jnp.bincount(key, length=n_here + 1)[:n_here].astype(
+            jnp.int32)
+        rows = x.astype(compute_dtype)[order // k]
+        h = jax.lax.ragged_dot(rows, w_in.astype(compute_dtype), sizes)
+        if act == "swiglu":
+            f = h.shape[-1] // 2
+            h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        elif act == "gelu":
+            h = jax.nn.gelu(h)
+        else:
+            raise ValueError(f"unknown expert activation {act!r}")
+        y = jax.lax.ragged_dot(h, w_out.astype(compute_dtype), sizes)
+        # rows past the last group are not part of any product: drop them
+        y = jnp.where(here[order][:, None], y.astype(jnp.float32)
+                      * weights.reshape(-1)[order][:, None], 0.0)
+        return jnp.zeros((n, y.shape[-1]), jnp.float32).at[order // k].add(y)
+
+
+def routed_ffn(params: Dict[str, Any], x, top_k: int,
+               compute_dtype=jnp.float32, router: str = "softmax",
+               act: str = "gelu", scale: float = 1.0, norm: bool = True,
+               valid=None):
+    """Route (N, D) rows and run the experts: ``(out (N, D) float32, stats
+    (E + 2,))``.  ``params``: ``router (D, E)``, ``bias (E,)`` for the
+    ``"sigmoid_bias"`` router, and the experts as ``w13``/``w2``
+    (SwiGLU) or ``w1``/``w2`` (GELU)."""
+    from tpulab.models.transformer import qmat
+    idx, weights = route(params["router"], x, top_k, router,
+                         params.get("bias"), scale, norm)
+    w_in = params["w13" if act == "swiglu" else "w1"]
+    out = expert_ffn(x, idx, weights, qmat(w_in, compute_dtype),
+                     qmat(params["w2"], compute_dtype), act, compute_dtype)
+    return out, routing_stats(idx, params["router"].shape[-1], valid)
 
 
 def moe_ffn(params: Dict[str, Any], x: jnp.ndarray, top_k: int = 2,
             compute_dtype=jnp.float32) -> jnp.ndarray:
-    """Dense single-device MoE FFN reference ((N, D) -> (N, D))."""
-    gates = _gates(params, x, top_k)                       # (N, E)
-    h = jnp.einsum("nd,edf->nef", x.astype(compute_dtype),
-                   params["w1"].astype(compute_dtype))
-    h = jax.nn.gelu(h)
-    y = jnp.einsum("nef,efd->ned", h, params["w2"].astype(compute_dtype))
-    return jnp.einsum("ned,ne->nd", y, gates.astype(compute_dtype))
+    """Single-device MoE FFN of the dry run's transformer ((N, D) -> (N,
+    D)): softmax top-k gating, GELU experts."""
+    return routed_ffn(params, x, top_k, compute_dtype)[0].astype(
+        compute_dtype)
 
 
 def make_expert_parallel_ffn(mesh: Mesh, axis_name: str = "model",
@@ -72,21 +160,14 @@ def make_expert_parallel_ffn(mesh: Mesh, axis_name: str = "model",
             lambda spec: NamedSharding(mesh, spec), param_specs))
 
     def local_ffn(params, x):
-        # x replicated; each device computes its LOCAL experts' contribution
+        # x and the router replicated: every shard routes over ALL experts
+        # and computes the part of the result its own experts give
         n_local = params["w1"].shape[0]
-        e0 = jax.lax.axis_index(axis_name) * n_local
-        gates = _gates_local(params, x, top_k, e0, n_local)
-        h = jnp.einsum("nd,edf->nef", x.astype(compute_dtype),
-                       params["w1"].astype(compute_dtype))
-        h = jax.nn.gelu(h)
-        y = jnp.einsum("nef,efd->ned", h, params["w2"].astype(compute_dtype))
-        out = jnp.einsum("ned,ne->nd", y, gates.astype(compute_dtype))
-        return jax.lax.psum(out, axis_name)  # combine expert shards
-
-    def _gates_local(params, x, top_k, e0, n_local):
-        # router is replicated: compute GLOBAL top-k gates, slice local cols
-        full = _gates({"router": params["router"]}, x, top_k)
-        return jax.lax.dynamic_slice_in_dim(full, e0, n_local, axis=1)
+        idx, weights = route(params["router"], x, top_k)
+        out = expert_ffn(x, idx, weights, params["w1"], params["w2"], "gelu",
+                         compute_dtype,
+                         first=jax.lax.axis_index(axis_name) * n_local)
+        return jax.lax.psum(out.astype(compute_dtype), axis_name)
 
     def ffn(sharded_params, x):
         return jax.shard_map(local_ffn, mesh=mesh,
