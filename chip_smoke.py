@@ -349,8 +349,9 @@ def phase_latent(smoke: Smoke) -> str:
     import jax.numpy as jnp
     import numpy as np
 
-    from tpulab.engine.paged import (PagedKVPool, paged_decode_step,
-                                     paged_mixed_step)
+    from tpulab.engine.paged import (PagedKVPool, pack_round,
+                                     paged_decode_step, paged_mixed_step,
+                                     paged_ragged_forward)
     from tpulab.models.spec import glm4_moe_lite_spec, init_params
     sz = smoke.sizes
     cfg, chunk, page = sz.glm, sz.glm_chunk, sz.lm_page_size
@@ -365,11 +366,15 @@ def phase_latent(smoke: Smoke) -> str:
     i32 = lambda x: jnp.asarray(x, jnp.int32)
     kw = dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
               compute_dtype=jnp.bfloat16, spec=spec)
-    # lane 0: the second chunk of a prompt; lanes 1, 2 decode; lane 3 idle
+    # three contexts go in through the padded form; then the round, packed
+    # by token: lane 0 the second chunk of its prompt, lanes 1, 2 decode,
+    # lane 3 idle
     fill = (i32(rng.integers(0, cfg["vocab_size"], (lanes, chunk))),
             i32([chunk, chunk - 3, chunk // 2, 0]))
-    seq = i32(rng.integers(0, cfg["vocab_size"], (lanes, chunk)))
-    q_lens = i32([chunk, 1, 1, 0])
+    toks, row_lane, row_off, q_lens = map(i32, pack_round(
+        lanes, {0: rng.integers(0, cfg["vocab_size"], chunk)},
+        {1: int(rng.integers(cfg["vocab_size"])),
+         2: int(rng.integers(cfg["vocab_size"]))}))
     kv_lens = i32([2 * chunk, chunk - 2, chunk // 2 + 1, 0])
     temps, seeds = jnp.zeros((lanes,), jnp.float32), jnp.zeros(
         (lanes, 2), jnp.uint32)
@@ -379,16 +384,18 @@ def phase_latent(smoke: Smoke) -> str:
                            jnp.bfloat16, latent_width=spec.latent_width)
         mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **kw),
                         donate_argnums=(1,))
+        padded = jax.jit(partial(paged_ragged_forward, use_kernel=uk,
+                                 last_only=True, **kw), donate_argnums=(1,))
         step = jax.jit(partial(paged_decode_step, use_kernel=uk, **kw),
                        donate_argnums=(1,))
         if uk:
             check_mosaic(smoke, "latent mixed round", partial(
                 paged_mixed_step, use_kernel=True, **kw), params, pool.kv,
-                i32(tables), seq, q_lens, kv_lens, temps, seeds)
-        _, _, _, kv, _ = mixed(params, pool.kv, i32(tables), *fill, fill[1],
-                               temps, seeds)
-        _, _, last, kv, moe = mixed(params, kv, i32(tables), seq, q_lens,
-                                    kv_lens, temps, seeds)
+                i32(tables), toks, row_lane, row_off, q_lens, kv_lens, temps,
+                seeds)
+        _, kv, _ = padded(params, pool.kv, i32(tables), *fill, fill[1])
+        _, _, last, kv, moe = mixed(params, kv, i32(tables), toks, row_lane,
+                                    row_off, q_lens, kv_lens, temps, seeds)
         logits, kv, _ = step(params, kv, i32(tables), kv_lens,
                              i32([5, 6, 7, 0]),
                              jnp.asarray([True, True, True, False]))

@@ -206,7 +206,8 @@ def _lower_args(cb, kind):
         "paged_decode_step_sampled": lambda: (
             cb._step_sampled, head + lane + samp),
         "paged_mixed_step": lambda: (
-            cb._mixed, head + (i32(b, mp), i32(b, 8), i32(b), i32(b)) + samp),
+            cb._mixed, head + (i32(b, mp), i32(8 + b), i32(8 + b),
+                               i32(8 + b), i32(b), i32(b)) + samp),
         "paged_prefill": lambda: (
             cb._prefill, head + one + (jnp.int32(5),)),
         "paged_extend": lambda: (

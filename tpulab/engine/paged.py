@@ -436,7 +436,10 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
     latent page store: ``(attn (B, M, H * v_head_dim), kv_pool)``.  The
     row ``[c_kv ; k_rope]`` (after norm and RoPE) is scattered once; the
     key up-projection moves into the query, the value up-projection
-    behind the weighted latent sum."""
+    behind the weighted latent sum.  In a packed round (``seg["rows"]``,
+    see :func:`_layer_block`) ``h`` is ``(1, T, D)``: only the absorbed
+    query is spread to ``(B, M)`` for the walk over the pages, and the
+    weighted latent sum is gathered back to rows before ``w_uv``."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _rmsnorm, apply_rope, qmat
@@ -463,6 +466,11 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
         qa = jnp.concatenate(
             [jnp.einsum("bmhn,hnc->bmhc", q[..., :nope],
                         qmat(p["w_uk"], compute_dtype)), qr], axis=-1)
+        packed = seg.get("rows")
+        if packed is not None:
+            spread, back, pos = packed
+            qa = jnp.take(qa.reshape(qa.shape[1:]), spread, axis=0,
+                          mode="clip").reshape(pos.shape + qa.shape[2:])
         if seg["use_kernel"]:
             from tpulab.ops.ragged_attention import ragged_latent_attention
             lat = ragged_latent_attention(
@@ -472,6 +480,9 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
             lat = _gather_attend_latent(
                 qa, kv_pool[layer, :, 0], seg["tables"], pos,
                 spec.kv_lora_rank, scale, compute_dtype)
+        if packed is not None:                 # (B, M, H, C) -> (1, T, H, C)
+            lat = jnp.take(lat.reshape((-1,) + lat.shape[2:]), back, axis=0,
+                           mode="clip")[None]
         attn = jnp.einsum("bmhc,hcv->bmhv", lat.astype(compute_dtype),
                           qmat(p["w_uv"], compute_dtype))
         return attn.reshape(b, m, -1), kv_pool
@@ -514,6 +525,19 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     (B,), and the attention path (``use_kernel``: the Pallas ragged kernel
     of the cache-entry kind, else the XLA gather; ``kernel_geometry``,
     ``mesh``).  ``valid`` (B, M) bool masks the expert counters only.
+
+    Three forms, told apart by what ``seg`` carries.  A decode step is
+    (B, 1).  The padded form is (B, M), lane b's segment left-packed in
+    row b (K+1 verify, where every lane's segment has one length).  A
+    packed round (:func:`paged_mixed_step`) carries ``seg["rows"]``: x is
+    (1, T, D), one row a token of the round, and everything but the walk
+    over the pages runs on those T rows; ``rows = (spread (B * M,), back
+    (T,), qpos (B, M))`` holds the row behind each slot of the (B, M)
+    form the attention takes and the slot behind each row: the query rows
+    are spread by one row gather and the attention's output gathered back
+    by another, two copies of at most lanes x M rows a layer, where the
+    padded form ran every product on lanes x M rows.  (``jnp.take``, not
+    ``x[idx]``: it is jitted, so sixteen layers trace it once.)
     Returns ``(x, kv_pool, stats)``: ``stats`` is the expert layer's
     ``(E + 2,)`` int32 counters
     (:func:`tpulab.parallel.moe.routing_stats`) or None on a dense layer.
@@ -526,6 +550,7 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     and a helper inside the kernel; the dense cell's set-up grew 10 %,
     96 -> 106 s, until the count was the parent's again).
     """
+    import jax.numpy as jnp
     from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
                                            split_qkv)
 
@@ -546,6 +571,12 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
                               knew.reshape(page_idx.shape + tail),
                               vnew.reshape(page_idx.shape + tail))
+        packed = seg.get("rows")
+        if packed is not None:
+            spread, back, pos = packed
+            b, m = pos.shape
+            q = jnp.take(q.reshape(q.shape[1:]), spread, axis=0,
+                         mode="clip").reshape((b, m) + q.shape[2:])
         if seg["use_kernel"]:
             # pallas ragged kernel: walks block tables page-by-page, no
             # dense gather materialization; fused pages = 1 DMA/page;
@@ -557,7 +588,6 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
                 # the jitted entry itself, not ``ragged_paged_attention``
                 # around it: one Python frame fewer above the kernel
                 # (the docstring says what a frame costs)
-                import jax.numpy as jnp
                 from tpulab.tpu.platform import pallas_interpret
                 attn = ra._ragged_attn(
                     q, kv_pool, jnp.asarray(layer, jnp.int32).reshape(1),
@@ -573,6 +603,9 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
             attn = _gather_attend(q, kv_pool[layer, :, 0],
                                   kv_pool[layer, :, 1], seg["tables"], pos,
                                   compute_dtype)
+        if packed is not None:                     # (B, M, H*D) -> (1, T, H*D)
+            attn = jnp.take(attn.reshape(b * m, -1), back, axis=0,
+                            mode="clip")[None]
     x, stats = _ffn_block(spec, p, layer,
                           x + attn @ qmat(p["wo"], compute_dtype), valid,
                           compute_dtype)
@@ -757,9 +790,12 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
                          mesh=None,
                          kernel_geometry: Optional[tuple] = None,
                          last_only: bool = False, spec=None):
-    """One fused multi-token forward over ragged per-lane segments — the
-    single program shape behind the ragged dispatch plan (ROADMAP item
-    2, "Ragged Paged Attention" in PAPERS.md).
+    """One fused multi-token forward over ragged per-lane segments in the
+    PADDED form, every product on ``B x M`` rows (ROADMAP item 2, "Ragged
+    Paged Attention" in PAPERS.md).  The K+1 speculative verify runs it
+    (every lane's segment has one length there, so the padding is dense);
+    a mixed round runs the same segments packed by token
+    (:func:`paged_mixed_step`) and is tested against this form.
 
     ``seq (B, M)`` int32, left-packed: lane b's valid tokens are
     ``seq[b, :q_lens[b]]``, token j at global position
@@ -827,41 +863,121 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
     return (_lm_head(params, x), kv_pool) + moe
 
 
-def paged_mixed_step(params, kv_pool, tables, seq, q_lens, kv_lens,
-                     temps, seeds, n_heads: int, n_layers: int,
-                     compute_dtype, use_kernel: bool = False,
+def round_width(prefill_tokens: int) -> int:
+    """``M`` of the mixed round that carries ``prefill_tokens`` prompt
+    tokens: the pow2 bucket its program is keyed by (few jits) and the
+    segment width its attention is called at."""
+    return 1 << (prefill_tokens - 1).bit_length()
+
+
+def pack_round(lanes: int, prefill: Dict[int, Any], decode: Dict[int, int]):
+    """Host half of :func:`paged_mixed_step`'s input: a round packed by
+    token.  ``prefill`` maps a lane to its chunk's tokens (at least one
+    token in all; packed in the mapping's order), ``decode`` a lane to its
+    current token.  Returns numpy ``(toks (T,), row_lane (T,), row_off
+    (T,), q_lens (lanes,))`` with ``T = round_width(prefill tokens) +
+    lanes``."""
+    m = round_width(sum(len(chunk) for chunk in prefill.values()))
+    toks = np.zeros((m + lanes,), np.int32)
+    row_lane = np.full((m + lanes,), -1, np.int32)
+    row_off = np.zeros((m + lanes,), np.int32)
+    q_lens = np.zeros((lanes,), np.int32)
+    row = 0
+    for lane, chunk in prefill.items():
+        rows = slice(row, row + len(chunk))
+        toks[rows], row_lane[rows] = chunk, lane
+        row_off[rows] = np.arange(len(chunk))
+        q_lens[lane] = len(chunk)
+        row = rows.stop
+    for lane, tok in decode.items():
+        toks[m + lane], row_lane[m + lane], q_lens[lane] = tok, lane, 1
+    return toks, row_lane, row_off, q_lens
+
+
+def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
+                     q_lens, kv_lens, temps, seeds, n_heads: int,
+                     n_layers: int, compute_dtype, use_kernel: bool = False,
                      n_kv_heads: Optional[int] = None,
                      rope_theta: Optional[float] = None,
                      mesh=None,
                      kernel_geometry: Optional[tuple] = None, spec=None):
-    """One mixed prefill+decode round: a ragged forward over per-lane
-    segments plus each lane's next-token pick, in ONE dispatch.
+    """One mixed prefill+decode round, packed by token: a ragged forward
+    over per-lane segments plus each lane's next-token pick, in ONE
+    dispatch whose rows are the round's tokens.
 
-    Prefilling lanes carry their prompt chunk (``q_lens = chunk``),
-    decoding lanes carry their current token (``q_lens = 1``); every
-    lane's pick is :func:`_device_sample_token` on its LAST valid
-    position's logits at position ``kv_lens - 1`` — exactly the decode
-    tick's stream for decode lanes and exactly the prefill first-token
-    stream (position ``t - 1``) for lanes finishing their prompt, so
-    one request is one (seed, position)-keyed stream regardless of
-    which dispatch kind served it.  The caller consumes picks only for
-    lanes that emit this round (a mid-prompt chunk's pick is discarded;
-    device sampling is stateless, so a discarded pick costs nothing).
+    Prefilling lanes carry a prompt chunk (``q_lens = chunk``), decoding
+    lanes their current token (``q_lens = 1``), idle lanes nothing
+    (``q_lens = 0``).  ``toks (T,)`` holds the round with ``T = M +
+    lanes``: rows ``[0, M)`` are the prefilling lanes' chunk tokens one
+    lane after the other, row ``M + b`` is lane b's decode token.
+    ``row_lane (T,)`` is each row's lane (-1: the row holds no token) and
+    ``row_off (T,)`` its offset in the lane's segment: token ``(b, j)``
+    sits at global position ``kv_lens[b] - q_lens[b] + j``.  Embedding,
+    norms, projections, RoPE, the row scatter into the lane's pages,
+    ``wo``, the FFN or the routed experts and their counters run on the T
+    rows; only the attention call sees the ``(B, M)`` form of
+    :func:`paged_ragged_forward` (:func:`_layer_block`), so a round costs
+    what its tokens cost, not lanes x the longest chunk.  ``M`` (from the
+    shapes, ``T - lanes``) is the ONE number the program is keyed by.
+
+    Every lane's pick is :func:`_device_sample_token` on its LAST valid
+    row's logits at position ``kv_lens - 1`` — exactly the decode tick's
+    stream for decode lanes and exactly the prefill first-token stream
+    (position ``t - 1``) for lanes finishing their prompt, so one request
+    is one (seed, position)-keyed stream regardless of which dispatch
+    kind served it.  The caller consumes picks only for lanes that emit
+    this round (a mid-prompt chunk's pick is discarded; device sampling
+    is stateless, so a discarded pick costs nothing).
 
     Returns ``(next_tokens (B,) i32, logprobs (B,) f32, last_logits
     (B, vocab), kv_pool)`` — ``last_logits`` stays device-resident
     unless a host-sampled lane fetches its row — and the expert layers'
-    counters behind the pool where ``spec`` has any.
+    counters behind the pool where ``spec`` has any.  The same segments
+    through ``paged_ragged_forward(last_only=True)`` give the same
+    logits: that is the plain form this one is tested against.
     """
     import jax
     import jax.numpy as jnp
+    from tpulab.models.transformer import _lm_head, _rmsnorm
 
-    last, kv_pool, *moe = paged_ragged_forward(
-        params, kv_pool, tables, seq, q_lens, kv_lens,
-        n_heads=n_heads, n_layers=n_layers, compute_dtype=compute_dtype,
-        use_kernel=use_kernel, n_kv_heads=n_kv_heads,
-        rope_theta=rope_theta, mesh=mesh,
-        kernel_geometry=kernel_geometry, last_only=True, spec=spec)
+    b, t = tables.shape[0], toks.shape[0]
+    m = t - b
+    page_size = kv_pool.shape[3]
+    emb = params["embed"].astype(compute_dtype)
+    x = emb[toks][None]                               # (1, T, D)
+    spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
+    valid = row_lane >= 0
+    lane = jnp.maximum(row_lane, 0)
+    start = kv_lens - q_lens                          # (B,) segment starts
+    pos = jnp.where(valid, start[lane] + row_off, 0)
+    page_idx = jnp.where(valid, tables[lane, pos // page_size], 0)
+    slot_idx = jnp.where(valid, pos % page_size, 0)
+    # the slot of the padded (B, M) form behind each row, and the row
+    # behind each slot; slots past a lane's segment read row 0, which the
+    # attention masks by q_lens
+    back = lane * m + row_off
+    spread = jnp.zeros((b * m,), jnp.int32).at[
+        jnp.where(valid, back, b * m)].set(
+            jnp.arange(t, dtype=jnp.int32), mode="drop")
+    qpos = start[:, None] + jnp.arange(m)[None, :]
+    seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+               use_kernel=use_kernel, kernel_geometry=kernel_geometry,
+               mesh=mesh, rows=(spread, back, qpos))
+    moe_stats = []
+    for layer in range(spec.n_layers):
+        x, kv_pool, stats = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos[None], valid[None],
+            kv_pool, page_idx[None], slot_idx[None], seg, compute_dtype)
+        if stats is not None:
+            moe_stats.append(stats)
+    moe = (jnp.stack(moe_stats),) if moe_stats else ()
+
+    # the vocab-sized head over ONE row a lane: its last valid token's
+    last_row = spread[jnp.arange(b) * m + jnp.maximum(q_lens - 1, 0)]
+    last = _lm_head(params, _rmsnorm(x[0][last_row],
+                                     params["final_norm"]["scale"],
+                                     spec.rms_eps))
     pos_last = jnp.maximum(kv_lens - 1, 0)
     next_tokens = jax.vmap(_device_sample_token)(
         last, temps, seeds.astype(jnp.uint32), pos_last)
@@ -1472,10 +1588,11 @@ class ContinuousBatcher:
     Ragged dispatch plan (``use_kernel=True`` or ``ragged=True``,
     docs/PERFORMANCE.md "Ragged paged attention"): prompts and decode
     lanes advance together through fused mixed rounds
-    (:func:`paged_mixed_step`) — per-lane (query_len, kv_len) segments,
-    ONE dispatch and one host sync per round, no separate prefill
-    programs — and the speculative verify forward rides the same
-    ragged kernel family.  Tokens are bit-exact vs the legacy split
+    (:func:`paged_mixed_step`) — per-lane (query_len, kv_len) segments
+    packed by token, at most ``RAGGED_CHUNK_CAP`` prompt tokens a round
+    for all lanes together, ONE dispatch and one host sync per round, no
+    separate prefill programs — and the speculative verify forward rides
+    the same ragged kernel family.  Tokens are bit-exact vs the legacy split
     dispatch (``use_kernel=False``, the escape hatch), mesh on or off.
 
     Model spec (``spec=``, tpulab.models.spec): a ``ModelSpec`` names the
@@ -1688,14 +1805,12 @@ class ContinuousBatcher:
         def kernel_error():
             """Mosaic's shape rule at the PER-SHARD geometry (one shard's
             program is the one that must build) and the widest segment a
-            dispatch can carry: a mixed round's pow2 chunk bucket under
-            the ragged plan, a K+1 verify otherwise."""
+            dispatch can carry: a mixed round that spends its whole token
+            budget under the ragged plan, a K+1 verify otherwise."""
             from tpulab.ops.ragged_attention import (kernel_geometry_error,
                                                      latent_geometry_error)
-            cap = min(self.prefill_chunk or self.RAGGED_CHUNK_CAP,
-                      self.RAGGED_CHUNK_CAP)
-            widest = (1 << (cap - 1).bit_length() if ragged is not False
-                      else self.BLOCK_K_MENU[-1] + 1)
+            widest = (round_width(self._round_budget)
+                      if ragged is not False else self.BLOCK_K_MENU[-1] + 1)
             if latent:
                 return latent_geometry_error(
                     widest, n_heads, self.pool.kv.shape[4],
@@ -1768,13 +1883,13 @@ class ContinuousBatcher:
             (psh, kvsh, rep, rep, rep, rep, rep, rep),
             (rep, rep, rep, kvsh))
         # mixed prefill+decode rounds (the ragged dispatch plan): ONE
-        # jitted program respecializes per pow2 segment-width bucket —
-        # prefilling lanes ride their chunk and decoding lanes their
-        # next token through a single ragged forward + on-device pick
+        # jitted program respecializes per pow2 bucket of the round's
+        # prefill tokens (round_width) — the chunks packed by token and
+        # a row for each lane's decode token through a single ragged
+        # forward + on-device pick
         self._mixed = self._jit(
             partial(paged_mixed_step, **self._step_kw), (1,),
-            (psh, kvsh, rep, rep, rep, rep, rep, rep),
-            (rep, rep, rep, kvsh))
+            (psh, kvsh) + (rep,) * 8, (rep, rep, rep, kvsh))
         if decode_block < 1:
             raise ValueError("decode_block must be >= 1")
         #: max fused-decode steps per dispatch (K): a K-block amortizes the
@@ -1802,6 +1917,11 @@ class ContinuousBatcher:
         #: prefill+decode rounds
         self.dispatch_kinds: Dict[str, int] = {"decode": 0, "verify": 0,
                                                "mixed": 0}
+        #: rows the mixed rounds computed (``M + lanes`` a round) and the
+        #: rows among them that held a token: their ratio is the fill of
+        #: the packed round
+        self.mixed_rows = 0
+        self.mixed_tokens = 0
         #: sum of K over plain decode dispatches (K-blocks and single
         #: ticks): over ``dispatch_kinds["decode"]`` it is the mean block
         self.decode_block_steps = 0
@@ -2537,6 +2657,8 @@ class ContinuousBatcher:
                          "use_kernel": self.use_kernel,
                          "ragged_dispatches": self.ragged_dispatches,
                          "kinds": dict(self.dispatch_kinds),
+                         "mixed_rows": self.mixed_rows,
+                         "mixed_tokens": self.mixed_tokens,
                          "decode_block_steps": self.decode_block_steps,
                          "stages": self._stages.stages(),
                          "queue_wait_s": self.queue_wait_s,
@@ -3324,11 +3446,18 @@ class ContinuousBatcher:
         return True
 
     # -- ragged dispatch plan (mixed prefill+decode rounds) ------------------
-    #: max prefill tokens one mixed round carries per lane (the pow2
-    #: segment-width bucket ceiling; ``prefill_chunk`` lowers it) —
-    #: longer prompts take multiple rounds, decode lanes never stalling
-    #: behind them
+    #: max prefill tokens one mixed round carries IN TOTAL (the ceiling
+    #: of the pow2 bucket the mixed program is keyed by; ``prefill_chunk``
+    #: lowers it): lanes that prefill at once share it, longer prompts
+    #: take multiple rounds, decode lanes never stall behind them
     RAGGED_CHUNK_CAP = 256
+
+    @property
+    def _round_budget(self) -> int:
+        """Prefill tokens one mixed round may carry, all lanes together
+        (the token budget of chunked prefill)."""
+        return min(self.prefill_chunk or self.RAGGED_CHUNK_CAP,
+                   self.RAGGED_CHUNK_CAP)
 
     def _ragged_prefill_start(self, req: _PagedRequest, lane: int) -> bool:
         """Host half of a prefill under the ragged plan: prefix-cache
@@ -3376,14 +3505,21 @@ class ContinuousBatcher:
 
     def _ragged_round(self, snapshot, jnp) -> bool:
         """One fused ragged mixed round (the unified dispatch plan):
-        every prefilling lane advances by one prompt chunk and — with no
+        prefilling lanes advance by a prompt chunk and — with no
         dispatched-ahead block in flight — every decoding lane advances
         by one token, all through ONE ``paged_mixed_step`` dispatch over
-        per-lane ``(q_len, kv_len)`` segments.  Lanes finishing their
-        prompt emit their first token from the same dispatch (no
-        separate prefill program, no per-lane logits fetch).  With no
-        pending prompts this is a no-op and the K-block decode path
-        owns the tick.  Returns True when any lane made progress."""
+        per-lane ``(q_len, kv_len)`` segments, packed by token.  The
+        round's prompt tokens never exceed ``_round_budget`` in total:
+        lanes that prefill at once share it, the oldest admission first;
+        what is left of a chunk, or a lane the budget did not reach,
+        waits a round (the oldest lane always advances, so none starves).
+        The program is keyed by :func:`round_width` of the tokens carried,
+        so the budget also bounds the programs: nine, each reached by a
+        single prompt.  Lanes finishing their prompt emit their first
+        token from the same dispatch (no separate prefill program, no
+        per-lane logits fetch).  With no pending prompts this is a no-op
+        and the K-block decode path owns the tick.  Returns True when any
+        lane made progress."""
         st = self._stages
         with stage(st, "plan"):
             progressed = False
@@ -3427,49 +3563,33 @@ class ContinuousBatcher:
                         continue
                     decode_parts.append((lane, req))
         with stage(st, "dispatch"):
-            cap = min(self.prefill_chunk or self.RAGGED_CHUNK_CAP,
-                      self.RAGGED_CHUNK_CAP)
+            left = self._round_budget
             chunks: Dict[int, int] = {}
-            m_max = 1
-            for lane, req in segs:
-                c = min(len(req.pending_prompt), cap)
-                chunks[lane] = c
-                m_max = max(m_max, c)
-            m_pad = 1 << (m_max - 1).bit_length()   # pow2 bucket: small jits
+            for lane, req in sorted(segs, key=lambda s: s[1].admit_seq):
+                chunks[lane] = min(len(req.pending_prompt), left)
+                left -= chunks[lane]
+            segs = [(lane, req) for lane, req in segs if chunks[lane]]
             b = self.lanes
+            toks, row_lane, row_off, q_lens = pack_round(
+                b, {lane: req.pending_prompt[:chunks[lane]]
+                    for lane, req in segs},
+                {lane: req.tokens_out[-1] for lane, req in decode_parts})
             tables = np.zeros((b, self.max_pages), np.int32)
-            seq = np.zeros((b, m_pad), np.int32)
-            q_lens = np.zeros((b,), np.int32)
             kv_lens = np.zeros((b,), np.int32)
             temps = np.zeros((b,), np.float32)
             seeds = np.zeros((b, 2), np.uint32)
             host_lanes: List[int] = []
             lane_reqs: Dict[int, _PagedRequest] = {}
-            for lane, req in segs:
-                c = chunks[lane]
+            for lane, req in segs + decode_parts:
                 lane_reqs[lane] = req
-                seq[lane, :c] = req.pending_prompt[:c]
-                q_lens[lane] = c
-                kv_lens[lane] = req.length + c
+                kv_lens[lane] = req.length + q_lens[lane]
                 tables[lane, :len(req.pages)] = req.pages
                 sp = req.sampling
-                if c == len(req.pending_prompt) and not req.resumed \
-                        and sp.temperature > 0.0:
-                    # final chunk: this round's pick IS the first token
-                    if sp.device:
-                        temps[lane] = sp.temperature
-                        seeds[lane] = (sp.seed & 0xFFFFFFFF,
-                                       (sp.seed >> 32) & 0xFFFFFFFF)
-                    else:
-                        host_lanes.append(lane)
-            for lane, req in decode_parts:
-                lane_reqs[lane] = req
-                seq[lane, 0] = req.tokens_out[-1]
-                q_lens[lane] = 1
-                kv_lens[lane] = req.length + 1
-                tables[lane, :len(req.pages)] = req.pages
-                sp = req.sampling
-                if sp.temperature > 0.0:
+                # a prompt's pick counts only off its final chunk, where
+                # it IS the first token (a resumed request made it before)
+                if sp.temperature > 0.0 and (not req.pending_prompt or (
+                        q_lens[lane] == len(req.pending_prompt)
+                        and not req.resumed)):
                     if sp.device:
                         temps[lane] = sp.temperature
                         seeds[lane] = (sp.seed & 0xFFFFFFFF,
@@ -3482,10 +3602,14 @@ class ContinuousBatcher:
             t0 = _time.perf_counter()
             nt_dev, lp_dev, last_dev, self.pool.kv, *moe = self._mixed(
                 self.params, self.pool.kv, jnp.asarray(tables),
-                jnp.asarray(seq), jnp.asarray(q_lens), jnp.asarray(kv_lens),
-                jnp.asarray(temps), jnp.asarray(seeds))
+                jnp.asarray(toks), jnp.asarray(row_lane),
+                jnp.asarray(row_off), jnp.asarray(q_lens),
+                jnp.asarray(kv_lens), jnp.asarray(temps),
+                jnp.asarray(seeds))
             self.decode_dispatches += 1
             self._note_dispatch("mixed")
+            self.mixed_rows += len(toks)
+            self.mixed_tokens += int(q_lens.sum())
         with stage(st, "fetch"):
             next_tokens = np.asarray(nt_dev, np.int32).copy()
             logprobs_arr = np.asarray(lp_dev, np.float32).copy()
