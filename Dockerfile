@@ -17,7 +17,7 @@ COPY tpulab/ tpulab/
 COPY cpp/ cpp/
 COPY examples/ examples/
 COPY tools/ tools/
-COPY bench.py __graft_entry__.py ./
+COPY __graft_entry__.py ./
 
 # native runtime core
 RUN cmake -S cpp -B cpp/build -G Ninja && ninja -C cpp/build

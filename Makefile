@@ -1,8 +1,8 @@
 # tpulab build/test targets (reference Makefile/build.sh analog).
 PY ?= python
 
-.PHONY: all native test test-native test-native-tsan bench bench-native \
-        bench-host dryrun smoke engine clean
+.PHONY: all native test test-native test-native-tsan bench-native \
+        dryrun smoke engine clean
 
 all: native test
 
@@ -25,12 +25,6 @@ test-native-tsan:
 
 bench-native: native
 	./cpp/build/bench_native
-
-bench:
-	$(PY) bench.py
-
-bench-host:
-	$(PY) benchmarks/bench_host.py
 
 dryrun:
 	$(PY) __graft_entry__.py 8

@@ -349,9 +349,10 @@ def phase_latent(smoke: Smoke) -> str:
     import jax.numpy as jnp
     import numpy as np
 
-    from tpulab.engine.paged import (PagedKVPool, pack_round,
-                                     paged_decode_step, paged_mixed_step,
-                                     paged_ragged_forward)
+    from tpulab.engine.kv_pool import PagedKVPool
+    from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
+                                           paged_mixed_step,
+                                           paged_ragged_forward)
     from tpulab.models.spec import glm4_moe_lite_spec, init_params
     sz = smoke.sizes
     cfg, chunk, page = sz.glm, sz.glm_chunk, sz.lm_page_size
@@ -442,7 +443,7 @@ def ragged_case(smoke: Smoke, name: str, q_lens, kv_lens, m: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from tpulab.engine.paged import _gather_attend
+    from tpulab.engine.paged_steps import _gather_attend
     from tpulab.ops.ragged_attention import ragged_paged_attention
     sz = smoke.sizes
     dtype = dtype or jnp.bfloat16

@@ -24,9 +24,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpulab.engine.paged import (PagedKVPool, pack_round,
-                                 paged_decode_step, paged_extend,
-                                 paged_mixed_step, paged_ragged_forward)
+from tpulab.engine.kv_pool import PagedKVPool
+from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
+                                       paged_extend, paged_mixed_step,
+                                       paged_ragged_forward)
 from tpulab.models.transformer import init_transformer_params
 
 GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",

@@ -16,8 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool,
-                                 paged_decode_step, paged_ragged_forward)
+from tpulab.engine.kv_pool import PagedKVPool
+from tpulab.engine.paged import ContinuousBatcher
+from tpulab.engine.paged_steps import paged_decode_step, paged_ragged_forward
 from tpulab.models.spec import glm4_moe_lite_spec, init_params, split_kv_b
 from tpulab.parallel import moe
 

@@ -16,7 +16,8 @@ import numpy as np
 import pytest
 
 from tpulab import chaos
-from tpulab.engine.paged import ContinuousBatcher, PagedKVPool
+from tpulab.engine.kv_pool import PagedKVPool
+from tpulab.engine.paged import ContinuousBatcher
 from tpulab.hbm import (KV_TENANT, SCRATCH_TENANT, WEIGHTS_TENANT,
                         DeviceHBMLedger, HBMArbiter)
 from tpulab.models.transformer import init_transformer_params

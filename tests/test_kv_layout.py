@@ -19,9 +19,9 @@ import numpy as np
 import pytest
 
 from tpulab.disagg import KVShipper
-from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool,
-                                 kv_page_shape, paged_decode_step,
-                                 paged_ragged_forward)
+from tpulab.engine.kv_pool import PagedKVPool, kv_page_shape
+from tpulab.engine.paged import ContinuousBatcher
+from tpulab.engine.paged_steps import paged_decode_step, paged_ragged_forward
 from tpulab.kvcache import KVOffloadManager
 from tpulab.models.transformer import init_transformer_params
 
@@ -257,7 +257,7 @@ def _latent_pages(use_kernel):
 
 
 def test_latent_page_shape_is_one_padded_row_a_token():
-    from tpulab.engine.paged import latent_page_shape
+    from tpulab.engine.kv_pool import latent_page_shape
     assert latent_page_shape(16, 576) == (1, 16, 640)
     assert latent_page_shape(8, 40) == (1, 8, 128)
     assert latent_page_shape(16, 512) == (1, 16, 512)

@@ -9,8 +9,8 @@ import numpy as np
 import pytest
 
 from tpulab import chaos
-from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool,
-                                 SamplingParams, kv_rows_view)
+from tpulab.engine.kv_pool import PagedKVPool, kv_rows_view
+from tpulab.engine.paged import ContinuousBatcher, SamplingParams
 from tpulab.kvcache import HostKVStore, KVOffloadManager
 from tpulab.models.transformer import init_transformer_params, make_generate_fn
 

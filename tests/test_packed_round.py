@@ -18,9 +18,10 @@ import numpy as np
 import pytest
 
 from test_glm_moe import CONFIG, D_FF, VOCAB
-from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool, pack_round,
-                                 paged_mixed_step, paged_ragged_forward,
-                                 round_width)
+from tpulab.engine.kv_pool import PagedKVPool
+from tpulab.engine.paged import ContinuousBatcher
+from tpulab.engine.paged_steps import (pack_round, paged_mixed_step,
+                                       paged_ragged_forward, round_width)
 from tpulab.models.spec import glm4_moe_lite_spec, init_params
 from tpulab.models.transformer import init_transformer_params
 

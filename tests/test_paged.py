@@ -6,8 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool,
-                                 SamplingParams)
+from tpulab.engine.kv_pool import PagedKVPool
+from tpulab.engine.paged import ContinuousBatcher, SamplingParams
 from tpulab.models.transformer import init_transformer_params, make_generate_fn
 
 
@@ -72,7 +72,7 @@ def test_prefix_cache_evict_for_alloc_skips_shared(lm):
     """Pool pressure must not wipe cache entries whose pages are still
     shared with active requests (refcount > 1): evicting them frees
     nothing.  Only sole-reference entries fall."""
-    from tpulab.engine.paged import PrefixCache
+    from tpulab.engine.kv_pool import PrefixCache
     pool = PagedKVPool(n_pages=8, page_size=8, n_layers=1, n_heads=2,
                        head_dim=16, dtype=jnp.float32)
     cache = PrefixCache(pool)
@@ -538,7 +538,7 @@ def test_kv_cache_quantization_fp8(lm):
     """kv_dtype narrower than compute: pages store fp8 (4x less HBM than
     f32), decode reads upcast, and the serving loop runs end to end with
     logits tracking the full-precision pool closely."""
-    from tpulab.engine.paged import paged_decode_step
+    from tpulab.engine.paged_steps import paged_decode_step
 
     cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=2, max_len=32,
                            page_size=8, compute_dtype=jnp.float32,

@@ -25,8 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpulab.engine.paged import (ContinuousBatcher, SamplingParams,
-                                 _gather_attend, kv_rows_view)
+from tpulab.engine.kv_pool import kv_rows_view
+from tpulab.engine.paged import ContinuousBatcher, SamplingParams
+from tpulab.engine.paged_steps import _gather_attend
 from tpulab.models.transformer import (early_exit_draft,
                                        init_transformer_params)
 from tpulab.ops.ragged_attention import ragged_paged_attention
@@ -465,12 +466,3 @@ def test_use_kernel_false_is_the_escape_hatch(lm):
         assert cb.ragged_dispatches == 0
     finally:
         cb.shutdown()
-
-
-@pytest.mark.slow
-def test_bench_ragged_attention_row(lm):
-    from tpulab.engine.paged import benchmark_ragged_attention
-    row = benchmark_ragged_attention(lanes=2, steps=8, prompt_len=6,
-                                     kernel=True)
-    assert row["ragged"]["parity"] and row["ragged_kernel"]["parity"]
-    assert row["ragged"]["dispatch_kinds"]["mixed"] >= 1

@@ -636,21 +636,6 @@ def test_engines_reject_out_of_range_ids_at_library_boundary():
         spec.stream(np.array([0, 64], np.int32), 2)  # EAGER: at call time
 
 
-def test_speculative_benchmark_row():
-    """The bench's speculative row (VERDICT r4 #7): early-exit draft gets
-    nonzero acceptance, exactness holds, and the record carries every
-    field the capture needs."""
-    from tpulab.engine.speculative import benchmark_speculative
-
-    row = benchmark_speculative(n_heads=4, n_layers=4, d_model=128,
-                                d_ff=256, vocab=128, draft_layers=1,
-                                k=3, steps=24, prompt_len=8, max_len=128)
-    assert row["exact_match"] is True  # speculation never changes content
-    assert 0.0 < row["acceptance"] <= 1.0
-    assert row["spec_tok_s"] > 0 and row["plain_tok_s"] > 0
-    assert row["rounds"] >= 24 // (3 + 1)
-
-
 def test_speculative_served_through_generate_rpc():
     """SpeculativeSessionEngine plugs speculation into the serving path:
     tokens stream over the Generate RPC in verified bursts and equal the

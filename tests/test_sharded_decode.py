@@ -24,8 +24,8 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tpulab.engine.paged import (ContinuousBatcher, PagedKVPool,
-                                 SamplingParams, kv_rows_view)
+from tpulab.engine.kv_pool import PagedKVPool, kv_rows_view
+from tpulab.engine.paged import ContinuousBatcher, SamplingParams
 from tpulab.models.transformer import (early_exit_draft,
                                        init_transformer_params,
                                        make_generate_fn)
@@ -290,23 +290,6 @@ def test_mesh_parity_matches_dryrun_contract(lm):
         finally:
             cb.shutdown()
     assert outs["sharded"] == outs["single"]
-
-
-# -------------------------------------------------------------- bench ----
-def test_benchmark_sharded_decode_row(lm):
-    """The bench ``sharded_decode`` row on the CPU capture path: greedy +
-    device-sampled parity recorded, one blocking fetch per dispatch in
-    BOTH modes, tok/s present (the speculative_decode row discipline)."""
-    from tpulab.engine.paged import benchmark_sharded_decode
-
-    row = benchmark_sharded_decode(model_shards=2, lanes=2, steps=16,
-                                   prompt_len=6, d_model=32, n_heads=2,
-                                   n_layers=2, vocab=64)
-    assert row["parity"] is True
-    assert row["sampled_parity"] is True
-    assert row["one_sync_per_dispatch"] is True
-    assert row["single"]["tok_s"] > 0 and row["sharded"]["tok_s"] > 0
-    assert row["mesh"] == {"model": 2}
 
 
 def test_sharded_prefix_cache_and_chunked_prefill_parity(lm, dense):

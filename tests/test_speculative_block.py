@@ -31,7 +31,7 @@ from tpulab.models.transformer import (early_exit_draft,
 def lm():
     p = init_transformer_params(vocab=64, d_model=32, n_heads=2,
                                 n_layers=2, d_ff=64)
-    # trained-model emulation (benchmark_speculative's tail_scale): shrink
+    # trained-model emulation: shrink
     # the post-exit layer's output projections so the 1-layer early-exit
     # draft actually agrees with the target — raw random tails pin
     # acceptance to ~0 and the early-exit tests would measure nothing
@@ -457,24 +457,6 @@ def test_spec_admission_cost_factor(lm):
     finally:
         cb_spec.shutdown()
         cb_plain.shutdown()
-
-
-@pytest.mark.slow
-def test_benchmark_speculative_decode_row(lm):
-    """The bench ``speculative_decode`` row on the CPU capture path:
-    greedy parity recorded, nonzero acceptance, both modes' tok/s and
-    tokens-per-dispatch present (the decode_dispatch row discipline)."""
-    from tpulab.engine.paged import benchmark_speculative_decode
-
-    row = benchmark_speculative_decode(k=4, lanes=2, steps=12,
-                                       prompt_len=6, d_model=32,
-                                       n_heads=2, n_layers=2,
-                                       draft_layers=1, vocab=64)
-    assert row["parity"] is True
-    assert 0.0 < row["spec"]["acceptance"] <= 1.0
-    assert row["spec"]["tok_s"] > 0 and row["plain"]["tok_s"] > 0
-    assert row["spec"]["tokens_per_dispatch"] > 0
-    assert row["spec"]["drafted"] >= row["spec"]["accepted"] > 0
 
 
 # -- transient-degrade probes (re-enable speculation within a request) -----

@@ -278,7 +278,7 @@ def test_compiled_model_weights_are_tracked():
 
 def test_paged_pool_hbm_tracked_and_closed():
     import jax.numpy as jnp
-    from tpulab.engine.paged import PagedKVPool
+    from tpulab.engine.kv_pool import PagedKVPool
 
     pool = PagedKVPool(n_pages=4, page_size=8, n_layers=2, n_heads=2,
                        head_dim=4, dtype=jnp.float32)

@@ -252,7 +252,7 @@ def _pallas_names(jaxpr):
 
 @pytest.mark.parametrize("kernel", [
     "ragged_paged_attention", "flash_attention_fwd",
-    "paged_decode_attention"])
+    "ragged_latent_attention"])
 def test_pallas_kernels_carry_names(kernel):
     """``name=`` on the three pallas_calls (interpret mode here): the
     name a device trace shows the kernel under."""
@@ -265,10 +265,13 @@ def test_pallas_kernels_carry_names(kernel):
         from tpulab.ops.flash_attention import flash_attention
         fn, q = (lambda q: flash_attention(q, q, q, causal=True)), \
             jnp.ones((1, 128, 2, 32))
-    elif kernel == "paged_decode_attention":
-        from tpulab.ops.paged_attention import paged_decode_attention
-        fn, q = (lambda q: paged_decode_attention(q, kv, tables, lens)), \
-            jnp.ones((2, 2, 32))
+    elif kernel == "ragged_latent_attention":
+        from tpulab.ops.ragged_attention import ragged_latent_attention
+        fn, q = (lambda q: ragged_latent_attention(
+            q, kv.reshape(1, 5, 1, 8, 4 * 32), 0, tables,
+            jnp.array([1, 2], jnp.int32), lens, v_width=64,
+            sm_scale=0.125)), \
+            jnp.ones((2, 2, 2, 96))
     else:
         from tpulab.ops.ragged_attention import ragged_paged_attention
         fn, q = (lambda q: ragged_paged_attention(

@@ -25,9 +25,7 @@ headroom — so the lane is composition:
   admission frontend keeps them strictly below any online priority.
 """
 
-from tpulab.batch.bench import benchmark_batch_soak  # noqa: F401
 from tpulab.batch.job import BatchJob, JSONLResultSink  # noqa: F401
 from tpulab.batch.scheduler import BatchScheduler  # noqa: F401
 
-__all__ = ["BatchJob", "JSONLResultSink", "BatchScheduler",
-           "benchmark_batch_soak"]
+__all__ = ["BatchJob", "JSONLResultSink", "BatchScheduler"]

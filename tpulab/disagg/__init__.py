@@ -18,9 +18,6 @@ prefill dispatches on the decode side, bit-identical tokens.
   replica (write-behind fence included), import + geometry validation on
   the decode replica.  ``disagg.ship`` chaos point on both sides; every
   failure degrades to local prefill on the decode replica.
-- :func:`~tpulab.disagg.bench.benchmark_disagg` — the ``bench.py
-  disagg`` row: ITL p99 + goodput, disaggregated vs unified, under a
-  prefill-heavy trace.
 
 Serving wire-up: ``mgr.serve(role="prefill"|"decode"|"unified", ...)``
 reports the role over the Status RPC;
@@ -29,12 +26,10 @@ prefill replicas and hands the shipment to a decode replica picked by
 the existing admission load gauges.
 """
 
-from tpulab.disagg.bench import benchmark_disagg  # noqa: F401
 from tpulab.disagg.shipper import KVShipper, ShippedKV  # noqa: F401
 from tpulab.disagg.wire import (WireFormatError,  # noqa: F401
                                 deserialize_snapshot, prompt_digest,
                                 serialize_snapshot)
 
 __all__ = ["KVShipper", "ShippedKV", "WireFormatError",
-           "serialize_snapshot", "deserialize_snapshot", "prompt_digest",
-           "benchmark_disagg"]
+           "serialize_snapshot", "deserialize_snapshot", "prompt_digest"]

@@ -1,0 +1,882 @@
+"""The paged step programs: pure functions of (params, page pool, block
+tables, tokens) that the scheduler jits.
+
+TPU-first mechanics:
+- the page pool is donated through the jitted step, so XLA updates K/V in
+  place (no per-token pool copies);
+- a step has a *static* shape (fixed lane count B, fixed max pages per
+  sequence) — one compiled program regardless of which sessions occupy the
+  lanes; inactive lanes are masked, not recompiled;
+- attention either gathers pages via the block table (pool[tables] ->
+  (B, MP*S, ...), the XLA fallback) or walks them in the pallas ragged
+  paged-attention kernel family (tpulab.ops.ragged_attention: per-lane
+  (query_len, kv_len) segments serve decode, K+1 verify, and mixed
+  chunked-prefill+decode rounds in one program, KV-heads-sharded under
+  a mesh — docs/PERFORMANCE.md "Ragged paged attention");
+- decode runs K ticks per dispatch (:func:`paged_decode_block`: lax.scan over
+  the step, on-device sampling + stop masks), so the host pays one dispatch
+  and ONE blocking fetch per K tokens — off-chip the per-token cost is the
+  host<->device RTT, and K amortizes it (docs/PERFORMANCE.md).
+
+Every forward runs ONE layer block (:func:`_layer_block`) read from a
+:class:`~tpulab.models.spec.ModelSpec`.  The functions keep their
+``__name__``: a trace names a program ``jit_<name>``, and the benchmark's
+readers key on it.  Nothing here imports the scheduler
+(:mod:`tpulab.engine.paged`).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional
+
+import numpy as np
+
+from tpulab.engine.kv_pool import kv_rows_view
+
+
+def _scatter_kv(kv_pool, layer, page_idx, slot_idx, knew, vnew):
+    """Write new K/V ``(..., Hkv, D)`` at ``(page_idx, slot_idx)`` (both
+    shaped ``(...)``) of ``layer``, as rows of the page store: a reshape
+    of the new rows, never of the pool.  Callers route what must not land
+    to the reserved scratch page 0."""
+    knew = kv_rows_view(knew.astype(kv_pool.dtype))
+    vnew = kv_rows_view(vnew.astype(kv_pool.dtype))
+    kv_pool = kv_pool.at[layer, page_idx, 0, slot_idx].set(knew)
+    return kv_pool.at[layer, page_idx, 1, slot_idx].set(vnew)
+
+
+def _gather_attend(q, k_layer, v_layer, tables, qpos, compute_dtype):
+    """Dense-gather paged attention (the XLA fallback math, single source
+    of truth for decode ticks and extend/chunked prefill).
+
+    q (B, M, H, D) query tokens; k_layer/v_layer (P, S, Hkv*D) one
+    layer's K and V rows (XLA fuses the slice of the pool into the
+    gather); tables (B, MP) page ids; qpos (B, M) global position
+    of each query token (visibility: context j attends iff j <= qpos).
+    Returns (B, M, H*D).
+    """
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import repeat_kv
+
+    b, m, h, d = q.shape
+    mp = tables.shape[1]
+    page_size = k_layer.shape[1]
+    k_ctx = repeat_kv(k_layer[tables].reshape(b, mp * page_size, -1, d), h)
+    v_ctx = repeat_kv(v_layer[tables].reshape(b, mp * page_size, -1, d), h)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                        k_ctx.astype(jnp.float32)) / np.sqrt(d)
+    j = jnp.arange(mp * page_size)
+    mask = j[None, None, :] <= qpos[:, :, None]          # (B, M, K)
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(compute_dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs,
+                      v_ctx.astype(compute_dtype)).reshape(b, m, h * d)
+
+
+def _scatter_latent(kv_pool, layer, page_idx, slot_idx, rows):
+    """Write latent rows ``(..., W)`` at ``(page_idx, slot_idx)`` of
+    ``layer`` of a latent page store, zero-padded to the page row."""
+    import jax.numpy as jnp
+    pad = [(0, 0)] * (rows.ndim - 1) + [(0, kv_pool.shape[4] - rows.shape[-1])]
+    return kv_pool.at[layer, page_idx, 0, slot_idx].set(
+        jnp.pad(rows.astype(kv_pool.dtype), pad))
+
+
+def _gather_attend_latent(q, c_layer, tables, qpos, v_width, sm_scale,
+                          compute_dtype):
+    """:func:`_gather_attend` for latent pages (absorbed MLA): q (B, M, H,
+    W) against one shared key row a position, c_layer (P, S, row >= W);
+    the value is the first ``v_width`` columns of the same rows.  Returns
+    (B, M, H, v_width)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, mp = tables.shape
+    page_size = c_layer.shape[1]
+    ctx = c_layer[tables].reshape(b, mp * page_size, -1)
+    scores = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32),
+                        ctx[..., :q.shape[-1]].astype(jnp.float32)) * sm_scale
+    j = jnp.arange(mp * page_size)
+    mask = j[None, None, :] <= qpos[:, :, None]          # (B, M, K)
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(compute_dtype)
+    return jnp.einsum("bhqk,bkc->bqhc", probs,
+                      ctx[..., :v_width].astype(compute_dtype))
+
+
+def _step_spec(spec, d_model: int, n_heads: int, n_layers: int, n_kv_heads,
+               rope_theta):
+    """The spec a step function runs: the caller's, or the dense decoder's
+    from the arguments the step functions always took."""
+    from tpulab.models.spec import dense_spec
+    return spec or dense_spec(d_model, n_heads, n_layers, n_kv_heads,
+                              rope_theta)
+
+
+def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
+                   compute_dtype):
+    """Multi-head latent attention of one layer in the absorbed form, on a
+    latent page store: ``(attn (B, M, H * v_head_dim), kv_pool)``.  The
+    row ``[c_kv ; k_rope]`` (after norm and RoPE) is scattered once; the
+    key up-projection moves into the query, the value up-projection
+    behind the weighted latent sum.  In a packed round (``seg["rows"]``,
+    see :func:`_layer_block`) ``h`` is ``(1, T, D)``: only the absorbed
+    query is spread to ``(B, M)`` for the walk over the pages, and the
+    weighted latent sum is gathered back to rows before ``w_uv``."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _rmsnorm, apply_rope, qmat
+
+    with jax.named_scope("mla_attention"):
+        eps = spec.rms_eps
+        b, m = h.shape[:2]
+        nope, rope = spec.qk_nope_head_dim, spec.qk_rope_head_dim
+        scale = 1.0 / np.sqrt(spec.qk_head_dim)
+        cq = _rmsnorm(h @ qmat(p["wq_a"], compute_dtype),
+                      p["q_norm"]["scale"], eps)
+        q = (cq @ qmat(p["wq_b"], compute_dtype)).reshape(
+            b, m, spec.n_heads, nope + rope)
+        kva = h @ qmat(p["wkv_a"], compute_dtype)
+        ckv = _rmsnorm(kva[..., :spec.kv_lora_rank], p["kv_norm"]["scale"],
+                       eps)
+        kr = apply_rope(kva[..., None, spec.kv_lora_rank:], pos,
+                        spec.rope_theta)[..., 0, :]
+        qr = apply_rope(q[..., nope:], pos, spec.rope_theta)
+        rows = jnp.concatenate([ckv, kr], axis=-1)           # (B, M, W)
+        kv_pool = _scatter_latent(
+            kv_pool, layer, page_idx, slot_idx,
+            rows.reshape(page_idx.shape + rows.shape[-1:]))
+        qa = jnp.concatenate(
+            [jnp.einsum("bmhn,hnc->bmhc", q[..., :nope],
+                        qmat(p["w_uk"], compute_dtype)), qr], axis=-1)
+        packed = seg.get("rows")
+        if packed is not None:
+            spread, back, pos = packed
+            qa = jnp.take(qa.reshape(qa.shape[1:]), spread, axis=0,
+                          mode="clip").reshape(pos.shape + qa.shape[2:])
+        if seg["use_kernel"]:
+            from tpulab.ops.ragged_attention import ragged_latent_attention
+            lat = ragged_latent_attention(
+                qa, kv_pool, layer, seg["tables"], seg["q_lens"],
+                seg["kv_lens"], v_width=spec.kv_lora_rank, sm_scale=scale)
+        else:
+            lat = _gather_attend_latent(
+                qa, kv_pool[layer, :, 0], seg["tables"], pos,
+                spec.kv_lora_rank, scale, compute_dtype)
+        if packed is not None:                 # (B, M, H, C) -> (1, T, H, C)
+            lat = jnp.take(lat.reshape((-1,) + lat.shape[2:]), back, axis=0,
+                           mode="clip")[None]
+        attn = jnp.einsum("bmhc,hcv->bmhv", lat.astype(compute_dtype),
+                          qmat(p["w_uv"], compute_dtype))
+        return attn.reshape(b, m, -1), kv_pool
+
+
+def _ffn_block(spec, p, layer, x, valid, compute_dtype):
+    """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
+    experts plus the shared expert.  Returns ``(x, stats)``, ``stats``
+    the expert layer's ``(E + 2,)`` counters or None."""
+    import jax
+    from tpulab.models.transformer import _dense_ffn, _rmsnorm
+
+    h = _rmsnorm(x, p["ln2"]["scale"], spec.rms_eps)
+    if spec.layer_kinds[layer] != "moe":
+        return x + _dense_ffn(p, h, compute_dtype).astype(x.dtype), None
+    from tpulab.parallel.moe import routed_ffn
+    b, m = x.shape[:2]
+    y, stats = routed_ffn(p["moe"], h.reshape(b * m, -1), spec.top_k,
+                          compute_dtype, router="sigmoid_bias", act="swiglu",
+                          scale=spec.routed_scale, norm=spec.norm_topk,
+                          valid=valid.reshape(-1))
+    with jax.named_scope("moe_shared"):
+        shared = _dense_ffn(p["shared"], h, compute_dtype)
+    return x + (y.reshape(b, m, -1) + shared).astype(x.dtype), stats
+
+
+def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
+                 seg, compute_dtype):
+    """ONE decoder layer over paged state, for every model and every step
+    function: norm, projections (+RoPE), the new rows scattered into the
+    lane's pages, attention over the block table (gather-after-scatter,
+    global causality), output projection, norm, FFN; residuals around both
+    halves.
+
+    x (B, M, D) at positions ``pos`` (B, M); ``page_idx``/``slot_idx`` are
+    the write targets, shaped (B, M) — or (B,) in a decode step, whose one
+    row a lane is then written without the M axis; rows that must not land
+    go to scratch page 0.  ``seg`` is the dispatch's segment description,
+    the same for every layer: ``tables`` (B, MP), ``q_lens``/``kv_lens``
+    (B,), and the attention path (``use_kernel``: the Pallas ragged kernel
+    of the cache-entry kind, else the XLA gather; ``kernel_geometry``,
+    ``mesh``).  ``valid`` (B, M) bool masks the expert counters only.
+
+    Three forms, told apart by what ``seg`` carries.  A decode step is
+    (B, 1).  The padded form is (B, M), lane b's segment left-packed in
+    row b (K+1 verify, where every lane's segment has one length).  A
+    packed round (:func:`paged_mixed_step`) carries ``seg["rows"]``: x is
+    (1, T, D), one row a token of the round, and everything but the walk
+    over the pages runs on those T rows; ``rows = (spread (B * M,), back
+    (T,), qpos (B, M))`` holds the row behind each slot of the (B, M)
+    form the attention takes and the slot behind each row: the query rows
+    are spread by one row gather and the attention's output gathered back
+    by another, two copies of at most lanes x M rows a layer, where the
+    padded form ran every product on lanes x M rows.  (``jnp.take``, not
+    ``x[idx]``: it is jitted, so sixteen layers trace it once.)
+    Returns ``(x, kv_pool, stats)``: ``stats`` is the expert layer's
+    ``(E + 2,)`` int32 counters
+    (:func:`tpulab.parallel.moe.routing_stats`) or None on a dense layer.
+
+    Kept short, the K/V kernel called from here and the rest in functions
+    of their own: on the v5e host, tracing a kernel body costs more with
+    every Python frame between the step function and the ``pallas_call``
+    (PR 28, my chip runs: a kernel's trace took 0.63 s a program with the
+    parent's frames, 0.87-0.97 s behind one more, 1.42 s behind four more
+    and a helper inside the kernel; the dense cell's set-up grew 10 %,
+    96 -> 106 s, until the count was the parent's again).
+    """
+    import jax.numpy as jnp
+    from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
+                                           split_qkv)
+
+    h = _rmsnorm(x, p["ln1"]["scale"], spec.rms_eps)
+    if spec.attention == "mla":
+        attn, kv_pool = _mla_attention(spec, p, layer, h, pos, kv_pool,
+                                       page_idx, slot_idx, seg,
+                                       compute_dtype)
+    else:
+        b, m = x.shape[:2]
+        q, knew, vnew = split_qkv(h @ qmat(p["wqkv"], compute_dtype), b, m,
+                                  spec.n_heads, spec.n_kv_heads,
+                                  spec.head_dim)
+        if spec.rope_theta:
+            q = apply_rope(q, pos, spec.rope_theta)
+            knew = apply_rope(knew, pos, spec.rope_theta)
+        tail = knew.shape[2:]
+        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
+                              knew.reshape(page_idx.shape + tail),
+                              vnew.reshape(page_idx.shape + tail))
+        packed = seg.get("rows")
+        if packed is not None:
+            spread, back, pos = packed
+            b, m = pos.shape
+            q = jnp.take(q.reshape(q.shape[1:]), spread, axis=0,
+                         mode="clip").reshape((b, m) + q.shape[2:])
+        if seg["use_kernel"]:
+            # pallas ragged kernel: walks block tables page-by-page, no
+            # dense gather materialization; fused pages = 1 DMA/page;
+            # under a mesh the walk shards on the KV-heads dim via
+            # shard_map (tpulab.ops.ragged_attention)
+            from tpulab.ops import ragged_attention as ra
+            gk, nk = seg["kernel_geometry"] or (None, None)
+            if seg["mesh"] is None:
+                # the jitted entry itself, not ``ragged_paged_attention``
+                # around it: one Python frame fewer above the kernel
+                # (the docstring says what a frame costs)
+                from tpulab.tpu.platform import pallas_interpret
+                attn = ra._ragged_attn(
+                    q, kv_pool, jnp.asarray(layer, jnp.int32).reshape(1),
+                    seg["tables"], seg["q_lens"], seg["kv_lens"],
+                    pallas_interpret(), g_pages=gk, nbuf=nk)
+            else:
+                attn = ra.ragged_paged_attention(
+                    q, kv_pool, layer, seg["tables"], seg["q_lens"],
+                    seg["kv_lens"], mesh=seg["mesh"], g_pages=gk, nbuf=nk)
+            attn = attn.astype(compute_dtype).reshape(b, m, -1)
+        else:
+            # XLA fallback: gather pages densely then mask
+            attn = _gather_attend(q, kv_pool[layer, :, 0],
+                                  kv_pool[layer, :, 1], seg["tables"], pos,
+                                  compute_dtype)
+        if packed is not None:                     # (B, M, H*D) -> (1, T, H*D)
+            attn = jnp.take(attn.reshape(b * m, -1), back, axis=0,
+                            mode="clip")[None]
+    x, stats = _ffn_block(spec, p, layer,
+                          x + attn @ qmat(p["wo"], compute_dtype), valid,
+                          compute_dtype)
+    return x, kv_pool, stats
+
+
+def paged_decode_step(params, kv_pool, tables, lengths, tokens,
+                      active, n_heads: int, n_layers: int,
+                      compute_dtype, use_kernel: bool = False,
+                      n_kv_heads: Optional[int] = None,
+                      rope_theta: Optional[float] = None,
+                      temps=None, seeds=None,
+                      kernel_geometry: Optional[tuple] = None,
+                      mesh=None, spec=None):
+    """One batched decode tick over the paged pool.
+
+    Shapes: kv_pool (L, P, 2, S, Hkv*D) fused page store (axis 2 = K/V,
+    :func:`kv_page_shape`),
+    tables (B, MP) int32 page ids (padded rows repeat page 0),
+    lengths (B,) current position per lane, tokens (B,), active (B,) bool.
+    Returns (logits (B, vocab), kv_pool) — the pool donated by the caller.
+    Under GQA (``n_kv_heads < n_heads``) the pool holds ``n_kv_heads``
+    heads per slot.
+
+    With ``temps (B,) f32`` + ``seeds (B, 2) uint32`` the return becomes
+    (next_tokens (B,) i32, logprobs (B,) f32, logits, kv_pool): lanes
+    with temp > 0 are Gumbel-max temperature-sampled ON DEVICE with a key
+    folded from (seed, position) — batch-composition- and
+    preemption-invariant — and temp == 0 lanes take the argmax;
+    ``logprobs`` is each lane's chosen-token log-probability
+    (log-softmax at the chosen id).  Callers then fetch only (B,)-sized
+    arrays (no per-tick (B, vocab) logits transfer).
+    """
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _lm_head, _rmsnorm
+
+    b = tokens.shape[0]
+    page_size = kv_pool.shape[3]
+    emb = params["embed"].astype(compute_dtype)
+    x = emb[tokens][:, None, :]
+    spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
+    # write target per lane: page id + slot for position `lengths`;
+    # inactive/padded lanes are routed to the RESERVED scratch page 0 so
+    # they can never clobber a live lane's pages
+    page_idx = tables[jnp.arange(b), lengths // page_size]      # (B,)
+    safe_page = jnp.where(active, page_idx, 0)
+    safe_slot = jnp.where(active, lengths % page_size, 0)
+    # the ragged kernel at the q=1 decode shape; per-lane positions: each
+    # lane decodes at its own length
+    pos = lengths[:, None]
+    seg = dict(tables=tables, q_lens=jnp.ones_like(lengths),
+               kv_lens=lengths + 1, use_kernel=use_kernel,
+               kernel_geometry=kernel_geometry, mesh=mesh)
+    moe_stats = []
+    for layer in range(spec.n_layers):
+        x, kv_pool, stats = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos, active[:, None],
+            kv_pool, safe_page, safe_slot, seg, compute_dtype)
+        if stats is not None:
+            moe_stats.append(stats)
+
+    x = _rmsnorm(x, params["final_norm"]["scale"], spec.rms_eps)
+    logits = _lm_head(params, x[:, 0])
+    # inactive lanes emit neutral logits (argmax 0) — callers mask on active
+    logits = jnp.where(active[:, None], logits, 0.0)
+    # an expert model's counters ride behind the pool (one small array)
+    moe = (jnp.stack(moe_stats),) if moe_stats else ()
+    if temps is None:
+        return (logits, kv_pool) + moe
+    import jax
+    next_tokens = jax.vmap(_device_sample_token)(
+        logits, temps, seeds.astype(jnp.uint32), lengths)
+    logp_rows = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    logprobs = jnp.take_along_axis(logp_rows, next_tokens[:, None],
+                                   axis=-1)[:, 0]
+    return (next_tokens, logprobs, logits, kv_pool) + moe
+
+
+def paged_decode_step_sampled(params, kv_pool, tables, lengths, tokens,
+                              active, temps, seeds, **kw):
+    """Positional-signature variant of :func:`paged_decode_step` with
+    device sampling armed — sharded jits need every array argument
+    positional so explicit ``in_shardings`` can be attached."""
+    return paged_decode_step(params, kv_pool, tables, lengths, tokens,
+                             active, temps=temps, seeds=seeds, **kw)
+
+
+def paged_decode_block(params, kv_pool, tables, lengths, tokens, active,
+                       temps, seeds, steps_rem, stop_ids,
+                       n_heads: int, n_layers: int, compute_dtype,
+                       k: int = 8, use_kernel: bool = False,
+                       n_kv_heads: Optional[int] = None,
+                       rope_theta: Optional[float] = None,
+                       kernel_geometry: Optional[tuple] = None,
+                       mesh=None, spec=None):
+    """K fused decode ticks in ONE dispatch: ``lax.scan`` over
+    :func:`paged_decode_step`, sampling every step on device.
+
+    The per-token serving cost off-chip is dominated by the host<->device
+    round trip (dispatch + blocking fetch), not the decode math — chaining
+    K steps inside one compiled program amortizes that RTT over K tokens
+    (the host then syncs once per K tokens instead of once per token, the
+    fused multi-token decode shape of TPU-native serving stacks).
+
+    Per-lane device-side stop mask: a lane is *live* while it is active,
+    has steps remaining, and has not emitted a stop token.  ``steps_rem
+    (B,) i32`` counts tokens still wanted per lane; ``stop_ids (B, S)
+    i32`` holds each lane's stop-token ids padded with -1 (token ids are
+    always >= 0, so the pad never matches).  A stop token IS emitted as
+    the lane's final token (matching the host-side contract), then the
+    lane goes dead for the rest of the block: its K/V writes route to the
+    reserved scratch page and its position stops advancing — which also
+    keeps the (seed, position)-folded device-sampling stream identical to
+    a K=1 run.
+
+    The CALLER pre-allocates pages: step j writes K/V at ``lengths + j``
+    for live lanes, so ``tables`` must already cover every position the
+    block can reach.
+
+    Returns ``(tokens (B, K) i32, logprobs (B, K) f32, emitted (B, K)
+    bool, lengths (B,), last_tokens (B,), live (B,), steps_rem (B,),
+    kv_pool)`` — and, for a ``spec`` with expert layers, their counters
+    ``(n_moe, E + 2)`` summed over the K steps as one more element;
+    ``lengths`` .. ``steps_rem`` and the pool are the carried state
+    *after* the block, returned as device arrays so a follow-up block can be
+    dispatched without a host round trip (dispatch-ahead overlap).
+    ``emitted[b]`` is a prefix mask: lane b's valid tokens are
+    ``tokens[b, :emitted[b].sum()]``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    def body(carry, _):
+        kv, lens, toks, live, rem = carry
+        nt, lp, _logits, kv, *moe = paged_decode_step(
+            params, kv, tables, lens, toks, live,
+            n_heads=n_heads, n_layers=n_layers,
+            compute_dtype=compute_dtype, use_kernel=use_kernel,
+            n_kv_heads=n_kv_heads, rope_theta=rope_theta,
+            temps=temps, seeds=seeds, kernel_geometry=kernel_geometry,
+            mesh=mesh, spec=spec)
+        emitted = live
+        nt = jnp.where(live, nt, toks)           # dead lanes hold position
+        lens = lens + emitted.astype(jnp.int32)
+        rem = rem - emitted.astype(jnp.int32)
+        hit_stop = (nt[:, None] == stop_ids).any(axis=1)
+        live = live & (rem > 0) & ~hit_stop
+        return (kv, lens, nt, live, rem), (nt, lp, emitted, *moe)
+
+    init = (kv_pool, lengths, tokens, active, steps_rem)
+    (kv_pool, lengths, tokens, live, steps_rem), (toks, lps, ems, *moe) = \
+        jax.lax.scan(body, init, None, length=k)
+    # an expert model's counters, summed over the block's steps
+    return (toks.T, lps.T, ems.T, lengths, tokens, live, steps_rem,
+            kv_pool) + tuple(m.sum(axis=0) for m in moe)
+
+
+def _device_sample_token(row, temp, seed2, pos):
+    """Gumbel-max temperature sample of one lane: key folded from the full
+    64-bit seed (lo, hi words) and the token position — the SINGLE
+    definition of the device-sampling stream (the decode step vmaps it;
+    the prefill first-token pick replays it on the fetched logits row so
+    one request is one stream end to end)."""
+    import jax
+    import jax.numpy as jnp
+    key = jax.random.fold_in(
+        jax.random.fold_in(
+            jax.random.fold_in(jax.random.PRNGKey(0), seed2[0]), seed2[1]),
+        pos)
+    g = jax.random.gumbel(key, row.shape, jnp.float32)
+    safe_t = jnp.where(temp > 0, temp, 1.0)
+    sampled = jnp.argmax(row / safe_t + g)
+    return jnp.where(temp > 0, sampled, jnp.argmax(row)).astype(jnp.int32)
+
+
+def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
+                         n_heads: int, n_layers: int, compute_dtype,
+                         use_kernel: bool = False,
+                         n_kv_heads: Optional[int] = None,
+                         rope_theta: Optional[float] = None,
+                         mesh=None,
+                         kernel_geometry: Optional[tuple] = None,
+                         last_only: bool = False, spec=None):
+    """One fused multi-token forward over ragged per-lane segments in the
+    PADDED form, every product on ``B x M`` rows (ROADMAP item 2, "Ragged
+    Paged Attention" in PAPERS.md).  The K+1 speculative verify runs it
+    (every lane's segment has one length there, so the padding is dense);
+    a mixed round runs the same segments packed by token
+    (:func:`paged_mixed_step`) and is tested against this form.
+
+    ``seq (B, M)`` int32, left-packed: lane b's valid tokens are
+    ``seq[b, :q_lens[b]]``, token j at global position
+    ``kv_lens[b] - q_lens[b] + j``.  Per layer all valid positions' K/V
+    scatter into the lane's pages first (invalid positions route to the
+    reserved scratch page 0), then attention gathers the lane's whole
+    block table masked by global causality — the gather-after-scatter
+    shape of :func:`paged_extend`, batched over ragged lanes.  One
+    static ``M`` serves every segment mix: plain decode (``q_lens=1``),
+    K+1 speculative verify (``q_lens=k+1``), chunked prefill
+    (``q_lens=chunk``) and any combination in one batch.
+
+    ``use_kernel`` selects the pallas ragged kernel
+    (:func:`tpulab.ops.ragged_attention.ragged_paged_attention`; under a
+    ``mesh`` it shards on the KV-heads dim via shard_map) over the XLA
+    dense-gather fallback.  ``last_only=True`` runs the vocab head over
+    each lane's LAST valid position only and returns ``(logits (B,
+    vocab), kv_pool)``; otherwise ``(logits (B, M, vocab), kv_pool)``
+    with invalid positions' logits garbage the caller must not consume.
+    The fused pool is donated by the caller either way.  A ``spec`` with
+    expert layers appends their counters ``(n_moe, E + 2)`` (valid
+    positions only) as a third element.
+    """
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _lm_head, _rmsnorm
+
+    b, m = seq.shape
+    page_size = kv_pool.shape[3]
+    emb = params["embed"].astype(compute_dtype)
+    x = emb[seq]                                      # (B, M, D)
+    spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
+    valid = jnp.arange(m)[None, :] < q_lens[:, None]  # (B, M)
+    pos = (kv_lens - q_lens)[:, None] + jnp.arange(m)[None, :]
+    # invalid positions' page index may run past the table width — XLA
+    # clamps the gather, and the mask below discards the clamped id
+    page_idx = jnp.where(valid,
+                         jnp.take_along_axis(
+                             tables,
+                             jnp.clip(pos // page_size, 0,
+                                      tables.shape[1] - 1), axis=1), 0)
+    slot_idx = jnp.where(valid, pos % page_size, 0)
+    # gather-after-scatter: token m sees cached context + the segment's
+    # own writes up to its position (global causality); one program for
+    # every segment mix
+    seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+               use_kernel=use_kernel, kernel_geometry=kernel_geometry,
+               mesh=mesh)
+    moe_stats = []
+    for layer in range(spec.n_layers):
+        x, kv_pool, stats = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos, valid, kv_pool,
+            page_idx, slot_idx, seg, compute_dtype)
+        if stats is not None:
+            moe_stats.append(stats)
+    moe = (jnp.stack(moe_stats),) if moe_stats else ()
+
+    if last_only:
+        # only each lane's last valid token seeds a pick — run the
+        # vocab-sized head over ONE row per lane (paged_extend's trick,
+        # batched)
+        x = jnp.take_along_axis(
+            x, jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)[:, 0]
+    x = _rmsnorm(x, params["final_norm"]["scale"], spec.rms_eps)
+    return (_lm_head(params, x), kv_pool) + moe
+
+
+def round_width(prefill_tokens: int) -> int:
+    """``M`` of the mixed round that carries ``prefill_tokens`` prompt
+    tokens: the pow2 bucket its program is keyed by (few jits) and the
+    segment width its attention is called at."""
+    return 1 << (prefill_tokens - 1).bit_length()
+
+
+def pack_round(lanes: int, prefill: Dict[int, Any], decode: Dict[int, int]):
+    """Host half of :func:`paged_mixed_step`'s input: a round packed by
+    token.  ``prefill`` maps a lane to its chunk's tokens (at least one
+    token in all; packed in the mapping's order), ``decode`` a lane to its
+    current token.  Returns numpy ``(toks (T,), row_lane (T,), row_off
+    (T,), q_lens (lanes,))`` with ``T = round_width(prefill tokens) +
+    lanes``."""
+    m = round_width(sum(len(chunk) for chunk in prefill.values()))
+    toks = np.zeros((m + lanes,), np.int32)
+    row_lane = np.full((m + lanes,), -1, np.int32)
+    row_off = np.zeros((m + lanes,), np.int32)
+    q_lens = np.zeros((lanes,), np.int32)
+    row = 0
+    for lane, chunk in prefill.items():
+        rows = slice(row, row + len(chunk))
+        toks[rows], row_lane[rows] = chunk, lane
+        row_off[rows] = np.arange(len(chunk))
+        q_lens[lane] = len(chunk)
+        row = rows.stop
+    for lane, tok in decode.items():
+        toks[m + lane], row_lane[m + lane], q_lens[lane] = tok, lane, 1
+    return toks, row_lane, row_off, q_lens
+
+
+def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
+                     q_lens, kv_lens, temps, seeds, n_heads: int,
+                     n_layers: int, compute_dtype, use_kernel: bool = False,
+                     n_kv_heads: Optional[int] = None,
+                     rope_theta: Optional[float] = None,
+                     mesh=None,
+                     kernel_geometry: Optional[tuple] = None, spec=None):
+    """One mixed prefill+decode round, packed by token: a ragged forward
+    over per-lane segments plus each lane's next-token pick, in ONE
+    dispatch whose rows are the round's tokens.
+
+    Prefilling lanes carry a prompt chunk (``q_lens = chunk``), decoding
+    lanes their current token (``q_lens = 1``), idle lanes nothing
+    (``q_lens = 0``).  ``toks (T,)`` holds the round with ``T = M +
+    lanes``: rows ``[0, M)`` are the prefilling lanes' chunk tokens one
+    lane after the other, row ``M + b`` is lane b's decode token.
+    ``row_lane (T,)`` is each row's lane (-1: the row holds no token) and
+    ``row_off (T,)`` its offset in the lane's segment: token ``(b, j)``
+    sits at global position ``kv_lens[b] - q_lens[b] + j``.  Embedding,
+    norms, projections, RoPE, the row scatter into the lane's pages,
+    ``wo``, the FFN or the routed experts and their counters run on the T
+    rows; only the attention call sees the ``(B, M)`` form of
+    :func:`paged_ragged_forward` (:func:`_layer_block`), so a round costs
+    what its tokens cost, not lanes x the longest chunk.  ``M`` (from the
+    shapes, ``T - lanes``) is the ONE number the program is keyed by.
+
+    Every lane's pick is :func:`_device_sample_token` on its LAST valid
+    row's logits at position ``kv_lens - 1`` — exactly the decode tick's
+    stream for decode lanes and exactly the prefill first-token stream
+    (position ``t - 1``) for lanes finishing their prompt, so one request
+    is one (seed, position)-keyed stream regardless of which dispatch
+    kind served it.  The caller consumes picks only for lanes that emit
+    this round (a mid-prompt chunk's pick is discarded; device sampling
+    is stateless, so a discarded pick costs nothing).
+
+    Returns ``(next_tokens (B,) i32, logprobs (B,) f32, last_logits
+    (B, vocab), kv_pool)`` — ``last_logits`` stays device-resident
+    unless a host-sampled lane fetches its row — and the expert layers'
+    counters behind the pool where ``spec`` has any.  The same segments
+    through ``paged_ragged_forward(last_only=True)`` give the same
+    logits: that is the plain form this one is tested against.
+    """
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _lm_head, _rmsnorm
+
+    b, t = tables.shape[0], toks.shape[0]
+    m = t - b
+    page_size = kv_pool.shape[3]
+    emb = params["embed"].astype(compute_dtype)
+    x = emb[toks][None]                               # (1, T, D)
+    spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
+    valid = row_lane >= 0
+    lane = jnp.maximum(row_lane, 0)
+    start = kv_lens - q_lens                          # (B,) segment starts
+    pos = jnp.where(valid, start[lane] + row_off, 0)
+    page_idx = jnp.where(valid, tables[lane, pos // page_size], 0)
+    slot_idx = jnp.where(valid, pos % page_size, 0)
+    # the slot of the padded (B, M) form behind each row, and the row
+    # behind each slot; slots past a lane's segment read row 0, which the
+    # attention masks by q_lens
+    back = lane * m + row_off
+    spread = jnp.zeros((b * m,), jnp.int32).at[
+        jnp.where(valid, back, b * m)].set(
+            jnp.arange(t, dtype=jnp.int32), mode="drop")
+    qpos = start[:, None] + jnp.arange(m)[None, :]
+    seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+               use_kernel=use_kernel, kernel_geometry=kernel_geometry,
+               mesh=mesh, rows=(spread, back, qpos))
+    moe_stats = []
+    for layer in range(spec.n_layers):
+        x, kv_pool, stats = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos[None], valid[None],
+            kv_pool, page_idx[None], slot_idx[None], seg, compute_dtype)
+        if stats is not None:
+            moe_stats.append(stats)
+    moe = (jnp.stack(moe_stats),) if moe_stats else ()
+
+    # the vocab-sized head over ONE row a lane: its last valid token's
+    last_row = spread[jnp.arange(b) * m + jnp.maximum(q_lens - 1, 0)]
+    last = _lm_head(params, _rmsnorm(x[0][last_row],
+                                     params["final_norm"]["scale"],
+                                     spec.rms_eps))
+    pos_last = jnp.maximum(kv_lens - 1, 0)
+    next_tokens = jax.vmap(_device_sample_token)(
+        last, temps, seeds.astype(jnp.uint32), pos_last)
+    logp_rows = jax.nn.log_softmax(last.astype(jnp.float32), axis=-1)
+    logprobs = jnp.take_along_axis(logp_rows, next_tokens[:, None],
+                                   axis=-1)[:, 0]
+    return (next_tokens, logprobs, last, kv_pool, *moe)
+
+
+def paged_speculative_block(params, draft_params, kv_pool, tables,
+                            draft_tables, lengths, tokens, active, temps,
+                            seeds, steps_rem, stop_ids,
+                            n_heads: int, n_layers: int,
+                            draft_n_heads: int, draft_n_layers: int,
+                            compute_dtype, k: int = 4,
+                            n_kv_heads: Optional[int] = None,
+                            draft_n_kv_heads: Optional[int] = None,
+                            rope_theta: Optional[float] = None,
+                            use_kernel: bool = False, mesh=None,
+                            kernel_geometry: Optional[tuple] = None):
+    """Speculative decode: draft-propose + target-verify + per-lane
+    accept/reject, ALL inside one device dispatch.
+
+    A small draft model proposes ``k`` tokens per lane (a ``lax.scan``
+    of single-token draft steps through a SECOND page table on the same
+    fused pool), the target model verifies the current token plus all k
+    proposals in ONE batched forward (:func:`_paged_verify_forward`),
+    and acceptance runs on device: each lane emits the longest prefix of
+    proposals matching the target's own choices, plus the target's
+    correction (or bonus) token — so emitted tokens are EXACTLY the
+    non-speculative stream, and one dispatch emits up to ``k + 1``
+    tokens instead of ``k``.  The target's "choice" is
+    :func:`_device_sample_token` at each position — greedy argmax for
+    temp==0 lanes, and for device-sampled lanes the same
+    (seed, position)-folded stream plain blocks use, so token parity is
+    bit-exact in both modes.  The draft proposes through the SAME
+    sampling function on its own logits (a perfect draft then reaches
+    full acceptance under sampling too).
+
+    Stop-mask machinery matches :func:`paged_decode_block`: a stop token
+    is emitted as the lane's final token and truncates the emission; the
+    per-lane steps-remaining budget caps it, and writes past the budget
+    route to the scratch page (so a full-K block at the tail of a
+    request can never write past the positions its reservation covers).
+    Dead lanes emit nothing and write only scratch.  The draft scan runs
+    ``k + 1`` iterations (last proposal discarded) so a fully-accepted
+    round leaves no hole in the draft KV — the dense
+    :class:`~tpulab.engine.speculative.SpeculativeGenerator` trick.
+    Rejected proposals leave stale K/V past the accepted horizon in both
+    tables; positions only advance, so every stale slot is overwritten
+    before any later query may attend it.
+
+    The CALLER pre-allocates BOTH tables to cover positions
+    ``lengths .. lengths + k`` (see ``_reserve_spec_pages``).
+    ``use_kernel`` routes attention on BOTH models through the ragged
+    pallas kernel family (draft proposal steps at q=1, the verify
+    forward at q=k+1 — the PR 7 follow-up retired); the XLA gather is
+    the fallback, and under a ``mesh`` the kernel shards on KV heads.
+
+    Returns ``(tokens (B, k+1) i32, logprobs (B, k+1) f32, emitted
+    (B, k+1) bool prefix mask, lengths (B,), last_tokens (B,), live
+    (B,), steps_rem (B,), drafted (B,) i32, accepted (B,) i32,
+    kv_pool)``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    seeds = seeds.astype(jnp.uint32)
+
+    # 1) draft proposes k tokens per lane through the second page table;
+    #    iterations past a lane's step budget write only scratch (their
+    #    proposals can never be emitted)
+    def dbody(carry, i):
+        kv, tok = carry
+        nt, _lp, _lg, kv = paged_decode_step(
+            draft_params, kv, draft_tables, lengths + i, tok,
+            active & (i < steps_rem),
+            n_heads=draft_n_heads, n_layers=draft_n_layers,
+            compute_dtype=compute_dtype, use_kernel=use_kernel,
+            n_kv_heads=draft_n_kv_heads, rope_theta=rope_theta,
+            temps=temps, seeds=seeds, kernel_geometry=kernel_geometry,
+            mesh=mesh)
+        return (kv, nt), nt
+
+    (kv_pool, _), props = jax.lax.scan(dbody, (kv_pool, tokens),
+                                       jnp.arange(k + 1))
+    drafts = props[:k].T                               # (B, k)
+
+    # 2) target verifies [cur, d_0..d_{k-1}] in ONE batched ragged
+    #    forward (q_lens = the valid prefix per lane); position j's
+    #    write is real only while the lane can still emit token j
+    #    (emitted n <= steps_rem, and query j consumes writes 0..j only,
+    #    so masking j >= steps_rem discards nothing live)
+    seq = jnp.concatenate([tokens[:, None], drafts], axis=1)  # (B, k+1)
+    q_lens = jnp.where(active,
+                       jnp.minimum(k + 1, jnp.maximum(steps_rem, 0)), 0)
+    logits, kv_pool = paged_ragged_forward(
+        params, kv_pool, tables, seq, q_lens, lengths + q_lens,
+        n_heads=n_heads, n_layers=n_layers, compute_dtype=compute_dtype,
+        use_kernel=use_kernel, n_kv_heads=n_kv_heads,
+        rope_theta=rope_theta, mesh=mesh, kernel_geometry=kernel_geometry)
+
+    # 3) the target's own choice at every position — the same sampling
+    #    stream as plain blocks, so the output is bit-identical
+    pos = lengths[:, None] + jnp.arange(k + 1)[None, :]
+    cand = jax.vmap(jax.vmap(_device_sample_token,
+                             in_axes=(0, None, None, 0)))(
+        logits, temps, seeds, pos)                      # (B, k+1)
+    lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    lps = jnp.take_along_axis(lsm, cand[..., None], axis=-1)[..., 0]
+
+    # 4) accept/reject + stop-mask, on device: emit the agreeing prefix
+    #    + correction, truncated by stop tokens and steps remaining
+    agree = drafts == cand[:, :k]
+    acc = jnp.cumprod(agree.astype(jnp.int32), axis=1).sum(axis=1)  # (B,)
+    avail = acc + 1                     # accepted prefix + correction
+    hit = (cand[:, :, None] == stop_ids[:, None, :]).any(axis=2)
+    first_stop = jnp.argmax(hit, axis=1)
+    stop_cap = jnp.where(hit.any(axis=1), first_stop + 1, k + 1)
+    n = jnp.minimum(jnp.minimum(avail, stop_cap), steps_rem)
+    n = jnp.where(active, n, 0)
+    emitted = jnp.arange(k + 1)[None, :] < n[:, None]   # (B, k+1)
+    lengths = lengths + n
+    last = jnp.take_along_axis(cand, jnp.maximum(n - 1, 0)[:, None],
+                               axis=1)[:, 0]
+    tokens = jnp.where(n > 0, last, tokens).astype(jnp.int32)
+    steps_rem = steps_rem - n
+    stopped = hit.any(axis=1) & (stop_cap <= n)
+    live = active & (steps_rem > 0) & ~stopped
+    drafted = jnp.where(active, k, 0)
+    accepted = jnp.where(active, jnp.minimum(acc, n), 0)
+    return (cand.astype(jnp.int32), lps, emitted, lengths, tokens, live,
+            steps_rem, drafted, accepted, kv_pool)
+
+
+def paged_prefill(params, kv_pool, tables, tokens, valid_len,
+                  n_heads: int, n_layers: int, compute_dtype,
+                  n_kv_heads: Optional[int] = None,
+                  rope_theta: Optional[float] = None,
+                  attention_fn=None):
+    """Fused prefill: ONE causal forward over the (padded) prompt, with each
+    layer's K/V scattered straight into the lane's pages.
+
+    tokens (1, T_pad) int32 (padded tail arbitrary), valid_len scalar int32,
+    tables (MP,) page ids for this lane.  Padded positions scatter to the
+    reserved scratch page 0.  Returns (last-valid-token logits (vocab,),
+    kv_pool) — the fused pool donated by the caller.
+    """
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import (causal_attention,
+                                           transformer_forward_collect_kv)
+
+    page_size = kv_pool.shape[3]
+    t_pad = tokens.shape[1]
+    logits, kvs = transformer_forward_collect_kv(
+        params, tokens, n_heads=n_heads, n_layers=n_layers,
+        compute_dtype=compute_dtype, n_kv_heads=n_kv_heads,
+        rope_theta=rope_theta,
+        attention_fn=attention_fn or causal_attention)
+    pos = jnp.arange(t_pad)
+    valid = pos < valid_len
+    page_idx = jnp.where(valid, tables[pos // page_size], 0)  # scratch if pad
+    slot_idx = jnp.where(valid, pos % page_size, 0)
+    for layer, (k, v) in enumerate(kvs):
+        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
+                              k[0], v[0])
+    last = logits[0, valid_len - 1]
+    return last, kv_pool
+
+
+def paged_extend(params, kv_pool, tables, tokens, start, valid_total,
+                 n_heads: int, n_layers: int, compute_dtype,
+                 n_kv_heads: Optional[int] = None,
+                 rope_theta: Optional[float] = None):
+    """Chunked/tail prefill against EXISTING paged context.
+
+    One fused forward over M tail tokens (positions ``start ..
+    start+M-1``) for a single lane whose positions ``[0, start)`` are
+    already resident in the pool (prefix-cache hits or earlier chunks of a
+    chunked prefill).  Per layer the tail K/V scatter into their pages
+    first, then attention gathers the lane's WHOLE block table — the
+    gather-after-scatter sees cached prefix and tail together, so the mask
+    is just global causality (tail token m attends position j iff
+    ``j <= start+m``).
+
+    tokens (1, M_pad) int32 (padded tail arbitrary); start scalar int32
+    (page-aligned: the tail must never write into a shared prefix page);
+    valid_total scalar int32 = true total length (prompt so far + tail);
+    tables (MP,) page ids covering all of it.  Returns (logits of the last
+    valid token (vocab,), kv_pool) — the fused pool donated by the caller.
+    """
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _lm_head, _rmsnorm
+
+    page_size = kv_pool.shape[3]
+    m_pad = tokens.shape[1]
+    emb = params["embed"].astype(compute_dtype)
+    x = emb[tokens]                                   # (1, M_pad, D)
+    spec = _step_spec(None, x.shape[-1], n_heads, n_layers, n_kv_heads,
+                      rope_theta)
+    pos = start + jnp.arange(m_pad)                   # global positions
+    valid = pos < valid_total
+    page_idx = jnp.where(valid, tables[pos // page_size], 0)  # pad -> scratch
+    slot_idx = jnp.where(valid, pos % page_size, 0)
+    # gather-after-scatter: context = cached prefix + this tail
+    seg = dict(tables=tables[None], use_kernel=False)
+    for layer in range(n_layers):
+        x, kv_pool, _ = _layer_block(
+            spec, params[f"layer{layer}"], layer, x, pos[None], valid[None],
+            kv_pool, page_idx, slot_idx, seg, compute_dtype)
+
+    # only the last valid token's logits are ever consumed — run the
+    # vocab-sized head over ONE row, not all M_pad rows
+    x_last = x[0, valid_total - 1 - start][None]      # (1, D)
+    x_last = _rmsnorm(x_last, params["final_norm"]["scale"])
+    last = _lm_head(params, x_last)[0]                # (vocab,)
+    return last, kv_pool

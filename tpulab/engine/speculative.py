@@ -192,109 +192,6 @@ class SpeculativeGenerator:
 from tpulab.models.transformer import early_exit_draft  # noqa: E402,F401
 
 
-def benchmark_speculative(n_heads: int = 8, n_layers: int = 8,
-                          d_model: int = 512, d_ff: int = 2048,
-                          vocab: int = 2048, draft_layers: int = 2,
-                          k: int = 4, steps: int = 128,
-                          prompt_len: int = 16, max_len: int = 512,
-                          compute_dtype=None, seed: int = 0,
-                          tail_scale: float = 0.05):
-    """Acceptance rate + tok/s of speculative vs plain greedy decode
-    (VERDICT r4 #7: 'a number, not a feature flag').
-
-    Capture-wise superseded by the serving-path ``speculative_decode``
-    row (:func:`tpulab.engine.paged.benchmark_speculative_decode`),
-    which runs spec and plain through ONE ContinuousBatcher workload —
-    no duplicated plain-baseline loop.  This dense-path variant stays as
-    the acceptance-mechanics microbenchmark.
-
-    Weights are synthetic, so ``tail_scale`` shrinks the output
-    projections of layers past the draft exit: in a *trained* model the
-    late layers refine the residual stream rather than overturn it (the
-    property early-exit speculation exploits); raw random layers instead
-    flip the argmax of near-uniform logits on every token (acceptance
-    pins to 0 and the row measures nothing).  The resulting acceptance
-    is an emulation — real-checkpoint acceptance depends on the model —
-    but the tok/s-at-acceptance mechanics and the exactness guarantee
-    are the real measurement.
-
-    Plain decode is measured serving-shaped — a host loop over one jitted
-    decode step, exactly how the generation engine streams tokens — so
-    both sides carry the same per-token host overhead.
-    """
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from tpulab.models.transformer import (init_kv_cache,
-                                           init_transformer_params,
-                                           transformer_chunk_step,
-                                           transformer_decode_step)
-
-    target = init_transformer_params(vocab=vocab, d_model=d_model,
-                                     n_heads=n_heads, n_layers=n_layers,
-                                     d_ff=d_ff, seed=seed)
-    for i in range(draft_layers, n_layers):  # see tail_scale docstring
-        for w in ("wo", "w2"):
-            target[f"layer{i}"][w] = target[f"layer{i}"][w] * tail_scale
-    draft = early_exit_draft(target, draft_layers)
-    spec = SpeculativeGenerator(
-        target, draft, n_heads=n_heads, n_layers=n_layers,
-        draft_n_heads=n_heads, draft_n_layers=draft_layers, k=k,
-        max_len=max_len, compute_dtype=compute_dtype)
-    rng = np.random.default_rng(seed)
-    prompt = rng.integers(0, vocab, (prompt_len,)).astype(np.int32)
-
-    spec.generate(prompt, 8)  # compile + warm both programs
-    t0 = time.perf_counter()
-    spec_toks = spec.generate(prompt, steps)
-    spec_s = time.perf_counter() - t0
-    acceptance = spec.accepted / max(spec.rounds * k, 1)
-
-    # plain greedy: host loop over one jitted single-token step (the
-    # serving shape), identical prefill
-    from functools import partial as _partial
-    cdt = compute_dtype or jnp.float32
-    head_dim = d_model // n_heads
-    prefill = jax.jit(_partial(transformer_chunk_step, n_heads=n_heads,
-                               n_layers=n_layers, compute_dtype=cdt))
-    step = jax.jit(_partial(transformer_decode_step, n_heads=n_heads,
-                            n_layers=n_layers, compute_dtype=cdt))
-
-    def plain(n: int) -> List[int]:
-        cache = init_kv_cache(1, max_len, n_layers, n_heads, head_dim, cdt)
-        t_pad = 1 << (prompt_len - 1).bit_length()
-        padded = np.zeros((1, t_pad), np.int32)
-        padded[0, :prompt_len] = prompt
-        logits, cache = prefill(target, cache, jnp.asarray(padded),
-                                jnp.int32(0))
-        cur = int(np.asarray(logits)[0, prompt_len - 1].argmax())
-        out = [cur]
-        pos = prompt_len
-        while len(out) < n:
-            lg, cache = step(target, cache,
-                             jnp.asarray([cur], jnp.int32), jnp.int32(pos))
-            cur = int(np.asarray(lg)[0].argmax())
-            out.append(cur)
-            pos += 1
-        return out
-
-    plain(8)  # warm
-    t0 = time.perf_counter()
-    plain_toks = plain(steps)
-    plain_s = time.perf_counter() - t0
-
-    return {"k": k, "draft_layers": draft_layers, "n_layers": n_layers,
-            "steps": steps,
-            "acceptance": round(acceptance, 3),
-            "rounds": spec.rounds,
-            "spec_tok_s": round(steps / spec_s, 1),
-            "plain_tok_s": round(steps / plain_s, 1),
-            "speedup": round(plain_s / spec_s, 3),
-            "exact_match": bool(spec_toks == plain_toks)}
-
-
 class _SpeculativeSession:
     """One admitted decode: usable directly (``close()``) or as a context
     manager, mirroring the dense :class:`GenerationSession` shape.  The

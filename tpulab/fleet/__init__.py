@@ -39,8 +39,6 @@ docs/SERVING.md "Fleet routing & autoscaling" + "Running a real fleet".
 from tpulab.fleet.autoscaler import (FleetAutoscaler,  # noqa: F401
                                      InProcessReplicaProvider,
                                      ReplicaProvider, spawn_with_retry)
-from tpulab.fleet.bench import (benchmark_fleet_obs,  # noqa: F401
-                                benchmark_prefix_affinity)
 from tpulab.fleet.control import FleetController  # noqa: F401
 from tpulab.fleet.election import (FileLeaseBackend,  # noqa: F401
                                    LeaderElector, LeaseBackend,
@@ -57,5 +55,4 @@ __all__ = ["PrefixAffinityRouter", "prefix_digest", "FleetAutoscaler",
            "SubprocessReplicaProvider", "FleetSupervisor",
            "LeaseBackend", "FileLeaseBackend", "LeaderElector",
            "StaleLeaderError", "FleetController", "FleetObserver",
-           "membership_snapshot", "apply_membership", "spawn_with_retry",
-           "benchmark_prefix_affinity", "benchmark_fleet_obs"]
+           "membership_snapshot", "apply_membership", "spawn_with_retry"]

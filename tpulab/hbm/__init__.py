@@ -3,7 +3,7 @@
 trtlab's foundation is ONE allocator/descriptor/arena framework that every
 higher layer rents from (PAPER.md layer map §0); tpulab reproduced that
 for host memory, but device HBM grew into three fiefdoms — the
-:class:`~tpulab.engine.paged.PagedKVPool` pre-carves pages, the
+:class:`~tpulab.engine.kv_pool.PagedKVPool` pre-carves pages, the
 :class:`~tpulab.modelstore.WeightMultiplexer` budgets weights *next to*
 (not with) KV accounting, and compiled-program scratch was invisible to
 both.  This package is the missing common ground:
@@ -22,11 +22,10 @@ both.  This package is the missing common ground:
 """
 
 from tpulab.hbm.arbiter import (KV_TENANT, SCRATCH_TENANT,  # noqa: F401
-                                WEIGHTS_TENANT, HBMArbiter,
-                                benchmark_hbm_arbiter)
+                                WEIGHTS_TENANT, HBMArbiter)
 from tpulab.hbm.ledger import DeviceHBMLedger  # noqa: F401
 from tpulab.hbm.scratch import MeasuredJit, scratch_bytes_of  # noqa: F401
 
 __all__ = ["DeviceHBMLedger", "HBMArbiter", "MeasuredJit",
-           "scratch_bytes_of", "benchmark_hbm_arbiter",
-           "KV_TENANT", "WEIGHTS_TENANT", "SCRATCH_TENANT"]
+           "scratch_bytes_of", "KV_TENANT", "WEIGHTS_TENANT",
+           "SCRATCH_TENANT"]

@@ -42,8 +42,7 @@ from typing import Any, Callable, Dict, Hashable, List, Optional
 from tpulab import chaos
 from tpulab.hbm.ledger import DeviceHBMLedger
 
-__all__ = ["HBMArbiter", "KV_TENANT", "WEIGHTS_TENANT", "SCRATCH_TENANT",
-           "benchmark_hbm_arbiter"]
+__all__ = ["HBMArbiter", "KV_TENANT", "WEIGHTS_TENANT", "SCRATCH_TENANT"]
 
 #: canonical tenant names (the ledger key's first half); the 2D-mesh
 #: work extends tags, not these
@@ -320,214 +319,3 @@ class HBMArbiter:
             pass  # an injected fault at deny still denies, atomically
         self.denials += 1
         return False
-
-
-# -- the bench row ------------------------------------------------------------
-def benchmark_hbm_arbiter(lanes: int = 4, steps: int = 24,
-                          prompt_len: int = 8, page_size: int = 8,
-                          d_model: int = 256, n_heads: int = 4,
-                          n_layers: int = 4, vocab: int = 256,
-                          n_llm: int = 12,
-                          dtype=None) -> Dict[str, Any]:
-    """The bench ``hbm_arbiter`` row: a mixed model-swap + KV-burst trace
-    under device-HBM oversubscription, arbiter ON vs today's static
-    split.
-
-    One device budget holds EITHER the full KV burst's pages OR the
-    second model's weights — never both.  The trace interleaves an
-    ``n_llm``-request LLM burst through the paged batcher with forwards
-    on a second dense model:
-
-    - **static split** (the pre-arbiter baseline): the pool is fixed at
-      its small static share and the second model owns its own weight
-      budget — the burst grinds through a starved pool while the model's
-      bytes sit idle between forwards;
-    - **arbiter on**: the burst grows the pool by evicting the cold
-      model (write-behind swap-out), and the model's next acquire
-      presses the KV tenant back down (demote + shrink) — the same bytes
-      serve whichever side is under load.
-
-    Both modes must produce identical greedy tokens and model outputs
-    (``parity``); the headline is goodput (completed ops/s) plus the
-    arbiter's demotion/eviction/denial counters."""
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
-    from tpulab.engine.paged import ContinuousBatcher
-    from tpulab.models.transformer import init_transformer_params
-
-    dtype = dtype or jnp.float32
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, vocab, (prompt_len,), np.int32)
-               for _ in range(n_llm)]
-
-    max_len = prompt_len + steps + 2
-    pages_per_req = (max_len + page_size - 1) // page_size
-    full_pages = lanes * pages_per_req + 1      # the burst's working set
-    small_pages = pages_per_req + 1             # the static KV share
-    # the batcher's elastic pool snaps to its size ladder (small * 2^k),
-    # so the burst's reachable top is the first ladder rung >= full
-    top_pages = small_pages
-    while top_pages < full_pages:
-        top_pages *= 2
-    page_nbytes = (n_layers * 2 * page_size * n_heads
-                   * (d_model // n_heads) * np.dtype(np.float32).itemsize)
-    # model B sized at exactly the pool's elastic range: holding B hot
-    # and serving the full burst are mutually exclusive under ``capacity``
-    # (top rung + half a page of slack — never the full pool AND B)
-    b_words = (top_pages - small_pages) * page_nbytes // 4
-    capacity = top_pages * page_nbytes + page_nbytes // 2
-
-    params = init_transformer_params(vocab=vocab, d_model=d_model,
-                                     n_heads=n_heads, n_layers=n_layers,
-                                     d_ff=4 * d_model)
-
-    def build_model_b():
-        r = np.random.default_rng(7)
-        return {"w": jnp.asarray(
-            r.standard_normal((b_words,)).astype(np.float32))}
-
-    b_fwd = jax.jit(lambda p: jnp.tanh(p["w"][:256]).sum())
-
-    class _Servable:
-        def __init__(self):
-            self.device_params = jax.device_put(build_model_b())
-
-        def resident(self):
-            return self.device_params is not None
-
-        def param_bytes(self):
-            from tpulab.modelstore.host_store import tree_nbytes
-            return tree_nbytes(self.device_params or build_model_b())
-
-        def busy(self):
-            return False
-
-        def detach(self):
-            dev, self.device_params = self.device_params, None
-            return dev
-
-        def on_detached(self):
-            pass
-
-        def attach(self, host_tree):
-            self.device_params = jax.device_put(host_tree)
-
-        def rebuild(self):
-            return build_model_b()
-
-    def run(arbiter_on: bool) -> Dict[str, Any]:
-        from tpulab.modelstore import WeightMultiplexer
-
-        b = _Servable()
-        b_bytes = b.param_bytes()
-        arb = (HBMArbiter(capacity, measure_scratch=False)
-               if arbiter_on else None)
-        # the static split can only run the lanes its fixed pool carries
-        # (a pre-arbiter deployment sizes lanes to the pool — admitting
-        # more would page-hoard-deadlock); the arbiter mode runs the full
-        # lane count because the pool grows to meet the burst
-        run_lanes = lanes if arbiter_on else max(
-            1, (small_pages - 1) // pages_per_req)
-        cb = ContinuousBatcher(
-            params, n_heads=n_heads, n_layers=n_layers, lanes=run_lanes,
-            max_len=max_len, page_size=page_size, n_pages=small_pages,
-            compute_dtype=dtype, kv_offload=True, hbm=arb)
-        mux = WeightMultiplexer(max(b_bytes, 1), hbm=arb)
-        mux.register("b", _BenchAdapter(b))
-
-        tokens: List[List[int]] = []
-        outs: List[float] = []
-
-        def b_op():
-            lease = mux.acquire("b")
-            try:
-                outs.append(round(float(np.asarray(
-                    b_fwd(b.device_params))), 4))
-            finally:
-                lease.release()
-
-        # warm the compiles out of the measurement (the kv_offload-row
-        # discipline).  Two waves: the first grows the pool mid-burst
-        # (arbiter mode), the second prefills + decodes entirely at the
-        # grown shape — every (program, pool-shape) pair the measured
-        # trace hits is compiled here; the b_op warms the squeeze path
-        for _ in range(2):
-            for f in [cb.submit(p, steps) for p in prompts[:lanes]]:
-                f.result(timeout=300)
-        b_op()
-        outs.clear()
-        d0 = dict(denials=arb.denials, demotions=arb.demotions_forced,
-                  evictions=arb.evictions_forced) if arb else {}
-        pre0, grow0, shrink0 = cb.preemptions, cb.hbm_grows, cb.hbm_shrinks
-        t0 = _time.perf_counter()
-        b_op()  # model op before the burst: B hot, pool squeezed
-        futs = [cb.submit(p, steps) for p in prompts]
-        # a model op lands mid-burst: the arbiter must squeeze KV back
-        futs[0].result(timeout=300)
-        b_op()
-        for f in futs:
-            tokens.append([int(t) for t in f.result(timeout=300)])
-        b_op()  # and one after: swap back in (bit-exact either way)
-        wall = max(1e-6, _time.perf_counter() - t0)
-        out = {
-            "wall_s": round(wall, 3),
-            "goodput_ops_s": round((len(futs) + 3) / wall, 2),
-            "tokens": tokens, "model_outs": outs,
-            "pool_pages_final": cb.pool.n_pages,
-            "preemptions": cb.preemptions - pre0,
-        }
-        if arb is not None:
-            out.update(
-                demotions=arb.demotions_forced - d0["demotions"],
-                evictions=arb.evictions_forced - d0["evictions"],
-                denials=arb.denials - d0["denials"],
-                grows=cb.hbm_grows - grow0,
-                shrinks=cb.hbm_shrinks - shrink0,
-                free_hbm_mb=round(arb.free_hbm_bytes / 2**20, 3))
-        cb.shutdown()
-        mux.close()
-        return out
-
-    on, off = run(True), run(False)
-    parity = (on.pop("tokens") == off.pop("tokens")
-              and on.pop("model_outs") == off.pop("model_outs"))
-    return {
-        "lanes": lanes, "steps": steps, "n_llm": n_llm,
-        "small_pages": small_pages, "full_pages": full_pages,
-        "arbiter_on": on, "static_split": off,
-        "parity": parity,
-        "goodput_ratio": round(
-            on["goodput_ops_s"] / max(1e-9, off["goodput_ops_s"]), 3),
-    }
-
-
-class _BenchAdapter:
-    """Adapter façade over the bench servable (same protocol as
-    CompiledModelAdapter/BatcherAdapter)."""
-
-    def __init__(self, servable):
-        self._s = servable
-
-    def resident(self):
-        return self._s.resident()
-
-    def param_bytes(self):
-        return self._s.param_bytes()
-
-    def busy(self):
-        return self._s.busy()
-
-    def detach(self):
-        return self._s.detach()
-
-    def on_detached(self):
-        self._s.on_detached()
-
-    def attach(self, host_tree):
-        self._s.attach(host_tree)
-
-    def rebuild(self):
-        return self._s.rebuild()

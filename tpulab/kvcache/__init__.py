@@ -9,7 +9,7 @@ tiering", docs/SERVING.md).
 - :class:`HostKVStore` — budgeted LRU host tier on the
   :mod:`tpulab.memory` allocator/descriptor framework.
 - :class:`KVOffloadManager` — async device<->host swap policy over a
-  :class:`~tpulab.engine.paged.PagedKVPool`, riding the
+  :class:`~tpulab.engine.kv_pool.PagedKVPool`, riding the
   :class:`~tpulab.tpu.transfer.TransferEngine` (write-behind swap-out).
 
 Wire-up: ``ContinuousBatcher(..., kv_offload=...)`` (True / budget bytes
@@ -18,8 +18,7 @@ Wire-up: ``ContinuousBatcher(..., kv_offload=...)`` (True / budget bytes
 
 from tpulab.kvcache.host_store import HostKVStore  # noqa: F401
 from tpulab.kvcache.offload import (DEFAULT_HOST_BUDGET,  # noqa: F401
-                                    KVOffloadManager, SwapHandle,
-                                    benchmark_kv_offload)
+                                    KVOffloadManager, SwapHandle)
 
 __all__ = ["HostKVStore", "KVOffloadManager", "SwapHandle",
-           "DEFAULT_HOST_BUDGET", "benchmark_kv_offload"]
+           "DEFAULT_HOST_BUDGET"]

@@ -1,6 +1,6 @@
 """Host-memory KV tier: budgeted, LRU, page-granular byte store.
 
-The serving stack's KV pages live in HBM (:class:`~tpulab.engine.paged.
+The serving stack's KV pages live in HBM (:class:`~tpulab.engine.kv_pool.
 PagedKVPool`); this module is the tier BELOW it — host RAM holding KV
 snapshots that HBM pressure pushed out (preempted lanes, evicted prefix
 cache entries).  It is deliberately dumb: keys map to opaque byte

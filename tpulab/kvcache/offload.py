@@ -199,7 +199,7 @@ class KVOffloadManager:
         host bytes onto the local topology), the pool device otherwise."""
         import jax
 
-        from tpulab.engine.paged import kv_rows_view
+        from tpulab.engine.kv_pool import kv_rows_view
         return jax.device_put(kv_rows_view(arr), self.pool.placement)
 
     # -- lane swap (preemption) ----------------------------------------------
@@ -457,101 +457,3 @@ class KVOffloadManager:
         if self._owns_transfer:
             self._transfer.shutdown()
         self.store.clear()
-
-
-def benchmark_kv_offload(lanes: int = 2, steps: int = 20,
-                         prompt_len: int = 12, page_size: int = 8,
-                         d_model: int = 64, n_heads: int = 4,
-                         n_layers: int = 2, vocab: int = 256,
-                         n_low: int = 4, n_hi: int = 4,
-                         dtype=None) -> Dict[str, Any]:
-    """The bench ``kv_offload`` row: goodput and re-prefill dispatches
-    under ~2x KV oversubscription, host tier on vs off.
-
-    The workload keeps ``n_low + n_hi`` requests outstanding against a
-    pool sized for ``lanes`` residents (outstanding KV demand ~2x the
-    pool): the low-priority half decodes long sequences, and each
-    high-priority preemptor is injected the moment a low lane is
-    observed decoding — every preemption then either re-prefills (tier
-    off) or swaps (tier on).  ``re_prefill_dispatches`` counts prefill
-    passes beyond the one each request legitimately pays; with the tier
-    on it should collapse toward zero.  On CPU jit the dispatch counts
-    are the signal (a re-prefill forward is cheap there); on-device each
-    avoided re-prefill is a whole prompt+generated forward not burned
-    twice, so goodput is the headline.
-    """
-    import threading as _th
-    import time
-
-    import jax.numpy as jnp
-
-    from tpulab.engine.paged import ContinuousBatcher
-    from tpulab.models.transformer import init_transformer_params
-
-    dtype = dtype or jnp.float32
-    low_steps = 2 * steps               # long victims: a real resume window
-    max_len = prompt_len + low_steps + 4
-    pages_per_req = (max_len + page_size - 1) // page_size
-    n_pages = lanes * pages_per_req + 1
-    outstanding = n_low + n_hi
-    params = init_transformer_params(vocab=vocab, d_model=d_model,
-                                     n_heads=n_heads, n_layers=n_layers,
-                                     d_ff=4 * d_model)
-    rng = np.random.default_rng(0)
-    low_prompts = [rng.integers(0, vocab, (prompt_len,), np.int32)
-                   for _ in range(n_low)]
-    hi_prompts = [rng.integers(0, vocab, (prompt_len,), np.int32)
-                  for _ in range(n_hi)]
-
-    def mode(offload_on: bool) -> Dict[str, Any]:
-        cb = ContinuousBatcher(
-            params, n_heads=n_heads, n_layers=n_layers, lanes=lanes,
-            max_len=max_len, page_size=page_size, n_pages=n_pages,
-            compute_dtype=dtype,
-            kv_offload=DEFAULT_HOST_BUDGET if offload_on else None)
-        try:
-            # warm the prefill/decode compiles out of the measurement
-            for f in [cb.submit(p, low_steps) for p in low_prompts[:lanes]]:
-                f.result(timeout=300)
-            for f in [cb.submit(p, steps) for p in hi_prompts[:lanes]]:
-                f.result(timeout=300)
-            pf0 = cb.prefill_dispatches
-            decoding = _th.Semaphore(0)  # one permit per low decode token
-            t0 = time.perf_counter()
-            futs = [cb.submit(p, low_steps,
-                              on_token=lambda _t, _i: decoding.release())
-                    for p in low_prompts]
-            for p in hi_prompts:
-                # inject each preemptor only once a low lane is decoding,
-                # so preemption (not plain admission) is what it exercises
-                decoding.acquire(timeout=30)
-                futs.append(cb.submit(p, steps, priority=10))
-            for f in futs:
-                f.result(timeout=300)
-            wall = max(1e-6, time.perf_counter() - t0)
-            entry = {
-                "goodput_rps": round(len(futs) / wall, 2),
-                "wall_s": round(wall, 3),
-                "preemptions": cb.preemptions,
-                "re_prefill_dispatches":
-                    cb.prefill_dispatches - pf0 - len(futs),
-            }
-            mgr = cb.kv_offload
-            if mgr is not None:
-                entry.update(
-                    swap_outs=mgr.swap_outs, swap_ins=mgr.swap_ins,
-                    swap_out_mb=round(mgr.swap_out_bytes / 2**20, 2),
-                    recompute_tokens_saved=mgr.recompute_tokens_saved,
-                    swap_failures=mgr.swap_failures)
-            return entry
-        finally:
-            cb.shutdown()
-
-    return {
-        "lanes": lanes, "steps": steps, "n_requests": n_low + n_hi,
-        "pool_pages": n_pages,
-        "oversubscription": round(
-            outstanding * pages_per_req / n_pages, 2),
-        "tier_off": mode(False),
-        "tier_on": mode(True),
-    }

@@ -6,10 +6,4 @@ See :mod:`tpulab.kvfabric.fabric` for the design; docs/SERVING.md
 
 from tpulab.kvfabric.fabric import KVFabric, PulledKV, fabric_export
 
-
-def benchmark_kv_fabric(**kw):
-    from tpulab.kvfabric.bench import benchmark_kv_fabric as _b
-    return _b(**kw)
-
-
-__all__ = ["KVFabric", "PulledKV", "fabric_export", "benchmark_kv_fabric"]
+__all__ = ["KVFabric", "PulledKV", "fabric_export"]

@@ -24,7 +24,7 @@ exactly what the router meant when it routed the original request there.
 First-token parity: the owner publishes the prefill's last-position
 logits row beside the snapshot (wire header extras), and the FETCHER
 picks the first token under its OWN sampling — argmax for greedy,
-:func:`~tpulab.engine.paged._device_sample_token` (the single
+:func:`~tpulab.engine.paged_steps._device_sample_token` (the single
 device-sampling stream definition) for device-sampled requests — so the
 token stream is bit-exact against a local prefill on either side.
 Host-sampled and logprob-streaming requests never pull (same rule as
@@ -369,7 +369,7 @@ class KVFabric:
         logits = np.frombuffer(base64.b64decode(b64), np.float32)
         import jax.numpy as jnp
 
-        from tpulab.engine.paged import _device_sample_token
+        from tpulab.engine.paged_steps import _device_sample_token
         pos = int(header["length"]) - 1
         return int(np.asarray(_device_sample_token(
             jnp.asarray(logits, jnp.float32),

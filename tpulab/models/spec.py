@@ -1,7 +1,7 @@
 """Model spec: what the paged engine has to know about a decoder's layers.
 
-The step functions of :mod:`tpulab.engine.paged` run ONE layer block
-(``paged._layer_block``) for every model they serve; a :class:`ModelSpec`
+The step functions of :mod:`tpulab.engine.paged_steps` run ONE layer block
+(``paged_steps._layer_block``) for every model they serve; a :class:`ModelSpec`
 tells it the attention kind and its widths, which layers carry a dense FFN
 and which a routed expert FFN, the RMSNorm epsilon, and with the attention
 kind the *cache-entry kind* the page store holds:

@@ -406,7 +406,7 @@ def early_exit_draft(target_params: Dict[str, Any],
     extra HBM beyond what the target already holds) and, by
     construction, the target's head geometry (head_dim, n_kv_heads) —
     exactly what the paged speculative path requires, since the draft's
-    KV rides the target's :class:`~tpulab.engine.paged.PagedKVPool`
+    KV rides the target's :class:`~tpulab.engine.kv_pool.PagedKVPool`
     through a second page table (``ContinuousBatcher(draft_params=...,
     draft_n_layers=...)``).  The dense
     :class:`~tpulab.engine.speculative.SpeculativeGenerator` takes the

@@ -15,8 +15,7 @@ from tpulab.modelstore.host_store import (DEFAULT_HOST_BUDGET,
                                           HostParamStore, tree_nbytes)
 from tpulab.modelstore.multiplexer import (BatcherAdapter,
                                            CompiledModelAdapter, ModelLease,
-                                           WeightMultiplexer,
-                                           benchmark_multi_model)
+                                           WeightMultiplexer)
 
 __all__ = [
     "DEFAULT_HOST_BUDGET",
@@ -26,5 +25,4 @@ __all__ = [
     "CompiledModelAdapter",
     "ModelLease",
     "WeightMultiplexer",
-    "benchmark_multi_model",
 ]

@@ -45,8 +45,6 @@ import time as _time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional
 
-import numpy as np
-
 from tpulab import chaos
 from tpulab.modelstore.host_store import (DEFAULT_HOST_BUDGET,
                                           HostParamStore, tree_nbytes)
@@ -641,202 +639,3 @@ class WeightMultiplexer:
         if self._owns_transfer:
             self._transfer.shutdown()
         self.store.clear()
-
-
-# -- the bench row ------------------------------------------------------------
-def benchmark_multi_model(switches: int = 6, steps: int = 8,
-                          prompt_len: int = 8, vocab: int = 128,
-                          d_model: int = 64, n_layers: int = 2,
-                          n_heads: int = 4) -> Dict[str, Any]:
-    """The bench ``multi_model`` row: an interleaved two-model trace
-    (a transformer LLM through the paged batcher + a dense ViT-style
-    classifier) under HBM weight pressure — the budget holds ONE model's
-    weights, so every switch is a swap.
-
-    Multiplexer **on**: switches ride host-tier swap-ins (promote the
-    bytes that left the device).  **Off** (the pre-modelstore baseline):
-    every switch is a serial cold rebuild — re-init + re-place.  Both
-    modes must produce identical outputs (``parity``/``llm_parity``);
-    the headline is mean swap-in vs cold-build latency and the eviction
-    count."""
-    import jax
-    import jax.numpy as jnp
-
-    from tpulab.engine.paged import ContinuousBatcher
-    from tpulab.models.transformer import init_transformer_params
-    from tpulab.models.vit import init_vit_params, vit_apply
-
-    rng = np.random.default_rng(0)
-    prompt = rng.integers(0, vocab, (prompt_len,), np.int32)
-    image = rng.standard_normal((1, 32, 32, 3)).astype(np.float32)
-
-    def build_llm_params():
-        return init_transformer_params(vocab=vocab, d_model=d_model,
-                                       n_heads=n_heads, n_layers=n_layers,
-                                       d_ff=4 * d_model, seed=0)
-
-    def build_vit_params():
-        return init_vit_params(variant="s", image_size=32, patch_size=16,
-                               num_classes=10, seed=0)
-
-    vit_fn = jax.jit(lambda p, x: vit_apply(
-        p, {"input": x}, n_heads=6, n_layers=12, patch_size=16,
-        compute_dtype=jnp.float32)["logits"])
-
-    class _VitServable:
-        """Minimal dense-model adapter target for the bench (the real
-        path uses CompiledModelAdapter; the swap mechanics are shared)."""
-
-        def __init__(self):
-            self.device_params = jax.device_put(build_vit_params())
-
-        def resident(self):
-            return self.device_params is not None
-
-        def param_bytes(self):
-            return tree_nbytes(self.device_params or build_vit_params())
-
-        def busy(self):
-            return False
-
-        def detach(self):
-            dev, self.device_params = self.device_params, None
-            return dev
-
-        def on_detached(self):
-            pass
-
-        def attach(self, host_tree):
-            self.device_params = jax.device_put(host_tree)
-
-        def rebuild(self):
-            return build_vit_params()
-
-    def run(mux_on: bool) -> Dict[str, Any]:
-        cb = ContinuousBatcher(build_llm_params(), n_heads=n_heads,
-                               n_layers=n_layers, lanes=2,
-                               max_len=prompt_len + steps + 4,
-                               compute_dtype=jnp.float32)
-        vit = _VitServable()
-        llm_bytes = tree_nbytes(cb.params)
-        vit_bytes = vit.param_bytes()
-        # holds the bigger model (plus half the smaller) but never both:
-        # every switch in the trace is forced to swap
-        budget = (max(llm_bytes, vit_bytes)
-                  + min(llm_bytes, vit_bytes) // 2)
-        mux = None
-        if mux_on:
-            mux = WeightMultiplexer(budget)
-            mux.register("llm", BatcherAdapter(cb, build_llm_params))
-            mux.register("vit", _VitServableAdapter(vit))
-        tokens: List[List[int]] = []
-        logits: List[np.ndarray] = []
-        swap_in_s: List[float] = []
-        cold_s: List[float] = []
-        t_all = _time.perf_counter()
-        try:
-            for i in range(switches):
-                want_llm = i % 2 == 0
-                name = "llm" if want_llm else "vit"
-                t0 = _time.perf_counter()
-                if mux is not None:
-                    was_cold = mux.state_of(name) != _HOT
-                    rebuilds0 = mux.cold_rebuilds
-                    lease = mux.acquire(name)
-                    mux.drain()
-                    if was_cold:
-                        (cold_s if mux.cold_rebuilds > rebuilds0
-                         else swap_in_s).append(
-                            _time.perf_counter() - t0)
-                else:
-                    # serial-rebuild baseline: the OTHER model's weights
-                    # are dropped and this one is rebuilt from scratch
-                    if want_llm and cb.params is None:
-                        cb.params = jax.device_put(build_llm_params(),
-                                                   cb.pool.device)
-                        cold_s.append(_time.perf_counter() - t0)
-                    elif not want_llm and vit.device_params is None:
-                        vit.attach(build_vit_params())
-                        cold_s.append(_time.perf_counter() - t0)
-                    lease = None
-                try:
-                    if want_llm:
-                        fut = cb.submit(prompt, steps)
-                        tokens.append([int(t) for t in
-                                       fut.result(timeout=300)])
-                    else:
-                        logits.append(np.asarray(vit_fn(vit.device_params,
-                                                        image)))
-                finally:
-                    if lease is not None:
-                        lease.release()
-                if mux is None:  # baseline drops the model it just used
-                    if want_llm:
-                        cb.params = None
-                    else:
-                        vit.device_params = None
-            wall = _time.perf_counter() - t_all
-            out = {
-                "wall_s": round(wall, 3),
-                "llm_tokens": tokens,
-                "vit_logits_digest": [round(float(np.abs(l).sum()), 4)
-                                      for l in logits],
-                "cold_build_ms_mean": round(
-                    1e3 * float(np.mean(cold_s)), 2) if cold_s else None,
-                "swap_in_ms_mean": round(
-                    1e3 * float(np.mean(swap_in_s)), 2) if swap_in_s
-                else None,
-            }
-            if mux is not None:
-                out.update(evictions=mux.evictions, swap_ins=mux.swap_ins,
-                           swap_outs=mux.swap_outs,
-                           cold_rebuilds=mux.cold_rebuilds,
-                           hbm_budget_mb=round(budget / 2**20, 2))
-            return out
-        finally:
-            cb.shutdown()
-            if mux is not None:
-                mux.close()
-
-    on, off = run(True), run(False)
-    llm_parity = on.pop("llm_tokens") == off.pop("llm_tokens")
-    vit_parity = (on.pop("vit_logits_digest")
-                  == off.pop("vit_logits_digest"))
-    son, soff = on.get("swap_in_ms_mean"), off.get("cold_build_ms_mean")
-    return {
-        "switches": switches, "steps": steps,
-        "mux_on": on, "mux_off": off,
-        "llm_parity": llm_parity, "vit_parity": vit_parity,
-        "parity": llm_parity and vit_parity,
-        "swap_in_faster_than_cold_build": (
-            son is not None and soff is not None and son < soff),
-    }
-
-
-class _VitServableAdapter:
-    """Adapter façade over the bench's ``_VitServable`` (same protocol as
-    CompiledModelAdapter/BatcherAdapter)."""
-
-    def __init__(self, servable):
-        self._s = servable
-
-    def resident(self):
-        return self._s.resident()
-
-    def param_bytes(self):
-        return self._s.param_bytes()
-
-    def busy(self):
-        return self._s.busy()
-
-    def detach(self):
-        return self._s.detach()
-
-    def on_detached(self):
-        self._s.on_detached()
-
-    def attach(self, host_tree):
-        self._s.attach(host_tree)
-
-    def rebuild(self):
-        return self._s.rebuild()

@@ -26,7 +26,6 @@ See docs/OBSERVABILITY.md ("Flight recorder", "Debugz", "Fleet
 observability").
 """
 
-from tpulab.obs.bench import benchmark_obs_overhead  # noqa: F401
 from tpulab.obs.debugz import arm_profile, debug_snapshot  # noqa: F401
 from tpulab.obs.flight import KEEP_REASONS, FlightRecorder  # noqa: F401
 from tpulab.obs.journal import (EventJournal, replay_journal,  # noqa: F401
@@ -34,5 +33,5 @@ from tpulab.obs.journal import (EventJournal, replay_journal,  # noqa: F401
 from tpulab.obs.slo import SLOTracker  # noqa: F401
 
 __all__ = ["FlightRecorder", "KEEP_REASONS", "debug_snapshot",
-           "arm_profile", "benchmark_obs_overhead", "EventJournal",
-           "replay_journal", "sequence_gaps", "SLOTracker"]
+           "arm_profile", "EventJournal", "replay_journal", "sequence_gaps",
+           "SLOTracker"]

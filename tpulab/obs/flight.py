@@ -28,8 +28,7 @@ existing :class:`~tpulab.utils.tracing.ChromeTraceRecorder`.
 Disarmed cost: the serving path pays one ``is None`` branch per request
 (the trace-recorder contract).  Armed, record assembly is a few dict
 writes per request plus one classify at completion —
-:meth:`FlightRecorder.assembly_quantiles` reports the measured cost and
-the bench ``obs_overhead`` row enforces the <5% budget.
+:meth:`FlightRecorder.assembly_quantiles` reports the measured cost.
 """
 
 from __future__ import annotations
@@ -86,8 +85,8 @@ class FlightRecorder:
         self.observed_total = 0
         self.dropped_total = 0
         self.kept_by_reason: Dict[str, int] = {}
-        #: record-assembly cost samples (seconds) — the obs_overhead
-        #: bench row's p99 source
+        #: record-assembly cost samples (seconds), read by
+        #: :meth:`assembly_quantiles`
         self._assembly_s = deque(maxlen=2048)
         #: downstream consumers of the UNSAMPLED event stream
         #: (tpulab.obs.slo rides here) — see add_tap

@@ -159,8 +159,7 @@ class EventJournal:
         return evs
 
     def append_quantiles(self) -> Dict[str, float]:
-        """p50/p99 of measured append cost in seconds — the bench
-        ``fleet_obs`` row's journal-cost source."""
+        """p50/p99 of measured append cost in seconds."""
         with self._lock:
             vals = sorted(self._append_s)
         if not vals:

@@ -12,15 +12,11 @@ framework owns its hot ops):
   K+1 speculative verify, and mixed chunked-prefill+decode batches in
   one program; block tables drive HBM->VMEM page DMAs with online
   softmax, and a ``mesh`` shards the walk over the KV-heads dim via
-  shard_map (the kernel side of engine.paged's ragged dispatch plan)
-- :mod:`paged_attention` — the original single-query decode kernel
-  (q=1 only, single-device); superseded in the engine by
-  ``ragged_attention`` but kept as the minimal reference walk
+  shard_map (the kernel side of engine.paged_steps' ragged forward)
 """
 
 from tpulab.ops.flash_attention import flash_attention, make_flash_attention_fn
-from tpulab.ops.paged_attention import paged_decode_attention
 from tpulab.ops.ragged_attention import ragged_paged_attention
 
 __all__ = ["flash_attention", "make_flash_attention_fn",
-           "paged_decode_attention", "ragged_paged_attention"]
+           "ragged_paged_attention"]

@@ -17,9 +17,9 @@ whole family:
 The XLA fallback gathers every lane's pages into a dense
 ``(B, MP*S, H, D)`` tensor; this kernel walks the block table per lane,
 DMA-ing fused K/V pages from HBM into VMEM scratch (one DMA per page)
-through the same ``nbuf``-deep slot-rotation prefetch pipeline as the
-legacy single-query kernel (:mod:`tpulab.ops.paged_attention`), and
-accumulates softmax online per query row — O(block) VMEM, no gather
+through an ``nbuf``-deep slot-rotation prefetch pipeline over blocks of
+``g_pages`` pages (:func:`_block_geometry`), and accumulates softmax
+online per query row — O(block) VMEM, no gather
 materialization, dead pages skipped by predication.
 
 Per-head compute rides the flash-attention dot shapes (2D matmuls only,
@@ -32,7 +32,7 @@ in the compact ``Hkv`` form (the bandwidth win) and slices each query
 head's KV block statically in VMEM.
 
 The pool goes in whole, ``(L, P, 2, S, Hkv*D)`` as
-:class:`~tpulab.engine.paged.PagedKVPool` keeps it, with the layer as one
+:class:`~tpulab.engine.kv_pool.PagedKVPool` keeps it, with the layer as one
 more scalar-prefetch word: the page DMAs read ``kv_pool[layer, page]``,
 and nothing slices or reshapes the pool ahead of the call (XLA cannot
 fuse into a ``pallas_call`` operand: either was a copy of a whole layer
@@ -58,9 +58,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpulab.ops.paged_attention import _block_geometry
-
 _NEG = -1e30
+
+_NBUF = 8  # max block-DMA groups in flight; clamped per geometry so K+V
+# scratch stays within a VMEM budget (see _block_geometry)
+_VMEM_BUDGET_BYTES = 8 << 20  # K+V staging combined; v5e VMEM is ~2x this
+_TARGET_BLOCK_ROWS = 256  # aim each compute step at ~this many KV rows
 
 _LANES, _SUBLANES = 128, 8         # one f32 vector register / tile
 #: Mosaic's default scoped-VMEM limit; a kernel that needs more asks for it
@@ -68,6 +71,19 @@ _VMEM_SCOPED_DEFAULT = 16 << 20
 #: the most this kernel asks for (a v5e core has 128 MiB of VMEM, and the
 #: XLA fusions around the call keep their own share)
 _VMEM_REQUEST_MAX = 96 << 20
+
+
+def _block_geometry(page_size: int, max_pages: int, hd: int,
+                    itemsize: int) -> tuple[int, int]:
+    """(g_pages, nbuf): pages per compute block and pipeline depth.
+    Total scratch (nbuf slots, double-buffer floor nbuf>=2) stays within
+    the VMEM budget: g shrinks first, so wide geometries trade block size
+    for a working pipeline rather than blowing VMEM."""
+    page_bytes = 2 * page_size * hd * itemsize
+    g = max(1, min(_TARGET_BLOCK_ROWS // page_size, max_pages,
+                   _VMEM_BUDGET_BYTES // max(2 * page_bytes, 1)))
+    nbuf = max(2, min(_NBUF, _VMEM_BUDGET_BYTES // max(g * page_bytes, 1)))
+    return g, nbuf
 
 
 def _plan(m: int, h: int, hkv: int, d: int, page_size: int, max_pages: int,
@@ -358,7 +374,7 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
     context position <= its own (the gather-after-scatter contract: the
     segment's K/V are already resident in the pool);
     kv_pool (L, P, 2, S, Hkv*D) — the WHOLE page store as
-    :class:`~tpulab.engine.paged.PagedKVPool` keeps it (axis 2 = K/V
+    :class:`~tpulab.engine.kv_pool.PagedKVPool` keeps it (axis 2 = K/V
     adjacent in HBM, one DMA per page; a row is the KV heads side by
     side, ``Hkv = row // D``, and ``Hkv < Hq`` selects GQA).  The kernel
     reads pages straight out of it: never hand it ``kv_pool[layer]`` or

@@ -2,7 +2,7 @@
 parallelism.
 
 ONE expert FFN serves every caller: the paged engine's expert layers
-(:func:`routed_ffn` from ``tpulab.engine.paged._layer_block``), the dense
+(:func:`routed_ffn` from ``tpulab.engine.paged_steps._layer_block``), the dense
 MoE transformer of the dry run (:func:`moe_ffn`) and the expert-parallel
 form (:func:`make_expert_parallel_ffn`).  It is *exact*: no capacity, no
 dropped token, whatever the routing.

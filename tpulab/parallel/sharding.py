@@ -30,7 +30,7 @@ def shard_batch(mesh: Mesh, axis: str = "data") -> NamedSharding:
 def kv_pool_sharding(mesh: Mesh, model_axis: str = "model") -> NamedSharding:
     """Paged-KV page-store sharding: the pool is
     ``(n_layers, n_pages, 2, page_size, n_kv_heads * head_dim)``
-    (:func:`tpulab.engine.paged.kv_page_shape`) and the page *payloads*
+    (:func:`tpulab.engine.kv_pool.kv_page_shape`) and the page *payloads*
     shard over the model axis on the row (axis 4): the KV heads lie side
     by side in it, so a shard holds a contiguous group of whole heads —
     matching the column-parallel ``wqkv`` that produces them, so a

@@ -19,11 +19,6 @@ controlled here:
 - ``FiberExecutor(contexts, cpu=...)`` pins the grpc.aio event-loop
   thread; handlers are coroutines, so a blocked handler costs no OS
   thread (the reference's detached-fiber-per-event property).
-
-The remaining per-call cost inside grpc-python itself is measured, not
-guessed: ``bench.py`` records a null-RPC (Health) siege as
-``grpc_health_rpc_us`` — the floor the progress engine imposes on every
-request.
 """
 
 from __future__ import annotations

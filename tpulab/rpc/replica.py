@@ -1726,89 +1726,3 @@ class GenerationReplicaSet(_BaseReplicaSet):
             if gen is not None:
                 gen.close()
         yield from fallback(delivered, toks)
-
-
-def benchmark_failover_recovery(prompt_len: int = 24, steps: int = 24,
-                                kill_at: int = 8) -> dict:
-    """bench.py ``failover_recovery`` row (docs/ROBUSTNESS.md "Stream
-    failover semantics"): two loopback replicas, a chaos mid-stream kill
-    (``rpc.stream=error``) at token ``kill_at``, resume-from-delivered ON
-    vs OFF.  Reported per mode: token parity with an uninterrupted run,
-    the recovery gap (largest inter-arrival gap at the consumer — the
-    dead air between the last pre-kill and first post-kill token), and
-    the replayed-token count.  On CPU jit the structural counts are the
-    signal (replayed tokens collapse to zero with resume ON; the
-    survivor pays one prefill); on-device the recovery-gap ratio is —
-    a full replay re-pays every delivered token's decode dispatch."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    import tpulab
-    from tpulab import chaos
-    from tpulab.engine.paged import ContinuousBatcher
-    from tpulab.models.mnist import make_mnist
-    from tpulab.models.transformer import init_transformer_params
-
-    params = init_transformer_params(vocab=128, d_model=32, n_heads=2,
-                                     n_layers=2, d_ff=64)
-
-    def serve():
-        cb = ContinuousBatcher(params, n_heads=2, n_layers=2, lanes=2,
-                               max_len=max(64, prompt_len + steps + 8),
-                               page_size=8, compute_dtype=jnp.float32)
-        mgr = tpulab.InferenceManager(max_exec_concurrency=1)
-        mgr.register_model("mnist", make_mnist(max_batch_size=1))
-        mgr.update_resources()
-        mgr.serve(port=0, generation_engines={"lm": cb})
-        return mgr, cb
-
-    (mgr_a, cb_a), (mgr_b, cb_b) = serve(), serve()
-    rng = np.random.default_rng(0)
-    prompt = rng.integers(0, 128, (prompt_len,), np.int32)
-    out = {"prompt_len": prompt_len, "steps": steps, "kill_at": kill_at}
-    try:
-        for cb in (cb_a, cb_b):  # warm compiles: the gap must be failover,
-            #                      not jit.  A STREAMING consumer is part
-            #                      of the warm-up: it drops the adaptive
-            #                      block to K<=2, a different compiled
-            #                      scan than batch-style submits use
-            cb.submit(prompt, steps,
-                      on_token=lambda *a: None).result(timeout=300)
-            # the resume prompt (prompt + kill_at delivered tokens) can
-            # land in a bigger pow2 prefill bucket — warm it too, or the
-            # resume mode pays a one-off compile in its recovery gap
-            cb.submit(rng.integers(0, 128, (prompt_len + kill_at,),
-                                   np.int32), 2,
-                      on_token=lambda *a: None).result(timeout=300)
-        expected = [int(t) for t in
-                    cb_a.submit(prompt, steps).result(timeout=300)]
-        addrs = [f"127.0.0.1:{m.server.bound_port}" for m in (mgr_a, mgr_b)]
-        for mode, resume in (("resume_on", True), ("resume_off", False)):
-            rs = GenerationReplicaSet(addrs, "lm", resume_failover=resume,
-                                      inter_token_timeout_s=10.0)
-            try:
-                prefills0 = cb_a.prefill_dispatches + cb_b.prefill_dispatches
-                arrivals, got = [], []
-                with chaos.inject(f"rpc.stream=error@{kill_at}+1"):
-                    for tok in rs.generate(prompt, steps):
-                        arrivals.append(time.perf_counter())
-                        got.append(int(tok))
-                gaps = np.diff(np.asarray(arrivals))
-                out[mode] = {
-                    "parity": got == expected,
-                    "recovery_gap_ms": (round(float(gaps.max()) * 1e3, 2)
-                                        if gaps.size else 0.0),
-                    "tokens_replayed": rs.tokens_replayed,
-                    "resumes": rs.resumes,
-                    "failover_prefills": (cb_a.prefill_dispatches
-                                          + cb_b.prefill_dispatches
-                                          - prefills0),
-                }
-            finally:
-                rs.close()
-    finally:
-        for m in (mgr_a, mgr_b):
-            m.shutdown()
-        for cb in (cb_a, cb_b):
-            cb.shutdown()
-    return out

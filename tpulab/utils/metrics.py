@@ -542,9 +542,8 @@ class GenerationMetrics:
     ``metrics=`` and it observes every completed request at the source —
     the distinction the inference-frameworks-benchmark line in PAPERS.md
     shows actually separates serving stacks (means hide the tail).
-    ``ttft_quantiles()`` / ``itl_quantiles()`` feed bench.py's tail-latency
-    rows from sliding-window reservoirs (exact quantiles, not bucket
-    interpolation)."""
+    ``ttft_quantiles()`` / ``itl_quantiles()`` read sliding-window
+    reservoirs (exact quantiles, not bucket interpolation)."""
 
     def __init__(self, namespace: str = "tpulab",
                  registry: Optional["CollectorRegistry"] = None,
@@ -1219,7 +1218,7 @@ class AdmissionMetrics:
         self.inflight.set(inflight)
 
     def queue_wait_quantiles(self) -> Dict[str, float]:
-        """Exact sliding-window quantiles (bench.py's overload row)."""
+        """Exact sliding-window quantiles."""
         return {f"p{int(q * 100)}": self._queue_wait_res.quantile(q)
                 for q in _QUANTILES}
 
