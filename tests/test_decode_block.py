@@ -319,3 +319,317 @@ def test_a_lane_admitted_beside_a_running_chain_is_not_starved(lm, ragged):
         assert done == ["late", "long"]
     finally:
         cb.shutdown()
+
+
+# -- the chain runs one block ahead of the host -----------------------------
+# Block N+1 is enqueued from block N's device-resident carry BEFORE block N
+# is fetched (ContinuousBatcher._chain_block), unless the host can foresee
+# that the lane set changes inside N.  It moves the moment of an enqueue and
+# nothing else: no token, no dispatch, no K.
+
+class _Chain:
+    """Records, on the scheduler thread, the order in which a batcher
+    enqueues and fetches its decode blocks (numbered as enqueued; the
+    chain fetches them in that order), checks the page hoard at every
+    enqueue, and runs a hook inside a chosen fetch: there the block ahead
+    is in flight and the block being fetched is not committed yet."""
+
+    def __init__(self, cb):
+        self.cb = cb
+        self.events = []          # ("enqueue", n) | ("fetch", n)
+        self.ks = []              # K of block n
+        self.at_fetch = {}        # n -> callable
+        self.at_enqueue = {}      # n -> callable, as block n is enqueued
+        self.hoarded = []         # (pages held, pages allowed)
+        self._consuming = None
+        dispatch, consume, note = (cb._dispatch_block, cb._consume_block,
+                                   cb._note_moe)
+
+        def dispatch_block(parts, k, jnp, carry=None, host=None):
+            ps = cb.page_size
+            for req in cb._active:
+                if req is not None and not req.pending_prompt:
+                    allowed = (req.length + 2 * k - 1) // ps + 1
+                    if len(req.pages) > allowed:
+                        self.hoarded.append((len(req.pages), allowed))
+            stash = dispatch(parts, k, jnp, carry=carry, host=host)
+            stash["n"] = len(self.ks)
+            self.events.append(("enqueue", stash["n"]))
+            self.ks.append(k)
+            hook = self.at_enqueue.pop(stash["n"], None)
+            if hook is not None:
+                hook()
+            return stash
+
+        def consume_block(stash, jnp):
+            self._consuming = stash["n"]
+            try:
+                return consume(stash, jnp)
+            finally:
+                self._consuming = None
+
+        def note_moe(moe, decode):
+            n = self._consuming
+            if decode and n is not None:
+                self.events.append(("fetch", n))
+                hook = self.at_fetch.pop(n, None)
+                if hook is not None:
+                    hook()
+            return note(moe, decode)
+
+        cb._dispatch_block, cb._consume_block, cb._note_moe = (
+            dispatch_block, consume_block, note_moe)
+
+    def ahead(self):
+        """Blocks enqueued before their predecessor was fetched."""
+        at = {e: i for i, e in enumerate(self.events)}
+        return [n for n in range(1, len(self.ks))
+                if at[("enqueue", n)] < at[("fetch", n - 1)]]
+
+
+def _sub(seed, **kw):
+    """Submit options of a stream that tells stale K/V from fresh: the
+    tiny model's greedy stream is one token over and over, a seeded
+    device-sampled one is not, and its logprobs move with every K/V row
+    the step read."""
+    return dict(kw, logprobs=True, sampling=SamplingParams(
+        temperature=0.9, seed=seed, device=True))
+
+
+def _reference(lm, cases, **kw):
+    """What a ``decode_block=1`` engine (no block, no chain) answers."""
+    cb = _batcher(lm, 1, **kw)
+    try:
+        return [cb.submit(p, s, **sub).result(timeout=120)
+                for p, s, sub in cases]
+    finally:
+        cb.shutdown()
+
+
+def _same(got, want):
+    assert list(got[0]) == list(want[0])
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("steps, blocks, ahead", [
+    (41, [8, 8, 8, 8, 8], [1, 2, 3, 4]),   # a steady chain: all but the first
+    (36, [8, 8, 8, 8, 4], [1, 2, 3]),      # K changes: a regular plan again
+    (20, [8, 8, 4], [1]),
+    (17, [8, 8], [1]),                     # block 1 holds the last 8 steps
+    (9, [8], []),
+    (6, [8], []),                          # the budget ends inside block 0
+])
+def test_chain_runs_one_block_ahead_up_to_a_foreseen_completion(
+        lm, steps, blocks, ahead):
+    """In a running chain the enqueue of block N+1 precedes the fetch of
+    block N.  A lane whose step budget ends inside N is a completion the
+    host can foresee: no block is enqueued past it, so a request of S
+    steps makes exactly the dispatches it made when every block was
+    enqueued after its predecessor's commit (``blocks``: the K of each),
+    and ``ahead_blocks`` counts the decode blocks less each chain's first
+    and the ones after a foreseen completion."""
+    p = np.random.default_rng(7).integers(0, 64, (5,), np.int32)
+    (want,) = _reference(lm, [(p, steps, _sub(3))], lanes=1)
+    cb = _batcher(lm, 8, lanes=1)
+    chain = _Chain(cb)
+    try:
+        got = cb.submit(p, steps, **_sub(3)).result(timeout=120)
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    _same(got, want)
+    assert len(got[0]) == steps
+    assert chain.ks == blocks
+    assert chain.ahead() == ahead
+    assert state["ahead_blocks"] == cb.ahead_blocks == len(ahead)
+    assert state["decode_dispatches"] == len(blocks)
+    assert state["decode_host_syncs"] == len(blocks)
+    assert not chain.hoarded
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("ragged", [False, True], ids=["legacy", "ragged"])
+def test_chain_ahead_parity_with_a_page_crossed_by_every_block(
+        lm, ragged, sampled):
+    """Tokens and logprobs of a chain that runs ahead are those of single
+    steps, with K equal to the page size so that every block enqueued
+    ahead reserves and crosses into a new page from a committed length
+    that lags it by a block (two lanes, different lengths)."""
+    rng = np.random.default_rng(17)
+    cases = [(rng.integers(0, 64, (n,), np.int32), s,
+              _sub(77 + n) if sampled else {"logprobs": True})
+             for n, s in ((5, 45), (11, 38))]
+    want = _reference(lm, cases, ragged=ragged)
+    cb = _batcher(lm, 8, ragged=ragged)
+    chain = _Chain(cb)
+    try:
+        futs = [cb.submit(p, s, **sub) for p, s, sub in cases]
+        got = [f.result(timeout=120) for f in futs]
+    finally:
+        cb.shutdown()
+    for g, w in zip(got, want):
+        _same(g, w)
+    assert len(chain.ahead()) >= 3 and cb.ahead_blocks == len(chain.ahead())
+    assert not chain.hoarded
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["legacy", "ragged"])
+def test_stop_token_with_the_block_ahead_in_flight(lm, ragged):
+    """A stop token is a completion the host cannot foresee: it lands in
+    block N with block N+1 already enqueued.  The stream ends on the stop
+    token, the dead block emits nothing (its lane is dead in the carry,
+    its writes go to the scratch page), every page comes home, and the
+    request admitted to that lane while the dead block is still in flight
+    reads no stale K/V."""
+    rng = np.random.default_rng(31)
+    pa = rng.integers(0, 64, (6,), np.int32)
+    pb = rng.integers(0, 64, (9,), np.int32)
+    ref_a, ref_b = _reference(lm, [(pa, 60, _sub(5)), (pb, 20, _sub(6))],
+                              lanes=1, max_len=128, ragged=ragged)
+    # a token first seen in the stream at index >= 18: in block 2 or later
+    # (token i comes out of block (i - 1) // 8)
+    toks = list(ref_a[0])
+    idx = next(i for i in range(18, 52) if toks[i] not in toks[:i])
+    n_stop = (idx - 1) // 8
+    streamed = []
+    cb = _batcher(lm, 8, lanes=1, max_len=128, ragged=ragged)
+    chain = _Chain(cb)
+    try:
+        fa = cb.submit(pa, 60, stop_tokens=[toks[idx]], **_sub(5))
+        fb = cb.submit(pb, 20, **_sub(
+            6, on_token=lambda tok, i, lp: streamed.append(tok)))
+        got_a = fa.result(timeout=120)
+        got_b = fb.result(timeout=120)
+    finally:
+        cb.shutdown()
+    _same(got_a, (toks[:idx + 1], ref_a[1][:idx + 1]))
+    assert n_stop + 1 in chain.ahead()     # the dead block was in flight,
+    assert chain.ks[:n_stop + 2] == [8] * (n_stop + 2)
+    assert chain.ks[n_stop + 2] != 8 or n_stop + 2 not in chain.ahead()
+    _same(got_b, ref_b)                    # and no block followed it
+    assert streamed == list(ref_b[0])
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+@pytest.mark.parametrize("event", ["cancel", "deadline", "preempt",
+                                   "preempt_kv_offload"])
+def test_host_event_with_the_block_ahead_in_flight(lm, event):
+    """Cancel, deadline expiry and preemption land while block N is being
+    fetched: block N+1 is already on the device's queue.  A cancelled
+    stream emits nothing more (not even block N), an expired one ends at
+    the sweep after N, a preempted one resumes exactly (its K/V swapped
+    out of the pool block N+1 returned, or prefilled again), and the lane's
+    next owner reads no stale K/V."""
+    from tpulab.core.deadline import DeadlineExceeded
+    rng = np.random.default_rng(41)
+    p_low = rng.integers(0, 64, (6,), np.int32)
+    p_next = rng.integers(0, 64, (5,), np.int32)
+    ref_low, ref_next = _reference(
+        lm, [(p_low, 60, _sub(8)), (p_next, 12, _sub(9))], lanes=1,
+        max_len=128)
+    kw = {"kv_offload": 32 << 20} if event == "preempt_kv_offload" else {}
+    cb = _batcher(lm, 8, lanes=1, max_len=128, **kw)
+    chain = _Chain(cb)
+    streamed, others = [], []
+    try:
+        # a streaming consumer: blocks of K = 2; tokens 2n+1, 2n+2 are
+        # block n's, so blocks 0..4 commit tokens 0..10
+        fut = cb.submit(p_low, 60, **_sub(
+            8, on_token=lambda tok, i, lp: streamed.append((i, tok))))
+
+        def act():
+            assert cb._pending_block is not None   # block 6 is in flight
+            if event == "cancel":
+                cb.cancel(fut)
+            elif event == "deadline":
+                cb._requests[fut].deadline = _time.monotonic() - 1.0
+            else:
+                others.append(cb.submit(p_next, 12, priority=10, **_sub(9)))
+
+        chain.at_fetch[5] = act
+        if event == "cancel":
+            with pytest.raises(Exception):
+                fut.result(timeout=120)
+            assert [i for i, _t in streamed] == list(range(11))
+        elif event == "deadline":
+            with pytest.raises(DeadlineExceeded):
+                fut.result(timeout=120)
+            assert [i for i, _t in streamed] == list(range(13))
+        else:
+            _same(fut.result(timeout=120), ref_low)
+            _same(others[0].result(timeout=120), ref_next)
+            assert cb.preemptions == 1
+            assert [i for i, _t in streamed] == list(range(60))
+            if kw:
+                assert (cb.kv_offload.swap_outs >= 1
+                        and cb.kv_offload.swap_ins >= 1)
+        assert [t for _i, t in streamed] == list(
+            ref_low[0][:len(streamed)])
+        assert 6 in chain.ahead() and not chain.at_fetch
+        # the lane's next owner, on pages the discarded block wrote
+        _same(cb.submit(p_next, 12, **_sub(9)).result(timeout=120), ref_next)
+    finally:
+        cb.shutdown()
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+@pytest.mark.parametrize("free, ks, ahead", [
+    (2, [8, 2, 8, 8, 8, 8], [3, 4, 5]),    # block 0 fits, block 1 does not
+    (3, [8, 8, 2, 8, 8, 8], [1, 4, 5]),    # block 1 ahead, block 2 refused
+    (4, [8, 8, 8, 2, 8, 8], [1, 2, 5]),
+])
+def test_chain_ahead_under_page_pressure_keeps_k_and_bounds_the_hoard(
+        lm, free, ks, ahead):
+    """Where the pool cannot cover the block ahead the chain does not run
+    ahead for it and never shrinks K for it: the block is planned after
+    its predecessor's commit, as it always was (that regular plan may
+    shrink K, here to the 2 steps the lane's last page still holds).  The
+    lane is not starved once pages return, every token is the
+    reference's, and a lane never holds pages past ``length + 2K``."""
+    p = np.random.default_rng(11).integers(0, 64, (6,), np.int32)
+    (want,) = _reference(lm, [(p, 41, _sub(21))], lanes=1)
+    cb = _batcher(lm, 8, lanes=1)
+    chain = _Chain(cb)
+    try:
+        # all but ``free`` pages are somebody else's until the short block
+        # (the one K shrank for) is being fetched
+        held = [cb.pool.allocate_page()
+                for _ in range(cb.pool.free_pages - free)]
+        chain.at_fetch[ks.index(2)] = lambda: cb.pool.release_pages(held)
+        got = cb.submit(p, 41, **_sub(21)).result(timeout=120)
+    finally:
+        cb.shutdown()
+    _same(got, want)
+    assert chain.ks == ks and chain.ahead() == ahead
+    assert cb.ahead_blocks == len(ahead)
+    assert not chain.hoarded
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+def test_a_request_that_arrives_during_a_dispatch_does_not_hold_the_chain(lm):
+    """A closed-loop caller's next request arrives while the scheduler
+    plans and enqueues a chain's first block, after the admission at the
+    top of its pass.  Queued, it would read as queue pressure to the K
+    policy (a streaming lane drops its K <= 2 cap) and hold block 1 back
+    until block 0 was fetched and its commit admitted the request; the
+    decision before the fetch admits it first, so block 1 runs ahead."""
+    rng = np.random.default_rng(51)
+    pa = rng.integers(0, 64, (6,), np.int32)
+    pb = rng.integers(0, 64, (7,), np.int32)
+    ref_a, ref_b = _reference(lm, [(pa, 30, _sub(12)), (pb, 10, _sub(13))],
+                              lanes=1, max_len=128)
+    cb = _batcher(lm, 8, lanes=2, max_len=128)
+    chain = _Chain(cb)
+    late = []
+    try:
+        chain.at_enqueue[0] = lambda: late.append(
+            cb.submit(pb, 10, **_sub(13)))
+        fa = cb.submit(pa, 30, **_sub(12, on_token=lambda tok, i, lp: None))
+        _same(fa.result(timeout=120), ref_a)
+        _same(late[0].result(timeout=120), ref_b)
+    finally:
+        cb.shutdown()
+    assert chain.ks[:2] == [2, 2] and 1 in chain.ahead()
+    assert cb.pool.free_pages == cb.pool.n_pages - 1

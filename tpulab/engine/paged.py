@@ -573,6 +573,7 @@ class ContinuousBatcher:
         self._block_cache: Dict[int, Any] = {}
         self._pending_block: Optional[Dict[str, Any]] = None
         self._step_ewma_s = 0.0   # per-scan-step device time estimate
+        self._block_fetched_t = 0.0   # return of the last decode block's fetch
         # -- dispatch/sync accounting (tokens_per_dispatch telemetry and
         #    the host-syncs-per-request regression guard read these) ------
         self.decode_dispatches = 0   # device decode dispatches (any K)
@@ -598,6 +599,10 @@ class ContinuousBatcher:
         #: sum of K over plain decode dispatches (K-blocks and single
         #: ticks): over ``dispatch_kinds["decode"]`` it is the mean block
         self.decode_block_steps = 0
+        #: decode blocks enqueued before their predecessor was fetched
+        #: (_chain_block): over ``dispatch_kinds["decode"]`` the share of
+        #: blocks whose host turn the device did not wait for
+        self.ahead_blocks = 0
         #: where a scheduler pass goes (docs/OBSERVABILITY.md "Debugz"):
         #: disjoint stages of the scheduler thread, each a ``sched.<stage>``
         #: span in a profiler capture and seconds + entries here
@@ -1333,6 +1338,7 @@ class ContinuousBatcher:
                          "mixed_rows": self.mixed_rows,
                          "mixed_tokens": self.mixed_tokens,
                          "decode_block_steps": self.decode_block_steps,
+                         "ahead_blocks": self.ahead_blocks,
                          "stages": self._stages.stages(),
                          "queue_wait_s": self.queue_wait_s,
                          "queue_waits": self.queue_waits,
@@ -2476,8 +2482,10 @@ class ContinuousBatcher:
         est = self._step_ewma_s or 0.005
         return min(1.0, max(0.05, 2.0 * self.decode_block * est))
 
-    def _pick_block_k(self, decode_lanes) -> int:
-        """Adaptive fused-decode block size for this dispatch.
+    def _pick_block_k(self, decode_lanes, ahead: int = 0) -> int:
+        """Adaptive fused-decode block size for this dispatch.  ``ahead``
+        is the steps of a block still in flight that the lanes' step
+        budgets do not show yet (see :meth:`_chain_block`).
 
         - any host-sampled (``top_k``/``top_p``) lane -> 1: its per-token
           pick needs the logits row on host every tick;
@@ -2509,7 +2517,8 @@ class ContinuousBatcher:
                 # hook is a durable checkpoint sink, not an interactive
                 # consumer — never let it drag the whole block to K<=2
                 streaming = True
-            max_rem = max(max_rem, req.steps - len(req.tokens_out))
+            max_rem = max(max_rem,
+                          req.steps - len(req.tokens_out) - ahead)
         if streaming and not self._queue:
             want = min(want, 2)
         cover = next((m for m in self.BLOCK_K_MENU if m >= max_rem),
@@ -2517,7 +2526,7 @@ class ContinuousBatcher:
         k = min(want, cover)
         return max(m for m in self.BLOCK_K_MENU if m <= k)
 
-    def _reserve_block_pages(self, decode_lanes, k: int):
+    def _reserve_block_pages(self, decode_lanes, k: int, ahead: int = 0):
         """Pre-allocate every page the next K appends will write, per lane.
 
         Decode step j writes K/V at position ``length + j`` — the device
@@ -2530,14 +2539,21 @@ class ContinuousBatcher:
         every participating lane can cover (snapped down onto
         BLOCK_K_MENU, surplus pages returned); a lane that cannot cover
         even one append skips this block entirely (same as the old
-        per-tick starvation skip).  Returns ``(k_eff, [(lane, req,
-        new_pages), ...])``.
+        per-tick starvation skip).  ``ahead`` is the steps of a block
+        still in flight: the lane's committed length and step budget lag
+        the block being reserved by that many, so it writes from
+        ``length + ahead`` (a lane then holds pages for at most two
+        blocks past its committed length).  Returns ``(k_eff, [(lane,
+        req, new_pages), ...])``.
         """
+        ps = self.page_size
         parts = []
         cap = k
         for lane, req in decode_lanes:
-            appends_want = max(1, min(k, req.steps - len(req.tokens_out)))
-            need = (req.length + appends_want - 1) // self.page_size + 1
+            base = req.length + ahead
+            rem = req.steps - len(req.tokens_out) - ahead
+            appends_want = max(1, min(k, rem))
+            need = (base + appends_want - 1) // ps + 1
             new: List[int] = []
             while len(req.pages) < need:
                 page = self._alloc_page()
@@ -2545,8 +2561,7 @@ class ContinuousBatcher:
                     break
                 req.pages.append(page)
                 new.append(page)
-            covered = len(req.pages) * self.page_size - req.length
-            appends = min(appends_want, covered)
+            appends = min(appends_want, len(req.pages) * ps - base)
             if appends <= 0:
                 for _ in new:  # starved: return the partial take
                     self.pool.release_pages([req.pages.pop()])
@@ -2560,9 +2575,9 @@ class ContinuousBatcher:
         if k_eff < k:
             # shrunk block: give back pages past the new write horizon
             for _lane, req, new in parts:
-                appends_eff = max(1, min(k_eff,
-                                         req.steps - len(req.tokens_out)))
-                need = (req.length + appends_eff - 1) // self.page_size + 1
+                base = req.length + ahead
+                rem = req.steps - len(req.tokens_out) - ahead
+                need = (base + max(1, min(k_eff, rem)) - 1) // ps + 1
                 while len(req.pages) > need and new:
                     self.pool.release_pages([req.pages.pop()])
                     new.pop()
@@ -2821,13 +2836,93 @@ class ContinuousBatcher:
                 "moe": moe, "carry": (len_f, tok_f, live_f, rem_f),
                 "host": (temps, seeds, stops), "t0": t0}
 
+    def _chain_block(self, stash, jnp, ahead: int):
+        """Enqueue the block that follows ``stash`` from its
+        device-resident carry, or return None where the chain must break.
+
+        Called twice by :meth:`_consume_block`.  BEFORE the fetch
+        (``ahead`` = the block's K: its tokens are not committed, so every
+        lane's length and step budget lag by K) block N+1 goes on the
+        device's queue behind block N, and the fetch, commit and emit of N
+        overlap it.  Everything the decision needs the host has known
+        since it enqueued N: the lane set is still the block's (cancel,
+        deadline sweep and preemption are host events; a queued request
+        is admitted first, as the commit would), no lane is waiting to
+        join, the same adaptive K is still the right choice,
+        and no lane's step budget ends inside N — a completion the host
+        can foresee breaks the chain, so the freed lane is re-admitted
+        before the next dispatch, never a block later.  A stop token it
+        cannot foresee ends a lane with N+1 already in flight: the carry's
+        live mask is false for it there (its writes go to the scratch
+        page) and the consume discards a lane that is no longer the
+        block's.  AFTER the commit (``ahead`` = 0) the same rule gives the
+        old order, for a block the first call held back and the commit
+        cleared (a deadline or a queue the K policy saw, pages that came
+        home).  Never shrinks K: pages reserved for a refused block stay
+        on the lanes for the next regular plan (bounded hoard: two blocks
+        a lane)."""
+        st = self._stages
+        k = stash["k"]
+        if k <= 1:
+            return None
+        lanes_now = list(stash["lane_reqs"].items())
+        with stage(st, "plan"):
+            with self._cv:
+                if self._shutdown or self._hbm_reclaim_bytes:
+                    return None
+                if ahead and self._queue:
+                    # a request that arrived since the top of _run takes
+                    # its lane now, not at the commit after the fetch:
+                    # left queued it reads as queue pressure to
+                    # _pick_block_k and holds this block back for a fetch
+                    with stage(st, "admit"):
+                        self._admit_locked()
+                for lane, req in lanes_now:
+                    # released (cancel/deadline sweep, a stop token) or
+                    # preempted since dispatch, or about to complete
+                    if (self._active[lane] is not req or req.cancelled
+                            or req.steps - len(req.tokens_out) <= ahead):
+                        return None
+                # a lane that finished its prompt while this chain ran (its
+                # first token is out) is in no block of the chain: chaining
+                # on would leave it without a step until a lane of the
+                # chain completes, hundreds of steps at long outputs
+                if any(r is not None and lane not in stash["lane_reqs"]
+                       and not r.pending_prompt and r.tokens_out
+                       and not r.cancelled
+                       for lane, r in enumerate(self._active)):
+                    return None
+            # a lane that re-armed speculation (a probe countdown expired)
+            # must flow back through _plan_decode — a plain chain here
+            # would starve the probe forever
+            if (self._spec is not None
+                    and all(self._spec_eligible(r) for _, r in lanes_now)):
+                return None
+            if self._pick_block_k(lanes_now, ahead) != k:
+                return None
+            k2, parts = self._reserve_block_pages(lanes_now, k, ahead)
+            if k2 != k or len(parts) != len(lanes_now):
+                return None
+        with stage(st, "dispatch"):
+            return self._dispatch_block(parts, k, jnp, carry=stash["carry"],
+                                        host=stash["host"])
+
     def _consume_block(self, stash, jnp) -> bool:
         """Fetch a dispatched block (ONE host sync for up to K tokens per
         lane) and unpack it through the per-token emit/trace/metrics
-        path; may dispatch the NEXT block before running the emit
-        callbacks (overlapping device compute with host-side emit)."""
+        path.  Block N+1 is enqueued first where :meth:`_chain_block`
+        allows, so the device computes it while the host fetches,
+        commits and emits block N; two blocks are un-fetched only inside
+        this method (one at the top of ``_run``'s loop, as ever).
+        Correctness never depends on the chain: a request released
+        between dispatch and consume has its block discarded below, and
+        its stale device writes only touch positions a new page owner
+        rewrites before reading."""
         st = self._stages
         k = stash["k"]
+        self._pending_block = self._chain_block(stash, jnp, ahead=k)
+        if self._pending_block is not None:
+            self.ahead_blocks += 1
         with stage(st, "fetch"):
             toks = np.asarray(stash["dev"][0], np.int32)
             lps = np.asarray(stash["dev"][1], np.float32)
@@ -2835,12 +2930,14 @@ class ContinuousBatcher:
             self._note_moe(stash["moe"], decode=True)
         self.decode_host_syncs += 1
         now = _time.perf_counter()  # post-fetch: device work is done
-        self._step_ewma_s = (
-            0.8 * self._step_ewma_s + 0.2 * ((now - stash["t0"]) / k)
-            if self._step_ewma_s else (now - stash["t0"]) / k)
+        # a block enqueued ahead started when its predecessor ended, which
+        # the host saw as the previous fetch's return
+        step_s = (now - max(stash["t0"], self._block_fetched_t)) / k
+        self._block_fetched_t = now
+        self._step_ewma_s = (0.8 * self._step_ewma_s + 0.2 * step_s
+                             if self._step_ewma_s else step_s)
         emits: List = []
         completed: List = []
-        clean = True        # every dispatched lane is still this request's
         emitted_total = 0
         with stage(st, "commit"), self._cv:
             for lane, req in stash["lane_reqs"].items():
@@ -2848,7 +2945,6 @@ class ContinuousBatcher:
                     # released (cancel/deadline sweep) or preempted since
                     # dispatch: its block tokens are DISCARDED — a resume
                     # regenerates them exactly, a cancel never emits them
-                    clean = False
                     continue
                 self._probe_countdown_locked(req)
                 n = int(ems[lane].sum())   # prefix mask: first n are valid
@@ -2880,49 +2976,11 @@ class ContinuousBatcher:
                     completed.append(req)
             with stage(st, "admit"):
                 self._admit_locked()
-            # a lane that finished its prompt while this chain ran (its
-            # first token is out) is in no block of the chain: chaining
-            # ahead would leave it without a step until a lane of the
-            # chain completes, hundreds of steps at long outputs
-            joiner = any(
-                r is not None and lane not in stash["lane_reqs"]
-                and not r.pending_prompt and r.tokens_out and not r.cancelled
-                for lane, r in enumerate(self._active))
         if self.trace is not None and emitted_total:
             self.trace.add_counter("decode_block", now,
                                    tokens=emitted_total, k=k)
-        # dispatch-ahead: with the lane set stable (nothing finished, no
-        # cancel/preempt observed) and the SAME adaptive K still the right
-        # choice, and no other lane waiting to join, enqueue block N+1
-        # from the device-resident carry BEFORE running block N's
-        # callbacks — the next block computes while the host emits.  Correctness never depends on this: a request
-        # released between dispatch and consume has its block discarded
-        # above, and its stale device writes only touch positions a new
-        # page owner rewrites before reading.
-        if (clean and not completed and not joiner and k > 1
-                and self._pending_block is None and not self._shutdown
-                and not self._hbm_reclaim_bytes):
-            lanes_now = list(stash["lane_reqs"].items())
-            parts2 = None
-            with stage(st, "plan"):
-                # a lane that just re-armed speculation (a probe countdown
-                # expiring above) must flow back through _plan_decode — a
-                # plain chain-ahead here would starve the probe forever
-                spec_next = (self._spec is not None
-                             and all(self._spec_eligible(r)
-                                     for _, r in lanes_now))
-                if not spec_next and self._pick_block_k(lanes_now) == k:
-                    k2, parts2 = self._reserve_block_pages(lanes_now, k)
-                    if k2 != k or len(parts2) != len(lanes_now):
-                        # pages stay reserved on the lanes for the next
-                        # regular plan (bounded hoard: <= one block per
-                        # lane)
-                        parts2 = None
-            if parts2 is not None:
-                with stage(st, "dispatch"):
-                    self._pending_block = self._dispatch_block(
-                        parts2, k, jnp, carry=stash["carry"],
-                        host=stash["host"])
+        if self._pending_block is None:
+            self._pending_block = self._chain_block(stash, jnp, ahead=0)
         self._deliver(emits, completed)
         return True
 
