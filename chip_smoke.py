@@ -24,6 +24,11 @@ Phases, in order; any phase that raises fails the run (exit 1):
               latent page store: a mixed round and a decode step through
               the latent ragged kernel against the XLA gather, the logit
               error printed.
+   jamba    — a three-layer Mamba/attention hybrid (AI21-Jamba2-3B's
+              widths: Mamba, attention, Mamba) on a lane-state store beside
+              the page store: three mixed rounds and a decode step through the
+              ``selective_scan`` and ragged kernels against the XLA forms,
+              logits and lane state.
 4. kernels  — the ragged and flash Pallas kernels compiled by Mosaic
               (``interpret=False``, custom call present in the lowered
               program) against the XLA gather / dense-softmax paths.
@@ -94,6 +99,14 @@ class Sizes:
         routed_scaling_factor=1.8, norm_topk_prob=True, rms_norm_eps=1e-5,
         rope_theta=1e6, vocab_size=50304))
     glm_chunk: int = 256
+    # AI21-Jamba2-3B's published widths (perf/configs/jamba2-3b.json);
+    # depth (one period of a shorter pattern) and vocabulary are the cuts
+    jamba: dict = field(default_factory=lambda: dict(
+        hidden_size=2560, intermediate_size=8192, num_attention_heads=20,
+        num_key_value_heads=1, num_hidden_layers=3, attn_layer_period=3,
+        attn_layer_offset=1, mamba_d_state=16, mamba_d_conv=4,
+        mamba_dt_rank=160, mamba_expand=2, num_experts=1,
+        rms_norm_eps=1e-6, vocab_size=50304))
     lm_max_len: int = 512
     lm_page_size: int = 16
     lm_prefill_chunk: int = 128
@@ -116,6 +129,11 @@ REHEARSAL_SIZES = Sizes(
              routed_scaling_factor=1.8, norm_topk_prob=True,
              rms_norm_eps=1e-5, rope_theta=1e6, vocab_size=256),
     glm_chunk=16,
+    jamba=dict(hidden_size=64, intermediate_size=96, num_attention_heads=4,
+               num_key_value_heads=1, num_hidden_layers=3,
+               attn_layer_period=3, attn_layer_offset=1, mamba_d_state=8,
+               mamba_d_conv=4, mamba_dt_rank=6, mamba_expand=2,
+               num_experts=1, rms_norm_eps=1e-6, vocab_size=256),
     lm_max_len=96, lm_page_size=8, lm_prefill_chunk=16,
     lm_prompt_lens=(5, 12, 40), lm_steps=6, flash_t=32)
 
@@ -420,6 +438,89 @@ def phase_latent(smoke: Smoke) -> str:
                                 f"{spec.n_experts} experts hit")
 
 
+# -- phase 3c: Mamba layers on a per-lane state -------------------------------
+def phase_jamba(smoke: Smoke) -> str:
+    """Three mixed rounds and a decode step of a Mamba / attention / Mamba
+    model over a lane-state store filled with junk (a reused lane): the
+    ``selective_scan`` and ragged kernels against the ``lax.scan`` form and
+    the XLA gather on the same inputs, logits and lane state."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.kv_pool import PagedKVPool, lane_state_shapes
+    from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
+                                           paged_mixed_step)
+    from tpulab.models.spec import init_params, jamba_spec
+    sz = smoke.sizes
+    cfg, chunk, page = sz.jamba, sz.glm_chunk, sz.lm_page_size
+    spec = jamba_spec(cfg)
+    vocab = cfg["vocab_size"]
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        init_params(spec, vocab, cfg["intermediate_size"]))
+    lanes, mp = 4, 3 * chunk // page
+    rng = np.random.default_rng(2)
+    tables = 1 + np.arange(lanes * mp, dtype=np.int32).reshape(lanes, mp)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    kw = dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
+              compute_dtype=jnp.bfloat16, spec=spec)
+    draw = lambda n: rng.integers(0, vocab, n)
+    temps, seeds = jnp.zeros((lanes,), jnp.float32), jnp.zeros(
+        (lanes, 2), jnp.uint32)
+    # rounds 1, 2: three lanes' first chunks (a round carries at most
+    # ``chunk`` prompt tokens, the engine's budget); round 3: lane 0 its
+    # second chunk, lanes 1, 2 decode, lane 3 a first chunk of 7 into a slot
+    # that holds junk; then a decode step of all four
+    half = chunk // 2
+    rounds = [({0: draw(chunk)}, {}, [0, 0, 0, 0]),
+              ({1: draw(half - 3), 2: draw(half)}, {}, [chunk, 0, 0, 0]),
+              ({0: draw(chunk - 8), 3: draw(7)},
+               {1: int(draw(1)[0]), 2: int(draw(1)[0])},
+               [chunk, half - 3, half, 0])]
+    final = [2 * chunk - 8, half - 2, half + 1, 7]
+    out = {}
+    for name, uk in (("xla", False), ("kernel", True)):
+        pool = PagedKVPool(lanes * mp + 1, page, len(spec.attention_layers),
+                           spec.n_kv_heads, spec.head_dim, jnp.bfloat16)
+        store = (pool.kv, tuple(jnp.full(shape, 3, dtype) for shape, dtype
+                                in lane_state_shapes(spec, lanes,
+                                                     jnp.bfloat16)))
+        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **kw),
+                        donate_argnums=(1,))
+        step = jax.jit(partial(paged_decode_step, use_kernel=uk, **kw),
+                       donate_argnums=(1,))
+        for prefill, decode, lengths in rounds:
+            toks, row_lane, row_off, q_lens = pack_round(lanes, prefill,
+                                                         decode)
+            args = (i32(tables), i32(toks), i32(row_lane), i32(row_off),
+                    i32(q_lens), i32(np.asarray(lengths) + q_lens), temps,
+                    seeds)
+            if uk and decode:
+                check_mosaic(smoke, "jamba mixed round", partial(
+                    paged_mixed_step, use_kernel=True, **kw), params, store,
+                    *args)
+            _, _, last, store = mixed(params, store, *args)
+        logits, store = step(params, store, i32(tables), i32(final),
+                             i32([5, 6, 7, 8]), jnp.ones((lanes,), bool))
+        out[name] = (np.asarray(last, np.float32),
+                     np.asarray(logits, np.float32),
+                     np.asarray(store[1][0]))
+        pool.close()
+    report = []
+    for i, what in enumerate(("mixed round", "decode step", "ssm state")):
+        ref, got = out["xla"][i], out["kernel"][i]
+        err = float(np.abs(got - ref).max())
+        scale = float(np.abs(ref).max())
+        if not np.isfinite(got).all() or err > LOGIT_RTOL * scale:
+            raise AssertionError(f"jamba {what}: with the kernels {err:.4g} "
+                                 f"from the XLA forms (largest {scale:.4g})")
+        report.append(f"{what} err {err:.4g} of {scale:.4g}")
+    return "; ".join(report)
+
+
 # -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------
 def check_mosaic(smoke: Smoke, name: str, fn, *args) -> None:
     """On the chip the lowered program must hold the Mosaic custom call;
@@ -687,6 +788,7 @@ def main(argv=None) -> int:
         smoke.run("rn50", phase_rn50)
         smoke.run("lm", phase_lm)
         smoke.run("latent", phase_latent)
+        smoke.run("jamba", phase_jamba)
         smoke.run("kernels", phase_kernels)
         smoke.run("multichip", phase_multichip)
 

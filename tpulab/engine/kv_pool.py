@@ -12,7 +12,10 @@ and who holds it:
   host-side free list, reference counts, grow/shrink and the host<->device
   page moves the host tier, the disagg wire and the fabric ride;
 - :class:`PrefixCache` — page-granular sharing of prompt prefixes over the
-  pool's reference counts.
+  pool's reference counts;
+- :class:`LaneStateStore` — the second kind of state, indexed by lane and
+  not by page: what a Mamba layer keeps of a sequence (its SSM state and
+  the tail of its causal convolution), of one size whatever the context.
 
 The step programs that read and write the pool are
 :mod:`tpulab.engine.paged_steps`; the scheduler that hands pages out is
@@ -334,6 +337,86 @@ class PagedKVPool:
             self._shape = (self._shape[0], cut) + self._shape[2:]
         self.kv = self._kv[:, :cut]
         return k
+
+
+def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
+    """``((shape, dtype) of ssm, (shape, dtype) of conv)``: the ONE
+    definition of what a model's Mamba layers keep a lane.
+
+    ``ssm``   ``(mamba layers, lanes, d_state, d_inner)`` float32: the SSM
+              state ``h`` (the published kernel accumulates in float32; in
+              bf16 its rounding compounds over every token of a sequence).
+              Channels are the minor dimension, so a lane's ``(d_state,
+              d_inner)`` is whole (8, 128) tiles on the device;
+    ``conv``  ``(mamba layers, d_conv - 1, lanes, d_inner)`` in ``dtype``
+              (the compute type): the last ``d_conv - 1`` inputs of the
+              layer's causal convolution, oldest first (lanes ahead of
+              channels, so the tile's sublanes are not padded from 3 to
+              16)."""
+    n_layers = len(spec.mamba_layers)
+    return (((n_layers, lanes, spec.d_state, spec.d_inner),
+             np.dtype(np.float32)),
+            ((n_layers, spec.d_conv - 1, lanes, spec.d_inner),
+             np.dtype(dtype)))
+
+
+class LaneStateStore:
+    """Per-lane recurrent state of a model's Mamba layers, beside the page
+    store: ``arrays = (ssm, conv)``, shaped by :func:`lane_state_shapes`.
+
+    A lane's slot belongs to whatever sequence runs in the lane.  Nothing
+    here resets it: the step programs start a segment at position 0 from
+    zeros whatever the slot holds (:mod:`tpulab.engine.paged_steps`), so
+    admission, lane reuse and a resume after preemption need no dispatch.
+    The pair rotates through the donated step programs like the page store;
+    the device allocator tracks it as one block."""
+
+    def __init__(self, spec, lanes: int, dtype=None, device=None):
+        import jax.numpy as jnp
+        from tpulab.tpu import platform as plat
+        from tpulab.tpu.allocators import make_tpu_allocator
+
+        if not spec.mamba_layers:
+            raise ValueError("the model has no Mamba layer: no lane state")
+        self.lanes = lanes
+        self.device = device if device is not None else plat.local_device(0)
+        self._shapes = lane_state_shapes(spec, lanes, dtype or jnp.bfloat16)
+        self._alloc = make_tpu_allocator(self.device)
+        self._addr, self._arrays = self._alloc.allocate_tree(self._zeros())
+
+    def _zeros(self):
+        import jax.numpy as jnp
+        return tuple(jnp.zeros(shape, dtype) for shape, dtype in self._shapes)
+
+    @property
+    def arrays(self):
+        return self._arrays
+
+    @arrays.setter
+    def arrays(self, value) -> None:
+        self._arrays = self._alloc.replace(self._addr, tuple(value))
+
+    @property
+    def hbm_bytes(self) -> int:
+        return (self._alloc.node_size(self._addr)
+                if self._addr is not None else 0)
+
+    @property
+    def bytes_per_lane(self) -> int:
+        """State bytes a lane holds, all Mamba layers, whatever its
+        context."""
+        return self.hbm_bytes // self.lanes
+
+    def reset(self) -> None:
+        """Re-materialize the store (recovery after a failed donated
+        step)."""
+        import jax
+        self.arrays = jax.device_put(self._zeros(), self.device)
+
+    def close(self) -> None:
+        if self._addr is not None:
+            self._alloc.deallocate_node(self._addr)
+            self._addr = self._arrays = None
 
 
 class PrefixCache:

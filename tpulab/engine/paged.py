@@ -26,7 +26,7 @@ import numpy as np
 
 from tpulab import chaos
 from tpulab.core.deadline import Deadline, DeadlineExceeded
-from tpulab.engine.kv_pool import PagedKVPool, PrefixCache
+from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool, PrefixCache
 from tpulab.engine.paged_steps import (_device_sample_token, pack_round,
                                        paged_decode_block, paged_decode_step,
                                        paged_decode_step_sampled,
@@ -275,9 +275,17 @@ class ContinuousBatcher:
     the page store and runs in the absorbed form (gather or the latent
     ragged kernel); expert layers run the routed FFN of
     tpulab.parallel.moe and count assignments (``debug_state()["moe"]``).
-    Such a spec is served on the ragged plan only; the options that plan
-    or that cache entry does not carry are refused at construction, by
-    name.
+    Mamba layers (``spec.mixers``) leave no pages: their recurrent state
+    lives in a :class:`~tpulab.engine.kv_pool.LaneStateStore`, a slot a
+    lane, which rotates through every dispatch beside the page store (the
+    run-ahead chain enqueues block N+1 on the state block N returns); the
+    page store then holds the attention layers alone.  A segment that
+    starts at position 0 starts from zeros on the device, so admission,
+    lane reuse and a re-prefill after preemption need no reset dispatch
+    (``debug_state()["state"]``).
+    Such a spec is served on the ragged plan only; the options that plan,
+    that cache entry or a per-lane state does not carry are refused at
+    construction, by name.
 
     Tiered KV (``kv_offload=``, tpulab.kvcache): preemption swaps the
     victim's KV pages to a budgeted host-RAM tier (async, write-behind)
@@ -332,12 +340,14 @@ class ContinuousBatcher:
         compute_dtype = compute_dtype or jnp.bfloat16
         #: tpulab.models.spec.ModelSpec: None serves the dense decoder of
         #: ``n_heads``/``n_kv_heads``/``rope_theta`` with today's constants;
-        #: a spec with a latent cache or expert layers is served on the
-        #: ragged plan alone, and the options that plan or that cache-entry
-        #: kind does not carry yet are refused here, by name
+        #: a spec with a latent cache, expert layers or Mamba layers is
+        #: served on the ragged plan alone, and the options that plan, that
+        #: cache-entry kind or a per-lane state (nothing snapshots, shares
+        #: or ships it yet) does not carry are refused here, by name
         self.model_spec = spec
+        hybrid = spec is not None and bool(spec.mamba_layers)
         special = spec is not None and (spec.cache_entry != "kv"
-                                        or spec.moe_layers)
+                                        or spec.moe_layers or hybrid)
         if special:
             refused = {
                 "ragged=False (the legacy split plan)": ragged is False,
@@ -355,8 +365,9 @@ class ContinuousBatcher:
             bad = [name for name, on in refused.items() if on]
             if bad:
                 raise NotImplementedError(
-                    "a model with a latent cache or expert layers is served "
-                    "on the ragged plan only; not supported with it: "
+                    "a model with a latent cache, expert layers or Mamba "
+                    "layers is served on the ragged plan only; not "
+                    "supported with it: "
                     + ", ".join(bad))
             if (spec.n_heads, spec.n_layers) != (n_heads, n_layers):
                 raise ValueError(
@@ -369,7 +380,9 @@ class ContinuousBatcher:
         # KV-bandwidth-bound).  Writes round on scatter, reads upcast in
         # the gather/kernel; attention math stays in f32 either way.
         kv_dtype = kv_dtype or compute_dtype
-        n_kv = n_kv_heads or n_heads
+        # a hybrid's attention layers are the spec's: its KV heads size
+        # the pages
+        n_kv = spec.n_kv_heads if hybrid else n_kv_heads or n_heads
         self.lanes = lanes
         self.max_len = max_len
         self.page_size = page_size
@@ -398,10 +411,22 @@ class ContinuousBatcher:
         if pool is not None and (pool.entry_kind == "latent") != bool(latent):
             raise ValueError(f"the provided pool holds {pool.entry_kind!r} "
                              "entries, the model another kind")
+        # only attention layers own a layer of the page store
+        pool_layers = len(spec.attention_layers) if hybrid else n_layers
+        if pool is not None and pool.n_layers != pool_layers:
+            raise ValueError(f"the provided pool has {pool.n_layers} layers, "
+                             f"the model {pool_layers} attention layers")
         self.pool = pool or PagedKVPool(
-            n_pages or self.max_pages * lanes + 1, page_size, n_layers,
+            n_pages or self.max_pages * lanes + 1, page_size, pool_layers,
             0 if latent else n_kv, 0 if latent else d_model // n_heads,
             kv_dtype, device, mesh=mesh, latent_width=latent)
+        #: the Mamba layers' per-lane recurrent state (None without any):
+        #: rotates through every dispatch beside ``pool.kv`` (_kv_state)
+        self.state = (LaneStateStore(spec, lanes, compute_dtype,
+                                     self.pool.device) if hybrid else None)
+        #: segments the device started from zeros (a first chunk at
+        #: position 0: admissions and re-prefills after preemption)
+        self.zero_starts = 0
         if pool is not None and mesh is not None and pool.mesh is not mesh:
             raise ValueError("provided pool was built on a different mesh "
                              "than the batcher's")
@@ -482,6 +507,11 @@ class ContinuousBatcher:
             budget under the ragged plan, a K+1 verify otherwise."""
             from tpulab.ops.ragged_attention import (kernel_geometry_error,
                                                      latent_geometry_error)
+            if hybrid:
+                from tpulab.ops.selective_scan import scan_geometry_error
+                err = scan_geometry_error(spec.d_inner, spec.d_state)
+                if err:
+                    return err
             widest = (round_width(self._round_budget)
                       if ragged is not False else self.BLOCK_K_MENU[-1] + 1)
             if latent:
@@ -515,6 +545,8 @@ class ContinuousBatcher:
             if err:
                 if self._owns_pool:
                     self.pool.close()
+                if self.state is not None:
+                    self.state.close()
                 raise ValueError(f"use_kernel=True: {err}")
         self.use_kernel = bool(use_kernel)
         #: ragged dispatch plan (docs/PERFORMANCE.md "Ragged paged
@@ -787,6 +819,22 @@ class ContinuousBatcher:
         self._thread = threading.Thread(target=self._run, name="cbatch",
                                         daemon=True)
         self._thread.start()
+
+    @property
+    def _kv_state(self):
+        """What the step programs take as ``kv_pool``, donate and return:
+        the page store, or with Mamba layers the pair ``(page store, lane
+        state)``."""
+        if self.state is None:
+            return self.pool.kv
+        return self.pool.kv, self.state.arrays
+
+    @_kv_state.setter
+    def _kv_state(self, value) -> None:
+        if self.state is None:
+            self.pool.kv = value
+        else:
+            self.pool.kv, self.state.arrays = value
 
     #: the stages of a scheduler pass, in the order a pass takes them
     STAGES = ("admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
@@ -1063,6 +1111,8 @@ class ContinuousBatcher:
             self.prefix_cache.clear()  # release the cache's page refs
         if self._owns_offload and not self._thread.is_alive():
             self.kv_offload.close()  # drain write-behind, free host tier
+        if self.state is not None and not self._thread.is_alive():
+            self.state.close()
         if self._owns_pool and not self._thread.is_alive():
             self.pool.close()  # free the page stores' HBM eagerly
             if self.hbm is not None:
@@ -1368,6 +1418,11 @@ class ContinuousBatcher:
                 "decode_steps": self.moe_decode_steps,
                 # summed over decode steps and expert layers
                 "experts_hit": self.moe_experts_hit}
+        if self.state is not None:
+            out["state"] = {"kind": "mamba", "lanes": self.state.lanes,
+                            "bytes_per_lane": self.state.bytes_per_lane,
+                            "hbm_bytes": self.state.hbm_bytes,
+                            "zero_starts": self.zero_starts}
         pc = self.prefix_cache
         if pc is not None:
             out["prefix_cache"] = {"entries": len(pc), "hits": pc.hits,
@@ -1854,6 +1909,8 @@ class ContinuousBatcher:
                 if self.prefix_cache is not None:
                     self.prefix_cache.drop_all()  # entries died with the pool
                 self.pool.reset()
+                if self.state is not None:
+                    self.state.reset()
 
     def _do_prefill(self, req: _PagedRequest, jnp, lane: int = 0) -> bool:
         """Fused prompt prefill: one compiled forward (per length bucket)
@@ -2248,6 +2305,8 @@ class ContinuousBatcher:
                 chunks[lane] = min(len(req.pending_prompt), left)
                 left -= chunks[lane]
             segs = [(lane, req) for lane, req in segs if chunks[lane]]
+            if self.state is not None:
+                self.zero_starts += sum(req.length == 0 for _, req in segs)
             b = self.lanes
             toks, row_lane, row_off, q_lens = pack_round(
                 b, {lane: req.pending_prompt[:chunks[lane]]
@@ -2279,8 +2338,8 @@ class ContinuousBatcher:
                 # decode lanes advance one tick this round — same fault site
                 chaos.trip("engine.step")
             t0 = _time.perf_counter()
-            nt_dev, lp_dev, last_dev, self.pool.kv, *moe = self._mixed(
-                self.params, self.pool.kv, jnp.asarray(tables),
+            nt_dev, lp_dev, last_dev, self._kv_state, *moe = self._mixed(
+                self.params, self._kv_state, jnp.asarray(tables),
                 jnp.asarray(toks), jnp.asarray(row_lane),
                 jnp.asarray(row_off), jnp.asarray(q_lens),
                 jnp.asarray(kv_lens), jnp.asarray(temps),
@@ -2824,8 +2883,8 @@ class ContinuousBatcher:
             chaos.trip("engine.step")
         t0 = _time.perf_counter()
         (toks, lps, ems, len_f, tok_f, live_f, rem_f,
-         self.pool.kv, *moe) = self._block_fn(k)(
-            self.params, self.pool.kv, jnp.asarray(tables),
+         self._kv_state, *moe) = self._block_fn(k)(
+            self.params, self._kv_state, jnp.asarray(tables),
             jnp.asarray(lengths), jnp.asarray(tokens),
             jnp.asarray(active), jnp.asarray(temps), jnp.asarray(seeds),
             jnp.asarray(rem), jnp.asarray(stops))
@@ -3220,9 +3279,9 @@ class ContinuousBatcher:
             t0 = _time.perf_counter()
             logprobs_arr = logp_dev = None
             if temps.any() or want_logp:
-                (tok_dev, logp_dev, logits, self.pool.kv,
+                (tok_dev, logp_dev, logits, self._kv_state,
                  *moe) = self._step_sampled(
-                    self.params, self.pool.kv,
+                    self.params, self._kv_state,
                     jnp.asarray(tables), jnp.asarray(lengths),
                     jnp.asarray(tokens), jnp.asarray(active),
                     jnp.asarray(temps), jnp.asarray(seeds))
@@ -3230,8 +3289,8 @@ class ContinuousBatcher:
                 # neither device sampling nor logprobs this tick: the plain
                 # step (no temps/seeds traced) — greedy stays one device
                 # argmax
-                logits, self.pool.kv, *moe = self._step(
-                    self.params, self.pool.kv,
+                logits, self._kv_state, *moe = self._step(
+                    self.params, self._kv_state,
                     jnp.asarray(tables), jnp.asarray(lengths),
                     jnp.asarray(tokens), jnp.asarray(active))
                 tok_dev = logits.argmax(-1)

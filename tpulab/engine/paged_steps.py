@@ -19,10 +19,14 @@ TPU-first mechanics:
   host<->device RTT, and K amortizes it (docs/PERFORMANCE.md).
 
 Every forward runs ONE layer block (:func:`_layer_block`) read from a
-:class:`~tpulab.models.spec.ModelSpec`.  The functions keep their
-``__name__``: a trace names a program ``jit_<name>``, and the benchmark's
-readers key on it.  Nothing here imports the scheduler
-(:mod:`tpulab.engine.paged`).
+:class:`~tpulab.models.spec.ModelSpec`.  For a model with Mamba layers the
+``kv_pool`` every step function takes, donates, carries through its scan
+and returns is the pair ``(page store, lane state)``: the attention
+layers' pages and the Mamba layers' per-lane recurrent state
+(:class:`~tpulab.engine.kv_pool.LaneStateStore` ``.arrays``), one pytree.
+The functions keep their ``__name__``: a trace names a program
+``jit_<name>``, and the benchmark's readers key on it.  Nothing here imports
+the scheduler (:mod:`tpulab.engine.paged`).
 """
 
 from __future__ import annotations
@@ -172,6 +176,112 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
         return attn.reshape(b, m, -1), kv_pool
 
 
+def _pages(kv_pool):
+    """The page store of a step function's ``kv_pool``: itself, or the
+    first of the pair a model with Mamba layers is served with."""
+    return kv_pool[0] if isinstance(kv_pool, tuple) else kv_pool
+
+
+def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
+    """The Mamba-1 mixer of one layer (Jamba's: RMSNorm on dt, B and C) over
+    the lane state ``state = (ssm, conv)``, whose layer ``at`` it reads and
+    writes: ``(out, state)``, ``out`` shaped like ``h``.
+
+    One rule for both forms: a segment that starts at position 0 starts
+    from zeros, whatever the lane's slot holds; any other segment starts
+    from the slot; the slot is written from the segment's last valid row;
+    rows without a token and lanes that are dead or idle write nothing.
+    So a reused lane, a preempted request that prefills again from 0 and a
+    resume need no reset.
+
+    A decode step (``h (B, 1, D)``, ``valid (B, 1)`` the live lanes) is the
+    one-token recurrence in XLA.  A packed round (``seg["row_seg"]``: ``h
+    (1, T, D)``) takes row t's convolution taps from rows ``t-1 ..`` of its
+    segment where the row's offset reaches that far, else from the lane's
+    tail, and scans the segments in
+    :func:`tpulab.ops.selective_scan.selective_scan`."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _rmsnorm, qmat
+
+    f32 = jnp.float32
+    ssm, conv = state
+    din, n, r, k = spec.d_inner, spec.d_state, spec.dt_rank, spec.d_conv
+    rows = seg.get("row_seg")
+    if rows is None and h.shape[1] != 1:
+        raise NotImplementedError(
+            "Mamba layers run in a decode step or a packed round "
+            "(paged_mixed_step), not in the padded (B, M) form")
+    with jax.named_scope("mamba_proj"):
+        uz = (h @ qmat(p["in_proj"], compute_dtype)).reshape(-1, 2 * din)
+        x, z = uz[:, :din], uz[:, din:]              # (rows, din)
+    with jax.named_scope("mamba_conv"):
+        w = p["conv_w"].astype(f32)
+        if rows is None:
+            live, fresh = valid[:, 0], pos[:, 0] == 0
+            tail = jnp.where(fresh[None, :, None], 0, conv[at])
+            window = jnp.concatenate([tail, x[None]], axis=0)  # (k, B, din)
+            acc = (window.astype(f32) * w[:, None, :]).sum(0)
+            new_tail = jnp.where(live[None, :, None], window[1:], conv[at])
+        else:
+            row_lane, row_off = rows
+            q_lens, kv_lens = seg["q_lens"], seg["kv_lens"]
+            b, t = q_lens.shape[0], x.shape[0]
+            lane = jnp.maximum(row_lane, 0)
+            fresh = (q_lens > 0) & (kv_lens == q_lens)
+            tail = jnp.where(fresh[None, :, None], 0, conv[at])
+            acc = x.astype(f32) * w[k - 1]
+            for back in range(1, k):
+                # the input ``back`` tokens back: a row of this round, or
+                # what the lane kept of the rounds before
+                kept = tail[jnp.clip(row_off - back + k - 1, 0, k - 2), lane]
+                src = jnp.where((row_off >= back)[:, None],
+                                jnp.pad(x, ((back, 0), (0, 0)))[:t], kept)
+                acc = acc + src.astype(f32) * w[k - 1 - back]
+            # the lane's new tail: the last k - 1 of [tail ; segment]
+            spread = seg["rows"][0]
+            s = q_lens[:, None] + jnp.arange(k - 1)[None, :] - (k - 1)
+            from_rows = x[spread[jnp.arange(b)[:, None] * (spread.shape[0]
+                                                           // b)
+                                 + jnp.maximum(s, 0)]]      # (B, k-1, din)
+            kept = jnp.take_along_axis(
+                tail, jnp.clip(s + k - 1, 0, k - 2).T[:, :, None], axis=0)
+            new_tail = jnp.where((s >= 0).T[:, :, None],
+                                 from_rows.transpose(1, 0, 2), kept)
+        conv = conv.at[at].set(new_tail.astype(conv.dtype))
+        u = jax.nn.silu(acc + p["conv_b"].astype(f32)).astype(compute_dtype)
+    with jax.named_scope("mamba_proj"):
+        eps = spec.rms_eps
+        xp = u @ qmat(p["x_proj"], compute_dtype)
+        bb = _rmsnorm(xp[:, r:r + n], p["b_norm"]["scale"], eps).astype(f32)
+        cc = _rmsnorm(xp[:, r + n:], p["c_norm"]["scale"], eps).astype(f32)
+        dt = jax.nn.softplus(
+            (_rmsnorm(xp[:, :r], p["dt_norm"]["scale"], eps)
+             @ qmat(p["dt_proj"], compute_dtype)).astype(f32)
+            + p["dt_bias"].astype(f32))
+    with jax.named_scope("mamba_scan"):
+        a = -jnp.exp(p["a_log"].astype(f32))                  # (N, din)
+        d = p["d"].astype(f32)
+        uf = u.astype(f32)
+        if rows is None:
+            h0 = jnp.where(fresh[:, None, None], 0.0, ssm[at])
+            hn = (jnp.exp(dt[:, None, :] * a[None]) * h0
+                  + (dt * uf)[:, None, :] * bb[:, :, None])
+            y = (hn * cc[:, :, None]).sum(1) + d * uf
+            ssm = ssm.at[at].set(
+                jnp.where(live[:, None, None], hn, ssm[at]))
+        else:
+            from tpulab.ops.selective_scan import row_flags, selective_scan
+            y, ssm = selective_scan(
+                uf, dt, bb, cc, a, d, ssm, at, row_lane,
+                row_flags(row_lane, row_off, q_lens, kv_lens),
+                use_kernel=seg["use_kernel"])
+    with jax.named_scope("mamba_out"):
+        out = ((y * jax.nn.silu(z.astype(f32))).astype(compute_dtype)
+               @ qmat(p["out_proj"], compute_dtype))
+    return out.reshape(h.shape), (ssm, conv)
+
+
 def _ffn_block(spec, p, layer, x, valid, compute_dtype):
     """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
     experts plus the shared expert.  Returns ``(x, stats)``, ``stats``
@@ -226,6 +336,12 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     ``(E + 2,)`` int32 counters
     (:func:`tpulab.parallel.moe.routing_stats`) or None on a dense layer.
 
+    The layer's mixer is attention over the pages (above) or, by
+    ``spec.mixers``, a Mamba block over the lane state
+    (:func:`_mamba_mixer`); ``kv_pool`` is then the pair ``(page store,
+    lane state)`` for every layer of the model, and only attention layers
+    own a layer of the page store (``spec.store_layer``).
+
     Kept short, the K/V kernel called from here and the rest in functions
     of their own: on the v5e host, tracing a kernel body costs more with
     every Python frame between the step function and the ``pallas_call``
@@ -239,12 +355,25 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
                                            split_qkv)
 
     h = _rmsnorm(x, p["ln1"]["scale"], spec.rms_eps)
+    state = None
+    if spec.mamba_layers:
+        # a hybrid's store is the pair (pages, lane state): a Mamba layer
+        # reads and writes the second alone, an attention layer the first
+        kv_pool, state = kv_pool
+        if spec.mixers[layer] == "mamba":
+            mixed, state = _mamba_mixer(
+                spec, p["mamba"], spec.store_layer(layer), h, pos, valid,
+                state, seg, compute_dtype)
+            x, stats = _ffn_block(spec, p, layer, x + mixed, valid,
+                                  compute_dtype)
+            return x, (kv_pool, state), stats
     if spec.attention == "mla":
         attn, kv_pool = _mla_attention(spec, p, layer, h, pos, kv_pool,
                                        page_idx, slot_idx, seg,
                                        compute_dtype)
     else:
         b, m = x.shape[:2]
+        at = spec.store_layer(layer)       # its layer of the page store
         q, knew, vnew = split_qkv(h @ qmat(p["wqkv"], compute_dtype), b, m,
                                   spec.n_heads, spec.n_kv_heads,
                                   spec.head_dim)
@@ -252,7 +381,7 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
             q = apply_rope(q, pos, spec.rope_theta)
             knew = apply_rope(knew, pos, spec.rope_theta)
         tail = knew.shape[2:]
-        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
+        kv_pool = _scatter_kv(kv_pool, at, page_idx, slot_idx,
                               knew.reshape(page_idx.shape + tail),
                               vnew.reshape(page_idx.shape + tail))
         packed = seg.get("rows")
@@ -274,18 +403,18 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
                 # (the docstring says what a frame costs)
                 from tpulab.tpu.platform import pallas_interpret
                 attn = ra._ragged_attn(
-                    q, kv_pool, jnp.asarray(layer, jnp.int32).reshape(1),
+                    q, kv_pool, jnp.asarray(at, jnp.int32).reshape(1),
                     seg["tables"], seg["q_lens"], seg["kv_lens"],
                     pallas_interpret(), g_pages=gk, nbuf=nk)
             else:
                 attn = ra.ragged_paged_attention(
-                    q, kv_pool, layer, seg["tables"], seg["q_lens"],
+                    q, kv_pool, at, seg["tables"], seg["q_lens"],
                     seg["kv_lens"], mesh=seg["mesh"], g_pages=gk, nbuf=nk)
             attn = attn.astype(compute_dtype).reshape(b, m, -1)
         else:
             # XLA fallback: gather pages densely then mask
-            attn = _gather_attend(q, kv_pool[layer, :, 0],
-                                  kv_pool[layer, :, 1], seg["tables"], pos,
+            attn = _gather_attend(q, kv_pool[at, :, 0],
+                                  kv_pool[at, :, 1], seg["tables"], pos,
                                   compute_dtype)
         if packed is not None:                     # (B, M, H*D) -> (1, T, H*D)
             attn = jnp.take(attn.reshape(b * m, -1), back, axis=0,
@@ -293,7 +422,7 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     x, stats = _ffn_block(spec, p, layer,
                           x + attn @ qmat(p["wo"], compute_dtype), valid,
                           compute_dtype)
-    return x, kv_pool, stats
+    return x, (kv_pool if state is None else (kv_pool, state)), stats
 
 
 def paged_decode_step(params, kv_pool, tables, lengths, tokens,
@@ -322,12 +451,16 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
     ``logprobs`` is each lane's chosen-token log-probability
     (log-softmax at the chosen id).  Callers then fetch only (B,)-sized
     arrays (no per-tick (B, vocab) logits transfer).
+
+    For a ``spec`` with Mamba layers ``kv_pool`` is the pair ``(page store,
+    lane state)``, in and out; a lane that is not ``active`` holds its
+    state as it routes its K/V to the scratch page.
     """
     import jax.numpy as jnp
     from tpulab.models.transformer import _lm_head, _rmsnorm
 
     b = tokens.shape[0]
-    page_size = kv_pool.shape[3]
+    page_size = _pages(kv_pool).shape[3]
     emb = params["embed"].astype(compute_dtype)
     x = emb[tokens][:, None, :]
     spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
@@ -507,7 +640,7 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
     from tpulab.models.transformer import _lm_head, _rmsnorm
 
     b, m = seq.shape
-    page_size = kv_pool.shape[3]
+    page_size = _pages(kv_pool).shape[3]
     emb = params["embed"].astype(compute_dtype)
     x = emb[seq]                                      # (B, M, D)
     spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
@@ -619,6 +752,12 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
     counters behind the pool where ``spec`` has any.  The same segments
     through ``paged_ragged_forward(last_only=True)`` give the same
     logits: that is the plain form this one is tested against.
+
+    For a ``spec`` with Mamba layers ``kv_pool`` is the pair ``(page store,
+    lane state)``, in and out: each lane's segment runs the convolution and
+    the scan from its own slot (from zeros where it starts at position 0)
+    and leaves its last row's state there; a lane without a segment keeps
+    what it held (:func:`_mamba_mixer`).
     """
     import jax
     import jax.numpy as jnp
@@ -626,7 +765,7 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
 
     b, t = tables.shape[0], toks.shape[0]
     m = t - b
-    page_size = kv_pool.shape[3]
+    page_size = _pages(kv_pool).shape[3]
     emb = params["embed"].astype(compute_dtype)
     x = emb[toks][None]                               # (1, T, D)
     spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
@@ -647,7 +786,8 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
     qpos = start[:, None] + jnp.arange(m)[None, :]
     seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
                use_kernel=use_kernel, kernel_geometry=kernel_geometry,
-               mesh=mesh, rows=(spread, back, qpos))
+               mesh=mesh, rows=(spread, back, qpos),
+               row_seg=(row_lane, row_off))
     moe_stats = []
     for layer in range(spec.n_layers):
         x, kv_pool, stats = _layer_block(

@@ -11,6 +11,13 @@ kind the *cache-entry kind* the page store holds:
               qk_rope_head_dim`` values a token a layer (multi-head latent
               attention, served in the absorbed form).
 
+A layer's *mixer* is attention or a Mamba selective state-space block
+(``mixers``).  A Mamba layer leaves no pages: it keeps a per-lane recurrent
+state of fixed size (SSM state ``d_state x d_inner`` in float32 and the
+last ``d_conv - 1`` inputs of its causal convolution) in the engine's
+lane-state store, so only attention layers own a layer of the page store
+(``store_layer``).
+
 The dense decoder the engine has always served is :func:`dense_spec` with
 today's constants (epsilon 1e-6, ``head_dim = d_model // n_heads``); the
 step functions build it themselves when no spec is passed, so a dense model
@@ -45,12 +52,37 @@ in the layout the layer block reads:
 :func:`split_kv_b` turns a published ``kv_b_proj`` into ``w_uk``/``w_uv``.
 RoPE is the engine's rotate-half convention over the ``qk_rope`` columns.
 The multi-token-prediction layer of the published model is not built.
+
+``jamba`` (AI21-Jamba2-3B): Mamba-1 mixers with RMSNorm on dt, B and C, a
+full-attention GQA mixer without positional encoding every
+``attn_layer_period`` layers, a dense SwiGLU FFN on every layer
+(``num_experts`` 1), a tied output head.  :func:`jamba_spec` reads the
+published keys.  An attention layer has the dense decoder's leaves
+(``wqkv`` = ``[q | k | v]``, ``wo``); a Mamba layer has, under ``mamba``:
+
+=============  ==========================================================
+``in_proj``    ``(d_model, 2 * d_inner)``, columns ``[u | z]``
+``conv_w``     ``(d_conv, d_inner)``: tap ``k`` weighs the input ``d_conv
+               - 1 - k`` tokens back (the published depthwise ``conv1d``
+               weight ``(d_inner, 1, d_conv)``, transposed)
+``conv_b``     ``(d_inner,)``
+``x_proj``     ``(d_inner, dt_rank + 2 * d_state)``, columns ``[r | B | C]``
+``dt_norm`` ``b_norm`` ``c_norm``   ``{"scale"}`` of ``dt_rank``, ``d_state``,
+               ``d_state``
+``dt_proj``    ``(dt_rank, d_inner)``; ``dt_bias (d_inner,)``
+``a_log``      ``(d_state, d_inner)``: the published ``A_log``, transposed
+               (channels minor, as the state is stored)
+``d``          ``(d_inner,)``
+``out_proj``   ``(d_inner, d_model)``
+=============  ==========================================================
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
+
+import numpy as np
 
 
 @dataclass(frozen=True)
@@ -76,6 +108,11 @@ class ModelSpec:
     norm_topk: bool = True
     rms_eps: float = 1e-6
     rope_theta: Optional[float] = None
+    mixers: Tuple[str, ...] = ()            # "attention" | "mamba", one a layer
+    d_inner: int = 0                        # mamba, all four
+    d_state: int = 0
+    d_conv: int = 0
+    dt_rank: int = 0
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -85,6 +122,20 @@ class ModelSpec:
             raise ValueError(f"layer_kinds {kinds} does not name a dense or "
                              f"moe FFN for each of {self.n_layers} layers")
         object.__setattr__(self, "layer_kinds", tuple(kinds))
+        mixers = self.mixers or ("attention",) * self.n_layers
+        if (len(mixers) != self.n_layers
+                or set(mixers) - {"attention", "mamba"}):
+            raise ValueError(f"mixers {mixers} does not name attention or "
+                             f"mamba for each of {self.n_layers} layers")
+        if "mamba" in mixers:
+            if self.attention != "gqa" or "attention" not in mixers:
+                raise ValueError("Mamba layers are served beside GQA "
+                                 "attention layers on K/V pages only")
+            if min(self.d_inner, self.d_state, self.d_conv - 1,
+                   self.dt_rank) < 1:
+                raise ValueError("a spec with Mamba layers gives d_inner, "
+                                 "d_state, d_conv (>= 2) and dt_rank")
+        object.__setattr__(self, "mixers", tuple(mixers))
 
     @property
     def cache_entry(self) -> str:
@@ -103,6 +154,22 @@ class ModelSpec:
     @property
     def moe_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, k in enumerate(self.layer_kinds) if k == "moe")
+
+    @property
+    def mamba_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, k in enumerate(self.mixers) if k == "mamba")
+
+    @property
+    def attention_layers(self) -> Tuple[int, ...]:
+        """The layers that own a layer of the page store."""
+        return tuple(i for i, k in enumerate(self.mixers) if k == "attention")
+
+    def store_layer(self, layer: int) -> int:
+        """Layer ``layer``'s index in the store of its mixer's kind: the
+        page store's layer axis for an attention layer, the lane-state
+        store's for a Mamba layer (``layer`` itself where every mixer is
+        attention)."""
+        return self.mixers[:layer].count(self.mixers[layer])
 
 
 def dense_spec(d_model: int, n_heads: int, n_layers: int,
@@ -149,6 +216,38 @@ def glm4_moe_lite_spec(config: Dict[str, Any]) -> ModelSpec:
         rope_theta=float(config["rope_theta"]))
 
 
+def jamba_spec(config: Dict[str, Any]) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type`` ``jamba``).
+    Layer ``i`` is attention iff ``i % attn_layer_period ==
+    attn_layer_offset``, else Mamba.  Refuses what the layer block does not
+    compute."""
+    if int(config.get("num_experts", 1)) > 1:
+        raise ValueError("num_experts > 1 (Jamba's expert layers) is not "
+                         "implemented")
+    if config.get("sliding_window") is not None:
+        raise ValueError("sliding_window is not implemented")
+    if config.get("mamba_proj_bias"):
+        raise ValueError("mamba_proj_bias is not implemented")
+    if not config.get("mamba_conv_bias", True):
+        raise ValueError("mamba_conv_bias false is not implemented")
+    n_layers = int(config["num_hidden_layers"])
+    d_model, n_heads = int(config["hidden_size"]), int(config[
+        "num_attention_heads"])
+    period, offset = (int(config["attn_layer_period"]),
+                      int(config["attn_layer_offset"]))
+    return ModelSpec(
+        n_layers=n_layers, d_model=d_model, n_heads=n_heads,
+        n_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=d_model // n_heads,
+        rms_eps=float(config["rms_norm_eps"]), rope_theta=None,
+        mixers=tuple("attention" if i % period == offset else "mamba"
+                     for i in range(n_layers)),
+        d_inner=int(config["mamba_expand"]) * d_model,
+        d_state=int(config["mamba_d_state"]),
+        d_conv=int(config["mamba_d_conv"]),
+        dt_rank=int(config["mamba_dt_rank"]))
+
+
 def split_kv_b(kv_b, spec: ModelSpec):
     """A published ``kv_b_proj`` ``(kv_lora_rank, n_heads * (qk_nope +
     v_head_dim))``, a head's columns ``[k_nope | v]``, as ``(w_uk (H, nope,
@@ -161,37 +260,71 @@ def split_kv_b(kv_b, spec: ModelSpec):
 
 def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                 scale: float = 0.02) -> Dict[str, Any]:
-    """Seeded random float32 parameters of an MLA (+ expert) decoder in the
-    layout above: weights normal ``scale``, norm scales 1, the router's
-    selection bias drawn like a weight (not zero: choosing with it and
-    weighting without it must differ).  Untied output head."""
+    """Seeded random float32 parameters in the layouts above: an MLA (+
+    expert) decoder with an untied output head, or a Mamba/attention hybrid
+    with a tied one (no ``lm_head``).  Weights normal ``scale``, norm scales
+    1, the router's selection bias drawn like a weight (not zero: choosing
+    with it and weighting without it must differ).
+
+    A Mamba layer's SSM leaves follow the published initialisation, not
+    normal ``scale`` (under which every channel forgets within three tokens
+    and a state that is dropped or rounded could not be seen in the
+    logits): ``a_log = log(1..d_state)`` on every channel, ``d = 1``,
+    ``dt_bias = softplus^-1(dt)`` with ``dt`` log-uniform in [1e-3, 1e-1],
+    the convolution uniform within ``d_conv ** -0.5``."""
     import jax
     import jax.numpy as jnp
 
-    if spec.attention != "mla":
-        raise ValueError("init_params draws MLA decoders; dense ones come "
-                         "from tpulab.models.transformer")
+    if spec.attention != "mla" and not spec.mamba_layers:
+        raise ValueError("init_params draws MLA decoders and Mamba hybrids; "
+                         "dense ones come from tpulab.models.transformer")
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                  16 * spec.n_layers + 4))
 
     def w(*shape):
         return jax.random.normal(next(keys), shape, jnp.float32) * scale
 
+    def uniform(lo, hi, *shape):
+        return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
     def norm(n):
         return {"scale": jnp.ones((n,), jnp.float32)}
 
     d, h = spec.d_model, spec.n_heads
-    params: Dict[str, Any] = {"embed": w(vocab, d), "final_norm": norm(d),
-                              "lm_head": w(d, vocab)}
+    params: Dict[str, Any] = {"embed": w(vocab, d), "final_norm": norm(d)}
+    if not spec.mamba_layers:
+        params["lm_head"] = w(d, vocab)
     for i, kind in enumerate(spec.layer_kinds):
-        p = {"ln1": norm(d), "ln2": norm(d),
-             "wq_a": w(d, spec.q_lora_rank), "q_norm": norm(spec.q_lora_rank),
-             "wq_b": w(spec.q_lora_rank, h * spec.qk_head_dim),
-             "wkv_a": w(d, spec.latent_width),
-             "kv_norm": norm(spec.kv_lora_rank),
-             "w_uk": w(h, spec.qk_nope_head_dim, spec.kv_lora_rank),
-             "w_uv": w(h, spec.kv_lora_rank, spec.v_head_dim),
-             "wo": w(h * spec.v_head_dim, d)}
+        p = {"ln1": norm(d), "ln2": norm(d)}
+        if spec.mixers[i] == "mamba":
+            di, n, r = spec.d_inner, spec.d_state, spec.dt_rank
+            bound = spec.d_conv ** -0.5
+            dt = jnp.exp(uniform(np.log(1e-3), np.log(1e-1), di))
+            p["mamba"] = {
+                "in_proj": w(d, 2 * di),
+                "conv_w": uniform(-bound, bound, spec.d_conv, di),
+                "conv_b": uniform(-bound, bound, di),
+                "x_proj": w(di, r + 2 * n),
+                "dt_norm": norm(r), "b_norm": norm(n), "c_norm": norm(n),
+                "dt_proj": w(r, di),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "a_log": jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None],
+                    (n, di)),
+                "d": jnp.ones((di,), jnp.float32),
+                "out_proj": w(di, d)}
+        elif spec.attention == "mla":
+            p.update(
+                wq_a=w(d, spec.q_lora_rank), q_norm=norm(spec.q_lora_rank),
+                wq_b=w(spec.q_lora_rank, h * spec.qk_head_dim),
+                wkv_a=w(d, spec.latent_width),
+                kv_norm=norm(spec.kv_lora_rank),
+                w_uk=w(h, spec.qk_nope_head_dim, spec.kv_lora_rank),
+                w_uv=w(h, spec.kv_lora_rank, spec.v_head_dim),
+                wo=w(h * spec.v_head_dim, d))
+        else:
+            p.update(wqkv=w(d, (h + 2 * spec.n_kv_heads) * spec.head_dim),
+                     wo=w(h * spec.head_dim, d))
         if kind == "dense":
             p.update(w1=w(d, d_ff), w3=w(d, d_ff), w2=w(d_ff, d))
         else:

@@ -13,6 +13,10 @@ framework owns its hot ops):
   one program; block tables drive HBM->VMEM page DMAs with online
   softmax, and a ``mesh`` shards the walk over the KV-heads dim via
   shard_map (the kernel side of engine.paged_steps' ragged forward)
+- :mod:`selective_scan` — the Mamba-1 recurrence over a packed round,
+  segmented by lane: a block of channels' state stays in registers across
+  the round's rows, each lane's slot of the state store is read where its
+  segment starts and written where it ends, in place
 """
 
 from tpulab.ops.flash_attention import flash_attention, make_flash_attention_fn
