@@ -2,14 +2,18 @@
 
 ``paged_mixed_step`` takes the round as rows = tokens (the prefilling lanes'
 chunks one after the other, then a row for each lane's decode token) and runs
-everything but the walk over the pages on those rows.  It is held here to the
-padded form it replaced, ``paged_ragged_forward(last_only=True)`` on the same
-segments: the same picks, last-row logits and pages.  The scheduler half
+everything but the walk over the pages on those rows; the attention is called
+once a segment kind, the chunk rows at ``(B, M)`` and the decode rows at ``(B,
+1)`` (PR 33).  It is held here to the padded form it replaced,
+``paged_ragged_forward(last_only=True)`` on the same segments: the same picks,
+last-row logits and pages.  (A model with Mamba layers has no padded form: its
+plain form is the decode step, token by token.)  The scheduler half
 (``ContinuousBatcher._ragged_round``): lanes that prefill at once share one
 token budget, oldest admission first, so the program is keyed by one bucketed
 number and a single prompt reaches every bucket.
 """
 
+import threading
 from functools import partial
 
 import jax
@@ -17,18 +21,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import test_jamba
 from test_glm_moe import CONFIG, D_FF, VOCAB
-from tpulab.engine.kv_pool import PagedKVPool
+from tpulab.engine import paged_steps
+from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool
 from tpulab.engine.paged import ContinuousBatcher
-from tpulab.engine.paged_steps import (pack_round, paged_mixed_step,
+from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
+                                       paged_mixed_step,
                                        paged_ragged_forward, round_width)
-from tpulab.models.spec import glm4_moe_lite_spec, init_params
+from tpulab.models.spec import glm4_moe_lite_spec, init_params, jamba_spec
 from tpulab.models.transformer import init_transformer_params
+from tpulab.ops import ragged_attention
 
 LANES, PAGE, MAX_PAGES = 8, 8, 5
 #: lane b owns pages 1 + 5 b .. 5 + 5 b (page 0 is the scratch page)
 OWN = 1 + np.arange(LANES * MAX_PAGES, dtype=np.int32).reshape(LANES,
                                                                MAX_PAGES)
+i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
 
 
 def _shared_prefix_tables():
@@ -40,7 +49,7 @@ def _shared_prefix_tables():
 
 
 #: segment mixes: ``ctx`` tokens already in each lane's pages (written
-#: through the padded form first), ``prefill`` {lane: chunk length} in row
+#: through the plain form first), ``prefill`` {lane: chunk length} in row
 #: order, ``decode`` lanes; a lane in neither is idle
 MIXES = {
     "chunk-and-7-decode": dict(
@@ -56,6 +65,17 @@ MIXES = {
     "prefix-cache-tail": dict(
         ctx=[0, 0, 0, 0, 20, 0, 6, 0], prefill={0: 5}, decode=[4, 6],
         tables=_shared_prefix_tables(), start={0: 16}),
+    # two chunk lanes fill one bucket to its last row, around decode lanes
+    # on either side of them
+    "two-chunks-fill-the-bucket": dict(
+        ctx=[7, 10, 0, 15, 0, 0, 3, 21], prefill={6: 9, 1: 7},
+        decode=[0, 3, 7]),
+    "no-decode-lane": dict(
+        ctx=[0, 0, 11, 0, 0, 17, 0, 0], prefill={5: 6, 2: 3, 7: 2},
+        decode=[]),
+    # every lane but one idle, and that one mid-prompt across a page edge
+    "one-lane-mid-prompt": dict(
+        ctx=[0, 0, 0, 0, 0, 0, 0, 13], prefill={7: 9}, decode=[]),
 }
 
 
@@ -65,40 +85,81 @@ def models():
                                     n_layers=2, d_ff=64, n_kv_heads=2,
                                     ffn="swiglu", tie_embeddings=False)
     spec = glm4_moe_lite_spec(CONFIG)
+    hybrid = jamba_spec(test_jamba.CONFIG)
     # (params, step arguments, pool arguments, tolerance): the dense golden's
-    # fallback, and what tests/test_glm_moe.py holds the expert model to
+    # fallback, and what tests/test_glm_moe.py and tests/test_jamba.py hold
+    # the expert model and the hybrid to
     return {
         "dense": (dense, dict(n_heads=4, n_kv_heads=2, n_layers=2,
                               rope_theta=10000.0),
-                  dict(n_heads=2, head_dim=16), 1e-6),
+                  dict(n_heads=2, head_dim=16, n_layers=2), 1e-6),
         "mla-experts": (init_params(spec, VOCAB, D_FF, seed=3, scale=0.1),
                         dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
                              spec=spec),
-                        dict(n_heads=0, head_dim=0,
+                        dict(n_heads=0, head_dim=0, n_layers=spec.n_layers,
                              latent_width=spec.latent_width), 2e-5),
+        "hybrid": (init_params(hybrid, VOCAB, test_jamba.D_FF, seed=3,
+                               scale=0.1),
+                   dict(n_heads=hybrid.n_heads, n_layers=hybrid.n_layers,
+                        spec=hybrid),
+                   dict(n_heads=hybrid.n_kv_heads, head_dim=hybrid.head_dim,
+                        n_layers=len(hybrid.attention_layers)), 3e-5),
     }
 
 
-@pytest.mark.parametrize("mix", list(MIXES))
+def _store(kw, pool_kw):
+    """A fresh page store; for a model with Mamba layers the pair (page
+    store, lane state), the state filled with what another sequence left."""
+    kv = PagedKVPool(n_pages=1 + LANES * MAX_PAGES, page_size=PAGE,
+                     dtype=jnp.float32, **pool_kw).kv
+    spec = kw.get("spec")
+    if spec is None or not spec.mamba_layers:
+        return kv
+    return kv, tuple(jnp.full(a.shape, 3.0, a.dtype) for a in
+                     LaneStateStore(spec, LANES, jnp.float32).arrays)
+
+
+def _plain_form(params, kw):
+    """``plain(kv, tables, seq (LANES, M), q_lens, kv_lens) -> (last logits,
+    kv, *moe)``: the padded form, or token by token through the decode
+    step where Mamba layers refuse the padded form."""
+    spec = kw.get("spec")
+    if spec is None or not spec.mamba_layers:
+        padded = jax.jit(partial(paged_ragged_forward, last_only=True, **kw))
+        return lambda kv, tables, seq, q_lens, kv_lens: padded(
+            params, kv, tables, i32(seq), i32(q_lens), i32(kv_lens))
+    step = jax.jit(partial(paged_decode_step, **kw))
+
+    def token_by_token(kv, tables, seq, q_lens, kv_lens):
+        last = np.zeros((LANES, VOCAB), np.float32)
+        for j in range(int(q_lens.max())):
+            active = j < q_lens
+            logits, kv = step(params, kv, tables,
+                              i32(np.where(active, kv_lens - q_lens + j, 0)),
+                              i32(seq[:, j]), jnp.asarray(active))
+            last[active] = np.asarray(logits)[active]
+        return last, kv
+    return token_by_token
+
+
 @pytest.mark.parametrize("use_kernel", [False, True],
                          ids=["gather", "kernel"])
-@pytest.mark.parametrize("model", ["dense", "mla-experts"])
-def test_packed_round_is_the_padded_round(models, model, use_kernel, mix):
+@pytest.mark.parametrize("model,mix", [
+    (model, mix) for model in ("dense", "mla-experts", "hybrid")
+    for mix in MIXES
+    # a lane state is not shared: no prefix-cache hit on a hybrid
+    if not (model == "hybrid" and "tables" in MIXES[mix])])
+def test_packed_round_is_the_padded_round(models, model, mix, use_kernel):
     params, kw, pool_kw, tol = models[model]
     kw = dict(kw, compute_dtype=jnp.float32, use_kernel=use_kernel)
     case = MIXES[mix]
     rng = np.random.default_rng(5)
-    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
     tables = i32(case.get("tables", OWN))
-    kv = PagedKVPool(n_pages=1 + LANES * MAX_PAGES, page_size=PAGE,
-                     n_layers=kw["n_layers"], dtype=jnp.float32,
-                     **pool_kw).kv
-    padded = jax.jit(partial(paged_ragged_forward, last_only=True, **kw))
+    plain = _plain_form(params, kw)
     # the contexts the round finds in the pages
     ctx = np.asarray(case["ctx"], np.int32)
     fill = rng.integers(0, VOCAB, (LANES, max(int(ctx.max()), 1)))
-    _logits, kv, *_ = padded(params, kv, tables, i32(fill), i32(ctx),
-                             i32(ctx))
+    _logits, kv, *_ = plain(_store(kw, pool_kw), tables, fill, ctx, ctx)
     start = ctx.copy()
     for lane, at in case.get("start", {}).items():
         start[lane] = at            # the shared pages hold its context
@@ -122,20 +183,67 @@ def test_packed_round_is_the_padded_round(models, model, use_kernel, mix):
         seq[lane, :len(chunk)] = chunk
     for lane, tok in decode.items():
         seq[lane, 0] = tok
-    want, kv_padded, *moe_padded = padded(params, kv, tables, i32(seq),
-                                          i32(q_lens), i32(kv_lens))
+    want, kv_plain, *moe_plain = plain(kv, tables, seq, q_lens, kv_lens)
     live = q_lens > 0
     np.testing.assert_allclose(np.asarray(last)[live],
                                np.asarray(want)[live], rtol=tol, atol=tol)
     np.testing.assert_array_equal(
         np.asarray(picks)[live], np.asarray(want).argmax(-1)[live])
-    # the same pages written (page 0 is where rows without a token land)
-    np.testing.assert_allclose(np.asarray(kv_packed)[:, 1:],
-                               np.asarray(kv_padded)[:, 1:], rtol=tol,
-                               atol=tol)
+    # the same pages written (page 0 is where rows without a token land),
+    # and the same lane state where the model keeps one
+    for got, ref in zip(jax.tree.leaves(kv_packed), jax.tree.leaves(kv_plain)):
+        got, ref = np.asarray(got), np.asarray(ref)
+        if got.shape[1] == 1 + LANES * MAX_PAGES:
+            got, ref = got[:, 1:], ref[:, 1:]
+        np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
     if moe:      # the expert counters see the rows that hold a token
         np.testing.assert_array_equal(np.asarray(moe[0]),
-                                      np.asarray(moe_padded[0]))
+                                      np.asarray(moe_plain[0]))
+
+
+@pytest.mark.parametrize("model", ["dense", "mla-experts"])
+def test_rows_without_a_token_leave_every_layer_finite(models, model,
+                                                       monkeypatch):
+    """The kernel leaves the output block of a lane it skips unwritten, and
+    on the chip that is whatever the buffer held.  With NaN there, every
+    row comes out of every ``_layer_block`` finite (a row without a token
+    takes zeros out of the attention) and the round's logits do not move."""
+    params, kw, pool_kw, tol = models[model]
+    kw = dict(kw, compute_dtype=jnp.float32, use_kernel=True)
+    rng = np.random.default_rng(7)
+    toks, row_lane, row_off, q_lens = pack_round(
+        LANES, {1: rng.integers(0, VOCAB, 7)},          # lane 0 is idle
+        {3: int(rng.integers(VOCAB)), 6: int(rng.integers(VOCAB))})
+    ctx = np.asarray([0, 5, 0, 9, 0, 0, 2, 0], np.int32)
+    plain = _plain_form(params, kw)
+    _logits, kv, *_ = plain(_store(kw, pool_kw), i32(OWN),
+                            rng.integers(0, VOCAB, (LANES, 9)), ctx, ctx)
+    args = (params, kv, i32(OWN), i32(toks), i32(row_lane), i32(row_off),
+            i32(q_lens), i32(np.where(q_lens > 0, ctx + q_lens, 0)),
+            jnp.zeros((LANES,), jnp.float32),
+            jnp.zeros((LANES, 2), jnp.uint32))
+    want = np.asarray(paged_mixed_step(*args, **kw)[2])
+
+    def unwritten_is_nan(kernel):
+        def call(q, kv_pool, layer, tables, q_lens, *rest, **kwargs):
+            out = kernel(q, kv_pool, layer, tables, q_lens, *rest, **kwargs)
+            return jnp.where((q_lens > 0)[:, None, None, None], out, jnp.nan)
+        return call
+    for name in ("_ragged_attn", "ragged_latent_attention"):
+        monkeypatch.setattr(ragged_attention, name,
+                            unwritten_is_nan(getattr(ragged_attention, name)))
+    layers, block = [], paged_steps._layer_block
+
+    def spy(*a):
+        out = block(*a)
+        layers.append(np.asarray(out[0]))
+        return out
+    monkeypatch.setattr(paged_steps, "_layer_block", spy)
+    got = np.asarray(paged_mixed_step(*args, **kw)[2])
+    assert len(layers) == kw["n_layers"]
+    assert all(np.isfinite(x).all() for x in layers)
+    live = q_lens > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
 
 
 # -- the scheduler half ---------------------------------------------------------
@@ -157,10 +265,13 @@ def _spy_rounds(cb):
     def spy(params, kv, tables, toks, row_lane, row_off, q_lens, kv_lens,
             *rest):
         q = np.asarray(q_lens)
+        m = toks.shape[0] - cb.lanes
+        decodes = np.asarray(row_lane)[m:] >= 0
         rounds.append(dict(
             rows=int(toks.shape[0]), tokens=int(q.sum()),
-            prefill=int((np.asarray(row_lane)[:toks.shape[0] - cb.lanes]
-                         >= 0).sum()),
+            prefill=int((np.asarray(row_lane)[:m] >= 0).sum()),
+            chunk_lanes=int(((q > 0) & ~decodes).sum()),
+            decode_lanes=int(decodes.sum()), width=int(m),
             lanes=[(req.admit_seq, len(req.pending_prompt), int(q[lane]))
                    for lane, req in enumerate(cb._active)
                    if req is not None and req.pf_started]))
@@ -248,6 +359,43 @@ def test_seeded_mixed_workload_streams_equal_the_split_plans(use_kernel):
     assert got == want
     assert cb.dispatch_kinds["mixed"] >= -(-sum(lens) // 32)
     assert 0 < cb.mixed_tokens <= cb.mixed_rows
+
+
+def test_mixed_attn_rows_counts_m_a_chunk_lane_and_one_a_decode_lane():
+    """``debug_state()["dispatch"]["mixed_attn_rows"]`` is what the rounds'
+    attention calls computed a layer: the round's width for each lane that
+    held a chunk, one row for each decoding lane, nothing for an idle lane
+    (a single call computed ``lanes x M``)."""
+    cb = _engine(lanes=4, max_len=256, ragged=True, use_kernel=False,
+                 prefill_chunk=32)
+    rounds = _spy_rounds(cb)
+    rng = np.random.default_rng(13)
+    streaming = threading.Event()
+    try:
+        # two lanes decode for long; prompts of one to three chunks arrive
+        # beside them, three at a time on the two lanes left
+        futs = [cb.submit(rng.integers(0, 64, 40), steps=90,
+                          on_token=lambda t, i: i == 2 and streaming.set()),
+                cb.submit(rng.integers(0, 64, 5), steps=90)]
+        assert streaming.wait(60)
+        for burst in ((70, 33, 9), (64, 17, 1)):
+            with cb._cv:     # one admission pass sees the burst
+                futs += [cb.submit(rng.integers(0, 64, n), steps=3)
+                         for n in burst]
+            futs[-1].result(timeout=120)
+        for f in futs:
+            f.result(timeout=120)
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    assert any(r["decode_lanes"] for r in rounds)
+    assert any(r["chunk_lanes"] > 1 for r in rounds)
+    assert any(r["chunk_lanes"] + r["decode_lanes"] < cb.lanes
+               for r in rounds)
+    assert state["mixed_attn_rows"] == sum(
+        r["width"] * r["chunk_lanes"] + r["decode_lanes"] for r in rounds)
+    assert (state["mixed_tokens"] <= state["mixed_attn_rows"]
+            < sum(r["width"] * cb.lanes for r in rounds))
 
 
 def test_nine_mixed_programs_all_reached_by_single_prompts():

@@ -27,10 +27,12 @@ import pytest
 
 from tpulab.engine.kv_pool import kv_rows_view
 from tpulab.engine.paged import ContinuousBatcher, SamplingParams
-from tpulab.engine.paged_steps import _gather_attend
+from tpulab.engine.paged_steps import (_gather_attend,
+                                       _gather_attend_latent)
 from tpulab.models.transformer import (early_exit_draft,
                                        init_transformer_params)
-from tpulab.ops.ragged_attention import ragged_paged_attention
+from tpulab.ops.ragged_attention import (ragged_latent_attention,
+                                         ragged_paged_attention)
 from tpulab.parallel import make_mesh
 
 # ------------------------------------------------------------ kernel ----
@@ -85,6 +87,9 @@ def _shape_case(name, page_size):
         "verify": ([5, 5, 5, 5], [7, s + 5, 2 * s + 5, 3 * s], m),
         # segments crossing page boundaries exactly at/around the edge
         "page_cross": ([4, 4, 1, 1], [s + 2, 2 * s, s + 1, s], m),
+        # q_lens of 0, 1 and a chunk: a lane without rows is skipped, also
+        # one that has context in its pages (the chunk call of a round)
+        "skipped": ([0, 1, s + 3, 0], [2 * s, 2 * s + 1, 2 * s + 3, 0], m),
     }[name]
 
 
@@ -92,7 +97,7 @@ def _shape_case(name, page_size):
 @pytest.mark.parametrize("page_size", [4, 8])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("shape", ["all_decode", "all_prefill", "mixed",
-                                   "verify", "page_cross"])
+                                   "verify", "page_cross", "skipped"])
 def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n):
     """The parity drift guard of the satellite grid: every raggedness
     shape x dtype x page size x mesh agrees with the dense reference."""
@@ -200,6 +205,109 @@ def test_kernel_walks_the_layer_it_is_given(case):
                                    rtol=2e-5, atol=2e-5)
         outs[layer] = got[valid]
     assert np.abs(outs[1] - outs[2]).max() > 1e-2   # the layers do differ
+
+
+def _round_case(kernel):
+    """A mixed round's attention on either kernel: ``(attend, reference,
+    q, q_lens, kv_lens)``.  Lanes 1 and 4 hold a chunk, 2 and 5 decode, 0
+    and 3 hold nothing; lane 0's pages and query rows are NaN, lane 3 has
+    sound context in its pages.  ``attend(q, q_lens)`` is the kernel at
+    ``q``'s width, ``reference`` the XLA gather at the same."""
+    ps, mp, m, h = 8, 3, 2 * 8, 4
+    q_lens = np.asarray([0, ps + 3, 1, 0, 5, 1], np.int32)
+    kv_lens = np.asarray([ps, 2 * ps + 3, ps + 1, 2 * ps, 5, 3 * ps],
+                         np.int32)
+    b = len(q_lens)
+    tables = jnp.asarray(np.arange(1, b * mp + 1).reshape(b, mp), jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(17), 2)
+    width = 48 if kernel == "latent" else 16
+    q = jax.random.normal(ks[0], (b, m, h, width), jnp.float32)
+    q = q.at[0].set(jnp.nan)
+    if kernel == "latent":
+        pool = jax.random.normal(ks[1], (2, b * mp + 1, 1, ps, 64),
+                                 jnp.float32)
+        pool = pool.at[..., width:].set(0.0)     # the row's zero padding
+        kw = dict(v_width=32, sm_scale=0.2)
+
+        def attend(q, q_lens):
+            return ragged_latent_attention(q, pool, 1, tables, q_lens,
+                                           jnp.asarray(kv_lens), **kw)
+
+        def reference(q, qpos):
+            return _gather_attend_latent(q, pool[1, :, 0], tables, qpos,
+                                         kw["v_width"], kw["sm_scale"],
+                                         jnp.float32)
+    else:
+        pool = jax.random.normal(ks[1], (2, b * mp + 1, 2, ps, 2 * width),
+                                 jnp.float32)
+        mesh = (make_mesh({"model": 2}, jax.devices()[:2])
+                if kernel == "kv-mesh2" else None)
+
+        def attend(q, q_lens):
+            return ragged_paged_attention(q, pool, 1, tables, q_lens,
+                                          jnp.asarray(kv_lens), mesh=mesh)
+
+        def reference(q, qpos):
+            return _gather_attend(q, pool[1, :, 0], pool[1, :, 1], tables,
+                                  qpos, jnp.float32).reshape(q.shape)
+    pool = pool.at[:, 1:1 + mp].set(jnp.nan)     # lane 0's pages
+    return attend, reference, q, q_lens, kv_lens
+
+
+@pytest.mark.parametrize("kernel", ["kv", "kv-mesh2", "latent"])
+def test_a_lane_pays_for_the_query_rows_it_holds(kernel):
+    """One call over a round's lanes, then the round in two calls (the
+    chunk lanes at M, the decode lanes at 1, ``q_lens`` zeroed for the
+    other kind): a lane without rows is skipped, NaN pages and all, and
+    the two calls give the one call's rows."""
+    attend, reference, q, q_lens, kv_lens = _round_case(kernel)
+    b, m = q.shape[:2]
+    start = kv_lens - q_lens
+    one = np.asarray(attend(q, jnp.asarray(q_lens)))
+    want = np.asarray(reference(
+        q, jnp.asarray(start[:, None] + np.arange(m)[None, :])))
+    valid = np.arange(m)[None, :] < q_lens[:, None]
+    assert np.isfinite(want[valid]).all()
+    np.testing.assert_allclose(one[valid], want[valid], rtol=2e-5,
+                               atol=2e-5)
+    # a skipped lane's block is unwritten (the interpreter starts an output
+    # as NaN; a lane computed with no valid row would write zeros)
+    assert np.isnan(one[q_lens == 0]).all()
+
+    chunk_lens = np.where(q_lens > 1, q_lens, 0)
+    dec_lens = (q_lens == 1).astype(np.int32)
+    chunks = np.asarray(attend(q, jnp.asarray(chunk_lens)))
+    decodes = np.asarray(attend(q[:, :1], jnp.asarray(dec_lens)))
+    assert chunks.shape == one.shape and decodes.shape[:2] == (b, 1)
+    in_chunk = valid & (chunk_lens > 0)[:, None]
+    np.testing.assert_allclose(chunks[in_chunk], one[in_chunk], rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(decodes[dec_lens > 0, 0],
+                               one[dec_lens > 0, 0], rtol=2e-5, atol=2e-5)
+    assert np.isnan(chunks[chunk_lens == 0]).all()
+    assert np.isnan(decodes[dec_lens == 0]).all()
+
+
+@pytest.mark.parametrize("kernel", ["kv", "latent"])
+def test_a_skipped_lane_starts_no_dma_and_writes_nothing(kernel):
+    """Everything a grid step does is under ``q_lens[lane] > 0``: outside
+    that one conditional the kernel body holds no DMA start or wait and no
+    store, only the reads of its scalar words."""
+    attend, _reference, q, q_lens, _kv_lens = _round_case(kernel)
+    jaxpr = jax.make_jaxpr(attend)(q, jnp.asarray(q_lens))
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["jaxpr"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from kernels(sub)
+    (body,) = kernels(jaxpr.jaxpr)
+    top = [eqn.primitive.name for eqn in body.eqns]
+    assert top.count("cond") == 1
+    assert not {"dma_start", "dma_wait", "swap", "addupdate"} & set(top)
+    inside = str(body.eqns[top.index("cond")].params["branches"])
+    assert "dma_start" in inside and "dma_wait" in inside
 
 
 def test_kernel_takes_a_traced_layer():
@@ -353,12 +461,13 @@ def test_mixed_round_is_one_fused_dispatch(lm):
         d0 = cb.decode_dispatches
         s0 = cb.decode_host_syncs
         m0 = cb.dispatch_kinds["mixed"]
-        futs = [cb.submit(p, 1) for p in prompts]
+        with cb._cv:     # simultaneous: one admission pass sees all three
+            futs = [cb.submit(p, 1) for p in prompts]
         outs = [list(f.result(timeout=300)) for f in futs]
         assert all(len(o) == 1 for o in outs)
         # every round is one dispatch and one blocking fetch; the three
-        # prompt fills fold into at most two rounds (admission may split
-        # the arrivals), never one program per lane
+        # prompt fills fold into at most two rounds, never one program per
+        # lane
         assert cb.decode_dispatches - d0 <= 2
         assert cb.decode_host_syncs - s0 == cb.decode_dispatches - d0
         assert cb.dispatch_kinds["mixed"] - m0 == cb.decode_dispatches - d0

@@ -625,9 +625,13 @@ class ContinuousBatcher:
                                                "mixed": 0}
         #: rows the mixed rounds computed (``M + lanes`` a round) and the
         #: rows among them that held a token: their ratio is the fill of
-        #: the packed round
+        #: the packed round; and the query rows their attention calls
+        #: computed a layer (``M`` a lane that held a chunk, one a decoding
+        #: lane): over ``mixed_tokens``, 1.0 is an attention that computes
+        #: only rows that hold a token
         self.mixed_rows = 0
         self.mixed_tokens = 0
+        self.mixed_attn_rows = 0
         #: sum of K over plain decode dispatches (K-blocks and single
         #: ticks): over ``dispatch_kinds["decode"]`` it is the mean block
         self.decode_block_steps = 0
@@ -1387,6 +1391,7 @@ class ContinuousBatcher:
                          "kinds": dict(self.dispatch_kinds),
                          "mixed_rows": self.mixed_rows,
                          "mixed_tokens": self.mixed_tokens,
+                         "mixed_attn_rows": self.mixed_attn_rows,
                          "decode_block_steps": self.decode_block_steps,
                          "ahead_blocks": self.ahead_blocks,
                          "stages": self._stages.stages(),
@@ -2348,6 +2353,8 @@ class ContinuousBatcher:
             self._note_dispatch("mixed")
             self.mixed_rows += len(toks)
             self.mixed_tokens += int(q_lens.sum())
+            self.mixed_attn_rows += ((len(toks) - b) * len(segs)
+                                     + len(decode_parts))
         with stage(st, "fetch"):
             next_tokens = np.asarray(nt_dev, np.int32).copy()
             logprobs_arr = np.asarray(lp_dev, np.float32).copy()

@@ -118,6 +118,47 @@ def _step_spec(spec, d_model: int, n_heads: int, n_layers: int, n_kv_heads,
                               rope_theta)
 
 
+def _segment_calls(q, pos, seg):
+    """The attention calls of one layer, ``(q, q_lens, qpos)`` each.  One,
+    as given, in a decode step and in the padded form.  A packed round
+    (``seg["rows"]``, see :func:`_layer_block`; q is ``(1, T, ...)``) makes
+    one a segment kind, at the width the kind has, since a call computes
+    every row of every lane it does not skip: the chunk rows ``[0, M)``
+    spread to ``(B, M)`` by one row gather, and the decode rows ``[M, M +
+    B)``, which ARE ``(B, 1)``.  Each call's ``q_lens`` are zero for the
+    other kind's lanes, which the kernel skips; ``kv_lens`` is the same
+    for both (they follow the layer's scatter).  (``jnp.take``, not
+    ``x[idx]``: it is jitted, so sixteen layers trace it once.)"""
+    import jax.numpy as jnp
+
+    packed = seg.get("rows")
+    if packed is None:
+        return [(q, seg.get("q_lens"), pos)]
+    spread, _back, qpos, chunk_lens, dec_lens = packed
+    b, m = qpos.shape
+    q = q.reshape(q.shape[1:])                               # (T, ...)
+    return [(jnp.take(q, spread, axis=0, mode="clip").reshape(
+                (b, m) + q.shape[1:]), chunk_lens, qpos),
+            (q[m:, None], dec_lens, seg["kv_lens"][:, None] - 1)]
+
+
+def _segment_rows(outs, seg):
+    """What the calls of :func:`_segment_calls` returned, as the rows the
+    layer carries: the one call's output, or ``(1, T, ...)`` with rows ``[0,
+    M)`` gathered back out of the chunk call's ``(B, M)`` and rows ``[M, M +
+    B)`` the decode call's as they stand.  A row that holds no token reads
+    what a skipped lane left unwritten: :func:`_layer_block` zeroes it."""
+    import jax.numpy as jnp
+
+    if seg.get("rows") is None:
+        return outs[0]
+    chunk, dec = outs
+    back = seg["rows"][1][:chunk.shape[1]]
+    return jnp.concatenate(
+        [jnp.take(chunk.reshape((-1,) + chunk.shape[2:]), back, axis=0,
+                  mode="clip"), dec[:, 0]])[None]
+
+
 def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
                    compute_dtype):
     """Multi-head latent attention of one layer in the absorbed form, on a
@@ -126,8 +167,8 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
     key up-projection moves into the query, the value up-projection
     behind the weighted latent sum.  In a packed round (``seg["rows"]``,
     see :func:`_layer_block`) ``h`` is ``(1, T, D)``: only the absorbed
-    query is spread to ``(B, M)`` for the walk over the pages, and the
-    weighted latent sum is gathered back to rows before ``w_uv``."""
+    query goes through :func:`_segment_calls` for the walk over the pages,
+    and the weighted latent sum is rows again before ``w_uv``."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _rmsnorm, apply_rope, qmat
@@ -154,23 +195,20 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
         qa = jnp.concatenate(
             [jnp.einsum("bmhn,hnc->bmhc", q[..., :nope],
                         qmat(p["w_uk"], compute_dtype)), qr], axis=-1)
-        packed = seg.get("rows")
-        if packed is not None:
-            spread, back, pos = packed
-            qa = jnp.take(qa.reshape(qa.shape[1:]), spread, axis=0,
-                          mode="clip").reshape(pos.shape + qa.shape[2:])
-        if seg["use_kernel"]:
-            from tpulab.ops.ragged_attention import ragged_latent_attention
-            lat = ragged_latent_attention(
-                qa, kv_pool, layer, seg["tables"], seg["q_lens"],
-                seg["kv_lens"], v_width=spec.kv_lora_rank, sm_scale=scale)
-        else:
-            lat = _gather_attend_latent(
-                qa, kv_pool[layer, :, 0], seg["tables"], pos,
-                spec.kv_lora_rank, scale, compute_dtype)
-        if packed is not None:                 # (B, M, H, C) -> (1, T, H, C)
-            lat = jnp.take(lat.reshape((-1,) + lat.shape[2:]), back, axis=0,
-                           mode="clip")[None]
+        outs = []
+        for qq, q_lens, qpos in _segment_calls(qa, pos, seg):
+            if seg["use_kernel"]:
+                from tpulab.ops.ragged_attention import (
+                    ragged_latent_attention)
+                outs.append(ragged_latent_attention(
+                    qq, kv_pool, layer, seg["tables"], q_lens,
+                    seg["kv_lens"], v_width=spec.kv_lora_rank,
+                    sm_scale=scale))
+            else:
+                outs.append(_gather_attend_latent(
+                    qq, kv_pool[layer, :, 0], seg["tables"], qpos,
+                    spec.kv_lora_rank, scale, compute_dtype))
+        lat = _segment_rows(outs, seg)                       # (b, m, H, C)
         attn = jnp.einsum("bmhc,hcv->bmhv", lat.astype(compute_dtype),
                           qmat(p["w_uv"], compute_dtype))
         return attn.reshape(b, m, -1), kv_pool
@@ -318,7 +356,9 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     the same for every layer: ``tables`` (B, MP), ``q_lens``/``kv_lens``
     (B,), and the attention path (``use_kernel``: the Pallas ragged kernel
     of the cache-entry kind, else the XLA gather; ``kernel_geometry``,
-    ``mesh``).  ``valid`` (B, M) bool masks the expert counters only.
+    ``mesh``).  ``valid`` (B, M) bool says which rows hold a token: the
+    others take zeros out of the kernel path's attention (a lane the kernel
+    skips leaves its rows unwritten) and are left out of the expert counters.
 
     Three forms, told apart by what ``seg`` carries.  A decode step is
     (B, 1).  The padded form is (B, M), lane b's segment left-packed in
@@ -326,12 +366,12 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     packed round (:func:`paged_mixed_step`) carries ``seg["rows"]``: x is
     (1, T, D), one row a token of the round, and everything but the walk
     over the pages runs on those T rows; ``rows = (spread (B * M,), back
-    (T,), qpos (B, M))`` holds the row behind each slot of the (B, M)
-    form the attention takes and the slot behind each row: the query rows
-    are spread by one row gather and the attention's output gathered back
-    by another, two copies of at most lanes x M rows a layer, where the
-    padded form ran every product on lanes x M rows.  (``jnp.take``, not
-    ``x[idx]``: it is jitted, so sixteen layers trace it once.)
+    (T,), qpos (B, M), chunk_lens (B,), decode_lens (B,))`` holds the row
+    behind each slot of the (B, M) form, the slot behind each row, and
+    ``q_lens`` split by segment kind.  The attention is called once a
+    kind (:func:`_segment_calls`): a lane that holds a chunk pays M query
+    rows, a decoding lane one, an idle lane none, where one call at (B,
+    M) made every lane pay M (PR 33: 65 of a round's 79 ms at 8 lanes).
     Returns ``(x, kv_pool, stats)``: ``stats`` is the expert layer's
     ``(E + 2,)`` int32 counters
     (:func:`tpulab.parallel.moe.routing_stats`) or None on a dense layer.
@@ -384,13 +424,14 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         kv_pool = _scatter_kv(kv_pool, at, page_idx, slot_idx,
                               knew.reshape(page_idx.shape + tail),
                               vnew.reshape(page_idx.shape + tail))
-        packed = seg.get("rows")
-        if packed is not None:
-            spread, back, pos = packed
-            b, m = pos.shape
-            q = jnp.take(q.reshape(q.shape[1:]), spread, axis=0,
-                         mode="clip").reshape((b, m) + q.shape[2:])
-        if seg["use_kernel"]:
+        outs = []
+        for qq, q_lens, qpos in _segment_calls(q, pos, seg):
+            if not seg["use_kernel"]:
+                # XLA fallback: gather pages densely then mask
+                outs.append(_gather_attend(
+                    qq, kv_pool[at, :, 0], kv_pool[at, :, 1], seg["tables"],
+                    qpos, compute_dtype).reshape(qq.shape))
+                continue
             # pallas ragged kernel: walks block tables page-by-page, no
             # dense gather materialization; fused pages = 1 DMA/page;
             # under a mesh the walk shards on the KV-heads dim via
@@ -402,23 +443,22 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
                 # around it: one Python frame fewer above the kernel
                 # (the docstring says what a frame costs)
                 from tpulab.tpu.platform import pallas_interpret
-                attn = ra._ragged_attn(
-                    q, kv_pool, jnp.asarray(at, jnp.int32).reshape(1),
-                    seg["tables"], seg["q_lens"], seg["kv_lens"],
-                    pallas_interpret(), g_pages=gk, nbuf=nk)
+                outs.append(ra._ragged_attn(
+                    qq, kv_pool, jnp.asarray(at, jnp.int32).reshape(1),
+                    seg["tables"], q_lens, seg["kv_lens"],
+                    pallas_interpret(), g_pages=gk, nbuf=nk))
             else:
-                attn = ra.ragged_paged_attention(
-                    q, kv_pool, at, seg["tables"], seg["q_lens"],
-                    seg["kv_lens"], mesh=seg["mesh"], g_pages=gk, nbuf=nk)
-            attn = attn.astype(compute_dtype).reshape(b, m, -1)
-        else:
-            # XLA fallback: gather pages densely then mask
-            attn = _gather_attend(q, kv_pool[at, :, 0],
-                                  kv_pool[at, :, 1], seg["tables"], pos,
-                                  compute_dtype)
-        if packed is not None:                     # (B, M, H*D) -> (1, T, H*D)
-            attn = jnp.take(attn.reshape(b * m, -1), back, axis=0,
-                            mode="clip")[None]
+                outs.append(ra.ragged_paged_attention(
+                    qq, kv_pool, at, seg["tables"], q_lens, seg["kv_lens"],
+                    mesh=seg["mesh"], g_pages=gk, nbuf=nk))
+        attn = _segment_rows(outs, seg).astype(compute_dtype)
+        attn = attn.reshape(b, m, -1)
+    if seg["use_kernel"]:
+        # rows that hold no token take zeros: the kernel leaves the block of
+        # a lane it skips unwritten, and what that holds may not be a number.
+        # (The gather path computes every row, and the dense golden holds its
+        # padded form to the parent's bits in the rows without a token too.)
+        attn = jnp.where(valid[..., None], attn, 0)
     x, stats = _ffn_block(spec, p, layer,
                           x + attn @ qmat(p["wo"], compute_dtype), valid,
                           compute_dtype)
@@ -732,10 +772,11 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
     sits at global position ``kv_lens[b] - q_lens[b] + j``.  Embedding,
     norms, projections, RoPE, the row scatter into the lane's pages,
     ``wo``, the FFN or the routed experts and their counters run on the T
-    rows; only the attention call sees the ``(B, M)`` form of
-    :func:`paged_ragged_forward` (:func:`_layer_block`), so a round costs
-    what its tokens cost, not lanes x the longest chunk.  ``M`` (from the
-    shapes, ``T - lanes``) is the ONE number the program is keyed by.
+    rows; the attention is called once a segment kind, the chunk rows in
+    the ``(B, M)`` form of :func:`paged_ragged_forward` and the decode
+    rows at ``(B, 1)`` (:func:`_segment_calls`), so a round costs what its
+    tokens cost, not lanes x the longest chunk.  ``M`` (from the shapes,
+    ``T - lanes``) is the ONE number the program is keyed by.
 
     Every lane's pick is :func:`_device_sample_token` on its LAST valid
     row's logits at position ``kv_lens - 1`` — exactly the decode tick's
@@ -784,10 +825,14 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
         jnp.where(valid, back, b * m)].set(
             jnp.arange(t, dtype=jnp.int32), mode="drop")
     qpos = start[:, None] + jnp.arange(m)[None, :]
+    # the layout says which kind a lane's segment is: its decode token, if
+    # it has one, is row M + b; every other segment is a chunk in [0, M)
+    decodes = valid[m:]
     seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
                use_kernel=use_kernel, kernel_geometry=kernel_geometry,
-               mesh=mesh, rows=(spread, back, qpos),
-               row_seg=(row_lane, row_off))
+               mesh=mesh, row_seg=(row_lane, row_off),
+               rows=(spread, back, qpos, jnp.where(decodes, 0, q_lens),
+                     decodes.astype(jnp.int32)))
     moe_stats = []
     for layer in range(spec.n_layers):
         x, kv_pool, stats = _layer_block(

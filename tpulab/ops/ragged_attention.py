@@ -14,6 +14,17 @@ whole family:
   chunk (``q_lens = chunk``), decoding lanes carry 1 — ONE fused program
   over the ragged batch instead of separate prefill and decode kinds.
 
+A lane pays for the query rows it holds.  A grid step computes all ``M``
+rows of its block whatever ``q_lens`` says, so ``M`` is the width the
+caller chooses, and a lane with ``q_lens == 0`` is *skipped*: no page
+DMA, no walk, no dot, its output block unwritten.  A packed round
+(:func:`tpulab.engine.paged_steps.paged_mixed_step`) therefore calls the
+kernel twice a layer, once a segment kind, on the same pages and
+``kv_lens``: the chunk rows at ``(B, M)`` with ``q_lens`` zeroed for the
+lanes that hold no chunk, and the decode rows at ``(B, 1)``, a decode
+step's shape, with ``q_lens`` zeroed for the lanes that hold a chunk or
+nothing.  The padded form (K+1 verify) and the decode step call it once.
+
 The XLA fallback gathers every lane's pages into a dense
 ``(B, MP*S, H, D)`` tensor; this kernel walks the block table per lane,
 DMA-ing fused K/V pages from HBM into VMEM scratch (one DMA per page)
@@ -156,50 +167,55 @@ def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
     """The walk over one lane's block table that every kernel of the family
     shares, whatever a page holds (K and V rows, or latent rows): starts
     the pipeline's prologue and returns ``(start_block, wait_block,
-    block_live)``.  A block is ``g_pages`` page DMAs from
-    ``kvpool_ref[layer, page]`` into slot ``slot`` of ``kv_buf`` (dest
-    strip static, source page id dynamic); every started DMA is waited
-    exactly once; pages past ``length`` are neither fetched nor waited."""
-    def page_live(p):
-        return p * page_size <= length
+    block_live)``.  A block is up to ``g_pages`` page DMAs from
+    ``kvpool_ref[layer, page]`` into slot ``slot`` of ``kv_buf`` (source
+    page id and dest strip dynamic); every started DMA is waited exactly
+    once; pages past ``length`` are neither fetched nor waited.
+
+    The pages of a block and the blocks of the prologue are loops in the
+    kernel, over the block's LIVE pages, not in Python: unrolled they were
+    8 sites x ``g_pages`` DMA starts, each under a conditional of its own,
+    and over half of what a kernel body costs to trace and to lower, which
+    a step program pays on the host inside ``setup_s`` for every width it
+    calls the kernel at (PR 33: chat's warm set-up 98 -> 67 s; the price is
+    ~16 ns a page of scalar work in the kernel, PERF.md section 6)."""
+    # live pages of the lane: page p is live iff p * page_size <= length
+    n_pages = jnp.minimum(length // page_size + 1, max_pages)
 
     # written out twice, not through a shared helper: every Python frame
     # between the kernel and a primitive shows in its trace time
     def start_block(j, slot):
-        for gg in range(g_pages):
-            p_idx = j * g_pages + gg
-
-            @pl.when(jnp.logical_and(p_idx < max_pages, page_live(p_idx)))
-            def _start(gg=gg, p_idx=p_idx):
-                page = tables_ref[lane * max_pages + p_idx]
-                pltpu.make_async_copy(
-                    kvpool_ref.at[layer, page],
-                    kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
-                    sem.at[slot, gg]).start()
+        def page(gg, _):
+            pid = tables_ref[lane * max_pages + j * g_pages + gg]
+            pltpu.make_async_copy(
+                kvpool_ref.at[layer, pid],
+                kv_buf.at[slot, :, pl.ds(
+                    pl.multiple_of(gg * page_size, page_size), page_size)],
+                sem.at[slot, gg]).start()
+        jax.lax.fori_loop(0, jnp.clip(n_pages - j * g_pages, 0, g_pages),
+                          page, None)
 
     def wait_block(j, slot):
-        for gg in range(g_pages):
-            p_idx = j * g_pages + gg
-
-            @pl.when(jnp.logical_and(p_idx < max_pages, page_live(p_idx)))
-            def _wait(gg=gg, p_idx=p_idx):
-                page = tables_ref[lane * max_pages + p_idx]
-                pltpu.make_async_copy(
-                    kvpool_ref.at[layer, page],
-                    kv_buf.at[slot, :, pl.ds(gg * page_size, page_size)],
-                    sem.at[slot, gg]).wait()
+        def page(gg, _):
+            pid = tables_ref[lane * max_pages + j * g_pages + gg]
+            pltpu.make_async_copy(
+                kvpool_ref.at[layer, pid],
+                kv_buf.at[slot, :, pl.ds(
+                    pl.multiple_of(gg * page_size, page_size), page_size)],
+                sem.at[slot, gg]).wait()
+        jax.lax.fori_loop(0, jnp.clip(n_pages - j * g_pages, 0, g_pages),
+                          page, None)
 
     def block_live(j):
-        return page_live(j * g_pages)  # first page live <=> any page live
+        return j * g_pages < n_pages
 
     # same deep prefetch pipeline as the single-query kernel (N-stage
     # slot rotation)
-    start_block(0, 0)  # block 0's first page is always live (length >= 0)
-    for jj in range(1, nbuf - 1):
-        if jj < n_blocks:
-            @pl.when(block_live(jj))
-            def _prologue(jj=jj):
-                start_block(jj, jj)
+    start_block(0, 0)
+
+    def prologue(jj, _):
+        start_block(jj, jj)      # a block past the lane's pages has no trip
+    jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
     return start_block, wait_block, block_live
 
 
@@ -212,89 +228,90 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
     layer = layer_ref[0]                      # which layer's pages to walk
     qn = qlens_ref[lane]                      # valid query rows this lane
     kvn = kvlens_ref[lane]                    # context length incl. segment
-    # last visible position; inactive lanes (kvn == 0) clamp to walking
-    # page 0 (the reserved scratch page) so the unconditional first-block
-    # DMA is always waited — their output rows are garbage the caller
-    # never consumes (q_lens == 0 masks them out downstream)
-    length = jnp.maximum(kvn, 1) - 1
-    start = kvn - qn                          # first query's position
-    h, d = n_heads, head_dim
-    hkv = n_kv_heads
-    g = h // hkv                              # GQA group size (1 = MHA)
-    gs = g_pages * page_size                  # KV rows per block
-    n_blocks = (max_pages + g_pages - 1) // g_pages
 
-    q = q_ref[0].astype(jnp.float32) * sm_scale    # (M, H*D)
-    # flash-style 2D dots only (the Mosaic-safe subset): scores contract
-    # over D with the K block transposed, values with the standard
-    # orientation — see tpulab.ops.flash_attention._attn_kernel
-    dot_qk = functools.partial(
-        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision)
-    dot_pv = functools.partial(
-        jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)
+    # a lane pays for the query rows it holds: one that holds none starts
+    # no DMA, walks nothing and leaves its block of o_ref unwritten (the
+    # caller reads no row of it)
+    @pl.when(qn > 0)
+    def _lane():
+        # last visible position (a malformed kvn == 0 clamps to 0: the
+        # walk always has a first page)
+        length = jnp.maximum(kvn, 1) - 1
+        start = kvn - qn                      # first query's position
+        h, d = n_heads, head_dim
+        hkv = n_kv_heads
+        g = h // hkv                          # GQA group size (1 = MHA)
+        gs = g_pages * page_size              # KV rows per block
+        n_blocks = (max_pages + g_pages - 1) // g_pages
 
-    start_block, wait_block, block_live = _page_walk(
-        tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
-        page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-        nbuf=nbuf, n_blocks=n_blocks)
+        q = q_ref[0].astype(jnp.float32) * sm_scale    # (M, H*D)
+        # flash-style 2D dots only (the Mosaic-safe subset): scores contract
+        # over D with the K block transposed, values with the standard
+        # orientation — see tpulab.ops.flash_attention._attn_kernel
+        dot_qk = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        dot_pv = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
 
-    # per-query-row positions/validity are loop-invariant
-    qrow = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 1)
-    qpos = start + qrow                       # (M, G*S) per-row position
-    row_valid = qrow < qn
-    vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
+        start_block, wait_block, block_live = _page_walk(
+            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+            nbuf=nbuf, n_blocks=n_blocks)
 
-    def body(j, carry):
-        def attend(carry):
-            slot = jax.lax.rem(j, nbuf)
-            wait_block(j, slot)
+        # per-query-row positions/validity are loop-invariant
+        qrow = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 1)
+        qpos = start + qrow                   # (M, G*S) per-row position
+        row_valid = qrow < qn
+        vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
 
-            @pl.when(jnp.logical_and(j + nbuf - 1 < n_blocks,
-                                     block_live(j + nbuf - 1)))
-            def _prefetch():
-                start_block(j + nbuf - 1,
-                            jax.lax.rem(j + nbuf - 1, nbuf))
+        def body(j, carry):
+            def attend(carry):
+                slot = jax.lax.rem(j, nbuf)
+                wait_block(j, slot)
 
-            kblk = kv_buf[slot, 0].astype(jnp.float32)   # (G*S, Hkv*D)
-            vblk = kv_buf[slot, 1].astype(jnp.float32)
-            # rows of dead/unfetched pages hold stale VMEM (possibly
-            # NaN): scores are neutralized by the mask below, but V
-            # rides a 0-weighted sum (0 * NaN = NaN) — zero explicitly
-            vblk = jnp.where(j * gs + vrow <= length, vblk, 0.0)
-            kpos = j * gs + col
-            mask = jnp.logical_and(kpos <= qpos, row_valid)  # (M, G*S)
-            out = []
-            for hh in range(h):
-                m_c, l_c, acc_c = carry[hh]
-                hk = hh // g                  # compact-form KV head
-                k_h = kblk[:, hk * d:(hk + 1) * d]          # (G*S, D)
-                v_h = vblk[:, hk * d:(hk + 1) * d]
-                q_h = q[:, hh * d:(hh + 1) * d]             # (M, D)
-                s = dot_qk(q_h, k_h)                        # (M, G*S)
-                s = jnp.where(mask, s, _NEG)
-                m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
-                alpha = jnp.exp(m_c - m_new)                # (M, 1)
-                p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-                l_new = l_c * alpha + p.sum(axis=1, keepdims=True)
-                acc_new = acc_c * alpha + dot_pv(p, v_h)    # (M, D)
-                out.append((m_new, l_new, acc_new))
-            return tuple(out)
+                # (a block past the lane's pages has no trip)
+                start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
 
-        # blocks fully beyond the lane's length contribute nothing — skip
-        return jax.lax.cond(block_live(j), attend, lambda c: c, carry)
+                kblk = kv_buf[slot, 0].astype(jnp.float32)   # (G*S, Hkv*D)
+                vblk = kv_buf[slot, 1].astype(jnp.float32)
+                # rows of dead/unfetched pages hold stale VMEM (possibly
+                # NaN): scores are neutralized by the mask below, but V
+                # rides a 0-weighted sum (0 * NaN = NaN) — zero explicitly
+                vblk = jnp.where(j * gs + vrow <= length, vblk, 0.0)
+                kpos = j * gs + col
+                mask = jnp.logical_and(kpos <= qpos, row_valid)  # (M, G*S)
+                out = []
+                for hh in range(h):
+                    m_c, l_c, acc_c = carry[hh]
+                    hk = hh // g                  # compact-form KV head
+                    k_h = kblk[:, hk * d:(hk + 1) * d]          # (G*S, D)
+                    v_h = vblk[:, hk * d:(hk + 1) * d]
+                    q_h = q[:, hh * d:(hh + 1) * d]             # (M, D)
+                    s = dot_qk(q_h, k_h)                        # (M, G*S)
+                    s = jnp.where(mask, s, _NEG)
+                    m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+                    alpha = jnp.exp(m_c - m_new)                # (M, 1)
+                    p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+                    l_new = l_c * alpha + p.sum(axis=1, keepdims=True)
+                    acc_new = acc_c * alpha + dot_pv(p, v_h)    # (M, D)
+                    out.append((m_new, l_new, acc_new))
+                return tuple(out)
 
-    init = tuple((jnp.full((m_q, 1), _NEG, jnp.float32),
-                  jnp.zeros((m_q, 1), jnp.float32),
-                  jnp.zeros((m_q, d), jnp.float32)) for _ in range(h))
-    final = jax.lax.fori_loop(0, n_blocks, body, init)
-    for hh in range(h):
-        _m, l_c, acc_c = final[hh]
-        o_ref[0, :, hh * d:(hh + 1) * d] = (
-            acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
+            # blocks fully beyond the lane's length contribute nothing — skip
+            return jax.lax.cond(block_live(j), attend, lambda c: c, carry)
+
+        init = tuple((jnp.full((m_q, 1), _NEG, jnp.float32),
+                      jnp.zeros((m_q, 1), jnp.float32),
+                      jnp.zeros((m_q, d), jnp.float32)) for _ in range(h))
+        final = jax.lax.fori_loop(0, n_blocks, body, init)
+        for hh in range(h):
+            _m, l_c, acc_c = final[hh]
+            o_ref[0, :, hh * d:(hh + 1) * d] = (
+                acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -384,8 +401,9 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
     it rides the scalar prefetch, the page DMAs read
     ``kv_pool[layer, page]``);
     tables (B, MP) int32 page ids (padded rows point at scratch page 0);
-    q_lens (B,) int32 — segment length per lane (0 = inactive: output
-    rows are garbage the caller must mask);
+    q_lens (B,) int32 — segment length per lane (0 = the lane is skipped:
+    nothing of it is read and its output rows are UNWRITTEN, whatever the
+    buffer held, so the caller must read none of them);
     kv_lens (B,) int32 — context length per lane INCLUDING the segment
     (NOTE: a count, not the last position — ``q_lens == 1,
     kv_lens == position + 1`` is the single-query decode shape).
@@ -500,65 +518,65 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
     layer = layer_ref[0]
     qn = qlens_ref[lane]
     kvn = kvlens_ref[lane]
-    length = jnp.maximum(kvn, 1) - 1     # see _ragged_attn_kernel
-    start = kvn - qn
-    gs = g_pages * page_size
-    n_blocks = (max_pages + g_pages - 1) // g_pages
 
-    q = q_ref[0].astype(jnp.float32) * sm_scale          # (R, W)
-    dot_qk = functools.partial(
-        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision)
-    dot_pv = functools.partial(
-        jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)
+    @pl.when(qn > 0)                     # see _ragged_attn_kernel
+    def _lane():
+        length = jnp.maximum(kvn, 1) - 1
+        start = kvn - qn
+        gs = g_pages * page_size
+        n_blocks = (max_pages + g_pages - 1) // g_pages
 
-    start_block, wait_block, block_live = _page_walk(
-        tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
-        page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-        nbuf=nbuf, n_blocks=n_blocks)
+        q = q_ref[0].astype(jnp.float32) * sm_scale          # (R, W)
+        dot_qk = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        dot_pv = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
 
-    qrow = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 1)
-    # token index of a stacked row: heads are m_q rows apart
-    qtok = (qrow & (m_q - 1) if m_q & (m_q - 1) == 0
-            else jax.lax.rem(qrow, m_q))
-    qpos = start + qtok
-    row_valid = qtok < qn
-    vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
+        start_block, wait_block, block_live = _page_walk(
+            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+            nbuf=nbuf, n_blocks=n_blocks)
 
-    def body(j, carry):
-        def attend(carry):
-            m_c, l_c, acc_c = carry
-            slot = jax.lax.rem(j, nbuf)
-            wait_block(j, slot)
+        qrow = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 1)
+        # token index of a stacked row: heads are m_q rows apart
+        qtok = (qrow & (m_q - 1) if m_q & (m_q - 1) == 0
+                else jax.lax.rem(qrow, m_q))
+        qpos = start + qtok
+        row_valid = qtok < qn
+        vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
 
-            @pl.when(jnp.logical_and(j + nbuf - 1 < n_blocks,
-                                     block_live(j + nbuf - 1)))
-            def _prefetch():
-                start_block(j + nbuf - 1,
-                            jax.lax.rem(j + nbuf - 1, nbuf))
+        def body(j, carry):
+            def attend(carry):
+                m_c, l_c, acc_c = carry
+                slot = jax.lax.rem(j, nbuf)
+                wait_block(j, slot)
 
-            blk = kv_buf[slot, 0].astype(jnp.float32)    # (G*S, W)
-            # rows of dead/unfetched pages hold stale VMEM (possibly NaN)
-            # and ride a 0-weighted sum as values: zero them
-            blk = jnp.where(j * gs + vrow <= length, blk, 0.0)
-            mask = jnp.logical_and(j * gs + col <= qpos, row_valid)
-            s = jnp.where(mask, dot_qk(q, blk), _NEG)    # (R, G*S)
-            m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
-            alpha = jnp.exp(m_c - m_new)
-            p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-            return (m_new, l_c * alpha + p.sum(axis=1, keepdims=True),
-                    acc_c * alpha + dot_pv(p, blk[:, :v_width]))
+                # (a block past the lane's pages has no trip)
+                start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
 
-        return jax.lax.cond(block_live(j), attend, lambda c: c, carry)
+                blk = kv_buf[slot, 0].astype(jnp.float32)    # (G*S, W)
+                # rows of dead/unfetched pages hold stale VMEM (possibly NaN)
+                # and ride a 0-weighted sum as values: zero them
+                blk = jnp.where(j * gs + vrow <= length, blk, 0.0)
+                mask = jnp.logical_and(j * gs + col <= qpos, row_valid)
+                s = jnp.where(mask, dot_qk(q, blk), _NEG)    # (R, G*S)
+                m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_c - m_new)
+                p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+                return (m_new, l_c * alpha + p.sum(axis=1, keepdims=True),
+                        acc_c * alpha + dot_pv(p, blk[:, :v_width]))
 
-    init = (jnp.full((rows, 1), _NEG, jnp.float32),
-            jnp.zeros((rows, 1), jnp.float32),
-            jnp.zeros((rows, v_width), jnp.float32))
-    _m, l_c, acc_c = jax.lax.fori_loop(0, n_blocks, body, init)
-    o_ref[0] = (acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
+            return jax.lax.cond(block_live(j), attend, lambda c: c, carry)
+
+        init = (jnp.full((rows, 1), _NEG, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32),
+                jnp.zeros((rows, v_width), jnp.float32))
+        _m, l_c, acc_c = jax.lax.fori_loop(0, n_blocks, body, init)
+        o_ref[0] = (acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("v_width", "sm_scale",
