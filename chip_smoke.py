@@ -29,6 +29,13 @@ Phases, in order; any phase that raises fails the run (exit 1):
               the page store: three mixed rounds and a decode step through the
               ``selective_scan`` and ragged kernels against the XLA forms,
               logits and lane state.
+   keye_vl2 — a two-layer GQA decoder with a learned indexer and 128
+              softmax-routed experts (Keye-VL-2.0-30B-A3B's widths and
+              ``sa_config``) on K/V pages with index rows beside them:
+              rounds that take one lane past ``topk`` 2,048 keys, a mixed
+              round and a decode step through the score, sparse-attention
+              and decode kernels against the XLA forms, logits and index
+              rows.
 4. kernels  — the ragged and flash Pallas kernels compiled by Mosaic
               (``interpret=False``, custom call present in the lowered
               program) against the XLA gather / dense-softmax paths.
@@ -107,6 +114,16 @@ class Sizes:
         attn_layer_offset=1, mamba_d_state=16, mamba_d_conv=4,
         mamba_dt_rank=160, mamba_expand=2, num_experts=1,
         rms_norm_eps=1e-6, vocab_size=50304))
+    # Keye-VL-2.0-30B-A3B's published widths and sa_config
+    # (perf/configs/keyevl2-l6.json); depth and vocabulary are the cuts
+    keye: dict = field(default_factory=lambda: dict(
+        hidden_size=2048, num_attention_heads=32, num_key_value_heads=4,
+        head_dim=128, num_hidden_layers=2, num_experts=128,
+        num_experts_per_tok=8, moe_intermediate_size=768,
+        norm_topk_prob=True, rms_norm_eps=1e-6, rope_theta=1e7,
+        sa_config=dict(indexer_head_dim=64, indexer_num_heads=16,
+                       indexer_num_kv_heads=1, topk=2048),
+        vocab_size=50304))
     lm_max_len: int = 512
     lm_page_size: int = 16
     lm_prefill_chunk: int = 128
@@ -134,6 +151,13 @@ REHEARSAL_SIZES = Sizes(
                attn_layer_period=3, attn_layer_offset=1, mamba_d_state=8,
                mamba_d_conv=4, mamba_dt_rank=6, mamba_expand=2,
                num_experts=1, rms_norm_eps=1e-6, vocab_size=256),
+    keye=dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+              head_dim=32, num_hidden_layers=2, num_experts=8,
+              num_experts_per_tok=2, moe_intermediate_size=48,
+              norm_topk_prob=True, rms_norm_eps=1e-6, rope_theta=1e4,
+              sa_config=dict(indexer_head_dim=16, indexer_num_heads=4,
+                             indexer_num_kv_heads=1, topk=24),
+              vocab_size=256),
     lm_max_len=96, lm_page_size=8, lm_prefill_chunk=16,
     lm_prompt_lens=(5, 12, 40), lm_steps=6, flash_t=32)
 
@@ -521,6 +545,88 @@ def phase_jamba(smoke: Smoke) -> str:
     return "; ".join(report)
 
 
+def phase_keye(smoke: Smoke) -> str:
+    """Rounds that fill lane 0 past ``topk`` keys, one mixed round (lane 0 a
+    later chunk whose every row drops keys, lanes 1 and 2 decode, lane 3 a
+    first chunk) and a decode step of a two-layer model with a learned
+    indexer: the score, sparse-attention and decode kernels against the
+    XLA forms on the same inputs, logits and index rows."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.kv_pool import PagedKVPool
+    from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
+                                           paged_mixed_step)
+    from tpulab.models.spec import init_params, keye_vl2_spec
+    sz = smoke.sizes
+    cfg, chunk, page = sz.keye, sz.glm_chunk, sz.lm_page_size
+    spec = keye_vl2_spec(cfg)
+    vocab = cfg["vocab_size"]
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                    init_params(spec, vocab, 0))
+    fills = -(-spec.index_topk // chunk) + 1     # lane 0 ends past topk
+    lanes, mp = 4, (fills + 2) * chunk // page
+    rng = np.random.default_rng(3)
+    tables = 1 + np.arange(lanes * mp, dtype=np.int32).reshape(lanes, mp)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    kw = dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
+              compute_dtype=jnp.bfloat16, spec=spec)
+    draw = lambda n: rng.integers(0, vocab, n)
+    temps, seeds = jnp.zeros((lanes,), jnp.float32), jnp.zeros(
+        (lanes, 2), jnp.uint32)
+    half = chunk // 2
+    rounds = [({0: draw(chunk)}, {}, [i * chunk, 0, 0, 0])
+              for i in range(fills)]
+    rounds += [({1: draw(half - 3), 2: draw(half)}, {},
+                [fills * chunk, 0, 0, 0]),
+               ({0: draw(chunk - 8), 3: draw(7)},
+                {1: int(draw(1)[0]), 2: int(draw(1)[0])},
+                [fills * chunk, half - 3, half, 0])]
+    final = [(fills + 1) * chunk - 8, half - 2, half + 1, 7]
+    out = {}
+    for name, uk in (("xla", False), ("kernel", True)):
+        pool = PagedKVPool(lanes * mp + 1, page, spec.n_layers,
+                           spec.n_kv_heads, spec.head_dim, jnp.bfloat16,
+                           index_dim=spec.index_dim)
+        store = (pool.kv, pool.index)
+        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **kw),
+                        donate_argnums=(1,))
+        step = jax.jit(partial(paged_decode_step, use_kernel=uk, **kw),
+                       donate_argnums=(1,))
+        for prefill, decode, lengths in rounds:
+            toks, row_lane, row_off, q_lens = pack_round(lanes, prefill,
+                                                         decode)
+            args = (i32(tables), i32(toks), i32(row_lane), i32(row_off),
+                    i32(q_lens), i32(np.asarray(lengths) + q_lens), temps,
+                    seeds)
+            if uk and decode:
+                check_mosaic(smoke, "keye_vl2 mixed round", partial(
+                    paged_mixed_step, use_kernel=True, **kw), params, store,
+                    *args)
+            _, _, last, store, _moe = mixed(params, store, *args)
+        logits, store, _moe = step(params, store, i32(tables), i32(final),
+                                   i32([5, 6, 7, 8]),
+                                   jnp.ones((lanes,), bool))
+        out[name] = (np.asarray(last, np.float32),
+                     np.asarray(logits, np.float32),
+                     np.asarray(store[1][0, 1:], np.float32))
+    report = []
+    for i, what in enumerate(("mixed round", "decode step", "index rows")):
+        ref, got = out["xla"][i], out["kernel"][i]
+        err = float(np.abs(got - ref).max())
+        scale = float(np.abs(ref).max())
+        if not np.isfinite(got).all() or err > LOGIT_RTOL * scale:
+            raise AssertionError(f"keye_vl2 {what}: with the kernels "
+                                 f"{err:.4g} from the XLA forms (largest "
+                                 f"{scale:.4g})")
+        report.append(f"{what} err {err:.4g} of {scale:.4g}")
+    return (f"lane 0 at {final[0] + 1} keys of topk {spec.index_topk}; "
+            + "; ".join(report))
+
+
 # -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------
 def check_mosaic(smoke: Smoke, name: str, fn, *args) -> None:
     """On the chip the lowered program must hold the Mosaic custom call;
@@ -789,6 +895,7 @@ def main(argv=None) -> int:
         smoke.run("lm", phase_lm)
         smoke.run("latent", phase_latent)
         smoke.run("jamba", phase_jamba)
+        smoke.run("keye_vl2", phase_keye)
         smoke.run("kernels", phase_kernels)
         smoke.run("multichip", phase_multichip)
 

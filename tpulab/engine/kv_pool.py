@@ -4,9 +4,11 @@ K/V live in a global pool of fixed-size *pages*; a session owns a *block
 table* of page ids.  This module is everything that knows what a page is
 and who holds it:
 
-- :func:`kv_page_shape` / :func:`latent_page_shape` — the ONE definition
-  of each cache-entry kind's page payload (K/V rows, or one latent row per
-  position for multi-head latent attention); :func:`kv_rows_view` is the
+- :func:`kv_page_shape` / :func:`latent_page_shape` /
+  :func:`index_page_shape` — the ONE definition of each cache-entry kind's
+  page payload (K/V rows, one latent row per position for multi-head latent
+  attention, or K/V rows with an index key a position beside them for
+  learned sparse attention); :func:`kv_rows_view` is the
   ``(Hkv, D)`` -> row reshape the host-side formats share;
 - :class:`PagedKVPool` — the device array ``(L, P, ...page)`` plus the
   host-side free list, reference counts, grow/shrink and the host<->device
@@ -60,6 +62,16 @@ def latent_page_shape(page_size: int, latent_width: int) -> tuple:
     return (1, page_size, -(-latent_width // 128) * 128)
 
 
+def index_page_shape(page_size: int, index_dim: int) -> tuple:
+    """``(S, row)``: one layer's share of one page of *index rows*, the
+    second array of the ``"kv_index"`` cache-entry kind — the ONE definition
+    of it.  A position leaves one index key of ``index_dim`` values (after
+    its norm and RoPE) beside its K and V rows, under the same page id and
+    slot; ``row`` is ``index_dim`` padded with zeros to whole 128-lane
+    tiles (what the device's tiled layout occupies anyway)."""
+    return (page_size, -(-index_dim // 128) * 128)
+
+
 def kv_rows_view(pages):
     """``(..., Hkv, D)`` heads as the ``(..., Hkv*D)`` rows the page store
     takes (numpy or jax; the same bytes in the same order)."""
@@ -78,11 +90,19 @@ class PagedKVPool:
     ``(L, P) + latent_page_shape(S, latent_width)``, one row a token a
     layer (``n_heads``/``head_dim`` are then unused: pass 0).  The host
     tier, the wire and the fabric do not carry it (``host_shape``
-    raises)."""
+    raises).
+
+    ``index_dim`` > 0 selects ``"kv_index"``: K/V pages as above and a
+    second device array ``index`` of ``(L, P) + index_page_shape(S,
+    index_dim)``, one index key a token a layer under the SAME page ids,
+    so the free list, the reference counts and every block table serve
+    both; the step programs take, donate and return the pair.  It is not
+    sharded, grown, shrunk nor carried by the host-side formats."""
 
     def __init__(self, n_pages: int, page_size: int, n_layers: int,
                  n_heads: int, head_dim: int, dtype=None, device=None,
-                 allocator=None, mesh=None, latent_width: int = 0):
+                 allocator=None, mesh=None, latent_width: int = 0,
+                 index_dim: int = 0):
         import jax.numpy as jnp
         from tpulab.tpu import platform as plat
         from tpulab.tpu.allocators import make_tpu_allocator
@@ -116,12 +136,18 @@ class PagedKVPool:
                            else plat.local_device(0))
         self.n_kv_heads = n_heads
         self.head_dim = head_dim
-        #: "kv" (K and V rows) or "latent" (one row a token)
-        self.entry_kind = "latent" if latent_width else "kv"
+        #: "kv" (K and V rows), "latent" (one row a token) or "kv_index" (K
+        #: and V rows, and an index key a token in ``index``)
+        self.entry_kind = ("latent" if latent_width
+                           else "kv_index" if index_dim else "kv")
         if latent_width and mesh is not None:
             raise NotImplementedError(
                 "mesh=: a latent page store is not sharded (every head "
                 "reads the whole row)")
+        if index_dim and (latent_width or mesh is not None):
+            raise NotImplementedError(
+                "index rows go beside K/V pages on one device (no latent "
+                "entry, no mesh)")
         self._shape = (n_layers, n_pages) + (
             latent_page_shape(page_size, latent_width) if latent_width
             else kv_page_shape(page_size, n_heads, head_dim))
@@ -135,6 +161,14 @@ class PagedKVPool:
         self._alloc = allocator or make_tpu_allocator(self.placement)
         self._kv_addr, self._kv = self._alloc.allocate_array(self._shape,
                                                              dtype)
+        # the index rows: a tracked block of their own through the same
+        # allocator, rotated through the donated steps beside ``kv``
+        self._index_shape = ((n_layers, n_pages) + index_page_shape(
+            page_size, index_dim)) if index_dim else None
+        self._index_addr = self._index = None
+        if index_dim:
+            self._index_addr, self._index = self._alloc.allocate_array(
+                self._index_shape, dtype)
         # page 0 is RESERVED as scratch: inactive/padded lanes scatter their
         # (masked-out) K/V there, so it must never hold live data
         self._free: List[int] = list(range(1, n_pages))
@@ -154,6 +188,16 @@ class PagedKVPool:
     @kv.setter
     def kv(self, value) -> None:
         self._kv = self._alloc.replace(self._kv_addr, value)
+
+    @property
+    def index(self):
+        """The index rows ``(L, P, S, row)`` of a ``"kv_index"`` pool (None
+        otherwise); rotates through donation like ``kv``."""
+        return self._index
+
+    @index.setter
+    def index(self, value) -> None:
+        self._index = self._alloc.replace(self._index_addr, value)
 
     @property
     def dtype(self):
@@ -191,8 +235,15 @@ class PagedKVPool:
         """Live LOGICAL HBM of this pool's page store (not allocator-wide:
         the allocator may be shared, e.g. a Runtime's).  Under a mesh this
         is the whole-array figure; each shard holds hbm_bytes_per_shard."""
-        return (self._alloc.node_size(self._kv_addr)
-                if self._kv_addr is not None else 0)
+        if self._kv_addr is None:
+            return 0
+        return self._alloc.node_size(self._kv_addr) + self.index_hbm_bytes
+
+    @property
+    def index_hbm_bytes(self) -> int:
+        """Tracked bytes of the index rows (0 without any)."""
+        return (self._alloc.node_size(self._index_addr)
+                if self._index_addr is not None else 0)
 
     @property
     def hbm_bytes_per_shard(self) -> int:
@@ -207,6 +258,9 @@ class PagedKVPool:
         import jax.numpy as jnp
         self.kv = jax.device_put(jnp.zeros(self._shape, self._dtype),
                                  self.placement)
+        if self._index_addr is not None:
+            self.index = jax.device_put(
+                jnp.zeros(self._index_shape, self._dtype), self.placement)
         with self._lock:
             self._free = list(range(1, self.n_pages))  # page 0 stays scratch
             self._refs.clear()
@@ -217,6 +271,9 @@ class PagedKVPool:
             self._alloc.deallocate_node(self._kv_addr)
             self._kv_addr = None
             self._kv = None
+        if self._index_addr is not None:
+            self._alloc.deallocate_node(self._index_addr)
+            self._index_addr = self._index = None
 
     @property
     def page_nbytes(self) -> int:
@@ -228,8 +285,13 @@ class PagedKVPool:
     def bytes_per_token(self) -> int:
         """Page-store bytes one cached token occupies, all layers: what
         the cache-entry kind costs (a latent row against K and V of every
-        KV head)."""
+        KV head; K, V and the index key for ``"kv_index"``)."""
         return self.page_nbytes // self.page_size
+
+    @property
+    def index_bytes_per_token(self) -> int:
+        """Of those, the index keys' (0 without any)."""
+        return self.index_hbm_bytes // max(1, self.n_pages * self.page_size)
 
     @property
     def free_pages(self) -> int:
@@ -305,6 +367,8 @@ class PagedKVPool:
         extra = int(extra_pages)
         if extra <= 0:
             return 0
+        if self._index_addr is not None:
+            raise NotImplementedError("a pool with index rows is not elastic")
         import jax
         import jax.numpy as jnp
         pad_shape = (self._shape[0], extra) + self._shape[2:]
@@ -322,6 +386,8 @@ class PagedKVPool:
         of the store (one device slice through the accounting slot).
         Returns the pages actually dropped — capped by what is free at
         the top; never page 0, never a live id."""
+        if self._index_addr is not None:
+            raise NotImplementedError("a pool with index rows is not elastic")
         with self._lock:
             free = set(self._free)
             k = 0

@@ -283,6 +283,12 @@ class ContinuousBatcher:
     starts at position 0 starts from zeros on the device, so admission,
     lane reuse and a re-prefill after preemption need no reset dispatch
     (``debug_state()["state"]``).
+    A learned indexer (``spec.index_topk``) keeps one index key a token a
+    layer in ``pool.index``, under the page ids of K and V, and the pair
+    ``(pool.kv, pool.index)`` rotates through every dispatch the same way;
+    a query row attends to the ``index_topk`` keys the indexer selects
+    (``tpulab.ops.sparse_attention``; ``debug_state()["sparse"]`` counts
+    rows, keys scored and keys attended from the lengths committed).
     Such a spec is served on the ragged plan only; the options that plan,
     that cache entry or a per-lane state does not carry are refused at
     construction, by name.
@@ -340,12 +346,16 @@ class ContinuousBatcher:
         compute_dtype = compute_dtype or jnp.bfloat16
         #: tpulab.models.spec.ModelSpec: None serves the dense decoder of
         #: ``n_heads``/``n_kv_heads``/``rope_theta`` with today's constants;
-        #: a spec with a latent cache, expert layers or Mamba layers is
-        #: served on the ragged plan alone, and the options that plan, that
-        #: cache-entry kind or a per-lane state (nothing snapshots, shares
-        #: or ships it yet) does not carry are refused here, by name
+        #: a spec with a latent cache, index rows, expert layers or Mamba
+        #: layers is served on the ragged plan alone, and the options that
+        #: plan, that cache-entry kind or a per-lane state (nothing
+        #: snapshots, shares or ships it yet) does not carry are refused
+        #: here, by name
         self.model_spec = spec
         hybrid = spec is not None and bool(spec.mamba_layers)
+        #: a learned indexer: index rows beside the K/V pages, the pair
+        #: rotated through every dispatch as a hybrid's (pages, state) is
+        sparse = spec is not None and bool(spec.index_topk)
         special = spec is not None and (spec.cache_entry != "kv"
                                         or spec.moe_layers or hybrid)
         if special:
@@ -382,7 +392,8 @@ class ContinuousBatcher:
         kv_dtype = kv_dtype or compute_dtype
         # a hybrid's attention layers are the spec's: its KV heads size
         # the pages
-        n_kv = spec.n_kv_heads if hybrid else n_kv_heads or n_heads
+        n_kv = (spec.n_kv_heads if hybrid or sparse
+                else n_kv_heads or n_heads)
         self.lanes = lanes
         self.max_len = max_len
         self.page_size = page_size
@@ -416,10 +427,15 @@ class ContinuousBatcher:
         if pool is not None and pool.n_layers != pool_layers:
             raise ValueError(f"the provided pool has {pool.n_layers} layers, "
                              f"the model {pool_layers} attention layers")
+        head_dim = spec.head_dim if sparse else d_model // n_heads
+        if pool is not None and sparse and pool.index is None:
+            raise ValueError("the model has an indexer: the provided pool "
+                             "needs index rows (index_dim=)")
         self.pool = pool or PagedKVPool(
             n_pages or self.max_pages * lanes + 1, page_size, pool_layers,
-            0 if latent else n_kv, 0 if latent else d_model // n_heads,
-            kv_dtype, device, mesh=mesh, latent_width=latent)
+            0 if latent else n_kv, 0 if latent else head_dim,
+            kv_dtype, device, mesh=mesh, latent_width=latent,
+            index_dim=spec.index_dim if sparse else 0)
         #: the Mamba layers' per-lane recurrent state (None without any):
         #: rotates through every dispatch beside ``pool.kv`` (_kv_state)
         self.state = (LaneStateStore(spec, lanes, compute_dtype,
@@ -521,7 +537,7 @@ class ContinuousBatcher:
                     compute_dtype, self.pool.dtype)
             return kernel_geometry_error(
                 widest, n_heads // n_shards, n_kv // n_shards,
-                d_model // n_heads, self.pool.page_size, self.max_pages,
+                head_dim, self.pool.page_size, self.max_pages,
                 compute_dtype, self.pool.dtype)
 
         if use_kernel is None:
@@ -575,6 +591,14 @@ class ContinuousBatcher:
             if spec is not None and spec.moe_layers else None)
         self.moe_decode_steps = 0    # decode steps that had a live lane
         self.moe_experts_hit = 0     # over those steps and expert layers
+        #: the indexer's work (``debug_state()["sparse"]``), host integers
+        #: from the lengths the scheduler commits, by dispatch kind: rows
+        #: x layers that ran the indexer, the keys they scored (a row's
+        #: context), the keys they attended (``min(context, topk)``), and
+        #: the rows whose context was within ``topk`` (all keys selected)
+        self._sparse = ({kind: dict(query_rows=0, keys_scored=0,
+                                    keys_attended=0, dense_rows=0)
+                         for kind in ("decode", "round")} if sparse else None)
         rep, psh = self._rep, self._param_sh
         kvsh = self.pool.kv_sharding
         self._step = self._jit(
@@ -829,16 +853,37 @@ class ContinuousBatcher:
         """What the step programs take as ``kv_pool``, donate and return:
         the page store, or with Mamba layers the pair ``(page store, lane
         state)``."""
-        if self.state is None:
-            return self.pool.kv
-        return self.pool.kv, self.state.arrays
+        if self.state is not None:
+            return self.pool.kv, self.state.arrays
+        if self.pool.index is not None:
+            return self.pool.kv, self.pool.index
+        return self.pool.kv
 
     @_kv_state.setter
     def _kv_state(self, value) -> None:
-        if self.state is None:
-            self.pool.kv = value
-        else:
+        if self.state is not None:
             self.pool.kv, self.state.arrays = value
+        elif self.pool.index is not None:
+            self.pool.kv, self.pool.index = value
+        else:
+            self.pool.kv = value
+
+    def _note_sparse(self, kind: str, start: int, n: int) -> None:
+        """Count ``n`` query rows of one lane at contexts ``start + 1 ..
+        start + n`` (keys at or before the row, itself included) under
+        ``kind`` ("decode" | "round"); a no-op without an indexer."""
+        if self._sparse is None or n <= 0:
+            return
+        k, layers = self.model_spec.index_topk, self.model_spec.n_layers
+        dense = min(max(k - start, 0), n)       # rows whose context <= k
+        scored = n * start + n * (n + 1) // 2
+        attended = (dense * start + dense * (dense + 1) // 2
+                    + (n - dense) * k)
+        c = self._sparse[kind]
+        c["query_rows"] += n * layers
+        c["keys_scored"] += scored * layers
+        c["keys_attended"] += attended * layers
+        c["dense_rows"] += dense * layers
 
     #: the stages of a scheduler pass, in the order a pass takes them
     STAGES = ("admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
@@ -1374,6 +1419,7 @@ class ContinuousBatcher:
                      "page_nbytes": pool.page_nbytes,
                      "entry_kind": pool.entry_kind,
                      "bytes_per_token": pool.bytes_per_token,
+                     "index_bytes_per_token": pool.index_bytes_per_token,
                      "hbm_bytes": pool.hbm_bytes,
                      "n_shards": pool.n_shards,
                      "elastic": self.hbm is not None,
@@ -1423,6 +1469,11 @@ class ContinuousBatcher:
                 "decode_steps": self.moe_decode_steps,
                 # summed over decode steps and expert layers
                 "experts_hit": self.moe_experts_hit}
+        if self._sparse is not None:
+            out["sparse"] = {"topk": self.model_spec.index_topk,
+                             **{name: {kind: c[name]
+                                       for kind, c in self._sparse.items()}
+                                for name in self._sparse["decode"]}}
         if self.state is not None:
             out["state"] = {"kind": "mamba", "lanes": self.state.lanes,
                             "bytes_per_lane": self.state.bytes_per_lane,
@@ -2385,6 +2436,7 @@ class ContinuousBatcher:
                 if self._active[lane] is not req or req.cancelled:
                     continue
                 c = chunks[lane]
+                self._note_sparse("round", req.length, c)
                 req.length += c
                 del req.pending_prompt[:c]
                 self._fl_pages(req)
@@ -2430,6 +2482,7 @@ class ContinuousBatcher:
                     continue
                 self._probe_countdown_locked(req)
                 self._note_second_token(req, now)
+                self._note_sparse("round", req.length, 1)
                 req.length += 1
                 tok = int(next_tokens[lane])
                 req.tokens_out.append(tok)
@@ -3017,6 +3070,7 @@ class ContinuousBatcher:
                 if n == 0:
                     continue
                 emitted_total += n
+                self._note_sparse("decode", req.length, n)
                 # the block is one device round trip: spread its wall time
                 # evenly over the lane's tokens so ITL keeps a true mean
                 # (the burst shape is documented in docs/PERFORMANCE.md)
@@ -3346,6 +3400,7 @@ class ContinuousBatcher:
                     continue  # the _run sweep releases it next round
                 self._probe_countdown_locked(req)
                 self._note_second_token(req, now)
+                self._note_sparse("decode", req.length, 1)
                 req.length += 1
                 req.tokens_out.append(int(next_tokens[lane]))
                 self.tokens_generated += 1

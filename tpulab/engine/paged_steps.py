@@ -23,7 +23,9 @@ Every forward runs ONE layer block (:func:`_layer_block`) read from a
 ``kv_pool`` every step function takes, donates, carries through its scan
 and returns is the pair ``(page store, lane state)``: the attention
 layers' pages and the Mamba layers' per-lane recurrent state
-(:class:`~tpulab.engine.kv_pool.LaneStateStore` ``.arrays``), one pytree.
+(:class:`~tpulab.engine.kv_pool.LaneStateStore` ``.arrays``), one pytree;
+for a model with a learned indexer it is the pair ``(page store, index
+rows)`` (``PagedKVPool.kv`` and ``.index``).
 The functions keep their ``__name__``: a trace names a program
 ``jit_<name>``, and the benchmark's readers key on it.  Nothing here imports
 the scheduler (:mod:`tpulab.engine.paged`).
@@ -214,9 +216,113 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
         return attn.reshape(b, m, -1), kv_pool
 
 
+def _sparse_attention(spec, p, layer, h, pos, valid, kv_pool, page_idx,
+                      slot_idx, seg, compute_dtype):
+    """GQA attention of one layer over the keys a learned indexer selects
+    (``spec.index_topk``; :mod:`tpulab.ops.sparse_attention`), on the pair
+    ``kv_pool = (page store, index rows)``: ``(attn (B, M, H * D),
+    kv_pool)``.
+
+    Projections, per-head QK-norm, RoPE; the new K/V rows and the new index
+    keys (LayerNorm, RoPE over their own width) are scattered under the
+    same ``(page_idx, slot_idx)``; then, on ROWS — every query token of the
+    dispatch with its lane, whatever the form (a decode step's ``B``, a
+    packed round's ``T``, the padded form's ``B * M``) — the indexer scores
+    each row against every key of its lane at or before it, the
+    ``index_topk`` largest are selected (all of them while the context is
+    no longer than that: plain causal GQA), and the row attends to those
+    alone.  A packed round (``seg["rows"]``) makes the three calls once a
+    segment kind, chunk rows ``[0, M)`` and decode rows ``[M, M + B)``, as
+    :func:`_segment_calls` does and for its reason: a call walks a lane's
+    pages for all the rows it is given."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
+                                           split_qkv)
+    from tpulab.ops import sparse_attention as sa
+
+    pages, index = kv_pool
+    b, m = h.shape[:2]
+    f32 = jnp.float32
+    eps, theta = spec.rms_eps, spec.rope_theta
+    q, knew, vnew = split_qkv(h @ qmat(p["wqkv"], compute_dtype), b, m,
+                              spec.n_heads, spec.n_kv_heads, spec.head_dim)
+    if spec.qk_norm:
+        q = _rmsnorm(q, p["q_norm"]["scale"], eps)
+        knew = _rmsnorm(knew, p["k_norm"]["scale"], eps)
+    if theta:
+        q, knew = apply_rope(q, pos, theta), apply_rope(knew, pos, theta)
+    tail = knew.shape[2:]
+    pages = _scatter_kv(pages, layer, page_idx, slot_idx,
+                        knew.reshape(page_idx.shape + tail),
+                        vnew.reshape(page_idx.shape + tail))
+    use_kernel, tables, kv_lens = (seg["use_kernel"], seg["tables"],
+                                   seg["kv_lens"])
+    with jax.named_scope("dsa_indexer"):
+        ix = p["indexer"]
+        hi, di = spec.index_heads, spec.index_dim
+        a = (h @ qmat(ix["wq"], compute_dtype)).reshape(b, m, hi, di)
+        key = (h @ qmat(ix["wk"], compute_dtype)).astype(f32)
+        key = key - key.mean(-1, keepdims=True)
+        key = (key * jax.lax.rsqrt(jnp.square(key).mean(-1, keepdims=True)
+                                   + eps) * ix["k_norm"]["scale"].astype(f32)
+               + ix["k_norm"]["bias"].astype(f32)).astype(compute_dtype)
+        if theta:
+            a = apply_rope(a, pos, theta)
+            key = apply_rope(key[..., None, :], pos, theta)[..., 0, :]
+        c = jnp.dot(h, qmat(ix["ww"], compute_dtype),
+                    preferred_element_type=f32) * (hi * di) ** -0.5
+        key = jnp.pad(key.astype(index.dtype).reshape(
+            page_idx.shape + (di,)),
+            [(0, 0)] * page_idx.ndim + [(0, index.shape[3] - di)])
+        index = index.at[layer, page_idx, slot_idx].set(key)
+        # gather-after-scatter, by whole pages: (B, W, row).  (Out of the
+        # whole store by ``layer * P + page`` the gather is a ``jnp.take``
+        # with a fill: 2.8 ms a decode step where this form, a slice of
+        # the layer's rows and then the gather, is 1.3: PR 34's chip runs)
+        ictx = index[layer][tables].reshape(tables.shape[0], -1,
+                                            index.shape[3])
+    w = ictx.shape[1]
+    packed = seg.get("rows")
+    row_lane = (seg["row_seg"][0] if packed is not None else jnp.broadcast_to(
+        jnp.arange(b, dtype=jnp.int32)[:, None], (b, m)).reshape(-1))
+    row_lane = jnp.where(valid.reshape(-1), row_lane, -1)
+    row_pos = pos.reshape(-1)
+    q = q.reshape((b * m,) + q.shape[2:])
+    a, c = a.reshape(b * m, hi, di), c.reshape(b * m, hi)
+    # (rows, the lanes that hold one, whether row b is lane b's one row)
+    if packed is not None:
+        cut = packed[2].shape[1]                      # M: chunk | decode rows
+        calls = [(slice(0, cut), packed[3] > 0, False),
+                 (slice(cut, None), packed[4] > 0, True)]
+    else:
+        calls = [(slice(None), valid.any(axis=1), m == 1)]
+    outs = []
+    for rows, lane_live, one_a_lane in calls:
+        with jax.named_scope("dsa_indexer"):
+            scores = sa.index_scores(a[rows], c[rows], row_lane[rows], ictx,
+                                     lane_live, kv_lens, use_kernel)
+        with jax.named_scope("dsa_select"):
+            live = ((jnp.arange(w)[None, :] <= row_pos[rows, None])
+                    & (row_lane[rows, None] >= 0))
+            chosen = sa.select_topk(scores, live, spec.index_topk)
+        with jax.named_scope("dsa_attention"):
+            if use_kernel and one_a_lane:
+                outs.append(sa.sparse_attend_decode(
+                    q[rows], chosen, pages, layer, tables, lane_live,
+                    kv_lens))
+                continue
+            outs.append(sa.sparse_attend(
+                q[rows], chosen, row_lane[rows], pages, layer, tables,
+                lane_live, kv_lens, compute_dtype, use_kernel))
+    attn = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
+    return attn.astype(compute_dtype).reshape(b, m, -1), (pages, index)
+
+
 def _pages(kv_pool):
     """The page store of a step function's ``kv_pool``: itself, or the
-    first of the pair a model with Mamba layers is served with."""
+    first of the pair a model with Mamba layers (``(pages, lane state)``)
+    or with an indexer (``(pages, index rows)``) is served with."""
     return kv_pool[0] if isinstance(kv_pool, tuple) else kv_pool
 
 
@@ -322,7 +428,8 @@ def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
 
 def _ffn_block(spec, p, layer, x, valid, compute_dtype):
     """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
-    experts plus the shared expert.  Returns ``(x, stats)``, ``stats``
+    experts (router kind ``spec.router``) plus the shared expert where the
+    model has one.  Returns ``(x, stats)``, ``stats``
     the expert layer's ``(E + 2,)`` counters or None."""
     import jax
     from tpulab.models.transformer import _dense_ffn, _rmsnorm
@@ -333,12 +440,14 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype):
     from tpulab.parallel.moe import routed_ffn
     b, m = x.shape[:2]
     y, stats = routed_ffn(p["moe"], h.reshape(b * m, -1), spec.top_k,
-                          compute_dtype, router="sigmoid_bias", act="swiglu",
+                          compute_dtype, router=spec.router, act="swiglu",
                           scale=spec.routed_scale, norm=spec.norm_topk,
                           valid=valid.reshape(-1))
-    with jax.named_scope("moe_shared"):
-        shared = _dense_ffn(p["shared"], h, compute_dtype)
-    return x + (y.reshape(b, m, -1) + shared).astype(x.dtype), stats
+    y = y.reshape(b, m, -1)
+    if spec.n_shared:
+        with jax.named_scope("moe_shared"):
+            y = y + _dense_ffn(p["shared"], h, compute_dtype)
+    return x + y.astype(x.dtype), stats
 
 
 def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
@@ -376,6 +485,10 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     ``(E + 2,)`` int32 counters
     (:func:`tpulab.parallel.moe.routing_stats`) or None on a dense layer.
 
+    With an indexer (``spec.index_topk``) ``kv_pool`` is the pair ``(page
+    store, index rows)`` and the attention reads only the keys the indexer
+    selects (:func:`_sparse_attention`).
+
     The layer's mixer is attention over the pages (above) or, by
     ``spec.mixers``, a Mamba block over the lane state
     (:func:`_mamba_mixer`); ``kv_pool`` is then the pair ``(page store,
@@ -411,6 +524,10 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         attn, kv_pool = _mla_attention(spec, p, layer, h, pos, kv_pool,
                                        page_idx, slot_idx, seg,
                                        compute_dtype)
+    elif spec.index_topk:
+        attn, kv_pool = _sparse_attention(spec, p, layer, h, pos, valid,
+                                          kv_pool, page_idx, slot_idx, seg,
+                                          compute_dtype)
     else:
         b, m = x.shape[:2]
         at = spec.store_layer(layer)       # its layer of the page store

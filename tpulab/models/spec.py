@@ -9,7 +9,11 @@ kind the *cache-entry kind* the page store holds:
 ``"kv"``      K and V rows of ``n_kv_heads * head_dim`` values (MHA/GQA);
 ``"latent"``  one row ``[c_kv ; k_rope]`` of ``kv_lora_rank +
               qk_rope_head_dim`` values a token a layer (multi-head latent
-              attention, served in the absorbed form).
+              attention, served in the absorbed form);
+``"kv_index"``  K and V rows as ``"kv"``, and beside them, under the same
+              page ids, one *index key* of ``index_dim`` values a token a
+              layer: what a learned indexer scores a query against to
+              choose the ``index_topk`` keys the query attends to.
 
 A layer's *mixer* is attention or a Mamba selective state-space block
 (``mixers``).  A Mamba layer leaves no pages: it keeps a per-lane recurrent
@@ -75,6 +79,28 @@ published keys.  An attention layer has the dense decoder's leaves
 ``d``          ``(d_inner,)``
 ``out_proj``   ``(d_inner, d_model)``
 =============  ==========================================================
+
+``keye_vl2`` (the decoder of Keye-VL-2.0-30B-A3B): a Qwen3-MoE-shaped GQA
+decoder (RMSNorm over each head of q and k before RoPE, softmax-routed
+experts renormalised over the chosen k, no shared expert) whose every layer
+carries a DeepSeek-Sparse-Attention indexer: ``index_heads`` index queries
+of ``index_dim`` and ONE index key a token; a query scores every key at or
+before it, ``I_ts = (index_heads * index_dim)^-0.5 * sum_i c_ti * relu(a_ti
+. b_s)`` in float32, and attends to the ``index_topk`` keys of largest
+``I`` (to all of them until the context passes ``index_topk``), one set a
+token shared by all heads.  :func:`keye_vl2_spec` reads the published keys
+and ``sa_config``.  A layer has ``wqkv`` = ``[q | k | v]``, ``q_norm`` /
+``k_norm`` ``{"scale": (head_dim,)}``, ``wo``, the ``moe`` leaves without
+``bias`` and no ``shared``, and under ``indexer``:
+
+=============  ==========================================================
+``wq``         ``(d_model, index_heads * index_dim)``: the index queries
+               ``a``, from the layer's normed input, RoPE over
+               ``index_dim``
+``wk``         ``(d_model, index_dim)``: the index key ``b``, LayerNorm
+               (``k_norm`` ``{"scale", "bias"}``) then RoPE
+``ww``         ``(d_model, index_heads)``: the heads' weights ``c``
+=============  ==========================================================
 """
 
 from __future__ import annotations
@@ -113,10 +139,24 @@ class ModelSpec:
     d_state: int = 0
     d_conv: int = 0
     dt_rank: int = 0
+    index_heads: int = 0                    # learned indexer (gqa), all three;
+    index_dim: int = 0                      # 0 = none: every key is attended
+    index_topk: int = 0
+    qk_norm: bool = False                   # RMSNorm over each head of q and k
+    router: str = "sigmoid_bias"            # | "softmax" (no selection bias)
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
             raise ValueError(f"unknown attention kind {self.attention!r}")
+        if self.router not in ("sigmoid_bias", "softmax"):
+            raise ValueError(f"unknown router kind {self.router!r}")
+        if self.index_topk or self.index_heads or self.index_dim:
+            if min(self.index_heads, self.index_dim, self.index_topk) < 1:
+                raise ValueError("an indexer gives index_heads, index_dim "
+                                 "and index_topk")
+            if self.attention != "gqa" or "mamba" in (self.mixers or ()):
+                raise ValueError("an indexer selects keys of GQA attention "
+                                 "on K/V pages only")
         kinds = self.layer_kinds or ("dense",) * self.n_layers
         if len(kinds) != self.n_layers or set(kinds) - {"dense", "moe"}:
             raise ValueError(f"layer_kinds {kinds} does not name a dense or "
@@ -139,8 +179,11 @@ class ModelSpec:
 
     @property
     def cache_entry(self) -> str:
-        """What a token leaves in the page store: ``"kv"`` or ``"latent"``."""
-        return "latent" if self.attention == "mla" else "kv"
+        """What a token leaves in the page store: ``"kv"``, ``"latent"`` or
+        ``"kv_index"``."""
+        if self.attention == "mla":
+            return "latent"
+        return "kv_index" if self.index_topk else "kv"
 
     @property
     def latent_width(self) -> int:
@@ -248,6 +291,49 @@ def jamba_spec(config: Dict[str, Any]) -> ModelSpec:
         dt_rank=int(config["mamba_dt_rank"]))
 
 
+def keye_vl2_spec(config: Dict[str, Any]) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type`` ``KeyeVL2``)
+    and its ``sa_config``.  Text positions only: with one position on all
+    three axes of ``mrope_section`` M-RoPE is RoPE.  Refuses what the layer
+    block does not compute."""
+    if config.get("mlp_only_layers"):
+        raise ValueError("mlp_only_layers is not implemented (every layer "
+                         "is an expert layer)")
+    if int(config.get("decoder_sparse_step", 1)) != 1:
+        raise ValueError("decoder_sparse_step != 1 is not implemented")
+    if config.get("use_sliding_window"):
+        raise ValueError("use_sliding_window is not implemented")
+    if config.get("attention_bias"):
+        raise ValueError("attention_bias is not implemented")
+    scaling = config.get("rope_scaling") or {}
+    kind = scaling.get("rope_type", scaling.get("type", "default"))
+    if kind not in ("default", "mrope"):
+        raise ValueError(f"rope_scaling type {kind!r} is not implemented")
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("norm_topk_prob false is not implemented (the "
+                         "softmax router renormalises over the chosen k)")
+    sa = config["sa_config"]
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError("indexer_num_kv_heads != 1 is not implemented (one "
+                         "index key a token)")
+    n_layers = int(config["num_hidden_layers"])
+    return ModelSpec(
+        n_layers=n_layers, d_model=int(config["hidden_size"]),
+        n_heads=int(config["num_attention_heads"]),
+        n_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=int(config["head_dim"]),
+        layer_kinds=("moe",) * n_layers,
+        n_experts=int(config["num_experts"]),
+        top_k=int(config["num_experts_per_tok"]),
+        moe_ff=int(config["moe_intermediate_size"]), n_shared=0,
+        router="softmax", qk_norm=True,
+        rms_eps=float(config["rms_norm_eps"]),
+        rope_theta=float(config["rope_theta"]),
+        index_heads=int(sa["indexer_num_heads"]),
+        index_dim=int(sa["indexer_head_dim"]),
+        index_topk=int(sa["topk"]))
+
+
 def split_kv_b(kv_b, spec: ModelSpec):
     """A published ``kv_b_proj`` ``(kv_lora_rank, n_heads * (qk_nope +
     v_head_dim))``, a head's columns ``[k_nope | v]``, as ``(w_uk (H, nope,
@@ -261,10 +347,11 @@ def split_kv_b(kv_b, spec: ModelSpec):
 def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                 scale: float = 0.02) -> Dict[str, Any]:
     """Seeded random float32 parameters in the layouts above: an MLA (+
-    expert) decoder with an untied output head, or a Mamba/attention hybrid
-    with a tied one (no ``lm_head``).  Weights normal ``scale``, norm scales
-    1, the router's selection bias drawn like a weight (not zero: choosing
-    with it and weighting without it must differ).
+    expert) decoder or a GQA decoder with an indexer, with an untied output
+    head, or a Mamba/attention hybrid with a tied one (no ``lm_head``).
+    Weights normal ``scale``, norm scales 1 (LayerNorm biases 0), the
+    ``"sigmoid_bias"`` router's selection bias drawn like a weight (not
+    zero: choosing with it and weighting without it must differ).
 
     A Mamba layer's SSM leaves follow the published initialisation, not
     normal ``scale`` (under which every channel forgets within three tokens
@@ -275,9 +362,11 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     import jax
     import jax.numpy as jnp
 
-    if spec.attention != "mla" and not spec.mamba_layers:
-        raise ValueError("init_params draws MLA decoders and Mamba hybrids; "
-                         "dense ones come from tpulab.models.transformer")
+    if (spec.attention != "mla" and not spec.mamba_layers
+            and not spec.index_topk):
+        raise ValueError("init_params draws MLA decoders, Mamba hybrids and "
+                         "decoders with an indexer; dense ones come from "
+                         "tpulab.models.transformer")
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                  16 * spec.n_layers + 4))
 
@@ -325,6 +414,16 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
         else:
             p.update(wqkv=w(d, (h + 2 * spec.n_kv_heads) * spec.head_dim),
                      wo=w(h * spec.head_dim, d))
+            if spec.qk_norm:
+                p.update(q_norm=norm(spec.head_dim),
+                         k_norm=norm(spec.head_dim))
+            if spec.index_topk:
+                p["indexer"] = {
+                    "wq": w(d, spec.index_heads * spec.index_dim),
+                    "wk": w(d, spec.index_dim),
+                    "k_norm": dict(norm(spec.index_dim), bias=jnp.zeros(
+                        (spec.index_dim,), jnp.float32)),
+                    "ww": w(d, spec.index_heads)}
         if kind == "dense":
             p.update(w1=w(d, d_ff), w3=w(d, d_ff), w2=w(d_ff, d))
         else:
@@ -333,6 +432,10 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                         "bias": w(spec.n_experts),
                         "w13": w(spec.n_experts, d, 2 * f),
                         "w2": w(spec.n_experts, f, d)}
-            p["shared"] = {"w1": w(d, fs), "w3": w(d, fs), "w2": w(fs, d)}
+            if spec.router != "sigmoid_bias":
+                del p["moe"]["bias"]
+            if fs:
+                p["shared"] = {"w1": w(d, fs), "w3": w(d, fs),
+                               "w2": w(fs, d)}
         params[f"layer{i}"] = p
     return params
