@@ -81,6 +81,10 @@ ATTN_TOL = 2e-2
 #: logits of the same request through two programs (different reduction
 #: order, bf16 activations), relative to the largest logit
 LOGIT_RTOL = 5e-2
+#: of the 64 expert assignments of phase keye_vl2's decode step (4 rows,
+#: top-8, 2 layers), how many the two forms may make differently: a
+#: router's near-tie falls either way under bf16 activations
+KEYE_FLIPS = 2
 
 
 @dataclass
@@ -550,7 +554,16 @@ def phase_keye(smoke: Smoke) -> str:
     later chunk whose every row drops keys, lanes 1 and 2 decode, lane 3 a
     first chunk) and a decode step of a two-layer model with a learned
     indexer: the score, sparse-attention and decode kernels against the
-    XLA forms on the same inputs, logits and index rows."""
+    XLA forms on the same inputs, logits and index rows, each path on the
+    store its own rounds filled (what production runs).  The decode step
+    also returns its expert counters: a row whose router scores nearly tie
+    may take another expert under one form than under the other (seen on
+    the chip at PR 35: one of the step's 64 assignments, in lane 0, and
+    that lane 0.33 of 4.41 from the XLA form where the other three read
+    0.027-0.032), so a lane may pass ``LOGIT_RTOL`` only as an assignment
+    that differs allows: at most ``KEYE_FLIPS`` of them, a lane each, and
+    such a lane within a ``top_k``-th of the scale (the expert that changes
+    is the least of its row's ``top_k``)."""
     from functools import partial
 
     import jax
@@ -586,7 +599,7 @@ def phase_keye(smoke: Smoke) -> str:
                 {1: int(draw(1)[0]), 2: int(draw(1)[0])},
                 [fills * chunk, half - 3, half, 0])]
     final = [(fills + 1) * chunk - 8, half - 2, half + 1, 7]
-    out = {}
+    out, counts = {}, {}
     for name, uk in (("xla", False), ("kernel", True)):
         pool = PagedKVPool(lanes * mp + 1, page, spec.n_layers,
                            spec.n_kv_heads, spec.head_dim, jnp.bfloat16,
@@ -607,24 +620,43 @@ def phase_keye(smoke: Smoke) -> str:
                     paged_mixed_step, use_kernel=True, **kw), params, store,
                     *args)
             _, _, last, store, _moe = mixed(params, store, *args)
-        logits, store, _moe = step(params, store, i32(tables), i32(final),
-                                   i32([5, 6, 7, 8]),
-                                   jnp.ones((lanes,), bool))
+        logits, store, experts = step(params, store, i32(tables), i32(final),
+                                      i32([5, 6, 7, 8]),
+                                      jnp.ones((lanes,), bool))
         out[name] = (np.asarray(last, np.float32),
                      np.asarray(logits, np.float32),
                      np.asarray(store[1][0, 1:], np.float32))
+        # the step's assignments per expert, a layer
+        counts[name] = np.asarray(experts)[:, :-2]
+    flips = int(np.abs(counts["kernel"] - counts["xla"]).sum()) // 2
+    if flips > KEYE_FLIPS:
+        raise AssertionError(f"keye_vl2 decode step: {flips} of "
+                             f"{int(counts['xla'].sum())} expert "
+                             f"assignments differ between the forms "
+                             f"(limit {KEYE_FLIPS})")
     report = []
     for i, what in enumerate(("mixed round", "decode step", "index rows")):
         ref, got = out["xla"][i], out["kernel"][i]
-        err = float(np.abs(got - ref).max())
         scale = float(np.abs(ref).max())
-        if not np.isfinite(got).all() or err > LOGIT_RTOL * scale:
-            raise AssertionError(f"keye_vl2 {what}: with the kernels "
-                                 f"{err:.4g} from the XLA forms (largest "
-                                 f"{scale:.4g})")
-        report.append(f"{what} err {err:.4g} of {scale:.4g}")
+        by_row = np.abs(got - ref).reshape(len(ref), -1).max(axis=1)
+        over = by_row > LOGIT_RTOL * scale
+        # only the decode step's lanes may lean on a flipped assignment
+        allowed = flips if what == "decode step" else 0
+        if (not np.isfinite(got).all() or over.sum() > allowed
+                or by_row.max() > scale / spec.top_k):
+            raise AssertionError(
+                f"keye_vl2 {what}: with the kernels "
+                f"{float(by_row.max()):.4g} from the XLA forms (largest "
+                f"{scale:.4g}; by row {np.round(by_row, 4).tolist()}; "
+                f"{flips} expert assignments differ)")
+        report.append(f"{what} err {float(by_row[~over].max()):.4g} of "
+                      f"{scale:.4g}" + (
+                          f" ({int(over.sum())} lane after another expert: "
+                          f"{float(by_row.max()):.4g})" if over.any()
+                          else ""))
     return (f"lane 0 at {final[0] + 1} keys of topk {spec.index_topk}; "
-            + "; ".join(report))
+            + "; ".join(report) + f"; {flips} of "
+            f"{int(counts['xla'].sum())} decode assignments differ")
 
 
 # -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------

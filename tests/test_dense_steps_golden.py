@@ -15,6 +15,17 @@ Since PR 29 ``paged_mixed_step`` takes its round packed by token (7 rows
 where the parent's padded form computed 3 x 4): the same segments, the same
 products on another row count, so that one case compares at the 1e-6
 fallback on every machine; the other four programs stay to the bit.
+
+Since PR 35 the ragged kernel's loop over a lane's blocks takes its trip
+count from the lane's length (the parent ran every block of the table under
+a conditional): the same operations in the same order, but XLA:CPU, which
+compiles the interpreter's program, sums the one-row products of a decode
+step in another order inside the new loop (with the conditional put back
+and nothing else changed the parent's bits return).  The two entries
+``<model>.decode.kernel=True`` are therefore PR 35's own bits, 5.96e-8
+(``mha``) and 8.94e-8 (``gqa_rope_swiglu``) from the parent's at the
+largest; every other entry is a365295's, and the kernel's rows at a chunk's
+width (``ragged``) stayed to the bit through that PR.
 """
 
 import os

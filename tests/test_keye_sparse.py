@@ -31,6 +31,9 @@ from tpulab.models.spec import (ModelSpec, dense_spec, init_params,
                                 keye_vl2_spec)
 from tpulab.ops import sparse_attention as sa
 
+from helpers_attention import (BF16_ATOL, BF16_RTOL, assert_parents_bits,
+                               sparse_attend_case, sparse_decode_case)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCAB, LANES, PAGE, TOPK = 97, 4, 8, 12
 CONFIG = {
@@ -280,21 +283,7 @@ def test_score_kernel_in_interpret_mode_matches_the_xla_form():
 
 
 def test_attention_kernel_in_interpret_mode_matches_the_xla_form():
-    rng = np.random.default_rng(4)
-    r, h, d, mp = 9, 4, 32, 5
-    pool = jnp.asarray(rng.standard_normal((2, 1 + 3 * mp, 2, PAGE, 2 * d)),
-                       jnp.float32)
-    tables = i32(1 + rng.permutation(3 * mp).reshape(3, mp))
-    q = jnp.asarray(rng.standard_normal((r, h, d)), jnp.float32)
-    lane = i32([0, 0, 2, 2, 2, -1, 0, 2, 2])
-    kv_lens = i32([17, 0, 40])
-    mask = rng.random((r, mp * PAGE)) < 0.3
-    mask &= np.arange(mp * PAGE)[None, :] < np.asarray(kv_lens)[
-        np.maximum(np.asarray(lane), 0)][:, None]
-    mask[np.asarray(lane) < 0] = False
-    mask[3] = False                       # a row that selects nothing
-    args = (q, jnp.asarray(mask), lane, pool, 1, tables, i32([1, 0, 1]),
-            kv_lens, jnp.float32)
+    args = sparse_attend_case()
     want = np.asarray(sa.sparse_attend(*args, use_kernel=False))
     got = np.asarray(sa.sparse_attend(*args, use_kernel=True))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
@@ -302,27 +291,46 @@ def test_attention_kernel_in_interpret_mode_matches_the_xla_form():
 
 
 def test_decode_kernel_in_interpret_mode_matches_the_xla_form():
-    """One row a lane, a lane skipped, a lane whose row selects nothing."""
-    rng = np.random.default_rng(15)
-    b, h, d, mp = 4, 4, 32, 5
-    pool = jnp.asarray(rng.standard_normal((2, 1 + b * mp, 2, PAGE, 2 * d)),
-                       jnp.float32)
-    tables = i32(1 + rng.permutation(b * mp).reshape(b, mp))
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    kv_lens = np.array([17, 0, 40, 9])
-    mask = (rng.random((b, mp * PAGE)) < 0.4) & (
-        np.arange(mp * PAGE)[None, :] < kv_lens[:, None])
-    mask[3] = False
-    live = i32([1, 0, 1, 1])
-    want = np.asarray(sa.sparse_attend(
-        q, jnp.asarray(mask), i32([0, -1, 2, 3]), pool, 1, tables, live,
-        i32(kv_lens), jnp.float32, use_kernel=False))
-    got = np.asarray(sa.sparse_attend_decode(
-        q, jnp.asarray(mask), pool, 1, tables, live, i32(kv_lens)))
+    args, as_rows = sparse_decode_case()
+    want = np.asarray(sa.sparse_attend(*as_rows, use_kernel=False))
+    got = np.asarray(sa.sparse_attend_decode(*args))
     for lane in (0, 2, 3):
         np.testing.assert_allclose(got[lane], want[lane], rtol=2e-5,
                                    atol=2e-5)
     assert not got[3].any()
+
+
+@pytest.mark.parametrize("kernel", ["sparse_paged_attention",
+                                    "sparse_paged_decode"])
+def test_float32_store_gives_the_parents_bits(kernel):
+    """A float32 store keeps both products at ``HIGHEST``: the outputs of
+    the commit before the operand rule, to the bit."""
+    if kernel == "sparse_paged_attention":
+        got = sa.sparse_attend(*sparse_attend_case(), use_kernel=True)
+    else:
+        got = sa.sparse_attend_decode(*sparse_decode_case()[0])
+        got = got.at[1].set(0.0)  # the skipped lane's row is unwritten
+    assert_parents_bits(kernel, got)
+
+
+@pytest.mark.parametrize("kernel", ["sparse_paged_attention",
+                                    "sparse_paged_decode"])
+def test_bf16_store_rounds_where_the_xla_form_does(kernel):
+    """A bf16 store: the kernel rounds the probabilities to bf16 before the
+    value product, as ``sparse_attend_xla`` does, so the two agree to the
+    rounding of their bf16 outputs (the interpreter shows half of this)."""
+    args, as_rows = sparse_decode_case(jnp.bfloat16)
+    if kernel == "sparse_paged_attention":
+        as_rows = sparse_attend_case(jnp.bfloat16)
+        got = sa.sparse_attend(*as_rows, use_kernel=True)
+        rows = np.asarray(as_rows[2]) >= 0
+    else:
+        got = sa.sparse_attend_decode(*args)
+        rows = np.asarray(args[5]) > 0
+    want = sa.sparse_attend(*as_rows, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(got, np.float32)[rows],
+                               np.asarray(want, np.float32)[rows],
+                               rtol=BF16_RTOL, atol=BF16_ATOL)
 
 
 # ---------------------------------------------------------- the layer's halves ----
@@ -375,15 +383,17 @@ def test_softmax_routing_without_a_shared_expert_matches_the_expert_loop(
 def test_one_chunk_uneven_chunks_and_token_by_token_agree(model, reference,
                                                           use_kernel):
     """A 29-token prompt (past ``topk`` 12) through mixed rounds in one
-    chunk, in chunks of 8, 3, 1, 9 and 8, and token by token through decode
-    steps: the same logits at the last position, the same K/V and index
-    rows, and the reference's logits."""
+    chunk, in chunks of 16, 2, 1 and 10 (a chunk that ends on a page's edge,
+    two short ones, one that crosses an edge; round widths the other tests
+    of this file compile anyway), and token by token through decode steps:
+    the same logits at the last position, the same K/V and index rows, and
+    the reference's logits."""
     spec, params = model
     tokens = np.random.default_rng(7).integers(0, VOCAB, 29)
     want = reference.last_logits(params, tokens.tolist(), 1,
                                  **reference.hyper_of(CONFIG))[0]
     outs = []
-    for sizes in ([29], [8, 3, 1, 9, 8]):
+    for sizes in ([29], [16, 2, 1, 10]):
         store, tables = _fresh(spec)
         at = 0
         for n in sizes:

@@ -119,16 +119,29 @@ def _store(kw, pool_kw):
                      LaneStateStore(spec, LANES, jnp.float32).arrays)
 
 
-def _plain_form(params, kw):
+_PROGRAMS = {}
+
+
+def _jit(fn, model, kw, **static):
+    """``fn`` jitted once a (model, attention path): the mixes of a model
+    share its compiled shapes (a round is keyed by its bucketed width)
+    instead of each compiling programs of its own."""
+    key = (fn, model, kw["use_kernel"], tuple(static.items()))
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = jax.jit(partial(fn, **static, **kw))
+    return _PROGRAMS[key]
+
+
+def _plain_form(model, params, kw):
     """``plain(kv, tables, seq (LANES, M), q_lens, kv_lens) -> (last logits,
     kv, *moe)``: the padded form, or token by token through the decode
     step where Mamba layers refuse the padded form."""
     spec = kw.get("spec")
     if spec is None or not spec.mamba_layers:
-        padded = jax.jit(partial(paged_ragged_forward, last_only=True, **kw))
+        padded = _jit(paged_ragged_forward, model, kw, last_only=True)
         return lambda kv, tables, seq, q_lens, kv_lens: padded(
             params, kv, tables, i32(seq), i32(q_lens), i32(kv_lens))
-    step = jax.jit(partial(paged_decode_step, **kw))
+    step = _jit(paged_decode_step, model, kw)
 
     def token_by_token(kv, tables, seq, q_lens, kv_lens):
         last = np.zeros((LANES, VOCAB), np.float32)
@@ -155,7 +168,7 @@ def test_packed_round_is_the_padded_round(models, model, mix, use_kernel):
     case = MIXES[mix]
     rng = np.random.default_rng(5)
     tables = i32(case.get("tables", OWN))
-    plain = _plain_form(params, kw)
+    plain = _plain_form(model, params, kw)
     # the contexts the round finds in the pages
     ctx = np.asarray(case["ctx"], np.int32)
     fill = rng.integers(0, VOCAB, (LANES, max(int(ctx.max()), 1)))
@@ -173,10 +186,9 @@ def test_packed_round_is_the_padded_round(models, model, mix, use_kernel):
     temps = jnp.zeros((LANES,), jnp.float32)
     seeds = jnp.zeros((LANES, 2), jnp.uint32)
 
-    picks, _lp, last, kv_packed, *moe = jax.jit(
-        partial(paged_mixed_step, **kw))(
-            params, kv, tables, i32(toks), i32(row_lane), i32(row_off),
-            i32(q_lens), i32(kv_lens), temps, seeds)
+    picks, _lp, last, kv_packed, *moe = _jit(paged_mixed_step, model, kw)(
+        params, kv, tables, i32(toks), i32(row_lane), i32(row_off),
+        i32(q_lens), i32(kv_lens), temps, seeds)
 
     seq = np.zeros((LANES, m), np.int32)
     for lane, chunk in prefill.items():
@@ -215,14 +227,14 @@ def test_rows_without_a_token_leave_every_layer_finite(models, model,
         LANES, {1: rng.integers(0, VOCAB, 7)},          # lane 0 is idle
         {3: int(rng.integers(VOCAB)), 6: int(rng.integers(VOCAB))})
     ctx = np.asarray([0, 5, 0, 9, 0, 0, 2, 0], np.int32)
-    plain = _plain_form(params, kw)
+    plain = _plain_form(model, params, kw)
     _logits, kv, *_ = plain(_store(kw, pool_kw), i32(OWN),
                             rng.integers(0, VOCAB, (LANES, 9)), ctx, ctx)
     args = (params, kv, i32(OWN), i32(toks), i32(row_lane), i32(row_off),
             i32(q_lens), i32(np.where(q_lens > 0, ctx + q_lens, 0)),
             jnp.zeros((LANES,), jnp.float32),
             jnp.zeros((LANES, 2), jnp.uint32))
-    want = np.asarray(paged_mixed_step(*args, **kw)[2])
+    want = np.asarray(_jit(paged_mixed_step, model, kw)(*args)[2])
 
     def unwritten_is_nan(kernel):
         def call(q, kv_pool, layer, tables, q_lens, *rest, **kwargs):

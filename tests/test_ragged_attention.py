@@ -18,6 +18,7 @@ paged attention"):
    guard, the PR 8 discipline).
 """
 
+import functools
 import threading
 
 import jax
@@ -31,9 +32,14 @@ from tpulab.engine.paged_steps import (_gather_attend,
                                        _gather_attend_latent)
 from tpulab.models.transformer import (early_exit_draft,
                                        init_transformer_params)
+from tpulab.ops import sparse_attention as sa
 from tpulab.ops.ragged_attention import (ragged_latent_attention,
                                          ragged_paged_attention)
 from tpulab.parallel import make_mesh
+
+from helpers_attention import (BF16_ATOL, BF16_RTOL, assert_operand_rule,
+                               assert_parents_bits, sparse_attend_case,
+                               sparse_decode_case)
 
 # ------------------------------------------------------------ kernel ----
 
@@ -93,6 +99,16 @@ def _shape_case(name, page_size):
     }[name]
 
 
+@functools.lru_cache(maxsize=None)
+def _grid_kernel(mesh_n):
+    """The kernel as the grid calls it, jitted once a mesh: the shapes of a
+    (dtype, page size) share one compiled program (an eager ``shard_map``
+    is traced and compiled again on every call)."""
+    mesh = (make_mesh({"model": mesh_n}, jax.devices()[:mesh_n])
+            if mesh_n else None)
+    return jax.jit(functools.partial(ragged_paged_attention, mesh=mesh))
+
+
 @pytest.mark.parametrize("mesh_n", [None, 2])
 @pytest.mark.parametrize("page_size", [4, 8])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -116,17 +132,25 @@ def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n):
                                jnp.float32)
     tables = jnp.asarray(
         np.arange(1, b * mp + 1).reshape(b, mp), jnp.int32)
-    mesh = (make_mesh({"model": mesh_n}, jax.devices()[:mesh_n])
-            if mesh_n else None)
-    got = ragged_paged_attention(
+    got = _grid_kernel(mesh_n)(
         q.astype(dt), _pool(k_pool, v_pool, dt), 0,
         tables, jnp.asarray(q_lens, jnp.int32),
-        jnp.asarray(kv_lens, jnp.int32), mesh=mesh)
-    want = _reference(np.asarray(q), np.asarray(k_pool),
-                      np.asarray(v_pool), np.asarray(tables),
-                      q_lens, kv_lens)
-    tol = dict(rtol=2e-5, atol=2e-5) if dt == jnp.float32 \
-        else dict(rtol=5e-2, atol=5e-2)
+        jnp.asarray(kv_lens, jnp.int32))
+    if dt == jnp.float32:
+        want = _reference(np.asarray(q), np.asarray(k_pool),
+                          np.asarray(v_pool), np.asarray(tables),
+                          q_lens, kv_lens)
+        tol = dict(rtol=2e-5, atol=2e-5)
+    else:
+        # a bf16 store against the XLA form of the same step on the same
+        # bf16 pages: both round the probabilities and the output to bf16
+        pool = _pool(k_pool, v_pool, dt)[0]
+        pos = (jnp.asarray(kv_lens) - jnp.asarray(q_lens))[:, None] \
+            + jnp.arange(m)[None, :]
+        want = np.asarray(_gather_attend(
+            q.astype(dt), pool[:, 0], pool[:, 1], tables, pos, dt),
+            np.float32).reshape(b, m, hq, d)
+        tol = dict(rtol=BF16_RTOL, atol=BF16_ATOL)
     for bb in range(b):
         n = int(q_lens[bb])
         np.testing.assert_allclose(
@@ -207,12 +231,13 @@ def test_kernel_walks_the_layer_it_is_given(case):
     assert np.abs(outs[1] - outs[2]).max() > 1e-2   # the layers do differ
 
 
-def _round_case(kernel):
+def _round_case(kernel, dtype=jnp.float32):
     """A mixed round's attention on either kernel: ``(attend, reference,
     q, q_lens, kv_lens)``.  Lanes 1 and 4 hold a chunk, 2 and 5 decode, 0
     and 3 hold nothing; lane 0's pages and query rows are NaN, lane 3 has
     sound context in its pages.  ``attend(q, q_lens)`` is the kernel at
-    ``q``'s width, ``reference`` the XLA gather at the same."""
+    ``q``'s width, ``reference`` the XLA gather at the same; queries and
+    page store in ``dtype``."""
     ps, mp, m, h = 8, 3, 2 * 8, 4
     q_lens = np.asarray([0, ps + 3, 1, 0, 5, 1], np.int32)
     kv_lens = np.asarray([ps, 2 * ps + 3, ps + 1, 2 * ps, 5, 3 * ps],
@@ -221,11 +246,11 @@ def _round_case(kernel):
     tables = jnp.asarray(np.arange(1, b * mp + 1).reshape(b, mp), jnp.int32)
     ks = jax.random.split(jax.random.PRNGKey(17), 2)
     width = 48 if kernel == "latent" else 16
-    q = jax.random.normal(ks[0], (b, m, h, width), jnp.float32)
+    q = jax.random.normal(ks[0], (b, m, h, width), jnp.float32).astype(dtype)
     q = q.at[0].set(jnp.nan)
     if kernel == "latent":
         pool = jax.random.normal(ks[1], (2, b * mp + 1, 1, ps, 64),
-                                 jnp.float32)
+                                 jnp.float32).astype(dtype)
         pool = pool.at[..., width:].set(0.0)     # the row's zero padding
         kw = dict(v_width=32, sm_scale=0.2)
 
@@ -236,10 +261,10 @@ def _round_case(kernel):
         def reference(q, qpos):
             return _gather_attend_latent(q, pool[1, :, 0], tables, qpos,
                                          kw["v_width"], kw["sm_scale"],
-                                         jnp.float32)
+                                         dtype)
     else:
         pool = jax.random.normal(ks[1], (2, b * mp + 1, 2, ps, 2 * width),
-                                 jnp.float32)
+                                 jnp.float32).astype(dtype)
         mesh = (make_mesh({"model": 2}, jax.devices()[:2])
                 if kernel == "kv-mesh2" else None)
 
@@ -249,7 +274,7 @@ def _round_case(kernel):
 
         def reference(q, qpos):
             return _gather_attend(q, pool[1, :, 0], pool[1, :, 1], tables,
-                                  qpos, jnp.float32).reshape(q.shape)
+                                  qpos, dtype).reshape(q.shape)
     pool = pool.at[:, 1:1 + mp].set(jnp.nan)     # lane 0's pages
     return attend, reference, q, q_lens, kv_lens
 
@@ -308,6 +333,65 @@ def test_a_skipped_lane_starts_no_dma_and_writes_nothing(kernel):
     assert not {"dma_start", "dma_wait", "swap", "addupdate"} & set(top)
     inside = str(body.eqns[top.index("cond")].params["branches"])
     assert "dma_start" in inside and "dma_wait" in inside
+
+
+def _rule_case(kernel, dtype):
+    """``(call, arguments, shape of the staged block)`` of one of the five
+    walks at a geometry this suite compiles anyway."""
+    if kernel.startswith("sparse"):
+        if kernel == "sparse_paged_attention":
+            args = sparse_attend_case(dtype)
+            return (lambda *a: sa.sparse_attend(*a, dtype, use_kernel=True),
+                    args[:-1], (5 * 8, 64))
+        return sa.sparse_attend_decode, sparse_decode_case(dtype)[0], (40, 64)
+    attend, _ref, q, q_lens, _kv = _round_case(
+        "latent" if kernel == "ragged_latent_attention" else "kv", dtype)
+    if kernel == "ragged_paged_attention-one-row":
+        q, q_lens = q[:, :1], (q_lens == 1).astype(np.int32)
+    return attend, (q, jnp.asarray(q_lens)), (24, q.shape[-1] * 2
+                                              if "latent" not in kernel
+                                              else 64)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kernel", [
+    "ragged_paged_attention", "ragged_paged_attention-one-row",
+    "ragged_latent_attention", "sparse_paged_attention",
+    "sparse_paged_decode"])
+def test_both_products_take_their_operands_from_the_stores_dtype(kernel,
+                                                                 dtype):
+    """The one rule of the five walks, read off the kernel body's jaxpr: a
+    bf16 store gives both products bf16 operands in one default pass with
+    float32 accumulation and no float32 copy of the staged block; a
+    float32 store keeps ``HIGHEST`` on both."""
+    fn, args, block = _rule_case(kernel, jnp.dtype(dtype))
+    assert_operand_rule(fn, args, jnp.dtype(dtype), block)
+
+
+@pytest.mark.parametrize("kernel", [
+    "ragged_paged_attention", "ragged_paged_attention-one-row",
+    "ragged_latent_attention"])
+def test_float32_store_gives_the_parents_bits(kernel):
+    """A float32 store keeps both products at ``HIGHEST``: the rows of the
+    commit before the operand rule, to the bit (a skipped lane's rows are
+    unwritten: NaN in the interpreter then and now; the one-row walk's
+    entry: see ``assert_parents_bits``)."""
+    fn, args, _block = _rule_case(kernel, jnp.float32)
+    assert_parents_bits(kernel, fn(*args))
+
+
+def test_bf16_latent_store_rounds_where_the_xla_form_does():
+    """:func:`test_kernel_matches_reference_grid`'s bf16 half for latent
+    pages: kernel and XLA gather round the probabilities to bf16 alike."""
+    attend, reference, q, q_lens, kv_lens = _round_case("latent",
+                                                        jnp.bfloat16)
+    m = q.shape[1]
+    got = np.asarray(attend(q, jnp.asarray(q_lens)), np.float32)
+    want = np.asarray(reference(q, jnp.asarray(
+        (kv_lens - q_lens)[:, None] + np.arange(m)[None, :])), np.float32)
+    valid = np.arange(m)[None, :] < q_lens[:, None]
+    np.testing.assert_allclose(got[valid], want[valid], rtol=BF16_RTOL,
+                               atol=BF16_ATOL)
 
 
 def test_kernel_takes_a_traced_layer():

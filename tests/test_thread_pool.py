@@ -120,3 +120,15 @@ def test_event_loop_group_runs_coroutines():
 def test_event_loop_group_submit_fn():
     with EventLoopGroup(1) as elg:
         assert elg.submit_fn(lambda: 42).result(timeout=5) == 42
+
+
+def test_on_one_frame_chunk_reserves_a_chunk_for_the_frames_beneath_it():
+    """The mechanism, not the clock: the helper returns ``fn``'s result, and
+    its frame asks for 256 KiB of 8-byte slots, more than the 16 KiB chunk
+    of CPython's frame stack, so it gets a chunk of its own whose rest its
+    callees share (what that buys is timed by the scan in
+    docs/PERFORMANCE.md, "Where a program is lowered")."""
+    from tpulab.core.threads import on_one_frame_chunk
+
+    assert on_one_frame_chunk(lambda a, b: a + b, 1, 2) == 3
+    assert on_one_frame_chunk.__code__.co_stacksize * 8 >= 256 << 10

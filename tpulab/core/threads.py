@@ -27,6 +27,30 @@ import threading
 from typing import Awaitable, Callable, Optional, Sequence
 
 
+def on_one_frame_chunk(fn: Callable, *args):
+    """``fn(*args)`` with every Python frame beneath it in ONE chunk of the
+    thread's frame stack.
+
+    CPython (3.11-3.12) keeps a thread's frames in chunks of 16 KiB and
+    returns a chunk the moment its first frame is popped.  A loop that sits
+    on a chunk's last frame therefore maps and unmaps 16 KiB around EVERY
+    call it makes, which costs more than the call; where the loop sits is an
+    accident of how deep the callers above it are.  JAX lowers a program
+    ~150 frames down, one tight loop per nesting level of the jaxpr: on the
+    chip's host the loop over a Pallas kernel body on such a boundary took
+    2.3 s a kernel where it takes 0.1 s a frame higher or lower (PERF.md
+    section 6, PR 35).  A frame that asks for more than a chunk gets a
+    chunk of its own, doubled until it fits, and the callees use what is
+    left of it: this one asks for 256 KiB, so the ~250 KiB behind it hold
+    some 600 frames with no boundary between them, for as long as ``fn``
+    runs.  The slots are reserved, never touched."""
+    return fn(*args)
+
+
+on_one_frame_chunk.__code__ = on_one_frame_chunk.__code__.replace(
+    co_stacksize=(1 << 15) + 64)
+
+
 class standard_threads:
     """OS-thread policy (reference standard_threads.h)."""
 

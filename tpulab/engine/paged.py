@@ -26,6 +26,7 @@ import numpy as np
 
 from tpulab import chaos
 from tpulab.core.deadline import Deadline, DeadlineExceeded
+from tpulab.core.threads import on_one_frame_chunk
 from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool, PrefixCache
 from tpulab.engine.paged_steps import (_device_sample_token, pack_round,
                                        paged_decode_block, paged_decode_step,
@@ -844,8 +845,10 @@ class ContinuousBatcher:
         self.tokens_generated = 0    # emitted across all requests
         self._cv = threading.Condition()
         self._shutdown = False
-        self._thread = threading.Thread(target=self._run, name="cbatch",
-                                        daemon=True)
+        # every step program is traced and lowered on this thread
+        self._thread = threading.Thread(
+            target=on_one_frame_chunk, args=(self._run,), name="cbatch",
+            daemon=True)
         self._thread.start()
 
     @property

@@ -33,6 +33,12 @@ through an ``nbuf``-deep slot-rotation prefetch pipeline over blocks of
 online per query row — O(block) VMEM, no gather
 materialization, dead pages skipped by predication.
 
+The MXU is fed what the store holds (:func:`mxu_operands`): a bf16 (or
+fp8) store gives both products of a key block bf16 operands in one default
+pass, the probabilities rounded to bf16 before the value product as the
+XLA form of the step rounds them; a float32 store keeps both at
+``HIGHEST``.  Running maximum, normaliser and accumulator are float32.
+
 Per-head compute rides the flash-attention dot shapes (2D matmuls only,
 the Mosaic-serialization-safe subset :mod:`flash_attention` already
 uses): for each query head the block's scores are
@@ -84,6 +90,20 @@ _VMEM_SCOPED_DEFAULT = 16 << 20
 _VMEM_REQUEST_MAX = 96 << 20
 
 
+def mxu_operands(q_dtype, kv_dtype):
+    """``(dtype, precision)`` of BOTH matrix products of a key block, read
+    from the page store's dtype and nothing else.  A store narrower than 32
+    bits (bf16, fp8 pages) feeds the MXU the query's dtype in one default
+    pass: the scaled query and the probabilities are rounded to it, the K
+    and V blocks go as stored, the accumulation stays float32 — what the
+    XLA form of the same step computes (``softmax(...).astype(compute)``
+    before the value product).  A float32 store keeps float32 operands at
+    ``HIGHEST`` (the default pass would round them to bf16)."""
+    if jnp.dtype(kv_dtype).itemsize >= 4:
+        return jnp.float32, jax.lax.Precision.HIGHEST
+    return jnp.dtype(q_dtype), jax.lax.Precision.DEFAULT
+
+
 def _block_geometry(page_size: int, max_pages: int, hd: int,
                     itemsize: int) -> tuple[int, int]:
     """(g_pages, nbuf): pages per compute block and pipeline depth.
@@ -102,11 +122,12 @@ def _plan(m: int, h: int, hkv: int, d: int, page_size: int, max_pages: int,
           nbuf: int | None = None) -> tuple[int, int, int]:
     """``(g_pages, nbuf, vmem_bytes)``: the block geometry (auto unless
     pinned) and the VMEM one grid step then holds, from the kernel's own
-    shapes: the page pipeline, the double-buffered q/o blocks, the f32
-    copies, the per-head (max, normalizer, accumulator) carry — live
-    twice across a loop step, its (M, 1) columns padded to a full
-    128-lane tile — and the score tiles.  Mosaic's own temporaries come
-    on top: the caller leaves headroom."""
+    shapes: the page pipeline, the double-buffered q/o blocks, the
+    per-head (max, normalizer, accumulator) carry — live twice across a
+    loop step, its (M, 1) columns padded to a full 128-lane tile — and
+    the score tiles.  The K/V blocks go to the dots as staged
+    (:func:`mxu_operands`), so nothing holds a copy of them.  Mosaic's
+    own temporaries come on top: the caller leaves headroom."""
     def pad(n, to):
         return -(-n // to) * to
     q_item, kv_item = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
@@ -117,12 +138,9 @@ def _plan(m: int, h: int, hkv: int, d: int, page_size: int, max_pages: int,
     gs = g_pages * page_size
     kv_buf = nbuf * 2 * gs * hkv * d * kv_item
     q_o_blocks = 2 * 2 * m * h * d * q_item
-    q_f32 = m * h * d * 4
     carry = 2 * h * m * (pad(d, _LANES) + 2 * _LANES) * 4
-    kv_f32 = 2 * gs * hkv * d * 4
     scores = 3 * m * pad(gs, _LANES) * 4
-    return g_pages, nbuf, (kv_buf + q_o_blocks + q_f32 + carry + kv_f32
-                           + scores)
+    return g_pages, nbuf, kv_buf + q_o_blocks + carry + scores
 
 
 def kernel_geometry_error(q_len: int, n_heads: int, n_kv_heads: int,
@@ -167,7 +185,9 @@ def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
     """The walk over one lane's block table that every kernel of the family
     shares, whatever a page holds (K and V rows, or latent rows): starts
     the pipeline's prologue and returns ``(start_block, wait_block,
-    block_live)``.  A block is up to ``g_pages`` page DMAs from
+    live_blocks)``, the last the lane's count of blocks that hold a live
+    page: the trip count of the caller's loop, so a block past the lane's
+    length is never entered.  A block is up to ``g_pages`` page DMAs from
     ``kvpool_ref[layer, page]`` into slot ``slot`` of ``kv_buf`` (source
     page id and dest strip dynamic); every started DMA is waited exactly
     once; pages past ``length`` are neither fetched nor waited.
@@ -206,9 +226,6 @@ def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
         jax.lax.fori_loop(0, jnp.clip(n_pages - j * g_pages, 0, g_pages),
                           page, None)
 
-    def block_live(j):
-        return j * g_pages < n_pages
-
     # same deep prefetch pipeline as the single-query kernel (N-stage
     # slot rotation)
     start_block(0, 0)
@@ -216,14 +233,35 @@ def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
     def prologue(jj, _):
         start_block(jj, jj)      # a block past the lane's pages has no trip
     jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
-    return start_block, wait_block, block_live
+    return start_block, wait_block, (n_pages + g_pages - 1) // g_pages
+
+
+def _zero_rows_past(kv_buf, slot, which: int, first_row, length):
+    """Zero, in the staged block ``kv_buf[slot, which]`` itself, the rows
+    at positions past ``length``: rows of pages not fetched hold stale VMEM
+    (possibly NaN), the scores of such rows are masked, but as VALUES they
+    ride a 0-weighted sum, and ``0 * NaN`` is NaN.  Only a lane's last
+    live block holds such a row, so no other block pays for the pass."""
+    gs = kv_buf.shape[2]
+
+    @pl.when(first_row + gs > length + 1)
+    def _zero():
+        row = first_row + jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
+        blk = kv_buf[slot, which]
+        kv_buf[slot, which] = jnp.where(row <= length, blk,
+                                        jnp.zeros_like(blk))
 
 
 def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
                         kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
                         max_pages: int, n_heads: int, head_dim: int,
                         n_kv_heads: int, m_q: int, sm_scale: float,
-                        precision, g_pages: int, nbuf: int):
+                        g_pages: int, nbuf: int):
+    """One lane's ``M`` query rows against the lane's K/V pages, a head at
+    a time.  Both products of a key block take their operands as
+    :func:`mxu_operands` reads them from the store's dtype (a bf16 store:
+    bf16 in one pass; a float32 store: float32 at ``HIGHEST``); the softmax
+    statistics and the accumulator are float32 either way."""
     lane = pl.program_id(0)
     layer = layer_ref[0]                      # which layer's pages to walk
     qn = qlens_ref[lane]                      # valid query rows this lane
@@ -244,7 +282,10 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         gs = g_pages * page_size              # KV rows per block
         n_blocks = (max_pages + g_pages - 1) // g_pages
 
-        q = q_ref[0].astype(jnp.float32) * sm_scale    # (M, H*D)
+        # both products take their operands in ``dt``: the scaled query
+        # rounded to it here, once, the probabilities a block
+        dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
+        q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)  # (M, H*D)
         # flash-style 2D dots only (the Mosaic-safe subset): scores contract
         # over D with the K block transposed, values with the standard
         # orientation — see tpulab.ops.flash_attention._attn_kernel
@@ -253,10 +294,9 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
             preferred_element_type=jnp.float32, precision=precision)
         dot_pv = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
+            preferred_element_type=jnp.float32, precision=precision)
 
-        start_block, wait_block, block_live = _page_walk(
+        start_block, wait_block, live_blocks = _page_walk(
             tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
             nbuf=nbuf, n_blocks=n_blocks)
@@ -266,48 +306,41 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 1)
         qpos = start + qrow                   # (M, G*S) per-row position
         row_valid = qrow < qn
-        vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
 
         def body(j, carry):
-            def attend(carry):
-                slot = jax.lax.rem(j, nbuf)
-                wait_block(j, slot)
+            slot = jax.lax.rem(j, nbuf)
+            wait_block(j, slot)
+            # (a block past the lane's pages has no trip)
+            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
 
-                # (a block past the lane's pages has no trip)
-                start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
-
-                kblk = kv_buf[slot, 0].astype(jnp.float32)   # (G*S, Hkv*D)
-                vblk = kv_buf[slot, 1].astype(jnp.float32)
-                # rows of dead/unfetched pages hold stale VMEM (possibly
-                # NaN): scores are neutralized by the mask below, but V
-                # rides a 0-weighted sum (0 * NaN = NaN) — zero explicitly
-                vblk = jnp.where(j * gs + vrow <= length, vblk, 0.0)
-                kpos = j * gs + col
-                mask = jnp.logical_and(kpos <= qpos, row_valid)  # (M, G*S)
-                out = []
-                for hh in range(h):
-                    m_c, l_c, acc_c = carry[hh]
-                    hk = hh // g                  # compact-form KV head
-                    k_h = kblk[:, hk * d:(hk + 1) * d]          # (G*S, D)
-                    v_h = vblk[:, hk * d:(hk + 1) * d]
-                    q_h = q[:, hh * d:(hh + 1) * d]             # (M, D)
-                    s = dot_qk(q_h, k_h)                        # (M, G*S)
-                    s = jnp.where(mask, s, _NEG)
-                    m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
-                    alpha = jnp.exp(m_c - m_new)                # (M, 1)
-                    p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-                    l_new = l_c * alpha + p.sum(axis=1, keepdims=True)
-                    acc_new = acc_c * alpha + dot_pv(p, v_h)    # (M, D)
-                    out.append((m_new, l_new, acc_new))
-                return tuple(out)
-
-            # blocks fully beyond the lane's length contribute nothing — skip
-            return jax.lax.cond(block_live(j), attend, lambda c: c, carry)
+            _zero_rows_past(kv_buf, slot, 1, j * gs, length)
+            # as stored (an fp8 block upcast): no float32 copy
+            kblk = kv_buf[slot, 0].astype(dt)                # (G*S, Hkv*D)
+            vblk = kv_buf[slot, 1].astype(dt)
+            kpos = j * gs + col
+            mask = jnp.logical_and(kpos <= qpos, row_valid)      # (M, G*S)
+            maskf = mask.astype(jnp.float32)
+            out = []
+            for hh in range(h):
+                m_c, l_c, acc_c = carry[hh]
+                hk = hh // g                      # compact-form KV head
+                k_h = kblk[:, hk * d:(hk + 1) * d]              # (G*S, D)
+                v_h = vblk[:, hk * d:(hk + 1) * d]
+                q_h = q[:, hh * d:(hh + 1) * d]                 # (M, D)
+                s = dot_qk(q_h, k_h)                            # (M, G*S)
+                s = jnp.where(mask, s, _NEG)
+                m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_c - m_new)                    # (M, 1)
+                p = jnp.exp(s - m_new) * maskf
+                l_new = l_c * alpha + p.sum(axis=1, keepdims=True)
+                acc_new = acc_c * alpha + dot_pv(p.astype(dt), v_h)
+                out.append((m_new, l_new, acc_new))
+            return tuple(out)
 
         init = tuple((jnp.full((m_q, 1), _NEG, jnp.float32),
                       jnp.zeros((m_q, 1), jnp.float32),
                       jnp.zeros((m_q, d), jnp.float32)) for _ in range(h))
-        final = jax.lax.fori_loop(0, n_blocks, body, init)
+        final = jax.lax.fori_loop(0, live_blocks, body, init)
         for hh in range(h):
             _m, l_c, acc_c = final[hh]
             o_ref[0, :, hh * d:(hh + 1) * d] = (
@@ -352,16 +385,10 @@ def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
             pltpu.SemaphoreType.DMA((nbuf, g_pages)),  # one DMA per page
         ],
     )
-    # f32 pools pin HIGHEST on the score dot (the default rounds f32 MXU
-    # operands to bf16); bf16 pools keep the fast default
-    precision = (jax.lax.Precision.HIGHEST
-                 if jnp.dtype(kv_pool.dtype).itemsize >= 4
-                 else jax.lax.Precision.DEFAULT)
     kernel = functools.partial(
         _ragged_attn_kernel, page_size=page_size, max_pages=max_pages,
         n_heads=h, head_dim=d, n_kv_heads=hkv, m_q=m,
-        sm_scale=1.0 / np.sqrt(d), precision=precision,
-        g_pages=g_pages, nbuf=nbuf)
+        sm_scale=1.0 / np.sqrt(d), g_pages=g_pages, nbuf=nbuf)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -414,6 +441,9 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
     tables/lengths/layer replicated) so the kernel compiles inside the
     engine's tensor-parallel jits.
     ``g_pages``/``nbuf`` override the auto block geometry.
+    Numerics: both matrix products of a key block follow
+    :func:`mxu_operands` (a store under 32 bits: the query's dtype in one
+    pass, float32 accumulation; a float32 store: ``HIGHEST``).
     Returns (B, M, Hq, D).
     """
     if interpret is None:
@@ -481,8 +511,8 @@ def _latent_plan(m: int, h: int, row: int, v_width: int, page_size: int,
     gs = g_pages * page_size
     return g_pages, nbuf, (
         nbuf * gs * row * kv_item + 2 * r * (row + v_width) * q_item
-        + r * row * 4 + 2 * r * (v_width + 2 * _LANES) * 4
-        + 2 * gs * row * 4 + 3 * r * -(-gs // _LANES) * _LANES * 4)
+        + 2 * r * (v_width + 2 * _LANES) * 4
+        + 3 * r * -(-gs // _LANES) * _LANES * 4)
 
 
 def latent_geometry_error(q_len: int, n_heads: int, row: int, v_width: int,
@@ -507,13 +537,15 @@ def latent_geometry_error(q_len: int, n_heads: int, row: int, v_width: int,
 def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
                         kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
                         max_pages: int, m_q: int, rows: int, v_width: int,
-                        sm_scale: float, precision, g_pages: int, nbuf: int):
+                        sm_scale: float, g_pages: int, nbuf: int):
     """One lane's tile of stacked heads against the lane's latent pages.
 
     ``q_ref (1, rows, W)``: row ``r`` is query token ``r % m_q`` of some
     head — every head attends the SAME ``W``-wide key row (absorbed MLA),
     so the heads of a lane are rows of one dot, and the value is the first
-    ``v_width`` columns of the same staged row."""
+    ``v_width`` columns of the same staged row.  Both products take their
+    operands as :func:`mxu_operands` reads them from the store's dtype: the
+    staged block goes to both as stored, the probabilities rounded to it."""
     lane = pl.program_id(0)
     layer = layer_ref[0]
     qn = qlens_ref[lane]
@@ -526,16 +558,16 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         gs = g_pages * page_size
         n_blocks = (max_pages + g_pages - 1) // g_pages
 
-        q = q_ref[0].astype(jnp.float32) * sm_scale          # (R, W)
+        dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
+        q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)   # (R, W)
         dot_qk = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         dot_pv = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
+            preferred_element_type=jnp.float32, precision=precision)
 
-        start_block, wait_block, block_live = _page_walk(
+        start_block, wait_block, live_blocks = _page_walk(
             tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
             nbuf=nbuf, n_blocks=n_blocks)
@@ -547,35 +579,29 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
                 else jax.lax.rem(qrow, m_q))
         qpos = start + qtok
         row_valid = qtok < qn
-        vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
 
         def body(j, carry):
-            def attend(carry):
-                m_c, l_c, acc_c = carry
-                slot = jax.lax.rem(j, nbuf)
-                wait_block(j, slot)
+            m_c, l_c, acc_c = carry
+            slot = jax.lax.rem(j, nbuf)
+            wait_block(j, slot)
+            # (a block past the lane's pages has no trip)
+            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
 
-                # (a block past the lane's pages has no trip)
-                start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
-
-                blk = kv_buf[slot, 0].astype(jnp.float32)    # (G*S, W)
-                # rows of dead/unfetched pages hold stale VMEM (possibly NaN)
-                # and ride a 0-weighted sum as values: zero them
-                blk = jnp.where(j * gs + vrow <= length, blk, 0.0)
-                mask = jnp.logical_and(j * gs + col <= qpos, row_valid)
-                s = jnp.where(mask, dot_qk(q, blk), _NEG)    # (R, G*S)
-                m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
-                alpha = jnp.exp(m_c - m_new)
-                p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-                return (m_new, l_c * alpha + p.sum(axis=1, keepdims=True),
-                        acc_c * alpha + dot_pv(p, blk[:, :v_width]))
-
-            return jax.lax.cond(block_live(j), attend, lambda c: c, carry)
+            # the one staged row is key and value
+            _zero_rows_past(kv_buf, slot, 0, j * gs, length)
+            blk = kv_buf[slot, 0].astype(dt)                     # (G*S, W)
+            mask = jnp.logical_and(j * gs + col <= qpos, row_valid)
+            s = jnp.where(mask, dot_qk(q, blk), _NEG)            # (R, G*S)
+            m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_c - m_new)
+            p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+            return (m_new, l_c * alpha + p.sum(axis=1, keepdims=True),
+                    acc_c * alpha + dot_pv(p.astype(dt), blk[:, :v_width]))
 
         init = (jnp.full((rows, 1), _NEG, jnp.float32),
                 jnp.zeros((rows, 1), jnp.float32),
                 jnp.zeros((rows, v_width), jnp.float32))
-        _m, l_c, acc_c = jax.lax.fori_loop(0, n_blocks, body, init)
+        _m, l_c, acc_c = jax.lax.fori_loop(0, live_blocks, body, init)
         o_ref[0] = (acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
@@ -616,13 +642,10 @@ def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
             pltpu.SemaphoreType.DMA((nbuf, g_pages)),
         ],
     )
-    precision = (jax.lax.Precision.HIGHEST
-                 if jnp.dtype(kv_pool.dtype).itemsize >= 4
-                 else jax.lax.Precision.DEFAULT)
     kernel = functools.partial(
         _latent_attn_kernel, page_size=page_size, max_pages=max_pages,
         m_q=m, rows=rows, v_width=v_width, sm_scale=sm_scale,
-        precision=precision, g_pages=g_pages, nbuf=nbuf)
+        g_pages=g_pages, nbuf=nbuf)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -650,8 +673,9 @@ def ragged_latent_attention(q, kv_pool, layer, tables, q_lens, kv_lens, *,
     row padded with zeros to whole lanes);
     ``sm_scale`` — the softmax scale of the *published* head width
     (``1 / sqrt(qk_nope + qk_rope)``), not of ``W``.
-    Same block tables, ``layer`` word, ``q_lens``/``kv_lens`` contract and
-    page walk as the K/V kernel.  Returns (B, M, H, v_width)."""
+    Same block tables, ``layer`` word, ``q_lens``/``kv_lens`` contract,
+    page walk and operand rule (:func:`mxu_operands`) as the K/V kernel.
+    Returns (B, M, H, v_width)."""
     if interpret is None:
         from tpulab.tpu.platform import pallas_interpret
         interpret = pallas_interpret()

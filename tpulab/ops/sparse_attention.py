@@ -29,7 +29,10 @@ rows of the padded form.  Three steps a layer:
     a time; a lane that holds no row of the call is skipped.  One row a
     lane (a decode step, a round's decode rows) goes through
     :func:`sparse_attend_decode`, the same walk with the query heads of a
-    KV head stacked into the rows of one dot.
+    KV head stacked into the rows of one dot.  Both kernels feed the MXU
+    by :func:`tpulab.ops.ragged_attention.mxu_operands`: a bf16 store gives
+    both products bf16 operands in one pass (the probabilities rounded as
+    :func:`sparse_attend_xla` rounds them), a float32 store ``HIGHEST``.
 
 Why a masked walk for a decode row too, where 2,048 chosen rows would do:
 on the v5e an XLA gather of 8 x 2,048 chosen (page, slot) rows of K and V
@@ -53,7 +56,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpulab.ops.ragged_attention import (_NEG, _VMEM_REQUEST_MAX,
-                                         _VMEM_SCOPED_DEFAULT, _plan)
+                                         _VMEM_SCOPED_DEFAULT, _plan,
+                                         _zero_rows_past, mxu_operands)
 
 #: key slots one grid step of ``dsa_index_scores`` scores (a multiple of the
 #: page size that divides the table's width; fewer steps of ~0.35 us each)
@@ -261,13 +265,16 @@ def sparse_attend_xla(q, mask, row_lane, kv_layer, tables, compute_dtype):
 
 def _sparse_attn_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
                         lane_ref, mask_ref, kvpool_ref, o_ref, kv_buf,
-                        mask_buf, sem, msem, *, page_size: int,
+                        mask_buf, sem, msem, *,
+                        page_size: int,
                         max_pages: int, n_heads: int, head_dim: int,
                         n_kv_heads: int, rows: int, sm_scale: float,
-                        precision, g_pages: int, nbuf: int):
+                        g_pages: int, nbuf: int):
     """One lane's pages against ALL the rows of the call: the rows of other
     lanes are masked and keep what ``o_ref`` holds (one block for the
-    whole grid, zeroed by the first step)."""
+    whole grid, zeroed by the first step).  Both products of a key block
+    take their operands as ``mxu_operands`` reads them from the store's
+    dtype (bf16 in one pass for a bf16 store, ``HIGHEST`` for float32)."""
     lane = pl.program_id(0)
     layer = layer_ref[0]
 
@@ -312,53 +319,49 @@ def _sparse_attn_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
             block(jj, jj, start)
         jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
 
-        q = q_ref[...].astype(jnp.float32) * sm_scale          # (R, H*D)
+        dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
+        q = (q_ref[...].astype(jnp.float32) * sm_scale).astype(dt)  # (R, H*D)
         dot_qk = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         dot_pv = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
+            preferred_element_type=jnp.float32, precision=precision)
         mine = lane_ref[...] == lane                            # (R, 1)
-        vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
 
         def body(j, carry):
-            def attend(carry):
-                slot = jax.lax.rem(j, nbuf)
-                block(j, slot, wait)
-                block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf), start)
-                kblk = kv_buf[slot, 0].astype(jnp.float32)   # (G*S, Hkv*D)
-                vblk = kv_buf[slot, 1].astype(jnp.float32)
-                # rows of pages not fetched hold stale VMEM: zero V (a
-                # 0-weighted NaN is a NaN), mask the scores
-                vblk = jnp.where(j * gs + vrow <= length, vblk, 0.0)
-                mask = jnp.logical_and(
-                    mask_buf[slot].astype(jnp.int32) != 0, mine)  # (R, G*S)
-                maskf = mask.astype(jnp.float32)
-                out = []
-                for hh in range(h):
-                    m_c, l_c, acc_c = carry[hh]
-                    hk = hh // g
-                    s = dot_qk(q[:, hh * d:(hh + 1) * d],
-                               kblk[:, hk * d:(hk + 1) * d])
-                    s = jnp.where(mask, s, _NEG)
-                    m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
-                    alpha = jnp.exp(m_c - m_new)
-                    p = jnp.exp(s - m_new) * maskf
-                    out.append((m_new,
-                                l_c * alpha + p.sum(axis=1, keepdims=True),
-                                acc_c * alpha + dot_pv(
-                                    p, vblk[:, hk * d:(hk + 1) * d])))
-                return tuple(out)
-
-            return jax.lax.cond(j * g_pages < n_pages, attend,
-                                lambda c_: c_, carry)
+            slot = jax.lax.rem(j, nbuf)
+            block(j, slot, wait)
+            block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf), start)
+            _zero_rows_past(kv_buf, slot, 1, j * gs, length)
+            kblk = kv_buf[slot, 0].astype(dt)                # (G*S, Hkv*D)
+            vblk = kv_buf[slot, 1].astype(dt)
+            mask = jnp.logical_and(
+                mask_buf[slot].astype(jnp.int32) != 0, mine)      # (R, G*S)
+            maskf = mask.astype(jnp.float32)
+            out = []
+            for hh in range(h):
+                m_c, l_c, acc_c = carry[hh]
+                hk = hh // g
+                s = dot_qk(q[:, hh * d:(hh + 1) * d],
+                           kblk[:, hk * d:(hk + 1) * d])
+                s = jnp.where(mask, s, _NEG)
+                m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_c - m_new)
+                p = jnp.exp(s - m_new) * maskf
+                out.append((m_new,
+                            l_c * alpha + p.sum(axis=1, keepdims=True),
+                            acc_c * alpha + dot_pv(
+                                p.astype(dt),
+                                vblk[:, hk * d:(hk + 1) * d])))
+            return tuple(out)
 
         init = tuple((jnp.full((rows, 1), _NEG, jnp.float32),
                       jnp.zeros((rows, 1), jnp.float32),
                       jnp.zeros((rows, d), jnp.float32)) for _ in range(h))
-        final = jax.lax.fori_loop(0, n_blocks, body, init)
+        # the lane's live blocks: one past its length is never walked
+        final = jax.lax.fori_loop(0, (n_pages + g_pages - 1) // g_pages,
+                                  body, init)
         for hh in range(h):
             _m, l_c, acc_c = final[hh]
             cols = slice(hh * d, (hh + 1) * d)
@@ -402,14 +405,10 @@ def _sparse_attn(q, mask, row_lane, kv_pool, layer, tables, lane_live,
             pltpu.SemaphoreType.DMA((nbuf,)),
         ],
     )
-    precision = (jax.lax.Precision.HIGHEST
-                 if jnp.dtype(kv_pool.dtype).itemsize >= 4
-                 else jax.lax.Precision.DEFAULT)
     kernel = functools.partial(
         _sparse_attn_kernel, page_size=page_size, max_pages=max_pages,
         n_heads=h, head_dim=d, n_kv_heads=hkv, rows=rp,
-        sm_scale=1.0 / np.sqrt(d), precision=precision, g_pages=g_pages,
-        nbuf=nbuf)
+        sm_scale=1.0 / np.sqrt(d), g_pages=g_pages, nbuf=nbuf)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -428,11 +427,13 @@ def _sparse_decode_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
                           mask_ref, kvpool_ref, o_ref, kv_buf, sem, *,
                           page_size: int, max_pages: int, n_heads: int,
                           head_dim: int, n_kv_heads: int, sm_scale: float,
-                          precision, g_pages: int, nbuf: int):
+                          g_pages: int, nbuf: int):
     """One lane's ONE query row against the lane's pages: the query heads
     of a KV head are the rows of one dot (``H / Hkv`` rows, ``Hkv`` dots a
     block), where the rows kernel would make ``H`` dots of a padded tile of
-    rows for the one that counts (PR 34: 24 of a decode step's 33 ms)."""
+    rows for the one that counts (PR 34: 24 of a decode step's 33 ms).
+    The operands of both products follow ``mxu_operands``, as in the rows
+    kernel: the store's dtype decides, nothing else."""
     lane = pl.program_id(0)
     layer = layer_ref[0]
 
@@ -465,48 +466,46 @@ def _sparse_decode_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
             block(jj, jj, start)
         jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
 
-        q = q_ref[0].astype(jnp.float32) * sm_scale             # (H, D)
+        dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
+        q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)    # (H, D)
         dot_qk = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
         dot_pv = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
-        vrow = jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
+            preferred_element_type=jnp.float32, precision=precision)
 
         def body(j, carry):
-            def attend(carry):
-                slot = jax.lax.rem(j, nbuf)
-                block(j, slot, wait)
-                block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf), start)
-                kblk = kv_buf[slot, 0].astype(jnp.float32)   # (G*S, Hkv*D)
-                vblk = kv_buf[slot, 1].astype(jnp.float32)
-                vblk = jnp.where(j * gs + vrow <= length, vblk, 0.0)
-                mask = mask_ref[0, :, pl.ds(pl.multiple_of(j * gs, gs),
-                                            gs)] != 0              # (1, G*S)
-                maskf = mask.astype(jnp.float32)
-                out = []
-                for hk in range(hkv):
-                    m_c, l_c, acc_c = carry[hk]
-                    cols = slice(hk * d, (hk + 1) * d)
-                    s = dot_qk(q[hk * g:(hk + 1) * g], kblk[:, cols])
-                    s = jnp.where(mask, s, _NEG)                  # (g, G*S)
-                    m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
-                    alpha = jnp.exp(m_c - m_new)
-                    p = jnp.exp(s - m_new) * maskf
-                    out.append((m_new,
-                                l_c * alpha + p.sum(axis=1, keepdims=True),
-                                acc_c * alpha + dot_pv(p, vblk[:, cols])))
-                return tuple(out)
-
-            return jax.lax.cond(j * g_pages < n_pages, attend,
-                                lambda c_: c_, carry)
+            slot = jax.lax.rem(j, nbuf)
+            block(j, slot, wait)
+            block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf), start)
+            _zero_rows_past(kv_buf, slot, 1, j * gs, length)
+            kblk = kv_buf[slot, 0].astype(dt)                # (G*S, Hkv*D)
+            vblk = kv_buf[slot, 1].astype(dt)
+            mask = mask_ref[0, :, pl.ds(pl.multiple_of(j * gs, gs),
+                                        gs)] != 0                  # (1, G*S)
+            maskf = mask.astype(jnp.float32)
+            out = []
+            for hk in range(hkv):
+                m_c, l_c, acc_c = carry[hk]
+                cols = slice(hk * d, (hk + 1) * d)
+                s = dot_qk(q[hk * g:(hk + 1) * g], kblk[:, cols])
+                s = jnp.where(mask, s, _NEG)                      # (g, G*S)
+                m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_c - m_new)
+                p = jnp.exp(s - m_new) * maskf
+                out.append((m_new,
+                            l_c * alpha + p.sum(axis=1, keepdims=True),
+                            acc_c * alpha + dot_pv(p.astype(dt),
+                                                   vblk[:, cols])))
+            return tuple(out)
 
         init = tuple((jnp.full((g, 1), _NEG, jnp.float32),
                       jnp.zeros((g, 1), jnp.float32),
                       jnp.zeros((g, d), jnp.float32)) for _ in range(hkv))
-        final = jax.lax.fori_loop(0, n_blocks, body, init)
+        # the lane's live blocks: one past its length is never walked
+        final = jax.lax.fori_loop(0, (n_pages + g_pages - 1) // g_pages,
+                                  body, init)
         for hk in range(hkv):
             _m, l_c, acc_c = final[hk]
             o_ref[0, hk * g:(hk + 1) * g] = (
@@ -544,13 +543,10 @@ def _sparse_decode(q, mask, kv_pool, layer, tables, lane_live, kv_lens,
             pltpu.SemaphoreType.DMA((nbuf, g_pages)),
         ],
     )
-    precision = (jax.lax.Precision.HIGHEST
-                 if jnp.dtype(kv_pool.dtype).itemsize >= 4
-                 else jax.lax.Precision.DEFAULT)
     kernel = functools.partial(
         _sparse_decode_kernel, page_size=page_size, max_pages=max_pages,
         n_heads=h, head_dim=d, n_kv_heads=hkv, sm_scale=1.0 / np.sqrt(d),
-        precision=precision, g_pages=g_pages, nbuf=nbuf)
+        g_pages=g_pages, nbuf=nbuf)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
