@@ -345,14 +345,14 @@ class _Chain:
         dispatch, consume, note = (cb._dispatch_block, cb._consume_block,
                                    cb._note_moe)
 
-        def dispatch_block(parts, k, jnp, carry=None, host=None):
+        def dispatch_block(parts, k, jnp, **chained):
             ps = cb.page_size
             for req in cb._active:
                 if req is not None and not req.pending_prompt:
                     allowed = (req.length + 2 * k - 1) // ps + 1
                     if len(req.pages) > allowed:
                         self.hoarded.append((len(req.pages), allowed))
-            stash = dispatch(parts, k, jnp, carry=carry, host=host)
+            stash = dispatch(parts, k, jnp, **chained)
             stash["n"] = len(self.ks)
             self.events.append(("enqueue", stash["n"]))
             self.ks.append(k)

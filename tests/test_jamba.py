@@ -370,7 +370,7 @@ def test_run_ahead_chain_gives_the_tokens_of_the_chain_without_it(
     def run(chain):
         if not chain:
             monkeypatch.setattr(ContinuousBatcher, "_chain_block",
-                                lambda self, stash, jnp, ahead: None)
+                                lambda self, stash, jnp, ahead: (None, "k1"))
         cb = _engine(spec, params)
         try:
             futs = [cb.submit(p, 24) for p in prompts]

@@ -134,6 +134,227 @@ def test_stages_cover_the_scheduler_thread():
     assert total >= 0.8 * (t_after - t_running)
 
 
+#: every ``sched.*`` span the scheduler can emit: the seven stages, the
+#: three parts of a dispatch, a turn by what opened it
+SCHED_SPANS = (
+    "sched.admit", "sched.plan", "sched.dispatch", "sched.fetch",
+    "sched.commit", "sched.emit", "sched.idle",
+    "sched.dispatch.arrays", "sched.dispatch.put", "sched.dispatch.call",
+    "sched.turn.completion", "sched.turn.joiner", "sched.turn.round",
+    "sched.turn.k", "sched.turn.pages", "sched.turn.released",
+    "sched.turn.single", "sched.turn.other")
+
+
+def _scheduler_clock():
+    from tpulab.engine.paged import ContinuousBatcher as CB
+    return tracing.StageClock(CB.STAGES, prefix="sched.",
+                              turn=CB.TURN_STAGES, causes=CB.TURN_CAUSES,
+                              parts=CB.DISPATCH_PARTS)
+
+
+def test_clock_turn_opens_at_an_empty_queue_and_ends_at_the_launch():
+    """The clock alone, no device: a turn opens where the fetch of the
+    last un-fetched program ends, runs through the stages after it and
+    ends INSIDE the dispatch stage where the next program is launched; a
+    wait inside it (an idle wait, a fetch that has nothing to wait for)
+    is no part of its seconds and closes its span; the stages read what
+    they always read, and a part does not pause its stage."""
+    from tpulab.utils.tracing import part, stage
+    st = _scheduler_clock()
+    nap = 0.01
+    with stage(st, "dispatch"):
+        time.sleep(nap)
+        first = st.launched()          # no turn was open: nothing to end
+    with stage(st, "fetch"):
+        time.sleep(nap)
+        st.landed(first, "completion", lanes=2)
+    assert st.turns()["n"] == 0 and st._in_turn and st._turn is not None
+    with stage(st, "commit"):
+        time.sleep(nap)
+        with stage(st, "admit"):       # nested: pauses commit, in the turn
+            time.sleep(nap)
+    with stage(st, "dispatch"):
+        with part(st, "dispatch.arrays"):
+            time.sleep(nap)
+        second = st.launched()         # the turn ends here, mid-stage
+        third = st.launched()          # chained behind it
+        time.sleep(nap)                # dispatch runs on, outside the turn
+    assert st.turns()["n"] == 1
+    with stage(st, "fetch"):
+        st.landed(second, "joiner")    # `third` is behind it: no turn
+    with stage(st, "emit"):
+        time.sleep(nap)
+    assert not st._in_turn and st._turn is None
+    with stage(st, "fetch"):
+        st.landed(third)
+    assert st._in_turn and st._turn is not None    # opened as `other`
+    with stage(st, "idle"):            # a wait: the span closes, the turn
+        assert st._in_turn and st._turn is None    # stays open
+        time.sleep(nap)
+    with stage(st, "fetch"):           # so does a fetch with nothing to
+        assert st._turn is None        # wait for, which renames the span
+        st.landed(third, "joiner", lanes=1)
+    assert st._in_turn and st._turn is not None and st.turns()["n"] == 1
+    with stage(st, "plan"):
+        time.sleep(nap)
+        st.launched()
+    t, stages, parts = st.turns(), st.stages(), st.parts()
+    assert t["n"] == 2 and not st._in_turn and set(t["stages"]) == {
+        "admit", "plan", "dispatch", "commit", "emit"}
+    assert t["s"] == pytest.approx(sum(t["stages"].values()), abs=1e-12)
+    assert t["stages"]["commit"] == pytest.approx(stages["commit"]["s"])
+    assert t["stages"]["admit"] == pytest.approx(stages["admit"]["s"])
+    assert nap <= t["stages"]["plan"] <= stages["plan"]["s"]
+    assert t["stages"]["emit"] == 0.0
+    # one of dispatch's three naps fell inside the turn, no idle second did
+    assert nap <= t["stages"]["dispatch"] < stages["dispatch"]["s"] - nap
+    assert 4 * nap <= t["s"] < 5 * nap + 0.05
+    assert stages["dispatch"]["n"] == 2 and stages["fetch"]["n"] == 4
+    assert parts["dispatch.arrays"]["n"] == 1
+    assert nap <= parts["dispatch.arrays"]["s"] <= stages["dispatch"]["s"]
+    assert parts["dispatch.put"] == {"s": 0.0, "n": 0}
+
+
+def _consumed_blocks(cb):
+    """Count the decode blocks ``cb`` consumes from here on."""
+    calls = []
+    inner = cb._consume_block
+
+    def counted(stash, jnp):
+        calls.append(stash["k"])
+        return inner(stash, jnp)
+    cb._consume_block = counted
+    return calls
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_turns_chain_and_parts_account_for_the_scheduler(ragged):
+    """``dispatch["turns"]``: its stages sum to its seconds and never
+    exceed the stages' own; ``dispatch["chain"]``: every consumed decode
+    block either had its successor enqueued ahead or a counted cause;
+    ``dispatch["dispatch_parts"]``: inside the ``dispatch`` stage."""
+    from tpulab.engine.paged import ContinuousBatcher as CB
+    cb = _tiny_engine(lanes=2, ragged=ragged)
+    consumed = _consumed_blocks(cb)
+    try:
+        futs = [cb.submit(np.arange(3 + i, dtype=np.int32), 7 + 3 * i,
+                          on_token=(lambda tok, i: None) if i % 2 else None)
+                for i in range(5)]
+        for i, f in enumerate(futs):
+            assert len(f.result(timeout=120)) == 7 + 3 * i
+    finally:
+        cb.shutdown()
+    d = cb.debug_state()["dispatch"]
+    turns, stages = d["turns"], d["stages"]
+    assert tuple(turns["stages"]) == CB.TURN_STAGES and turns["n"] > 0
+    assert turns["s"] == pytest.approx(sum(turns["stages"].values()),
+                                       abs=1e-12)
+    for name, s in turns["stages"].items():
+        assert 0 <= s <= stages[name]["s"] + 1e-9, name
+    assert turns["s"] > 0
+    # a turn ends with a launch: never more of them than programs launched
+    assert turns["n"] <= d["decode_dispatches"] + d["prefill_dispatches"]
+    chain = d["chain"]
+    assert tuple(chain["breaks"]) == CB.BREAK_CAUSES
+    assert consumed and len(consumed) == (
+        d["ahead_blocks"] + sum(chain["breaks"].values()))
+    assert chain["breaks"]["k1"] == 0 and chain["breaks"]["completion"] > 0
+    assert 0 <= chain["late_links"] <= sum(chain["breaks"].values())
+    parts = d["dispatch_parts"]
+    assert tuple(parts) == ("arrays", "put", "call")
+    blocks_and_rounds = len(consumed) + d["kinds"]["mixed"]
+    assert all(p["n"] == blocks_and_rounds for p in parts.values())
+    assert 0 < sum(p["s"] for p in parts.values()) <= stages["dispatch"]["s"]
+    assert (d["kinds"]["mixed"] > 0) == ragged
+
+
+def _force_completion(cb, seen):
+    # one lane, nine tokens: the first from the prefill, a K=8 block whose
+    # step budget ends inside it
+    cb.submit(np.arange(4, dtype=np.int32), 9).result(timeout=120)
+    return dict(cb.chain_breaks)
+
+
+def _force_k(cb, seen):
+    # twelve tokens to a batch consumer: after the K=8 block three are
+    # left, which a K=4 block covers: the policy changes its mind
+    def on_token(tok, i):
+        if i == 1:                     # the first token of that block
+            seen.append(dict(cb.chain_breaks))
+    cb.submit(np.arange(4, dtype=np.int32), 12, on_token=on_token,
+              request_class="batch").result(timeout=120)
+    return seen[0]
+
+
+def _force_released(cb, seen):
+    # cancelled from its own token hook (the scheduler thread, in `emit`)
+    # while the next block is already on the queue
+    futs = []
+
+    def on_token(tok, i):
+        if i == 5:
+            cb.cancel(futs[0])
+    futs.append(cb.submit(np.arange(4, dtype=np.int32), 40,
+                          on_token=on_token))
+    with pytest.raises(Exception):
+        futs[0].result(timeout=120)
+    deadline = time.monotonic() + 30
+    while cb.active_lanes and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return dict(cb.chain_breaks)
+
+
+def _force_joiner(cb, seen):
+    # a second request submitted while the first one's chain runs: it
+    # prefills beside the chain, and with its first token out the next
+    # block of the chain is held back for it
+    futs = []
+
+    def second(tok, i):
+        if i == 1:                     # it has had a decode step: joined
+            seen.append(dict(cb.chain_breaks))
+
+    def first(tok, i):
+        if i == 5:
+            futs.append(cb.submit(np.arange(6, dtype=np.int32), 6,
+                                  on_token=second))
+    futs.append(cb.submit(np.arange(4, dtype=np.int32), 40, on_token=first))
+    for f in list(futs) + futs[1:]:
+        f.result(timeout=120)
+    return seen[0]
+
+
+@pytest.mark.parametrize("cause,lanes,force", [
+    ("completion", 1, _force_completion),
+    ("k", 1, _force_k),
+    ("released", 1, _force_released),
+    ("joiner", 2, _force_joiner),
+])
+@pytest.mark.parametrize("ragged", [False, True])
+def test_chain_break_is_counted_under_its_cause(cause, lanes, force, ragged):
+    """Each cause a CPU batcher can be driven into, forced from the
+    scheduler's own thread (a token hook) so the order is the program's:
+    counted once under its name, and nothing under any other."""
+    cb = _tiny_engine(lanes=lanes, ragged=ragged)
+    try:
+        breaks = force(cb, [])
+    finally:
+        cb.shutdown()
+    assert breaks.pop(cause) == 1
+    assert not any(breaks.values()), breaks
+
+
+@pytest.mark.parametrize("name", SCHED_SPANS)
+def test_sched_span_is_documented(name):
+    """Every span the scheduler's clock can emit has its row in the span
+    table of docs/OBSERVABILITY.md (and the list above is the clock's)."""
+    st = _scheduler_clock()
+    emitted = set(st.span_names.values()) | set(st.turn_names.values())
+    assert emitted == set(SCHED_SPANS)
+    doc = open(f"{REPO}/docs/OBSERVABILITY.md").read()
+    assert f"| `{name}` |" in doc, f"{name} has no row in the span table"
+
+
 def test_wait_counters_match_the_requests():
     """One lane, requests one after the other, nine tokens each: the
     first from the prefill, the other eight in one K=8 block."""
@@ -184,7 +405,31 @@ def test_tokens_identical_with_profiler_on_and_off(tmp_path, switch_closed):
     with tracing.trace(str(tmp_path / "on")):
         on = run()
     assert on == off
-    assert _captures(str(tmp_path / "on"))
+    captures = _captures(str(tmp_path / "on"))
+    assert captures
+    # the capture holds the turn, the parts and the stats on a dispatch
+    from jax.profiler import ProfileData
+    spans = {}
+    for plane in ProfileData.from_file(captures[-1]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("sched."):
+                    spans.setdefault(ev.name, []).append(dict(ev.stats))
+    assert set(spans) <= set(SCHED_SPANS)
+    assert {"sched.dispatch.arrays", "sched.dispatch.put",
+            "sched.dispatch.call"} <= set(spans)
+    turns = {name: evs for name, evs in spans.items()
+             if name.startswith("sched.turn.")}
+    # every turn a fetch opened carries the lanes of what was fetched (one
+    # that an idle wait opened, an `other`, has nothing to say)
+    assert turns and all("lanes" in stats for name, evs in turns.items()
+                         for stats in evs
+                         if stats or name != "sched.turn.other")
+    blocks = [stats for stats in spans["sched.dispatch"] if stats]
+    assert len(blocks) == len(spans["sched.dispatch.call"])
+    assert {b["program"] for b in blocks} == {"paged_decode_block_k8"}
+    assert all(set(b) == {"program", "k", "lanes", "rows", "ahead"}
+               and b["k"] == 8 and b["ahead"] in (0, 1) for b in blocks)
 
 
 def _lower_args(cb, kind):
@@ -630,9 +875,6 @@ def test_chrome_trace_ring_counts_drops(tmp_path, caplog):
     # the survivors are the most recent window
     assert [e["name"] for e in doc["traceEvents"]] == \
         ["s6", "s7", "s8", "s9"]
-    # counters overflow through the same accounting
-    rec.add_counter("c", 0.0, v=1)
-    assert rec.dropped_events == 7
 
 
 def test_metrics_inventory_documented_and_disjoint():

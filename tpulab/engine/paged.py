@@ -35,7 +35,7 @@ from tpulab.engine.paged_steps import (_device_sample_token, pack_round,
                                        paged_prefill, paged_speculative_block,
                                        round_width)
 from tpulab.utils import tracing
-from tpulab.utils.tracing import stage
+from tpulab.utils.tracing import part, stage
 
 
 class SamplingParams:
@@ -628,6 +628,7 @@ class ContinuousBatcher:
         #: multi-step dispatch entirely.
         self.decode_block = min(int(decode_block), self.BLOCK_K_MENU[-1])
         self._block_cache: Dict[int, Any] = {}
+        self._block_names: Dict[int, str] = {}   # K -> the program's name
         self._pending_block: Optional[Dict[str, Any]] = None
         self._step_ewma_s = 0.0   # per-scan-step device time estimate
         self._block_fetched_t = 0.0   # return of the last decode block's fetch
@@ -664,10 +665,20 @@ class ContinuousBatcher:
         #: (_chain_block): over ``dispatch_kinds["decode"]`` the share of
         #: blocks whose host turn the device did not wait for
         self.ahead_blocks = 0
+        #: why :meth:`_chain_block` left a block without a successor before
+        #: its fetch, by cause, and the blocks linked after the commit
+        #: instead: consumed blocks = ``ahead_blocks`` + the sum of these
+        self.chain_breaks: Dict[str, int] = dict.fromkeys(
+            self.BREAK_CAUSES, 0)
+        self.late_links = 0
         #: where a scheduler pass goes (docs/OBSERVABILITY.md "Debugz"):
         #: disjoint stages of the scheduler thread, each a ``sched.<stage>``
-        #: span in a profiler capture and seconds + entries here
-        self._stages = tracing.StageClock(self.STAGES, prefix="sched.")
+        #: span in a profiler capture and seconds + entries here; the same
+        #: clock reads the turns the thread takes with nothing un-fetched
+        #: on the device's queue, and a dispatch by part
+        self._stages = tracing.StageClock(
+            self.STAGES, prefix="sched.", turn=self.TURN_STAGES,
+            causes=self.TURN_CAUSES, parts=self.DISPATCH_PARTS)
         #: request waits, summed where the observers above see them
         #: (seconds, count): submit -> prefill start, submit -> first
         #: token, first token -> second token (what a newly admitted lane
@@ -890,6 +901,19 @@ class ContinuousBatcher:
 
     #: the stages of a scheduler pass, in the order a pass takes them
     STAGES = ("admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
+    #: the stages that are work (not waiting): what a turn is made of
+    TURN_STAGES = ("admit", "plan", "dispatch", "commit", "emit")
+    #: why a decode block got no successor before its fetch
+    #: (:meth:`_chain_block`, in the order it tests them)
+    BREAK_CAUSES = ("k1", "shutdown_or_reclaim", "released", "completion",
+                    "joiner", "spec", "k", "pages")
+    #: what opens a turn: a break cause that is a span name of its own, a
+    #: mixed round's fetch, a single tick's; everything else is ``other``
+    TURN_CAUSES = ("completion", "joiner", "round", "k", "pages",
+                   "released", "single")
+    #: the parts of a decode block's and a mixed round's ``dispatch``
+    #: stage: host arrays, their transfer, the jitted call
+    DISPATCH_PARTS = ("dispatch.arrays", "dispatch.put", "dispatch.call")
 
     def _jit(self, fn, donate, in_sh, out_sh):
         """``jax.jit`` with explicit in/out shardings under a mesh — the
@@ -1443,7 +1467,13 @@ class ContinuousBatcher:
                          "mixed_attn_rows": self.mixed_attn_rows,
                          "decode_block_steps": self.decode_block_steps,
                          "ahead_blocks": self.ahead_blocks,
+                         "chain": {"breaks": dict(self.chain_breaks),
+                                   "late_links": self.late_links},
                          "stages": self._stages.stages(),
+                         "turns": self._stages.turns(),
+                         "dispatch_parts": {
+                             name.partition(".")[2]: v for name, v
+                             in self._stages.parts().items()},
                          "queue_wait_s": self.queue_wait_s,
                          "queue_waits": self.queue_waits,
                          "ttft_s": self.ttft_s,
@@ -2043,6 +2073,7 @@ class ContinuousBatcher:
                 last_logits, self.pool.kv = self._prefill(
                     self.params, self.pool.kv, tables_j,
                     jnp.asarray(tokens), jnp.int32(t))
+                ticket = st.launched()
             else:
                 # tail (and/or chunked) prefill against resident context
                 chunk = self.prefill_chunk or (t - start)
@@ -2056,6 +2087,7 @@ class ContinuousBatcher:
                         self.params, self.pool.kv, tables_j,
                         jnp.asarray(tokens), jnp.int32(start),
                         jnp.int32(start + m))
+                    ticket = st.launched()
                     start += m
         with stage(st, "commit"):
             req.length = t
@@ -2098,6 +2130,7 @@ class ContinuousBatcher:
                         import jax.numpy as _j
                         lp = float(np.asarray(_jax.nn.log_softmax(
                             _j.asarray(last_logits, _j.float32))[tok]))
+                    st.landed(ticket, lanes=1)
                 req.tokens_out.append(tok)
                 self.tokens_generated += 1
                 if req.want_logprobs:
@@ -2358,51 +2391,60 @@ class ContinuousBatcher:
                         continue
                     decode_parts.append((lane, req))
         with stage(st, "dispatch"):
-            left = self._round_budget
-            chunks: Dict[int, int] = {}
-            for lane, req in sorted(segs, key=lambda s: s[1].admit_seq):
-                chunks[lane] = min(len(req.pending_prompt), left)
-                left -= chunks[lane]
-            segs = [(lane, req) for lane, req in segs if chunks[lane]]
-            if self.state is not None:
-                self.zero_starts += sum(req.length == 0 for _, req in segs)
-            b = self.lanes
-            toks, row_lane, row_off, q_lens = pack_round(
-                b, {lane: req.pending_prompt[:chunks[lane]]
-                    for lane, req in segs},
-                {lane: req.tokens_out[-1] for lane, req in decode_parts})
-            tables = np.zeros((b, self.max_pages), np.int32)
-            kv_lens = np.zeros((b,), np.int32)
-            temps = np.zeros((b,), np.float32)
-            seeds = np.zeros((b, 2), np.uint32)
-            host_lanes: List[int] = []
-            lane_reqs: Dict[int, _PagedRequest] = {}
-            for lane, req in segs + decode_parts:
-                lane_reqs[lane] = req
-                kv_lens[lane] = req.length + q_lens[lane]
-                tables[lane, :len(req.pages)] = req.pages
-                sp = req.sampling
-                # a prompt's pick counts only off its final chunk, where
-                # it IS the first token (a resumed request made it before)
-                if sp.temperature > 0.0 and (not req.pending_prompt or (
-                        q_lens[lane] == len(req.pending_prompt)
-                        and not req.resumed)):
-                    if sp.device:
-                        temps[lane] = sp.temperature
-                        seeds[lane] = (sp.seed & 0xFFFFFFFF,
-                                       (sp.seed >> 32) & 0xFFFFFFFF)
-                    else:
-                        host_lanes.append(lane)
+            with part(st, "dispatch.arrays"):
+                left = self._round_budget
+                chunks: Dict[int, int] = {}
+                for lane, req in sorted(segs, key=lambda s: s[1].admit_seq):
+                    chunks[lane] = min(len(req.pending_prompt), left)
+                    left -= chunks[lane]
+                segs = [(lane, req) for lane, req in segs if chunks[lane]]
+                if self.state is not None:
+                    self.zero_starts += sum(req.length == 0
+                                            for _, req in segs)
+                b = self.lanes
+                toks, row_lane, row_off, q_lens = pack_round(
+                    b, {lane: req.pending_prompt[:chunks[lane]]
+                        for lane, req in segs},
+                    {lane: req.tokens_out[-1] for lane, req in decode_parts})
+                tables = np.zeros((b, self.max_pages), np.int32)
+                kv_lens = np.zeros((b,), np.int32)
+                temps = np.zeros((b,), np.float32)
+                seeds = np.zeros((b, 2), np.uint32)
+                host_lanes: List[int] = []
+                lane_reqs: Dict[int, _PagedRequest] = {}
+                for lane, req in segs + decode_parts:
+                    lane_reqs[lane] = req
+                    kv_lens[lane] = req.length + q_lens[lane]
+                    tables[lane, :len(req.pages)] = req.pages
+                    sp = req.sampling
+                    # a prompt's pick counts only off its final chunk,
+                    # where it IS the first token (a resumed request made
+                    # it before)
+                    if sp.temperature > 0.0 and (not req.pending_prompt or (
+                            q_lens[lane] == len(req.pending_prompt)
+                            and not req.resumed)):
+                        if sp.device:
+                            temps[lane] = sp.temperature
+                            seeds[lane] = (sp.seed & 0xFFFFFFFF,
+                                           (sp.seed >> 32) & 0xFFFFFFFF)
+                        else:
+                            host_lanes.append(lane)
             if decode_parts:
                 # decode lanes advance one tick this round — same fault site
                 chaos.trip("engine.step")
             t0 = _time.perf_counter()
-            nt_dev, lp_dev, last_dev, self._kv_state, *moe = self._mixed(
-                self.params, self._kv_state, jnp.asarray(tables),
-                jnp.asarray(toks), jnp.asarray(row_lane),
-                jnp.asarray(row_off), jnp.asarray(q_lens),
-                jnp.asarray(kv_lens), jnp.asarray(temps),
-                jnp.asarray(seeds))
+            with part(st, "dispatch.put"):
+                args = (jnp.asarray(tables), jnp.asarray(toks),
+                        jnp.asarray(row_lane), jnp.asarray(row_off),
+                        jnp.asarray(q_lens), jnp.asarray(kv_lens),
+                        jnp.asarray(temps), jnp.asarray(seeds))
+            with part(st, "dispatch.call"):
+                (nt_dev, lp_dev, last_dev, self._kv_state,
+                 *moe) = self._mixed(self.params, self._kv_state, *args)
+            ticket = st.launched()
+            st.note(program="paged_mixed_step", k=1, lanes=len(lane_reqs),
+                    rows=len(toks),
+                    ahead=int(self._pending_block is not None))
             self.decode_dispatches += 1
             self._note_dispatch("mixed")
             self.mixed_rows += len(toks)
@@ -2429,6 +2471,7 @@ class ContinuousBatcher:
                         logprobs_arr[lane] = float(
                             row[next_tokens[lane]]
                             - np.log(np.exp(row).sum()))
+            st.landed(ticket, "round", lanes=len(lane_reqs))
         now = _time.perf_counter()
         self._step_ewma_s = (0.8 * self._step_ewma_s + 0.2 * (now - t0)
                              if self._step_ewma_s else now - t0)
@@ -2595,6 +2638,7 @@ class ContinuousBatcher:
                            (1,), (self._param_sh, kvsh) + (rep,) * 8,
                            (rep,) * 7 + (kvsh,))
             self._block_cache[k] = fn
+            self._block_names[k] = f"paged_decode_block_k{k}"
         return fn
 
     def _tight_slack_s(self) -> float:
@@ -2895,48 +2939,53 @@ class ContinuousBatcher:
         return self._consume_block(stash, jnp)
 
     def _dispatch_block(self, parts, k: int, jnp, carry=None,
-                        host=None):
-        """Issue one K-step fused decode dispatch (async — no host sync).
+                        host=None, ahead: int = 0):
+        """Issue one K-step fused decode dispatch (async — no host sync),
+        inside the caller's ``dispatch`` stage.
 
         ``carry``/``host`` chain a follow-up block from a previous one's
         device-resident final state (dispatch-ahead overlap) — the block
         table is rebuilt host-side either way (new pages may have been
         reserved), but lengths/tokens/live/steps-remaining stay on device
-        so chaining costs no round trip.
+        so chaining costs no round trip.  ``ahead`` (the predecessor's K
+        where it is not fetched yet) only goes onto the stage's span.
         """
-        b = self.lanes
-        tables = np.zeros((b, self.max_pages), np.int32)
-        lane_reqs = {}
-        for lane, req, _new in parts:
-            lane_reqs[lane] = req
-            tables[lane, :len(req.pages)] = req.pages
-        if host is None:
-            lengths = np.zeros((b,), np.int32)
-            tokens = np.zeros((b,), np.int32)
-            active = np.zeros((b,), bool)
-            temps = np.zeros((b,), np.float32)
-            seeds = np.zeros((b, 2), np.uint32)   # (lo, hi) words
-            rem = np.zeros((b,), np.int32)
-            n_stop = max((len(r.stop_tokens) for _, r, _ in parts),
-                         default=0)
-            width = (1 << (n_stop - 1).bit_length()) if n_stop > 1 else 1
-            stops = np.full((b, width), -1, np.int32)  # ids >= 0: pad safe
+        clock = self._stages
+        with part(clock, "dispatch.arrays"):
+            b = self.lanes
+            tables = np.zeros((b, self.max_pages), np.int32)
+            lane_reqs = {}
             for lane, req, _new in parts:
-                lengths[lane] = req.length
-                tokens[lane] = req.tokens_out[-1]
-                active[lane] = True
-                rem[lane] = req.steps - len(req.tokens_out)
-                sp = req.sampling
-                if sp.device and sp.temperature > 0.0:
-                    temps[lane] = sp.temperature
-                    seeds[lane] = (sp.seed & 0xFFFFFFFF,
-                                   (sp.seed >> 32) & 0xFFFFFFFF)
-                if req.stop_tokens:
-                    st = sorted(req.stop_tokens)
-                    stops[lane, :len(st)] = st
-        else:
-            temps, seeds, stops = host
-            lengths, tokens, active, rem = carry
+                lane_reqs[lane] = req
+                tables[lane, :len(req.pages)] = req.pages
+            if host is None:
+                lengths = np.zeros((b,), np.int32)
+                tokens = np.zeros((b,), np.int32)
+                active = np.zeros((b,), bool)
+                temps = np.zeros((b,), np.float32)
+                seeds = np.zeros((b, 2), np.uint32)   # (lo, hi) words
+                rem = np.zeros((b,), np.int32)
+                n_stop = max((len(r.stop_tokens) for _, r, _ in parts),
+                             default=0)
+                width = ((1 << (n_stop - 1).bit_length()) if n_stop > 1
+                         else 1)
+                stops = np.full((b, width), -1, np.int32)  # ids >= 0: pad safe
+                for lane, req, _new in parts:
+                    lengths[lane] = req.length
+                    tokens[lane] = req.tokens_out[-1]
+                    active[lane] = True
+                    rem[lane] = req.steps - len(req.tokens_out)
+                    sp = req.sampling
+                    if sp.device and sp.temperature > 0.0:
+                        temps[lane] = sp.temperature
+                        seeds[lane] = (sp.seed & 0xFFFFFFFF,
+                                       (sp.seed >> 32) & 0xFFFFFFFF)
+                    if req.stop_tokens:
+                        st = sorted(req.stop_tokens)
+                        stops[lane, :len(st)] = st
+            else:
+                temps, seeds, stops = host
+                lengths, tokens, active, rem = carry
         # chaos: decode fault site — tripped once per DECODE TICK (k times
         # per block), so a deterministic schedule written against
         # per-token serving (error@N, per-tick delays) keeps its meaning
@@ -2945,22 +2994,29 @@ class ContinuousBatcher:
         for _ in range(k):
             chaos.trip("engine.step")
         t0 = _time.perf_counter()
-        (toks, lps, ems, len_f, tok_f, live_f, rem_f,
-         self._kv_state, *moe) = self._block_fn(k)(
-            self.params, self._kv_state, jnp.asarray(tables),
-            jnp.asarray(lengths), jnp.asarray(tokens),
-            jnp.asarray(active), jnp.asarray(temps), jnp.asarray(seeds),
-            jnp.asarray(rem), jnp.asarray(stops))
+        with part(clock, "dispatch.put"):
+            args = (jnp.asarray(tables), jnp.asarray(lengths),
+                    jnp.asarray(tokens), jnp.asarray(active),
+                    jnp.asarray(temps), jnp.asarray(seeds),
+                    jnp.asarray(rem), jnp.asarray(stops))
+        with part(clock, "dispatch.call"):
+            (toks, lps, ems, len_f, tok_f, live_f, rem_f,
+             self._kv_state, *moe) = self._block_fn(k)(
+                self.params, self._kv_state, *args)
+        ticket = clock.launched()
+        clock.note(program=self._block_names[k], k=k, lanes=len(lane_reqs),
+                   rows=len(lane_reqs), ahead=int(ahead > 0))
         self.decode_dispatches += 1
         self.decode_block_steps += k
         self._note_dispatch("decode")
         return {"k": k, "lane_reqs": lane_reqs, "dev": (toks, lps, ems),
                 "moe": moe, "carry": (len_f, tok_f, live_f, rem_f),
-                "host": (temps, seeds, stops), "t0": t0}
+                "host": (temps, seeds, stops), "t0": t0, "ticket": ticket}
 
     def _chain_block(self, stash, jnp, ahead: int):
         """Enqueue the block that follows ``stash`` from its
-        device-resident carry, or return None where the chain must break.
+        device-resident carry: ``(block, None)``, or ``(None, cause)``
+        where the chain must break, ``cause`` one of ``BREAK_CAUSES``.
 
         Called twice by :meth:`_consume_block`.  BEFORE the fetch
         (``ahead`` = the block's K: its tokens are not committed, so every
@@ -2986,12 +3042,12 @@ class ContinuousBatcher:
         st = self._stages
         k = stash["k"]
         if k <= 1:
-            return None
+            return None, "k1"
         lanes_now = list(stash["lane_reqs"].items())
         with stage(st, "plan"):
             with self._cv:
                 if self._shutdown or self._hbm_reclaim_bytes:
-                    return None
+                    return None, "shutdown_or_reclaim"
                 if ahead and self._queue:
                     # a request that arrived since the top of _run takes
                     # its lane now, not at the commit after the fetch:
@@ -3001,10 +3057,12 @@ class ContinuousBatcher:
                         self._admit_locked()
                 for lane, req in lanes_now:
                     # released (cancel/deadline sweep, a stop token) or
-                    # preempted since dispatch, or about to complete
-                    if (self._active[lane] is not req or req.cancelled
-                            or req.steps - len(req.tokens_out) <= ahead):
-                        return None
+                    # preempted since dispatch
+                    if self._active[lane] is not req or req.cancelled:
+                        return None, "released"
+                for lane, req in lanes_now:    # about to complete
+                    if req.steps - len(req.tokens_out) <= ahead:
+                        return None, "completion"
                 # a lane that finished its prompt while this chain ran (its
                 # first token is out) is in no block of the chain: chaining
                 # on would leave it without a step until a lane of the
@@ -3013,21 +3071,22 @@ class ContinuousBatcher:
                        and not r.pending_prompt and r.tokens_out
                        and not r.cancelled
                        for lane, r in enumerate(self._active)):
-                    return None
+                    return None, "joiner"
             # a lane that re-armed speculation (a probe countdown expired)
             # must flow back through _plan_decode — a plain chain here
             # would starve the probe forever
             if (self._spec is not None
                     and all(self._spec_eligible(r) for _, r in lanes_now)):
-                return None
+                return None, "spec"
             if self._pick_block_k(lanes_now, ahead) != k:
-                return None
+                return None, "k"
             k2, parts = self._reserve_block_pages(lanes_now, k, ahead)
             if k2 != k or len(parts) != len(lanes_now):
-                return None
+                return None, "pages"
         with stage(st, "dispatch"):
             return self._dispatch_block(parts, k, jnp, carry=stash["carry"],
-                                        host=stash["host"])
+                                        host=stash["host"],
+                                        ahead=ahead), None
 
     def _consume_block(self, stash, jnp) -> bool:
         """Fetch a dispatched block (ONE host sync for up to K tokens per
@@ -3042,14 +3101,17 @@ class ContinuousBatcher:
         rewrites before reading."""
         st = self._stages
         k = stash["k"]
-        self._pending_block = self._chain_block(stash, jnp, ahead=k)
-        if self._pending_block is not None:
+        self._pending_block, why = self._chain_block(stash, jnp, ahead=k)
+        if why is None:
             self.ahead_blocks += 1
+        else:
+            self.chain_breaks[why] += 1
         with stage(st, "fetch"):
             toks = np.asarray(stash["dev"][0], np.int32)
             lps = np.asarray(stash["dev"][1], np.float32)
             ems = np.asarray(stash["dev"][2], bool)
             self._note_moe(stash["moe"], decode=True)
+            st.landed(stash["ticket"], why, lanes=len(stash["lane_reqs"]))
         self.decode_host_syncs += 1
         now = _time.perf_counter()  # post-fetch: device work is done
         # a block enqueued ahead started when its predecessor ended, which
@@ -3060,7 +3122,6 @@ class ContinuousBatcher:
                              if self._step_ewma_s else step_s)
         emits: List = []
         completed: List = []
-        emitted_total = 0
         with stage(st, "commit"), self._cv:
             for lane, req in stash["lane_reqs"].items():
                 if self._active[lane] is not req or req.cancelled:
@@ -3072,7 +3133,6 @@ class ContinuousBatcher:
                 n = int(ems[lane].sum())   # prefix mask: first n are valid
                 if n == 0:
                     continue
-                emitted_total += n
                 self._note_sparse("decode", req.length, n)
                 # the block is one device round trip: spread its wall time
                 # evenly over the lane's tokens so ITL keeps a true mean
@@ -3099,11 +3159,10 @@ class ContinuousBatcher:
                     completed.append(req)
             with stage(st, "admit"):
                 self._admit_locked()
-        if self.trace is not None and emitted_total:
-            self.trace.add_counter("decode_block", now,
-                                   tokens=emitted_total, k=k)
         if self._pending_block is None:
-            self._pending_block = self._chain_block(stash, jnp, ahead=0)
+            self._pending_block, why = self._chain_block(stash, jnp, ahead=0)
+            if why is None:
+                self.late_links += 1
         self._deliver(emits, completed)
         return True
 
@@ -3214,11 +3273,13 @@ class ContinuousBatcher:
             jnp.asarray(lengths), jnp.asarray(tokens), jnp.asarray(active),
             jnp.asarray(temps), jnp.asarray(seeds), jnp.asarray(rem),
             jnp.asarray(stops))
+        ticket = self._stages.launched()
         self.decode_dispatches += 1
         self.spec_dispatches += 1
         self._note_dispatch("verify")
         return {"k": k, "lane_reqs": lane_reqs,
-                "dev": (toks, lps, ems, drafted, accepted), "t0": t0}
+                "dev": (toks, lps, ems, drafted, accepted), "t0": t0,
+                "ticket": ticket}
 
     def _consume_spec_block(self, stash, jnp) -> bool:
         """Fetch a speculative dispatch (ONE host sync for up to K+1
@@ -3235,6 +3296,7 @@ class ContinuousBatcher:
             ems = np.asarray(stash["dev"][2], bool)
             drafted = np.asarray(stash["dev"][3], np.int32)
             accepted = np.asarray(stash["dev"][4], np.int32)
+            st.landed(stash["ticket"], lanes=len(stash["lane_reqs"]))
         self.decode_host_syncs += 1
         now = _time.perf_counter()
         self._step_ewma_s = (
@@ -3242,8 +3304,6 @@ class ContinuousBatcher:
             if self._step_ewma_s else (now - stash["t0"]) / (k + 1))
         emits: List = []
         completed: List = []
-        emitted_total = 0
-        accepted_total = 0
         with stage(st, "commit"), self._cv:
             for lane, req in stash["lane_reqs"].items():
                 if self._active[lane] is not req or req.cancelled:
@@ -3253,7 +3313,6 @@ class ContinuousBatcher:
                 self.spec_tokens_accepted += a
                 req.spec_drafted += d
                 req.spec_accepted += a
-                accepted_total += a
                 rate = a / d if d else 0.0
                 req.spec_ewma = (self.SPEC_EWMA_DECAY * req.spec_ewma
                                  + (1.0 - self.SPEC_EWMA_DECAY) * rate)
@@ -3267,7 +3326,6 @@ class ContinuousBatcher:
                 n = int(ems[lane].sum())   # prefix mask: first n are valid
                 if n == 0:
                     continue
-                emitted_total += n
                 dt = (now - req.t_last) / n if req.t_last is not None \
                     else None
                 self._note_second_token(req, now)
@@ -3295,10 +3353,6 @@ class ContinuousBatcher:
                     completed.append(req)
             with stage(st, "admit"):
                 self._admit_locked()
-        if self.trace is not None and emitted_total:
-            self.trace.add_counter("decode_block", now,
-                                   tokens=emitted_total, k=k,
-                                   accepted=accepted_total)
         self._deliver(emits, completed)
         return True
 
@@ -3358,6 +3412,7 @@ class ContinuousBatcher:
                     jnp.asarray(tables), jnp.asarray(lengths),
                     jnp.asarray(tokens), jnp.asarray(active))
                 tok_dev = logits.argmax(-1)
+            ticket = st.launched()
             self.decode_dispatches += 1
             self.decode_block_steps += 1
             self._note_dispatch("decode")
@@ -3390,6 +3445,7 @@ class ContinuousBatcher:
                         logprobs_arr[lane] = float(
                             row[next_tokens[lane]]
                             - np.log(np.exp(row).sum()))
+            st.landed(ticket, "single", lanes=len(lane_reqs))
 
         emits: List = []
         completed: List = []

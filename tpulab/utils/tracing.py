@@ -6,11 +6,13 @@ equivalent adds the XLA profiler).
   profiler switch: the only code in ``tpulab/`` that calls
   ``jax.profiler.start_trace`` / ``stop_trace`` (the Debug RPC's
   ``profile_ticks``, :func:`trace` and the benchmark all go through it).
-- :func:`annotate` / :func:`stage` / :class:`StageClock` — named regions
-  in the profiler's trace, on the device trace's clock (the nvtx-range
-  analog the reference lacked); :func:`stage` also adds the region's
-  host-clock seconds to an accumulator its caller owns (the scheduler's
-  ``debug_state()["dispatch"]["stages"]``).
+- :func:`annotate` / :func:`stage` / :func:`part` / :class:`StageClock` —
+  named regions in the profiler's trace, on the device trace's clock (the
+  nvtx-range analog the reference lacked); :func:`stage` also adds the
+  region's host-clock seconds to an accumulator its caller owns (the
+  scheduler's ``debug_state()["dispatch"]["stages"]``), and the same
+  clock reads the thread's turns with an empty device queue and the
+  parts of a stage (``["turns"]``, ``["dispatch_parts"]``).
 - :class:`TraceContext` / :class:`ChromeTraceRecorder` /
   :func:`merge_chrome_traces` — request-scoped distributed tracing: the
   client mints a trace id, carries it over gRPC (request field + metadata),
@@ -167,18 +169,124 @@ class StageClock:
     outermost stage lasts until the next one begins: what the thread
     loses between two ``with`` blocks, e.g. the interpreter lock to the
     threads it has just handed work, belongs to the stage that ended),
-    so their seconds sum to the thread's time since its first stage."""
+    so their seconds sum to the thread's time since its first stage.
 
-    def __init__(self, names: Iterable[str], prefix: str = ""):
+    The same clock keeps two finer readings of the same seconds:
+
+    - **the exposed turn** (``turn`` names the stages that count): the
+      thread says where a program went onto the device's queue
+      (:meth:`launched`) and, inside the stage that waited for it, that
+      it came back (:meth:`landed`).  The queue is in order, so what is
+      un-fetched then was launched later.  A *turn* is the time the
+      thread works with nothing un-fetched: it opens where a stage
+      outside ``turn`` (the fetch, the idle wait) ends with the queue
+      empty and closes at the next :meth:`launched`.  While one is open
+      the seconds of the stages in ``turn`` are also added to
+      ``turn_seconds``: the stages' own readings do not move, and a
+      turn's seconds are its stages' by construction (a wait inside it,
+      an idle wait or the fetch of a program that had already ended,
+      is no part of them).  In a capture a turn is a span
+      ``<prefix>turn.<cause>``, closed over such a wait and opened again
+      behind it under what that wait gave as the cause.
+    - **parts** (:func:`part`): named regions inside a stage that do not
+      pause it, seconds and entries of their own."""
+
+    def __init__(self, names: Iterable[str], prefix: str = "",
+                 turn: Iterable[str] = (), causes: Iterable[str] = (),
+                 parts: Iterable[str] = ()):
         self.prefix = prefix
         self.seconds: Dict[str, float] = {n: 0.0 for n in names}
         self.entries: Dict[str, int] = {n: 0 for n in names}
         self._open: list = []          # the stack of open _Stage
         self._ended: Optional[tuple] = None   # (name, when): last outermost
+        self.turn_seconds: Dict[str, float] = {n: 0.0 for n in turn}
+        self.turn_entries = 0
+        self.part_seconds: Dict[str, float] = {p: 0.0 for p in parts}
+        self.part_entries: Dict[str, int] = {p: 0 for p in parts}
+        # every span name the clock can emit, built once
+        self.span_names: Dict[str, str] = {
+            n: prefix + n for n in (*self.seconds, *self.part_seconds)}
+        self.turn_names: Dict[str, str] = {
+            c: f"{prefix}turn.{c}" for c in (*causes, "other")}
+        self._launched = self._landed = 0     # tickets, in queue order
+        self._in_turn = False
+        self._turn = None                     # the open turn's open span
+        self._cause: Optional[tuple] = None   # (cause, stats) of landed()
 
     def stages(self) -> Dict[str, Dict[str, float]]:
         return {n: {"s": s, "n": self.entries[n]}
                 for n, s in self.seconds.items()}
+
+    def turns(self) -> Dict[str, object]:
+        """``{"n", "s", "stages": {stage: s}}`` of the turns so far; the
+        open one's seconds are in as far as its stages have booked."""
+        by_stage = dict(self.turn_seconds)
+        return {"n": self.turn_entries, "s": sum(by_stage.values()),
+                "stages": by_stage}
+
+    def parts(self) -> Dict[str, Dict[str, float]]:
+        return {p: {"s": s, "n": self.part_entries[p]}
+                for p, s in self.part_seconds.items()}
+
+    def launched(self) -> int:
+        """A program went onto the device's queue (call where the jitted
+        function returns): an open turn ends here.  Returns the ticket
+        :meth:`landed` takes."""
+        self._launched += 1
+        if self._in_turn:
+            self._book(time.perf_counter())   # the turn's edge, mid-stage
+            self._in_turn = False
+            self.turn_entries += 1
+            self._close_span()
+        return self._launched
+
+    def landed(self, ticket: int, cause: str = "other", **stats) -> None:
+        """The program ``ticket`` names is fetched (call inside the stage
+        that waited for it).  Where nothing launched after it, a turn
+        opens as that stage ends, its span named by ``cause`` (one of the
+        clock's ``causes``, else ``other``) with ``stats``; where a turn
+        is open already, its span goes on under that name."""
+        if ticket > self._landed:
+            self._landed = ticket
+        self._cause = (cause, stats)
+
+    def note(self, **stats) -> None:
+        """Stats on the innermost open stage's span: read only while a
+        capture is open."""
+        if self._open:
+            self._open[-1].span.set_metadata(**stats)
+
+    def _book(self, now: float) -> None:
+        """Add the running stage's seconds up to ``now``: the innermost
+        open stage's, or else the last outermost one's, which lasts
+        until the next begins."""
+        if self._open:
+            top = self._open[-1]
+            self._add(top.name, now - top.t0)
+            top.t0 = now
+        elif self._ended is not None:
+            name, when = self._ended
+            self._add(name, now - when)
+            self._ended = (name, now)
+
+    def _add(self, name: str, dt: float) -> None:
+        self.seconds[name] += dt
+        if self._in_turn and name in self.turn_seconds:
+            self.turn_seconds[name] += dt
+
+    def _close_span(self) -> None:
+        if self._turn is not None:
+            self._turn.__exit__(None, None, None)
+            self._turn = None
+
+    def _leave(self) -> None:
+        """A stage outside the turn's set (a wait) has ended."""
+        (cause, stats), self._cause = self._cause or ("other", {}), None
+        if self._launched == self._landed:
+            self._in_turn = True
+            self._turn = annotate(
+                self.turn_names.get(cause, self.turn_names["other"]), **stats)
+            self._turn.__enter__()
 
 
 class _Stage:
@@ -186,18 +294,15 @@ class _Stage:
 
     def __init__(self, clock: StageClock, name: str):
         self.clock, self.name = clock, name
-        self.span = annotate(clock.prefix + name)
+        self.span = annotate(clock.span_names[name])
 
     def __enter__(self):
         clock = self.clock
         self.span.__enter__()
         now = time.perf_counter()
-        if clock._open:                # pause the stage around this one
-            outer = clock._open[-1]
-            clock.seconds[outer.name] += now - outer.t0
-        elif clock._ended is not None:  # the last one lasted until now
-            name, when = clock._ended
-            clock.seconds[name] += now - when
+        clock._book(now)    # pause the stage around this one, or end the last
+        if clock._turn is not None and self.name not in clock.turn_seconds:
+            clock._close_span()        # a wait is no part of a turn
         clock._open.append(self)
         self.t0 = now
         return self
@@ -205,7 +310,7 @@ class _Stage:
     def __exit__(self, *exc):
         clock = self.clock
         now = time.perf_counter()
-        clock.seconds[self.name] += now - self.t0
+        clock._add(self.name, now - self.t0)
         clock.entries[self.name] += 1
         clock._open.pop()
         if clock._open:
@@ -213,6 +318,8 @@ class _Stage:
         else:
             clock._ended = (self.name, now)
         self.span.__exit__(*exc)
+        if clock.turn_seconds and self.name not in clock.turn_seconds:
+            clock._leave()
         return False
 
 
@@ -223,6 +330,33 @@ def stage(clock: StageClock, name: str) -> _Stage:
     ``clock``.  Always on: two clock reads and an inactive-TraceMe check
     per use."""
     return _Stage(clock, name)
+
+
+class _Part:
+    __slots__ = ("clock", "name", "t0", "span")
+
+    def __init__(self, clock: StageClock, name: str):
+        self.clock, self.name = clock, name
+        self.span = annotate(clock.span_names[name])
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        clock = self.clock
+        clock.part_seconds[self.name] += time.perf_counter() - self.t0
+        clock.part_entries[self.name] += 1
+        self.span.__exit__(*exc)
+        return False
+
+
+def part(clock: StageClock, name: str) -> _Part:
+    """``with part(clock, "dispatch.put"):`` inside a :func:`stage`: a
+    span and seconds of its own, while the stage around it runs on (its
+    seconds include the part's).  Same cost as :func:`stage`."""
+    return _Part(clock, name)
 
 
 class ChromeTraceRecorder:
@@ -284,19 +418,6 @@ class ChromeTraceRecorder:
                     "events are being dropped; saved traces carry the count "
                     "in otherData.dropped_events", self._events.maxlen)
         self._events.append(ev)
-
-    def add_counter(self, name: str, ts_s: float, **values) -> None:
-        """One counter ('C') sample; ``ts_s`` is a time.perf_counter value
-        from the same process.  Perfetto/chrome render each name as a
-        stacked counter track — the batcher samples ``decode_block``
-        (tokens delivered + block size K per fused dispatch) so the
-        tokens-per-dispatch shape is visible on the same timeline as the
-        request spans it explains."""
-        ev = {"name": name, "ph": "C", "pid": self._pid, "tid": 0,
-              "ts": round((ts_s - self._t0) * 1e6, 3),
-              "args": {k: float(v) for k, v in values.items()}}
-        with self._lock:
-            self._append_locked(ev)
 
     def __len__(self) -> int:
         with self._lock:
