@@ -396,9 +396,11 @@ def phase_latent(smoke: Smoke) -> str:
     import numpy as np
 
     from tpulab.engine.kv_pool import PagedKVPool
-    from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
+    from tpulab.engine.paged_steps import (moe_shape, pack_round,
+                                           paged_decode_step,
                                            paged_mixed_step,
-                                           paged_ragged_forward)
+                                           paged_ragged_forward,
+                                           result_fields, unpack_words)
     from tpulab.models.spec import glm4_moe_lite_spec, init_params
     sz = smoke.sizes
     cfg, chunk, page = sz.glm, sz.glm_chunk, sz.lm_page_size
@@ -418,18 +420,17 @@ def phase_latent(smoke: Smoke) -> str:
     # lane 3 idle
     fill = (i32(rng.integers(0, cfg["vocab_size"], (lanes, chunk))),
             i32([chunk, chunk - 3, chunk // 2, 0]))
-    toks, row_lane, row_off, q_lens = map(i32, pack_round(
+    kv_lens = i32([2 * chunk, chunk - 2, chunk // 2 + 1, 0])
+    packed = round_buffer(tables, *pack_round(
         lanes, {0: rng.integers(0, cfg["vocab_size"], chunk)},
         {1: int(rng.integers(cfg["vocab_size"])),
-         2: int(rng.integers(cfg["vocab_size"]))}))
-    kv_lens = i32([2 * chunk, chunk - 2, chunk // 2 + 1, 0])
-    temps, seeds = jnp.zeros((lanes,), jnp.float32), jnp.zeros(
-        (lanes, 2), jnp.uint32)
+         2: int(rng.integers(cfg["vocab_size"]))}), kv_lens)
+    round_kw = dict(kw, lanes=lanes, max_pages=mp)
     out = {}
     for name, uk in (("gather", False), ("kernel", True)):
         pool = PagedKVPool(3 * mp + 1, page, spec.n_layers, 0, 0,
                            jnp.bfloat16, latent_width=spec.latent_width)
-        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **kw),
+        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **round_kw),
                         donate_argnums=(1,))
         padded = jax.jit(partial(paged_ragged_forward, use_kernel=uk,
                                  last_only=True, **kw), donate_argnums=(1,))
@@ -437,12 +438,12 @@ def phase_latent(smoke: Smoke) -> str:
                        donate_argnums=(1,))
         if uk:
             check_mosaic(smoke, "latent mixed round", partial(
-                paged_mixed_step, use_kernel=True, **kw), params, pool.kv,
-                i32(tables), toks, row_lane, row_off, q_lens, kv_lens, temps,
-                seeds)
+                paged_mixed_step, use_kernel=True, **round_kw), params,
+                pool.kv, packed)
         _, kv, _ = padded(params, pool.kv, i32(tables), *fill, fill[1])
-        _, _, last, kv, moe = mixed(params, kv, i32(tables), toks, row_lane,
-                                    row_off, q_lens, kv_lens, temps, seeds)
+        res, last, kv = mixed(params, kv, packed)
+        moe = unpack_words(result_fields(lanes, moe=moe_shape(spec)),
+                           np.asarray(res))["moe"]
         logits, kv, _ = step(params, kv, i32(tables), kv_lens,
                              i32([5, 6, 7, 0]),
                              jnp.asarray([True, True, True, False]))
@@ -496,8 +497,6 @@ def phase_jamba(smoke: Smoke) -> str:
     kw = dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
               compute_dtype=jnp.bfloat16, spec=spec)
     draw = lambda n: rng.integers(0, vocab, n)
-    temps, seeds = jnp.zeros((lanes,), jnp.float32), jnp.zeros(
-        (lanes, 2), jnp.uint32)
     # rounds 1, 2: three lanes' first chunks (a round carries at most
     # ``chunk`` prompt tokens, the engine's budget); round 3: lane 0 its
     # second chunk, lanes 1, 2 decode, lane 3 a first chunk of 7 into a slot
@@ -516,21 +515,20 @@ def phase_jamba(smoke: Smoke) -> str:
         store = (pool.kv, tuple(jnp.full(shape, 3, dtype) for shape, dtype
                                 in lane_state_shapes(spec, lanes,
                                                      jnp.bfloat16)))
-        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **kw),
-                        donate_argnums=(1,))
+        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, lanes=lanes,
+                                max_pages=mp, **kw), donate_argnums=(1,))
         step = jax.jit(partial(paged_decode_step, use_kernel=uk, **kw),
                        donate_argnums=(1,))
         for prefill, decode, lengths in rounds:
             toks, row_lane, row_off, q_lens = pack_round(lanes, prefill,
                                                          decode)
-            args = (i32(tables), i32(toks), i32(row_lane), i32(row_off),
-                    i32(q_lens), i32(np.asarray(lengths) + q_lens), temps,
-                    seeds)
+            packed = round_buffer(tables, toks, row_lane, row_off, q_lens,
+                                  np.asarray(lengths) + q_lens)
             if uk and decode:
                 check_mosaic(smoke, "jamba mixed round", partial(
-                    paged_mixed_step, use_kernel=True, **kw), params, store,
-                    *args)
-            _, _, last, store = mixed(params, store, *args)
+                    paged_mixed_step, use_kernel=True, lanes=lanes,
+                    max_pages=mp, **kw), params, store, packed)
+            _, last, store = mixed(params, store, packed)
         logits, store = step(params, store, i32(tables), i32(final),
                              i32([5, 6, 7, 8]), jnp.ones((lanes,), bool))
         out[name] = (np.asarray(last, np.float32),
@@ -588,8 +586,6 @@ def phase_keye(smoke: Smoke) -> str:
     kw = dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
               compute_dtype=jnp.bfloat16, spec=spec)
     draw = lambda n: rng.integers(0, vocab, n)
-    temps, seeds = jnp.zeros((lanes,), jnp.float32), jnp.zeros(
-        (lanes, 2), jnp.uint32)
     half = chunk // 2
     rounds = [({0: draw(chunk)}, {}, [i * chunk, 0, 0, 0])
               for i in range(fills)]
@@ -605,21 +601,20 @@ def phase_keye(smoke: Smoke) -> str:
                            spec.n_kv_heads, spec.head_dim, jnp.bfloat16,
                            index_dim=spec.index_dim)
         store = (pool.kv, pool.index)
-        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, **kw),
-                        donate_argnums=(1,))
+        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, lanes=lanes,
+                                max_pages=mp, **kw), donate_argnums=(1,))
         step = jax.jit(partial(paged_decode_step, use_kernel=uk, **kw),
                        donate_argnums=(1,))
         for prefill, decode, lengths in rounds:
             toks, row_lane, row_off, q_lens = pack_round(lanes, prefill,
                                                          decode)
-            args = (i32(tables), i32(toks), i32(row_lane), i32(row_off),
-                    i32(q_lens), i32(np.asarray(lengths) + q_lens), temps,
-                    seeds)
+            packed = round_buffer(tables, toks, row_lane, row_off, q_lens,
+                                  np.asarray(lengths) + q_lens)
             if uk and decode:
                 check_mosaic(smoke, "keye_vl2 mixed round", partial(
-                    paged_mixed_step, use_kernel=True, **kw), params, store,
-                    *args)
-            _, _, last, store, _moe = mixed(params, store, *args)
+                    paged_mixed_step, use_kernel=True, lanes=lanes,
+                    max_pages=mp, **kw), params, store, packed)
+            _, last, store = mixed(params, store, packed)
         logits, store, experts = step(params, store, i32(tables), i32(final),
                                       i32([5, 6, 7, 8]),
                                       jnp.ones((lanes,), bool))
@@ -660,6 +655,23 @@ def phase_keye(smoke: Smoke) -> str:
 
 
 # -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------
+def round_buffer(tables, toks, row_lane, row_off, q_lens, kv_lens):
+    """A mixed round of greedy lanes as the ONE buffer ``paged_mixed_step``
+    takes (what ``ContinuousBatcher._ragged_round`` sends)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.paged_steps import dispatch_fields, pack_words
+    lanes, max_pages = tables.shape
+    i32 = lambda x: np.asarray(x, np.int32)  # noqa: E731
+    return jnp.asarray(pack_words(
+        dispatch_fields("round", lanes, max_pages), dict(
+            tables=i32(tables), q_lens=i32(q_lens), kv_lens=i32(kv_lens),
+            temps=np.zeros((lanes,), np.float32),
+            seeds=np.zeros((lanes, 2), np.uint32),
+            rows=np.stack([i32(toks), i32(row_lane), i32(row_off)]))))
+
+
 def check_mosaic(smoke: Smoke, name: str, fn, *args) -> None:
     """On the chip the lowered program must hold the Mosaic custom call;
     in the rehearsal (interpret mode) it must not."""

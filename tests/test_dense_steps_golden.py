@@ -29,12 +29,14 @@ width (``ragged``) stayed to the bit through that PR.
 """
 
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers_steps import mixed_step
 from tpulab.engine.kv_pool import PagedKVPool
 from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
                                        paged_extend, paged_mixed_step,
@@ -87,10 +89,10 @@ def dense_step_logits(name, use_kernel):
     # token: lane 0's chunk of four, lane 1's decode token, lane 2 idle
     toks, row_lane, row_off, q_lens = map(i32, pack_round(
         3, {0: np.asarray(seq[0, :4])}, {1: int(seq[1, 0])}))
-    _nt, _lp, last, kv_packed = jax.jit(lambda p, kv: paged_mixed_step(
-        p, kv, tables, toks, row_lane, row_off, q_lens, i32([12, 6, 0]),
-        jnp.zeros((3,), jnp.float32), jnp.zeros((3, 2), jnp.uint32),
-        use_kernel=use_kernel, **common))(params, kv)
+    _nt, _lp, last, kv_packed = mixed_step(
+        jax.jit(partial(paged_mixed_step, lanes=3, max_pages=4,
+                        use_kernel=use_kernel, **common)),
+        params, kv, tables, toks, row_lane, row_off, q_lens, [12, 6, 0])
     out["mixed"] = np.asarray(last)[:2]
     # the steps below read what the round wrote: they get it from the
     # padded form, whose bits are the parent's, and the packed round's

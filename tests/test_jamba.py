@@ -12,12 +12,15 @@ attention, nothing imported from the program).
 import importlib.util
 import os
 import threading
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers_engine import FirstTokenGate
+from helpers_steps import decode_block, mixed_step
 from tpulab.engine.kv_pool import (LaneStateStore, PagedKVPool,
                                    lane_state_shapes)
 from tpulab.engine.paged import ContinuousBatcher
@@ -85,10 +88,10 @@ def _round(spec, params, store, tables, prefill, decode, lengths,
     toks, row_lane, row_off, q_lens = pack_round(LANES, prefill, decode)
     kv_lens = np.asarray(lengths, np.int32) + q_lens
     kv_lens[q_lens == 0] = 0          # as the scheduler leaves idle lanes
-    _nt, _lp, last, store = paged_mixed_step(
-        params, store, tables, i32(toks), i32(row_lane), i32(row_off),
-        i32(q_lens), i32(kv_lens), jnp.zeros((LANES,), jnp.float32),
-        jnp.zeros((LANES, 2), jnp.uint32), **_kw(spec, use_kernel))
+    _nt, _lp, last, store = mixed_step(
+        partial(paged_mixed_step, lanes=LANES, max_pages=4,
+                **_kw(spec, use_kernel)),
+        params, store, tables, toks, row_lane, row_off, q_lens, kv_lens)
     return np.asarray(last), store
 
 
@@ -247,16 +250,17 @@ def test_a_lane_that_stops_inside_a_block_holds_its_state(model):
         store, tables = _fresh(spec, junk=True)
         last, store = _round(spec, params, store, tables, prompts, {},
                              [0, 0, 0, 0])
-        carry = (i32([5, 7, 0, 0]), i32(last.argmax(-1)),
-                 jnp.asarray([True, True, False, False]), i32([8, 3, 0, 0]))
+        carry = ([5, 7, 0, 0], last.argmax(-1), [True, True, False, False],
+                 [8, 3, 0, 0])
+        block = partial(paged_decode_block, lanes=LANES, max_pages=4, k=k,
+                        **_kw(spec))
         out = []
-        for _ in range(8 // k):
-            toks, _lps, ems, *carry, store = paged_decode_block(
-                params, store, tables, *carry[:3],
-                jnp.zeros((LANES,), jnp.float32),
-                jnp.zeros((LANES, 2), jnp.uint32), carry[3],
-                jnp.full((LANES, 1), -1, jnp.int32), k=k, **_kw(spec))
-            out.append(np.where(np.asarray(ems), np.asarray(toks), -1))
+        for i in range(8 // k):
+            # the first block takes its state from the buffer, the ones
+            # behind it from the carry the block before returned
+            toks, _lps, ems, carry, store = decode_block(
+                block, params, store, tables, carry, k, fresh=i == 0)
+            out.append(np.where(ems, toks, -1))
         return np.concatenate(out, axis=1), store, carry
 
     toks8, store8, carry8 = run(8)
@@ -412,10 +416,11 @@ def test_a_preempted_request_resumes_with_a_fresh_engines_tokens(model):
     p_low, p_hi = (rng.integers(0, VOCAB, n).tolist() for n in (10, 6))
     cb = _engine(spec, params, lanes=1)
     try:
-        started = threading.Event()
-        f_low = cb.submit(p_low, 14, on_token=lambda t, i: started.set())
+        started = FirstTokenGate()
+        f_low = cb.submit(p_low, 14, on_token=started)
         assert started.wait(timeout=120)
         f_hi = cb.submit(p_hi, 5, priority=10)
+        started.release()
         got_hi, got_low = f_hi.result(timeout=300), f_low.result(timeout=300)
         assert cb.preemptions >= 1
         assert cb.debug_state()["state"]["zero_starts"] >= 3

@@ -31,6 +31,8 @@ from tpulab.models.spec import (ModelSpec, dense_spec, init_params,
                                 keye_vl2_spec)
 from tpulab.ops import sparse_attention as sa
 
+from helpers_engine import FirstTokenGate
+from helpers_steps import decode_block, mixed_step
 from helpers_attention import (BF16_ATOL, BF16_RTOL, assert_parents_bits,
                                sparse_attend_case, sparse_decode_case)
 
@@ -100,10 +102,11 @@ def _round(spec, params, store, tables, prefill, decode, lengths,
     toks, row_lane, row_off, q_lens = pack_round(LANES, prefill, decode)
     kv_lens = np.asarray(lengths, np.int32) + q_lens
     kv_lens[q_lens == 0] = 0          # as the scheduler leaves idle lanes
-    _nt, _lp, last, store, _moe = _jit(paged_mixed_step, spec, use_kernel)(
-        params, store, tables, i32(toks), i32(row_lane), i32(row_off),
-        i32(q_lens), i32(kv_lens), jnp.zeros((LANES,), jnp.float32),
-        jnp.zeros((LANES, 2), jnp.uint32))
+    _nt, _lp, last, store, _moe = mixed_step(
+        _jit(paged_mixed_step, spec, use_kernel, lanes=LANES,
+             max_pages=tables.shape[1]),
+        params, store, tables, toks, row_lane, row_off, q_lens, kv_lens,
+        spec=spec)
     return np.asarray(last), store
 
 
@@ -482,17 +485,17 @@ def test_one_block_of_eight_is_eight_blocks_of_one(model):
         store, tables = _fresh(spec)
         _last, store = _round(spec, params, store, tables, prompts, {},
                               [0] * 4)
-        carry = (i32([14, 0, 21, 0]), i32([5, 0, 9, 0]),
-                 jnp.asarray([True, False, True, False]), i32([8, 0, 3, 0]))
+        carry = ([14, 0, 21, 0], [5, 0, 9, 0], [True, False, True, False],
+                 [8, 0, 3, 0])
+        block = _jit(paged_decode_block, spec, k=k, lanes=LANES,
+                     max_pages=tables.shape[1])
         out = []
-        for _ in range(8 // k):
-            toks, _lps, ems, *rest = _jit(paged_decode_block, spec, k=k)(
-                params, store, tables, *carry[:3],
-                jnp.zeros((LANES,), jnp.float32),
-                jnp.zeros((LANES, 2), jnp.uint32), carry[3],
-                jnp.full((LANES, 1), -1, jnp.int32))
-            carry, store = tuple(rest[:4]), rest[4]
-            out.append(np.where(np.asarray(ems), np.asarray(toks), -1))
+        for i in range(8 // k):
+            # a chain: the first block's state in its buffer, then the carry
+            toks, _lps, ems, carry, store, _moe = decode_block(
+                block, params, store, tables, carry, k, fresh=i == 0,
+                spec=spec)
+            out.append(np.where(ems, toks, -1))
         return np.concatenate(out, axis=1), _lane_rows(store, tables, 2, 24)
 
     (t8, (kv8, ix8)), (t1, (kv1, ix1)) = run(8), run(1)
@@ -600,10 +603,11 @@ def test_a_preempted_request_resumes_with_a_fresh_engines_tokens(model):
     p_low, p_hi = (rng.integers(0, VOCAB, n).tolist() for n in (17, 6))
     cb = _engine(spec, params, lanes=1)
     try:
-        started = threading.Event()
-        f_low = cb.submit(p_low, 14, on_token=lambda t, i: started.set())
+        started = FirstTokenGate()
+        f_low = cb.submit(p_low, 14, on_token=started)
         assert started.wait(timeout=120)
         f_hi = cb.submit(p_hi, 5, priority=10)
+        started.release()
         got_hi, got_low = f_hi.result(timeout=300), f_low.result(timeout=300)
         assert cb.preemptions >= 1
     finally:

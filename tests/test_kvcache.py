@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers_engine import FirstTokenGate
 from tpulab import chaos
 from tpulab.engine.kv_pool import PagedKVPool, kv_rows_view
 from tpulab.engine.paged import ContinuousBatcher, SamplingParams
@@ -138,10 +139,11 @@ def test_preempt_resume_no_reprefill_token_parity(lm):
                            page_size=8, compute_dtype=jnp.float32,
                            kv_offload=32 << 20)
     try:
-        started = threading.Event()
-        f_low = cb.submit(p_low, 10, on_token=lambda t, i: started.set())
-        assert started.wait(timeout=60)
+        started = FirstTokenGate()
+        f_low = cb.submit(p_low, 10, on_token=started)
+        assert started.wait()
         f_hi = cb.submit(p_hi, 4, priority=10)    # outranks -> preempts
+        started.release()
         got_hi = f_hi.result(timeout=120)
         got_low = f_low.result(timeout=120)
         assert cb.preemptions >= 1
@@ -157,13 +159,15 @@ def test_preempt_resume_no_reprefill_token_parity(lm):
 
         # seeded-sampled victim: the swap restore must not perturb the
         # host PRNG stream either
-        started2 = threading.Event()
+        started2 = FirstTokenGate()
         pf = cb.prefill_dispatches
         f_s = cb.submit(p_low, 10,
                         sampling=SamplingParams(temperature=0.9, seed=123),
-                        on_token=lambda t, i: started2.set())
-        assert started2.wait(timeout=60)
-        cb.submit(p_hi, 2, priority=10).result(timeout=120)
+                        on_token=started2)
+        assert started2.wait()
+        f_hi2 = cb.submit(p_hi, 2, priority=10)
+        started2.release()
+        f_hi2.result(timeout=120)
         assert list(f_s.result(timeout=120)) == list(sampled_ref)
         assert cb.prefill_dispatches == pf + 2    # still no re-prefill
     finally:
@@ -207,10 +211,11 @@ def test_demoted_prefix_promotion_hit(lm):
 # -- chaos degradation -------------------------------------------------------
 
 def _preempt_run(cb, p_low, p_hi):
-    started = threading.Event()
-    f_low = cb.submit(p_low, 10, on_token=lambda t, i: started.set())
-    assert started.wait(timeout=60)
+    started = FirstTokenGate()
+    f_low = cb.submit(p_low, 10, on_token=started)
+    assert started.wait()
     f_hi = cb.submit(p_hi, 4, priority=10)
+    started.release()
     return f_hi.result(timeout=120), f_low.result(timeout=120)
 
 

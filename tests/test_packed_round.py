@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 import test_jamba
+from helpers_steps import mixed_step
 from test_glm_moe import CONFIG, D_FF, VOCAB
 from tpulab.engine import paged_steps
 from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool
@@ -38,6 +39,8 @@ LANES, PAGE, MAX_PAGES = 8, 8, 5
 OWN = 1 + np.arange(LANES * MAX_PAGES, dtype=np.int32).reshape(LANES,
                                                                MAX_PAGES)
 i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+#: what a packed step program is bound to beside the model
+PACKED = dict(lanes=LANES, max_pages=MAX_PAGES)
 
 
 def _shared_prefix_tables():
@@ -183,12 +186,10 @@ def test_packed_round_is_the_padded_round(models, model, mix, use_kernel):
     m = round_width(sum(case["prefill"].values()))
     assert len(toks) == m + LANES and (row_lane >= 0).sum() == q_lens.sum()
     kv_lens = np.where(q_lens > 0, start + q_lens, 0)
-    temps = jnp.zeros((LANES,), jnp.float32)
-    seeds = jnp.zeros((LANES, 2), jnp.uint32)
 
-    picks, _lp, last, kv_packed, *moe = _jit(paged_mixed_step, model, kw)(
-        params, kv, tables, i32(toks), i32(row_lane), i32(row_off),
-        i32(q_lens), i32(kv_lens), temps, seeds)
+    picks, _lp, last, kv_packed, *moe = mixed_step(
+        _jit(paged_mixed_step, model, kw, **PACKED), params, kv, tables,
+        toks, row_lane, row_off, q_lens, kv_lens, spec=kw.get("spec"))
 
     seq = np.zeros((LANES, m), np.int32)
     for lane, chunk in prefill.items():
@@ -230,11 +231,11 @@ def test_rows_without_a_token_leave_every_layer_finite(models, model,
     plain = _plain_form(model, params, kw)
     _logits, kv, *_ = plain(_store(kw, pool_kw), i32(OWN),
                             rng.integers(0, VOCAB, (LANES, 9)), ctx, ctx)
-    args = (params, kv, i32(OWN), i32(toks), i32(row_lane), i32(row_off),
-            i32(q_lens), i32(np.where(q_lens > 0, ctx + q_lens, 0)),
-            jnp.zeros((LANES,), jnp.float32),
-            jnp.zeros((LANES, 2), jnp.uint32))
-    want = np.asarray(_jit(paged_mixed_step, model, kw)(*args)[2])
+    args = (params, kv, OWN, toks, row_lane, row_off, q_lens,
+            np.where(q_lens > 0, ctx + q_lens, 0))
+    want = np.asarray(mixed_step(
+        _jit(paged_mixed_step, model, kw, **PACKED), *args,
+        spec=kw.get("spec"))[2])
 
     def unwritten_is_nan(kernel):
         def call(q, kv_pool, layer, tables, q_lens, *rest, **kwargs):
@@ -251,7 +252,8 @@ def test_rows_without_a_token_leave_every_layer_finite(models, model,
         layers.append(np.asarray(out[0]))
         return out
     monkeypatch.setattr(paged_steps, "_layer_block", spy)
-    got = np.asarray(paged_mixed_step(*args, **kw)[2])
+    got = np.asarray(mixed_step(partial(paged_mixed_step, **PACKED, **kw),
+                                *args, spec=kw.get("spec"))[2])
     assert len(layers) == kw["n_layers"]
     assert all(np.isfinite(x).all() for x in layers)
     live = q_lens > 0
@@ -274,21 +276,21 @@ def _spy_rounds(cb):
     pending and what the round took."""
     rounds, mixed = [], cb._mixed
 
-    def spy(params, kv, tables, toks, row_lane, row_off, q_lens, kv_lens,
-            *rest):
-        q = np.asarray(q_lens)
+    def spy(params, kv, packed):
+        f = paged_steps.unpack_words(cb._fields["round"], np.asarray(packed))
+        toks, row_lane, _row_off = f["rows"]
+        q = f["q_lens"]
         m = toks.shape[0] - cb.lanes
-        decodes = np.asarray(row_lane)[m:] >= 0
+        decodes = row_lane[m:] >= 0
         rounds.append(dict(
             rows=int(toks.shape[0]), tokens=int(q.sum()),
-            prefill=int((np.asarray(row_lane)[:m] >= 0).sum()),
+            prefill=int((row_lane[:m] >= 0).sum()),
             chunk_lanes=int(((q > 0) & ~decodes).sum()),
             decode_lanes=int(decodes.sum()), width=int(m),
             lanes=[(req.admit_seq, len(req.pending_prompt), int(q[lane]))
                    for lane, req in enumerate(cb._active)
                    if req is not None and req.pf_started]))
-        return mixed(params, kv, tables, toks, row_lane, row_off, q_lens,
-                     kv_lens, *rest)
+        return mixed(params, kv, packed)
     cb._mixed = spy
     return rounds
 

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers_engine import FirstTokenGate
 from tpulab.engine.kv_pool import PagedKVPool
 from tpulab.engine.paged import ContinuousBatcher, SamplingParams
 from tpulab.models.transformer import init_transformer_params, make_generate_fn
@@ -425,10 +426,11 @@ def test_preemption_exact_resume(lm):
     cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=1, max_len=64,
                            page_size=8, compute_dtype=jnp.float32)
     try:
-        started = threading.Event()
-        f_low = cb.submit(p_low, 10, on_token=lambda t, i: started.set())
-        assert started.wait(timeout=60)
+        started = FirstTokenGate()
+        f_low = cb.submit(p_low, 10, on_token=started)
+        assert started.wait()
         f_hi = cb.submit(p_hi, 4, priority=10)      # outranks -> preempts
+        started.release()
         got_hi = f_hi.result(timeout=120)
         got_low = f_low.result(timeout=120)
         assert cb.preemptions >= 1
@@ -438,12 +440,14 @@ def test_preemption_exact_resume(lm):
             np.asarray(got_hi), np.asarray(dense(p_hi[None, :], 4)[0]))
 
         # seeded-sampled victim: preemption must not perturb the PRNG
-        started2 = threading.Event()
+        started2 = FirstTokenGate()
         f_s = cb.submit(p_low, 10,
                         sampling=SamplingParams(temperature=0.9, seed=123),
-                        on_token=lambda t, i: started2.set())
-        assert started2.wait(timeout=60)
-        cb.submit(p_hi, 2, priority=10).result(timeout=120)
+                        on_token=started2)
+        assert started2.wait()
+        f_hi2 = cb.submit(p_hi, 2, priority=10)
+        started2.release()
+        f_hi2.result(timeout=120)
         assert list(f_s.result(timeout=120)) == list(sampled_ref)
     finally:
         cb.shutdown()
@@ -750,19 +754,22 @@ def test_device_sampling_reproducible_and_batch_invariant(lm):
                                max_len=64, page_size=8,
                                compute_dtype=jnp.float32)
         try:
-            started = threading.Event()
+            started = FirstTokenGate()
+            if not preempt:
+                started.release()
             fut = cb.submit(p, 10,
                             sampling=SamplingParams(temperature=0.9,
                                                     seed=1234, device=True),
-                            on_token=lambda t, i: started.set())
+                            on_token=started)
             if extra_traffic:
                 cb.submit(np.full((3,), 7, np.int32), 10,
                           sampling=SamplingParams(temperature=1.5, seed=9,
                                                   device=True))
             if preempt:
-                assert started.wait(timeout=60)
-                cb.submit(np.full((4,), 2, np.int32), 3, priority=10
-                          ).result(timeout=120)
+                assert started.wait()
+                hi = cb.submit(np.full((4,), 2, np.int32), 3, priority=10)
+                started.release()
+                hi.result(timeout=120)
             return list(fut.result(timeout=120))
         finally:
             cb.shutdown()

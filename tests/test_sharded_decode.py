@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from helpers_engine import FirstTokenGate
 from tpulab.engine.kv_pool import PagedKVPool, kv_rows_view
 from tpulab.engine.paged import ContinuousBatcher, SamplingParams
 from tpulab.models.transformer import (early_exit_draft,
@@ -228,10 +229,11 @@ def test_sharded_preempt_resume_through_host_tier(lm, dense):
     p_hi = np.random.default_rng(22).integers(0, 64, (5,), np.int32)
     cb = _batcher(lm, mesh=_mesh(2), lanes=1, kv_offload=32 << 20)
     try:
-        started = threading.Event()
-        f_low = cb.submit(p_low, 10, on_token=lambda t, i: started.set())
+        started = FirstTokenGate()
+        f_low = cb.submit(p_low, 10, on_token=started)
         assert started.wait(timeout=120)
         f_hi = cb.submit(p_hi, 4, priority=10)    # outranks -> preempts
+        started.release()
         got_hi = list(f_hi.result(timeout=300))
         got_low = list(f_low.result(timeout=300))
         assert cb.preemptions >= 1

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers_engine import FirstTokenGate
 from tpulab import chaos
 from tpulab.engine.paged import (ContinuousBatcher, SamplingParams,
                                  _PagedRequest)
@@ -338,10 +339,11 @@ def test_spec_preempt_resume_regenerates_exactly(lm, dense):
     p_hi = np.random.default_rng(32).integers(0, 64, (5,), np.int32)
     cb = _batcher(lm, draft="self", lanes=1, max_len=64, n_pages=17)
     try:
-        started = threading.Event()
-        f_low = cb.submit(p_low, 24, on_token=lambda t, i: started.set())
+        started = FirstTokenGate()
+        f_low = cb.submit(p_low, 24, on_token=started)
         assert started.wait(timeout=120)
         f_hi = cb.submit(p_hi, 4, priority=10)
+        started.release()
         got_hi = list(f_hi.result(timeout=300))
         got_low = list(f_low.result(timeout=300))
         assert cb.preemptions >= 1

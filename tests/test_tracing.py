@@ -436,36 +436,39 @@ def _lower_args(cb, kind):
     """The jitted step of ``kind`` and arguments of the engine's own
     shapes to lower it with."""
     import jax.numpy as jnp
+    from tpulab.engine.paged_steps import pack_words
     b, mp = cb.lanes, cb.max_pages
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
-    lane = (i32(b, mp), i32(b), i32(b), jnp.zeros((b,), bool))
-    samp = (jnp.zeros((b,), jnp.float32), jnp.zeros((b, 2), jnp.uint32))
+
+    def packed(program, width):
+        """A buffer of zeros for ``program``, its open field ``width``."""
+        fields = cb._fields[program]
+        return jnp.asarray(pack_words(fields, {
+            name: np.zeros([width if n < 0 else n for n in shape], dtype)
+            for name, dtype, shape in fields}))
+
     one = (i32(mp), i32(1, 8))
     head = (cb.params, cb.pool.kv)
+    block = lambda: head + (packed("block", 1), cb._no_carry)  # noqa: E731
     return {
-        "paged_decode_block_k2": lambda: (
-            cb._block_fn(2), head + lane + samp + (i32(b), i32(b, 1))),
-        "paged_decode_block_k8": lambda: (
-            cb._block_fn(8), head + lane + samp + (i32(b), i32(b, 1))),
-        "paged_decode_step": lambda: (cb._step, head + lane),
+        "paged_decode_block_k2": lambda: (cb._block_fn(2), block()),
+        "paged_decode_block_k8": lambda: (cb._block_fn(8), block()),
         "paged_decode_step_sampled": lambda: (
-            cb._step_sampled, head + lane + samp),
+            cb._step_sampled, head + (packed("tick", 0),)),
         "paged_mixed_step": lambda: (
-            cb._mixed, head + (i32(b, mp), i32(8 + b), i32(8 + b),
-                               i32(8 + b), i32(b), i32(b)) + samp),
+            cb._mixed, head + (packed("round", 8 + b),)),
         "paged_prefill": lambda: (
             cb._prefill, head + one + (jnp.int32(5),)),
         "paged_extend": lambda: (
             cb._extend, head + one + (jnp.int32(8), jnp.int32(13))),
         "paged_speculative_block_k2": lambda: (
             cb._spec_block_fn(2),
-            (cb.params, cb._spec["params"], cb.pool.kv, lane[0], i32(b, mp))
-            + lane[1:] + samp + (i32(b), i32(b, 1))),
+            (cb.params, cb._spec["params"], cb.pool.kv, packed("spec", 1))),
     }[kind]()
 
 
 @pytest.mark.parametrize("kind", [
-    "paged_decode_block_k2", "paged_decode_block_k8", "paged_decode_step",
+    "paged_decode_block_k2", "paged_decode_block_k8",
     "paged_decode_step_sampled", "paged_mixed_step", "paged_prefill",
     "paged_extend", "paged_speculative_block_k2"])
 def test_step_programs_carry_stable_names(kind):

@@ -28,12 +28,14 @@ from tpulab import chaos
 from tpulab.core.deadline import Deadline, DeadlineExceeded
 from tpulab.core.threads import on_one_frame_chunk
 from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool, PrefixCache
-from tpulab.engine.paged_steps import (_device_sample_token, pack_round,
-                                       paged_decode_block, paged_decode_step,
+from tpulab.engine.paged_steps import (_device_sample_token,
+                                       dispatch_fields, moe_shape, pack_round,
+                                       pack_words, paged_decode_block,
                                        paged_decode_step_sampled,
                                        paged_extend, paged_mixed_step,
                                        paged_prefill, paged_speculative_block,
-                                       round_width)
+                                       result_fields, round_width,
+                                       unpack_words)
 from tpulab.utils import tracing
 from tpulab.utils.tracing import part, stage
 
@@ -238,7 +240,12 @@ class ContinuousBatcher:
     is attached with no queue pressure, so interactive TTFT/ITL does not
     regress; per-token ``on_token`` callbacks still fire in order, and
     cancellation/deadline sweeps act at block boundaries (a request stops
-    within at most one block of the sweep observing it).
+    within at most one block of the sweep observing it).  A dispatch
+    crosses the host-device boundary once each way: what the host knows
+    goes in as one packed buffer (:meth:`_put`), the tokens, their
+    log-probabilities and the expert counters come back as one
+    (:meth:`_fetch`); ``debug_state()["dispatch"]["transfers"]`` counts
+    both.
 
     Speculative decoding (``draft_params=``, docs/PERFORMANCE.md): a
     small draft model (e.g. :func:`tpulab.models.transformer.
@@ -575,7 +582,8 @@ class ContinuousBatcher:
         #: XLA gather path, ``use_kernel=False`` alone keeps the legacy
         #: split dispatch — the escape hatch.
         self.ragged = self.use_kernel if ragged is None else bool(ragged)
-        self._step_kw = dict(n_heads=n_heads, n_layers=n_layers,
+        self._step_kw = dict(lanes=lanes, max_pages=self.max_pages,
+                             n_heads=n_heads, n_layers=n_layers,
                              compute_dtype=compute_dtype,
                              use_kernel=self.use_kernel,
                              n_kv_heads=n_kv, rope_theta=rope_theta,
@@ -587,9 +595,10 @@ class ContinuousBatcher:
         #: expert layers' counters (``debug_state()["moe"]``), summed on
         #: the host from the small array every dispatch of an expert model
         #: returns and the scheduler fetches WITH the dispatch's tokens
+        self._moe_shape = moe_shape(spec)
         self._moe_assignments = (
             np.zeros((len(spec.moe_layers), spec.n_experts), np.int64)
-            if spec is not None and spec.moe_layers else None)
+            if self._moe_shape else None)
         self.moe_decode_steps = 0    # decode steps that had a live lane
         self.moe_experts_hit = 0     # over those steps and expert layers
         #: the indexer's work (``debug_state()["sparse"]``), host integers
@@ -602,16 +611,17 @@ class ContinuousBatcher:
                          for kind in ("decode", "round")} if sparse else None)
         rep, psh = self._rep, self._param_sh
         kvsh = self.pool.kv_sharding
-        self._step = self._jit(
-            partial(paged_decode_step, **self._step_kw), (1,),
-            (psh, kvsh, rep, rep, rep, rep), (rep, kvsh))
-        # sampled K=1 variant (positional temps/seeds so the sharded jit
-        # can attach in_shardings; identical compiled programs at mesh=None
-        # — jit specialized on temps=None vs arrays before too)
+        #: host -> device and device -> host transfers the scheduler's
+        #: thread made (:meth:`_put`, :meth:`_fetch`): one each a dispatch
+        self.transfers: Dict[str, int] = {"h2d": 0, "d2h": 0}
+        #: what each program takes from the host, as fields of one buffer
+        self._fields = {kind: dispatch_fields(kind, lanes, self.max_pages)
+                        for kind in ("tick", "block", "spec", "round")}
+        # the K=1 tick: every array argument positional (a sharded jit
+        # attaches in_shardings by position), the host's as one buffer
         self._step_sampled = self._jit(
             partial(paged_decode_step_sampled, **self._step_kw), (1,),
-            (psh, kvsh, rep, rep, rep, rep, rep, rep),
-            (rep, rep, rep, kvsh))
+            (psh, kvsh, rep), (rep, rep, kvsh))
         # mixed prefill+decode rounds (the ragged dispatch plan): ONE
         # jitted program respecializes per pow2 bucket of the round's
         # prefill tokens (round_width) — the chunks packed by token and
@@ -619,7 +629,7 @@ class ContinuousBatcher:
         # forward + on-device pick
         self._mixed = self._jit(
             partial(paged_mixed_step, **self._step_kw), (1,),
-            (psh, kvsh) + (rep,) * 8, (rep, rep, rep, kvsh))
+            (psh, kvsh, rep), (rep, rep, kvsh))
         if decode_block < 1:
             raise ValueError("decode_block must be >= 1")
         #: max fused-decode steps per dispatch (K): a K-block amortizes the
@@ -629,6 +639,12 @@ class ContinuousBatcher:
         self.decode_block = min(int(decode_block), self.BLOCK_K_MENU[-1])
         self._block_cache: Dict[int, Any] = {}
         self._block_names: Dict[int, str] = {}   # K -> the program's name
+        #: the carry a chain's first block passes: every lane of its buffer
+        #: is ``fresh``, so only the shapes count; made once, never donated
+        self._no_carry = jax.device_put(
+            tuple(np.zeros((lanes,), t)
+                  for t in (np.int32, np.int32, bool, np.int32)),
+            self._rep if self.mesh is not None else self.pool.device)
         self._pending_block: Optional[Dict[str, Any]] = None
         self._step_ewma_s = 0.0   # per-scan-step device time estimate
         self._block_fetched_t = 0.0   # return of the last decode block's fetch
@@ -754,7 +770,8 @@ class ContinuousBatcher:
                 draft_dev = jax.device_put(draft_params, self.pool.device)
             self._spec = {"params": draft_dev,
                           "n_heads": dh, "n_layers": dl, "n_kv_heads": dkv}
-            self._spec_kw = dict(n_heads=n_heads, n_layers=n_layers,
+            self._spec_kw = dict(lanes=lanes, max_pages=self.max_pages,
+                                 n_heads=n_heads, n_layers=n_layers,
                                  draft_n_heads=dh, draft_n_layers=dl,
                                  compute_dtype=compute_dtype,
                                  n_kv_heads=n_kv, draft_n_kv_heads=dkv,
@@ -898,6 +915,26 @@ class ContinuousBatcher:
         c["keys_scored"] += scored * layers
         c["keys_attended"] += attended * layers
         c["dense_rows"] += dense * layers
+
+    def _put(self, host):
+        """ONE host -> device transfer, counted: ``host`` (a numpy array)
+        on the engine's device, replicated under a mesh."""
+        import jax
+        self.transfers["h2d"] += 1
+        return jax.device_put(
+            host, self._rep if self.mesh is not None else self.pool.device)
+
+    def _fetch(self, dev) -> np.ndarray:
+        """ONE blocking device -> host fetch, counted."""
+        self.transfers["d2h"] += 1
+        return np.asarray(dev)
+
+    def _results(self, out, k: Optional[int] = None, spec: bool = False):
+        """A dispatch's one result array, fetched and taken apart
+        (``result_fields``)."""
+        return unpack_words(
+            result_fields(self.lanes, k, None if spec else self._moe_shape,
+                          spec), self._fetch(out))
 
     #: the stages of a scheduler pass, in the order a pass takes them
     STAGES = ("admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
@@ -1462,6 +1499,7 @@ class ContinuousBatcher:
                          "use_kernel": self.use_kernel,
                          "ragged_dispatches": self.ragged_dispatches,
                          "kinds": dict(self.dispatch_kinds),
+                         "transfers": dict(self.transfers),
                          "mixed_rows": self.mixed_rows,
                          "mixed_tokens": self.mixed_tokens,
                          "mixed_attn_rows": self.mixed_attn_rows,
@@ -2047,7 +2085,7 @@ class ContinuousBatcher:
         with stage(st, "dispatch"):
             tables = np.zeros((self.max_pages,), np.int32)
             tables[:len(req.pages)] = req.pages
-            tables_j = jnp.asarray(tables)
+            tables_j = self._put(tables)
             # pages secured: the queue wait ends HERE (first prefill only
             # — a preemption resume re-prefills but already left the queue
             # once)
@@ -2072,7 +2110,7 @@ class ContinuousBatcher:
                 tokens[0, :t] = prompt
                 last_logits, self.pool.kv = self._prefill(
                     self.params, self.pool.kv, tables_j,
-                    jnp.asarray(tokens), jnp.int32(t))
+                    self._put(tokens), self._put(np.int32(t)))
                 ticket = st.launched()
             else:
                 # tail (and/or chunked) prefill against resident context
@@ -2085,8 +2123,8 @@ class ContinuousBatcher:
                     tokens[0, :m] = prompt[start:start + m]
                     last_logits, self.pool.kv = self._extend(
                         self.params, self.pool.kv, tables_j,
-                        jnp.asarray(tokens), jnp.int32(start),
-                        jnp.int32(start + m))
+                        self._put(tokens), self._put(np.int32(start)),
+                        self._put(np.int32(start + m)))
                     ticket = st.launched()
                     start += m
         with stage(st, "commit"):
@@ -2112,15 +2150,15 @@ class ContinuousBatcher:
                         # fetched once per request; per-TICK logits are
                         # never fetched for device-sampled lanes.
                         import jax.numpy as _j
-                        tok = int(np.asarray(_device_sample_token(
+                        tok = int(self._fetch(_device_sample_token(
                             _j.asarray(last_logits, _j.float32),
-                            _j.float32(sp.temperature),
-                            _j.asarray([sp.seed & 0xFFFFFFFF,
-                                        (sp.seed >> 32) & 0xFFFFFFFF],
-                                       _j.uint32),
-                            _j.int32(t - 1))))
+                            self._put(np.float32(sp.temperature)),
+                            self._put(np.array(
+                                [sp.seed & 0xFFFFFFFF,
+                                 (sp.seed >> 32) & 0xFFFFFFFF], np.uint32)),
+                            self._put(np.int32(t - 1)))))
                     else:
-                        tok = sp.pick(np.asarray(last_logits))
+                        tok = sp.pick(self._fetch(last_logits))
                     lp = None
                     if req.want_logprobs:
                         # same f32 device log_softmax as paged_decode_step:
@@ -2128,7 +2166,7 @@ class ContinuousBatcher:
                         # to end
                         import jax as _jax
                         import jax.numpy as _j
-                        lp = float(np.asarray(_jax.nn.log_softmax(
+                        lp = float(self._fetch(_jax.nn.log_softmax(
                             _j.asarray(last_logits, _j.float32))[tok]))
                     st.landed(ticket, lanes=1)
                 req.tokens_out.append(tok)
@@ -2205,7 +2243,7 @@ class ContinuousBatcher:
             return
         if not self.kv_offload.store.put(
                 ("fablog", digest),
-                np.asarray(last_logits, np.float32).reshape(-1)):
+                self._fetch(last_logits).astype(np.float32).reshape(-1)):
             self.kv_offload.discard(handle)
             return
         self.kv_publishes += 1
@@ -2429,19 +2467,23 @@ class ContinuousBatcher:
                                            (sp.seed >> 32) & 0xFFFFFFFF)
                         else:
                             host_lanes.append(lane)
+                buf = pack_words(self._fields["round"], dict(
+                    tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+                    temps=temps, seeds=seeds,
+                    rows=np.stack([toks, row_lane, row_off])))
             if decode_parts:
                 # decode lanes advance one tick this round — same fault site
                 chaos.trip("engine.step")
             t0 = _time.perf_counter()
             with part(st, "dispatch.put"):
-                args = (jnp.asarray(tables), jnp.asarray(toks),
-                        jnp.asarray(row_lane), jnp.asarray(row_off),
-                        jnp.asarray(q_lens), jnp.asarray(kv_lens),
-                        jnp.asarray(temps), jnp.asarray(seeds))
+                packed = self._put(buf)
             with part(st, "dispatch.call"):
-                (nt_dev, lp_dev, last_dev, self._kv_state,
-                 *moe) = self._mixed(self.params, self._kv_state, *args)
-            ticket = st.launched()
+                out, last_dev, self._kv_state = self._mixed(
+                    self.params, self._kv_state, packed)
+                # the program is on the device's queue: the turn ends here,
+                # the results' copy to the host starts behind it
+                ticket = st.launched()
+                out.copy_to_host_async()
             st.note(program="paged_mixed_step", k=1, lanes=len(lane_reqs),
                     rows=len(toks),
                     ahead=int(self._pending_block is not None))
@@ -2452,15 +2494,16 @@ class ContinuousBatcher:
             self.mixed_attn_rows += ((len(toks) - b) * len(segs)
                                      + len(decode_parts))
         with stage(st, "fetch"):
-            next_tokens = np.asarray(nt_dev, np.int32).copy()
-            logprobs_arr = np.asarray(lp_dev, np.float32).copy()
+            res = self._results(out)
+            next_tokens = res["tokens"].copy()
+            logprobs_arr = res["logprobs"].copy()
             self.decode_host_syncs += 1
-            self._note_moe(moe, decode=False)
+            self._note_moe(res.get("moe"), decode=False)
             if host_lanes:
                 # fetch ONLY the host-sampled rows (same shape discipline —
                 # and PRNG rule — as _tick_single)
-                rows = np.asarray(
-                    last_dev[jnp.asarray(np.asarray(host_lanes, np.int32))])
+                rows = self._fetch(
+                    last_dev[self._put(np.asarray(host_lanes, np.int32))])
                 self.decode_host_syncs += 1
                 for i, lane in enumerate(host_lanes):
                     req = lane_reqs[lane]
@@ -2604,15 +2647,15 @@ class ContinuousBatcher:
             self.first_decode_waits += 1
 
     def _note_moe(self, moe, decode: bool) -> None:
-        """Add a dispatch's expert counters (``[(n_moe, E + 2)]`` from the
-        step program, or ``[]`` for a model without expert layers) to the
-        totals.  Called where the dispatch's tokens were just fetched: the
-        array is ready with them, so this is no further wait.  Mixed
-        rounds count assignments only; decode dispatches also the steps
-        that had a live lane and the experts those steps hit."""
-        if not moe:
+        """Add a dispatch's expert counters (``(n_moe, E + 2)`` out of the
+        step program's results, or None for a model without expert
+        layers) to the totals: they came with the dispatch's tokens, in
+        the same fetch.  Mixed rounds count assignments only; decode
+        dispatches also the steps that had a live lane and the experts
+        those steps hit."""
+        if moe is None:
             return
-        stats = np.asarray(moe[0], np.int64)
+        stats = moe.astype(np.int64)
         n = self._moe_assignments.shape[1]
         self._moe_assignments += stats[:, :n]
         if decode:
@@ -2635,8 +2678,8 @@ class ContinuousBatcher:
         if fn is None:
             rep, kvsh = self._rep, self.pool.kv_sharding
             fn = self._jit(partial(paged_decode_block, k=k, **self._step_kw),
-                           (1,), (self._param_sh, kvsh) + (rep,) * 8,
-                           (rep,) * 7 + (kvsh,))
+                           (1,), (self._param_sh, kvsh, rep, rep),
+                           (rep,) * 5 + (kvsh,))
             self._block_cache[k] = fn
             self._block_names[k] = f"paged_decode_block_k{k}"
         return fn
@@ -2944,11 +2987,15 @@ class ContinuousBatcher:
         inside the caller's ``dispatch`` stage.
 
         ``carry``/``host`` chain a follow-up block from a previous one's
-        device-resident final state (dispatch-ahead overlap) — the block
+        device-resident final state (dispatch-ahead overlap): the block
         table is rebuilt host-side either way (new pages may have been
-        reserved), but lengths/tokens/live/steps-remaining stay on device
-        so chaining costs no round trip.  ``ahead`` (the predecessor's K
-        where it is not fetched yet) only goes onto the stage's span.
+        reserved) and travels with ``host``'s sampling and stop arrays in
+        the block's ONE buffer, whose ``fresh`` flags are off, so
+        lengths/tokens/live/steps-remaining are the carry's and chaining
+        costs no round trip.  A chain's first block sends all of it, every
+        lane ``fresh``, beside a carry nobody reads: the same program.
+        ``ahead`` (the predecessor's K where it is not fetched yet) only
+        goes onto the stage's span.
         """
         clock = self._stages
         with part(clock, "dispatch.arrays"):
@@ -2958,13 +3005,13 @@ class ContinuousBatcher:
             for lane, req, _new in parts:
                 lane_reqs[lane] = req
                 tables[lane, :len(req.pages)] = req.pages
+            lengths = np.zeros((b,), np.int32)
+            tokens = np.zeros((b,), np.int32)
+            active = np.zeros((b,), bool)
+            rem = np.zeros((b,), np.int32)
             if host is None:
-                lengths = np.zeros((b,), np.int32)
-                tokens = np.zeros((b,), np.int32)
-                active = np.zeros((b,), bool)
                 temps = np.zeros((b,), np.float32)
                 seeds = np.zeros((b, 2), np.uint32)   # (lo, hi) words
-                rem = np.zeros((b,), np.int32)
                 n_stop = max((len(r.stop_tokens) for _, r, _ in parts),
                              default=0)
                 width = ((1 << (n_stop - 1).bit_length()) if n_stop > 1
@@ -2985,7 +3032,10 @@ class ContinuousBatcher:
                         stops[lane, :len(st)] = st
             else:
                 temps, seeds, stops = host
-                lengths, tokens, active, rem = carry
+            buf = pack_words(self._fields["block"], dict(
+                tables=tables, lengths=lengths, tokens=tokens, active=active,
+                temps=temps, seeds=seeds, rem=rem, stops=stops,
+                fresh=np.full((b,), host is None)))
         # chaos: decode fault site — tripped once per DECODE TICK (k times
         # per block), so a deterministic schedule written against
         # per-token serving (error@N, per-tick delays) keeps its meaning
@@ -2995,22 +3045,21 @@ class ContinuousBatcher:
             chaos.trip("engine.step")
         t0 = _time.perf_counter()
         with part(clock, "dispatch.put"):
-            args = (jnp.asarray(tables), jnp.asarray(lengths),
-                    jnp.asarray(tokens), jnp.asarray(active),
-                    jnp.asarray(temps), jnp.asarray(seeds),
-                    jnp.asarray(rem), jnp.asarray(stops))
+            packed = self._put(buf)
         with part(clock, "dispatch.call"):
-            (toks, lps, ems, len_f, tok_f, live_f, rem_f,
-             self._kv_state, *moe) = self._block_fn(k)(
-                self.params, self._kv_state, *args)
-        ticket = clock.launched()
+            (out, len_f, tok_f, live_f, rem_f,
+             self._kv_state) = self._block_fn(k)(
+                self.params, self._kv_state, packed,
+                carry or self._no_carry)
+            ticket = clock.launched()
+            out.copy_to_host_async()
         clock.note(program=self._block_names[k], k=k, lanes=len(lane_reqs),
                    rows=len(lane_reqs), ahead=int(ahead > 0))
         self.decode_dispatches += 1
         self.decode_block_steps += k
         self._note_dispatch("decode")
-        return {"k": k, "lane_reqs": lane_reqs, "dev": (toks, lps, ems),
-                "moe": moe, "carry": (len_f, tok_f, live_f, rem_f),
+        return {"k": k, "lane_reqs": lane_reqs, "out": out,
+                "carry": (len_f, tok_f, live_f, rem_f),
                 "host": (temps, seeds, stops), "t0": t0, "ticket": ticket}
 
     def _chain_block(self, stash, jnp, ahead: int):
@@ -3107,10 +3156,9 @@ class ContinuousBatcher:
         else:
             self.chain_breaks[why] += 1
         with stage(st, "fetch"):
-            toks = np.asarray(stash["dev"][0], np.int32)
-            lps = np.asarray(stash["dev"][1], np.float32)
-            ems = np.asarray(stash["dev"][2], bool)
-            self._note_moe(stash["moe"], decode=True)
+            res = self._results(stash["out"], k)
+            toks, lps, ems = res["tokens"], res["logprobs"], res["emitted"]
+            self._note_moe(res.get("moe"), decode=True)
             st.landed(stash["ticket"], why, lanes=len(stash["lane_reqs"]))
         self.decode_host_syncs += 1
         now = _time.perf_counter()  # post-fetch: device work is done
@@ -3182,9 +3230,8 @@ class ContinuousBatcher:
             fn = self._jit(partial(paged_speculative_block, k=k,
                                    **self._spec_kw),
                            (2,),
-                           (self._param_sh, self._draft_param_sh, kvsh)
-                           + (rep,) * 9,
-                           (rep,) * 9 + (kvsh,))
+                           (self._param_sh, self._draft_param_sh, kvsh, rep),
+                           (rep,) * 5 + (kvsh,))
             self._spec_block_cache[k] = fn
         return fn
 
@@ -3209,8 +3256,9 @@ class ContinuousBatcher:
         tables = np.zeros((self.max_pages,), np.int32)
         tables[:len(req.draft_pages)] = req.draft_pages
         _last, self.pool.kv = self._draft_extend(
-            self._spec["params"], self.pool.kv, jnp.asarray(tables),
-            jnp.asarray(tokens), jnp.int32(start), jnp.int32(t))
+            self._spec["params"], self.pool.kv, self._put(tables),
+            self._put(tokens), self._put(np.int32(start)),
+            self._put(np.int32(t)))
         req.draft_len = t
         self.spec_draft_prefills += 1
 
@@ -3266,19 +3314,19 @@ class ContinuousBatcher:
                 st = sorted(req.stop_tokens)
                 stops[lane, :len(st)] = st
         t0 = _time.perf_counter()
-        (toks, lps, ems, _len_f, _tok_f, _live_f, _rem_f, drafted,
-         accepted, self.pool.kv) = self._spec_block_fn(k)(
+        (out, _len_f, _tok_f, _live_f, _rem_f,
+         self.pool.kv) = self._spec_block_fn(k)(
             self.params, self._spec["params"], self.pool.kv,
-            jnp.asarray(tables), jnp.asarray(dtables),
-            jnp.asarray(lengths), jnp.asarray(tokens), jnp.asarray(active),
-            jnp.asarray(temps), jnp.asarray(seeds), jnp.asarray(rem),
-            jnp.asarray(stops))
+            self._put(pack_words(self._fields["spec"], dict(
+                tables=tables, draft_tables=dtables, lengths=lengths,
+                tokens=tokens, active=active, temps=temps, seeds=seeds,
+                rem=rem, stops=stops))))
         ticket = self._stages.launched()
+        out.copy_to_host_async()
         self.decode_dispatches += 1
         self.spec_dispatches += 1
         self._note_dispatch("verify")
-        return {"k": k, "lane_reqs": lane_reqs,
-                "dev": (toks, lps, ems, drafted, accepted), "t0": t0,
+        return {"k": k, "lane_reqs": lane_reqs, "out": out, "t0": t0,
                 "ticket": ticket}
 
     def _consume_spec_block(self, stash, jnp) -> bool:
@@ -3291,11 +3339,9 @@ class ContinuousBatcher:
         st = self._stages
         k = stash["k"]
         with stage(st, "fetch"):
-            toks = np.asarray(stash["dev"][0], np.int32)
-            lps = np.asarray(stash["dev"][1], np.float32)
-            ems = np.asarray(stash["dev"][2], bool)
-            drafted = np.asarray(stash["dev"][3], np.int32)
-            accepted = np.asarray(stash["dev"][4], np.int32)
+            res = self._results(stash["out"], k + 1, spec=True)
+            toks, lps, ems = res["tokens"], res["logprobs"], res["emitted"]
+            drafted, accepted = res["drafted"], res["accepted"]
             st.landed(stash["ticket"], lanes=len(stash["lane_reqs"]))
         self.decode_host_syncs += 1
         now = _time.perf_counter()
@@ -3373,7 +3419,6 @@ class ContinuousBatcher:
             temps = np.zeros((b,), np.float32)
             seeds = np.zeros((b, 2), np.uint32)   # (lo, hi) words
             host_lanes = []
-            want_logp = False
             lane_reqs = {}
             for lane, req, _new in parts:
                 lane_reqs[lane] = req
@@ -3381,7 +3426,6 @@ class ContinuousBatcher:
                 tables[lane, :len(req.pages)] = req.pages
                 lengths[lane] = req.length
                 active[lane] = True
-                want_logp |= req.want_logprobs
                 sp = req.sampling
                 if sp.temperature > 0.0:
                     if sp.device:
@@ -3395,35 +3439,24 @@ class ContinuousBatcher:
             # delay makes every lane's step slow (deadline-storm scenarios)
             chaos.trip("engine.step")
             t0 = _time.perf_counter()
-            logprobs_arr = logp_dev = None
-            if temps.any() or want_logp:
-                (tok_dev, logp_dev, logits, self._kv_state,
-                 *moe) = self._step_sampled(
-                    self.params, self._kv_state,
-                    jnp.asarray(tables), jnp.asarray(lengths),
-                    jnp.asarray(tokens), jnp.asarray(active),
-                    jnp.asarray(temps), jnp.asarray(seeds))
-            else:
-                # neither device sampling nor logprobs this tick: the plain
-                # step (no temps/seeds traced) — greedy stays one device
-                # argmax
-                logits, self._kv_state, *moe = self._step(
-                    self.params, self._kv_state,
-                    jnp.asarray(tables), jnp.asarray(lengths),
-                    jnp.asarray(tokens), jnp.asarray(active))
-                tok_dev = logits.argmax(-1)
+            out, logits, self._kv_state = self._step_sampled(
+                self.params, self._kv_state,
+                self._put(pack_words(self._fields["tick"], dict(
+                    tables=tables, lengths=lengths, tokens=tokens,
+                    active=active, temps=temps, seeds=seeds))))
             ticket = st.launched()
+            out.copy_to_host_async()
             self.decode_dispatches += 1
             self.decode_block_steps += 1
             self._note_dispatch("decode")
         with stage(st, "fetch"):
             # greedy + device-sampled lanes: ONLY (B,)-sized arrays cross the
-            # link (token ids + chosen-token logprobs)
-            next_tokens = np.asarray(tok_dev, np.int32).copy()
-            if logp_dev is not None:
-                logprobs_arr = np.asarray(logp_dev, np.float32).copy()
+            # link (token ids + chosen-token logprobs), as one
+            res = self._results(out)
+            next_tokens = res["tokens"].copy()
+            logprobs_arr = res["logprobs"].copy()
             self.decode_host_syncs += 1
-            self._note_moe(moe, decode=True)
+            self._note_moe(res.get("moe"), decode=True)
             if host_lanes:
                 # fetch ONLY the host-sampled rows: gather them device-side,
                 # then one (n_host, vocab) transfer — not the full
@@ -3431,12 +3464,13 @@ class ContinuousBatcher:
                 # Only active host-sampled lanes consume PRNG state: a
                 # page-starved or pending-prefill lane must not perturb a
                 # seeded request's token sequence (per-request reproducibility)
-                rows = np.asarray(
-                    logits[jnp.asarray(np.asarray(host_lanes, np.int32))])
+                rows = self._fetch(
+                    logits[self._put(np.asarray(host_lanes, np.int32))])
                 self.decode_host_syncs += 1
                 for i, lane in enumerate(host_lanes):
-                    next_tokens[lane] = lane_reqs[lane].sampling.pick(rows[i])
-                    if logprobs_arr is not None:
+                    req = lane_reqs[lane]
+                    next_tokens[lane] = req.sampling.pick(rows[i])
+                    if req.want_logprobs:
                         # f32 log-sum-exp: the same precision class as the
                         # device log_softmax used for prefill and for
                         # device-sampled lanes — one request, one precision
@@ -3469,8 +3503,7 @@ class ContinuousBatcher:
                                (now - req.t_last)
                                if req.t_last is not None else None)
                 req.t_last = now
-                lp = (float(logprobs_arr[lane])
-                      if logprobs_arr is not None else None)
+                lp = float(logprobs_arr[lane]) if req.want_logprobs else None
                 if req.want_logprobs:
                     req.logprobs_out.append(lp)
                 emits.append((req, req.tokens_out[-1],

@@ -16,7 +16,12 @@ TPU-first mechanics:
 - decode runs K ticks per dispatch (:func:`paged_decode_block`: lax.scan over
   the step, on-device sampling + stop masks), so the host pays one dispatch
   and ONE blocking fetch per K tokens — off-chip the per-token cost is the
-  host<->device RTT, and K amortizes it (docs/PERFORMANCE.md).
+  host<->device RTT, and K amortizes it (docs/PERFORMANCE.md);
+- a dispatch crosses the host-device boundary once each way: what the host
+  knows goes in as ONE int32 buffer and the small results come back as ONE
+  (:func:`pack_words` / :func:`unpack_words`, the fields a program in
+  :func:`dispatch_fields` and :func:`result_fields`); the page store, a
+  block's carry and a round's logits stay on the device.
 
 Every forward runs ONE layer block (:func:`_layer_block`) read from a
 :class:`~tpulab.models.spec.ModelSpec`.  For a model with Mamba layers the
@@ -582,6 +587,131 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     return x, (kv_pool if state is None else (kv_pool, state)), stats
 
 
+# -- one buffer each way ------------------------------------------------------
+# A transfer costs the same 0.5-0.7 ms whatever it carries (PERF.md, PR 36),
+# so a dispatch makes one in and one out.  A buffer is int32 words: float32
+# and uint32 travel as their bits, bool as 0/1, so every value a program
+# computes with is the value the host wrote.  ``fields`` is the static
+# description both sides build from the same numbers: ``(name, dtype,
+# shape)`` in the buffer's order; the last field's shape may hold one -1,
+# which takes what is left of the buffer (the one width a program is keyed
+# by: a block's stop ids a lane, a round's rows).
+_I32, _F32, _U32, _BOOL = (np.dtype(t) for t in (np.int32, np.float32,
+                                                 np.uint32, np.bool_))
+
+
+def _bitcast(x, dtype):
+    """``x`` as ``dtype`` of the same width, bit for bit: a view of a numpy
+    array, ``bitcast_convert_type`` of a jax array."""
+    if x.dtype == dtype:
+        return x
+    if isinstance(x, np.ndarray):
+        return x.view(dtype)
+    import jax
+    return jax.lax.bitcast_convert_type(x, dtype)
+
+
+def pack_words(fields, arrays: Dict[str, Any]):
+    """``arrays`` (by field name; numpy arrays on the host, jax arrays in a
+    program) as ONE 1-D int32 buffer, each field's words one after the
+    other.  Every array must have its field's dtype and shape."""
+    if set(arrays) != {name for name, _, _ in fields}:
+        raise ValueError(f"fields {[f[0] for f in fields]} != arrays "
+                         f"{sorted(arrays)}")
+    parts = []
+    for name, dtype, shape in fields:
+        a = arrays[name]
+        if a.dtype != dtype or len(shape) != a.ndim or any(
+                s not in (-1, n) for s, n in zip(shape, a.shape)):
+            raise ValueError(f"field {name}: {a.dtype}{tuple(a.shape)} is "
+                             f"not {dtype}{tuple(shape)}")
+        a = a.astype(np.int32) if dtype == _BOOL else _bitcast(a, np.int32)
+        parts.append(a.reshape(-1))
+    if all(isinstance(a, np.ndarray) for a in parts):
+        return np.concatenate(parts)
+    import jax.numpy as jnp
+    return jnp.concatenate(parts)
+
+
+def unpack_words(fields, buf) -> Dict[str, Any]:
+    """The inverse of :func:`pack_words`: ``{name: array}`` out of a 1-D
+    int32 buffer, by static slices (numpy in, numpy out; in a program,
+    jax arrays)."""
+    sizes = [int(np.prod([n for n in shape if n >= 0]))
+             for _name, _dtype, shape in fields]
+    open_end = -1 in fields[-1][2]
+    left = buf.shape[0] - sum(sizes[:-1] if open_end else sizes)
+    if left < 0 or (left % sizes[-1] if open_end else left):
+        raise ValueError(f"a buffer of {buf.shape[0]} words does not fit "
+                         f"fields of {sizes} words")
+    if open_end:
+        sizes[-1] = left
+    out, at = {}, 0
+    for (name, dtype, shape), n in zip(fields, sizes):
+        words = buf[at:at + n]
+        at += n
+        words = words != 0 if dtype == _BOOL else _bitcast(words, dtype)
+        out[name] = words.reshape(shape)
+    return out
+
+
+def dispatch_fields(program: str, lanes: int, max_pages: int):
+    """What the host sends with a dispatch of ``program``: ``"tick"``
+    (:func:`paged_decode_step_sampled`), ``"block"``
+    (:func:`paged_decode_block`; ``fresh`` says, a lane, whether lengths,
+    tokens, active and rem count or the carry's), ``"spec"``
+    (:func:`paged_speculative_block`) or ``"round"``
+    (:func:`paged_mixed_step`; ``rows`` stacks :func:`pack_round`'s
+    ``toks``, ``row_lane``, ``row_off``)."""
+    b, table = (lanes,), ("tables", _I32, (lanes, max_pages))
+    sampling = (("temps", _F32, b), ("seeds", _U32, (lanes, 2)))
+    if program == "round":
+        return (table, ("q_lens", _I32, b), ("kv_lens", _I32, b)) + sampling \
+            + (("rows", _I32, (3, -1)),)
+    fields = (table, ("lengths", _I32, b), ("tokens", _I32, b),
+              ("active", _BOOL, b)) + sampling
+    if program == "tick":
+        return fields
+    extra = {"block": ("fresh", _BOOL, b),
+             "spec": ("draft_tables", _I32, (lanes, max_pages))}[program]
+    return fields + (extra, ("rem", _I32, b), ("stops", _I32, (lanes, -1)))
+
+
+def result_fields(lanes: int, k: Optional[int] = None, moe=None,
+                  spec: bool = False):
+    """What a dispatch brings back: a pick a lane (``k`` None: a tick, a
+    round) or ``k`` picks a lane with their prefix mask (a block; a
+    speculative block also its ``drafted`` and ``accepted`` a lane), and
+    the expert layers' counters where the model has any (``moe``: their
+    shape ``(n_moe, E + 2)``, see :func:`moe_shape`)."""
+    shape = (lanes,) if k is None else (lanes, k)
+    fields = (("tokens", _I32, shape), ("logprobs", _F32, shape))
+    if k is not None:
+        fields += (("emitted", _BOOL, shape),)
+    if spec:
+        fields += (("drafted", _I32, (lanes,)), ("accepted", _I32, (lanes,)))
+    if moe is not None:
+        fields += (("moe", _I32, tuple(moe)),)
+    return fields
+
+
+def moe_shape(spec):
+    """The shape of a dispatch's expert counters, None for a model without
+    expert layers (``spec`` may be None: the dense decoder)."""
+    if spec is None or not spec.moe_layers:
+        return None
+    return (len(spec.moe_layers), spec.n_experts + 2)
+
+
+def _pack_results(lanes, k, moe, spec=False, **arrays):
+    """A program's small results as one array (:func:`result_fields`);
+    ``moe`` is the list a step returns, empty without expert layers."""
+    if moe:
+        arrays["moe"] = moe[0]
+    return pack_words(
+        result_fields(lanes, k, moe[0].shape if moe else None, spec), arrays)
+
+
 def paged_decode_step(params, kv_pool, tables, lengths, tokens,
                       active, n_heads: int, n_layers: int,
                       compute_dtype, use_kernel: bool = False,
@@ -659,18 +789,25 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
     return (next_tokens, logprobs, logits, kv_pool) + moe
 
 
-def paged_decode_step_sampled(params, kv_pool, tables, lengths, tokens,
-                              active, temps, seeds, **kw):
-    """Positional-signature variant of :func:`paged_decode_step` with
-    device sampling armed — sharded jits need every array argument
-    positional so explicit ``in_shardings`` can be attached."""
-    return paged_decode_step(params, kv_pool, tables, lengths, tokens,
-                             active, temps=temps, seeds=seeds, **kw)
+def paged_decode_step_sampled(params, kv_pool, packed, lanes: int,
+                              max_pages: int, **kw):
+    """The scheduler's K=1 tick: :func:`paged_decode_step` with device
+    sampling armed (a greedy lane's pick is the argmax), on ONE packed
+    buffer (:func:`dispatch_fields` ``"tick"``).  Returns ``(results,
+    logits (B, vocab), kv_pool)``: ``results`` is :func:`result_fields`
+    ``(lanes, moe=...)`` packed, ``logits`` stays on the device unless a
+    host-sampled lane fetches its row."""
+    f = unpack_words(dispatch_fields("tick", lanes, max_pages), packed)
+    nt, lp, logits, kv_pool, *moe = paged_decode_step(
+        params, kv_pool, f["tables"], f["lengths"], f["tokens"], f["active"],
+        temps=f["temps"], seeds=f["seeds"], **kw)
+    return (_pack_results(lanes, None, moe, tokens=nt, logprobs=lp), logits,
+            kv_pool)
 
 
-def paged_decode_block(params, kv_pool, tables, lengths, tokens, active,
-                       temps, seeds, steps_rem, stop_ids,
-                       n_heads: int, n_layers: int, compute_dtype,
+def paged_decode_block(params, kv_pool, packed, carry, lanes: int,
+                       max_pages: int, n_heads: int, n_layers: int,
+                       compute_dtype,
                        k: int = 8, use_kernel: bool = False,
                        n_kv_heads: Optional[int] = None,
                        rope_theta: Optional[float] = None,
@@ -700,18 +837,35 @@ def paged_decode_block(params, kv_pool, tables, lengths, tokens, active,
     for live lanes, so ``tables`` must already cover every position the
     block can reach.
 
-    Returns ``(tokens (B, K) i32, logprobs (B, K) f32, emitted (B, K)
-    bool, lengths (B,), last_tokens (B,), live (B,), steps_rem (B,),
-    kv_pool)`` — and, for a ``spec`` with expert layers, their counters
-    ``(n_moe, E + 2)`` summed over the K steps as one more element;
-    ``lengths`` .. ``steps_rem`` and the pool are the carried state
-    *after* the block, returned as device arrays so a follow-up block can be
-    dispatched without a host round trip (dispatch-ahead overlap).
-    ``emitted[b]`` is a prefix mask: lane b's valid tokens are
-    ``tokens[b, :emitted[b].sum()]``.
+    What the host knows arrives as ONE buffer, ``packed``
+    (:func:`dispatch_fields` ``"block"``: ``tables``, ``lengths``,
+    ``tokens``, ``active``, ``temps``, ``seeds``, ``fresh``, ``rem``,
+    ``stops``).  ``carry = (lengths, tokens, live, steps_rem)`` is what the
+    block before this one returned: a lane takes its state from there
+    unless the buffer says ``fresh`` (a chain's first block is fresh in
+    every lane and passes any carry of the right shapes), so both are the
+    same compiled program.
+
+    Returns ``(results, lengths (B,), last_tokens (B,), live (B,),
+    steps_rem (B,), kv_pool)``.  ``results`` is ONE int32 array,
+    :func:`result_fields` ``(lanes, k, moe)``: ``tokens (B, K)``,
+    ``logprobs (B, K)`` as their bits, ``emitted (B, K)`` and, for a
+    ``spec`` with expert layers, their counters ``(n_moe, E + 2)`` summed
+    over the K steps; ``lengths`` .. ``steps_rem`` and the pool are the
+    carried state *after* the block, returned as device arrays so a
+    follow-up block can be dispatched without a host round trip
+    (dispatch-ahead overlap).  ``emitted[b]`` is a prefix mask: lane b's
+    valid tokens are ``tokens[b, :emitted[b].sum()]``.
     """
     import jax
     import jax.numpy as jnp
+
+    f = unpack_words(dispatch_fields("block", lanes, max_pages), packed)
+    tables, temps, seeds, stop_ids = (f["tables"], f["temps"], f["seeds"],
+                                      f["stops"])
+    lengths, tokens, active, steps_rem = (
+        jnp.where(f["fresh"], f[name], kept) for name, kept in zip(
+            ("lengths", "tokens", "active", "rem"), carry))
 
     def body(carry, _):
         kv, lens, toks, live, rem = carry
@@ -734,8 +888,9 @@ def paged_decode_block(params, kv_pool, tables, lengths, tokens, active,
     (kv_pool, lengths, tokens, live, steps_rem), (toks, lps, ems, *moe) = \
         jax.lax.scan(body, init, None, length=k)
     # an expert model's counters, summed over the block's steps
-    return (toks.T, lps.T, ems.T, lengths, tokens, live, steps_rem,
-            kv_pool) + tuple(m.sum(axis=0) for m in moe)
+    results = _pack_results(lanes, k, [m.sum(axis=0) for m in moe],
+                            tokens=toks.T, logprobs=lps.T, emitted=ems.T)
+    return results, lengths, tokens, live, steps_rem, kv_pool
 
 
 def _device_sample_token(row, temp, seed2, pos):
@@ -868,9 +1023,9 @@ def pack_round(lanes: int, prefill: Dict[int, Any], decode: Dict[int, int]):
     return toks, row_lane, row_off, q_lens
 
 
-def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
-                     q_lens, kv_lens, temps, seeds, n_heads: int,
-                     n_layers: int, compute_dtype, use_kernel: bool = False,
+def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
+                     n_heads: int, n_layers: int, compute_dtype,
+                     use_kernel: bool = False,
                      n_kv_heads: Optional[int] = None,
                      rope_theta: Optional[float] = None,
                      mesh=None,
@@ -879,6 +1034,9 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
     over per-lane segments plus each lane's next-token pick, in ONE
     dispatch whose rows are the round's tokens.
 
+    The round arrives as ONE buffer, ``packed`` (:func:`dispatch_fields`
+    ``"round"``: ``tables``, ``q_lens``, ``kv_lens``, ``temps``, ``seeds``
+    and ``rows``, the stack of ``toks``, ``row_lane``, ``row_off``).
     Prefilling lanes carry a prompt chunk (``q_lens = chunk``), decoding
     lanes their current token (``q_lens = 1``), idle lanes nothing
     (``q_lens = 0``).  ``toks (T,)`` holds the round with ``T = M +
@@ -904,12 +1062,13 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
     this round (a mid-prompt chunk's pick is discarded; device sampling
     is stateless, so a discarded pick costs nothing).
 
-    Returns ``(next_tokens (B,) i32, logprobs (B,) f32, last_logits
-    (B, vocab), kv_pool)`` — ``last_logits`` stays device-resident
-    unless a host-sampled lane fetches its row — and the expert layers'
-    counters behind the pool where ``spec`` has any.  The same segments
-    through ``paged_ragged_forward(last_only=True)`` give the same
-    logits: that is the plain form this one is tested against.
+    Returns ``(results, last_logits (B, vocab), kv_pool)``: ``results`` is
+    ONE int32 array, :func:`result_fields` ``(lanes, moe=...)``:
+    ``tokens (B,)``, ``logprobs (B,)`` as their bits and the expert
+    layers' counters where ``spec`` has any; ``last_logits`` stays
+    device-resident unless a host-sampled lane fetches its row.  The same
+    segments through ``paged_ragged_forward(last_only=True)`` give the
+    same logits: that is the plain form this one is tested against.
 
     For a ``spec`` with Mamba layers ``kv_pool`` is the pair ``(page store,
     lane state)``, in and out: each lane's segment runs the convolution and
@@ -921,7 +1080,10 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
     import jax.numpy as jnp
     from tpulab.models.transformer import _lm_head, _rmsnorm
 
-    b, t = tables.shape[0], toks.shape[0]
+    f = unpack_words(dispatch_fields("round", lanes, max_pages), packed)
+    tables, q_lens, kv_lens = f["tables"], f["q_lens"], f["kv_lens"]
+    toks, row_lane, row_off = f["rows"]
+    b, t = lanes, toks.shape[0]
     m = t - b
     page_size = _pages(kv_pool).shape[3]
     emb = params["embed"].astype(compute_dtype)
@@ -966,16 +1128,16 @@ def paged_mixed_step(params, kv_pool, tables, toks, row_lane, row_off,
                                      spec.rms_eps))
     pos_last = jnp.maximum(kv_lens - 1, 0)
     next_tokens = jax.vmap(_device_sample_token)(
-        last, temps, seeds.astype(jnp.uint32), pos_last)
+        last, f["temps"], f["seeds"], pos_last)
     logp_rows = jax.nn.log_softmax(last.astype(jnp.float32), axis=-1)
     logprobs = jnp.take_along_axis(logp_rows, next_tokens[:, None],
                                    axis=-1)[:, 0]
-    return (next_tokens, logprobs, last, kv_pool, *moe)
+    return (_pack_results(lanes, None, moe, tokens=next_tokens,
+                          logprobs=logprobs), last, kv_pool)
 
 
-def paged_speculative_block(params, draft_params, kv_pool, tables,
-                            draft_tables, lengths, tokens, active, temps,
-                            seeds, steps_rem, stop_ids,
+def paged_speculative_block(params, draft_params, kv_pool, packed,
+                            lanes: int, max_pages: int,
                             n_heads: int, n_layers: int,
                             draft_n_heads: int, draft_n_layers: int,
                             compute_dtype, k: int = 4,
@@ -1023,15 +1185,24 @@ def paged_speculative_block(params, draft_params, kv_pool, tables,
     forward at q=k+1 — the PR 7 follow-up retired); the XLA gather is
     the fallback, and under a ``mesh`` the kernel shards on KV heads.
 
-    Returns ``(tokens (B, k+1) i32, logprobs (B, k+1) f32, emitted
-    (B, k+1) bool prefix mask, lengths (B,), last_tokens (B,), live
-    (B,), steps_rem (B,), drafted (B,) i32, accepted (B,) i32,
-    kv_pool)``.
+    The host's side arrives as ONE buffer, ``packed``
+    (:func:`dispatch_fields` ``"spec"``: a block's fields with the
+    ``draft_tables`` in place of ``fresh``).  Returns ``(results, lengths
+    (B,), last_tokens (B,), live (B,), steps_rem (B,), kv_pool)``,
+    ``results`` ONE int32 array (:func:`result_fields` ``(lanes, k + 1,
+    spec=True)``): ``tokens (B, k+1)``, ``logprobs (B, k+1)`` as their
+    bits, the ``emitted (B, k+1)`` prefix mask, ``drafted (B,)`` and
+    ``accepted (B,)``.
     """
     import jax
     import jax.numpy as jnp
 
-    seeds = seeds.astype(jnp.uint32)
+    f = unpack_words(dispatch_fields("spec", lanes, max_pages), packed)
+    tables, draft_tables, lengths, tokens, active = (
+        f["tables"], f["draft_tables"], f["lengths"], f["tokens"],
+        f["active"])
+    temps, seeds, steps_rem, stop_ids = (f["temps"], f["seeds"], f["rem"],
+                                         f["stops"])
 
     # 1) draft proposes k tokens per lane through the second page table;
     #    iterations past a lane's step budget write only scratch (their
@@ -1095,8 +1266,10 @@ def paged_speculative_block(params, draft_params, kv_pool, tables,
     live = active & (steps_rem > 0) & ~stopped
     drafted = jnp.where(active, k, 0)
     accepted = jnp.where(active, jnp.minimum(acc, n), 0)
-    return (cand.astype(jnp.int32), lps, emitted, lengths, tokens, live,
-            steps_rem, drafted, accepted, kv_pool)
+    results = _pack_results(
+        lanes, k + 1, [], spec=True, tokens=cand.astype(jnp.int32),
+        logprobs=lps, emitted=emitted, drafted=drafted, accepted=accepted)
+    return results, lengths, tokens, live, steps_rem, kv_pool
 
 
 def paged_prefill(params, kv_pool, tables, tokens, valid_len,
