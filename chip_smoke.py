@@ -36,6 +36,13 @@ Phases, in order; any phase that raises fails the run (exit 1):
               round and a decode step through the score, sparse-attention
               and decode kernels against the XLA forms, logits and index
               rows.
+   qwen3_next — a two-layer Gated DeltaNet / gated-attention hybrid
+              (Qwen3-Next-80B-A3B's widths: a 128 x 128 float32 state a head
+              a lane, 16/2 heads of 256 with RoPE over 64, a share of 32 of
+              128 softmax-routed experts at top-10 and a gated shared expert)
+              on a lane-state store filled with junk: three mixed rounds
+              and a decode step through the ``chunk_gated_delta_rule`` and
+              ragged kernels against the XLA forms, logits and lane state.
 4. kernels  — the ragged and flash Pallas kernels compiled by Mosaic
               (``interpret=False``, custom call present in the lowered
               program) against the XLA gather / dense-softmax paths.
@@ -128,6 +135,20 @@ class Sizes:
         sa_config=dict(indexer_head_dim=64, indexer_num_heads=16,
                        indexer_num_kv_heads=1, topk=2048),
         vocab_size=50304))
+    # Qwen3-Next-80B-A3B's published widths
+    # (perf/configs/qwen3next-l8-ep4.json); depth (one period of a shorter
+    # pattern), the router's width and the vocabulary are the cuts; this
+    # model holds experts 32 .. 64 of its router's 128
+    qwen3_next: dict = field(default_factory=lambda: dict(
+        hidden_size=2048, num_attention_heads=16, num_key_value_heads=2,
+        head_dim=256, partial_rotary_factor=0.25, num_hidden_layers=2,
+        full_attention_interval=2, linear_num_key_heads=16,
+        linear_num_value_heads=32, linear_key_head_dim=128,
+        linear_value_head_dim=128, linear_conv_kernel_dim=4, num_experts=128,
+        num_experts_per_tok=10, moe_intermediate_size=512,
+        shared_expert_intermediate_size=512, norm_topk_prob=True,
+        rms_norm_eps=1e-6, rope_theta=1e7, vocab_size=50304))
+    qwen3_next_share: tuple = (32, 32)     # first, held
     lm_max_len: int = 512
     lm_page_size: int = 16
     lm_prefill_chunk: int = 128
@@ -162,6 +183,16 @@ REHEARSAL_SIZES = Sizes(
               sa_config=dict(indexer_head_dim=16, indexer_num_heads=4,
                              indexer_num_kv_heads=1, topk=24),
               vocab_size=256),
+    qwen3_next=dict(
+        hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=32, partial_rotary_factor=0.25, num_hidden_layers=2,
+        full_attention_interval=2, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16,
+        linear_value_head_dim=16, linear_conv_kernel_dim=4, num_experts=16,
+        num_experts_per_tok=4, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, norm_topk_prob=True,
+        rms_norm_eps=1e-6, rope_theta=1e4, vocab_size=256),
+    qwen3_next_share=(4, 4),
     lm_max_len=96, lm_page_size=8, lm_prefill_chunk=16,
     lm_prompt_lens=(5, 12, 40), lm_steps=6, flash_t=32)
 
@@ -654,6 +685,107 @@ def phase_keye(smoke: Smoke) -> str:
             f"{int(counts['xla'].sum())} decode assignments differ")
 
 
+# -- phase 3e: Gated DeltaNet layers on a matrix-valued lane state -------------
+def phase_qwen3_next(smoke: Smoke) -> str:
+    """Three mixed rounds and a decode step of a Gated DeltaNet / gated
+    attention model that holds a share of its experts, over a lane-state
+    store filled with junk (a reused lane): the ``chunk_gated_delta_rule``
+    and ragged kernels against the ``lax.scan`` form and the XLA gather on
+    the same inputs, logits and lane state.  As in phase ``keye_vl2`` a
+    router's near tie may fall either way under the two forms (the
+    attention layer's rows differ by bf16 rounding), so the decode step's
+    expert counters say how many assignments differ, and only so many lanes
+    may pass ``LOGIT_RTOL``."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.kv_pool import PagedKVPool, lane_state_shapes
+    from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
+                                           paged_mixed_step)
+    from tpulab.models.spec import init_params, qwen3_next_spec
+    sz = smoke.sizes
+    cfg, chunk, page = sz.qwen3_next, sz.glm_chunk, sz.lm_page_size
+    first, held = sz.qwen3_next_share
+    spec = qwen3_next_spec(cfg, first=first, held=held)
+    vocab = cfg["vocab_size"]
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                    init_params(spec, vocab, 0))
+    lanes, mp = 4, 3 * chunk // page
+    rng = np.random.default_rng(4)
+    tables = 1 + np.arange(lanes * mp, dtype=np.int32).reshape(lanes, mp)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    kw = dict(n_heads=spec.n_heads, n_layers=spec.n_layers,
+              compute_dtype=jnp.bfloat16, spec=spec)
+    draw = lambda n: rng.integers(0, vocab, n)
+    # the rounds of phase ``jamba``: two that fill three lanes, then lane 0
+    # its second chunk (four passes of the chunk kernel from the slot), lanes
+    # 1 and 2 decode, lane 3 a first chunk of 7 (a pass that shares lane 0's
+    # last chunk of rows) into a slot that holds junk; then a decode step
+    half = chunk // 2
+    rounds = [({0: draw(chunk)}, {}, [0, 0, 0, 0]),
+              ({1: draw(half - 3), 2: draw(half)}, {}, [chunk, 0, 0, 0]),
+              ({0: draw(chunk - 8), 3: draw(7)},
+               {1: int(draw(1)[0]), 2: int(draw(1)[0])},
+               [chunk, half - 3, half, 0])]
+    final = [2 * chunk - 8, half - 2, half + 1, 7]
+    out, counts = {}, {}
+    for name, uk in (("xla", False), ("kernel", True)):
+        pool = PagedKVPool(lanes * mp + 1, page, len(spec.attention_layers),
+                           spec.n_kv_heads, spec.head_dim, jnp.bfloat16)
+        store = (pool.kv, tuple(jnp.full(shape, 3, dtype) for shape, dtype
+                                in lane_state_shapes(spec, lanes,
+                                                     jnp.bfloat16)))
+        mixed = jax.jit(partial(paged_mixed_step, use_kernel=uk, lanes=lanes,
+                                max_pages=mp, **kw), donate_argnums=(1,))
+        step = jax.jit(partial(paged_decode_step, use_kernel=uk, **kw),
+                       donate_argnums=(1,))
+        for prefill, decode, lengths in rounds:
+            toks, row_lane, row_off, q_lens = pack_round(lanes, prefill,
+                                                         decode)
+            packed = round_buffer(tables, toks, row_lane, row_off, q_lens,
+                                  np.asarray(lengths) + q_lens)
+            if uk and decode:
+                check_mosaic(smoke, "qwen3_next mixed round", partial(
+                    paged_mixed_step, use_kernel=True, lanes=lanes,
+                    max_pages=mp, **kw), params, store, packed)
+            _, last, store = mixed(params, store, packed)
+        logits, store, experts = step(params, store, i32(tables), i32(final),
+                                      i32([5, 6, 7, 8]),
+                                      jnp.ones((lanes,), bool))
+        out[name] = (np.asarray(last, np.float32),
+                     np.asarray(logits, np.float32),
+                     np.asarray(store[1][0]).reshape(lanes, -1))
+        counts[name] = np.asarray(experts)[:, :-2]
+    flips = int(np.abs(counts["kernel"] - counts["xla"]).sum()) // 2
+    if flips > KEYE_FLIPS:
+        raise AssertionError(f"qwen3_next decode step: {flips} of "
+                             f"{int(counts['xla'].sum())} expert "
+                             f"assignments differ between the forms "
+                             f"(limit {KEYE_FLIPS})")
+    report = []
+    for i, what in enumerate(("mixed round", "decode step", "gdn state")):
+        ref, got = out["xla"][i], out["kernel"][i]
+        scale = float(np.abs(ref).max())
+        by_row = np.abs(got - ref).max(axis=1)
+        over = by_row > LOGIT_RTOL * scale
+        allowed = flips if what == "decode step" else 0
+        if (not np.isfinite(got).all() or over.sum() > allowed
+                or by_row.max() > scale / spec.top_k):
+            raise AssertionError(
+                f"qwen3_next {what}: with the kernels "
+                f"{float(by_row.max()):.4g} from the XLA forms (largest "
+                f"{scale:.4g}; by lane {np.round(by_row, 4).tolist()}; "
+                f"{flips} expert assignments differ)")
+        report.append(f"{what} err {float(by_row.max()):.4g} of {scale:.4g}")
+    here = counts["xla"][:, first:first + held].sum()
+    return ("; ".join(report) + f"; {flips} of {int(counts['xla'].sum())} "
+            f"decode assignments differ, {int(here)} of them on experts "
+            f"{first}..{first + held} held here")
+
+
 # -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------
 def round_buffer(tables, toks, row_lane, row_off, q_lens, kv_lens):
     """A mixed round of greedy lanes as the ONE buffer ``paged_mixed_step``
@@ -940,6 +1072,7 @@ def main(argv=None) -> int:
         smoke.run("latent", phase_latent)
         smoke.run("jamba", phase_jamba)
         smoke.run("keye_vl2", phase_keye)
+        smoke.run("qwen3_next", phase_qwen3_next)
         smoke.run("kernels", phase_kernels)
         smoke.run("multichip", phase_multichip)
 

@@ -407,7 +407,8 @@ class PagedKVPool:
 
 def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
     """``((shape, dtype) of ssm, (shape, dtype) of conv)``: the ONE
-    definition of what a model's Mamba layers keep a lane.
+    definition of what a model's Mamba or Gated DeltaNet layers
+    (``spec.state_kind``) keep a lane.
 
     ``ssm``   ``(mamba layers, lanes, d_state, d_inner)`` float32: the SSM
               state ``h`` (the published kernel accumulates in float32; in
@@ -418,8 +419,19 @@ def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
               (the compute type): the last ``d_conv - 1`` inputs of the
               layer's causal convolution, oldest first (lanes ahead of
               channels, so the tile's sublanes are not padded from 3 to
-              16)."""
-    n_layers = len(spec.mamba_layers)
+              16).
+
+    Kind ``"gdn"`` keeps the same pair: ``ssm`` is the delta rule's
+    matrix-valued state ``(layers, lanes, value heads, d_k, d_v)`` float32
+    (a head's ``(d_k, d_v)`` whole tiles), ``conv`` the tail of the
+    convolution over the ``[q | k | v]`` channels."""
+    n_layers = len(spec.state_layers)
+    if spec.state_kind == "gdn":
+        return (((n_layers, lanes, spec.gdn_v_heads, spec.gdn_k_dim,
+                  spec.gdn_v_dim), np.dtype(np.float32)),
+                ((n_layers, spec.d_conv - 1, lanes,
+                  2 * spec.gdn_k_heads * spec.gdn_k_dim
+                  + spec.gdn_v_heads * spec.gdn_v_dim), np.dtype(dtype)))
     return (((n_layers, lanes, spec.d_state, spec.d_inner),
              np.dtype(np.float32)),
             ((n_layers, spec.d_conv - 1, lanes, spec.d_inner),
@@ -427,8 +439,9 @@ def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
 
 
 class LaneStateStore:
-    """Per-lane recurrent state of a model's Mamba layers, beside the page
-    store: ``arrays = (ssm, conv)``, shaped by :func:`lane_state_shapes`.
+    """Per-lane recurrent state of a model's Mamba or Gated DeltaNet layers,
+    beside the page store: ``arrays = (ssm, conv)``, shaped by
+    :func:`lane_state_shapes`.
 
     A lane's slot belongs to whatever sequence runs in the lane.  Nothing
     here resets it: the step programs start a segment at position 0 from
@@ -442,8 +455,9 @@ class LaneStateStore:
         from tpulab.tpu import platform as plat
         from tpulab.tpu.allocators import make_tpu_allocator
 
-        if not spec.mamba_layers:
-            raise ValueError("the model has no Mamba layer: no lane state")
+        if not spec.state_layers:
+            raise ValueError("the model has no layer with a lane state")
+        self.kind = spec.state_kind
         self.lanes = lanes
         self.device = device if device is not None else plat.local_device(0)
         self._shapes = lane_state_shapes(spec, lanes, dtype or jnp.bfloat16)
@@ -469,7 +483,7 @@ class LaneStateStore:
 
     @property
     def bytes_per_lane(self) -> int:
-        """State bytes a lane holds, all Mamba layers, whatever its
+        """State bytes a lane holds, all its layers, whatever its
         context."""
         return self.hbm_bytes // self.lanes
 

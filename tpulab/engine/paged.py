@@ -283,7 +283,8 @@ class ContinuousBatcher:
     the page store and runs in the absorbed form (gather or the latent
     ragged kernel); expert layers run the routed FFN of
     tpulab.parallel.moe and count assignments (``debug_state()["moe"]``).
-    Mamba layers (``spec.mixers``) leave no pages: their recurrent state
+    Mamba and Gated DeltaNet layers (``spec.mixers``; a lane state of kind
+    ``spec.state_kind``) leave no pages: their recurrent state
     lives in a :class:`~tpulab.engine.kv_pool.LaneStateStore`, a slot a
     lane, which rotates through every dispatch beside the page store (the
     run-ahead chain enqueues block N+1 on the state block N returns); the
@@ -354,13 +355,13 @@ class ContinuousBatcher:
         compute_dtype = compute_dtype or jnp.bfloat16
         #: tpulab.models.spec.ModelSpec: None serves the dense decoder of
         #: ``n_heads``/``n_kv_heads``/``rope_theta`` with today's constants;
-        #: a spec with a latent cache, index rows, expert layers or Mamba
-        #: layers is served on the ragged plan alone, and the options that
-        #: plan, that cache-entry kind or a per-lane state (nothing
+        #: a spec with a latent cache, index rows, expert layers or layers
+        #: with a lane state is served on the ragged plan alone, and the
+        #: options that plan, that cache-entry kind or a per-lane state (nothing
         #: snapshots, shares or ships it yet) does not carry are refused
         #: here, by name
         self.model_spec = spec
-        hybrid = spec is not None and bool(spec.mamba_layers)
+        hybrid = spec is not None and bool(spec.state_layers)
         #: a learned indexer: index rows beside the K/V pages, the pair
         #: rotated through every dispatch as a hybrid's (pages, state) is
         sparse = spec is not None and bool(spec.index_topk)
@@ -382,10 +383,14 @@ class ContinuousBatcher:
             }
             bad = [name for name, on in refused.items() if on]
             if bad:
+                kinds = sorted({f"{spec.cache_entry} pages"}
+                               | {f"{k} layers" for k in spec.mixers
+                                  if k != "attention"}
+                               | {f"{k} FFNs" for k in spec.layer_kinds
+                                  if k != "dense"})
                 raise NotImplementedError(
-                    "a model with a latent cache, expert layers or Mamba "
-                    "layers is served on the ragged plan only; not "
-                    "supported with it: "
+                    f"a model with {', '.join(kinds)} is served on the "
+                    "ragged plan only; not supported with it: "
                     + ", ".join(bad))
             if (spec.n_heads, spec.n_layers) != (n_heads, n_layers):
                 raise ValueError(
@@ -435,7 +440,8 @@ class ContinuousBatcher:
         if pool is not None and pool.n_layers != pool_layers:
             raise ValueError(f"the provided pool has {pool.n_layers} layers, "
                              f"the model {pool_layers} attention layers")
-        head_dim = spec.head_dim if sparse else d_model // n_heads
+        head_dim = (spec.head_dim if spec is not None
+                    else 0) or d_model // n_heads
         if pool is not None and sparse and pool.index is None:
             raise ValueError("the model has an indexer: the provided pool "
                              "needs index rows (index_dim=)")
@@ -444,13 +450,18 @@ class ContinuousBatcher:
             0 if latent else n_kv, 0 if latent else head_dim,
             kv_dtype, device, mesh=mesh, latent_width=latent,
             index_dim=spec.index_dim if sparse else 0)
-        #: the Mamba layers' per-lane recurrent state (None without any):
+        #: the per-lane recurrent state of the layers that keep one (Mamba,
+        #: Gated DeltaNet: ``spec.state_layers``; None without any):
         #: rotates through every dispatch beside ``pool.kv`` (_kv_state)
         self.state = (LaneStateStore(spec, lanes, compute_dtype,
                                      self.pool.device) if hybrid else None)
         #: segments the device started from zeros (a first chunk at
         #: position 0: admissions and re-prefills after preemption)
         self.zero_starts = 0
+        #: what the request released last held (``debug_state()
+        #: ["last_release"]``): a slot and pages keep their contents until
+        #: another request takes them, so a check can read them there
+        self.last_release: Optional[Dict[str, Any]] = None
         if pool is not None and mesh is not None and pool.mesh is not mesh:
             raise ValueError("provided pool was built on a different mesh "
                              "than the batcher's")
@@ -532,8 +543,11 @@ class ContinuousBatcher:
             from tpulab.ops.ragged_attention import (kernel_geometry_error,
                                                      latent_geometry_error)
             if hybrid:
+                from tpulab.ops.gated_delta_rule import rule_geometry_error
                 from tpulab.ops.selective_scan import scan_geometry_error
-                err = scan_geometry_error(spec.d_inner, spec.d_state)
+                err = (scan_geometry_error(spec.d_inner, spec.d_state)
+                       if spec.state_kind == "mamba" else
+                       rule_geometry_error(spec.gdn_k_dim, spec.gdn_v_dim))
                 if err:
                     return err
             widest = (round_width(self._round_budget)
@@ -677,6 +691,14 @@ class ContinuousBatcher:
         #: sum of K over plain decode dispatches (K-blocks and single
         #: ticks): over ``dispatch_kinds["decode"]`` it is the mean block
         self.decode_block_steps = 0
+        #: what the lanes of the plain decode dispatches and of the mixed
+        #: rounds ran (:meth:`_note_rows`): ``passes`` a lane went through
+        #: the layers (a decode step a token, a round's segment once: what
+        #: reads and writes a lane state), the ``rows`` they computed and
+        #: the ``keys`` at or before the last row of each pass (what an
+        #: attention layer reads of the lane's pages at least)
+        self.lane_work = {kind: dict(passes=0, rows=0, keys=0)
+                          for kind in ("decode", "round")}
         #: decode blocks enqueued before their predecessor was fetched
         #: (_chain_block): over ``dispatch_kinds["decode"]`` the share of
         #: blocks whose host turn the device did not wait for
@@ -882,7 +904,7 @@ class ContinuousBatcher:
     @property
     def _kv_state(self):
         """What the step programs take as ``kv_pool``, donate and return:
-        the page store, or with Mamba layers the pair ``(page store, lane
+        the page store, or with a lane state the pair ``(page store, lane
         state)``."""
         if self.state is not None:
             return self.pool.kv, self.state.arrays
@@ -899,20 +921,28 @@ class ContinuousBatcher:
         else:
             self.pool.kv = value
 
-    def _note_sparse(self, kind: str, start: int, n: int) -> None:
+    def _note_rows(self, kind: str, start: int, n: int) -> None:
         """Count ``n`` query rows of one lane at contexts ``start + 1 ..
         start + n`` (keys at or before the row, itself included) under
-        ``kind`` ("decode" | "round"); a no-op without an indexer."""
-        if self._sparse is None or n <= 0:
+        ``kind``: "decode" (a row a step) or "round" (a segment of a mixed
+        round) in ``lane_work``; with an indexer also what it scored and
+        selected."""
+        if n <= 0:
+            return
+        triangle = n * start + n * (n + 1) // 2
+        w = self.lane_work[kind]
+        w["passes"] += n if kind == "decode" else 1
+        w["rows"] += n
+        w["keys"] += triangle if kind == "decode" else start + n
+        if self._sparse is None:
             return
         k, layers = self.model_spec.index_topk, self.model_spec.n_layers
         dense = min(max(k - start, 0), n)       # rows whose context <= k
-        scored = n * start + n * (n + 1) // 2
         attended = (dense * start + dense * (dense + 1) // 2
                     + (n - dense) * k)
         c = self._sparse[kind]
         c["query_rows"] += n * layers
-        c["keys_scored"] += scored * layers
+        c["keys_scored"] += triangle * layers
         c["keys_attended"] += attended * layers
         c["dense_rows"] += dense * layers
 
@@ -1467,6 +1497,7 @@ class ContinuousBatcher:
                           for q in self._queue[:16]]
             queued = len(self._queue)
             profile_armed = self._profile is not None
+            last_release = self.last_release
         pool = self.pool
         rung, size = 0, self._hbm_pool_base
         while size and size * 2 <= pool.n_pages:
@@ -1504,6 +1535,8 @@ class ContinuousBatcher:
                          "mixed_tokens": self.mixed_tokens,
                          "mixed_attn_rows": self.mixed_attn_rows,
                          "decode_block_steps": self.decode_block_steps,
+                         "lane_work": {kind: dict(w) for kind, w
+                                       in self.lane_work.items()},
                          "ahead_blocks": self.ahead_blocks,
                          "chain": {"breaks": dict(self.chain_breaks),
                                    "late_links": self.late_links},
@@ -1523,6 +1556,7 @@ class ContinuousBatcher:
                          "completed_requests": self.completed_requests,
                          "tokens_generated": self.tokens_generated},
             "profile_armed": profile_armed,
+            "last_release": last_release,
         }
         if self._spec is not None:
             out["spec"] = {"dispatches": self.spec_dispatches,
@@ -1533,12 +1567,21 @@ class ContinuousBatcher:
                            "probes": self.spec_probes,
                            "probe_recoveries": self.spec_probe_recoveries}
         if self._moe_assignments is not None:
+            first = self.model_spec.expert_first
+            held = self.model_spec.experts_held or self.model_spec.n_experts
             out["moe"] = {
                 "expert_layers": list(self.model_spec.moe_layers),
                 # cumulative (row, expert) assignments, [expert layer][expert]
+                # over every column of the router
                 "assignments": self._moe_assignments.tolist(),
+                # the experts whose weights are here, and the assignments
+                # that went to them, [expert layer]
+                "first": first, "held": held,
+                "assignments_here": self._moe_assignments[
+                    :, first:first + held].sum(axis=1).tolist(),
                 "decode_steps": self.moe_decode_steps,
-                # summed over decode steps and expert layers
+                # experts held here with a row, summed over decode steps
+                # and expert layers
                 "experts_hit": self.moe_experts_hit}
         if self._sparse is not None:
             out["sparse"] = {"topk": self.model_spec.index_topk,
@@ -1546,7 +1589,8 @@ class ContinuousBatcher:
                                        for kind, c in self._sparse.items()}
                                 for name in self._sparse["decode"]}}
         if self.state is not None:
-            out["state"] = {"kind": "mamba", "lanes": self.state.lanes,
+            out["state"] = {"kind": self.state.kind,
+                            "lanes": self.state.lanes,
                             "bytes_per_lane": self.state.bytes_per_lane,
                             "hbm_bytes": self.state.hbm_bytes,
                             "zero_starts": self.zero_starts}
@@ -2525,7 +2569,7 @@ class ContinuousBatcher:
                 if self._active[lane] is not req or req.cancelled:
                     continue
                 c = chunks[lane]
-                self._note_sparse("round", req.length, c)
+                self._note_rows("round", req.length, c)
                 req.length += c
                 del req.pending_prompt[:c]
                 self._fl_pages(req)
@@ -2571,7 +2615,7 @@ class ContinuousBatcher:
                     continue
                 self._probe_countdown_locked(req)
                 self._note_second_token(req, now)
-                self._note_sparse("round", req.length, 1)
+                self._note_rows("round", req.length, 1)
                 req.length += 1
                 tok = int(next_tokens[lane])
                 req.tokens_out.append(tok)
@@ -3181,7 +3225,7 @@ class ContinuousBatcher:
                 n = int(ems[lane].sum())   # prefix mask: first n are valid
                 if n == 0:
                     continue
-                self._note_sparse("decode", req.length, n)
+                self._note_rows("decode", req.length, n)
                 # the block is one device round trip: spread its wall time
                 # evenly over the lane's tokens so ITL keeps a true mean
                 # (the burst shape is documented in docs/PERFORMANCE.md)
@@ -3493,7 +3537,7 @@ class ContinuousBatcher:
                     continue  # the _run sweep releases it next round
                 self._probe_countdown_locked(req)
                 self._note_second_token(req, now)
-                self._note_sparse("decode", req.length, 1)
+                self._note_rows("decode", req.length, 1)
                 req.length += 1
                 req.tokens_out.append(int(next_tokens[lane]))
                 self.tokens_generated += 1
@@ -3540,6 +3584,8 @@ class ContinuousBatcher:
             req.future._tpulab_kv_export = self.kv_offload.swap_out(
                 req.pages[:needed], req.length, self.pool.kv,
                 key=("ship", req.export_digest))
+        self.last_release = {"lane": lane, "pages": list(req.pages),
+                             "length": req.length}
         self.pool.release_pages(req.pages)
         if req.draft_pages:
             self.pool.release_pages(req.draft_pages)

@@ -24,10 +24,10 @@ TPU-first mechanics:
   block's carry and a round's logits stay on the device.
 
 Every forward runs ONE layer block (:func:`_layer_block`) read from a
-:class:`~tpulab.models.spec.ModelSpec`.  For a model with Mamba layers the
-``kv_pool`` every step function takes, donates, carries through its scan
-and returns is the pair ``(page store, lane state)``: the attention
-layers' pages and the Mamba layers' per-lane recurrent state
+:class:`~tpulab.models.spec.ModelSpec`.  For a model with Mamba or Gated
+DeltaNet layers the ``kv_pool`` every step function takes, donates, carries
+through its scan and returns is the pair ``(page store, lane state)``: the
+attention layers' pages and the other layers' per-lane recurrent state
 (:class:`~tpulab.engine.kv_pool.LaneStateStore` ``.arrays``), one pytree;
 for a model with a learned indexer it is the pair ``(page store, index
 rows)`` (``PagedKVPool.kv`` and ``.index``).
@@ -326,9 +326,61 @@ def _sparse_attention(spec, p, layer, h, pos, valid, kv_pool, page_idx,
 
 def _pages(kv_pool):
     """The page store of a step function's ``kv_pool``: itself, or the
-    first of the pair a model with Mamba layers (``(pages, lane state)``)
+    first of the pair a model with a lane state (``(pages, lane state)``)
     or with an indexer (``(pages, index rows)``) is served with."""
     return kv_pool[0] if isinstance(kv_pool, tuple) else kv_pool
+
+
+def _segment_conv(x, w, conv, at, seg, live=None, fresh=None):
+    """The depthwise causal convolution of a lane-state mixer over the rows
+    ``x (rows, C)`` with taps ``w (k, C)``, from the lanes' kept tails
+    ``conv[at] (k - 1, lanes, C)``: ``(acc (rows, C) float32 before bias
+    and activation, conv with layer at's new tails)``.
+
+    The one rule of :func:`_mamba_mixer`, for the convolution: a segment at
+    position 0 starts from a zero tail whatever the slot holds, any other
+    from the slot; the new tail is the last ``k - 1`` inputs of ``[tail ;
+    segment]``; rows without a token and dead or idle lanes write nothing.
+    A decode step (no ``seg["row_seg"]``: row b is lane b's one token;
+    ``live`` the lanes that run, ``fresh`` those at position 0) shifts the
+    lane's window by one.  A packed round takes row t's tap ``back`` tokens
+    back from row ``t - back`` of its segment where the row's offset
+    reaches that far, else from the lane's tail."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    k = w.shape[0]
+    rows = seg.get("row_seg")
+    if rows is None:
+        tail = jnp.where(fresh[None, :, None], 0, conv[at])
+        window = jnp.concatenate([tail, x[None]], axis=0)      # (k, B, C)
+        acc = (window.astype(f32) * w[:, None, :]).sum(0)
+        new_tail = jnp.where(live[None, :, None], window[1:], conv[at])
+    else:
+        row_lane, row_off = rows
+        q_lens, kv_lens = seg["q_lens"], seg["kv_lens"]
+        b, t = q_lens.shape[0], x.shape[0]
+        lane = jnp.maximum(row_lane, 0)
+        fresh = (q_lens > 0) & (kv_lens == q_lens)
+        tail = jnp.where(fresh[None, :, None], 0, conv[at])
+        acc = x.astype(f32) * w[k - 1]
+        for back in range(1, k):
+            # the input ``back`` tokens back: a row of this round, or
+            # what the lane kept of the rounds before
+            kept = tail[jnp.clip(row_off - back + k - 1, 0, k - 2), lane]
+            src = jnp.where((row_off >= back)[:, None],
+                            jnp.pad(x, ((back, 0), (0, 0)))[:t], kept)
+            acc = acc + src.astype(f32) * w[k - 1 - back]
+        # the lane's new tail: the last k - 1 of [tail ; segment]
+        spread = seg["rows"][0]
+        s = q_lens[:, None] + jnp.arange(k - 1)[None, :] - (k - 1)
+        from_rows = x[spread[jnp.arange(b)[:, None] * (spread.shape[0] // b)
+                             + jnp.maximum(s, 0)]]             # (B, k-1, C)
+        kept = jnp.take_along_axis(
+            tail, jnp.clip(s + k - 1, 0, k - 2).T[:, :, None], axis=0)
+        new_tail = jnp.where((s >= 0).T[:, :, None],
+                             from_rows.transpose(1, 0, 2), kept)
+    return acc, conv.at[at].set(new_tail.astype(conv.dtype))
 
 
 def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
@@ -345,17 +397,16 @@ def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
 
     A decode step (``h (B, 1, D)``, ``valid (B, 1)`` the live lanes) is the
     one-token recurrence in XLA.  A packed round (``seg["row_seg"]``: ``h
-    (1, T, D)``) takes row t's convolution taps from rows ``t-1 ..`` of its
-    segment where the row's offset reaches that far, else from the lane's
-    tail, and scans the segments in
-    :func:`tpulab.ops.selective_scan.selective_scan`."""
+    (1, T, D)``) scans the segments in
+    :func:`tpulab.ops.selective_scan.selective_scan`.  The convolution of
+    both forms is :func:`_segment_conv`."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _rmsnorm, qmat
 
     f32 = jnp.float32
     ssm, conv = state
-    din, n, r, k = spec.d_inner, spec.d_state, spec.dt_rank, spec.d_conv
+    din, n, r = spec.d_inner, spec.d_state, spec.dt_rank
     rows = seg.get("row_seg")
     if rows is None and h.shape[1] != 1:
         raise NotImplementedError(
@@ -364,40 +415,12 @@ def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     with jax.named_scope("mamba_proj"):
         uz = (h @ qmat(p["in_proj"], compute_dtype)).reshape(-1, 2 * din)
         x, z = uz[:, :din], uz[:, din:]              # (rows, din)
+    live = fresh = None
     with jax.named_scope("mamba_conv"):
         w = p["conv_w"].astype(f32)
         if rows is None:
             live, fresh = valid[:, 0], pos[:, 0] == 0
-            tail = jnp.where(fresh[None, :, None], 0, conv[at])
-            window = jnp.concatenate([tail, x[None]], axis=0)  # (k, B, din)
-            acc = (window.astype(f32) * w[:, None, :]).sum(0)
-            new_tail = jnp.where(live[None, :, None], window[1:], conv[at])
-        else:
-            row_lane, row_off = rows
-            q_lens, kv_lens = seg["q_lens"], seg["kv_lens"]
-            b, t = q_lens.shape[0], x.shape[0]
-            lane = jnp.maximum(row_lane, 0)
-            fresh = (q_lens > 0) & (kv_lens == q_lens)
-            tail = jnp.where(fresh[None, :, None], 0, conv[at])
-            acc = x.astype(f32) * w[k - 1]
-            for back in range(1, k):
-                # the input ``back`` tokens back: a row of this round, or
-                # what the lane kept of the rounds before
-                kept = tail[jnp.clip(row_off - back + k - 1, 0, k - 2), lane]
-                src = jnp.where((row_off >= back)[:, None],
-                                jnp.pad(x, ((back, 0), (0, 0)))[:t], kept)
-                acc = acc + src.astype(f32) * w[k - 1 - back]
-            # the lane's new tail: the last k - 1 of [tail ; segment]
-            spread = seg["rows"][0]
-            s = q_lens[:, None] + jnp.arange(k - 1)[None, :] - (k - 1)
-            from_rows = x[spread[jnp.arange(b)[:, None] * (spread.shape[0]
-                                                           // b)
-                                 + jnp.maximum(s, 0)]]      # (B, k-1, din)
-            kept = jnp.take_along_axis(
-                tail, jnp.clip(s + k - 1, 0, k - 2).T[:, :, None], axis=0)
-            new_tail = jnp.where((s >= 0).T[:, :, None],
-                                 from_rows.transpose(1, 0, 2), kept)
-        conv = conv.at[at].set(new_tail.astype(conv.dtype))
+        acc, conv = _segment_conv(x, w, conv, at, seg, live, fresh)
         u = jax.nn.silu(acc + p["conv_b"].astype(f32)).astype(compute_dtype)
     with jax.named_scope("mamba_proj"):
         eps = spec.rms_eps
@@ -421,9 +444,10 @@ def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
                 jnp.where(live[:, None, None], hn, ssm[at]))
         else:
             from tpulab.ops.selective_scan import row_flags, selective_scan
+            row_lane, row_off = rows
             y, ssm = selective_scan(
                 uf, dt, bb, cc, a, d, ssm, at, row_lane,
-                row_flags(row_lane, row_off, q_lens, kv_lens),
+                row_flags(row_lane, row_off, seg["q_lens"], seg["kv_lens"]),
                 use_kernel=seg["use_kernel"])
     with jax.named_scope("mamba_out"):
         out = ((y * jax.nn.silu(z.astype(f32))).astype(compute_dtype)
@@ -431,12 +455,168 @@ def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     return out.reshape(h.shape), (ssm, conv)
 
 
+def _gdn_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
+    """The Gated DeltaNet mixer of one layer (Qwen3-Next's) over the lane
+    state ``state = (ssm, conv)``, whose layer ``at`` it reads and writes:
+    ``(out, state)``, ``out`` shaped like ``h``.  ``ssm[at]`` is ``(lanes,
+    value heads, d_k, d_v)`` float32, ``conv[at]`` the last ``d_conv - 1``
+    inputs of the convolution over the ``[q | k | v]`` channels.
+
+    :func:`_mamba_mixer`'s one rule, in a function of its own (so that the
+    Mamba hybrids' programs and call paths are what they were): a segment
+    at position 0 starts from zeros whatever the slot holds, any other from
+    the slot; the slot is written from the segment's last valid row; rows
+    without a token and dead or idle lanes write nothing.
+
+    A decode step (``h (B, 1, D)``) is the one-token delta rule
+    (:func:`tpulab.ops.gated_delta_rule.gated_delta_step`).  A packed round
+    (``seg["row_seg"]``: ``h (1, T, D)``) takes the convolution's taps
+    across segment starts by :func:`_segment_conv`; its chunk rows ``[0,
+    M)`` run the chunk kernel ``chunk_gated_delta_rule`` and its decode rows
+    ``[M, M + B)``, one a lane, the one-token rule (different lanes, so in
+    either order); without kernels every row goes through the sequential
+    form."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _rmsnorm, qmat
+    from tpulab.ops import gated_delta_rule as gdr
+
+    f32 = jnp.float32
+    ssm, conv = state
+    hk, hv, dk, dv = (spec.gdn_k_heads, spec.gdn_v_heads, spec.gdn_k_dim,
+                      spec.gdn_v_dim)
+    rep, nk = hv // hk, hk * dk
+    rows = seg.get("row_seg")
+    if rows is None and h.shape[1] != 1:
+        raise NotImplementedError(
+            "Gated DeltaNet layers run in a decode step or a packed round "
+            "(paged_mixed_step), not in the padded (B, M) form")
+    n = h.shape[0] * h.shape[1]
+    with jax.named_scope("gdn_proj"):
+        qkvz = (h @ qmat(p["in_qkvz"], compute_dtype)).reshape(n, -1)
+        ba = (h @ qmat(p["in_ba"], compute_dtype)).astype(f32).reshape(n, -1)
+        x, z = qkvz[:, :2 * nk + hv * dv], qkvz[:, 2 * nk + hv * dv:].reshape(
+            n, hv, dv)
+        beta = jax.nn.sigmoid(ba[:, :hv])
+        g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
+            ba[:, hv:] + p["dt_bias"].astype(f32))
+    live = fresh = None
+    with jax.named_scope("gdn_conv"):
+        w = p["conv_w"].astype(f32)
+        if rows is None:
+            live, fresh = valid[:, 0], pos[:, 0] == 0
+        acc, conv = _segment_conv(x, w, conv, at, seg, live, fresh)
+        u = jax.nn.silu(acc)
+
+        def unit(t):               # a head's q or k over its L2 norm
+            t = t.reshape(n, hk, dk)
+            return t * jax.lax.rsqrt(jnp.square(t).sum(-1, keepdims=True)
+                                     + 1e-6)
+        qh, kh, v = unit(u[:, :nk]) * dk ** -0.5, unit(u[:, nk:2 * nk]), \
+            u[:, 2 * nk:]
+    with jax.named_scope("gdn_rule"):
+        def one_token(qh, kh, v, g, beta, live, fresh):
+            """Lane b's one row: the one-token rule on its slot."""
+            s0 = jnp.where(fresh[:, None, None, None], 0.0, ssm[at])
+            o, s1 = gdr.gated_delta_step(
+                jnp.repeat(qh, rep, axis=1), jnp.repeat(kh, rep, axis=1),
+                v.reshape(-1, hv, dv), g, beta, s0)
+            return o.reshape(-1, hv * dv), ssm.at[at].set(
+                jnp.where(live[:, None, None, None], s1, ssm[at]))
+
+        if rows is None:
+            o, ssm = one_token(qh, kh, v, g, beta, live, fresh)
+        else:
+            from tpulab.ops.selective_scan import ROW_ZERO, row_flags
+            row_lane, row_off = rows
+            flags = row_flags(row_lane, row_off, seg["q_lens"],
+                              seg["kv_lens"])
+            # the kernel's rows: all but the lanes' decode rows
+            m = n - seg["q_lens"].shape[0] if seg["use_kernel"] else n
+            o, ssm = gdr.chunk_gated_delta_rule(
+                qh[:m].reshape(m, nk), kh[:m].reshape(m, nk), v[:m], g[:m],
+                beta[:m], ssm, at, row_lane[:m], flags[:m],
+                use_kernel=seg["use_kernel"])
+            if m < n:
+                # a row without a token reads what the kernel never wrote,
+                # which may not be a number (and 0 x that is not 0 where an
+                # attention layer reads the scratch page it scatters to)
+                o = jnp.where((row_lane[:m] >= 0)[:, None], o, 0.0)
+                o_dec, ssm = one_token(
+                    qh[m:], kh[m:], v[m:], g[m:], beta[m:], row_lane[m:] >= 0,
+                    (flags[m:] & ROW_ZERO) != 0)
+                o = jnp.concatenate([o, o_dec])
+    with jax.named_scope("gdn_out"):
+        o = _rmsnorm(o.reshape(n, hv, dv), p["norm"]["scale"].astype(f32),
+                     spec.rms_eps) * jax.nn.silu(z.astype(f32))
+        out = (o.reshape(n, hv * dv).astype(compute_dtype)
+               @ qmat(p["out_proj"], compute_dtype))
+    return out.reshape(h.shape), (ssm, conv)
+
+
+def _gated_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx,
+                     seg, compute_dtype):
+    """GQA attention of one layer with an output gate (``spec.attn_gate``:
+    ``wqkv`` = ``[q | k | v]``, a query head's columns ``[query | gate]``),
+    RMSNorm over each head of q and k and RoPE over the first
+    ``spec.rotary_dim`` columns of a head: ``(attn * sigmoid(gate) (B, M, H
+    * D), kv_pool)`` on ``"kv"`` pages.  The walk over the pages is the
+    dense decoder's (:func:`_layer_block`), written out here so that its
+    own path keeps the frames it has."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import _rmsnorm, apply_rope, qmat
+
+    b, m = h.shape[:2]
+    at = spec.store_layer(layer)
+    hq, hkv, d = spec.n_heads, spec.n_kv_heads, spec.head_dim
+    qkv = h @ qmat(p["wqkv"], compute_dtype)
+    qg = qkv[..., :2 * hq * d].reshape(b, m, hq, 2 * d)
+    q, gate = qg[..., :d], qg[..., d:]
+    knew = qkv[..., 2 * hq * d:(2 * hq + hkv) * d].reshape(b, m, hkv, d)
+    vnew = qkv[..., (2 * hq + hkv) * d:].reshape(b, m, hkv, d)
+    if spec.qk_norm:
+        q = _rmsnorm(q, p["q_norm"]["scale"], spec.rms_eps)
+        knew = _rmsnorm(knew, p["k_norm"]["scale"], spec.rms_eps)
+    if spec.rope_theta:
+        rot = spec.rotary_dim or d
+        q, knew = (jnp.concatenate(
+            [apply_rope(t[..., :rot], pos, spec.rope_theta), t[..., rot:]],
+            axis=-1) for t in (q, knew))
+    tail = knew.shape[2:]
+    kv_pool = _scatter_kv(kv_pool, at, page_idx, slot_idx,
+                          knew.reshape(page_idx.shape + tail),
+                          vnew.reshape(page_idx.shape + tail))
+    outs = []
+    for qq, q_lens, qpos in _segment_calls(q, pos, seg):
+        if not seg["use_kernel"]:
+            outs.append(_gather_attend(
+                qq, kv_pool[at, :, 0], kv_pool[at, :, 1], seg["tables"],
+                qpos, compute_dtype).reshape(qq.shape))
+            continue
+        from tpulab.ops import ragged_attention as ra
+        from tpulab.tpu.platform import pallas_interpret
+        gk, nk = seg["kernel_geometry"] or (None, None)
+        outs.append(ra._ragged_attn(
+            qq, kv_pool, jnp.asarray(at, jnp.int32).reshape(1),
+            seg["tables"], q_lens, seg["kv_lens"], pallas_interpret(),
+            g_pages=gk, nbuf=nk))
+    attn = _segment_rows(outs, seg).astype(compute_dtype).reshape(b, m, hq, d)
+    with jax.named_scope("attn_gate"):
+        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            compute_dtype)
+    return attn.reshape(b, m, -1), kv_pool
+
+
 def _ffn_block(spec, p, layer, x, valid, compute_dtype):
     """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
-    experts (router kind ``spec.router``) plus the shared expert where the
-    model has one.  Returns ``(x, stats)``, ``stats``
-    the expert layer's ``(E + 2,)`` counters or None."""
+    experts (router kind ``spec.router``; of the router's ``E`` experts the
+    share ``spec.expert_first`` / ``spec.experts_held`` whose weights are
+    here) plus the shared expert where the model has one, scaled by its
+    sigmoid gate where it has that (``spec.shared_gate``).  Returns ``(x,
+    stats)``, ``stats`` the expert layer's ``(E + 2,)`` counters or None."""
     import jax
+    import jax.numpy as jnp
     from tpulab.models.transformer import _dense_ffn, _rmsnorm
 
     h = _rmsnorm(x, p["ln2"]["scale"], spec.rms_eps)
@@ -447,9 +627,16 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype):
     y, stats = routed_ffn(p["moe"], h.reshape(b * m, -1), spec.top_k,
                           compute_dtype, router=spec.router, act="swiglu",
                           scale=spec.routed_scale, norm=spec.norm_topk,
-                          valid=valid.reshape(-1))
+                          valid=valid.reshape(-1), first=spec.expert_first,
+                          held=spec.experts_held or None)
     y = y.reshape(b, m, -1)
-    if spec.n_shared:
+    if spec.shared_gate:
+        from tpulab.models.transformer import qmat
+        with jax.named_scope("moe_shared"):
+            y = y + _dense_ffn(p["shared"], h, compute_dtype) * jax.nn.sigmoid(
+                (h @ qmat(p["shared"]["gate"], compute_dtype)).astype(
+                    jnp.float32))
+    elif spec.n_shared:
         with jax.named_scope("moe_shared"):
             y = y + _dense_ffn(p["shared"], h, compute_dtype)
     return x + y.astype(x.dtype), stats
@@ -494,11 +681,12 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     store, index rows)`` and the attention reads only the keys the indexer
     selects (:func:`_sparse_attention`).
 
-    The layer's mixer is attention over the pages (above) or, by
-    ``spec.mixers``, a Mamba block over the lane state
-    (:func:`_mamba_mixer`); ``kv_pool`` is then the pair ``(page store,
-    lane state)`` for every layer of the model, and only attention layers
-    own a layer of the page store (``spec.store_layer``).
+    The layer's mixer is attention over the pages (above; with an output
+    gate and partial RoPE, :func:`_gated_attention`) or, by ``spec.mixers``,
+    a Mamba block (:func:`_mamba_mixer`) or a Gated DeltaNet block
+    (:func:`_gdn_mixer`) over the lane state; ``kv_pool`` is then the pair
+    ``(page store, lane state)`` for every layer of the model, and only
+    attention layers own a layer of the page store (``spec.store_layer``).
 
     Kept short, the K/V kernel called from here and the rest in functions
     of their own: on the v5e host, tracing a kernel body costs more with
@@ -525,6 +713,15 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
             x, stats = _ffn_block(spec, p, layer, x + mixed, valid,
                                   compute_dtype)
             return x, (kv_pool, state), stats
+    elif spec.gdn_k_heads:
+        kv_pool, state = kv_pool
+        if spec.mixers[layer] == "gdn":
+            mixed, state = _gdn_mixer(
+                spec, p["gdn"], spec.store_layer(layer), h, pos, valid,
+                state, seg, compute_dtype)
+            x, stats = _ffn_block(spec, p, layer, x + mixed, valid,
+                                  compute_dtype)
+            return x, (kv_pool, state), stats
     if spec.attention == "mla":
         attn, kv_pool = _mla_attention(spec, p, layer, h, pos, kv_pool,
                                        page_idx, slot_idx, seg,
@@ -533,6 +730,10 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         attn, kv_pool = _sparse_attention(spec, p, layer, h, pos, valid,
                                           kv_pool, page_idx, slot_idx, seg,
                                           compute_dtype)
+    elif spec.attn_gate:
+        attn, kv_pool = _gated_attention(spec, p, layer, h, pos, kv_pool,
+                                         page_idx, slot_idx, seg,
+                                         compute_dtype)
     else:
         b, m = x.shape[:2]
         at = spec.store_layer(layer)       # its layer of the page store

@@ -15,12 +15,14 @@ kind the *cache-entry kind* the page store holds:
               layer: what a learned indexer scores a query against to
               choose the ``index_topk`` keys the query attends to.
 
-A layer's *mixer* is attention or a Mamba selective state-space block
-(``mixers``).  A Mamba layer leaves no pages: it keeps a per-lane recurrent
-state of fixed size (SSM state ``d_state x d_inner`` in float32 and the
-last ``d_conv - 1`` inputs of its causal convolution) in the engine's
-lane-state store, so only attention layers own a layer of the page store
-(``store_layer``).
+A layer's *mixer* is attention, a Mamba selective state-space block or a
+Gated DeltaNet linear-attention block (``mixers``).  A Mamba or Gated
+DeltaNet layer leaves no pages: it keeps a per-lane recurrent state of fixed
+size in the engine's lane-state store (``state_kind``: ``"mamba"``, the SSM
+state ``d_state x d_inner`` in float32; ``"gdn"``, a matrix ``gdn_k_dim x
+gdn_v_dim`` in float32 a value head; both with the last ``d_conv - 1``
+inputs of the layer's causal convolution), so only attention layers own a
+layer of the page store (``store_layer``).
 
 The dense decoder the engine has always served is :func:`dense_spec` with
 today's constants (epsilon 1e-6, ``head_dim = d_model // n_heads``); the
@@ -101,6 +103,42 @@ and ``sa_config``.  A layer has ``wqkv`` = ``[q | k | v]``, ``q_norm`` /
                (``k_norm`` ``{"scale", "bias"}``) then RoPE
 ``ww``         ``(d_model, index_heads)``: the heads' weights ``c``
 =============  ==========================================================
+
+``qwen3_next`` (Qwen3-Next-80B-A3B): three Gated DeltaNet layers, then one
+gated softmax-attention layer (``full_attention_interval``); every layer's
+FFN is an expert layer (softmax router renormalised over the chosen k) with
+one shared expert scaled by a sigmoid gate.  :func:`qwen3_next_spec` reads
+the published keys; ``first`` / ``held`` give the contiguous share of the
+routed experts this device holds (the router keeps every column).  Norm
+scales hold ``1 + w`` of the published zero-centred weights.  An attention
+layer has ``wqkv`` = ``[q | k | v]`` where a query head's columns are
+``[query head_dim | gate head_dim]`` (the published ``q_proj``), ``q_norm``
+/ ``k_norm``, ``wo``; RoPE turns the first ``rotary_dim`` columns of a
+head.  Every layer has ``moe`` with ``router (d_model, E)`` and ``w13`` /
+``w2`` of the ``held`` experts, and ``shared`` ``w1 w3 w2`` plus ``gate
+(d_model, 1)``.  A Gated DeltaNet layer has, under ``gdn``:
+
+=============  ==========================================================
+``in_qkvz``    ``(d_model, 2 * Hk * d_k + 2 * Hv * d_v)``, columns ``[q | k |
+               v | z]``, heads in order inside each (the published
+               ``in_proj_qkvz`` interleaves them key head by key head, ``[q
+               d_k | k d_k | v (Hv / Hk) * d_v | z (Hv / Hk) * d_v]``:
+               :func:`split_qkvz` turns one into the other, once, at load
+               time; taken apart on the activations XLA copied the whole
+               matrix every dispatch, 0.13 ms a layer on a v5e)
+``in_ba``      ``(d_model, 2 * Hv)``, columns ``[b | a]`` (published: key
+               head by key head ``[b Hv / Hk | a Hv / Hk]``;
+               :func:`split_qkvz` again)
+``conv_w``     ``(d_conv, 2 * Hk * d_k + Hv * d_v)`` over the channels
+               ``[q | k | v]`` (heads in order inside each), tap ``k``
+               weighing the input ``d_conv - 1 - k`` tokens back; no bias
+``a_log``      ``(Hv,)``; ``dt_bias (Hv,)``
+``norm``       ``{"scale": (d_v,)}``: the gated RMSNorm of a head's output
+               (plain scale, not ``1 + w``)
+``out_proj``   ``(Hv * d_v, d_model)``
+=============  ==========================================================
+
+The multi-token-prediction layer of the published model is not built.
 """
 
 from __future__ import annotations
@@ -134,16 +172,25 @@ class ModelSpec:
     norm_topk: bool = True
     rms_eps: float = 1e-6
     rope_theta: Optional[float] = None
-    mixers: Tuple[str, ...] = ()            # "attention" | "mamba", one a layer
+    mixers: Tuple[str, ...] = ()            # "attention" | "mamba" | "gdn"
     d_inner: int = 0                        # mamba, all four
     d_state: int = 0
-    d_conv: int = 0
+    d_conv: int = 0                         # mamba and gdn
     dt_rank: int = 0
     index_heads: int = 0                    # learned indexer (gqa), all three;
     index_dim: int = 0                      # 0 = none: every key is attended
     index_topk: int = 0
     qk_norm: bool = False                   # RMSNorm over each head of q and k
     router: str = "sigmoid_bias"            # | "softmax" (no selection bias)
+    gdn_k_heads: int = 0                    # Gated DeltaNet, all four (+ d_conv)
+    gdn_v_heads: int = 0
+    gdn_k_dim: int = 0
+    gdn_v_dim: int = 0
+    attn_gate: bool = False                 # gqa: o * sigmoid(gate), from q_proj
+    rotary_dim: int = 0                     # gqa: RoPE columns; 0 = the head
+    experts_held: int = 0                   # routed experts here; 0 = all
+    expert_first: int = 0                   # the first of them
+    shared_gate: bool = False               # shared expert * sigmoid(h w_g)
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -154,7 +201,8 @@ class ModelSpec:
             if min(self.index_heads, self.index_dim, self.index_topk) < 1:
                 raise ValueError("an indexer gives index_heads, index_dim "
                                  "and index_topk")
-            if self.attention != "gqa" or "mamba" in (self.mixers or ()):
+            if self.attention != "gqa" or set(self.mixers or ()) - {
+                    "attention"}:
                 raise ValueError("an indexer selects keys of GQA attention "
                                  "on K/V pages only")
         kinds = self.layer_kinds or ("dense",) * self.n_layers
@@ -164,9 +212,37 @@ class ModelSpec:
         object.__setattr__(self, "layer_kinds", tuple(kinds))
         mixers = self.mixers or ("attention",) * self.n_layers
         if (len(mixers) != self.n_layers
-                or set(mixers) - {"attention", "mamba"}):
-            raise ValueError(f"mixers {mixers} does not name attention or "
-                             f"mamba for each of {self.n_layers} layers")
+                or set(mixers) - {"attention", "mamba", "gdn"}):
+            raise ValueError(f"mixers {mixers} does not name attention, "
+                             f"mamba or gdn for each of {self.n_layers} "
+                             "layers")
+        if "gdn" in mixers:
+            if (self.attention != "gqa" or "attention" not in mixers
+                    or "mamba" in mixers):
+                raise ValueError("Gated DeltaNet layers are served beside "
+                                 "GQA attention layers on K/V pages only, "
+                                 "and a lane state is of one kind")
+            if min(self.gdn_k_heads, self.gdn_v_heads, self.gdn_k_dim,
+                   self.gdn_v_dim, self.d_conv - 1) < 1 or (
+                       self.gdn_v_heads % self.gdn_k_heads):
+                raise ValueError(
+                    "a spec with Gated DeltaNet layers gives gdn_k_heads, "
+                    "gdn_v_heads (a multiple of them), gdn_k_dim, gdn_v_dim "
+                    "and d_conv (>= 2)")
+        if (self.attn_gate or self.rotary_dim) and (
+                self.attention != "gqa" or self.index_topk):
+            raise ValueError("attn_gate and rotary_dim belong to plain GQA "
+                             "attention")
+        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
+            raise ValueError(f"rotary_dim {self.rotary_dim} is not an even "
+                             f"part of head_dim {self.head_dim}")
+        held = self.experts_held or self.n_experts
+        if not 0 <= self.expert_first <= self.n_experts - held:
+            raise ValueError(
+                f"experts {self.expert_first} .. {self.expert_first + held} "
+                f"are not a share of the router's {self.n_experts}")
+        if self.shared_gate and not self.n_shared:
+            raise ValueError("shared_gate without a shared expert")
         if "mamba" in mixers:
             if self.attention != "gqa" or "attention" not in mixers:
                 raise ValueError("Mamba layers are served beside GQA "
@@ -203,6 +279,20 @@ class ModelSpec:
         return tuple(i for i, k in enumerate(self.mixers) if k == "mamba")
 
     @property
+    def state_kind(self) -> Optional[str]:
+        """The kind of per-lane state the model's layers keep beside the
+        pages: ``"mamba"``, ``"gdn"`` or None."""
+        for kind in ("mamba", "gdn"):
+            if kind in self.mixers:
+                return kind
+        return None
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that own a layer of the lane-state store."""
+        return tuple(i for i, k in enumerate(self.mixers) if k != "attention")
+
+    @property
     def attention_layers(self) -> Tuple[int, ...]:
         """The layers that own a layer of the page store."""
         return tuple(i for i, k in enumerate(self.mixers) if k == "attention")
@@ -210,8 +300,8 @@ class ModelSpec:
     def store_layer(self, layer: int) -> int:
         """Layer ``layer``'s index in the store of its mixer's kind: the
         page store's layer axis for an attention layer, the lane-state
-        store's for a Mamba layer (``layer`` itself where every mixer is
-        attention)."""
+        store's for a Mamba or Gated DeltaNet layer (``layer`` itself where
+        every mixer is attention)."""
         return self.mixers[:layer].count(self.mixers[layer])
 
 
@@ -334,6 +424,78 @@ def keye_vl2_spec(config: Dict[str, Any]) -> ModelSpec:
         index_topk=int(sa["topk"]))
 
 
+def qwen3_next_spec(config: Dict[str, Any], first: int = 0,
+                    held: Optional[int] = None) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type``
+    ``qwen3_next``).  Layer ``i`` is attention iff ``(i + 1) %
+    full_attention_interval == 0``, else Gated DeltaNet; every layer's FFN
+    is an expert layer.  ``first`` / ``held``: the contiguous share of the
+    ``num_experts`` routed experts this device holds (all of them by
+    default); the router keeps every column.  Refuses what the layer block
+    does not compute."""
+    if config.get("mlp_only_layers"):
+        raise ValueError("mlp_only_layers is not implemented (every layer "
+                         "is an expert layer)")
+    if int(config.get("decoder_sparse_step", 1)) != 1:
+        raise ValueError("decoder_sparse_step != 1 is not implemented")
+    if config.get("use_sliding_window"):
+        raise ValueError("use_sliding_window is not implemented")
+    if config.get("rope_scaling") is not None:
+        raise ValueError("rope_scaling is not implemented")
+    if config.get("attention_bias"):
+        raise ValueError("attention_bias is not implemented")
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("norm_topk_prob false is not implemented (the "
+                         "softmax router renormalises over the chosen k)")
+    moe_ff = int(config["moe_intermediate_size"])
+    shared = int(config["shared_expert_intermediate_size"])
+    if shared % moe_ff:
+        raise ValueError(f"shared_expert_intermediate_size {shared} is not "
+                         f"a multiple of moe_intermediate_size {moe_ff}")
+    n_layers = int(config["num_hidden_layers"])
+    period = int(config["full_attention_interval"])
+    head_dim = int(config["head_dim"])
+    n_experts = int(config["num_experts"])
+    return ModelSpec(
+        n_layers=n_layers, d_model=int(config["hidden_size"]),
+        n_heads=int(config["num_attention_heads"]),
+        n_kv_heads=int(config["num_key_value_heads"]), head_dim=head_dim,
+        layer_kinds=("moe",) * n_layers, n_experts=n_experts,
+        top_k=int(config["num_experts_per_tok"]), moe_ff=moe_ff,
+        n_shared=shared // moe_ff, shared_gate=True, router="softmax",
+        experts_held=n_experts if held is None else int(held),
+        expert_first=int(first), qk_norm=True, attn_gate=True,
+        rotary_dim=int(head_dim * float(config.get("partial_rotary_factor",
+                                                   1))),
+        rms_eps=float(config["rms_norm_eps"]),
+        rope_theta=float(config["rope_theta"]),
+        mixers=tuple("attention" if (i + 1) % period == 0 else "gdn"
+                     for i in range(n_layers)),
+        d_conv=int(config["linear_conv_kernel_dim"]),
+        gdn_k_heads=int(config["linear_num_key_heads"]),
+        gdn_v_heads=int(config["linear_num_value_heads"]),
+        gdn_k_dim=int(config["linear_key_head_dim"]),
+        gdn_v_dim=int(config["linear_value_head_dim"]))
+
+
+def split_qkvz(w, spec: ModelSpec):
+    """A published ``in_proj_qkvz`` ``(d_model, 2 * Hk * d_k + 2 * Hv *
+    d_v)`` or ``in_proj_ba`` ``(d_model, 2 * Hv)`` of a Gated DeltaNet layer
+    (told apart by their width), whose columns go key head by key head
+    (``[q | k | v | z]`` or ``[b | a]`` of one key head, then the next), as
+    the served ``in_qkvz`` / ``in_ba``: every head's ``q``, then every
+    head's ``k``, ``v``, ``z`` (or ``b``, then ``a``)."""
+    hk, rep = spec.gdn_k_heads, spec.gdn_v_heads // spec.gdn_k_heads
+    parts = ((spec.gdn_k_dim, spec.gdn_k_dim, rep * spec.gdn_v_dim,
+              rep * spec.gdn_v_dim)
+             if w.shape[1] != 2 * spec.gdn_v_heads else (rep, rep))
+    w = w.reshape(w.shape[0], hk, sum(parts))
+    cuts = np.cumsum((0,) + parts)
+    return np.concatenate(
+        [np.asarray(w[:, :, a:b]).reshape(w.shape[0], -1)
+         for a, b in zip(cuts[:-1], cuts[1:])], axis=1)
+
+
 def split_kv_b(kv_b, spec: ModelSpec):
     """A published ``kv_b_proj`` ``(kv_lora_rank, n_heads * (qk_nope +
     v_head_dim))``, a head's columns ``[k_nope | v]``, as ``(w_uk (H, nope,
@@ -347,8 +509,9 @@ def split_kv_b(kv_b, spec: ModelSpec):
 def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                 scale: float = 0.02) -> Dict[str, Any]:
     """Seeded random float32 parameters in the layouts above: an MLA (+
-    expert) decoder or a GQA decoder with an indexer, with an untied output
-    head, or a Mamba/attention hybrid with a tied one (no ``lm_head``).
+    expert) decoder, a GQA decoder with an indexer or a Gated DeltaNet /
+    attention hybrid, with an untied output head, or a Mamba/attention
+    hybrid with a tied one (no ``lm_head``).
     Weights normal ``scale``, norm scales 1 (LayerNorm biases 0), the
     ``"sigmoid_bias"`` router's selection bias drawn like a weight (not
     zero: choosing with it and weighting without it must differ).
@@ -358,14 +521,17 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     and a state that is dropped or rounded could not be seen in the
     logits): ``a_log = log(1..d_state)`` on every channel, ``d = 1``,
     ``dt_bias = softplus^-1(dt)`` with ``dt`` log-uniform in [1e-3, 1e-1],
-    the convolution uniform within ``d_conv ** -0.5``."""
+    the convolution uniform within ``d_conv ** -0.5``.  A Gated DeltaNet
+    layer's for the same reason: ``a_log = log(U(0, 16))``, ``dt_bias`` and
+    the convolution as Mamba's."""
     import jax
     import jax.numpy as jnp
 
-    if (spec.attention != "mla" and not spec.mamba_layers
-            and not spec.index_topk):
-        raise ValueError("init_params draws MLA decoders, Mamba hybrids and "
-                         "decoders with an indexer; dense ones come from "
+    if (spec.attention != "mla" and not spec.state_layers
+            and not spec.index_topk and not spec.attn_gate):
+        raise ValueError("init_params draws MLA decoders, hybrids with a "
+                         "lane state, decoders with an indexer or an output "
+                         "gate; dense ones come from "
                          "tpulab.models.transformer")
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                  16 * spec.n_layers + 4))
@@ -402,6 +568,20 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                     (n, di)),
                 "d": jnp.ones((di,), jnp.float32),
                 "out_proj": w(di, d)}
+        elif spec.mixers[i] == "gdn":
+            nk, nv = (spec.gdn_k_heads * spec.gdn_k_dim,
+                      spec.gdn_v_heads * spec.gdn_v_dim)
+            bound = spec.d_conv ** -0.5
+            dt = jnp.exp(uniform(np.log(1e-3), np.log(1e-1),
+                                 spec.gdn_v_heads))
+            p["gdn"] = {
+                "in_qkvz": w(d, 2 * nk + 2 * nv),
+                "in_ba": w(d, 2 * spec.gdn_v_heads),
+                "conv_w": uniform(-bound, bound, spec.d_conv, 2 * nk + nv),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "a_log": jnp.log(uniform(1e-6, 16.0, spec.gdn_v_heads)),
+                "norm": norm(spec.gdn_v_dim),
+                "out_proj": w(nv, d)}
         elif spec.attention == "mla":
             p.update(
                 wq_a=w(d, spec.q_lora_rank), q_norm=norm(spec.q_lora_rank),
@@ -412,7 +592,8 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                 w_uv=w(h, spec.kv_lora_rank, spec.v_head_dim),
                 wo=w(h * spec.v_head_dim, d))
         else:
-            p.update(wqkv=w(d, (h + 2 * spec.n_kv_heads) * spec.head_dim),
+            hq = 2 * h if spec.attn_gate else h   # a head's [query | gate]
+            p.update(wqkv=w(d, (hq + 2 * spec.n_kv_heads) * spec.head_dim),
                      wo=w(h * spec.head_dim, d))
             if spec.qk_norm:
                 p.update(q_norm=norm(spec.head_dim),
@@ -428,14 +609,17 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
             p.update(w1=w(d, d_ff), w3=w(d, d_ff), w2=w(d_ff, d))
         else:
             f, fs = spec.moe_ff, spec.n_shared * spec.moe_ff
+            held = spec.experts_held or spec.n_experts
             p["moe"] = {"router": w(d, spec.n_experts),
                         "bias": w(spec.n_experts),
-                        "w13": w(spec.n_experts, d, 2 * f),
-                        "w2": w(spec.n_experts, f, d)}
+                        "w13": w(held, d, 2 * f),
+                        "w2": w(held, f, d)}
             if spec.router != "sigmoid_bias":
                 del p["moe"]["bias"]
             if fs:
                 p["shared"] = {"w1": w(d, fs), "w3": w(d, fs),
                                "w2": w(fs, d)}
+                if spec.shared_gate:
+                    p["shared"]["gate"] = w(d, 1)
         params[f"layer{i}"] = p
     return params

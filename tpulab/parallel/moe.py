@@ -69,18 +69,25 @@ def route(router_w, x, top_k: int, kind: str = "softmax", bias=None,
         return idx, w * scale
 
 
-def routing_stats(idx, n_experts: int, valid=None):
-    """``(E + 2,)`` int32 counters of one routing: assignments per expert,
-    then the number of experts with at least one row, then 1 if any row
-    was valid (so that sums over steps count the steps that had work).
+def routing_stats(idx, n_experts: int, valid=None, first: int = 0,
+                  held=None):
+    """``(E + 2,)`` int32 counters of one routing: assignments per expert
+    (every column of the router), then the number of experts HELD HERE
+    (``first .. first + held``; all of them by default) with at least one
+    row, which is what the step reads of the routed weights, then 1 if any
+    row was valid (so that sums over steps count the steps that had work).
     ``valid (N,)`` masks rows that carry no token."""
     hot = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32).sum(axis=1)
     if valid is not None:
         hot = hot * valid.astype(jnp.int32)[:, None]
     counts = hot.sum(axis=0)
-    hit = (counts > 0).sum()
-    return jnp.concatenate([counts, hit[None], (hit > 0)[None]]
-                           ).astype(jnp.int32)
+    if held is None:
+        hit = (counts > 0).sum()
+        work = hit > 0
+    else:       # a step whose rows all chose experts held elsewhere had work
+        hit = (counts[first:first + held] > 0).sum()
+        work = counts.sum() > 0
+    return jnp.concatenate([counts, hit[None], work[None]]).astype(jnp.int32)
 
 
 def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
@@ -122,18 +129,26 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
 def routed_ffn(params: Dict[str, Any], x, top_k: int,
                compute_dtype=jnp.float32, router: str = "softmax",
                act: str = "gelu", scale: float = 1.0, norm: bool = True,
-               valid=None):
+               valid=None, first: int = 0, held=None):
     """Route (N, D) rows and run the experts: ``(out (N, D) float32, stats
     (E + 2,))``.  ``params``: ``router (D, E)``, ``bias (E,)`` for the
     ``"sigmoid_bias"`` router, and the experts as ``w13``/``w2``
-    (SwiGLU) or ``w1``/``w2`` (GELU)."""
-    from tpulab.models.transformer import qmat
+    (SwiGLU) or ``w1``/``w2`` (GELU).  ``first`` / ``held``: the share of
+    the router's ``E`` experts whose weights ``params`` holds (all of them
+    by default); the rows are routed over all ``E`` and the output is the
+    part the held experts give."""
+    from tpulab.models.transformer import qmat, weight_shape
     idx, weights = route(params["router"], x, top_k, router,
                          params.get("bias"), scale, norm)
     w_in = params["w13" if act == "swiglu" else "w1"]
+    n_experts = params["router"].shape[-1]
+    if weight_shape(w_in)[0] != (n_experts if held is None else held):
+        raise ValueError(f"{weight_shape(w_in)[0]} experts' weights for a "
+                         f"share of {held} of the router's {n_experts}")
     out = expert_ffn(x, idx, weights, qmat(w_in, compute_dtype),
-                     qmat(params["w2"], compute_dtype), act, compute_dtype)
-    return out, routing_stats(idx, params["router"].shape[-1], valid)
+                     qmat(params["w2"], compute_dtype), act, compute_dtype,
+                     first=first)
+    return out, routing_stats(idx, n_experts, valid, first, held)
 
 
 def moe_ffn(params: Dict[str, Any], x: jnp.ndarray, top_k: int = 2,
