@@ -26,25 +26,31 @@ GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                       "attention_kernels_f32_5829881.npz")
 
 
+def pallas_calls(fn, *args):
+    """The ``pallas_call`` equations that ``fn(*args)`` traces, in order,
+    those inside loops and conditionals included: ``params["name"]`` is
+    the kernel's name, ``params["jaxpr"]`` its body."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from calls(sub)
+    return list(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
 def kernel_eqns(fn, *args):
     """Every equation of the ONE ``pallas_call`` body that ``fn(*args)``
     traces, the bodies of its loops and conditionals included."""
-    def bodies(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                yield eqn.params["jaxpr"]
-            else:
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    yield from bodies(sub)
-
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             yield eqn
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from walk(sub)
 
-    (body,) = bodies(jax.make_jaxpr(fn)(*args).jaxpr)
-    return list(walk(body))
+    (call,) = pallas_calls(fn, *args)
+    return list(walk(call.params["jaxpr"]))
 
 
 def assert_operand_rule(fn, args, store_dtype, block_shape):
@@ -133,14 +139,18 @@ def assert_parents_bits(name, got):
     where the arithmetic is the generating machine's, to 1e-6 elsewhere.
     Entries that are NaN in the golden (rows the kernel leaves unwritten)
     must be NaN.  One entry is not the parent's: the one-row walk of the
-    K/V kernel (``ragged_paged_attention-one-row``) holds the bits of the
-    PR that brought the rule, 1.8e-7 from the parent's at the largest (72
-    of 128 values).  Its loop over a lane's blocks takes its trip count
-    from the lane's length where the parent's ran every block under a
-    conditional: the same operations in the same order, but XLA:CPU, which
-    compiles the interpreter's program, sums a one-row product in another
-    order inside the new loop (the conditional put back alone returns the
-    parent's bits)."""
+    K/V kernel (``ragged_paged_attention-one-row``).  PR 35, which brought
+    the rule, left it 1.8e-7 from its parent's at the largest (72 of 128
+    values): the loop over a lane's blocks takes its trip count from the
+    lane's length where the parent's ran every block under a conditional,
+    the same operations in the same order, but XLA:CPU, which compiles the
+    interpreter's program, sums a one-row product in another order inside
+    the new loop.  Since PR 40 one row a lane runs the kernel
+    ``ragged_paged_decode`` (a KV head's query heads the rows of one
+    product, one positional mask row): the entry holds PR 40's bits,
+    2.4e-7 from PR 35's at the largest (104 of 128 values), again the
+    summation order of other shapes and nothing else (both products stay
+    float32 at ``HIGHEST``)."""
     with np.load(GOLDEN) as golden:
         want, same_machine = golden[name], np.array_equal(golden["canary"],
                                                           canary())

@@ -26,6 +26,17 @@ and nothing else changed the parent's bits return).  The two entries
 (``mha``) and 8.94e-8 (``gqa_rope_swiglu``) from the parent's at the
 largest; every other entry is a365295's, and the kernel's rows at a chunk's
 width (``ragged``) stayed to the bit through that PR.
+
+Since PR 40 one row a lane goes through the kernel ``ragged_paged_decode``:
+the query heads of a KV head are the rows of ONE product and the block's
+mask is one positional row, where the rows kernel made a product a head
+against a mask a query row.  The same sums over the same keys, in the
+order XLA:CPU gives the new shapes: the two ``decode.kernel=True`` entries
+are PR 40's bits, 5.96e-8 (``mha``, 104 of 192 values) and 8.94e-8
+(``gqa_rope_swiglu``, 114 of 192) from the parent's (PR 35's) at the
+largest.  The round's decode row takes the same kernel (``mha.mixed``
+5.96e-8 from the golden, inside that case's 1e-6); ``ragged`` stays to the
+bit.
 """
 
 import os

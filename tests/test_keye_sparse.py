@@ -682,3 +682,28 @@ def test_mosaic_compiles_the_attention_kernel_at_the_published_widths(
         shape(lanes, dtype=jnp.int32), shape(lanes, dtype=jnp.int32)
     ).compile()
     assert "sparse_paged_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes,h,hkv,d,max_pages,pool", [
+    (8, 32, 8, 128, 512, (16, 1025)),
+    (32, 20, 1, 128, 512, (2, 8193)),
+    (32, 16, 2, 256, 1024, (2, 20481)),
+], ids=["mistral7b-l16-g4", "jamba2-3b-g20", "qwen3next-l8-ep4-g8-d256"])
+def test_mosaic_compiles_the_one_row_kv_kernel_at_the_cells_widths(
+        one_chip, lanes, h, hkv, d, max_pages, pool):
+    """The dense K/V walk at one row a lane slices a KV head's ``g`` query
+    heads out of its ``(H, D)`` block (here, beside the sparse walk that
+    shares its block function: one file describes the chip): groups of 4,
+    20 and 8 rows of bf16 are no whole tiles, and Mosaic takes them."""
+    from tpulab.ops.ragged_attention import _ragged_attn
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    i32s = lambda *dims: shape(*dims, dtype=jnp.int32)      # noqa: E731
+    text = _ragged_attn.lower(
+        shape(lanes, 1, h, d), shape(*pool, 2, 16, hkv * d), i32s(1),
+        i32s(lanes, max_pages), i32s(lanes), i32s(lanes),
+        interpret=False).compile().as_text()
+    assert "ragged_paged_decode" in text
+    assert "ragged_paged_attention" not in text

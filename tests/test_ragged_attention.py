@@ -38,8 +38,8 @@ from tpulab.ops.ragged_attention import (ragged_latent_attention,
 from tpulab.parallel import make_mesh
 
 from helpers_attention import (BF16_ATOL, BF16_RTOL, assert_operand_rule,
-                               assert_parents_bits, sparse_attend_case,
-                               sparse_decode_case)
+                               assert_parents_bits, pallas_calls,
+                               sparse_attend_case, sparse_decode_case)
 
 # ------------------------------------------------------------ kernel ----
 
@@ -100,29 +100,81 @@ def _shape_case(name, page_size):
 
 
 @functools.lru_cache(maxsize=None)
-def _grid_kernel(mesh_n):
+def _grid_kernel(mesh_n, g_pages=None, nbuf=None):
     """The kernel as the grid calls it, jitted once a mesh: the shapes of a
     (dtype, page size) share one compiled program (an eager ``shard_map``
     is traced and compiled again on every call)."""
     mesh = (make_mesh({"model": mesh_n}, jax.devices()[:mesh_n])
             if mesh_n else None)
-    return jax.jit(functools.partial(ragged_paged_attention, mesh=mesh))
+    return jax.jit(functools.partial(ragged_paged_attention, mesh=mesh,
+                                     g_pages=g_pages, nbuf=nbuf))
 
 
-@pytest.mark.parametrize("mesh_n", [None, 2])
-@pytest.mark.parametrize("page_size", [4, 8])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("shape", ["all_decode", "all_prefill", "mixed",
-                                   "verify", "page_cross", "skipped"])
-def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n):
+#: ``(group size g, KV heads, head width)`` of the one-row cases: the three
+#: cells' (Mistral 4 x 8 x 128, Qwen3-Next 8 x 2 x 256, Jamba 20 x 1 x 128),
+#: plain MHA, and each other value once more
+_ONE_ROW_HEADS = [(1, 2, 128), (1, 8, 256), (4, 8, 128), (8, 2, 256),
+                  (20, 1, 128), (20, 2, 256)]
+_GRID = [(shape, dtype, page_size, mesh_n, (2, 2, 16))
+         for mesh_n in (None, 2) for page_size in (4, 8)
+         for dtype in ("float32", "bfloat16")
+         for shape in ("all_decode", "all_prefill", "mixed", "verify",
+                       "page_cross", "skipped")]
+_GRID += [("one_row", dtype, page_size, mesh_n, heads)
+          for heads in _ONE_ROW_HEADS for mesh_n in (None, 2)
+          for page_size in (8, 16) for dtype in ("float32", "bfloat16")
+          if mesh_n is None or heads[1] % mesh_n == 0]
+
+
+def _one_row_case(page_size):
+    """``(q_lens, kv_lens, M, table width, g_pages, nbuf)`` of the one-row
+    shape: blocks of two pages in a pipeline two deep.  Lane 0's context is
+    four blocks (more than ``nbuf``: the loop refills a slot); lane 1 ends
+    on the first key of its second block's second page; lane 2 holds no row
+    (no DMA, its rows unwritten); lane 3's second block has one live page,
+    so the block's other half is what lane 1 left in the slot; lane 4 ends
+    on a block's last key.  :func:`_poisoned` makes every key past a lane's
+    length no number, so the stale half and the tail of each last page
+    reach the value product only through ``_zero_rows_past``."""
+    s = page_size
+    return ([1, 1, 0, 1, 1], [6 * s + 3, 3 * s + 1, 2 * s, 2 * s + 3, 4 * s],
+            1, 8, 2, 2)
+
+
+def _poisoned(pool, tables, q_lens, kv_lens):
+    """``pool`` with NaN in every row of the lanes' pages that no query
+    sees: the keys past a lane's length (the tail of its last page and the
+    pages after it) and every page of a lane without a row."""
+    page_size = pool.shape[3]
+    for lane, (qn, kvn) in enumerate(zip(q_lens, kv_lens)):
+        live = kvn if qn else 0
+        for i, page in enumerate(np.asarray(tables[lane])):
+            first = max(live - i * page_size, 0)
+            if first < page_size:
+                pool = pool.at[:, int(page), :, first:].set(jnp.nan)
+    return pool
+
+
+@pytest.mark.parametrize("shape,dtype,page_size,mesh_n,heads", _GRID, ids=[
+    "-".join(map(str, (c[0], c[1], c[2], c[3], "x".join(map(str, c[4])))))
+    for c in _GRID])
+def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n,
+                                       heads):
     """The parity drift guard of the satellite grid: every raggedness
-    shape x dtype x page size x mesh agrees with the dense reference."""
+    shape x dtype x page size x mesh agrees with the dense reference; at
+    one row a lane (``one_row``: the stacked kernel) also every group size
+    x KV heads x head width the serving cells have."""
     dt = jnp.dtype(dtype)
     rng = jax.random.PRNGKey(hash((shape, page_size)) % 2**31)
-    hq, hkv, d = 4, 2, 16
-    q_lens, kv_lens, m = _shape_case(shape, page_size)
+    g, hkv, d = heads
+    hq = g * hkv
+    if shape == "one_row":
+        q_lens, kv_lens, m, mp, g_pages, nbuf = _one_row_case(page_size)
+    else:
+        q_lens, kv_lens, m = _shape_case(shape, page_size)
+        mp = 4   # fixed table width: every shape reuses one compiled kernel
+        g_pages = nbuf = None
     b = len(q_lens)
-    mp = 4   # fixed table width: every shape reuses one compiled kernel
     pages = b * mp + 1
     ks = jax.random.split(rng, 3)
     q = jax.random.normal(ks[0], (b, m, hq, d), jnp.float32)
@@ -132,9 +184,11 @@ def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n):
                                jnp.float32)
     tables = jnp.asarray(
         np.arange(1, b * mp + 1).reshape(b, mp), jnp.int32)
-    got = _grid_kernel(mesh_n)(
-        q.astype(dt), _pool(k_pool, v_pool, dt), 0,
-        tables, jnp.asarray(q_lens, jnp.int32),
+    pool = _pool(k_pool, v_pool, dt)
+    got = _grid_kernel(mesh_n, g_pages, nbuf)(
+        q.astype(dt),
+        _poisoned(pool, tables, q_lens, kv_lens) if shape == "one_row"
+        else pool, 0, tables, jnp.asarray(q_lens, jnp.int32),
         jnp.asarray(kv_lens, jnp.int32))
     if dt == jnp.float32:
         want = _reference(np.asarray(q), np.asarray(k_pool),
@@ -144,17 +198,20 @@ def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n):
     else:
         # a bf16 store against the XLA form of the same step on the same
         # bf16 pages: both round the probabilities and the output to bf16
-        pool = _pool(k_pool, v_pool, dt)[0]
         pos = (jnp.asarray(kv_lens) - jnp.asarray(q_lens))[:, None] \
             + jnp.arange(m)[None, :]
         want = np.asarray(_gather_attend(
-            q.astype(dt), pool[:, 0], pool[:, 1], tables, pos, dt),
+            q.astype(dt), pool[0, :, 0], pool[0, :, 1], tables, pos, dt),
             np.float32).reshape(b, m, hq, d)
         tol = dict(rtol=BF16_RTOL, atol=BF16_ATOL)
+    got = np.asarray(got, np.float32)
     for bb in range(b):
         n = int(q_lens[bb])
-        np.testing.assert_allclose(
-            np.asarray(got, jnp.float32)[bb, :n], want[bb, :n], **tol)
+        np.testing.assert_allclose(got[bb, :n], want[bb, :n], **tol)
+        if shape == "one_row" and not n:
+            # unwritten: the interpreter starts an output as NaN, and a
+            # lane computed with no valid row would write numbers
+            assert np.isnan(got[bb]).all()
 
 
 def test_kernel_long_walk_exceeds_pipeline_depth():
@@ -319,15 +376,8 @@ def test_a_skipped_lane_starts_no_dma_and_writes_nothing(kernel):
     that one conditional the kernel body holds no DMA start or wait and no
     store, only the reads of its scalar words."""
     attend, _reference, q, q_lens, _kv_lens = _round_case(kernel)
-    jaxpr = jax.make_jaxpr(attend)(q, jnp.asarray(q_lens))
-
-    def kernels(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                yield eqn.params["jaxpr"]
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from kernels(sub)
-    (body,) = kernels(jaxpr.jaxpr)
+    (call,) = pallas_calls(attend, q, jnp.asarray(q_lens))
+    body = call.params["jaxpr"]
     top = [eqn.primitive.name for eqn in body.eqns]
     assert top.count("cond") == 1
     assert not {"dma_start", "dma_wait", "swap", "addupdate"} & set(top)
@@ -416,6 +466,58 @@ def test_kernel_takes_a_traced_layer():
             np.asarray(scanned[layer]),
             np.asarray(ragged_paged_attention(q, pool, layer, tables,
                                               q_lens, kv_lens)))
+
+
+def test_the_shape_selects_the_kernel_one_row_stacked_wider_rows_not():
+    """The step programs as they lower: a decode block's steps and a
+    packed round's decode rows go through ``ragged_paged_decode``, a
+    round's chunk rows and the K+1 verify form through
+    ``ragged_paged_attention``; one call a layer a segment kind, and
+    nothing but the rows' width chooses."""
+    from helpers_steps import decode_block, mixed_step
+    from tpulab.engine.kv_pool import PagedKVPool
+    from tpulab.engine.paged_steps import (pack_round, paged_decode_block,
+                                           paged_mixed_step,
+                                           paged_ragged_forward)
+
+    params = init_transformer_params(vocab=64, d_model=64, n_heads=4,
+                                     n_layers=2, d_ff=64, n_kv_heads=2)
+    kv = PagedKVPool(n_pages=10, page_size=8, n_layers=2, n_heads=2,
+                     head_dim=16, dtype=jnp.float32).kv
+    tables = np.asarray([[1, 2, 3, 0], [4, 5, 6, 0], [7, 8, 0, 0]], np.int32)
+    common = dict(n_heads=4, n_kv_heads=2, n_layers=2,
+                  compute_dtype=jnp.float32, use_kernel=True)
+    names = []
+
+    def _kernel_names(fn, *args):
+        return [call.params["name"] for call in pallas_calls(fn, *args)]
+
+    def traced(step):
+        def call(*args):
+            names.append(_kernel_names(step, *args))
+            return step(*args)
+        return call
+
+    decode_block(
+        traced(functools.partial(paged_decode_block, lanes=3, max_pages=4,
+                                 k=2, **common)),
+        params, kv, tables, ([12, 6, 0], [3, 9, 0], [True, True, False],
+                             [2, 2, 0]), 2)
+    toks, row_lane, row_off, q_lens = pack_round(
+        3, {0: np.arange(4)}, {1: 5})
+    mixed_step(
+        traced(functools.partial(paged_mixed_step, lanes=3, max_pages=4,
+                                 **common)),
+        params, kv, tables, toks, row_lane, row_off, q_lens, [12, 6, 0])
+    i32 = lambda x: jnp.asarray(x, jnp.int32)        # noqa: E731
+    names.append(_kernel_names(
+        lambda p, kv: paged_ragged_forward(        # K + 1 = 5 rows a lane
+            p, kv, i32(tables), i32(np.zeros((3, 5))), i32([5, 5, 0]),
+            i32([12, 9, 0]), **common), params, kv))
+    block, round_, verify = names
+    assert block == ["ragged_paged_decode"] * 2          # the scan's body
+    assert round_ == ["ragged_paged_attention", "ragged_paged_decode"] * 2
+    assert verify == ["ragged_paged_attention"] * 2
 
 
 # ------------------------------------------------------------ engine ----

@@ -1,10 +1,10 @@
 """Pallas ragged paged-attention kernel family (decode / verify / prefill).
 
-One kernel serves every paged-attention shape the engine dispatches
-("Ragged Paged Attention", PAPERS.md): each lane carries a *segment* of
-``q_lens[b]`` query tokens ending at context position ``kv_lens[b] - 1``
-over its own block table of KV pages.  Per-lane segment lengths key the
-whole family:
+One entry, :func:`_ragged_attn`, serves every paged-attention shape the
+engine dispatches ("Ragged Paged Attention", PAPERS.md): each lane carries
+a *segment* of ``q_lens[b]`` query tokens ending at context position
+``kv_lens[b] - 1`` over its own block table of KV pages.  Per-lane segment
+lengths key the whole family:
 
 - plain decode: ``q_lens = 1`` per live lane (the old single-query
   kernel's shape);
@@ -14,23 +14,41 @@ whole family:
   chunk (``q_lens = chunk``), decoding lanes carry 1 — ONE fused program
   over the ragged batch instead of separate prefill and decode kinds.
 
+Which shape takes which kernel — the rows' width ``M`` decides, at trace
+time, and nothing else does (no argument, option or model name):
+
+- ``M == 1`` (a decode step of ``paged_decode_block``; a packed round's
+  decode rows) -> ``ragged_paged_decode`` (:func:`_ragged_decode_kernel`):
+  the ``g = H / Hkv`` query heads of a KV head are the ROWS of one dot, so
+  a key block costs ``Hkv`` dot pairs and its K and V pass the MXU once.
+  Its per-block work is :func:`_stacked_block`, which
+  ``sparse_paged_decode`` (:mod:`tpulab.ops.sparse_attention`) calls too:
+  the two differ in the block's mask alone (positional here, a slice of
+  the selection's mask row there).
+- ``M > 1`` (a round's chunk rows, the K+1 verify form, the padded form)
+  -> ``ragged_paged_attention`` (:func:`_ragged_attn_kernel`): a query
+  head at a time, ``M`` rows a dot, ``H`` dot pairs a block.  At one row
+  that was ``g`` passes of the same K and V block for one live row of a
+  padded tile each (PR 40: 1.46 -> 0.42 ms a call at 32 lanes, 16 heads on
+  2 KV heads of 256, ~3 k keys a lane, on a v5e).
+
 A lane pays for the query rows it holds.  A grid step computes all ``M``
 rows of its block whatever ``q_lens`` says, so ``M`` is the width the
 caller chooses, and a lane with ``q_lens == 0`` is *skipped*: no page
 DMA, no walk, no dot, its output block unwritten.  A packed round
-(:func:`tpulab.engine.paged_steps.paged_mixed_step`) therefore calls the
-kernel twice a layer, once a segment kind, on the same pages and
-``kv_lens``: the chunk rows at ``(B, M)`` with ``q_lens`` zeroed for the
-lanes that hold no chunk, and the decode rows at ``(B, 1)``, a decode
+(:func:`tpulab.engine.paged_steps.paged_mixed_step`) therefore calls
+:func:`_ragged_attn` twice a layer, once a segment kind, on the same pages
+and ``kv_lens``: the chunk rows at ``(B, M)`` with ``q_lens`` zeroed for
+the lanes that hold no chunk, and the decode rows at ``(B, 1)``, a decode
 step's shape, with ``q_lens`` zeroed for the lanes that hold a chunk or
 nothing.  The padded form (K+1 verify) and the decode step call it once.
 
 The XLA fallback gathers every lane's pages into a dense
-``(B, MP*S, H, D)`` tensor; this kernel walks the block table per lane,
+``(B, MP*S, H, D)`` tensor; these kernels walk the block table per lane,
 DMA-ing fused K/V pages from HBM into VMEM scratch (one DMA per page)
 through an ``nbuf``-deep slot-rotation prefetch pipeline over blocks of
-``g_pages`` pages (:func:`_block_geometry`), and accumulates softmax
-online per query row — O(block) VMEM, no gather
+``g_pages`` pages (:func:`_block_geometry`, :func:`_page_walk`), and
+accumulate softmax online per query row — O(block) VMEM, no gather
 materialization, dead pages skipped by predication.
 
 The MXU is fed what the store holds (:func:`mxu_operands`): a bf16 (or
@@ -41,12 +59,13 @@ XLA form of the step rounds them; a float32 store keeps both at
 
 Per-head compute rides the flash-attention dot shapes (2D matmuls only,
 the Mosaic-serialization-safe subset :mod:`flash_attention` already
-uses): for each query head the block's scores are
+uses): in the rows kernel, for each query head, the block's scores are
 ``q_h (M, D) x k_h^T -> (M, G*S)`` and the weighted values
-``p (M, G*S) x v_h -> (M, D)``, with the running (max, normalizer,
-accumulator) carried per head through the block walk.  GQA stages pages
-in the compact ``Hkv`` form (the bandwidth win) and slices each query
-head's KV block statically in VMEM.
+``p (M, G*S) x v_h -> (M, D)``; in the one-row kernel, for each KV head,
+``q_g (g, D) x k^T -> (g, G*S)`` and ``p (g, G*S) x v -> (g, D)``; the
+running (max, normalizer, accumulator) carried per head (per KV head)
+through the block walk.  GQA stages pages in the compact ``Hkv`` form (the
+bandwidth win) and slices each head's KV block statically in VMEM.
 
 The pool goes in whole, ``(L, P, 2, S, Hkv*D)`` as
 :class:`~tpulab.engine.kv_pool.PagedKVPool` keeps it, with the layer as one
@@ -58,7 +77,8 @@ of the pool per call).
 Sharded serving: ``mesh=`` wraps the kernel in ``shard_map`` over the
 KV heads — each model-axis shard walks the SAME replicated block
 tables but DMAs only its own heads' share of each page row (matching
-``kv_pool_sharding``) and attends its own query heads, so the kernel
+``kv_pool_sharding``) and attends its own query heads (a shard holds whole
+groups: ``g`` is what it is unsharded), so the kernel
 composes with the tensor-parallel engine instead of being rejected at
 construction.  ``interpret=True`` (automatic off TPU) runs the same
 kernel on CPU for hermetic tests — tier-1 exercises the real kernel
@@ -143,24 +163,46 @@ def _plan(m: int, h: int, hkv: int, d: int, page_size: int, max_pages: int,
     return g_pages, nbuf, kv_buf + q_o_blocks + carry + scores
 
 
+def _stacked_plan(h: int, hkv: int, d: int, page_size: int, max_pages: int,
+                  q_dtype, kv_dtype, g_pages: int | None = None,
+                  nbuf: int | None = None) -> tuple[int, int, int]:
+    """:func:`_plan` for a walk at one row a lane with a KV head's query
+    heads stacked (``ragged_paged_decode``, ``sparse_paged_decode``): the
+    ``H / Hkv`` rows of one dot and a carry a KV head."""
+    return _plan(h // hkv, hkv, hkv, d, page_size, max_pages, q_dtype,
+                 kv_dtype, g_pages, nbuf)
+
+
 def kernel_geometry_error(q_len: int, n_heads: int, n_kv_heads: int,
                           head_dim: int, page_size: int, max_pages: int,
                           q_dtype, kv_dtype, g_pages: int | None = None,
                           nbuf: int | None = None) -> str | None:
-    """Why Mosaic cannot have the ragged kernel at this geometry, or None.
+    """Why Mosaic cannot have the ragged kernels at this geometry, or None.
 
-    The rule that selects and rejects the kernel — from shapes alone, so
+    The rule that selects and rejects the kernels — from shapes alone, so
     a caller learns it at construction and a real compile error is never
-    caught to mean "use the other path".  Under a mesh pass the PER-SHARD
-    head counts.  Constraints (each seen on a v5e, jax 0.9.0):
+    caught to mean "use the other path".  ``q_len`` is the widest segment
+    a dispatch carries: more than one row takes ``ragged_paged_attention``
+    (a query head at a time), ONE row takes ``ragged_paged_decode`` (a KV
+    head's ``g = n_heads / n_kv_heads`` query heads the rows of one dot).
+    An engine runs decode steps whatever its widest segment is, so the
+    one-row kernel is held to the rule at every ``q_len``.  Under a mesh
+    pass the PER-SHARD head counts (``g`` is the same).  Constraints (each
+    seen on a v5e, jax 0.9.0):
 
     - a page is DMA'd into a slice of the VMEM pipeline buffer, and
       Mosaic refuses a slice that is not whole tiles: the page row
       ``n_kv_heads * head_dim`` must be a multiple of 128 lanes and
       ``page_size`` a multiple of 8 sublanes;
     - one grid step's VMEM (:func:`_plan`) must fit the most the kernel
-      may request; ``q_len`` (the widest segment) and the heads per shard
-      drive it.
+      may request; ``q_len`` and the heads per shard drive it in the rows
+      kernel, ``g`` rows and ``n_kv_heads`` carries in the one-row kernel
+      (:func:`_stacked_plan`);
+    - a group's rows need NOT be whole tiles: the one-row kernel slices
+      ``g`` rows out of its ``(H, D)`` query block and stores ``g`` rows
+      of its output, and Mosaic takes groups of 1, 2, 4, 8 and 20 rows, in
+      bf16 and in float32, as they are (compiled for a v5e and run on one,
+      PR 40), so the wrapper pads nothing and no ``g`` is refused.
     """
     row = n_kv_heads * head_dim
     if row % _LANES:
@@ -169,13 +211,20 @@ def kernel_geometry_error(q_len: int, n_heads: int, n_kv_heads: int,
     if page_size % _SUBLANES:
         return (f"page_size {page_size} is not a multiple of {_SUBLANES} "
                 "sublanes")
-    need = _plan(q_len, n_heads, n_kv_heads, head_dim, page_size, max_pages,
-                 q_dtype, kv_dtype, g_pages, nbuf)[2]
+    limit = f"exceeds the {_VMEM_REQUEST_MAX >> 20} MiB it may request"
+    need = _stacked_plan(n_heads, n_kv_heads, head_dim, page_size, max_pages,
+                         q_dtype, kv_dtype, g_pages, nbuf)[2]
     if need > _VMEM_REQUEST_MAX:
-        return (f"kernel VMEM {need >> 20} MiB for q_len={q_len}, "
-                f"{n_heads} q heads x {head_dim} exceeds the "
-                f"{_VMEM_REQUEST_MAX >> 20} MiB it may request (shorten "
-                "the segment: prefill_chunk, or shard the heads)")
+        return (f"kernel VMEM {need >> 20} MiB for one row a lane, "
+                f"{n_kv_heads} groups of {n_heads // n_kv_heads} rows x "
+                f"{head_dim}, {limit} (shard the heads)")
+    if q_len > 1:
+        need = _plan(q_len, n_heads, n_kv_heads, head_dim, page_size,
+                     max_pages, q_dtype, kv_dtype, g_pages, nbuf)[2]
+        if need > _VMEM_REQUEST_MAX:
+            return (f"kernel VMEM {need >> 20} MiB for q_len={q_len}, "
+                    f"{n_heads} q heads x {head_dim} {limit} (shorten "
+                    "the segment: prefill_chunk, or shard the heads)")
     return None
 
 
@@ -250,6 +299,69 @@ def _zero_rows_past(kv_buf, slot, which: int, first_row, length):
         blk = kv_buf[slot, which]
         kv_buf[slot, which] = jnp.where(row <= length, blk,
                                         jnp.zeros_like(blk))
+
+
+def _stacked_carry(n_kv_heads: int, g: int, head_dim: int):
+    """The start of a stacked one-row walk's carry: ``(running maximum (g,
+    1), normaliser (g, 1), accumulator (g, D))`` a KV head, float32."""
+    return tuple((jnp.full((g, 1), _NEG, jnp.float32),
+                  jnp.zeros((g, 1), jnp.float32),
+                  jnp.zeros((g, head_dim), jnp.float32))
+                 for _ in range(n_kv_heads))
+
+
+def _stacked_operands(q_ref, kv_buf, sm_scale: float):
+    """``(q, dot_qk, dot_pv)`` of a stacked one-row walk: the lane's query
+    block ``q_ref[0] (Hkv * g, D)`` scaled and rounded ONCE to the dtype
+    :func:`mxu_operands` gives both products, and the two 2D dots at its
+    precision (scores contract over D with the K block transposed, values
+    in the standard orientation), float32 out."""
+    dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
+    q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)
+    dot_qk = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+    dot_pv = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision)
+    return q, dot_qk, dot_pv
+
+
+def _stacked_block(q, kblk, vblk, mask, carry, dot_qk, dot_pv):
+    """One key block of a walk at ONE query row a lane, the ``g`` query
+    heads of a KV head stacked into the rows of one dot: ``Hkv`` dot pairs
+    a block, where a head at a time is ``H`` pairs of one live row each.
+    The one body of the family's one-row walks (``ragged_paged_decode``,
+    ``sparse_paged_decode``), which differ in the block's ``mask`` alone.
+
+    ``q (Hkv * g, D)`` scaled and in the products' dtype, head ``hk``'s
+    group rows ``hk * g`` on; ``kblk``, ``vblk (G*S, Hkv*D)`` the staged
+    block; ``mask (1, G*S)`` bool, the keys of the block the row sees;
+    ``carry`` as :func:`_stacked_carry` starts it.  Returns the carry."""
+    g, d = carry[0][2].shape
+    maskf = mask.astype(jnp.float32)
+    out = []
+    for hk, (m_c, l_c, acc_c) in enumerate(carry):
+        cols = slice(hk * d, (hk + 1) * d)
+        s = dot_qk(q[hk * g:(hk + 1) * g], kblk[:, cols])
+        s = jnp.where(mask, s, _NEG)                          # (g, G*S)
+        m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_c - m_new)
+        p = jnp.exp(s - m_new) * maskf
+        out.append((m_new,
+                    l_c * alpha + p.sum(axis=1, keepdims=True),
+                    acc_c * alpha + dot_pv(p.astype(q.dtype),
+                                           vblk[:, cols])))
+    return tuple(out)
+
+
+def _stacked_store(o_ref, carry):
+    """The end of a stacked one-row walk: each group's accumulator over
+    its normaliser into the group's rows of ``o_ref (1, Hkv * g, D)``."""
+    for hk, (_m, l_c, acc_c) in enumerate(carry):
+        g = acc_c.shape[0]
+        o_ref[0, hk * g:(hk + 1) * g] = (
+            acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
 def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
@@ -347,6 +459,88 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
                 acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
+def _ragged_decode_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref,
+                          q_ref, kvpool_ref, o_ref, kv_buf, sem, *,
+                          page_size: int, max_pages: int, n_kv_heads: int,
+                          sm_scale: float, g_pages: int, nbuf: int):
+    """One lane's ONE query row against the lane's K/V pages, the query
+    heads of a KV head the rows of one dot (:func:`_stacked_block`): the
+    rows kernel at ``M = 1`` pushed every K and V block through the MXU
+    ``H / Hkv`` times, a padded tile of rows for the one that counts.
+    ``q_ref``, ``o_ref (1, Hkv * g, D)``.  At one row the mask of a block
+    is positional: every key at or before the lane's last position."""
+    lane = pl.program_id(0)
+    layer = layer_ref[0]
+
+    @pl.when(qlens_ref[lane] > 0)        # see _ragged_attn_kernel
+    def _lane():
+        length = jnp.maximum(kvlens_ref[lane], 1) - 1
+        gs = g_pages * page_size
+        start_block, wait_block, live_blocks = _page_walk(
+            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+            nbuf=nbuf, n_blocks=(max_pages + g_pages - 1) // g_pages)
+
+        q, dot_qk, dot_pv = _stacked_operands(q_ref, kv_buf, sm_scale)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, gs), 1)
+
+        def body(j, carry):
+            slot = jax.lax.rem(j, nbuf)
+            wait_block(j, slot)
+            # (a block past the lane's pages has no trip)
+            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
+            _zero_rows_past(kv_buf, slot, 1, j * gs, length)
+            return _stacked_block(
+                q, kv_buf[slot, 0].astype(q.dtype),
+                kv_buf[slot, 1].astype(q.dtype), j * gs + col <= length,
+                carry, dot_qk, dot_pv)
+
+        _stacked_store(o_ref, jax.lax.fori_loop(
+            0, live_blocks, body,
+            _stacked_carry(n_kv_heads, q.shape[0] // n_kv_heads,
+                           q.shape[1])))
+
+
+def _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
+                   interpret: bool, g_pages, nbuf):
+    """:func:`_ragged_attn` at one query row a lane: ``q (B, 1, H, D)``
+    through the kernel ``ragged_paged_decode``."""
+    b, _one, h, d = q.shape
+    page_size, row = kv_pool.shape[3], kv_pool.shape[4]
+    hkv = row // d
+    max_pages = tables.shape[1]
+    g_pages, nbuf, need = _stacked_plan(h, hkv, d, page_size, max_pages,
+                                        q.dtype, kv_pool.dtype, g_pages, nbuf)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,    # layer, tables (flat), q_lens, kv_lens
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, h, d), lambda lane, *_: (lane, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # KV pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((1, h, d), lambda lane, *_: (lane, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((nbuf, 2, g_pages * page_size, row), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((nbuf, g_pages)),  # one DMA per page
+        ],
+    )
+    kernel = functools.partial(
+        _ragged_decode_kernel, page_size=page_size, max_pages=max_pages,
+        n_kv_heads=hkv, sm_scale=1.0 / np.sqrt(d), g_pages=g_pages,
+        nbuf=nbuf)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
+            max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
+        interpret=interpret,
+        name="ragged_paged_decode",
+    )(layer, tables.reshape(-1), q_lens, kv_lens, q.reshape(b, h, d),
+      kv_pool)
+    return out.reshape(b, 1, h, d)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "g_pages", "nbuf"))
 def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
@@ -365,6 +559,12 @@ def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
                                     q.dtype, kv_pool.dtype, g_pages, nbuf)
         if err:
             raise ValueError(f"ragged_paged_attention: {err}")
+    if m == 1:
+        # one row a lane (a decode step, a round's decode rows): the heads
+        # of a KV head stack into one dot's rows.  The shape decides,
+        # nothing else
+        return _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
+                              interpret, g_pages, nbuf)
     # the pool goes in as it is stored — a reshape or a slice of it ahead
     # of the call would be a copy of a layer of the pool on every call (a
     # pallas_call operand is not fused into); queries as (B, M, H*D)
@@ -441,6 +641,9 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
     tables/lengths/layer replicated) so the kernel compiles inside the
     engine's tensor-parallel jits.
     ``g_pages``/``nbuf`` override the auto block geometry.
+    ``M == 1`` runs the kernel ``ragged_paged_decode`` (a KV head's query
+    heads the rows of one dot), ``M > 1`` ``ragged_paged_attention`` (a
+    query head at a time): the module docstring says why.
     Numerics: both matrix products of a key block follow
     :func:`mxu_operands` (a store under 32 bits: the query's dtype in one
     pass, float32 accumulation; a float32 store: ``HIGHEST``).

@@ -29,7 +29,10 @@ rows of the padded form.  Three steps a layer:
     a time; a lane that holds no row of the call is skipped.  One row a
     lane (a decode step, a round's decode rows) goes through
     :func:`sparse_attend_decode`, the same walk with the query heads of a
-    KV head stacked into the rows of one dot.  Both kernels feed the MXU
+    KV head stacked into the rows of one dot (the block's work is
+    :func:`tpulab.ops.ragged_attention._stacked_block`, which the dense
+    K/V walk at one row shares: this one's mask is the slice of the row's
+    mask, that one's positional).  Both kernels feed the MXU
     by :func:`tpulab.ops.ragged_attention.mxu_operands`: a bf16 store gives
     both products bf16 operands in one pass (the probabilities rounded as
     :func:`sparse_attend_xla` rounds them), a float32 store ``HIGHEST``.
@@ -57,7 +60,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpulab.ops.ragged_attention import (_NEG, _VMEM_REQUEST_MAX,
                                          _VMEM_SCOPED_DEFAULT, _plan,
-                                         _zero_rows_past, mxu_operands)
+                                         _stacked_block, _stacked_carry,
+                                         _stacked_operands, _stacked_plan,
+                                         _stacked_store, _zero_rows_past,
+                                         mxu_operands)
 
 #: key slots one grid step of ``dsa_index_scores`` scores (a multiple of the
 #: page size that divides the table's width; fewer steps of ~0.35 us each)
@@ -466,50 +472,24 @@ def _sparse_decode_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
             block(jj, jj, start)
         jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
 
-        dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
-        q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)    # (H, D)
-        dot_qk = functools.partial(
-            jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
-        dot_pv = functools.partial(
-            jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
+        q, dot_qk, dot_pv = _stacked_operands(q_ref, kv_buf, sm_scale)
 
         def body(j, carry):
             slot = jax.lax.rem(j, nbuf)
             block(j, slot, wait)
             block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf), start)
             _zero_rows_past(kv_buf, slot, 1, j * gs, length)
-            kblk = kv_buf[slot, 0].astype(dt)                # (G*S, Hkv*D)
-            vblk = kv_buf[slot, 1].astype(dt)
-            mask = mask_ref[0, :, pl.ds(pl.multiple_of(j * gs, gs),
-                                        gs)] != 0                  # (1, G*S)
-            maskf = mask.astype(jnp.float32)
-            out = []
-            for hk in range(hkv):
-                m_c, l_c, acc_c = carry[hk]
-                cols = slice(hk * d, (hk + 1) * d)
-                s = dot_qk(q[hk * g:(hk + 1) * g], kblk[:, cols])
-                s = jnp.where(mask, s, _NEG)                      # (g, G*S)
-                m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
-                alpha = jnp.exp(m_c - m_new)
-                p = jnp.exp(s - m_new) * maskf
-                out.append((m_new,
-                            l_c * alpha + p.sum(axis=1, keepdims=True),
-                            acc_c * alpha + dot_pv(p.astype(dt),
-                                                   vblk[:, cols])))
-            return tuple(out)
+            return _stacked_block(
+                q, kv_buf[slot, 0].astype(q.dtype),          # (G*S, Hkv*D)
+                kv_buf[slot, 1].astype(q.dtype),
+                mask_ref[0, :, pl.ds(pl.multiple_of(j * gs, gs),
+                                     gs)] != 0,                    # (1, G*S)
+                carry, dot_qk, dot_pv)
 
-        init = tuple((jnp.full((g, 1), _NEG, jnp.float32),
-                      jnp.zeros((g, 1), jnp.float32),
-                      jnp.zeros((g, d), jnp.float32)) for _ in range(hkv))
+        init = _stacked_carry(hkv, g, d)
         # the lane's live blocks: one past its length is never walked
-        final = jax.lax.fori_loop(0, (n_pages + g_pages - 1) // g_pages,
-                                  body, init)
-        for hk in range(hkv):
-            _m, l_c, acc_c = final[hk]
-            o_ref[0, hk * g:(hk + 1) * g] = (
-                acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
+        _stacked_store(o_ref, jax.lax.fori_loop(
+            0, (n_pages + g_pages - 1) // g_pages, body, init))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -519,10 +499,8 @@ def _sparse_decode(q, mask, kv_pool, layer, tables, lane_live, kv_lens,
     page_size, row = kv_pool.shape[3], kv_pool.shape[4]
     hkv = row // d
     max_pages = tables.shape[1]
-    # the walk's geometry and VMEM as the rows kernel plans them, for the
-    # H / Hkv rows of one dot and a carry a KV head
-    g_pages, nbuf, need = _plan(h // hkv, hkv, hkv, d, page_size, max_pages,
-                                q.dtype, kv_pool.dtype)
+    g_pages, nbuf, need = _stacked_plan(h, hkv, d, page_size, max_pages,
+                                        q.dtype, kv_pool.dtype)
     gs = g_pages * page_size
     wp = -(-max_pages // g_pages) * gs
     # a lane's mask row as a block of its own, (1, W): int32, so that a row
