@@ -43,6 +43,13 @@ Phases, in order; any phase that raises fails the run (exit 1):
               on a lane-state store filled with junk: three mixed rounds
               and a decode step through the ``chunk_gated_delta_rule`` and
               ragged kernels against the XLA forms, logits and lane state.
+   evabyte  — a two-layer EvaByte (its published widths: 32 heads on 32 KV
+              heads of 128, SwiGLU 11008, windows of 2,048 bytes in chunks
+              of 16, 320 rows, eight prediction heads) through
+              ``ContinuousBatcher``: a prompt across two window boundaries
+              (two compactions in rounds, the ``eva_chunk_summary`` kernel),
+              then decode across a third, against the plain float32
+              reference (``perf/reference/evabyte.py``).
 4. kernels  — the ragged and flash Pallas kernels compiled by Mosaic
               (``interpret=False``, custom call present in the lowered
               program) against the XLA gather / dense-softmax paths.
@@ -149,6 +156,14 @@ class Sizes:
         shared_expert_intermediate_size=512, norm_topk_prob=True,
         rms_norm_eps=1e-6, rope_theta=1e7, vocab_size=50304))
     qwen3_next_share: tuple = (32, 32)     # first, held
+    # EvaByte's published widths (perf/configs/evabyte-l8.json); depth is
+    # the cut
+    evabyte: dict = field(default_factory=lambda: dict(
+        model_type="evabyte", attention_class="eva", hidden_size=4096,
+        intermediate_size=11008, num_attention_heads=32,
+        num_key_value_heads=32, num_hidden_layers=2, num_pred_heads=8,
+        window_size=2048, chunk_size=16, rms_norm_eps=1e-5, rope_theta=1e5,
+        vocab_size=320))
     lm_max_len: int = 512
     lm_page_size: int = 16
     lm_prefill_chunk: int = 128
@@ -193,6 +208,11 @@ REHEARSAL_SIZES = Sizes(
         shared_expert_intermediate_size=32, norm_topk_prob=True,
         rms_norm_eps=1e-6, rope_theta=1e4, vocab_size=256),
     qwen3_next_share=(4, 4),
+    evabyte=dict(
+        model_type="evabyte", attention_class="eva", hidden_size=64,
+        intermediate_size=96, num_attention_heads=4, num_key_value_heads=4,
+        num_hidden_layers=2, num_pred_heads=3, window_size=64, chunk_size=8,
+        rms_norm_eps=1e-5, rope_theta=1e5, vocab_size=64),
     lm_max_len=96, lm_page_size=8, lm_prefill_chunk=16,
     lm_prompt_lens=(5, 12, 40), lm_steps=6, flash_t=32)
 
@@ -786,6 +806,67 @@ def phase_qwen3_next(smoke: Smoke) -> str:
             f"{first}..{first + held} held here")
 
 
+def phase_evabyte(smoke: Smoke) -> str:
+    """One stream through the scheduler with the kernels on: a prompt eight
+    rows short of its third window (two compactions in mixed rounds), then
+    sixteen greedy tokens across the third boundary (a compaction between
+    decode blocks), its log-probabilities against the benchmark's plain
+    float32 reference, which knows no page, row or kernel."""
+    import importlib.util
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpulab.engine.paged import ContinuousBatcher
+    from tpulab.engine.paged_steps import paged_eva_compact
+    from tpulab.models.spec import evabyte_spec, init_params
+    cfg = smoke.sizes.evabyte
+    spec = evabyte_spec(cfg)
+    vocab, window, chunk = cfg["vocab_size"], spec.eva_window, spec.eva_chunk
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        init_params(spec, vocab, cfg["intermediate_size"]))
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perf",
+                        "reference", "evabyte.py")
+    mod_spec = importlib.util.spec_from_file_location("ref_evabyte", path)
+    reference = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(reference)
+    steps = 16
+    prompt = np.random.default_rng(6).integers(
+        0, vocab, 3 * window - 8).tolist()
+    cb = ContinuousBatcher(params, spec.n_heads, spec.n_layers, spec=spec,
+                           lanes=2, max_len=4 * window, page_size=chunk,
+                           compute_dtype=jnp.bfloat16, use_kernel=True)
+    try:
+        free = cb.pool.free_pages
+        check_mosaic(smoke, "evabyte compaction", partial(
+            paged_eva_compact, spec=spec, use_kernel=True), params,
+            cb.pool.kv, jnp.arange(1, 1 + window // chunk, dtype=jnp.int32))
+        toks, lps = cb.submit(prompt, steps=steps, logprobs=True).result(
+            timeout=900)
+        eva = cb.debug_state()["eva"]
+        home = cb.pool.free_pages == free
+    finally:
+        cb.shutdown()
+    got = reference.compare(params, prompt, toks, lps,
+                            **reference.hyper_of(cfg))
+    if eva["compactions"] != {"round": 2, "decode": 1} or not home:
+        raise AssertionError(f"evabyte: compactions {eva['compactions']}, "
+                             f"pages home: {home}")
+    if not max(got["logprob_err"], got["argmax_gap"]) <= reference.TOLERANCE \
+            or not got["logprob_err_max"] <= reference.MAX_TOLERANCE:
+        raise AssertionError(f"evabyte: the served stream is {got} from the "
+                             f"reference (limit {reference.TOLERANCE})")
+    return (f"prompt of {len(prompt)} and {steps} tokens across position "
+            f"{3 * window}: logprob_err median {got['logprob_err']:.4g} "
+            f"(limit {reference.TOLERANCE}) largest "
+            f"{got['logprob_err_max']:.4g} (limit "
+            f"{reference.MAX_TOLERANCE}); {eva['rows_compacted']} rows "
+            f"compacted, {eva['pages_released']} pages returned")
+
+
 # -- phase 4: the Pallas kernels, compiled by Mosaic -------------------------
 def round_buffer(tables, toks, row_lane, row_off, q_lens, kv_lens):
     """A mixed round of greedy lanes as the ONE buffer ``paged_mixed_step``
@@ -1073,6 +1154,7 @@ def main(argv=None) -> int:
         smoke.run("jamba", phase_jamba)
         smoke.run("keye_vl2", phase_keye)
         smoke.run("qwen3_next", phase_qwen3_next)
+        smoke.run("evabyte", phase_evabyte)
         smoke.run("kernels", phase_kernels)
         smoke.run("multichip", phase_multichip)
 

@@ -142,7 +142,7 @@ SCHED_SPANS = (
     "sched.dispatch.arrays", "sched.dispatch.put", "sched.dispatch.call",
     "sched.turn.completion", "sched.turn.joiner", "sched.turn.round",
     "sched.turn.k", "sched.turn.pages", "sched.turn.released",
-    "sched.turn.single", "sched.turn.other")
+    "sched.turn.single", "sched.turn.compact", "sched.turn.other")
 
 
 def _scheduler_clock():

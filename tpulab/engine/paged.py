@@ -32,8 +32,9 @@ from tpulab.engine.paged_steps import (_device_sample_token,
                                        dispatch_fields, moe_shape, pack_round,
                                        pack_words, paged_decode_block,
                                        paged_decode_step_sampled,
-                                       paged_extend, paged_mixed_step,
-                                       paged_prefill, paged_speculative_block,
+                                       paged_eva_compact, paged_extend,
+                                       paged_mixed_step, paged_prefill,
+                                       paged_speculative_block,
                                        result_fields, round_width,
                                        unpack_words)
 from tpulab.utils import tracing
@@ -120,7 +121,8 @@ class _PagedRequest:
                  "draft_pages", "draft_len", "spec_enabled", "spec_ewma",
                  "spec_drafted", "spec_accepted", "spec_probe_in",
                  "spec_probing", "tenant", "lane", "fl", "batch",
-                 "pf_started", "pf_digests", "pf_shared", "pf_t0")
+                 "pf_started", "pf_digests", "pf_shared", "pf_t0",
+                 "eva_done")
 
     def __init__(self, prompt: np.ndarray, steps: int, on_token=None,
                  sampling: Optional[SamplingParams] = None,
@@ -193,6 +195,9 @@ class _PagedRequest:
         #                              prompt completion)
         self.pf_shared = 0           # prefix-cache pages served shared
         self.pf_t0: Optional[float] = None  # this prefill's start (spans)
+        #: EVA windows of this lane already compacted into summary rows:
+        #: its rows are ``length - eva_done * (window - summaries)``
+        self.eva_done = 0
         self.t_submit = _time.perf_counter()
         self.t_prefill0: Optional[float] = None  # first prefill start
         self.t_first: Optional[float] = None     # first emitted token
@@ -365,8 +370,12 @@ class ContinuousBatcher:
         #: a learned indexer: index rows beside the K/V pages, the pair
         #: rotated through every dispatch as a hybrid's (pages, state) is
         sparse = spec is not None and bool(spec.index_topk)
+        #: EVA windows: a lane's rows are not its positions (a finished
+        #: window is compacted into summary rows), so nothing that takes a
+        #: request's pages for its positions is carried
+        eva = spec is not None and bool(spec.eva_window)
         special = spec is not None and (spec.cache_entry != "kv"
-                                        or spec.moe_layers or hybrid)
+                                        or spec.moe_layers or hybrid or eva)
         if special:
             refused = {
                 "ragged=False (the legacy split plan)": ragged is False,
@@ -387,7 +396,9 @@ class ContinuousBatcher:
                                | {f"{k} layers" for k in spec.mixers
                                   if k != "attention"}
                                | {f"{k} FFNs" for k in spec.layer_kinds
-                                  if k != "dense"})
+                                  if k != "dense"}
+                               | ({"EVA windows (compacted pages)"} if eva
+                                  else set()))
                 raise NotImplementedError(
                     f"a model with {', '.join(kinds)} is served on the "
                     "ragged plan only; not supported with it: "
@@ -396,6 +407,11 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"spec (n_heads {spec.n_heads}, n_layers {spec.n_layers})"
                     f" disagrees with n_heads={n_heads}, n_layers={n_layers}")
+            if eva and page_size != spec.eva_chunk:
+                raise ValueError(
+                    f"page_size {page_size} is not the spec's eva_chunk "
+                    f"{spec.eva_chunk}: a chunk's summary is taken from one "
+                    "page and written as one row")
             ragged = True
         # KV-cache quantization: pages may store a NARROWER dtype than the
         # compute path (e.g. kv_dtype=jnp.float8_e4m3fn under bf16 compute
@@ -405,12 +421,19 @@ class ContinuousBatcher:
         kv_dtype = kv_dtype or compute_dtype
         # a hybrid's attention layers are the spec's: its KV heads size
         # the pages
-        n_kv = (spec.n_kv_heads if hybrid or sparse
+        n_kv = (spec.n_kv_heads if hybrid or sparse or eva
                 else n_kv_heads or n_heads)
         self.lanes = lanes
         self.max_len = max_len
         self.page_size = page_size
-        self.max_pages = (max_len + page_size - 1) // page_size
+        #: EVA: positions a window, and the rows a compaction takes off a
+        #: lane's table (both 0 without: a row is a position)
+        self._eva_window = spec.eva_window if eva else 0
+        self._eva_saved = (spec.eva_window - spec.eva_summaries if eva
+                           else 0)
+        # a table covers the most ROWS a lane of max_len positions holds
+        self.max_pages = ((spec.cache_rows_peak(max_len) if eva else max_len)
+                          + page_size - 1) // page_size
         if prefill_chunk is not None:
             if prefill_chunk < page_size:
                 raise ValueError("prefill_chunk must be >= page_size")
@@ -550,6 +573,12 @@ class ContinuousBatcher:
                        rule_geometry_error(spec.gdn_k_dim, spec.gdn_v_dim))
                 if err:
                     return err
+            if eva:
+                from tpulab.ops.eva_summary import summary_geometry_error
+                err = summary_geometry_error(head_dim, spec.eva_chunk,
+                                             page_size)
+                if err:
+                    return err
             widest = (round_width(self._round_budget)
                       if ragged is not False else self.BLOCK_K_MENU[-1] + 1)
             if latent:
@@ -644,6 +673,22 @@ class ContinuousBatcher:
         self._mixed = self._jit(
             partial(paged_mixed_step, **self._step_kw), (1,),
             (psh, kvsh, rep), (rep, rep, kvsh))
+        # EVA: a finished window's rows compacted into its summaries, one
+        # lane a dispatch, between the dispatches that write on either side
+        # of the boundary (_eva_compact); never fetched
+        self._compact = (self._jit(
+            partial(paged_eva_compact, spec=spec,
+                    use_kernel=self.use_kernel), (1,),
+            (psh, kvsh, rep), kvsh) if eva else None)
+        #: EVA's work (``debug_state()["eva"]``): compactions by the kind of
+        #: dispatch that finished the window (a mixed round's chunk or
+        #: decode row, a decode block's step), the rows they read, the
+        #: pages they returned to the pool, and the scheduler thread's
+        #: seconds in them (dispatch and release; the device's are the
+        #: program's ``jit_paged_eva_compact`` in a capture)
+        self._eva_stats = (dict(compactions={"round": 0, "decode": 0},
+                                rows_compacted=0, pages_released=0,
+                                compact_s=0.0) if eva else None)
         if decode_block < 1:
             raise ValueError("decode_block must be >= 1")
         #: max fused-decode steps per dispatch (K): a K-block amortizes the
@@ -697,7 +742,10 @@ class ContinuousBatcher:
         #: reads and writes a lane state), the ``rows`` they computed and
         #: the ``keys`` at or before the last row of each pass (what an
         #: attention layer reads of the lane's pages at least)
-        self.lane_work = {kind: dict(passes=0, rows=0, keys=0)
+        #: with EVA windows ``keys`` are the ROWS attended (summaries and
+        #: the window's own) and ``summary_keys`` the summaries among them
+        self.lane_work = {kind: dict(passes=0, rows=0, keys=0,
+                                     **({"summary_keys": 0} if eva else {}))
                           for kind in ("decode", "round")}
         #: decode blocks enqueued before their predecessor was fetched
         #: (_chain_block): over ``dispatch_kinds["decode"]`` the share of
@@ -929,8 +977,15 @@ class ContinuousBatcher:
         selected."""
         if n <= 0:
             return
-        triangle = n * start + n * (n + 1) // 2
         w = self.lane_work[kind]
+        if self._eva_window:
+            # the rows of one window (no dispatch crosses a boundary): the
+            # keys of a row are the rows of the table at or before it
+            done = start // self._eva_window
+            summaries = done * self.model_spec.eva_summaries
+            start -= done * self._eva_saved
+            w["summary_keys"] += summaries * (n if kind == "decode" else 1)
+        triangle = n * start + n * (n + 1) // 2
         w["passes"] += n if kind == "decode" else 1
         w["rows"] += n
         w["keys"] += triangle if kind == "decode" else start + n
@@ -945,6 +1000,69 @@ class ContinuousBatcher:
         c["keys_scored"] += triangle * layers
         c["keys_attended"] += attended * layers
         c["dense_rows"] += dense * layers
+
+    def _rows(self, req: _PagedRequest, n: int) -> int:
+        """The rows of ``req``'s page table that hold its positions ``[0,
+        n)``, ``n`` inside the window it is in: ``n`` itself, less what its
+        compacted EVA windows gave back."""
+        return n - req.eva_done * self._eva_saved
+
+    def _boundary(self, req: _PagedRequest) -> int:
+        """The first position ``req`` may not take in before a compaction:
+        the end of its EVA window (without EVA, no such position)."""
+        if not self._eva_window:
+            return 1 << 62
+        return (req.eva_done + 1) * self._eva_window
+
+    def _peak_rows(self, req: _PagedRequest, total: int) -> int:
+        """The most rows ``req`` holds on its way to ``total`` positions."""
+        if not self._eva_window:
+            return total
+        return self.model_spec.cache_rows_peak(total, req.eva_done)
+
+    def _eva_compact(self) -> None:
+        """Compact every lane that stands at the end of an EVA window: its
+        window's ``eva_window`` rows become ``eva_summaries`` rows in place
+        (:func:`paged_eva_compact`, one lane a dispatch, never fetched: the
+        device's queue is in order, so the next program reads the summaries),
+        and the pages behind the lane's new last row go back to the pool,
+        less what the rest of its prompt will need at its widest.  Called
+        where no program that writes the lane's rows can be in flight: at
+        the top of a pass and before a decode plan, for the lanes outside a
+        block dispatched ahead (whose lanes the chain holds short of their
+        boundary, :meth:`_chain_block`)."""
+        w, ps, spec = self._eva_window, self.page_size, self.model_spec
+        chained = (self._pending_block["lane_reqs"]
+                   if self._pending_block is not None else ())
+        with self._cv:
+            due = [(lane, req) for lane, req in enumerate(self._active)
+                   if req is not None and not req.cancelled
+                   and lane not in chained
+                   and req.length >= self._boundary(req)]
+        for lane, req in due:
+            t0 = _time.perf_counter()
+            with stage(self._stages, "dispatch"):
+                first = req.eva_done * (spec.eva_summaries // ps)
+                pages = np.asarray(req.pages[first:first + w // ps], np.int32)
+                self._kv_state = self._compact(
+                    self.params, self._kv_state, self._put(pages))
+                self._stages.launched()
+            with self._cv:
+                req.eva_done += 1
+                keep = (self._peak_rows(req, req.length + len(
+                    req.pending_prompt)) + ps - 1) // ps
+                freed, req.pages = req.pages[keep:], req.pages[:keep]
+                self.pool.release_pages(freed)
+                e = self._eva_stats
+                e["compactions"]["round" if req.length <= len(req.prompt)
+                                 else "decode"] += 1
+                e["rows_compacted"] += w
+                e["pages_released"] += len(freed)
+                dur = _time.perf_counter() - t0
+                e["compact_s"] += dur
+            self._span("eva.compact", lane, t0, dur, req,
+                       window=req.eva_done - 1, rows_in=w,
+                       rows_out=spec.eva_summaries, pages_released=len(freed))
 
     def _put(self, host):
         """ONE host -> device transfer, counted: ``host`` (a numpy array)
@@ -973,11 +1091,11 @@ class ContinuousBatcher:
     #: why a decode block got no successor before its fetch
     #: (:meth:`_chain_block`, in the order it tests them)
     BREAK_CAUSES = ("k1", "shutdown_or_reclaim", "released", "completion",
-                    "joiner", "spec", "k", "pages")
+                    "joiner", "spec", "k", "pages", "compact")
     #: what opens a turn: a break cause that is a span name of its own, a
     #: mixed round's fetch, a single tick's; everything else is ``other``
     TURN_CAUSES = ("completion", "joiner", "round", "k", "pages",
-                   "released", "single")
+                   "released", "single", "compact")
     #: the parts of a decode block's and a mixed round's ``dispatch``
     #: stage: host arrays, their transfer, the jitted call
     DISPATCH_PARTS = ("dispatch.arrays", "dispatch.put", "dispatch.call")
@@ -1273,6 +1391,17 @@ class ContinuousBatcher:
             return len(self._queue)
 
     @property
+    def decode_holdings(self):
+        """``(pages, positions)`` the decoding lanes hold right now: a cheap
+        gauge of what a position of context costs (with EVA windows a
+        lane's finished windows are summaries, so pages grow slower than
+        positions)."""
+        with self._cv:
+            held = [(len(r.pages), r.length) for r in self._active
+                    if r is not None and not r.pending_prompt]
+        return sum(p for p, _ in held), sum(n for _, n in held)
+
+    @property
     def spec_acceptance(self) -> float:
         """Lifetime draft acceptance rate (accepted / drafted)."""
         return self.spec_tokens_accepted / max(1, self.spec_tokens_drafted)
@@ -1489,6 +1618,7 @@ class ContinuousBatcher:
                     "pages": len(req.pages),
                     "draft_pages": len(req.draft_pages),
                     "cancelled": req.cancelled,
+                    "length": req.length, "eva_done": req.eva_done,
                 })
             queue_head = [{"tenant": q.tenant, "priority": q.priority,
                            "age_s": round(now - q.t_submit, 6),
@@ -1588,6 +1718,21 @@ class ContinuousBatcher:
                              **{name: {kind: c[name]
                                        for kind, c in self._sparse.items()}
                                 for name in self._sparse["decode"]}}
+        if self._eva_stats is not None:
+            e, spec = self._eva_stats, self.model_spec
+            summary_rows = raw_rows = 0
+            for ln in lanes:
+                if ln["state"] != "idle":
+                    summary_rows += ln["eva_done"] * spec.eva_summaries
+                    raw_rows += ln["length"] - ln["eva_done"] * spec.eva_window
+            out["eva"] = {"window": spec.eva_window, "chunk": spec.eva_chunk,
+                          "compactions": dict(e["compactions"]),
+                          "rows_compacted": e["rows_compacted"],
+                          "pages_released": e["pages_released"],
+                          "compact_s": e["compact_s"],
+                          # what the live lanes hold right now
+                          "summary_rows_live": summary_rows,
+                          "raw_rows_live": raw_rows}
         if self.state is not None:
             out["state"] = {"kind": self.state.kind,
                             "lanes": self.state.lanes,
@@ -1947,6 +2092,7 @@ class ContinuousBatcher:
         else:
             req.pending_prompt = list(req.prompt)
         req.length = 0
+        req.eva_done = 0
         req.pf_started = False   # ragged plan: the resume re-secures pages
         self._active[lane] = None
         self._enqueue_locked(req, front_of_class=True)
@@ -2017,6 +2163,8 @@ class ContinuousBatcher:
                         f"({len(req.tokens_out)}/{req.steps} tokens)"))
             try:
                 prefilled = False
+                if self._eva_window:
+                    self._eva_compact()
                 if self.ragged:
                     # ragged dispatch plan: pending prompts and decode
                     # lanes advance together in ONE fused mixed round
@@ -2037,6 +2185,8 @@ class ContinuousBatcher:
                         self._admit_locked()
                         snapshot = list(self._active)
                     self._deliver((), done_reqs)
+                if self._eva_window:
+                    self._eva_compact()     # a window the round finished
                 progressed = self._tick(snapshot, jnp) or prefilled
                 if self.hbm is not None:
                     # KV-burst side of the economy: queued/starved demand
@@ -2384,7 +2534,9 @@ class ContinuousBatcher:
                                                        self.page_size)
         private = req.pages
         req.pages = shared + private
-        needed = (t + self.page_size - 1) // self.page_size
+        # with EVA windows: the most ROWS the prompt holds on its way in
+        needed = (self._peak_rows(req, t) + self.page_size
+                  - 1) // self.page_size
         while len(req.pages) < needed:
             page = self._alloc_page()
             if page is None:
@@ -2459,7 +2611,7 @@ class ContinuousBatcher:
                     if (req is None or req.pending_prompt or req.cancelled
                             or not req.tokens_out):
                         continue
-                    need = req.length // self.page_size + 1
+                    need = self._rows(req, req.length) // self.page_size + 1
                     new: List[int] = []
                     while len(req.pages) < need:
                         page = self._alloc_page()
@@ -2477,7 +2629,10 @@ class ContinuousBatcher:
                 left = self._round_budget
                 chunks: Dict[int, int] = {}
                 for lane, req in sorted(segs, key=lambda s: s[1].admit_seq):
-                    chunks[lane] = min(len(req.pending_prompt), left)
+                    # a chunk ends where its lane's window does: the
+                    # compaction comes before the next row is written
+                    chunks[lane] = min(len(req.pending_prompt), left,
+                                       self._boundary(req) - req.length)
                     left -= chunks[lane]
                 segs = [(lane, req) for lane, req in segs if chunks[lane]]
                 if self.state is not None:
@@ -2805,7 +2960,10 @@ class ContinuousBatcher:
         for lane, req in decode_lanes:
             base = req.length + ahead
             rem = req.steps - len(req.tokens_out) - ahead
-            appends_want = max(1, min(k, rem))
+            # a lane stops where its EVA window ends (the device masks it
+            # there): the block writes no row past the boundary
+            appends_want = max(1, min(k, rem, self._boundary(req) - base))
+            base = self._rows(req, base)
             need = (base + appends_want - 1) // ps + 1
             new: List[int] = []
             while len(req.pages) < need:
@@ -2830,7 +2988,8 @@ class ContinuousBatcher:
             for _lane, req, new in parts:
                 base = req.length + ahead
                 rem = req.steps - len(req.tokens_out) - ahead
-                need = (base + max(1, min(k_eff, rem)) - 1) // ps + 1
+                want = max(1, min(k_eff, rem, self._boundary(req) - base))
+                need = (self._rows(req, base) + want - 1) // ps + 1
                 while len(req.pages) > need and new:
                     self.pool.release_pages([req.pages.pop()])
                     new.pop()
@@ -3156,6 +3315,12 @@ class ContinuousBatcher:
                 for lane, req in lanes_now:    # about to complete
                     if req.steps - len(req.tokens_out) <= ahead:
                         return None, "completion"
+                for lane, req in lanes_now:
+                    # may have reached the end of its EVA window in the
+                    # block(s) in flight: its rows are compacted before
+                    # another is written
+                    if req.length + ahead >= self._boundary(req):
+                        return None, "compact"
                 # a lane that finished its prompt while this chain ran (its
                 # first token is out) is in no block of the chain: chaining
                 # on would leave it without a step until a lane of the

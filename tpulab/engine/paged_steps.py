@@ -140,7 +140,9 @@ def _segment_calls(q, pos, seg):
 
     packed = seg.get("rows")
     if packed is None:
-        return [(q, seg.get("q_lens"), pos)]
+        # (``qpos``: the rows the causal mask runs on, where they are not
+        # the positions RoPE turns: EVA windows)
+        return [(q, seg.get("q_lens"), seg.get("qpos", pos))]
     spread, _back, qpos, chunk_lens, dec_lens = packed
     b, m = qpos.shape
     q = q.reshape(q.shape[1:])                               # (T, ...)
@@ -329,6 +331,17 @@ def _pages(kv_pool):
     first of the pair a model with a lane state (``(pages, lane state)``)
     or with an indexer (``(pages, index rows)``) is served with."""
     return kv_pool[0] if isinstance(kv_pool, tuple) else kv_pool
+
+
+def _row_lens(spec, kv_lens):
+    """``kv_lens`` (positions a lane holds after the dispatch) as the rows
+    its table holds then: itself, or with EVA windows the row behind the
+    last position, plus one (:meth:`ModelSpec.cache_row`; no segment
+    crosses a window's end, so a segment's rows are consecutive too)."""
+    if not spec.eva_window:
+        return kv_lens
+    import jax.numpy as jnp
+    return jnp.where(kv_lens > 0, spec.cache_row(kv_lens - 1) + 1, 0)
 
 
 def _segment_conv(x, w, conv, at, seg, live=None, fresh=None):
@@ -956,15 +969,20 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
     # write target per lane: page id + slot for position `lengths`;
     # inactive/padded lanes are routed to the RESERVED scratch page 0 so
     # they can never clobber a live lane's pages
-    page_idx = tables[jnp.arange(b), lengths // page_size]      # (B,)
+    # (the ROW of the table that holds the position: the position itself,
+    # or behind the summaries of its EVA windows, ModelSpec.cache_row)
+    row = spec.cache_row(lengths)
+    page_idx = tables[jnp.arange(b), row // page_size]          # (B,)
     safe_page = jnp.where(active, page_idx, 0)
-    safe_slot = jnp.where(active, lengths % page_size, 0)
+    safe_slot = jnp.where(active, row % page_size, 0)
     # the ragged kernel at the q=1 decode shape; per-lane positions: each
     # lane decodes at its own length
     pos = lengths[:, None]
     seg = dict(tables=tables, q_lens=jnp.ones_like(lengths),
-               kv_lens=lengths + 1, use_kernel=use_kernel,
+               kv_lens=row + 1, use_kernel=use_kernel,
                kernel_geometry=kernel_geometry, mesh=mesh)
+    if spec.eva_window:
+        seg["qpos"] = row[:, None]
     moe_stats = []
     for layer in range(spec.n_layers):
         x, kv_pool, stats = _layer_block(
@@ -1064,6 +1082,7 @@ def paged_decode_block(params, kv_pool, packed, carry, lanes: int,
     f = unpack_words(dispatch_fields("block", lanes, max_pages), packed)
     tables, temps, seeds, stop_ids = (f["tables"], f["temps"], f["seeds"],
                                       f["stops"])
+    window = spec.eva_window if spec is not None else 0
     lengths, tokens, active, steps_rem = (
         jnp.where(f["fresh"], f[name], kept) for name, kept in zip(
             ("lengths", "tokens", "active", "rem"), carry))
@@ -1083,6 +1102,10 @@ def paged_decode_block(params, kv_pool, packed, carry, lanes: int,
         rem = rem - emitted.astype(jnp.int32)
         hit_stop = (nt[:, None] == stop_ids).any(axis=1)
         live = live & (rem > 0) & ~hit_stop
+        if window:
+            # a lane whose EVA window is full waits for its compaction,
+            # which the host dispatches behind this block
+            live = live & (lens % window != 0)
         return (kv, lens, nt, live, rem), (nt, lp, emitted, *moe)
 
     init = (kv_pool, lengths, tokens, active, steps_rem)
@@ -1160,20 +1183,23 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
                       rope_theta)
     valid = jnp.arange(m)[None, :] < q_lens[:, None]  # (B, M)
     pos = (kv_lens - q_lens)[:, None] + jnp.arange(m)[None, :]
+    row = spec.cache_row(pos)      # the rows behind the positions
     # invalid positions' page index may run past the table width — XLA
     # clamps the gather, and the mask below discards the clamped id
     page_idx = jnp.where(valid,
                          jnp.take_along_axis(
                              tables,
-                             jnp.clip(pos // page_size, 0,
+                             jnp.clip(row // page_size, 0,
                                       tables.shape[1] - 1), axis=1), 0)
-    slot_idx = jnp.where(valid, pos % page_size, 0)
+    slot_idx = jnp.where(valid, row % page_size, 0)
     # gather-after-scatter: token m sees cached context + the segment's
     # own writes up to its position (global causality); one program for
     # every segment mix
-    seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+    seg = dict(tables=tables, q_lens=q_lens, kv_lens=_row_lens(spec, kv_lens),
                use_kernel=use_kernel, kernel_geometry=kernel_geometry,
                mesh=mesh)
+    if spec.eva_window:
+        seg["qpos"] = row
     moe_stats = []
     for layer in range(spec.n_layers):
         x, kv_pool, stats = _layer_block(
@@ -1295,8 +1321,9 @@ def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
     lane = jnp.maximum(row_lane, 0)
     start = kv_lens - q_lens                          # (B,) segment starts
     pos = jnp.where(valid, start[lane] + row_off, 0)
-    page_idx = jnp.where(valid, tables[lane, pos // page_size], 0)
-    slot_idx = jnp.where(valid, pos % page_size, 0)
+    row = spec.cache_row(pos)      # the rows behind the positions
+    page_idx = jnp.where(valid, tables[lane, row // page_size], 0)
+    slot_idx = jnp.where(valid, row % page_size, 0)
     # the slot of the padded (B, M) form behind each row, and the row
     # behind each slot; slots past a lane's segment read row 0, which the
     # attention masks by q_lens
@@ -1304,11 +1331,11 @@ def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
     spread = jnp.zeros((b * m,), jnp.int32).at[
         jnp.where(valid, back, b * m)].set(
             jnp.arange(t, dtype=jnp.int32), mode="drop")
-    qpos = start[:, None] + jnp.arange(m)[None, :]
+    qpos = spec.cache_row(start)[:, None] + jnp.arange(m)[None, :]
     # the layout says which kind a lane's segment is: its decode token, if
     # it has one, is row M + b; every other segment is a chunk in [0, M)
     decodes = valid[m:]
-    seg = dict(tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+    seg = dict(tables=tables, q_lens=q_lens, kv_lens=_row_lens(spec, kv_lens),
                use_kernel=use_kernel, kernel_geometry=kernel_geometry,
                mesh=mesh, row_seg=(row_lane, row_off),
                rows=(spread, back, qpos, jnp.where(decodes, 0, q_lens),
@@ -1335,6 +1362,34 @@ def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
                                    axis=-1)[:, 0]
     return (_pack_results(lanes, None, moe, tokens=next_tokens,
                           logprobs=logprobs), last, kv_pool)
+
+
+def paged_eva_compact(params, kv_pool, pages, spec, use_kernel: bool = False):
+    """Compact ONE finished EVA window of one lane, every layer, in place.
+
+    ``pages (W / S,)`` int32: the pages that hold the window's ``W =
+    spec.eva_window`` rows, in order (the page size ``S`` is
+    ``spec.eva_chunk``, so a page is a chunk).  Every page becomes one
+    summary row ``(k~, v~)`` (:func:`tpulab.ops.eva_summary.
+    summarize_chunks` with the layers' ``eva_mu`` / ``eva_phi``), and the
+    ``W / S`` summaries are written over the window's first ``W / S / S``
+    pages: the rows the lane's table holds for the window from then on.
+    The other pages keep what they held; the host returns them to the pool.
+    Functional, so no summary lands on a chunk not yet read.  Returns the
+    page store, donated by the caller."""
+    import jax.numpy as jnp
+    from tpulab.ops.eva_summary import summarize_chunks
+
+    layers = range(spec.n_layers)
+    mu = jnp.stack([params[f"layer{i}"]["eva_mu"] for i in layers])
+    phi = jnp.stack([params[f"layer{i}"]["eva_phi"] for i in layers])
+    rows = summarize_chunks(kv_pool, pages, mu, phi, use_kernel=use_kernel)
+    size = kv_pool.shape[3]
+    kept = pages.shape[0] // size
+    # (L, pages, 2, row) -> (L, pages / S, 2, S, row): S summaries a page
+    rows = rows.reshape(spec.n_layers, kept, size, 2, -1).transpose(
+        0, 1, 3, 2, 4)
+    return kv_pool.at[:, pages[:kept]].set(rows)
 
 
 def paged_speculative_block(params, draft_params, kv_pool, packed,

@@ -139,6 +139,27 @@ head.  Every layer has ``moe`` with ``router (d_model, E)`` and ``w13`` /
 =============  ==========================================================
 
 The multi-token-prediction layer of the published model is not built.
+
+``evabyte`` (EvaByte 6.5B, ``attention_class`` ``eva``): a dense MHA decoder
+over bytes whose attention keeps a context in two forms in ONE softmax: the
+rows of the query's own window of ``eva_window`` positions whole, and every
+EARLIER window as ``eva_window / eva_chunk`` summary rows, one ``(k~, v~)``
+a chunk: ``k~ = sum_m softmax_m(mu_h . k_m) k_m`` and ``v~ = sum_m
+softmax_m(phi_h . k_m) v_m`` over the chunk's roped keys, ``mu`` and ``phi``
+a learned vector a head.  So a lane's ROWS are not its positions: position
+``p`` lives at row ``p - (p // window) * (window - window / chunk)``
+(:meth:`ModelSpec.cache_row`), and when a window completes its rows are
+compacted in place into its summaries (:func:`tpulab.engine.paged_steps.
+paged_eva_compact`) before the next position is written.  RoPE turns ``q``
+and ``k`` at the TRUE position; the page store, the ragged kernels and their
+causal mask run on the row.  :func:`evabyte_spec` reads the published keys.
+A layer has the dense decoder's leaves (``wqkv`` = ``[q | k | v]``, ``wo``,
+``w1 w3 w2``) and ``eva_mu`` / ``eva_phi`` ``(n_heads, head_dim)``; norm
+scales hold ``1 + w`` (``norm_add_unit_offset``).  The published head is
+``num_pred_heads x vocab_size`` rows: ``lm_head (d_model, vocab)`` is
+prediction head 0, the one a plain ``generate`` reads, and ``mtp_heads
+(d_model, (num_pred_heads - 1) * vocab)`` the further heads, held and not
+run (:func:`split_pred_heads`).
 """
 
 from __future__ import annotations
@@ -191,6 +212,9 @@ class ModelSpec:
     experts_held: int = 0                   # routed experts here; 0 = all
     expert_first: int = 0                   # the first of them
     shared_gate: bool = False               # shared expert * sigmoid(h w_g)
+    eva_window: int = 0                     # EVA (gqa): positions a window,
+    eva_chunk: int = 0                      # positions a summary row; 0 = none
+    pred_heads: int = 0                     # output heads held (head 0 is read)
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -236,6 +260,16 @@ class ModelSpec:
         if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
             raise ValueError(f"rotary_dim {self.rotary_dim} is not an even "
                              f"part of head_dim {self.head_dim}")
+        if self.eva_window or self.eva_chunk:
+            w, c = self.eva_window, self.eva_chunk
+            if min(w, c) < 1 or w % c or (w // c) % c:
+                raise ValueError(
+                    f"eva_window {w} / eva_chunk {c}: a window is whole "
+                    "chunks and its summaries are whole chunks (pages) too")
+            if (self.attention != "gqa" or self.index_topk or self.attn_gate
+                    or set(self.mixers or ()) - {"attention"}):
+                raise ValueError("EVA windows belong to plain GQA attention "
+                                 "on K/V pages, every layer")
         held = self.experts_held or self.n_experts
         if not 0 <= self.expert_first <= self.n_experts - held:
             raise ValueError(
@@ -260,6 +294,34 @@ class ModelSpec:
         if self.attention == "mla":
             return "latent"
         return "kv_index" if self.index_topk else "kv"
+
+    @property
+    def eva_summaries(self) -> int:
+        """Summary rows a finished window leaves (0 without EVA)."""
+        return self.eva_window // self.eva_chunk if self.eva_window else 0
+
+    def cache_row(self, pos):
+        """The row of a lane's page table that holds position ``pos`` (an
+        integer or an array of them): ``pos`` itself, or with EVA windows
+        ``(pos // W) * S + pos % W``: every earlier window is ``S`` summary
+        rows, the position's own window follows them whole."""
+        if not self.eva_window:
+            return pos
+        return pos - (pos // self.eva_window) * (
+            self.eva_window - self.eva_summaries)
+
+    def cache_rows_peak(self, n: int, done: int = 0) -> int:
+        """The most rows a lane holds from now until it has taken in ``n``
+        positions, its first ``done`` windows compacted already: ``n``
+        itself, or with EVA windows the larger of the rows of the final
+        state and, where a window is still to finish on the way, that
+        window whole beside the summaries before it."""
+        if not self.eva_window or n <= 0:
+            return max(n, 0)
+        w, s = self.eva_window, self.eva_summaries
+        last = max((n - 1) // w, done)
+        rows = last * s + n - last * w
+        return max(rows, (last - 1) * s + w) if last > done else rows
 
     @property
     def latent_width(self) -> int:
@@ -478,6 +540,49 @@ def qwen3_next_spec(config: Dict[str, Any], first: int = 0,
         gdn_v_dim=int(config["linear_value_head_dim"]))
 
 
+def evabyte_spec(config: Dict[str, Any]) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type`` ``evabyte``,
+    ``attention_class`` ``eva``).  Refuses what the layer block does not
+    compute."""
+    if config.get("attention_class", "eva") != "eva":
+        raise ValueError(f"attention_class {config['attention_class']!r} is "
+                         "not implemented (eva alone)")
+    if config.get("rope_scaling") is not None:
+        raise ValueError("rope_scaling is not implemented")
+    if config.get("attention_bias"):
+        raise ValueError("attention_bias is not implemented")
+    if config.get("tie_word_embeddings"):
+        raise ValueError("tie_word_embeddings is not implemented (the head "
+                         "is num_pred_heads x vocab_size rows of its own)")
+    if not config.get("norm_add_unit_offset", True):
+        raise ValueError("norm_add_unit_offset false is not implemented "
+                         "(norm scales are loaded as 1 + w)")
+    if config.get("num_chunks") is not None:
+        raise ValueError("num_chunks is not implemented (chunk_size alone "
+                         "sizes a summary)")
+    window, chunk = int(config["window_size"]), int(config["chunk_size"])
+    if window % chunk:
+        raise ValueError(f"window_size {window} is not whole chunks of "
+                         f"chunk_size {chunk}")
+    d_model, n_heads = (int(config["hidden_size"]),
+                        int(config["num_attention_heads"]))
+    return ModelSpec(
+        n_layers=int(config["num_hidden_layers"]), d_model=d_model,
+        n_heads=n_heads, n_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=d_model // n_heads, rms_eps=float(config["rms_norm_eps"]),
+        rope_theta=float(config["rope_theta"]), eva_window=window,
+        eva_chunk=chunk, pred_heads=int(config.get("num_pred_heads", 1)))
+
+
+def split_pred_heads(head, spec: ModelSpec):
+    """A published output head ``(d_model, num_pred_heads * vocab)`` (the
+    transposed ``lm_head.weight``, prediction head ``j`` in columns ``[j *
+    vocab, (j + 1) * vocab)``) as the served ``(lm_head, mtp_heads)``: head
+    0 and the further heads."""
+    vocab = head.shape[1] // max(spec.pred_heads, 1)
+    return head[:, :vocab], head[:, vocab:]
+
+
 def split_qkvz(w, spec: ModelSpec):
     """A published ``in_proj_qkvz`` ``(d_model, 2 * Hk * d_k + 2 * Hv *
     d_v)`` or ``in_proj_ba`` ``(d_model, 2 * Hv)`` of a Gated DeltaNet layer
@@ -523,15 +628,18 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     ``dt_bias = softplus^-1(dt)`` with ``dt`` log-uniform in [1e-3, 1e-1],
     the convolution uniform within ``d_conv ** -0.5``.  A Gated DeltaNet
     layer's for the same reason: ``a_log = log(U(0, 16))``, ``dt_bias`` and
-    the convolution as Mamba's."""
+    the convolution as Mamba's.  The EVA scorers ``eva_mu`` / ``eva_phi``
+    are a unit normal cut at two deviations (the published
+    initialisation)."""
     import jax
     import jax.numpy as jnp
 
     if (spec.attention != "mla" and not spec.state_layers
-            and not spec.index_topk and not spec.attn_gate):
+            and not spec.index_topk and not spec.attn_gate
+            and not spec.eva_window):
         raise ValueError("init_params draws MLA decoders, hybrids with a "
-                         "lane state, decoders with an indexer or an output "
-                         "gate; dense ones come from "
+                         "lane state, decoders with an indexer, an output "
+                         "gate or EVA windows; dense ones come from "
                          "tpulab.models.transformer")
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                  16 * spec.n_layers + 4))
@@ -549,6 +657,8 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     params: Dict[str, Any] = {"embed": w(vocab, d), "final_norm": norm(d)}
     if not spec.mamba_layers:
         params["lm_head"] = w(d, vocab)
+    if spec.pred_heads > 1:
+        params["mtp_heads"] = w(d, (spec.pred_heads - 1) * vocab)
     for i, kind in enumerate(spec.layer_kinds):
         p = {"ln1": norm(d), "ln2": norm(d)}
         if spec.mixers[i] == "mamba":
@@ -598,6 +708,11 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
             if spec.qk_norm:
                 p.update(q_norm=norm(spec.head_dim),
                          k_norm=norm(spec.head_dim))
+            if spec.eva_window:
+                # the published scorers: a unit normal cut at two deviations
+                p.update({name: jax.random.truncated_normal(
+                    next(keys), -2.0, 2.0, (h, spec.head_dim), jnp.float32)
+                    for name in ("eva_mu", "eva_phi")})
             if spec.index_topk:
                 p["indexer"] = {
                     "wq": w(d, spec.index_heads * spec.index_dim),
