@@ -330,6 +330,48 @@ def test_a_round_where_lanes_finish_windows_while_others_decode(
                 ) < now["decode"]["keys"] - work["decode"]["keys"]
 
 
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernels-interpret"])
+def test_a_chunk_ends_at_its_window_under_the_widest_budget(model, reference,
+                                                            use_kernel):
+    """Without ``prefill_chunk`` the budget is the widest a window holds
+    (32 here; 512 of EvaByte's 2,048).  Two lanes share it, the older
+    first, and the younger one's chunks start off the budget's grid: its
+    second chunk ends where its window does, with budget to spare, and
+    the streams are the reference's."""
+    from tpulab.engine.paged_steps import unpack_words
+    cb = _engine(model, use_kernel=use_kernel, prefill_chunk=None, lanes=2)
+    state = cb.debug_state()["dispatch"]
+    assert state["round_budget"] == WINDOW
+    assert f"windows of {WINDOW}" in state["round_budget_why"]
+    taken, mixed = [], cb._mixed
+
+    def spy(params, kv, packed):
+        q = unpack_words(cb._fields["round"], np.asarray(packed))["q_lens"]
+        taken.append([int(q[lane]) if req is not None and req.pf_started
+                      else 0 for lane, req in enumerate(cb._active)])
+        return mixed(params, kv, packed)
+    cb._mixed = spy
+    jobs = [(_prompt(20, 21), 6), (_prompt(100, 22), 6)]
+    try:
+        with cb._cv:     # one admission pass sees both
+            futures = [cb.submit(p, steps=s, logprobs=True) for p, s in jobs]
+        for (prompt, _steps), f in zip(jobs, futures):
+            toks, lps = f.result(timeout=600)
+            got = _errors(reference, model, prompt, toks, lps)
+            assert got["logprob_err_max"] < 2e-5, got
+            assert got["argmax_gap"] == 0.0, got
+        state = cb.debug_state()
+    finally:
+        cb.shutdown()
+    chunks = [[n for n in lane if n] for lane in zip(*taken)]
+    assert chunks == [[20], [12, 20, 32, 32, 4]]
+    assert state["eva"]["compactions"]["round"] == 3
+    assert state["dispatch"]["mixed_prompt_tokens"] == 120
+    # the first round (20 + 12) and the two whole windows
+    assert state["dispatch"]["budget_rounds"] == 3
+
+
 def test_lane_work_counts_rows_attended_and_the_summaries_among_them(model):
     """A prompt of 40 in chunks of 12 that stop at the boundary: segments
     end at rows 12, 24, 32 and, behind 8 summaries, 8 + 8; ten decode steps
@@ -536,8 +578,9 @@ def test_mosaic_compiles_the_summariser_at_the_published_widths(one_chip):
 
 
 @pytest.mark.parametrize("rows,name", [(1, "ragged_paged_decode"),
-                                       (256, "ragged_paged_attention")],
-                         ids=["decode", "chunk-256"])
+                                       (256, "ragged_paged_attention"),
+                                       (512, "ragged_paged_attention")],
+                         ids=["decode", "chunk-256", "chunk-512"])
 def test_mosaic_compiles_the_kv_kernels_at_the_cells_widths(one_chip, rows,
                                                             name):
     """32 query heads on 32 KV heads of 128 (a group of ONE row a KV head in

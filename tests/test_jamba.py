@@ -463,11 +463,12 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows", [288, 33], ids=["M256", "M1"])
+@pytest.mark.parametrize("rows", [544, 288, 33], ids=["M512", "M256", "M1"])
 def test_mosaic_compiles_the_scan_kernel_at_the_published_widths(one_chip,
                                                                  rows):
     """AI21-Jamba2-3B's widths, the cell's 32 lanes and 26 layers of state,
-    a full round (256 + 32 rows) and the smallest (1 + 32, no whole tile):
+    a full round (512 + 32 rows; 256 + 32 before PR 42) and the smallest
+    (1 + 32, no whole tile):
     what the interpreter cannot refuse, Mosaic can (tiling, VMEM)."""
     from tpulab.ops.selective_scan import _scan_call
     d_inner, n, lanes, layers = 5120, 16, 32, 26

@@ -653,7 +653,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows", [8, 256], ids=["decode", "chunk"])
+@pytest.mark.parametrize("rows", [8, 256, 520],
+                         ids=["decode", "chunk", "chunk-512"])
 def test_mosaic_compiles_the_score_kernel_at_the_published_widths(one_chip,
                                                                   rows):
     def shape(*dims, dtype=jnp.bfloat16):
@@ -668,7 +669,8 @@ def test_mosaic_compiles_the_score_kernel_at_the_published_widths(one_chip,
     assert "dsa_index_scores" in compiled.as_text()
 
 
-@pytest.mark.parametrize("rows", [8, 64], ids=["decode", "chunk-64"])
+@pytest.mark.parametrize("rows", [8, 64, 520],
+                         ids=["decode", "chunk-64", "chunk-512"])
 def test_mosaic_compiles_the_attention_kernel_at_the_published_widths(
         one_chip, rows):
     def shape(*dims, dtype=jnp.bfloat16):

@@ -296,11 +296,13 @@ def _spy_rounds(cb):
 
 
 def test_a_round_shares_one_token_budget_oldest_first():
-    cb = _engine(lanes=4, ragged=True, use_kernel=False)
+    cb = _engine(lanes=4, max_len=1024, ragged=True, use_kernel=False)
     rounds = _spy_rounds(cb)
     budget = cb.RAGGED_CHUNK_CAP
+    assert budget == cb.debug_state()["dispatch"]["round_budget"] == 512
     rng = np.random.default_rng(3)
-    lens = [300, 200, 40, 10, 270, 5]       # two more than the lanes
+    # two more than the lanes; the first two all but fill a round
+    lens = [budget + 44, budget - 56, 40, 10, budget + 14, 5]
     try:
         with cb._cv:     # one admission pass sees all six
             futs = [cb.submit(rng.integers(0, 64, n), steps=3)
@@ -412,26 +414,183 @@ def test_mixed_attn_rows_counts_m_a_chunk_lane_and_one_a_decode_lane():
             < sum(r["width"] * cb.lanes for r in rounds))
 
 
-def test_nine_mixed_programs_all_reached_by_single_prompts():
-    """The harness's warm-up (``perf/models/lm.py``) sends single prompts
-    of ``256 + b`` tokens, b a power of two up to 128, and one of 256:
-    after it the mixed program's cache holds nine entries, and a burst of
-    concurrent prompts adds none."""
+def test_every_mixed_program_is_reached_by_a_single_prompt():
+    """The harness's warm-up (``perf/models/lm.py``) reads the engine's
+    ``RAGGED_CHUNK_CAP`` and sends single prompts of ``cap + b`` tokens, b
+    every power of two under it, and one of ``cap``: after it the mixed
+    program's cache holds one entry a power of two from 2 up to the budget
+    (a one-token tail pads to two rows: nine at 512, as there were at
+    256), and a burst of concurrent prompts adds none."""
     # a rope_theta of its own: the jitted program is shared by engines of
     # one geometry, and this test counts its cache
-    cb = _engine(lanes=4, max_len=512, rope_theta=29.0, ragged=True,
+    cb = _engine(lanes=4, max_len=1024, rope_theta=29.0, ragged=True,
                  use_kernel=False)
     cap = cb.RAGGED_CHUNK_CAP
+    tails = [1 << i for i in range(cap.bit_length())]
+    assert tails[-1] == cap == 512
     rng = np.random.default_rng(2)
     try:
-        for b in [1, 2, 4, 8, 16, 32, 64, 128, 256]:
+        for b in tails:
             n = cap + b if b < cap else cap
             cb.submit(rng.integers(0, 64, n), steps=2).result(timeout=120)
-        assert cb._mixed._cache_size() == 9
+        assert cb._mixed._cache_size() == len(tails) - 1 == 9
         futs = [cb.submit(rng.integers(0, 64, n), steps=12)
-                for n in (64, 64, 64, 64, 64, 64, 64, 64, 300, 7, 250, 129)]
+                for n in (64, 64, 64, 64, 64, 64, 64, 64, 300, 7, 250, 129,
+                          700, 1)]
         for f in futs:
             f.result(timeout=120)
         assert cb._mixed._cache_size() == 9
     finally:
         cb.shutdown()
+
+
+# -- the round's budget: derived from the shapes, counted where it engages ------
+@pytest.mark.parametrize("kw", [
+    dict(use_kernel=False), dict(use_kernel=True),
+    dict(use_kernel=False, prefill_chunk=64)],
+    ids=["ceiling-gather", "ceiling-kernel", "prefill_chunk-64"])
+def test_a_long_prompt_takes_whole_budgets_then_its_tail(kw):
+    """A prompt of ``2 x budget + 40`` is three rounds of ``budget, budget,
+    40`` tokens, and its greedy stream is the legacy split plan's."""
+    rng = np.random.default_rng(17)
+
+    def run(prompt_of, **kw):
+        cb = _engine(lanes=2, max_len=1200, **kw)
+        rounds = _spy_rounds(cb) if cb.ragged else None
+        budget = cb._round_budget
+        try:
+            prompt = prompt_of(budget)
+            toks = list(cb.submit(prompt, steps=5).result(timeout=300))
+            return prompt, toks, rounds, cb.debug_state()["dispatch"]
+        finally:
+            cb.shutdown()
+    prompt, got, rounds, state = run(
+        lambda budget: rng.integers(0, 64, 2 * budget + 40), ragged=True,
+        **kw)
+    budget = state["round_budget"]
+    assert budget == kw.get("prefill_chunk", 512)
+    assert [r["prefill"] for r in rounds] == [budget, budget, 40]
+    assert [r["width"] for r in rounds] == [budget, budget, 64]
+    assert (state["budget_rounds"], state["mixed_prompt_tokens"]) == (
+        2, len(prompt))
+    _, want, _, _ = run(lambda _b: prompt, ragged=False, use_kernel=False)
+    assert got == want
+
+
+def _geometry_engine(**kw):
+    """Two heads of 64 on pages of 8 rows: a page row of 128 lanes, the
+    smallest geometry the rows kernel's rule admits."""
+    params = init_transformer_params(vocab=64, d_model=128, n_heads=2,
+                                     n_layers=1, d_ff=64)
+    return ContinuousBatcher(params, n_heads=2, n_layers=1, lanes=2,
+                             max_len=1024, page_size=8,
+                             compute_dtype=jnp.float32, use_kernel=True, **kw)
+
+
+@pytest.mark.parametrize("admits,kw,budget", [
+    (512, {}, 512), (256, {}, 256), (64, {}, 64),
+    (256, dict(prefill_chunk=96), 96), (64, dict(prefill_chunk=16), 16)],
+    ids=["admits-512", "refuses-512", "refuses-128", "chunk-under-the-cap",
+         "chunk-fits-where-the-cap-would-not"])
+def test_the_budget_is_the_widest_round_the_geometry_rule_admits(
+        monkeypatch, admits, kw, budget):
+    """The engine asks its kernels' rule at construction, the ceiling first
+    and then each power of two under it, and says in ``debug_state()`` what
+    it got and what refused the next wider round.  ``prefill_chunk`` lowers
+    the budget and is what the rule is asked about: a chunk of 16 pads to a
+    round of 16 rows whatever the cap."""
+    def plan(rows):
+        return ragged_attention._plan(rows, 2, 2, 64, 8, 128, jnp.float32,
+                                      jnp.float32)[2]
+    monkeypatch.setattr(ragged_attention, "_VMEM_REQUEST_MAX", plan(admits))
+    cb = _geometry_engine(**kw)
+    try:
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    chunk = kw.get("prefill_chunk")
+    cap = 512 if chunk and round_width(chunk) <= admits else admits
+    assert cb.RAGGED_CHUNK_CAP == cap
+    assert ContinuousBatcher.RAGGED_CHUNK_CAP == 512      # the ceiling
+    assert state["round_budget"] == cb._round_budget == budget
+    assert state["use_kernel"] and state["ragged"]
+    if cap == 512:
+        assert state["round_budget_why"] is None
+    else:
+        assert f"q_len={2 * cap}" in state["round_budget_why"]
+        assert "VMEM" in state["round_budget_why"]
+
+
+@pytest.mark.parametrize("max_len,budget", [
+    (1024, 512), (1023, 256), (640, 256), (256, 128), (24, 8)])
+def test_twice_the_budget_fits_max_len(max_len, budget):
+    """Each width's program is reached by one prompt that spends the
+    budget and leaves a tail of that width (``perf/models/lm.py`` warms
+    them so): an engine whose ``max_len`` does not hold twice the ceiling
+    takes the widest power of two it does hold twice, and says so."""
+    cb = _engine(lanes=2, max_len=max_len, ragged=True, use_kernel=False)
+    try:
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    assert state["round_budget"] == cb.RAGGED_CHUNK_CAP == budget
+    assert (state["round_budget_why"] is None) == (budget == 512)
+    if budget < 512:
+        assert f"max_len {max_len}" in state["round_budget_why"]
+
+
+def test_a_geometry_that_admits_no_round_is_still_refused_by_name(
+        monkeypatch):
+    """Where the rule refuses every width (here: the one-row kernel's own
+    VMEM) nothing is narrowed: a compiled engine is refused with the
+    rule's words, as it was; the interpreter, which the rule does not
+    bind, runs the ceiling."""
+    from tpulab.tpu import platform
+    monkeypatch.setattr(ragged_attention, "_VMEM_REQUEST_MAX", 1 << 10)
+    cb = _geometry_engine()
+    try:
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    assert (state["round_budget"], state["round_budget_why"]) == (512, None)
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    with pytest.raises(ValueError, match="use_kernel=True: kernel VMEM .* "
+                                         "for one row a lane"):
+        _geometry_engine()
+
+
+@pytest.mark.parametrize("chunk", [None, 32], ids=["ceiling", "chunk-32"])
+def test_budget_counters_count_what_the_spy_sees(chunk):
+    """``round_budget`` is the budget the planner reads, and
+    ``mixed_prompt_tokens`` / ``budget_rounds`` the prompt tokens the rounds
+    carried (``mixed_tokens`` less the decode rows) and the rounds that
+    spent the whole budget."""
+    cb = _engine(lanes=3, max_len=1400, ragged=True, use_kernel=False,
+                 prefill_chunk=chunk)
+    rounds = _spy_rounds(cb)
+    budget = cb._round_budget
+    rng = np.random.default_rng(19)
+    streaming = threading.Event()
+    try:
+        # one lane decodes while prompts of under one to over two budgets
+        # arrive beside it, two at a time
+        futs = [cb.submit(rng.integers(0, 64, 9), steps=60,
+                          on_token=lambda t, i: i == 2 and streaming.set())]
+        assert streaming.wait(60)
+        for burst in ((2 * budget + 7, budget // 2), (budget, 3)):
+            with cb._cv:
+                futs += [cb.submit(rng.integers(0, 64, n), steps=3)
+                         for n in burst]
+            futs[-1].result(timeout=300)
+        for f in futs:
+            f.result(timeout=300)
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    assert state["round_budget"] == budget == (chunk or 512)
+    assert state["mixed_prompt_tokens"] == sum(r["prefill"] for r in rounds)
+    assert state["mixed_prompt_tokens"] == state["mixed_tokens"] - sum(
+        r["decode_lanes"] for r in rounds)
+    spent = sum(r["prefill"] == budget for r in rounds)
+    assert state["budget_rounds"] == spent >= 3
+    assert any(r["decode_lanes"] for r in rounds)

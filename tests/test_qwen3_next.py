@@ -652,12 +652,12 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows", [256, 1], ids=["M256", "M1"])
+@pytest.mark.parametrize("rows", [512, 256, 1], ids=["M512", "M256", "M1"])
 def test_mosaic_compiles_the_chunk_kernel_at_the_published_widths(one_chip,
                                                                   rows):
     """Qwen3-Next's widths (16 key heads under 32 value heads of 128), the
-    cell's 32 lanes and 6 layers of state, a full round's chunk rows and
-    the smallest (one row, no whole chunk): what the interpreter cannot
+    cell's 32 lanes and 6 layers of state, a full round's chunk rows (512;
+    256 before PR 42) and the smallest (one row, no whole chunk): what the interpreter cannot
     refuse, Mosaic can (tiling, VMEM, a transposed product)."""
     from tpulab.ops.gated_delta_rule import _rule_call
 

@@ -276,10 +276,14 @@ class ContinuousBatcher:
     lanes advance together through fused mixed rounds
     (:func:`paged_mixed_step`) — per-lane (query_len, kv_len) segments
     packed by token, at most ``RAGGED_CHUNK_CAP`` prompt tokens a round
-    for all lanes together, ONE dispatch and one host sync per round, no
-    separate prefill programs — and the speculative verify forward rides
-    the same ragged kernel family.  Tokens are bit-exact vs the legacy split
-    dispatch (``use_kernel=False``, the escape hatch), mesh on or off.
+    for all lanes together (512, or the widest power of two under it that
+    ``max_len`` holds twice and the kernels' geometry rule admits at this
+    engine's shapes: derived at construction,
+    ``debug_state()["dispatch"]["round_budget"]``), ONE
+    dispatch and one host sync per round, no separate prefill programs —
+    and the speculative verify forward rides the same ragged kernel
+    family.  Tokens are bit-exact vs the legacy split dispatch
+    (``use_kernel=False``, the escape hatch), mesh on or off.
 
     Model spec (``spec=``, tpulab.models.spec): a ``ModelSpec`` names the
     attention kind, the layer kinds and the cache-entry kind; without one
@@ -558,11 +562,12 @@ class ContinuousBatcher:
                 "kernel shards the page walk on the heads dim")
         from tpulab.tpu.platform import is_tpu, pallas_interpret
 
-        def kernel_error():
+        def kernel_error(cap: int):
             """Mosaic's shape rule at the PER-SHARD geometry (one shard's
             program is the one that must build) and the widest segment a
-            dispatch can carry: a mixed round that spends its whole token
-            budget under the ragged plan, a K+1 verify otherwise."""
+            dispatch can carry: a mixed round that spends a token budget
+            of ``cap`` (``prefill_chunk`` lowers it) under the ragged
+            plan, a K+1 verify otherwise."""
             from tpulab.ops.ragged_attention import (kernel_geometry_error,
                                                      latent_geometry_error)
             if hybrid:
@@ -579,7 +584,7 @@ class ContinuousBatcher:
                                              page_size)
                 if err:
                     return err
-            widest = (round_width(self._round_budget)
+            widest = (round_width(min(prefill_chunk or cap, cap))
                       if ragged is not False else self.BLOCK_K_MENU[-1] + 1)
             if latent:
                 return latent_geometry_error(
@@ -591,30 +596,58 @@ class ContinuousBatcher:
                 head_dim, self.pool.page_size, self.max_pages,
                 compute_dtype, self.pool.dtype)
 
-        if use_kernel is None:
-            # auto: the pallas ragged kernel on TPU at LONG contexts only
-            # (where the gather path's O(lanes*max_len) dense HBM
-            # materialization per step should dominate) and only at a
-            # geometry the shape rule admits; the XLA gather elsewhere.
-            # No chip measurement backs the threshold yet (ROADMAP S3);
-            # explicit use_kernel=True overrides it.  Under a mesh the
-            # kernel shards on the KV-heads dim (shard_map), so the auto
-            # pick covers sharded serving too.
-            use_kernel = (is_tpu()
-                          and max_len >= self.KERNEL_AUTO_MIN_CTX
-                          and n_heads % n_shards == 0
-                          and kernel_error() is None)
-        elif use_kernel and not pallas_interpret():
-            # asked for a kernel the geometry cannot have: say which
-            # constraint, up front — a Mosaic error past this rule is a
-            # real error and propagates
-            err = kernel_error()
-            if err:
+        #: the round's token budget (:attr:`_round_budget`) comes from the
+        #: shapes, a power of two at most the class's ``RAGGED_CHUNK_CAP``.
+        #: Every width's program must be one a SINGLE prompt reaches, by
+        #: spending the budget and leaving a tail of that width (how a
+        #: harness warms them, so that none is first met under load): so
+        #: twice the budget fits ``max_len``, and the budget a window,
+        #: where a lane's chunk ends.  With the kernels it is also a round
+        #: their geometry rule admits
+        reach = min(max_len // 2, self._eva_window or max_len)
+        cap = min(self.RAGGED_CHUNK_CAP, 1 << max(reach, 1).bit_length() - 1)
+        self.round_budget_why = None if cap == self.RAGGED_CHUNK_CAP else (
+            f"a prompt cannot spend {2 * cap} tokens a round and leave a "
+            f"tail: max_len {max_len}"
+            + (f", windows of {self._eva_window}" if eva else ""))
+
+        # auto: the pallas ragged kernel on TPU at LONG contexts only
+        # (where the gather path's O(lanes*max_len) dense HBM
+        # materialization per step should dominate) and only at a
+        # geometry the shape rule admits; the XLA gather elsewhere.
+        # No chip measurement backs the threshold yet (ROADMAP S3);
+        # explicit use_kernel=True overrides it.  Under a mesh the
+        # kernel shards on the KV-heads dim (shard_map), so the auto
+        # pick covers sharded serving too.
+        auto = use_kernel is None
+        if auto:
+            use_kernel = (is_tpu() and max_len >= self.KERNEL_AUTO_MIN_CTX
+                          and n_heads % n_shards == 0)
+        if use_kernel:
+            # the widest round under ``cap`` the rule admits, and what it
+            # said of the next wider one; 0 where it admits no width
+            admitted, refusal = cap, None
+            while admitted and (err := kernel_error(admitted)):
+                admitted, refusal = admitted // 2, err
+            if admitted:
+                cap = admitted
+                self.round_budget_why = refusal or self.round_budget_why
+            elif auto:
+                use_kernel = False
+            elif not pallas_interpret():
+                # asked for a kernel the geometry cannot have: say which
+                # constraint, up front — a Mosaic error past this rule is
+                # a real error and propagates.  (The interpreter builds
+                # any geometry: a rule that admits no width binds nothing
+                # there.)
                 if self._owns_pool:
                     self.pool.close()
                 if self.state is not None:
                     self.state.close()
-                raise ValueError(f"use_kernel=True: {err}")
+                raise ValueError(f"use_kernel=True: {refusal}")
+        #: the widest budget THIS engine runs: what a harness sizes its
+        #: warm-up prompts by (a power of two; the class's is the ceiling)
+        self.RAGGED_CHUNK_CAP = cap
         self.use_kernel = bool(use_kernel)
         #: ragged dispatch plan (docs/PERFORMANCE.md "Ragged paged
         #: attention"): mixed prefill+decode rounds run as ONE fused
@@ -733,6 +766,11 @@ class ContinuousBatcher:
         self.mixed_rows = 0
         self.mixed_tokens = 0
         self.mixed_attn_rows = 0
+        #: how far the round's budget engages: the prompt tokens the rounds
+        #: carried (``mixed_tokens`` less the decode rows) and the rounds
+        #: that spent the whole budget
+        self.mixed_prompt_tokens = 0
+        self.budget_rounds = 0
         #: sum of K over plain decode dispatches (K-blocks and single
         #: ticks): over ``dispatch_kinds["decode"]`` it is the mean block
         self.decode_block_steps = 0
@@ -1664,6 +1702,10 @@ class ContinuousBatcher:
                          "mixed_rows": self.mixed_rows,
                          "mixed_tokens": self.mixed_tokens,
                          "mixed_attn_rows": self.mixed_attn_rows,
+                         "round_budget": self._round_budget,
+                         "round_budget_why": self.round_budget_why,
+                         "mixed_prompt_tokens": self.mixed_prompt_tokens,
+                         "budget_rounds": self.budget_rounds,
                          "decode_block_steps": self.decode_block_steps,
                          "lane_work": {kind: dict(w) for kind, w
                                        in self.lane_work.items()},
@@ -2507,15 +2549,26 @@ class ContinuousBatcher:
 
     # -- ragged dispatch plan (mixed prefill+decode rounds) ------------------
     #: max prefill tokens one mixed round carries IN TOTAL (the ceiling
-    #: of the pow2 bucket the mixed program is keyed by; ``prefill_chunk``
-    #: lowers it): lanes that prefill at once share it, longer prompts
-    #: take multiple rounds, decode lanes never stall behind them
-    RAGGED_CHUNK_CAP = 256
+    #: of the pow2 bucket the mixed program is keyed by): lanes that
+    #: prefill at once share it, longer prompts take multiple rounds,
+    #: decode lanes never stall behind them.  A round reads every weight
+    #: once whatever its rows, so a prompt costs a weight pass, a turn of
+    #: the host and a gap in the decode chain every this many tokens.  On
+    #: the class it is the ceiling, as wide as the rows kernels hold a
+    #: segment today (a whole query and its carry in VMEM: 1,024 rows do
+    #: not fit at a published model's heads).  An ENGINE's attribute of
+    #: this name is what its constructor derived from its shapes: the
+    #: widest power of two under the ceiling that ``max_len`` holds twice
+    #: and that its kernels' geometry rule admits
+    #: (``debug_state()["dispatch"]["round_budget_why"]`` says what
+    #: refused the next wider); ``prefill_chunk`` lowers it
+    RAGGED_CHUNK_CAP = 512
 
     @property
     def _round_budget(self) -> int:
         """Prefill tokens one mixed round may carry, all lanes together
-        (the token budget of chunked prefill)."""
+        (the token budget of chunked prefill): the engine's
+        ``RAGGED_CHUNK_CAP``, or ``prefill_chunk`` where that is less."""
         return min(self.prefill_chunk or self.RAGGED_CHUNK_CAP,
                    self.RAGGED_CHUNK_CAP)
 
@@ -2576,12 +2629,12 @@ class ContinuousBatcher:
         what is left of a chunk, or a lane the budget did not reach,
         waits a round (the oldest lane always advances, so none starves).
         The program is keyed by :func:`round_width` of the tokens carried,
-        so the budget also bounds the programs: nine, each reached by a
-        single prompt.  Lanes finishing their prompt emit their first
-        token from the same dispatch (no separate prefill program, no
-        per-lane logits fetch).  With no pending prompts this is a no-op
-        and the K-block decode path owns the tick.  Returns True when any
-        lane made progress."""
+        so the budget also bounds the programs: a power of two each from 2
+        up to the budget (nine at 512), each reached by a single prompt.
+        Lanes finishing their prompt emit their first token from the same
+        dispatch (no separate prefill program, no per-lane logits fetch).
+        With no pending prompts this is a no-op and the K-block decode
+        path owns the tick.  Returns True when any lane made progress."""
         st = self._stages
         with stage(st, "plan"):
             progressed = False
@@ -2683,13 +2736,16 @@ class ContinuousBatcher:
                 # the results' copy to the host starts behind it
                 ticket = st.launched()
                 out.copy_to_host_async()
+            carried = self._round_budget - left
             st.note(program="paged_mixed_step", k=1, lanes=len(lane_reqs),
-                    rows=len(toks),
+                    rows=len(toks), prompt_tokens=carried,
                     ahead=int(self._pending_block is not None))
             self.decode_dispatches += 1
             self._note_dispatch("mixed")
             self.mixed_rows += len(toks)
             self.mixed_tokens += int(q_lens.sum())
+            self.mixed_prompt_tokens += carried
+            self.budget_rounds += left == 0
             self.mixed_attn_rows += ((len(toks) - b) * len(segs)
                                      + len(decode_parts))
         with stage(st, "fetch"):

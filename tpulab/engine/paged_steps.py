@@ -1222,8 +1222,11 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
 def round_width(prefill_tokens: int) -> int:
     """``M`` of the mixed round that carries ``prefill_tokens`` prompt
     tokens: the pow2 bucket its program is keyed by (few jits) and the
-    segment width its attention is called at."""
-    return 1 << (prefill_tokens - 1).bit_length()
+    segment width its attention is called at.  Never under 2: a one-token
+    tail pads to two rows, which is one program less to trace, lower and
+    load at every start (nine up to a budget of 512, as there were up to
+    256: ``setup_s``)."""
+    return max(2, 1 << (prefill_tokens - 1).bit_length())
 
 
 def pack_round(lanes: int, prefill: Dict[int, Any], decode: Dict[int, int]):
