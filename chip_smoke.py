@@ -1015,7 +1015,7 @@ def prefill_logits(cb, prompt):
     tables = np.zeros((cb.max_pages,), np.int32)
     n = -(-t // cb.page_size)
     tables[:n] = 1 + np.arange(n)
-    logits, _ = cb._prefill(cb.params, kv, jnp.asarray(tables),
+    logits, _ = cb.programs.prefill(cb.params, kv, jnp.asarray(tables),
                             jnp.asarray(tokens), jnp.int32(t))
     return np.asarray(logits, np.float32)
 

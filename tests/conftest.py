@@ -15,9 +15,9 @@ from tpulab.tpu.platform import force_cpu  # noqa: E402
 
 force_cpu(8)
 # The persistent XLA compilation cache is not enabled here: in-process
-# compile reuse for the serving engine comes from ContinuousBatcher's
-# program memo (engine/paged.py _JIT_MEMO), which shares jitted programs
-# across identical-geometry engines without any serialization.
+# compile reuse for the serving engine comes from the step programs' memo
+# (engine/paged_steps.py _JIT_MEMO), which shares jitted programs across
+# identical-geometry engines without any serialization.
 
 
 def free_port() -> int:

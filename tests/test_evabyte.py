@@ -344,14 +344,15 @@ def test_a_chunk_ends_at_its_window_under_the_widest_budget(model, reference,
     state = cb.debug_state()["dispatch"]
     assert state["round_budget"] == WINDOW
     assert f"windows of {WINDOW}" in state["round_budget_why"]
-    taken, mixed = [], cb._mixed
+    taken, mixed = [], cb.programs.mixed
 
     def spy(params, kv, packed):
-        q = unpack_words(cb._fields["round"], np.asarray(packed))["q_lens"]
+        q = unpack_words(cb.programs.fields["round"],
+                         np.asarray(packed))["q_lens"]
         taken.append([int(q[lane]) if req is not None and req.pf_started
                       else 0 for lane, req in enumerate(cb._active)])
         return mixed(params, kv, packed)
-    cb._mixed = spy
+    cb.programs.mixed = spy
     jobs = [(_prompt(20, 21), 6), (_prompt(100, 22), 6)]
     try:
         with cb._cv:     # one admission pass sees both

@@ -193,66 +193,3 @@ def test_single_query_attention_skips_dead_pages():
     lengths = jnp.asarray([2], jnp.int32)  # only first page, 3 tokens visible
     out = _single_query_attention(q, k_pool, v_pool, tables, lengths)
     np.testing.assert_allclose(np.asarray(out), 5.0, rtol=1e-6)
-
-
-def test_flash_attention_backward_matches_dense():
-    """Custom-VJP blockwise backward == autodiff through dense attention
-    (both causal and full), f32."""
-    import jax
-    import jax.numpy as jnp
-    from tpulab.ops.flash_attention import flash_attention
-
-    b, t, h, d = 2, 64, 2, 16
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
-
-    def dense(q, k, v, causal):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
-        if causal:
-            mask = jnp.tril(jnp.ones((t, t), bool))
-            s = jnp.where(mask[None, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-    for causal in (True, False):
-        def loss_flash(args):
-            return (flash_attention(*args, causal=causal, block_q=16,
-                                    block_k=16) ** 2).sum()
-
-        def loss_dense(args):
-            return (dense(*args, causal) ** 2).sum()
-
-        gf = jax.grad(loss_flash)((q, k, v))
-        gd = jax.grad(loss_dense)((q, k, v))
-        for a, b_ in zip(gf, gd):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                       atol=2e-3, rtol=2e-3)
-
-
-def test_flash_attention_trains_through_transformer():
-    """The flash attention_fn plugs into a gradient step (gap: 'flash
-    attention backward if training matters')."""
-    import jax
-    import jax.numpy as jnp
-    from tpulab.models.transformer import (init_transformer_params,
-                                           transformer_apply)
-    from tpulab.ops.flash_attention import make_flash_attention_fn
-
-    params = init_transformer_params(vocab=64, d_model=32, n_heads=2,
-                                     n_layers=2, d_ff=64)
-    tokens = np.random.default_rng(1).integers(0, 64, (2, 32), np.int32)
-    attn = make_flash_attention_fn(causal=True, block_q=16, block_k=16)
-
-    def loss(p):
-        out = transformer_apply(p, {"tokens": tokens}, n_heads=2,
-                                n_layers=2, compute_dtype=jnp.float32,
-                                attention_fn=attn)
-        return jnp.mean(out["logits"] ** 2)
-
-    val, grads = jax.value_and_grad(loss)(params)
-    assert np.isfinite(float(val))
-    leaves = jax.tree_util.tree_leaves(grads)
-    assert all(np.isfinite(np.asarray(l)).all() for l in leaves)
-    assert any(float(np.abs(np.asarray(l)).max()) > 0 for l in leaves)

@@ -240,7 +240,8 @@ def test_first_and_chained_blocks_are_one_compiled_program():
                         on_token=lambda t, i: streaming.set()).result(
                             timeout=300)
         state = cb.debug_state()["dispatch"]
-        sizes = {k: fn._cache_size() for k, fn in cb._block_cache.items()}
+        sizes = {k: fn._cache_size()
+                 for k, fn in cb.programs.blocks.items()}
     finally:
         cb.shutdown()
     assert len(out) == 64 and streaming.is_set()

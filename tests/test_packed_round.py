@@ -274,10 +274,11 @@ def _spy_rounds(cb):
     """Record what each mixed round dispatches: its rows, and for every
     lane that had prompt tokens pending its admission number, what was
     pending and what the round took."""
-    rounds, mixed = [], cb._mixed
+    rounds, mixed = [], cb.programs.mixed
 
     def spy(params, kv, packed):
-        f = paged_steps.unpack_words(cb._fields["round"], np.asarray(packed))
+        f = paged_steps.unpack_words(cb.programs.fields["round"],
+                                     np.asarray(packed))
         toks, row_lane, _row_off = f["rows"]
         q = f["q_lens"]
         m = toks.shape[0] - cb.lanes
@@ -291,7 +292,7 @@ def _spy_rounds(cb):
                    for lane, req in enumerate(cb._active)
                    if req is not None and req.pf_started]))
         return mixed(params, kv, packed)
-    cb._mixed = spy
+    cb.programs.mixed = spy
     return rounds
 
 
@@ -433,13 +434,13 @@ def test_every_mixed_program_is_reached_by_a_single_prompt():
         for b in tails:
             n = cap + b if b < cap else cap
             cb.submit(rng.integers(0, 64, n), steps=2).result(timeout=120)
-        assert cb._mixed._cache_size() == len(tails) - 1 == 9
+        assert cb.programs.mixed._cache_size() == len(tails) - 1 == 9
         futs = [cb.submit(rng.integers(0, 64, n), steps=12)
                 for n in (64, 64, 64, 64, 64, 64, 64, 64, 300, 7, 250, 129,
                           700, 1)]
         for f in futs:
             f.result(timeout=120)
-        assert cb._mixed._cache_size() == 9
+        assert cb.programs.mixed._cache_size() == 9
     finally:
         cb.shutdown()
 

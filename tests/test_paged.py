@@ -659,16 +659,16 @@ def test_prefill_flash_compile_failure_is_an_error(lm):
                            page_size=8, compute_dtype=jnp.float32,
                            prefill_flash=True)
     try:
-        real = cb._prefill
+        real = cb.programs.prefill
 
         def boom(*a, **k):
             raise RuntimeError("Mosaic rejected this bucket")
-        cb._prefill = boom
+        cb.programs.prefill = boom
         p = np.random.default_rng(1).integers(0, 64, (6,), np.int32)
         with pytest.raises(RuntimeError, match="Mosaic rejected"):
             cb.submit(p, 4).result(timeout=120)
-        assert cb.prefill_flash is True and cb._prefill is boom
-        cb._prefill = real
+        assert cb.prefill_flash is True and cb.programs.prefill is boom
+        cb.programs.prefill = real
         assert len(cb.submit(p, 4).result(timeout=120)) == 4
     finally:
         cb.shutdown()

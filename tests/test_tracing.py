@@ -442,7 +442,7 @@ def _lower_args(cb, kind):
 
     def packed(program, width):
         """A buffer of zeros for ``program``, its open field ``width``."""
-        fields = cb._fields[program]
+        fields = cb.programs.fields[program]
         return jnp.asarray(pack_words(fields, {
             name: np.zeros([width if n < 0 else n for n in shape], dtype)
             for name, dtype, shape in fields}))
@@ -451,18 +451,18 @@ def _lower_args(cb, kind):
     head = (cb.params, cb.pool.kv)
     block = lambda: head + (packed("block", 1), cb._no_carry)  # noqa: E731
     return {
-        "paged_decode_block_k2": lambda: (cb._block_fn(2), block()),
-        "paged_decode_block_k8": lambda: (cb._block_fn(8), block()),
+        "paged_decode_block_k2": lambda: (cb.programs.block(2), block()),
+        "paged_decode_block_k8": lambda: (cb.programs.block(8), block()),
         "paged_decode_step_sampled": lambda: (
-            cb._step_sampled, head + (packed("tick", 0),)),
+            cb.programs.tick, head + (packed("tick", 0),)),
         "paged_mixed_step": lambda: (
-            cb._mixed, head + (packed("round", 8 + b),)),
+            cb.programs.mixed, head + (packed("round", 8 + b),)),
         "paged_prefill": lambda: (
-            cb._prefill, head + one + (jnp.int32(5),)),
+            cb.programs.prefill, head + one + (jnp.int32(5),)),
         "paged_extend": lambda: (
-            cb._extend, head + one + (jnp.int32(8), jnp.int32(13))),
+            cb.programs.extend, head + one + (jnp.int32(8), jnp.int32(13))),
         "paged_speculative_block_k2": lambda: (
-            cb._spec_block_fn(2),
+            cb.programs.spec_block(2),
             (cb.params, cb._spec["params"], cb.pool.kv, packed("spec", 1))),
     }[kind]()
 
@@ -472,7 +472,7 @@ def _lower_args(cb, kind):
     "paged_decode_step_sampled", "paged_mixed_step", "paged_prefill",
     "paged_extend", "paged_speculative_block_k2"])
 def test_step_programs_carry_stable_names(kind):
-    """Every step program is built in ContinuousBatcher._jit, which names
+    """Every step program is built in StepPrograms._jit, which names
     it after its function (+ the block size the partial binds): a trace's
     XLA Modules line shows ``jit_<kind>``, never ``jit__unknown``."""
     from tpulab.models.transformer import init_transformer_params

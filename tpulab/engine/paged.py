@@ -18,8 +18,8 @@ from __future__ import annotations
 
 import threading
 import time as _time
+from collections import OrderedDict
 from concurrent.futures import Future
-from functools import partial
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -28,15 +28,10 @@ from tpulab import chaos
 from tpulab.core.deadline import Deadline, DeadlineExceeded
 from tpulab.core.threads import on_one_frame_chunk
 from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool, PrefixCache
-from tpulab.engine.paged_steps import (_device_sample_token,
-                                       dispatch_fields, moe_shape, pack_round,
-                                       pack_words, paged_decode_block,
-                                       paged_decode_step_sampled,
-                                       paged_eva_compact, paged_extend,
-                                       paged_mixed_step, paged_prefill,
-                                       paged_speculative_block,
-                                       result_fields, round_width,
-                                       unpack_words)
+from tpulab.engine.paged_steps import (StepPrograms, _device_sample_token,
+                                       moe_shape, pack_round, pack_words,
+                                       result_fields, unpack_words)
+from tpulab.engine.plan import plan_engine
 from tpulab.utils import tracing
 from tpulab.utils.tracing import part, stage
 
@@ -213,15 +208,6 @@ class _PagedRequest:
             or self.tokens_out[-1] in self.stop_tokens)
 
 
-#: process-level memo of jitted engine programs (see
-#: ContinuousBatcher._jit): identical-geometry engines share one jitted
-#: callable and therefore one compiled-program cache.  Bounded by the
-#: process's program-config variety; entries hold compiled executables,
-#: never parameter or pool buffers (those are traced arguments).
-_JIT_MEMO: Dict[Any, Any] = {}
-_JIT_MEMO_LOCK = threading.Lock()
-
-
 class ContinuousBatcher:
     """Continuous-batching scheduler over the paged pool.
 
@@ -360,128 +346,61 @@ class ContinuousBatcher:
                  spec=None):
         import jax
         import jax.numpy as jnp
+        from tpulab.models.transformer import weight_shape
 
         compute_dtype = compute_dtype or jnp.bfloat16
-        #: tpulab.models.spec.ModelSpec: None serves the dense decoder of
-        #: ``n_heads``/``n_kv_heads``/``rope_theta`` with today's constants;
-        #: a spec with a latent cache, index rows, expert layers or layers
-        #: with a lane state is served on the ragged plan alone, and the
-        #: options that plan, that cache-entry kind or a per-lane state (nothing
-        #: snapshots, shares or ships it yet) does not carry are refused
-        #: here, by name
+        vocab, d_model = weight_shape(params["embed"])[:2]
+        #: what this engine is (tpulab.engine.plan): the model's kinds, the
+        #: page store's geometry, the dispatch plan, the round's budget;
+        #: what it refuses it refuses here, before anything is allocated
+        self.plan = plan = plan_engine(
+            spec=spec, n_heads=n_heads, n_layers=n_layers,
+            n_kv_heads=n_kv_heads, rope_theta=rope_theta,
+            d_model=int(d_model), vocab=int(vocab), lanes=lanes,
+            max_len=max_len, page_size=page_size,
+            prefill_chunk=prefill_chunk, use_kernel=use_kernel,
+            ragged=ragged, prefill_flash=prefill_flash,
+            compute_dtype=compute_dtype, kv_dtype=kv_dtype,
+            round_ceiling=self.RAGGED_CHUNK_CAP,
+            kernel_auto_min_ctx=self.KERNEL_AUTO_MIN_CTX,
+            verify_width=self.BLOCK_K_MENU[-1] + 1, pool=pool, mesh=mesh,
+            hbm=hbm, draft_params=draft_params,
+            draft_n_layers=draft_n_layers, draft_n_heads=draft_n_heads,
+            draft_n_kv_heads=draft_n_kv_heads, kv_offload=kv_offload,
+            kv_publish=kv_publish, prefix_cache=prefix_cache)
+        if decode_block < 1:
+            raise ValueError("decode_block must be >= 1")
+        if kv_publish and kv_offload in (None, False):
+            raise ValueError("kv_publish requires kv_offload")
+        #: tpulab.models.spec.ModelSpec (None: the dense decoder)
         self.model_spec = spec
-        hybrid = spec is not None and bool(spec.state_layers)
-        #: a learned indexer: index rows beside the K/V pages, the pair
-        #: rotated through every dispatch as a hybrid's (pages, state) is
-        sparse = spec is not None and bool(spec.index_topk)
-        #: EVA windows: a lane's rows are not its positions (a finished
-        #: window is compacted into summary rows), so nothing that takes a
-        #: request's pages for its positions is carried
-        eva = spec is not None and bool(spec.eva_window)
-        special = spec is not None and (spec.cache_entry != "kv"
-                                        or spec.moe_layers or hybrid or eva)
-        if special:
-            refused = {
-                "ragged=False (the legacy split plan)": ragged is False,
-                "draft_params (speculative blocks)": draft_params is not None,
-                "mesh": mesh is not None
-                or getattr(pool, "mesh", None) is not None,
-                "kv_offload": kv_offload not in (None, False),
-                "kv_publish": bool(kv_publish),
-                "prefix_cache": bool(prefix_cache),
-                "kv_dtype other than the compute dtype":
-                    kv_dtype is not None
-                    and jnp.dtype(kv_dtype) != jnp.dtype(compute_dtype),
-                "hbm (the elastic page store)": hbm is not None,
-            }
-            bad = [name for name, on in refused.items() if on]
-            if bad:
-                kinds = sorted({f"{spec.cache_entry} pages"}
-                               | {f"{k} layers" for k in spec.mixers
-                                  if k != "attention"}
-                               | {f"{k} FFNs" for k in spec.layer_kinds
-                                  if k != "dense"}
-                               | ({"EVA windows (compacted pages)"} if eva
-                                  else set()))
-                raise NotImplementedError(
-                    f"a model with {', '.join(kinds)} is served on the "
-                    "ragged plan only; not supported with it: "
-                    + ", ".join(bad))
-            if (spec.n_heads, spec.n_layers) != (n_heads, n_layers):
-                raise ValueError(
-                    f"spec (n_heads {spec.n_heads}, n_layers {spec.n_layers})"
-                    f" disagrees with n_heads={n_heads}, n_layers={n_layers}")
-            if eva and page_size != spec.eva_chunk:
-                raise ValueError(
-                    f"page_size {page_size} is not the spec's eva_chunk "
-                    f"{spec.eva_chunk}: a chunk's summary is taken from one "
-                    "page and written as one row")
-            ragged = True
-        # KV-cache quantization: pages may store a NARROWER dtype than the
-        # compute path (e.g. kv_dtype=jnp.float8_e4m3fn under bf16 compute
-        # halves KV HBM *and* decode bandwidth — the decode tick is
-        # KV-bandwidth-bound).  Writes round on scatter, reads upcast in
-        # the gather/kernel; attention math stays in f32 either way.
-        kv_dtype = kv_dtype or compute_dtype
-        # a hybrid's attention layers are the spec's: its KV heads size
-        # the pages
-        n_kv = (spec.n_kv_heads if hybrid or sparse or eva
-                else n_kv_heads or n_heads)
         self.lanes = lanes
         self.max_len = max_len
         self.page_size = page_size
-        #: EVA: positions a window, and the rows a compaction takes off a
-        #: lane's table (both 0 without: a row is a position)
-        self._eva_window = spec.eva_window if eva else 0
-        self._eva_saved = (spec.eva_window - spec.eva_summaries if eva
-                           else 0)
-        # a table covers the most ROWS a lane of max_len positions holds
-        self.max_pages = ((spec.cache_rows_peak(max_len) if eva else max_len)
-                          + page_size - 1) // page_size
-        if prefill_chunk is not None:
-            if prefill_chunk < page_size:
-                raise ValueError("prefill_chunk must be >= page_size")
-            # chunk starts must stay page-aligned (a chunk's successor
-            # writes from a page boundary)
-            prefill_chunk -= prefill_chunk % page_size
-        self.prefill_chunk = prefill_chunk
-        from tpulab.models.transformer import weight_shape
-        d_model = int(weight_shape(params["embed"])[1])
+        self.max_pages = plan.max_pages
+        self.prefill_chunk = plan.prefill_chunk
         #: id-validation bound (public: the Generate RPC checks it too)
-        self.vocab = int(weight_shape(params["embed"])[0])
+        self.vocab = plan.vocab
+        self.use_kernel, self.ragged = plan.use_kernel, plan.ragged
+        self.prefill_flash = plan.prefill_flash
+        #: the widest budget THIS engine runs: what a harness sizes its
+        #: warm-up prompts by (a power of two; the class's is the ceiling)
+        self.RAGGED_CHUNK_CAP = plan.round_cap
+        self.round_budget_why = plan.round_budget_why
         # +1: page 0 is the reserved scratch page.  GQA pools store the
         # compact n_kv_heads form — KV HBM shrinks by n_heads/n_kv_heads.
         self._owns_pool = pool is None
-        if pool is not None and kv_dtype != compute_dtype \
-                and pool.dtype != kv_dtype:
-            raise ValueError(
-                f"kv_dtype={jnp.dtype(kv_dtype).name} conflicts with the "
-                f"provided pool's dtype {jnp.dtype(pool.dtype).name}")
-        latent = (spec.latent_width
-                  if spec is not None and spec.cache_entry == "latent" else 0)
-        if pool is not None and (pool.entry_kind == "latent") != bool(latent):
-            raise ValueError(f"the provided pool holds {pool.entry_kind!r} "
-                             "entries, the model another kind")
-        # only attention layers own a layer of the page store
-        pool_layers = len(spec.attention_layers) if hybrid else n_layers
-        if pool is not None and pool.n_layers != pool_layers:
-            raise ValueError(f"the provided pool has {pool.n_layers} layers, "
-                             f"the model {pool_layers} attention layers")
-        head_dim = (spec.head_dim if spec is not None
-                    else 0) or d_model // n_heads
-        if pool is not None and sparse and pool.index is None:
-            raise ValueError("the model has an indexer: the provided pool "
-                             "needs index rows (index_dim=)")
         self.pool = pool or PagedKVPool(
-            n_pages or self.max_pages * lanes + 1, page_size, pool_layers,
-            0 if latent else n_kv, 0 if latent else head_dim,
-            kv_dtype, device, mesh=mesh, latent_width=latent,
-            index_dim=spec.index_dim if sparse else 0)
+            n_pages or plan.max_pages * lanes + 1, page_size,
+            plan.pool_layers, 0 if plan.latent else plan.n_kv,
+            0 if plan.latent else plan.head_dim, plan.kv_dtype, device,
+            mesh=mesh, latent_width=plan.latent, index_dim=plan.index_dim)
         #: the per-lane recurrent state of the layers that keep one (Mamba,
         #: Gated DeltaNet: ``spec.state_layers``; None without any):
         #: rotates through every dispatch beside ``pool.kv`` (_kv_state)
         self.state = (LaneStateStore(spec, lanes, compute_dtype,
-                                     self.pool.device) if hybrid else None)
+                                     self.pool.device)
+                      if plan.state_kind else None)
         #: segments the device started from zeros (a first chunk at
         #: position 0: admissions and re-prefills after preemption)
         self.zero_starts = 0
@@ -489,16 +408,12 @@ class ContinuousBatcher:
         #: ["last_release"]``): a slot and pages keep their contents until
         #: another request takes them, so a check can read them there
         self.last_release: Optional[Dict[str, Any]] = None
-        if pool is not None and mesh is not None and pool.mesh is not mesh:
-            raise ValueError("provided pool was built on a different mesh "
-                             "than the batcher's")
         # unified HBM economy (tpulab.hbm, docs/PERFORMANCE.md "HBM
         # economy"): with an arbiter the batcher is the KV TENANT — the
         # pool's page store becomes elastic (a KV burst wins bytes from
         # cold models via the arbiter's pressure protocol; a hot model's
         # acquire squeezes idle KV down to the host tier), and every jit
-        # this engine compiles records its scratch with the ledger.  Set
-        # before the first _jit so scratch measuring can wrap them.
+        # this engine compiles records its scratch with the ledger.
         self.hbm = hbm
         self._hbm_reclaim_bytes = 0  # outstanding arbiter reclaim target
         self.hbm_grows = 0           # pool grow ops granted by the arbiter
@@ -513,167 +428,31 @@ class ContinuousBatcher:
         self._hbm_starved_passes = 0  # hold-and-wait breaker streak
         if hbm is not None:
             self.pool.prefer_low_pages = True
-        # sharded serving (docs/PERFORMANCE.md "Sharded serving"): with a
-        # ``mesh`` ({"model": M}, tpulab.parallel) one replica serves a
-        # model sharded over M devices — params placed by the Megatron-TP
-        # rules (wqkv/w1/w3/lm_head column-, wo/w2 row-parallel), the KV
-        # page store sharded on the KV-heads dim, and every dispatch a
-        # sharded jit with explicit in/out shardings so XLA inserts the
-        # psums INSIDE the fused program: the one-host-sync-per-block
-        # contract and device-side sampling are unchanged, and per-lane
-        # carry/state stays replicated.  mesh=None is bit-for-bit today's
-        # single-device path.
-        self.mesh = getattr(self.pool, "mesh", None)
-        if self.hbm is not None and self.mesh is not None:
-            # PR 11's named follow-up, closed as an explicit contract:
-            # the elastic pool's grow/shrink per-shard accounting is
-            # UNTESTED under a mesh (the ladder recompiles sharded
-            # programs per size and concat/slice re-infer the output
-            # sharding) — reject at construction rather than leave a
-            # silent corruption path.  ROADMAP item 3 (per-axis ledger)
-            # is where this lands properly.
-            if self._owns_pool:
-                self.pool.close()
-            raise NotImplementedError(
-                "HBM-arbiter-armed serving (elastic PagedKVPool) under a "
-                "mesh is not supported: grow/shrink per-shard accounting "
-                "is untested — serve the arbiter single-device, or the "
-                "mesh without an arbiter (hbm=None)")
+        # sharded serving (the class docstring): params placed by the
+        # Megatron-TP rules (wqkv/w1/w3/lm_head column-, wo/w2
+        # row-parallel), per-lane carry/state replicated
+        self.mesh = plan.mesh
+        param_sh = draft_sh = self._rep = None
         if self.mesh is not None:
             from tpulab.parallel.sharding import (replicate,
                                                   transformer_param_shardings)
             self._rep = replicate(self.mesh)
-            self._param_sh = transformer_param_shardings(params, self.mesh)
-            self.params = jax.device_put(params, self._param_sh)
-            if prefill_flash:
-                raise ValueError(
-                    "the pallas flash prefill kernel is single-device; "
-                    "mesh serving prefills through the dense or ragged "
-                    "paths (prefill_flash must be False or None)")
-            prefill_flash = False
-        else:
-            self._rep = self._param_sh = None
-            self.params = jax.device_put(params, self.pool.device)
-        n_shards = self.pool.n_shards
-        if use_kernel and self.mesh is not None and n_heads % n_shards:
-            raise ValueError(
-                f"use_kernel under a mesh needs query heads ({n_heads}) "
-                f"divisible by the model axis ({n_shards}) — the ragged "
-                "kernel shards the page walk on the heads dim")
-        from tpulab.tpu.platform import is_tpu, pallas_interpret
-
-        def kernel_error(cap: int):
-            """Mosaic's shape rule at the PER-SHARD geometry (one shard's
-            program is the one that must build) and the widest segment a
-            dispatch can carry: a mixed round that spends a token budget
-            of ``cap`` (``prefill_chunk`` lowers it) under the ragged
-            plan, a K+1 verify otherwise."""
-            from tpulab.ops.ragged_attention import (kernel_geometry_error,
-                                                     latent_geometry_error)
-            if hybrid:
-                from tpulab.ops.gated_delta_rule import rule_geometry_error
-                from tpulab.ops.selective_scan import scan_geometry_error
-                err = (scan_geometry_error(spec.d_inner, spec.d_state)
-                       if spec.state_kind == "mamba" else
-                       rule_geometry_error(spec.gdn_k_dim, spec.gdn_v_dim))
-                if err:
-                    return err
-            if eva:
-                from tpulab.ops.eva_summary import summary_geometry_error
-                err = summary_geometry_error(head_dim, spec.eva_chunk,
-                                             page_size)
-                if err:
-                    return err
-            widest = (round_width(min(prefill_chunk or cap, cap))
-                      if ragged is not False else self.BLOCK_K_MENU[-1] + 1)
-            if latent:
-                return latent_geometry_error(
-                    widest, n_heads, self.pool.kv.shape[4],
-                    spec.kv_lora_rank, self.pool.page_size, self.max_pages,
-                    compute_dtype, self.pool.dtype)
-            return kernel_geometry_error(
-                widest, n_heads // n_shards, n_kv // n_shards,
-                head_dim, self.pool.page_size, self.max_pages,
-                compute_dtype, self.pool.dtype)
-
-        #: the round's token budget (:attr:`_round_budget`) comes from the
-        #: shapes, a power of two at most the class's ``RAGGED_CHUNK_CAP``.
-        #: Every width's program must be one a SINGLE prompt reaches, by
-        #: spending the budget and leaving a tail of that width (how a
-        #: harness warms them, so that none is first met under load): so
-        #: twice the budget fits ``max_len``, and the budget a window,
-        #: where a lane's chunk ends.  With the kernels it is also a round
-        #: their geometry rule admits
-        reach = min(max_len // 2, self._eva_window or max_len)
-        cap = min(self.RAGGED_CHUNK_CAP, 1 << max(reach, 1).bit_length() - 1)
-        self.round_budget_why = None if cap == self.RAGGED_CHUNK_CAP else (
-            f"a prompt cannot spend {2 * cap} tokens a round and leave a "
-            f"tail: max_len {max_len}"
-            + (f", windows of {self._eva_window}" if eva else ""))
-
-        # auto: the pallas ragged kernel on TPU at LONG contexts only
-        # (where the gather path's O(lanes*max_len) dense HBM
-        # materialization per step should dominate) and only at a
-        # geometry the shape rule admits; the XLA gather elsewhere.
-        # No chip measurement backs the threshold yet (ROADMAP S3);
-        # explicit use_kernel=True overrides it.  Under a mesh the
-        # kernel shards on the KV-heads dim (shard_map), so the auto
-        # pick covers sharded serving too.
-        auto = use_kernel is None
-        if auto:
-            use_kernel = (is_tpu() and max_len >= self.KERNEL_AUTO_MIN_CTX
-                          and n_heads % n_shards == 0)
-        if use_kernel:
-            # the widest round under ``cap`` the rule admits, and what it
-            # said of the next wider one; 0 where it admits no width
-            admitted, refusal = cap, None
-            while admitted and (err := kernel_error(admitted)):
-                admitted, refusal = admitted // 2, err
-            if admitted:
-                cap = admitted
-                self.round_budget_why = refusal or self.round_budget_why
-            elif auto:
-                use_kernel = False
-            elif not pallas_interpret():
-                # asked for a kernel the geometry cannot have: say which
-                # constraint, up front — a Mosaic error past this rule is
-                # a real error and propagates.  (The interpreter builds
-                # any geometry: a rule that admits no width binds nothing
-                # there.)
-                if self._owns_pool:
-                    self.pool.close()
-                if self.state is not None:
-                    self.state.close()
-                raise ValueError(f"use_kernel=True: {refusal}")
-        #: the widest budget THIS engine runs: what a harness sizes its
-        #: warm-up prompts by (a power of two; the class's is the ceiling)
-        self.RAGGED_CHUNK_CAP = cap
-        self.use_kernel = bool(use_kernel)
-        #: ragged dispatch plan (docs/PERFORMANCE.md "Ragged paged
-        #: attention"): mixed prefill+decode rounds run as ONE fused
-        #: ragged program (paged_mixed_step) instead of per-lane prefill
-        #: dispatches followed by a separate decode kind.  Default rides
-        #: ``use_kernel`` (the kernel family and the dispatch plan ship
-        #: together); ``ragged=True`` forces the unified plan onto the
-        #: XLA gather path, ``use_kernel=False`` alone keeps the legacy
-        #: split dispatch — the escape hatch.
-        self.ragged = self.use_kernel if ragged is None else bool(ragged)
-        self._step_kw = dict(lanes=lanes, max_pages=self.max_pages,
-                             n_heads=n_heads, n_layers=n_layers,
-                             compute_dtype=compute_dtype,
-                             use_kernel=self.use_kernel,
-                             n_kv_heads=n_kv, rope_theta=rope_theta,
-                             mesh=self.mesh)
-        if spec is not None:
-            # only where a spec was given: a dense engine's programs keep
-            # the key they always had in the jit memo
-            self._step_kw["spec"] = spec
+            param_sh = transformer_param_shardings(params, self.mesh)
+            if draft_params is not None:
+                draft_sh = transformer_param_shardings(draft_params,
+                                                       self.mesh)
+        self.params = jax.device_put(
+            params, self.pool.device if param_sh is None else param_sh)
+        #: the step programs (tpulab.engine.paged_steps.StepPrograms): the
+        #: scheduler looks one up where it dispatches and calls it
+        self.programs = StepPrograms(plan, param_sh, self.pool.kv_sharding,
+                                     self._rep, hbm, draft_sh)
         #: expert layers' counters (``debug_state()["moe"]``), summed on
         #: the host from the small array every dispatch of an expert model
         #: returns and the scheduler fetches WITH the dispatch's tokens
         self._moe_shape = moe_shape(spec)
         self._moe_assignments = (
-            np.zeros((len(spec.moe_layers), spec.n_experts), np.int64)
+            np.zeros((self._moe_shape[0], self._moe_shape[1] - 2), np.int64)
             if self._moe_shape else None)
         self.moe_decode_steps = 0    # decode steps that had a live lane
         self.moe_experts_hit = 0     # over those steps and expert layers
@@ -684,35 +463,11 @@ class ContinuousBatcher:
         #: the rows whose context was within ``topk`` (all keys selected)
         self._sparse = ({kind: dict(query_rows=0, keys_scored=0,
                                     keys_attended=0, dense_rows=0)
-                         for kind in ("decode", "round")} if sparse else None)
-        rep, psh = self._rep, self._param_sh
-        kvsh = self.pool.kv_sharding
+                         for kind in ("decode", "round")}
+                        if plan.sparse else None)
         #: host -> device and device -> host transfers the scheduler's
         #: thread made (:meth:`_put`, :meth:`_fetch`): one each a dispatch
         self.transfers: Dict[str, int] = {"h2d": 0, "d2h": 0}
-        #: what each program takes from the host, as fields of one buffer
-        self._fields = {kind: dispatch_fields(kind, lanes, self.max_pages)
-                        for kind in ("tick", "block", "spec", "round")}
-        # the K=1 tick: every array argument positional (a sharded jit
-        # attaches in_shardings by position), the host's as one buffer
-        self._step_sampled = self._jit(
-            partial(paged_decode_step_sampled, **self._step_kw), (1,),
-            (psh, kvsh, rep), (rep, rep, kvsh))
-        # mixed prefill+decode rounds (the ragged dispatch plan): ONE
-        # jitted program respecializes per pow2 bucket of the round's
-        # prefill tokens (round_width) — the chunks packed by token and
-        # a row for each lane's decode token through a single ragged
-        # forward + on-device pick
-        self._mixed = self._jit(
-            partial(paged_mixed_step, **self._step_kw), (1,),
-            (psh, kvsh, rep), (rep, rep, kvsh))
-        # EVA: a finished window's rows compacted into its summaries, one
-        # lane a dispatch, between the dispatches that write on either side
-        # of the boundary (_eva_compact); never fetched
-        self._compact = (self._jit(
-            partial(paged_eva_compact, spec=spec,
-                    use_kernel=self.use_kernel), (1,),
-            (psh, kvsh, rep), kvsh) if eva else None)
         #: EVA's work (``debug_state()["eva"]``): compactions by the kind of
         #: dispatch that finished the window (a mixed round's chunk or
         #: decode row, a decode block's step), the rows they read, the
@@ -721,22 +476,19 @@ class ContinuousBatcher:
         #: program's ``jit_paged_eva_compact`` in a capture)
         self._eva_stats = (dict(compactions={"round": 0, "decode": 0},
                                 rows_compacted=0, pages_released=0,
-                                compact_s=0.0) if eva else None)
-        if decode_block < 1:
-            raise ValueError("decode_block must be >= 1")
+                                compact_s=0.0)
+                           if plan.eva_window else None)
         #: max fused-decode steps per dispatch (K): a K-block amortizes the
         #: host<->device round trip over K tokens.  The per-block K is
         #: adaptive (see _pick_block_k) — this is the ceiling; 1 disables
         #: multi-step dispatch entirely.
         self.decode_block = min(int(decode_block), self.BLOCK_K_MENU[-1])
-        self._block_cache: Dict[int, Any] = {}
-        self._block_names: Dict[int, str] = {}   # K -> the program's name
         #: the carry a chain's first block passes: every lane of its buffer
         #: is ``fresh``, so only the shapes count; made once, never donated
         self._no_carry = jax.device_put(
             tuple(np.zeros((lanes,), t)
                   for t in (np.int32, np.int32, bool, np.int32)),
-            self._rep if self.mesh is not None else self.pool.device)
+            self._rep or self.pool.device)
         self._pending_block: Optional[Dict[str, Any]] = None
         self._step_ewma_s = 0.0   # per-scan-step device time estimate
         self._block_fetched_t = 0.0   # return of the last decode block's fetch
@@ -783,7 +535,8 @@ class ContinuousBatcher:
         #: with EVA windows ``keys`` are the ROWS attended (summaries and
         #: the window's own) and ``summary_keys`` the summaries among them
         self.lane_work = {kind: dict(passes=0, rows=0, keys=0,
-                                     **({"summary_keys": 0} if eva else {}))
+                                     **({"summary_keys": 0}
+                                        if plan.eva_window else {}))
                           for kind in ("decode", "round")}
         #: decode blocks enqueued before their predecessor was fetched
         #: (_chain_block): over ``dispatch_kinds["decode"]`` the share of
@@ -810,39 +563,10 @@ class ContinuousBatcher:
         self.queue_wait_s, self.queue_waits = 0.0, 0
         self.ttft_s, self.ttfts = 0.0, 0
         self.first_decode_wait_s, self.first_decode_waits = 0.0, 0
-        if prefill_flash is None:
-            # auto: pallas flash attention for the FULL-PROMPT forward on
-            # TPU (O(T*block) VMEM instead of a dense (T, T) score
-            # materialization).  Scope: the start==0 un-chunked prefill
-            # only — chunked prefills and prefix-cache tails run
-            # paged_extend's gather attention, which has no flash analog
-            # here.
-            prefill_flash = is_tpu()
-        self.prefill_flash = bool(prefill_flash)
-        self._prefill_kw = dict(n_heads=n_heads, n_layers=n_layers,
-                                compute_dtype=compute_dtype,
-                                n_kv_heads=n_kv, rope_theta=rope_theta)
-        self._prefill = self._build_prefill(self.prefill_flash)
-        # tail/chunk prefill against existing pool context (prefix-cache
-        # hits, chunked long prompts) — compiled per tail-length bucket
-        self._extend = self._jit(
-            partial(paged_extend, n_heads=n_heads, n_layers=n_layers,
-                    compute_dtype=compute_dtype, n_kv_heads=n_kv,
-                    rope_theta=rope_theta),
-            (1,), (psh, kvsh, rep, rep, rep, rep), (rep, kvsh))
-        # -- speculative decoding (a draft model riding the SAME pool
-        #    through a second per-lane page table; docs/PERFORMANCE.md) -----
-        # ``draft_params`` arms it: the draft proposes K tokens per lane
-        # inside the fused dispatch, the target verifies all of them in one
-        # batched forward, and each dispatch emits up to K+1 ACCEPTED
-        # tokens — multiplying the decode-block dispatch amortization by
-        # the acceptance rate.  Emitted tokens are bit-identical to the
-        # non-speculative stream (greedy and device-sampled); host-sampled
-        # lanes never enter the speculative path, and a lane whose rolling
-        # acceptance EWMA falls below ``spec_accept_floor`` (or whose
-        # verify dispatch trips chaos) degrades to plain blocks for the
-        # rest of its request.
-        self._spec: Optional[Dict[str, Any]] = None
+        # -- speculative decoding (the class docstring): ``draft_params``
+        #    arms it; a lane whose rolling acceptance EWMA falls below
+        #    ``spec_accept_floor`` (or whose verify dispatch trips chaos)
+        #    degrades to plain blocks -------------------------------------
         self.spec_accept_floor = float(spec_accept_floor)
         self.spec_dispatches = 0        # speculative decode dispatches
         self.spec_fallbacks = 0         # lanes degraded to plain blocks
@@ -853,47 +577,10 @@ class ContinuousBatcher:
         #                                 lane (EWMA degrades only)
         self.spec_probe_recoveries = 0  # probes whose lane stayed
         #                                 speculative (acceptance came back)
-        self._spec_block_cache: Dict[int, Any] = {}
-        if draft_params is not None:
-            dl = draft_n_layers or n_layers
-            dh = draft_n_heads or n_heads
-            dkv = draft_n_kv_heads or (n_kv if draft_n_heads is None else dh)
-            dd = weight_shape(draft_params["layer0"]["wqkv"])[0]
-            if dd // dh != d_model // n_heads or dkv != n_kv:
-                raise ValueError(
-                    "draft model KV geometry (head_dim, n_kv_heads) must "
-                    "match the target's — both write the shared paged pool")
-            if dl > n_layers:
-                raise ValueError("draft_n_layers must be <= n_layers (the "
-                                 "draft shares the pool's layer axis)")
-            if self.mesh is not None:
-                from tpulab.parallel.sharding import \
-                    transformer_param_shardings
-                self._draft_param_sh = transformer_param_shardings(
-                    draft_params, self.mesh)
-                draft_dev = jax.device_put(draft_params,
-                                           self._draft_param_sh)
-            else:
-                self._draft_param_sh = None
-                draft_dev = jax.device_put(draft_params, self.pool.device)
-            self._spec = {"params": draft_dev,
-                          "n_heads": dh, "n_layers": dl, "n_kv_heads": dkv}
-            self._spec_kw = dict(lanes=lanes, max_pages=self.max_pages,
-                                 n_heads=n_heads, n_layers=n_layers,
-                                 draft_n_heads=dh, draft_n_layers=dl,
-                                 compute_dtype=compute_dtype,
-                                 n_kv_heads=n_kv, draft_n_kv_heads=dkv,
-                                 rope_theta=rope_theta,
-                                 use_kernel=self.use_kernel,
-                                 mesh=self.mesh)
-            # draft-table warm-up: one fused draft forward over whatever
-            # context tail the second table is missing (never synced)
-            self._draft_extend = self._jit(
-                partial(paged_extend, n_heads=dh, n_layers=dl,
-                        compute_dtype=compute_dtype, n_kv_heads=dkv,
-                        rope_theta=rope_theta),
-                (1,), (self._draft_param_sh, kvsh, rep, rep, rep, rep),
-                (rep, kvsh))
+        self._spec = None if draft_params is None else {
+            "params": jax.device_put(
+                draft_params,
+                self.pool.device if draft_sh is None else draft_sh)}
         self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
         # host-memory KV tier (tpulab.kvcache): None/False = off (zero
         # cost); True = a manager with the default host budget; an int =
@@ -924,15 +611,12 @@ class ContinuousBatcher:
         # prefill's last-position logits row under ("fablog", digest), so
         # a FetchKV RPC can serve both to the digest's routed-astray
         # fetchers without evicting this replica's own copy.  Requires
-        # kv_offload (the host tier IS the export buffer).  Publishes
-        # ride the legacy prefill dispatch only: the ragged plan's mixed
-        # rounds never fetch a host-visible logits row (documented
-        # limitation; ROADMAP follow-up).
-        if kv_publish and self.kv_offload is None:
-            raise ValueError("kv_publish requires kv_offload")
+        # kv_offload (the host tier IS the export buffer: refused above
+        # without one).  Publishes ride the legacy prefill dispatch only:
+        # the ragged plan's mixed rounds never fetch a host-visible logits
+        # row (documented limitation; ROADMAP follow-up).
         self.kv_publish = bool(kv_publish)
-        from collections import OrderedDict as _OD
-        self._fab_handles: "Dict[bytes, Any]" = _OD()
+        self._fab_handles: "Dict[bytes, Any]" = OrderedDict()
         self._fab_lock = threading.Lock()
         self.kv_publishes = 0  # prompt snapshots exported to the fabric
         #: rolling prefill throughput (tokens/s, EWMA) — the fabric's
@@ -1016,12 +700,12 @@ class ContinuousBatcher:
         if n <= 0:
             return
         w = self.lane_work[kind]
-        if self._eva_window:
+        if self.plan.eva_window:
             # the rows of one window (no dispatch crosses a boundary): the
             # keys of a row are the rows of the table at or before it
-            done = start // self._eva_window
+            done = start // self.plan.eva_window
             summaries = done * self.model_spec.eva_summaries
-            start -= done * self._eva_saved
+            start -= done * self.plan.eva_saved
             w["summary_keys"] += summaries * (n if kind == "decode" else 1)
         triangle = n * start + n * (n + 1) // 2
         w["passes"] += n if kind == "decode" else 1
@@ -1043,18 +727,18 @@ class ContinuousBatcher:
         """The rows of ``req``'s page table that hold its positions ``[0,
         n)``, ``n`` inside the window it is in: ``n`` itself, less what its
         compacted EVA windows gave back."""
-        return n - req.eva_done * self._eva_saved
+        return n - req.eva_done * self.plan.eva_saved
 
     def _boundary(self, req: _PagedRequest) -> int:
         """The first position ``req`` may not take in before a compaction:
         the end of its EVA window (without EVA, no such position)."""
-        if not self._eva_window:
+        if not self.plan.eva_window:
             return 1 << 62
-        return (req.eva_done + 1) * self._eva_window
+        return (req.eva_done + 1) * self.plan.eva_window
 
     def _peak_rows(self, req: _PagedRequest, total: int) -> int:
         """The most rows ``req`` holds on its way to ``total`` positions."""
-        if not self._eva_window:
+        if not self.plan.eva_window:
             return total
         return self.model_spec.cache_rows_peak(total, req.eva_done)
 
@@ -1069,7 +753,7 @@ class ContinuousBatcher:
         the top of a pass and before a decode plan, for the lanes outside a
         block dispatched ahead (whose lanes the chain holds short of their
         boundary, :meth:`_chain_block`)."""
-        w, ps, spec = self._eva_window, self.page_size, self.model_spec
+        w, ps, spec = self.plan.eva_window, self.page_size, self.model_spec
         chained = (self._pending_block["lane_reqs"]
                    if self._pending_block is not None else ())
         with self._cv:
@@ -1082,7 +766,7 @@ class ContinuousBatcher:
             with stage(self._stages, "dispatch"):
                 first = req.eva_done * (spec.eva_summaries // ps)
                 pages = np.asarray(req.pages[first:first + w // ps], np.int32)
-                self._kv_state = self._compact(
+                self._kv_state = self.programs.compact(
                     self.params, self._kv_state, self._put(pages))
                 self._stages.launched()
             with self._cv:
@@ -1107,8 +791,7 @@ class ContinuousBatcher:
         on the engine's device, replicated under a mesh."""
         import jax
         self.transfers["h2d"] += 1
-        return jax.device_put(
-            host, self._rep if self.mesh is not None else self.pool.device)
+        return jax.device_put(host, self._rep or self.pool.device)
 
     def _fetch(self, dev) -> np.ndarray:
         """ONE blocking device -> host fetch, counted."""
@@ -1137,81 +820,6 @@ class ContinuousBatcher:
     #: the parts of a decode block's and a mixed round's ``dispatch``
     #: stage: host arrays, their transfer, the jitted call
     DISPATCH_PARTS = ("dispatch.arrays", "dispatch.put", "dispatch.call")
-
-    def _jit(self, fn, donate, in_sh, out_sh):
-        """``jax.jit`` with explicit in/out shardings under a mesh — the
-        partitioner then inserts the collectives (psum after row-parallel
-        matmuls, gathers where layouts demand) INSIDE the compiled
-        program — and a plain single-device jit otherwise (``in_sh`` /
-        ``out_sh`` ignored; mesh=None is exactly the pre-mesh build).
-
-        Jitted programs are shared through a process-level memo
-        (:data:`_JIT_MEMO`) keyed by the function + its baked static
-        config + donation + shardings: engines with identical program
-        geometry (test suites, fleets of loopback replicas, bench
-        modes) reuse one compiled-program cache instead of re-tracing
-        and re-compiling identical HLO per engine.  Params and pools
-        are traced ARGUMENTS, never baked, so sharing is purely a
-        compile-time dedupe; configs with unhashable baked state (e.g.
-        a flash-attention closure) fall back to a private jit.
-
-        With an arbiter measuring scratch, the (shared) jit is wrapped
-        per engine so each distinct shape signature records its
-        compile-time temp bytes as a ``("scratch", ...)`` ledger claim
-        (tpulab.hbm.scratch) — the third tenant the pre-arbiter
-        headroom math never saw."""
-        import jax
-
-        base = getattr(fn, "func", fn)
-        if fn is not base:
-            # a bare partial is ``jit__unknown`` in a trace: name the
-            # program after its function (+ the block size it binds)
-            k = fn.keywords.get("k")
-            fn.__name__ = base.__name__ + (f"_k{k}" if k is not None else "")
-
-        def build():
-            if self.mesh is None:
-                return jax.jit(fn, donate_argnums=donate)
-            return jax.jit(fn, donate_argnums=donate,
-                           in_shardings=in_sh, out_shardings=out_sh)
-
-        try:
-            key = (base.__module__, base.__qualname__,
-                   getattr(fn, "args", ()),
-                   tuple(sorted(getattr(fn, "keywords", {}).items())),
-                   donate,
-                   in_sh if self.mesh is not None else None,
-                   out_sh if self.mesh is not None else None)
-            hash(key)
-        except TypeError:
-            key = None
-        if key is None:
-            jitted = build()
-        else:
-            with _JIT_MEMO_LOCK:
-                jitted = _JIT_MEMO.get(key)
-            if jitted is None:
-                jitted = build()
-                with _JIT_MEMO_LOCK:
-                    jitted = _JIT_MEMO.setdefault(key, jitted)
-        if self.hbm is not None and self.hbm.measure_scratch:
-            from tpulab.hbm import MeasuredJit
-            name = getattr(getattr(fn, "func", fn), "__name__", "jit")
-            jitted = MeasuredJit(jitted, self.hbm, name)
-        return jitted
-
-    def _build_prefill(self, flash: bool):
-        """Jitted fused prefill, compiled per prompt-length bucket (powers
-        of two); ``flash`` selects the pallas prompt-attention kernel."""
-        attn_fn = None
-        if flash:
-            from tpulab.ops.flash_attention import make_flash_attention_fn
-            attn_fn = make_flash_attention_fn(causal=True)
-        rep, kvsh = self._rep, self.pool.kv_sharding
-        return self._jit(
-            partial(paged_prefill, attention_fn=attn_fn,
-                    **self._prefill_kw),
-            (1,), (self._param_sh, kvsh, rep, rep, rep), (rep, kvsh))
 
     # -- public -------------------------------------------------------------
     def submit(self, prompt, steps: int, on_token=None,
@@ -2205,7 +1813,7 @@ class ContinuousBatcher:
                         f"({len(req.tokens_out)}/{req.steps} tokens)"))
             try:
                 prefilled = False
-                if self._eva_window:
+                if self.plan.eva_window:
                     self._eva_compact()
                 if self.ragged:
                     # ragged dispatch plan: pending prompts and decode
@@ -2227,7 +1835,7 @@ class ContinuousBatcher:
                         self._admit_locked()
                         snapshot = list(self._active)
                     self._deliver((), done_reqs)
-                if self._eva_window:
+                if self.plan.eva_window:
                     self._eva_compact()     # a window the round finished
                 progressed = self._tick(snapshot, jnp) or prefilled
                 if self.hbm is not None:
@@ -2344,7 +1952,7 @@ class ContinuousBatcher:
                 t_pad = 1 << (t - 1).bit_length()  # pow2: small jit cache
                 tokens = np.zeros((1, t_pad), np.int32)
                 tokens[0, :t] = prompt
-                last_logits, self.pool.kv = self._prefill(
+                last_logits, self.pool.kv = self.programs.prefill(
                     self.params, self.pool.kv, tables_j,
                     self._put(tokens), self._put(np.int32(t)))
                 ticket = st.launched()
@@ -2357,7 +1965,7 @@ class ContinuousBatcher:
                     m_pad = 1 << (m - 1).bit_length()
                     tokens = np.zeros((1, m_pad), np.int32)
                     tokens[0, :m] = prompt[start:start + m]
-                    last_logits, self.pool.kv = self._extend(
+                    last_logits, self.pool.kv = self.programs.extend(
                         self.params, self.pool.kv, tables_j,
                         self._put(tokens), self._put(np.int32(start)),
                         self._put(np.int32(start + m)))
@@ -2719,7 +2327,7 @@ class ContinuousBatcher:
                                            (sp.seed >> 32) & 0xFFFFFFFF)
                         else:
                             host_lanes.append(lane)
-                buf = pack_words(self._fields["round"], dict(
+                buf = pack_words(self.programs.fields["round"], dict(
                     tables=tables, q_lens=q_lens, kv_lens=kv_lens,
                     temps=temps, seeds=seeds,
                     rows=np.stack([toks, row_lane, row_off])))
@@ -2730,7 +2338,7 @@ class ContinuousBatcher:
             with part(st, "dispatch.put"):
                 packed = self._put(buf)
             with part(st, "dispatch.call"):
-                out, last_dev, self._kv_state = self._mixed(
+                out, last_dev, self._kv_state = self.programs.mixed(
                     self.params, self._kv_state, packed)
                 # the program is on the device's queue: the turn ends here,
                 # the results' copy to the host starts behind it
@@ -2927,18 +2535,6 @@ class ContinuousBatcher:
             self.ragged_dispatches += 1
 
     # -- fused decode dispatch ----------------------------------------------
-    def _block_fn(self, k: int):
-        """Jitted K-step fused decode (compiled once per block size)."""
-        fn = self._block_cache.get(k)
-        if fn is None:
-            rep, kvsh = self._rep, self.pool.kv_sharding
-            fn = self._jit(partial(paged_decode_block, k=k, **self._step_kw),
-                           (1,), (self._param_sh, kvsh, rep, rep),
-                           (rep,) * 5 + (kvsh,))
-            self._block_cache[k] = fn
-            self._block_names[k] = f"paged_decode_block_k{k}"
-        return fn
-
     def _tight_slack_s(self) -> float:
         """Deadline slack below which a lane counts as *tight* (adaptive K
         drops to <=2): roughly two max-size blocks of measured decode
@@ -3291,7 +2887,7 @@ class ContinuousBatcher:
                         stops[lane, :len(st)] = st
             else:
                 temps, seeds, stops = host
-            buf = pack_words(self._fields["block"], dict(
+            buf = pack_words(self.programs.fields["block"], dict(
                 tables=tables, lengths=lengths, tokens=tokens, active=active,
                 temps=temps, seeds=seeds, rem=rem, stops=stops,
                 fresh=np.full((b,), host is None)))
@@ -3307,13 +2903,14 @@ class ContinuousBatcher:
             packed = self._put(buf)
         with part(clock, "dispatch.call"):
             (out, len_f, tok_f, live_f, rem_f,
-             self._kv_state) = self._block_fn(k)(
+             self._kv_state) = self.programs.block(k)(
                 self.params, self._kv_state, packed,
                 carry or self._no_carry)
             ticket = clock.launched()
             out.copy_to_host_async()
-        clock.note(program=self._block_names[k], k=k, lanes=len(lane_reqs),
-                   rows=len(lane_reqs), ahead=int(ahead > 0))
+        clock.note(program=self.programs.block_names[k], k=k,
+                   lanes=len(lane_reqs), rows=len(lane_reqs),
+                   ahead=int(ahead > 0))
         self.decode_dispatches += 1
         self.decode_block_steps += k
         self._note_dispatch("decode")
@@ -3487,19 +3084,6 @@ class ContinuousBatcher:
     #: chaos-verify degrades never probe (plain for the rest of the request)
     SPEC_PROBE_INTERVAL = 4
 
-    def _spec_block_fn(self, k: int):
-        """Jitted speculative block (compiled once per draft length)."""
-        fn = self._spec_block_cache.get(k)
-        if fn is None:
-            rep, kvsh = self._rep, self.pool.kv_sharding
-            fn = self._jit(partial(paged_speculative_block, k=k,
-                                   **self._spec_kw),
-                           (2,),
-                           (self._param_sh, self._draft_param_sh, kvsh, rep),
-                           (rep,) * 5 + (kvsh,))
-            self._spec_block_cache[k] = fn
-        return fn
-
     def _warm_draft(self, req: _PagedRequest, jnp) -> None:
         """Bring the lane's draft KV up to the target context (positions
         ``[draft_len, length)``): one fused draft forward over the
@@ -3520,7 +3104,7 @@ class ContinuousBatcher:
         tokens[0, :m] = ctx[start:t]
         tables = np.zeros((self.max_pages,), np.int32)
         tables[:len(req.draft_pages)] = req.draft_pages
-        _last, self.pool.kv = self._draft_extend(
+        _last, self.pool.kv = self.programs.draft_extend(
             self._spec["params"], self.pool.kv, self._put(tables),
             self._put(tokens), self._put(np.int32(start)),
             self._put(np.int32(t)))
@@ -3580,9 +3164,9 @@ class ContinuousBatcher:
                 stops[lane, :len(st)] = st
         t0 = _time.perf_counter()
         (out, _len_f, _tok_f, _live_f, _rem_f,
-         self.pool.kv) = self._spec_block_fn(k)(
+         self.pool.kv) = self.programs.spec_block(k)(
             self.params, self._spec["params"], self.pool.kv,
-            self._put(pack_words(self._fields["spec"], dict(
+            self._put(pack_words(self.programs.fields["spec"], dict(
                 tables=tables, draft_tables=dtables, lengths=lengths,
                 tokens=tokens, active=active, temps=temps, seeds=seeds,
                 rem=rem, stops=stops))))
@@ -3704,9 +3288,9 @@ class ContinuousBatcher:
             # delay makes every lane's step slow (deadline-storm scenarios)
             chaos.trip("engine.step")
             t0 = _time.perf_counter()
-            out, logits, self._kv_state = self._step_sampled(
+            out, logits, self._kv_state = self.programs.tick(
                 self.params, self._kv_state,
-                self._put(pack_words(self._fields["tick"], dict(
+                self._put(pack_words(self.programs.fields["tick"], dict(
                     tables=tables, lengths=lengths, tokens=tokens,
                     active=active, temps=temps, seeds=seeds))))
             ticket = st.launched()

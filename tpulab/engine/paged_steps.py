@@ -32,12 +32,17 @@ attention layers' pages and the other layers' per-lane recurrent state
 for a model with a learned indexer it is the pair ``(page store, index
 rows)`` (``PagedKVPool.kv`` and ``.index``).
 The functions keep their ``__name__``: a trace names a program
-``jit_<name>``, and the benchmark's readers key on it.  Nothing here imports
-the scheduler (:mod:`tpulab.engine.paged`).
+``jit_<name>``, and the benchmark's readers key on it.
+:class:`StepPrograms` jits them for one engine plan
+(:mod:`tpulab.engine.plan`) through the process-level program memo and is
+what the scheduler dispatches through.  Nothing here imports the scheduler
+(:mod:`tpulab.engine.paged`).
 """
 
 from __future__ import annotations
 
+import threading
+from functools import partial
 from typing import Any, Dict, Optional
 
 import numpy as np
@@ -1614,3 +1619,172 @@ def paged_extend(params, kv_pool, tables, tokens, start, valid_total,
     x_last = _rmsnorm(x_last, params["final_norm"]["scale"])
     last = _lm_head(params, x_last)[0]                # (vocab,)
     return last, kv_pool
+
+
+#: process-level memo of jitted engine programs (see StepPrograms._jit):
+#: identical-geometry engines share one jitted callable and therefore one
+#: compiled-program cache.  Bounded by the process's program-config
+#: variety; entries hold compiled executables, never parameter or pool
+#: buffers (those are traced arguments).
+_JIT_MEMO: Dict[Any, Any] = {}
+_JIT_MEMO_LOCK = threading.Lock()
+
+
+class StepPrograms:
+    """The jitted step programs of one engine plan
+    (:class:`~tpulab.engine.plan.EnginePlan`), under the parameters'
+    (``psh``), the page store's and the replicated sharding (None without a
+    mesh).  ``tick``,
+    ``mixed``, ``compact``, ``prefill``, ``extend`` and ``draft_extend``
+    (None where the plan has no such program) hold the jitted callable
+    itself, as does what :meth:`block` and :meth:`spec_block` return: the
+    scheduler calls it where it dispatches, with no Python frame between
+    its call and the program's ``pallas_call`` s (a frame there costs
+    ``setup_s``: :func:`_layer_block`)."""
+
+    def __init__(self, plan, psh=None, kvsh=None, rep=None, hbm=None,
+                 draft_psh=None):
+        self.mesh, self.hbm = plan.mesh, hbm
+        #: what each program takes from the host, as fields of one buffer
+        self.fields = {kind: dispatch_fields(kind, plan.lanes, plan.max_pages)
+                       for kind in ("tick", "block", "spec", "round")}
+        attn_fn = None
+        if plan.prefill_flash:
+            from tpulab.ops.flash_attention import make_flash_attention_fn
+            attn_fn = make_flash_attention_fn(causal=True)
+        step_kw = plan.step_kw
+        model_kw = {name: step_kw[name] for name in (
+            "n_heads", "n_layers", "compute_dtype", "n_kv_heads",
+            "rope_theta")}
+        # a program: function, bound keywords, donated arguments, in and out
+        # shardings.  Every array argument is positional (a sharded jit
+        # attaches in_shardings by position), the host's one packed buffer
+        step = ((1,), (psh, kvsh, rep), (rep, rep, kvsh))
+        one_lane = ((1,), (psh, kvsh, rep, rep, rep, rep), (rep, kvsh))
+        table = {
+            # the K=1 tick
+            "tick": (paged_decode_step_sampled, step_kw) + step,
+            # mixed prefill+decode rounds (the ragged dispatch plan): ONE
+            # program respecializes per pow2 bucket of the round's prefill
+            # tokens (round_width): the chunks packed by token and a row
+            # for each lane's decode token through a single ragged forward
+            # + on-device pick
+            "mixed": (paged_mixed_step, step_kw) + step,
+            # the legacy plan's fused prefill, compiled per prompt-length
+            # bucket (powers of two); ``prefill_flash`` selects the pallas
+            # prompt-attention kernel
+            "prefill": (paged_prefill,
+                        dict(model_kw, attention_fn=attn_fn),
+                        (1,), (psh, kvsh, rep, rep, rep), (rep, kvsh)),
+            # tail/chunk prefill against existing pool context
+            # (prefix-cache hits, chunked long prompts), compiled per
+            # tail-length bucket
+            "extend": (paged_extend, model_kw) + one_lane,
+        }
+        self.compact = self.draft_extend = None
+        if plan.eva_window:
+            # EVA: a finished window's rows compacted into its summaries,
+            # one lane a dispatch; never fetched
+            table["compact"] = (
+                paged_eva_compact,
+                dict(spec=plan.spec, use_kernel=plan.use_kernel),
+                (1,), (psh, kvsh, rep), kvsh)
+        if plan.draft:
+            # draft-table warm-up: one fused draft forward over whatever
+            # context tail the second table is missing (never synced)
+            table["draft_extend"] = (
+                paged_extend, dict(model_kw, **plan.draft), (1,),
+                (draft_psh,) + one_lane[1][1:], one_lane[2])
+        for name, (fn, kw, donate, in_sh, out_sh) in table.items():
+            setattr(self, name,
+                    self._jit(partial(fn, **kw), donate, in_sh, out_sh))
+        # the block programs, compiled once per block size in use
+        self._block = (paged_decode_block, step_kw, (1,),
+                       (psh, kvsh, rep, rep), (rep,) * 5 + (kvsh,))
+        self._spec_block = (
+            paged_speculative_block,
+            dict({k: v for k, v in step_kw.items() if k != "spec"},
+                 **{"draft_" + k: v for k, v in (plan.draft or {}).items()}),
+            (2,), (psh, draft_psh, kvsh, rep), (rep,) * 5 + (kvsh,))
+        self.blocks: Dict[int, Any] = {}
+        self.block_names: Dict[int, str] = {}   # K -> the program's name
+        self.spec_blocks: Dict[int, Any] = {}
+
+    def block(self, k: int):
+        """Jitted K-step fused decode (compiled once per block size)."""
+        if k not in self.blocks:
+            self.blocks[k] = self._sized(self._block, k)
+            self.block_names[k] = f"paged_decode_block_k{k}"
+        return self.blocks[k]
+
+    def spec_block(self, k: int):
+        """Jitted speculative block (compiled once per draft length)."""
+        if k not in self.spec_blocks:
+            self.spec_blocks[k] = self._sized(self._spec_block, k)
+        return self.spec_blocks[k]
+
+    def _sized(self, program, k: int):
+        fn, kw, *how = program
+        return self._jit(partial(fn, k=k, **kw), *how)
+
+    def _jit(self, fn, donate, in_sh, out_sh):
+        """``jax.jit`` with explicit in/out shardings under a mesh — the
+        partitioner then inserts the collectives (psum after row-parallel
+        matmuls, gathers where layouts demand) INSIDE the compiled
+        program — and a plain single-device jit otherwise (``in_sh`` /
+        ``out_sh`` ignored; mesh=None is exactly the pre-mesh build).
+
+        Jitted programs are shared through a process-level memo
+        (:data:`_JIT_MEMO`) keyed by the function + its baked static
+        config + donation + shardings: engines with identical program
+        geometry (test suites, fleets of loopback replicas, bench
+        modes) reuse one compiled-program cache instead of re-tracing
+        and re-compiling identical HLO per engine.  Params and pools
+        are traced ARGUMENTS, never baked, so sharing is purely a
+        compile-time dedupe; configs with unhashable baked state (e.g.
+        a flash-attention closure) fall back to a private jit.
+
+        With an arbiter measuring scratch, the (shared) jit is wrapped
+        per engine so each distinct shape signature records its
+        compile-time temp bytes as a ``("scratch", ...)`` ledger claim
+        (tpulab.hbm.scratch) — the third tenant the pre-arbiter
+        headroom math never saw."""
+        import jax
+
+        base = getattr(fn, "func", fn)
+        if fn is not base:
+            # a bare partial is ``jit__unknown`` in a trace: name the
+            # program after its function (+ the block size it binds)
+            k = fn.keywords.get("k")
+            fn.__name__ = base.__name__ + (f"_k{k}" if k is not None else "")
+
+        def build():
+            if self.mesh is None:
+                return jax.jit(fn, donate_argnums=donate)
+            return jax.jit(fn, donate_argnums=donate,
+                           in_shardings=in_sh, out_shardings=out_sh)
+
+        try:
+            key = (base.__module__, base.__qualname__,
+                   getattr(fn, "args", ()),
+                   tuple(sorted(getattr(fn, "keywords", {}).items())),
+                   donate,
+                   in_sh if self.mesh is not None else None,
+                   out_sh if self.mesh is not None else None)
+            hash(key)
+        except TypeError:
+            key = None
+        if key is None:
+            jitted = build()
+        else:
+            with _JIT_MEMO_LOCK:
+                jitted = _JIT_MEMO.get(key)
+            if jitted is None:
+                jitted = build()
+                with _JIT_MEMO_LOCK:
+                    jitted = _JIT_MEMO.setdefault(key, jitted)
+        if self.hbm is not None and self.hbm.measure_scratch:
+            from tpulab.hbm import MeasuredJit
+            name = getattr(getattr(fn, "func", fn), "__name__", "jit")
+            jitted = MeasuredJit(jitted, self.hbm, name)
+        return jitted

@@ -1,0 +1,359 @@
+"""The engine plan: what a :class:`~tpulab.engine.paged.ContinuousBatcher`
+will be, decided from its arguments before anything is allocated.
+
+:func:`plan_engine` returns an :class:`EnginePlan` (the model's kinds, the
+page store's geometry, ``use_kernel`` and ``ragged``, the round's token
+budget, the keywords the step programs are keyed by) and raises, by name,
+every option a kind of model refuses and every geometry the kernels' rules
+refuse.  It builds no pool and no lane-state store, puts nothing on a device
+and starts no thread, so a refusal has nothing to clean up and a decision
+is tested without an engine (``tests/test_engine_plan.py``).
+:func:`kernel_error` is the one place that knows which geometry rule of
+:mod:`tpulab.ops` goes with which kind of model.  Nothing here imports the
+scheduler.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Optional
+
+from tpulab.engine.kv_pool import latent_page_shape
+from tpulab.engine.paged_steps import round_width
+from tpulab.tpu import platform
+
+
+@dataclasses.dataclass(frozen=True)
+class EnginePlan:
+    """What :func:`plan_engine` decided.  ``use_kernel`` and ``ragged`` are
+    the constructor's arguments (None: not chosen yet) while the budget is
+    searched, booleans in the plan it returns."""
+
+    #: tpulab.models.spec.ModelSpec: None serves the dense decoder of
+    #: ``n_heads``/``n_kv``/``rope_theta`` with today's constants
+    spec: Optional[Any]
+    lanes: int
+    max_len: int
+    page_size: int
+    n_heads: int
+    n_layers: int
+    rope_theta: Optional[float]
+    vocab: int
+    compute_dtype: Any
+    #: what a page stores: may be NARROWER than the compute path (e.g.
+    #: float8_e4m3fn under bf16 compute halves KV HBM *and* decode bandwidth:
+    #: the decode tick is KV-bandwidth-bound).  Writes round on scatter,
+    #: reads upcast in the gather/kernel; attention math stays in f32
+    kv_dtype: Any
+    mesh: Optional[Any]
+    #: model-axis shards of the page payloads (1 on one device)
+    n_shards: int
+    #: the kind of per-lane recurrent state ("mamba", "gdn"; None without
+    #: layers that keep one); the page store then holds the attention layers
+    state_kind: Optional[str]
+    #: a learned indexer: index rows beside the K/V pages, the pair rotated
+    #: through every dispatch as a hybrid's (pages, state) is
+    sparse: bool
+    #: EVA: positions a window, and the rows a compaction takes off a
+    #: lane's table (both 0 without: a row is a position).  With windows a
+    #: lane's rows are not its positions, so nothing that takes a request's
+    #: pages for its positions is carried
+    eva_window: int
+    eva_saved: int
+    #: the latent cache entry's width (0: K and V rows)
+    latent: int
+    #: KV heads and head width of the pages (a hybrid's attention layers are
+    #: the spec's: its KV heads size the pages)
+    n_kv: int
+    head_dim: int
+    #: layers of the page store: only attention layers own one
+    pool_layers: int
+    #: an index key's width (0 without an indexer)
+    index_dim: int
+    #: a page table's width: it covers the most ROWS a lane of ``max_len``
+    #: positions holds
+    max_pages: int
+    #: page-aligned (a chunk's successor writes from a page boundary)
+    prefill_chunk: Optional[int]
+    #: the pallas flash kernel for the FULL-PROMPT forward of the legacy plan
+    prefill_flash: bool
+    #: attention through the pallas ragged kernel family (the XLA gather
+    #: otherwise)
+    use_kernel: Optional[bool]
+    #: ragged dispatch plan (docs/PERFORMANCE.md "Ragged paged attention"):
+    #: mixed prefill+decode rounds as ONE fused program (paged_mixed_step),
+    #: not per-lane prefill dispatches and a separate decode kind.  Default
+    #: rides ``use_kernel`` (the kernel family and the plan ship together);
+    #: ``ragged=True`` forces it onto the XLA gather, ``use_kernel=False``
+    #: alone keeps the legacy split dispatch: the escape hatch
+    ragged: Optional[bool]
+    #: the widest token budget of a mixed round THIS engine runs (a power
+    #: of two), and what refused the next wider one (None at the ceiling)
+    round_cap: int
+    round_budget_why: Optional[str]
+    #: the speculative draft's ``n_heads``, ``n_layers``, ``n_kv_heads``
+    draft: Optional[Dict[str, int]] = None
+
+    @property
+    def step_kw(self) -> Dict[str, Any]:
+        """The keywords the step programs bind, and are keyed by in the
+        process's program memo."""
+        kw = dict(lanes=self.lanes, max_pages=self.max_pages,
+                  n_heads=self.n_heads, n_layers=self.n_layers,
+                  compute_dtype=self.compute_dtype,
+                  use_kernel=self.use_kernel, n_kv_heads=self.n_kv,
+                  rope_theta=self.rope_theta, mesh=self.mesh)
+        if self.spec is not None:
+            # only where a spec was given: a dense engine's programs keep
+            # the key they always had in the memo
+            kw["spec"] = self.spec
+        return kw
+
+
+def kernel_error(plan: EnginePlan, cap: int, verify_width: int):
+    """What the kernels' geometry rules say of ``plan`` (None: admitted):
+    Mosaic's shape rule at the PER-SHARD geometry (one shard's program is
+    the one that must build) and the widest segment a dispatch can carry: a
+    mixed round that spends a token budget of ``cap`` (``prefill_chunk``
+    lowers it) under the ragged plan, a K+1 verify of ``verify_width``
+    otherwise."""
+    from tpulab.ops.ragged_attention import (kernel_geometry_error,
+                                             latent_geometry_error)
+    spec = plan.spec
+    if plan.state_kind:
+        from tpulab.ops.gated_delta_rule import rule_geometry_error
+        from tpulab.ops.selective_scan import scan_geometry_error
+        err = (scan_geometry_error(spec.d_inner, spec.d_state)
+               if plan.state_kind == "mamba" else
+               rule_geometry_error(spec.gdn_k_dim, spec.gdn_v_dim))
+        if err:
+            return err
+    if plan.eva_window:
+        from tpulab.ops.eva_summary import summary_geometry_error
+        err = summary_geometry_error(plan.head_dim, spec.eva_chunk,
+                                     plan.page_size)
+        if err:
+            return err
+    widest = (round_width(min(plan.prefill_chunk or cap, cap))
+              if plan.ragged is not False else verify_width)
+    if plan.latent:
+        return latent_geometry_error(
+            widest, plan.n_heads,
+            latent_page_shape(plan.page_size, plan.latent)[2],
+            spec.kv_lora_rank, plan.page_size, plan.max_pages,
+            plan.compute_dtype, plan.kv_dtype)
+    return kernel_geometry_error(
+        widest, plan.n_heads // plan.n_shards, plan.n_kv // plan.n_shards,
+        plan.head_dim, plan.page_size, plan.max_pages,
+        plan.compute_dtype, plan.kv_dtype)
+
+
+def plan_engine(*, spec, n_heads: int, n_layers: int,
+                n_kv_heads: Optional[int], rope_theta: Optional[float],
+                d_model: int, vocab: int, lanes: int, max_len: int,
+                page_size: int, prefill_chunk: Optional[int],
+                use_kernel: Optional[bool], ragged: Optional[bool],
+                prefill_flash: Optional[bool], compute_dtype, kv_dtype,
+                round_ceiling: int, kernel_auto_min_ctx: int,
+                verify_width: int, pool=None, mesh=None, hbm=None,
+                draft_params=None,
+                draft_n_layers: Optional[int] = None,
+                draft_n_heads: Optional[int] = None,
+                draft_n_kv_heads: Optional[int] = None, kv_offload=None,
+                kv_publish: bool = False,
+                prefix_cache: bool = False) -> EnginePlan:
+    """The :class:`EnginePlan` of a scheduler built with these arguments
+    (``ContinuousBatcher``'s own, under their names).  ``d_model`` and
+    ``vocab`` are the embedding's shape; ``round_ceiling``,
+    ``kernel_auto_min_ctx`` and ``verify_width`` the scheduler class's
+    ``RAGGED_CHUNK_CAP``, ``KERNEL_AUTO_MIN_CTX`` and widest block + 1.  Of
+    ``hbm`` and ``kv_offload`` it reads whether they are there, of
+    ``draft_params`` the draft's width, of a provided ``pool`` its
+    ``dtype``, ``entry_kind``, ``n_layers``, ``mesh`` and whether it has
+    ``index`` rows."""
+    import jax.numpy as jnp
+
+    hybrid = spec is not None and bool(spec.state_layers)
+    sparse = spec is not None and bool(spec.index_topk)
+    eva = spec is not None and bool(spec.eva_window)
+    special = spec is not None and (spec.cache_entry != "kv"
+                                    or spec.moe_layers or hybrid or eva)
+    if special:
+        # such a model is served on the ragged plan alone: the options that
+        # plan, that cache-entry kind or a per-lane state (nothing
+        # snapshots, shares or ships it yet) does not carry are refused here
+        refused = {
+            "ragged=False (the legacy split plan)": ragged is False,
+            "draft_params (speculative blocks)": draft_params is not None,
+            "mesh": mesh is not None
+            or getattr(pool, "mesh", None) is not None,
+            "kv_offload": kv_offload not in (None, False),
+            "kv_publish": bool(kv_publish),
+            "prefix_cache": bool(prefix_cache),
+            "kv_dtype other than the compute dtype":
+                kv_dtype is not None
+                and jnp.dtype(kv_dtype) != jnp.dtype(compute_dtype),
+            "hbm (the elastic page store)": hbm is not None,
+        }
+        bad = [name for name, on in refused.items() if on]
+        if bad:
+            kinds = sorted({f"{spec.cache_entry} pages"}
+                           | {f"{k} layers" for k in spec.mixers
+                              if k != "attention"}
+                           | {f"{k} FFNs" for k in spec.layer_kinds
+                              if k != "dense"}
+                           | ({"EVA windows (compacted pages)"} if eva
+                              else set()))
+            raise NotImplementedError(
+                f"a model with {', '.join(kinds)} is served on the "
+                "ragged plan only; not supported with it: "
+                + ", ".join(bad))
+        if (spec.n_heads, spec.n_layers) != (n_heads, n_layers):
+            raise ValueError(
+                f"spec (n_heads {spec.n_heads}, n_layers {spec.n_layers})"
+                f" disagrees with n_heads={n_heads}, n_layers={n_layers}")
+        if eva and page_size != spec.eva_chunk:
+            raise ValueError(
+                f"page_size {page_size} is not the spec's eva_chunk "
+                f"{spec.eva_chunk}: a chunk's summary is taken from one "
+                "page and written as one row")
+        ragged = True
+    kv_dtype = kv_dtype or compute_dtype
+    n_kv = (spec.n_kv_heads if hybrid or sparse or eva
+            else n_kv_heads or n_heads)
+    eva_window = spec.eva_window if eva else 0
+    max_pages = ((spec.cache_rows_peak(max_len) if eva else max_len)
+                 + page_size - 1) // page_size
+    if prefill_chunk is not None:
+        if prefill_chunk < page_size:
+            raise ValueError("prefill_chunk must be >= page_size")
+        prefill_chunk -= prefill_chunk % page_size
+    latent = (spec.latent_width
+              if spec is not None and spec.cache_entry == "latent" else 0)
+    pool_layers = len(spec.attention_layers) if hybrid else n_layers
+    head_dim = (spec.head_dim if spec is not None else 0) or d_model // n_heads
+    if pool is not None:
+        if kv_dtype != compute_dtype and pool.dtype != kv_dtype:
+            raise ValueError(
+                f"kv_dtype={jnp.dtype(kv_dtype).name} conflicts with the "
+                f"provided pool's dtype {jnp.dtype(pool.dtype).name}")
+        if (pool.entry_kind == "latent") != bool(latent):
+            raise ValueError(f"the provided pool holds {pool.entry_kind!r} "
+                             "entries, the model another kind")
+        if pool.n_layers != pool_layers:
+            raise ValueError(f"the provided pool has {pool.n_layers} layers, "
+                             f"the model {pool_layers} attention layers")
+        if sparse and pool.index is None:
+            raise ValueError("the model has an indexer: the provided pool "
+                             "needs index rows (index_dim=)")
+        if mesh is not None and pool.mesh is not mesh:
+            raise ValueError("provided pool was built on a different mesh "
+                             "than the batcher's")
+        kv_dtype, mesh = pool.dtype, pool.mesh
+    if hbm is not None and mesh is not None:
+        # PR 11's named follow-up, closed as an explicit contract: the
+        # elastic pool's grow/shrink per-shard accounting is UNTESTED under
+        # a mesh (the ladder recompiles sharded programs per size and
+        # concat/slice re-infer the output sharding): reject at
+        # construction rather than leave a silent corruption path.
+        # ROADMAP item 3 (per-axis ledger) is where this lands properly.
+        raise NotImplementedError(
+            "HBM-arbiter-armed serving (elastic PagedKVPool) under a "
+            "mesh is not supported: grow/shrink per-shard accounting "
+            "is untested — serve the arbiter single-device, or the "
+            "mesh without an arbiter (hbm=None)")
+    if mesh is not None:
+        if prefill_flash:
+            raise ValueError(
+                "the pallas flash prefill kernel is single-device; "
+                "mesh serving prefills through the dense or ragged "
+                "paths (prefill_flash must be False or None)")
+        prefill_flash = False
+    elif prefill_flash is None:
+        # auto: pallas flash attention for the FULL-PROMPT forward on TPU
+        # (O(T*block) VMEM instead of a dense (T, T) score
+        # materialization).  Scope: the start==0 un-chunked prefill only:
+        # chunked prefills and prefix-cache tails run paged_extend's gather
+        # attention, which has no flash analog here.
+        prefill_flash = platform.is_tpu()
+    n_shards = int(dict(mesh.shape).get("model", 1)) if mesh is not None else 1
+    if use_kernel and mesh is not None and n_heads % n_shards:
+        raise ValueError(
+            f"use_kernel under a mesh needs query heads ({n_heads}) "
+            f"divisible by the model axis ({n_shards}) — the ragged "
+            "kernel shards the page walk on the heads dim")
+    draft = None
+    if draft_params is not None:
+        from tpulab.models.transformer import weight_shape
+        dl = draft_n_layers or n_layers
+        dh = draft_n_heads or n_heads
+        dkv = draft_n_kv_heads or (n_kv if draft_n_heads is None else dh)
+        dd = weight_shape(draft_params["layer0"]["wqkv"])[0]
+        if dd // dh != d_model // n_heads or dkv != n_kv:
+            raise ValueError(
+                "draft model KV geometry (head_dim, n_kv_heads) must "
+                "match the target's — both write the shared paged pool")
+        if dl > n_layers:
+            raise ValueError("draft_n_layers must be <= n_layers (the "
+                             "draft shares the pool's layer axis)")
+        draft = dict(n_heads=dh, n_layers=dl, n_kv_heads=dkv)
+
+    # The round's token budget comes from the shapes, a power of two at
+    # most ``round_ceiling``.  Every width's program must be one a SINGLE
+    # prompt reaches, by spending the budget and leaving a tail of that
+    # width (how a harness warms them, so that none is first met under
+    # load): so twice the budget fits ``max_len``, and the budget a window,
+    # where a lane's chunk ends.  With the kernels it is also a round their
+    # geometry rule admits
+    reach = min(max_len // 2, eva_window or max_len)
+    cap = min(round_ceiling, 1 << max(reach, 1).bit_length() - 1)
+    why = None if cap == round_ceiling else (
+        f"a prompt cannot spend {2 * cap} tokens a round and leave a "
+        f"tail: max_len {max_len}"
+        + (f", windows of {eva_window}" if eva else ""))
+    plan = EnginePlan(
+        spec=spec, lanes=lanes, max_len=max_len, page_size=page_size,
+        n_heads=n_heads, n_layers=n_layers, rope_theta=rope_theta,
+        vocab=vocab, compute_dtype=compute_dtype, kv_dtype=kv_dtype,
+        mesh=mesh, n_shards=n_shards,
+        state_kind=spec.state_kind if hybrid else None, sparse=sparse,
+        eva_window=eva_window,
+        eva_saved=spec.eva_window - spec.eva_summaries if eva else 0,
+        latent=latent, n_kv=n_kv, head_dim=head_dim, pool_layers=pool_layers,
+        index_dim=spec.index_dim if sparse else 0, max_pages=max_pages,
+        prefill_chunk=prefill_chunk, prefill_flash=bool(prefill_flash),
+        use_kernel=use_kernel, ragged=ragged, round_cap=cap,
+        round_budget_why=why, draft=draft)
+
+    # auto: the pallas ragged kernel on TPU at LONG contexts only (where
+    # the gather path's O(lanes*max_len) dense HBM materialization per step
+    # should dominate) and only at a geometry the shape rule admits; the
+    # XLA gather elsewhere.  No chip measurement backs the threshold yet
+    # (ROADMAP S3); explicit use_kernel=True overrides it.  Under a mesh
+    # the kernel shards on the KV-heads dim (shard_map), so the auto pick
+    # covers sharded serving too.
+    auto = use_kernel is None
+    if auto:
+        use_kernel = (platform.is_tpu() and max_len >= kernel_auto_min_ctx
+                      and n_heads % n_shards == 0)
+    if use_kernel:
+        # the widest round under ``cap`` the rule admits, and what it said
+        # of the next wider one; 0 where it admits no width
+        admitted, refusal = cap, None
+        while admitted and (err := kernel_error(plan, admitted,
+                                                verify_width)):
+            admitted, refusal = admitted // 2, err
+        if admitted:
+            cap, why = admitted, refusal or why
+        elif auto:
+            use_kernel = False
+        elif not platform.pallas_interpret():
+            # asked for a kernel the geometry cannot have: say which
+            # constraint, up front — a Mosaic error past this rule is a
+            # real error and propagates.  (The interpreter builds any
+            # geometry: a rule that admits no width binds nothing there.)
+            raise ValueError(f"use_kernel=True: {refusal}")
+    return dataclasses.replace(
+        plan, use_kernel=bool(use_kernel),
+        ragged=bool(use_kernel if ragged is None else ragged),
+        round_cap=cap, round_budget_why=why)

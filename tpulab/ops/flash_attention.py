@@ -106,100 +106,11 @@ def _flash_bhd(q, k, v, causal: bool, block_q: int, block_k: int,
     )(q, k, v)
 
 
-# -- backward (custom VJP) ----------------------------------------------------
-# The forward kernel discards the softmax statistics; the backward pass is
-# the flash-style recompute: one blockwise scan rebuilds the per-row
-# log-sum-exp, a second accumulates dq/dk/dv — O(T * block_k) live memory,
-# never the (T, T) score matrix, all in XLA (scan fuses on TPU).
-
-def _bwd_mask(t: int, block_k: int, j, dtype=jnp.float32):
-    qpos = jax.lax.broadcasted_iota(jnp.int32, (t, block_k), 0)
-    kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (t, block_k), 1)
-    return qpos >= kpos
-
-
-def _flash_bwd_bhd(q, k, v, out, dout, causal: bool, block_k: int):
-    bh, t, d = q.shape
-    scale = 1.0 / np.sqrt(d)
-    qf = q.astype(jnp.float32) * scale
-    outf = out.astype(jnp.float32)
-    doutf = dout.astype(jnp.float32)
-    nb = t // block_k
-    kb = k.astype(jnp.float32).reshape(bh, nb, block_k, d)
-    vb = v.astype(jnp.float32).reshape(bh, nb, block_k, d)
-
-    def scores(kj, j):
-        s = jnp.einsum("bqd,bkd->bqk", qf, kj)
-        if causal:
-            s = jnp.where(_bwd_mask(t, block_k, j)[None], s, _NEG)
-        return s
-
-    # pass 1: per-row log-sum-exp, blockwise online
-    def lse_step(carry, inp):
-        m, l = carry
-        kj, j = inp
-        s = scores(kj, j)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        l = l * jnp.exp(m - m_new) + jnp.exp(
-            s - m_new[..., None]).sum(axis=-1)
-        return (m_new, l), None
-
-    (m, l), _ = jax.lax.scan(
-        lse_step,
-        (jnp.full((bh, t), _NEG, jnp.float32), jnp.zeros((bh, t), jnp.float32)),
-        (kb.transpose(1, 0, 2, 3), jnp.arange(nb)))
-    lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    delta = (doutf * outf).sum(axis=-1)          # (BH, T)
-
-    # pass 2: accumulate gradients blockwise
-    def bwd_step(dq, inp):
-        kj, vj, j = inp
-        s = scores(kj, j)
-        p = jnp.exp(s - lse[..., None])
-        if causal:
-            p = jnp.where(_bwd_mask(t, block_k, j)[None], p, 0.0)
-        dv_j = jnp.einsum("bqk,bqd->bkd", p, doutf)
-        dp = jnp.einsum("bqd,bkd->bqk", doutf, vj)
-        ds = p * (dp - delta[..., None])
-        dq = dq + jnp.einsum("bqk,bkd->bqd", ds, kj)
-        dk_j = jnp.einsum("bqk,bqd->bkd", ds, qf)  # qf carries the scale
-        return dq, (dk_j, dv_j)
-
-    dq, (dkb, dvb) = jax.lax.scan(
-        bwd_step, jnp.zeros((bh, t, d), jnp.float32),
-        (kb.transpose(1, 0, 2, 3), vb.transpose(1, 0, 2, 3), jnp.arange(nb)))
-    dq = (dq * scale).astype(q.dtype)
-    dk = dkb.transpose(1, 0, 2, 3).reshape(bh, t, d).astype(k.dtype)
-    dv = dvb.transpose(1, 0, 2, 3).reshape(bh, t, d).astype(v.dtype)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_diff_bhd(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_bhd(q, k, v, causal, block_q, block_k, interpret)
-
-
-def _flash_diff_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out = _flash_bhd(q, k, v, causal, block_q, block_k, interpret)
-    return out, (q, k, v, out)
-
-
-def _flash_diff_bwd(causal, block_q, block_k, interpret, res, dout):
-    q, k, v, out = res
-    return _flash_bwd_bhd(q, k, v, out, dout, causal, block_k)
-
-
-_flash_diff_bhd.defvjp(_flash_diff_fwd, _flash_diff_bwd)
-
-
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, interpret: bool | None = None):
     """Flash attention over (B, T, H, D) q/k/v (same layout as
-    :func:`tpulab.models.transformer.dense_attention`).
-
-    Differentiable: the backward pass is the flash-style blockwise
-    recompute (custom VJP) — O(T * block) memory both ways, so it drops
-    into training (e.g. under ``jax.grad`` / the sharded train step)."""
+    :func:`tpulab.models.transformer.dense_attention`).  Forward only: what
+    a serving path runs (the engine's ``prefill_flash``)."""
     b, t, h, d = q.shape
     block_q = min(block_q, t)
     block_k = min(block_k, t)
@@ -213,8 +124,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
     def to_bhd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
-    out = _flash_diff_bhd(to_bhd(q), to_bhd(k), to_bhd(v), causal,
-                          block_q, block_k, interpret)
+    out = _flash_bhd(to_bhd(q), to_bhd(k), to_bhd(v), causal,
+                     block_q, block_k, interpret)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
