@@ -538,6 +538,12 @@ class ContinuousBatcher:
                                      **({"summary_keys": 0}
                                         if plan.eva_window else {}))
                           for kind in ("decode", "round")}
+        #: the (query row, key) pairs behind the rounds' rows, exactly: a
+        #: row at context ``c`` attends ``c`` keys (``lane_work["round"]``
+        #: ``keys`` counts a segment once, at its last row: what is READ;
+        #: this is what is COMPUTED, an attention layer; for decode steps
+        #: the two are one number)
+        self.round_attn_pairs = 0
         #: decode blocks enqueued before their predecessor was fetched
         #: (_chain_block): over ``dispatch_kinds["decode"]`` the share of
         #: blocks whose host turn the device did not wait for
@@ -711,6 +717,8 @@ class ContinuousBatcher:
         w["passes"] += n if kind == "decode" else 1
         w["rows"] += n
         w["keys"] += triangle if kind == "decode" else start + n
+        if kind == "round":
+            self.round_attn_pairs += triangle
         if self._sparse is None:
             return
         k, layers = self.model_spec.index_topk, self.model_spec.n_layers
@@ -1317,6 +1325,7 @@ class ContinuousBatcher:
                          "decode_block_steps": self.decode_block_steps,
                          "lane_work": {kind: dict(w) for kind, w
                                        in self.lane_work.items()},
+                         "round_attn_pairs": self.round_attn_pairs,
                          "ahead_blocks": self.ahead_blocks,
                          "chain": {"breaks": dict(self.chain_breaks),
                                    "late_links": self.late_links},
@@ -1348,9 +1357,14 @@ class ContinuousBatcher:
                            "probe_recoveries": self.spec_probe_recoveries}
         if self._moe_assignments is not None:
             first = self.model_spec.expert_first
-            held = self.model_spec.experts_held or self.model_spec.n_experts
+            held = (self.model_spec.experts_held
+                    or self.model_spec.ffn_experts)
             out["moe"] = {
                 "expert_layers": list(self.model_spec.moe_layers),
+                # the router's last columns that are identity experts (no
+                # weights, no product): where they start and how many
+                "zero_first": self.model_spec.ffn_experts,
+                "zero_columns": self.model_spec.zero_experts,
                 # cumulative (row, expert) assignments, [expert layer][expert]
                 # over every column of the router
                 "assignments": self._moe_assignments.tolist(),

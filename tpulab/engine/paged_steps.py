@@ -173,6 +173,60 @@ def _segment_rows(outs, seg):
                   mode="clip"), dec[:, 0]])[None]
 
 
+#: A packed round's chunk rows reach the attention spread to the padded
+#: ``(lanes, M)`` form (:func:`_segment_calls`) while that form is small.
+#: Past this many bytes of spread queries a layer, the latent attention
+#: takes them one chunk LANE at a time (:func:`_chunk_lanes`): the spread
+#: costs what ``lanes x M`` rows cost whichever lanes hold a chunk, and at
+#: 32 lanes of 64 heads of 576 it was 1.2 GB a layer for one lane's 37 MB,
+#: 145 of a 187 ms round with its copies (PERF.md section 6, PR 46).  The
+#: configurations under it (``glm4_moe_lite``: 94 MB) keep the programs
+#: they had.
+SPREAD_LIMIT_BYTES = 256 << 20
+
+
+def _chunk_lanes(q, seg, attend):
+    """The chunk rows ``q[:M]`` of a packed round (``q (T, ...)``, see
+    :func:`_layer_block`) through ``attend`` one chunk LANE at a time, in
+    the order the lanes' rows are packed: ``(M, ...)`` rows of what
+    ``attend`` returns.
+
+    ``attend(qq (1, M, ...), tables (1, MP), q_lens (1,), kv_lens (1,),
+    qpos (1, M)) -> (1, M, ...)`` is one lane's call.  A lane's chunk is
+    consecutive rows from its first, so its queries are a slice of ``M``
+    rows there (``q_lens`` says how many are its own) and no row is
+    gathered; its result is written back over the same rows, and what it
+    leaves behind its own rows the next lane's result overwrites, the last
+    lane's falls on rows that hold no token.  The loop runs as many times
+    as lanes hold a chunk."""
+    import jax
+    import jax.numpy as jnp
+
+    spread, _back, qpos, chunk_lens, _dec = seg["rows"]
+    b, m = qpos.shape
+    first = spread.reshape(b, m)[:, 0]           # a lane's first row
+    held = chunk_lens > 0
+    order = jnp.argsort(jnp.where(held, first, m))
+    rows = jnp.pad(q[:m], ((0, m),) + ((0, 0),) * (q.ndim - 1))
+
+    def one(lane, at):
+        cut = partial(jax.lax.dynamic_slice_in_dim, start_index=lane,
+                      slice_size=1)
+        return attend(jax.lax.dynamic_slice_in_dim(rows, at, m)[None],
+                      cut(seg["tables"]), cut(chunk_lens),
+                      cut(seg["kv_lens"]), cut(qpos))[0]
+
+    def body(i, out):
+        at = first[order[i]]
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, one(order[i], at).astype(out.dtype), at, 0)
+
+    like = jax.eval_shape(one, order[0], first[0])
+    out = jax.lax.fori_loop(
+        0, held.sum(), body, jnp.zeros((2 * m,) + like.shape[1:], like.dtype))
+    return out[:m]
+
+
 def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
                    compute_dtype):
     """Multi-head latent attention of one layer in the absorbed form, on a
@@ -182,7 +236,10 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
     behind the weighted latent sum.  In a packed round (``seg["rows"]``,
     see :func:`_layer_block`) ``h`` is ``(1, T, D)``: only the absorbed
     query goes through :func:`_segment_calls` for the walk over the pages,
-    and the weighted latent sum is rows again before ``w_uv``."""
+    and the weighted latent sum is rows again before ``w_uv``; where the
+    round's padded ``(lanes, M)`` form of that query would pass
+    ``SPREAD_LIMIT_BYTES`` its chunk rows go a lane at a time instead
+    (:func:`_chunk_lanes`)."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _rmsnorm, apply_rope, qmat
@@ -209,20 +266,42 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
         qa = jnp.concatenate(
             [jnp.einsum("bmhn,hnc->bmhc", q[..., :nope],
                         qmat(p["w_uk"], compute_dtype)), qr], axis=-1)
-        outs = []
-        for qq, q_lens, qpos in _segment_calls(qa, pos, seg):
-            if seg["use_kernel"]:
-                from tpulab.ops.ragged_attention import (
-                    ragged_latent_attention)
-                outs.append(ragged_latent_attention(
-                    qq, kv_pool, layer, seg["tables"], q_lens,
-                    seg["kv_lens"], v_width=spec.kv_lora_rank,
-                    sm_scale=scale))
-            else:
-                outs.append(_gather_attend_latent(
-                    qq, kv_pool[layer, :, 0], seg["tables"], qpos,
-                    spec.kv_lora_rank, scale, compute_dtype))
-        lat = _segment_rows(outs, seg)                       # (b, m, H, C)
+        packed = seg.get("rows")
+        if packed is not None and (packed[2].size * spec.n_heads * qa.shape[-1]
+                                   * qa.dtype.itemsize > SPREAD_LIMIT_BYTES):
+            # a wide round: the chunk rows a lane at a time, the decode
+            # rows [M, M + B) as the (B, 1) call they are
+            def attend(qq, tables, q_lens, kv_lens, qpos):
+                if seg["use_kernel"]:
+                    from tpulab.ops.ragged_attention import (
+                        ragged_latent_attention)
+                    return ragged_latent_attention(
+                        qq, kv_pool, layer, tables, q_lens, kv_lens,
+                        v_width=spec.kv_lora_rank, sm_scale=scale)
+                return _gather_attend_latent(
+                    qq, kv_pool[layer, :, 0], tables, qpos,
+                    spec.kv_lora_rank, scale, compute_dtype)
+            cut = packed[2].shape[1]
+            lat = jnp.concatenate([
+                _chunk_lanes(qa[0], seg, attend),
+                attend(qa[0, cut:, None], seg["tables"], packed[4],
+                       seg["kv_lens"], seg["kv_lens"][:, None] - 1)[:, 0]])[
+                           None]
+        else:
+            outs = []
+            for qq, q_lens, qpos in _segment_calls(qa, pos, seg):
+                if seg["use_kernel"]:
+                    from tpulab.ops.ragged_attention import (
+                        ragged_latent_attention)
+                    outs.append(ragged_latent_attention(
+                        qq, kv_pool, layer, seg["tables"], q_lens,
+                        seg["kv_lens"], v_width=spec.kv_lora_rank,
+                        sm_scale=scale))
+                else:
+                    outs.append(_gather_attend_latent(
+                        qq, kv_pool[layer, :, 0], seg["tables"], qpos,
+                        spec.kv_lora_rank, scale, compute_dtype))
+            lat = _segment_rows(outs, seg)                   # (b, m, H, C)
         attn = jnp.einsum("bmhc,hcv->bmhv", lat.astype(compute_dtype),
                           qmat(p["w_uv"], compute_dtype))
         return attn.reshape(b, m, -1), kv_pool
@@ -626,28 +705,43 @@ def _gated_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx,
     return attn.reshape(b, m, -1), kv_pool
 
 
-def _ffn_block(spec, p, layer, x, valid, compute_dtype):
+def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
     """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
     experts (router kind ``spec.router``; of the router's ``E`` experts the
     share ``spec.expert_first`` / ``spec.experts_held`` whose weights are
     here) plus the shared expert where the model has one, scaled by its
     sigmoid gate where it has that (``spec.shared_gate``).  Returns ``(x,
-    stats)``, ``stats`` the expert layer's ``(E + 2,)`` counters or None."""
+    stats)``, ``stats`` the expert layer's ``(E + 2,)`` counters or None.
+
+    A ``"shortcut"`` layer (LongCat-Flash) runs BOTH on the one normed
+    input: its ``x`` is ``x + dense(h)`` and the expert block's output ``m``
+    (the held experts' part and the identity columns' ``weight * h``) goes
+    out beside it, ``((x, m), stats)``, to be added after the NEXT layer's
+    FFN, which is handed it as ``shortcut``."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _dense_ffn, _rmsnorm
 
     h = _rmsnorm(x, p["ln2"]["scale"], spec.rms_eps)
-    if spec.layer_kinds[layer] != "moe":
-        return x + _dense_ffn(p, h, compute_dtype).astype(x.dtype), None
+    kind = spec.layer_kinds[layer]
+    if kind == "dense":
+        x = x + _dense_ffn(p, h, compute_dtype).astype(x.dtype)
+        if shortcut is not None:
+            with jax.named_scope("moe_shortcut"):
+                x = x + shortcut
+        return x, None
     from tpulab.parallel.moe import routed_ffn
     b, m = x.shape[:2]
     y, stats = routed_ffn(p["moe"], h.reshape(b * m, -1), spec.top_k,
                           compute_dtype, router=spec.router, act="swiglu",
                           scale=spec.routed_scale, norm=spec.norm_topk,
                           valid=valid.reshape(-1), first=spec.expert_first,
-                          held=spec.experts_held or None)
+                          held=spec.experts_held or None,
+                          zero=spec.zero_experts)
     y = y.reshape(b, m, -1)
+    if kind == "shortcut":
+        return (x + _dense_ffn(p, h, compute_dtype).astype(x.dtype),
+                y.astype(x.dtype)), stats
     if spec.shared_gate:
         from tpulab.models.transformer import qmat
         with jax.named_scope("moe_shared"):
@@ -706,6 +800,13 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     ``(page store, lane state)`` for every layer of the model, and only
     attention layers own a layer of the page store (``spec.store_layer``).
 
+    A ``"shortcut"`` layer (``spec.layer_kinds``; LongCat-Flash's
+    shortcut-connected expert block) returns its ``x`` as the pair ``(x,
+    m)``: the residual stream and the expert block's output, computed from
+    this layer's FFN input and due after the NEXT layer's FFN.  The step
+    functions hand the pair on as they hand ``x`` on, and the next layer
+    (always ``"dense"``) takes it apart again (:func:`_ffn_block`).
+
     Kept short, the K/V kernel called from here and the rest in functions
     of their own: on the v5e host, tracing a kernel body costs more with
     every Python frame between the step function and the ``pallas_call``
@@ -718,6 +819,9 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
                                            split_qkv)
 
+    shortcut = None
+    if layer and spec.layer_kinds[layer - 1] == "shortcut":
+        x, shortcut = x
     h = _rmsnorm(x, p["ln1"]["scale"], spec.rms_eps)
     state = None
     if spec.mamba_layers:
@@ -802,7 +906,7 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         attn = jnp.where(valid[..., None], attn, 0)
     x, stats = _ffn_block(spec, p, layer,
                           x + attn @ qmat(p["wo"], compute_dtype), valid,
-                          compute_dtype)
+                          compute_dtype, shortcut)
     return x, (kv_pool if state is None else (kv_pool, state)), stats
 
 

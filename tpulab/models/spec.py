@@ -160,6 +160,46 @@ scales hold ``1 + w`` (``norm_add_unit_offset``).  The published head is
 prediction head 0, the one a plain ``generate`` reads, and ``mtp_heads
 (d_model, (num_pred_heads - 1) * vocab)`` the further heads, held and not
 run (:func:`split_pred_heads`).
+
+``longcat_flash`` (LongCat-Flash-Chat): a published layer is TWO latent
+attentions, TWO dense SwiGLU FFNs and ONE expert block on a shortcut: ``a1
+= x + MLA_0(norm(x))``, ``h1 = norm(a1)``, ``m = MoE(h1)``, ``b1 = a1 +
+FFN_0(h1)``, ``a2 = b1 + MLA_1(norm(b1))``, ``out = a2 + FFN_1(norm(a2)) +
+m``: the expert block reads the first attention's output and is added at
+the layer's END.  It is served as two engine layers, each with its own
+layer of the latent page store: kinds ``("shortcut", "dense")``, the first
+running the expert block on its dense FFN's input and handing ``m`` to the
+second (:func:`tpulab.engine.paged_steps._layer_block`).  The router has
+``n_routed_experts + zero_expert_num`` columns, the last ``zero_experts``
+of them *identity experts* that return their input and hold no weights:
+``s = softmax(h W_r)`` in float32 over every column, the ``top_k`` columns
+of largest ``s + b`` are chosen, a chosen column weighs
+``routed_scaling_factor * s`` (not renormalised), ``MoE(h) = sum over the
+chosen FFN experts of w_j SwiGLU_j(h) + (sum over the chosen identity
+columns of w_j) h`` (router kind ``"softmax_bias"``).
+:func:`longcat_flash_spec` reads the published keys; ``first`` / ``held``
+give the share of the FFN experts held here.  A layer has the MLA leaves of
+``glm4_moe_lite`` above, ``w1 w3 w2``, and on the first of a pair ``moe``
+(``router (d_model, E + Z)``, ``bias (E + Z,)``, ``w13`` / ``w2`` of the
+held experts).  The published factors ``mla_scale_q_lora`` (``q`` times
+``(d_model / q_lora_rank)^0.5``) and ``mla_scale_kv_lora`` (the normed
+``c_kv`` times ``(d_model / kv_lora_rank)^0.5``) and the published
+interleaved RoPE are taken up once, at load time, by
+:func:`longcat_flash_layout`:
+
+=============  ==========================================================
+``wq_b``       the published ``q_b_proj`` times the query factor, a head's
+               rope columns reordered ``[even | odd]`` (interleaved pairs
+               become the engine's rotate-half pairs)
+``wkv_a``      the published ``kv_a_proj_with_mqa``, its rope columns
+               reordered the same way
+``w_uk w_uv``  :func:`split_kv_b` of the published ``kv_b_proj``, both
+               times the latent factor (the latent row stays the plain
+               normed ``c_kv``)
+=============  ==========================================================
+
+so the latent kernel, its program and the cache entry are those of
+``glm4_moe_lite``.
 """
 
 from __future__ import annotations
@@ -184,8 +224,8 @@ class ModelSpec:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    layer_kinds: Tuple[str, ...] = ()       # "dense" | "moe", one a layer
-    n_experts: int = 0
+    layer_kinds: Tuple[str, ...] = ()       # "dense" | "moe" | "shortcut"
+    n_experts: int = 0                      # the router's columns
     top_k: int = 0
     moe_ff: int = 0
     n_shared: int = 0
@@ -203,6 +243,7 @@ class ModelSpec:
     index_topk: int = 0
     qk_norm: bool = False                   # RMSNorm over each head of q and k
     router: str = "sigmoid_bias"            # | "softmax" (no selection bias)
+                                            # | "softmax_bias" (all columns)
     gdn_k_heads: int = 0                    # Gated DeltaNet, all four (+ d_conv)
     gdn_v_heads: int = 0
     gdn_k_dim: int = 0
@@ -215,11 +256,13 @@ class ModelSpec:
     eva_window: int = 0                     # EVA (gqa): positions a window,
     eva_chunk: int = 0                      # positions a summary row; 0 = none
     pred_heads: int = 0                     # output heads held (head 0 is read)
+    zero_experts: int = 0                   # the router's LAST columns:
+                                            # identity experts (no weights)
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
             raise ValueError(f"unknown attention kind {self.attention!r}")
-        if self.router not in ("sigmoid_bias", "softmax"):
+        if self.router not in ("sigmoid_bias", "softmax", "softmax_bias"):
             raise ValueError(f"unknown router kind {self.router!r}")
         if self.index_topk or self.index_heads or self.index_dim:
             if min(self.index_heads, self.index_dim, self.index_topk) < 1:
@@ -230,10 +273,22 @@ class ModelSpec:
                 raise ValueError("an indexer selects keys of GQA attention "
                                  "on K/V pages only")
         kinds = self.layer_kinds or ("dense",) * self.n_layers
-        if len(kinds) != self.n_layers or set(kinds) - {"dense", "moe"}:
-            raise ValueError(f"layer_kinds {kinds} does not name a dense or "
-                             f"moe FFN for each of {self.n_layers} layers")
+        if len(kinds) != self.n_layers or set(kinds) - {"dense", "moe",
+                                                        "shortcut"}:
+            raise ValueError(f"layer_kinds {kinds} does not name a dense, "
+                             f"moe or shortcut FFN for each of "
+                             f"{self.n_layers} layers")
         object.__setattr__(self, "layer_kinds", tuple(kinds))
+        if "shortcut" in kinds:
+            # the expert block's output lands after the NEXT layer's FFN
+            after = [k for i, k in enumerate(kinds + ("",))
+                     if i and kinds[i - 1] == "shortcut"]
+            if set(after) != {"dense"} or set(self.mixers or ()) - {
+                    "attention"}:
+                raise ValueError(
+                    f"layer_kinds {kinds}: a shortcut layer is followed by "
+                    "a dense layer that takes its expert block's output, "
+                    "and every mixer is attention")
         mixers = self.mixers or ("attention",) * self.n_layers
         if (len(mixers) != self.n_layers
                 or set(mixers) - {"attention", "mamba", "gdn"}):
@@ -270,11 +325,15 @@ class ModelSpec:
                     or set(self.mixers or ()) - {"attention"}):
                 raise ValueError("EVA windows belong to plain GQA attention "
                                  "on K/V pages, every layer")
-        held = self.experts_held or self.n_experts
-        if not 0 <= self.expert_first <= self.n_experts - held:
+        if not 0 <= self.zero_experts <= max(self.n_experts - 1, 0):
+            raise ValueError(
+                f"zero_experts {self.zero_experts} are not the last columns "
+                f"of a router of {self.n_experts} that has an FFN expert")
+        held = self.experts_held or self.ffn_experts
+        if not 0 <= self.expert_first <= self.ffn_experts - held:
             raise ValueError(
                 f"experts {self.expert_first} .. {self.expert_first + held} "
-                f"are not a share of the router's {self.n_experts}")
+                f"are not a share of the router's {self.ffn_experts}")
         if self.shared_gate and not self.n_shared:
             raise ValueError("shared_gate without a shared expert")
         if "mamba" in mixers:
@@ -333,8 +392,16 @@ class ModelSpec:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
+    def ffn_experts(self) -> int:
+        """The router's columns that are FFN experts: all of them but the
+        last ``zero_experts``."""
+        return self.n_experts - self.zero_experts
+
+    @property
     def moe_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i, k in enumerate(self.layer_kinds) if k == "moe")
+        """The layers that run an expert block (``"moe"``, ``"shortcut"``)."""
+        return tuple(i for i, k in enumerate(self.layer_kinds)
+                     if k != "dense")
 
     @property
     def mamba_layers(self) -> Tuple[int, ...]:
@@ -574,6 +641,91 @@ def evabyte_spec(config: Dict[str, Any]) -> ModelSpec:
         eva_chunk=chunk, pred_heads=int(config.get("num_pred_heads", 1)))
 
 
+def longcat_flash_spec(config: Dict[str, Any], first: int = 0,
+                       held: Optional[int] = None) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type``
+    ``longcat_flash``).  A published layer is two engine layers, kinds
+    ``("shortcut", "dense")``, each with its own layer of the latent page
+    store.  ``first`` / ``held``: the contiguous share of the
+    ``n_routed_experts`` FFN experts this device holds (all of them by
+    default); the router keeps every column, the ``zero_expert_num``
+    identity experts behind them.  Refuses what the layer block does not
+    compute."""
+    if config.get("zero_expert_type", "identity") != "identity":
+        raise ValueError(f"zero_expert_type {config['zero_expert_type']!r} "
+                         "is not implemented (identity alone)")
+    if config.get("rope_scaling") is not None:
+        raise ValueError("rope_scaling is not implemented")
+    if config.get("attention_bias"):
+        raise ValueError("attention_bias is not implemented")
+    if config.get("router_bias"):
+        raise ValueError("router_bias is not implemented (the router is a "
+                         "matrix; e_score_correction_bias is the selection "
+                         "bias)")
+    if config.get("norm_topk_prob"):
+        raise ValueError("norm_topk_prob true is not implemented (a chosen "
+                         "column weighs routed_scaling_factor * s)")
+    if config.get("attention_method", "MLA") != "MLA":
+        raise ValueError(f"attention_method {config['attention_method']!r} "
+                         "is not implemented (MLA alone)")
+    n_ffn, n_zero = (int(config["n_routed_experts"]),
+                     int(config.get("zero_expert_num", 0)))
+    return ModelSpec(
+        n_layers=2 * int(config["num_layers"]),
+        d_model=int(config["hidden_size"]),
+        n_heads=int(config["num_attention_heads"]), attention="mla",
+        q_lora_rank=int(config["q_lora_rank"]),
+        kv_lora_rank=int(config["kv_lora_rank"]),
+        qk_nope_head_dim=int(config["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(config["qk_rope_head_dim"]),
+        v_head_dim=int(config["v_head_dim"]),
+        layer_kinds=("shortcut", "dense") * int(config["num_layers"]),
+        n_experts=n_ffn + n_zero, zero_experts=n_zero,
+        top_k=int(config["moe_topk"]),
+        moe_ff=int(config["expert_ffn_hidden_size"]), n_shared=0,
+        router="softmax_bias", norm_topk=False,
+        routed_scale=float(config["routed_scaling_factor"]),
+        experts_held=n_ffn if held is None else int(held),
+        expert_first=int(first), rms_eps=float(config["rms_norm_eps"]),
+        rope_theta=float(config["rope_theta"]))
+
+
+def mla_scales(config: Dict[str, Any]) -> Tuple[float, float]:
+    """``(query factor, latent factor)`` of a ``longcat_flash`` config:
+    ``(hidden_size / q_lora_rank)^0.5`` where ``mla_scale_q_lora``,
+    ``(hidden_size / kv_lora_rank)^0.5`` where ``mla_scale_kv_lora``, else
+    1."""
+    d = float(config["hidden_size"])
+    return ((d / int(config["q_lora_rank"])) ** 0.5
+            if config.get("mla_scale_q_lora") else 1.0,
+            (d / int(config["kv_lora_rank"])) ** 0.5
+            if config.get("mla_scale_kv_lora") else 1.0)
+
+
+def longcat_flash_layout(wq_b, wkv_a, kv_b, spec: ModelSpec,
+                         q_scale: float = 1.0, kv_scale: float = 1.0):
+    """One attention's published ``q_b_proj (q_lora_rank, H * (nope +
+    rope))``, ``kv_a_proj_with_mqa (d_model, kv_lora_rank + rope)`` and
+    ``kv_b_proj`` (all transposed: inputs by outputs) as the served
+    ``(wq_b, wkv_a, w_uk, w_uv)``, float32 (numpy or jax arrays): the rope
+    columns of a query head and of the shared key reordered ``[even | odd]``
+    (the published RoPE turns interleaved pairs ``(2j, 2j + 1)``, the
+    engine's rotate-half pairs ``(j, j + rope / 2)``: the same rotation of
+    the same pair), ``wq_b`` times ``q_scale``, the two halves of
+    ``kv_b_proj`` (:func:`split_kv_b`) times ``kv_scale``
+    (:func:`mla_scales`)."""
+    nope, rope = spec.qk_nope_head_dim, spec.qk_rope_head_dim
+    turn = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+    head = np.concatenate([np.arange(nope), nope + turn])
+    q_cols = (np.arange(spec.n_heads)[:, None] * (nope + rope)
+              + head[None, :]).reshape(-1)
+    kv_cols = np.concatenate([np.arange(spec.kv_lora_rank),
+                              spec.kv_lora_rank + turn])
+    w_uk, w_uv = split_kv_b(kv_b, spec)
+    return (wq_b[:, q_cols] * q_scale, wkv_a[:, kv_cols], w_uk * kv_scale,
+            w_uv * kv_scale)
+
+
 def split_pred_heads(head, spec: ModelSpec):
     """A published output head ``(d_model, num_pred_heads * vocab)`` (the
     transposed ``lm_head.weight``, prediction head ``j`` in columns ``[j *
@@ -619,7 +771,9 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     hybrid with a tied one (no ``lm_head``).
     Weights normal ``scale``, norm scales 1 (LayerNorm biases 0), the
     ``"sigmoid_bias"`` router's selection bias drawn like a weight (not
-    zero: choosing with it and weighting without it must differ).
+    zero: choosing with it and weighting without it must differ), the
+    ``"softmax_bias"`` router's a unit normal over the router's columns
+    (the scale of its scores).
 
     A Mamba layer's SSM leaves follow the published initialisation, not
     normal ``scale`` (under which every channel forgets within three tokens
@@ -720,17 +874,23 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                     "k_norm": dict(norm(spec.index_dim), bias=jnp.zeros(
                         (spec.index_dim,), jnp.float32)),
                     "ww": w(d, spec.index_heads)}
-        if kind == "dense":
+        if kind != "moe":
             p.update(w1=w(d, d_ff), w3=w(d, d_ff), w2=w(d_ff, d))
-        else:
+        if kind != "dense":
             f, fs = spec.moe_ff, spec.n_shared * spec.moe_ff
-            held = spec.experts_held or spec.n_experts
+            held = spec.experts_held or spec.ffn_experts
             p["moe"] = {"router": w(d, spec.n_experts),
                         "bias": w(spec.n_experts),
                         "w13": w(held, d, 2 * f),
                         "w2": w(held, f, d)}
-            if spec.router != "sigmoid_bias":
+            if spec.router == "softmax":
                 del p["moe"]["bias"]
+            elif spec.router == "softmax_bias":
+                # at the scale of the scores (1 / columns on average), not
+                # of a weight: at ``scale`` it would outweigh every score
+                # and choose the same columns for every row
+                p["moe"]["bias"] = p["moe"]["bias"] / (scale
+                                                       * spec.n_experts)
             if fs:
                 p["shared"] = {"w1": w(d, fs), "w3": w(d, fs),
                                "w2": w(fs, d)}

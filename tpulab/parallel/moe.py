@@ -7,14 +7,24 @@ MoE transformer of the dry run (:func:`moe_ffn`) and the expert-parallel
 form (:func:`make_expert_parallel_ffn`).  It is *exact*: no capacity, no
 dropped token, whatever the routing.
 
-Routing (:func:`route`), two router kinds:
+Routing (:func:`route`), three router kinds:
 
 ``"softmax"``       top-k of the router logits, softmax over the chosen k;
 ``"sigmoid_bias"``  DeepSeek-V3 / GLM-4.x ``noaux_tc``: scores ``s =
                     sigmoid(x W_g)`` in float32; the k experts are chosen
                     by ``s + bias`` (``e_score_correction_bias``), weighted
                     by ``s`` itself (without the bias), normalised over the
-                    chosen k (``+ 1e-20``) and scaled.
+                    chosen k (``+ 1e-20``) and scaled;
+``"softmax_bias"``  LongCat-Flash: the same with ``s = softmax(x W_g)`` over
+                    ALL the router's columns (and there without the
+                    normalisation: a chosen column weighs ``scale * s``).
+
+The router's last ``zero`` columns may be *identity experts*
+(:func:`routed_ffn`): they hold no weights and return their input, so a row
+costs as many expert products as it chose FFN columns, and the identity part
+of the result is ``(sum of the chosen identity columns' weights) * x``,
+computed for every row wherever the row is (under a share of the experts it
+counts once, as a shared expert does).
 
 The expert product (:func:`expert_ffn`) follows rows x top-k, not rows x
 experts: the (row, expert) assignments are sorted by expert and each
@@ -59,9 +69,12 @@ def route(router_w, x, top_k: int, kind: str = "softmax", bias=None,
         if kind == "softmax":
             vals, idx = jax.lax.top_k(logits, top_k)
             return idx, jax.nn.softmax(vals, axis=-1)
-        if kind != "sigmoid_bias":
+        if kind == "sigmoid_bias":
+            s = jax.nn.sigmoid(logits)
+        elif kind == "softmax_bias":
+            s = jax.nn.softmax(logits, axis=-1)
+        else:
             raise ValueError(f"unknown router kind {kind!r}")
-        s = jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
         w = jnp.take_along_axis(s, idx, axis=1)
         if norm:
@@ -129,25 +142,35 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
 def routed_ffn(params: Dict[str, Any], x, top_k: int,
                compute_dtype=jnp.float32, router: str = "softmax",
                act: str = "gelu", scale: float = 1.0, norm: bool = True,
-               valid=None, first: int = 0, held=None):
+               valid=None, first: int = 0, held=None, zero: int = 0):
     """Route (N, D) rows and run the experts: ``(out (N, D) float32, stats
     (E + 2,))``.  ``params``: ``router (D, E)``, ``bias (E,)`` for the
-    ``"sigmoid_bias"`` router, and the experts as ``w13``/``w2``
-    (SwiGLU) or ``w1``/``w2`` (GELU).  ``first`` / ``held``: the share of
-    the router's ``E`` experts whose weights ``params`` holds (all of them
-    by default); the rows are routed over all ``E`` and the output is the
-    part the held experts give."""
+    ``"sigmoid_bias"`` and ``"softmax_bias"`` routers, and the experts as
+    ``w13``/``w2`` (SwiGLU) or ``w1``/``w2`` (GELU).  ``zero``: the router's
+    last ``zero`` columns are identity experts, the ``E - zero`` before
+    them FFN experts.  ``first`` / ``held``: the share of the FFN experts
+    whose weights ``params`` holds (all of them by default); the rows are
+    routed over all ``E`` columns and the output is the part the held
+    experts give plus, for every row, the identity part."""
     from tpulab.models.transformer import qmat, weight_shape
     idx, weights = route(params["router"], x, top_k, router,
                          params.get("bias"), scale, norm)
     w_in = params["w13" if act == "swiglu" else "w1"]
     n_experts = params["router"].shape[-1]
+    if zero and held is None:
+        held = n_experts - zero
     if weight_shape(w_in)[0] != (n_experts if held is None else held):
         raise ValueError(f"{weight_shape(w_in)[0]} experts' weights for a "
                          f"share of {held} of the router's {n_experts}")
+    # (an identity column's assignment is one to an expert not held here:
+    # ``expert_ffn`` leaves it out of every product)
     out = expert_ffn(x, idx, weights, qmat(w_in, compute_dtype),
                      qmat(params["w2"], compute_dtype), act, compute_dtype,
                      first=first)
+    if zero:
+        with jax.named_scope("moe_zero"):
+            out = out + jnp.where(idx >= n_experts - zero, weights, 0.0).sum(
+                axis=-1, keepdims=True) * x.astype(jnp.float32)
     return out, routing_stats(idx, n_experts, valid, first, held)
 
 
