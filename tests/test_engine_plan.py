@@ -104,9 +104,18 @@ def test_the_plan_is_what_a_constructed_engine_reports(kind, use_kernel):
     try:
         pool = cb.debug_state()["pool"]
         state = cb.debug_state().get("state")
+        tile = cb.debug_state()["dispatch"]["latent_tile"]
     finally:
         cb.shutdown()
     assert isinstance(plan, EnginePlan) and cb.plan == plan
+    # the latent kernels' tile, where they run: every head of a lane at
+    # one row, and at the round's width what the kernel's plan gives
+    assert tile == plan.latent_tile
+    assert (tile is not None) == bool(plan.latent and use_kernel)
+    if tile:
+        assert tile["one_row"]["heads"] == heads
+        assert tile["one_row"]["walks"] == 1
+        assert tile["round"]["heads"] * tile["round"]["walks"] == heads
     assert (cb.max_pages, cb.RAGGED_CHUNK_CAP, cb.use_kernel, cb.ragged,
             cb.round_budget_why, cb.prefill_chunk, cb.vocab) == (
         plan.max_pages, plan.round_cap, plan.use_kernel, plan.ragged,
@@ -302,3 +311,49 @@ def test_a_geometry_that_admits_no_round_is_refused_before_anything_is_built(
     auto = _geometry_plan(use_kernel=None, max_len=8192)
     assert (auto.use_kernel, auto.ragged, auto.prefill_flash) == (
         False, False, True)
+
+
+#: the two latent configurations' kernel calls: heads, pages a lane, and the
+#: heads a tile the plan gives a chunk of 512 rows (a row of 640, 512 of
+#: them the value, pages of 16, bf16)
+LATENT_CALLS = {"longcat-flash": (64, 1024, 8), "glm47flash": (20, 512, 5)}
+
+
+@pytest.mark.parametrize("config", list(LATENT_CALLS))
+def test_the_latent_tile_of_a_chunk_fits_vmem_and_one_row_keeps_its_tile(
+        config):
+    """At the published widths a chunk lane's tile is several heads within
+    the tile budget, so a lane's pages are walked ``heads / tile`` times
+    and not once a head; one row a lane is every head one tile, as it
+    was."""
+    heads, max_pages, heads_tile = LATENT_CALLS[config]
+    shape = (heads, 640, 512, 16, max_pages, jnp.bfloat16, jnp.bfloat16)
+    plan = ragged_attention._latent_plan(512, *shape)
+    assert (plan.heads_tile, plan.rows) == (heads_tile, 512 * heads_tile)
+    assert plan.vmem_bytes <= ragged_attention._LATENT_TILE_BUDGET
+    assert ragged_attention.latent_geometry_error(512, *shape) is None
+    assert ragged_attention.latent_tile(512, *shape) == dict(
+        heads=heads_tile, walks=heads // heads_tile,
+        vmem_bytes=plan.vmem_bytes)
+    one = ragged_attention._latent_plan(1, *shape)
+    assert (one.heads_tile, one.rows) == (heads, -(-heads // 16) * 16)
+    assert ragged_attention.latent_geometry_error(1, *shape) is None
+    # the tile follows the rows: a narrower chunk's holds more heads
+    assert [ragged_attention._latent_plan(m, *shape).heads_tile
+            for m in (256, 64, 16)] == ([16, 64, 64] if heads == 64
+                                        else [10, 20, 20])
+
+
+def test_a_latent_tile_past_vmem_is_refused_with_the_widest_that_fits():
+    shape = (64, 640, 512, 16, 1024, jnp.bfloat16, jnp.bfloat16)
+    assert ragged_attention._latent_plan(
+        512, *shape, heads_tile=64).vmem_bytes > \
+        ragged_attention._VMEM_REQUEST_MAX
+    said = ragged_attention.latent_geometry_error(512, *shape, heads_tile=64)
+    assert "at 64 heads a tile exceeds the 96 MiB" in said
+    assert "the widest tile that fits holds 8" in said
+    assert ragged_attention.latent_geometry_error(
+        512, *shape, heads_tile=8) is None
+    # a segment no tile holds: the plan falls to one head and says so
+    said = ragged_attention.latent_geometry_error(16384, *shape)
+    assert "at 1 heads a tile" in said and "no tile fits" in said

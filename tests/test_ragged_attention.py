@@ -33,12 +33,13 @@ from tpulab.engine.paged_steps import (_gather_attend,
 from tpulab.models.transformer import (early_exit_draft,
                                        init_transformer_params)
 from tpulab.ops import sparse_attention as sa
+from tpulab.ops import ragged_attention as ra
 from tpulab.ops.ragged_attention import (ragged_latent_attention,
                                          ragged_paged_attention)
 from tpulab.parallel import make_mesh
 
 from helpers_attention import (BF16_ATOL, BF16_RTOL, assert_operand_rule,
-                               assert_parents_bits, pallas_calls,
+                               assert_parents_bits, kernel_eqns, pallas_calls,
                                sparse_attend_case, sparse_decode_case)
 
 # ------------------------------------------------------------ kernel ----
@@ -124,6 +125,13 @@ _GRID += [("one_row", dtype, page_size, mesh_n, heads)
           for heads in _ONE_ROW_HEADS for mesh_n in (None, 2)
           for page_size in (8, 16) for dtype in ("float32", "bfloat16")
           if mesh_n is None or heads[1] % mesh_n == 0]
+#: the latent kernel at more than one row a lane: ``(rows a lane, heads,
+#: heads a tile where the case pins them)``: every head of the lane one
+#: tile, five heads a tile and four tiles a lane, and the agent cell's
+#: chunk call (eight heads a tile, eight tiles a lane)
+_GRID += [("latent", dtype, 8, None, rows)
+          for rows in ((16, 4, None), (64, 20, 5), (512, 64, 8))
+          for dtype in ("float32", "bfloat16")]
 
 
 def _one_row_case(page_size):
@@ -139,6 +147,61 @@ def _one_row_case(page_size):
     s = page_size
     return ([1, 1, 0, 1, 1], [6 * s + 3, 3 * s + 1, 2 * s, 2 * s + 3, 4 * s],
             1, 8, 2, 2)
+
+
+def _latent_case(m):
+    """``(q_lens, kv_lens, table width)`` of the latent rows cases, at
+    blocks of ``gs`` = 256 keys (32 pages of 8).  Lane 0's chunk starts at
+    position 0: no row sees a whole block.  Lane 1's starts three keys
+    into its second block.  Lane 2 holds fewer rows than ``m`` and its
+    first row sits on a block's last key.  Lane 3 holds no row, lane 5
+    one, on its only block's last key.  Lane 4's last block holds one live
+    page.  Keys past a lane's length are no number (:func:`_poisoned`)."""
+    gs = 256
+    q_lens = [m, m, m - 3, 0, m, 1]
+    kv_lens = [m, m + gs + 3, m - 3 + 2 * gs - 1, 300,
+               -(-m // gs) * gs + gs + 5, gs]
+    return q_lens, kv_lens, -(-max(kv_lens) // 8) + 2
+
+
+def _latent_grid_case(dt, m, h, heads_tile):
+    """A latent rows case of the grid against the XLA gather: the rows a
+    lane holds agree, the rows past them are zero, a lane without rows is
+    unwritten."""
+    q_lens, kv_lens, mp = _latent_case(m)
+    b, ps, w, row, v_width, scale = len(q_lens), 8, 48, 64, 32, 0.2
+    ks = jax.random.split(jax.random.PRNGKey(m), 2)
+    q = jax.random.normal(ks[0], (b, m, h, w), jnp.float32).astype(dt)
+    pool = jax.random.normal(ks[1], (2, b * mp + 1, 1, ps, row),
+                             jnp.float32).astype(dt)
+    pool = pool.at[..., w:].set(0.0)             # the row's zero padding
+    tables = jnp.asarray(np.arange(1, b * mp + 1).reshape(b, mp), jnp.int32)
+    plan = ra._latent_plan(m, h, row, v_width, ps, mp, dt, dt, heads_tile)
+    assert (plan.g_pages * ps, plan.heads_tile) == (256, heads_tile or h)
+    assert plan.rows == plan.heads_tile * m
+    got = np.asarray(ra._latent_attn(
+        q, _poisoned(pool, tables, q_lens, kv_lens),
+        jnp.ones((1,), jnp.int32), tables, jnp.asarray(q_lens, jnp.int32),
+        jnp.asarray(kv_lens, jnp.int32), v_width=v_width, sm_scale=scale,
+        interpret=True, heads_tile=heads_tile), np.float32)
+    # the reference on a few heads (it holds a score for every key of the
+    # table): the first and last of a tile, of the lane
+    heads = sorted({0, h // 2 - 1, h // 2, h - 1} | (
+        {heads_tile - 1, heads_tile} if heads_tile else set()))
+    pos = jnp.asarray(kv_lens) - jnp.asarray(q_lens)
+    want = np.asarray(_gather_attend_latent(
+        q[:, :, heads], pool[1, :, 0], tables,
+        pos[:, None] + jnp.arange(m)[None, :], v_width, scale, dt),
+        np.float32)
+    tol = (dict(rtol=2e-5, atol=2e-5) if dt == jnp.float32
+           else dict(rtol=BF16_RTOL, atol=BF16_ATOL))
+    for bb, n in enumerate(q_lens):
+        if not n:
+            assert np.isnan(got[bb]).all()
+            continue
+        np.testing.assert_allclose(got[bb, :n][:, heads], want[bb, :n],
+                                   **tol)
+        assert not got[bb, n:].any()
 
 
 def _poisoned(pool, tables, q_lens, kv_lens):
@@ -163,8 +226,11 @@ def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n,
     """The parity drift guard of the satellite grid: every raggedness
     shape x dtype x page size x mesh agrees with the dense reference; at
     one row a lane (``one_row``: the stacked kernel) also every group size
-    x KV heads x head width the serving cells have."""
+    x KV heads x head width the serving cells have; the latent kernel at
+    more than one row a lane (``latent``) against its own XLA form."""
     dt = jnp.dtype(dtype)
+    if shape == "latent":
+        return _latent_grid_case(dt, *heads)
     rng = jax.random.PRNGKey(hash((shape, page_size)) % 2**31)
     g, hkv, d = heads
     hq = g * hkv
@@ -383,6 +449,27 @@ def test_a_skipped_lane_starts_no_dma_and_writes_nothing(kernel):
     assert not {"dma_start", "dma_wait", "swap", "addupdate"} & set(top)
     inside = str(body.eqns[top.index("cond")].params["branches"])
     assert "dma_start" in inside and "dma_wait" in inside
+
+
+@pytest.mark.parametrize("rows", [1, 16])
+def test_the_latent_kernels_tile_follows_the_rows_a_lane(rows):
+    """One row a lane keeps the program it had: every head of the lane one
+    tile, the statistics a loop's carry (two scratch operands: the page
+    pipeline and its semaphores).  More rows take the rows kernel: the
+    plan's tile, the statistics in three more scratch buffers."""
+    attend, _reference, q, q_lens, _kv_lens = _round_case("latent")
+    b, _m, h, _w = q.shape
+    (call,) = pallas_calls(attend, q[:, :rows], jnp.asarray(q_lens))
+    mapping = call.params["grid_mapping"]
+    plan = ra._latent_plan(rows, h, 64, 32, 8, 3, q.dtype, q.dtype)
+    assert plan.heads_tile == h and plan.rows == max(rows * h, 16)
+    assert mapping.grid == (b, h // plan.heads_tile)
+    assert mapping.num_scratch_operands == (2 if rows == 1 else 5)
+    # the accumulator is the carry of a loop at one row alone
+    carried = {v.aval.shape for e in kernel_eqns(
+        attend, q[:, :rows], jnp.asarray(q_lens))
+        if e.primitive.name == "while" for v in e.outvars}
+    assert ((plan.rows, 32) in carried) == (rows == 1)
 
 
 def _rule_case(kernel, dtype):

@@ -1320,6 +1320,7 @@ class ContinuousBatcher:
                          "mixed_attn_rows": self.mixed_attn_rows,
                          "round_budget": self._round_budget,
                          "round_budget_why": self.round_budget_why,
+                         "latent_tile": self.plan.latent_tile,
                          "mixed_prompt_tokens": self.mixed_prompt_tokens,
                          "budget_rounds": self.budget_rounds,
                          "decode_block_steps": self.decode_block_steps,

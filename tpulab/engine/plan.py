@@ -93,6 +93,11 @@ class EnginePlan:
     round_budget_why: Optional[str]
     #: the speculative draft's ``n_heads``, ``n_layers``, ``n_kv_heads``
     draft: Optional[Dict[str, int]] = None
+    #: with the kernels on a latent store, the tile their plan gives a lane
+    #: of the round's widest chunk (``"round"``) and of one row
+    #: (``"one_row"``): ``heads`` a tile, ``walks`` of the lane's pages (its
+    #: tiles), ``vmem_bytes`` a grid step
+    latent_tile: Optional[Dict[str, Dict[str, int]]] = None
 
     @property
     def step_kw(self) -> Dict[str, Any]:
@@ -137,15 +142,20 @@ def kernel_error(plan: EnginePlan, cap: int, verify_width: int):
     widest = (round_width(min(plan.prefill_chunk or cap, cap))
               if plan.ragged is not False else verify_width)
     if plan.latent:
-        return latent_geometry_error(
-            widest, plan.n_heads,
-            latent_page_shape(plan.page_size, plan.latent)[2],
-            spec.kv_lora_rank, plan.page_size, plan.max_pages,
-            plan.compute_dtype, plan.kv_dtype)
+        return latent_geometry_error(*_latent_call(plan, widest))
     return kernel_geometry_error(
         widest, plan.n_heads // plan.n_shards, plan.n_kv // plan.n_shards,
         plan.head_dim, plan.page_size, plan.max_pages,
         plan.compute_dtype, plan.kv_dtype)
+
+
+def _latent_call(plan: EnginePlan, q_len: int) -> tuple:
+    """The shapes of ``plan``'s latent kernel call at ``q_len`` rows a lane,
+    as ``latent_geometry_error`` and ``latent_tile`` take them."""
+    return (q_len, plan.n_heads,
+            latent_page_shape(plan.page_size, plan.latent)[2],
+            plan.spec.kv_lora_rank, plan.page_size, plan.max_pages,
+            plan.compute_dtype, plan.kv_dtype)
 
 
 def plan_engine(*, spec, n_heads: int, n_layers: int,
@@ -353,7 +363,13 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
             # real error and propagates.  (The interpreter builds any
             # geometry: a rule that admits no width binds nothing there.)
             raise ValueError(f"use_kernel=True: {refusal}")
+    tile = None
+    if latent and use_kernel:
+        from tpulab.ops.ragged_attention import latent_tile
+        widest = round_width(min(prefill_chunk or cap, cap))
+        tile = {"round": latent_tile(*_latent_call(plan, widest)),
+                "one_row": latent_tile(*_latent_call(plan, 1))}
     return dataclasses.replace(
         plan, use_kernel=bool(use_kernel),
         ragged=bool(use_kernel if ragged is None else ragged),
-        round_cap=cap, round_budget_why=why)
+        round_cap=cap, round_budget_why=why, latent_tile=tile)

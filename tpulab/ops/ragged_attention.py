@@ -88,6 +88,7 @@ path, sharded and not.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -684,56 +685,100 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
 # latent pages (multi-head latent attention, absorbed form)
 # ---------------------------------------------------------------------------
 
-#: most query rows one grid step of the latent kernel holds: a lane's heads
-#: stack into rows, and a 256-token chunk of 20 heads is 5,120 of them.
-#: On the v5e (PR 28, one layer of a 256-token mixed round, 8 lanes) 256
-#: rows ran 5.44 ms, 512 5.74, 1280 5.96, and Mosaic compiled them in 1.3,
-#: 1.9 and 5.8 s a call site (a step program has one a layer)
-_LATENT_TILE_ROWS = 256
+#: what a tile of a latent kernel may plan (:func:`_latent_plan`), little
+#: over half of ``_VMEM_REQUEST_MAX``: Mosaic's temporaries come on top.  The
+#: widest tile within it is taken: a tile walks its lane's pages once
+#: whatever it holds, and a key block staged once is the weights of ONE pair
+#: of products for all the tile's rows
+_LATENT_TILE_BUDGET = 56 << 20
 
 
-def _latent_tiling(m: int, h: int) -> tuple[int, int]:
-    """``(heads_per_tile, rows_per_tile)``: the most whole heads whose
-    ``m`` rows each fit ``_LATENT_TILE_ROWS`` (at least one), rows padded
-    to a packed bf16 tile."""
-    hb = max((c for c in range(1, h + 1)
-              if h % c == 0 and c * m <= _LATENT_TILE_ROWS), default=1)
-    return hb, -(-hb * m // 16) * 16
+class _LatentPlan(NamedTuple):
+    """What one grid step of a latent kernel holds (:func:`_latent_plan`)."""
+    heads_tile: int     # whole heads a tile: ``n_heads / heads_tile`` walks
+    rows: int           # their ``heads_tile * m`` rows, padded to a tile
+    g_pages: int
+    nbuf: int
+    vmem_bytes: int
 
 
 def _latent_plan(m: int, h: int, row: int, v_width: int, page_size: int,
                  max_pages: int, q_dtype, kv_dtype,
-                 g_pages: int | None = None,
-                 nbuf: int | None = None) -> tuple[int, int, int]:
-    """:func:`_plan` for the latent kernel: one carry for all the rows of
-    a tile, the staged block read once as keys and once as values."""
+                 heads_tile: int | None = None) -> _LatentPlan:
+    """:func:`_plan` for the latent kernels, and the tile it is the plan of.
+
+    A lane's rows are ``(head, token)`` pairs, head-major, and every head
+    attends the same key rows, so whole heads stack into the rows of one
+    product and a tile is ``heads_tile`` of them: the most whose bytes stay
+    within ``_LATENT_TILE_BUDGET`` (at least one; ``heads_tile`` pins it),
+    as :func:`_block_geometry` picks ``g_pages``.  At ONE row a lane that
+    is every head of the lane at the published widths.  The bytes: the page
+    pipeline, the double-buffered query and output blocks, the running
+    maximum, the normaliser (a 128-lane tile a row each) and the
+    accumulator in float32 (at one row a loop's carry, live twice across a
+    step; at more in scratch, beside the tile's scaled queries), and the
+    score tiles."""
     q_item, kv_item = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
-    auto_g, auto_nbuf = _block_geometry(page_size, max_pages, row, kv_item)
-    g_pages, nbuf = g_pages or auto_g, nbuf or auto_nbuf
-    r = _latent_tiling(m, h)[1]
+    # at more than one row the scaled queries are a copy worth counting
+    scaled = (jnp.dtype(mxu_operands(q_dtype, kv_dtype)[0]).itemsize
+              if m > 1 else 0)
+    g_pages, nbuf = _block_geometry(page_size, max_pages, row, kv_item)
     gs = g_pages * page_size
-    return g_pages, nbuf, (
-        nbuf * gs * row * kv_item + 2 * r * (row + v_width) * q_item
-        + 2 * r * (v_width + 2 * _LANES) * 4
-        + 3 * r * -(-gs // _LANES) * _LANES * 4)
+
+    def rows(heads):
+        return -(-heads * m // 16) * 16           # a packed bf16 tile
+
+    def need(r):
+        return (nbuf * gs * row * kv_item
+                + 2 * r * (row + v_width) * q_item + r * row * scaled
+                + (2 if m == 1 else 1) * r * (v_width + 2 * _LANES) * 4
+                + 3 * r * -(-gs // _LANES) * _LANES * 4)
+
+    if heads_tile is None:
+        heads_tile = max((c for c in range(1, h + 1) if h % c == 0
+                          and need(rows(c)) <= _LATENT_TILE_BUDGET),
+                         default=1)
+    r = rows(heads_tile)
+    return _LatentPlan(heads_tile, r, g_pages, nbuf, need(r))
+
+
+def latent_tile(q_len: int, n_heads: int, row: int, v_width: int,
+                page_size: int, max_pages: int, q_dtype, kv_dtype) -> dict:
+    """The tile :func:`_latent_plan` gives a lane of ``q_len`` rows, as a
+    person reads it: ``heads`` a tile, ``walks`` of the lane's pages (one a
+    tile), ``vmem_bytes`` a grid step."""
+    plan = _latent_plan(q_len, n_heads, row, v_width, page_size, max_pages,
+                        q_dtype, kv_dtype)
+    return {"heads": plan.heads_tile, "walks": n_heads // plan.heads_tile,
+            "vmem_bytes": plan.vmem_bytes}
 
 
 def latent_geometry_error(q_len: int, n_heads: int, row: int, v_width: int,
-                          page_size: int, max_pages: int, q_dtype,
-                          kv_dtype) -> str | None:
+                          page_size: int, max_pages: int, q_dtype, kv_dtype,
+                          heads_tile: int | None = None) -> str | None:
     """:func:`kernel_geometry_error` for latent pages: whole-tile page
-    DMAs, whole-tile value columns, and a grid step that fits VMEM."""
+    DMAs, whole-tile value columns, and a grid step that fits VMEM
+    (:func:`_latent_plan`: the tile it picks, or one of ``heads_tile``
+    heads; the refusal names the widest tile that does fit)."""
     if row % _LANES or v_width % _LANES:
         return (f"latent row {row} and its value width {v_width} must be "
                 f"multiples of {_LANES} lanes")
     if page_size % _SUBLANES:
         return (f"page_size {page_size} is not a multiple of {_SUBLANES} "
                 "sublanes")
-    need = _latent_plan(q_len, n_heads, row, v_width, page_size, max_pages,
-                        q_dtype, kv_dtype)[2]
-    if need > _VMEM_REQUEST_MAX:
-        return (f"kernel VMEM {need >> 20} MiB for q_len={q_len} exceeds the "
-                f"{_VMEM_REQUEST_MAX >> 20} MiB it may request")
+
+    def plan(heads):
+        return _latent_plan(q_len, n_heads, row, v_width, page_size,
+                            max_pages, q_dtype, kv_dtype, heads)
+    tile = plan(heads_tile)
+    if tile.vmem_bytes > _VMEM_REQUEST_MAX:
+        fits = max((c for c in range(1, tile.heads_tile) if n_heads % c == 0
+                    and plan(c).vmem_bytes <= _VMEM_REQUEST_MAX), default=0)
+        return (f"kernel VMEM {tile.vmem_bytes >> 20} MiB for q_len={q_len} "
+                f"at {tile.heads_tile} heads a tile exceeds the "
+                f"{_VMEM_REQUEST_MAX >> 20} MiB it may request ("
+                + (f"the widest tile that fits holds {fits}" if fits else
+                   "no tile fits: shorten the segment, prefill_chunk") + ")")
     return None
 
 
@@ -748,7 +793,12 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
     so the heads of a lane are rows of one dot, and the value is the first
     ``v_width`` columns of the same staged row.  Both products take their
     operands as :func:`mxu_operands` reads them from the store's dtype: the
-    staged block goes to both as stored, the probabilities rounded to it."""
+    staged block goes to both as stored, the probabilities rounded to it.
+
+    :func:`_latent_attn` gives it the calls at ONE row a lane (a decode
+    step, a round's decode rows: every head of the lane one tile); the body
+    is PR 28's for any ``m_q``, kept to the letter so that those calls keep
+    their program.  More rows a lane go to :func:`_latent_rows_kernel`."""
     lane = pl.program_id(0)
     layer = layer_ref[0]
     qn = qlens_ref[lane]
@@ -808,10 +858,99 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         o_ref[0] = (acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
+def _latent_rows_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
+                        kvpool_ref, o_ref, kv_buf, sem, m_ref, l_ref, acc_ref,
+                        *, page_size: int, max_pages: int, m_q: int,
+                        v_width: int, sm_scale: float, g_pages: int,
+                        nbuf: int):
+    """One lane's tile of stacked heads at MORE than one row a head,
+    against the lane's latent pages: :func:`_latent_attn_kernel`'s products
+    and online softmax, block for block, for a tile of thousands of rows.
+
+    ``q_ref (1, rows, W)``: whole heads of ``m_q`` rows each.  The lane's
+    pages are walked ONCE for the tile, and a staged key block
+    is the weights of one pair of products for all its rows.  The running
+    maximum, normaliser and accumulator live in VMEM scratch (``m_ref``,
+    ``l_ref (rows, 1)``, ``acc_ref (rows, v_width)``): as a loop's carry
+    Mosaic took 19 s to compile a tile of 4,096 rows and ran it a fifth
+    slower (PERF.md section 6, PR 47).
+
+    A block's mask is positional alone, and it is applied once, to the
+    scores.  ``kv_lens`` counts the segment, so the chunk's first position
+    ``kvn - qn`` is not negative and every row sees key 0: its running
+    maximum is a score from the first block on, a masked score's
+    ``exp(-1e30 - m)`` is exactly 0, and the probabilities need no second
+    multiply by the mask.  Rows past ``qn`` are zeroed where the tile is
+    stored."""
+    lane = pl.program_id(0)
+    layer = layer_ref[0]
+    qn = qlens_ref[lane]
+    kvn = kvlens_ref[lane]
+
+    @pl.when(qn > 0)                     # see _ragged_attn_kernel
+    def _lane():
+        length = jnp.maximum(kvn, 1) - 1
+        start = kvn - qn
+        gs = g_pages * page_size
+        rows = q_ref.shape[1]
+        dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
+        q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)   # (R, W)
+        dot_qk = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        dot_pv = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        start_block, wait_block, live_blocks = _page_walk(
+            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+            nbuf=nbuf, n_blocks=(max_pages + g_pages - 1) // g_pages)
+
+        def token(shape):
+            # token index of a stacked row: heads are m_q rows apart
+            r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            return (r & (m_q - 1) if m_q & (m_q - 1) == 0
+                    else jax.lax.rem(r, m_q))
+        # key column - query token: a row sees key ``j * gs + col`` iff
+        # this is at most ``start - j * gs``
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 1)
+                 - token((rows, gs)))
+
+        def block(j, _):
+            slot = jax.lax.rem(j, nbuf)
+            wait_block(j, slot)
+            # (a block past the lane's pages has no trip)
+            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
+
+            # the one staged row is key and value
+            _zero_rows_past(kv_buf, slot, 0, j * gs, length)
+            blk = kv_buf[slot, 0].astype(dt)                     # (G*S, W)
+            s = jnp.where(ahead <= start - j * gs, dot_qk(q, blk),
+                          _NEG)                                  # (R, G*S)
+            m_c = m_ref[...]
+            m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_c - m_new)
+            p = jnp.exp(s - m_new)
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + dot_pv(
+                p.astype(dt), blk[:, :v_width])
+
+        jax.lax.fori_loop(0, live_blocks, block, None)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = jnp.where(token((rows, 1)) < qn, out, 0.0).astype(
+            o_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("v_width", "sm_scale",
-                                             "interpret"))
+                                             "interpret", "heads_tile"))
 def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
-                 sm_scale: float, interpret: bool):
+                 sm_scale: float, interpret: bool,
+                 heads_tile: int | None = None):
     b, m, h, w = q.shape
     page_size, row = kv_pool.shape[3], kv_pool.shape[4]
     max_pages = tables.shape[1]
@@ -820,17 +959,30 @@ def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
                          f"(L, P, 1, S, row >= {w}); got {kv_pool.shape}")
     if not interpret:
         err = latent_geometry_error(m, h, row, v_width, page_size, max_pages,
-                                    q.dtype, kv_pool.dtype)
+                                    q.dtype, kv_pool.dtype, heads_tile)
         if err:
             raise ValueError(f"ragged_latent_attention: {err}")
-    hb, rows = _latent_tiling(m, h)
+    hb, rows, g_pages, nbuf, need = _latent_plan(
+        m, h, row, v_width, page_size, max_pages, q.dtype, kv_pool.dtype,
+        heads_tile)
     n_tiles = h // hb
     # heads stack into rows, head-major, a tile of whole heads; query
     # columns padded to the (zero-padded) page row
     qs = q.transpose(0, 2, 1, 3).reshape(b, n_tiles, hb * m, w)
+    kw = dict(page_size=page_size, max_pages=max_pages, m_q=m,
+              v_width=v_width, sm_scale=sm_scale, g_pages=g_pages, nbuf=nbuf)
+    scratch = [pltpu.VMEM((nbuf, 1, g_pages * page_size, row), kv_pool.dtype),
+               pltpu.SemaphoreType.DMA((nbuf, g_pages))]
+    if m == 1:
+        # one row a lane (a decode step, a round's decode rows): the shape
+        # decides, nothing else
+        kernel = functools.partial(_latent_attn_kernel, rows=rows, **kw)
+    else:
+        kernel = functools.partial(_latent_rows_kernel, **kw)
+        scratch += [pltpu.VMEM((rows, 1), jnp.float32),
+                    pltpu.VMEM((rows, 1), jnp.float32),
+                    pltpu.VMEM((rows, v_width), jnp.float32)]
     qs = jnp.pad(qs, ((0, 0), (0, 0), (0, rows - hb * m), (0, row - w)))
-    g_pages, nbuf, need = _latent_plan(m, h, row, v_width, page_size,
-                                       max_pages, q.dtype, kv_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,    # layer, tables (flat), q_lens, kv_lens
         grid=(b, n_tiles),
@@ -840,15 +992,8 @@ def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
         ],
         out_specs=pl.BlockSpec((1, rows, v_width),
                                lambda lane, t, *_: (lane, t, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, 1, g_pages * page_size, row), kv_pool.dtype),
-            pltpu.SemaphoreType.DMA((nbuf, g_pages)),
-        ],
+        scratch_shapes=scratch,
     )
-    kernel = functools.partial(
-        _latent_attn_kernel, page_size=page_size, max_pages=max_pages,
-        m_q=m, rows=rows, v_width=v_width, sm_scale=sm_scale,
-        g_pages=g_pages, nbuf=nbuf)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
