@@ -490,9 +490,9 @@ def phase_latent(smoke: Smoke) -> str:
         if uk:
             check_mosaic(smoke, "latent mixed round", partial(
                 paged_mixed_step, use_kernel=True, **round_kw), params,
-                pool.kv, packed)
+                pool.kv, packed, no_carry(lanes))
         _, kv, _ = padded(params, pool.kv, i32(tables), *fill, fill[1])
-        res, last, kv = mixed(params, kv, packed)
+        res, last, *_carry, kv = mixed(params, kv, packed, no_carry(lanes))
         moe = unpack_words(result_fields(lanes, moe=moe_shape(spec)),
                            np.asarray(res))["moe"]
         logits, kv, _ = step(params, kv, i32(tables), kv_lens,
@@ -578,8 +578,10 @@ def phase_jamba(smoke: Smoke) -> str:
             if uk and decode:
                 check_mosaic(smoke, "jamba mixed round", partial(
                     paged_mixed_step, use_kernel=True, lanes=lanes,
-                    max_pages=mp, **kw), params, store, packed)
-            _, last, store = mixed(params, store, packed)
+                    max_pages=mp, **kw), params, store, packed,
+                    no_carry(lanes))
+            _, last, *_carry, store = mixed(params, store, packed,
+                                            no_carry(lanes))
         logits, store = step(params, store, i32(tables), i32(final),
                              i32([5, 6, 7, 8]), jnp.ones((lanes,), bool))
         out[name] = (np.asarray(last, np.float32),
@@ -664,8 +666,10 @@ def phase_keye(smoke: Smoke) -> str:
             if uk and decode:
                 check_mosaic(smoke, "keye_vl2 mixed round", partial(
                     paged_mixed_step, use_kernel=True, lanes=lanes,
-                    max_pages=mp, **kw), params, store, packed)
-            _, last, store = mixed(params, store, packed)
+                    max_pages=mp, **kw), params, store, packed,
+                    no_carry(lanes))
+            _, last, *_carry, store = mixed(params, store, packed,
+                                            no_carry(lanes))
         logits, store, experts = step(params, store, i32(tables), i32(final),
                                       i32([5, 6, 7, 8]),
                                       jnp.ones((lanes,), bool))
@@ -770,8 +774,10 @@ def phase_qwen3_next(smoke: Smoke) -> str:
             if uk and decode:
                 check_mosaic(smoke, "qwen3_next mixed round", partial(
                     paged_mixed_step, use_kernel=True, lanes=lanes,
-                    max_pages=mp, **kw), params, store, packed)
-            _, last, store = mixed(params, store, packed)
+                    max_pages=mp, **kw), params, store, packed,
+                    no_carry(lanes))
+            _, last, *_carry, store = mixed(params, store, packed,
+                                            no_carry(lanes))
         logits, store, experts = step(params, store, i32(tables), i32(final),
                                       i32([5, 6, 7, 8]),
                                       jnp.ones((lanes,), bool))
@@ -874,7 +880,8 @@ def round_buffer(tables, toks, row_lane, row_off, q_lens, kv_lens):
     import jax.numpy as jnp
     import numpy as np
 
-    from tpulab.engine.paged_steps import dispatch_fields, pack_words
+    from tpulab.engine.paged_steps import (ROUND_STOPS, dispatch_fields,
+                                           pack_words)
     lanes, max_pages = tables.shape
     i32 = lambda x: np.asarray(x, np.int32)  # noqa: E731
     return jnp.asarray(pack_words(
@@ -882,7 +889,17 @@ def round_buffer(tables, toks, row_lane, row_off, q_lens, kv_lens):
             tables=i32(tables), q_lens=i32(q_lens), kv_lens=i32(kv_lens),
             temps=np.zeros((lanes,), np.float32),
             seeds=np.zeros((lanes, 2), np.uint32),
+            fresh=np.ones((lanes,), bool), rem=np.zeros((lanes,), np.int32),
+            stops=np.full((lanes, ROUND_STOPS), -1, np.int32),
             rows=np.stack([i32(toks), i32(row_lane), i32(row_off)]))))
+
+
+def no_carry(lanes: int):
+    """The carry a round takes beside a buffer that is ``fresh`` in every
+    lane: only its shapes count."""
+    import jax.numpy as jnp
+    return tuple(jnp.zeros((lanes,), t)
+                 for t in (jnp.int32, jnp.int32, bool, jnp.int32))
 
 
 def check_mosaic(smoke: Smoke, name: str, fn, *args) -> None:

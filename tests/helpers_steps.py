@@ -23,22 +23,46 @@ def _sampling(lanes, temps, seeds):
                else np.asarray(seeds, np.uint32)))
 
 
+def no_carry(lanes):
+    """A carry of the right shapes for a dispatch fresh in every lane."""
+    return tuple(jnp.zeros((lanes,), t)
+                 for t in (jnp.int32, jnp.int32, bool, jnp.int32))
+
+
 def mixed_step(fn, params, kv, tables, toks, row_lane, row_off, q_lens,
-               kv_lens, temps=None, seeds=None, spec=None):
+               kv_lens, temps=None, seeds=None, spec=None, carry=None,
+               fresh=None, rem=None, stops=None, with_carry=False):
     """One round on unpacked arguments: ``(next_tokens, logprobs, last
-    logits, kv, *moe)``, the first two and the counters as numpy."""
+    logits, kv, *moe)``, the first two and the counters as numpy.
+    ``carry`` (with ``fresh`` false in the lanes that take it) is what the
+    dispatch before returned; ``with_carry`` puts the round's own carry
+    before ``kv`` in what is returned."""
     lanes, max_pages = np.shape(tables)
-    packed = pack_words(dispatch_fields("round", lanes, max_pages), dict(
+    fields = dispatch_fields("round", lanes, max_pages)
+    width = dict((name, shape) for name, _t, shape in fields)["stops"][1]
+    sent = np.full((lanes, width), -1, np.int32)
+    if stops is not None:
+        stops = np.asarray(stops, np.int32)
+        sent[:, :stops.shape[1]] = stops
+    packed = pack_words(fields, dict(
         tables=np.asarray(tables, np.int32),
         q_lens=np.asarray(q_lens, np.int32),
         kv_lens=np.asarray(kv_lens, np.int32),
+        fresh=(np.ones((lanes,), bool) if fresh is None
+               else np.asarray(fresh, bool)),
+        rem=(np.zeros((lanes,), np.int32) if rem is None
+             else np.asarray(rem, np.int32)),
+        stops=sent,
         rows=np.stack([np.asarray(a, np.int32)
                        for a in (toks, row_lane, row_off)]),
         **_sampling(lanes, temps, seeds)))
-    out, last, kv = fn(params, kv, jnp.asarray(packed))
+    out, last, *after, kv = fn(params, kv, jnp.asarray(packed),
+                               no_carry(lanes) if carry is None
+                               else tuple(carry))
     res = unpack_words(result_fields(lanes, moe=moe_shape(spec)),
                        np.asarray(out))
-    return (res["tokens"], res["logprobs"], last, kv,
+    return (res["tokens"], res["logprobs"], last,
+            *([tuple(after)] if with_carry else []), kv,
             *([res["moe"]] if "moe" in res else []))
 
 

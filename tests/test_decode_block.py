@@ -341,6 +341,7 @@ class _Chain:
         self.at_fetch = {}        # n -> callable
         self.at_enqueue = {}      # n -> callable, as block n is enqueued
         self.hoarded = []         # (pages held, pages allowed)
+        self.sent_ahead = []      # block n went behind an un-fetched dispatch
         self._consuming = None
         dispatch, consume, note = (cb._dispatch_block, cb._consume_block,
                                    cb._note_moe)
@@ -356,6 +357,7 @@ class _Chain:
             stash["n"] = len(self.ks)
             self.events.append(("enqueue", stash["n"]))
             self.ks.append(k)
+            self.sent_ahead.append(bool(chained.get("ahead")))
             hook = self.at_enqueue.pop(stash["n"], None)
             if hook is not None:
                 hook()
@@ -470,7 +472,13 @@ def test_chain_ahead_parity_with_a_page_crossed_by_every_block(
         cb.shutdown()
     for g, w in zip(got, want):
         _same(g, w)
-    assert len(chain.ahead()) >= 3 and cb.ahead_blocks == len(chain.ahead())
+    # (under the ragged plan a chain's first block goes behind the round
+    # that brought its lanes' prompts in, not yet fetched either)
+    assert len(chain.ahead()) >= 3
+    assert cb.ahead_blocks == sum(chain.sent_ahead)
+    assert set(chain.ahead()) <= {n for n, a in enumerate(chain.sent_ahead)
+                                  if a}
+    assert cb.ahead_blocks - len(chain.ahead()) <= (2 if ragged else 0)
     assert not chain.hoarded
     assert cb.pool.free_pages == cb.pool.n_pages - 1
 
@@ -632,4 +640,223 @@ def test_a_request_that_arrives_during_a_dispatch_does_not_hold_the_chain(lm):
     finally:
         cb.shutdown()
     assert chain.ks[:2] == [2, 2] and 1 in chain.ahead()
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+# -- the mixed round is a member of the chain --------------------------------
+# While a prompt waits every dispatch is a round, and every round carries
+# every decoding lane from its predecessor's device carry
+# (ContinuousBatcher._chain_block): a round goes behind an un-fetched block
+# or round, a block behind an un-fetched round.
+
+class _Dispatches:
+    """Every decode block and mixed round a batcher enqueues, numbered as
+    enqueued (``kinds``, ``stashes``), and the order in which it enqueues
+    them and fetches their results (``cb._fetch`` of the dispatch's one
+    result array), recorded on the scheduler thread."""
+
+    def __init__(self, cb):
+        self.kinds, self.stashes = [], []
+        self.events = []          # ("enqueue", n) | ("fetch", n)
+        by_out = {}
+        block, round_, fetch = (cb._dispatch_block, cb._dispatch_round,
+                                cb._fetch)
+
+        def note(stash):
+            by_out[id(stash["out"])] = len(self.kinds)
+            self.events.append(("enqueue", len(self.kinds)))
+            self.kinds.append(stash["kind"])
+            self.stashes.append(stash)
+            return stash
+
+        def fetched(dev):
+            if id(dev) in by_out:
+                self.events.append(("fetch", by_out[id(dev)]))
+            return fetch(dev)
+        cb._dispatch_block = lambda *a, **kw: note(block(*a, **kw))
+        cb._dispatch_round = lambda *a, **kw: note(round_(*a, **kw))
+        cb._fetch = fetched
+
+    def last_fetched(self):
+        return next(n for what, n in reversed(self.events)
+                    if what == "fetch")
+
+    def behind(self, n):
+        """Dispatch ``n`` was enqueued before dispatch ``n - 1`` was
+        fetched."""
+        at = {e: i for i, e in enumerate(self.events)}
+        return n > 0 and at[("enqueue", n)] < at[("fetch", n - 1)]
+
+    def links(self):
+        """``(kind of n - 1, kind of n)`` of every dispatch that went
+        behind an un-fetched predecessor."""
+        return {(self.kinds[n - 1], self.kinds[n])
+                for n in range(1, len(self.kinds)) if self.behind(n)}
+
+
+def _beside(lm, first, second, at=5, reference=True, **kw):
+    """``first`` = (prompt, steps, submit options) streams on one lane of a
+    ragged engine of two (pages of 8, a round budget of 8); as its token
+    ``at`` is emitted, from the scheduler's own thread, ``second`` is
+    submitted: its prompt comes in beside the running chain.  Returns
+    ``(cb, spy, [first's result, second's result], tokens by dispatch)``;
+    the caller shuts ``cb`` down."""
+    cb = _batcher(lm, 8, lanes=2, max_len=160, ragged=True, prefill_chunk=8,
+                  **kw)
+    spy = _Dispatches(cb)
+    futs, emitted_by = [], {}
+    (pa, sa, sub_a), (pb, sb, sub_b) = first, second
+    hook_a = sub_a.pop("on_token", None)
+
+    def on_token(tok, i, *lp):
+        emitted_by[i] = spy.last_fetched() if spy.events else None
+        if hook_a is not None:
+            hook_a(tok, i, *lp)
+        if i == at:
+            futs.append(cb.submit(pb, sb, **sub_b))
+    futs.append(cb.submit(pa, sa, on_token=on_token, **sub_a))
+    return cb, spy, futs, emitted_by
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_rounds_and_blocks_chain_through_one_carry(lm, sampled):
+    """A prompt of four rounds arrives beside a streaming lane: the first
+    of its rounds goes behind the un-fetched block of the running chain,
+    each next round behind its un-fetched predecessor, with the streaming
+    lane a decode row from the carry; the block that follows the prompt's
+    LAST round goes behind that round un-fetched, and carries the new
+    lane from the round's carry: no ``joiner`` break.  Every stream is the
+    ``decode_block=1`` engine's, which fetches every dispatch."""
+    rng = np.random.default_rng(61)
+    pa = rng.integers(0, 64, (5,), np.int32)
+    pb = rng.integers(0, 64, (30,), np.int32)
+    sub = (lambda s: _sub(s)) if sampled else (lambda s: {"logprobs": True})
+    ref_a, ref_b = _reference(lm, [(pa, 40, sub(21)), (pb, 12, sub(22))],
+                              lanes=1, max_len=160, ragged=True)
+    cb, spy, futs, _by = _beside(lm, (pa, 40, sub(21)), (pb, 12, sub(22)))
+    try:
+        got_a = futs[0].result(timeout=120)
+        got_b = futs[1].result(timeout=120)
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    _same(got_a, ref_a)
+    _same(got_b, ref_b)
+    assert spy.links() >= {("block", "round"), ("round", "round"),
+                           ("round", "block")}
+    rounds = [n for n, kind in enumerate(spy.kinds) if kind == "round"]
+    # the second prompt: four rounds of 8, 8, 8 and 6 tokens, all of them
+    # enqueued ahead, the streaming lane a decode row of each
+    late = rounds[-4:]
+    assert late == list(range(late[0], late[0] + 4))
+    assert all(spy.behind(n) for n in late)
+    assert all(len(spy.stashes[n]["decodes"]) == 1 for n in late)
+    # its prompt ends in the last of them, un-fetched when the block
+    # behind it takes both lanes from its carry
+    last = spy.stashes[late[-1]]
+    (lane_b, req_b, _resumed), = last["firsts"]
+    after = spy.stashes[late[-1] + 1]
+    assert after["kind"] == "block" and spy.behind(late[-1] + 1)
+    assert after["lane_reqs"][lane_b] is req_b and len(after["lane_reqs"]) == 2
+    assert state["chain"]["breaks"]["joiner"] == 0
+    assert state["ahead_rounds"] >= 4 and state["rounds_after_round"] >= 3
+    assert state["mixed_decode_rows"] >= 4
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+def test_stop_token_inside_an_unfetched_round(lm):
+    """A stop token drawn by a decode row of round N, with round N+1
+    already enqueued: the stream ends on the stop token, the lane's row in
+    N+1 is dead through the carry (nothing is emitted, nothing of its
+    pages moves), every page comes home and the lane's next owner reads
+    no stale K/V."""
+    rng = np.random.default_rng(67)
+    pa = rng.integers(0, 64, (6,), np.int32)
+    pb = rng.integers(0, 64, (80,), np.int32)        # ten rounds of 8
+    pc = rng.integers(0, 64, (9,), np.int32)
+    ref_a, ref_b, ref_c = _reference(
+        lm, [(pa, 60, _sub(5)), (pb, 6, _sub(6)), (pc, 10, _sub(7))],
+        lanes=1, max_len=160, ragged=True)
+    # tokens 9 .. 18 of the first stream come out of the second prompt's
+    # rounds, one a round: stop on one first seen there
+    toks = list(ref_a[0])
+    idx = next(i for i in range(11, 17) if toks[i] not in toks[:i])
+    cb, spy, futs, by = _beside(
+        lm, (pa, 60, dict(_sub(5), stop_tokens=[toks[idx]])),
+        (pb, 6, _sub(6)))
+    try:
+        got_a = futs[0].result(timeout=120)
+        got_b = futs[1].result(timeout=120)
+        got_c = cb.submit(pc, 10, **_sub(7)).result(timeout=120)
+        breaks = dict(cb.chain_breaks)
+    finally:
+        cb.shutdown()
+    _same(got_a, (toks[:idx + 1], ref_a[1][:idx + 1]))
+    _same(got_b, ref_b)
+    _same(got_c, ref_c)
+    n = by[idx]                      # the dispatch that drew the stop token
+    assert spy.kinds[n] == spy.kinds[n + 1] == "round" and spy.behind(n + 1)
+    (lane_a, _req), = spy.stashes[n]["decodes"]
+    # the round behind it held a row for the lane: dead, and the chain
+    # broke at ITS consume, where the host had seen the lane released
+    assert [lane for lane, _ in spy.stashes[n + 1]["decodes"]] == [lane_a]
+    assert not spy.behind(n + 2) and breaks["released"] >= 1
+    assert max(by) == idx
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
+def _host_sampled(seed):
+    return dict(sampling=SamplingParams(temperature=0.8, top_k=4, seed=seed))
+
+
+@pytest.mark.parametrize("cause", ["completion", "released", "host"])
+def test_a_round_s_chain_breaks_with_its_cause(lm, cause):
+    """Where the host can foresee that the lane set changes inside a round
+    (a step budget that ends in it), where it changed (a cancel) and where
+    a lane's pick is made on the host (``top_k``: its token is not in the
+    carry), the round is fetched before its successor is planned, counted
+    under its cause, and the streams are the reference's."""
+    rng = np.random.default_rng(71)
+    pa = rng.integers(0, 64, (6,), np.int32)
+    pb = rng.integers(0, 64, (80,), np.int32)
+    steps = 14 if cause == "completion" else 40
+    # (a host-sampled request's PRNG lives in its options: one each a run)
+    options = (lambda: _host_sampled(3)) if cause == "host" else (
+        lambda: _sub(5))
+    ref_a, ref_b = _reference(lm, [(pa, steps, options()), (pb, 6, _sub(6))],
+                              lanes=1, max_len=160, ragged=True)
+    sub_a = options()
+    streamed = []
+    if cause == "released":
+        sub_a["on_token"] = lambda tok, i, lp: (
+            streamed.append(tok), i == 12 and cb.cancel(futs[0]))
+    cb, spy, futs, by = _beside(lm, (pa, steps, sub_a), (pb, 6, _sub(6)))
+    try:
+        if cause == "released":
+            with pytest.raises(Exception):
+                futs[0].result(timeout=120)
+            assert streamed == list(ref_a[0][:13])
+        elif cause == "host":
+            assert list(futs[0].result(timeout=120)) == list(ref_a)
+        else:
+            _same(futs[0].result(timeout=120), ref_a)
+        _same(futs[1].result(timeout=120), ref_b)
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
+    breaks = state["chain"]["breaks"]
+    assert breaks[cause] >= 1
+    rounds = [n for n, kind in enumerate(spy.kinds) if kind == "round"]
+    if cause == "completion":
+        # tokens 9 .. 13 came out of rounds; the round that holds the last
+        # one got no successor before its fetch
+        n = by[steps - 1]
+        assert spy.kinds[n] == "round" and not spy.behind(n + 1)
+        assert spy.behind(n)
+    elif cause == "host":
+        # every round that carried the host-sampled lane was fetched first
+        carried = [n for n in rounds if spy.stashes[n]["host_lanes"]]
+        assert len(carried) >= 5 and breaks["host"] >= len(carried) - 1
+        assert not any(spy.behind(n + 1) for n in carried
+                       if n + 1 < len(spy.kinds))
     assert cb.pool.free_pages == cb.pool.n_pages - 1

@@ -346,12 +346,12 @@ def test_a_chunk_ends_at_its_window_under_the_widest_budget(model, reference,
     assert f"windows of {WINDOW}" in state["round_budget_why"]
     taken, mixed = [], cb.programs.mixed
 
-    def spy(params, kv, packed):
+    def spy(params, kv, packed, carry):
         q = unpack_words(cb.programs.fields["round"],
                          np.asarray(packed))["q_lens"]
         taken.append([int(q[lane]) if req is not None and req.pf_started
                       else 0 for lane, req in enumerate(cb._active)])
-        return mixed(params, kv, packed)
+        return mixed(params, kv, packed, carry)
     cb.programs.mixed = spy
     jobs = [(_prompt(20, 21), 6), (_prompt(100, 22), 6)]
     try:

@@ -175,8 +175,11 @@ def _count_transfers(cb):
         setattr(cb, name, call)
     counted("_dispatch_block", lambda a, kw: (
         "chained block" if kw.get("carry") is not None else "first block"))
+    counted("_dispatch_round", lambda a, kw: (
+        "chained round" if kw.get("chain") is not None else "first round"))
     counted("_consume_block", lambda a, kw: "fetch")
-    counted("_ragged_round", lambda a, kw: "round")
+    counted("_consume_round", lambda a, kw: "fetch")
+    counted("_ragged_round", lambda a, kw: "head")
     return seen
 
 
@@ -200,15 +203,18 @@ def test_one_transfer_a_dispatch_and_one_fetch_for_it(model):
     assert moved == {"h2d": sum(kinds.values()),
                      "d2h": state["decode_host_syncs"]}
     assert state["decode_host_syncs"] == sum(kinds.values())
-    # and each kind by itself (a round that found no prompt did nothing)
+    # and each kind by itself: a dispatch is one buffer in whether it heads
+    # a chain or goes behind an un-fetched predecessor, a consume one array
+    # out (the head of a chain does nothing beside its dispatch and consume)
     by_kind = {}
     for what, h2d, d2h in seen:
         by_kind.setdefault(what, set()).add((h2d, d2h))
-    assert by_kind.pop("round") <= {(1, 1), (0, 0)}
-    assert by_kind == {"first block": {(1, 0)}, "chained block": {(1, 0)},
-                       "fetch": {(0, 1)}}
+    assert by_kind.pop("head") == {(0, 0)}
+    assert by_kind.pop("first block", {(1, 0)}) == {(1, 0)}
+    assert by_kind == {"first round": {(1, 0)}, "chained round": {(1, 0)},
+                       "chained block": {(1, 0)}, "fetch": {(0, 1)}}
     assert kinds["mixed"] >= 3 and state["ahead_blocks"] >= 1
-    assert kinds["decode"] > state["ahead_blocks"]
+    assert state["ahead_rounds"] >= 2
 
 
 def test_a_host_sampled_lane_pays_its_rows_beside_the_one_fetch():
@@ -248,6 +254,33 @@ def test_first_and_chained_blocks_are_one_compiled_program():
     assert state["ahead_blocks"] >= 5             # a chain ran
     assert state["kinds"]["decode"] > state["ahead_blocks"]
     assert sizes and set(sizes.values()) == {1}, sizes
+
+
+def test_first_and_chained_rounds_are_one_compiled_program():
+    """A chain's first round (fresh in every lane, beside ``_no_carry``)
+    and the rounds behind un-fetched blocks and rounds (decode rows from
+    the carry) are one program a width: a geometry no other test of this
+    process has, so that the shared jit's cache counts this engine's."""
+    spec, params = _dense()
+    cb = _engine(spec, params, lanes=4, max_len=120)
+    rng = np.random.default_rng(5)
+    streaming = threading.Event()
+    try:
+        futs = [cb.submit(np.arange(7), steps=50,
+                          on_token=lambda t, i: i == 3 and streaming.set())]
+        assert streaming.wait(60)
+        # prompts of 8 + 8 + 8 + 3 tokens: rounds of width 8, 8, 8 and 4
+        futs += [cb.submit(rng.integers(0, 64, 27), steps=4)
+                 for _ in range(3)]
+        for f in futs:
+            f.result(timeout=300)
+        state = cb.debug_state()["dispatch"]
+        size = cb.programs.mixed._cache_size()
+    finally:
+        cb.shutdown()
+    assert state["kinds"]["mixed"] > state["ahead_rounds"] >= 4
+    assert state["mixed_decode_rows"] >= 4
+    assert size == 2, size                  # widths 8 and 4, chained or not
 
 
 def test_a_chained_block_takes_its_state_from_the_carry_alone():

@@ -21,7 +21,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import test_evabyte
 import test_jamba
+import test_keye_sparse
+import test_qwen3_next
 from helpers_steps import mixed_step
 from test_glm_moe import CONFIG, D_FF, VOCAB
 from tpulab.engine import paged_steps
@@ -30,7 +33,8 @@ from tpulab.engine.paged import ContinuousBatcher
 from tpulab.engine.paged_steps import (pack_round, paged_decode_step,
                                        paged_mixed_step,
                                        paged_ragged_forward, round_width)
-from tpulab.models.spec import glm4_moe_lite_spec, init_params, jamba_spec
+from tpulab.models.spec import (evabyte_spec, glm4_moe_lite_spec, init_params,
+                                jamba_spec, keye_vl2_spec, qwen3_next_spec)
 from tpulab.models.transformer import init_transformer_params
 from tpulab.ops import ragged_attention
 
@@ -260,6 +264,134 @@ def test_rows_without_a_token_leave_every_layer_finite(models, model,
     np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
 
 
+# -- the round takes a carry and returns one ------------------------------------
+def _carry_np(carry):
+    return [np.asarray(c) for c in carry]
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["gather", "kernel"])
+@pytest.mark.parametrize("model", ["dense", "mla-experts", "hybrid"])
+def test_a_round_behind_a_round_takes_its_decode_rows_from_the_carry(
+        models, model, use_kernel):
+    """Two rounds.  The first, fresh in every lane: lane 0's prompt ENDS in
+    it (the host says so with ``rem``), lane 5 is in mid-prompt, lanes 1, 2
+    and 3 decode; lane 2's budget ends with this token and lane 3 draws its
+    stop token.  Its carry says who goes on: lanes 0 and 1.  The second
+    round carries a chunk for lane 5 and a decode row for lanes 0-3 from
+    that carry, with a buffer that says nothing of their tokens or lengths:
+    it equals the round sent ``fresh`` with the state read back, the rows
+    of lanes 2 and 3 are dead (no page and no lane state of theirs moves)
+    and its own carry holds them dead."""
+    params, kw, pool_kw, tol = models[model]
+    kw = dict(kw, compute_dtype=jnp.float32, use_kernel=use_kernel)
+    spec = kw.get("spec")
+    rng = np.random.default_rng(23)
+    tables = i32(OWN)
+    plain = _plain_form(model, params, kw)
+    ctx = np.asarray([6, 9, 4, 11, 0, 3, 0, 0], np.int32)
+    fill = rng.integers(0, VOCAB, (LANES, int(ctx.max())))
+    _logits, kv, *_ = plain(_store(kw, pool_kw), tables, fill, ctx, ctx)
+    step = _jit(paged_mixed_step, model, kw, **PACKED)
+
+    first = dict(prefill={0: rng.integers(0, VOCAB, 5),
+                          5: rng.integers(0, VOCAB, 4)},
+                 decode={lane: int(rng.integers(VOCAB)) for lane in (1, 2, 3)})
+    toks, row_lane, row_off, q_lens = pack_round(LANES, first["prefill"],
+                                                 first["decode"])
+    kv_lens = np.where(q_lens > 0, ctx + q_lens, 0)
+    rem = np.asarray([7, 4, 1, 9, 0, 0, 0, 0], np.int32)   # lane 5: mid-prompt
+    # lane 3's stop token is what it draws here: read it off a dry run
+    picks, *_rest = mixed_step(step, params, kv, tables, toks, row_lane,
+                               row_off, q_lens, kv_lens, spec=spec, rem=rem)
+    stops = np.full((LANES, 2), -1, np.int32)
+    stops[3] = [int(picks[3]), -1]
+    stops[1] = [(int(picks[1]) + 1) % VOCAB, -1]        # not what lane 1 draws
+    picks1, _lp, _last, carry, kv1, *_moe = mixed_step(
+        step, params, kv, tables, toks, row_lane, row_off, q_lens, kv_lens,
+        spec=spec, rem=rem, stops=stops, with_carry=True)
+    np.testing.assert_array_equal(picks1, picks)
+    lens, last_toks, live, left = _carry_np(carry)
+    np.testing.assert_array_equal(live, [True, True, False, False, False,
+                                         False, False, False])
+    np.testing.assert_array_equal(lens[:4], (ctx + q_lens)[:4])
+    np.testing.assert_array_equal(last_toks[:4], picks[:4])
+    np.testing.assert_array_equal(left[:4], rem[:4] - 1)
+
+    # the second round: lane 5's next chunk, a decode row for lanes 0-3
+    chunk = {5: rng.integers(0, VOCAB, 3)}
+    toks2, row_lane2, row_off2, q2 = pack_round(
+        LANES, chunk, {lane: 0 for lane in (0, 1, 2, 3)})
+    held = ctx + q_lens                               # what the host knows
+    kv2 = np.where(q2 > 0, held + q2, 0)
+    kv2[:4] = 0                                       # the carry's to say
+    fresh = np.ones((LANES,), bool)
+    fresh[:4] = False
+    chained = mixed_step(step, params, kv1, tables, toks2, row_lane2,
+                         row_off2, q2, kv2, spec=spec, carry=carry,
+                         fresh=fresh, stops=stops, with_carry=True)
+    # the same round sent fresh: the live lanes alone, with what the carry
+    # held read back to the host
+    toks3, row_lane3, row_off3, q3 = pack_round(
+        LANES, chunk, {lane: int(last_toks[lane]) for lane in (0, 1)})
+    kv3 = np.where(q3 > 0, held + q3, 0)
+    resent = mixed_step(step, params, kv1, tables, toks3, row_lane3,
+                        row_off3, q3, kv3, spec=spec,
+                        rem=np.where(np.arange(LANES) < 2, left, 0),
+                        stops=stops, with_carry=True)
+    ran = q3 > 0
+    np.testing.assert_array_equal(chained[0][ran], resent[0][ran])
+    np.testing.assert_allclose(np.asarray(chained[2])[ran],
+                               np.asarray(resent[2])[ran], rtol=tol, atol=tol)
+    for got, want in zip(_carry_np(chained[3]), _carry_np(resent[3])):
+        np.testing.assert_array_equal(got[ran], want[ran])
+    assert not _carry_np(chained[3])[2][2:].any()       # dead stays dead
+    # the pages and lane state: the dead rows wrote nothing of their own
+    for got, ref in zip(jax.tree.leaves(chained[4]),
+                        jax.tree.leaves(resent[4])):
+        got, ref = np.asarray(got), np.asarray(ref)
+        if got.shape[1] == 1 + LANES * MAX_PAGES:
+            got, ref = got[:, 1:], ref[:, 1:]
+        np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+    if len(chained) > 5:
+        np.testing.assert_array_equal(np.asarray(chained[5]),
+                                      np.asarray(resent[5]))
+
+
+def test_a_prompt_that_ends_on_its_window_is_held_for_its_compaction():
+    """EVA: a lane whose prompt ends exactly where its window does, and a
+    decode row that fills its window, come out of the round not live: the
+    block body's hold, so that the compaction comes before the next row."""
+    spec = evabyte_spec(test_evabyte.CONFIG)
+    window = spec.eva_window
+    params = init_params(spec, test_evabyte.VOCAB, test_evabyte.D_FF, seed=3,
+                         scale=0.1)
+    lanes, page = 3, test_evabyte.CHUNK
+    mp = 2 * window // page
+    tables = 1 + np.arange(lanes * mp, dtype=np.int32).reshape(lanes, mp)
+    kv = PagedKVPool(n_pages=1 + lanes * mp, page_size=page,
+                     n_layers=spec.n_layers, n_heads=spec.n_kv_heads,
+                     head_dim=spec.head_dim, dtype=jnp.float32).kv
+    step = jax.jit(partial(paged_mixed_step, lanes=lanes, max_pages=mp,
+                           n_heads=spec.n_heads, n_layers=spec.n_layers,
+                           compute_dtype=jnp.float32, spec=spec))
+    rng = np.random.default_rng(29)
+    ctx = np.asarray([window - 6, window - 1, window - 9], np.int32)
+    _p, _l, _last, kv, *_ = mixed_step(
+        step, params, kv, tables, *pack_round(
+            lanes, {b: rng.integers(0, test_evabyte.VOCAB, int(ctx[b]))
+                    for b in range(lanes)}, {}), ctx, spec=spec)
+    toks, row_lane, row_off, q_lens = pack_round(
+        lanes, {0: rng.integers(0, test_evabyte.VOCAB, 6)}, {1: 7, 2: 9})
+    out = mixed_step(step, params, kv, tables, toks, row_lane, row_off,
+                     q_lens, ctx + q_lens, spec=spec, rem=[5, 5, 5],
+                     with_carry=True)
+    lens, _toks, live, left = _carry_np(out[3])
+    np.testing.assert_array_equal(lens, [window, window, window - 8])
+    np.testing.assert_array_equal(live, [False, False, True])
+    np.testing.assert_array_equal(left, [4, 4, 4])
+
+
 # -- the scheduler half ---------------------------------------------------------
 def _engine(lanes, max_len=512, rope_theta=10000.0, **kw):
     params = init_transformer_params(vocab=64, d_model=32, n_heads=2,
@@ -276,7 +408,7 @@ def _spy_rounds(cb):
     pending and what the round took."""
     rounds, mixed = [], cb.programs.mixed
 
-    def spy(params, kv, packed):
+    def spy(params, kv, packed, carry):
         f = paged_steps.unpack_words(cb.programs.fields["round"],
                                      np.asarray(packed))
         toks, row_lane, _row_off = f["rows"]
@@ -291,7 +423,7 @@ def _spy_rounds(cb):
             lanes=[(req.admit_seq, len(req.pending_prompt), int(q[lane]))
                    for lane, req in enumerate(cb._active)
                    if req is not None and req.pf_started]))
-        return mixed(params, kv, packed)
+        return mixed(params, kv, packed, carry)
     cb.programs.mixed = spy
     return rounds
 
@@ -376,6 +508,97 @@ def test_seeded_mixed_workload_streams_equal_the_split_plans(use_kernel):
     assert got == want
     assert cb.dispatch_kinds["mixed"] >= -(-sum(lens) // 32)
     assert 0 < cb.mixed_tokens <= cb.mixed_rows
+    # the rounds ran as members of the chain (every family, greedy and
+    # sampled, against the plan that fetches every dispatch:
+    # test_chained_rounds_give_the_streams_of_the_plan_that_fetches_every_dispatch)
+    assert cb.ahead_rounds > 0 and cb.mixed_decode_rows > 0
+
+
+def _dense_family():
+    return None, init_transformer_params(vocab=64, d_model=32, n_heads=2,
+                                         n_layers=1, d_ff=64), 64
+
+
+def _spec_family(make_spec, module, d_ff, scale=0.1):
+    def build():
+        spec = make_spec(module.CONFIG)
+        return (spec, init_params(spec, module.VOCAB, d_ff, seed=3,
+                                  scale=scale), module.VOCAB)
+    return build
+
+
+#: one tiny model of every family the engine serves: (spec, params, vocab)
+FAMILIES = {
+    "dense": _dense_family,
+    "latent-experts": lambda: (
+        glm4_moe_lite_spec(CONFIG),
+        init_params(glm4_moe_lite_spec(CONFIG), VOCAB, D_FF, seed=3,
+                    scale=0.1), VOCAB),
+    "mamba": _spec_family(jamba_spec, test_jamba, test_jamba.D_FF),
+    "gated-deltanet": _spec_family(qwen3_next_spec, test_qwen3_next, 0),
+    "sparse": _spec_family(keye_vl2_spec, test_keye_sparse, 0, scale=0.3),
+    "eva": _spec_family(evabyte_spec, test_evabyte, test_evabyte.D_FF),
+}
+
+
+def _family_engine(family, spec, params, **kw):
+    heads = (2, 1) if spec is None else (spec.n_heads, spec.n_layers)
+    geometry = (dict(max_len=160, page_size=test_evabyte.CHUNK,
+                     prefill_chunk=12) if family == "eva"
+                else dict(max_len=96, page_size=8, prefill_chunk=8))
+    return ContinuousBatcher(params, *heads, spec=spec, lanes=3,
+                             compute_dtype=jnp.float32, ragged=True,
+                             use_kernel=False, **dict(geometry, **kw))
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_chained_rounds_give_the_streams_of_the_plan_that_fetches_every_dispatch(
+        family, sampled, monkeypatch):
+    """A seeded mixed workload (prompts of one to several rounds arriving
+    beside lanes that decode) on a tiny model of every family: with the
+    round a member of the chain (rounds enqueued behind un-fetched blocks
+    and rounds, their decode rows from the device carry) every stream is,
+    token for token, that of the engine whose every dispatch is fetched
+    before the next is planned."""
+    from tpulab.engine.paged import SamplingParams
+    spec, params, vocab = FAMILIES[family]()
+    rng = np.random.default_rng(37)
+    lens = [9, 30, 5, 21, 14, 3, 26, 11]
+    if family == "eva":
+        lens = [9, 70, 5, 33, 14, 3, 40, 11]     # past a window, and two
+    prompts = [rng.integers(0, vocab, n) for n in lens]
+
+    def run(chain):
+        if not chain:
+            monkeypatch.setattr(ContinuousBatcher, "_chain_block",
+                                lambda self, stash, jnp, ahead: (None, "k1"))
+        cb = _family_engine(family, spec, params)
+        try:
+            futs = []
+            for i, p in enumerate(prompts):
+                sub = dict(sampling=SamplingParams(
+                    temperature=0.9, seed=100 + i, device=True)) \
+                    if sampled else {}
+                futs.append(cb.submit(p, steps=9 + 5 * (i % 3), **sub))
+                if i % 3 == 2:      # arrivals in bursts of three
+                    futs[-2].result(timeout=300)
+            return ([list(f.result(timeout=300)) for f in futs],
+                    cb.debug_state()["dispatch"])
+        finally:
+            cb.shutdown()
+            monkeypatch.undo()
+    got, state = run(True)
+    want, plain = run(False)
+    assert got == want
+    assert [len(t) for t in got] == [9 + 5 * (i % 3)
+                                     for i in range(len(lens))]
+    assert plain["ahead_rounds"] == plain["ahead_blocks"] == 0
+    assert state["ahead_rounds"] >= 3 and state["rounds_after_round"] >= 1
+    assert state["mixed_decode_rows"] > 0
+    assert state["chain"]["breaks"]["joiner"] == 0
+    if family == "eva":
+        assert state["chain"]["breaks"]["compact"] >= 2
 
 
 def test_mixed_attn_rows_counts_m_a_chunk_lane_and_one_a_decode_lane():
@@ -440,7 +663,10 @@ def test_every_mixed_program_is_reached_by_a_single_prompt():
                           700, 1)]
         for f in futs:
             f.result(timeout=120)
+        # with or without a predecessor: the burst's rounds went behind
+        # un-fetched blocks and rounds, on the same nine programs
         assert cb.programs.mixed._cache_size() == 9
+        assert cb.ahead_rounds > 0 and cb.mixed_decode_rows > 0
     finally:
         cb.shutdown()
 

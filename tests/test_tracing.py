@@ -216,26 +216,32 @@ def test_clock_turn_opens_at_an_empty_queue_and_ends_at_the_launch():
 
 
 def _consumed_blocks(cb):
-    """Count the decode blocks ``cb`` consumes from here on."""
-    calls = []
-    inner = cb._consume_block
+    """Count the decode blocks and the mixed rounds ``cb`` consumes from
+    here on: ``(K of each block, rounds)``."""
+    calls, rounds = [], []
+    block, round_ = cb._consume_block, cb._consume_round
 
     def counted(stash, jnp):
         calls.append(stash["k"])
-        return inner(stash, jnp)
-    cb._consume_block = counted
-    return calls
+        return block(stash, jnp)
+
+    def counted_round(stash, jnp):
+        rounds.append(stash["kind"])
+        return round_(stash, jnp)
+    cb._consume_block, cb._consume_round = counted, counted_round
+    return calls, rounds
 
 
 @pytest.mark.parametrize("ragged", [False, True])
 def test_turns_chain_and_parts_account_for_the_scheduler(ragged):
     """``dispatch["turns"]``: its stages sum to its seconds and never
     exceed the stages' own; ``dispatch["chain"]``: every consumed decode
-    block either had its successor enqueued ahead or a counted cause;
+    block and mixed round either had its successor (a block or a round)
+    enqueued ahead or a counted cause;
     ``dispatch["dispatch_parts"]``: inside the ``dispatch`` stage."""
     from tpulab.engine.paged import ContinuousBatcher as CB
     cb = _tiny_engine(lanes=2, ragged=ragged)
-    consumed = _consumed_blocks(cb)
+    consumed, rounds = _consumed_blocks(cb)
     try:
         futs = [cb.submit(np.arange(3 + i, dtype=np.int32), 7 + 3 * i,
                           on_token=(lambda tok, i: None) if i % 2 else None)
@@ -256,8 +262,10 @@ def test_turns_chain_and_parts_account_for_the_scheduler(ragged):
     assert turns["n"] <= d["decode_dispatches"] + d["prefill_dispatches"]
     chain = d["chain"]
     assert tuple(chain["breaks"]) == CB.BREAK_CAUSES
-    assert consumed and len(consumed) == (
-        d["ahead_blocks"] + sum(chain["breaks"].values()))
+    assert consumed and len(consumed) + len(rounds) == (
+        d["ahead_blocks"] + d["ahead_rounds"]
+        + sum(chain["breaks"].values()))
+    assert len(rounds) == d["kinds"]["mixed"]
     assert chain["breaks"]["k1"] == 0 and chain["breaks"]["completion"] > 0
     assert 0 <= chain["late_links"] <= sum(chain["breaks"].values())
     parts = d["dispatch_parts"]
@@ -334,12 +342,19 @@ def _force_joiner(cb, seen):
 def test_chain_break_is_counted_under_its_cause(cause, lanes, force, ragged):
     """Each cause a CPU batcher can be driven into, forced from the
     scheduler's own thread (a token hook) so the order is the program's:
-    counted once under its name, and nothing under any other."""
+    counted once under its name, and nothing under any other.  Under the
+    ragged plan a request that arrives beside a running chain is no
+    ``joiner``: its prompt rides a round of the chain and its lane decodes
+    from that round's carry, so nothing breaks."""
     cb = _tiny_engine(lanes=lanes, ragged=ragged)
     try:
         breaks = force(cb, [])
     finally:
         cb.shutdown()
+    if ragged and cause == "joiner":
+        assert not any(breaks.values()), breaks
+        assert cb.ahead_rounds >= 1
+        return
     assert breaks.pop(cause) == 1
     assert not any(breaks.values()), breaks
 
@@ -456,7 +471,8 @@ def _lower_args(cb, kind):
         "paged_decode_step_sampled": lambda: (
             cb.programs.tick, head + (packed("tick", 0),)),
         "paged_mixed_step": lambda: (
-            cb.programs.mixed, head + (packed("round", 8 + b),)),
+            cb.programs.mixed,
+            head + (packed("round", 8 + b), cb._no_carry)),
         "paged_prefill": lambda: (
             cb.programs.prefill, head + one + (jnp.int32(5),)),
         "paged_extend": lambda: (

@@ -28,9 +28,10 @@ from tpulab import chaos
 from tpulab.core.deadline import Deadline, DeadlineExceeded
 from tpulab.core.threads import on_one_frame_chunk
 from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool, PrefixCache
-from tpulab.engine.paged_steps import (StepPrograms, _device_sample_token,
-                                       moe_shape, pack_round, pack_words,
-                                       result_fields, unpack_words)
+from tpulab.engine.paged_steps import (ROUND_STOPS, StepPrograms,
+                                       _device_sample_token, moe_shape,
+                                       pack_round, pack_words, result_fields,
+                                       unpack_words)
 from tpulab.engine.plan import plan_engine
 from tpulab.utils import tracing
 from tpulab.utils.tracing import part, stage
@@ -268,8 +269,13 @@ class ContinuousBatcher:
     ``debug_state()["dispatch"]["round_budget"]``), ONE
     dispatch and one host sync per round, no separate prefill programs —
     and the speculative verify forward rides the same ragged kernel
-    family.  Tokens are bit-exact vs the legacy split dispatch
-    (``use_kernel=False``, the escape hatch), mesh on or off.
+    family.  The round is a member of the run-ahead chain: it takes the
+    carry a decode block returns and returns one, so while a prompt waits
+    every dispatch is a round that carries every decoding lane as a row
+    from its predecessor's device carry, enqueued before that predecessor
+    is fetched (:meth:`_chain_block`).  Tokens are bit-exact vs the legacy
+    split dispatch (``use_kernel=False``, the escape hatch), mesh on or
+    off.
 
     Model spec (``spec=``, tpulab.models.spec): a ``ModelSpec`` names the
     attention kind, the layer kinds and the cache-entry kind; without one
@@ -489,9 +495,11 @@ class ContinuousBatcher:
             tuple(np.zeros((lanes,), t)
                   for t in (np.int32, np.int32, bool, np.int32)),
             self._rep or self.pool.device)
+        #: the ONE dispatch in flight, un-fetched, at the top of a pass: a
+        #: decode block or a mixed round (its ``kind``)
         self._pending_block: Optional[Dict[str, Any]] = None
         self._step_ewma_s = 0.0   # per-scan-step device time estimate
-        self._block_fetched_t = 0.0   # return of the last decode block's fetch
+        self._block_fetched_t = 0.0   # return of the chain's last fetch
         # -- dispatch/sync accounting (tokens_per_dispatch telemetry and
         #    the host-syncs-per-request regression guard read these) ------
         self.decode_dispatches = 0   # device decode dispatches (any K)
@@ -523,6 +531,13 @@ class ContinuousBatcher:
         #: that spent the whole budget
         self.mixed_prompt_tokens = 0
         self.budget_rounds = 0
+        #: the rounds as members of the chain: the decode rows they carried
+        #: (a lane's token out of a round's weight pass), the rounds
+        #: enqueued before their predecessor was fetched, and those among
+        #: them whose predecessor was a round
+        self.mixed_decode_rows = 0
+        self.ahead_rounds = 0
+        self.rounds_after_round = 0
         #: sum of K over plain decode dispatches (K-blocks and single
         #: ticks): over ``dispatch_kinds["decode"]`` it is the mean block
         self.decode_block_steps = 0
@@ -544,13 +559,15 @@ class ContinuousBatcher:
         #: this is what is COMPUTED, an attention layer; for decode steps
         #: the two are one number)
         self.round_attn_pairs = 0
-        #: decode blocks enqueued before their predecessor was fetched
-        #: (_chain_block): over ``dispatch_kinds["decode"]`` the share of
-        #: blocks whose host turn the device did not wait for
+        #: decode blocks enqueued before their predecessor (a block or a
+        #: round) was fetched (_chain_block): over
+        #: ``dispatch_kinds["decode"]`` the share of blocks whose host turn
+        #: the device did not wait for
         self.ahead_blocks = 0
-        #: why :meth:`_chain_block` left a block without a successor before
-        #: its fetch, by cause, and the blocks linked after the commit
-        #: instead: consumed blocks = ``ahead_blocks`` + the sum of these
+        #: why :meth:`_chain_block` left a block or a round without a
+        #: successor before its fetch, by cause, and the blocks linked after
+        #: the commit instead: consumed blocks and rounds = ``ahead_blocks``
+        #: + ``ahead_rounds`` + the sum of these
         self.chain_breaks: Dict[str, int] = dict.fromkeys(
             self.BREAK_CAUSES, 0)
         self.late_links = 0
@@ -758,9 +775,10 @@ class ContinuousBatcher:
         and the pages behind the lane's new last row go back to the pool,
         less what the rest of its prompt will need at its widest.  Called
         where no program that writes the lane's rows can be in flight: at
-        the top of a pass and before a decode plan, for the lanes outside a
-        block dispatched ahead (whose lanes the chain holds short of their
-        boundary, :meth:`_chain_block`)."""
+        the top of a pass and before a decode plan, for the lanes outside
+        the block or round dispatched ahead (whose lanes the chain holds
+        short of their boundary: it breaks, cause ``compact``, where one
+        reaches it, :meth:`_chain_block`)."""
         w, ps, spec = self.plan.eva_window, self.page_size, self.model_spec
         chained = (self._pending_block["lane_reqs"]
                    if self._pending_block is not None else ())
@@ -817,10 +835,11 @@ class ContinuousBatcher:
     STAGES = ("admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
     #: the stages that are work (not waiting): what a turn is made of
     TURN_STAGES = ("admit", "plan", "dispatch", "commit", "emit")
-    #: why a decode block got no successor before its fetch
-    #: (:meth:`_chain_block`, in the order it tests them)
+    #: why a decode block or a mixed round got no successor before its
+    #: fetch (:meth:`_chain_block`)
     BREAK_CAUSES = ("k1", "shutdown_or_reclaim", "released", "completion",
-                    "joiner", "spec", "k", "pages", "compact")
+                    "joiner", "spec", "k", "pages", "compact", "host",
+                    "resumed", "stops")
     #: what opens a turn: a break cause that is a span name of its own, a
     #: mixed round's fetch, a single tick's; everything else is ``other``
     TURN_CAUSES = ("completion", "joiner", "round", "k", "pages",
@@ -1323,6 +1342,9 @@ class ContinuousBatcher:
                          "latent_tile": self.plan.latent_tile,
                          "mixed_prompt_tokens": self.mixed_prompt_tokens,
                          "budget_rounds": self.budget_rounds,
+                         "mixed_decode_rows": self.mixed_decode_rows,
+                         "ahead_rounds": self.ahead_rounds,
+                         "rounds_after_round": self.rounds_after_round,
                          "decode_block_steps": self.decode_block_steps,
                          "lane_work": {kind: dict(w) for kind, w
                                        in self.lane_work.items()},
@@ -1832,7 +1854,9 @@ class ContinuousBatcher:
                     self._eva_compact()
                 if self.ragged:
                     # ragged dispatch plan: pending prompts and decode
-                    # lanes advance together in ONE fused mixed round
+                    # lanes advance together in ONE fused mixed round (the
+                    # head of a chain here; with a dispatch in flight the
+                    # chain plans its own rounds as _tick consumes it)
                     prefilled = self._ragged_round(snapshot, jnp)
                 else:
                     for lane, req in enumerate(snapshot):
@@ -2242,137 +2266,226 @@ class ContinuousBatcher:
         return True
 
     def _ragged_round(self, snapshot, jnp) -> bool:
-        """One fused ragged mixed round (the unified dispatch plan):
-        prefilling lanes advance by a prompt chunk and — with no
-        dispatched-ahead block in flight — every decoding lane advances
+        """Head a chain with a fused ragged mixed round (the unified
+        dispatch plan): with nothing in flight and a prompt waiting,
+        prefilling lanes advance by a prompt chunk and every decoding lane
         by one token, all through ONE ``paged_mixed_step`` dispatch over
-        per-lane ``(q_len, kv_len)`` segments, packed by token.  The
-        round's prompt tokens never exceed ``_round_budget`` in total:
-        lanes that prefill at once share it, the oldest admission first;
-        what is left of a chunk, or a lane the budget did not reach,
-        waits a round (the oldest lane always advances, so none starves).
-        The program is keyed by :func:`round_width` of the tokens carried,
-        so the budget also bounds the programs: a power of two each from 2
-        up to the budget (nine at 512), each reached by a single prompt.
-        Lanes finishing their prompt emit their first token from the same
-        dispatch (no separate prefill program, no per-lane logits fetch).
-        With no pending prompts this is a no-op and the K-block decode
-        path owns the tick.  Returns True when any lane made progress."""
+        per-lane ``(q_len, kv_len)`` segments, packed by token
+        (:meth:`_dispatch_round`), fresh in every lane; its consume
+        (:meth:`_consume_round`) enqueues the dispatch behind it before it
+        fetches this one.  With a dispatch in flight this is a no-op: the
+        chain plans its own rounds (:meth:`_chain_block`: while a prompt
+        waits every dispatch is a round, and every round carries every
+        decoding lane from its predecessor's device carry).  With no
+        pending prompts it is a no-op too and the K-block decode path owns
+        the tick.  Returns True when any lane made progress."""
+        if self._pending_block is not None:
+            return False
         st = self._stages
         with stage(st, "plan"):
-            progressed = False
-            segs: List = []                     # (lane, req)
-            for lane, req in enumerate(snapshot):
-                if req is None or not req.pending_prompt or req.cancelled:
-                    continue
-                if req.kv_handle is not None:
-                    swapped = self._try_swap_in(req, len(req.pending_prompt),
-                                                lane)
-                    if swapped is True:
-                        progressed = True
-                        continue
-                    if swapped is False:
-                        continue         # page-starved: snapshot kept
-                if not req.pf_started and not self._ragged_prefill_start(
-                        req, lane):
-                    continue             # page-starved: retry next pass
-                segs.append((lane, req))
+            segs, progressed = self._round_segments(snapshot)
             if not segs:
                 return progressed
-            # decode lanes join the round only when no dispatched-ahead
-            # block is in flight (its device carry covers those lanes)
-            decode_parts: List = []
-            if self._pending_block is None:
-                for lane, req in enumerate(snapshot):
-                    if (req is None or req.pending_prompt or req.cancelled
-                            or not req.tokens_out):
-                        continue
-                    need = self._rows(req, req.length) // self.page_size + 1
-                    new: List[int] = []
-                    while len(req.pages) < need:
-                        page = self._alloc_page()
-                        if page is None:
-                            break
-                        req.pages.append(page)
-                        new.append(page)
-                    if len(req.pages) < need:
-                        for _ in new:    # starved: return the partial take
-                            self.pool.release_pages([req.pages.pop()])
-                        continue
-                    decode_parts.append((lane, req))
+            decoding = [(lane, req) for lane, req in enumerate(snapshot)
+                        if req is not None and not req.pending_prompt
+                        and not req.cancelled and req.tokens_out]
+            rows = self._round_rows(decoding)
         with stage(st, "dispatch"):
-            with part(st, "dispatch.arrays"):
-                left = self._round_budget
-                chunks: Dict[int, int] = {}
-                for lane, req in sorted(segs, key=lambda s: s[1].admit_seq):
-                    # a chunk ends where its lane's window does: the
-                    # compaction comes before the next row is written
-                    chunks[lane] = min(len(req.pending_prompt), left,
-                                       self._boundary(req) - req.length)
-                    left -= chunks[lane]
-                segs = [(lane, req) for lane, req in segs if chunks[lane]]
-                if self.state is not None:
-                    self.zero_starts += sum(req.length == 0
-                                            for _, req in segs)
-                b = self.lanes
-                toks, row_lane, row_off, q_lens = pack_round(
-                    b, {lane: req.pending_prompt[:chunks[lane]]
-                        for lane, req in segs},
-                    {lane: req.tokens_out[-1] for lane, req in decode_parts})
-                tables = np.zeros((b, self.max_pages), np.int32)
-                kv_lens = np.zeros((b,), np.int32)
-                temps = np.zeros((b,), np.float32)
-                seeds = np.zeros((b, 2), np.uint32)
-                host_lanes: List[int] = []
-                lane_reqs: Dict[int, _PagedRequest] = {}
-                for lane, req in segs + decode_parts:
-                    lane_reqs[lane] = req
-                    kv_lens[lane] = req.length + q_lens[lane]
-                    tables[lane, :len(req.pages)] = req.pages
-                    sp = req.sampling
-                    # a prompt's pick counts only off its final chunk,
-                    # where it IS the first token (a resumed request made
-                    # it before)
-                    if sp.temperature > 0.0 and (not req.pending_prompt or (
-                            q_lens[lane] == len(req.pending_prompt)
-                            and not req.resumed)):
-                        if sp.device:
-                            temps[lane] = sp.temperature
-                            seeds[lane] = (sp.seed & 0xFFFFFFFF,
-                                           (sp.seed >> 32) & 0xFFFFFFFF)
-                        else:
-                            host_lanes.append(lane)
-                buf = pack_words(self.programs.fields["round"], dict(
-                    tables=tables, q_lens=q_lens, kv_lens=kv_lens,
-                    temps=temps, seeds=seeds,
-                    rows=np.stack([toks, row_lane, row_off])))
-            if decode_parts:
-                # decode lanes advance one tick this round — same fault site
-                chaos.trip("engine.step")
-            t0 = _time.perf_counter()
-            with part(st, "dispatch.put"):
-                packed = self._put(buf)
-            with part(st, "dispatch.call"):
-                out, last_dev, self._kv_state = self.programs.mixed(
-                    self.params, self._kv_state, packed)
-                # the program is on the device's queue: the turn ends here,
-                # the results' copy to the host starts behind it
-                ticket = st.launched()
-                out.copy_to_host_async()
-            carried = self._round_budget - left
-            st.note(program="paged_mixed_step", k=1, lanes=len(lane_reqs),
-                    rows=len(toks), prompt_tokens=carried,
-                    ahead=int(self._pending_block is not None))
-            self.decode_dispatches += 1
-            self._note_dispatch("mixed")
-            self.mixed_rows += len(toks)
-            self.mixed_tokens += int(q_lens.sum())
-            self.mixed_prompt_tokens += carried
-            self.budget_rounds += left == 0
-            self.mixed_attn_rows += ((len(toks) - b) * len(segs)
-                                     + len(decode_parts))
+            stash = self._dispatch_round(segs, rows)
+        self._consume_round(stash, jnp)
+        return True
+
+    def _round_segments(self, snapshot):
+        """The lanes of ``snapshot`` whose prompt has tokens a round can
+        carry, ``[(lane, req), ...]``, their pages secured (a snapshot of a
+        preempted lane swapped back in instead; a page-starved lane waits
+        a pass), and whether a swap-in made progress."""
+        progressed = False
+        segs: List = []
+        for lane, req in enumerate(snapshot):
+            if req is None or not req.pending_prompt or req.cancelled:
+                continue
+            if req.kv_handle is not None:
+                swapped = self._try_swap_in(req, len(req.pending_prompt),
+                                            lane)
+                if swapped is True:
+                    progressed = True
+                    continue
+                if swapped is False:
+                    continue         # page-starved: snapshot kept
+            if not req.pf_started and not self._ragged_prefill_start(
+                    req, lane):
+                continue             # page-starved: retry next pass
+            segs.append((lane, req))
+        return segs, progressed
+
+    def _round_rows(self, decoding, lag=None):
+        """The lanes of ``decoding`` a round can carry as decode rows: those
+        whose next row's page is reserved (a one-step block's reservation,
+        :meth:`_reserve_block_pages`, ``lag`` as there; a starved lane is
+        left out)."""
+        return [(lane, req) for lane, req, _new
+                in self._reserve_block_pages(decoding, 1, lag)[1]]
+
+    @staticmethod
+    def _host_sampled(req: _PagedRequest) -> bool:
+        """``top_k`` / ``top_p`` / host-PRNG temperature: the pick needs
+        the logits row on the host."""
+        return req.sampling.temperature > 0.0 and not req.sampling.device
+
+    def _dispatch_round(self, segs, decode_parts, chain=None):
+        """Issue one mixed round (async, no host sync), inside the caller's
+        ``dispatch`` stage: ``segs`` advance by a prompt chunk,
+        ``decode_parts`` by one token.  The round's prompt tokens never
+        exceed ``_round_budget`` in total: lanes that prefill at once share
+        it, the oldest admission first; what is left of a chunk, or a lane
+        the budget did not reach, waits a round (the oldest lane always
+        advances, so none starves).  The program is keyed by
+        :func:`round_width` of the tokens carried, so the budget also
+        bounds the programs: a power of two each from 2 up to the budget
+        (nine at 512), each reached by a single prompt, with or without a
+        predecessor.
+
+        ``chain`` is the dispatch in flight this round goes behind (a
+        decode block or a round, un-fetched): the decode rows are then not
+        ``fresh`` and take token, length, budget and liveness from its
+        device carry; a chain's first round sends all of it beside
+        ``_no_carry``, exactly as a chain's first block does.
+
+        What the round changes on the host that needs no result is
+        committed HERE: a chunk lane's ``length`` and ``pending_prompt``,
+        and which lanes finish their prompt (their first token is a row of
+        ``out``).  Tokens, logprobs, TTFT and the expert counters wait for
+        the fetch (:meth:`_consume_round`)."""
+        st = self._stages
+        with part(st, "dispatch.arrays"):
+            left = self._round_budget
+            chunks: Dict[int, int] = {}
+            for lane, req in sorted(segs, key=lambda s: s[1].admit_seq):
+                # a chunk ends where its lane's window does: the
+                # compaction comes before the next row is written
+                chunks[lane] = min(len(req.pending_prompt), left,
+                                   self._boundary(req) - req.length)
+                left -= chunks[lane]
+            segs = [(lane, req) for lane, req in segs if chunks[lane]]
+            if self.state is not None:
+                self.zero_starts += sum(req.length == 0 for _, req in segs)
+            b = self.lanes
+            toks, row_lane, row_off, q_lens = pack_round(
+                b, {lane: req.pending_prompt[:chunks[lane]]
+                    for lane, req in segs},
+                {lane: 0 if chain else req.tokens_out[-1]
+                 for lane, req in decode_parts})
+            tables = np.zeros((b, self.max_pages), np.int32)
+            kv_lens = np.zeros((b,), np.int32)
+            temps = np.zeros((b,), np.float32)
+            seeds = np.zeros((b, 2), np.uint32)
+            fresh = np.ones((b,), bool)
+            rem = np.zeros((b,), np.int32)
+            stops = np.full((b, ROUND_STOPS), -1, np.int32)
+            host_lanes: List[int] = []
+            lane_reqs: Dict[int, _PagedRequest] = {}
+            for lane, req in segs + decode_parts:
+                lane_reqs[lane] = req
+                kv_lens[lane] = req.length + q_lens[lane]
+                tables[lane, :len(req.pages)] = req.pages
+                if req.pending_prompt and q_lens[lane] < len(
+                        req.pending_prompt):
+                    continue        # mid-prompt: no pick, no budget
+                # the tokens it still wants, this round's included
+                rem[lane] = req.steps - len(req.tokens_out)
+                ids = sorted(req.stop_tokens)[:ROUND_STOPS]
+                stops[lane, :len(ids)] = ids
+                # a prompt's pick counts only off its final chunk, where it
+                # IS the first token (a resumed request made it before)
+                sp = req.sampling
+                if sp.temperature > 0.0 and not (req.pending_prompt
+                                                 and req.resumed):
+                    if sp.device:
+                        temps[lane] = sp.temperature
+                        seeds[lane] = (sp.seed & 0xFFFFFFFF,
+                                       (sp.seed >> 32) & 0xFFFFFFFF)
+                    else:
+                        host_lanes.append(lane)
+            if chain is not None:
+                fresh[[lane for lane, _ in decode_parts]] = False
+            buf = pack_words(self.programs.fields["round"], dict(
+                tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+                temps=temps, seeds=seeds, fresh=fresh, rem=rem,
+                stops=stops, rows=np.stack([toks, row_lane, row_off])))
+        if decode_parts:
+            # decode lanes advance one tick this round — same fault site
+            chaos.trip("engine.step")
+        t0 = _time.perf_counter()
+        with part(st, "dispatch.put"):
+            packed = self._put(buf)
+        with part(st, "dispatch.call"):
+            (out, last_dev, len_f, tok_f, live_f, rem_f,
+             self._kv_state) = self.programs.mixed(
+                self.params, self._kv_state, packed,
+                chain["carry"] if chain else self._no_carry)
+            # the program is on the device's queue: the turn ends here,
+            # the results' copy to the host starts behind it
+            ticket = st.launched()
+            out.copy_to_host_async()
+        carried = self._round_budget - left
+        st.note(program="paged_mixed_step", k=1, lanes=len(lane_reqs),
+                rows=len(toks), prompt_tokens=carried,
+                decode_rows=len(decode_parts), ahead=int(chain is not None))
+        self.decode_dispatches += 1
+        self._note_dispatch("mixed")
+        self.mixed_rows += len(toks)
+        self.mixed_tokens += int(q_lens.sum())
+        self.mixed_prompt_tokens += carried
+        self.budget_rounds += left == 0
+        self.mixed_attn_rows += ((len(toks) - b) * len(segs)
+                                 + len(decode_parts))
+        self.mixed_decode_rows += len(decode_parts)
+        if chain is not None:
+            self.ahead_rounds += 1
+            self.rounds_after_round += chain["kind"] == "round"
+        firsts: List = []      # (lane, req, its pick was made before)
+        with self._cv:
+            for lane, req in segs:
+                c = chunks[lane]
+                self._note_rows("round", req.length, c)
+                req.length += c
+                del req.pending_prompt[:c]
+                self._fl_pages(req)
+                if not req.pending_prompt:
+                    firsts.append((lane, req, req.resumed))
+                    req.resumed = False
+                    req.pf_started = False
+        # once this round is done these lanes decode: its decode rows, a
+        # position and a token behind on the host until its fetch, and the
+        # lanes whose prompt ended here, a token behind
+        after = dict(decode_parts)
+        after.update((lane, req) for lane, req, _ in firsts)
+        lag = {lane: (1, 1) for lane, _ in decode_parts}
+        lag.update((lane, (0, 1)) for lane, _req, _ in firsts)
+        return {"kind": "round", "k": 1, "lane_reqs": lane_reqs,
+                "out": out, "last": last_dev,
+                "carry": (len_f, tok_f, live_f, rem_f), "host": None,
+                "firsts": firsts, "decodes": decode_parts,
+                "host_lanes": host_lanes, "next": after, "ahead": lag,
+                "t0": t0, "ticket": ticket}
+
+    def _consume_round(self, stash, jnp) -> bool:
+        """Fetch a dispatched round and commit what needed its result:
+        the first token of each lane whose prompt ended in it, a token for
+        each decode row.  The dispatch behind it is enqueued first where
+        :meth:`_chain_block` allows (a round while a prompt still waits,
+        else a K-block), so the device computes it while the host fetches,
+        commits and emits this one.  A lane released since the dispatch
+        (cancel, deadline sweep, preemption, a stop token in the dispatch
+        before, which left its row here dead) has its pick discarded."""
+        st = self._stages
+        self._pending_block, why = self._chain_block(stash, jnp, ahead=1)
+        if why is not None:
+            self.chain_breaks[why] += 1
+        host_lanes, lane_reqs = stash["host_lanes"], stash["lane_reqs"]
         with stage(st, "fetch"):
-            res = self._results(out)
+            res = self._results(stash["out"])
             next_tokens = res["tokens"].copy()
             logprobs_arr = res["logprobs"].copy()
             self.decode_host_syncs += 1
@@ -2380,8 +2493,8 @@ class ContinuousBatcher:
             if host_lanes:
                 # fetch ONLY the host-sampled rows (same shape discipline —
                 # and PRNG rule — as _tick_single)
-                rows = self._fetch(
-                    last_dev[self._put(np.asarray(host_lanes, np.int32))])
+                rows = self._fetch(stash["last"][
+                    self._put(np.asarray(host_lanes, np.int32))])
                 self.decode_host_syncs += 1
                 for i, lane in enumerate(host_lanes):
                     req = lane_reqs[lane]
@@ -2392,32 +2505,27 @@ class ContinuousBatcher:
                         logprobs_arr[lane] = float(
                             row[next_tokens[lane]]
                             - np.log(np.exp(row).sum()))
-            st.landed(ticket, "round", lanes=len(lane_reqs))
+            st.landed(stash["ticket"],
+                      why if why in self.TURN_CAUSES else "round",
+                      lanes=len(lane_reqs))
         now = _time.perf_counter()
-        self._step_ewma_s = (0.8 * self._step_ewma_s + 0.2 * (now - t0)
-                             if self._step_ewma_s else now - t0)
+        # a round enqueued ahead started when its predecessor ended, which
+        # the host saw as the previous fetch's return
+        step_s = now - max(stash["t0"], self._block_fetched_t)
+        self._block_fetched_t = now
+        self._step_ewma_s = (0.8 * self._step_ewma_s + 0.2 * step_s
+                             if self._step_ewma_s else step_s)
         emits: List = []
         completed: List = []
         with stage(st, "commit"), self._cv:
-            for lane, req in segs:
-                if self._active[lane] is not req or req.cancelled:
+            for lane, req, was_resumed in stash["firsts"]:
+                if (self._active[lane] is not req or req.cancelled
+                        or req.pending_prompt):
                     continue
-                c = chunks[lane]
-                self._note_rows("round", req.length, c)
-                req.length += c
-                del req.pending_prompt[:c]
-                self._fl_pages(req)
-                progressed = True
-                if req.pending_prompt:
-                    continue         # mid-prompt: nothing emitted yet
-                t_total = req.length
-                was_resumed = req.resumed
-                if was_resumed:
-                    # the pick already happened before preemption/on the
-                    # prefill replica: discard this round's (stateless)
-                    # sample, just continue decoding
-                    req.resumed = False
-                else:
+                # a resumed request's pick happened before preemption / on
+                # the prefill replica: this round's (stateless) sample is
+                # discarded, the lane just continues decoding
+                if not was_resumed:
                     tok = int(next_tokens[lane])
                     req.tokens_out.append(tok)
                     self.tokens_generated += 1
@@ -2427,7 +2535,7 @@ class ContinuousBatcher:
                         req.logprobs_out.append(lp)
                     emits.append((req, tok, len(req.tokens_out) - 1, lp))
                 self._span("prefill", lane, req.pf_t0, now - req.pf_t0,
-                           req, prompt_tokens=t_total,
+                           req, prompt_tokens=req.length,
                            cached_pages=req.pf_shared)
                 req.chunk_t0 = now
                 req.chunk_start = len(req.tokens_out)
@@ -2438,14 +2546,17 @@ class ContinuousBatcher:
                     self.ttfts += 1
                     if self.metrics is not None:
                         self.metrics.observe_ttft(now - req.t_submit)
-                if self.prefix_cache is not None and not was_resumed:
-                    self.prefix_cache.count_lookup(req.pf_shared,
-                                                   len(req.pf_digests))
-                    self.prefix_cache.insert(
-                        req.pf_digests, req.pages[:len(req.pf_digests)])
-                req.pf_started = False
-            for lane, req in decode_parts:
-                if self._active[lane] is not req or req.cancelled:
+                    if self.prefix_cache is not None:
+                        self.prefix_cache.count_lookup(req.pf_shared,
+                                                       len(req.pf_digests))
+                        self.prefix_cache.insert(
+                            req.pf_digests, req.pages[:len(req.pf_digests)])
+                if req.finished():       # a steps == 1 request
+                    self._release_lane_locked(lane, req)
+                    completed.append(req)
+            for lane, req in stash["decodes"]:
+                if (self._active[lane] is not req or req.cancelled
+                        or req.pending_prompt):
                     continue
                 self._probe_countdown_locked(req)
                 self._note_second_token(req, now)
@@ -2454,7 +2565,6 @@ class ContinuousBatcher:
                 tok = int(next_tokens[lane])
                 req.tokens_out.append(tok)
                 self.tokens_generated += 1
-                progressed = True
                 dt = (now - req.t_last) if req.t_last is not None else None
                 if self.metrics is not None and dt is not None:
                     self.metrics.observe_itl(dt)
@@ -2475,7 +2585,7 @@ class ContinuousBatcher:
             with stage(st, "admit"):
                 self._admit_locked()
         self._deliver(emits, completed)
-        return progressed or bool(segs)
+        return True
 
     def _discard_handle(self, req: _PagedRequest) -> None:
         """Drop a never-to-be-restored snapshot (cancel/expiry while
@@ -2557,10 +2667,11 @@ class ContinuousBatcher:
         est = self._step_ewma_s or 0.005
         return min(1.0, max(0.05, 2.0 * self.decode_block * est))
 
-    def _pick_block_k(self, decode_lanes, ahead: int = 0) -> int:
-        """Adaptive fused-decode block size for this dispatch.  ``ahead``
-        is the steps of a block still in flight that the lanes' step
-        budgets do not show yet (see :meth:`_chain_block`).
+    def _pick_block_k(self, decode_lanes, lag=None) -> int:
+        """Adaptive fused-decode block size for this dispatch.  ``lag``
+        (lane -> (positions, tokens)) is what a dispatch still in flight
+        gives a lane that its committed length and step budget do not show
+        yet (see :meth:`_chain_block`).
 
         - any host-sampled (``top_k``/``top_p``) lane -> 1: its per-token
           pick needs the logits row on host every tick;
@@ -2580,7 +2691,8 @@ class ContinuousBatcher:
         want = kmax
         streaming = False
         max_rem = 1
-        for _lane, req in decode_lanes:
+        lag = lag or {}
+        for lane, req in decode_lanes:
             sp = req.sampling
             if sp.temperature > 0.0 and not sp.device:
                 return 1
@@ -2592,8 +2704,8 @@ class ContinuousBatcher:
                 # hook is a durable checkpoint sink, not an interactive
                 # consumer — never let it drag the whole block to K<=2
                 streaming = True
-            max_rem = max(max_rem,
-                          req.steps - len(req.tokens_out) - ahead)
+            max_rem = max(max_rem, req.steps - len(req.tokens_out)
+                          - lag.get(lane, (0, 0))[1])
         if streaming and not self._queue:
             want = min(want, 2)
         cover = next((m for m in self.BLOCK_K_MENU if m >= max_rem),
@@ -2601,7 +2713,7 @@ class ContinuousBatcher:
         k = min(want, cover)
         return max(m for m in self.BLOCK_K_MENU if m <= k)
 
-    def _reserve_block_pages(self, decode_lanes, k: int, ahead: int = 0):
+    def _reserve_block_pages(self, decode_lanes, k: int, lag=None):
         """Pre-allocate every page the next K appends will write, per lane.
 
         Decode step j writes K/V at position ``length + j`` — the device
@@ -2614,19 +2726,21 @@ class ContinuousBatcher:
         every participating lane can cover (snapped down onto
         BLOCK_K_MENU, surplus pages returned); a lane that cannot cover
         even one append skips this block entirely (same as the old
-        per-tick starvation skip).  ``ahead`` is the steps of a block
-        still in flight: the lane's committed length and step budget lag
-        the block being reserved by that many, so it writes from
-        ``length + ahead`` (a lane then holds pages for at most two
-        blocks past its committed length).  Returns ``(k_eff, [(lane,
-        req, new_pages), ...])``.
+        per-tick starvation skip).  ``lag`` (lane -> (positions,
+        tokens)) is what a dispatch still in flight gives a lane: its
+        committed length and step budget lag the block being reserved by
+        that much, so it writes from ``length + positions`` (a lane then
+        holds pages for at most two blocks past its committed length).
+        Returns ``(k_eff, [(lane, req, new_pages), ...])``.
         """
         ps = self.page_size
         parts = []
         cap = k
+        lag = lag or {}
         for lane, req in decode_lanes:
+            ahead, ahead_tok = lag.get(lane, (0, 0))
             base = req.length + ahead
-            rem = req.steps - len(req.tokens_out) - ahead
+            rem = req.steps - len(req.tokens_out) - ahead_tok
             # a lane stops where its EVA window ends (the device masks it
             # there): the block writes no row past the boundary
             appends_want = max(1, min(k, rem, self._boundary(req) - base))
@@ -2652,9 +2766,10 @@ class ContinuousBatcher:
         k_eff = max(m for m in self.BLOCK_K_MENU if m <= max(1, cap))
         if k_eff < k:
             # shrunk block: give back pages past the new write horizon
-            for _lane, req, new in parts:
+            for lane, req, new in parts:
+                ahead, ahead_tok = lag.get(lane, (0, 0))
                 base = req.length + ahead
-                rem = req.steps - len(req.tokens_out) - ahead
+                rem = req.steps - len(req.tokens_out) - ahead_tok
                 want = max(1, min(k_eff, rem, self._boundary(req) - base))
                 need = (self._rows(req, base) + want - 1) // ps + 1
                 while len(req.pages) > need and new:
@@ -2816,13 +2931,16 @@ class ContinuousBatcher:
         return {"k": k, "parts": parts, "mode": "plain"}
 
     def _tick(self, snapshot, jnp) -> bool:
-        """One scheduler decode pass: consume the dispatched-ahead block
-        if one is in flight, else plan + dispatch + consume.  Returns True
+        """One scheduler decode pass: consume the dispatch in flight (a
+        decode block or a mixed round, which enqueues its successor first)
+        if there is one, else plan + dispatch + consume.  Returns True
         when any lane made progress, False when every decode lane is
         starved (pool pressure) or idle."""
         st = self._stages
         if self._pending_block is not None:
             stash, self._pending_block = self._pending_block, None
+            if stash["kind"] == "round":
+                return self._consume_round(stash, jnp)
             return self._consume_block(stash, jnp)
         with stage(st, "plan"):
             plan = self._plan_decode(snapshot)
@@ -2856,16 +2974,17 @@ class ContinuousBatcher:
         """Issue one K-step fused decode dispatch (async — no host sync),
         inside the caller's ``dispatch`` stage.
 
-        ``carry``/``host`` chain a follow-up block from a previous one's
-        device-resident final state (dispatch-ahead overlap): the block
-        table is rebuilt host-side either way (new pages may have been
-        reserved) and travels with ``host``'s sampling and stop arrays in
-        the block's ONE buffer, whose ``fresh`` flags are off, so
-        lengths/tokens/live/steps-remaining are the carry's and chaining
-        costs no round trip.  A chain's first block sends all of it, every
-        lane ``fresh``, beside a carry nobody reads: the same program.
-        ``ahead`` (the predecessor's K where it is not fetched yet) only
-        goes onto the stage's span.
+        ``carry`` chains the block behind a dispatch in flight (a block or
+        a mixed round) from its device-resident final state
+        (dispatch-ahead overlap): the block table is rebuilt host-side
+        either way (new pages may have been reserved) and travels with the
+        sampling and stop arrays (``host``: a predecessor block's, reused;
+        None: built from the lanes) in the block's ONE buffer, whose
+        ``fresh`` flags are off, so lengths/tokens/live/steps-remaining
+        are the carry's and chaining costs no round trip.  A chain's first
+        block sends all of it, every lane ``fresh``, beside a carry nobody
+        reads: the same program.  ``ahead`` (the predecessor's steps where
+        it is not fetched yet) counts the block as enqueued ahead.
         """
         clock = self._stages
         with part(clock, "dispatch.arrays"):
@@ -2879,6 +2998,12 @@ class ContinuousBatcher:
             tokens = np.zeros((b,), np.int32)
             active = np.zeros((b,), bool)
             rem = np.zeros((b,), np.int32)
+            if carry is None:
+                for lane, req, _new in parts:
+                    lengths[lane] = req.length
+                    tokens[lane] = req.tokens_out[-1]
+                    active[lane] = True
+                    rem[lane] = req.steps - len(req.tokens_out)
             if host is None:
                 temps = np.zeros((b,), np.float32)
                 seeds = np.zeros((b, 2), np.uint32)   # (lo, hi) words
@@ -2888,10 +3013,6 @@ class ContinuousBatcher:
                          else 1)
                 stops = np.full((b, width), -1, np.int32)  # ids >= 0: pad safe
                 for lane, req, _new in parts:
-                    lengths[lane] = req.length
-                    tokens[lane] = req.tokens_out[-1]
-                    active[lane] = True
-                    rem[lane] = req.steps - len(req.tokens_out)
                     sp = req.sampling
                     if sp.device and sp.temperature > 0.0:
                         temps[lane] = sp.temperature
@@ -2905,7 +3026,7 @@ class ContinuousBatcher:
             buf = pack_words(self.programs.fields["block"], dict(
                 tables=tables, lengths=lengths, tokens=tokens, active=active,
                 temps=temps, seeds=seeds, rem=rem, stops=stops,
-                fresh=np.full((b,), host is None)))
+                fresh=np.full((b,), carry is None)))
         # chaos: decode fault site — tripped once per DECODE TICK (k times
         # per block), so a deterministic schedule written against
         # per-token serving (error@N, per-tick delays) keeps its meaning
@@ -2928,42 +3049,65 @@ class ContinuousBatcher:
                    ahead=int(ahead > 0))
         self.decode_dispatches += 1
         self.decode_block_steps += k
+        self.ahead_blocks += ahead > 0
         self._note_dispatch("decode")
-        return {"k": k, "lane_reqs": lane_reqs, "out": out,
+        return {"kind": "block", "k": k, "lane_reqs": lane_reqs, "out": out,
                 "carry": (len_f, tok_f, live_f, rem_f),
-                "host": (temps, seeds, stops), "t0": t0, "ticket": ticket}
+                "host": (temps, seeds, stops), "next": lane_reqs,
+                "ahead": dict.fromkeys(lane_reqs, (k, k)), "t0": t0,
+                "ticket": ticket}
 
     def _chain_block(self, stash, jnp, ahead: int):
-        """Enqueue the block that follows ``stash`` from its
-        device-resident carry: ``(block, None)``, or ``(None, cause)``
-        where the chain must break, ``cause`` one of ``BREAK_CAUSES``.
+        """Enqueue the dispatch that follows ``stash`` (a decode block or
+        a mixed round) from its device-resident carry: ``(dispatch,
+        None)``, or ``(None, cause)`` where the chain must break, ``cause``
+        one of ``BREAK_CAUSES``.
 
-        Called twice by :meth:`_consume_block`.  BEFORE the fetch
-        (``ahead`` = the block's K: its tokens are not committed, so every
-        lane's length and step budget lag by K) block N+1 goes on the
-        device's queue behind block N, and the fetch, commit and emit of N
+        **The rule for the successor**: while any admitted request has
+        prompt tokens that the dispatches so far do not carry, it is a
+        mixed round (:meth:`_dispatch_round`) with the budget's chunks,
+        oldest admission first, and EVERY lane that decodes once ``stash``
+        is done as a row: the lanes of a block, the decode rows of a round,
+        and the lanes whose prompt ends in that round (their first token is
+        still on the device).  Such a round IS the decoding lanes' step: no
+        block is owed behind it.  Otherwise it is the K-block
+        :meth:`_pick_block_k` picks.
+
+        Called by :meth:`_consume_block` and :meth:`_consume_round` BEFORE
+        the fetch (``ahead`` = the dispatch's steps, 1 for a round: its
+        tokens are not committed, so the lanes' lengths and step budgets
+        lag by ``stash["ahead"]``): the successor goes on the device's
+        queue behind ``stash``, and the fetch, commit and emit of ``stash``
         overlap it.  Everything the decision needs the host has known
-        since it enqueued N: the lane set is still the block's (cancel,
+        since it enqueued ``stash``: the lane set is still its own (cancel,
         deadline sweep and preemption are host events; a queued request
-        is admitted first, as the commit would), no lane is waiting to
-        join, the same adaptive K is still the right choice,
-        and no lane's step budget ends inside N — a completion the host
-        can foresee breaks the chain, so the freed lane is re-admitted
-        before the next dispatch, never a block later.  A stop token it
-        cannot foresee ends a lane with N+1 already in flight: the carry's
-        live mask is false for it there (its writes go to the scratch
-        page) and the consume discards a lane that is no longer the
-        block's.  AFTER the commit (``ahead`` = 0) the same rule gives the
-        old order, for a block the first call held back and the commit
-        cleared (a deadline or a queue the K policy saw, pages that came
-        home).  Never shrinks K: pages reserved for a refused block stay
+        is admitted first, as the commit would), no decoding lane stands
+        outside it, and no lane's step budget ends inside it — a
+        completion the host can foresee breaks the chain, so the freed
+        lane is re-admitted before the next dispatch, never one later.  A
+        stop token it cannot foresee ends a lane with the successor
+        already in flight: the carry's live mask is false for it there (a
+        block's writes go to the scratch page, a round holds no row for
+        it) and the consume discards a lane that is no longer the
+        dispatch's.  The chain also breaks where a lane stands at its EVA
+        window's end (the compaction goes where no writer is in flight),
+        where a lane's pick is made on the host (``host``: its token is
+        not in the carry), where a round's pick was a resumed request's
+        (``resumed``: discarded, the carry's token is not the lane's) and
+        where a lane has more stop ids than a round's buffer holds
+        (``stops``).  By :meth:`_consume_block` once more AFTER the commit
+        (``ahead`` = 0): the same rule gives the old order, for a block
+        the first call held back and the commit cleared (a deadline or a
+        queue the K policy saw, pages that came home) while no prompt
+        waits.  Never shrinks K: pages reserved for a refused block stay
         on the lanes for the next regular plan (bounded hoard: two blocks
         a lane)."""
         st = self._stages
-        k = stash["k"]
-        if k <= 1:
+        k, after_round = stash["k"], stash["kind"] == "round"
+        if k <= 1 and not after_round:
             return None, "k1"
-        lanes_now = list(stash["lane_reqs"].items())
+        lag = stash["ahead"] if ahead else {}
+        lanes_now = list(stash["next"].items())
         with stage(st, "plan"):
             with self._cv:
                 if self._shutdown or self._hbm_reclaim_bytes:
@@ -2981,56 +3125,95 @@ class ContinuousBatcher:
                     if self._active[lane] is not req or req.cancelled:
                         return None, "released"
                 for lane, req in lanes_now:    # about to complete
-                    if req.steps - len(req.tokens_out) <= ahead:
+                    if (req.steps - len(req.tokens_out)
+                            <= lag.get(lane, (0, 0))[1]):
                         return None, "completion"
                 for lane, req in lanes_now:
                     # may have reached the end of its EVA window in the
-                    # block(s) in flight: its rows are compacted before
+                    # dispatch in flight: its rows are compacted before
                     # another is written
-                    if req.length + ahead >= self._boundary(req):
+                    if (req.length + lag.get(lane, (0, 0))[0]
+                            >= self._boundary(req)):
                         return None, "compact"
-                # a lane that finished its prompt while this chain ran (its
-                # first token is out) is in no block of the chain: chaining
-                # on would leave it without a step until a lane of the
-                # chain completes, hundreds of steps at long outputs
-                if any(r is not None and lane not in stash["lane_reqs"]
+                waiting = [r for r in self._active
+                           if r is not None and r.pending_prompt
+                           and not r.cancelled] if self.ragged else []
+                # a decoding lane outside the chain (swapped back in, or
+                # left out of a round for want of a page; soon: a snapshot
+                # about to be swapped in): chaining on would leave it
+                # without a step until a lane of the chain completes
+                if any(r is not None and lane not in stash["next"]
                        and not r.pending_prompt and r.tokens_out
                        and not r.cancelled
-                       for lane, r in enumerate(self._active)):
+                       for lane, r in enumerate(self._active)) or any(
+                           r.kv_handle is not None for r in waiting):
                     return None, "joiner"
+                # a prompt that stands at its window's end waits for its
+                # compaction, which goes where no writer is in flight
+                if any(r.pf_started and r.length >= self._boundary(r)
+                       for r in waiting):
+                    return None, "compact"
+                snapshot = list(self._active)
+            if after_round and ahead:
+                # what a round's carry cannot hold of a lane
+                if any(self._host_sampled(r) for _, r in lanes_now):
+                    return None, "host"
+                if any(resumed for _l, _r, resumed in stash["firsts"]):
+                    return None, "resumed"
+                if any(len(r.stop_tokens) > ROUND_STOPS
+                       for _, r in lanes_now):
+                    return None, "stops"
             # a lane that re-armed speculation (a probe countdown expired)
             # must flow back through _plan_decode — a plain chain here
             # would starve the probe forever
             if (self._spec is not None
                     and all(self._spec_eligible(r) for _, r in lanes_now)):
                 return None, "spec"
-            if self._pick_block_k(lanes_now, ahead) != k:
+            if waiting:
+                if not ahead:
+                    # a link after the commit is a block's: the next pass
+                    # heads a chain with the round
+                    return None, "joiner"
+                segs, _ = self._round_segments(snapshot)
+                if segs:
+                    # a round behind an un-fetched predecessor carries
+                    # every decoding lane or none
+                    rows = self._round_rows(lanes_now, lag)
+                    if len(rows) != len(lanes_now):
+                        return None, "pages"
+                    with stage(st, "dispatch"):
+                        return self._dispatch_round(segs, rows,
+                                                    chain=stash), None
+            k2 = self._pick_block_k(lanes_now, lag)
+            if after_round:
+                if k2 <= 1:
+                    return None, "k1"
+            elif k2 != k:
                 return None, "k"
-            k2, parts = self._reserve_block_pages(lanes_now, k, ahead)
-            if k2 != k or len(parts) != len(lanes_now):
+            k3, parts = self._reserve_block_pages(lanes_now, k2, lag)
+            if k3 != k2 or len(parts) != len(lanes_now):
                 return None, "pages"
         with stage(st, "dispatch"):
-            return self._dispatch_block(parts, k, jnp, carry=stash["carry"],
-                                        host=stash["host"],
-                                        ahead=ahead), None
+            return self._dispatch_block(
+                parts, k2, jnp, carry=stash["carry"], host=stash["host"],
+                ahead=ahead), None
 
     def _consume_block(self, stash, jnp) -> bool:
         """Fetch a dispatched block (ONE host sync for up to K tokens per
         lane) and unpack it through the per-token emit/trace/metrics
-        path.  Block N+1 is enqueued first where :meth:`_chain_block`
-        allows, so the device computes it while the host fetches,
-        commits and emits block N; two blocks are un-fetched only inside
-        this method (one at the top of ``_run``'s loop, as ever).
-        Correctness never depends on the chain: a request released
-        between dispatch and consume has its block discarded below, and
-        its stale device writes only touch positions a new page owner
-        rewrites before reading."""
+        path.  The dispatch behind it (block N+1, or a mixed round where a
+        prompt waits) is enqueued first where :meth:`_chain_block` allows,
+        so the device computes it while the host fetches, commits and
+        emits block N; two dispatches are un-fetched only inside this
+        method and :meth:`_consume_round` (one at the top of ``_run``'s
+        loop, as ever).  Correctness never depends on the chain: a request
+        released between dispatch and consume has its block discarded
+        below, and its stale device writes only touch positions a new page
+        owner rewrites before reading."""
         st = self._stages
         k = stash["k"]
         self._pending_block, why = self._chain_block(stash, jnp, ahead=k)
-        if why is None:
-            self.ahead_blocks += 1
-        else:
+        if why is not None:
             self.chain_breaks[why] += 1
         with stage(st, "fetch"):
             res = self._results(stash["out"], k)
@@ -3049,9 +3232,11 @@ class ContinuousBatcher:
         completed: List = []
         with stage(st, "commit"), self._cv:
             for lane, req in stash["lane_reqs"].items():
-                if self._active[lane] is not req or req.cancelled:
+                if (self._active[lane] is not req or req.cancelled
+                        or req.pending_prompt):
                     # released (cancel/deadline sweep) or preempted since
-                    # dispatch: its block tokens are DISCARDED — a resume
+                    # dispatch (and maybe on a lane again, its prompt to
+                    # fill): its block tokens are DISCARDED — a resume
                     # regenerates them exactly, a cancel never emits them
                     continue
                 self._probe_countdown_locked(req)

@@ -978,6 +978,13 @@ def unpack_words(fields, buf) -> Dict[str, Any]:
     return out
 
 
+#: stop ids a lane in a round's buffer.  A round's ONE open width is its
+#: rows, so its stop ids have a width of their own; a lane with more of them
+#: takes no carry through a round (the scheduler fetches such a round before
+#: it plans the next dispatch: chain break ``stops``)
+ROUND_STOPS = 8
+
+
 def dispatch_fields(program: str, lanes: int, max_pages: int):
     """What the host sends with a dispatch of ``program``: ``"tick"``
     (:func:`paged_decode_step_sampled`), ``"block"``
@@ -985,12 +992,17 @@ def dispatch_fields(program: str, lanes: int, max_pages: int):
     tokens, active and rem count or the carry's), ``"spec"``
     (:func:`paged_speculative_block`) or ``"round"``
     (:func:`paged_mixed_step`; ``rows`` stacks :func:`pack_round`'s
-    ``toks``, ``row_lane``, ``row_off``)."""
+    ``toks``, ``row_lane``, ``row_off``; ``fresh`` says, a lane, whether
+    its decode row's token, ``kv_lens`` and ``rem`` count or the carry's;
+    ``rem`` is the tokens a lane that emits in this round still wants, this
+    round's included, and 0 for a lane in mid-prompt; ``stops`` its stop
+    ids padded with -1, :data:`ROUND_STOPS` wide)."""
     b, table = (lanes,), ("tables", _I32, (lanes, max_pages))
     sampling = (("temps", _F32, b), ("seeds", _U32, (lanes, 2)))
     if program == "round":
         return (table, ("q_lens", _I32, b), ("kv_lens", _I32, b)) + sampling \
-            + (("rows", _I32, (3, -1)),)
+            + (("fresh", _BOOL, b), ("rem", _I32, b),
+               ("stops", _I32, (lanes, ROUND_STOPS)), ("rows", _I32, (3, -1)))
     fields = (table, ("lengths", _I32, b), ("tokens", _I32, b),
               ("active", _BOOL, b)) + sampling
     if program == "tick":
@@ -1362,8 +1374,9 @@ def pack_round(lanes: int, prefill: Dict[int, Any], decode: Dict[int, int]):
     return toks, row_lane, row_off, q_lens
 
 
-def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
-                     n_heads: int, n_layers: int, compute_dtype,
+def paged_mixed_step(params, kv_pool, packed, carry, lanes: int,
+                     max_pages: int, n_heads: int, n_layers: int,
+                     compute_dtype,
                      use_kernel: bool = False,
                      n_kv_heads: Optional[int] = None,
                      rope_theta: Optional[float] = None,
@@ -1374,8 +1387,19 @@ def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
     dispatch whose rows are the round's tokens.
 
     The round arrives as ONE buffer, ``packed`` (:func:`dispatch_fields`
-    ``"round"``: ``tables``, ``q_lens``, ``kv_lens``, ``temps``, ``seeds``
-    and ``rows``, the stack of ``toks``, ``row_lane``, ``row_off``).
+    ``"round"``: ``tables``, ``q_lens``, ``kv_lens``, ``temps``, ``seeds``,
+    ``fresh``, ``rem``, ``stops`` and ``rows``, the stack of ``toks``,
+    ``row_lane``, ``row_off``), beside ``carry = (lengths, tokens, live,
+    steps_rem)``: what the dispatch before this one returned, a decode
+    block (:func:`paged_decode_block`) or a round.  The round is a member
+    of the scheduler's chain: a decode row ``M + b`` whose lane is not
+    ``fresh`` takes its token, its ``kv_len`` (the carried length + 1), its
+    step budget and whether it runs at all (``live``: a lane that hit a
+    stop token in the dispatch before holds no row here, writes nothing
+    and emits nothing) from the carry, so the host plans and enqueues the
+    round before it has fetched its predecessor.  A ``fresh`` lane takes
+    them from the buffer; a chain's first round is fresh in every lane
+    beside a carry nobody reads, so both are the same compiled program.
     Prefilling lanes carry a prompt chunk (``q_lens = chunk``), decoding
     lanes their current token (``q_lens = 1``), idle lanes nothing
     (``q_lens = 0``).  ``toks (T,)`` holds the round with ``T = M +
@@ -1401,13 +1425,20 @@ def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
     this round (a mid-prompt chunk's pick is discarded; device sampling
     is stateless, so a discarded pick costs nothing).
 
-    Returns ``(results, last_logits (B, vocab), kv_pool)``: ``results`` is
+    Returns ``(results, last_logits (B, vocab), lengths (B,), last_tokens
+    (B,), live (B,), steps_rem (B,), kv_pool)``: ``results`` is
     ONE int32 array, :func:`result_fields` ``(lanes, moe=...)``:
     ``tokens (B,)``, ``logprobs (B,)`` as their bits and the expert
     layers' counters where ``spec`` has any; ``last_logits`` stays
     device-resident unless a host-sampled lane fetches its row.  The same
     segments through ``paged_ragged_forward(last_only=True)`` give the
     same logits: that is the plain form this one is tested against.
+    ``lengths`` .. ``steps_rem`` are the carry AFTER the round, device
+    arrays a block or a round behind this one starts from: a lane that
+    emitted here (a decode row that ran; a chunk whose prompt ENDS in this
+    round, which the host marks with ``rem`` > 0) has its new length, its
+    pick and the block body's liveness (budget left, no stop token, short
+    of its EVA window's end); a lane in mid-prompt or idle is not live.
 
     For a ``spec`` with Mamba layers ``kv_pool`` is the pair ``(page store,
     lane state)``, in and out: each lane's segment runs the convolution and
@@ -1424,6 +1455,18 @@ def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
     toks, row_lane, row_off = f["rows"]
     b, t = lanes, toks.shape[0]
     m = t - b
+    # the decode rows the host planned; those of a lane that is not fresh
+    # are the carry's: its token, its length, and no row at all where the
+    # dispatch before ended the lane
+    c_len, c_tok, c_live, c_rem = carry
+    planned = row_lane[m:] >= 0
+    kept = planned & ~f["fresh"]
+    runs = jnp.where(kept, c_live, planned)
+    toks = toks.at[m:].set(jnp.where(kept, c_tok, toks[m:]))
+    row_lane = row_lane.at[m:].set(jnp.where(runs, row_lane[m:], -1))
+    q_lens = jnp.where(kept, runs.astype(jnp.int32), q_lens)
+    kv_lens = jnp.where(kept, jnp.where(runs, c_len + 1, 0), kv_lens)
+    steps_rem = jnp.where(kept, c_rem, f["rem"])
     page_size = _pages(kv_pool).shape[3]
     emb = params["embed"].astype(compute_dtype)
     x = emb[toks][None]                               # (1, T, D)
@@ -1472,8 +1515,18 @@ def paged_mixed_step(params, kv_pool, packed, lanes: int, max_pages: int,
     logp_rows = jax.nn.log_softmax(last.astype(jnp.float32), axis=-1)
     logprobs = jnp.take_along_axis(logp_rows, next_tokens[:, None],
                                    axis=-1)[:, 0]
+    # the carry after the round, by the block body's rule: a lane that
+    # emitted (a decode row, a prompt's last chunk) goes on while it has
+    # budget left, drew no stop token and stands short of its window's end
+    emitted = (q_lens > 0) & (steps_rem > 0)
+    steps_rem = steps_rem - emitted.astype(jnp.int32)
+    hit_stop = (next_tokens[:, None] == f["stops"]).any(axis=1)
+    live = emitted & (steps_rem > 0) & ~hit_stop
+    if spec.eva_window:
+        live = live & (kv_lens % spec.eva_window != 0)
     return (_pack_results(lanes, None, moe, tokens=next_tokens,
-                          logprobs=logprobs), last, kv_pool)
+                          logprobs=logprobs), last, kv_lens, next_tokens,
+            live, steps_rem, kv_pool)
 
 
 def paged_eva_compact(params, kv_pool, pages, spec, use_kernel: bool = False):
@@ -1764,6 +1817,7 @@ class StepPrograms:
         # shardings.  Every array argument is positional (a sharded jit
         # attaches in_shardings by position), the host's one packed buffer
         step = ((1,), (psh, kvsh, rep), (rep, rep, kvsh))
+        chained = ((1,), (psh, kvsh, rep, rep), (rep,) * 6 + (kvsh,))
         one_lane = ((1,), (psh, kvsh, rep, rep, rep, rep), (rep, kvsh))
         table = {
             # the K=1 tick
@@ -1773,7 +1827,7 @@ class StepPrograms:
             # tokens (round_width): the chunks packed by token and a row
             # for each lane's decode token through a single ragged forward
             # + on-device pick
-            "mixed": (paged_mixed_step, step_kw) + step,
+            "mixed": (paged_mixed_step, step_kw) + chained,
             # the legacy plan's fused prefill, compiled per prompt-length
             # bucket (powers of two); ``prefill_flash`` selects the pallas
             # prompt-attention kernel
