@@ -7,7 +7,10 @@ sets the virtual-device count, before any backend is created.  What only a
 chip can show is in ``chip_smoke.py`` (run through the chip tool).
 """
 
+import gc
 import os
+
+import pytest
 
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
@@ -26,3 +29,43 @@ def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+def _mappings() -> int:
+    """Memory mappings of this process (0 where ``/proc`` has no such file)."""
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+def _mappings_allowed() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530
+
+
+_MAPPINGS_ALLOWED = _mappings_allowed()
+
+
+@pytest.fixture(autouse=True)
+def _bounded_mappings():
+    """Drop JAX's compiled programs once this process holds half the memory
+    mappings the kernel allows it (``vm.max_map_count``, 65,530).
+
+    The CPU backend maps three regions for every object file of a compiled
+    program and the jit caches keep every program alive, so a worker that
+    ``--dist loadfile`` happens to hand several engine-heavy files in a row
+    (``test_packed_round.py`` alone leaves ~55,000 mappings) reaches the
+    limit, the next ``mmap`` fails inside the compiler and the worker dies
+    of a segmentation fault in whatever test compiles next (PR 50: the same
+    files in the same order killed the parent's tree too).  A program a later
+    test needs again compiles again."""
+    yield
+    if _mappings() > _MAPPINGS_ALLOWED // 2:
+        import jax
+        jax.clear_caches()
+        gc.collect()

@@ -1400,6 +1400,19 @@ class ContinuousBatcher:
                 # experts held here with a row, summed over decode steps
                 # and expert layers
                 "experts_hit": self.moe_experts_hit}
+        if self.model_spec is not None and self.model_spec.hc_mult:
+            spec = self.model_spec
+            out["mhc"] = {
+                "streams": spec.hc_mult,
+                # hyper-connected sublayers a forward: two a layer
+                "sublayers": 2 * spec.n_layers,
+                "sinkhorn_iters": spec.hc_sinkhorn_iters,
+                # what a token row holds between sublayers
+                "stream_bytes_per_row": spec.hc_mult * spec.d_model
+                * np.dtype(self.plan.compute_dtype).itemsize,
+                # token rows that went through the streams, cumulative
+                "rows": {kind: self.lane_work[kind]["rows"]
+                         for kind in ("round", "decode")}}
         if self._sparse is not None:
             out["sparse"] = {"topk": self.model_spec.index_topk,
                              **{name: {kind: c[name]

@@ -256,9 +256,11 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
         kva = h @ qmat(p["wkv_a"], compute_dtype)
         ckv = _rmsnorm(kva[..., :spec.kv_lora_rank], p["kv_norm"]["scale"],
                        eps)
+        # (YaRN's frequencies where the spec scales them, else theta's)
+        inv = spec.rope_inv_freq()
         kr = apply_rope(kva[..., None, spec.kv_lora_rank:], pos,
-                        spec.rope_theta)[..., 0, :]
-        qr = apply_rope(q[..., nope:], pos, spec.rope_theta)
+                        spec.rope_theta, inv)[..., 0, :]
+        qr = apply_rope(q[..., nope:], pos, spec.rope_theta, inv)
         rows = jnp.concatenate([ckv, kr], axis=-1)           # (B, M, W)
         kv_pool = _scatter_latent(
             kv_pool, layer, page_idx, slot_idx,
@@ -705,6 +707,84 @@ def _gated_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx,
     return attn.reshape(b, m, -1), kv_pool
 
 
+def _mhc_pre(spec, p, x):
+    """The READ side of one sublayer's hyper-connection (mHC; ``p`` is the
+    sublayer's ``hc_attn`` or ``hc_ffn``): from the streams ``x (B, M, n,
+    D)`` the row the sublayer reads and the maps it writes back through,
+    ``(u (B, M, D), h_res (B, M, n, n), h_post (B, M, n))``, a set a token::
+
+        v      = RMSNorm_g(vec(X))             over all n D values, hc_eps
+        h_pre  = sigmoid(a_pre (v phi_pre) + b_pre)
+        h_post = 2 sigmoid(a_post (v phi_post) + b_post)
+        S      = clip(a_res mat(v phi_res) + b_res, hc_clamp)
+        H_res  = exp(S), then hc_sinkhorn_iters times: rows over (their
+                 sum + hc_eps), columns over (their sum + hc_eps)
+        u      = sum_i h_pre[i] X[i]
+
+    The streams are held in their own dtype; ``v``, the projections, the
+    sweeps and ``u``'s sum are float32 (the projection at full precision:
+    the TPU's default would round ``v`` and ``phi`` to bf16), and the maps
+    stay float32 for :func:`_mhc_post`.  The sums over the ``n`` streams
+    are written out term by term: element-wise work XLA fuses, where an
+    einsum over a width of 4 becomes a batch of tiny matrix products."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("mhc_pre"):
+        f32, n = jnp.float32, spec.hc_mult
+        b, m = x.shape[:2]
+        xf = x.astype(f32)
+        flat = xf.reshape(b, m, -1)
+        v = (flat * jax.lax.rsqrt(jnp.square(flat).mean(-1, keepdims=True)
+                                  + spec.hc_eps)
+             * p["norm"]["scale"].astype(f32))
+        proj = jnp.einsum("bmk,kj->bmj", v, p["phi"].astype(f32),
+                          precision=jax.lax.Precision.HIGHEST)
+        alpha, bias = p["alpha"].astype(f32), p["bias"].astype(f32)
+        h_pre = jax.nn.sigmoid(alpha[0] * proj[..., :n] + bias[:n])
+        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * proj[..., n:2 * n]
+                                      + bias[n:2 * n])
+        h_res = jnp.exp(jnp.clip(alpha[2] * proj[..., 2 * n:] + bias[2 * n:],
+                                 *spec.hc_clamp)).reshape(b, m, n, n)
+        for _ in range(spec.hc_sinkhorn_iters):
+            h_res = h_res / (h_res.sum(-1, keepdims=True) + spec.hc_eps)
+            h_res = h_res / (h_res.sum(-2, keepdims=True) + spec.hc_eps)
+        u = sum(h_pre[..., i, None] * xf[..., i, :] for i in range(n))
+        return u.astype(x.dtype), h_res, h_post
+
+
+def _mhc_post(x, h_res, h_post, f):
+    """The WRITE side of one sublayer's hyper-connection: ``X' = H_res X +
+    outer(h_post, f)`` from the streams ``x (B, M, n, D)`` the sublayer
+    read, :func:`_mhc_pre`'s maps and the sublayer's output ``f (B, M,
+    D)``; accumulated in float32, held in the streams' dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("mhc_post"):
+        xf, n = x.astype(jnp.float32), x.shape[-2]
+        mixed = sum(h_res[..., :, j, None] * xf[..., None, j, :]
+                    for j in range(n))
+        return (mixed + h_post[..., None] * f.astype(jnp.float32)[
+            ..., None, :]).astype(x.dtype)
+
+
+def _streams(spec, x, out: bool = False):
+    """The step functions' two ends of the hyper-connected residual: the
+    embedding rows ``x (..., D)`` widened to ``hc_mult`` equal streams
+    ``(..., n, D)``, or (``out``) the streams after the last layer summed
+    (in float32) for the final norm.  ``x`` itself where the spec has the
+    plain residual."""
+    import jax.numpy as jnp
+
+    if not spec.hc_mult:
+        return x
+    if out:
+        return x.astype(jnp.float32).sum(-2)
+    return jnp.broadcast_to(x[..., None, :],
+                            x.shape[:-1] + (spec.hc_mult, x.shape[-1]))
+
+
 def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
     """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
     experts (router kind ``spec.router``; of the router's ``E`` experts the
@@ -717,14 +797,24 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
     input: its ``x`` is ``x + dense(h)`` and the expert block's output ``m``
     (the held experts' part and the identity columns' ``weight * h``) goes
     out beside it, ``((x, m), stats)``, to be added after the NEXT layer's
-    FFN, which is handed it as ``shortcut``."""
+    FFN, which is handed it as ``shortcut``.
+
+    With hyper-connections (``spec.hc_mult``) ``x`` is the streams ``(B, M,
+    n, D)``: the FFN reads the row :func:`_mhc_pre` makes of them (the
+    layer's ``hc_ffn``) and :func:`_mhc_post` writes its output back."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _dense_ffn, _rmsnorm
 
+    streams = None
+    if spec.hc_mult:
+        streams, (x, *maps) = x, _mhc_pre(spec, p["hc_ffn"], x)
     h = _rmsnorm(x, p["ln2"]["scale"], spec.rms_eps)
     kind = spec.layer_kinds[layer]
     if kind == "dense":
+        if streams is not None:
+            return _mhc_post(streams, *maps,
+                             _dense_ffn(p, h, compute_dtype)), None
         x = x + _dense_ffn(p, h, compute_dtype).astype(x.dtype)
         if shortcut is not None:
             with jax.named_scope("moe_shortcut"):
@@ -751,6 +841,8 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
     elif spec.n_shared:
         with jax.named_scope("moe_shared"):
             y = y + _dense_ffn(p["shared"], h, compute_dtype)
+    if streams is not None:
+        return _mhc_post(streams, *maps, y), stats
     return x + y.astype(x.dtype), stats
 
 
@@ -807,6 +899,16 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     functions hand the pair on as they hand ``x`` on, and the next layer
     (always ``"dense"``) takes it apart again (:func:`_ffn_block`).
 
+    With hyper-connections (``spec.hc_mult``; Xing4.0's mHC) ``x`` is a
+    token's ``n`` residual streams, ``(B, M, n, D)`` in every form (``(1,
+    T, n, D)`` in a packed round): each of the layer's two sublayers reads
+    ONE row a token, a weighted sum of the streams (:func:`_mhc_pre`; the
+    layer's ``hc_attn`` here, ``hc_ffn`` in :func:`_ffn_block`), and its
+    output goes back through a doubly stochastic ``n x n`` matrix and an
+    ``n``-vector a token (:func:`_mhc_post`) where the plain residual adds
+    it.  The step functions widen the embedding into the streams and sum
+    them before the final norm (:func:`_streams`).
+
     Kept short, the K/V kernel called from here and the rest in functions
     of their own: on the v5e host, tracing a kernel body costs more with
     every Python frame between the step function and the ``pallas_call``
@@ -819,9 +921,11 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
                                            split_qkv)
 
-    shortcut = None
+    shortcut = streams = None
     if layer and spec.layer_kinds[layer - 1] == "shortcut":
         x, shortcut = x
+    if spec.hc_mult:
+        streams, (x, *maps) = x, _mhc_pre(spec, p["hc_attn"], x)
     h = _rmsnorm(x, p["ln1"]["scale"], spec.rms_eps)
     state = None
     if spec.mamba_layers:
@@ -904,9 +1008,11 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         # (The gather path computes every row, and the dense golden holds its
         # padded form to the parent's bits in the rows without a token too.)
         attn = jnp.where(valid[..., None], attn, 0)
-    x, stats = _ffn_block(spec, p, layer,
-                          x + attn @ qmat(p["wo"], compute_dtype), valid,
-                          compute_dtype, shortcut)
+    attn = attn @ qmat(p["wo"], compute_dtype)
+    x, stats = _ffn_block(
+        spec, p, layer,
+        x + attn if streams is None else _mhc_post(streams, *maps, attn),
+        valid, compute_dtype, shortcut)
     return x, (kv_pool if state is None else (kv_pool, state)), stats
 
 
@@ -1087,6 +1193,7 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
     x = emb[tokens][:, None, :]
     spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
                       rope_theta)
+    x = _streams(spec, x)
     # write target per lane: page id + slot for position `lengths`;
     # inactive/padded lanes are routed to the RESERVED scratch page 0 so
     # they can never clobber a live lane's pages
@@ -1112,7 +1219,8 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
         if stats is not None:
             moe_stats.append(stats)
 
-    x = _rmsnorm(x, params["final_norm"]["scale"], spec.rms_eps)
+    x = _rmsnorm(_streams(spec, x, out=True), params["final_norm"]["scale"],
+                 spec.rms_eps)
     logits = _lm_head(params, x[:, 0])
     # inactive lanes emit neutral logits (argmax 0) — callers mask on active
     logits = jnp.where(active[:, None], logits, 0.0)
@@ -1302,6 +1410,7 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
     x = emb[seq]                                      # (B, M, D)
     spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
                       rope_theta)
+    x = _streams(spec, x)
     valid = jnp.arange(m)[None, :] < q_lens[:, None]  # (B, M)
     pos = (kv_lens - q_lens)[:, None] + jnp.arange(m)[None, :]
     row = spec.cache_row(pos)      # the rows behind the positions
@@ -1330,6 +1439,7 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
             moe_stats.append(stats)
     moe = (jnp.stack(moe_stats),) if moe_stats else ()
 
+    x = _streams(spec, x, out=True)
     if last_only:
         # only each lane's last valid token seeds a pick — run the
         # vocab-sized head over ONE row per lane (paged_extend's trick,
@@ -1472,6 +1582,7 @@ def paged_mixed_step(params, kv_pool, packed, carry, lanes: int,
     x = emb[toks][None]                               # (1, T, D)
     spec = _step_spec(spec, x.shape[-1], n_heads, n_layers, n_kv_heads,
                       rope_theta)
+    x = _streams(spec, x)
     valid = row_lane >= 0
     lane = jnp.maximum(row_lane, 0)
     start = kv_lens - q_lens                          # (B,) segment starts
@@ -1506,7 +1617,7 @@ def paged_mixed_step(params, kv_pool, packed, carry, lanes: int,
 
     # the vocab-sized head over ONE row a lane: its last valid token's
     last_row = spread[jnp.arange(b) * m + jnp.maximum(q_lens - 1, 0)]
-    last = _lm_head(params, _rmsnorm(x[0][last_row],
+    last = _lm_head(params, _rmsnorm(_streams(spec, x[0][last_row], out=True),
                                      params["final_norm"]["scale"],
                                      spec.rms_eps))
     pos_last = jnp.maximum(kv_lens - 1, 0)

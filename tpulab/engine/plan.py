@@ -213,7 +213,9 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                            | {f"{k} FFNs" for k in spec.layer_kinds
                               if k != "dense"}
                            | ({"EVA windows (compacted pages)"} if eva
-                              else set()))
+                              else set())
+                           | ({"hyper-connected residual streams"}
+                              if spec.hc_mult else set()))
             raise NotImplementedError(
                 f"a model with {', '.join(kinds)} is served on the "
                 "ragged plan only; not supported with it: "

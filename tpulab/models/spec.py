@@ -200,10 +200,40 @@ interleaved RoPE are taken up once, at load time, by
 
 so the latent kernel, its program and the cache entry are those of
 ``glm4_moe_lite``.
+
+``xing4_0`` (Xing4.0-29B-A4B): the ``glm4_moe_lite`` layer (latent attention,
+leading dense layers, sigmoid-routed experts and a shared expert) inside
+*manifold-constrained hyper-connections* (mHC, arXiv:2512.24880): a token's
+residual state is ``hc_mult`` streams ``X (n, d_model)``, and each SUBLAYER
+(the attention, the FFN) reads one row ``u = sum_i h_pre[i] X[i]`` and writes
+``X' = H_res X + outer(h_post, F(norm(u)))``; ``h_pre``, ``h_post`` and the
+``n x n`` matrix ``H_res`` are computed for every token from the normed,
+flattened streams, ``H_res`` projected onto the doubly stochastic matrices
+by ``hc_sinkhorn_iters`` Sinkhorn sweeps
+(:func:`tpulab.engine.paged_steps._mhc_pre`).  The streams start as copies
+of the embedding and end as their sum.  A layer has the ``glm4_moe_lite``
+leaves and, under ``hc_attn`` and ``hc_ffn`` (one a sublayer):
+
+=============  ==========================================================
+``norm``       ``{"scale": (n * d_model,)}``: RMSNorm over all the streams'
+               values, epsilon ``hc_eps``
+``phi``        ``(n * d_model, 2 n + n * n)``, columns ``[pre | post |
+               res]`` (``res`` row-major: entry ``(i, j)`` weighs stream
+               ``j`` in new stream ``i``)
+``alpha``      ``(3,)``: the scalars on the three projections
+``bias``       ``(2 n + n * n,)``, laid out as ``phi``'s columns
+=============  ==========================================================
+
+RoPE's frequencies are YaRN's (``rope_scaling``; :meth:`ModelSpec.
+rope_inv_freq`), and the factor YaRN puts on the softmax scale is folded
+into ``wq_b`` at load time (:func:`mla_scales`, :func:`scale_queries`), so
+the latent kernel's call is ``glm4_moe_lite``'s.  The prediction layer is
+not built.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -258,6 +288,14 @@ class ModelSpec:
     pred_heads: int = 0                     # output heads held (head 0 is read)
     zero_experts: int = 0                   # the router's LAST columns:
                                             # identity experts (no weights)
+    hc_mult: int = 0                        # residual streams (mHC); 0 = the
+                                            # plain residual x + f(norm(x))
+    hc_sinkhorn_iters: int = 0              # sweeps that project H_res
+    hc_eps: float = 1e-6                    # stream norm, Sinkhorn sums
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)   # H_res' logits
+    rope_scaling: Tuple[float, ...] = ()    # YaRN: (factor, original max
+                                            # positions, beta_fast,
+                                            # beta_slow); () = none
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -336,6 +374,19 @@ class ModelSpec:
                 f"are not a share of the router's {self.ffn_experts}")
         if self.shared_gate and not self.n_shared:
             raise ValueError("shared_gate without a shared expert")
+        if self.hc_mult:
+            if (self.hc_mult < 2 or self.hc_sinkhorn_iters < 1
+                    or "shortcut" in kinds
+                    or set(self.mixers or ()) - {"attention"}):
+                raise ValueError(
+                    "hyper-connections give hc_mult (>= 2) streams and "
+                    "hc_sinkhorn_iters (>= 1) around attention and a dense "
+                    "or expert FFN (no shortcut layer, no lane-state mixer)")
+        if self.rope_scaling and (len(self.rope_scaling) != 4
+                                  or self.attention != "mla"):
+            raise ValueError("rope_scaling is YaRN's (factor, original max "
+                             "positions, beta_fast, beta_slow) on the rope "
+                             "columns of latent attention")
         if "mamba" in mixers:
             if self.attention != "gqa" or "attention" not in mixers:
                 raise ValueError("Mamba layers are served beside GQA "
@@ -390,6 +441,28 @@ class ModelSpec:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def rope_inv_freq(self) -> Optional[np.ndarray]:
+        """The inverse frequencies RoPE turns the ``qk_rope_head_dim``
+        columns by where ``rope_scaling`` changes them (float32, ``(rope /
+        2,)``), else None: ``theta^(-2j / d)`` it is.  YaRN: pair ``j``
+        keeps its frequency where it turns more than ``beta_fast`` times
+        within the original context, has it divided by ``factor`` where it
+        turns fewer than ``beta_slow`` times, and a linear ramp between."""
+        if not self.rope_scaling:
+            return None
+        factor, original, fast, slow = self.rope_scaling
+        d, theta = self.qk_rope_head_dim, self.rope_theta
+
+        def corr(turns):
+            return d * np.log(original / (2 * np.pi * turns)) / (
+                2 * np.log(theta))
+        low = int(np.clip(np.floor(corr(fast)), 0, d // 2 - 1))
+        high = int(np.clip(np.ceil(corr(slow)), 0, d // 2 - 1))
+        j = np.arange(d // 2, dtype=np.float64)
+        ramp = np.clip((j - low) / max(high - low, 1e-3), 0, 1)
+        return (theta ** (-2 * j / d)
+                * ((1 - ramp) + ramp / factor)).astype(np.float32)
 
     @property
     def ffn_experts(self) -> int:
@@ -690,16 +763,76 @@ def longcat_flash_spec(config: Dict[str, Any], first: int = 0,
         rope_theta=float(config["rope_theta"]))
 
 
+def xing4_spec(config: Dict[str, Any]) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type`` ``xing4_0``):
+    :func:`glm4_moe_lite_spec`'s layer inside ``hc_mult`` hyper-connected
+    residual streams, RoPE under YaRN.  Refuses what the layer block does
+    not compute."""
+    scaling = config.get("rope_scaling") or {}
+    kind = scaling.get("rope_type", scaling.get("type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling type {kind!r} is not implemented "
+                         "(yarn alone)")
+    if float(scaling.get("mscale", 1)) != float(scaling.get("mscale_all_dim",
+                                                            0)):
+        raise ValueError("rope_scaling mscale != mscale_all_dim (a factor "
+                         "on cos and sin) is not implemented")
+    if int(config.get("moe_layer_freq", 1)) != 1:
+        raise ValueError("moe_layer_freq != 1 is not implemented")
+    if int(config.get("ep_size", 1)) != 1:
+        raise ValueError("ep_size != 1 is not implemented")
+    if int(config.get("hc_mult", 0)) < 2:
+        raise ValueError("hc_mult < 2 is not implemented (xing4_0 is served "
+                         "with its residual streams)")
+    base = glm4_moe_lite_spec(dict(config, rope_scaling=None))
+    return dataclasses.replace(
+        base, hc_mult=int(config["hc_mult"]),
+        hc_sinkhorn_iters=int(config["hc_sinkhorn_iters"]),
+        hc_eps=float(config["hc_eps"]),
+        hc_clamp=(float(config["mhc_h_res_clamp_min"]),
+                  float(config["mhc_h_res_clamp_max"])),
+        rope_scaling=(float(scaling["factor"]),
+                      float(scaling["original_max_position_embeddings"]),
+                      float(scaling["beta_fast"]),
+                      float(scaling["beta_slow"])))
+
+
 def mla_scales(config: Dict[str, Any]) -> Tuple[float, float]:
-    """``(query factor, latent factor)`` of a ``longcat_flash`` config:
+    """``(query factor, latent factor)`` that a config puts on latent
+    attention and the program folds into its matrices at load time:
     ``(hidden_size / q_lora_rank)^0.5`` where ``mla_scale_q_lora``,
-    ``(hidden_size / kv_lora_rank)^0.5`` where ``mla_scale_kv_lora``, else
-    1."""
+    ``(hidden_size / kv_lora_rank)^0.5`` where ``mla_scale_kv_lora``
+    (``longcat_flash``), else 1; under YaRN (``rope_scaling``) the query
+    factor also carries what YaRN multiplies the softmax scale by,
+    ``mscale(factor, mscale_all_dim)^2`` with ``mscale(s, m) = 0.1 m ln s +
+    1`` (``xing4_0``: 1.4159^2 at factor 64)."""
     d = float(config["hidden_size"])
-    return ((d / int(config["q_lora_rank"])) ** 0.5
-            if config.get("mla_scale_q_lora") else 1.0,
-            (d / int(config["kv_lora_rank"])) ** 0.5
+    q = ((d / int(config["q_lora_rank"])) ** 0.5
+         if config.get("mla_scale_q_lora") else 1.0)
+    scaling = config.get("rope_scaling") or {}
+    if scaling.get("rope_type", scaling.get("type")) == "yarn":
+        s, m = float(scaling["factor"]), float(scaling.get("mscale_all_dim",
+                                                           0))
+        if s > 1 and m:
+            q *= (0.1 * m * np.log(s) + 1.0) ** 2
+    return (float(q), (d / int(config["kv_lora_rank"])) ** 0.5
             if config.get("mla_scale_kv_lora") else 1.0)
+
+
+def scale_queries(params: Dict[str, Any], spec: ModelSpec,
+                  q_scale: float) -> Dict[str, Any]:
+    """``params`` (a tree in the served layout, numpy or jax arrays) with
+    every layer's ``wq_b`` times ``q_scale`` (:func:`mla_scales`): what
+    turns a tree whose ``wq_b`` is the published ``q_b_proj`` into the one
+    an ``xing4_0`` engine is handed.  The product is float32 (a bf16 matrix
+    times a bf16 2.0047 would be times 2)."""
+    out = dict(params)
+    for i in range(spec.n_layers):
+        p = params[f"layer{i}"]
+        out[f"layer{i}"] = dict(
+            p, wq_b=(p["wq_b"].astype("float32") * q_scale).astype(
+                p["wq_b"].dtype))
+    return out
 
 
 def longcat_flash_layout(wq_b, wkv_a, kv_b, spec: ModelSpec,
@@ -784,7 +917,8 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     layer's for the same reason: ``a_log = log(U(0, 16))``, ``dt_bias`` and
     the convolution as Mamba's.  The EVA scorers ``eva_mu`` / ``eva_phi``
     are a unit normal cut at two deviations (the published
-    initialisation)."""
+    initialisation).  The hyper-connections' leaves are
+    :func:`init_hyper_connection`'s."""
     import jax
     import jax.numpy as jnp
 
@@ -896,5 +1030,34 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                                "w2": w(fs, d)}
                 if spec.shared_gate:
                     p["shared"]["gate"] = w(d, 1)
+        if spec.hc_mult:
+            p.update(hc_attn=init_hyper_connection(next(keys), spec),
+                     hc_ffn=init_hyper_connection(next(keys), spec))
         params[f"layer{i}"] = p
     return params
+
+
+def init_hyper_connection(key, spec: ModelSpec) -> Dict[str, Any]:
+    """Seeded float32 leaves of ONE sublayer's hyper-connection (``norm``,
+    ``phi``, ``alpha``, ``bias``; the module docstring has the layout).
+    Not normal 0.02: with a small ``phi`` the three maps are constants and
+    ``H_res`` the uniform matrix, which a program that ignored the streams
+    would compute as well.  ``phi`` is normal ``(n d_model)^-0.5`` (the
+    normed streams have unit mean square, so a projection has deviation
+    about 1), ``alpha`` 1, the biases of ``h_pre`` and ``h_post`` a unit
+    normal, those of ``H_res`` normal 0.5 and 1 higher on the diagonal:
+    every token has its own maps, and ``H_res`` is neither uniform nor a
+    permutation (at twice these two numbers a fifth of the sublayers drew
+    an entry past 0.95, and twenty sweeps left their rows 2e-3 off 1)."""
+    import jax
+    import jax.numpy as jnp
+
+    n, nc = spec.hc_mult, spec.hc_mult * spec.d_model
+    k_phi, k_bias = jax.random.split(key)
+    bias = jax.random.normal(k_bias, (2 * n + n * n,), jnp.float32)
+    bias = bias.at[2 * n:].multiply(0.5)
+    return {"norm": {"scale": jnp.ones((nc,), jnp.float32)},
+            "phi": jax.random.normal(k_phi, (nc, 2 * n + n * n),
+                                     jnp.float32) * nc ** -0.5,
+            "alpha": jnp.ones((3,), jnp.float32),
+            "bias": bias.at[2 * n:].add(jnp.eye(n).reshape(-1))}

@@ -82,16 +82,20 @@ def repeat_kv(kv, n_heads):
     return jnp.repeat(kv, n_heads // hkv, axis=-2)
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
+def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None):
     """Rotary position embedding (HF Llama rotate-half convention).
 
     x (..., T, H, D); positions (..., T) int — broadcast against x's batch
     dims.  K is rotated BEFORE cache/pool writes, so cached keys are
-    position-baked and attention needs no further rotation.
+    position-baked and attention needs no further rotation.  ``inv_freq``
+    ``(D / 2,)`` float32, where a model scales its frequencies
+    (:meth:`tpulab.models.spec.ModelSpec.rope_inv_freq`), stands in for
+    ``theta``'s.
     """
     d = x.shape[-1]
     half = d // 2
-    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = (1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+           if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     ang = positions.astype(jnp.float32)[..., None] * inv   # (..., T, half)
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[..., None, :]  # (.., T, 1, D)
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[..., None, :]
