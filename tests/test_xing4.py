@@ -561,6 +561,45 @@ def test_no_mhc_group_without_streams():
         cb.shutdown()
 
 
+def test_debug_state_lists_the_grouped_products_the_programs_were_traced_at(
+        model):
+    """``debug_state()["moe"]["product"]``: both products of the expert
+    layers at a decode step's ``lanes x top_k`` rows and at a round's
+    ``(prompt tokens + lanes) x top_k``, written while the step programs
+    were traced (here ``ragged_dot`` is kept: no TPU); a dense engine has
+    no ``moe`` group."""
+    spec, _, served = model
+    cb = _engine(spec, served, use_kernel=False)
+    try:
+        cb.submit(list(range(1, 22)), 4).result(timeout=600)
+        state = cb.debug_state()
+    finally:
+        cb.shutdown()
+    product = state["moe"]["product"]
+    d, f, top_k = spec.d_model, spec.moe_ff, spec.top_k
+    widths = {(d, 2 * f), (f, d)}
+    assert {(p["k"], p["n"]) for p in product} == widths
+    assert all(p["tiles"] is None and p["vmem_bytes"] is None
+               and p["dtype"] == "float32" for p in product)
+    rows = {p["rows"] for p in product}
+    budget = state["dispatch"]["round_budget"]
+    assert {cb.lanes * top_k, (budget + cb.lanes) * top_k} <= rows
+    # every row count with both of its products
+    assert all({(p["k"], p["n"]) for p in product if p["rows"] == r}
+               == widths for r in rows)
+
+    from tpulab.models.transformer import init_transformer_params
+    dense_spec, vocab, d_ff, kw = test_engine_plan.KINDS["dense"]
+    dense = ContinuousBatcher(
+        init_transformer_params(vocab=vocab, d_model=32, n_heads=2,
+                                n_layers=2, d_ff=d_ff), 2, 2,
+        compute_dtype=jnp.float32, **kw)
+    try:
+        assert dense_spec is None and "moe" not in dense.debug_state()
+    finally:
+        dense.shutdown()
+
+
 @pytest.mark.parametrize("name, kwargs", [
     ("mesh", {"mesh": object()}),
     ("prefix_cache", {"prefix_cache": True}),
@@ -673,3 +712,27 @@ def test_mosaic_compiles_the_latent_kernel_at_the_cells_widths(one_chip,
         shape(lanes, 1024), shape(lanes), shape(lanes), v_width=512,
         sm_scale=192 ** -0.5, interpret=False).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("rows", [128, 2176], ids=["decode", "round"])
+def test_mosaic_compiles_the_grouped_products_at_the_cells_widths(one_chip,
+                                                                  rows):
+    """Both products of an expert layer (64 experts, 3584 -> 2 x 1024 ->
+    3584) at a decode step's 32 x 4 assignment rows and a round's 544 x 4,
+    under the tiles the plan gives them."""
+    from tpulab.ops import grouped_matmul as gm
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    for k, n in ((3584, 2048), (1024, 3584)):
+        plan = gm._gmm_plan(rows, k, n, jnp.bfloat16)
+        assert plan.tm == {128: 128, 2176: 544}[rows]
+        compiled = gm._gmm_call.lower(
+            shape(rows, k), shape(64, k, n), shape(64, dtype=jnp.int32),
+            tm=plan.tm, ts=plan.ts, tn=plan.tn, interpret=False).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and "grouped_matmul" in text
+        assert "ragged-dot" not in text
+        # no padded copy of the rows: the kernel takes them as they are
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -1399,7 +1399,10 @@ class ContinuousBatcher:
                 "decode_steps": self.moe_decode_steps,
                 # experts held here with a row, summed over decode steps
                 # and expert layers
-                "experts_hit": self.moe_experts_hit}
+                "experts_hit": self.moe_experts_hit,
+                # the grouped products the step programs were traced at
+                # and the kernel's tiles there (None: ``ragged_dot`` kept)
+                "product": self.programs.expert_products(self.model_spec)}
         if self.model_spec is not None and self.model_spec.hc_mult:
             spec = self.model_spec
             out["mhc"] = {

@@ -1992,6 +1992,18 @@ class StepPrograms:
             self.spec_blocks[k] = self._sized(self._spec_block, k)
         return self.spec_blocks[k]
 
+    @staticmethod
+    def expert_products(spec):
+        """The grouped products the step programs of ``spec``'s expert
+        layers were traced at (a decode step's and each round width's rows
+        x top-k, at the experts' two widths) with the kernel's tiles there,
+        ``tiles`` None where ``ragged_dot`` is kept: host data written at
+        trace time (``tpulab.ops.grouped_matmul.traced_products``), shared
+        by the process's engines of one width like the memo's programs."""
+        from tpulab.ops.grouped_matmul import traced_products
+        widths = ((spec.d_model, 2 * spec.moe_ff), (spec.moe_ff, spec.d_model))
+        return [p for p in traced_products() if (p["k"], p["n"]) in widths]
+
     def _sized(self, program, k: int):
         fn, kw, *how = program
         return self._jit(partial(fn, k=k, **kw), *how)

@@ -17,6 +17,10 @@ framework owns its hot ops):
   segmented by lane: a block of channels' state stays in registers across
   the round's rows, each lane's slot of the state store is read where its
   segment starts and written where it ends, in place
+- :mod:`grouped_matmul` — the experts' grouped product over rows sorted by
+  expert: each expert that has rows is one read of its weights and as near
+  one pass through the MXU as its rows allow, tiled by the traced row count
+  (the kernel under ``tpulab.parallel.moe.expert_ffn``)
 """
 
 from tpulab.ops.flash_attention import flash_attention, make_flash_attention_fn

@@ -29,8 +29,10 @@ counts once, as a shared expert does).
 The expert product (:func:`expert_ffn`) follows rows x top-k, not rows x
 experts: the (row, expert) assignments are sorted by expert and each
 projection is ONE grouped matrix product over the sorted rows
-(``jax.lax.ragged_dot`` with the group sizes), so an expert that no row
-chose costs no FLOPs.  It is told which experts it holds (``first``, and
+(``tpulab.ops.grouped_matmul.grouped_product`` with the group sizes: a
+Pallas kernel tiled by the traced row count on a TPU, ``jax.lax.ragged_dot``
+elsewhere), so an expert that no row chose costs no FLOPs and none of its
+weights is read.  It is told which experts it holds (``first``, and
 the leading axis of the weights): assignments to other experts contribute
 nothing — on one device it holds them all; under
 :func:`make_expert_parallel_ffn` each shard holds a contiguous range and a
@@ -112,6 +114,7 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
     up]`` and the hidden is ``silu(gate) * up``; ``"gelu"``: ``gelu(x
     w_in)``.  Returns (N, D) float32: the weighted sum of each row's
     chosen experts that live here."""
+    from tpulab.ops.grouped_matmul import grouped_product
     with jax.named_scope("moe_experts"):
         n, k = idx.shape
         n_here = w_in.shape[0]
@@ -124,7 +127,7 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
         sizes = jnp.bincount(key, length=n_here + 1)[:n_here].astype(
             jnp.int32)
         rows = x.astype(compute_dtype)[order // k]
-        h = jax.lax.ragged_dot(rows, w_in.astype(compute_dtype), sizes)
+        h = grouped_product(rows, w_in.astype(compute_dtype), sizes)
         if act == "swiglu":
             f = h.shape[-1] // 2
             h = jax.nn.silu(h[:, :f]) * h[:, f:]
@@ -132,7 +135,7 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
             h = jax.nn.gelu(h)
         else:
             raise ValueError(f"unknown expert activation {act!r}")
-        y = jax.lax.ragged_dot(h, w_out.astype(compute_dtype), sizes)
+        y = grouped_product(h, w_out.astype(compute_dtype), sizes)
         # rows past the last group are not part of any product: drop them
         y = jnp.where(here[order][:, None], y.astype(jnp.float32)
                       * weights.reshape(-1)[order][:, None], 0.0)
