@@ -135,14 +135,15 @@ def test_stages_cover_the_scheduler_thread():
 
 
 #: every ``sched.*`` span the scheduler can emit: the seven stages, the
-#: three parts of a dispatch, a turn by what opened it
+#: three parts of a dispatch, a turn by what opened it, a stall
 SCHED_SPANS = (
     "sched.admit", "sched.plan", "sched.dispatch", "sched.fetch",
     "sched.commit", "sched.emit", "sched.idle",
     "sched.dispatch.arrays", "sched.dispatch.put", "sched.dispatch.call",
     "sched.turn.completion", "sched.turn.joiner", "sched.turn.round",
     "sched.turn.k", "sched.turn.pages", "sched.turn.released",
-    "sched.turn.single", "sched.turn.compact", "sched.turn.other")
+    "sched.turn.single", "sched.turn.compact", "sched.turn.other",
+    "sched.stall")
 
 
 def _scheduler_clock():
@@ -364,10 +365,315 @@ def test_sched_span_is_documented(name):
     """Every span the scheduler's clock can emit has its row in the span
     table of docs/OBSERVABILITY.md (and the list above is the clock's)."""
     st = _scheduler_clock()
-    emitted = set(st.span_names.values()) | set(st.turn_names.values())
+    emitted = (set(st.span_names.values()) | set(st.turn_names.values())
+               | {st.stall_name})
     assert emitted == set(SCHED_SPANS)
     doc = open(f"{REPO}/docs/OBSERVABILITY.md").read()
     assert f"| `{name}` |" in doc, f"{name} has no row in the span table"
+
+
+def test_collector_span_is_documented():
+    doc = open(f"{REPO}/docs/OBSERVABILITY.md").read()
+    assert "| `host.gc` |" in doc
+
+
+@pytest.fixture
+def collector_watched():
+    """The collector's counters are the process's: a test reads deltas,
+    and automatic collections are held off while it does."""
+    import gc
+    tracing.watch_collector()
+    enabled = gc.isenabled()
+    gc.disable()
+    yield gc
+    if enabled:
+        gc.enable()
+
+
+def test_watch_collector_installs_one_callback(collector_watched):
+    gc = collector_watched
+    tracing.watch_collector()
+    cb = _tiny_engine(lanes=1)          # an engine asks for it too
+    cb.shutdown()
+    assert gc.callbacks.count(tracing._on_collection) == 1
+    p = tracing.collector_pauses()
+    assert set(p) == {"n", "s", "max_s", "collected", "threshold", "frozen"}
+    assert set(p["n"]) == set(p["s"]) == {"gen0", "gen1", "gen2"}
+    assert p["threshold"] == list(gc.get_threshold())
+    assert p["frozen"] == gc.get_freeze_count()
+
+
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_a_collection_moves_its_generation_alone(collector_watched,
+                                                 generation):
+    """``gc.collect(g)`` adds one entry and its seconds under ``gen<g>``;
+    generations 1 and 2 land in the ring the stalls read, generation 0 is
+    counted only."""
+    gc = collector_watched
+    before, ring = tracing.collector_pauses(), len(tracing._gc_ring)
+    last = tracing._gc_ring[-1] if ring else None
+    t0 = time.perf_counter()
+    gc.collect(generation)
+    t1 = time.perf_counter()
+    after = tracing.collector_pauses()
+    for g in range(3):
+        name = f"gen{g}"
+        moved = int(g == generation)
+        assert after["n"][name] - before["n"][name] == moved
+        assert (after["s"][name] > before["s"][name]) == bool(moved)
+    assert after["max_s"] >= after["s"][f"gen{generation}"] - before["s"][
+        f"gen{generation}"]
+    assert after["collected"] >= before["collected"]
+    if generation == 0:
+        assert (tracing._gc_ring[-1] if ring else None) == last
+    else:
+        p0, dt, gen = tracing._gc_ring[-1]
+        assert gen == generation and t0 <= p0 and p0 + dt <= t1 and dt > 0
+        assert len(tracing._gc_ring) <= 64
+
+
+def _stall_clock(**kw):
+    from tpulab.engine.paged import ContinuousBatcher as CB
+    return tracing.StageClock(CB.STAGES, prefix="sched.",
+                              turn=CB.TURN_STAGES, causes=CB.TURN_CAUSES,
+                              parts=CB.DISPATCH_PARTS, **kw)
+
+
+def test_a_slow_working_stage_is_one_stall_and_a_slow_wait_is_none(
+        collector_watched):
+    from tpulab.utils.tracing import stage
+    st = _stall_clock(slow_s=0.01)
+    assert st.stalls() == {
+        "n": 0, "s": 0.0, "max_s": 0.0, "gc_s": 0.0, "last": [],
+        "by_stage": {n: {"n": 0, "s": 0.0} for n in (
+            "admit", "plan", "dispatch", "commit", "emit")}}
+    with stage(st, "fetch"):
+        time.sleep(0.015)              # a wait is long by nature
+    with stage(st, "idle"):
+        time.sleep(0.015)
+    with stage(st, "plan"):
+        pass                           # fast: no stall
+    assert st.stalls()["n"] == 0
+    with stage(st, "commit"):
+        time.sleep(0.015)
+    stalls = st.stalls()
+    assert stalls["n"] == 1 and stalls["by_stage"]["commit"]["n"] == 1
+    assert 0.015 <= stalls["s"] == stalls["max_s"] < 0.05
+    assert stalls["by_stage"]["commit"]["s"] == stalls["s"]
+    assert stalls["gc_s"] == 0.0       # no collection ran inside it
+    # the waits before it ended with nothing un-fetched: a turn was open
+    assert stalls["last"] == [{"stage": "commit", "s": stalls["s"],
+                               "gc_s": 0.0, "in_turn": True}]
+    with stage(st, "dispatch"):
+        st.launched()
+        time.sleep(0.015)              # under a program on the queue
+    assert st.stalls()["last"][-1]["in_turn"] is False
+    assert st.stalls()["n"] == 2
+    assert sum(v["n"] for v in stalls["by_stage"].values()) == 1
+
+
+def test_a_stall_knows_the_collections_inside_it(collector_watched):
+    """A full collection inside a working stage: ``0 < gc_s <= s``, and
+    the stall says it stood inside a turn."""
+    from tpulab.utils.tracing import stage
+    gc = collector_watched
+    st = _stall_clock(slow_s=0.01)
+    with stage(st, "dispatch"):
+        ticket = st.launched()
+    with stage(st, "fetch"):
+        st.landed(ticket, "completion")
+    junk = [[i] for i in range(20000)]  # something to walk
+    with stage(st, "emit"):
+        time.sleep(0.012)
+        gc.collect()
+    del junk
+    stalls = st.stalls()
+    assert stalls["n"] == 1 and stalls["last"][0]["stage"] == "emit"
+    assert stalls["last"][0]["in_turn"] is True
+    assert 0 < stalls["gc_s"] <= stalls["s"]
+    assert stalls["last"][0]["gc_s"] == stalls["gc_s"]
+    # a pause before the run began is no part of it
+    with stage(st, "plan"):
+        time.sleep(0.012)
+    assert st.stalls()["last"][-1] == {
+        "stage": "plan", "s": st.stalls()["by_stage"]["plan"]["s"],
+        "gc_s": 0.0, "in_turn": True}
+
+
+def test_capture_holds_the_collection_and_the_stall(tmp_path, switch_closed,
+                                                   collector_watched):
+    """In a capture: ``host.gc`` around a collection of generation 1 or 2
+    (none around generation 0), on the device trace's clock and the
+    collecting thread, inside the stage it stopped; ``sched.stall`` where
+    the slow run ends, with what the collector took of it."""
+    from jax.profiler import ProfileData
+
+    from tpulab.utils.tracing import stage
+    gc = collector_watched
+    st = _stall_clock(slow_s=0.005)
+    with tracing.trace(str(tmp_path / "t")):
+        with stage(st, "commit"):
+            time.sleep(0.006)
+            gc.collect(0)
+            gc.collect(1)
+            gc.collect(2)
+    events = {}
+    for plane in ProfileData.from_file(
+            _captures(str(tmp_path / "t"))[-1]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("host.gc", "sched.stall", "sched.commit"):
+                    events.setdefault(ev.name, []).append(
+                        (line.name, ev.start_ns, ev.duration_ns,
+                         dict(ev.stats)))
+    assert [e[3]["generation"] for e in events["host.gc"]] == [1, 2]
+    assert all(e[3]["collected"] >= 0 for e in events["host.gc"])
+    (thread, c0, cdur, _), = events["sched.commit"]
+    for line, t0, dur, _stats in events["host.gc"]:
+        assert line == thread and c0 <= t0 and t0 + dur <= c0 + cdur
+    (line, _t0, _dur, stats), = events["sched.stall"]
+    assert line == thread and stats["stage"] == "commit"
+    assert 0 < stats["gc_ms"] <= stats["ms"]
+    last = st.stalls()["last"][-1]
+    assert stats["ms"] == pytest.approx(last["s"] * 1e3, abs=1e-3)
+
+
+def test_fast_stages_never_read_the_collectors_ring(monkeypatch):
+    """The normal path is one comparison a booked run."""
+    from tpulab.utils.tracing import part, stage
+
+    def raises(*a):
+        raise AssertionError("the ring was read on a fast run")
+    monkeypatch.setattr(tracing, "_collector_seconds_within", raises)
+    st = _stall_clock()                # the default: 20 ms
+    assert st.slow_s == 0.02
+    for _ in range(50):
+        with stage(st, "plan"):
+            pass
+        with stage(st, "dispatch"):
+            with part(st, "dispatch.arrays"):
+                pass
+            ticket = st.launched()
+        with stage(st, "fetch"):
+            time.sleep(0.0005)
+            st.landed(ticket, "completion")
+        with stage(st, "commit"):
+            with stage(st, "admit"):
+                pass
+        with stage(st, "emit"):
+            pass
+    assert st.stalls()["n"] == 0 and st.turns()["n"] == 49
+    st.slow_s = 0.0                    # and a slow run does read it
+    with pytest.raises(AssertionError, match="ring was read"):
+        with stage(st, "plan"):
+            pass
+
+
+def test_turns_by_cause_sum_to_the_turns():
+    """A scripted sequence: a turn that closes under the cause it opened
+    with, a turn of two spans (a wait inside it renames it: seconds to
+    both causes, the entry to the second), a cause the clock does not
+    name (``other``), and a fetch that opens no turn."""
+    from tpulab.utils.tracing import stage
+    st = _scheduler_clock()
+    nap = 0.003
+
+    def block(*causes):
+        with stage(st, "dispatch"):
+            time.sleep(nap)
+            ticket = st.launched()
+        for cause in causes:
+            with stage(st, "fetch"):
+                st.landed(ticket, cause)
+            with stage(st, "commit"):
+                time.sleep(nap)
+    block("completion")
+    block("joiner", "round")           # one turn, two spans
+    block("no-such-cause")
+    with stage(st, "dispatch"):
+        a = st.launched()
+        b = st.launched()
+    with stage(st, "fetch"):
+        st.landed(a, "k")              # `b` is behind it: no turn
+    with stage(st, "emit"):
+        time.sleep(nap)
+    with stage(st, "fetch"):
+        st.landed(b, "compact")
+    with stage(st, "idle"):            # a wait renames the open turn
+        pass
+    with stage(st, "plan"):
+        time.sleep(nap)
+        st.launched()
+    t = st.turns()
+    by_cause = t["by_cause"]
+    assert tuple(by_cause) == (*st.turn_names,) and "other" in by_cause
+    assert {c: v["n"] for c, v in by_cause.items() if v["n"]} == {
+        "completion": 1, "round": 1, "other": 2}
+    assert sum(v["n"] for v in by_cause.values()) == t["n"] == 4
+    assert sum(v["s"] for v in by_cause.values()) == pytest.approx(
+        t["s"], abs=1e-12)
+    for cause in ("completion", "joiner", "round"):
+        assert by_cause[cause]["s"] >= nap, cause
+    assert by_cause["joiner"]["n"] == 0 and by_cause["k"]["s"] == 0.0
+    assert by_cause["compact"] == {"n": 0, "s": 0.0}   # renamed at once
+    assert by_cause["other"]["s"] >= 2 * nap
+
+
+def test_engine_reports_host_fetches_and_turns_by_cause():
+    """A paged engine on the tiny model: ``host``, ``fetches`` and
+    ``turns.by_cause`` in ``debug_state()["dispatch"]``, consistent and
+    monotone over two snapshots."""
+    from tpulab.engine.paged import ContinuousBatcher as CB
+    assert CB.STALL_S == 0.02
+    cb = _tiny_engine(lanes=2)
+    snaps = []
+    try:
+        assert cb._stages.slow_s == CB.STALL_S
+        for _ in range(2):
+            futs = [cb.submit(np.arange(3 + i, dtype=np.int32), 9)
+                    for i in range(3)]
+            for f in futs:
+                assert len(f.result(timeout=120)) == 9
+            snaps.append(cb.debug_state()["dispatch"])
+    finally:
+        cb.shutdown()
+    for d in snaps:
+        f, turns, host = d["fetches"], d["turns"], d["host"]
+        assert set(f) == {"n", "s", "ready_n", "ready_s", "ready_slow_n",
+                          "ready_slow_s"}
+        assert 0 <= f["ready_slow_n"] <= f["ready_n"] <= f["n"]
+        assert 0 <= f["ready_slow_s"] <= f["ready_s"] <= f["s"]
+        assert f["n"] == d["transfers"]["d2h"] > 0
+        by_cause = turns["by_cause"]
+        assert tuple(by_cause) == (*CB.TURN_CAUSES, "other")
+        assert sum(v["n"] for v in by_cause.values()) == turns["n"] > 0
+        assert sum(v["s"] for v in by_cause.values()) == pytest.approx(
+            turns["s"], abs=1e-9)
+        stalls, pauses = host["stalls"], host["gc"]
+        assert 0 <= stalls["gc_s"] <= stalls["s"] + 1e-12
+        assert stalls["n"] == sum(
+            v["n"] for v in stalls["by_stage"].values())
+        assert tuple(stalls["by_stage"]) == CB.TURN_STAGES
+        assert len(stalls["last"]) == min(stalls["n"], 8)
+        assert set(pauses["n"]) == {"gen0", "gen1", "gen2"}
+
+    def numbers(tree, path=()):
+        for key, v in tree.items():
+            if isinstance(v, dict):
+                yield from numbers(v, path + (key,))
+            elif isinstance(v, (int, float)) and key not in (
+                    "frozen", "max_s"):
+                yield path + (key,), v
+    first = dict(numbers({k: snaps[0][k]
+                          for k in ("fetches", "host", "turns")}))
+    second = dict(numbers({k: snaps[1][k]
+                           for k in ("fetches", "host", "turns")}))
+    assert first.keys() == second.keys()
+    for path, v in first.items():
+        assert v <= second[path], path
+    assert second[("fetches", "n")] > first[("fetches", "n")]
+    assert snaps[1]["host"]["stalls"]["max_s"] >= snaps[0]["host"][
+        "stalls"]["max_s"]
 
 
 def test_wait_counters_match_the_requests():

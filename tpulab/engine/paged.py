@@ -575,10 +575,20 @@ class ContinuousBatcher:
         #: disjoint stages of the scheduler thread, each a ``sched.<stage>``
         #: span in a profiler capture and seconds + entries here; the same
         #: clock reads the turns the thread takes with nothing un-fetched
-        #: on the device's queue, and a dispatch by part
+        #: on the device's queue, a dispatch by part, and the runs of a
+        #: working stage that stood still (beside the collector's pauses,
+        #: which stop this thread whichever thread collects)
+        tracing.watch_collector()
         self._stages = tracing.StageClock(
             self.STAGES, prefix="sched.", turn=self.TURN_STAGES,
-            causes=self.TURN_CAUSES, parts=self.DISPATCH_PARTS)
+            causes=self.TURN_CAUSES, parts=self.DISPATCH_PARTS,
+            slow_s=self.STALL_S)
+        #: the blocking fetches and their seconds; those whose array was
+        #: READY when the fetch began (the device had ended: the host came
+        #: late, and the seconds are the copy and the interpreter lock, no
+        #: wait for the device), and of those the ones of ``STALL_S`` or more
+        self.fetches = {"n": 0, "s": 0.0, "ready_n": 0, "ready_s": 0.0,
+                        "ready_slow_n": 0, "ready_slow_s": 0.0}
         #: request waits, summed where the observers above see them
         #: (seconds, count): submit -> prefill start, submit -> first
         #: token, first token -> second token (what a newly admitted lane
@@ -820,9 +830,23 @@ class ContinuousBatcher:
         return jax.device_put(host, self._rep or self.pool.device)
 
     def _fetch(self, dev) -> np.ndarray:
-        """ONE blocking device -> host fetch, counted."""
+        """ONE blocking device -> host fetch, counted, by whether the
+        device had already ended when it began."""
         self.transfers["d2h"] += 1
-        return np.asarray(dev)
+        f = self.fetches
+        ready = dev.is_ready()
+        t0 = _time.perf_counter()
+        host = np.asarray(dev)
+        dt = _time.perf_counter() - t0
+        f["n"] += 1
+        f["s"] += dt
+        if ready:
+            f["ready_n"] += 1
+            f["ready_s"] += dt
+            if dt >= self.STALL_S:
+                f["ready_slow_n"] += 1
+                f["ready_slow_s"] += dt
+        return host
 
     def _results(self, out, k: Optional[int] = None, spec: bool = False):
         """A dispatch's one result array, fetched and taken apart
@@ -835,6 +859,10 @@ class ContinuousBatcher:
     STAGES = ("admit", "plan", "dispatch", "fetch", "commit", "emit", "idle")
     #: the stages that are work (not waiting): what a turn is made of
     TURN_STAGES = ("admit", "plan", "dispatch", "commit", "emit")
+    #: one run of a working stage this long is a stall (``debug_state()``
+    #: ``["dispatch"]["host"]["stalls"]``): four times the longest mean
+    #: stage of any benchmark cell's turn (5.06 ms of emit, PR 51's ledger)
+    STALL_S = 0.02
     #: why a decode block or a mixed round got no successor before its
     #: fetch (:meth:`_chain_block`)
     BREAK_CAUSES = ("k1", "shutdown_or_reclaim", "released", "completion",
@@ -1354,6 +1382,9 @@ class ContinuousBatcher:
                                    "late_links": self.late_links},
                          "stages": self._stages.stages(),
                          "turns": self._stages.turns(),
+                         "host": {"gc": tracing.collector_pauses(),
+                                  "stalls": self._stages.stalls()},
+                         "fetches": dict(self.fetches),
                          "dispatch_parts": {
                              name.partition(".")[2]: v for name, v
                              in self._stages.parts().items()},

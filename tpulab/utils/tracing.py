@@ -12,7 +12,12 @@ equivalent adds the XLA profiler).
   region's host-clock seconds to an accumulator its caller owns (the
   scheduler's ``debug_state()["dispatch"]["stages"]``), and the same
   clock reads the thread's turns with an empty device queue and the
-  parts of a stage (``["turns"]``, ``["dispatch_parts"]``).
+  parts of a stage (``["turns"]``, ``["dispatch_parts"]``), and names a
+  working stage's run that stood still (``["host"]["stalls"]``).
+- :func:`watch_collector` / :func:`collector_pauses` — the cyclic
+  collector's pauses, which stop every Python thread at once: counters by
+  generation over the process's life and a ``host.gc`` span on the device
+  trace's clock, on the thread that collects.
 - :class:`TraceContext` / :class:`ChromeTraceRecorder` /
   :func:`merge_chrome_traces` — request-scoped distributed tracing: the
   client mints a trace id, carries it over gRPC (request field + metadata),
@@ -22,7 +27,9 @@ equivalent adds the XLA profiler).
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import os
 import threading
 import time
@@ -162,6 +169,66 @@ def annotate(name: str, **kw):
     return jax.profiler.TraceAnnotation(name, **kw)
 
 
+_GENERATIONS = ("gen0", "gen1", "gen2")
+#: what :func:`collector_pauses` reports, counted by :func:`_on_collection`
+_gc = {"n": dict.fromkeys(_GENERATIONS, 0),
+       "s": dict.fromkeys(_GENERATIONS, 0.0), "max_s": 0.0, "collected": 0}
+#: the last pauses of generation 1 and 2, ``(t0, seconds, generation)`` on
+#: ``perf_counter``: what :class:`StageClock` sets a slow run against
+_gc_ring: collections.deque = collections.deque(maxlen=64)
+_gc_open: Optional[tuple] = None     # (t0, span) of the running collection
+
+
+def _on_collection(phase: str, info: Dict[str, int]) -> None:
+    """The process's one entry in ``gc.callbacks``.  It runs on the thread
+    that collects, and a collection never starts inside another, so
+    ``start`` and ``stop`` pair up through one module global."""
+    global _gc_open
+    if phase == "start":
+        span = None
+        if info["generation"]:
+            span = annotate("host.gc", generation=info["generation"])
+            span.__enter__()
+        _gc_open = (time.perf_counter(), span)
+    elif _gc_open is not None:
+        now = time.perf_counter()
+        (t0, span), _gc_open = _gc_open, None
+        dt, gen = now - t0, _GENERATIONS[info["generation"]]
+        _gc["n"][gen] += 1
+        _gc["s"][gen] += dt
+        _gc["collected"] += info["collected"]
+        _gc["max_s"] = max(_gc["max_s"], dt)
+        if span is not None:
+            span.set_metadata(collected=info["collected"])
+            span.__exit__(None, None, None)
+            _gc_ring.append((t0, dt, info["generation"]))
+
+
+def watch_collector() -> None:
+    """Count and span the cyclic collector's pauses from here on
+    (idempotent: ONE entry in ``gc.callbacks`` however often it is
+    called).  A collection stops every Python thread of the process for
+    as long as it lasts, on whichever thread crossed the threshold."""
+    import jax  # noqa: F401  (the callback's annotate() must find it loaded)
+    if _on_collection not in gc.callbacks:
+        gc.callbacks.append(_on_collection)
+
+
+def collector_pauses() -> Dict[str, object]:
+    """Collections and their seconds by generation since
+    :func:`watch_collector` (process-wide, monotone), the longest one,
+    the objects they freed, and the collector's settings now."""
+    return {**_gc, "n": dict(_gc["n"]), "s": dict(_gc["s"]),
+            "threshold": list(gc.get_threshold()),
+            "frozen": gc.get_freeze_count()}
+
+
+def _collector_seconds_within(t0: float, t1: float) -> float:
+    """Seconds of the ring's pauses that lie inside ``[t0, t1]``."""
+    return sum(max(0.0, min(p0 + dt, t1) - max(p0, t0))
+               for p0, dt, _gen in tuple(_gc_ring))
+
+
 class StageClock:
     """Seconds and entries per named stage, for ONE thread (the
     scheduler's).  ``stages()`` is monotone; the stages never overlap (a
@@ -187,14 +254,24 @@ class StageClock:
       an idle wait or the fetch of a program that had already ended,
       is no part of them).  In a capture a turn is a span
       ``<prefix>turn.<cause>``, closed over such a wait and opened again
-      behind it under what that wait gave as the cause.
+      behind it under what that wait gave as the cause; the turn's
+      seconds are booked to the cause its span carries while they pass
+      and its entry to the cause it closes under (``turns()["by_cause"]``).
     - **parts** (:func:`part`): named regions inside a stage that do not
-      pause it, seconds and entries of their own."""
+      pause it, seconds and entries of their own.
+    - **stalls**: one run of seconds booked to a stage in ``turn`` (the
+      stages that are work; a wait is long by nature) that lasted
+      ``slow_s`` or more: counted by stage, with the seconds of it the
+      collector's pauses (:func:`watch_collector`, any thread's) account
+      for, the last few kept whole; in a capture a span
+      ``<prefix>stall`` where the run ends.  A mean cannot hold one run
+      of 100 ms among thousands of 3 ms; this can."""
 
     def __init__(self, names: Iterable[str], prefix: str = "",
                  turn: Iterable[str] = (), causes: Iterable[str] = (),
-                 parts: Iterable[str] = ()):
+                 parts: Iterable[str] = (), slow_s: float = 0.02):
         self.prefix = prefix
+        self.slow_s = slow_s
         self.seconds: Dict[str, float] = {n: 0.0 for n in names}
         self.entries: Dict[str, int] = {n: 0 for n in names}
         self._open: list = []          # the stack of open _Stage
@@ -212,6 +289,15 @@ class StageClock:
         self._in_turn = False
         self._turn = None                     # the open turn's open span
         self._cause: Optional[tuple] = None   # (cause, stats) of landed()
+        self._turn_cause = "other"            # the cause that span carries
+        self.cause_seconds: Dict[str, float] = {
+            c: 0.0 for c in self.turn_names}
+        self.cause_entries: Dict[str, int] = {c: 0 for c in self.turn_names}
+        self.stall_name = prefix + "stall"
+        self._stalls = {"n": 0, "s": 0.0, "max_s": 0.0, "gc_s": 0.0}
+        self._stalls_by_stage = {n: {"n": 0, "s": 0.0}
+                                 for n in self.turn_seconds}
+        self._last_stalls: collections.deque = collections.deque(maxlen=8)
 
     def stages(self) -> Dict[str, Dict[str, float]]:
         return {n: {"s": s, "n": self.entries[n]}
@@ -222,7 +308,18 @@ class StageClock:
         open one's seconds are in as far as its stages have booked."""
         by_stage = dict(self.turn_seconds)
         return {"n": self.turn_entries, "s": sum(by_stage.values()),
-                "stages": by_stage}
+                "stages": by_stage,
+                "by_cause": {c: {"n": self.cause_entries[c], "s": s}
+                             for c, s in self.cause_seconds.items()}}
+
+    def stalls(self) -> Dict[str, object]:
+        """``{"n", "s", "max_s", "gc_s", "by_stage": {stage: {n, s}},
+        "last": [{stage, s, gc_s, in_turn}]}``: the runs of a working
+        stage that lasted ``slow_s`` or more (monotone but ``last``)."""
+        return {**self._stalls,
+                "by_stage": {n: dict(v)
+                             for n, v in self._stalls_by_stage.items()},
+                "last": list(self._last_stalls)}
 
     def parts(self) -> Dict[str, Dict[str, float]]:
         return {p: {"s": s, "n": self.part_entries[p]}
@@ -237,6 +334,7 @@ class StageClock:
             self._book(time.perf_counter())   # the turn's edge, mid-stage
             self._in_turn = False
             self.turn_entries += 1
+            self.cause_entries[self._turn_cause] += 1
             self._close_span()
         return self._launched
 
@@ -262,17 +360,36 @@ class StageClock:
         until the next begins."""
         if self._open:
             top = self._open[-1]
-            self._add(top.name, now - top.t0)
+            self._add(top.name, now - top.t0, now)
             top.t0 = now
         elif self._ended is not None:
             name, when = self._ended
-            self._add(name, now - when)
+            self._add(name, now - when, now)
             self._ended = (name, now)
 
-    def _add(self, name: str, dt: float) -> None:
+    def _add(self, name: str, dt: float, now: float) -> None:
+        """Book the run of ``dt`` seconds that ends at ``now``."""
         self.seconds[name] += dt
         if self._in_turn and name in self.turn_seconds:
             self.turn_seconds[name] += dt
+            self.cause_seconds[self._turn_cause] += dt
+        if dt >= self.slow_s and name in self.turn_seconds:
+            self._stalled(name, dt, now)
+
+    def _stalled(self, name: str, dt: float, now: float) -> None:
+        gc_s = min(dt, _collector_seconds_within(now - dt, now))
+        total, by_stage = self._stalls, self._stalls_by_stage[name]
+        total["n"] += 1
+        total["s"] += dt
+        total["gc_s"] += gc_s
+        total["max_s"] = max(total["max_s"], dt)
+        by_stage["n"] += 1
+        by_stage["s"] += dt
+        self._last_stalls.append({"stage": name, "s": dt, "gc_s": gc_s,
+                                  "in_turn": self._in_turn})
+        with annotate(self.stall_name, stage=name, ms=round(dt * 1e3, 3),
+                      gc_ms=round(gc_s * 1e3, 3)):
+            pass
 
     def _close_span(self) -> None:
         if self._turn is not None:
@@ -284,8 +401,8 @@ class StageClock:
         (cause, stats), self._cause = self._cause or ("other", {}), None
         if self._launched == self._landed:
             self._in_turn = True
-            self._turn = annotate(
-                self.turn_names.get(cause, self.turn_names["other"]), **stats)
+            self._turn_cause = cause if cause in self.turn_names else "other"
+            self._turn = annotate(self.turn_names[self._turn_cause], **stats)
             self._turn.__enter__()
 
 
@@ -310,7 +427,7 @@ class _Stage:
     def __exit__(self, *exc):
         clock = self.clock
         now = time.perf_counter()
-        clock._add(self.name, now - self.t0)
+        clock._add(self.name, now - self.t0, now)
         clock.entries[self.name] += 1
         clock._open.pop()
         if clock._open:
