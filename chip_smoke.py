@@ -41,8 +41,9 @@ Phases, in order; any phase that raises fails the run (exit 1):
               a lane, 16/2 heads of 256 with RoPE over 64, a share of 32 of
               128 softmax-routed experts at top-10 and a gated shared expert)
               on a lane-state store filled with junk: three mixed rounds
-              and a decode step through the ``chunk_gated_delta_rule`` and
-              ragged kernels against the XLA forms, logits and lane state.
+              and a decode step through the ``chunk_gated_delta_rule``,
+              ``gated_delta_step`` and ragged kernels against the XLA forms,
+              logits and lane state.
    evabyte  — a two-layer EvaByte (its published widths: 32 heads on 32 KV
               heads of 128, SwiGLU 11008, windows of 2,048 bytes in chunks
               of 16, 320 rows, eight prediction heads) through
@@ -713,9 +714,10 @@ def phase_keye(smoke: Smoke) -> str:
 def phase_qwen3_next(smoke: Smoke) -> str:
     """Three mixed rounds and a decode step of a Gated DeltaNet / gated
     attention model that holds a share of its experts, over a lane-state
-    store filled with junk (a reused lane): the ``chunk_gated_delta_rule``
-    and ragged kernels against the ``lax.scan`` form and the XLA gather on
-    the same inputs, logits and lane state.  As in phase ``keye_vl2`` a
+    store filled with junk (a reused lane): the ``chunk_gated_delta_rule``,
+    ``gated_delta_step`` (the decode step's lanes, the round's decode rows)
+    and ragged kernels against the ``lax.scan`` form, the XLA one-token rule
+    and the XLA gather on the same inputs, logits and lane state.  As in phase ``keye_vl2`` a
     router's near tie may fall either way under the two forms (the
     attention layer's rows differ by bf16 rounding), so the decode step's
     expert counters say how many assignments differ, and only so many lanes

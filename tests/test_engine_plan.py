@@ -313,6 +313,37 @@ def test_a_geometry_that_admits_no_round_is_refused_before_anything_is_built(
         False, False, True)
 
 
+def test_a_width_the_rule_refuses_keeps_the_xla_rule_in_both_programs(
+        monkeypatch, nothing_allocated):
+    """The tiny Gated DeltaNet model's heads are 16 wide, not whole 128-lane
+    tiles: on a chip the automatic plan takes no kernel, so the decode step
+    and the round both run the XLA rule, and a kernel asked for by name is
+    refused in ``rule_geometry_error``'s words.  The interpreter, which the
+    rule does not bind, runs the kernels in both; the Mamba hybrid's decode
+    step is XLA in either plan; a model without a lane state has no rule."""
+    from tpulab.tpu import platform
+    spec = KINDS["qwen3next-gdn"][0]
+    kernels = dict(decode="kernel", round="kernel")
+    assert _plan(spec, use_kernel=True).state_rule == kernels
+    assert _plan(spec, use_kernel=False).state_rule == dict(
+        decode="xla", round="xla")
+    assert _plan(KINDS["jamba-mamba"][0], use_kernel=True).state_rule == dict(
+        decode="xla", round="kernel")
+    assert _plan(use_kernel=True).state_rule is None
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(platform, "is_tpu", lambda: True)
+    auto = _plan(spec, use_kernel=None, max_len=8192)
+    assert (auto.use_kernel, auto.state_rule) == (
+        False, dict(decode="xla", round="xla"))
+    with pytest.raises(ValueError, match="use_kernel=True: head widths d_k "
+                                         "16, d_v 16 are not whole 128-lane"):
+        _plan(spec, use_kernel=True)
+    wide = specs.qwen3_next_spec(dict(      # and pages of whole 128-lane rows
+        test_qwen3_next.CONFIG, linear_key_head_dim=128,
+        linear_value_head_dim=128, head_dim=64))
+    assert _plan(wide, use_kernel=None, max_len=8192).state_rule == kernels
+
+
 #: the two latent configurations' kernel calls: heads, pages a lane, and the
 #: heads a tile the plan gives a chunk of 512 rows (a row of 640, 512 of
 #: them the value, pages of 16, bf16)

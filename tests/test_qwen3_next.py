@@ -26,12 +26,13 @@ from helpers_steps import mixed_step
 from tpulab.engine.kv_pool import (LaneStateStore, PagedKVPool,
                                    lane_state_shapes)
 from tpulab.engine.paged import ContinuousBatcher
-from tpulab.engine.paged_steps import (_ffn_block, pack_round,
+from tpulab.engine.paged_steps import (_ffn_block, _gdn_mixer, pack_round,
                                        paged_decode_step, paged_mixed_step,
                                        paged_ragged_forward)
 from tpulab.models.spec import init_params, qwen3_next_spec, split_qkvz
 from tpulab.ops.gated_delta_rule import (CHUNK, chunk_gated_delta_rule,
-                                         gated_delta_step)
+                                         gated_delta_step,
+                                         one_token_gated_delta_rule)
 from tpulab.ops.selective_scan import row_flags
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -288,19 +289,120 @@ def test_both_forms_of_the_rule_against_the_recurrence_by_hand(use_kernel, t,
         np.float32))
 
 
-def test_one_token_rule_is_the_recurrence():
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernel-interpret"])
+def test_a_round_moves_the_slots_of_its_segments_and_no_other(use_kernel):
+    """A store that holds a distinct value a slot (layer, lane, head) and a
+    round whose segments touch two lanes of eight, one from its slot and
+    one from zeros: the six other slots of the layer and every other layer
+    are what they were, bit for bit, and the two are written from their
+    segments' last rows (the kernel copies no block of the store: it loads
+    and stores slots)."""
+    lanes, layers, t = 8, 3, CHUNK + 9
+    case = _rule_case(np.random.default_rng(5), t, [(6, CHUNK - 3, 11),
+                                                    (2, 12, 0)], lanes,
+                      layers=layers)
+    q, k, v, g, beta, states, row_lane, row_off, q_lens, kv_lens = case
+    slot = np.arange(layers * lanes * 4, dtype=np.float64).reshape(
+        layers, lanes, 4, 1, 1)
+    states = np.broadcast_to(1 + slot / 8, states.shape).copy()
+    _want_o, want_s = _by_hand(q, k, v, g, beta, states, *case[6:], layer=1)
+    f32 = lambda x: jnp.asarray(x.reshape(t, -1), jnp.float32)   # noqa: E731
+    flags = row_flags(i32(row_lane), i32(row_off), i32(q_lens), i32(kv_lens))
+    before = states.astype(np.float32)
+    _o, s = chunk_gated_delta_rule(
+        f32(q), f32(k), f32(v), f32(g), f32(beta), jnp.asarray(before), 1,
+        i32(row_lane), flags, use_kernel=use_kernel)
+    s = np.asarray(s)
+    others = [lane for lane in range(lanes) if lane not in (2, 6)]
+    np.testing.assert_array_equal(s[1][others], before[1][others])
+    np.testing.assert_array_equal(s[[0, 2]], before[[0, 2]])
+    np.testing.assert_allclose(s[1][[2, 6]], want_s[1][[2, 6]], rtol=2e-5,
+                               atol=5e-6)
+    assert (s[1][[2, 6]] != before[1][[2, 6]]).any(axis=(1, 2, 3)).all()
+
+
+#: the lanes of a one-token step: ``(lane, tokens before it)`` of those that
+#: hold a row, among ``lanes``, and the lanes whose slots hold junk that is
+#: not a number
+ONE_TOKEN = {
+    "all-live": (3, [(0, 5), (1, 2), (2, 9)], []),
+    "some-dead": (5, [(1, 5), (3, 1)], []),
+    "a-fresh-lane-over-garbage": (4, [(0, 3), (2, 0), (3, 7)], [2]),
+    "one-lane": (1, [(0, 4)], []),
+}
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernel-interpret"])
+@pytest.mark.parametrize("case", list(ONE_TOKEN))
+def test_one_token_rule_is_the_recurrence(model, use_kernel, case):
+    """One row a lane on the state store, in XLA and as the kernel
+    ``gated_delta_step`` (interpreter): a live lane's output and new slot
+    are the recurrence's by hand (a lane at position 0 from zeros, over a
+    slot of NaNs); the slots of the lanes without a row and the store's
+    other layers are what they were, bit for bit; and through the mixer
+    of a whole layer so is the convolution's tail."""
+    lanes, rows, garbage = ONE_TOKEN[case]
     rng = np.random.default_rng(7)
-    case = _rule_case(rng, 3, [(0, 1, 5), (1, 1, 0), (2, 1, 2)], lanes=3,
-                      layers=1)
-    q, k, v, g, beta, states, *_ = case
-    want_o, want_s = _by_hand(*case, layer=0)
-    s0 = states[0].copy()
-    s0[1] = 0                       # lane 1 starts at position 0
-    f32 = lambda x: jnp.asarray(x, jnp.float32)          # noqa: E731
-    o, s = gated_delta_step(f32(np.repeat(q, 2, 1)), f32(np.repeat(k, 2, 1)),
-                            f32(v), f32(g), f32(beta), f32(s0))
-    np.testing.assert_allclose(np.asarray(o), want_o, rtol=2e-5, atol=2e-6)
-    np.testing.assert_allclose(np.asarray(s), want_s[0], rtol=2e-5, atol=5e-6)
+    live = np.zeros(lanes, bool)
+    live[[lane for lane, _ in rows]] = True
+    fresh = np.zeros(lanes, bool)
+    fresh[[lane for lane, before in rows if before == 0]] = True
+    # row b is lane b's: a dead lane's row is there and is nobody's
+    q, k, v, g, beta, states, *_ = _rule_case(
+        rng, lanes, [(lane, 1, 1) for lane in range(lanes)], lanes, layers=3)
+    states[:, garbage] = np.nan
+    row_lane = np.where(live, np.arange(lanes), -1).astype(np.int32)
+    q_lens, kv_lens = live.astype(np.int32), np.zeros(lanes, np.int32)
+    for lane, before in rows:
+        kv_lens[lane] = before + 1
+    want_o, want_s = _by_hand(q, k, v, g, beta, states, row_lane,
+                              np.zeros(lanes, np.int32), q_lens, kv_lens,
+                              layer=1)
+    def f32(x):
+        return jnp.asarray(x, jnp.float32).reshape(lanes, -1)
+    before = np.asarray(states, np.float32)
+    o, s = one_token_gated_delta_rule(
+        f32(q), f32(k), f32(v), f32(g), f32(beta), jnp.asarray(before), 1,
+        jnp.asarray(live), jnp.asarray(fresh), use_kernel=use_kernel)
+    o, s = np.asarray(o), np.asarray(s)
+    np.testing.assert_allclose(o[live], want_o.reshape(lanes, -1)[live],
+                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(s[1][live], want_s[1][live], rtol=2e-5,
+                               atol=5e-6)
+    assert np.isfinite(s[1][live]).all()
+    same = lambda a, b: a.tobytes() == b.tobytes()          # noqa: E731
+    assert same(s[1][~live], before[1][~live])
+    assert same(s[0], before[0]) and same(s[2], before[2])
+    # gated_delta_step, the definition, on the slots themselves
+    s0 = jnp.where(jnp.asarray(fresh)[:, None, None, None], 0.0, before[1])
+    o_def, s_def = gated_delta_step(
+        jnp.asarray(np.repeat(q, 2, 1), jnp.float32),
+        jnp.asarray(np.repeat(k, 2, 1), jnp.float32),
+        jnp.asarray(v, jnp.float32), jnp.asarray(g, jnp.float32),
+        jnp.asarray(beta, jnp.float32), s0)
+    np.testing.assert_allclose(o[live], np.asarray(o_def).reshape(
+        lanes, -1)[live], rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(s[1][live], np.asarray(s_def)[live],
+                               rtol=1e-6, atol=1e-7)
+
+    # a whole layer's mixer at the same lanes: the tail of the convolution
+    spec, params = model
+    ssm, conv = (jnp.asarray(rng.standard_normal(a.shape), jnp.float32)
+                 for a in LaneStateStore(spec, lanes, jnp.float32).arrays)
+    h = jnp.asarray(rng.standard_normal((lanes, 1, spec.d_model)),
+                    jnp.float32)
+    _out, (ssm1, conv1) = _gdn_mixer(
+        spec, params["layer1"]["gdn"], 1, h, jnp.asarray(kv_lens - 1)[:, None],
+        jnp.asarray(live)[:, None], (ssm, conv), dict(use_kernel=use_kernel),
+        jnp.float32)
+    for was, now in ((np.asarray(ssm), np.asarray(ssm1)),
+                     (np.asarray(conv).swapaxes(1, 2),
+                      np.asarray(conv1).swapaxes(1, 2))):
+        assert same(now[1][~live], was[1][~live])
+        assert same(np.delete(now, 1, 0), np.delete(was, 1, 0))
+        assert not (now[1][live] == was[1][live]).all()
 
 
 # ----------------------------------------------- the steps, the reference ----
@@ -345,10 +447,17 @@ def test_one_chunk_uneven_chunks_and_token_by_token_agree(model, reference,
 
 @pytest.mark.parametrize("use_kernel", [False, True],
                          ids=["xla", "kernels-interpret"])
-def test_a_round_of_several_lanes_is_each_lane_alone(model, use_kernel):
+@pytest.mark.parametrize("first_chunk", [True, False],
+                         ids=["two-chunks", "a-chunk-and-an-idle-lane"])
+def test_a_round_of_several_lanes_is_each_lane_alone(model, use_kernel,
+                                                     first_chunk):
     """Two lanes' chunks (one a first chunk, one a later chunk) and two
     other lanes' decode rows in ONE packed round give, lane for lane, the
-    logits and the state of four rounds that carry one lane each."""
+    logits and the state of four rounds that carry one lane each: the
+    chunk rows through the chunk kernel, the decode rows beside them
+    through the one-token kernel, on one store.  Without the first chunk
+    its lane is idle over junk, which the round leaves as it was, bit for
+    bit, in every layer."""
     spec, params = model
     rng = np.random.default_rng(4)
     seqs = [rng.integers(0, VOCAB, n) for n in (14, 9, 12, 7)]
@@ -360,12 +469,18 @@ def test_a_round_of_several_lanes_is_each_lane_alone(model, use_kernel):
                       [0, 0, 0, 0], use_kernel)[1]
 
     lengths = [9, 8, 0, 6]
-    prefill = {0: seqs[0][9:14], 2: seqs[2]}        # a later and a first chunk
+    prefill = {0: seqs[0][9:14]}                     # a later chunk
+    if first_chunk:
+        prefill[2] = seqs[2]                         # and a first chunk
     decode = {1: int(seqs[1][8]), 3: int(seqs[3][6])}
     store, tables = _fresh(spec, junk=True)
     together, store, _ = _round(spec, params, warm(store, tables), tables,
                                 prefill, decode, lengths, use_kernel)
     for lane in range(LANES):
+        if lane == 2 and not first_chunk:
+            ssm, conv = _lane_state(store, lane)
+            assert (ssm == 3).all() and (conv == 3).all()
+            continue
         alone, tables = _fresh(spec, junk=True)
         last, alone, _ = _round(
             spec, params, warm(alone, tables), tables,
@@ -525,6 +640,15 @@ def test_lane_work_counts_the_lanes_and_keys_each_program_ran(model):
         assert d["lane_work"]["decode"] == {"passes": 9, "rows": 9,
                                             "keys": sum(range(22, 31))}
         assert d["kinds"]["mixed"] == 3 and d["decode_block_steps"] >= 9
+        # the slots (a lane's state in one of the four Gated DeltaNet
+        # layers) the dispatches held, and those a lane with a row touched
+        state = cb.debug_state()["state"]
+        assert state["rule"] == {"decode": "xla", "round": "xla"}
+        assert state["slots"] == {
+            "round": {"held": 3 * state["lanes"] * 4, "touched": 3 * 4},
+            "decode": {"held": d["decode_block_steps"] * state["lanes"] * 4,
+                       "touched": 9 * 4}}
+        assert state["lanes"] == 3
         futures = [cb.submit(prompt[:n], steps=6) for n in (13, 7)]
         for f in futures:
             f.result(timeout=300)
@@ -652,21 +776,34 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows", [512, 256, 1], ids=["M512", "M256", "M1"])
+@pytest.mark.parametrize("rows", [512, 256, 1, -32, -1],
+                         ids=["M512", "M256", "M1", "one-token-32-lanes",
+                              "one-token-1-lane"])
 def test_mosaic_compiles_the_chunk_kernel_at_the_published_widths(one_chip,
                                                                   rows):
     """Qwen3-Next's widths (16 key heads under 32 value heads of 128), the
     cell's 32 lanes and 6 layers of state, a full round's chunk rows (512;
-    256 before PR 42) and the smallest (one row, no whole chunk): what the interpreter cannot
-    refuse, Mosaic can (tiling, VMEM, a transposed product)."""
-    from tpulab.ops.gated_delta_rule import _rule_call
+    256 before PR 42) and the smallest (one row, no whole chunk); and the
+    one-token kernel at the cell's 32 lanes and at one: what the interpreter
+    cannot refuse, Mosaic can (tiling, VMEM, a transposed product, a
+    transpose of the key and query heads)."""
+    from tpulab.ops.gated_delta_rule import _rule_call, _step_call
 
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     i32s = lambda *dims: shape(*dims, dtype=jnp.int32)      # noqa: E731
-    compiled = _rule_call.lower(
-        shape(rows, 2048), shape(rows, 2048), shape(rows, 4096),
-        shape(rows, 32), shape(rows, 32), shape(6, 32, 32, 128, 128), i32s(1),
-        i32s(rows), i32s(rows), interpret=False).compile()
+    if rows < 0:
+        lanes = -rows
+        compiled = _step_call.lower(
+            shape(lanes, 2048), shape(lanes, 2048), shape(lanes, 4096),
+            shape(lanes, 32), shape(lanes, 32),
+            shape(6, lanes, 32, 128, 128), i32s(1),
+            shape(lanes, dtype=jnp.bool_), shape(lanes, dtype=jnp.bool_),
+            interpret=False).compile()
+    else:
+        compiled = _rule_call.lower(
+            shape(rows, 2048), shape(rows, 2048), shape(rows, 4096),
+            shape(rows, 32), shape(rows, 32), shape(6, 32, 32, 128, 128),
+            i32s(1), i32s(rows), i32s(rows), interpret=False).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

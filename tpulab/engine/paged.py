@@ -1468,11 +1468,24 @@ class ContinuousBatcher:
                           "summary_rows_live": summary_rows,
                           "raw_rows_live": raw_rows}
         if self.state is not None:
+            # a slot is one lane's state in one layer of the store; a
+            # dispatch HOLDS every slot and a pass of a lane through the
+            # layers TOUCHES that lane's (what a rule that visits only the
+            # lanes with a row reads and writes)
+            layers = len(self.model_spec.state_layers)
+            steps = {"decode": self.decode_block_steps,
+                     "round": self.dispatch_kinds["mixed"]}
             out["state"] = {"kind": self.state.kind,
                             "lanes": self.state.lanes,
                             "bytes_per_lane": self.state.bytes_per_lane,
                             "hbm_bytes": self.state.hbm_bytes,
-                            "zero_starts": self.zero_starts}
+                            "zero_starts": self.zero_starts,
+                            "rule": self.plan.state_rule,
+                            "slots": {kind: {
+                                "held": self.state.lanes * layers * n,
+                                "touched":
+                                    self.lane_work[kind]["passes"] * layers}
+                                for kind, n in steps.items()}}
         pc = self.prefix_cache
         if pc is not None:
             out["prefix_cache"] = {"entries": len(pc), "hits": pc.hits,

@@ -567,13 +567,16 @@ def _gdn_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     the slot; the slot is written from the segment's last valid row; rows
     without a token and dead or idle lanes write nothing.
 
-    A decode step (``h (B, 1, D)``) is the one-token delta rule
-    (:func:`tpulab.ops.gated_delta_rule.gated_delta_step`).  A packed round
-    (``seg["row_seg"]``: ``h (1, T, D)``) takes the convolution's taps
-    across segment starts by :func:`_segment_conv`; its chunk rows ``[0,
-    M)`` run the chunk kernel ``chunk_gated_delta_rule`` and its decode rows
-    ``[M, M + B)``, one a lane, the one-token rule (different lanes, so in
-    either order); without kernels every row goes through the sequential
+    A decode step (``h (B, 1, D)``) is the one-token delta rule on the
+    store (:func:`tpulab.ops.gated_delta_rule.one_token_gated_delta_rule`).
+    A packed round (``seg["row_seg"]``: ``h (1, T, D)``) takes the
+    convolution's taps across segment starts by :func:`_segment_conv`; its
+    chunk rows ``[0, M)`` run the chunk kernel ``chunk_gated_delta_rule``
+    and its decode rows ``[M, M + B)``, one a lane, the one-token kernel
+    ``gated_delta_step`` (different lanes, so in either order); each kernel
+    moves the slots of the lanes that hold a row and no other.  Without
+    kernels (``seg["use_kernel"]`` false) a decode step is the XLA form of
+    the one-token rule and every row of a round goes through the sequential
     form."""
     import jax
     import jax.numpy as jnp
@@ -584,7 +587,7 @@ def _gdn_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     ssm, conv = state
     hk, hv, dk, dv = (spec.gdn_k_heads, spec.gdn_v_heads, spec.gdn_k_dim,
                       spec.gdn_v_dim)
-    rep, nk = hv // hk, hk * dk
+    nk = hk * dk
     rows = seg.get("row_seg")
     if rows is None and h.shape[1] != 1:
         raise NotImplementedError(
@@ -614,36 +617,31 @@ def _gdn_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
         qh, kh, v = unit(u[:, :nk]) * dk ** -0.5, unit(u[:, nk:2 * nk]), \
             u[:, 2 * nk:]
     with jax.named_scope("gdn_rule"):
-        def one_token(qh, kh, v, g, beta, live, fresh):
-            """Lane b's one row: the one-token rule on its slot."""
-            s0 = jnp.where(fresh[:, None, None, None], 0.0, ssm[at])
-            o, s1 = gdr.gated_delta_step(
-                jnp.repeat(qh, rep, axis=1), jnp.repeat(kh, rep, axis=1),
-                v.reshape(-1, hv, dv), g, beta, s0)
-            return o.reshape(-1, hv * dv), ssm.at[at].set(
-                jnp.where(live[:, None, None, None], s1, ssm[at]))
-
+        kernel = seg["use_kernel"]
         if rows is None:
-            o, ssm = one_token(qh, kh, v, g, beta, live, fresh)
+            o, ssm = gdr.one_token_gated_delta_rule(
+                qh.reshape(n, nk), kh.reshape(n, nk), v, g, beta, ssm, at,
+                live, fresh, use_kernel=kernel)
         else:
             from tpulab.ops.selective_scan import ROW_ZERO, row_flags
             row_lane, row_off = rows
             flags = row_flags(row_lane, row_off, seg["q_lens"],
                               seg["kv_lens"])
-            # the kernel's rows: all but the lanes' decode rows
-            m = n - seg["q_lens"].shape[0] if seg["use_kernel"] else n
+            # the chunk kernel's rows: all but the lanes' decode rows
+            m = n - seg["q_lens"].shape[0] if kernel else n
             o, ssm = gdr.chunk_gated_delta_rule(
                 qh[:m].reshape(m, nk), kh[:m].reshape(m, nk), v[:m], g[:m],
                 beta[:m], ssm, at, row_lane[:m], flags[:m],
-                use_kernel=seg["use_kernel"])
+                use_kernel=kernel)
             if m < n:
                 # a row without a token reads what the kernel never wrote,
                 # which may not be a number (and 0 x that is not 0 where an
                 # attention layer reads the scratch page it scatters to)
                 o = jnp.where((row_lane[:m] >= 0)[:, None], o, 0.0)
-                o_dec, ssm = one_token(
-                    qh[m:], kh[m:], v[m:], g[m:], beta[m:], row_lane[m:] >= 0,
-                    (flags[m:] & ROW_ZERO) != 0)
+                o_dec, ssm = gdr.one_token_gated_delta_rule(
+                    qh[m:].reshape(n - m, nk), kh[m:].reshape(n - m, nk),
+                    v[m:], g[m:], beta[m:], ssm, at, row_lane[m:] >= 0,
+                    (flags[m:] & ROW_ZERO) != 0, use_kernel=kernel)
                 o = jnp.concatenate([o, o_dec])
     with jax.named_scope("gdn_out"):
         o = _rmsnorm(o.reshape(n, hv, dv), p["norm"]["scale"].astype(f32),

@@ -100,6 +100,20 @@ class EnginePlan:
     latent_tile: Optional[Dict[str, Dict[str, int]]] = None
 
     @property
+    def state_rule(self) -> Optional[Dict[str, str]]:
+        """What runs a lane state's recurrence in the two programs that
+        touch it, ``{"decode": ..., "round": ...}`` each ``"kernel"`` or
+        ``"xla"`` (None without a lane state): with the kernels, a round
+        scans its segments in one and a Gated DeltaNet layer's one-token
+        rule is one too (a decode step, a round's decode rows); a Mamba
+        layer's decode step is XLA in either plan."""
+        if not self.state_kind:
+            return None
+        form = "kernel" if self.use_kernel else "xla"
+        return {"decode": form if self.state_kind == "gdn" else "xla",
+                "round": form}
+
+    @property
     def step_kw(self) -> Dict[str, Any]:
         """The keywords the step programs bind, and are keyed by in the
         process's program memo."""

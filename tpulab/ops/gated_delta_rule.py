@@ -17,10 +17,15 @@ rows without a token and lanes without a segment write nothing.
 
 Three forms of the same function:
 
-- :func:`gated_delta_step`: one token a lane, ``(B, 1)``: a decode step, and
-  the decode rows of a round.  Elementwise float32 over the state, which it
-  reads twice and writes once (both products with the old state in one
-  pass, then the update).
+- :func:`gated_delta_step`: one token a lane on states handed to it, in
+  XLA: the definition, elementwise float32.  :func:`one_token_gated_delta_rule`
+  runs it on the state store, one row a lane: a decode step, and the decode
+  rows of a round.  With ``use_kernel=True`` as a Pallas kernel named
+  ``gated_delta_step``: a grid over the lanes that hold a row (a prefetched
+  visit list; a lane without a row is never visited), a lane's slot of the
+  aliased store loaded once and stored once, both products with the old
+  state and the update from the one loaded block.  Without, the XLA form
+  reads the layer twice, writes it once and merges it back under ``live``.
 - :func:`chunk_gated_delta_rule` with ``use_kernel=True``: the chunk form
   (arXiv:2412.06464, the WY / UT transform with cumulative log-decays) as a
   Pallas kernel named ``chunk_gated_delta_rule``.  Grid over value heads;
@@ -28,8 +33,10 @@ Three forms of the same function:
   *pass* is the part of one chunk that belongs to one lane (a chunk that
   holds the end of one segment and the start of the next makes two), found
   from the row flags outside the kernel; a head's loop runs the live passes
-  in row order with the state in registers, loading it where a segment
-  starts and storing it where one ends.  Inside a pass every product is a
+  in row order with the state in registers, from its lane's slot where a
+  segment starts and to it where one ends.  The store stays where it is
+  (aliased, never a block of it in VMEM): a slot is one DMA each way, and
+  no other slot moves.  Inside a pass every product is a
   matrix product: ``T = (I + tril(beta K K^T * D, -1))^-1`` by doubling
   (``I + A`` with ``A`` nilpotent: ``(I - A)(I + A^2)(I + A^4)...``), ``u =
   T (beta V)``, ``w = T (beta K e^G)``, ``v' = u - w S``, ``o = (Q e^G) S +
@@ -86,6 +93,119 @@ def gated_delta_step(q, k, v, g, beta, s0):
     return o, eg[..., None] * s0 + k[..., :, None] * d[..., None, :]
 
 
+def _step_kernel(layer_ref, lanes_ref, n_ref, fresh_ref, eg_ref, beta_ref,
+                 qk_ref, kq_ref, v_ref, sin_ref, o_ref, sout_ref):
+    """Grid step ``i`` is the ``i``-th lane that holds a row: ``sin_ref`` /
+    ``sout_ref (1, 1, H, d_k, d_v)`` its slot of the aliased store, ``kq_ref
+    (1, 2 Hk, d_k)`` its key heads over its query heads, ``v_ref``, ``o_ref
+    (1, H, d_v)``; a head's ``exp(g)``, ``beta`` and ``q . k`` are scalars
+    in SMEM.  Steps past the count repeat the last lane's blocks (nothing
+    is fetched, the slot is written back once) and do nothing."""
+    del layer_ref                      # the index maps read it
+    i, n = pl.program_id(0), n_ref[0]
+    vh, kh = v_ref.shape[1], kq_ref.shape[1] // 2
+
+    @pl.when(i < n)
+    def _lane():
+        lane = lanes_ref[i]
+        fresh = fresh_ref[lane] != 0
+        # a head's k and q down the sublanes, as the state's rows lie
+        kqt = kq_ref[0].T                              # (d_k, 2 Hk)
+        for j in range(kh):
+            k, q = kqt[:, j:j + 1], kqt[:, kh + j:kh + j + 1]     # (d_k, 1)
+            qk = qk_ref[lane * kh + j]
+            for h in range(j * (vh // kh), (j + 1) * (vh // kh)):
+                eg, beta = eg_ref[lane * vh + h], beta_ref[lane * vh + h]
+                s0 = jnp.where(fresh, 0.0, sin_ref[0, 0, h])
+                ks = (k * s0).sum(0, keepdims=True) * eg           # (1, d_v)
+                qs = (q * s0).sum(0, keepdims=True) * eg
+                d = beta * (v_ref[0, h:h + 1, :] - ks)
+                o_ref[0, h:h + 1, :] = qs + qk * d
+                sout_ref[0, 0, h] = eg * s0 + k * d
+
+    @pl.when((i == 0) & (n == 0))
+    def _no_lane():                    # the one slot the pipeline writes back
+        sout_ref[...] = sin_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _step_call(q, k, v, g, beta, states, layer, live, fresh, interpret: bool):
+    b, (_, _, vh, dk, dv) = q.shape[0], states.shape
+    kh = q.shape[1] // dk
+    if not interpret:
+        err = rule_geometry_error(dk, dv)
+        if err:
+            raise ValueError(f"gated_delta_step: {err}")
+    q, k = q.reshape(b, kh, dk), k.reshape(b, kh, dk)
+    # the lanes that hold a row, in order; past their count the last again
+    n = live.sum().astype(jnp.int32)
+    lanes = jnp.nonzero(live, size=b, fill_value=0)[0].astype(jnp.int32)[
+        jnp.minimum(jnp.arange(b), jnp.maximum(n - 1, 0))]
+    row = lambda i, at, lanes, *_: (lanes[i], 0, 0)               # noqa: E731
+    slot = lambda i, at, lanes, *_: (at[0], lanes[i], 0, 0, 0)    # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # layer, the visit list, its count, fresh, and a head's three scalars
+        num_scalar_prefetch=7,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, 2 * kh, dk), row),           # k over q
+                  pl.BlockSpec((1, vh, dv), row),               # v
+                  pl.BlockSpec((1, 1, vh, dk, dv), slot)],      # the slot
+        out_specs=[pl.BlockSpec((1, vh, dv), row),
+                   pl.BlockSpec((1, 1, vh, dk, dv), slot)],
+    )
+    o, states = pl.pallas_call(
+        _step_kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, vh, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        # operand 9 (the state store, behind seven prefetched scalars) is
+        # output 1: a visited lane's slot is rewritten in place, no other
+        # slot and no other layer ever moves
+        input_output_aliases={9: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="gated_delta_step",
+    )(layer, lanes, n[None], fresh.astype(jnp.int32),
+      jnp.exp(g).reshape(-1), beta.reshape(-1), (q * k).sum(-1).reshape(-1),
+      jnp.concatenate([k, q], axis=1), v.reshape(b, vh, dv), states)
+    # a lane without a row was never visited: its output is what the
+    # buffer held, which may not be a number
+    return jnp.where(live[:, None], o.reshape(b, vh * dv), 0.0), states
+
+
+def one_token_gated_delta_rule(q, k, v, g, beta, states, layer: int, live,
+                               fresh, *, use_kernel: bool,
+                               interpret: bool | None = None):
+    """The rule of one layer at one row a lane: row ``b`` is lane ``b``'s.
+
+    ``q``, ``k (B, Hk * d_k)``, ``v (B, H * d_v)``, ``g``, ``beta (B, H)``
+    as :func:`chunk_gated_delta_rule` takes a round's rows; ``states (L, B,
+    H, d_k, d_v)`` float32 the state store; ``live (B,)`` the lanes that
+    hold a row, ``fresh (B,)`` those among them at position 0, which start
+    from zeros whatever their slot holds.  Returns ``(o (B, H * d_v)
+    float32, states)``: a live lane's slot of layer ``layer`` holds its new
+    state, every other slot and layer what it held, bit for bit."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    if not use_kernel:
+        b, (_, _, vh, dk, dv) = q.shape[0], states.shape
+        rep = vh // (q.shape[1] // dk)
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, states[layer])
+        o, s1 = gated_delta_step(
+            jnp.repeat(q.reshape(b, -1, dk), rep, axis=1),
+            jnp.repeat(k.reshape(b, -1, dk), rep, axis=1),
+            v.reshape(b, vh, dv), g, beta, s0)
+        return o.reshape(b, vh * dv), states.at[layer].set(
+            jnp.where(live[:, None, None, None], s1, states[layer]))
+    if interpret is None:
+        from tpulab.tpu.platform import pallas_interpret
+        interpret = pallas_interpret()
+    return _step_call(q, k, v, g, beta, states,
+                      jnp.asarray(layer, jnp.int32).reshape(1), live, fresh,
+                      interpret=interpret)
+
+
 def _rule_rows(q, k, v, g, beta, states, row_lane, flags):
     """The plain form: one ``lax.scan`` step a row, the whole ``states
     (lanes, H, d_k, d_v)`` in the carry.  ``q``, ``k (T, H, d_k)``."""
@@ -129,10 +249,23 @@ def _passes(row_lane, flags, n_max: int):
 
 def _rule_kernel(layer_ref, n_ref, chunk_ref, lane_ref, flag_ref, q_ref,
                  k_ref, v_ref, cols_ref, grow_ref, lcol_ref, lrow_ref,
-                 sin_ref, o_ref, sout_ref):
-    del layer_ref                      # the index maps read it
-    sout_ref[...] = sin_ref[...]       # lanes without a segment keep theirs
+                 sin_ref, o_ref, sout_ref, s_in, s_out, sem, stored):
+    """One value head's passes.  ``sin_ref`` / ``sout_ref`` are the whole
+    store, left where it is (one buffer: aliased), and only the slots of
+    the round's segments move, a DMA each way a slot.
+
+    The passes are every head's, so a head knows the next head's loads: it
+    starts them (the slots of the passes that start a segment from its
+    slot, in pass order, into the other half of ``s_in``) before its own
+    loop and finds its own, started a grid step ago, complete: a slot's DMA
+    between a pass's products is not hidden by them (2.2 us a head: my
+    chip runs, PR 53).  A
+    pass that ends a segment copies ``s_out`` to the slot, and nobody waits
+    for that until the buffer is needed again, by a later segment or the
+    next head (``stored`` in SMEM says so across grid steps; the last head
+    waits for the last)."""
     f32 = jnp.float32
+    layer, head, heads = layer_ref[0], pl.program_id(0), pl.num_programs(0)
     dot = functools.partial(jnp.dot, preferred_element_type=f32,
                             precision=jax.lax.Precision.HIGHEST)
     dot_nt = functools.partial(                        # a @ b.T
@@ -145,10 +278,41 @@ def _rule_kernel(layer_ref, n_ref, chunk_ref, lane_ref, flag_ref, q_ref,
     jj = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
     eye = (ii == jj).astype(f32)
 
-    def one_pass(p, s):
+    def loads_slot(flag):              # a segment that starts from its slot
+        return (flag & (ROW_START | ROW_ZERO)) == ROW_START
+
+    def load(p, h, j):                 # pass p's slot of head h: its j-th
+        return pltpu.make_async_copy(sin_ref.at[layer, lane_ref[p], h],
+                                     s_in.at[h % 2, j], sem.at[h % 2])
+
+    def store(lane):
+        return pltpu.make_async_copy(s_out, sout_ref.at[layer, lane, head],
+                                     sem.at[2])
+
+    def each_load(fn):
+        def one(p, j):
+            need = loads_slot(flag_ref[p])
+            pl.when(need)(lambda: fn(p, j))
+            return j + need.astype(jnp.int32)
+        jax.lax.fori_loop(0, n_ref[0], one, jnp.int32(0))
+
+    @pl.when(head == 0)
+    def _first():
+        stored[0] = 0
+        each_load(lambda p, j: load(p, head, j).start())
+
+    def next_and_mine(p, j):
+        pl.when(head + 1 < heads)(lambda: load(p, head + 1, j).start())
+        load(p, head, j).wait()
+    each_load(next_and_mine)
+
+    def one_pass(p, carry):
+        s, j = carry
         c, lane, flag = chunk_ref[p], lane_ref[p], flag_ref[p]
         rows = pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
-        first = jnp.where((flag & ROW_ZERO) != 0, 0.0, sin_ref[0, lane, 0])
+        # a segment at position 0 starts from zeros whatever its slot holds
+        first = jnp.where((flag & ROW_ZERO) != 0, 0.0,
+                          s_in[head % 2, jnp.minimum(j, s_in.shape[1] - 1)])
         s = jnp.where((flag & ROW_START) != 0, first, s)
         mine = lcol_ref[c] == lane                     # (C, 1) the pass's rows
         both = mine & (lrow_ref[c] == lane)            # (C, C)
@@ -176,11 +340,15 @@ def _rule_kernel(layer_ref, n_ref, chunk_ref, lane_ref, flag_ref, q_ref,
 
         @pl.when((flag & ROW_END) != 0)
         def _store():
-            sout_ref[0, lane, 0] = s
-        return s
+            pl.when(stored[0] != 0)(lambda: store(lane).wait())
+            s_out[...] = s
+            store(lane).start()
+            stored[0] = 1
+        return s, j + loads_slot(flag).astype(jnp.int32)
 
     jax.lax.fori_loop(0, n_ref[0], one_pass,
-                      jnp.zeros(sin_ref.shape[3:], f32))
+                      (jnp.zeros(s_out.shape, f32), jnp.int32(0)))
+    pl.when((head == heads - 1) & (stored[0] != 0))(lambda: store(0).wait())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -216,7 +384,6 @@ def _rule_call(q, k, v, g, beta, states, layer, row_lane, flags,
     key_head = lambda h, *_: (0, h // (vh // kh))          # noqa: E731
     per_head = lambda h, *_: (h, 0, 0, 0)                  # noqa: E731
     whole = lambda h, *_: (0, 0, 0)                        # noqa: E731
-    state = lambda h, layer, *_: (layer[0], 0, h, 0, 0)    # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,         # layer, passes, their chunk/lane/flag
         grid=(vh,),
@@ -228,18 +395,25 @@ def _rule_call(q, k, v, g, beta, states, layer, row_lane, flags,
             pl.BlockSpec((1, nc, 1, CHUNK), per_head),          # G along lanes
             pl.BlockSpec((nc, CHUNK, 1), whole),                # rows' lanes
             pl.BlockSpec((nc, 1, CHUNK), whole),
-            pl.BlockSpec((1, lanes, 1, dk, dv), state),         # the states
+            pl.BlockSpec(memory_space=pl.ANY),                  # the store
         ],
         out_specs=[pl.BlockSpec((tp, dv), head),
-                   pl.BlockSpec((1, lanes, 1, dk, dv), state)],
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[
+            # the slots a head loads, for this head and the next: as many
+            # as segments can start in the round
+            pltpu.VMEM((2, min(lanes, tp), dk, dv), jnp.float32),
+            pltpu.VMEM((dk, dv), jnp.float32),                  # a slot out
+            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SMEM((1,), jnp.int32)],                       # on its way?
     )
     o, states = pl.pallas_call(
         _rule_kernel, grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((tp, vh * dv), jnp.float32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
         # operand 12 (the state store, behind five prefetched scalars) is
-        # output 1: the layer's blocks are rewritten in place, the other
-        # layers never move
+        # output 1: the segments' slots are rewritten in place, no other
+        # slot and no other layer ever moves
         input_output_aliases={12: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
