@@ -407,7 +407,7 @@ class PagedKVPool:
 
 def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
     """``((shape, dtype) of ssm, (shape, dtype) of conv)``: the ONE
-    definition of what a model's Mamba or Gated DeltaNet layers
+    definition of what a model's Mamba, Gated DeltaNet or CCA layers
     (``spec.state_kind``) keep a lane.
 
     ``ssm``   ``(mamba layers, lanes, d_state, d_inner)`` float32: the SSM
@@ -424,8 +424,21 @@ def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
     Kind ``"gdn"`` keeps the same pair: ``ssm`` is the delta rule's
     matrix-valued state ``(layers, lanes, value heads, d_k, d_v)`` float32
     (a head's ``(d_k, d_v)`` whole tiles), ``conv`` the tail of the
-    convolution over the ``[q | k | v]`` channels."""
+    convolution over the ``[q | k | v]`` channels.
+
+    Kind ``"cca"`` keeps tails only, three of them and no recurrent matrix,
+    each ``(layers, inputs kept, lanes, channels)`` in ``dtype`` as ``conv``
+    is: the last ``cca_taps[0] - 1`` rows of ``c = [q~ ; k~]`` (what the
+    depthwise taps reach back to), the last ``cca_taps[1] - 1`` rows of the
+    depthwise convolution's output ``a`` (the grouped taps'), and ``h W_v2``
+    of the lane's last token (the value's shifted half)."""
     n_layers = len(spec.state_layers)
+    if spec.state_kind == "cca":
+        c = (spec.n_heads + spec.n_kv_heads) * spec.head_dim
+        return tuple(((n_layers, kept, lanes, width), np.dtype(dtype))
+                     for kept, width in (
+                         (spec.cca_taps[0] - 1, c), (spec.cca_taps[1] - 1, c),
+                         (1, spec.n_kv_heads * spec.head_dim // 2)))
     if spec.state_kind == "gdn":
         return (((n_layers, lanes, spec.gdn_v_heads, spec.gdn_k_dim,
                   spec.gdn_v_dim), np.dtype(np.float32)),
@@ -441,7 +454,8 @@ def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
 class LaneStateStore:
     """Per-lane recurrent state of a model's Mamba or Gated DeltaNet layers,
     beside the page store: ``arrays = (ssm, conv)``, shaped by
-    :func:`lane_state_shapes`.
+    :func:`lane_state_shapes` (a CCA model's three tails: ``arrays = (c
+    tail, a tail, shifted value)``).
 
     A lane's slot belongs to whatever sequence runs in the lane.  Nothing
     here resets it: the step programs start a segment at position 0 from

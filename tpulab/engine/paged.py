@@ -289,10 +289,12 @@ class ContinuousBatcher:
     lives in a :class:`~tpulab.engine.kv_pool.LaneStateStore`, a slot a
     lane, which rotates through every dispatch beside the page store (the
     run-ahead chain enqueues block N+1 on the state block N returns); the
-    page store then holds the attention layers alone.  A segment that
-    starts at position 0 starts from zeros on the device, so admission,
-    lane reuse and a re-prefill after preemption need no reset dispatch
-    (``debug_state()["state"]``).
+    page store then holds the attention layers alone.  A CCA layer
+    (``spec.cca_taps``) owns a layer of BOTH: K/V rows in the page store and
+    its convolutions' tails in the lane's slot (``debug_state()["cca"]``).
+    A segment that starts at position 0 starts from zeros on the device, so
+    admission, lane reuse and a re-prefill after preemption need no reset
+    dispatch (``debug_state()["state"]``).
     A learned indexer (``spec.index_topk``) keeps one index key a token a
     layer in ``pool.index``, under the page ids of K and V, and the pair
     ``(pool.kv, pool.index)`` rotates through every dispatch the same way;
@@ -401,8 +403,8 @@ class ContinuousBatcher:
             plan.pool_layers, 0 if plan.latent else plan.n_kv,
             0 if plan.latent else plan.head_dim, plan.kv_dtype, device,
             mesh=mesh, latent_width=plan.latent, index_dim=plan.index_dim)
-        #: the per-lane recurrent state of the layers that keep one (Mamba,
-        #: Gated DeltaNet: ``spec.state_layers``; None without any):
+        #: the per-lane state of the layers that keep one (Mamba, Gated
+        #: DeltaNet, CCA: ``spec.state_layers``; None without any):
         #: rotates through every dispatch beside ``pool.kv`` (_kv_state)
         self.state = (LaneStateStore(spec, lanes, compute_dtype,
                                      self.pool.device)
@@ -1445,6 +1447,15 @@ class ContinuousBatcher:
                 "stream_bytes_per_row": spec.hc_mult * spec.d_model
                 * np.dtype(self.plan.compute_dtype).itemsize,
                 # token rows that went through the streams, cumulative
+                "rows": {kind: self.lane_work[kind]["rows"]
+                         for kind in ("round", "decode")}}
+        if self.model_spec is not None and self.model_spec.cca_taps:
+            out["cca"] = {
+                "taps": list(self.model_spec.cca_taps),
+                # what a lane keeps beside its pages, and a token in them
+                "state_bytes_per_lane": self.state.bytes_per_lane,
+                "kv_bytes_per_token": self.pool.bytes_per_token,
+                # token rows that went through the layers, cumulative
                 "rows": {kind: self.lane_work[kind]["rows"]
                          for kind in ("round", "decode")}}
         if self._sparse is not None:

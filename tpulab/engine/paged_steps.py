@@ -24,11 +24,12 @@ TPU-first mechanics:
   block's carry and a round's logits stay on the device.
 
 Every forward runs ONE layer block (:func:`_layer_block`) read from a
-:class:`~tpulab.models.spec.ModelSpec`.  For a model with Mamba or Gated
-DeltaNet layers the ``kv_pool`` every step function takes, donates, carries
-through its scan and returns is the pair ``(page store, lane state)``: the
-attention layers' pages and the other layers' per-lane recurrent state
-(:class:`~tpulab.engine.kv_pool.LaneStateStore` ``.arrays``), one pytree;
+:class:`~tpulab.models.spec.ModelSpec`.  For a model with Mamba, Gated
+DeltaNet or CCA layers the ``kv_pool`` every step function takes, donates,
+carries through its scan and returns is the pair ``(page store, lane
+state)``: the attention layers' pages and the per-lane state of the layers
+that keep one (:class:`~tpulab.engine.kv_pool.LaneStateStore` ``.arrays``;
+a CCA layer keeps both), one pytree;
 for a model with a learned indexer it is the pair ``(page store, index
 rows)`` (``PagedKVPool.kv`` and ``.index``).
 The functions keep their ``__name__``: a trace names a program
@@ -430,46 +431,48 @@ def _row_lens(spec, kv_lens):
     return jnp.where(kv_lens > 0, spec.cache_row(kv_lens - 1) + 1, 0)
 
 
-def _segment_conv(x, w, conv, at, seg, live=None, fresh=None):
-    """The depthwise causal convolution of a lane-state mixer over the rows
-    ``x (rows, C)`` with taps ``w (k, C)``, from the lanes' kept tails
-    ``conv[at] (k - 1, lanes, C)``: ``(acc (rows, C) float32 before bias
-    and activation, conv with layer at's new tails)``.
+def _segment_window(x, k, tails, seg, live=None, fresh=None):
+    """The row gather of a lane-state layer's causal window: the last ``k``
+    inputs of every row of ``x (rows, C)``, from the rows themselves and the
+    lanes' kept tails ``tails (k - 1, lanes, C)`` (one layer of the store):
+    ``(window (k, rows, C)``, oldest first, ``window[k - 1]`` is ``x``; the
+    lanes' new tails, shaped and typed as ``tails``)``.  What
+    :func:`_segment_conv` weighs a channel, and what CCA's grouped taps
+    multiply a head and its shifted value reads one token back
+    (:func:`_cca_qkv`).
 
-    The one rule of :func:`_mamba_mixer`, for the convolution: a segment at
+    The one rule of :func:`_mamba_mixer`, for the window: a segment at
     position 0 starts from a zero tail whatever the slot holds, any other
     from the slot; the new tail is the last ``k - 1`` inputs of ``[tail ;
     segment]``; rows without a token and dead or idle lanes write nothing.
     A decode step (no ``seg["row_seg"]``: row b is lane b's one token;
     ``live`` the lanes that run, ``fresh`` those at position 0) shifts the
-    lane's window by one.  A packed round takes row t's tap ``back`` tokens
-    back from row ``t - back`` of its segment where the row's offset
+    lane's window by one.  A packed round takes row t's input ``back``
+    tokens back from row ``t - back`` of its segment where the row's offset
     reaches that far, else from the lane's tail."""
     import jax.numpy as jnp
 
-    f32 = jnp.float32
-    k = w.shape[0]
     rows = seg.get("row_seg")
     if rows is None:
-        tail = jnp.where(fresh[None, :, None], 0, conv[at])
+        tail = jnp.where(fresh[None, :, None], 0, tails)
         window = jnp.concatenate([tail, x[None]], axis=0)      # (k, B, C)
-        acc = (window.astype(f32) * w[:, None, :]).sum(0)
-        new_tail = jnp.where(live[None, :, None], window[1:], conv[at])
+        new_tail = jnp.where(live[None, :, None], window[1:], tails)
     else:
         row_lane, row_off = rows
         q_lens, kv_lens = seg["q_lens"], seg["kv_lens"]
         b, t = q_lens.shape[0], x.shape[0]
         lane = jnp.maximum(row_lane, 0)
         fresh = (q_lens > 0) & (kv_lens == q_lens)
-        tail = jnp.where(fresh[None, :, None], 0, conv[at])
-        acc = x.astype(f32) * w[k - 1]
+        tail = jnp.where(fresh[None, :, None], 0, tails)
+        window = [x]
         for back in range(1, k):
             # the input ``back`` tokens back: a row of this round, or
             # what the lane kept of the rounds before
             kept = tail[jnp.clip(row_off - back + k - 1, 0, k - 2), lane]
-            src = jnp.where((row_off >= back)[:, None],
-                            jnp.pad(x, ((back, 0), (0, 0)))[:t], kept)
-            acc = acc + src.astype(f32) * w[k - 1 - back]
+            window.insert(0, jnp.where(
+                (row_off >= back)[:, None],
+                jnp.pad(x, ((back, 0), (0, 0)))[:t], kept))
+        window = jnp.stack(window)
         # the lane's new tail: the last k - 1 of [tail ; segment]
         spread = seg["rows"][0]
         s = q_lens[:, None] + jnp.arange(k - 1)[None, :] - (k - 1)
@@ -479,7 +482,21 @@ def _segment_conv(x, w, conv, at, seg, live=None, fresh=None):
             tail, jnp.clip(s + k - 1, 0, k - 2).T[:, :, None], axis=0)
         new_tail = jnp.where((s >= 0).T[:, :, None],
                              from_rows.transpose(1, 0, 2), kept)
-    return acc, conv.at[at].set(new_tail.astype(conv.dtype))
+    return window, new_tail.astype(tails.dtype)
+
+
+def _segment_conv(x, w, conv, at, seg, live=None, fresh=None):
+    """The depthwise causal convolution of a lane-state mixer over the rows
+    ``x (rows, C)`` with taps ``w (k, C)`` (tap ``j`` weighs the input ``k -
+    1 - j`` tokens back), from the lanes' kept tails ``conv[at] (k - 1,
+    lanes, C)`` (:func:`_segment_window`, whose rule it follows): ``(acc
+    (rows, C) float32 before bias and activation, conv with layer at's new
+    tails)``."""
+    import jax.numpy as jnp
+
+    window, tails = _segment_window(x, w.shape[0], conv[at], seg, live, fresh)
+    acc = (window.astype(jnp.float32) * w[:, None, :]).sum(0)
+    return acc, conv.at[at].set(tails)
 
 
 def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
@@ -651,6 +668,107 @@ def _gdn_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     return out.reshape(h.shape), (ssm, conv)
 
 
+def _cca_qkv(spec, p, at, h, pos, valid, state, seg, compute_dtype):
+    """What compressed convolutional attention (ZAYA1's CCA, ``p`` the
+    layer's ``cca`` leaves) hands the dense decoder's K/V walk, and the lane
+    state ``state = (c tails, a tails, shifted value)`` with layer ``at``'s
+    new tails: ``(q (B, M, H, D), k, v (B, M, KV, D), state)``.
+    :func:`_layer_block` scatters the rows into the lane's pages and attends
+    as it does for plain GQA (scale ``D^-0.5``), so a CCA layer reads and
+    writes layer ``at`` of BOTH stores.
+
+    On ROWS (a decode step's ``B``, a packed round's ``T``): ``c = [q~ ;
+    k~]`` and the value's two halves from one product; the depthwise taps
+    over ``c`` and the grouped taps (a ``head_dim x head_dim`` block a head a
+    tap) over their output ``a``, each from its own window of the rows and
+    the lane's tail (:func:`_segment_window`: ``a`` is rounded to the
+    compute type before it is windowed, so that a row reads the same ``a``
+    of the token before it from the round as from the tail); the q-k mean of
+    the PRE-convolution ``c`` added to both; each head's L2 norm in float32,
+    ``q`` times ``sqrt(D)`` and ``k`` times ``tau sqrt(D)``; RoPE over the
+    first ``rotary_dim`` columns; ``v = [h_t W_v1 ; h_(t-1) W_v2]``, the
+    second half read through the same window, cut into the KV heads in that
+    order.  :func:`_mamba_mixer`'s one rule holds for the three tails, so a
+    reused lane, a re-prefill and a resume need no reset.  The padded ``(B,
+    M)`` form is refused as a lane state's is."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import apply_rope, qmat
+
+    f32 = jnp.float32
+    hq, hkv, d = spec.n_heads, spec.n_kv_heads, spec.head_dim
+    nq, nc, rep = hq * d, (hq + hkv) * d, hq // hkv
+    rows = seg.get("row_seg")
+    if rows is None and h.shape[1] != 1:
+        raise NotImplementedError(
+            "CCA layers run in a decode step or a packed round "
+            "(paged_mixed_step), not in the padded (B, M) form")
+    n = h.shape[0] * h.shape[1]
+    live = fresh = None
+    if rows is None:
+        live, fresh = valid[:, 0], pos[:, 0] == 0
+    with jax.named_scope("cca_proj"):
+        proj = (h @ qmat(p["in_proj"], compute_dtype)).reshape(n, -1)
+        c, v1, v2 = (proj[:, :nc], proj[:, nc:nc + hkv * d // 2],
+                     proj[:, nc + hkv * d // 2:])
+    with jax.named_scope("cca_mix"):
+        tail_c, tail_a, tail_v = state
+        acc, tail_c = _segment_conv(c, p["conv0_w"].astype(f32), tail_c, at,
+                                    seg, live, fresh)
+        a = (acc + p["conv0_b"].astype(f32)).astype(compute_dtype)
+        win, tails = _segment_window(a, spec.cca_taps[1], tail_a[at], seg,
+                                     live, fresh)
+        tail_a = tail_a.at[at].set(tails)
+        conv = jnp.einsum(
+            "jngd,jgde->nge", win.reshape(win.shape[:2] + (hq + hkv, d)),
+            qmat(p["conv1_w"], compute_dtype),
+            preferred_element_type=f32) + p["conv1_b"].astype(f32).reshape(
+                hq + hkv, d)
+        # the q-k mean, from the values before the convolutions: a query
+        # head with its KV head's key, a KV head with its query heads' mean
+        qt = c[:, :nq].astype(f32).reshape(n, hkv, rep, d)
+        kt = c[:, nq:].astype(f32).reshape(n, hkv, 1, d)
+        q = conv[:, :hq].reshape(n, hkv, rep, d) + (qt + kt) / 2
+        k = conv[:, hq:].reshape(n, hkv, 1, d) + (
+            qt.mean(2, keepdims=True) + kt) / 2
+
+        def unit(t):               # a head over its L2 norm, times sqrt(D)
+            return t * jax.lax.rsqrt(jnp.maximum(
+                jnp.square(t).sum(-1, keepdims=True), 1e-24)) * d ** 0.5
+        q = unit(q).reshape(h.shape[:2] + (hq, d))
+        k = (unit(k) * p["tau"].astype(f32).reshape(hkv, 1, 1)).reshape(
+            h.shape[:2] + (hkv, d))
+        if spec.rope_theta:
+            rot = spec.rotary_dim or d
+            q, k = (jnp.concatenate(
+                [apply_rope(t[..., :rot], pos, spec.rope_theta),
+                 t[..., rot:]], axis=-1) for t in (q, k))
+        win, tails = _segment_window(v2, 2, tail_v[at], seg, live, fresh)
+        tail_v = tail_v.at[at].set(tails)
+        v = jnp.concatenate([v1, win[0]], axis=-1).reshape(k.shape)
+    return q.astype(compute_dtype), k, v, (tail_c, tail_a, tail_v)
+
+
+def _residual(spec, p, name, x, y):
+    """A sublayer's output ``y`` joined to the residual ``x``: ``x + y``, or
+    with residual scaling (``spec.res_scale``; ``p[name]`` the sublayer's
+    four vectors ``s_r b_r s_o b_o``, ``name`` ``"res_attn"`` or
+    ``"res_ffn"``) ``s_r (x + b_r) + s_o (y + b_o)``, in float32, held in
+    the residual's dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    if not spec.res_scale:
+        return x + y.astype(x.dtype)
+    p = p[name]
+    with jax.named_scope("res_scale"):
+        f32 = jnp.float32
+        return (p["s_r"].astype(f32) * (x.astype(f32) + p["b_r"].astype(f32))
+                + p["s_o"].astype(f32) * (y.astype(f32)
+                                          + p["b_o"].astype(f32))
+                ).astype(x.dtype)
+
+
 def _gated_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx,
                      seg, compute_dtype):
     """GQA attention of one layer with an output gate (``spec.attn_gate``:
@@ -783,7 +901,8 @@ def _streams(spec, x, out: bool = False):
                             x.shape[:-1] + (spec.hc_mult, x.shape[-1]))
 
 
-def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
+def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None,
+               routed=None):
     """``x + ffn(norm(x))`` of one layer: the dense FFN, or the routed
     experts (router kind ``spec.router``; of the router's ``E`` experts the
     share ``spec.expert_first`` / ``spec.experts_held`` whose weights are
@@ -799,7 +918,13 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
 
     With hyper-connections (``spec.hc_mult``) ``x`` is the streams ``(B, M,
     n, D)``: the FFN reads the row :func:`_mhc_pre` makes of them (the
-    layer's ``hc_ffn``) and :func:`_mhc_post` writes its output back."""
+    layer's ``hc_ffn``) and :func:`_mhc_post` writes its output back.
+
+    With residual scaling (``spec.res_scale``) the sum is :func:`_residual`'s
+    with the layer's ``res_ffn``.  With the ``"mlp"`` router the expert
+    layer returns ``((x, state), stats)``: ``state`` is what its router
+    hands to the next layer's (depth averaging), which is handed it as
+    ``routed`` (None on the first expert layer)."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _dense_ffn, _rmsnorm
@@ -813,19 +938,20 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
         if streams is not None:
             return _mhc_post(streams, *maps,
                              _dense_ffn(p, h, compute_dtype)), None
-        x = x + _dense_ffn(p, h, compute_dtype).astype(x.dtype)
+        x = _residual(spec, p, "res_ffn", x, _dense_ffn(p, h, compute_dtype))
         if shortcut is not None:
             with jax.named_scope("moe_shortcut"):
                 x = x + shortcut
         return x, None
     from tpulab.parallel.moe import routed_ffn
     b, m = x.shape[:2]
-    y, stats = routed_ffn(p["moe"], h.reshape(b * m, -1), spec.top_k,
-                          compute_dtype, router=spec.router, act="swiglu",
-                          scale=spec.routed_scale, norm=spec.norm_topk,
-                          valid=valid.reshape(-1), first=spec.expert_first,
-                          held=spec.experts_held or None,
-                          zero=spec.zero_experts)
+    y, stats, *state = routed_ffn(
+        p["moe"], h.reshape(b * m, -1), spec.top_k, compute_dtype,
+        router=spec.router, act="swiglu", scale=spec.routed_scale,
+        norm=spec.norm_topk, valid=valid.reshape(-1),
+        first=spec.expert_first, held=spec.experts_held or None,
+        zero=spec.zero_experts,
+        prev=routed, eps=spec.rms_eps)
     y = y.reshape(b, m, -1)
     if kind == "shortcut":
         return (x + _dense_ffn(p, h, compute_dtype).astype(x.dtype),
@@ -841,7 +967,8 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None):
             y = y + _dense_ffn(p["shared"], h, compute_dtype)
     if streams is not None:
         return _mhc_post(streams, *maps, y), stats
-    return x + y.astype(x.dtype), stats
+    x = _residual(spec, p, "res_ffn", x, y)
+    return ((x, *state) if state else x), stats
 
 
 def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
@@ -907,6 +1034,18 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     it.  The step functions widen the embedding into the streams and sum
     them before the final norm (:func:`_streams`).
 
+    A CCA layer (``spec.cca_taps``; ZAYA1's compressed convolutional
+    attention) owns a layer of BOTH stores: ``kv_pool`` is the pair ``(page
+    store, lane state)`` as a hybrid's is; :func:`_cca_qkv` makes ``q``,
+    ``k`` and ``v`` from the lane's tails and writes the new ones into the
+    second, and the plain GQA walk below scatters the rows into the first
+    and attends.  With residual scaling (``spec.res_scale``) both
+    sublayers join the residual through :func:`_residual`.  With the
+    ``"mlp"`` router every layer but the last returns its ``x`` as the pair
+    ``(x, router state)`` and the next layer takes it apart, as a
+    ``"shortcut"`` layer's pair travels: the state is a function of the
+    token, handed on inside the program and never stored.
+
     Kept short, the K/V kernel called from here and the rest in functions
     of their own: on the v5e host, tracing a kernel body costs more with
     every Python frame between the step function and the ``pallas_call``
@@ -915,13 +1054,18 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     and a helper inside the kernel; the dense cell's set-up grew 10 %,
     96 -> 106 s, until the count was the parent's again).
     """
+    from contextlib import nullcontext
+
+    import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
                                            split_qkv)
 
-    shortcut = streams = None
+    shortcut = streams = routed = None
     if layer and spec.layer_kinds[layer - 1] == "shortcut":
         x, shortcut = x
+    if layer and spec.router == "mlp":
+        x, routed = x
     if spec.hc_mult:
         streams, (x, *maps) = x, _mhc_pre(spec, p["hc_attn"], x)
     h = _rmsnorm(x, p["ln1"]["scale"], spec.rms_eps)
@@ -946,6 +1090,8 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
             x, stats = _ffn_block(spec, p, layer, x + mixed, valid,
                                   compute_dtype)
             return x, (kv_pool, state), stats
+    elif spec.cca_taps:
+        kv_pool, state = kv_pool
     if spec.attention == "mla":
         attn, kv_pool = _mla_attention(spec, p, layer, h, pos, kv_pool,
                                        page_idx, slot_idx, seg,
@@ -961,12 +1107,17 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     else:
         b, m = x.shape[:2]
         at = spec.store_layer(layer)       # its layer of the page store
-        q, knew, vnew = split_qkv(h @ qmat(p["wqkv"], compute_dtype), b, m,
-                                  spec.n_heads, spec.n_kv_heads,
-                                  spec.head_dim)
-        if spec.rope_theta:
-            q = apply_rope(q, pos, spec.rope_theta)
-            knew = apply_rope(knew, pos, spec.rope_theta)
+        if spec.cca_taps:
+            # ... and of the lane state: q, k and v from its convolutions
+            q, knew, vnew, state = _cca_qkv(spec, p["cca"], at, h, pos,
+                                            valid, state, seg, compute_dtype)
+        else:
+            q, knew, vnew = split_qkv(h @ qmat(p["wqkv"], compute_dtype), b,
+                                      m, spec.n_heads, spec.n_kv_heads,
+                                      spec.head_dim)
+            if spec.rope_theta:
+                q = apply_rope(q, pos, spec.rope_theta)
+                knew = apply_rope(knew, pos, spec.rope_theta)
         tail = knew.shape[2:]
         kv_pool = _scatter_kv(kv_pool, at, page_idx, slot_idx,
                               knew.reshape(page_idx.shape + tail),
@@ -1006,11 +1157,15 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         # (The gather path computes every row, and the dense golden holds its
         # padded form to the parent's bits in the rows without a token too.)
         attn = jnp.where(valid[..., None], attn, 0)
-    attn = attn @ qmat(p["wo"], compute_dtype)
+    with jax.named_scope("cca_out") if spec.cca_taps else nullcontext():
+        attn = attn @ qmat(p["wo"], compute_dtype)
     x, stats = _ffn_block(
         spec, p, layer,
-        x + attn if streams is None else _mhc_post(streams, *maps, attn),
-        valid, compute_dtype, shortcut)
+        (_residual(spec, p, "res_attn", x, attn) if streams is None
+         else _mhc_post(streams, *maps, attn)),
+        valid, compute_dtype, shortcut, routed)
+    if spec.router == "mlp" and layer == spec.n_layers - 1:
+        x = x[0]                # nobody reads the last router's state
     return x, (kv_pool if state is None else (kv_pool, state)), stats
 
 

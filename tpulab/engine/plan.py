@@ -48,8 +48,9 @@ class EnginePlan:
     mesh: Optional[Any]
     #: model-axis shards of the page payloads (1 on one device)
     n_shards: int
-    #: the kind of per-lane recurrent state ("mamba", "gdn"; None without
+    #: the kind of per-lane state ("mamba", "gdn", "cca"; None without
     #: layers that keep one); the page store then holds the attention layers
+    #: (a CCA layer owns a layer of both)
     state_kind: Optional[str]
     #: a learned indexer: index rows beside the K/V pages, the pair rotated
     #: through every dispatch as a hybrid's (pages, state) is
@@ -106,9 +107,12 @@ class EnginePlan:
         ``"xla"`` (None without a lane state): with the kernels, a round
         scans its segments in one and a Gated DeltaNet layer's one-token
         rule is one too (a decode step, a round's decode rows); a Mamba
-        layer's decode step is XLA in either plan."""
+        layer's decode step is XLA in either plan, and a CCA layer's tails
+        are XLA in both programs (its K/V walk is the page store's)."""
         if not self.state_kind:
             return None
+        if self.state_kind == "cca":
+            return {"decode": "xla", "round": "xla"}
         form = "kernel" if self.use_kernel else "xla"
         return {"decode": form if self.state_kind == "gdn" else "xla",
                 "round": form}
@@ -139,7 +143,7 @@ def kernel_error(plan: EnginePlan, cap: int, verify_width: int):
     from tpulab.ops.ragged_attention import (kernel_geometry_error,
                                              latent_geometry_error)
     spec = plan.spec
-    if plan.state_kind:
+    if plan.state_kind in ("mamba", "gdn"):
         from tpulab.ops.gated_delta_rule import rule_geometry_error
         from tpulab.ops.selective_scan import scan_geometry_error
         err = (scan_geometry_error(spec.d_inner, spec.d_state)

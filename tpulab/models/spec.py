@@ -229,6 +229,53 @@ rope_inv_freq`), and the factor YaRN puts on the softmax scale is folded
 into ``wq_b`` at load time (:func:`mla_scales`, :func:`scale_queries`), so
 the latent kernel's call is ``glm4_moe_lite``'s.  The prediction layer is
 not built.
+
+``zaya`` (ZAYA1-8B): every layer is *compressed convolutional attention*
+(CCA, arXiv:2510.04476) on K/V pages AND a lane state, then an expert block
+behind an MLP router.  CCA: ``c = [q~ ; k~] = h [W_q | W_k]`` (the query
+heads first); two causal convolutions over the sequence, depthwise
+(``cca_taps[0]`` taps a channel) then grouped by head (``cca_taps[1]`` taps
+of one ``head_dim x head_dim`` block a head); a q-k mean from the
+PRE-convolution ``c`` added to both; an L2 norm a head in float32 (``q``
+times ``sqrt(head_dim)``, ``k`` times ``tau sqrt(head_dim)``, ``tau`` a
+learned scalar a KV head); RoPE over the first
+``rotary_dim`` columns; ``v = [h_t W_v1 ; h_(t-1) W_v2]`` cut into the KV
+heads in that order.  K and V rows go to the lane's pages (mixer ``"cca"``
+owns a layer of the page store) and the convolutions' tails and the
+shifted value's to the lane-state store (``state_kind`` ``"cca"``: the same
+layer owns a layer of that too).  Each sublayer's residual is *scaled*
+(``res_scale``): ``x <- s_r (x + b_r) + s_o (f(norm(x)) + b_o)``.  The
+router (kind ``"mlp"``) is ``r = h W_d + b_d`` (``router_width``), *depth
+averaging* ``r <- r + gamma r_prev`` with the state the layer before handed
+on (after ITS averaging; the first expert layer has none), an
+RMSNorm, two GELU layers of ``router_width`` and a projection onto the
+router's columns, ``p = softmax`` in float32, the ``top_k`` of ``p + bias``
+chosen and weighted by ``p``; the last ``zero_experts`` columns are the
+skip column(s) (``p_e h``).  :func:`zaya_spec` reads the published keys.
+A layer has ``wo (n_heads * head_dim, d_model)``, ``res_attn`` / ``res_ffn``,
+``moe`` and, under ``cca``, the first five rows of:
+
+=============  ==========================================================
+``in_proj``    ``(d_model, (n_heads + 2 * n_kv_heads) * head_dim)``,
+               columns ``[q~ | k~ | v1 | v2]`` (``v1`` and ``v2`` each
+               ``n_kv_heads * head_dim / 2`` wide)
+``conv0_w``    ``(cca_taps[0], (n_heads + n_kv_heads) * head_dim)``: tap
+               ``j`` weighs the input ``cca_taps[0] - 1 - j`` tokens back
+               (the published depthwise ``conv1d`` weight, transposed);
+               ``conv0_b`` a channel
+``conv1_w``    ``(cca_taps[1], n_heads + n_kv_heads, head_dim, head_dim)``:
+               tap ``j``'s block of head ``g``, inputs by outputs (the
+               published grouped ``conv1d`` weight ``(out, in / groups,
+               taps)``, transposed); ``conv1_b`` a channel
+``tau``        ``(n_kv_heads,)``
+``res_attn`` ``res_ffn``   ``{"s_r", "b_r", "s_o", "b_o"}`` of ``d_model``
+``moe``        ``router {"down" (d_model, W), "down_b" (W,), "gamma" (W,)
+               (not on layer 0), "norm" {"scale" (W,)}, "w1" "w2" (W, W),
+               "b1" "b2" (W,), "w3" (W, E + Z)}``, ``bias (E + Z,)``,
+               ``w13`` / ``w2`` of the ``E`` FFN experts
+=============  ==========================================================
+
+The head is tied to the embedding (no ``lm_head``).
 """
 
 from __future__ import annotations
@@ -264,6 +311,7 @@ class ModelSpec:
     rms_eps: float = 1e-6
     rope_theta: Optional[float] = None
     mixers: Tuple[str, ...] = ()            # "attention" | "mamba" | "gdn"
+                                            # | "cca" (set from cca_taps)
     d_inner: int = 0                        # mamba, all four
     d_state: int = 0
     d_conv: int = 0                         # mamba and gdn
@@ -274,6 +322,8 @@ class ModelSpec:
     qk_norm: bool = False                   # RMSNorm over each head of q and k
     router: str = "sigmoid_bias"            # | "softmax" (no selection bias)
                                             # | "softmax_bias" (all columns)
+                                            # | "mlp" (an MLP, softmax_bias's
+                                            #   choice and weights)
     gdn_k_heads: int = 0                    # Gated DeltaNet, all four (+ d_conv)
     gdn_v_heads: int = 0
     gdn_k_dim: int = 0
@@ -296,12 +346,58 @@ class ModelSpec:
     rope_scaling: Tuple[float, ...] = ()    # YaRN: (factor, original max
                                             # positions, beta_fast,
                                             # beta_slow); () = none
+    cca_taps: Tuple[int, ...] = ()          # CCA (gqa): taps of the depthwise
+                                            # and of the grouped convolution
+                                            # over [q ; k]; () = none
+    router_width: int = 0                   # router "mlp": its hidden width
+    res_scale: bool = False                 # s_r (x + b_r) + s_o (f + b_o)
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
             raise ValueError(f"unknown attention kind {self.attention!r}")
-        if self.router not in ("sigmoid_bias", "softmax", "softmax_bias"):
+        if self.router not in ("sigmoid_bias", "softmax", "softmax_bias",
+                               "mlp"):
             raise ValueError(f"unknown router kind {self.router!r}")
+        if (self.router == "mlp") != (self.router_width > 0):
+            raise ValueError('router="mlp" gives router_width (its hidden '
+                             "width), and no other router has one")
+        if self.cca_taps:
+            # a CCA layer owns a layer of the page store AND of the lane
+            # state store: what else keeps rows or a state a layer is
+            # refused by name
+            beside = {
+                "latent attention": self.attention != "gqa",
+                "an indexer": bool(self.index_topk),
+                "EVA windows": bool(self.eva_window),
+                "an output gate": self.attn_gate,
+                "hyper-connections": bool(self.hc_mult),
+                "a shortcut layer": "shortcut" in self.layer_kinds,
+                "Mamba layers": "mamba" in self.mixers,
+                "Gated DeltaNet layers": "gdn" in self.mixers}
+            for other, there in beside.items():
+                if there:
+                    raise ValueError(
+                        f"CCA beside {other} is not implemented: a CCA "
+                        "layer keeps K/V pages and its convolutions' tails, "
+                        "on plain GQA attention alone")
+            if len(self.cca_taps) != 2 or min(self.cca_taps) < 2:
+                raise ValueError(
+                    f"cca_taps {self.cca_taps}: the taps (>= 2 each) of the "
+                    "depthwise and of the grouped convolution")
+            if set(self.mixers) - {"cca"}:
+                raise ValueError(f"mixers {self.mixers}: with cca_taps every "
+                                 "layer's mixer is cca")
+            if self.n_kv_heads * self.head_dim % 2:
+                raise ValueError("CCA cuts the value in two halves")
+            object.__setattr__(self, "mixers", ("cca",) * self.n_layers)
+        elif "cca" in self.mixers:
+            raise ValueError("mixer cca comes with cca_taps")
+        if self.res_scale and (self.hc_mult or "shortcut" in self.layer_kinds
+                               or set(self.mixers) & {"mamba", "gdn"}):
+            raise ValueError(
+                "res_scale scales the plain residual around attention and a "
+                "dense or expert FFN (no hyper-connections, no shortcut "
+                "layer, no Mamba or Gated DeltaNet layer)")
         if self.index_topk or self.index_heads or self.index_dim:
             if min(self.index_heads, self.index_dim, self.index_topk) < 1:
                 raise ValueError("an indexer gives index_heads, index_dim "
@@ -329,9 +425,9 @@ class ModelSpec:
                     "and every mixer is attention")
         mixers = self.mixers or ("attention",) * self.n_layers
         if (len(mixers) != self.n_layers
-                or set(mixers) - {"attention", "mamba", "gdn"}):
+                or set(mixers) - {"attention", "mamba", "gdn", "cca"}):
             raise ValueError(f"mixers {mixers} does not name attention, "
-                             f"mamba or gdn for each of {self.n_layers} "
+                             f"mamba, gdn or cca for each of {self.n_layers} "
                              "layers")
         if "gdn" in mixers:
             if (self.attention != "gqa" or "attention" not in mixers
@@ -483,8 +579,8 @@ class ModelSpec:
     @property
     def state_kind(self) -> Optional[str]:
         """The kind of per-lane state the model's layers keep beside the
-        pages: ``"mamba"``, ``"gdn"`` or None."""
-        for kind in ("mamba", "gdn"):
+        pages: ``"mamba"``, ``"gdn"``, ``"cca"`` or None."""
+        for kind in ("mamba", "gdn", "cca"):
             if kind in self.mixers:
                 return kind
         return None
@@ -496,14 +592,16 @@ class ModelSpec:
 
     @property
     def attention_layers(self) -> Tuple[int, ...]:
-        """The layers that own a layer of the page store."""
-        return tuple(i for i, k in enumerate(self.mixers) if k == "attention")
+        """The layers that own a layer of the page store (a CCA layer owns
+        one of each store)."""
+        return tuple(i for i, k in enumerate(self.mixers)
+                     if k in ("attention", "cca"))
 
     def store_layer(self, layer: int) -> int:
         """Layer ``layer``'s index in the store of its mixer's kind: the
         page store's layer axis for an attention layer, the lane-state
         store's for a Mamba or Gated DeltaNet layer (``layer`` itself where
-        every mixer is attention)."""
+        every mixer is attention, or CCA, which owns that layer of both)."""
         return self.mixers[:layer].count(self.mixers[layer])
 
 
@@ -797,6 +895,55 @@ def xing4_spec(config: Dict[str, Any]) -> ModelSpec:
                       float(scaling["beta_slow"])))
 
 
+def zaya_spec(config: Dict[str, Any]) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type`` ``zaya``):
+    every layer (``layer_types`` all ``"hybrid"``) compressed convolutional
+    attention and ``num_experts`` experts behind the MLP router, whose last
+    column is the skip column (the family's ``zaya_use_mod``; depth
+    averaging its ``zaya_use_eda``; residual scaling its
+    ``scale_residual_merge``: the published config of this model has no key
+    for the three, the configuration file's ``assumed`` says so).  Refuses
+    what the layer block does not compute."""
+    kinds = set(config.get("layer_types") or ["hybrid"])
+    if kinds != {"hybrid"}:
+        raise ValueError(f"layer_types {sorted(kinds)} is not implemented "
+                         "(hybrid alone: no sliding-window layer)")
+    if config.get("sliding_window") is not None:
+        raise ValueError("sliding_window is not implemented")
+    if config.get("attention_bias"):
+        raise ValueError("attention_bias is not implemented")
+    if config.get("lm_head_bias"):
+        raise ValueError("lm_head_bias is not implemented")
+    if not config.get("tie_word_embeddings", True):
+        raise ValueError("tie_word_embeddings false is not implemented (the "
+                         "head is the embedding)")
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {config['hidden_act']!r} is not "
+                         "implemented (SwiGLU experts)")
+    rope = (config.get("rope_parameters") or {}).get("hybrid") or config
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"rope_type {rope['rope_type']!r} is not "
+                         "implemented")
+    n_layers, head_dim = (int(config["num_hidden_layers"]),
+                          int(config["head_dim"]))
+    return ModelSpec(
+        n_layers=n_layers, d_model=int(config["hidden_size"]),
+        n_heads=int(config["num_attention_heads"]),
+        n_kv_heads=int(config["num_key_value_heads"]), head_dim=head_dim,
+        rotary_dim=int(head_dim * float(rope.get("partial_rotary_factor",
+                                                 1))),
+        rope_theta=float(rope["rope_theta"]),
+        rms_eps=float(config["rms_norm_eps"]),
+        cca_taps=(int(config["cca_time0"]), int(config["cca_time1"])),
+        res_scale=True,
+        layer_kinds=("moe",) * n_layers,
+        n_experts=int(config["num_experts"]) + 1, zero_experts=1,
+        top_k=int(config["num_experts_per_tok"]),
+        moe_ff=int(config["moe_intermediate_size"]), n_shared=0,
+        router="mlp", router_width=int(config["router_hidden_size"]),
+        norm_topk=False)
+
+
 def mla_scales(config: Dict[str, Any]) -> Tuple[float, float]:
     """``(query factor, latent factor)`` that a config puts on latent
     attention and the program folds into its matrices at load time:
@@ -901,7 +1048,7 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     """Seeded random float32 parameters in the layouts above: an MLA (+
     expert) decoder, a GQA decoder with an indexer or a Gated DeltaNet /
     attention hybrid, with an untied output head, or a Mamba/attention
-    hybrid with a tied one (no ``lm_head``).
+    hybrid or a CCA decoder with a tied one (no ``lm_head``).
     Weights normal ``scale``, norm scales 1 (LayerNorm biases 0), the
     ``"sigmoid_bias"`` router's selection bias drawn like a weight (not
     zero: choosing with it and weighting without it must differ), the
@@ -918,7 +1065,9 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     the convolution as Mamba's.  The EVA scorers ``eva_mu`` / ``eva_phi``
     are a unit normal cut at two deviations (the published
     initialisation).  The hyper-connections' leaves are
-    :func:`init_hyper_connection`'s."""
+    :func:`init_hyper_connection`'s; a CCA layer's convolutions, its key
+    temperature, the residual scaling and the MLP router are
+    :func:`zaya_leaf`'s (normal ``scale`` would switch each of them off)."""
     import jax
     import jax.numpy as jnp
 
@@ -929,8 +1078,11 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                          "lane state, decoders with an indexer, an output "
                          "gate or EVA windows; dense ones come from "
                          "tpulab.models.transformer")
+    # (twice the keys where zaya_leaf draws a layer's further leaves: the
+    # other kinds keep the draws a seed always gave them)
+    zaya = bool(spec.cca_taps or spec.res_scale or spec.router == "mlp")
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
-                                 16 * spec.n_layers + 4))
+                                 (32 if zaya else 16) * spec.n_layers + 4))
 
     def w(*shape):
         return jax.random.normal(next(keys), shape, jnp.float32) * scale
@@ -941,9 +1093,12 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     def norm(n):
         return {"scale": jnp.ones((n,), jnp.float32)}
 
+    def drawn(path, *shape):       # a leaf zaya_leaf draws, by its name
+        return zaya_leaf(path, shape, next(keys))
+
     d, h = spec.d_model, spec.n_heads
     params: Dict[str, Any] = {"embed": w(vocab, d), "final_norm": norm(d)}
-    if not spec.mamba_layers:
+    if not spec.mamba_layers and not spec.cca_taps:
         params["lm_head"] = w(d, vocab)
     if spec.pred_heads > 1:
         params["mtp_heads"] = w(d, (spec.pred_heads - 1) * vocab)
@@ -980,6 +1135,17 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                 "a_log": jnp.log(uniform(1e-6, 16.0, spec.gdn_v_heads)),
                 "norm": norm(spec.gdn_v_dim),
                 "out_proj": w(nv, d)}
+        elif spec.mixers[i] == "cca":
+            hd, c = spec.head_dim, (h + spec.n_kv_heads) * spec.head_dim
+            p["cca"] = {
+                "in_proj": w(d, c + spec.n_kv_heads * hd),
+                "conv0_w": drawn("['conv0_w']", spec.cca_taps[0], c),
+                "conv0_b": w(c),
+                "conv1_w": drawn("['conv1_w']", spec.cca_taps[1],
+                                 h + spec.n_kv_heads, hd, hd),
+                "conv1_b": w(c),
+                "tau": drawn("['tau']", spec.n_kv_heads)}
+            p["wo"] = w(h * hd, d)
         elif spec.attention == "mla":
             p.update(
                 wq_a=w(d, spec.q_lora_rank), q_norm=norm(spec.q_lora_rank),
@@ -1013,7 +1179,18 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
         if kind != "dense":
             f, fs = spec.moe_ff, spec.n_shared * spec.moe_ff
             held = spec.experts_held or spec.ffn_experts
-            p["moe"] = {"router": w(d, spec.n_experts),
+            if spec.router == "mlp":
+                r, at = spec.router_width, "['router']['%s']"
+                router = {
+                    "down": drawn(at % "down", d, r), "down_b": w(r),
+                    "norm": norm(r), "w1": drawn(at % "w1", r, r),
+                    "b1": w(r), "w2": drawn(at % "w2", r, r), "b2": w(r),
+                    "w3": drawn(at % "w3", r, spec.n_experts)}
+                if i:           # depth averaging: none on the first
+                    router["gamma"] = drawn(at % "gamma", r)
+            else:
+                router = w(d, spec.n_experts)
+            p["moe"] = {"router": router,
                         "bias": w(spec.n_experts),
                         "w13": w(held, d, 2 * f),
                         "w2": w(held, f, d)}
@@ -1033,8 +1210,58 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
         if spec.hc_mult:
             p.update(hc_attn=init_hyper_connection(next(keys), spec),
                      hc_ffn=init_hyper_connection(next(keys), spec))
+        if spec.res_scale:
+            for name in ("res_attn", "res_ffn"):
+                p[name] = {"s_r": drawn("['s_r']", d), "b_r": w(d),
+                           "s_o": drawn("['s_o']", d), "b_o": w(d)}
         params[f"layer{i}"] = p
     return params
+
+
+def zaya_leaf(path: str, shape, key):
+    """One leaf of a ``zaya`` tree that is NOT drawn normal 0.02, float32,
+    by the end of its tree path (``jax.tree_util.keystr``); None for every
+    other leaf.  Each constant is where normal 0.02 would switch the leaf's
+    mechanism off and a program without it would pass for one with it:
+
+    ``conv0_w`` normal 0.7 and ``conv1_w`` normal ``(taps * head_dim)^-0.5``:
+    the convolved part of ``q`` and ``k`` about as large as the q-k mean it
+    is added to (at 0.02 it is 3 % of it, and a lost tail would not show);
+    ``tau`` uniform 0.8-1.2 (not 0.02: a key of that length is no key);
+    the router's ``down`` normal ``d_model^-0.5``, ``w1`` and ``w2`` normal
+    ``1.5 W^-0.5``, ``w3`` normal ``2 W^-0.5``, ``gamma`` uniform 0.5-1: the
+    softmax has another largest column from token to token and the state
+    handed on changes the choice (at 0.02 ``p`` is flat and the selection
+    bias alone picks one column for every token).  ``w2`` and ``w3`` have
+    each column's mean over its inputs taken out: a GELU's outputs share a
+    positive mean, which a column that does not sum to zero turns into an
+    offset of its own, the same for every token, and at these widths the
+    offsets outweigh what a token adds (without it a column took 69 % of
+    4,096 rows and others none; with it every column of every layer takes
+    2-12 %);
+    ``s_r`` and ``s_o`` uniform 0.8-1.2 (a residual scaled by 0.02 ends the
+    signal in two layers)."""
+    import jax
+    import jax.numpy as jnp
+
+    name = path.rsplit("['", 1)[-1].rstrip("']")
+    if "['router']" in path:
+        std = {"down": shape[0] ** -0.5, "w1": 1.5 * shape[0] ** -0.5,
+               "w2": 1.5 * shape[0] ** -0.5,
+               "w3": 2.0 * shape[0] ** -0.5}.get(name)
+        if name == "gamma":
+            return jax.random.uniform(key, shape, jnp.float32, 0.5, 1.0)
+        if name in ("w2", "w3"):
+            w = std * jax.random.normal(key, shape, jnp.float32)
+            return w - w.mean(axis=0, keepdims=True)
+    elif name in ("tau", "s_r", "s_o"):
+        return jax.random.uniform(key, shape, jnp.float32, 0.8, 1.2)
+    else:
+        std = {"conv0_w": 0.7,
+               "conv1_w": (shape[0] * shape[-1]) ** -0.5}.get(name)
+    if std is None:
+        return None
+    return std * jax.random.normal(key, shape, jnp.float32)
 
 
 def init_hyper_connection(key, spec: ModelSpec) -> Dict[str, Any]:

@@ -7,7 +7,7 @@ MoE transformer of the dry run (:func:`moe_ffn`) and the expert-parallel
 form (:func:`make_expert_parallel_ffn`).  It is *exact*: no capacity, no
 dropped token, whatever the routing.
 
-Routing (:func:`route`), three router kinds:
+Routing (:func:`route`), four router kinds:
 
 ``"softmax"``       top-k of the router logits, softmax over the chosen k;
 ``"sigmoid_bias"``  DeepSeek-V3 / GLM-4.x ``noaux_tc``: scores ``s =
@@ -17,7 +17,14 @@ Routing (:func:`route`), three router kinds:
                     chosen k (``+ 1e-20``) and scaled;
 ``"softmax_bias"``  LongCat-Flash: the same with ``s = softmax(x W_g)`` over
                     ALL the router's columns (and there without the
-                    normalisation: a chosen column weighs ``scale * s``).
+                    normalisation: a chosen column weighs ``scale * s``);
+``"mlp"``           ZAYA1: ``"softmax_bias"``'s choice and weights on the
+                    logits of an MLP, not of a matrix: ``r = x W_d + b_d``,
+                    *depth averaging* ``r <- r + gamma r_prev`` with the
+                    state ``r_prev`` the layer before handed on, ``u =
+                    RMSNorm(r)``, ``z = W_3 gelu(W_2 gelu(W_1 u + b_1) +
+                    b_2)`` (the exact GELU).  ``route`` returns ``r`` as a
+                    third value: the state this layer hands on.
 
 The router's last ``zero`` columns may be *identity experts*
 (:func:`routed_ffn`): they hold no weights and return their input, so a row
@@ -59,21 +66,49 @@ def init_moe_params(d_model: int = 64, d_ff: int = 128, n_experts: int = 8,
     }
 
 
+def _mlp_logits(r, x, prev, eps):
+    """The ``"mlp"`` router's ``(logits (N, E), state (N, W))`` of rows ``x``:
+    ``r`` its leaves (``down``, ``down_b``, ``gamma`` unless it is the first
+    expert layer, ``norm``, ``w1 b1 w2 b2 w3``), ``prev`` the state the
+    layer before handed on (None: none).  Float32 at full precision, as the
+    matrix routers' one product is."""
+    f32 = jnp.float32
+    dot = lambda a, w: jnp.dot(a, w.astype(f32),
+                               precision=jax.lax.Precision.HIGHEST)
+    state = dot(x.astype(f32), r["down"]) + r["down_b"].astype(f32)
+    if prev is not None and "gamma" in r:
+        state = state + r["gamma"].astype(f32) * prev
+    u = state * jax.lax.rsqrt(jnp.square(state).mean(-1, keepdims=True)
+                              + eps) * r["norm"]["scale"].astype(f32)
+    for w, b in (("w1", "b1"), ("w2", "b2")):
+        u = jax.nn.gelu(dot(u, r[w]) + r[b].astype(f32), approximate=False)
+    return dot(u, r["w3"]), state
+
+
 def route(router_w, x, top_k: int, kind: str = "softmax", bias=None,
-          scale: float = 1.0, norm: bool = True):
+          scale: float = 1.0, norm: bool = True, prev=None,
+          eps: float = 1e-6):
     """(N, D) rows -> ``(idx (N, k) int32, weights (N, k) float32)``, in
     float32 whatever the rows' dtype (``lax.top_k`` breaks ties
-    deterministically: tied scores still choose exactly k experts)."""
+    deterministically: tied scores still choose exactly k experts).  Kind
+    ``"mlp"`` (``router_w`` the MLP's leaves, ``prev`` the state handed on
+    to it, ``eps`` its norm's) returns ``(idx, weights, state (N, W))``."""
     with jax.named_scope("moe_router"):
-        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
+        state = ()
+        if kind == "mlp":
+            logits, handed = _mlp_logits(router_w, x, prev, eps)
+            state = (handed,)
+        else:
+            logits = jnp.dot(x.astype(jnp.float32),
+                             router_w.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
         top_k = min(top_k, logits.shape[-1])
         if kind == "softmax":
             vals, idx = jax.lax.top_k(logits, top_k)
             return idx, jax.nn.softmax(vals, axis=-1)
         if kind == "sigmoid_bias":
             s = jax.nn.sigmoid(logits)
-        elif kind == "softmax_bias":
+        elif kind in ("softmax_bias", "mlp"):
             s = jax.nn.softmax(logits, axis=-1)
         else:
             raise ValueError(f"unknown router kind {kind!r}")
@@ -81,7 +116,7 @@ def route(router_w, x, top_k: int, kind: str = "softmax", bias=None,
         w = jnp.take_along_axis(s, idx, axis=1)
         if norm:
             w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
-        return idx, w * scale
+        return (idx, w * scale, *state)
 
 
 def routing_stats(idx, n_experts: int, valid=None, first: int = 0,
@@ -145,10 +180,13 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
 def routed_ffn(params: Dict[str, Any], x, top_k: int,
                compute_dtype=jnp.float32, router: str = "softmax",
                act: str = "gelu", scale: float = 1.0, norm: bool = True,
-               valid=None, first: int = 0, held=None, zero: int = 0):
+               valid=None, first: int = 0, held=None, zero: int = 0,
+               prev=None, eps: float = 1e-6):
     """Route (N, D) rows and run the experts: ``(out (N, D) float32, stats
-    (E + 2,))``.  ``params``: ``router (D, E)``, ``bias (E,)`` for the
-    ``"sigmoid_bias"`` and ``"softmax_bias"`` routers, and the experts as
+    (E + 2,))``, and with the ``"mlp"`` router ``(out, stats, state)``:
+    what :func:`route` hands on, ``prev`` and ``eps`` being its arguments.
+    ``params``: ``router (D, E)`` (the ``"mlp"`` router's leaves), ``bias
+    (E,)`` for the routers that choose by it, and the experts as
     ``w13``/``w2`` (SwiGLU) or ``w1``/``w2`` (GELU).  ``zero``: the router's
     last ``zero`` columns are identity experts, the ``E - zero`` before
     them FFN experts.  ``first`` / ``held``: the share of the FFN experts
@@ -156,10 +194,10 @@ def routed_ffn(params: Dict[str, Any], x, top_k: int,
     routed over all ``E`` columns and the output is the part the held
     experts give plus, for every row, the identity part."""
     from tpulab.models.transformer import qmat, weight_shape
-    idx, weights = route(params["router"], x, top_k, router,
-                         params.get("bias"), scale, norm)
+    idx, weights, *state = route(params["router"], x, top_k, router,
+                                 params.get("bias"), scale, norm, prev, eps)
     w_in = params["w13" if act == "swiglu" else "w1"]
-    n_experts = params["router"].shape[-1]
+    n_experts = params["bias" if router == "mlp" else "router"].shape[-1]
     if zero and held is None:
         held = n_experts - zero
     if weight_shape(w_in)[0] != (n_experts if held is None else held):
@@ -174,7 +212,7 @@ def routed_ffn(params: Dict[str, Any], x, top_k: int,
         with jax.named_scope("moe_zero"):
             out = out + jnp.where(idx >= n_experts - zero, weights, 0.0).sum(
                 axis=-1, keepdims=True) * x.astype(jnp.float32)
-    return out, routing_stats(idx, n_experts, valid, first, held)
+    return (out, routing_stats(idx, n_experts, valid, first, held), *state)
 
 
 def moe_ffn(params: Dict[str, Any], x: jnp.ndarray, top_k: int = 2,
