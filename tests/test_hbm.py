@@ -561,7 +561,6 @@ def test_hbm_metrics_poll():
 
 def test_pool_grow_shrink_tracked_bytes():
     pool = PagedKVPool(4, 8, 1, 2, 16, jnp.float32)
-    pool.prefer_low_pages = True
     pn = pool.page_nbytes
     assert pool.hbm_bytes == 4 * pn
     # prefer-low allocation packs the bottom, keeping the top shrinkable

@@ -250,7 +250,7 @@ def _latent_pages(use_kernel):
         assert cb.pool.kv.dtype == jnp.float32
         prompt = np.asarray([3, 14, 1, 5, 9, 2, 6, 11, 8, 7, 13], np.int32)
         cb.submit(prompt, 1).result(timeout=120)
-        # page 0 is scratch; the stream's pages are the three highest ids
+        # page 0 is scratch; the stream's pages are the three lowest ids
         return spec, np.asarray(cb.pool.kv)[:, 1:]
     finally:
         cb.shutdown()
@@ -277,9 +277,9 @@ def test_latent_pages_hold_the_row_once(plan):
     pad; nothing stored twice, nothing expanded."""
     spec, pages = _latent_pages(use_kernel=(plan == "ragged_kernel"))
     assert list(pages.shape) == LATENT_GOLDEN["shape"]
-    used = pages[:, -3:]                      # 11 tokens: 4 + 4 + 3 slots
-    assert not pages[:, :-3].any()            # no other page was written
-    rows = used[:, ::-1].reshape(2, 12, 128)  # pages pop from the top
+    used = pages[:, :3]                       # 11 tokens: 4 + 4 + 3 slots
+    assert not pages[:, 3:].any()             # no other page was written
+    rows = used.reshape(2, 12, 128)           # one ascending run of ids
     assert not rows[:, 11].any()              # nor the slot past the prompt
     rows = rows[:, :11]
     assert not rows[..., spec.latent_width:].any()     # the pad stays zero
@@ -296,6 +296,9 @@ def test_latent_pages_hold_the_row_once(plan):
     norms = (k_r[0] ** 2).reshape(11, 2, 2).sum(axis=1)
     np.testing.assert_allclose(norms, np.broadcast_to(norms[:1], norms.shape),
                                rtol=1e-5)
-    got = hashlib.sha256(np.asarray(pages, jnp.bfloat16).tobytes()
+    # the golden was taken when a prompt's pages popped off the top of a
+    # LIFO list (ids 7, 6, 5): the same pages, laid where they lay then
+    as_then = np.concatenate([pages[:, 3:], used[:, ::-1]], axis=1)
+    got = hashlib.sha256(np.asarray(as_then, jnp.bfloat16).tobytes()
                          ).hexdigest()
     assert got == LATENT_GOLDEN["sha256"]

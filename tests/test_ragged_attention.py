@@ -280,6 +280,150 @@ def test_kernel_matches_reference_grid(shape, dtype, page_size, mesh_n,
             assert np.isnan(got[bb]).all()
 
 
+# -- a block of adjacent pages is one copy: the table decides, bits do not --
+
+_RUN_PS, _RUN_MP, _RUN_ROW = 8, 80, 128
+_RUN_KERNELS = {          # name -> (pages a block, pinned or the kernel's own)
+    "ragged_paged_decode": 8, "ragged_paged_attention": 8,
+    "ragged_latent_attention-one-row": 32, "ragged_latent_attention-rows": 32,
+    "sparse_paged_decode": 32, "sparse_paged_attention": 32}
+_RUN_TABLES = ["ascending", "descending", "shuffled", "run_from_mid_block",
+               "runs_and_scattered_mixed", "partial_last_block",
+               "lanes_without_rows", "shared_pages"]
+
+
+def _run_tables(name, g, live_pages):
+    """``(B, MP)`` page ids of the layout ``name``: lane ``l`` owns ids ``1
+    + l * MP`` on; ``g`` pages a key block."""
+    b, mp = len(live_pages), _RUN_MP
+    own = 1 + np.arange(b * mp).reshape(b, mp)
+    rng = np.random.default_rng(7)
+    if name == "descending":
+        return own[:, ::-1].copy()
+    if name == "shuffled":
+        return np.stack([rng.permutation(row) for row in own])
+    if name == "run_from_mid_block":
+        # seven scattered ids, then one run: block 0 is none, block 1 is
+        return np.concatenate([own[:, :-8:-1], own[:, :mp - 7]], axis=1)
+    if name == "runs_and_scattered_mixed":
+        out = own.copy()
+        for j in range(1, -(-mp // g), 2):          # odd blocks scattered
+            out[:, j * g:(j + 1) * g] = rng.permuted(
+                out[:, j * g:(j + 1) * g], axis=1)
+        return out
+    if name == "partial_last_block":
+        # the engine's table: zeros past the pages a lane holds
+        return np.where(np.arange(mp)[None, :]
+                        < np.asarray(live_pages)[:, None], own, 0)
+    return own            # ascending, lanes_without_rows, shared_pages
+
+
+def _run_case(kernel, table_name, permuted=False):
+    """The kernel's call on one set of LOGICAL rows a lane under the layout
+    ``table_name``: ``(got, want, rows a lane holds)``.  ``permuted``: the
+    same table with every id sent through one permutation of all the ids,
+    so that no block of it is a run.  Lane 0 ends inside
+    its third block, lane 1 on its first block's last key, lane 2 holds no
+    row, lane 3 ends inside its first block, lane 4 one key into its third,
+    lane 5 mid-way through its second.  Keys past a lane's length are no
+    number (:func:`_poisoned`)."""
+    ps, mp, row = _RUN_PS, _RUN_MP, _RUN_ROW
+    g = _RUN_KERNELS[kernel]
+    gs = g * ps
+    kv_lens = [2 * gs + 5 * ps + 3, gs, 2 * gs, gs // 2 + 3, 2 * gs + 1,
+               gs + gs // 2]
+    m = 1 if kernel.endswith(("decode", "one-row")) else 3
+    q_lens = [m, m, 0, m, m - (m > 1), m]
+    if table_name == "lanes_without_rows":
+        q_lens = [0, m, 0, 0, m, 0]
+    b = len(kv_lens)
+    live_pages = [(n - 1) // ps + 1 for n in kv_lens]
+    tables = _run_tables(table_name, g, live_pages)
+    latent = "latent" in kernel
+    parts = 1 if latent else 2
+    rng = np.random.default_rng(11)
+    logical = rng.standard_normal((2, b, mp, parts, ps, row)).astype(
+        np.float32)
+    if table_name == "shared_pages":
+        # lane 1's first block IS lane 0's (a shared prompt prefix)
+        logical[:, 1, :g] = logical[:, 0, :g]
+        tables[1, :g] = tables[0, :g]
+    if permuted:
+        tables = np.concatenate([[0], 1 + np.random.default_rng(
+            3).permutation(b * mp)])[tables]
+    pool = np.zeros((2, 1 + b * mp, parts, ps, row), np.float32)
+    for lane in range(b):
+        for i in range(live_pages[lane]):
+            pool[:, tables[lane, i]] = logical[:, lane, i]
+    pool, tables = jnp.asarray(pool), jnp.asarray(tables, jnp.int32)
+    dirty = _poisoned(pool, tables, q_lens, kv_lens)
+    ql, kl = jnp.asarray(q_lens, jnp.int32), jnp.asarray(kv_lens, jnp.int32)
+    layer = jnp.ones((1,), jnp.int32)
+    pos = (kl - ql)[:, None] + jnp.arange(m)[None, :]
+    if kernel.startswith("ragged_paged"):
+        h, hkv, d = 4, 2, 64
+        q = jnp.asarray(rng.standard_normal((b, m, h, d)), jnp.float32)
+        got = ra._ragged_attn(q, dirty, layer, tables, ql, kl,
+                              interpret=True, g_pages=g, nbuf=2)
+        kv = np.asarray(pool[1]).reshape(-1, 2, ps, hkv, d)
+        want = _reference(np.asarray(q), kv[:, 0], kv[:, 1],
+                          np.asarray(tables), q_lens, kv_lens)
+    elif latent:
+        h, w, v_width = 4, 96, 64
+        q = jnp.asarray(rng.standard_normal((b, m, h, w)), jnp.float32)
+        got = ra._latent_attn(q, dirty, layer, tables, ql, kl,
+                              v_width=v_width, sm_scale=0.2, interpret=True)
+        want = _gather_attend_latent(q, pool[1, :, 0], tables, pos, v_width,
+                                     0.2, jnp.float32)
+    else:
+        h, hkv, d = 4, 2, 64
+        rows = [(lane, j) for lane in range(b) for j in range(q_lens[lane])]
+        q = jnp.asarray(rng.standard_normal((len(rows), h, d)), jnp.float32)
+        row_lane = jnp.asarray([lane for lane, _ in rows], jnp.int32)
+        sees = np.asarray([kv_lens[lane] - q_lens[lane] + j
+                           for lane, j in rows])
+        mask = jnp.asarray((rng.random((len(rows), mp * ps)) < 0.5)
+                           & (np.arange(mp * ps)[None, :] <= sees[:, None]))
+        live = (ql > 0).astype(jnp.int32)
+        if kernel == "sparse_paged_decode":
+            at = np.cumsum([0] + q_lens[:-1])       # lane -> its one row
+            got = sa._sparse_decode(
+                jnp.where((ql > 0)[:, None, None], q[at % len(rows)], 0.0),
+                jnp.where((ql > 0)[:, None], mask[at % len(rows)], False),
+                dirty, layer, tables, live, kl, interpret=True)
+            got = got[np.asarray([lane for lane, _ in rows])]
+        else:
+            got = sa._sparse_attn(q, mask, row_lane, dirty, layer, tables,
+                                  live, kl, interpret=True)
+        want = sa.sparse_attend_xla(q, mask, row_lane, pool[1], tables,
+                                    jnp.float32)
+        return np.asarray(got), np.asarray(want), None
+    return np.asarray(got), np.asarray(want), q_lens
+
+
+@pytest.mark.parametrize("table_name", _RUN_TABLES)
+@pytest.mark.parametrize("kernel", sorted(_RUN_KERNELS))
+def test_a_run_of_pages_is_one_copy_and_the_same_bits(kernel, table_name):
+    """Every kernel of the family on every shape of table: blocks that are
+    ascending runs (one copy), scattered blocks (a copy a page), a run that
+    starts mid-block, both kinds in one lane, a partial last block, lanes
+    without rows, pages shared between lanes.  Each agrees with the XLA
+    form within the grid's limits AND, bit for bit, with the same logical
+    rows under the same table sent through a permutation of all the ids:
+    what a block's copies land is the same bytes in the same place,
+    whichever path."""
+    got, want, q_lens = _run_case(kernel, table_name)
+    base, _want, _q_lens = _run_case(kernel, table_name, permuted=True)
+    tol = dict(rtol=2e-5, atol=2e-5)
+    if q_lens is None:                      # the sparse kernels: rows
+        np.testing.assert_allclose(got, want, **tol)
+    else:
+        assert any(q_lens)
+        for lane, n in enumerate(q_lens):
+            np.testing.assert_allclose(got[lane, :n], want[lane, :n], **tol)
+    np.testing.assert_array_equal(got, base)
+
+
 def test_kernel_long_walk_exceeds_pipeline_depth():
     """More KV blocks than nbuf slots exercises the in-loop slot refill
     (the DMA pipeline inherited from the single-query kernel)."""

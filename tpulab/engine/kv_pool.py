@@ -11,8 +11,10 @@ and who holds it:
   learned sparse attention); :func:`kv_rows_view` is the
   ``(Hkv, D)`` -> row reshape the host-side formats share;
 - :class:`PagedKVPool` — the device array ``(L, P, ...page)`` plus the
-  host-side free list, reference counts, grow/shrink and the host<->device
-  page moves the host tier, the disagg wire and the fabric ride;
+  host-side free extents (a grant is ascending runs of ids: a key block
+  whose pages are one run is one DMA), reference counts, grow/shrink and
+  the host<->device page moves the host tier, the disagg wire and the
+  fabric ride;
 - :class:`PrefixCache` — page-granular sharing of prompt prefixes over the
   pool's reference counts;
 - :class:`LaneStateStore` — the second kind of state, indexed by lane and
@@ -26,6 +28,7 @@ The step programs that read and write the pool are
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Dict, List, Optional
 
@@ -37,8 +40,11 @@ def kv_page_shape(page_size: int, n_kv_heads: int, head_dim: int) -> tuple:
     keeps it — the ONE definition of the page payload.
 
     FUSED: a page's K rows (``[0]``) and V rows (``[1]``) are adjacent in
-    HBM, so the ragged kernel fetches both with one DMA per page (the walk
-    is DMA-issue-bound; fusing halves the issue count).  A row is one
+    HBM, so the ragged kernel fetches both with one DMA (the walk is
+    DMA-issue-bound; fusing halves the issue count), and so are the pages
+    of one layer: a key block whose page ids are an ascending run is ONE
+    DMA (``_page_walk``; :meth:`PagedKVPool.allocate_pages` hands out
+    runs).  A row is one
     position's KV heads side by side, ``Hkv*D`` wide: the shape the kernel
     DMAs into VMEM, so the page store goes into the ``pallas_call`` as it
     is and no step reshapes or slices it first (on a TPU merging
@@ -76,6 +82,95 @@ def kv_rows_view(pages):
     """``(..., Hkv, D)`` heads as the ``(..., Hkv*D)`` rows the page store
     takes (numpy or jax; the same bytes in the same order)."""
     return pages.reshape(pages.shape[:-2] + (-1,))
+
+
+class _FreeExtents:
+    """The free page ids as address-ordered extents ``[start, end)``: a
+    released id coalesces with its neighbours, and a grant comes off the
+    START of an extent, so its ids ascend.  Not locked: the pool's lock
+    covers every call."""
+
+    def __init__(self, lo: int, hi: int):
+        self._starts: List[int] = []        # ascending
+        self._end: Dict[int, int] = {}      # start -> end (exclusive)
+        self._start: Dict[int, int] = {}    # end -> start
+        self.count = 0                      # free ids, all extents
+        self.add(lo, hi)
+
+    def add(self, lo: int, hi: int) -> None:
+        """Free ``[lo, hi)``, merged with the extent that ends at ``lo`` and
+        the one that starts at ``hi``."""
+        if hi <= lo:
+            return
+        self.count += hi - lo
+        if lo in self._start:               # an extent ends where this starts
+            lo = self._start.pop(lo)
+        else:
+            bisect.insort(self._starts, lo)
+        if hi in self._end:                 # and one starts where it ends
+            self._starts.pop(bisect.bisect_left(self._starts, hi))
+            hi = self._end.pop(hi)
+        self._end[lo] = hi
+        self._start[hi] = lo
+
+    def take(self, start: int, n: int) -> range:
+        """The first ``n`` ids of the extent that starts at ``start``."""
+        end = self._end.pop(start)
+        i = bisect.bisect_left(self._starts, start)
+        if start + n == end:
+            self._starts.pop(i)
+            del self._start[end]
+        else:
+            self._starts[i] = start + n
+            self._end[start + n] = end
+            self._start[end] = start + n
+        self.count -= n
+        return range(start, start + n)
+
+    def size_at(self, start: int) -> int:
+        """Ids of the extent that starts at ``start`` (0: none does)."""
+        return self._end.get(start, start) - start
+
+    def top(self, hi: int) -> int:
+        """Ids of the extent that ends at ``hi`` (0: none does)."""
+        return hi - self._start.get(hi, hi)
+
+    def cut_top(self, hi: int, n: int) -> None:
+        """Forget the last ``n`` ids of the extent that ends at ``hi``."""
+        lo = self._start.pop(hi)
+        self.count -= n
+        if hi - n == lo:
+            self._starts.pop(bisect.bisect_left(self._starts, lo))
+            del self._end[lo]
+        else:
+            self._end[lo] = hi - n
+            self._start[hi - n] = lo
+
+    def grant(self, n: int, after: int = 0) -> Optional[List[int]]:
+        """``n`` ids, or None (and nothing taken) where fewer are free.
+        First what continues ``after`` (the extent that starts at ``after +
+        1``, as far as it goes), then the rest ascending from as few extents
+        as hold it: the first, by address, that holds it whole, behind the
+        largest ones whole while none does."""
+        if n > self.count:
+            return None
+        out: List[int] = []
+        if after and after + 1 in self._end:
+            out.extend(self.take(after + 1, min(n, self.size_at(after + 1))))
+        rest: List[int] = []
+        need = n - len(out)
+        while need:
+            fit = next((s for s in self._starts
+                        if self._end[s] - s >= need), None)
+            if fit is None:
+                fit = max(self._starts, key=self.size_at)
+            got = self.take(fit, min(need, self.size_at(fit)))
+            rest.extend(got)
+            need -= len(got)
+        return out + sorted(rest)
+
+    def __iter__(self):
+        return ((s, self._end[s]) for s in self._starts)
 
 
 class PagedKVPool:
@@ -171,13 +266,12 @@ class PagedKVPool:
                 self._index_shape, dtype)
         # page 0 is RESERVED as scratch: inactive/padded lanes scatter their
         # (masked-out) K/V there, so it must never hold live data
-        self._free: List[int] = list(range(1, n_pages))
+        # the free ids, address-ordered: a grant is the lowest extent that
+        # holds it, so live data packs toward page 0 and the TOP of the
+        # store stays contiguously free for :meth:`shrink`
+        self._free = _FreeExtents(1, n_pages)
         self._refs: Dict[int, int] = {}  # live page -> refcount
         self._lock = threading.Lock()
-        #: allocate lowest page ids first (the HBM arbiter arms this):
-        #: live data packs toward page 0, so the TOP of the store stays
-        #: contiguously free and :meth:`shrink` can return real bytes
-        self.prefer_low_pages = False
 
     # the KV buffer rotates through XLA donation; the setter keeps the
     # device allocator's accounting slot pointing at the live generation
@@ -262,7 +356,7 @@ class PagedKVPool:
             self.index = jax.device_put(
                 jnp.zeros(self._index_shape, self._dtype), self.placement)
         with self._lock:
-            self._free = list(range(1, self.n_pages))  # page 0 stays scratch
+            self._free = _FreeExtents(1, self.n_pages)  # page 0: scratch
             self._refs.clear()
 
     def close(self) -> None:
@@ -296,19 +390,28 @@ class PagedKVPool:
     @property
     def free_pages(self) -> int:
         with self._lock:
-            return len(self._free)
+            return self._free.count
 
-    def allocate_page(self) -> Optional[int]:
+    def allocate_pages(self, n: int, after: int = 0) -> Optional[List[int]]:
+        """``n`` page ids in ascending runs, all or nothing: None, and
+        nothing held, where fewer are free.  ``after`` is the last page of
+        the table the grant goes behind: the grant starts at ``after + 1``
+        where that id is free, so that a lane's admission page, its prompt's
+        pages and the pages decode adds later stay ONE run of ids, which
+        the page walk reads a block of as one copy
+        (:mod:`tpulab.ops.ragged_attention`).  What does not continue
+        ``after`` comes ascending from as few extents as hold it
+        (:meth:`_FreeExtents.grant`)."""
         with self._lock:
-            if not self._free:
-                return None
-            if self.prefer_low_pages:
-                page = min(self._free)
-                self._free.remove(page)
-            else:
-                page = self._free.pop()
-            self._refs[page] = 1
-            return page
+            pages = self._free.grant(int(n), int(after or 0))
+            if pages is not None:
+                self._refs.update(dict.fromkeys(pages, 1))
+            return pages
+
+    def allocate_page(self, after: int = 0) -> Optional[int]:
+        """:meth:`allocate_pages` of one."""
+        pages = self.allocate_pages(1, after)
+        return pages[0] if pages else None
 
     def add_ref(self, page: int) -> None:
         """Share an allocated page (prefix caching): one extra
@@ -323,15 +426,23 @@ class PagedKVPool:
         (pages from pre-refcount callers behave exactly as before: one
         allocate, one release)."""
         with self._lock:
+            freed = []
             for p in pages:
                 if not p:
                     continue  # 0/None never re-enter
                 n = self._refs.get(p, 1) - 1
                 if n <= 0:
                     self._refs.pop(p, None)
-                    self._free.append(p)
+                    freed.append(p)
                 else:
                     self._refs[p] = n
+            # a lane's pages are mostly runs: free each run as one extent
+            freed = sorted(set(freed))
+            lo = 0
+            for i, p in enumerate(freed):
+                if i + 1 == len(freed) or freed[i + 1] != p + 1:
+                    self._free.add(freed[lo], p + 1)
+                    lo = i + 1
 
     def refcount(self, page: int) -> int:
         """Current reference count (0 for free/unknown pages)."""
@@ -351,13 +462,7 @@ class PagedKVPool:
         """Free pages contiguously at the TOP of the store — the ids a
         shrink could drop right now without touching live data."""
         with self._lock:
-            free = set(self._free)
-            n = 0
-            p = self.n_pages - 1
-            while p >= 1 and p in free:
-                n += 1
-                p -= 1
-            return n
+            return self._free.top(self.n_pages)
 
     def grow(self, extra_pages: int) -> int:
         """Append ``extra_pages`` zeroed pages to the store (one device
@@ -376,7 +481,7 @@ class PagedKVPool:
                              self.placement)
         self.kv = jnp.concatenate([self._kv, pad], axis=1)
         with self._lock:
-            self._free.extend(range(self.n_pages, self.n_pages + extra))
+            self._free.add(self.n_pages, self.n_pages + extra)
             self.n_pages += extra
             self._shape = (self._shape[0], self.n_pages) + self._shape[2:]
         return extra
@@ -389,16 +494,11 @@ class PagedKVPool:
         if self._index_addr is not None:
             raise NotImplementedError("a pool with index rows is not elastic")
         with self._lock:
-            free = set(self._free)
-            k = 0
-            p = self.n_pages - 1
-            while p >= 1 and p in free and k < int(drop_pages):
-                k += 1
-                p -= 1
-            if k == 0:
+            k = min(self._free.top(self.n_pages), int(drop_pages))
+            if k <= 0:
                 return 0
+            self._free.cut_top(self.n_pages, k)
             cut = self.n_pages - k
-            self._free = [q for q in self._free if q < cut]
             self.n_pages = cut
             self._shape = (self._shape[0], cut) + self._shape[2:]
         self.kv = self._kv[:, :cut]

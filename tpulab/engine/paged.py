@@ -118,7 +118,7 @@ class _PagedRequest:
                  "spec_drafted", "spec_accepted", "spec_probe_in",
                  "spec_probing", "tenant", "lane", "fl", "batch",
                  "pf_started", "pf_digests", "pf_shared", "pf_t0",
-                 "eva_done")
+                 "eva_done", "walk_runs")
 
     def __init__(self, prompt: np.ndarray, steps: int, on_token=None,
                  sampling: Optional[SamplingParams] = None,
@@ -194,12 +194,31 @@ class _PagedRequest:
         #: EVA windows of this lane already compacted into summary rows:
         #: its rows are ``length - eva_done * (window - summaries)``
         self.eva_done = 0
+        #: :meth:`run_blocks`: key blocks of the page table looked at, and
+        #: those among them that were one ascending run of ids
+        self.walk_runs = (0, 0)
         self.t_submit = _time.perf_counter()
         self.t_prefill0: Optional[float] = None  # first prefill start
         self.t_first: Optional[float] = None     # first emitted token
         self.t_last: Optional[float] = None      # latest emitted token
         self.chunk_t0: Optional[float] = None    # open decode-chunk start
         self.chunk_start = 0                     # first token idx in chunk
+
+    def run_blocks(self, g_pages: int, full: int) -> int:
+        """Of the page table's first ``full`` blocks of ``g_pages`` entries,
+        those whose ids are one ascending run (what the page walk fetches
+        with one copy).  Kept as the table grows: a block is looked at
+        once, O(1) a page (a table cut below what was looked at is counted
+        again from its start)."""
+        seen, runs = self.walk_runs
+        if full < seen:
+            seen = runs = 0
+        while seen < full:
+            blk = self.pages[seen * g_pages:(seen + 1) * g_pages]
+            runs += blk == list(range(blk[0], blk[0] + g_pages))
+            seen += 1
+        self.walk_runs = (seen, runs)
+        return runs
 
     def finished(self) -> bool:
         """steps exhausted, or the last emitted token is a stop token
@@ -434,8 +453,6 @@ class ContinuousBatcher:
         #: discipline applied to capacity)
         self._hbm_pool_base = self.pool.n_pages
         self._hbm_starved_passes = 0  # hold-and-wait breaker streak
-        if hbm is not None:
-            self.pool.prefer_low_pages = True
         # sharded serving (the class docstring): params placed by the
         # Megatron-TP rules (wqkv/w1/w3/lm_head column-, wo/w2
         # row-parallel), per-lane carry/state replicated
@@ -538,6 +555,14 @@ class ContinuousBatcher:
         #: enqueued before their predecessor was fetched, and those among
         #: them whose predecessor was a round
         self.mixed_decode_rows = 0
+        #: pages a key block of the attention kernels' walk holds at this
+        #: engine's shapes (their own geometry), and over every decode row
+        #: dispatched (a block's K steps a lane, a round's decode rows) the
+        #: FULL key blocks its lane's walk read and those among them whose
+        #: pages were one ascending run of ids: one DMA (:meth:`_note_walk`)
+        self._walk_pages = plan.walk_block_pages
+        self.walk_blocks = 0
+        self.walk_run_blocks = 0
         self.ahead_rounds = 0
         self.rounds_after_round = 0
         #: sum of K over plain decode dispatches (K-blocks and single
@@ -765,6 +790,17 @@ class ContinuousBatcher:
         n)``, ``n`` inside the window it is in: ``n`` itself, less what its
         compacted EVA windows gave back."""
         return n - req.eva_done * self.plan.eva_saved
+
+    def _note_walk(self, reqs, steps: int = 1) -> None:
+        """Count the key blocks ``steps`` decode rows of each of ``reqs``
+        walk (``debug_state()["pool"]``): the full blocks under the lane's
+        last position as the host knows it, by the kernels' geometry."""
+        g, ps = self._walk_pages, self.page_size
+        for req in reqs:
+            full = min(self._rows(req, req.length) // ps + 1,
+                       len(req.pages)) // g
+            self.walk_blocks += steps * full
+            self.walk_run_blocks += steps * req.run_blocks(g, full)
 
     def _boundary(self, req: _PagedRequest) -> int:
         """The first position ``req`` may not take in before a compaction:
@@ -1354,7 +1390,10 @@ class ContinuousBatcher:
                      "ladder_base": self._hbm_pool_base,
                      "ladder_rung": rung,
                      "grows": self.hbm_grows,
-                     "shrinks": self.hbm_shrinks},
+                     "shrinks": self.hbm_shrinks,
+                     "walk_block_pages": self._walk_pages,
+                     "walk_blocks": self.walk_blocks,
+                     "walk_run_blocks": self.walk_run_blocks},
             "dispatch": {"decode_block": self.decode_block,
                          "decode_dispatches": self.decode_dispatches,
                          "decode_host_syncs": self.decode_host_syncs,
@@ -1528,15 +1567,41 @@ class ContinuousBatcher:
                 return
         self._queue.append(req)
 
-    def _alloc_page(self) -> Optional[int]:
-        """Pool page, evicting cold prefix-cache entries under pressure —
-        live requests always outrank cached prefixes (with kv_offload the
-        eviction DEMOTES the entry to the host tier instead of losing it)."""
-        page = self.pool.allocate_page()
-        while (page is None and self.prefix_cache is not None
+    def _alloc_pages(self, n: int, after: int = 0) -> Optional[List[int]]:
+        """``n`` pool pages in ascending runs, continuing ``after`` (the
+        table's last page) where the pool can, all or nothing — evicting
+        cold prefix-cache entries under pressure: live requests always
+        outrank cached prefixes (with kv_offload the eviction DEMOTES the
+        entry to the host tier instead of losing it)."""
+        pages = self.pool.allocate_pages(n, after)
+        while (pages is None and self.prefix_cache is not None
                and self.prefix_cache.evict_for_alloc()):
-            page = self.pool.allocate_page()
-        return page
+            pages = self.pool.allocate_pages(n, after)
+        return pages
+
+    def _alloc_page(self, after: int = 0) -> Optional[int]:
+        """:meth:`_alloc_pages` of one."""
+        pages = self._alloc_pages(1, after)
+        return pages[0] if pages else None
+
+    def _secure_pages(self, req: _PagedRequest, shared: List[int],
+                      needed: int) -> bool:
+        """The page table a prompt starts from: ``shared`` (the prefix
+        cache's pages, first) and then private pages up to ``needed`` in
+        all, in ONE grant.  The admission page goes back first, nothing
+        written in it yet, and comes again as part of the grant, so that
+        the private pages are one ascending run where the pool has one.
+        False under page pressure, and then the request holds NOTHING: two
+        starved prefills must not hold-and-wait each other."""
+        private = max(needed - len(shared), len(req.pages))
+        self.pool.release_pages(req.pages)
+        pages = self._alloc_pages(private, shared[-1] if shared else 0)
+        if pages is None:
+            self.pool.release_pages(shared)
+            req.pages = []
+            return False
+        req.pages = shared + pages
+        return True
 
     # -- host KV tier (kv_offload) -------------------------------------------
     def _demote_prefix(self, digest: bytes, page: int) -> None:
@@ -2022,19 +2087,9 @@ class ContinuousBatcher:
                                                            self.page_size)
             # page layout: shared prefix pages first, then private pages (the
             # admission page + extras) for the tail/write region
-            private = req.pages
-            req.pages = shared + private
             needed = (t + self.page_size - 1) // self.page_size
-            while len(req.pages) < needed:
-                page = self._alloc_page()
-                if page is None:
-                    # page pressure: release partial holdings before
-                    # retrying — two starved prefills must not hold-and-wait
-                    # each other
-                    self.pool.release_pages(req.pages)
-                    req.pages = []
-                    return False
-                req.pages.append(page)
+            if not self._secure_pages(req, shared, needed):
+                return False
             start = len(shared) * self.page_size
         with stage(st, "dispatch"):
             tables = np.zeros((self.max_pages,), np.int32)
@@ -2231,15 +2286,10 @@ class ContinuousBatcher:
         snapshot's covered positions (prompt + generated - 1)."""
         handle = req.kv_handle
         needed = handle.n_pages
-        while len(req.pages) < needed:
-            page = self._alloc_page()
-            if page is None:
-                # page pressure: release partial holdings (no hold-and-
-                # wait), KEEP the handle — the snapshot outlives retries
-                self.pool.release_pages(req.pages)
-                req.pages = []
-                return False
-            req.pages.append(page)
+        if not self._secure_pages(req, [], needed):
+            # page pressure: nothing held (no hold-and-wait), the handle
+            # KEPT — the snapshot outlives retries
+            return False
         t0 = _time.perf_counter()
         new_kv = self.kv_offload.restore(handle, req.pages[:needed],
                                          self.pool.kv)
@@ -2303,18 +2353,11 @@ class ContinuousBatcher:
         if self.prefix_cache is not None:
             shared, digests = self.prefix_cache.lookup(prompt,
                                                        self.page_size)
-        private = req.pages
-        req.pages = shared + private
         # with EVA windows: the most ROWS the prompt holds on its way in
         needed = (self._peak_rows(req, t) + self.page_size
                   - 1) // self.page_size
-        while len(req.pages) < needed:
-            page = self._alloc_page()
-            if page is None:
-                self.pool.release_pages(req.pages)
-                req.pages = []
-                return False
-            req.pages.append(page)
+        if not self._secure_pages(req, shared, needed):
+            return False
         # shared prefix positions are already resident: chunks cover
         # only the tail (the last prompt token is never served shared)
         req.pf_digests = digests
@@ -2512,6 +2555,7 @@ class ContinuousBatcher:
         self.mixed_attn_rows += ((len(toks) - b) * len(segs)
                                  + len(decode_parts))
         self.mixed_decode_rows += len(decode_parts)
+        self._note_walk(req for _, req in decode_parts)
         if chain is not None:
             self.ahead_rounds += 1
             self.rounds_after_round += chain["kind"] == "round"
@@ -2819,7 +2863,7 @@ class ContinuousBatcher:
             need = (base + appends_want - 1) // ps + 1
             new: List[int] = []
             while len(req.pages) < need:
-                page = self._alloc_page()
+                page = self._alloc_page(req.pages[-1] if req.pages else 0)
                 if page is None:
                     break
                 req.pages.append(page)
@@ -2920,7 +2964,7 @@ class ContinuousBatcher:
             need = (req.length + want - 1) // self.page_size + 1
             new_t: List[int] = []
             while len(req.pages) < need:
-                page = self._alloc_page()
+                page = self._alloc_page(req.pages[-1] if req.pages else 0)
                 if page is None:
                     break
                 req.pages.append(page)
@@ -2932,7 +2976,8 @@ class ContinuousBatcher:
                 continue
             new_d: List[int] = []
             while len(req.draft_pages) < need:
-                page = self._alloc_page()
+                page = self._alloc_page(
+                    req.draft_pages[-1] if req.draft_pages else 0)
                 if page is None:
                     break
                 req.draft_pages.append(page)
@@ -3121,6 +3166,7 @@ class ContinuousBatcher:
         self.decode_dispatches += 1
         self.decode_block_steps += k
         self.ahead_blocks += ahead > 0
+        self._note_walk(lane_reqs.values(), k)
         self._note_dispatch("decode")
         return {"kind": "block", "k": k, "lane_reqs": lane_reqs, "out": out,
                 "carry": (len_f, tok_f, live_f, rem_f),

@@ -118,6 +118,19 @@ class EnginePlan:
                 "round": form}
 
     @property
+    def walk_block_pages(self) -> int:
+        """Pages a key block of the attention kernels' page walk holds at
+        this engine's shapes, by the kernels' own geometry: a block whose
+        table entries are one ascending run of ids is one DMA."""
+        from tpulab.engine.kv_pool import latent_page_shape
+        from tpulab.ops.ragged_attention import walk_block_pages
+        row = (latent_page_shape(self.page_size, self.latent)[-1]
+               if self.latent
+               else self.n_kv * self.head_dim // max(1, self.n_shards))
+        return walk_block_pages(self.page_size, self.max_pages, row,
+                                self.kv_dtype)
+
+    @property
     def step_kw(self) -> Dict[str, Any]:
         """The keywords the step programs bind, and are keyed by in the
         process's program memo."""

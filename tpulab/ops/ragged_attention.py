@@ -45,11 +45,16 @@ nothing.  The padded form (K+1 verify) and the decode step call it once.
 
 The XLA fallback gathers every lane's pages into a dense
 ``(B, MP*S, H, D)`` tensor; these kernels walk the block table per lane,
-DMA-ing fused K/V pages from HBM into VMEM scratch (one DMA per page)
-through an ``nbuf``-deep slot-rotation prefetch pipeline over blocks of
-``g_pages`` pages (:func:`_block_geometry`, :func:`_page_walk`), and
-accumulate softmax online per query row — O(block) VMEM, no gather
-materialization, dead pages skipped by predication.
+DMA-ing fused K/V pages from HBM into VMEM scratch through an
+``nbuf``-deep slot-rotation prefetch pipeline over blocks of ``g_pages``
+pages (:func:`_block_geometry`, :func:`_page_walk`), and accumulate
+softmax online per query row — O(block) VMEM, no gather materialization,
+dead pages skipped by predication.  A block whose table entries are an
+ascending run of page ids is ``g_pages`` ADJACENT pages of the layer and
+is ONE DMA; any other block is one DMA a page.  The table decides, block
+by block (:func:`_table_runs`), and :class:`~tpulab.engine.kv_pool.
+PagedKVPool` hands a lane its pages in runs: where a page is narrow the
+walk is bound by the count of its DMAs, not by their bytes.
 
 The MXU is fed what the store holds (:func:`mxu_operands`): a bf16 (or
 fp8) store gives both products of a key block bf16 operands in one default
@@ -136,6 +141,15 @@ def _block_geometry(page_size: int, max_pages: int, hd: int,
                    _VMEM_BUDGET_BYTES // max(2 * page_bytes, 1)))
     nbuf = max(2, min(_NBUF, _VMEM_BUDGET_BYTES // max(g * page_bytes, 1)))
     return g, nbuf
+
+
+def walk_block_pages(page_size: int, max_pages: int, row: int,
+                     kv_dtype) -> int:
+    """Pages a key block holds in every walk of the family over pages whose
+    rows are ``row`` wide (:func:`_block_geometry`): the unit a table's
+    runs are counted in (``debug_state()["pool"]``)."""
+    return _block_geometry(page_size, max_pages, row,
+                           jnp.dtype(kv_dtype).itemsize)[0]
 
 
 def _plan(m: int, h: int, hkv: int, d: int, page_size: int, max_pages: int,
@@ -229,18 +243,58 @@ def kernel_geometry_error(q_len: int, n_heads: int, n_kv_heads: int,
     return None
 
 
-def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
-               page_size: int, max_pages: int, g_pages: int, nbuf: int,
-               n_blocks: int):
+def _walk_scratch(nbuf: int, g_pages: int, kv_pool) -> list:
+    """The scratch :func:`_page_walk` works in, ahead of a kernel's own:
+    the staging buffer ``(nbuf, g_pages) + page`` (page-major: a slot is
+    ``g_pages`` whole pages as the store keeps them, so a run of adjacent
+    pages lands in it with ONE copy) and a DMA semaphore a page a slot (a
+    run signals its first page's)."""
+    return [pltpu.VMEM((nbuf, g_pages) + kv_pool.shape[2:], kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((nbuf, g_pages))]
+
+
+def _table_runs(tables, g_pages: int):
+    """``(B * n_blocks,)`` int32, the scalar-prefetch word :func:`_page_walk`
+    reads a block: 1 where the block's ``g_pages`` table entries are an
+    ASCENDING RUN of ids (``tables[b, j * g + i] == tables[b, j * g] + i``),
+    so that its pages are adjacent in the store.  From the table alone, in
+    the program that calls the kernel (one small fusion a program: the
+    layers share it); whether a block's pages are all LIVE the kernel
+    knows.  A block that reaches past the table's width is none."""
+    b, mp = tables.shape
+    n_blocks = -(-mp // g_pages)
+    t = jnp.pad(tables, ((0, 0), (0, n_blocks * g_pages - mp)))
+    t = t.reshape(b, n_blocks, g_pages)
+    return (t[..., 1:] - t[..., :-1] == 1).all(axis=-1).astype(
+        jnp.int32).reshape(-1)
+
+
+def _page_walk(tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer,
+               length, *, page_size: int, max_pages: int, g_pages: int,
+               nbuf: int, also=None):
     """The walk over one lane's block table that every kernel of the family
     shares, whatever a page holds (K and V rows, or latent rows): starts
     the pipeline's prologue and returns ``(start_block, wait_block,
     live_blocks)``, the last the lane's count of blocks that hold a live
     page: the trip count of the caller's loop, so a block past the lane's
-    length is never entered.  A block is up to ``g_pages`` page DMAs from
-    ``kvpool_ref[layer, page]`` into slot ``slot`` of ``kv_buf`` (source
-    page id and dest strip dynamic); every started DMA is waited exactly
+    length is never entered.  A block is ``g_pages`` table entries staged
+    in slot ``slot`` of ``kv_buf``; every started DMA is waited exactly
     once; pages past ``length`` are neither fetched nor waited.
+
+    A block whose ``g_pages`` entries are all live and an ASCENDING RUN of
+    ids (``runs_ref``, :func:`_table_runs`) is ``g_pages`` adjacent pages
+    of the layer, contiguous in HBM, and is fetched by ONE copy of
+    ``kvpool_ref[layer, pid0 : pid0 + g_pages]`` into the slot; any other
+    block (scattered ids, a lane's last partial block) by one copy a live
+    page into the slot's strip ``gg``.  A page walk is bound by the count
+    of its DMAs where a page is narrow (a 16 KiB page cost ~65 ns on a v5e,
+    20 ns of bytes: PERF.md section 6, PR 55), and the pool hands a lane
+    runs (:meth:`~tpulab.engine.kv_pool.PagedKVPool.allocate_pages`).  The
+    TABLE the kernel is handed decides, block by block, and nothing else
+    does; the bytes that land and where are the same either way.
+
+    ``also(j, slot, go)`` rides a block that holds a live page: what else
+    the caller stages a block (``go`` starts or waits a copy).
 
     The pages of a block and the blocks of the prologue are loops in the
     kernel, over the block's LIVE pages, not in Python: unrolled they were
@@ -251,30 +305,35 @@ def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
     ~16 ns a page of scalar work in the kernel, PERF.md section 6)."""
     # live pages of the lane: page p is live iff p * page_size <= length
     n_pages = jnp.minimum(length // page_size + 1, max_pages)
+    n_blocks = -(-max_pages // g_pages)         # as _table_runs counts them
 
-    # written out twice, not through a shared helper: every Python frame
-    # between the kernel and a primitive shows in its trace time
-    def start_block(j, slot):
+    def block(j, slot, go):
+        live = jnp.clip(n_pages - j * g_pages, 0, g_pages)
+        full = live == g_pages
+        # (a block past the table reads no word of it)
+        run = jnp.logical_and(
+            full, runs_ref[jnp.where(full, lane * n_blocks + j, 0)] != 0)
+        first = lane * max_pages + j * g_pages
+
+        @pl.when(run)
+        def _run():
+            go(pltpu.make_async_copy(
+                kvpool_ref.at[layer, pl.ds(tables_ref[first], g_pages)],
+                kv_buf.at[slot], sem.at[slot, 0]))
+
         def page(gg, _):
-            pid = tables_ref[lane * max_pages + j * g_pages + gg]
-            pltpu.make_async_copy(
-                kvpool_ref.at[layer, pid],
-                kv_buf.at[slot, :, pl.ds(
-                    pl.multiple_of(gg * page_size, page_size), page_size)],
-                sem.at[slot, gg]).start()
-        jax.lax.fori_loop(0, jnp.clip(n_pages - j * g_pages, 0, g_pages),
-                          page, None)
+            go(pltpu.make_async_copy(
+                kvpool_ref.at[layer, tables_ref[first + gg]],
+                kv_buf.at[slot, gg], sem.at[slot, gg]))
+        jax.lax.fori_loop(0, jnp.where(run, 0, live), page, None)
+        if also is not None:
+            pl.when(live > 0)(lambda: also(j, slot, go))
+
+    def start_block(j, slot):
+        block(j, slot, lambda copy: copy.start())
 
     def wait_block(j, slot):
-        def page(gg, _):
-            pid = tables_ref[lane * max_pages + j * g_pages + gg]
-            pltpu.make_async_copy(
-                kvpool_ref.at[layer, pid],
-                kv_buf.at[slot, :, pl.ds(
-                    pl.multiple_of(gg * page_size, page_size), page_size)],
-                sem.at[slot, gg]).wait()
-        jax.lax.fori_loop(0, jnp.clip(n_pages - j * g_pages, 0, g_pages),
-                          page, None)
+        block(j, slot, lambda copy: copy.wait())
 
     # same deep prefetch pipeline as the single-query kernel (N-stage
     # slot rotation)
@@ -286,20 +345,32 @@ def _page_walk(tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length, *,
     return start_block, wait_block, (n_pages + g_pages - 1) // g_pages
 
 
-def _zero_rows_past(kv_buf, slot, which: int, first_row, length):
-    """Zero, in the staged block ``kv_buf[slot, which]`` itself, the rows
-    at positions past ``length``: rows of pages not fetched hold stale VMEM
-    (possibly NaN), the scores of such rows are masked, but as VALUES they
-    ride a 0-weighted sum, and ``0 * NaN`` is NaN.  Only a lane's last
-    live block holds such a row, so no other block pays for the pass."""
-    gs = kv_buf.shape[2]
+def _staged_rows(kv_buf, slot, which: int):
+    """The staged block's rows ``(g_pages * S, row)`` of part ``which`` (K
+    rows 0, V rows 1; a latent page's one part 0), page after page.  ``S``
+    rows of a page are whole tiles of sublanes, so merging the pages is
+    layout-free."""
+    g, _parts, s, row = kv_buf.shape[1:]
+    return kv_buf[slot, :, which].reshape(g * s, row)
 
-    @pl.when(first_row + gs > length + 1)
+
+def _zero_rows_past(kv_buf, slot, which: int, first_row, length):
+    """Zero, in the staged block ``kv_buf[slot, :, which]`` itself, the
+    rows at positions past ``length``: rows of pages not fetched hold stale
+    VMEM (possibly NaN), the scores of such rows are masked, but as VALUES
+    they ride a 0-weighted sum, and ``0 * NaN`` is NaN.  Only a lane's last
+    live block holds such a row, so no other block pays for the pass."""
+    g, _parts, s, _row = kv_buf.shape[1:]
+
+    @pl.when(first_row + g * s > length + 1)
     def _zero():
-        row = first_row + jax.lax.broadcasted_iota(jnp.int32, (gs, 1), 0)
-        blk = kv_buf[slot, which]
-        kv_buf[slot, which] = jnp.where(row <= length, blk,
-                                        jnp.zeros_like(blk))
+        # a row's position, page-major as the block is staged
+        at = (first_row
+              + jax.lax.broadcasted_iota(jnp.int32, (g, s, 1), 0) * s
+              + jax.lax.broadcasted_iota(jnp.int32, (g, s, 1), 1))
+        blk = kv_buf[slot, :, which]
+        kv_buf[slot, :, which] = jnp.where(at <= length, blk,
+                                           jnp.zeros_like(blk))
 
 
 def _stacked_carry(n_kv_heads: int, g: int, head_dim: int):
@@ -365,8 +436,9 @@ def _stacked_store(o_ref, carry):
             acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
-def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
-                        kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
+def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
+                        q_ref, kvpool_ref, o_ref, kv_buf, sem, *,
+                        page_size: int,
                         max_pages: int, n_heads: int, head_dim: int,
                         n_kv_heads: int, m_q: int, sm_scale: float,
                         g_pages: int, nbuf: int):
@@ -393,7 +465,6 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         hkv = n_kv_heads
         g = h // hkv                          # GQA group size (1 = MHA)
         gs = g_pages * page_size              # KV rows per block
-        n_blocks = (max_pages + g_pages - 1) // g_pages
 
         # both products take their operands in ``dt``: the scaled query
         # rounded to it here, once, the probabilities a block
@@ -410,9 +481,9 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
             preferred_element_type=jnp.float32, precision=precision)
 
         start_block, wait_block, live_blocks = _page_walk(
-            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-            nbuf=nbuf, n_blocks=n_blocks)
+            nbuf=nbuf)
 
         # per-query-row positions/validity are loop-invariant
         qrow = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 0)
@@ -428,8 +499,8 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
 
             _zero_rows_past(kv_buf, slot, 1, j * gs, length)
             # as stored (an fp8 block upcast): no float32 copy
-            kblk = kv_buf[slot, 0].astype(dt)                # (G*S, Hkv*D)
-            vblk = kv_buf[slot, 1].astype(dt)
+            kblk = _staged_rows(kv_buf, slot, 0).astype(dt)  # (G*S, Hkv*D)
+            vblk = _staged_rows(kv_buf, slot, 1).astype(dt)
             kpos = j * gs + col
             mask = jnp.logical_and(kpos <= qpos, row_valid)      # (M, G*S)
             maskf = mask.astype(jnp.float32)
@@ -460,8 +531,8 @@ def _ragged_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
                 acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
-def _ragged_decode_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref,
-                          q_ref, kvpool_ref, o_ref, kv_buf, sem, *,
+def _ragged_decode_kernel(layer_ref, tables_ref, runs_ref, qlens_ref,
+                          kvlens_ref, q_ref, kvpool_ref, o_ref, kv_buf, sem, *,
                           page_size: int, max_pages: int, n_kv_heads: int,
                           sm_scale: float, g_pages: int, nbuf: int):
     """One lane's ONE query row against the lane's K/V pages, the query
@@ -478,9 +549,9 @@ def _ragged_decode_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref,
         length = jnp.maximum(kvlens_ref[lane], 1) - 1
         gs = g_pages * page_size
         start_block, wait_block, live_blocks = _page_walk(
-            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-            nbuf=nbuf, n_blocks=(max_pages + g_pages - 1) // g_pages)
+            nbuf=nbuf)
 
         q, dot_qk, dot_pv = _stacked_operands(q_ref, kv_buf, sm_scale)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, gs), 1)
@@ -492,9 +563,9 @@ def _ragged_decode_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref,
             start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
             _zero_rows_past(kv_buf, slot, 1, j * gs, length)
             return _stacked_block(
-                q, kv_buf[slot, 0].astype(q.dtype),
-                kv_buf[slot, 1].astype(q.dtype), j * gs + col <= length,
-                carry, dot_qk, dot_pv)
+                q, _staged_rows(kv_buf, slot, 0).astype(q.dtype),
+                _staged_rows(kv_buf, slot, 1).astype(q.dtype),
+                j * gs + col <= length, carry, dot_qk, dot_pv)
 
         _stacked_store(o_ref, jax.lax.fori_loop(
             0, live_blocks, body,
@@ -513,17 +584,14 @@ def _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
     g_pages, nbuf, need = _stacked_plan(h, hkv, d, page_size, max_pages,
                                         q.dtype, kv_pool.dtype, g_pages, nbuf)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,    # layer, tables (flat), q_lens, kv_lens
+        num_scalar_prefetch=5,    # layer, tables (flat), runs, q/kv_lens
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda lane, *_: (lane, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),   # KV pool stays in HBM
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda lane, *_: (lane, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, 2, g_pages * page_size, row), kv_pool.dtype),
-            pltpu.SemaphoreType.DMA((nbuf, g_pages)),  # one DMA per page
-        ],
+        scratch_shapes=_walk_scratch(nbuf, g_pages, kv_pool),
     )
     kernel = functools.partial(
         _ragged_decode_kernel, page_size=page_size, max_pages=max_pages,
@@ -537,8 +605,8 @@ def _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
             max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
         interpret=interpret,
         name="ragged_paged_decode",
-    )(layer, tables.reshape(-1), q_lens, kv_lens, q.reshape(b, h, d),
-      kv_pool)
+    )(layer, tables.reshape(-1), _table_runs(tables, g_pages), q_lens,
+      kv_lens, q.reshape(b, h, d), kv_pool)
     return out.reshape(b, 1, h, d)
 
 
@@ -573,7 +641,7 @@ def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
     g_pages, nbuf, need = _plan(m, h, hkv, d, page_size, max_pages, q.dtype,
                                 kv_pool.dtype, g_pages, nbuf)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,    # layer, tables (flat), q_lens, kv_lens
+        num_scalar_prefetch=5,    # layer, tables (flat), runs, q/kv_lens
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, m, h * d), lambda lane, *_: (lane, 0, 0)),
@@ -581,10 +649,7 @@ def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
         ],
         out_specs=pl.BlockSpec((1, m, h * d),
                                lambda lane, *_: (lane, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, 2, g_pages * page_size, row), kv_pool.dtype),
-            pltpu.SemaphoreType.DMA((nbuf, g_pages)),  # one DMA per page
-        ],
+        scratch_shapes=_walk_scratch(nbuf, g_pages, kv_pool),
     )
     kernel = functools.partial(
         _ragged_attn_kernel, page_size=page_size, max_pages=max_pages,
@@ -601,7 +666,8 @@ def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
             max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(layer, tables.reshape(-1), q_lens, kv_lens, q2, kv_pool)
+    )(layer, tables.reshape(-1), _table_runs(tables, g_pages), q_lens,
+      kv_lens, q2, kv_pool)
     return out.reshape(b, m, h, d)
 
 
@@ -620,7 +686,8 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
     segment's K/V are already resident in the pool);
     kv_pool (L, P, 2, S, Hkv*D) — the WHOLE page store as
     :class:`~tpulab.engine.kv_pool.PagedKVPool` keeps it (axis 2 = K/V
-    adjacent in HBM, one DMA per page; a row is the KV heads side by
+    adjacent in HBM: one DMA a page, or one a block of adjacent pages; a
+    row is the KV heads side by
     side, ``Hkv = row // D``, and ``Hkv < Hq`` selects GQA).  The kernel
     reads pages straight out of it: never hand it ``kv_pool[layer]`` or
     a reshape, which XLA would materialise as a copy of a layer of the
@@ -782,8 +849,9 @@ def latent_geometry_error(q_len: int, n_heads: int, row: int, v_width: int,
     return None
 
 
-def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
-                        kvpool_ref, o_ref, kv_buf, sem, *, page_size: int,
+def _latent_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
+                        q_ref, kvpool_ref, o_ref, kv_buf, sem, *,
+                        page_size: int,
                         max_pages: int, m_q: int, rows: int, v_width: int,
                         sm_scale: float, g_pages: int, nbuf: int):
     """One lane's tile of stacked heads against the lane's latent pages.
@@ -809,7 +877,6 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         length = jnp.maximum(kvn, 1) - 1
         start = kvn - qn
         gs = g_pages * page_size
-        n_blocks = (max_pages + g_pages - 1) // g_pages
 
         dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
         q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)   # (R, W)
@@ -821,9 +888,9 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
             preferred_element_type=jnp.float32, precision=precision)
 
         start_block, wait_block, live_blocks = _page_walk(
-            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-            nbuf=nbuf, n_blocks=n_blocks)
+            nbuf=nbuf)
 
         qrow = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (rows, gs), 1)
@@ -842,7 +909,7 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
 
             # the one staged row is key and value
             _zero_rows_past(kv_buf, slot, 0, j * gs, length)
-            blk = kv_buf[slot, 0].astype(dt)                     # (G*S, W)
+            blk = _staged_rows(kv_buf, slot, 0).astype(dt)       # (G*S, W)
             mask = jnp.logical_and(j * gs + col <= qpos, row_valid)
             s = jnp.where(mask, dot_qk(q, blk), _NEG)            # (R, G*S)
             m_new = jnp.maximum(m_c, s.max(axis=1, keepdims=True))
@@ -858,9 +925,9 @@ def _latent_attn_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         o_ref[0] = (acc_c / jnp.maximum(l_c, 1e-30)).astype(o_ref.dtype)
 
 
-def _latent_rows_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
-                        kvpool_ref, o_ref, kv_buf, sem, m_ref, l_ref, acc_ref,
-                        *, page_size: int, max_pages: int, m_q: int,
+def _latent_rows_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
+                        q_ref, kvpool_ref, o_ref, kv_buf, sem, m_ref, l_ref,
+                        acc_ref, *, page_size: int, max_pages: int, m_q: int,
                         v_width: int, sm_scale: float, g_pages: int,
                         nbuf: int):
     """One lane's tile of stacked heads at MORE than one row a head,
@@ -906,9 +973,9 @@ def _latent_rows_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         start_block, wait_block, live_blocks = _page_walk(
-            tables_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-            nbuf=nbuf, n_blocks=(max_pages + g_pages - 1) // g_pages)
+            nbuf=nbuf)
 
         def token(shape):
             # token index of a stacked row: heads are m_q rows apart
@@ -928,7 +995,7 @@ def _latent_rows_kernel(layer_ref, tables_ref, qlens_ref, kvlens_ref, q_ref,
 
             # the one staged row is key and value
             _zero_rows_past(kv_buf, slot, 0, j * gs, length)
-            blk = kv_buf[slot, 0].astype(dt)                     # (G*S, W)
+            blk = _staged_rows(kv_buf, slot, 0).astype(dt)       # (G*S, W)
             s = jnp.where(ahead <= start - j * gs, dot_qk(q, blk),
                           _NEG)                                  # (R, G*S)
             m_c = m_ref[...]
@@ -971,8 +1038,7 @@ def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
     qs = q.transpose(0, 2, 1, 3).reshape(b, n_tiles, hb * m, w)
     kw = dict(page_size=page_size, max_pages=max_pages, m_q=m,
               v_width=v_width, sm_scale=sm_scale, g_pages=g_pages, nbuf=nbuf)
-    scratch = [pltpu.VMEM((nbuf, 1, g_pages * page_size, row), kv_pool.dtype),
-               pltpu.SemaphoreType.DMA((nbuf, g_pages))]
+    scratch = _walk_scratch(nbuf, g_pages, kv_pool)
     if m == 1:
         # one row a lane (a decode step, a round's decode rows): the shape
         # decides, nothing else
@@ -984,7 +1050,7 @@ def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
                     pltpu.VMEM((rows, v_width), jnp.float32)]
     qs = jnp.pad(qs, ((0, 0), (0, 0), (0, rows - hb * m), (0, row - w)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,    # layer, tables (flat), q_lens, kv_lens
+        num_scalar_prefetch=5,    # layer, tables (flat), runs, q/kv_lens
         grid=(b, n_tiles),
         in_specs=[
             pl.BlockSpec((1, rows, row), lambda lane, t, *_: (lane, t, 0)),
@@ -1002,8 +1068,8 @@ def _latent_attn(q, kv_pool, layer, tables, q_lens, kv_lens, v_width: int,
             max(_VMEM_SCOPED_DEFAULT, need * 3 // 2), _VMEM_REQUEST_MAX)),
         interpret=interpret,
         name="ragged_latent_attention",
-    )(layer, tables.reshape(-1), q_lens, kv_lens,
-      qs.reshape(b, n_tiles * rows, row), kv_pool)
+    )(layer, tables.reshape(-1), _table_runs(tables, g_pages), q_lens,
+      kv_lens, qs.reshape(b, n_tiles * rows, row), kv_pool)
     out = out.reshape(b, n_tiles, rows, v_width)[:, :, :hb * m]
     return out.reshape(b, h, m, v_width).transpose(0, 2, 1, 3)
 

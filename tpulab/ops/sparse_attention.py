@@ -59,10 +59,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpulab.ops.ragged_attention import (_NEG, _VMEM_REQUEST_MAX,
-                                         _VMEM_SCOPED_DEFAULT, _plan,
-                                         _stacked_block, _stacked_carry,
-                                         _stacked_operands, _stacked_plan,
-                                         _stacked_store, _zero_rows_past,
+                                         _VMEM_SCOPED_DEFAULT, _page_walk,
+                                         _plan, _stacked_block,
+                                         _stacked_carry, _stacked_operands,
+                                         _stacked_plan, _stacked_store,
+                                         _staged_rows, _table_runs,
+                                         _walk_scratch, _zero_rows_past,
                                          mxu_operands)
 
 #: key slots one grid step of ``dsa_index_scores`` scores (a multiple of the
@@ -269,9 +271,9 @@ def sparse_attend_xla(q, mask, row_lane, kv_layer, tables, compute_dtype):
     return out
 
 
-def _sparse_attn_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
-                        lane_ref, mask_ref, kvpool_ref, o_ref, kv_buf,
-                        mask_buf, sem, msem, *,
+def _sparse_attn_kernel(layer_ref, tables_ref, runs_ref, live_ref, kvlens_ref,
+                        q_ref, lane_ref, mask_ref, kvpool_ref, o_ref, kv_buf,
+                        sem, mask_buf, msem, *,
                         page_size: int,
                         max_pages: int, n_heads: int, head_dim: int,
                         n_kv_heads: int, rows: int, sm_scale: float,
@@ -291,39 +293,20 @@ def _sparse_attn_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
     @pl.when(live_ref[lane] > 0)
     def _lane():
         length = jnp.maximum(kvlens_ref[lane], 1) - 1
-        n_pages = jnp.minimum(length // page_size + 1, max_pages)
         h, d, hkv = n_heads, head_dim, n_kv_heads
         g = h // hkv
         gs = g_pages * page_size
-        n_blocks = (max_pages + g_pages - 1) // g_pages
 
-        def block(j, slot, go):
-            # a block: its live pages' K/V and the mask's columns for them;
-            # ``go`` starts or waits (every started DMA is waited once)
-            def page(gg, _):
-                pid = tables_ref[lane * max_pages + j * g_pages + gg]
-                go(pltpu.make_async_copy(
-                    kvpool_ref.at[layer, pid],
-                    kv_buf.at[slot, :, pl.ds(
-                        pl.multiple_of(gg * page_size, page_size),
-                        page_size)],
-                    sem.at[slot, gg]))
-            live_pages = jnp.clip(n_pages - j * g_pages, 0, g_pages)
-            jax.lax.fori_loop(0, live_pages, page, None)
+        def mask_columns(j, slot, go):
+            # beside a block's pages, the mask's columns for them
+            go(pltpu.make_async_copy(
+                mask_ref.at[:, pl.ds(pl.multiple_of(j * gs, gs), gs)],
+                mask_buf.at[slot], msem.at[slot]))
 
-            @pl.when(live_pages > 0)
-            def _mask():
-                go(pltpu.make_async_copy(
-                    mask_ref.at[:, pl.ds(pl.multiple_of(j * gs, gs), gs)],
-                    mask_buf.at[slot], msem.at[slot]))
-
-        start = lambda c: c.start()
-        wait = lambda c: c.wait()
-        block(0, 0, start)
-
-        def prologue(jj, _):
-            block(jj, jj, start)
-        jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
+        start_block, wait_block, live_blocks = _page_walk(
+            tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+            nbuf=nbuf, also=mask_columns)
 
         dt, precision = mxu_operands(q_ref.dtype, kv_buf.dtype)
         q = (q_ref[...].astype(jnp.float32) * sm_scale).astype(dt)  # (R, H*D)
@@ -337,11 +320,12 @@ def _sparse_attn_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
 
         def body(j, carry):
             slot = jax.lax.rem(j, nbuf)
-            block(j, slot, wait)
-            block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf), start)
+            wait_block(j, slot)
+            # (a block past the lane's pages has no trip)
+            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
             _zero_rows_past(kv_buf, slot, 1, j * gs, length)
-            kblk = kv_buf[slot, 0].astype(dt)                # (G*S, Hkv*D)
-            vblk = kv_buf[slot, 1].astype(dt)
+            kblk = _staged_rows(kv_buf, slot, 0).astype(dt)  # (G*S, Hkv*D)
+            vblk = _staged_rows(kv_buf, slot, 1).astype(dt)
             mask = jnp.logical_and(
                 mask_buf[slot].astype(jnp.int32) != 0, mine)      # (R, G*S)
             maskf = mask.astype(jnp.float32)
@@ -366,8 +350,7 @@ def _sparse_attn_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
                       jnp.zeros((rows, 1), jnp.float32),
                       jnp.zeros((rows, d), jnp.float32)) for _ in range(h))
         # the lane's live blocks: one past its length is never walked
-        final = jax.lax.fori_loop(0, (n_pages + g_pages - 1) // g_pages,
-                                  body, init)
+        final = jax.lax.fori_loop(0, live_blocks, body, init)
         for hh in range(h):
             _m, l_c, acc_c = final[hh]
             cols = slice(hh * d, (hh + 1) * d)
@@ -395,7 +378,7 @@ def _sparse_attn(q, mask, row_lane, kv_pool, layer, tables, lane_live,
     lane_col = jnp.pad(row_lane.astype(jnp.int32), (0, rp - r),
                        constant_values=-1)[:, None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,    # layer, tables (flat), lane_live, kv_lens
+        num_scalar_prefetch=5,    # layer, tables (flat), runs, live, kv_lens
         grid=(b,),
         in_specs=[
             pl.BlockSpec((rp, h * d), lambda lane, *_: (0, 0)),
@@ -404,10 +387,8 @@ def _sparse_attn(q, mask, row_lane, kv_pool, layer, tables, lane_live,
             pl.BlockSpec(memory_space=pl.ANY),   # and so does the page store
         ],
         out_specs=pl.BlockSpec((rp, h * d), lambda lane, *_: (0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, 2, gs, row), kv_pool.dtype),
+        scratch_shapes=_walk_scratch(nbuf, g_pages, kv_pool) + [
             pltpu.VMEM((nbuf, rp, gs), _MASK_DTYPE),
-            pltpu.SemaphoreType.DMA((nbuf, g_pages)),
             pltpu.SemaphoreType.DMA((nbuf,)),
         ],
     )
@@ -424,13 +405,14 @@ def _sparse_attn(q, mask, row_lane, kv_pool, layer, tables, lane_live,
                 (need + 2 * nbuf * rp * gs) * 3 // 2), _VMEM_REQUEST_MAX)),
         interpret=interpret,
         name="sparse_paged_attention",
-    )(layer, tables.reshape(-1), lane_live, kv_lens, q2, lane_col, mask,
-      kv_pool)
+    )(layer, tables.reshape(-1), _table_runs(tables, g_pages), lane_live,
+      kv_lens, q2, lane_col, mask, kv_pool)
     return out[:r].reshape(r, h, d)
 
 
-def _sparse_decode_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
-                          mask_ref, kvpool_ref, o_ref, kv_buf, sem, *,
+def _sparse_decode_kernel(layer_ref, tables_ref, runs_ref, live_ref,
+                          kvlens_ref, q_ref, mask_ref, kvpool_ref, o_ref,
+                          kv_buf, sem, *,
                           page_size: int, max_pages: int, n_heads: int,
                           head_dim: int, n_kv_heads: int, sm_scale: float,
                           g_pages: int, nbuf: int):
@@ -446,50 +428,32 @@ def _sparse_decode_kernel(layer_ref, tables_ref, live_ref, kvlens_ref, q_ref,
     @pl.when(live_ref[lane] > 0)
     def _lane():
         length = jnp.maximum(kvlens_ref[lane], 1) - 1
-        n_pages = jnp.minimum(length // page_size + 1, max_pages)
         d, hkv = head_dim, n_kv_heads
         g = n_heads // hkv
         gs = g_pages * page_size
-        n_blocks = (max_pages + g_pages - 1) // g_pages
-
-        def block(j, slot, go):
-            def page(gg, _):
-                pid = tables_ref[lane * max_pages + j * g_pages + gg]
-                go(pltpu.make_async_copy(
-                    kvpool_ref.at[layer, pid],
-                    kv_buf.at[slot, :, pl.ds(
-                        pl.multiple_of(gg * page_size, page_size),
-                        page_size)],
-                    sem.at[slot, gg]))
-            jax.lax.fori_loop(0, jnp.clip(n_pages - j * g_pages, 0, g_pages),
-                              page, None)
-
-        start = lambda c: c.start()
-        wait = lambda c: c.wait()
-        block(0, 0, start)
-
-        def prologue(jj, _):
-            block(jj, jj, start)
-        jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
+        start_block, wait_block, live_blocks = _page_walk(
+            tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
+            page_size=page_size, max_pages=max_pages, g_pages=g_pages,
+            nbuf=nbuf)
 
         q, dot_qk, dot_pv = _stacked_operands(q_ref, kv_buf, sm_scale)
 
         def body(j, carry):
             slot = jax.lax.rem(j, nbuf)
-            block(j, slot, wait)
-            block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf), start)
+            wait_block(j, slot)
+            # (a block past the lane's pages has no trip)
+            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
             _zero_rows_past(kv_buf, slot, 1, j * gs, length)
             return _stacked_block(
-                q, kv_buf[slot, 0].astype(q.dtype),          # (G*S, Hkv*D)
-                kv_buf[slot, 1].astype(q.dtype),
+                q, _staged_rows(kv_buf, slot, 0).astype(q.dtype),
+                _staged_rows(kv_buf, slot, 1).astype(q.dtype),  # (G*S, Hkv*D)
                 mask_ref[0, :, pl.ds(pl.multiple_of(j * gs, gs),
                                      gs)] != 0,                    # (1, G*S)
                 carry, dot_qk, dot_pv)
 
         init = _stacked_carry(hkv, g, d)
         # the lane's live blocks: one past its length is never walked
-        _stacked_store(o_ref, jax.lax.fori_loop(
-            0, (n_pages + g_pages - 1) // g_pages, body, init))
+        _stacked_store(o_ref, jax.lax.fori_loop(0, live_blocks, body, init))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -508,7 +472,7 @@ def _sparse_decode(q, mask, kv_pool, layer, tables, lane_live, kv_lens,
     mask = jnp.pad(mask.astype(jnp.int32),
                    ((0, 0), (0, wp - mask.shape[1])))[:, None, :]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,    # layer, tables (flat), lane_live, kv_lens
+        num_scalar_prefetch=5,    # layer, tables (flat), runs, live, kv_lens
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda lane, *_: (lane, 0, 0)),
@@ -516,10 +480,7 @@ def _sparse_decode(q, mask, kv_pool, layer, tables, lane_live, kv_lens,
             pl.BlockSpec(memory_space=pl.ANY),   # the page store stays in HBM
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda lane, *_: (lane, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, 2, gs, row), kv_pool.dtype),
-            pltpu.SemaphoreType.DMA((nbuf, g_pages)),
-        ],
+        scratch_shapes=_walk_scratch(nbuf, g_pages, kv_pool),
     )
     kernel = functools.partial(
         _sparse_decode_kernel, page_size=page_size, max_pages=max_pages,
@@ -534,7 +495,8 @@ def _sparse_decode(q, mask, kv_pool, layer, tables, lane_live, kv_lens,
             _VMEM_REQUEST_MAX)),
         interpret=interpret,
         name="sparse_paged_decode",
-    )(layer, tables.reshape(-1), lane_live, kv_lens, q, mask, kv_pool)
+    )(layer, tables.reshape(-1), _table_runs(tables, g_pages), lane_live,
+      kv_lens, q, mask, kv_pool)
 
 
 def sparse_attend_decode(q, mask, kv_pool, layer, tables, lane_live, kv_lens,
