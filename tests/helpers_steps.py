@@ -31,14 +31,18 @@ def no_carry(lanes):
 
 def mixed_step(fn, params, kv, tables, toks, row_lane, row_off, q_lens,
                kv_lens, temps=None, seeds=None, spec=None, carry=None,
-               fresh=None, rem=None, stops=None, with_carry=False):
+               fresh=None, rem=None, stops=None, with_carry=False,
+               wtables=None):
     """One round on unpacked arguments: ``(next_tokens, logprobs, last
     logits, kv, *moe)``, the first two and the counters as numpy.
     ``carry`` (with ``fresh`` false in the lanes that take it) is what the
     dispatch before returned; ``with_carry`` puts the round's own carry
-    before ``kv`` in what is returned."""
+    before ``kv`` in what is returned.  ``wtables``: the window group's
+    table a lane, for a model with window layers."""
     lanes, max_pages = np.shape(tables)
-    fields = dispatch_fields("round", lanes, max_pages)
+    fields = dispatch_fields("round", lanes, max_pages, wtables is not None)
+    groups = ({} if wtables is None
+              else {"wtables": np.asarray(wtables, np.int32)})
     width = dict((name, shape) for name, _t, shape in fields)["stops"][1]
     sent = np.full((lanes, width), -1, np.int32)
     if stops is not None:
@@ -55,7 +59,7 @@ def mixed_step(fn, params, kv, tables, toks, row_lane, row_off, q_lens,
         stops=sent,
         rows=np.stack([np.asarray(a, np.int32)
                        for a in (toks, row_lane, row_off)]),
-        **_sampling(lanes, temps, seeds)))
+        **groups, **_sampling(lanes, temps, seeds)))
     out, last, *after, kv = fn(params, kv, jnp.asarray(packed),
                                no_carry(lanes) if carry is None
                                else tuple(carry))
@@ -67,7 +71,7 @@ def mixed_step(fn, params, kv, tables, toks, row_lane, row_off, q_lens,
 
 
 def decode_block(fn, params, kv, tables, carry, k, stops=None, temps=None,
-                 seeds=None, fresh=True, spec=None):
+                 seeds=None, fresh=True, spec=None, wtables=None):
     """One block of ``k`` steps from ``carry = (lengths, tokens, live,
     steps_rem)``: sent in the buffer when ``fresh`` (a chain's first
     block), else passed on as the device arrays the block before returned.
@@ -78,7 +82,11 @@ def decode_block(fn, params, kv, tables, carry, k, stops=None, temps=None,
              np.zeros((lanes,), bool), np.zeros((lanes,), np.int32))
     sent = tuple(np.asarray(c, z.dtype) for c, z in zip(carry, zeros)) \
         if fresh else zeros
-    packed = pack_words(dispatch_fields("block", lanes, max_pages), dict(
+    groups = ({} if wtables is None
+              else {"wtables": np.asarray(wtables, np.int32)})
+    packed = pack_words(dispatch_fields("block", lanes, max_pages,
+                                        wtables is not None), dict(
+        groups,
         tables=np.asarray(tables, np.int32), lengths=sent[0], tokens=sent[1],
         active=sent[2], rem=sent[3], fresh=np.full((lanes,), fresh),
         stops=(np.full((lanes, 1), -1, np.int32) if stops is None

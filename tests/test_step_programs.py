@@ -1,9 +1,11 @@
 """The step programs of every model kind, lowered and not run: a decode tick,
 a mixed round and a K = 2 block of a tiny engine's geometry, held to the
-hashes of ``tests/data/step_programs_pr54.json``.  A PR that adds a kind
-shows with it that the kinds before it lower to the text they had; a PR that
-changes a program on purpose writes the file again (``python
-tests/test_step_programs.py`` prints it).
+hashes of ``tests/data/step_programs_pr56.json`` (PR 54's file with kinds
+``zaya`` and ``mellum`` added; the eight kinds of PR 54's file are held to
+THAT file too, letter for letter).  A PR that adds a kind shows with it that
+the kinds before it lower to the text they had; a PR that changes a program
+on purpose writes the file again (``python tests/test_step_programs.py``
+prints it).
 """
 
 import hashlib
@@ -19,7 +21,8 @@ from tpulab.engine.paged import ContinuousBatcher
 from tpulab.models.spec import init_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-GOLDEN = os.path.join(ROOT, "tests", "data", "step_programs_pr54.json")
+GOLDEN = os.path.join(ROOT, "tests", "data", "step_programs_pr56.json")
+GOLDEN_PR54 = os.path.join(ROOT, "tests", "data", "step_programs_pr54.json")
 
 
 def _lowered(spec, vocab, d_ff, kw):
@@ -59,40 +62,73 @@ def _lowered(spec, vocab, d_ff, kw):
 
 def _kinds():
     import test_longcat_flash as tl
+    import test_mellum as tm
     import test_xing4 as tx
-    from tpulab.models.spec import longcat_flash_spec, xing4_spec
+    import test_zaya as tz
+    from tpulab.models.spec import (longcat_flash_spec, mellum_spec,
+                                    xing4_spec, zaya_spec)
     small = dict(lanes=2, max_len=64, page_size=8)
     return dict(test_engine_plan.KINDS,
                 longcat=(longcat_flash_spec(tl.CONFIG), tl.VOCAB, tl.D_FF,
                          small),
-                xing4=(xing4_spec(tx.CONFIG), tx.VOCAB, tx.D_FF, small))
+                xing4=(xing4_spec(tx.CONFIG), tx.VOCAB, tx.D_FF, small),
+                zaya=(zaya_spec(tz.CONFIG), tz.VOCAB, 0, small),
+                mellum=(mellum_spec(tm.CONFIG), tm.VOCAB, 0, small))
 
 
 @pytest.mark.parametrize("kind", ["dense", "glm-latent-moe", "jamba-mamba",
                                   "keye-indexer", "qwen3next-gdn",
-                                  "evabyte-eva", "longcat", "xing4"])
+                                  "evabyte-eva", "longcat", "xing4", "zaya"])
 def test_the_other_kinds_programs_are_the_parents_text(kind):
-    """The eight kinds the benchmark already had lower to the text they had
-    at the parent of PR 54 (``tests/data/step_programs_pr54.json``: a hash
-    of the StableHLO of a decode tick, a mixed round and a K = 2 block,
-    without locations), and hold no ``cca`` or ``res_scale`` scope.  The two
-    kinds with a lane state are the exception the file states: their
-    convolution now reads the window ``_segment_window`` gathers, the same
-    arithmetic in another order (a tick and a block) and as one weighted sum
-    over the stacked window (a round); their hashes are this PR's.
+    """The nine kinds the benchmark already had lower to the text they had
+    at the parent of PR 56: eight to the hashes of PR 54's file
+    (``tests/data/step_programs_pr54.json``: a hash of the StableHLO of a
+    decode tick, a mixed round and a K = 2 block, without locations), which
+    this PR's file repeats letter for letter, and ``zaya`` to the hashes its
+    programs had at the parent of PR 56 (430d32e: PR 54's file held none
+    for it).  Only ``zaya`` holds a ``cca`` or ``res_scale`` scope, and none
+    of the nine a table of a window group.  (In PR 54's file the two kinds
+    with a lane state are the exception it states: their convolution reads
+    the window ``_segment_window`` gathers; their hashes are that PR's.)
 
     A later PR that changes a program on purpose writes the file again:
     ``python tests/test_step_programs.py`` prints it."""
     with open(GOLDEN, encoding="utf-8") as f:
         golden = json.load(f)
+    with open(GOLDEN_PR54, encoding="utf-8") as f:
+        before = json.load(f)
     texts, scoped = _lowered(*_kinds()[kind])
     got = [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts]
     assert got == golden["programs"][kind], (kind, got)
-    assert (kind in golden["changed_in_pr54"]) == (kind in ("jamba-mamba",
+    if kind != "zaya":
+        assert golden["programs"][kind] == before["programs"][kind]
+    assert (kind in before["changed_in_pr54"]) == (kind in ("jamba-mamba",
                                                             "qwen3next-gdn"))
     for scope in ("cca_", "res_scale"):
-        assert scope not in scoped
+        assert (scope in scoped) == (kind == "zaya")
     assert "paged_mixed_step" in scoped
+
+
+def test_the_new_kinds_programs_carry_two_groups_and_two_tables():
+    """Kind ``mellum``: the hashes this PR's file holds, the page store a
+    PAIR of arrays of one and three layers that every program takes and
+    returns, and a second table in every program's buffer."""
+    with open(GOLDEN, encoding="utf-8") as f:
+        golden = json.load(f)
+    spec, vocab, d_ff, kw = _kinds()["mellum"]
+    texts, scoped = _lowered(spec, vocab, d_ff, kw)
+    got = [hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts]
+    assert got == golden["programs"]["mellum"], got
+    assert set(golden["programs"]) == set(_kinds())
+    # 2 lanes x 8 pages of 8 rows: full (1, 17, 2, 8, 32), window (3, P, ...)
+    for text in texts:
+        assert "tensor<1x17x2x8x32xf32>" in text
+        assert "tensor<3x" in text and "x2x8x32xf32>" in text
+    from tpulab.engine.paged_steps import dispatch_fields
+    for program in ("tick", "block", "round"):
+        one = dispatch_fields(program, 2, 8)
+        two = dispatch_fields(program, 2, 8, True)
+        assert [f for f in two if f[0] != "wtables"] == list(one)
 
 
 if __name__ == "__main__":

@@ -21,6 +21,23 @@ and who holds it:
   not by page: what a Mamba layer keeps of a sequence (its SSM state and
   the tail of its causal convolution), of one size whatever the context.
 
+**Layer groups.**  A :class:`PagedKVPool` is ONE group of layers under ONE
+table a lane: what every model whose attention layers agree on what a lane's
+pages are is served with.  A model with window layers beside full ones
+(``ModelSpec.page_groups``) is served with TWO pools, the full layers' (the
+engine's ``pool``: what ``n_pages`` sizes, admission waits on and the gauges
+read) and the window layers' (``wpool``), each with its own device array
+``(L_g, P_g) + kv_page_shape``, free extents, reference counts and table a
+lane; the step programs take, donate and return the pair, as they carry
+``(kv, index)``.  The scheduler takes and returns the window group's pages
+in whole key blocks of the kernels' walk
+(:meth:`~tpulab.engine.plan.EnginePlan.window_lane_pages`), every block one
+grant, so that the group's extents stay whole blocks and a block a window
+layer walks is one run of ids; the blocks wholly behind a lane's window go
+back to the group while the request lives
+(``ContinuousBatcher._window_pages`` says why the next owner may have them
+at once).
+
 The step programs that read and write the pool are
 :mod:`tpulab.engine.paged_steps`; the scheduler that hands pages out is
 :mod:`tpulab.engine.paged`.  Neither is imported here.

@@ -118,7 +118,7 @@ class _PagedRequest:
                  "spec_drafted", "spec_accepted", "spec_probe_in",
                  "spec_probing", "tenant", "lane", "fl", "batch",
                  "pf_started", "pf_digests", "pf_shared", "pf_t0",
-                 "eva_done", "walk_runs")
+                 "eva_done", "walk_runs", "wpages", "wfirst")
 
     def __init__(self, prompt: np.ndarray, steps: int, on_token=None,
                  sampling: Optional[SamplingParams] = None,
@@ -197,6 +197,12 @@ class _PagedRequest:
         #: :meth:`run_blocks`: key blocks of the page table looked at, and
         #: those among them that were one ascending run of ids
         self.walk_runs = (0, 0)
+        #: the lane's pages in the page store's WINDOW group (a model with
+        #: window layers): whole key blocks of the walk, entries ``[wfirst,
+        #: wfirst + len(wpages))`` of its window table; the blocks behind
+        #: the window have gone back to the group
+        self.wpages: List[int] = []
+        self.wfirst = 0
         self.t_submit = _time.perf_counter()
         self.t_prefill0: Optional[float] = None  # first prefill start
         self.t_first: Optional[float] = None     # first emitted token
@@ -422,6 +428,28 @@ class ContinuousBatcher:
             plan.pool_layers, 0 if plan.latent else plan.n_kv,
             0 if plan.latent else plan.head_dim, plan.kv_dtype, device,
             mesh=mesh, latent_width=plan.latent, index_dim=plan.index_dim)
+        #: the page store's WINDOW group (None: one group, ``pool``, as
+        #: every model without window layers has): the window layers' own
+        #: array, free extents, reference counts and a table a lane.  Sized
+        #: here, from the lanes, the window and the rows a dispatch may
+        #: write ahead (a round's budget; two decode blocks in flight), so
+        #: that a lane's blocks are always there: admission waits on
+        #: ``pool`` (the full group, which ``n_pages`` sizes) alone
+        self.wpool = None
+        if plan.window:
+            self._wlane_pages = plan.window_lane_pages(
+                max(self._round_budget, 2 * self.BLOCK_K_MENU[-1]))
+            self.wpool = PagedKVPool(
+                self._wlane_pages * lanes + 1, page_size, plan.window_layers,
+                plan.n_kv, plan.head_dim, plan.kv_dtype, self.pool.device)
+        #: the window group's turnover (``debug_state()["dispatch"]
+        #: ["window"]``): pages that went back to it under a living request,
+        #: the reservations that returned any, and the scheduler thread's
+        #: seconds in those (inside its ``plan`` and ``dispatch`` stages)
+        self._window_stats = dict(pages_released=0, releases=0,
+                                  release_s=0.0)
+        #: of ``walk_blocks`` and ``walk_run_blocks``, the window group's
+        self._window_walk = [0, 0]
         #: the per-lane state of the layers that keep one (Mamba, Gated
         #: DeltaNet, CCA: ``spec.state_layers``; None without any):
         #: rotates through every dispatch beside ``pool.kv`` (_kv_state)
@@ -576,9 +604,13 @@ class ContinuousBatcher:
         #: attention layer reads of the lane's pages at least)
         #: with EVA windows ``keys`` are the ROWS attended (summaries and
         #: the window's own) and ``summary_keys`` the summaries among them
+        #: with window layers ``window_keys`` is what ``keys`` is on a
+        #: window layer: the keys inside the window of each pass
         self.lane_work = {kind: dict(passes=0, rows=0, keys=0,
                                      **({"summary_keys": 0}
-                                        if plan.eva_window else {}))
+                                        if plan.eva_window else {}),
+                                     **({"window_keys": 0}
+                                        if plan.window else {}))
                           for kind in ("decode", "round")}
         #: the (query row, key) pairs behind the rounds' rows, exactly: a
         #: row at context ``c`` attends ``c`` keys (``lane_work["round"]``
@@ -586,6 +618,9 @@ class ContinuousBatcher:
         #: this is what is COMPUTED, an attention layer; for decode steps
         #: the two are one number)
         self.round_attn_pairs = 0
+        #: those pairs on a window layer: a row at context ``c`` attends
+        #: ``min(c, window)`` keys (0 without window layers)
+        self.round_window_pairs = 0
         #: decode blocks enqueued before their predecessor (a block or a
         #: round) was fetched (_chain_block): over
         #: ``dispatch_kinds["decode"]`` the share of blocks whose host turn
@@ -735,11 +770,14 @@ class ContinuousBatcher:
     def _kv_state(self):
         """What the step programs take as ``kv_pool``, donate and return:
         the page store, or with a lane state the pair ``(page store, lane
-        state)``."""
+        state)``, with an indexer ``(page store, index rows)``, with window
+        layers the store's two groups ``(full, window)``."""
         if self.state is not None:
             return self.pool.kv, self.state.arrays
         if self.pool.index is not None:
             return self.pool.kv, self.pool.index
+        if self.wpool is not None:
+            return self.pool.kv, self.wpool.kv
         return self.pool.kv
 
     @_kv_state.setter
@@ -748,6 +786,8 @@ class ContinuousBatcher:
             self.pool.kv, self.state.arrays = value
         elif self.pool.index is not None:
             self.pool.kv, self.pool.index = value
+        elif self.wpool is not None:
+            self.pool.kv, self.wpool.kv = value
         else:
             self.pool.kv = value
 
@@ -773,6 +813,17 @@ class ContinuousBatcher:
         w["keys"] += triangle if kind == "decode" else start + n
         if kind == "round":
             self.round_attn_pairs += triangle
+        if self.plan.window:
+            # a window layer's row at context c attends min(c, window) keys;
+            # a segment reads the keys from its first row's window on
+            win = self.plan.window
+            short = min(max(win - start, 0), n)     # rows with c <= window
+            pairs = (short * start + short * (short + 1) // 2
+                     + (n - short) * win)
+            w["window_keys"] += (pairs if kind == "decode"
+                                 else min(start + n, win + n - 1))
+            if kind == "round":
+                self.round_window_pairs += pairs
         if self._sparse is None:
             return
         k, layers = self.model_spec.index_topk, self.model_spec.n_layers
@@ -801,6 +852,20 @@ class ContinuousBatcher:
                        len(req.pages)) // g
             self.walk_blocks += steps * full
             self.walk_run_blocks += steps * req.run_blocks(g, full)
+            if self.wpool is not None:
+                # the window group's walk: the full blocks from the one that
+                # holds the oldest visible key on, each taken as one grant
+                first = max(req.length - self.plan.window + 1, 0) // (g * ps)
+                blocks = [req.wpages[i:i + g] for i in range(
+                    max(first * g - req.wfirst, 0), full * g - req.wfirst,
+                    g)]
+                # (a grant's ids ascend: one run iff its ends are g - 1 apart)
+                runs = sum(len(blk) == g and blk[-1] - blk[0] == g - 1
+                           for blk in blocks)
+                self.walk_blocks += steps * len(blocks)
+                self.walk_run_blocks += steps * runs
+                self._window_walk[0] += steps * len(blocks)
+                self._window_walk[1] += steps * runs
 
     def _boundary(self, req: _PagedRequest) -> int:
         """The first position ``req`` may not take in before a compaction:
@@ -814,6 +879,68 @@ class ContinuousBatcher:
         if not self.plan.eva_window:
             return total
         return self.model_spec.cache_rows_peak(total, req.eva_done)
+
+    def _window_pages(self, req: _PagedRequest, lo: int, hi: int) -> None:
+        """Move ``req``'s table in the page store's WINDOW group to the key
+        blocks that overlap ``(lo - window, hi]``: ``lo`` the lowest
+        position the dispatch about to be made may hold a query row at (the
+        lane's committed length: what is in flight only moves it up), ``hi``
+        the last position it may write.  Blocks wholly behind ``lo -
+        window`` go back to the group NOW, under the living request, and
+        the blocks up to ``hi`` are taken, a block a grant (its ids one
+        run: the walk reads it as one copy).  Nothing is rewritten: the
+        table moves.
+
+        Why a returned page may be handed to another lane at once: a
+        dispatched program holds the TABLE it was given (the tables are
+        fields of its one packed buffer), so the programs in flight still
+        read the page through theirs; the page's next owner writes it in a
+        program dispatched LATER, and the programs of one device run in the
+        order they were dispatched, so every read of the old owner's rows
+        is done before the first write of the new owner's.  A later program
+        of the old owner never sees the page: its table starts behind it,
+        and its walk and mask start at ``row position - window + 1``.
+
+        The group is sized so that the blocks are always there
+        (``EnginePlan.window_lane_pages``): admission never waits on it."""
+        g, ps, win = self._walk_pages, self.page_size, self.plan.window
+        first = max(lo - win + 1, 0) // (g * ps) * g
+        if first > req.wfirst:
+            t0 = _time.perf_counter()
+            n = min(first - req.wfirst, len(req.wpages))
+            freed, req.wpages = req.wpages[:n], req.wpages[n:]
+            req.wfirst = first
+            if freed:
+                self.wpool.release_pages(freed)
+                ws = self._window_stats
+                ws["pages_released"] += len(freed)
+                ws["releases"] += 1
+                ws["release_s"] += _time.perf_counter() - t0
+        end = (hi // (g * ps) + 1) * g
+        while req.wfirst + len(req.wpages) < end:
+            pages = self.wpool.allocate_pages(g)
+            if pages is None:
+                raise RuntimeError(
+                    "the page store's window group has no free block for a "
+                    f"lane that holds {len(req.wpages)} of its "
+                    f"{self._wlane_pages} pages: the group is sized so that "
+                    "this cannot be")
+            req.wpages.extend(pages)
+
+    def _lane_tables(self, lanes_reqs) -> Dict[str, np.ndarray]:
+        """The table fields of a dispatch's buffer, ``tables`` (and, with
+        window layers, ``wtables``: the window group's) with the rows of
+        ``lanes_reqs`` ``[(lane, req), ...]`` filled in: entry ``p //
+        page_size`` is position ``p``'s page."""
+        out = {"tables": np.zeros((self.lanes, self.max_pages), np.int32)}
+        if self.wpool is not None:
+            out["wtables"] = np.zeros_like(out["tables"])
+        for lane, req in lanes_reqs:
+            out["tables"][lane, :len(req.pages)] = req.pages
+            if self.wpool is not None:
+                out["wtables"][lane, req.wfirst:req.wfirst
+                               + len(req.wpages)] = req.wpages
+        return out
 
     def _eva_compact(self) -> None:
         """Compact every lane that stands at the end of an EVA window: its
@@ -1115,6 +1242,8 @@ class ContinuousBatcher:
             self.state.close()
         if self._owns_pool and not self._thread.is_alive():
             self.pool.close()  # free the page stores' HBM eagerly
+            if self.wpool is not None:
+                self.wpool.close()
             if self.hbm is not None:
                 from tpulab.hbm import KV_TENANT
                 self.hbm.release(KV_TENANT, "pool")
@@ -1139,6 +1268,16 @@ class ContinuousBatcher:
             held = [(len(r.pages), r.length) for r in self._active
                     if r is not None and not r.pending_prompt]
         return sum(p for p, _ in held), sum(n for _, n in held)
+
+    @property
+    def decode_window_pages(self) -> int:
+        """The pages of the page store's WINDOW group the decoding lanes
+        hold right now (0 without window layers): beside
+        :attr:`decode_holdings`, what a position costs a lane whose window
+        layers keep their window alone."""
+        with self._cv:
+            return sum(len(r.wpages) for r in self._active
+                       if r is not None and not r.pending_prompt)
 
     @property
     def spec_acceptance(self) -> float:
@@ -1358,6 +1497,9 @@ class ContinuousBatcher:
                     "draft_pages": len(req.draft_pages),
                     "cancelled": req.cancelled,
                     "length": req.length, "eva_done": req.eva_done,
+                    **({"window_pages": len(req.wpages),
+                        "window_first": req.wfirst}
+                       if self.wpool is not None else {}),
                 })
             queue_head = [{"tenant": q.tenant, "priority": q.priority,
                            "age_s": round(now - q.t_submit, 6),
@@ -1418,6 +1560,11 @@ class ContinuousBatcher:
                          "lane_work": {kind: dict(w) for kind, w
                                        in self.lane_work.items()},
                          "round_attn_pairs": self.round_attn_pairs,
+                         **({"round_window_pairs": self.round_window_pairs,
+                             "window": dict(
+                                 self._window_stats, keys=self.plan.window,
+                                 lane_pages=self._wlane_pages)}
+                            if self.wpool is not None else {}),
                          "ahead_blocks": self.ahead_blocks,
                          "chain": {"breaks": dict(self.chain_breaks),
                                    "late_links": self.late_links},
@@ -1442,6 +1589,19 @@ class ContinuousBatcher:
             "profile_armed": profile_armed,
             "last_release": last_release,
         }
+        if self.wpool is not None:
+            # the page store by layer group; the keys above read the FULL
+            # group (what admission waits on and the gauges mean)
+            ww, wr = self._window_walk
+            out["pool"]["groups"] = {
+                name: {"n_pages": grp.n_pages, "free_pages": grp.free_pages,
+                       "page_nbytes": grp.page_nbytes,
+                       "layers": grp.n_layers, "hbm_bytes": grp.hbm_bytes,
+                       "walk_blocks": blocks, "walk_run_blocks": runs}
+                for name, grp, blocks, runs in (
+                    ("full", pool, self.walk_blocks - ww,
+                     self.walk_run_blocks - wr),
+                    ("window", self.wpool, ww, wr))}
         if self._spec is not None:
             out["spec"] = {"dispatches": self.spec_dispatches,
                            "fallbacks": self.spec_fallbacks,
@@ -1899,6 +2059,9 @@ class ContinuousBatcher:
                            pages=needed, tokens=req.length)
         self.pool.release_pages(req.pages)
         req.pages = []
+        if req.wpages:
+            self.wpool.release_pages(req.wpages)
+        req.wpages, req.wfirst = [], 0
         # the draft table is never snapshotted: it is cheap to regenerate
         # (one draft forward at resume), so its pages go home NOW and the
         # resume's warm-up rebuilds it exactly
@@ -2055,6 +2218,8 @@ class ContinuousBatcher:
                 if self.prefix_cache is not None:
                     self.prefix_cache.drop_all()  # entries died with the pool
                 self.pool.reset()
+                if self.wpool is not None:
+                    self.wpool.reset()
                 if self.state is not None:
                     self.state.reset()
 
@@ -2490,7 +2655,11 @@ class ContinuousBatcher:
                     for lane, req in segs},
                 {lane: 0 if chain else req.tokens_out[-1]
                  for lane, req in decode_parts})
-            tables = np.zeros((b, self.max_pages), np.int32)
+            if self.wpool is not None:
+                for lane, req in segs:
+                    self._window_pages(req, req.length,
+                                       req.length + chunks[lane] - 1)
+            tables = self._lane_tables(segs + decode_parts)
             kv_lens = np.zeros((b,), np.int32)
             temps = np.zeros((b,), np.float32)
             seeds = np.zeros((b, 2), np.uint32)
@@ -2502,7 +2671,6 @@ class ContinuousBatcher:
             for lane, req in segs + decode_parts:
                 lane_reqs[lane] = req
                 kv_lens[lane] = req.length + q_lens[lane]
-                tables[lane, :len(req.pages)] = req.pages
                 if req.pending_prompt and q_lens[lane] < len(
                         req.pending_prompt):
                     continue        # mid-prompt: no pick, no budget
@@ -2524,7 +2692,7 @@ class ContinuousBatcher:
             if chain is not None:
                 fresh[[lane for lane, _ in decode_parts]] = False
             buf = pack_words(self.programs.fields["round"], dict(
-                tables=tables, q_lens=q_lens, kv_lens=kv_lens,
+                tables, q_lens=q_lens, kv_lens=kv_lens,
                 temps=temps, seeds=seeds, fresh=fresh, rem=rem,
                 stops=stops, rows=np.stack([toks, row_lane, row_off])))
         if decode_parts:
@@ -2875,6 +3043,11 @@ class ContinuousBatcher:
                 continue
             if appends < appends_want:
                 cap = min(cap, appends)
+            if self.wpool is not None:
+                # the window group moves with the lane: behind its
+                # COMMITTED length (what is in flight holds its own table),
+                # ahead to the last row this block may write
+                self._window_pages(req, req.length, base + appends - 1)
             parts.append((lane, req, new))
         if not parts:
             return k, []
@@ -3105,11 +3278,8 @@ class ContinuousBatcher:
         clock = self._stages
         with part(clock, "dispatch.arrays"):
             b = self.lanes
-            tables = np.zeros((b, self.max_pages), np.int32)
-            lane_reqs = {}
-            for lane, req, _new in parts:
-                lane_reqs[lane] = req
-                tables[lane, :len(req.pages)] = req.pages
+            lane_reqs = {lane: req for lane, req, _new in parts}
+            tables = self._lane_tables(lane_reqs.items())
             lengths = np.zeros((b,), np.int32)
             tokens = np.zeros((b,), np.int32)
             active = np.zeros((b,), bool)
@@ -3140,7 +3310,7 @@ class ContinuousBatcher:
             else:
                 temps, seeds, stops = host
             buf = pack_words(self.programs.fields["block"], dict(
-                tables=tables, lengths=lengths, tokens=tokens, active=active,
+                tables, lengths=lengths, tokens=tokens, active=active,
                 temps=temps, seeds=seeds, rem=rem, stops=stops,
                 fresh=np.full((b,), carry is None)))
         # chaos: decode fault site — tripped once per DECODE TICK (k times
@@ -3574,7 +3744,7 @@ class ContinuousBatcher:
         st = self._stages
         with stage(st, "dispatch"):
             b = self.lanes
-            tables = np.zeros((b, self.max_pages), np.int32)
+            tables = self._lane_tables((lane, req) for lane, req, _ in parts)
             lengths = np.zeros((b,), np.int32)
             tokens = np.zeros((b,), np.int32)
             active = np.zeros((b,), bool)
@@ -3589,7 +3759,6 @@ class ContinuousBatcher:
             for lane, req, _new in parts:
                 lane_reqs[lane] = req
                 tokens[lane] = req.tokens_out[-1]
-                tables[lane, :len(req.pages)] = req.pages
                 lengths[lane] = req.length
                 active[lane] = True
                 sp = req.sampling
@@ -3608,7 +3777,7 @@ class ContinuousBatcher:
             out, logits, self._kv_state = self.programs.tick(
                 self.params, self._kv_state,
                 self._put(pack_words(self.programs.fields["tick"], dict(
-                    tables=tables, lengths=lengths, tokens=tokens,
+                    tables, lengths=lengths, tokens=tokens,
                     active=active, temps=temps, seeds=seeds))))
             ticket = st.launched()
             out.copy_to_host_async()
@@ -3708,6 +3877,14 @@ class ContinuousBatcher:
                 key=("ship", req.export_digest))
         self.last_release = {"lane": lane, "pages": list(req.pages),
                              "length": req.length}
+        if self.wpool is not None:
+            # what the window group still held of it: the pages of table
+            # entries ``[window_first, ...)``, position ``window_first *
+            # page_size`` on
+            self.last_release.update(window_pages=list(req.wpages),
+                                     window_first=req.wfirst)
+            self.wpool.release_pages(req.wpages)
+            req.wpages = []
         self.pool.release_pages(req.pages)
         if req.draft_pages:
             self.pool.release_pages(req.draft_pages)

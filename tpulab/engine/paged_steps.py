@@ -31,7 +31,10 @@ state)``: the attention layers' pages and the per-lane state of the layers
 that keep one (:class:`~tpulab.engine.kv_pool.LaneStateStore` ``.arrays``;
 a CCA layer keeps both), one pytree;
 for a model with a learned indexer it is the pair ``(page store, index
-rows)`` (``PagedKVPool.kv`` and ``.index``).
+rows)`` (``PagedKVPool.kv`` and ``.index``); for a model with window layers
+beside full ones the pair of the page store's two layer groups ``(full,
+window)``, each a :class:`~tpulab.engine.kv_pool.PagedKVPool` ``.kv`` with a
+table a lane of its own (:func:`_layer_block`).
 The functions keep their ``__name__``: a trace names a program
 ``jit_<name>``, and the benchmark's readers key on it.
 :class:`StepPrograms` jits them for one engine plan
@@ -62,14 +65,17 @@ def _scatter_kv(kv_pool, layer, page_idx, slot_idx, knew, vnew):
     return kv_pool.at[layer, page_idx, 1, slot_idx].set(vnew)
 
 
-def _gather_attend(q, k_layer, v_layer, tables, qpos, compute_dtype):
+def _gather_attend(q, k_layer, v_layer, tables, qpos, compute_dtype,
+                   window: int = 0):
     """Dense-gather paged attention (the XLA fallback math, single source
     of truth for decode ticks and extend/chunked prefill).
 
     q (B, M, H, D) query tokens; k_layer/v_layer (P, S, Hkv*D) one
     layer's K and V rows (XLA fuses the slice of the pool into the
     gather); tables (B, MP) page ids; qpos (B, M) global position
-    of each query token (visibility: context j attends iff j <= qpos).
+    of each query token (visibility: context j attends iff j <= qpos, and
+    on a window layer, ``window`` > 0, iff also ``j > qpos - window``: the
+    table's entries under the window may be any id, the scratch page's).
     Returns (B, M, H*D).
     """
     import jax
@@ -85,6 +91,8 @@ def _gather_attend(q, k_layer, v_layer, tables, qpos, compute_dtype):
                         k_ctx.astype(jnp.float32)) / np.sqrt(d)
     j = jnp.arange(mp * page_size)
     mask = j[None, None, :] <= qpos[:, :, None]          # (B, M, K)
+    if window:
+        mask &= j[None, None, :] > qpos[:, :, None] - window
     scores = jnp.where(mask[:, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(compute_dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs,
@@ -418,6 +426,12 @@ def _pages(kv_pool):
     first of the pair a model with a lane state (``(pages, lane state)``)
     or with an indexer (``(pages, index rows)``) is served with."""
     return kv_pool[0] if isinstance(kv_pool, tuple) else kv_pool
+
+
+def _windowed(spec) -> bool:
+    """Whether ``spec`` (None: the dense decoder) has window layers: its
+    page store is then two groups and a dispatch carries a table each."""
+    return spec is not None and bool(spec.window)
 
 
 def _row_lens(spec, kv_lens):
@@ -1008,7 +1022,20 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
 
     With an indexer (``spec.index_topk``) ``kv_pool`` is the pair ``(page
     store, index rows)`` and the attention reads only the keys the indexer
-    selects (:func:`_sparse_attention`).
+    selects (:func:`_sparse_attention`).  With window layers beside full
+    ones (``spec.window``, ``spec.attn_kinds``; Mellum2's) it is the pair of
+    the page store's two groups ``(full, window)``, each ``(L_g, P_g) +
+    kv_page_shape``, and ``seg`` carries the window group's table and write
+    targets (``wtables``, ``wpage_idx``, read from it as ``page_idx`` is
+    from ``tables``; ``slot_idx`` is the position's slot in either): the
+    plain GQA walk below scatters into and walks the layer's OWN group at
+    its index there.  A window layer sees the ``spec.window`` keys that end
+    at the row: its table holds live ids from the block of the lane's
+    oldest visible key on, and the walk and the mask take the window as
+    their lower bound (:func:`tpulab.ops.ragged_attention._ragged_attn`
+    ``window=``; :func:`_gather_attend`).  Both kinds norm each head of q
+    and k (``spec.qk_norm``); a full layer turns them by YaRN's table with
+    its factor on cos and sin, a window layer by ``theta^(-2j / d)``.
 
     The layer's mixer is attention over the pages (above; with an output
     gate and partial RoPE, :func:`_gated_attention`) or, by ``spec.mixers``,
@@ -1107,6 +1134,15 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     else:
         b, m = x.shape[:2]
         at = spec.store_layer(layer)       # its layer of the page store
+        # with window layers beside full ones the store is two groups, each
+        # with a table a lane: the layer scatters into and walks ITS group
+        # (``window`` keys at most, 0: every key), the other passes through
+        window, tables, other = spec.layer_window(layer), seg["tables"], None
+        if spec.window:
+            full, win = kv_pool
+            kv_pool, other = (win, full) if window else (full, win)
+            if window:
+                tables, page_idx = seg["wtables"], seg["wpage_idx"]
         if spec.cca_taps:
             # ... and of the lane state: q, k and v from its convolutions
             q, knew, vnew, state = _cca_qkv(spec, p["cca"], at, h, pos,
@@ -1115,7 +1151,18 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
             q, knew, vnew = split_qkv(h @ qmat(p["wqkv"], compute_dtype), b,
                                       m, spec.n_heads, spec.n_kv_heads,
                                       spec.head_dim)
-            if spec.rope_theta:
+            if spec.qk_norm:
+                q = _rmsnorm(q, p["q_norm"]["scale"], spec.rms_eps)
+                knew = _rmsnorm(knew, p["k_norm"]["scale"], spec.rms_eps)
+            if spec.rope_scaling and not window:
+                # a FULL layer under YaRN: its frequencies, and its factor
+                # on cos and sin (so q . k carries the factor's square), in
+                # float32 before the cast; a window layer turns by theta
+                inv, f = spec.rope_inv_freq(), spec.rope_factor
+                q, knew = ((apply_rope(t.astype(jnp.float32), pos,
+                                       inv_freq=inv) * f).astype(t.dtype)
+                           for t in (q, knew))
+            elif spec.rope_theta:
                 q = apply_rope(q, pos, spec.rope_theta)
                 knew = apply_rope(knew, pos, spec.rope_theta)
         tail = knew.shape[2:]
@@ -1127,8 +1174,8 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
             if not seg["use_kernel"]:
                 # XLA fallback: gather pages densely then mask
                 outs.append(_gather_attend(
-                    qq, kv_pool[at, :, 0], kv_pool[at, :, 1], seg["tables"],
-                    qpos, compute_dtype).reshape(qq.shape))
+                    qq, kv_pool[at, :, 0], kv_pool[at, :, 1], tables,
+                    qpos, compute_dtype, window).reshape(qq.shape))
                 continue
             # pallas ragged kernel: walks block tables page-by-page, no
             # dense gather materialization; fused pages = 1 DMA/page;
@@ -1143,14 +1190,16 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
                 from tpulab.tpu.platform import pallas_interpret
                 outs.append(ra._ragged_attn(
                     qq, kv_pool, jnp.asarray(at, jnp.int32).reshape(1),
-                    seg["tables"], q_lens, seg["kv_lens"],
-                    pallas_interpret(), g_pages=gk, nbuf=nk))
+                    tables, q_lens, seg["kv_lens"],
+                    pallas_interpret(), g_pages=gk, nbuf=nk, window=window))
             else:
                 outs.append(ra.ragged_paged_attention(
-                    qq, kv_pool, at, seg["tables"], q_lens, seg["kv_lens"],
-                    mesh=seg["mesh"], g_pages=gk, nbuf=nk))
+                    qq, kv_pool, at, tables, q_lens, seg["kv_lens"],
+                    mesh=seg["mesh"], g_pages=gk, nbuf=nk, window=window))
         attn = _segment_rows(outs, seg).astype(compute_dtype)
         attn = attn.reshape(b, m, -1)
+        if other is not None:
+            kv_pool = (other, kv_pool) if window else (kv_pool, other)
     if seg["use_kernel"]:
         # rows that hold no token take zeros: the kernel leaves the block of
         # a lane it skips unwritten, and what that holds may not be a number.
@@ -1244,7 +1293,8 @@ def unpack_words(fields, buf) -> Dict[str, Any]:
 ROUND_STOPS = 8
 
 
-def dispatch_fields(program: str, lanes: int, max_pages: int):
+def dispatch_fields(program: str, lanes: int, max_pages: int,
+                    window: bool = False):
     """What the host sends with a dispatch of ``program``: ``"tick"``
     (:func:`paged_decode_step_sampled`), ``"block"``
     (:func:`paged_decode_block`; ``fresh`` says, a lane, whether lengths,
@@ -1255,15 +1305,22 @@ def dispatch_fields(program: str, lanes: int, max_pages: int):
     its decode row's token, ``kv_lens`` and ``rem`` count or the carry's;
     ``rem`` is the tokens a lane that emits in this round still wants, this
     round's included, and 0 for a lane in mid-prompt; ``stops`` its stop
-    ids padded with -1, :data:`ROUND_STOPS` wide)."""
-    b, table = (lanes,), ("tables", _I32, (lanes, max_pages))
+    ids padded with -1, :data:`ROUND_STOPS` wide).  ``window``: the model
+    has window layers, and behind ``tables`` (the full group's) goes
+    ``wtables``, the window group's table a lane, as wide: entry ``p //
+    page_size`` is position ``p``'s page in either, the window group's live
+    from the block of the lane's oldest visible key on."""
+    b = (lanes,)
+    table = (("tables", _I32, (lanes, max_pages)),) + (
+        (("wtables", _I32, (lanes, max_pages)),) if window else ())
     sampling = (("temps", _F32, b), ("seeds", _U32, (lanes, 2)))
     if program == "round":
-        return (table, ("q_lens", _I32, b), ("kv_lens", _I32, b)) + sampling \
-            + (("fresh", _BOOL, b), ("rem", _I32, b),
-               ("stops", _I32, (lanes, ROUND_STOPS)), ("rows", _I32, (3, -1)))
-    fields = (table, ("lengths", _I32, b), ("tokens", _I32, b),
-              ("active", _BOOL, b)) + sampling
+        return table + (("q_lens", _I32, b), ("kv_lens", _I32, b)) \
+            + sampling + (("fresh", _BOOL, b), ("rem", _I32, b),
+                          ("stops", _I32, (lanes, ROUND_STOPS)),
+                          ("rows", _I32, (3, -1)))
+    fields = table + (("lengths", _I32, b), ("tokens", _I32, b),
+                      ("active", _BOOL, b)) + sampling
     if program == "tick":
         return fields
     extra = {"block": ("fresh", _BOOL, b),
@@ -1313,7 +1370,7 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
                       rope_theta: Optional[float] = None,
                       temps=None, seeds=None,
                       kernel_geometry: Optional[tuple] = None,
-                      mesh=None, spec=None):
+                      mesh=None, spec=None, wtables=None):
     """One batched decode tick over the paged pool.
 
     Shapes: kv_pool (L, P, 2, S, Hkv*D) fused page store (axis 2 = K/V,
@@ -1335,7 +1392,10 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
 
     For a ``spec`` with Mamba layers ``kv_pool`` is the pair ``(page store,
     lane state)``, in and out; a lane that is not ``active`` holds its
-    state as it routes its K/V to the scratch page.
+    state as it routes its K/V to the scratch page.  For a ``spec`` with
+    window layers it is the pair of the page store's groups ``(full,
+    window)`` and ``wtables (B, MP)`` the window group's table a lane
+    (:func:`_layer_block`).
     """
     import jax.numpy as jnp
     from tpulab.models.transformer import _lm_head, _rmsnorm
@@ -1364,6 +1424,9 @@ def paged_decode_step(params, kv_pool, tables, lengths, tokens,
                kernel_geometry=kernel_geometry, mesh=mesh)
     if spec.eva_window:
         seg["qpos"] = row[:, None]
+    if spec.window:
+        seg.update(wtables=wtables, wpage_idx=jnp.where(
+            active, wtables[jnp.arange(b), row // page_size], 0))
     moe_stats = []
     for layer in range(spec.n_layers):
         x, kv_pool, stats = _layer_block(
@@ -1398,7 +1461,10 @@ def paged_decode_step_sampled(params, kv_pool, packed, lanes: int,
     logits (B, vocab), kv_pool)``: ``results`` is :func:`result_fields`
     ``(lanes, moe=...)`` packed, ``logits`` stays on the device unless a
     host-sampled lane fetches its row."""
-    f = unpack_words(dispatch_fields("tick", lanes, max_pages), packed)
+    f = unpack_words(dispatch_fields("tick", lanes, max_pages,
+                                     _windowed(kw.get("spec"))), packed)
+    if "wtables" in f:
+        kw = dict(kw, wtables=f["wtables"])
     nt, lp, logits, kv_pool, *moe = paged_decode_step(
         params, kv_pool, f["tables"], f["lengths"], f["tokens"], f["active"],
         temps=f["temps"], seeds=f["seeds"], **kw)
@@ -1461,9 +1527,12 @@ def paged_decode_block(params, kv_pool, packed, carry, lanes: int,
     import jax
     import jax.numpy as jnp
 
-    f = unpack_words(dispatch_fields("block", lanes, max_pages), packed)
+    f = unpack_words(dispatch_fields("block", lanes, max_pages,
+                                     _windowed(spec)), packed)
     tables, temps, seeds, stop_ids = (f["tables"], f["temps"], f["seeds"],
                                       f["stops"])
+    # (the window group's table rides every step where the model has one)
+    groups = {"wtables": f["wtables"]} if "wtables" in f else {}
     window = spec.eva_window if spec is not None else 0
     lengths, tokens, active, steps_rem = (
         jnp.where(f["fresh"], f[name], kept) for name, kept in zip(
@@ -1477,7 +1546,7 @@ def paged_decode_block(params, kv_pool, packed, carry, lanes: int,
             compute_dtype=compute_dtype, use_kernel=use_kernel,
             n_kv_heads=n_kv_heads, rope_theta=rope_theta,
             temps=temps, seeds=seeds, kernel_geometry=kernel_geometry,
-            mesh=mesh, spec=spec)
+            mesh=mesh, spec=spec, **groups)
         emitted = live
         nt = jnp.where(live, nt, toks)           # dead lanes hold position
         lens = lens + emitted.astype(jnp.int32)
@@ -1524,7 +1593,7 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
                          rope_theta: Optional[float] = None,
                          mesh=None,
                          kernel_geometry: Optional[tuple] = None,
-                         last_only: bool = False, spec=None):
+                         last_only: bool = False, spec=None, wtables=None):
     """One fused multi-token forward over ragged per-lane segments in the
     PADDED form, every product on ``B x M`` rows (ROADMAP item 2, "Ragged
     Paged Attention" in PAPERS.md).  The K+1 speculative verify runs it
@@ -1583,6 +1652,13 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
                mesh=mesh)
     if spec.eva_window:
         seg["qpos"] = row
+    if spec.window:
+        # the window group's table and write targets (``wtables``, as
+        # ``tables`` is the full group's)
+        seg.update(wtables=wtables, wpage_idx=jnp.where(
+            valid, jnp.take_along_axis(
+                wtables, jnp.clip(row // page_size, 0,
+                                  wtables.shape[1] - 1), axis=1), 0))
     moe_stats = []
     for layer in range(spec.n_layers):
         x, kv_pool, stats = _layer_block(
@@ -1713,7 +1789,8 @@ def paged_mixed_step(params, kv_pool, packed, carry, lanes: int,
     import jax.numpy as jnp
     from tpulab.models.transformer import _lm_head, _rmsnorm
 
-    f = unpack_words(dispatch_fields("round", lanes, max_pages), packed)
+    f = unpack_words(dispatch_fields("round", lanes, max_pages,
+                                     _windowed(spec)), packed)
     tables, q_lens, kv_lens = f["tables"], f["q_lens"], f["kv_lens"]
     toks, row_lane, row_off = f["rows"]
     b, t = lanes, toks.shape[0]
@@ -1759,6 +1836,9 @@ def paged_mixed_step(params, kv_pool, packed, carry, lanes: int,
                mesh=mesh, row_seg=(row_lane, row_off),
                rows=(spread, back, qpos, jnp.where(decodes, 0, q_lens),
                      decodes.astype(jnp.int32)))
+    if spec.window:
+        seg.update(wtables=f["wtables"], wpage_idx=jnp.where(
+            valid, f["wtables"][lane, row // page_size], 0)[None])
     moe_stats = []
     for layer in range(spec.n_layers):
         x, kv_pool, stats = _layer_block(
@@ -2067,7 +2147,8 @@ class StepPrograms:
                  draft_psh=None):
         self.mesh, self.hbm = plan.mesh, hbm
         #: what each program takes from the host, as fields of one buffer
-        self.fields = {kind: dispatch_fields(kind, plan.lanes, plan.max_pages)
+        self.fields = {kind: dispatch_fields(kind, plan.lanes, plan.max_pages,
+                                             _windowed(plan.spec))
                        for kind in ("tick", "block", "spec", "round")}
         attn_fn = None
         if plan.prefill_flash:

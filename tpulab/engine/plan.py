@@ -67,8 +67,17 @@ class EnginePlan:
     #: the spec's: its KV heads size the pages)
     n_kv: int
     head_dim: int
-    #: layers of the page store: only attention layers own one
+    #: layers of the page store: only attention layers own one (with
+    #: window layers: of its FIRST group, the full layers')
     pool_layers: int
+    #: window layers beside full ones: the keys a window layer's row
+    #: attends, and the layers of the page store's second group, theirs (0
+    #: and 0 without: one group).  A lane's pages in that group are not its
+    #: positions' (the pages behind the window go back while the request
+    #: lives), so nothing that takes a request's pages for its positions is
+    #: carried, as with EVA windows
+    window: int
+    window_layers: int
     #: an index key's width (0 without an indexer)
     index_dim: int
     #: a page table's width: it covers the most ROWS a lane of ``max_len``
@@ -129,6 +138,16 @@ class EnginePlan:
                else self.n_kv * self.head_dim // max(1, self.n_shards))
         return walk_block_pages(self.page_size, self.max_pages, row,
                                 self.kv_dtype)
+
+    def window_lane_pages(self, ahead: int) -> int:
+        """The most pages of the window group ONE lane holds, in whole key
+        blocks of the kernels' walk (the unit the scheduler takes and
+        returns them in, so that a block it walks is one run of ids): the
+        blocks that overlap ``(length - window, length + ahead]`` at their
+        widest, ``ahead`` the rows a dispatch may write past the lane's
+        committed length (a round's budget; two decode blocks in flight)."""
+        g = self.walk_block_pages * self.page_size
+        return (-(-(self.window + ahead) // g) + 1) * self.walk_block_pages
 
     @property
     def step_kw(self) -> Dict[str, Any]:
@@ -217,8 +236,10 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
     hybrid = spec is not None and bool(spec.state_layers)
     sparse = spec is not None and bool(spec.index_topk)
     eva = spec is not None and bool(spec.eva_window)
+    windowed = spec is not None and bool(spec.window)
     special = spec is not None and (spec.cache_entry != "kv"
-                                    or spec.moe_layers or hybrid or eva)
+                                    or spec.moe_layers or hybrid or eva
+                                    or windowed)
     if special:
         # such a model is served on the ragged plan alone: the options that
         # plan, that cache-entry kind or a per-lane state (nothing
@@ -245,6 +266,9 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                               if k != "dense"}
                            | ({"EVA windows (compacted pages)"} if eva
                               else set())
+                           | ({"window layers (a page table a layer kind: "
+                               "the pages behind the window go back while "
+                               "the request lives)"} if windowed else set())
                            | ({"hyper-connected residual streams"}
                               if spec.hc_mult else set()))
             raise NotImplementedError(
@@ -262,7 +286,7 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                 "page and written as one row")
         ragged = True
     kv_dtype = kv_dtype or compute_dtype
-    n_kv = (spec.n_kv_heads if hybrid or sparse or eva
+    n_kv = (spec.n_kv_heads if hybrid or sparse or eva or windowed
             else n_kv_heads or n_heads)
     eva_window = spec.eva_window if eva else 0
     max_pages = ((spec.cache_rows_peak(max_len) if eva else max_len)
@@ -273,7 +297,13 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
         prefill_chunk -= prefill_chunk % page_size
     latent = (spec.latent_width
               if spec is not None and spec.cache_entry == "latent" else 0)
-    pool_layers = len(spec.attention_layers) if hybrid else n_layers
+    pool_layers = (len(spec.attention_layers) if hybrid
+                   else dict(spec.page_groups)["full"] if windowed
+                   else n_layers)
+    if windowed and pool is not None:
+        raise NotImplementedError(
+            "a provided pool with window layers is not supported: the "
+            "engine builds the page store's two groups itself")
     head_dim = (spec.head_dim if spec is not None else 0) or d_model // n_heads
     if pool is not None:
         if kv_dtype != compute_dtype and pool.dtype != kv_dtype:
@@ -363,6 +393,8 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
         eva_window=eva_window,
         eva_saved=spec.eva_window - spec.eva_summaries if eva else 0,
         latent=latent, n_kv=n_kv, head_dim=head_dim, pool_layers=pool_layers,
+        window=spec.window if windowed else 0,
+        window_layers=dict(spec.page_groups)["window"] if windowed else 0,
         index_dim=spec.index_dim if sparse else 0, max_pages=max_pages,
         prefill_chunk=prefill_chunk, prefill_flash=bool(prefill_flash),
         use_kernel=use_kernel, ragged=ragged, round_cap=cap,

@@ -276,6 +276,26 @@ A layer has ``wo (n_heads * head_dim, d_model)``, ``res_attn`` / ``res_ffn``,
 =============  ==========================================================
 
 The head is tied to the embedding (no ``lm_head``).
+
+``mellum`` (Mellum2-12B-A2.5B): a Qwen3-MoE-shaped GQA decoder (RMSNorm over
+each head of q and k before RoPE, softmax-routed experts renormalised over
+the chosen k, no shared expert, an untied head) whose layers are of two
+attention KINDS (``attn_kinds``, from the published ``layer_types``): a
+``"full"`` layer sees every key at or before the row, a ``"window"`` layer
+the ``window`` keys that end at it (key ``j`` from position ``i`` iff ``i -
+window < j <= i``).  RoPE is a layer kind's too: the window layers turn by
+``theta^(-2j / d)``, the full layers by YaRN's table
+(:meth:`ModelSpec.rope_inv_freq`) with YaRN's factor on cos and sin
+(``rope_factor``; ``q . k`` carries its square).  The page store is then two
+LAYER GROUPS (:attr:`ModelSpec.page_groups`: ``"full"`` and ``"window"``),
+each with its own array, free extents, reference counts and table a lane
+(:mod:`tpulab.engine.kv_pool`), ``store_layer`` a layer's index in its
+group: a window layer's pages behind its window go back to its group while
+the request lives, and the full layers keep theirs.  :func:`mellum_spec`
+reads the published keys.  A layer has ``wqkv`` = ``[q | k | v]``, ``q_norm``
+/ ``k_norm`` ``{"scale": (head_dim,)}``, ``wo`` and the ``moe`` leaves
+without ``bias`` and no ``shared``, whatever its kind.  The prediction head
+the model's description names has no key in its config and is not built.
 """
 
 from __future__ import annotations
@@ -351,6 +371,12 @@ class ModelSpec:
                                             # over [q ; k]; () = none
     router_width: int = 0                   # router "mlp": its hidden width
     res_scale: bool = False                 # s_r (x + b_r) + s_o (f + b_o)
+    window: int = 0                         # gqa: keys a "window" layer's row
+                                            # attends (itself among them)
+    attn_kinds: Tuple[str, ...] = ()        # "full" | "window" a layer; () =
+                                            # every layer full attention
+    rope_factor: float = 1.0                # YaRN's factor on cos and sin of
+                                            # the FULL layers (window: none)
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -479,10 +505,51 @@ class ModelSpec:
                     "hc_sinkhorn_iters (>= 1) around attention and a dense "
                     "or expert FFN (no shortcut layer, no lane-state mixer)")
         if self.rope_scaling and (len(self.rope_scaling) != 4
-                                  or self.attention != "mla"):
+                                  or (self.attention != "mla"
+                                      and not self.window)):
             raise ValueError("rope_scaling is YaRN's (factor, original max "
                              "positions, beta_fast, beta_slow) on the rope "
-                             "columns of latent attention")
+                             "columns of latent attention, or on the full "
+                             "layers of a model with window layers")
+        if self.window or self.attn_kinds:
+            # window layers beside full ones: two groups of the page store
+            # (``page_groups``), a table a lane each.  What else keeps rows,
+            # a state or another mask a layer is refused by name
+            beside = {
+                "latent attention": self.attention != "gqa",
+                "an indexer": bool(self.index_topk),
+                "EVA windows": bool(self.eva_window),
+                "an output gate": self.attn_gate,
+                "partial RoPE": bool(self.rotary_dim),
+                "hyper-connections": bool(self.hc_mult),
+                "a shortcut layer": "shortcut" in self.layer_kinds,
+                "a lane state (Mamba, Gated DeltaNet, CCA layers)":
+                    bool(set(mixers) - {"attention"})}
+            for other, there in beside.items():
+                if there:
+                    raise ValueError(
+                        f"window layers beside {other} are not implemented: "
+                        "a sliding window is a lower bound on plain GQA "
+                        "attention over K/V pages")
+            kinds_a = tuple(self.attn_kinds)
+            if (len(kinds_a) != self.n_layers
+                    or set(kinds_a) - {"full", "window"}):
+                raise ValueError(f"attn_kinds {kinds_a} does not name full "
+                                 f"or window for each of {self.n_layers} "
+                                 "layers")
+            if ("window" in kinds_a) != (self.window > 0):
+                raise ValueError("window (the keys a row attends) comes with "
+                                 "window layers in attn_kinds, and they "
+                                 "with it")
+            if "full" not in kinds_a:
+                raise ValueError(
+                    "window layers alone are not implemented: the page "
+                    "store's first group is the full layers', which "
+                    "admission and the gauges read")
+            object.__setattr__(self, "attn_kinds", kinds_a)
+        if self.rope_factor != 1.0 and not self.rope_scaling:
+            raise ValueError("rope_factor is YaRN's factor on cos and sin: "
+                             "it comes with rope_scaling")
         if "mamba" in mixers:
             if self.attention != "gqa" or "attention" not in mixers:
                 raise ValueError("Mamba layers are served beside GQA "
@@ -540,7 +607,9 @@ class ModelSpec:
 
     def rope_inv_freq(self) -> Optional[np.ndarray]:
         """The inverse frequencies RoPE turns the ``qk_rope_head_dim``
-        columns by where ``rope_scaling`` changes them (float32, ``(rope /
+        columns by (latent attention; the ``head_dim`` columns of a GQA
+        model's FULL layers, whose window layers keep ``theta^(-2j / d)``)
+        where ``rope_scaling`` changes them (float32, ``(rope /
         2,)``), else None: ``theta^(-2j / d)`` it is.  YaRN: pair ``j``
         keeps its frequency where it turns more than ``beta_fast`` times
         within the original context, has it divided by ``factor`` where it
@@ -548,7 +617,9 @@ class ModelSpec:
         if not self.rope_scaling:
             return None
         factor, original, fast, slow = self.rope_scaling
-        d, theta = self.qk_rope_head_dim, self.rope_theta
+        d = (self.qk_rope_head_dim if self.attention == "mla"
+             else self.head_dim)
+        theta = self.rope_theta
 
         def corr(turns):
             return d * np.log(original / (2 * np.pi * turns)) / (
@@ -601,8 +672,32 @@ class ModelSpec:
         """Layer ``layer``'s index in the store of its mixer's kind: the
         page store's layer axis for an attention layer, the lane-state
         store's for a Mamba or Gated DeltaNet layer (``layer`` itself where
-        every mixer is attention, or CCA, which owns that layer of both)."""
+        every mixer is attention, or CCA, which owns that layer of both).
+        With window layers (``attn_kinds``) an attention layer's index in
+        the page store's GROUP of its kind (:meth:`page_groups`)."""
+        if self.attn_kinds:
+            return self.attn_kinds[:layer].count(self.attn_kinds[layer])
         return self.mixers[:layer].count(self.mixers[layer])
+
+    def layer_window(self, layer: int) -> int:
+        """The keys a row of ``layer`` attends at most, itself among them
+        (key ``j`` is seen from position ``i`` iff ``i - window < j <=
+        i``); 0 on a full layer: every key at or before the row."""
+        return (self.window if self.attn_kinds
+                and self.attn_kinds[layer] == "window" else 0)
+
+    @property
+    def page_groups(self) -> Tuple[Tuple[str, int], ...]:
+        """The page store's layer groups, ``(name, layers)`` each: one,
+        ``"full"``, of every layer that owns pages, or with window layers
+        two, ``"full"`` and ``"window"``, each with its own array, free
+        extents, reference counts and table a lane: a window layer's pages
+        behind its window go back to its group while the full layers keep
+        theirs."""
+        if not self.attn_kinds:
+            return (("full", len(self.attention_layers)),)
+        return tuple((kind, self.attn_kinds.count(kind))
+                     for kind in ("full", "window"))
 
 
 def dense_spec(d_model: int, n_heads: int, n_layers: int,
@@ -944,6 +1039,94 @@ def zaya_spec(config: Dict[str, Any]) -> ModelSpec:
         norm_topk=False)
 
 
+def mellum_spec(config: Dict[str, Any]) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type`` ``mellum``):
+    ``layer_types`` names each layer ``sliding_attention`` (``sliding_window``
+    keys, plain RoPE: ``rope_parameters.sliding_attention``) or
+    ``full_attention`` (YaRN: ``rope_parameters.full_attention``, its
+    ``attention_factor`` on cos and sin), GQA with an RMSNorm over each head
+    of q and k (the family's, ``assumed`` in the configuration file), every
+    layer followed by ``num_experts`` softmax-routed experts renormalised
+    over the chosen ``num_experts_per_tok``, no shared expert, an untied
+    head.  Refuses what the layer block does not compute."""
+    n_layers = int(config["num_hidden_layers"])
+    # (a configuration cut in depth keeps the published lists whole: the
+    # layers served are their first ``num_hidden_layers`` entries)
+    types = list(config.get("layer_types")
+                 or ["full_attention"] * n_layers)[:n_layers]
+    known = {"sliding_attention": "window", "full_attention": "full"}
+    if len(types) != n_layers or set(types) - set(known):
+        raise ValueError(f"layer_types {sorted(set(types) - set(known))} is "
+                         "not implemented (sliding_attention and "
+                         f"full_attention, one for each of {n_layers} "
+                         "layers)")
+    ffn = set((config.get("mlp_layer_types") or ["sparse"])[:n_layers])
+    if ffn != {"sparse"}:
+        raise ValueError(f"mlp_layer_types {sorted(ffn - {'sparse'})} is not "
+                         "implemented (every layer is an expert layer: "
+                         "sparse alone)")
+    if config.get("tie_word_embeddings"):
+        raise ValueError("tie_word_embeddings true is not implemented (the "
+                         "head is a matrix of its own)")
+    if config.get("attention_bias"):
+        raise ValueError("attention_bias is not implemented")
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {config['hidden_act']!r} is not "
+                         "implemented (SwiGLU experts)")
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("norm_topk_prob false is not implemented (the "
+                         "softmax router renormalises over the chosen k)")
+    kinds = tuple(known[t] for t in types)
+    windowed = "window" in kinds
+    if windowed and not config.get("use_sliding_window", True):
+        raise ValueError("use_sliding_window false beside sliding_attention "
+                         "layers is not implemented")
+    if windowed and int(config.get("max_window_layers") or 0):
+        raise ValueError("max_window_layers != 0 is not implemented "
+                         "(layer_types says which layers slide)")
+    rope = config.get("rope_parameters") or {}
+    slide = rope.get("sliding_attention") or {}
+    full = rope.get("full_attention") or {}
+    if windowed and slide.get("rope_type", "default") != "default":
+        raise ValueError(f"rope_parameters.sliding_attention rope_type "
+                         f"{slide['rope_type']!r} is not implemented "
+                         "(default alone)")
+    kind = full.get("rope_type", "default")
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"rope_parameters.full_attention rope_type {kind!r} "
+                         "is not implemented (default and yarn)")
+    theta = float(full.get("rope_theta", config.get("rope_theta", 0)) or 0)
+    if windowed and float(slide.get("rope_theta", theta)) != theta:
+        raise ValueError("rope_theta that differs between the layer kinds "
+                         "is not implemented")
+    scaling, factor = (), 1.0
+    if kind == "yarn":
+        if full.get("truncate") is False:
+            raise ValueError("rope_parameters.full_attention truncate false "
+                             "is not implemented")
+        s = float(full["factor"])
+        scaling = (s, float(full["original_max_position_embeddings"]),
+                   float(full.get("beta_fast", 32)),
+                   float(full.get("beta_slow", 1)))
+        # Hugging Face's default where the config gives none: 0.1 ln s + 1
+        factor = float(full.get("attention_factor")
+                       or 0.1 * np.log(s) + 1.0)
+    return ModelSpec(
+        n_layers=n_layers, d_model=int(config["hidden_size"]),
+        n_heads=int(config["num_attention_heads"]),
+        n_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=int(config["head_dim"]),
+        layer_kinds=("moe",) * n_layers,
+        n_experts=int(config["num_experts"]),
+        top_k=int(config["num_experts_per_tok"]),
+        moe_ff=int(config["moe_intermediate_size"]), n_shared=0,
+        router="softmax", qk_norm=True,
+        rms_eps=float(config["rms_norm_eps"]), rope_theta=theta,
+        window=int(config["sliding_window"]) if windowed else 0,
+        attn_kinds=kinds if windowed else (),
+        rope_scaling=scaling, rope_factor=factor)
+
+
 def mla_scales(config: Dict[str, Any]) -> Tuple[float, float]:
     """``(query factor, latent factor)`` that a config puts on latent
     attention and the program folds into its matrices at load time:
@@ -1073,7 +1256,7 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
 
     if (spec.attention != "mla" and not spec.state_layers
             and not spec.index_topk and not spec.attn_gate
-            and not spec.eva_window):
+            and not spec.eva_window and not spec.qk_norm):
         raise ValueError("init_params draws MLA decoders, hybrids with a "
                          "lane state, decoders with an indexer, an output "
                          "gate or EVA windows; dense ones come from "

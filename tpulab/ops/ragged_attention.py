@@ -271,7 +271,7 @@ def _table_runs(tables, g_pages: int):
 
 def _page_walk(tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer,
                length, *, page_size: int, max_pages: int, g_pages: int,
-               nbuf: int, also=None):
+               nbuf: int, also=None, first_block=None):
     """The walk over one lane's block table that every kernel of the family
     shares, whatever a page holds (K and V rows, or latent rows): starts
     the pipeline's prologue and returns ``(start_block, wait_block,
@@ -295,6 +295,13 @@ def _page_walk(tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer,
 
     ``also(j, slot, go)`` rides a block that holds a live page: what else
     the caller stages a block (``go`` starts or waits a copy).
+
+    ``first_block`` (a traced int32; None: block 0) is the LOWER bound of
+    the walk, a window layer's: the blocks under it are neither fetched nor
+    waited, the prologue starts there, and so does the caller's loop (``for
+    j in [first_block, live_blocks)``, slot ``(j - first_block) % nbuf``).
+    The first live block is fetched WHOLE, by its run word like any other:
+    the keys in it that lie under the window are masked by the caller.
 
     The pages of a block and the blocks of the prologue are loops in the
     kernel, over the block's LIVE pages, not in Python: unrolled they were
@@ -337,10 +344,11 @@ def _page_walk(tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer,
 
     # same deep prefetch pipeline as the single-query kernel (N-stage
     # slot rotation)
-    start_block(0, 0)
+    start_block(0 if first_block is None else first_block, 0)
 
     def prologue(jj, _):
-        start_block(jj, jj)      # a block past the lane's pages has no trip
+        # (a block past the lane's pages has no trip)
+        start_block(jj if first_block is None else first_block + jj, jj)
     jax.lax.fori_loop(1, min(nbuf - 1, n_blocks), prologue, None)
     return start_block, wait_block, (n_pages + g_pages - 1) // g_pages
 
@@ -441,12 +449,16 @@ def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
                         page_size: int,
                         max_pages: int, n_heads: int, head_dim: int,
                         n_kv_heads: int, m_q: int, sm_scale: float,
-                        g_pages: int, nbuf: int):
+                        g_pages: int, nbuf: int, window: int = 0):
     """One lane's ``M`` query rows against the lane's K/V pages, a head at
     a time.  Both products of a key block take their operands as
     :func:`mxu_operands` reads them from the store's dtype (a bf16 store:
     bf16 in one pass; a float32 store: float32 at ``HIGHEST``); the softmax
-    statistics and the accumulator are float32 either way."""
+    statistics and the accumulator are float32 either way.
+
+    ``window`` > 0 (a window layer): the row at position ``i`` sees key
+    ``j`` iff ``i - window < j <= i``, each row its own bound; the walk
+    starts at the block that holds the FIRST row's bound."""
     lane = pl.program_id(0)
     layer = layer_ref[0]                      # which layer's pages to walk
     qn = qlens_ref[lane]                      # valid query rows this lane
@@ -480,10 +492,13 @@ def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
             jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
 
+        # a window layer's walk starts at the block of its first row's
+        # oldest visible key (no bound: block 0, the walk it always was)
+        j0 = (jnp.maximum(start - window + 1, 0) // gs if window else None)
         start_block, wait_block, live_blocks = _page_walk(
             tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-            nbuf=nbuf)
+            nbuf=nbuf, first_block=j0)
 
         # per-query-row positions/validity are loop-invariant
         qrow = jax.lax.broadcasted_iota(jnp.int32, (m_q, gs), 0)
@@ -492,10 +507,12 @@ def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
         row_valid = qrow < qn
 
         def body(j, carry):
-            slot = jax.lax.rem(j, nbuf)
+            # (a window layer's slots count from its first block)
+            at = j - j0 if window else j
+            slot = jax.lax.rem(at, nbuf)
             wait_block(j, slot)
             # (a block past the lane's pages has no trip)
-            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
+            start_block(j + nbuf - 1, jax.lax.rem(at + nbuf - 1, nbuf))
 
             _zero_rows_past(kv_buf, slot, 1, j * gs, length)
             # as stored (an fp8 block upcast): no float32 copy
@@ -503,6 +520,8 @@ def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
             vblk = _staged_rows(kv_buf, slot, 1).astype(dt)
             kpos = j * gs + col
             mask = jnp.logical_and(kpos <= qpos, row_valid)      # (M, G*S)
+            if window:
+                mask = jnp.logical_and(mask, kpos > qpos - window)
             maskf = mask.astype(jnp.float32)
             out = []
             for hh in range(h):
@@ -524,7 +543,8 @@ def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
         init = tuple((jnp.full((m_q, 1), _NEG, jnp.float32),
                       jnp.zeros((m_q, 1), jnp.float32),
                       jnp.zeros((m_q, d), jnp.float32)) for _ in range(h))
-        final = jax.lax.fori_loop(0, live_blocks, body, init)
+        final = jax.lax.fori_loop(j0 if window else 0, live_blocks, body,
+                                  init)
         for hh in range(h):
             _m, l_c, acc_c = final[hh]
             o_ref[0, :, hh * d:(hh + 1) * d] = (
@@ -534,13 +554,16 @@ def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
 def _ragged_decode_kernel(layer_ref, tables_ref, runs_ref, qlens_ref,
                           kvlens_ref, q_ref, kvpool_ref, o_ref, kv_buf, sem, *,
                           page_size: int, max_pages: int, n_kv_heads: int,
-                          sm_scale: float, g_pages: int, nbuf: int):
+                          sm_scale: float, g_pages: int, nbuf: int,
+                          window: int = 0):
     """One lane's ONE query row against the lane's K/V pages, the query
     heads of a KV head the rows of one dot (:func:`_stacked_block`): the
     rows kernel at ``M = 1`` pushed every K and V block through the MXU
     ``H / Hkv`` times, a padded tile of rows for the one that counts.
     ``q_ref``, ``o_ref (1, Hkv * g, D)``.  At one row the mask of a block
-    is positional: every key at or before the lane's last position."""
+    is positional: every key at or before the lane's last position, and
+    with ``window`` > 0 (a window layer) no key ``window`` or more behind
+    it: the walk starts at the block of the oldest visible key."""
     lane = pl.program_id(0)
     layer = layer_ref[0]
 
@@ -548,33 +571,40 @@ def _ragged_decode_kernel(layer_ref, tables_ref, runs_ref, qlens_ref,
     def _lane():
         length = jnp.maximum(kvlens_ref[lane], 1) - 1
         gs = g_pages * page_size
+        if window:
+            first = jnp.maximum(length - window + 1, 0)  # oldest visible key
+        j0 = first // gs if window else None
         start_block, wait_block, live_blocks = _page_walk(
             tables_ref, runs_ref, kvpool_ref, kv_buf, sem, lane, layer, length,
             page_size=page_size, max_pages=max_pages, g_pages=g_pages,
-            nbuf=nbuf)
+            nbuf=nbuf, first_block=j0)
 
         q, dot_qk, dot_pv = _stacked_operands(q_ref, kv_buf, sm_scale)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, gs), 1)
 
         def body(j, carry):
-            slot = jax.lax.rem(j, nbuf)
+            # (a window layer's slots count from its first block)
+            at = j - j0 if window else j
+            slot = jax.lax.rem(at, nbuf)
             wait_block(j, slot)
             # (a block past the lane's pages has no trip)
-            start_block(j + nbuf - 1, jax.lax.rem(j + nbuf - 1, nbuf))
+            start_block(j + nbuf - 1, jax.lax.rem(at + nbuf - 1, nbuf))
             _zero_rows_past(kv_buf, slot, 1, j * gs, length)
-            return _stacked_block(
-                q, _staged_rows(kv_buf, slot, 0).astype(q.dtype),
-                _staged_rows(kv_buf, slot, 1).astype(q.dtype),
-                j * gs + col <= length, carry, dot_qk, dot_pv)
+            kblk = _staged_rows(kv_buf, slot, 0).astype(q.dtype)
+            vblk = _staged_rows(kv_buf, slot, 1).astype(q.dtype)
+            mask = j * gs + col <= length
+            if window:
+                mask = jnp.logical_and(mask, j * gs + col >= first)
+            return _stacked_block(q, kblk, vblk, mask, carry, dot_qk, dot_pv)
 
         _stacked_store(o_ref, jax.lax.fori_loop(
-            0, live_blocks, body,
+            j0 if window else 0, live_blocks, body,
             _stacked_carry(n_kv_heads, q.shape[0] // n_kv_heads,
                            q.shape[1])))
 
 
 def _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
-                   interpret: bool, g_pages, nbuf):
+                   interpret: bool, g_pages, nbuf, window: int = 0):
     """:func:`_ragged_attn` at one query row a lane: ``q (B, 1, H, D)``
     through the kernel ``ragged_paged_decode``."""
     b, _one, h, d = q.shape
@@ -596,7 +626,7 @@ def _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
     kernel = functools.partial(
         _ragged_decode_kernel, page_size=page_size, max_pages=max_pages,
         n_kv_heads=hkv, sm_scale=1.0 / np.sqrt(d), g_pages=g_pages,
-        nbuf=nbuf)
+        nbuf=nbuf, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -611,9 +641,10 @@ def _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("interpret", "g_pages", "nbuf"))
+                   static_argnames=("interpret", "g_pages", "nbuf", "window"))
 def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
-                 g_pages: int | None = None, nbuf: int | None = None):
+                 g_pages: int | None = None, nbuf: int | None = None,
+                 window: int = 0):
     b, m, h, d = q.shape
     page_size, row = kv_pool.shape[3], kv_pool.shape[4]
     hkv = row // d
@@ -633,7 +664,7 @@ def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
         # of a KV head stack into one dot's rows.  The shape decides,
         # nothing else
         return _ragged_decode(q, kv_pool, layer, tables, q_lens, kv_lens,
-                              interpret, g_pages, nbuf)
+                              interpret, g_pages, nbuf, window)
     # the pool goes in as it is stored — a reshape or a slice of it ahead
     # of the call would be a copy of a layer of the pool on every call (a
     # pallas_call operand is not fused into); queries as (B, M, H*D)
@@ -654,7 +685,8 @@ def _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens, interpret: bool,
     kernel = functools.partial(
         _ragged_attn_kernel, page_size=page_size, max_pages=max_pages,
         n_heads=h, head_dim=d, n_kv_heads=hkv, m_q=m,
-        sm_scale=1.0 / np.sqrt(d), g_pages=g_pages, nbuf=nbuf)
+        sm_scale=1.0 / np.sqrt(d), g_pages=g_pages, nbuf=nbuf,
+        window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -675,7 +707,7 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
                            mesh=None, model_axis: str = "model",
                            interpret: bool | None = None,
                            g_pages: int | None = None,
-                           nbuf: int | None = None):
+                           nbuf: int | None = None, window: int = 0):
     """Ragged paged attention over per-lane ``(query_len, kv_len)``
     segments (MHA or grouped-query), on one layer of the page store.
 
@@ -709,6 +741,10 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
     tables/lengths/layer replicated) so the kernel compiles inside the
     engine's tensor-parallel jits.
     ``g_pages``/``nbuf`` override the auto block geometry.
+    ``window`` > 0 makes the layer a WINDOW layer: the query at position
+    ``i`` sees key ``j`` iff ``i - window < j <= i``; the walk starts at the
+    block that holds the first row's oldest visible key, so ``tables`` need
+    hold live ids from that block on only (one device, no ``mesh``).
     ``M == 1`` runs the kernel ``ragged_paged_decode`` (a KV head's query
     heads the rows of one dot), ``M > 1`` ``ragged_paged_attention`` (a
     query head at a time): the module docstring says why.
@@ -726,7 +762,11 @@ def ragged_paged_attention(q, kv_pool, layer, tables, q_lens, kv_lens,
     kv_lens = kv_lens.astype(jnp.int32)
     if mesh is None:
         return _ragged_attn(q, kv_pool, layer, tables, q_lens, kv_lens,
-                            interpret, g_pages=g_pages, nbuf=nbuf)
+                            interpret, g_pages=g_pages, nbuf=nbuf,
+                            window=window)
+    if window:
+        raise NotImplementedError("a window layer's walk is not sharded "
+                                  "(mesh= with window=)")
     from jax.sharding import PartitionSpec as P
 
     n_model = dict(mesh.shape)[model_axis]
