@@ -9,6 +9,10 @@ chip can show is in ``chip_smoke.py`` (run through the chip tool).
 
 import gc
 import os
+import signal
+import sys
+import threading
+import traceback
 
 import pytest
 
@@ -69,3 +73,43 @@ def _bounded_mappings():
         import jax
         jax.clear_caches()
         gc.collect()
+
+
+#: Seconds a test may run (its call, not its fixtures): three times the
+#: slowest honest test of a whole run under the driver's six workers (105 s at
+#: PR 57), so that it never fails a test that is only slow on a loaded box.  No
+#: marker, option or environment variable changes it: a test that needs more
+#: is ``slow``.  It stays well under ``pytest.ini``'s ``faulthandler_timeout``,
+#: the net under it.
+TEST_LIMIT_S = 315
+
+
+def _every_threads_stack() -> str:
+    names = {t.ident: t.name for t in threading.enumerate()}
+    return "\n".join(
+        f"--- thread {names.get(ident, '?')} ({ident}) ---\n"
+        + "".join(traceback.format_stack(frame))
+        for ident, frame in sys._current_frames().items())
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    """A test that stands still fails alone: at ``TEST_LIMIT_S`` a
+    ``SIGALRM`` raises in the main thread, where pytest (and an xdist worker)
+    runs the test, out of ``time.sleep``, ``Event.wait``, ``Thread.join`` or
+    ``Future.result``; a call into a compiler is left when it returns.  The
+    test FAILS with every thread's stack, its ``finally`` blocks run, and the
+    run goes on with the next test (PR 56's whole run was cut at its limit by
+    one poll with no end, and counted nine tests short)."""
+    def stood_still(signum, frame):
+        pytest.fail(f"{item.nodeid} ran past its {TEST_LIMIT_S} s limit "
+                    f"(tests/conftest.py TEST_LIMIT_S)\n"
+                    + _every_threads_stack(), pytrace=False)
+
+    before = signal.signal(signal.SIGALRM, stood_still)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)

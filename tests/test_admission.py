@@ -12,6 +12,7 @@ import time
 import numpy as np
 import pytest
 
+from helpers_engine import join_all, wait_until
 from tpulab.core.deadline import Deadline
 from tpulab.serving import (AdmissionConfig, AdmissionController,
                             AdmissionRejected, DeficitRoundRobinQueue,
@@ -201,15 +202,13 @@ def test_admission_fair_queue_non_starvation():
     for _ in range(5):  # greedy enqueues its backlog first
         ths.append(threading.Thread(target=worker, args=("greedy",)))
         ths[-1].start()
-        while ctrl.queue_depth < len(ths):
-            time.sleep(0.005)
+        wait_until(lambda: ctrl.queue_depth >= len(ths),
+                   "a greedy waiter queued")
     ths.append(threading.Thread(target=worker, args=("slow",)))
     ths[-1].start()
-    while ctrl.queue_depth < len(ths):
-        time.sleep(0.005)
+    wait_until(lambda: ctrl.queue_depth >= len(ths), "the slow waiter queued")
     blocker.release()
-    for t in ths:
-        t.join(timeout=10)
+    join_all(ths, timeout_s=10)
     assert order.count("slow") == 1
     assert "slow" in order[:2], order  # served in round 1, not position 6
 

@@ -11,6 +11,7 @@ import time
 import numpy as np
 import pytest
 
+from helpers_engine import GatedSink, TokenGate, join_all, wait_until
 from tpulab import chaos
 from tpulab.batch import BatchJob, BatchScheduler, JSONLResultSink
 
@@ -35,6 +36,37 @@ def _batcher(lm, lanes=2, **kw):
 def _prompts(n, rng_seed=0, length=6):
     rng = np.random.default_rng(rng_seed)
     return [rng.integers(0, 64, (length,), np.int32) for _ in range(n)]
+
+
+def _run_in_thread(sched, job):
+    """``sched.run(job)`` on a feeder thread; the report lands in ``res``."""
+    res = {}
+    th = threading.Thread(
+        target=lambda: res.update(sched.run(job, timeout_s=120)),
+        daemon=True)
+    th.start()
+    return th, res
+
+
+def _gated_sink(gate, tmp_path):
+    """Every delivered token durable at once (``flush_every=1``), the item
+    held at ``gate``'s token."""
+    return GatedSink(gate, JSONLResultSink(str(tmp_path / "held.jsonl"),
+                                           flush_every=1))
+
+
+def _release_on_cancel(cb, gate):
+    """A feeder that dies (chaos, drain) cancels its in-flight item, and the
+    engine sweeps a cancel at its next tick boundary.  The held item is let
+    go the moment its cancel is registered: it can neither run on to its end
+    first nor keep the feeder waiting for a sweep the hold keeps away."""
+    cancel = cb.cancel
+
+    def cancel_then_release(fut):
+        cancel(fut)
+        gate.release()
+
+    cb.cancel = cancel_then_release
 
 
 # -- manifest + sink ----------------------------------------------------------
@@ -118,28 +150,24 @@ def test_spare_capacity_gate_defers_to_online(lm, tmp_path):
     try:
         prompts = _prompts(2, rng_seed=2)
         ref = [cb.submit(p, 4).result(timeout=120) for p in prompts]
-        sched = BatchScheduler(cb, poll_s=0.001)
-        online = cb.submit(prompts[0], 48, on_token=lambda *a: None)
-        while cb.active_lanes == 0:
-            time.sleep(0.001)
-        res = {}
-        th = threading.Thread(
-            target=lambda: res.update(sched.run(
-                BatchJob("g", prompts, steps=4), timeout_s=120)),
-            daemon=True)
-        th.start()
-        time.sleep(0.08)  # online still decoding: nothing may be fed
-        assert sched.tokens_delivered == 0
-        assert sched.spare_denials > 0
+        sched = BatchScheduler(cb)
+        held = TokenGate()
+        online = cb.submit(prompts[0], 48, on_token=held)
+        assert held.wait(timeout=60)  # the only lane is online's, and stays
+        th, res = _run_in_thread(sched, BatchJob("g", prompts, steps=4))
+        wait_until(lambda: sched.spare_denials > 0,
+                   "the feeder's first deferral")
+        assert sched.tokens_delivered == 0  # online held: nothing was fed
+        held.release()
         online.result(timeout=120)
-        th.join(timeout=120)
+        join_all([th], timeout_s=120)
         assert res["items_done"] == 2
         assert [res["results"][i] for i in range(2)] == ref
     finally:
         cb.shutdown()
 
 
-def test_online_arrival_preempts_batch_lane_first(lm):
+def test_online_arrival_preempts_batch_lane_first(lm, tmp_path):
     """Acceptance: an online burst preempts the mid-decode BATCH lane —
     not the other online lane — and the batch job still completes with
     bit-exact token parity vs an uncontended run (satellite 3)."""
@@ -148,27 +176,26 @@ def test_online_arrival_preempts_batch_lane_first(lm):
         prompts = _prompts(3, rng_seed=3)
         ref_batch = cb.submit(prompts[0], 40).result(timeout=120)
         ref_o2 = cb.submit(prompts[2], 4).result(timeout=120)
-        sched = BatchScheduler(cb, poll_s=0.001)
-        res = {}
-        th = threading.Thread(
-            target=lambda: res.update(sched.run(
-                BatchJob("p", [prompts[0]], steps=40), timeout_s=120)),
-            daemon=True)
-        th.start()
-        while sched.tokens_delivered < 3:  # batch mid-decode
-            time.sleep(0.001)
-        o1 = cb.submit(prompts[1], 40, on_token=lambda *a: None)
-        while cb.active_lanes < 2:
-            time.sleep(0.001)
+        batch_held, o1_held = TokenGate(3), TokenGate()
+        sched = BatchScheduler(cb, sink=_gated_sink(batch_held, tmp_path))
+        th, res = _run_in_thread(sched,
+                                 BatchJob("p", [prompts[0]], steps=40))
+        assert batch_held.wait(timeout=60)  # batch mid-decode, and held
+        o1 = cb.submit(prompts[1], 40, on_token=o1_held)  # queued
+        batch_held.release()
+        # held at o1's first token, the batch lane 36 tokens from its end
+        assert o1_held.wait(timeout=60)
+        assert cb.active_lanes == 2
         p0, bp0 = cb.preemptions, cb.batch_preemptions
         # default-priority online arrival with both lanes busy: the
         # BATCH lane falls, the online lane is untouched
-        got_o2 = cb.submit(prompts[2], 4).result(timeout=120)
-        assert got_o2 == ref_o2
+        o2 = cb.submit(prompts[2], 4)  # queued behind two held lanes
+        o1_held.release()
+        assert o2.result(timeout=120) == ref_o2
         assert cb.batch_preemptions - bp0 >= 1
         assert (cb.preemptions - p0) == (cb.batch_preemptions - bp0)
         o1.result(timeout=120)
-        th.join(timeout=120)
+        join_all([th], timeout_s=120)
         assert res["interrupted"] is None
         assert res["batch_preemptions"] >= 1
         assert res["results"][0] == ref_batch  # exact in-engine resume
@@ -192,18 +219,14 @@ def test_chaos_batch_run_kill_resumes_from_checkpoint(lm, tmp_path,
         ref = cb.submit(prompt, steps,
                         sampling=BatchJob("r", [prompt], **job_kw)
                         .sampling()).result(timeout=120)
-        sink = JSONLResultSink(str(tmp_path / "k.jsonl"), flush_every=1)
-        sched = BatchScheduler(cb, sink=sink, poll_s=0.001)
-        res = {}
-        th = threading.Thread(
-            target=lambda: res.update(sched.run(
-                BatchJob("k", [prompt], **job_kw), timeout_s=120)),
-            daemon=True)
-        th.start()
-        while sched.tokens_delivered < 5:
-            time.sleep(0.001)
+        held = TokenGate(5)
+        sink = _gated_sink(held, tmp_path)
+        sched = BatchScheduler(cb, sink=sink)
+        _release_on_cancel(cb, held)
+        th, res = _run_in_thread(sched, BatchJob("k", [prompt], **job_kw))
+        assert held.wait(timeout=60)  # mid-decode, and held
         with chaos.inject(f"batch.run={action}") as sched_chaos:
-            th.join(timeout=120)
+            join_all([th], timeout_s=120)
             assert sched_chaos.fired("batch.run") >= 1
         assert res["interrupted"] == action
         assert sched.interrupted_runs == 1
@@ -231,19 +254,15 @@ def test_host_sampled_interrupt_restarts_behind_reset(lm, tmp_path):
     try:
         prompt = _prompts(1, rng_seed=5)[0]
         steps = 32
-        sink = JSONLResultSink(str(tmp_path / "h.jsonl"), flush_every=1)
-        sched = BatchScheduler(cb, sink=sink, poll_s=0.001)
+        held = TokenGate(4)
+        sink = _gated_sink(held, tmp_path)
+        sched = BatchScheduler(cb, sink=sink)
+        _release_on_cancel(cb, held)
         job_kw = dict(steps=steps, temperature=0.9, top_k=4, seed=7)
-        res = {}
-        th = threading.Thread(
-            target=lambda: res.update(sched.run(
-                BatchJob("h", [prompt], **job_kw), timeout_s=120)),
-            daemon=True)
-        th.start()
-        while sched.tokens_delivered < 4:
-            time.sleep(0.001)
+        th, res = _run_in_thread(sched, BatchJob("h", [prompt], **job_kw))
+        assert held.wait(timeout=60)  # mid-decode, and held
         with chaos.inject("batch.run=drop"):
-            th.join(timeout=120)
+            join_all([th], timeout_s=120)
         assert res["interrupted"] == "drop"
         lost = len(sink.load_progress("h")[0].tokens)
         assert lost > 0
@@ -299,21 +318,18 @@ def test_admission_batch_strictly_below_online_and_drr_exempt():
                                       request_class="batch"),
                           daemon=True)
     tb.start()                          # batch queues FIRST
-    while ctrl.batch_queue_depth != 1:
-        time.sleep(0.005)
+    wait_until(lambda: ctrl.batch_queue_depth == 1, "the batch waiter queued")
     to = threading.Thread(target=take, args=("online",),
                           kwargs=dict(tenant="a"), daemon=True)
     to.start()
-    while ctrl.queue_depth != 1:
-        time.sleep(0.005)
+    wait_until(lambda: ctrl.queue_depth == 1, "the online waiter queued")
     # structural exemption: the online DRR queue never saw the batch
     # tenant; the debugz view namespaces it
     depths = ctrl.queue_depths()
     assert depths.get("batch:bulk") == 1 and depths.get("a") == 1
     assert ctrl._queue.deficit_of("bulk") == 0.0
     first.release()
-    to.join(timeout=10)
-    tb.join(timeout=10)
+    join_all([to, tb], timeout_s=10)
     assert order == ["online", "batch"]  # arrival order reversed
     assert ctrl.batch_admitted_total == 1
 
@@ -339,11 +355,11 @@ def test_admission_queue_wait_ewma_excludes_batch_and_autoscaler_holds():
             done.set()
 
         threading.Thread(target=second, daemon=True).start()
-        while (ctrl.batch_queue_depth + ctrl.queue_depth) != 1:
-            time.sleep(0.002)
+        wait_until(lambda: ctrl.batch_queue_depth + ctrl.queue_depth == 1,
+                   f"the {request_class} waiter queued")
         time.sleep(0.03)                # accrue a real queue wait
         first.release()
-        done.wait(timeout=10)
+        assert done.wait(timeout=10)
 
     queued_admit("batch")
     assert waited["batch"] > 0.0        # it DID wait...
@@ -406,7 +422,8 @@ def test_admission_batch_spare_gate_consults_engine_idle():
 
 # -- fleet: batch drains first ------------------------------------------------
 
-def test_autoscaler_batch_drain_hook_fires_before_provider_drain(lm):
+def test_autoscaler_batch_drain_hook_fires_before_provider_drain(lm,
+                                                                 tmp_path):
     from tpulab.fleet import FleetAutoscaler, ReplicaProvider
 
     events = []
@@ -447,16 +464,12 @@ def test_autoscaler_batch_drain_hook_fires_before_provider_drain(lm):
 
     cb = _batcher(lm, lanes=1)
     try:
-        sched = BatchScheduler(cb, poll_s=0.001)
-        res = {}
-        th = threading.Thread(
-            target=lambda: res.update(sched.run(
-                BatchJob("d", _prompts(1, rng_seed=6), steps=64),
-                timeout_s=120)),
-            daemon=True)
-        th.start()
-        while sched.tokens_delivered < 2:
-            time.sleep(0.001)
+        held = TokenGate(2)
+        sched = BatchScheduler(cb, sink=_gated_sink(held, tmp_path))
+        _release_on_cancel(cb, held)
+        th, res = _run_in_thread(
+            sched, BatchJob("d", _prompts(1, rng_seed=6), steps=64))
+        assert held.wait(timeout=60)  # mid-decode, and held
 
         def batch_drain(addr):
             events.append(("batch_drain", addr))
@@ -472,7 +485,7 @@ def test_autoscaler_batch_drain_hook_fires_before_provider_drain(lm):
         # provider drain (which only waits on online streams)
         kinds = [k for k, _ in events]
         assert kinds.index("batch_drain") < kinds.index("provider_drain")
-        th.join(timeout=30)
+        join_all([th], timeout_s=120)
         # the run ended without finishing (its in-flight was cancelled,
         # feeding paused) — delivered tokens stay durable for a resume
         assert res["items_done"] == 0 and sched.paused

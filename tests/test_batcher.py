@@ -7,6 +7,7 @@ import time
 
 import pytest
 
+from helpers_engine import join_all
 from tpulab.core import AsyncDispatcher, Dispatcher, StandardBatcher
 from tpulab.core.async_compute import async_compute
 
@@ -111,7 +112,7 @@ def test_dispatcher_concurrent_producers():
             target=lambda base=b: [d.enqueue(base * 100 + i) for i in range(25)])
             for b in range(4)]
         [t.start() for t in threads]
-        [t.join() for t in threads]
+        join_all(threads)
         time.sleep(0.3)
     assert sorted(total) == sorted(b * 100 + i for b in range(4) for i in range(25))
 

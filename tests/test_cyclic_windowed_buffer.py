@@ -7,6 +7,7 @@ from concurrent.futures import Future
 import numpy as np
 import pytest
 
+from helpers_engine import join_all
 from tpulab import memory as tm
 from tpulab.core import (CyclicWindowedReservedStack, CyclicWindowedStack,
                          CyclicWindowedTaskExecutor, ThreadPool)
@@ -95,7 +96,7 @@ def test_backpressure_blocks_on_inflight_window():
     assert not done.is_set()  # blocked — backpressure works
     gate.set_result(None)
     assert done.wait(timeout=2)
-    t.join()
+    join_all([t])
     stack.release()
 
 

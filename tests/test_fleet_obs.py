@@ -39,6 +39,7 @@ import numpy as np
 import pytest
 
 import tpulab
+from helpers_engine import wait_until
 from tpulab.fleet import (FileLeaseBackend, FleetAutoscaler, FleetController,
                           FleetObserver, FleetSupervisor, LeaderElector,
                           ReplicaProvider, SubprocessReplicaProvider)
@@ -282,10 +283,8 @@ def test_autoscaler_journals_decisions_with_evidence(tmp_path):
     assert asc.evaluate() == "drain_started"
     (dr,) = j.events(kind="drain_start")
     assert dr["wait_ewma_s"] == 0.0
-    deadline = time.monotonic() + 10
-    while asc.evaluate() != "scale_down":
-        assert time.monotonic() < deadline, "drain never completed"
-        time.sleep(0.01)
+    wait_until(lambda: asc.evaluate() == "scale_down",
+               "the drain completed", timeout_s=10)
     (down,) = j.events(kind="scale_down")
     assert down["drain_ok"] is True and down["active"] == 1
     assert sequence_gaps(j.events()) == []
@@ -368,10 +367,8 @@ def test_killed_leader_takeover_reconstructs_from_journals(tmp_path):
 
         proc.kill()                              # no release, no goodbye
         proc.wait(timeout=10)
-        t0 = time.monotonic()
-        while not ctl.tick()["leader"]:
-            assert time.monotonic() - t0 < 5.0, "takeover never happened"
-            time.sleep(0.02)
+        wait_until(lambda: ctl.tick()["leader"], "the takeover",
+                   timeout_s=5, poll_s=0.02)
         ctl.tick()                               # heal + publish again
 
         child_evs = replay_journal(child_journal)

@@ -41,6 +41,7 @@ import numpy as np
 import pytest
 
 import tpulab
+from helpers_engine import wait_until
 from tpulab import chaos
 from tpulab.fleet import (FileLeaseBackend, FleetAutoscaler, FleetController,
                           FleetSupervisor, InProcessReplicaProvider,
@@ -707,21 +708,15 @@ def test_subprocess_fleet_kill_resume_respawn_and_scaledown():
         # the stream bit-exact on the survivor
         got = list(rs.generate(prompt, steps, timeout=120))
         assert got == expected, (got, expected)
-        deadline = time.monotonic() + 60
-        while prov.is_alive(victim) is not False:
-            assert time.monotonic() < deadline, "victim never died"
-            time.sleep(0.1)
+        wait_until(lambda: prov.is_alive(victim) is False,
+                   "the victim died", timeout_s=60, poll_s=0.1)
 
         # 2. the supervisor heals: death detected, lineage respawned
         acts = sup.probe()
         assert victim in acts["deaths"]
         assert prov.exit_code(victim) == chaos.KILL_EXIT_CODE
-        deadline = time.monotonic() + 240
-        respawned = []
-        while not respawned:
-            assert time.monotonic() < deadline, "respawn never happened"
-            time.sleep(0.1)
-            respawned = sup.probe()["respawns"]
+        respawned = wait_until(lambda: sup.probe()["respawns"],
+                               "the respawn", timeout_s=240, poll_s=0.1)
         assert rs.active_count == 2
         got2 = list(rs.generate(prompt, steps, timeout=120))
         assert got2 == expected                # healed fleet serves

@@ -7,6 +7,8 @@ import threading
 import numpy as np
 import pytest
 
+from helpers_engine import wait_until
+
 
 def test_pool_fuzz_conservation():
     """Resources are never lost or duplicated under random pop/release/
@@ -45,15 +47,14 @@ def test_pool_fuzz_conservation():
     assert not any(t.is_alive() for t in threads), "pool fuzz worker hung"
     assert not errors
     import gc
-    gc.collect()
+
     # conservation: pool items + detached == original 6
-    deadline = 50
-    while pool.available + len(detached) < 6 and deadline:
+    def all_home():
         gc.collect()
-        import time
-        time.sleep(0.1)
-        deadline -= 1
-    assert pool.available + len(detached) == 6
+        return pool.available + len(detached) == 6
+
+    wait_until(all_home, "every pool item is home or detached",
+               timeout_s=5, poll_s=0.1)
     got = sorted(detached + [pool.pop(timeout=1).detach()
                              for _ in range(pool.available)])
     assert got == sorted(set(got))  # no duplication

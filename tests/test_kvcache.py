@@ -141,7 +141,7 @@ def test_preempt_resume_no_reprefill_token_parity(lm):
     try:
         started = FirstTokenGate()
         f_low = cb.submit(p_low, 10, on_token=started)
-        assert started.wait()
+        assert started.wait(timeout=60)
         f_hi = cb.submit(p_hi, 4, priority=10)    # outranks -> preempts
         started.release()
         got_hi = f_hi.result(timeout=120)
@@ -164,7 +164,7 @@ def test_preempt_resume_no_reprefill_token_parity(lm):
         f_s = cb.submit(p_low, 10,
                         sampling=SamplingParams(temperature=0.9, seed=123),
                         on_token=started2)
-        assert started2.wait()
+        assert started2.wait(timeout=60)
         f_hi2 = cb.submit(p_hi, 2, priority=10)
         started2.release()
         f_hi2.result(timeout=120)
@@ -213,7 +213,7 @@ def test_demoted_prefix_promotion_hit(lm):
 def _preempt_run(cb, p_low, p_hi):
     started = FirstTokenGate()
     f_low = cb.submit(p_low, 10, on_token=started)
-    assert started.wait()
+    assert started.wait(timeout=60)
     f_hi = cb.submit(p_hi, 4, priority=10)
     started.release()
     return f_hi.result(timeout=120), f_low.result(timeout=120)

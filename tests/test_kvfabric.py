@@ -6,7 +6,6 @@ acceptance contract at the engine level, and the full two-replica RPC
 fleet (slow) including owner death mid-fetch."""
 
 import threading
-import time
 from types import SimpleNamespace
 
 import numpy as np
@@ -14,6 +13,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from helpers_engine import wait_until
 from tpulab import chaos
 from tpulab.disagg import KVShipper, prompt_digest
 from tpulab.disagg.wire import deserialize_snapshot
@@ -44,15 +44,11 @@ def _sampling():
     return SamplingParams(temperature=0.8, device=True, seed=1234)
 
 
-def _wait_published(cb, digest, timeout=30.0):
+def _wait_published(cb, digest):
     """Publish is write-behind: wait for the snapshot to land resident
     in the owner's host tier (the fablog row lands synchronously)."""
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if ("fab", digest) in cb.kv_offload.store:
-            return
-        time.sleep(0.01)
-    raise AssertionError("fabric publish never settled")
+    wait_until(lambda: ("fab", digest) in cb.kv_offload.store,
+               "the fabric publish settled", timeout_s=30)
 
 
 class _DirectClient:
@@ -336,10 +332,8 @@ def test_single_flight_one_fetch_for_concurrent_misses(lm, owner):
         assert entered.wait(30)                    # a leader is in flight
         for t in ts[1:]:
             t.start()
-        deadline = time.monotonic() + 30
-        while fab.snapshot()["coalesced"] < 3:
-            assert time.monotonic() < deadline, "waiters never queued"
-            time.sleep(0.01)
+        wait_until(lambda: fab.snapshot()["coalesced"] >= 3,
+                   "the three waiters queued", timeout_s=30)
         release.set()
         for t in ts:
             t.join(timeout=60)

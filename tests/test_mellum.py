@@ -561,10 +561,9 @@ def test_one_lane_takes_the_block_another_returned_under_a_living_stream(
         rng = np.random.default_rng(3)
         pa, pb = (rng.integers(0, VOCAB, n).tolist() for n in (90, 70))
         gate = FirstTokenGate()
-        fa = cb.submit(pa, 80, logprobs=True,
-                       on_token=lambda tok, i, _lp: gate(tok, i))
+        fa = cb.submit(pa, 80, logprobs=True, on_token=gate)
         req_a = cb._requests[fa]
-        assert gate.wait()
+        assert gate.wait(timeout=60)
         fb = cb.submit(pb, 40, logprobs=True)
         req_b = cb._requests[fb]
         gate.release()
@@ -666,7 +665,7 @@ def test_a_preempted_request_prefills_again_to_a_fresh_engines_tokens(model):
     try:
         gate = FirstTokenGate()
         victim = cb.submit(prompt, 30, on_token=gate)
-        assert gate.wait()
+        assert gate.wait(timeout=60)
         other = cb.submit(prompt[:20], 4, priority=5)
         gate.release()
         assert len(other.result(timeout=300)) == 4

@@ -7,6 +7,7 @@ import gc
 import numpy as np
 import pytest
 
+from helpers_engine import join_all
 from tpulab import memory as tm
 from tpulab.memory.raw_allocators import FirstTouchAllocator
 
@@ -290,7 +291,7 @@ def test_transactional_thread_safety():
 
     threads = [threading.Thread(target=worker) for _ in range(8)]
     [th.start() for th in threads]
-    [th.join() for th in threads]
+    join_all(threads)
     assert not errors
 
 

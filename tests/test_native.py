@@ -7,6 +7,7 @@ import threading
 import numpy as np
 import pytest
 
+from helpers_engine import join_all
 from tpulab import native
 
 pytestmark = pytest.mark.skipif(not native.available(),
@@ -67,7 +68,7 @@ def test_native_transactional_threads():
 
     threads = [threading.Thread(target=worker) for _ in range(8)]
     [t.start() for t in threads]
-    [t.join() for t in threads]
+    join_all(threads)
     assert not errors
     tx.close()
 

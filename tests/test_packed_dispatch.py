@@ -19,6 +19,7 @@ import pytest
 import test_glm_moe
 import test_jamba
 import test_keye_sparse
+from helpers_engine import TokenGate
 from helpers_steps import decode_block
 from tpulab.engine.paged import ContinuousBatcher, SamplingParams
 from tpulab.engine.paged_steps import (dispatch_fields, pack_words,
@@ -264,14 +265,14 @@ def test_first_and_chained_rounds_are_one_compiled_program():
     spec, params = _dense()
     cb = _engine(spec, params, lanes=4, max_len=120)
     rng = np.random.default_rng(5)
-    streaming = threading.Event()
+    streaming = TokenGate(4)
     try:
-        futs = [cb.submit(np.arange(7), steps=50,
-                          on_token=lambda t, i: i == 3 and streaming.set())]
+        futs = [cb.submit(np.arange(7), steps=50, on_token=streaming)]
         assert streaming.wait(60)
         # prompts of 8 + 8 + 8 + 3 tokens: rounds of width 8, 8, 8 and 4
         futs += [cb.submit(rng.integers(0, 64, 27), steps=4)
                  for _ in range(3)]
+        streaming.release()
         for f in futs:
             f.result(timeout=300)
         state = cb.debug_state()["dispatch"]

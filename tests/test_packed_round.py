@@ -13,7 +13,6 @@ token budget, oldest admission first, so the program is keyed by one bucketed
 number and a single prompt reaches every bucket.
 """
 
-import threading
 from functools import partial
 
 import jax
@@ -25,6 +24,7 @@ import test_evabyte
 import test_jamba
 import test_keye_sparse
 import test_qwen3_next
+from helpers_engine import TokenGate
 from helpers_steps import mixed_step
 from test_glm_moe import CONFIG, D_FF, VOCAB
 from tpulab.engine import paged_steps
@@ -610,18 +610,19 @@ def test_mixed_attn_rows_counts_m_a_chunk_lane_and_one_a_decode_lane():
                  prefill_chunk=32)
     rounds = _spy_rounds(cb)
     rng = np.random.default_rng(13)
-    streaming = threading.Event()
+    streaming = TokenGate(3)
     try:
         # two lanes decode for long; prompts of one to three chunks arrive
         # beside them, three at a time on the two lanes left
         futs = [cb.submit(rng.integers(0, 64, 40), steps=90,
-                          on_token=lambda t, i: i == 2 and streaming.set()),
+                          on_token=streaming),
                 cb.submit(rng.integers(0, 64, 5), steps=90)]
         assert streaming.wait(60)
         for burst in ((70, 33, 9), (64, 17, 1)):
             with cb._cv:     # one admission pass sees the burst
                 futs += [cb.submit(rng.integers(0, 64, n), steps=3)
                          for n in burst]
+            streaming.release()
             futs[-1].result(timeout=120)
         for f in futs:
             f.result(timeout=120)
@@ -797,17 +798,18 @@ def test_budget_counters_count_what_the_spy_sees(chunk):
     rounds = _spy_rounds(cb)
     budget = cb._round_budget
     rng = np.random.default_rng(19)
-    streaming = threading.Event()
+    streaming = TokenGate(3)
     try:
         # one lane decodes while prompts of under one to over two budgets
         # arrive beside it, two at a time
         futs = [cb.submit(rng.integers(0, 64, 9), steps=60,
-                          on_token=lambda t, i: i == 2 and streaming.set())]
+                          on_token=streaming)]
         assert streaming.wait(60)
         for burst in ((2 * budget + 7, budget // 2), (budget, 3)):
             with cb._cv:
                 futs += [cb.submit(rng.integers(0, 64, n), steps=3)
                          for n in burst]
+            streaming.release()
             futs[-1].result(timeout=300)
         for f in futs:
             f.result(timeout=300)

@@ -373,27 +373,20 @@ def test_prefix_cache_eviction_under_pressure(lm):
 def test_priority_admission_order(lm):
     """With one lane, queued requests admit by priority (high first),
     FIFO within a class."""
-    import threading
-    release = threading.Event()
-    first_started = threading.Event()
-
-    def gate(tok, i):
-        first_started.set()
-        release.wait(timeout=60)
-
+    gate = FirstTokenGate()
     cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=1, max_len=32,
                            page_size=8, compute_dtype=jnp.float32)
     try:
         order = []
         f0 = cb.submit(np.full((3,), 1, np.int32), 4, on_token=gate)
-        assert first_started.wait(timeout=60)
+        assert gate.wait(timeout=60)
         # lane busy: queue three more at mixed priorities
         fs = [cb.submit(np.full((3,), 2 + i, np.int32), 2, priority=pri,
                         on_token=lambda tok, i, tag=tag: (
                             order.append(tag) if i == 0 else None))
               for i, (pri, tag) in enumerate([(0, "low"), (5, "hi"),
                                               (1, "mid")])]
-        release.set()
+        gate.release()
         f0.result(timeout=120)
         for f in fs:
             f.result(timeout=120)
@@ -428,7 +421,7 @@ def test_preemption_exact_resume(lm):
     try:
         started = FirstTokenGate()
         f_low = cb.submit(p_low, 10, on_token=started)
-        assert started.wait()
+        assert started.wait(timeout=60)
         f_hi = cb.submit(p_hi, 4, priority=10)      # outranks -> preempts
         started.release()
         got_hi = f_hi.result(timeout=120)
@@ -444,7 +437,7 @@ def test_preemption_exact_resume(lm):
         f_s = cb.submit(p_low, 10,
                         sampling=SamplingParams(temperature=0.9, seed=123),
                         on_token=started2)
-        assert started2.wait()
+        assert started2.wait(timeout=60)
         f_hi2 = cb.submit(p_hi, 2, priority=10)
         started2.release()
         f_hi2.result(timeout=120)
@@ -766,7 +759,7 @@ def test_device_sampling_reproducible_and_batch_invariant(lm):
                           sampling=SamplingParams(temperature=1.5, seed=9,
                                                   device=True))
             if preempt:
-                assert started.wait()
+                assert started.wait(timeout=60)
                 hi = cb.submit(np.full((4,), 2, np.int32), 3, priority=10)
                 started.release()
                 hi.result(timeout=120)

@@ -8,6 +8,7 @@ import time
 
 import pytest
 
+from helpers_engine import join_all
 from tpulab.core import Pool, Queue, UniquePool
 
 
@@ -142,7 +143,7 @@ def test_pool_concurrent_stress():
 
     threads = [threading.Thread(target=worker) for _ in range(8)]
     [t.start() for t in threads]
-    [t.join() for t in threads]
+    join_all(threads)
     assert len(counts) == 400
     assert pool.available == 4
 
@@ -199,7 +200,7 @@ def test_native_backed_pool_concurrent_stress():
 
     threads = [threading.Thread(target=worker) for _ in range(8)]
     [t.start() for t in threads]
-    [t.join() for t in threads]
+    join_all(threads)
     assert len(counts) == 400
     assert pool.available == 4
 

@@ -19,7 +19,6 @@ paged attention"):
 """
 
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +37,7 @@ from tpulab.ops.ragged_attention import (ragged_latent_attention,
                                          ragged_paged_attention)
 from tpulab.parallel import make_mesh
 
+from helpers_engine import TokenGate
 from helpers_attention import (BF16_ATOL, BF16_RTOL, assert_operand_rule,
                                assert_parents_bits, kernel_eqns, pallas_calls,
                                sparse_attend_case, sparse_decode_case)
@@ -892,12 +892,12 @@ def test_mixed_round_is_one_fused_dispatch(lm):
 
         # mixed prefill+decode: a prompt arriving mid-decode rides the
         # same fused round as the decoding lane
-        evt = threading.Event()
-        f0 = cb.submit(prompts[0], 16,
-                       on_token=lambda t, i: evt.set() if i == 2 else None)
-        assert evt.wait(60)
+        held = TokenGate(3)
+        f0 = cb.submit(prompts[0], 16, on_token=held)
+        assert held.wait(60)
         d1 = cb.decode_dispatches
         f1 = cb.submit(prompts[1], 4)
+        held.release()
         r1 = f1.result(timeout=300)
         r0 = f0.result(timeout=300)
         assert cb.dispatch_kinds["mixed"] - m0 >= 3
@@ -935,12 +935,11 @@ def test_chunked_prefill_prefix_cache_and_resume(lm):
     cb = _batcher(lm, use_kernel=False, ragged=True, lanes=1,
                   decode_block=2)
     try:
-        f1 = cb.submit(short_p, 20, priority=0)
-        evt = threading.Event()
-        t = threading.Timer(0.2, evt.set)
-        t.start()
-        evt.wait()
+        started = TokenGate()
+        f1 = cb.submit(short_p, 20, priority=0, on_token=started)
+        assert started.wait(timeout=120)
         f2 = cb.submit(long_p[:9], 4, priority=5)
+        started.release()
         r2, r1 = f2.result(timeout=300), f1.result(timeout=300)
         assert cb.preemptions >= 1
     finally:

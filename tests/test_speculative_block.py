@@ -13,8 +13,6 @@ never speculate, a chaos-tripped verify degrades the lane without a
 corrupt or duplicated emission, and draft-table pages always come home.
 """
 
-import threading
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -505,14 +503,15 @@ def test_spec_probe_recovers_after_transient_degrade(lm, dense):
     p = np.random.default_rng(17).integers(0, 64, (5,), np.int32)
     cb = _batcher(lm, draft="self", lanes=1, max_len=96)
     try:
-        started = threading.Event()
-        fut = cb.submit(p, 60, on_token=lambda t, i: started.set())
+        started = FirstTokenGate()
+        fut = cb.submit(p, 60, on_token=started)
         assert started.wait(timeout=120)
         # transient degrade, exactly what a low-acceptance stretch does
         with cb._cv:
             req = next(r for r in cb._active if r is not None)
             cb._degrade_spec(req, probe=True)
         spec_after_degrade = cb.spec_dispatches
+        started.release()
         got = list(fut.result(timeout=300))
         np.testing.assert_array_equal(
             np.asarray(got), np.asarray(dense(p[None, :], 60)[0]))
