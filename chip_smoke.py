@@ -18,7 +18,8 @@ Phases, in order; any phase that raises fails the run (exit 1):
               logits must match a direct ``runner.infer`` of the same input.
 3. lm       — the paged LM (hidden 2048, 16Q/4KV heads of 128, bf16,
               page 16, vocab 50304, depth cut) behind the Generate RPC,
-              concurrent streams under each dispatch plan the engine has.
+              concurrent streams through the XLA gather and through the
+              kernels (one dispatch plan: prompts ride mixed rounds).
    latent   — a two-layer MLA + expert model (GLM-4.7-Flash's widths, one
               dense and one expert layer, 64 experts top-4 + shared) on a
               latent page store: a mixed round and a decode step through
@@ -51,9 +52,9 @@ Phases, in order; any phase that raises fails the run (exit 1):
               (two compactions in rounds, the ``eva_chunk_summary`` kernel),
               then decode across a third, against the plain float32
               reference (``perf/reference/evabyte.py``).
-4. kernels  — the ragged and flash Pallas kernels compiled by Mosaic
+4. kernels  — the ragged Pallas kernels compiled by Mosaic
               (``interpret=False``, custom call present in the lowered
-              program) against the XLA gather / dense-softmax paths.
+              program) against the XLA gather.
 5. multichip— with more than one device: one RN50 replica per chip through
               ``MultiDeviceDispatcher`` and the LM on a ``{"model": N}``
               mesh against the single-device logits.
@@ -170,7 +171,6 @@ class Sizes:
     lm_prefill_chunk: int = 128
     lm_prompt_lens: tuple = (8, 50, 300)   # one longer than prefill_chunk
     lm_steps: int = 24
-    flash_t: int = 512
 
 
 REHEARSAL_SIZES = Sizes(
@@ -215,7 +215,7 @@ REHEARSAL_SIZES = Sizes(
         num_hidden_layers=2, num_pred_heads=3, window_size=64, chunk_size=8,
         rms_norm_eps=1e-5, rope_theta=1e5, vocab_size=64),
     lm_max_len=96, lm_page_size=8, lm_prefill_chunk=16,
-    lm_prompt_lens=(5, 12, 40), lm_steps=6, flash_t=32)
+    lm_prompt_lens=(5, 12, 40), lm_steps=6)
 
 
 class Smoke:
@@ -311,18 +311,13 @@ def phase_rn50(smoke: Smoke) -> str:
 
 
 # -- phase 3: the paged LM through the Generate RPC --------------------------
-#: every dispatch plan the engine can select (ContinuousBatcher options)
+#: the two attentions the engine's one dispatch plan runs through
+#: (ContinuousBatcher options)
 LM_PLANS = (
-    ("legacy_split_gather", dict(ragged=False, use_kernel=False,
-                                 prefill_flash=False)),
-    ("ragged_gather", dict(ragged=True, use_kernel=False,
-                           prefill_flash=False)),
-    # these two un-chunked: the long prompt rides the widest mixed round
-    # (RAGGED_CHUNK_CAP) through the kernel, its whole bucket through flash
-    ("ragged_kernel", dict(ragged=True, use_kernel=True,
-                           prefill_flash=False, prefill_chunk=None)),
-    ("flash_prefill", dict(ragged=False, use_kernel=False,
-                           prefill_flash=True, prefill_chunk=None)),
+    ("lm_gather", dict(use_kernel=False)),
+    # un-chunked: the long prompt rides the widest mixed round
+    # (RAGGED_CHUNK_CAP) through the kernel
+    ("lm_kernel", dict(use_kernel=True, prefill_chunk=None)),
 )
 
 
@@ -407,12 +402,10 @@ def phase_lm(smoke: Smoke) -> str:
     try:
         for name, plan in LM_PLANS:
             cb = engines[name] = lm_engine(smoke, params, **plan)
-            picked = dict(ragged=cb.ragged, use_kernel=cb.use_kernel,
-                          prefill_flash=cb.prefill_flash)
-            asked = {k: plan[k] for k in picked}
-            if picked != asked:
-                raise AssertionError(f"plan {name}: asked {asked}, engine "
-                                     f"selected {picked}")
+            if cb.use_kernel != plan["use_kernel"]:
+                raise AssertionError(
+                    f"plan {name}: asked use_kernel={plan['use_kernel']}, "
+                    f"engine selected {cb.use_kernel}")
         manager.serve(port=0, generation_engines=engines)
         remote = tpulab.RemoteInferenceManager(
             f"localhost:{manager.server.bound_port}")
@@ -421,12 +414,11 @@ def phase_lm(smoke: Smoke) -> str:
             n = stream_generations(smoke, remote, name)
             cb = engines[name]
             report.append(f"{name}: {n} tokens, dispatches="
-                          f"{dict(cb.dispatch_kinds)} prefills="
-                          f"{cb.prefill_dispatches}")
-        if engines["ragged_gather"].dispatch_kinds["mixed"] == 0:
-            raise AssertionError("ragged plan ran no mixed round")
-        if engines["legacy_split_gather"].prefill_dispatches == 0:
-            raise AssertionError("legacy plan ran no prefill dispatch")
+                          f"{dict(cb.dispatch_kinds)}")
+            kinds = cb.dispatch_kinds
+            if not (kinds["mixed"] and kinds["decode"]):
+                raise AssertionError(f"plan {name} ran no mixed round or no "
+                                     f"decode block: {kinds}")
         return "; ".join(report)
     finally:
         if remote is not None:
@@ -972,8 +964,6 @@ def phase_kernels(smoke: Smoke) -> str:
     import numpy as np
 
     from tpulab.engine.paged import ContinuousBatcher
-    from tpulab.models.transformer import causal_attention
-    from tpulab.ops.flash_attention import flash_attention
     sz = smoke.sizes
     ps, top = sz.lm_page_size, sz.lm_max_len
     # the widest segment the engine dispatches un-chunked
@@ -996,47 +986,36 @@ def phase_kernels(smoke: Smoke) -> str:
         smoke, "ragged slot refill", [1, 1], [16 * ps - 1, 8 * ps + 2], m=1,
         dtype=jnp.float32, g_pages=2, nbuf=3, heads=(2, 2), tol=2e-3)
 
-    t, h = sz.flash_t, sz.lm["n_heads"]
-    d = sz.lm["d_model"] // h
-    rng = np.random.default_rng(1)
-    q, k, v = (jnp.asarray(rng.standard_normal((1, t, h, d)), jnp.bfloat16)
-               for _ in range(3))
-
-    def flash(q, k, v):
-        return flash_attention(q, k, v, causal=True,
-                               interpret=smoke.rehearsal)
-
-    check_mosaic(smoke, "flash", flash, q, k, v)
-    got = np.asarray(jax.block_until_ready(flash(q, k, v)), np.float32)
-    want = np.asarray(causal_attention(q, k, v), np.float32)
-    errs["flash"] = float(np.abs(got - want).max())
-    if not np.isfinite(got).all() or errs["flash"] > ATTN_TOL:
-        raise AssertionError(f"flash vs dense softmax max abs error "
-                             f"{errs['flash']:.3g} > {ATTN_TOL}")
     shown = " ".join(f"{k}={v:.2g}" for k, v in errs.items())
     return f"max_abs_err_vs_xla: {shown} (tol {ATTN_TOL}, f32 case 2e-3)"
 
 
 # -- phase 5: more than one device -------------------------------------------
-def prefill_logits(cb, prompt):
+def round_logits(cb, prompt):
     """Last-position logits of ``prompt`` from the engine's own jitted
-    prefill program (its shardings included), over a scratch page pool."""
+    mixed round (its shardings included): the whole prompt as lane 0's
+    chunk, over a scratch page pool."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from tpulab.engine.paged_steps import ROUND_STOPS, pack_round, pack_words
     pool = cb.pool
     kv = jax.device_put(jnp.zeros(pool._shape, pool.dtype), pool.placement)
-    t = len(prompt)
-    t_pad = 1 << (t - 1).bit_length()
-    tokens = np.zeros((1, t_pad), np.int32)
-    tokens[0, :t] = prompt
-    tables = np.zeros((cb.max_pages,), np.int32)
-    n = -(-t // cb.page_size)
-    tables[:n] = 1 + np.arange(n)
-    logits, _ = cb.programs.prefill(cb.params, kv, jnp.asarray(tables),
-                            jnp.asarray(tokens), jnp.int32(t))
-    return np.asarray(logits, np.float32)
+    b = cb.lanes
+    toks, row_lane, row_off, q_lens = pack_round(b, {0: prompt}, {})
+    tables = np.zeros((b, cb.max_pages), np.int32)
+    n = -(-len(prompt) // cb.page_size)
+    tables[0, :n] = 1 + np.arange(n)
+    packed = pack_words(cb.programs.fields["round"], dict(
+        tables=tables, q_lens=q_lens, kv_lens=q_lens,
+        temps=np.zeros((b,), np.float32), seeds=np.zeros((b, 2), np.uint32),
+        fresh=np.ones((b,), bool), rem=np.zeros((b,), np.int32),
+        stops=np.full((b, ROUND_STOPS), -1, np.int32),
+        rows=np.stack([toks, row_lane, row_off])))
+    _out, last, *_ = cb.programs.mixed(cb.params, kv, cb._put(packed),
+                                       cb._no_carry)
+    return np.asarray(last[0], np.float32)
 
 
 def phase_multichip(smoke: Smoke) -> str:
@@ -1086,23 +1065,21 @@ def phase_multichip(smoke: Smoke) -> str:
     # the LM tensor-parallel over every chip, against the single-device run
     params = lm_params(smoke)
     mesh = make_mesh({"model": n}, devices)
-    plan = dict(ragged=False, use_kernel=False, prefill_flash=False)
-    single = lm_engine(smoke, params, **plan)
-    sharded = lm_engine(smoke, params, mesh=mesh, **plan)
+    single = lm_engine(smoke, params, use_kernel=False)
+    sharded = lm_engine(smoke, params, mesh=mesh, use_kernel=False)
     # and the ragged kernel under shard_map, each chip walking its own
     # KV heads' pages
-    sharded_kernel = lm_engine(smoke, params, mesh=mesh, ragged=True,
-                               use_kernel=True, prefill_flash=False)
+    sharded_kernel = lm_engine(smoke, params, mesh=mesh, use_kernel=True)
     manager = tpulab.InferenceManager(max_exec_concurrency=1)
     remote = None
     try:
         prompt = np.random.default_rng(2).integers(
             0, sz.lm["vocab"], (sz.lm_prompt_lens[1],)).astype(np.int32)
-        a, b = prefill_logits(single, prompt), prefill_logits(sharded, prompt)
+        a, b = round_logits(single, prompt), round_logits(sharded, prompt)
         err = float(np.abs(a - b).max() / max(1.0, float(np.abs(a).max())))
         if not np.isfinite(b).all() or err > LOGIT_RTOL:
             raise AssertionError(
-                f"{{'model': {n}}} prefill logits differ from single-device "
+                f"{{'model': {n}}} round logits differ from single-device "
                 f"by {err:.3g} (limit {LOGIT_RTOL})")
         manager.serve(port=0, generation_engines={
             "lm_mesh": sharded, "lm_mesh_kernel": sharded_kernel})
@@ -1119,7 +1096,7 @@ def phase_multichip(smoke: Smoke) -> str:
     return (f"multichip: {n} devices; rn50 served={list(disp.served)} "
             f"bytes_in_use={held} (weights {weight_bytes}); lm mesh "
             f"{{'model': {n}}} logits rel err {err:.2g}, {tokens} tokens "
-            "streamed (gather and ragged-kernel plans)")
+            "streamed (gather and kernel attention)")
 
 
 def main(argv=None) -> int:

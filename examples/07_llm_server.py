@@ -232,7 +232,7 @@ def main():
     print(f"LLM server on :{mgr.server.bound_port} "
           f"(lanes={args.lanes} max_len={args.max_len} "
           f"int8={args.int8} kv_fp8={args.kv_fp8} "
-          f"kernel={cb.use_kernel} flash_prefill={cb.prefill_flash} "
+          f"kernel={cb.use_kernel} "
           f"admission={'on' if admission else 'off'} role={args.role})",
           flush=True)
     import time

@@ -7,10 +7,15 @@ and never waits without an end.
 - :func:`wait_until` is the one poll, for what no hook can hold (a second
   thread's arrival in a queue, a write-behind publish): it ends, and says
   what it waited for.
+- :func:`greedy_reference` is the dense decoder's greedy stream by the plain
+  forward over the whole sequence: a reference that shares no code with the
+  engine (no pages, no cache, no step program).
 """
 
 import threading
 import time
+
+import numpy as np
 
 
 class TokenGate:
@@ -83,3 +88,32 @@ def wait_until(predicate, what: str, timeout_s: float = 60.0,
         time.sleep(poll_s)
     assert got, f"{what}: not within {timeout_s:g} s (last value {got!r})"
     return got
+
+
+def greedy_reference(params, prompt, steps: int, n_heads: int, n_layers: int,
+                     **model):
+    """``(tokens, logprobs)`` of ``steps`` greedy tokens after ``prompt``,
+    each from ``tpulab.models.transformer.transformer_apply`` over the WHOLE
+    sequence so far at float32 (``model``: ``n_kv_heads``, ``rope_theta``):
+    no K/V is kept from one token to the next, so nothing of the engine (its
+    pages, its step programs, its sampler) is in it.  The sequence is padded
+    to one power of two (a causal row reads nothing behind it), so the
+    forward compiles once."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpulab.models.transformer import transformer_apply
+    n = len(prompt)
+    seq = np.zeros((1, 1 << (n + steps - 1).bit_length()), np.int32)
+    seq[0, :n] = prompt
+    forward = jax.jit(lambda tokens: transformer_apply(
+        params, {"tokens": tokens}, n_heads=n_heads, n_layers=n_layers,
+        compute_dtype=jnp.float32, **model)["logits"])
+    tokens, logprobs = [], []
+    for at in range(n, n + steps):
+        row = np.asarray(forward(seq)[0, at - 1], np.float64)
+        tokens.append(int(row.argmax()))
+        logprobs.append(float(row[tokens[-1]] - row.max()
+                              - np.log(np.exp(row - row.max()).sum())))
+        seq[0, at] = tokens[-1]
+    return tokens, logprobs

@@ -71,3 +71,27 @@ def test_engine_names_the_constraint_up_front(monkeypatch):
         ContinuousBatcher(params, n_heads=2, n_layers=1, lanes=1,
                           max_len=32, page_size=8, use_kernel=True,
                           compute_dtype=jnp.float32)
+
+
+def test_the_smokes_plan_table_is_one_plan_through_both_attentions():
+    """``LM_PLANS`` names the two attentions of the engine's one dispatch
+    plan, by options the constructor has: no plan is chosen by name any
+    more, and the flash prefill row went with its kernel."""
+    import importlib.util
+    import inspect
+
+    from tpulab.engine.paged import ContinuousBatcher
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", f"{REPO}/chip_smoke.py")
+    smoke = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = smoke      # its dataclasses look themselves up
+    try:
+        spec.loader.exec_module(smoke)
+    finally:
+        del sys.modules[spec.name]
+    assert [name for name, _ in smoke.LM_PLANS] == ["lm_gather", "lm_kernel"]
+    assert [plan["use_kernel"] for _, plan in smoke.LM_PLANS] == [False, True]
+    taken = set(inspect.signature(ContinuousBatcher.__init__).parameters)
+    assert all(set(plan) <= taken for _, plan in smoke.LM_PLANS)
+    assert not {"ragged", "prefill_flash"} & taken
+    assert not hasattr(smoke, "prefill_logits")

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers_engine import TokenGate
 from tpulab.engine.paged import (ContinuousBatcher, SamplingParams,
                                  _PagedRequest)
 from tpulab.models.transformer import init_transformer_params, make_generate_fn
@@ -206,22 +207,24 @@ def test_block_streaming_callbacks_in_order(lm):
 
 
 def test_host_sync_budget_per_request(lm):
-    """Regression guard against reintroducing per-token host syncs: a
-    greedy request's blocking decode fetches stay <= ceil(steps/K), plus
-    one prefill pass (counted separately)."""
+    """Regression guard against reintroducing per-token host syncs.  A
+    greedy request's prompt of 5 tokens rides ONE mixed round (the budget
+    is ``max_len / 2`` = 32), which emits the first token; the other
+    ``steps - 1`` come out of blocks of K: its dispatches, and its blocking
+    fetches, are at most ``1 + ceil((steps - 1) / K)``."""
     cb = _batcher(lm, 8, lanes=1)
     try:
         p = np.random.default_rng(7).integers(0, 64, (5,), np.int32)
         cb.submit(p, 17).result(timeout=120)   # warm compiles
         s0, d0 = cb.decode_host_syncs, cb.decode_dispatches
-        pf0, tg0 = cb.prefill_dispatches, cb.tokens_generated
+        r0, tg0 = cb.dispatch_kinds["mixed"], cb.tokens_generated
         out = cb.submit(p, 17).result(timeout=120)
         assert len(out) == 17
         syncs = cb.decode_host_syncs - s0
-        budget = math.ceil(17 / cb.decode_block)
+        budget = 1 + math.ceil((17 - 1) / cb.decode_block)
         assert syncs <= budget, (syncs, budget)
         assert cb.decode_dispatches - d0 <= budget
-        assert cb.prefill_dispatches - pf0 == 1
+        assert cb.dispatch_kinds["mixed"] - r0 == 1
         # and the telemetry ratio reflects the amortization
         toks = cb.tokens_generated - tg0
         assert toks == 17 and syncs / toks < 0.2
@@ -295,8 +298,9 @@ def test_block_deadline_expiry_within_one_block(lm):
         cb.shutdown()
 
 
-@pytest.mark.parametrize("ragged", [False, True], ids=["legacy", "ragged"])
-def test_a_lane_admitted_beside_a_running_chain_is_not_starved(lm, ragged):
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernels-interpret"])
+def test_a_lane_admitted_beside_a_running_chain_is_not_starved(lm, use_kernel):
     """Blocks are dispatched ahead from the device-resident carry for as
     long as the lane set is stable; a request admitted meanwhile is in no
     block of that chain.  It must get its steps as soon as its prompt is
@@ -304,7 +308,7 @@ def test_a_lane_admitted_beside_a_running_chain_is_not_starved(lm, ragged):
     runs 160 steps, and the late request's 4 tokens must not wait for
     them."""
     done = []
-    cb = _batcher(lm, 8, max_len=256, ragged=ragged)
+    cb = _batcher(lm, 8, max_len=256, use_kernel=use_kernel)
     try:
         started = _time.monotonic()
         long_run = cb.submit([3, 14, 15, 9, 2], 160)
@@ -426,10 +430,14 @@ def test_chain_runs_one_block_ahead_up_to_a_foreseen_completion(
     """In a running chain the enqueue of block N+1 precedes the fetch of
     block N.  A lane whose step budget ends inside N is a completion the
     host can foresee: no block is enqueued past it, so a request of S
-    steps makes exactly the dispatches it made when every block was
-    enqueued after its predecessor's commit (``blocks``: the K of each),
-    and ``ahead_blocks`` counts the decode blocks less each chain's first
-    and the ones after a foreseen completion."""
+    steps makes exactly the blocks it made when every block was enqueued
+    after its predecessor's commit (``blocks``: the K of each).  The chain's
+    head is the round that carries the prompt (5 tokens: one round, which
+    emits the first token): by ``_chain_block``'s rule block 0 goes behind
+    it un-fetched wherever the request wants more than one token more (K
+    > 1) and its budget does not end in the round, as in every case here.
+    So ``ahead_blocks`` counts block 0 and the blocks ``ahead`` lists, and
+    the request makes ``len(blocks) + 1`` dispatches and fetches."""
     p = np.random.default_rng(7).integers(0, 64, (5,), np.int32)
     (want,) = _reference(lm, [(p, steps, _sub(3))], lanes=1)
     cb = _batcher(lm, 8, lanes=1)
@@ -443,17 +451,20 @@ def test_chain_runs_one_block_ahead_up_to_a_foreseen_completion(
     assert len(got[0]) == steps
     assert chain.ks == blocks
     assert chain.ahead() == ahead
-    assert state["ahead_blocks"] == cb.ahead_blocks == len(ahead)
-    assert state["decode_dispatches"] == len(blocks)
-    assert state["decode_host_syncs"] == len(blocks)
+    assert chain.sent_ahead[0]            # behind the un-fetched round
+    assert state["ahead_blocks"] == cb.ahead_blocks == len(ahead) + 1
+    assert state["kinds"] == {"mixed": 1, "decode": len(blocks), "verify": 0}
+    assert state["decode_dispatches"] == len(blocks) + 1
+    assert state["decode_host_syncs"] == len(blocks) + 1
     assert not chain.hoarded
     assert cb.pool.free_pages == cb.pool.n_pages - 1
 
 
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
-@pytest.mark.parametrize("ragged", [False, True], ids=["legacy", "ragged"])
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernels-interpret"])
 def test_chain_ahead_parity_with_a_page_crossed_by_every_block(
-        lm, ragged, sampled):
+        lm, use_kernel, sampled):
     """Tokens and logprobs of a chain that runs ahead are those of single
     steps, with K equal to the page size so that every block enqueued
     ahead reserves and crosses into a new page from a committed length
@@ -462,8 +473,8 @@ def test_chain_ahead_parity_with_a_page_crossed_by_every_block(
     cases = [(rng.integers(0, 64, (n,), np.int32), s,
               _sub(77 + n) if sampled else {"logprobs": True})
              for n, s in ((5, 45), (11, 38))]
-    want = _reference(lm, cases, ragged=ragged)
-    cb = _batcher(lm, 8, ragged=ragged)
+    want = _reference(lm, cases, use_kernel=use_kernel)
+    cb = _batcher(lm, 8, use_kernel=use_kernel)
     chain = _Chain(cb)
     try:
         futs = [cb.submit(p, s, **sub) for p, s, sub in cases]
@@ -472,19 +483,20 @@ def test_chain_ahead_parity_with_a_page_crossed_by_every_block(
         cb.shutdown()
     for g, w in zip(got, want):
         _same(g, w)
-    # (under the ragged plan a chain's first block goes behind the round
-    # that brought its lanes' prompts in, not yet fetched either)
+    # (a chain's first block goes behind the round that brought its
+    # lanes' prompts in, not yet fetched either: at most one a request)
     assert len(chain.ahead()) >= 3
     assert cb.ahead_blocks == sum(chain.sent_ahead)
     assert set(chain.ahead()) <= {n for n, a in enumerate(chain.sent_ahead)
                                   if a}
-    assert cb.ahead_blocks - len(chain.ahead()) <= (2 if ragged else 0)
+    assert cb.ahead_blocks - len(chain.ahead()) <= 2
     assert not chain.hoarded
     assert cb.pool.free_pages == cb.pool.n_pages - 1
 
 
-@pytest.mark.parametrize("ragged", [False, True], ids=["legacy", "ragged"])
-def test_stop_token_with_the_block_ahead_in_flight(lm, ragged):
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernels-interpret"])
+def test_stop_token_with_the_block_ahead_in_flight(lm, use_kernel):
     """A stop token is a completion the host cannot foresee: it lands in
     block N with block N+1 already enqueued.  The stream ends on the stop
     token, the dead block emits nothing (its lane is dead in the carry,
@@ -495,14 +507,14 @@ def test_stop_token_with_the_block_ahead_in_flight(lm, ragged):
     pa = rng.integers(0, 64, (6,), np.int32)
     pb = rng.integers(0, 64, (9,), np.int32)
     ref_a, ref_b = _reference(lm, [(pa, 60, _sub(5)), (pb, 20, _sub(6))],
-                              lanes=1, max_len=128, ragged=ragged)
+                              lanes=1, max_len=128, use_kernel=use_kernel)
     # a token first seen in the stream at index >= 18: in block 2 or later
     # (token i comes out of block (i - 1) // 8)
     toks = list(ref_a[0])
     idx = next(i for i in range(18, 52) if toks[i] not in toks[:i])
     n_stop = (idx - 1) // 8
     streamed = []
-    cb = _batcher(lm, 8, lanes=1, max_len=128, ragged=ragged)
+    cb = _batcher(lm, 8, lanes=1, max_len=128, use_kernel=use_kernel)
     chain = _Chain(cb)
     try:
         fa = cb.submit(pa, 60, stop_tokens=[toks[idx]], **_sub(5))
@@ -595,7 +607,10 @@ def test_chain_ahead_under_page_pressure_keeps_k_and_bounds_the_hoard(
     its predecessor's commit, as it always was (that regular plan may
     shrink K, here to the 2 steps the lane's last page still holds).  The
     lane is not starved once pages return, every token is the
-    reference's, and a lane never holds pages past ``length + 2K``."""
+    reference's, and a lane never holds pages past ``length + 2K``.  Block
+    0 goes behind the un-fetched round that carried the prompt (6 tokens
+    in the admission page; its 8 rows end in the one page more that every
+    case leaves free), so ``ahead_blocks`` counts it beside ``ahead``."""
     p = np.random.default_rng(11).integers(0, 64, (6,), np.int32)
     (want,) = _reference(lm, [(p, 41, _sub(21))], lanes=1)
     cb = _batcher(lm, 8, lanes=1)
@@ -611,35 +626,8 @@ def test_chain_ahead_under_page_pressure_keeps_k_and_bounds_the_hoard(
         cb.shutdown()
     _same(got, want)
     assert chain.ks == ks and chain.ahead() == ahead
-    assert cb.ahead_blocks == len(ahead)
+    assert chain.sent_ahead[0] and cb.ahead_blocks == len(ahead) + 1
     assert not chain.hoarded
-    assert cb.pool.free_pages == cb.pool.n_pages - 1
-
-
-def test_a_request_that_arrives_during_a_dispatch_does_not_hold_the_chain(lm):
-    """A closed-loop caller's next request arrives while the scheduler
-    plans and enqueues a chain's first block, after the admission at the
-    top of its pass.  Queued, it would read as queue pressure to the K
-    policy (a streaming lane drops its K <= 2 cap) and hold block 1 back
-    until block 0 was fetched and its commit admitted the request; the
-    decision before the fetch admits it first, so block 1 runs ahead."""
-    rng = np.random.default_rng(51)
-    pa = rng.integers(0, 64, (6,), np.int32)
-    pb = rng.integers(0, 64, (7,), np.int32)
-    ref_a, ref_b = _reference(lm, [(pa, 30, _sub(12)), (pb, 10, _sub(13))],
-                              lanes=1, max_len=128)
-    cb = _batcher(lm, 8, lanes=2, max_len=128)
-    chain = _Chain(cb)
-    late = []
-    try:
-        chain.at_enqueue[0] = lambda: late.append(
-            cb.submit(pb, 10, **_sub(13)))
-        fa = cb.submit(pa, 30, **_sub(12, on_token=lambda tok, i, lp: None))
-        _same(fa.result(timeout=120), ref_a)
-        _same(late[0].result(timeout=120), ref_b)
-    finally:
-        cb.shutdown()
-    assert chain.ks[:2] == [2, 2] and 1 in chain.ahead()
     assert cb.pool.free_pages == cb.pool.n_pages - 1
 
 
@@ -658,6 +646,7 @@ class _Dispatches:
     def __init__(self, cb):
         self.kinds, self.stashes = [], []
         self.events = []          # ("enqueue", n) | ("fetch", n)
+        self.at_enqueue = {}      # n -> callable, as dispatch n is enqueued
         by_out = {}
         block, round_, fetch = (cb._dispatch_block, cb._dispatch_round,
                                 cb._fetch)
@@ -667,6 +656,9 @@ class _Dispatches:
             self.events.append(("enqueue", len(self.kinds)))
             self.kinds.append(stash["kind"])
             self.stashes.append(stash)
+            hook = self.at_enqueue.pop(len(self.kinds) - 1, None)
+            if hook is not None:
+                hook()
             return stash
 
         def fetched(dev):
@@ -694,14 +686,47 @@ class _Dispatches:
                 for n in range(1, len(self.kinds)) if self.behind(n)}
 
 
+def test_a_request_that_arrives_during_a_dispatch_does_not_hold_the_chain(lm):
+    """A closed-loop caller's next request arrives while the scheduler
+    enqueues a chain's first dispatch (the round that carries the first
+    request's prompt), after the admission at the top of its pass.  Left
+    queued it would wait for the round's fetch and commit to take its
+    lane, and read meanwhile as queue pressure to the K policy.  The
+    decision before the fetch (``_chain_block``) admits it first, and a
+    prompt that waits makes the successor a round: dispatch 1 carries the
+    late prompt and the first lane's decode row from the carry, enqueued
+    before round 0 is fetched, and the block behind it takes both lanes."""
+    rng = np.random.default_rng(51)
+    pa = rng.integers(0, 64, (6,), np.int32)
+    pb = rng.integers(0, 64, (7,), np.int32)
+    ref_a, ref_b = _reference(lm, [(pa, 30, _sub(12)), (pb, 10, _sub(13))],
+                              lanes=1, max_len=128)
+    cb = _batcher(lm, 8, lanes=2, max_len=128)
+    spy = _Dispatches(cb)
+    late = []
+    try:
+        spy.at_enqueue[0] = lambda: late.append(
+            cb.submit(pb, 10, **_sub(13)))
+        fa = cb.submit(pa, 30, **_sub(12, on_token=lambda tok, i, lp: None))
+        _same(fa.result(timeout=120), ref_a)
+        _same(late[0].result(timeout=120), ref_b)
+    finally:
+        cb.shutdown()
+    assert spy.kinds[:3] == ["round", "round", "block"]
+    assert spy.behind(1) and spy.behind(2)
+    assert len(spy.stashes[1]["decodes"]) == len(spy.stashes[1]["firsts"]) == 1
+    assert len(spy.stashes[2]["lane_reqs"]) == 2
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
 def _beside(lm, first, second, at=5, reference=True, **kw):
-    """``first`` = (prompt, steps, submit options) streams on one lane of a
-    ragged engine of two (pages of 8, a round budget of 8); as its token
+    """``first`` = (prompt, steps, submit options) streams on one lane of an
+    engine of two (pages of 8, a round budget of 8); as its token
     ``at`` is emitted, from the scheduler's own thread, ``second`` is
     submitted: its prompt comes in beside the running chain.  Returns
     ``(cb, spy, [first's result, second's result], tokens by dispatch)``;
     the caller shuts ``cb`` down."""
-    cb = _batcher(lm, 8, lanes=2, max_len=160, ragged=True, prefill_chunk=8,
+    cb = _batcher(lm, 8, lanes=2, max_len=160, prefill_chunk=8,
                   **kw)
     spy = _Dispatches(cb)
     futs, emitted_by = [], {}
@@ -732,7 +757,7 @@ def test_rounds_and_blocks_chain_through_one_carry(lm, sampled):
     pb = rng.integers(0, 64, (30,), np.int32)
     sub = (lambda s: _sub(s)) if sampled else (lambda s: {"logprobs": True})
     ref_a, ref_b = _reference(lm, [(pa, 40, sub(21)), (pb, 12, sub(22))],
-                              lanes=1, max_len=160, ragged=True)
+                              lanes=1, max_len=160)
     cb, spy, futs, _by = _beside(lm, (pa, 40, sub(21)), (pb, 12, sub(22)))
     try:
         got_a = futs[0].result(timeout=120)
@@ -776,7 +801,7 @@ def test_stop_token_inside_an_unfetched_round(lm):
     pc = rng.integers(0, 64, (9,), np.int32)
     ref_a, ref_b, ref_c = _reference(
         lm, [(pa, 60, _sub(5)), (pb, 6, _sub(6)), (pc, 10, _sub(7))],
-        lanes=1, max_len=160, ragged=True)
+        lanes=1, max_len=160)
     # tokens 9 .. 18 of the first stream come out of the second prompt's
     # rounds, one a round: stop on one first seen there
     toks = list(ref_a[0])
@@ -805,6 +830,43 @@ def test_stop_token_inside_an_unfetched_round(lm):
     assert cb.pool.free_pages == cb.pool.n_pages - 1
 
 
+@pytest.mark.parametrize("publish", [False, True],
+                         ids=["kv_publish-off", "kv_publish-on"])
+def test_a_first_prompts_end_breaks_the_chain_only_where_it_publishes(
+        lm, publish):
+    """With ``kv_publish`` the round in which a first prompt ends is
+    fetched before anything goes behind it (the snapshot is a gather on
+    the pages as that round left them), counted under the cause ``host``.
+    Without it (every cell) that round takes no such branch: two greedy
+    lanes, the second prompt arriving while the first is held at a token,
+    add nothing to ``host``, and each prompt's last round has its
+    successor enqueued before its fetch."""
+    rng = np.random.default_rng(73)
+    pa = rng.integers(0, 64, (6,), np.int32)
+    pb = rng.integers(0, 64, (7,), np.int32)
+    cb = _batcher(lm, 8, lanes=2, max_len=128, kv_offload=32 << 20,
+                  kv_publish=publish)
+    spy = _Dispatches(cb)
+    try:
+        held = TokenGate(4)
+        fa = cb.submit(pa, 30, on_token=held)
+        assert held.wait(timeout=60)
+        fb = cb.submit(pb, 10)
+        held.release()
+        assert len(fa.result(timeout=120)) == 30
+        assert len(fb.result(timeout=120)) == 10
+        breaks = cb.debug_state()["dispatch"]["chain"]["breaks"]
+    finally:
+        cb.shutdown()
+    ends = [n for n, stash in enumerate(spy.stashes)
+            if stash["kind"] == "round" and stash["firsts"]]
+    assert len(ends) == 2
+    assert breaks["host"] == (2 if publish else 0)
+    assert [spy.behind(n + 1) for n in ends] == [not publish] * 2
+    assert cb.kv_publishes == (2 if publish else 0)
+    assert cb.pool.free_pages == cb.pool.n_pages - 1
+
+
 def _host_sampled(seed):
     return dict(sampling=SamplingParams(temperature=0.8, top_k=4, seed=seed))
 
@@ -824,7 +886,7 @@ def test_a_round_s_chain_breaks_with_its_cause(lm, cause):
     options = (lambda: _host_sampled(3)) if cause == "host" else (
         lambda: _sub(5))
     ref_a, ref_b = _reference(lm, [(pa, steps, options()), (pb, 6, _sub(6))],
-                              lanes=1, max_len=160, ragged=True)
+                              lanes=1, max_len=160)
     sub_a = options()
     streamed = []
     if cause == "released":

@@ -115,7 +115,8 @@ def test_shipper_rejects_mismatched_geometry(lm):
 
 def test_handoff_zero_prefill_dispatches_token_parity(lm):
     """The acceptance contract: a prefill-replica -> decode-replica
-    handoff admits with ZERO prefill dispatches on the decode replica
+    handoff admits with ZERO prefill dispatches on the decode replica (no
+    mixed round: a prompt's rows are computed in one and nowhere else)
     and the stream is bit-identical to a unified-replica run."""
     prompt = np.random.default_rng(2).integers(0, 64, (13,), np.int32)
     ref = _batcher(lm)
@@ -128,8 +129,8 @@ def test_handoff_zero_prefill_dispatches_token_parity(lm):
     try:
         got, in_sh = _handoff(bp, bd, prompt, 8, sampling=_sampling())
         assert got == want
-        assert bd.prefill_dispatches == 0          # the headline
-        assert bp.prefill_dispatches == 1
+        assert bd.dispatch_kinds["mixed"] == 0          # the headline
+        assert bp.dispatch_kinds["mixed"] == 1
         assert in_sh.imports == 1 and in_sh.import_failures == 0
         assert bd.kv_offload.swap_ins == 1         # admitted via restore
     finally:
@@ -155,7 +156,7 @@ def test_handoff_greedy_parity_and_multi_request(lm):
         for p, want in zip(prompts, wants):
             got, _ = _handoff(bp, bd, p, 6)
             assert got == want
-        assert bd.prefill_dispatches == 0
+        assert bd.dispatch_kinds["mixed"] == 0
     finally:
         bp.shutdown()
         bd.shutdown()
@@ -180,7 +181,7 @@ def test_chaos_tripped_shipment_degrades_to_local_prefill(lm, spec):
             got, _ = _handoff(bp, bd, prompt, 6, sampling=_sampling())
             assert sched.fired("disagg.ship") == 1
         assert got == want
-        assert bd.prefill_dispatches == 1   # the local-prefill fallback
+        assert bd.dispatch_kinds["mixed"] == 1   # the local-prefill fallback
         assert bd.kv_offload.swap_ins == 0
     finally:
         bp.shutdown()
@@ -211,7 +212,7 @@ def test_corrupt_shipment_degrades_to_local_prefill(lm):
                               corrupt=flip)
         assert got == want
         assert in_sh.import_failures == 1
-        assert bd.prefill_dispatches == 1
+        assert bd.dispatch_kinds["mixed"] == 1
     finally:
         bp.shutdown()
         bd.shutdown()
@@ -296,8 +297,8 @@ def test_replicaset_disagg_routing_end_to_end(lm):
         got = list(rs.generate(prompt, 7, temperature=0.8,
                                device_sampling=True, seed=1234))
         assert got == want
-        assert cbd.prefill_dispatches == 0       # shipped admit only
-        assert cbp.prefill_dispatches == 1
+        assert cbd.dispatch_kinds["mixed"] == 0       # shipped admit only
+        assert cbp.dispatch_kinds["mixed"] == 1
         assert rs.disagg_handoffs == 1 and rs.disagg_fallbacks == 0
 
         # chaos: the export trips server-side -> no shipment ships; the
@@ -307,7 +308,7 @@ def test_replicaset_disagg_routing_end_to_end(lm):
                                     device_sampling=True, seed=1234))
             assert sched.fired("disagg.ship") == 1
         assert got2 == want
-        assert cbd.prefill_dispatches == 1       # the local fallback ran
+        assert cbd.dispatch_kinds["mixed"] == 1       # the local fallback ran
         assert rs.disagg_handoffs == 2           # still a two-hop serve
     finally:
         if rs is not None:
@@ -366,10 +367,10 @@ def test_disagg_prefill_side_affinity_keeps_prompt_kv_home(lm):
             assert len(toks) == 5
         assert rs.disagg_handoffs == 4 and rs.disagg_fallbacks == 0
         # ALL prefills landed on the prefix's one home replica
-        counts = sorted([cbp1.prefill_dispatches,
-                         cbp2.prefill_dispatches])
+        counts = sorted([cbp1.dispatch_kinds["mixed"],
+                         cbp2.dispatch_kinds["mixed"]])
         assert counts == [0, 4], counts
-        assert cbd.prefill_dispatches == 0   # decode stayed shipped-only
+        assert cbd.dispatch_kinds["mixed"] == 0   # decode stayed shipped-only
     finally:
         if rs is not None:
             rs.close()

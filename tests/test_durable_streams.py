@@ -102,7 +102,7 @@ def _set(pair, **kw):
 
 
 def _snap(cb):
-    return (cb.tokens_generated, cb.prefill_dispatches)
+    return (cb.tokens_generated, cb.dispatch_kinds["mixed"])
 
 
 # ------------------------------------------------ resume bit-exactness ----

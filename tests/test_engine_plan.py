@@ -50,7 +50,7 @@ KINDS = {
     "evabyte-eva": (
         specs.evabyte_spec(test_evabyte.CONFIG), test_evabyte.VOCAB,
         test_evabyte.D_FF, dict(lanes=3, max_len=160, page_size=4,
-                                ragged=True, prefill_chunk=12)),
+                                prefill_chunk=12)),
 }
 
 
@@ -62,11 +62,11 @@ def _plan(spec=None, **kw):
                   d_model=spec.d_model))
     return plan_engine(spec=spec, **{**dict(
         n_kv_heads=None, rope_theta=None, vocab=64, lanes=4, max_len=256,
-        page_size=16, prefill_chunk=None, use_kernel=None, ragged=None,
-        prefill_flash=None, compute_dtype=jnp.float32, kv_dtype=None,
+        page_size=16, prefill_chunk=None, use_kernel=None,
+        compute_dtype=jnp.float32, kv_dtype=None,
         round_ceiling=ContinuousBatcher.RAGGED_CHUNK_CAP,
-        kernel_auto_min_ctx=ContinuousBatcher.KERNEL_AUTO_MIN_CTX,
-        verify_width=ContinuousBatcher.BLOCK_K_MENU[-1] + 1), **shape, **kw})
+        kernel_auto_min_ctx=ContinuousBatcher.KERNEL_AUTO_MIN_CTX),
+        **shape, **kw})
 
 
 @pytest.fixture
@@ -86,7 +86,7 @@ def nothing_allocated(monkeypatch):
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_the_plan_is_what_a_constructed_engine_reports(kind, use_kernel):
     """The plan of each kind of model, made from the arguments alone, and
-    the engine those arguments build: the dispatch plan, the page tables'
+    the engine those arguments build: the attention's form, the page tables'
     width, the round's budget, and a page store of the layers, entry kind
     and bytes a token the plan's fields give."""
     spec, vocab, d_ff, kw = KINDS[kind]
@@ -116,12 +116,11 @@ def test_the_plan_is_what_a_constructed_engine_reports(kind, use_kernel):
         assert tile["one_row"]["heads"] == heads
         assert tile["one_row"]["walks"] == 1
         assert tile["round"]["heads"] * tile["round"]["walks"] == heads
-    assert (cb.max_pages, cb.RAGGED_CHUNK_CAP, cb.use_kernel, cb.ragged,
+    assert (cb.max_pages, cb.RAGGED_CHUNK_CAP, cb.use_kernel,
             cb.round_budget_why, cb.prefill_chunk, cb.vocab) == (
-        plan.max_pages, plan.round_cap, plan.use_kernel, plan.ragged,
+        plan.max_pages, plan.round_cap, plan.use_kernel,
         plan.round_budget_why, plan.prefill_chunk, vocab)
     assert plan.use_kernel == use_kernel
-    assert plan.ragged == (use_kernel or spec is not None)
     assert cb.pool.n_layers == plan.pool_layers
     assert pool["n_pages"] == plan.max_pages * plan.lanes + 1
     assert pool["entry_kind"] == ("latent" if plan.latent else
@@ -137,7 +136,6 @@ def test_the_plan_is_what_a_constructed_engine_reports(kind, use_kernel):
 
 
 REFUSED = [
-    ("ragged=False", dict(ragged=False)),
     ("draft_params", dict(draft_params={"layer0": {}})),
     ("mesh", dict(mesh=object())),
     ("kv_offload", dict(kv_offload=True)),
@@ -152,19 +150,33 @@ REFUSED = [
 @pytest.mark.parametrize("name,option", REFUSED, ids=[n for n, _ in REFUSED])
 def test_each_refused_option_is_refused_by_name(nothing_allocated, name,
                                                 option):
-    """The eight options the ragged plan, another cache entry or a lane
-    state does not carry, each refused for a kind of model in the words the
+    """The seven options another cache entry or a lane state does not
+    carry, each refused for a kind of model in the words the
     constructor raised, and only the one that is set."""
     spec = KINDS["jamba-mamba"][0]
     with pytest.raises(NotImplementedError) as e:
         _plan(spec, **option)
     said = str(e.value)
-    assert said.startswith("a model with kv pages, mamba layers is served "
-                           "on the ragged plan only; not supported with it: ")
+    assert said.startswith("a model with kv pages, mamba layers is not "
+                           "supported with: ")
     assert [n for n, _ in REFUSED if n in said.partition(": ")[2]] == [name]
     # a dense model takes each of them (a mesh and an arbiter: not both)
     if name not in ("mesh", "draft_params"):
         assert _plan(**option).state_kind is None
+
+
+@pytest.mark.parametrize("name", ["ragged", "prefill_flash", "verify_width"])
+def test_the_plan_takes_no_option_that_chose_a_plan(nothing_allocated, name):
+    """There is one dispatch plan: neither ``plan_engine`` nor the
+    scheduler's constructor takes ``ragged`` or ``prefill_flash`` (nor the
+    plan the K + 1 verify width the other plan's geometry check read), and
+    ``EnginePlan`` has no such field."""
+    import dataclasses
+    with pytest.raises(TypeError, match=name):
+        _plan(**{name: None})
+    with pytest.raises(TypeError, match=name):
+        ContinuousBatcher({}, 2, 2, **{name: None})
+    assert name not in {f.name for f in dataclasses.fields(EnginePlan)}
 
 
 def _pool(**facts):
@@ -218,9 +230,6 @@ ERRORS = {
         lambda: _plan(mesh=_mesh(), hbm=object()),
         NotImplementedError, "HBM-arbiter-armed serving .* under a mesh is "
                              "not supported"),
-    "flash-prefill-under-a-mesh": (
-        lambda: _plan(mesh=_mesh(), prefill_flash=True),
-        ValueError, "the pallas flash prefill kernel is single-device"),
     "heads-that-do-not-divide-the-shards": (
         lambda: _plan(n_heads=3, d_model=48, mesh=_mesh(), use_kernel=True),
         ValueError, r"use_kernel under a mesh needs query heads \(3\) "
@@ -266,7 +275,7 @@ def test_the_budget_is_the_widest_round_the_geometry_rule_admits(
     plan = _geometry_plan(**kw)
     chunk = kw.get("prefill_chunk")
     cap = 512 if chunk and round_width(chunk) <= admits else admits
-    assert (plan.round_cap, plan.use_kernel, plan.ragged) == (cap, True, True)
+    assert (plan.round_cap, plan.use_kernel) == (cap, True)
     assert min(plan.prefill_chunk or cap, cap) == budget
     if cap == 512:
         assert plan.round_budget_why is None
@@ -284,8 +293,7 @@ def test_twice_the_budget_fits_max_len(nothing_allocated, max_len, window,
     program is reached by one prompt that spends the budget and leaves a
     tail), and with EVA windows at most a window, where a chunk ends."""
     plan = (_plan(KINDS["evabyte-eva"][0], max_len=max_len, page_size=4)
-            if window else _plan(max_len=max_len, ragged=True,
-                                 use_kernel=False))
+            if window else _plan(max_len=max_len, use_kernel=False))
     assert plan.round_cap == budget and plan.eva_window == window
     assert (plan.round_budget_why is None) == (budget == 512)
     if budget < 512:
@@ -309,8 +317,7 @@ def test_a_geometry_that_admits_no_round_is_refused_before_anything_is_built(
         _geometry_plan()
     monkeypatch.setattr(platform, "is_tpu", lambda: True)
     auto = _geometry_plan(use_kernel=None, max_len=8192)
-    assert (auto.use_kernel, auto.ragged, auto.prefill_flash) == (
-        False, False, True)
+    assert auto.use_kernel is False
 
 
 def test_a_width_the_rule_refuses_keeps_the_xla_rule_in_both_programs(

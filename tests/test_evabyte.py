@@ -67,7 +67,7 @@ def _engine(model, use_kernel=False, **kw):
     spec, params = model
     kw = dict(dict(lanes=LANES, max_len=160, page_size=CHUNK,
                    compute_dtype=jnp.float32, use_kernel=use_kernel,
-                   ragged=True, prefill_chunk=12), **kw)
+                   prefill_chunk=12), **kw)
     return ContinuousBatcher(params, spec.n_heads, spec.n_layers, spec=spec,
                              **kw)
 
@@ -489,12 +489,12 @@ def test_preemption_starts_the_windows_over(model, reference):
 
 @pytest.mark.parametrize("option,value", [
     ("prefix_cache", True), ("kv_offload", True), ("kv_publish", True),
-    ("ragged", False), ("kv_dtype", jnp.float8_e4m3fn),
+    ("kv_dtype", jnp.float8_e4m3fn),
 ], ids=lambda v: v if isinstance(v, str) else "")
 def test_engine_refuses_by_name_what_does_not_carry_compacted_pages(
         model, option, value):
     with pytest.raises(NotImplementedError, match="EVA windows") as e:
-        _engine(model, **{"ragged": True, option: value})
+        _engine(model, **{option: value})
     assert option.split("_")[0] in str(e.value)
 
 

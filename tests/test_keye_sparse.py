@@ -523,7 +523,7 @@ def test_tiny_model_end_to_end_against_the_reference(model, reference,
     spec, params = model
     cb = _engine(spec, params, use_kernel=use_kernel)
     try:
-        assert cb.ragged and cb.use_kernel == use_kernel
+        assert cb.use_kernel == use_kernel
         pool = cb.debug_state()["pool"]
         assert pool["entry_kind"] == "kv_index"
         assert pool["index_bytes_per_token"] == 2 * 128 * 4
@@ -620,9 +620,9 @@ def test_a_preempted_request_resumes_with_a_fresh_engines_tokens(model):
     dict(prefix_cache=True), dict(kv_offload=True),
     dict(kv_offload=True, kv_publish=True), dict(hbm=object()),
     dict(draft_params={"layer0": {}}), dict(mesh=object()),
-    dict(ragged=False), dict(kv_dtype=jnp.float16)],
+    dict(kv_dtype=jnp.float16)],
     ids=["prefix_cache", "kv_offload", "kv_publish", "hbm", "draft_params",
-         "mesh", "ragged=False", "kv_dtype"])
+         "mesh", "kv_dtype"])
 def test_options_the_index_rows_do_not_carry_are_refused_by_name(
         model, option, request):
     spec, params = model

@@ -151,12 +151,12 @@ def _export(use_kernel):
     return held, blob
 
 
-@pytest.mark.parametrize("plan", ["legacy_gather", "ragged_kernel"])
-def test_exported_pages_keep_the_parents_bytes(plan):
+@pytest.mark.parametrize("attention", ["gather", "kernel"])
+def test_exported_pages_keep_the_parents_bytes(attention):
     """A page written through the engine leaves through
     ``kvcache/offload.py`` (the host tier's array) and ``disagg/wire.py``
     (header and shipment) exactly as it did at the parent commit."""
-    held, blob = _export(use_kernel=(plan == "ragged_kernel"))
+    held, blob = _export(use_kernel=(attention == "kernel"))
     assert list(held.shape) == GOLDEN["shape"]
     assert held.dtype == jnp.bfloat16
     # written where it was: K of layer 0, position 5 (page 1, slot 1)

@@ -187,8 +187,7 @@ def _engine(**kw):
     lm = init_transformer_params(vocab=64, d_model=32, n_heads=2,
                                  n_layers=2, d_ff=64)
     opts = dict(n_heads=2, n_layers=2, lanes=2, max_len=384, page_size=8,
-                n_pages=100, compute_dtype=jnp.float32, ragged=True,
-                use_kernel=False)
+                n_pages=100, compute_dtype=jnp.float32, use_kernel=False)
     opts.update(kw)
     return ContinuousBatcher(lm, **opts)
 

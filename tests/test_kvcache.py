@@ -150,8 +150,10 @@ def test_preempt_resume_no_reprefill_token_parity(lm):
         mgr = cb.kv_offload
         assert mgr.swap_outs >= 1 and mgr.swap_ins >= 1
         assert mgr.recompute_tokens_saved >= len(p_low)
-        # zero re-prefill: exactly one prefill dispatch per request
-        assert cb.prefill_dispatches == 2
+        # zero re-prefill: a round a prompt (each fits the budget), and
+        # no prompt row computed for the resume
+        assert cb.dispatch_kinds["mixed"] == 2
+        assert cb.mixed_prompt_tokens == len(p_low) + len(p_hi)
         np.testing.assert_array_equal(
             np.asarray(got_low), np.asarray(dense(p_low[None, :], 10)[0]))
         np.testing.assert_array_equal(
@@ -160,7 +162,7 @@ def test_preempt_resume_no_reprefill_token_parity(lm):
         # seeded-sampled victim: the swap restore must not perturb the
         # host PRNG stream either
         started2 = FirstTokenGate()
-        pf = cb.prefill_dispatches
+        pf = cb.dispatch_kinds["mixed"]
         f_s = cb.submit(p_low, 10,
                         sampling=SamplingParams(temperature=0.9, seed=123),
                         on_token=started2)
@@ -169,7 +171,7 @@ def test_preempt_resume_no_reprefill_token_parity(lm):
         started2.release()
         f_hi2.result(timeout=120)
         assert list(f_s.result(timeout=120)) == list(sampled_ref)
-        assert cb.prefill_dispatches == pf + 2    # still no re-prefill
+        assert cb.dispatch_kinds["mixed"] == pf + 2   # still no re-prefill
     finally:
         cb.shutdown()
     assert cb.pool.free_pages == cb.pool.n_pages - 1
@@ -239,7 +241,9 @@ def test_chaos_swap_degrades_to_recompute(lm, spec):
         assert cb.preemptions >= 1
         assert cb.kv_offload.swap_failures >= 1
         assert cb.kv_offload.swap_ins == 0    # the resume re-prefilled
-        assert cb.prefill_dispatches >= 3     # 2 prefills + >=1 re-prefill
+        # 2 prefills + >= 1 re-prefill, of the prompt and what was generated
+        assert cb.dispatch_kinds["mixed"] >= 3
+        assert cb.mixed_prompt_tokens > len(p_low) + len(p_hi)
         np.testing.assert_array_equal(
             np.asarray(got_low), np.asarray(dense(p_low[None, :], 10)[0]))
         np.testing.assert_array_equal(

@@ -13,7 +13,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from helpers_engine import wait_until
+from helpers_engine import TokenGate, wait_until
 from tpulab import chaos
 from tpulab.disagg import KVShipper, prompt_digest
 from tpulab.disagg.wire import deserialize_snapshot
@@ -276,7 +276,7 @@ def test_pull_degrades_on_miss_corruption_and_geometry(lm, owner):
         assert geo.pull(prompt, None, cbf16,
                         KVShipper(cbf16.kv_offload)) is None
         assert geo.snapshot()["degrades"] == 1
-        assert cbf.prefill_dispatches == 0         # nothing leaked a lane
+        assert cbf.dispatch_kinds["mixed"] == 0         # nothing leaked a lane
     finally:
         cbf.shutdown()
         cbf16.shutdown()
@@ -393,11 +393,134 @@ def test_pull_zero_prefill_dispatches_token_parity(lm):
                 sampling=sp).result(timeout=120))
             assert got == want                     # bit-exact, index 0 on
             assert got[0] == pulled.first_token
-        assert cbf.prefill_dispatches == 0         # the headline
-        assert cb_owner.prefill_dispatches == 2
+        assert cbf.dispatch_kinds["mixed"] == 0         # the headline
+        assert cb_owner.dispatch_kinds["mixed"] == 2
     finally:
         cb_owner.shutdown()
         cbf.shutdown()
+
+
+# -- the publish rides the round in which a first prompt ends -----------------
+
+def test_a_prompt_that_ends_in_a_round_beside_a_decoding_lane_publishes(lm):
+    """The publisher's prompt comes in beside a running stream, a chunk a
+    round (21 tokens at ``prefill_chunk=8``: three rounds, each with the
+    other lane's decode row); the round in which it ends publishes its
+    pages and its last row's logits.  A second engine fetches the digest
+    and continues with the tokens the publisher itself goes on to
+    generate, device-sampled, with no round of its own."""
+    rng = np.random.default_rng(41)
+    pa = rng.integers(0, 64, (5,), np.int32)
+    pb = rng.integers(0, 64, (21,), np.int32)
+    cb_owner = _batcher(lm, lanes=2, prefill_chunk=8)
+    cbf = _batcher(lm)
+    try:
+        held = TokenGate(3)
+        fa = cb_owner.submit(pa, 30, on_token=held)
+        assert held.wait(timeout=60)
+        fb = cb_owner.submit(pb, 8, sampling=_sampling())
+        held.release()
+        want = list(fb.result(timeout=120))
+        assert len(fa.result(timeout=120)) == 30
+        d = cb_owner.debug_state()["dispatch"]
+        # B's three rounds carried A's decode row, and each round that
+        # ended a first prompt (A's, B's) was fetched before its successor
+        assert d["kinds"]["mixed"] == 4 and d["mixed_decode_rows"] == 3
+        assert d["chain"]["breaks"]["host"] == 2
+        assert cb_owner.kv_publishes == 2
+        _wait_published(cb_owner, prompt_digest(pb))
+        pulled = _fabric(pb, _DirectClient(cb_owner)).pull(
+            pb, _sampling(), cbf, KVShipper(cbf.kv_offload))
+        assert pulled is not None and pulled.first_token == want[0]
+        got = list(cbf.submit_shipped(
+            pb, 8, pulled.first_token, pulled.handle,
+            sampling=_sampling()).result(timeout=120))
+        assert got == want
+        assert cbf.dispatch_kinds["mixed"] == 0 and cbf.kv_publishes == 0
+    finally:
+        cb_owner.shutdown()
+        cbf.shutdown()
+
+
+def test_the_snapshots_tail_page_holds_no_row_past_the_prompt(lm):
+    """The snapshot is a gather on the pool as the prompt's last round left
+    it: with ``kv_publish`` on nothing is enqueued behind that round
+    (``_chain_block``: cause ``host``), so the gather is ordered before
+    the first decode write.  A prompt of 13 tokens on pages of 8 in a new
+    pool (zeros): the tail page's rows 0-4 are the prompt's, rows 5-7
+    stay zero in the snapshot though the request went on to write them."""
+    prompt = np.random.default_rng(43).integers(0, 64, (13,), np.int32)
+    digest = prompt_digest(prompt)
+    cb = _batcher(lm)
+    try:
+        held = TokenGate(5)
+        fut = cb.submit(prompt, 12, on_token=held)
+        assert held.wait(timeout=60)
+        # a block of 8 decode steps is committed: rows 13 .. 20 are written
+        pages = list(cb._requests[fut].pages)
+        tail_now = np.asarray(cb.pool.kv)[:, pages[1]]
+        held.release()
+        assert len(fut.result(timeout=120)) == 12
+        _wait_published(cb, digest)
+        snap = cb.kv_offload.store.peek(("fab", digest))
+        breaks = cb.debug_state()["dispatch"]["chain"]["breaks"]
+    finally:
+        cb.shutdown()
+    assert snap.shape[:2] == (2, 2)            # layers, the prompt's pages
+    tail = snap[:, 1]                          # (layers, k / v, rows, width)
+    assert np.abs(tail[:, :, :5]).min(axis=-1).min() > 0
+    assert not tail[:, :, 5:].any()
+    assert np.abs(tail_now[:, :, 5:]).min(axis=-1).min() > 0
+    np.testing.assert_array_equal(tail_now[:, :, :5],
+                                  tail[:, :, :5].reshape(2, 2, 5, -1))
+    assert breaks["host"] == 1
+
+
+def test_a_resumed_request_does_not_publish(lm):
+    """A request whose first token was picked elsewhere (a shipment that
+    was lost: ``submit_shipped`` with no handle) computes its prompt here
+    in a round whose pick is discarded: it is a resume, and a resume
+    publishes nothing.  The same prompt submitted as a first prefill
+    does."""
+    prompt = np.random.default_rng(47).integers(0, 64, (13,), np.int32)
+    digest = prompt_digest(prompt)
+    ref, cb = _batcher(lm, kv_publish=False), _batcher(lm)
+    try:
+        want = list(ref.submit(prompt, 6).result(timeout=120))
+        got = list(cb.submit_shipped(prompt, 6, want[0], None).result(
+            timeout=120))
+        assert got == want
+        assert cb.dispatch_kinds["mixed"] == 1 and cb.kv_publishes == 0
+        assert cb.fab_handle(digest) is None
+        assert cb.chain_breaks["host"] == 0
+        assert list(cb.submit(prompt, 6).result(timeout=120)) == want
+        assert cb.kv_publishes == 1 and cb.fab_handle(digest) is not None
+    finally:
+        ref.shutdown()
+        cb.shutdown()
+
+
+def test_the_cost_gate_reads_a_prefill_rate_after_one_prompt(lm):
+    """``prefill_ewma_tok_s`` is updated where a first prompt's TTFT is
+    stamped, in the round: after one prompt the fabric's cost gate has a
+    recompute estimate to weigh a fetch against (it read 0.0 for ever on
+    the plan every cell runs), and a resume (no TTFT) leaves it alone."""
+    prompt = np.random.default_rng(49).integers(0, 64, (13,), np.int32)
+    cb = _batcher(lm, kv_publish=False)
+    try:
+        assert cb.prefill_ewma_tok_s == 0.0
+        first = cb.submit(prompt, 2).result(timeout=120)[0]
+        rate = cb.prefill_ewma_tok_s
+        assert rate > 0.0
+        cb.submit_shipped(prompt, 3, first, None).result(timeout=120)
+        assert cb.prefill_ewma_tok_s == rate
+        fab = _fabric(prompt, client=None)
+        fab.fetch_bytes_per_s = 1.0                 # 1 B/s: glacial wire
+        assert fab._gate_skips(len(prompt), cb)
+        fab.fetch_bytes_per_s = 1e15                # wire ~free
+        assert not fab._gate_skips(len(prompt), cb)
+    finally:
+        cb.shutdown()
 
 
 # -- metrics ------------------------------------------------------------------
@@ -472,7 +595,7 @@ def test_rpc_fleet_pull_end_to_end_and_owner_death(lm):
         got = list(GenerateStreamClient(clients[astray], "lm").generate(
             prompt, 8, temperature=0.8, device_sampling=True, seed=1234))
         assert got == want
-        assert cb_astray.prefill_dispatches == 0   # the acceptance bar
+        assert cb_astray.dispatch_kinds["mixed"] == 0   # the acceptance bar
         snap = fab_astray.snapshot()
         assert snap["pulls"] == 1 and snap["degrades"] == 0
         assert snap["recompute_tokens_saved"] == len(prompt)
@@ -490,7 +613,7 @@ def test_rpc_fleet_pull_end_to_end_and_owner_death(lm):
         got2 = list(GenerateStreamClient(clients[astray], "lm").generate(
             p2, 6, temperature=0.8, device_sampling=True, seed=77))
         assert len(got2) == 6                      # served, not stranded
-        assert cb_astray.prefill_dispatches == 1   # the local fallback ran
+        assert cb_astray.dispatch_kinds["mixed"] == 1   # the local fallback ran
         assert fab_astray.snapshot()["degrades"] == 1
     finally:
         for c in clients.values():

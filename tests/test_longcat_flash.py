@@ -459,7 +459,7 @@ def test_scheduler_rounds_blocks_and_readmission_against_the_reference(
     spec, published, served = model
     cb = _engine(spec, served, use_kernel=use_kernel)
     try:
-        assert cb.ragged and cb.use_kernel == use_kernel
+        assert cb.use_kernel == use_kernel
         assert cb.pool.entry_kind == "latent" and cb.pool.n_layers == 4
         assert cb.pool.bytes_per_token == 4 * 128 * 4
         rng = np.random.default_rng(0)
@@ -474,7 +474,6 @@ def test_scheduler_rounds_blocks_and_readmission_against_the_reference(
         state = cb.debug_state()
         assert state["dispatch"]["kinds"]["mixed"] >= 5
         assert state["dispatch"]["kinds"]["decode"] > 0
-        assert state["dispatch"]["prefill_dispatches"] == 0
         got = state["moe"]
         assert got["expert_layers"] == [0, 2]
         assert (got["zero_first"], got["zero_columns"]) == (8, 4)
@@ -554,8 +553,7 @@ def test_a_share_of_the_experts_through_the_scheduler(model, reference):
 @pytest.mark.parametrize("name, kwargs", [
     ("mesh", {"mesh": object()}),
     ("prefix_cache", {"prefix_cache": True}),
-    ("kv_dtype", {"kv_dtype": jnp.bfloat16}),
-    ("ragged=False", {"ragged": False})])
+    ("kv_dtype", {"kv_dtype": jnp.bfloat16})])
 def test_options_the_latent_cache_does_not_carry_are_refused_by_name(
         model, name, kwargs):
     spec, _published, served = model

@@ -153,9 +153,8 @@ def test_parameter_and_cache_byte_counts_are_the_issues(monkeypatch):
     plan = plan_engine(
         spec=sp, n_heads=32, n_layers=8, n_kv_heads=4, rope_theta=None,
         d_model=2304, vocab=98304, lanes=32, max_len=32768, page_size=16,
-        prefill_chunk=None, use_kernel=True, ragged=None, prefill_flash=None,
-        compute_dtype=jnp.bfloat16, kv_dtype=None, round_ceiling=512,
-        kernel_auto_min_ctx=2048, verify_width=17)
+        prefill_chunk=None, use_kernel=True, compute_dtype=jnp.bfloat16,
+        kv_dtype=None, round_ceiling=512, kernel_auto_min_ctx=2048)
     assert (plan.pool_layers, plan.window_layers, plan.window) == (2, 6, 1024)
     assert plan.max_pages == 2048 and plan.round_cap == 512
     # the window group a lane: the 256-row blocks that overlap 1,024 keys
@@ -451,7 +450,7 @@ def test_scheduler_under_across_and_past_the_window_against_the_reference(
         moved(req, lo, hi),
         held.append((len(req.wpages), req.wfirst, lo, hi)))[0]
     try:
-        assert cb.ragged and cb.use_kernel == use_kernel
+        assert cb.use_kernel == use_kernel
         assert (cb.pool.n_layers, cb.wpool.n_layers) == (1, 3)
         assert cb._walk_pages * PAGE == BLOCK_ROWS
         # 24 keys and a round of 64 overlap 3 blocks of 32 at most, + 1
@@ -468,7 +467,7 @@ def test_scheduler_under_across_and_past_the_window_against_the_reference(
         state = cb.debug_state()
         d, groups = state["dispatch"], state["pool"]["groups"]
         assert d["kinds"]["mixed"] >= 4 and d["kinds"]["decode"] > 0
-        assert d["prefill_dispatches"] == 0 and d["preemptions"] == 0
+        assert d["preemptions"] == 0
         # the bound: never more than the lane's share, and always the blocks
         # that overlap (lo - window, hi]
         for n, first, lo, hi in held:
@@ -681,10 +680,10 @@ def test_a_preempted_request_prefills_again_to_a_fresh_engines_tokens(model):
 @pytest.mark.parametrize("option", [
     dict(prefix_cache=True), dict(kv_offload=True),
     dict(kv_offload=True, kv_publish=True), dict(hbm=object()),
-    dict(draft_params={}), dict(mesh=object()), dict(ragged=False),
+    dict(draft_params={}), dict(mesh=object()),
     dict(kv_dtype=jnp.float8_e4m3fn)],
     ids=["prefix_cache", "kv_offload", "kv_publish", "hbm", "draft_params",
-         "mesh", "ragged=False", "kv_dtype"])
+         "mesh", "kv_dtype"])
 def test_options_two_page_groups_do_not_carry_are_refused_by_name(
         model, option, request):
     """What hangs on "a request's pages hold its positions" (the prefix

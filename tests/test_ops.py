@@ -6,65 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpulab.models.transformer import dense_attention
-from tpulab.ops import flash_attention, make_flash_attention_fn
-
-
-def _qkv(b=2, t=256, h=2, d=64, seed=0, dtype=jnp.float32):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    return tuple(jax.random.normal(k, (b, t, h, d), dtype) for k in ks)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_matches_dense(causal):
-    q, k, v = _qkv()
-    want = dense_attention(q, k, v, causal=causal)
-    got = flash_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_flash_small_seq_blocks_clamp():
-    q, k, v = _qkv(t=64)
-    want = dense_attention(q, k, v, causal=True)
-    got = flash_attention(q, k, v, causal=True)  # block sizes clamp to 64
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_flash_uneven_blocks_rejected():
-    q, k, v = _qkv(t=96)
-    with pytest.raises(ValueError, match="divide"):
-        flash_attention(q, k, v, block_q=64, block_k=64)
-
-
-def test_flash_bf16_io():
-    q, k, v = _qkv(dtype=jnp.bfloat16, t=128)
-    want = dense_attention(q, k, v, causal=True)
-    got = flash_attention(q, k, v, causal=True)
-    assert got.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=3e-2, atol=3e-2)
-
-
-def test_flash_in_transformer():
-    from functools import partial
-    from tpulab.models.transformer import (init_transformer_params,
-                                           transformer_apply)
-    params = init_transformer_params(vocab=64, d_model=32, n_heads=2,
-                                     n_layers=2, d_ff=64)
-    ref = partial(transformer_apply, n_heads=2, n_layers=2,
-                  compute_dtype=jnp.float32)
-    fla = partial(transformer_apply, n_heads=2, n_layers=2,
-                  compute_dtype=jnp.float32,
-                  attention_fn=make_flash_attention_fn(block_q=64, block_k=64))
-    tokens = np.random.default_rng(0).integers(0, 64, (2, 128), np.int32)
-    want = ref(params, {"tokens": tokens})["logits"]
-    got = fla(params, {"tokens": tokens})["logits"]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-4)
-
 
 # ------------------------------------- the ragged kernel, one query a lane --
 def _paged_reference(q, k_pool, v_pool, tables, lengths):

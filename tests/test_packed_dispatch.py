@@ -149,9 +149,8 @@ MODELS = {"dense": _dense, "experts": _experts, "lane-state": _lane_state,
 
 def _engine(spec, params, **kw):
     heads = (2, 1) if spec is None else (spec.n_heads, spec.n_layers)
-    kw = dict(dict(lanes=3, max_len=96, page_size=8, ragged=True,
-                   use_kernel=False, compute_dtype=jnp.float32,
-                   prefill_chunk=8), **kw)
+    kw = dict(dict(lanes=3, max_len=96, page_size=8, use_kernel=False,
+                   compute_dtype=jnp.float32, prefill_chunk=8), **kw)
     return ContinuousBatcher(params, *heads, spec=spec, **kw)
 
 

@@ -24,7 +24,7 @@ import test_evabyte
 import test_jamba
 import test_keye_sparse
 import test_qwen3_next
-from helpers_engine import TokenGate
+from helpers_engine import TokenGate, greedy_reference
 from helpers_steps import mixed_step
 from test_glm_moe import CONFIG, D_FF, VOCAB
 from tpulab.engine import paged_steps
@@ -393,10 +393,13 @@ def test_a_prompt_that_ends_on_its_window_is_held_for_its_compaction():
 
 
 # -- the scheduler half ---------------------------------------------------------
+def _params():
+    return init_transformer_params(vocab=64, d_model=32, n_heads=2,
+                                   n_layers=1, d_ff=64)
+
+
 def _engine(lanes, max_len=512, rope_theta=10000.0, **kw):
-    params = init_transformer_params(vocab=64, d_model=32, n_heads=2,
-                                     n_layers=1, d_ff=64)
-    return ContinuousBatcher(params, n_heads=2, n_layers=1, lanes=lanes,
+    return ContinuousBatcher(_params(), n_heads=2, n_layers=1, lanes=lanes,
                              max_len=max_len, page_size=8,
                              compute_dtype=jnp.float32,
                              rope_theta=rope_theta, **kw)
@@ -429,7 +432,7 @@ def _spy_rounds(cb):
 
 
 def test_a_round_shares_one_token_budget_oldest_first():
-    cb = _engine(lanes=4, max_len=1024, ragged=True, use_kernel=False)
+    cb = _engine(lanes=4, max_len=1024, use_kernel=False)
     rounds = _spy_rounds(cb)
     budget = cb.RAGGED_CHUNK_CAP
     assert budget == cb.debug_state()["dispatch"]["round_budget"] == 512
@@ -465,7 +468,7 @@ def test_a_round_shares_one_token_budget_oldest_first():
 
 
 def test_prefill_chunk_lowers_the_budget_for_all_lanes_together():
-    cb = _engine(lanes=3, ragged=True, use_kernel=False, prefill_chunk=16)
+    cb = _engine(lanes=3, use_kernel=False, prefill_chunk=16)
     rounds = _spy_rounds(cb)
     rng = np.random.default_rng(4)
     try:
@@ -483,10 +486,11 @@ def test_prefill_chunk_lowers_the_budget_for_all_lanes_together():
 
 @pytest.mark.parametrize("use_kernel", [False, True],
                          ids=["gather", "kernel"])
-def test_seeded_mixed_workload_streams_equal_the_split_plans(use_kernel):
-    """Greedy streams of staggered prompts, short and long, under the
-    ragged plan against the legacy split plan, which this change does not
-    touch: the parent's tokens."""
+def test_seeded_mixed_workload_streams_equal_the_plain_forwards(use_kernel):
+    """Greedy streams of staggered prompts, short and long, in rounds they
+    share against a reference that shares no code with the engine: the
+    plain forward over each whole sequence, a token at a time
+    (``helpers_engine.greedy_reference``)."""
     rng = np.random.default_rng(11)
     lens = [70, 5, 33, 130, 17, 9, 64, 1]
     prompts = [rng.integers(0, 64, n) for n in lens]
@@ -502,9 +506,11 @@ def test_seeded_mixed_workload_streams_equal_the_split_plans(use_kernel):
             return [list(f.result(timeout=120)) for f in futs], cb
         finally:
             cb.shutdown()
-    want, _ = run(ragged=False, use_kernel=False)
+    want = [greedy_reference(_params(), p, 6 + i % 4, 2, 1,
+                             rope_theta=10000.0)[0]
+            for i, p in enumerate(prompts)]
     # a budget of 32 makes the long prompts share rounds with the short
-    got, cb = run(ragged=True, use_kernel=use_kernel, prefill_chunk=32)
+    got, cb = run(use_kernel=use_kernel, prefill_chunk=32)
     assert got == want
     assert cb.dispatch_kinds["mixed"] >= -(-sum(lens) // 32)
     assert 0 < cb.mixed_tokens <= cb.mixed_rows
@@ -547,7 +553,7 @@ def _family_engine(family, spec, params, **kw):
                      prefill_chunk=12) if family == "eva"
                 else dict(max_len=96, page_size=8, prefill_chunk=8))
     return ContinuousBatcher(params, *heads, spec=spec, lanes=3,
-                             compute_dtype=jnp.float32, ragged=True,
+                             compute_dtype=jnp.float32,
                              use_kernel=False, **dict(geometry, **kw))
 
 
@@ -606,7 +612,7 @@ def test_mixed_attn_rows_counts_m_a_chunk_lane_and_one_a_decode_lane():
     attention calls computed a layer: the round's width for each lane that
     held a chunk, one row for each decoding lane, nothing for an idle lane
     (a single call computed ``lanes x M``)."""
-    cb = _engine(lanes=4, max_len=256, ragged=True, use_kernel=False,
+    cb = _engine(lanes=4, max_len=256, use_kernel=False,
                  prefill_chunk=32)
     rounds = _spy_rounds(cb)
     rng = np.random.default_rng(13)
@@ -648,7 +654,7 @@ def test_every_mixed_program_is_reached_by_a_single_prompt():
     256), and a burst of concurrent prompts adds none."""
     # a rope_theta of its own: the jitted program is shared by engines of
     # one geometry, and this test counts its cache
-    cb = _engine(lanes=4, max_len=1024, rope_theta=29.0, ragged=True,
+    cb = _engine(lanes=4, max_len=1024, rope_theta=29.0,
                  use_kernel=False)
     cap = cb.RAGGED_CHUNK_CAP
     tails = [1 << i for i in range(cap.bit_length())]
@@ -679,30 +685,26 @@ def test_every_mixed_program_is_reached_by_a_single_prompt():
     ids=["ceiling-gather", "ceiling-kernel", "prefill_chunk-64"])
 def test_a_long_prompt_takes_whole_budgets_then_its_tail(kw):
     """A prompt of ``2 x budget + 40`` is three rounds of ``budget, budget,
-    40`` tokens, and its greedy stream is the legacy split plan's."""
+    40`` tokens, and its greedy stream is the plain forward's over the
+    whole sequence (``helpers_engine.greedy_reference``: no code of the
+    engine's)."""
     rng = np.random.default_rng(17)
-
-    def run(prompt_of, **kw):
-        cb = _engine(lanes=2, max_len=1200, **kw)
-        rounds = _spy_rounds(cb) if cb.ragged else None
-        budget = cb._round_budget
-        try:
-            prompt = prompt_of(budget)
-            toks = list(cb.submit(prompt, steps=5).result(timeout=300))
-            return prompt, toks, rounds, cb.debug_state()["dispatch"]
-        finally:
-            cb.shutdown()
-    prompt, got, rounds, state = run(
-        lambda budget: rng.integers(0, 64, 2 * budget + 40), ragged=True,
-        **kw)
+    cb = _engine(lanes=2, max_len=1200, **kw)
+    rounds = _spy_rounds(cb)
+    try:
+        prompt = rng.integers(0, 64, 2 * cb._round_budget + 40)
+        got = list(cb.submit(prompt, steps=5).result(timeout=300))
+        state = cb.debug_state()["dispatch"]
+    finally:
+        cb.shutdown()
     budget = state["round_budget"]
     assert budget == kw.get("prefill_chunk", 512)
     assert [r["prefill"] for r in rounds] == [budget, budget, 40]
     assert [r["width"] for r in rounds] == [budget, budget, 64]
     assert (state["budget_rounds"], state["mixed_prompt_tokens"]) == (
         2, len(prompt))
-    _, want, _, _ = run(lambda _b: prompt, ragged=False, use_kernel=False)
-    assert got == want
+    assert got == greedy_reference(_params(), prompt, 5, 2, 1,
+                                   rope_theta=10000.0)[0]
 
 
 def _geometry_engine(**kw):
@@ -741,7 +743,7 @@ def test_the_budget_is_the_widest_round_the_geometry_rule_admits(
     assert cb.RAGGED_CHUNK_CAP == cap
     assert ContinuousBatcher.RAGGED_CHUNK_CAP == 512      # the ceiling
     assert state["round_budget"] == cb._round_budget == budget
-    assert state["use_kernel"] and state["ragged"]
+    assert state["use_kernel"]
     if cap == 512:
         assert state["round_budget_why"] is None
     else:
@@ -756,7 +758,7 @@ def test_twice_the_budget_fits_max_len(max_len, budget):
     budget and leaves a tail of that width (``perf/models/lm.py`` warms
     them so): an engine whose ``max_len`` does not hold twice the ceiling
     takes the widest power of two it does hold twice, and says so."""
-    cb = _engine(lanes=2, max_len=max_len, ragged=True, use_kernel=False)
+    cb = _engine(lanes=2, max_len=max_len, use_kernel=False)
     try:
         state = cb.debug_state()["dispatch"]
     finally:
@@ -793,7 +795,7 @@ def test_budget_counters_count_what_the_spy_sees(chunk):
     ``mixed_prompt_tokens`` / ``budget_rounds`` the prompt tokens the rounds
     carried (``mixed_tokens`` less the decode rows) and the rounds that
     spent the whole budget."""
-    cb = _engine(lanes=3, max_len=1400, ragged=True, use_kernel=False,
+    cb = _engine(lanes=3, max_len=1400, use_kernel=False,
                  prefill_chunk=chunk)
     rounds = _spy_rounds(cb)
     budget = cb._round_budget

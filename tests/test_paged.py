@@ -621,47 +621,54 @@ def test_scheduler_churn_soak(lm):
     assert cb.pool.free_pages == cb.pool.n_pages - 1
 
 
-def test_prefill_flash_matches_dense(lm):
-    """prefill_flash=True routes the FULL-PROMPT forward through the
-    pallas flash kernel (interpret off-TPU); generated tokens must equal
-    the dense-causal prefill across bucket sizes.  (Prefix-cache tails
-    and chunked prefills use paged_extend's gather attention either way —
-    flash covers only the start==0 un-chunked forward.)"""
-    outs = {}
-    for flash in (False, True):
-        cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=2,
-                               max_len=64, page_size=8,
-                               compute_dtype=jnp.float32,
-                               prefill_flash=flash, prefix_cache=True)
-        try:
-            rng = np.random.default_rng(17)
-            prompts = [rng.integers(0, 64, (n,), np.int32)
-                       for n in (1, 5, 16, 33)]
-            outs[flash] = [list(cb.submit(p, 5).result(timeout=120))
-                           for p in prompts]
-        finally:
-            cb.shutdown()
-    assert outs[True] == outs[False]
-
-
-def test_prefill_flash_compile_failure_is_an_error(lm):
-    """A Mosaic refusal of the flash prefill is an error the requester
-    sees — the batcher never swaps in the dense path behind its back —
-    and serving goes on for the next request."""
-    cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=1, max_len=32,
+def test_an_engine_at_the_defaults_serves_prompts_in_rounds(lm):
+    """There is one dispatch plan.  An engine built with the constructor's
+    defaults on the CPU takes the XLA gather (``use_kernel`` chooses the
+    attention, never the plan) and every prompt rides mixed rounds: the
+    counter of rounds moves, and tokens equal the dense engine's across the
+    rounds' widths (a one-token prompt pads to two rows; 33 tokens with the
+    prefix cache on take two rounds the second time: the shared pages'
+    positions are not computed again)."""
+    dense = make_generate_fn(lm, n_heads=2, n_layers=2, max_len=64,
+                             compute_dtype=jnp.float32)
+    cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=2, max_len=64,
                            page_size=8, compute_dtype=jnp.float32,
-                           prefill_flash=True)
+                           prefix_cache=True)
     try:
-        real = cb.programs.prefill
+        assert cb.use_kernel is False
+        assert cb.debug_state()["dispatch"]["kinds"]["mixed"] == 0
+        rng = np.random.default_rng(17)
+        prompts = [rng.integers(0, 64, (n,), np.int32) for n in (1, 5, 16, 33)]
+        for p in prompts + prompts[-1:]:
+            np.testing.assert_array_equal(
+                np.asarray(cb.submit(p, 5).result(timeout=120)),
+                np.asarray(dense(p[None, :], 5)[0]))
+        d = cb.debug_state()["dispatch"]
+        # a round a prompt (the budget is max_len / 2 = 32: 33 tokens take
+        # two, and one the second time, 32 of them shared)
+        assert d["kinds"]["mixed"] == 3 + 2 + 1
+        assert cb.prefix_cache.hits == 4
+    finally:
+        cb.shutdown()
+
+
+def test_a_round_that_fails_is_an_error_the_requester_sees(lm):
+    """A failure of the round's program (a Mosaic refusal of its bucket,
+    say) is an error the requester sees: the batcher swaps in no other
+    path behind its back, and serving goes on for the next request."""
+    cb = ContinuousBatcher(lm, n_heads=2, n_layers=2, lanes=1, max_len=32,
+                           page_size=8, compute_dtype=jnp.float32)
+    try:
+        real = cb.programs.mixed
 
         def boom(*a, **k):
             raise RuntimeError("Mosaic rejected this bucket")
-        cb.programs.prefill = boom
+        cb.programs.mixed = boom
         p = np.random.default_rng(1).integers(0, 64, (6,), np.int32)
         with pytest.raises(RuntimeError, match="Mosaic rejected"):
             cb.submit(p, 4).result(timeout=120)
-        assert cb.prefill_flash is True and cb.programs.prefill is boom
-        cb.programs.prefill = real
+        assert cb.programs.mixed is boom
+        cb.programs.mixed = real
         assert len(cb.submit(p, 4).result(timeout=120)) == 4
     finally:
         cb.shutdown()

@@ -563,7 +563,7 @@ def test_tiny_hybrid_end_to_end_against_the_reference(model, reference,
     spec, params = model
     cb = _engine(spec, params, use_kernel=use_kernel)
     try:
-        assert cb.ragged and cb.use_kernel == use_kernel
+        assert cb.use_kernel == use_kernel
         assert cb.pool.n_layers == 1
         assert cb.pool.bytes_per_token == 2 * 2 * 32 * 4
         prompt = np.random.default_rng(1).integers(0, VOCAB, 21).tolist()
@@ -743,9 +743,8 @@ def test_a_preempted_request_resumes_with_a_fresh_engines_tokens(model):
 
 
 @pytest.mark.parametrize("option", [
-    dict(prefix_cache=True), dict(kv_offload=True), dict(mesh=object()),
-    dict(ragged=False)],
-    ids=["prefix_cache", "kv_offload", "mesh", "ragged=False"])
+    dict(prefix_cache=True), dict(kv_offload=True), dict(mesh=object())],
+    ids=["prefix_cache", "kv_offload", "mesh"])
 def test_options_the_lane_state_does_not_carry_are_refused_by_name(
         model, option, request):
     """And the message names the spec's own kinds, not a hand-written

@@ -10,12 +10,12 @@ paged attention"):
    {"model": 2}} on the 8-fake-CPU-device harness (pallas interpret
    mode: tier-1 exercises the real kernel path).
 
-2. Engine parity — ContinuousBatcher token streams, ragged plan
-   (kernel and XLA-gather attention, mesh on and off) bit-identical to
-   the legacy split dispatch for greedy / device-sampled / logprobs /
-   host-sampled / speculative requests, with the mixed
-   prefill+decode round running as ONE fused dispatch (host-sync count
-   guard, the PR 8 discipline).
+2. Engine parity — ContinuousBatcher token streams through the kernels
+   (mesh on and off) bit-identical to the XLA gather's for greedy /
+   device-sampled / logprobs / host-sampled / speculative requests, the
+   gather's greedy streams to the plain forward over each whole sequence,
+   with the mixed prefill+decode round running as ONE fused dispatch
+   (host-sync count guard, the PR 8 discipline).
 """
 
 import functools
@@ -37,7 +37,7 @@ from tpulab.ops.ragged_attention import (ragged_latent_attention,
                                          ragged_paged_attention)
 from tpulab.parallel import make_mesh
 
-from helpers_engine import TokenGate
+from helpers_engine import TokenGate, greedy_reference
 from helpers_attention import (BF16_ATOL, BF16_RTOL, assert_operand_rule,
                                assert_parents_bits, kernel_eqns, pallas_calls,
                                sparse_attend_case, sparse_decode_case)
@@ -796,7 +796,7 @@ def _run_cases(cb):
 
 
 @pytest.fixture(scope="module")
-def legacy_ref(lm):
+def gather_ref(lm):
     cb = _batcher(lm, use_kernel=False)
     try:
         return _run_cases(cb)
@@ -804,23 +804,35 @@ def legacy_ref(lm):
         cb.shutdown()
 
 
-@pytest.mark.parametrize("mode", ["ragged_xla", "kernel", "kernel_mesh"])
-def test_engine_token_parity(lm, legacy_ref, mode):
-    """Ragged plan == legacy split dispatch, bit-exact tokens across
-    greedy/device-sampled/logprobs/host-sampled, kernel and XLA
-    attention, mesh on and off — the house parity style."""
-    kw = {"ragged_xla": dict(use_kernel=False, ragged=True),
-          "kernel": dict(use_kernel=True),
+def test_the_gathers_greedy_streams_are_the_plain_forwards(lm, gather_ref):
+    """The XLA gather's greedy streams and logprobs (what the kernels are
+    held to below) against a reference that shares no code with the
+    engine: the plain forward over each whole sequence."""
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 64, (n,), np.int32) for n, _ in _CASES]
+    want = [greedy_reference(lm, p, s, 2, 2)
+            for p, (_, s) in zip(prompts, _CASES)]
+    assert gather_ref[0][:2] == [toks for toks, _ in want]
+    assert gather_ref[0][3] == want[1][0][:6]
+    np.testing.assert_allclose(gather_ref[1], want[1][1][:6],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mode", ["kernel", "kernel_mesh"])
+def test_engine_token_parity(lm, gather_ref, mode):
+    """Bit-exact tokens across greedy/device-sampled/logprobs/host-sampled
+    streams, kernel against XLA attention, mesh on and off: the house
+    parity style."""
+    kw = {"kernel": dict(use_kernel=True),
           "kernel_mesh": dict(use_kernel=True, mesh_n=2)}[mode]
     cb = _batcher(lm, **kw)
     try:
         out, lps = _run_cases(cb)
-        assert cb.ragged and cb.prefill_dispatches == 0
         assert cb.dispatch_kinds["mixed"] >= 1
     finally:
         cb.shutdown()
-    assert out == legacy_ref[0]
-    np.testing.assert_allclose(lps, legacy_ref[1], rtol=1e-5, atol=1e-5)
+    assert out == gather_ref[0]
+    np.testing.assert_allclose(lps, gather_ref[1], rtol=1e-5, atol=1e-5)
 
 
 def _run_spec(cb):
@@ -867,10 +879,9 @@ def test_speculative_verify_parity(lm, spec_ref, mesh_n):
 
 def test_mixed_round_is_one_fused_dispatch(lm):
     """The acceptance guard: N simultaneous prompt fills fold into ONE
-    ragged dispatch (legacy: one prefill program per lane), a mixed
-    prefill+decode round costs one dispatch = one host sync, and the
-    ragged plan never runs a separate prefill program."""
-    cb = _batcher(lm, use_kernel=False, ragged=True, lanes=3)
+    ragged dispatch (never a program a lane), and a mixed prefill+decode
+    round costs one dispatch = one host sync."""
+    cb = _batcher(lm, use_kernel=False, lanes=3)
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, 64, (6,), np.int32) for _ in range(3)]
     try:
@@ -888,7 +899,6 @@ def test_mixed_round_is_one_fused_dispatch(lm):
         assert cb.decode_dispatches - d0 <= 2
         assert cb.decode_host_syncs - s0 == cb.decode_dispatches - d0
         assert cb.dispatch_kinds["mixed"] - m0 == cb.decode_dispatches - d0
-        assert cb.prefill_dispatches == 0
 
         # mixed prefill+decode: a prompt arriving mid-decode rides the
         # same fused round as the decoding lane
@@ -902,7 +912,6 @@ def test_mixed_round_is_one_fused_dispatch(lm):
         r0 = f0.result(timeout=300)
         assert cb.dispatch_kinds["mixed"] - m0 >= 3
         assert cb.decode_host_syncs == cb.decode_dispatches
-        assert cb.prefill_dispatches == 0
         assert len(r0) == 16 and len(r1) == 4
     finally:
         cb.shutdown()
@@ -910,30 +919,34 @@ def test_mixed_round_is_one_fused_dispatch(lm):
 
 def test_chunked_prefill_prefix_cache_and_resume(lm):
     """Multi-round chunked prefill (prefill_chunk bounds the per-round
-    segment), prefix-cache hits, and preempt/resume all compose with
-    the ragged plan — token streams stay bit-exact vs legacy."""
+    segment), prefix-cache hits, and preempt/resume all compose: token
+    streams stay bit-exact against an engine that has none of the three
+    (whole budgets a round, no cache, nothing preempted) and against the
+    plain forward over the whole sequence."""
     rng = np.random.default_rng(3)
     long_p = rng.integers(0, 64, (34,), np.int32)
     short_p = rng.integers(0, 64, (7,), np.int32)
-    cb = _batcher(lm, use_kernel=False, ragged=True, max_len=96,
+    cb = _batcher(lm, use_kernel=False, max_len=96,
                   prefill_chunk=16, prefix_cache=True, n_pages=40)
     try:
         o1 = list(cb.submit(long_p, 8).result(timeout=300))
         hits0 = cb.prefix_cache.hits
         assert list(cb.submit(long_p, 8).result(timeout=300)) == o1
-        assert cb.prefix_cache.hits > hits0     # ragged rounds share pages
-        assert cb.prefill_dispatches == 0
+        assert cb.prefix_cache.hits > hits0     # the rounds share pages
+        # 34 tokens in chunks of 16: three rounds; 32 of them shared: one
+        assert cb.dispatch_kinds["mixed"] == 3 + 1
     finally:
         cb.shutdown()
+    assert o1 == greedy_reference(lm, long_p, 8, 2, 2)[0]
     ref = _batcher(lm, use_kernel=False, max_len=96)
     try:
         assert list(ref.submit(long_p, 8).result(timeout=300)) == o1
+        assert ref.dispatch_kinds["mixed"] == 2      # a budget of 32, then 2
     finally:
         ref.shutdown()
-    # preemption: a higher-priority arrival evicts the ragged lane; the
-    # resume re-prefills through mixed rounds and stays bit-exact
-    cb = _batcher(lm, use_kernel=False, ragged=True, lanes=1,
-                  decode_block=2)
+    # preemption: a higher-priority arrival evicts the lane; the resume
+    # re-prefills through mixed rounds and stays bit-exact
+    cb = _batcher(lm, use_kernel=False, lanes=1, decode_block=2)
     try:
         started = TokenGate()
         f1 = cb.submit(short_p, 20, priority=0, on_token=started)
@@ -960,13 +973,13 @@ def test_ragged_metrics_and_debug_state(lm):
 
     from tpulab.utils.metrics import GenerationMetrics
 
-    cb = _batcher(lm, use_kernel=False, ragged=True)
+    cb = _batcher(lm, use_kernel=False)
     m = GenerationMetrics(registry=CollectorRegistry())
     try:
         cb.submit(np.arange(5, dtype=np.int32) + 1, 6).result(timeout=300)
         m.poll(cb)
         dbg = cb.debug_state()["dispatch"]
-        assert dbg["ragged"] and dbg["ragged_dispatches"] >= 1
+        assert dbg["ragged_dispatches"] >= 1
         assert dbg["kinds"]["mixed"] >= 1
     finally:
         cb.shutdown()
@@ -979,15 +992,16 @@ def test_ragged_metrics_and_debug_state(lm):
     assert kinds.get("mixed", 0) >= 1
 
 
-def test_use_kernel_false_is_the_escape_hatch(lm):
-    """Explicit use_kernel=False keeps the legacy split dispatch: no
-    mixed rounds, prefill programs still dispatched."""
+def test_use_kernel_chooses_the_attention_not_the_plan(lm):
+    """Explicit ``use_kernel=False`` is the XLA gather under the one plan:
+    the prompt rides a mixed round, which is the only dispatch counted
+    among the ragged family's (the blocks' attention ran no kernel)."""
     cb = _batcher(lm, use_kernel=False)
     try:
-        assert not cb.ragged
         cb.submit(np.arange(5, dtype=np.int32) + 1, 4).result(timeout=300)
-        assert cb.dispatch_kinds["mixed"] == 0
-        assert cb.prefill_dispatches == 1
-        assert cb.ragged_dispatches == 0
+        assert not cb.use_kernel
+        assert cb.dispatch_kinds["mixed"] == 1
+        assert cb.dispatch_kinds["decode"] >= 1
+        assert cb.ragged_dispatches == 1
     finally:
         cb.shutdown()

@@ -90,16 +90,13 @@ def test_pool_rejects_bad_mesh_geometry(lm):
 
 def test_batcher_accepts_kernel_under_mesh_rejects_foreign_pool(lm):
     """The ragged pallas kernel shards over the KV-heads dim (PR 8's
-    named follow-up retired): use_kernel=True under a mesh constructs —
-    only the single-device flash prefill still rejects — and a provided
-    pool must be built on the batcher's own mesh."""
+    named follow-up retired): use_kernel=True under a mesh constructs,
+    and a provided pool must be built on the batcher's own mesh."""
     cb = _batcher(lm, mesh=_mesh(2), use_kernel=True)
     try:
-        assert cb.use_kernel and cb.ragged and cb.mesh is not None
+        assert cb.use_kernel and cb.mesh is not None
     finally:
         cb.shutdown()
-    with pytest.raises(ValueError, match="single-device"):
-        _batcher(lm, mesh=_mesh(2), prefill_flash=True)
     other = PagedKVPool(17, 8, 2, 2, 16, jnp.float32, mesh=_mesh(2))
     with pytest.raises(ValueError, match="different mesh"):
         _batcher(lm, mesh=make_mesh({"model": 2}, jax.devices()[2:4]),
@@ -238,7 +235,7 @@ def test_sharded_preempt_resume_through_host_tier(lm, dense):
         got_low = list(f_low.result(timeout=300))
         assert cb.preemptions >= 1
         assert cb.kv_offload.swap_outs >= 1 and cb.kv_offload.swap_ins >= 1
-        assert cb.prefill_dispatches == 2   # zero re-prefill
+        assert cb.dispatch_kinds["mixed"] == 2   # zero re-prefill
         np.testing.assert_array_equal(
             np.asarray(got_low), np.asarray(dense(p_low[None, :], 10)[0]))
         np.testing.assert_array_equal(

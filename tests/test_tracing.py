@@ -233,15 +233,16 @@ def _consumed_blocks(cb):
     return calls, rounds
 
 
-@pytest.mark.parametrize("ragged", [False, True])
-def test_turns_chain_and_parts_account_for_the_scheduler(ragged):
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernels-interpret"])
+def test_turns_chain_and_parts_account_for_the_scheduler(use_kernel):
     """``dispatch["turns"]``: its stages sum to its seconds and never
     exceed the stages' own; ``dispatch["chain"]``: every consumed decode
     block and mixed round either had its successor (a block or a round)
     enqueued ahead or a counted cause;
     ``dispatch["dispatch_parts"]``: inside the ``dispatch`` stage."""
     from tpulab.engine.paged import ContinuousBatcher as CB
-    cb = _tiny_engine(lanes=2, ragged=ragged)
+    cb = _tiny_engine(lanes=2, use_kernel=use_kernel)
     consumed, rounds = _consumed_blocks(cb)
     try:
         futs = [cb.submit(np.arange(3 + i, dtype=np.int32), 7 + 3 * i,
@@ -260,7 +261,7 @@ def test_turns_chain_and_parts_account_for_the_scheduler(ragged):
         assert 0 <= s <= stages[name]["s"] + 1e-9, name
     assert turns["s"] > 0
     # a turn ends with a launch: never more of them than programs launched
-    assert turns["n"] <= d["decode_dispatches"] + d["prefill_dispatches"]
+    assert turns["n"] <= d["decode_dispatches"]
     chain = d["chain"]
     assert tuple(chain["breaks"]) == CB.BREAK_CAUSES
     assert consumed and len(consumed) + len(rounds) == (
@@ -274,7 +275,7 @@ def test_turns_chain_and_parts_account_for_the_scheduler(ragged):
     blocks_and_rounds = len(consumed) + d["kinds"]["mixed"]
     assert all(p["n"] == blocks_and_rounds for p in parts.values())
     assert 0 < sum(p["s"] for p in parts.values()) <= stages["dispatch"]["s"]
-    assert (d["kinds"]["mixed"] > 0) == ragged
+    assert d["kinds"]["mixed"] > 0
 
 
 def _force_completion(cb, seen):
@@ -339,20 +340,22 @@ def _force_joiner(cb, seen):
     ("released", 1, _force_released),
     ("joiner", 2, _force_joiner),
 ])
-@pytest.mark.parametrize("ragged", [False, True])
-def test_chain_break_is_counted_under_its_cause(cause, lanes, force, ragged):
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernels-interpret"])
+def test_chain_break_is_counted_under_its_cause(cause, lanes, force,
+                                                use_kernel):
     """Each cause a CPU batcher can be driven into, forced from the
     scheduler's own thread (a token hook) so the order is the program's:
-    counted once under its name, and nothing under any other.  Under the
-    ragged plan a request that arrives beside a running chain is no
-    ``joiner``: its prompt rides a round of the chain and its lane decodes
-    from that round's carry, so nothing breaks."""
-    cb = _tiny_engine(lanes=lanes, ragged=ragged)
+    counted once under its name, and nothing under any other.  A request
+    that arrives beside a running chain is no ``joiner``: its prompt rides
+    a round of the chain and its lane decodes from that round's carry, so
+    nothing breaks."""
+    cb = _tiny_engine(lanes=lanes, use_kernel=use_kernel)
     try:
         breaks = force(cb, [])
     finally:
         cb.shutdown()
-    if ragged and cause == "joiner":
+    if cause == "joiner":
         assert not any(breaks.values()), breaks
         assert cb.ahead_rounds >= 1
         return
@@ -678,8 +681,9 @@ def test_engine_reports_host_fetches_and_turns_by_cause():
 
 def test_wait_counters_match_the_requests():
     """One lane, requests one after the other, nine tokens each: the
-    first from the prefill, the other eight in one K=8 block."""
-    cb = _tiny_engine(lanes=1, decode_block=8, ragged=False)
+    first from the round that carried the prompt, the other eight in one
+    K=8 block."""
+    cb = _tiny_engine(lanes=1, decode_block=8)
     n = 3
     try:
         for i in range(n):
@@ -694,7 +698,7 @@ def test_wait_counters_match_the_requests():
     assert d["first_decode_wait_s"] > 0
     # single ticks count one step each; a one-token request never waits
     # for a second token
-    cb = _tiny_engine(lanes=1, decode_block=1, ragged=True)
+    cb = _tiny_engine(lanes=1, decode_block=1)
     try:
         cb.submit(np.arange(5, dtype=np.int32), 4).result(timeout=120)
         cb.submit(np.arange(5, dtype=np.int32), 1).result(timeout=120)
@@ -746,11 +750,18 @@ def test_tokens_identical_with_profiler_on_and_off(tmp_path, switch_closed):
     assert turns and all("lanes" in stats for name, evs in turns.items()
                          for stats in evs
                          if stats or name != "sched.turn.other")
-    blocks = [stats for stats in spans["sched.dispatch"] if stats]
-    assert len(blocks) == len(spans["sched.dispatch.call"])
-    assert {b["program"] for b in blocks} == {"paged_decode_block_k8"}
+    noted = [stats for stats in spans["sched.dispatch"] if stats]
+    assert len(noted) == len(spans["sched.dispatch.call"])
+    blocks = [d for d in noted if d["program"] == "paged_decode_block_k8"]
+    rounds = [d for d in noted if d["program"] == "paged_mixed_step"]
+    assert blocks and rounds and len(blocks) + len(rounds) == len(noted)
     assert all(set(b) == {"program", "k", "lanes", "rows", "ahead"}
                and b["k"] == 8 and b["ahead"] in (0, 1) for b in blocks)
+    # the prompts came in rounds (26 tokens in all: 5 + 6 + 7 + 8)
+    assert all(set(r) == {"program", "k", "lanes", "rows", "prompt_tokens",
+                          "decode_rows", "ahead"}
+               and r["k"] == 1 and r["ahead"] in (0, 1) for r in rounds)
+    assert sum(r["prompt_tokens"] for r in rounds) == 26
 
 
 def _lower_args(cb, kind):
@@ -779,10 +790,17 @@ def _lower_args(cb, kind):
         "paged_mixed_step": lambda: (
             cb.programs.mixed,
             head + (packed("round", 8 + b), cb._no_carry)),
-        "paged_prefill": lambda: (
-            cb.programs.prefill, head + one + (jnp.int32(5),)),
+        # the speculative draft's warm-up: the one caller ``paged_extend``
+        # has left
         "paged_extend": lambda: (
-            cb.programs.extend, head + one + (jnp.int32(8), jnp.int32(13))),
+            cb.programs.draft_extend,
+            (cb._spec["params"], cb.pool.kv) + one
+            + (jnp.int32(8), jnp.int32(13))),
+        # EVA: a finished window's pages (``perf/layer_metrics/
+        # eva.summary_roofline.py`` reads the program by this name)
+        "paged_eva_compact": lambda: (
+            cb.programs.compact,
+            head + (i32(cb.plan.eva_window // cb.page_size),)),
         "paged_speculative_block_k2": lambda: (
             cb.programs.spec_block(2),
             (cb.params, cb._spec["params"], cb.pool.kv, packed("spec", 1))),
@@ -791,16 +809,23 @@ def _lower_args(cb, kind):
 
 @pytest.mark.parametrize("kind", [
     "paged_decode_block_k2", "paged_decode_block_k8",
-    "paged_decode_step_sampled", "paged_mixed_step", "paged_prefill",
-    "paged_extend", "paged_speculative_block_k2"])
+    "paged_decode_step_sampled", "paged_mixed_step", "paged_extend",
+    "paged_eva_compact", "paged_speculative_block_k2"])
 def test_step_programs_carry_stable_names(kind):
     """Every step program is built in StepPrograms._jit, which names
     it after its function (+ the block size the partial binds): a trace's
     XLA Modules line shows ``jit_<kind>``, never ``jit__unknown``."""
-    from tpulab.models.transformer import init_transformer_params
-    draft = init_transformer_params(vocab=64, d_model=32, n_heads=2,
-                                    n_layers=1, d_ff=64, seed=1)
-    cb = _tiny_engine(draft_params=draft, draft_n_layers=1)
+    if kind == "paged_eva_compact":
+        import test_evabyte
+        from tpulab.models.spec import evabyte_spec, init_params
+        spec = evabyte_spec(test_evabyte.CONFIG)
+        cb = test_evabyte._engine((spec, init_params(
+            spec, test_evabyte.VOCAB, test_evabyte.D_FF)))
+    else:
+        from tpulab.models.transformer import init_transformer_params
+        draft = init_transformer_params(vocab=64, d_model=32, n_heads=2,
+                                        n_layers=1, d_ff=64, seed=1)
+        cb = _tiny_engine(draft_params=draft, draft_n_layers=1)
     try:
         fn, args = _lower_args(cb, kind)
         text = fn.lower(*args).as_text()
@@ -821,21 +846,18 @@ def _pallas_names(jaxpr):
 
 
 @pytest.mark.parametrize("kernel", [
-    "ragged_paged_attention", "flash_attention_fwd",
+    "ragged_paged_attention", "ragged_paged_decode",
     "ragged_latent_attention"])
 def test_pallas_kernels_carry_names(kernel):
-    """``name=`` on the three pallas_calls (interpret mode here): the
-    name a device trace shows the kernel under."""
+    """``name=`` on three of the pallas_calls (interpret mode here): the
+    name a device trace shows the kernel under.  One row a lane takes the
+    one-row kernel, by the shape alone."""
     import jax
     import jax.numpy as jnp
     kv = jnp.ones((5, 2, 8, 2, 32))
     tables = jnp.zeros((2, 4), jnp.int32)
     lens = jnp.array([3, 9], jnp.int32)
-    if kernel == "flash_attention_fwd":
-        from tpulab.ops.flash_attention import flash_attention
-        fn, q = (lambda q: flash_attention(q, q, q, causal=True)), \
-            jnp.ones((1, 128, 2, 32))
-    elif kernel == "ragged_latent_attention":
+    if kernel == "ragged_latent_attention":
         from tpulab.ops.ragged_attention import ragged_latent_attention
         fn, q = (lambda q: ragged_latent_attention(
             q, kv.reshape(1, 5, 1, 8, 4 * 32), 0, tables,
@@ -844,10 +866,11 @@ def test_pallas_kernels_carry_names(kernel):
             jnp.ones((2, 2, 2, 96))
     else:
         from tpulab.ops.ragged_attention import ragged_paged_attention
+        rows = 1 if kernel == "ragged_paged_decode" else 2
         fn, q = (lambda q: ragged_paged_attention(
             q, kv.reshape(1, 5, 2, 8, 2 * 32), 0, tables,
-            jnp.array([1, 2], jnp.int32), lens)), \
-            jnp.ones((2, 2, 2, 32))
+            jnp.array([1, rows], jnp.int32), lens)), \
+            jnp.ones((2, rows, 2, 32))
     assert list(_pallas_names(jax.make_jaxpr(fn)(q).jaxpr)) == [kernel]
     assert kernel in jax.jit(fn).lower(q).as_text(debug_info=True)
 

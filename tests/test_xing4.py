@@ -517,7 +517,7 @@ def test_scheduler_rounds_blocks_and_readmission_against_the_reference(
     spec, published, served = model
     cb = _engine(spec, served, use_kernel=use_kernel)
     try:
-        assert cb.ragged and cb.use_kernel == use_kernel
+        assert cb.use_kernel == use_kernel
         assert cb.pool.entry_kind == "latent" and cb.pool.n_layers == 3
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, VOCAB, n).tolist()
@@ -531,7 +531,7 @@ def test_scheduler_rounds_blocks_and_readmission_against_the_reference(
         state = cb.debug_state()
         d = state["dispatch"]
         assert d["kinds"]["mixed"] >= 5 and d["kinds"]["decode"] > 0
-        assert d["prefill_dispatches"] == 0 and d["mixed_decode_rows"] > 0
+        assert d["mixed_decode_rows"] > 0
         per_layer = np.asarray(state["moe"]["assignments"])
         assert per_layer.shape == (2, 8)
         assert (per_layer.sum(1) == 3 * (sum(map(len, prompts)) + 5 * 8)).all()
@@ -604,7 +604,6 @@ def test_debug_state_lists_the_grouped_products_the_programs_were_traced_at(
     ("mesh", {"mesh": object()}),
     ("prefix_cache", {"prefix_cache": True}),
     ("kv_dtype", {"kv_dtype": jnp.bfloat16}),
-    ("ragged=False", {"ragged": False}),
     ("kv_offload", {"kv_offload": True}),
     ("kv_publish", {"kv_publish": True}),
     ("draft_params", {"draft_params": {"layer0": {}}})])

@@ -731,7 +731,7 @@ def test_scheduler_rounds_blocks_and_readmission_against_the_reference(
     hyper = reference.hyper_of(CONFIG)
     cb = _engine(spec, params, use_kernel=use_kernel)
     try:
-        assert cb.ragged and cb.use_kernel == use_kernel
+        assert cb.use_kernel == use_kernel
         assert cb.pool.entry_kind == "kv" and cb.pool.n_layers == 3
         assert cb.state.kind == "cca" and len(cb.state.arrays) == 3
         rng = np.random.default_rng(0)
@@ -745,7 +745,7 @@ def test_scheduler_rounds_blocks_and_readmission_against_the_reference(
         state = cb.debug_state()
         d = state["dispatch"]
         assert d["kinds"]["mixed"] >= 5 and d["kinds"]["decode"] > 0
-        assert d["prefill_dispatches"] == 0 and d["mixed_decode_rows"] > 0
+        assert d["mixed_decode_rows"] > 0
         assert state["state"]["kind"] == "cca"
         assert state["state"]["zero_starts"] == 5
         assert state["state"]["rule"] == {"decode": "xla", "round": "xla"}
@@ -850,9 +850,9 @@ def test_a_preempted_request_prefills_again_to_a_fresh_engines_tokens(model):
 @pytest.mark.parametrize("option", [
     dict(prefix_cache=True), dict(draft_params={"layer0": {}}),
     dict(mesh=object()), dict(kv_offload=True), dict(kv_publish=True),
-    dict(ragged=False), dict(kv_dtype=jnp.bfloat16)],
+    dict(kv_dtype=jnp.bfloat16)],
     ids=["prefix_cache", "draft_params", "mesh", "kv_offload", "kv_publish",
-         "ragged=False", "kv_dtype"])
+         "kv_dtype"])
 def test_options_the_lane_state_does_not_carry_are_refused_by_name(
         model, option, request):
     spec, params = model

@@ -635,9 +635,10 @@ class PrefixCache:
 
     Maps a digest of the token prefix ``prompt[:(i+1)*S]`` to the page
     holding that S-token span's K/V.  A hit lets a new request *share* the
-    cached pages (``PagedKVPool.add_ref``) and prefill only the tail via
-    :func:`paged_extend` — the paged-serving time-to-first-token
-    optimization for shared system prompts / few-shot preambles.
+    cached pages (``PagedKVPool.add_ref``) and compute only the tail's
+    rows (its rounds start at the first position not shared) — the
+    paged-serving time-to-first-token optimization for shared system
+    prompts / few-shot preambles.
 
     Safety: only FULL prompt pages enter the cache, and a request's write
     region (tail prefill + decode appends) always sits at page boundaries

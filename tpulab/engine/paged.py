@@ -7,8 +7,8 @@ serving shape): K/V live in a global pool of fixed-size *pages*
 :class:`ContinuousBatcher` steps every active session in one fused batched
 decode per tick — continuous batching: new requests join the batch the moment
 a slot frees, finished ones leave without draining the rest.  The programs it
-jits and dispatches (decode blocks, mixed rounds, the speculative block,
-prefill and extend) are the pure functions of
+jits and dispatches (decode blocks, mixed rounds, the speculative block and
+its draft's warm-up) are the pure functions of
 :mod:`tpulab.engine.paged_steps`; this file holds the request
 (:class:`SamplingParams`, ``_PagedRequest``), the process-level jit memo and
 the scheduler, and nothing else.
@@ -28,8 +28,7 @@ from tpulab import chaos
 from tpulab.core.deadline import Deadline, DeadlineExceeded
 from tpulab.core.threads import on_one_frame_chunk
 from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool, PrefixCache
-from tpulab.engine.paged_steps import (ROUND_STOPS, StepPrograms,
-                                       _device_sample_token, moe_shape,
+from tpulab.engine.paged_steps import (ROUND_STOPS, StepPrograms, moe_shape,
                                        pack_round, pack_words, result_fields,
                                        unpack_words)
 from tpulab.engine.plan import plan_engine
@@ -185,7 +184,7 @@ class _PagedRequest:
         #: flight-recorder per-request detail (None = recorder disarmed:
         #: the scheduling hot path pays one None check per site)
         self.fl: Optional[dict] = None
-        # -- ragged dispatch plan: multi-round chunked-prefill state --------
+        # -- a prompt on its way in, a chunk a round ------------------------
         self.pf_started = False      # pages secured, chunks may dispatch
         self.pf_digests = None       # full-prompt-page digests (insert at
         #                              prompt completion)
@@ -283,8 +282,9 @@ class ContinuousBatcher:
     streams, and the host-sync count per block is unchanged — see
     docs/PERFORMANCE.md "Sharded serving".
 
-    Ragged dispatch plan (``use_kernel=True`` or ``ragged=True``,
-    docs/PERFORMANCE.md "Ragged paged attention"): prompts and decode
+    The dispatch plan (docs/PERFORMANCE.md "Ragged paged attention";
+    ``use_kernel`` chooses the attention under it, the Pallas kernels or
+    the XLA gather, never the plan): prompts and decode
     lanes advance together through fused mixed rounds
     (:func:`paged_mixed_step`) — per-lane (query_len, kv_len) segments
     packed by token, at most ``RAGGED_CHUNK_CAP`` prompt tokens a round
@@ -298,9 +298,7 @@ class ContinuousBatcher:
     carry a decode block returns and returns one, so while a prompt waits
     every dispatch is a round that carries every decoding lane as a row
     from its predecessor's device carry, enqueued before that predecessor
-    is fetched (:meth:`_chain_block`).  Tokens are bit-exact vs the legacy
-    split dispatch (``use_kernel=False``, the escape hatch), mesh on or
-    off.
+    is fetched (:meth:`_chain_block`).
 
     Model spec (``spec=``, tpulab.models.spec): a ``ModelSpec`` names the
     attention kind, the layer kinds and the cache-entry kind; without one
@@ -326,9 +324,8 @@ class ContinuousBatcher:
     a query row attends to the ``index_topk`` keys the indexer selects
     (``tpulab.ops.sparse_attention``; ``debug_state()["sparse"]`` counts
     rows, keys scored and keys attended from the lengths committed).
-    Such a spec is served on the ragged plan only; the options that plan,
-    that cache entry or a per-lane state does not carry are refused at
-    construction, by name.
+    The options that such a spec's cache entry or per-lane state does not
+    carry are refused at construction, by name.
 
     Tiered KV (``kv_offload=``, tpulab.kvcache): preemption swaps the
     victim's KV pages to a budgeted host-RAM tier (async, write-behind)
@@ -364,7 +361,6 @@ class ContinuousBatcher:
                  prefix_cache: bool = False,
                  prefill_chunk: Optional[int] = None,
                  kv_dtype=None,
-                 prefill_flash: Optional[bool] = None,
                  trace=None, metrics=None,
                  decode_block: int = 8,
                  kv_offload=None,
@@ -374,7 +370,6 @@ class ContinuousBatcher:
                  draft_n_kv_heads: Optional[int] = None,
                  spec_accept_floor: float = 0.35,
                  mesh=None, hbm=None, flight=None,
-                 ragged: Optional[bool] = None,
                  kv_publish: bool = False,
                  spec=None):
         import jax
@@ -384,7 +379,7 @@ class ContinuousBatcher:
         compute_dtype = compute_dtype or jnp.bfloat16
         vocab, d_model = weight_shape(params["embed"])[:2]
         #: what this engine is (tpulab.engine.plan): the model's kinds, the
-        #: page store's geometry, the dispatch plan, the round's budget;
+        #: page store's geometry, ``use_kernel``, the round's budget;
         #: what it refuses it refuses here, before anything is allocated
         self.plan = plan = plan_engine(
             spec=spec, n_heads=n_heads, n_layers=n_layers,
@@ -392,12 +387,10 @@ class ContinuousBatcher:
             d_model=int(d_model), vocab=int(vocab), lanes=lanes,
             max_len=max_len, page_size=page_size,
             prefill_chunk=prefill_chunk, use_kernel=use_kernel,
-            ragged=ragged, prefill_flash=prefill_flash,
             compute_dtype=compute_dtype, kv_dtype=kv_dtype,
             round_ceiling=self.RAGGED_CHUNK_CAP,
-            kernel_auto_min_ctx=self.KERNEL_AUTO_MIN_CTX,
-            verify_width=self.BLOCK_K_MENU[-1] + 1, pool=pool, mesh=mesh,
-            hbm=hbm, draft_params=draft_params,
+            kernel_auto_min_ctx=self.KERNEL_AUTO_MIN_CTX, pool=pool,
+            mesh=mesh, hbm=hbm, draft_params=draft_params,
             draft_n_layers=draft_n_layers, draft_n_heads=draft_n_heads,
             draft_n_kv_heads=draft_n_kv_heads, kv_offload=kv_offload,
             kv_publish=kv_publish, prefix_cache=prefix_cache)
@@ -414,8 +407,14 @@ class ContinuousBatcher:
         self.prefill_chunk = plan.prefill_chunk
         #: id-validation bound (public: the Generate RPC checks it too)
         self.vocab = plan.vocab
-        self.use_kernel, self.ragged = plan.use_kernel, plan.ragged
-        self.prefill_flash = plan.prefill_flash
+        self.use_kernel = plan.use_kernel
+        # of a choice the engine no longer has (one dispatch plan, no flash
+        # prefill), what the benchmark still prints (``perf/models/*.py``)
+        # and subscripts (``sched.tokens_per_dispatch``: the key
+        # ``prefill_dispatches`` of :meth:`debug_state`, a literal 0 there
+        # beside ``"ragged": True``).  Nothing in ``tpulab/`` reads them; a
+        # ``benchmark`` issue frees the names (ROADMAP D17)
+        self.ragged, self.prefill_flash = True, False
         #: the widest budget THIS engine runs: what a harness sizes its
         #: warm-up prompts by (a power of two; the class's is the ceiling)
         self.RAGGED_CHUNK_CAP = plan.round_cap
@@ -551,14 +550,11 @@ class ContinuousBatcher:
         #    the host-syncs-per-request regression guard read these) ------
         self.decode_dispatches = 0   # device decode dispatches (any K)
         self.decode_host_syncs = 0   # blocking device->host decode fetches
-        self.prefill_dispatches = 0  # prefill passes (one per prompt fill;
-        #                              stays 0 under the ragged plan —
-        #                              prompts ride mixed rounds instead)
         #: dispatches through the ragged kernel family: every mixed
         #: round, plus plain/spec dispatches whose attention ran the
         #: pallas ragged kernel (use_kernel)
         self.ragged_dispatches = 0
-        #: per-dispatch-kind counts (the ragged plan's three descriptor
+        #: per-dispatch-kind counts (the three descriptor
         #: kinds): "decode" = plain K-blocks and single ticks, "verify"
         #: = speculative draft+verify blocks, "mixed" = ragged mixed
         #: prefill+decode rounds
@@ -707,9 +703,9 @@ class ContinuousBatcher:
         # a FetchKV RPC can serve both to the digest's routed-astray
         # fetchers without evicting this replica's own copy.  Requires
         # kv_offload (the host tier IS the export buffer: refused above
-        # without one).  Publishes ride the legacy prefill dispatch only:
-        # the ragged plan's mixed rounds never fetch a host-visible logits
-        # row (documented limitation; ROADMAP follow-up).
+        # without one).  The round in which a first prompt ends publishes
+        # (:meth:`_consume_round`) and is not chained ahead, so the
+        # snapshot's gather goes before any decode write into the tail page.
         self.kv_publish = bool(kv_publish)
         self._fab_handles: "Dict[bytes, Any]" = OrderedDict()
         self._fab_lock = threading.Lock()
@@ -1539,8 +1535,9 @@ class ContinuousBatcher:
             "dispatch": {"decode_block": self.decode_block,
                          "decode_dispatches": self.decode_dispatches,
                          "decode_host_syncs": self.decode_host_syncs,
-                         "prefill_dispatches": self.prefill_dispatches,
-                         "ragged": self.ragged,
+                         # the benchmark's names (see ``__init__``)
+                         "prefill_dispatches": 0,
+                         "ragged": True,
                          "use_kernel": self.use_kernel,
                          "ragged_dispatches": self.ragged_dispatches,
                          "kinds": dict(self.dispatch_kinds),
@@ -2079,7 +2076,7 @@ class ContinuousBatcher:
             req.pending_prompt = list(req.prompt)
         req.length = 0
         req.eva_done = 0
-        req.pf_started = False   # ragged plan: the resume re-secures pages
+        req.pf_started = False   # the resume re-secures its pages
         self._active[lane] = None
         self._enqueue_locked(req, front_of_class=True)
         self.preemptions += 1
@@ -2148,31 +2145,20 @@ class ContinuousBatcher:
                         "generation deadline exceeded "
                         f"({len(req.tokens_out)}/{req.steps} tokens)"))
             try:
-                prefilled = False
                 if self.plan.eva_window:
                     self._eva_compact()
-                if self.ragged:
-                    # ragged dispatch plan: pending prompts and decode
-                    # lanes advance together in ONE fused mixed round (the
-                    # head of a chain here; with a dispatch in flight the
-                    # chain plans its own rounds as _tick consumes it)
-                    prefilled = self._ragged_round(snapshot, jnp)
-                else:
-                    for lane, req in enumerate(snapshot):
-                        if req is not None and req.pending_prompt:
-                            prefilled |= self._do_prefill(req, jnp, lane)
+                # pending prompts and decode lanes advance together in ONE
+                # fused mixed round (the head of a chain here; with a
+                # dispatch in flight the chain plans its own rounds as
+                # _tick consumes it)
+                prefilled = self._ragged_round(snapshot, jnp)
                 if prefilled:
-                    # a steps==1 request can complete at prefill
-                    done_reqs = []
+                    # the round's consume released what finished in it (a
+                    # steps == 1 request) and admitted behind it: decode
+                    # goes on with the lanes as they stand now
                     with stage(st, "admit"), self._cv:
-                        for lane, req in enumerate(self._active):
-                            if (req is not None and not req.pending_prompt
-                                    and req.finished()):
-                                self._release_lane_locked(lane, req)
-                                done_reqs.append(req)
                         self._admit_locked()
                         snapshot = list(self._active)
-                    self._deliver((), done_reqs)
                 if self.plan.eva_window:
                     self._eva_compact()     # a window the round finished
                 progressed = self._tick(snapshot, jnp) or prefilled
@@ -2223,189 +2209,35 @@ class ContinuousBatcher:
                 if self.state is not None:
                     self.state.reset()
 
-    def _do_prefill(self, req: _PagedRequest, jnp, lane: int = 0) -> bool:
-        """Fused prompt prefill: one compiled forward (per length bucket)
-        fills the whole prompt's KV pages.  With a prefix cache, shared
-        full-page prefixes are reused and only the tail runs (paged_extend);
-        with ``prefill_chunk`` long tails run in page-aligned chunks.
-        Returns False (retry later) when the pool can't yet supply the
-        prompt's pages."""
-        st = self._stages
-        with stage(st, "plan"):
-            if req.cancelled or req.length != 0:  # swept / already started
-                return False
-            t = len(req.pending_prompt)
-            if req.kv_handle is not None:
-                # recompute-free resume: swap the preemption snapshot back in
-                # instead of re-prefilling.  True = restored (zero prefill
-                # dispatches); False = page-starved (handle kept, retry next
-                # pass); None = swap degraded (handle consumed, fall through
-                # to the exact re-prefill below — today's path)
-                swapped = self._try_swap_in(req, t, lane)
-                if swapped is not None:
-                    return swapped
-            prompt = np.asarray(req.pending_prompt, np.int32)
-            shared: List[int] = []
-            digests: List[bytes] = []
-            if self.prefix_cache is not None:
-                shared, digests = self.prefix_cache.lookup(prompt,
-                                                           self.page_size)
-            # page layout: shared prefix pages first, then private pages (the
-            # admission page + extras) for the tail/write region
-            needed = (t + self.page_size - 1) // self.page_size
-            if not self._secure_pages(req, shared, needed):
-                return False
-            start = len(shared) * self.page_size
-        with stage(st, "dispatch"):
-            tables = np.zeros((self.max_pages,), np.int32)
-            tables[:len(req.pages)] = req.pages
-            tables_j = self._put(tables)
-            # pages secured: the queue wait ends HERE (first prefill only
-            # — a preemption resume re-prefills but already left the queue
-            # once)
-            t_pf0 = _time.perf_counter()
-            if req.t_prefill0 is None:
-                req.t_prefill0 = t_pf0
-                self._span("queue_wait", lane, req.t_submit,
-                           t_pf0 - req.t_submit, req)
-                self.queue_wait_s += t_pf0 - req.t_submit
-                self.queue_waits += 1
-                if self.metrics is not None:
-                    self.metrics.observe_queue_wait(t_pf0 - req.t_submit)
-            # chaos: prefill fault site — an error here rides the
-            # scheduler's recovery path (fail actives + pool reset), a delay
-            # is a slow prefill under deadline pressure
-            chaos.trip("engine.prefill")
-            self.prefill_dispatches += 1
-            if start == 0 and (self.prefill_chunk is None
-                               or t <= self.prefill_chunk):
-                t_pad = 1 << (t - 1).bit_length()  # pow2: small jit cache
-                tokens = np.zeros((1, t_pad), np.int32)
-                tokens[0, :t] = prompt
-                last_logits, self.pool.kv = self.programs.prefill(
-                    self.params, self.pool.kv, tables_j,
-                    self._put(tokens), self._put(np.int32(t)))
-                ticket = st.launched()
-            else:
-                # tail (and/or chunked) prefill against resident context
-                chunk = self.prefill_chunk or (t - start)
-                last_logits = None
-                while start < t:
-                    m = min(chunk, t - start)
-                    m_pad = 1 << (m - 1).bit_length()
-                    tokens = np.zeros((1, m_pad), np.int32)
-                    tokens[0, :m] = prompt[start:start + m]
-                    last_logits, self.pool.kv = self.programs.extend(
-                        self.params, self.pool.kv, tables_j,
-                        self._put(tokens), self._put(np.int32(start)),
-                        self._put(np.int32(start + m)))
-                    ticket = st.launched()
-                    start += m
-        with stage(st, "commit"):
-            req.length = t
-            req.pending_prompt = []
-            self._fl_pages(req)
-            was_resumed = req.resumed
-            if was_resumed:
-                # preemption resume: the fed tail ends at tokens_out[-2];
-                # the last emitted token was picked before eviction —
-                # discard these logits, consume no PRNG state, just
-                # continue decoding
-                req.resumed = False
-            else:
-                sp = req.sampling
-                with stage(st, "fetch"):
-                    if sp.device and sp.temperature > 0.0:
-                        # first token rides the SAME (seed, position) stream
-                        # as the decode ticks (position t-1 = the last
-                        # prompt token's query; decode ticks start at
-                        # position t) — one request is one reproducible
-                        # stream end to end.  The prefill logits row is
-                        # fetched once per request; per-TICK logits are
-                        # never fetched for device-sampled lanes.
-                        import jax.numpy as _j
-                        tok = int(self._fetch(_device_sample_token(
-                            _j.asarray(last_logits, _j.float32),
-                            self._put(np.float32(sp.temperature)),
-                            self._put(np.array(
-                                [sp.seed & 0xFFFFFFFF,
-                                 (sp.seed >> 32) & 0xFFFFFFFF], np.uint32)),
-                            self._put(np.int32(t - 1)))))
-                    else:
-                        tok = sp.pick(self._fetch(last_logits))
-                    lp = None
-                    if req.want_logprobs:
-                        # same f32 device log_softmax as paged_decode_step:
-                        # one request's logprob stream is one precision end
-                        # to end
-                        import jax as _jax
-                        import jax.numpy as _j
-                        lp = float(self._fetch(_jax.nn.log_softmax(
-                            _j.asarray(last_logits, _j.float32))[tok]))
-                    st.landed(ticket, lanes=1)
-                req.tokens_out.append(tok)
-                self.tokens_generated += 1
-                if req.want_logprobs:
-                    req.logprobs_out.append(lp)
-                with stage(st, "emit"):
-                    self._emit(req, tok, 0, lp)
-            # prefill span closes after the first-token pick (the pick's
-            # logits fetch is the fence that makes the device time real);
-            # decode chunks start from here
-            t_pf1 = _time.perf_counter()
-            self._span("prefill", lane, t_pf0, t_pf1 - t_pf0, req,
-                       prompt_tokens=t, cached_pages=len(shared))
-            req.chunk_t0 = t_pf1
-            req.chunk_start = len(req.tokens_out)
-            if not was_resumed:
-                req.t_first = t_pf1
-                req.t_last = t_pf1
-                self.ttft_s += t_pf1 - req.t_submit
-                self.ttfts += 1
-                if self.metrics is not None:
-                    self.metrics.observe_ttft(t_pf1 - req.t_submit)
-            if self.prefix_cache is not None and not was_resumed:
-                # count each logical request once (resume prefills re-walk
-                # already-counted pages) and publish only first-prefill
-                # pages: full prompt pages are immutable from here on
-                # (decode writes at positions >= t), while a resume's tail
-                # pages hold generated tokens unique to this request — not
-                # worth caching
-                self.prefix_cache.count_lookup(len(shared), len(digests))
-                self.prefix_cache.insert(digests, req.pages[:len(digests)])
-            dt = t_pf1 - t_pf0
-            if dt > 0:
-                # rolling prefill throughput — the fabric cost gate's
-                # recompute-time estimate (see kv_publish in __init__)
-                inst = t / dt
-                self.prefill_ewma_tok_s = (
-                    inst if self.prefill_ewma_tok_s == 0.0
-                    else 0.7 * self.prefill_ewma_tok_s + 0.3 * inst)
-            if (self.kv_publish and not was_resumed
-                    and req.export_digest is None):
-                self._fab_publish(req, prompt, t, last_logits)
-        return True
-
     #: published fabric snapshots kept addressable (digest -> handle);
     #: beyond this the oldest export is forgotten — its store entries
     #: removed — so the fabric can never squat the whole host tier
     FAB_PUBLISH_CAP = 32
 
-    def _fab_publish(self, req: _PagedRequest, prompt: np.ndarray, t: int,
-                     last_logits) -> None:
+    def _publishes(self, req: _PagedRequest, was_resumed: bool) -> bool:
+        """Whether the round that ends ``req``'s prompt exports it to the
+        fabric: a FIRST prefill (a resume's pages hold generated tokens
+        too, and its pick was made before) that no prefill replica's
+        hand-off takes at release.  Never without ``kv_publish``."""
+        return (self.kv_publish and not was_resumed
+                and req.export_digest is None)
+
+    def _fab_publish(self, req: _PagedRequest, last_logits) -> None:
         """Export a finished first prefill to the fleet KV fabric
         (tpulab.kvfabric): the prompt's pages snapshot to the host tier
         under ``("fab", digest)`` through the same write-behind swap_out
-        the preemption path uses (gather dispatched HERE, before any
-        decode write into the tail page, so dispatch ordering makes the
-        snapshot prompt-only), and the last-position logits row lands
+        the preemption path uses (gather dispatched HERE, behind the round
+        that ended the prompt and before any decode write into the tail
+        page: :meth:`_chain_block` enqueues nothing behind such a round,
+        so dispatch ordering makes the snapshot prompt-only), and the
+        last-position logits row lands
         beside it under ``("fablog", digest)`` so a fetcher picks the
         first token under its OWN sampling seed.  Best-effort end to
         end: a degraded swap, a budget-refused put or a mid-flight
         eviction all surface as an honest FetchKV NOT_FOUND — never a
         wrong answer."""
         from tpulab.disagg.wire import prompt_digest
-        digest = prompt_digest(prompt)
+        digest, t = prompt_digest(req.prompt), len(req.prompt)
         with self._fab_lock:
             if digest in self._fab_handles:
                 self._fab_handles.move_to_end(digest)
@@ -2443,13 +2275,14 @@ class ContinuousBatcher:
                 self._fab_handles.move_to_end(digest)
             return h
 
-    def _try_swap_in(self, req: _PagedRequest, t: int,
-                     lane: int) -> Optional[bool]:
+    def _try_swap_in(self, req: _PagedRequest, lane: int) -> Optional[bool]:
         """Restore a preempted lane's host-tier KV snapshot into freshly
-        allocated pages (see _do_prefill for the tri-state contract).
-        ``t`` is the resume length — by construction equal to the
-        snapshot's covered positions (prompt + generated - 1)."""
-        handle = req.kv_handle
+        allocated pages: True = restored (no prompt row is computed), False
+        = page-starved (the handle kept, retry next pass), None = the swap
+        degraded (the handle consumed: the caller re-prefills exactly).
+        The resume length (prompt + generated - 1, what ``pending_prompt``
+        holds) is by construction the positions the snapshot covers."""
+        handle, t = req.kv_handle, len(req.pending_prompt)
         needed = handle.n_pages
         if not self._secure_pages(req, [], needed):
             # page pressure: nothing held (no hold-and-wait), the handle
@@ -2480,7 +2313,7 @@ class ContinuousBatcher:
         req.chunk_start = len(req.tokens_out)
         return True
 
-    # -- ragged dispatch plan (mixed prefill+decode rounds) ------------------
+    # -- mixed prefill+decode rounds -----------------------------------------
     #: max prefill tokens one mixed round carries IN TOTAL (the ceiling
     #: of the pow2 bucket the mixed program is keyed by): lanes that
     #: prefill at once share it, longer prompts take multiple rounds,
@@ -2506,10 +2339,10 @@ class ContinuousBatcher:
                    self.RAGGED_CHUNK_CAP)
 
     def _ragged_prefill_start(self, req: _PagedRequest, lane: int) -> bool:
-        """Host half of a prefill under the ragged plan: prefix-cache
-        lookup + secure EVERY page the full prompt needs (all-or-nothing,
-        the legacy _do_prefill contract — two starved prefills must not
-        hold-and-wait each other), then mark the lane chunk-ready.
+        """Host half of a prefill: prefix-cache lookup + secure EVERY page
+        the full prompt needs, all of them or none (a lane that held some
+        while it waited for the rest could starve another that does the
+        same: no hold-and-wait), then mark the lane chunk-ready.
         True = segments may build; False = page-starved (retry later)."""
         prompt = np.asarray(req.pending_prompt, np.int32)
         t = len(prompt)
@@ -2539,8 +2372,9 @@ class ContinuousBatcher:
             self.queue_waits += 1
             if self.metrics is not None:
                 self.metrics.observe_queue_wait(req.pf_t0 - req.t_submit)
-        # chaos: same prefill fault site + semantics as _do_prefill (one
-        # trip per prefill start, errors ride the scheduler's recovery)
+        # chaos: the prefill fault site, one trip a prefill start: an error
+        # here rides the scheduler's recovery path (fail actives + pool
+        # reset), a delay is a slow prefill under deadline pressure
         chaos.trip("engine.prefill")
         return True
 
@@ -2585,8 +2419,7 @@ class ContinuousBatcher:
             if req is None or not req.pending_prompt or req.cancelled:
                 continue
             if req.kv_handle is not None:
-                swapped = self._try_swap_in(req, len(req.pending_prompt),
-                                            lane)
+                swapped = self._try_swap_in(req, lane)
                 if swapped is True:
                     progressed = True
                     continue
@@ -2791,6 +2624,14 @@ class ContinuousBatcher:
             st.landed(stash["ticket"],
                       why if why in self.TURN_CAUSES else "round",
                       lanes=len(lane_reqs))
+        for lane, req, was_resumed in stash["firsts"]:
+            # a first prompt that ended here is exported as this round
+            # left it (no dispatch stands behind the round: _chain_block);
+            # the scheduler's thread alone changes ``_active`` and a lane's
+            # pages, so they are read as the commit below will find them
+            if (self._publishes(req, was_resumed)
+                    and self._active[lane] is req and not req.cancelled):
+                self._fab_publish(req, stash["last"][lane])
         now = _time.perf_counter()
         # a round enqueued ahead started when its predecessor ended, which
         # the host saw as the previous fetch's return
@@ -2829,6 +2670,13 @@ class ContinuousBatcher:
                     self.ttfts += 1
                     if self.metrics is not None:
                         self.metrics.observe_ttft(now - req.t_submit)
+                    if now > req.pf_t0:
+                        # rolling prefill throughput: what the fabric's
+                        # cost gate takes a recompute of the prompt to cost
+                        inst = len(req.prompt) / (now - req.pf_t0)
+                        self.prefill_ewma_tok_s = (
+                            inst if self.prefill_ewma_tok_s == 0.0 else
+                            0.7 * self.prefill_ewma_tok_s + 0.3 * inst)
                     if self.prefix_cache is not None:
                         self.prefix_cache.count_lookup(req.pf_shared,
                                                        len(req.pf_digests))
@@ -2934,8 +2782,8 @@ class ContinuousBatcher:
             self.moe_decode_steps += int(stats[0, n + 1])
 
     def _note_dispatch(self, kind: str) -> None:
-        """Dispatch-kind accounting (the ragged plan's three descriptor
-        kinds); ``ragged_dispatches`` counts the ragged kernel family —
+        """Dispatch-kind accounting (the three descriptor kinds);
+        ``ragged_dispatches`` counts the ragged kernel family —
         every mixed round, plus decode/verify dispatches whose attention
         ran the pallas ragged kernel."""
         self.dispatch_kinds[kind] += 1
@@ -3379,7 +3227,9 @@ class ContinuousBatcher:
         dispatch's.  The chain also breaks where a lane stands at its EVA
         window's end (the compaction goes where no writer is in flight),
         where a lane's pick is made on the host (``host``: its token is
-        not in the carry), where a round's pick was a resumed request's
+        not in the carry; also a round that ends a prompt ``kv_publish``
+        exports: the snapshot is taken before the next write), where a
+        round's pick was a resumed request's
         (``resumed``: discarded, the carry's token is not the lane's) and
         where a lane has more stop ids than a round's buffer holds
         (``stops``).  By :meth:`_consume_block` once more AFTER the commit
@@ -3424,7 +3274,7 @@ class ContinuousBatcher:
                         return None, "compact"
                 waiting = [r for r in self._active
                            if r is not None and r.pending_prompt
-                           and not r.cancelled] if self.ragged else []
+                           and not r.cancelled]
                 # a decoding lane outside the chain (swapped back in, or
                 # left out of a round for want of a page; soon: a snapshot
                 # about to be swapped in): chaining on would leave it
@@ -3443,7 +3293,9 @@ class ContinuousBatcher:
                 snapshot = list(self._active)
             if after_round and ahead:
                 # what a round's carry cannot hold of a lane
-                if any(self._host_sampled(r) for _, r in lanes_now):
+                if (any(self._host_sampled(r) for _, r in lanes_now)
+                        or any(self._publishes(r, resumed)
+                               for _l, r, resumed in stash["firsts"])):
                     return None, "host"
                 if any(resumed for _l, _r, resumed in stash["firsts"]):
                     return None, "resumed"

@@ -1606,8 +1606,8 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
     ``kv_lens[b] - q_lens[b] + j``.  Per layer all valid positions' K/V
     scatter into the lane's pages first (invalid positions route to the
     reserved scratch page 0), then attention gathers the lane's whole
-    block table masked by global causality — the gather-after-scatter
-    shape of :func:`paged_extend`, batched over ragged lanes.  One
+    block table masked by global causality: a row sees the cached
+    context and the segment's own writes up to its position.  One
     static ``M`` serves every segment mix: plain decode (``q_lens=1``),
     K+1 speculative verify (``q_lens=k+1``), chunked prefill
     (``q_lens=chunk``) and any combination in one batch.
@@ -1670,9 +1670,8 @@ def paged_ragged_forward(params, kv_pool, tables, seq, q_lens, kv_lens,
 
     x = _streams(spec, x, out=True)
     if last_only:
-        # only each lane's last valid token seeds a pick — run the
-        # vocab-sized head over ONE row per lane (paged_extend's trick,
-        # batched)
+        # only each lane's last valid token seeds a pick: run the
+        # vocab-sized head over ONE row per lane
         x = jnp.take_along_axis(
             x, jnp.maximum(q_lens - 1, 0)[:, None, None], axis=1)[:, 0]
     x = _rmsnorm(x, params["final_norm"]["scale"], spec.rms_eps)
@@ -2037,60 +2036,26 @@ def paged_speculative_block(params, draft_params, kv_pool, packed,
     return results, lengths, tokens, live, steps_rem, kv_pool
 
 
-def paged_prefill(params, kv_pool, tables, tokens, valid_len,
-                  n_heads: int, n_layers: int, compute_dtype,
-                  n_kv_heads: Optional[int] = None,
-                  rope_theta: Optional[float] = None,
-                  attention_fn=None):
-    """Fused prefill: ONE causal forward over the (padded) prompt, with each
-    layer's K/V scattered straight into the lane's pages.
-
-    tokens (1, T_pad) int32 (padded tail arbitrary), valid_len scalar int32,
-    tables (MP,) page ids for this lane.  Padded positions scatter to the
-    reserved scratch page 0.  Returns (last-valid-token logits (vocab,),
-    kv_pool) — the fused pool donated by the caller.
-    """
-    import jax
-    import jax.numpy as jnp
-    from tpulab.models.transformer import (causal_attention,
-                                           transformer_forward_collect_kv)
-
-    page_size = kv_pool.shape[3]
-    t_pad = tokens.shape[1]
-    logits, kvs = transformer_forward_collect_kv(
-        params, tokens, n_heads=n_heads, n_layers=n_layers,
-        compute_dtype=compute_dtype, n_kv_heads=n_kv_heads,
-        rope_theta=rope_theta,
-        attention_fn=attention_fn or causal_attention)
-    pos = jnp.arange(t_pad)
-    valid = pos < valid_len
-    page_idx = jnp.where(valid, tables[pos // page_size], 0)  # scratch if pad
-    slot_idx = jnp.where(valid, pos % page_size, 0)
-    for layer, (k, v) in enumerate(kvs):
-        kv_pool = _scatter_kv(kv_pool, layer, page_idx, slot_idx,
-                              k[0], v[0])
-    last = logits[0, valid_len - 1]
-    return last, kv_pool
-
-
 def paged_extend(params, kv_pool, tables, tokens, start, valid_total,
                  n_heads: int, n_layers: int, compute_dtype,
                  n_kv_heads: Optional[int] = None,
                  rope_theta: Optional[float] = None):
-    """Chunked/tail prefill against EXISTING paged context.
+    """A tail of tokens against EXISTING paged context, one lane: what the
+    speculative draft's warm-up runs (``StepPrograms.draft_extend``, the
+    scheduler's ``_warm_draft``) to fill the draft's page table with the
+    context it is missing.  Prompts themselves ride mixed rounds
+    (:func:`paged_mixed_step`).
 
     One fused forward over M tail tokens (positions ``start ..
     start+M-1``) for a single lane whose positions ``[0, start)`` are
-    already resident in the pool (prefix-cache hits or earlier chunks of a
-    chunked prefill).  Per layer the tail K/V scatter into their pages
-    first, then attention gathers the lane's WHOLE block table — the
-    gather-after-scatter sees cached prefix and tail together, so the mask
-    is just global causality (tail token m attends position j iff
+    already resident in the pool.  Per layer the tail K/V scatter into
+    their pages first, then attention gathers the lane's WHOLE block table:
+    the gather-after-scatter sees resident context and tail together, so
+    the mask is just global causality (tail token m attends position j iff
     ``j <= start+m``).
 
-    tokens (1, M_pad) int32 (padded tail arbitrary); start scalar int32
-    (page-aligned: the tail must never write into a shared prefix page);
-    valid_total scalar int32 = true total length (prompt so far + tail);
+    tokens (1, M_pad) int32 (padded tail arbitrary); start scalar int32;
+    valid_total scalar int32 = true total length (context so far + tail);
     tables (MP,) page ids covering all of it.  Returns (logits of the last
     valid token (vocab,), kv_pool) — the fused pool donated by the caller.
     """
@@ -2135,8 +2100,7 @@ class StepPrograms:
     """The jitted step programs of one engine plan
     (:class:`~tpulab.engine.plan.EnginePlan`), under the parameters'
     (``psh``), the page store's and the replicated sharding (None without a
-    mesh).  ``tick``,
-    ``mixed``, ``compact``, ``prefill``, ``extend`` and ``draft_extend``
+    mesh).  ``tick``, ``mixed``, ``compact`` and ``draft_extend``
     (None where the plan has no such program) hold the jitted callable
     itself, as does what :meth:`block` and :meth:`spec_block` return: the
     scheduler calls it where it dispatches, with no Python frame between
@@ -2150,39 +2114,20 @@ class StepPrograms:
         self.fields = {kind: dispatch_fields(kind, plan.lanes, plan.max_pages,
                                              _windowed(plan.spec))
                        for kind in ("tick", "block", "spec", "round")}
-        attn_fn = None
-        if plan.prefill_flash:
-            from tpulab.ops.flash_attention import make_flash_attention_fn
-            attn_fn = make_flash_attention_fn(causal=True)
         step_kw = plan.step_kw
-        model_kw = {name: step_kw[name] for name in (
-            "n_heads", "n_layers", "compute_dtype", "n_kv_heads",
-            "rope_theta")}
         # a program: function, bound keywords, donated arguments, in and out
         # shardings.  Every array argument is positional (a sharded jit
         # attaches in_shardings by position), the host's one packed buffer
         step = ((1,), (psh, kvsh, rep), (rep, rep, kvsh))
         chained = ((1,), (psh, kvsh, rep, rep), (rep,) * 6 + (kvsh,))
-        one_lane = ((1,), (psh, kvsh, rep, rep, rep, rep), (rep, kvsh))
         table = {
             # the K=1 tick
             "tick": (paged_decode_step_sampled, step_kw) + step,
-            # mixed prefill+decode rounds (the ragged dispatch plan): ONE
-            # program respecializes per pow2 bucket of the round's prefill
-            # tokens (round_width): the chunks packed by token and a row
-            # for each lane's decode token through a single ragged forward
-            # + on-device pick
+            # mixed prefill+decode rounds: ONE program respecializes per
+            # pow2 bucket of the round's prefill tokens (round_width): the
+            # chunks packed by token and a row for each lane's decode token
+            # through a single ragged forward + on-device pick
             "mixed": (paged_mixed_step, step_kw) + chained,
-            # the legacy plan's fused prefill, compiled per prompt-length
-            # bucket (powers of two); ``prefill_flash`` selects the pallas
-            # prompt-attention kernel
-            "prefill": (paged_prefill,
-                        dict(model_kw, attention_fn=attn_fn),
-                        (1,), (psh, kvsh, rep, rep, rep), (rep, kvsh)),
-            # tail/chunk prefill against existing pool context
-            # (prefix-cache hits, chunked long prompts), compiled per
-            # tail-length bucket
-            "extend": (paged_extend, model_kw) + one_lane,
         }
         self.compact = self.draft_extend = None
         if plan.eva_window:
@@ -2196,8 +2141,10 @@ class StepPrograms:
             # draft-table warm-up: one fused draft forward over whatever
             # context tail the second table is missing (never synced)
             table["draft_extend"] = (
-                paged_extend, dict(model_kw, **plan.draft), (1,),
-                (draft_psh,) + one_lane[1][1:], one_lane[2])
+                paged_extend,
+                dict(compute_dtype=plan.compute_dtype,
+                     rope_theta=plan.rope_theta, **plan.draft),
+                (1,), (draft_psh, kvsh, rep, rep, rep, rep), (rep, kvsh))
         for name, (fn, kw, donate, in_sh, out_sh) in table.items():
             setattr(self, name,
                     self._jit(partial(fn, **kw), donate, in_sh, out_sh))
@@ -2256,8 +2203,8 @@ class StepPrograms:
         modes) reuse one compiled-program cache instead of re-tracing
         and re-compiling identical HLO per engine.  Params and pools
         are traced ARGUMENTS, never baked, so sharing is purely a
-        compile-time dedupe; configs with unhashable baked state (e.g.
-        a flash-attention closure) fall back to a private jit.
+        compile-time dedupe; configs with unhashable baked state fall
+        back to a private jit.
 
         With an arbiter measuring scratch, the (shared) jit is wrapped
         per engine so each distinct shape signature records its

@@ -2,12 +2,12 @@
 will be, decided from its arguments before anything is allocated.
 
 :func:`plan_engine` returns an :class:`EnginePlan` (the model's kinds, the
-page store's geometry, ``use_kernel`` and ``ragged``, the round's token
-budget, the keywords the step programs are keyed by) and raises, by name,
-every option a kind of model refuses and every geometry the kernels' rules
-refuse.  It builds no pool and no lane-state store, puts nothing on a device
-and starts no thread, so a refusal has nothing to clean up and a decision
-is tested without an engine (``tests/test_engine_plan.py``).
+page store's geometry, ``use_kernel``, the round's token budget, the
+keywords the step programs are keyed by) and raises, by name, every option
+a kind of model refuses and every geometry the kernels' rules refuse.  It
+builds no pool and no lane-state store, puts nothing on a device and starts
+no thread, so a refusal has nothing to clean up and a decision is tested
+without an engine (``tests/test_engine_plan.py``).
 :func:`kernel_error` is the one place that knows which geometry rule of
 :mod:`tpulab.ops` goes with which kind of model.  Nothing here imports the
 scheduler.
@@ -25,9 +25,9 @@ from tpulab.tpu import platform
 
 @dataclasses.dataclass(frozen=True)
 class EnginePlan:
-    """What :func:`plan_engine` decided.  ``use_kernel`` and ``ragged`` are
-    the constructor's arguments (None: not chosen yet) while the budget is
-    searched, booleans in the plan it returns."""
+    """What :func:`plan_engine` decided.  ``use_kernel`` is the
+    constructor's argument (None: not chosen yet) while the budget is
+    searched, a boolean in the plan it returns."""
 
     #: tpulab.models.spec.ModelSpec: None serves the dense decoder of
     #: ``n_heads``/``n_kv``/``rope_theta`` with today's constants
@@ -85,18 +85,10 @@ class EnginePlan:
     max_pages: int
     #: page-aligned (a chunk's successor writes from a page boundary)
     prefill_chunk: Optional[int]
-    #: the pallas flash kernel for the FULL-PROMPT forward of the legacy plan
-    prefill_flash: bool
     #: attention through the pallas ragged kernel family (the XLA gather
-    #: otherwise)
+    #: otherwise); the dispatch plan is the same either way: prompts ride
+    #: mixed rounds (docs/PERFORMANCE.md "Ragged paged attention")
     use_kernel: Optional[bool]
-    #: ragged dispatch plan (docs/PERFORMANCE.md "Ragged paged attention"):
-    #: mixed prefill+decode rounds as ONE fused program (paged_mixed_step),
-    #: not per-lane prefill dispatches and a separate decode kind.  Default
-    #: rides ``use_kernel`` (the kernel family and the plan ship together);
-    #: ``ragged=True`` forces it onto the XLA gather, ``use_kernel=False``
-    #: alone keeps the legacy split dispatch: the escape hatch
-    ragged: Optional[bool]
     #: the widest token budget of a mixed round THIS engine runs (a power
     #: of two), and what refused the next wider one (None at the ceiling)
     round_cap: int
@@ -165,13 +157,12 @@ class EnginePlan:
         return kw
 
 
-def kernel_error(plan: EnginePlan, cap: int, verify_width: int):
+def kernel_error(plan: EnginePlan, cap: int):
     """What the kernels' geometry rules say of ``plan`` (None: admitted):
     Mosaic's shape rule at the PER-SHARD geometry (one shard's program is
     the one that must build) and the widest segment a dispatch can carry: a
     mixed round that spends a token budget of ``cap`` (``prefill_chunk``
-    lowers it) under the ragged plan, a K+1 verify of ``verify_width``
-    otherwise."""
+    lowers it)."""
     from tpulab.ops.ragged_attention import (kernel_geometry_error,
                                              latent_geometry_error)
     spec = plan.spec
@@ -189,8 +180,7 @@ def kernel_error(plan: EnginePlan, cap: int, verify_width: int):
                                      plan.page_size)
         if err:
             return err
-    widest = (round_width(min(plan.prefill_chunk or cap, cap))
-              if plan.ragged is not False else verify_width)
+    widest = round_width(min(plan.prefill_chunk or cap, cap))
     if plan.latent:
         return latent_geometry_error(*_latent_call(plan, widest))
     return kernel_geometry_error(
@@ -212,11 +202,9 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                 n_kv_heads: Optional[int], rope_theta: Optional[float],
                 d_model: int, vocab: int, lanes: int, max_len: int,
                 page_size: int, prefill_chunk: Optional[int],
-                use_kernel: Optional[bool], ragged: Optional[bool],
-                prefill_flash: Optional[bool], compute_dtype, kv_dtype,
+                use_kernel: Optional[bool], compute_dtype, kv_dtype,
                 round_ceiling: int, kernel_auto_min_ctx: int,
-                verify_width: int, pool=None, mesh=None, hbm=None,
-                draft_params=None,
+                pool=None, mesh=None, hbm=None, draft_params=None,
                 draft_n_layers: Optional[int] = None,
                 draft_n_heads: Optional[int] = None,
                 draft_n_kv_heads: Optional[int] = None, kv_offload=None,
@@ -224,10 +212,10 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                 prefix_cache: bool = False) -> EnginePlan:
     """The :class:`EnginePlan` of a scheduler built with these arguments
     (``ContinuousBatcher``'s own, under their names).  ``d_model`` and
-    ``vocab`` are the embedding's shape; ``round_ceiling``,
-    ``kernel_auto_min_ctx`` and ``verify_width`` the scheduler class's
-    ``RAGGED_CHUNK_CAP``, ``KERNEL_AUTO_MIN_CTX`` and widest block + 1.  Of
-    ``hbm`` and ``kv_offload`` it reads whether they are there, of
+    ``vocab`` are the embedding's shape; ``round_ceiling`` and
+    ``kernel_auto_min_ctx`` the scheduler class's ``RAGGED_CHUNK_CAP`` and
+    ``KERNEL_AUTO_MIN_CTX``.  Of ``hbm`` and ``kv_offload`` it reads
+    whether they are there, of
     ``draft_params`` the draft's width, of a provided ``pool`` its
     ``dtype``, ``entry_kind``, ``n_layers``, ``mesh`` and whether it has
     ``index`` rows."""
@@ -241,11 +229,10 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                                     or spec.moe_layers or hybrid or eva
                                     or windowed)
     if special:
-        # such a model is served on the ragged plan alone: the options that
-        # plan, that cache-entry kind or a per-lane state (nothing
-        # snapshots, shares or ships it yet) does not carry are refused here
+        # the options that such a model's cache-entry kind or per-lane
+        # state (nothing snapshots, shares or ships it yet) does not carry
+        # are refused here
         refused = {
-            "ragged=False (the legacy split plan)": ragged is False,
             "draft_params (speculative blocks)": draft_params is not None,
             "mesh": mesh is not None
             or getattr(pool, "mesh", None) is not None,
@@ -272,8 +259,7 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                            | ({"hyper-connected residual streams"}
                               if spec.hc_mult else set()))
             raise NotImplementedError(
-                f"a model with {', '.join(kinds)} is served on the "
-                "ragged plan only; not supported with it: "
+                f"a model with {', '.join(kinds)} is not supported with: "
                 + ", ".join(bad))
         if (spec.n_heads, spec.n_layers) != (n_heads, n_layers):
             raise ValueError(
@@ -284,7 +270,6 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
                 f"page_size {page_size} is not the spec's eva_chunk "
                 f"{spec.eva_chunk}: a chunk's summary is taken from one "
                 "page and written as one row")
-        ragged = True
     kv_dtype = kv_dtype or compute_dtype
     n_kv = (spec.n_kv_heads if hybrid or sparse or eva or windowed
             else n_kv_heads or n_heads)
@@ -335,20 +320,6 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
             "mesh is not supported: grow/shrink per-shard accounting "
             "is untested — serve the arbiter single-device, or the "
             "mesh without an arbiter (hbm=None)")
-    if mesh is not None:
-        if prefill_flash:
-            raise ValueError(
-                "the pallas flash prefill kernel is single-device; "
-                "mesh serving prefills through the dense or ragged "
-                "paths (prefill_flash must be False or None)")
-        prefill_flash = False
-    elif prefill_flash is None:
-        # auto: pallas flash attention for the FULL-PROMPT forward on TPU
-        # (O(T*block) VMEM instead of a dense (T, T) score
-        # materialization).  Scope: the start==0 un-chunked prefill only:
-        # chunked prefills and prefix-cache tails run paged_extend's gather
-        # attention, which has no flash analog here.
-        prefill_flash = platform.is_tpu()
     n_shards = int(dict(mesh.shape).get("model", 1)) if mesh is not None else 1
     if use_kernel and mesh is not None and n_heads % n_shards:
         raise ValueError(
@@ -396,8 +367,7 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
         window=spec.window if windowed else 0,
         window_layers=dict(spec.page_groups)["window"] if windowed else 0,
         index_dim=spec.index_dim if sparse else 0, max_pages=max_pages,
-        prefill_chunk=prefill_chunk, prefill_flash=bool(prefill_flash),
-        use_kernel=use_kernel, ragged=ragged, round_cap=cap,
+        prefill_chunk=prefill_chunk, use_kernel=use_kernel, round_cap=cap,
         round_budget_why=why, draft=draft)
 
     # auto: the pallas ragged kernel on TPU at LONG contexts only (where
@@ -415,8 +385,7 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
         # the widest round under ``cap`` the rule admits, and what it said
         # of the next wider one; 0 where it admits no width
         admitted, refusal = cap, None
-        while admitted and (err := kernel_error(plan, admitted,
-                                                verify_width)):
+        while admitted and (err := kernel_error(plan, admitted)):
             admitted, refusal = admitted // 2, err
         if admitted:
             cap, why = admitted, refusal or why
@@ -435,6 +404,5 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
         tile = {"round": latent_tile(*_latent_call(plan, widest)),
                 "one_row": latent_tile(*_latent_call(plan, 1))}
     return dataclasses.replace(
-        plan, use_kernel=bool(use_kernel),
-        ragged=bool(use_kernel if ragged is None else ragged),
-        round_cap=cap, round_budget_why=why, latent_tile=tile)
+        plan, use_kernel=bool(use_kernel), round_cap=cap,
+        round_budget_why=why, latent_tile=tile)

@@ -169,22 +169,18 @@ def _lm_head(params, x):
 
 
 def _forward(params, tokens, n_heads, n_layers, compute_dtype, attention_fn,
-             collect_kv: bool = False, ffn_fn=_dense_ffn,
-             n_kv_heads: Optional[int] = None,
+             ffn_fn=_dense_ffn, n_kv_heads: Optional[int] = None,
              rope_theta: Optional[float] = None):
-    """Shared transformer trunk: (B, T) tokens -> (logits, kvs or None).
+    """Shared transformer trunk: (B, T) tokens -> logits.
     ``ffn_fn(layer_params, h, compute_dtype)`` swaps the FFN (dense / MoE).
-    ``collect_kv`` returns the UNexpanded (B, T, Hkv, D) heads — the
-    compact form KV caches/pools store under GQA.  ``rope_theta`` enables
-    rotary embeddings at absolute positions 0..T-1 (collected K is rotated,
-    matching the decode paths' position-baked caches).  Under sequence
-    parallelism pass pre-roped inputs or keep rope off here."""
+    ``rope_theta`` enables rotary embeddings at absolute positions 0..T-1.
+    Under sequence parallelism pass pre-roped inputs or keep rope off
+    here."""
     n_kv = n_kv_heads or n_heads
     emb = params["embed"].astype(compute_dtype)
     x = emb[tokens]
     b, t, d_model = x.shape
     head_dim = d_model // n_heads
-    kvs = [] if collect_kv else None
     positions = jnp.arange(t) if rope_theta else None
     for i in range(n_layers):
         p = params[f"layer{i}"]
@@ -194,15 +190,13 @@ def _forward(params, tokens, n_heads, n_layers, compute_dtype, attention_fn,
         if rope_theta:
             q = apply_rope(q, positions, rope_theta)
             k = apply_rope(k, positions, rope_theta)
-        if collect_kv:
-            kvs.append((k, v))
         attn = attention_fn(q, repeat_kv(k, n_heads),
                             repeat_kv(v, n_heads)).reshape(b, t, d_model)
         x = x + attn @ qmat(p["wo"], compute_dtype)
         h = _rmsnorm(x, p["ln2"]["scale"])
         x = x + ffn_fn(p, h, compute_dtype).astype(x.dtype)
     x = _rmsnorm(x, params["final_norm"]["scale"])
-    return _lm_head(params, x), kvs
+    return _lm_head(params, x)
 
 
 def transformer_apply(params: Dict[str, Any], inputs: Dict[str, jnp.ndarray],
@@ -213,10 +207,9 @@ def transformer_apply(params: Dict[str, Any], inputs: Dict[str, jnp.ndarray],
                       rope_theta: Optional[float] = None
                       ) -> Dict[str, jnp.ndarray]:
     """tokens (B, T) int32 -> logits (B, T, vocab) f32."""
-    logits, _ = _forward(params, inputs["tokens"], n_heads, n_layers,
-                         compute_dtype, attention_fn,
-                         n_kv_heads=n_kv_heads, rope_theta=rope_theta)
-    return {"logits": logits}
+    return {"logits": _forward(params, inputs["tokens"], n_heads, n_layers,
+                               compute_dtype, attention_fn,
+                               n_kv_heads=n_kv_heads, rope_theta=rope_theta)}
 
 
 def make_transformer(vocab: int = 32000, d_model: int = 512, n_heads: int = 8,
@@ -382,22 +375,6 @@ def make_generate_fn(params: Dict[str, Any], n_heads: int, n_layers: int,
     return jax.jit(generate, static_argnums=1)
 
 
-def transformer_forward_collect_kv(params: Dict[str, Any],
-                                   tokens: jnp.ndarray,
-                                   n_heads: int = 8, n_layers: int = 6,
-                                   compute_dtype=jnp.bfloat16,
-                                   attention_fn: Callable = causal_attention,
-                                   n_kv_heads: Optional[int] = None,
-                                   rope_theta: Optional[float] = None):
-    """Causal forward over (B, T) tokens that also returns each layer's
-    K/V (B, T, Hkv, Dh) — the fused-prefill building block: one forward
-    fills a whole prompt's KV instead of T decode steps.  Shares the trunk
-    with :func:`transformer_apply` (single source of truth)."""
-    return _forward(params, tokens, n_heads, n_layers, compute_dtype,
-                    attention_fn, collect_kv=True, n_kv_heads=n_kv_heads,
-                    rope_theta=rope_theta)
-
-
 def early_exit_draft(target_params: Dict[str, Any],
                      draft_layers: int) -> Dict[str, Any]:
     """Self-speculative draft: the target's first ``draft_layers`` layers
@@ -460,9 +437,9 @@ def make_moe_transformer(vocab: int = 32000, d_model: int = 512,
                        compute_dtype=cdtype).reshape(b, t, dm)
 
     def apply_fn(p, inputs):
-        logits, _ = _forward(p, inputs["tokens"], n_heads, n_layers,
-                             compute_dtype, attention_fn, ffn_fn=moe_block)
-        return {"logits": logits}
+        return {"logits": _forward(p, inputs["tokens"], n_heads, n_layers,
+                                   compute_dtype, attention_fn,
+                                   ffn_fn=moe_block)}
 
     return Model(
         name="moe_transformer",

@@ -5,8 +5,6 @@ VMEM scheduling wins (the role .cu kernels would play in a CUDA framework —
 the reference has none because TensorRT owns its kernels; a TPU-native
 framework owns its hot ops):
 
-- :mod:`flash_attention` — blockwise-softmax attention, O(T) memory,
-  MXU-shaped 128x128 tiles (drop-in ``attention_fn`` for the transformer)
 - :mod:`ragged_attention` — the ragged paged-attention kernel FAMILY:
   per-lane ``(query_len, kv_len)`` segments serve plain decode (q=1),
   K+1 speculative verify, and mixed chunked-prefill+decode batches in
@@ -23,8 +21,6 @@ framework owns its hot ops):
   (the kernel under ``tpulab.parallel.moe.expert_ffn``)
 """
 
-from tpulab.ops.flash_attention import flash_attention, make_flash_attention_fn
 from tpulab.ops.ragged_attention import ragged_paged_attention
 
-__all__ = ["flash_attention", "make_flash_attention_fn",
-           "ragged_paged_attention"]
+__all__ = ["ragged_paged_attention"]

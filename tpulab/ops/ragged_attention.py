@@ -63,8 +63,7 @@ XLA form of the step rounds them; a float32 store keeps both at
 ``HIGHEST``.  Running maximum, normaliser and accumulator are float32.
 
 Per-head compute rides the flash-attention dot shapes (2D matmuls only,
-the Mosaic-serialization-safe subset :mod:`flash_attention` already
-uses): in the rows kernel, for each query head, the block's scores are
+the Mosaic-serialization-safe subset): in the rows kernel, for each query head, the block's scores are
 ``q_h (M, D) x k_h^T -> (M, G*S)`` and the weighted values
 ``p (M, G*S) x v_h -> (M, D)``; in the one-row kernel, for each KV head,
 ``q_g (g, D) x k^T -> (g, G*S)`` and ``p (g, G*S) x v -> (g, D)``; the
@@ -484,7 +483,7 @@ def _ragged_attn_kernel(layer_ref, tables_ref, runs_ref, qlens_ref, kvlens_ref,
         q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(dt)  # (M, H*D)
         # flash-style 2D dots only (the Mosaic-safe subset): scores contract
         # over D with the K block transposed, values with the standard
-        # orientation — see tpulab.ops.flash_attention._attn_kernel
+        # orientation
         dot_qk = functools.partial(
             jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
