@@ -249,6 +249,10 @@ def test_rows_without_a_token_leave_every_layer_finite(models, model,
     for name in ("_ragged_attn", "ragged_latent_attention"):
         monkeypatch.setattr(ragged_attention, name,
                             unwritten_is_nan(getattr(ragged_attention, name)))
+    # (the K/V walk's lane loop is a jitted helper, kept a process: here a
+    # fresh one, traced over the patched kernel)
+    monkeypatch.setattr(paged_steps, "_jitted",
+                        paged_steps._jitted.__wrapped__)
     layers, block = [], paged_steps._layer_block
 
     def spy(*a):
@@ -643,6 +647,10 @@ def test_mixed_attn_rows_counts_m_a_chunk_lane_and_one_a_decode_lane():
         r["width"] * r["chunk_lanes"] + r["decode_lanes"] for r in rounds)
     assert (state["mixed_tokens"] <= state["mixed_attn_rows"]
             < sum(r["width"] * cb.lanes for r in rounds))
+    # ... and the chunk lanes themselves, a call of the K/V walk each (PR
+    # 59): more than one a round here, never more than the lanes
+    assert state["round_chunk_lanes"] == sum(r["chunk_lanes"] for r in rounds)
+    assert len(rounds) == state["kinds"]["mixed"] < state["round_chunk_lanes"]
 
 
 def test_every_mixed_program_is_reached_by_a_single_prompt():

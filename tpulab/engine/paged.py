@@ -569,6 +569,11 @@ class ContinuousBatcher:
         self.mixed_rows = 0
         self.mixed_tokens = 0
         self.mixed_attn_rows = 0
+        #: the lanes that held a chunk, summed over the rounds: the K/V
+        #: walk makes one call of ``M`` rows a chunk lane
+        #: (``paged_steps._kv_walk``), so over ``dispatch_kinds["mixed"]``
+        #: it is the rows-kernel calls a layer a round
+        self.round_chunk_lanes = 0
         #: how far the round's budget engages: the prompt tokens the rounds
         #: carried (``mixed_tokens`` less the decode rows) and the rounds
         #: that spent the whole budget
@@ -1545,6 +1550,7 @@ class ContinuousBatcher:
                          "mixed_rows": self.mixed_rows,
                          "mixed_tokens": self.mixed_tokens,
                          "mixed_attn_rows": self.mixed_attn_rows,
+                         "round_chunk_lanes": self.round_chunk_lanes,
                          "round_budget": self._round_budget,
                          "round_budget_why": self.round_budget_why,
                          "latent_tile": self.plan.latent_tile,
@@ -2555,6 +2561,7 @@ class ContinuousBatcher:
         self.budget_rounds += left == 0
         self.mixed_attn_rows += ((len(toks) - b) * len(segs)
                                  + len(decode_parts))
+        self.round_chunk_lanes += len(segs)
         self.mixed_decode_rows += len(decode_parts)
         self._note_walk(req for _, req in decode_parts)
         if chain is not None:

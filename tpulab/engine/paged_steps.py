@@ -46,12 +46,21 @@ what the scheduler dispatches through.  Nothing here imports the scheduler
 from __future__ import annotations
 
 import threading
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, Optional
 
 import numpy as np
 
 from tpulab.engine.kv_pool import kv_rows_view
+
+
+@lru_cache(maxsize=None)
+def _jitted(fn, static=()):
+    """``jax.jit(fn)`` made once (this module imports JAX where it runs,
+    not where it is imported): a helper that every layer of a program
+    calls at one shape is then traced and lowered once."""
+    import jax
+    return jax.jit(fn, static_argnames=static)
 
 
 def _scatter_kv(kv_pool, layer, page_idx, slot_idx, knew, vnew):
@@ -140,7 +149,10 @@ def _step_spec(spec, d_model: int, n_heads: int, n_layers: int, n_kv_heads,
 
 
 def _segment_calls(q, pos, seg):
-    """The attention calls of one layer, ``(q, q_lens, qpos)`` each.  One,
+    """The attention calls of one layer in the SPREAD form, ``(q, q_lens,
+    qpos)`` each: what the latent attention takes while its spread is small
+    (:data:`SPREAD_LIMIT_BYTES`; the K/V walk takes a round's chunk rows a
+    lane at a time, :func:`_kv_walk`).  One,
     as given, in a decode step and in the padded form.  A packed round
     (``seg["rows"]``, see :func:`_layer_block`; q is ``(1, T, ...)``) makes
     one a segment kind, at the width the kind has, since a call computes
@@ -182,32 +194,37 @@ def _segment_rows(outs, seg):
                   mode="clip"), dec[:, 0]])[None]
 
 
-#: A packed round's chunk rows reach the attention spread to the padded
-#: ``(lanes, M)`` form (:func:`_segment_calls`) while that form is small.
-#: Past this many bytes of spread queries a layer, the latent attention
-#: takes them one chunk LANE at a time (:func:`_chunk_lanes`): the spread
-#: costs what ``lanes x M`` rows cost whichever lanes hold a chunk, and at
-#: 32 lanes of 64 heads of 576 it was 1.2 GB a layer for one lane's 37 MB,
-#: 145 of a 187 ms round with its copies (PERF.md section 6, PR 46).  The
-#: configurations under it (``glm4_moe_lite``: 94 MB) keep the programs
-#: they had.
+#: A packed round's chunk rows reach the LATENT attention spread to the
+#: padded ``(lanes, M)`` form (:func:`_segment_calls`) while that form is
+#: small.  Past this many bytes of spread queries a layer it takes them one
+#: chunk LANE at a time (:func:`_chunk_lanes`), as the K/V rows kernel's
+#: callers always do (:func:`_kv_walk`): the spread costs what ``lanes x M``
+#: rows cost whichever lanes hold a chunk, and at 32 lanes of 64 heads of
+#: 576 it was 1.2 GB a layer for one lane's 37 MB, 145 of a 187 ms round
+#: with its copies (PERF.md section 6, PR 46).  The configurations under it
+#: (``glm4_moe_lite``: 94 MB) keep the programs they had.
 SPREAD_LIMIT_BYTES = 256 << 20
 
 
-def _chunk_lanes(q, seg, attend):
+def _chunk_lanes(q, seg, tables, attend, width=None):
     """The chunk rows ``q[:M]`` of a packed round (``q (T, ...)``, see
     :func:`_layer_block`) through ``attend`` one chunk LANE at a time, in
     the order the lanes' rows are packed: ``(M, ...)`` rows of what
-    ``attend`` returns.
+    ``attend`` returns, shaped and typed as the queries but ``width`` wide
+    where that is given (the latent attention's value width).
 
     ``attend(qq (1, M, ...), tables (1, MP), q_lens (1,), kv_lens (1,),
-    qpos (1, M)) -> (1, M, ...)`` is one lane's call.  A lane's chunk is
-    consecutive rows from its first, so its queries are a slice of ``M``
-    rows there (``q_lens`` says how many are its own) and no row is
-    gathered; its result is written back over the same rows, and what it
-    leaves behind its own rows the next lane's result overwrites, the last
-    lane's falls on rows that hold no token.  The loop runs as many times
-    as lanes hold a chunk."""
+    qpos (1, M)) -> (1, M, ...)`` is one lane's call, on the lane's cut of
+    exactly what the padded ``(B, M)`` call took: its row of ``tables``
+    (the layer's own: a window layer's is the window group's), of
+    ``seg["kv_lens"]`` and of ``qpos`` (rows of the table, which under EVA
+    windows are not positions).  A lane's chunk is consecutive rows from
+    its first, so its queries are a slice of ``M`` rows there (``q_lens``
+    says how many are its own) and no row is gathered; its result is
+    written back over the same rows, and what it leaves behind its own
+    rows the next lane's result overwrites, the last lane's falls on rows
+    that hold no token.  The loop runs as many times as lanes hold a
+    chunk."""
     import jax
     import jax.numpy as jnp
 
@@ -218,22 +235,108 @@ def _chunk_lanes(q, seg, attend):
     order = jnp.argsort(jnp.where(held, first, m))
     rows = jnp.pad(q[:m], ((0, m),) + ((0, 0),) * (q.ndim - 1))
 
-    def one(lane, at):
+    def body(i, out):
+        lane, at = order[i], first[order[i]]
         cut = partial(jax.lax.dynamic_slice_in_dim, start_index=lane,
                       slice_size=1)
-        return attend(jax.lax.dynamic_slice_in_dim(rows, at, m)[None],
-                      cut(seg["tables"]), cut(chunk_lens),
-                      cut(seg["kv_lens"]), cut(qpos))[0]
-
-    def body(i, out):
-        at = first[order[i]]
+        one = attend(jax.lax.dynamic_slice_in_dim(rows, at, m)[None],
+                     cut(tables), cut(chunk_lens), cut(seg["kv_lens"]),
+                     cut(qpos))[0]
         return jax.lax.dynamic_update_slice_in_dim(
-            out, one(order[i], at).astype(out.dtype), at, 0)
+            out, one.astype(out.dtype), at, 0)
 
-    like = jax.eval_shape(one, order[0], first[0])
-    out = jax.lax.fori_loop(
-        0, held.sum(), body, jnp.zeros((2 * m,) + like.shape[1:], like.dtype))
+    out = jax.lax.fori_loop(0, held.sum(), body, jnp.zeros(
+        (2 * m,) + q.shape[1:-1] + (width or q.shape[-1],), q.dtype))
     return out[:m]
+
+
+def _lane_calls(q, seg, tables, attend, width=None):
+    """A packed round's attention without the ``(B, M)`` form: the chunk
+    rows ``q[0, :M]`` a lane at a time (:func:`_chunk_lanes`) and the
+    decode rows ``[M, M + B)`` as the ``(B, 1)`` call they are, as the
+    round's rows ``(1, T, ...)`` again."""
+    import jax.numpy as jnp
+
+    _spread, _back, qpos, _chunk, dec_lens = seg["rows"]
+    return jnp.concatenate([
+        _chunk_lanes(q[0], seg, tables, attend, width),
+        attend(q[0, qpos.shape[1]:, None], tables, dec_lens, seg["kv_lens"],
+               seg["kv_lens"][:, None] - 1)[:, 0]])[None]
+
+
+def _kv_call(kv_pool, at, seg, compute_dtype, window):
+    """ONE call of the attention over layer ``at`` of a K/V page store, by
+    the dispatch's path (``seg["use_kernel"]``, ``kernel_geometry``,
+    ``mesh``): ``attend(qq (B, M, H, D), tables, q_lens, kv_lens, qpos) ->
+    (B, M, H, D)``."""
+    import jax.numpy as jnp
+
+    def attend(qq, tables, q_lens, kv_lens, qpos):
+        if not seg["use_kernel"]:
+            # XLA fallback: gather pages densely then mask
+            return _gather_attend(
+                qq, kv_pool[at, :, 0], kv_pool[at, :, 1], tables, qpos,
+                compute_dtype, window).reshape(qq.shape)
+        # pallas ragged kernel: walks block tables page-by-page, no dense
+        # gather materialization; fused pages = 1 DMA/page; under a mesh
+        # the walk shards on the KV-heads dim via shard_map
+        # (tpulab.ops.ragged_attention)
+        from tpulab.ops import ragged_attention as ra
+        gk, nk = seg["kernel_geometry"] or (None, None)
+        if seg["mesh"] is not None:
+            return ra.ragged_paged_attention(
+                qq, kv_pool, at, tables, q_lens, kv_lens, mesh=seg["mesh"],
+                g_pages=gk, nbuf=nk, window=window)
+        # the jitted entry itself, not ``ragged_paged_attention`` around
+        # it: one Python frame fewer above the kernel
+        from tpulab.tpu.platform import pallas_interpret
+        return ra._ragged_attn(
+            qq, kv_pool, jnp.asarray(at, jnp.int32).reshape(1), tables,
+            q_lens, kv_lens, pallas_interpret(), g_pages=gk, nbuf=nk,
+            window=window)
+    return attend
+
+
+def _kernel_lane_calls(q, rows, kv_lens, tables, kv_pool, at, geometry,
+                       window):
+    """:func:`_lane_calls` over the K/V kernels on one device, every
+    operand an argument (``at`` a traced scalar) and ``geometry`` and
+    ``window`` static: what :func:`_kv_walk` jits, so that the layers of a
+    program trace and lower the lane loop ONCE a shape (as they do the
+    kernel's jitted entry; inline, the loop cost ~30 ms a layer a program
+    of the host's time to trace and lower, half again a dense round's:
+    ``setup_s``)."""
+    seg = dict(rows=rows, kv_lens=kv_lens, use_kernel=True,
+               kernel_geometry=geometry, mesh=None)
+    return _lane_calls(q, seg, tables,
+                       _kv_call(kv_pool, at, seg, None, window))
+
+
+def _kv_walk(q, pos, kv_pool, at, tables, seg, compute_dtype, window=0):
+    """The walk over a layer's K/V pages, for every caller of the K/V
+    kernels (the plain GQA walk of :func:`_layer_block` and
+    :func:`_gated_attention`): ``q (B, M, H, D)`` against layer ``at`` of
+    ``kv_pool`` under ``tables``, in ``q``'s shape.  A decode step and the
+    padded form are ONE call as given.  A packed round (``seg["rows"]``; q
+    is ``(1, T, H, D)``) is one call a chunk LANE over its ``M`` rows
+    (:func:`_chunk_lanes`: no program writes the ``(B, M)`` form of the
+    queries or reads that form of the result) and the decode rows ``[M, M
+    + B)``, which ARE ``(B, 1)``, in one call: a lane that holds a chunk
+    pays ``M`` query rows, a decoding lane one, an idle lane none.  Both
+    forms of a call take this path, the Pallas ragged kernel
+    (``seg["use_kernel"]``) and the XLA gather."""
+    packed = seg.get("rows")
+    if packed is not None and seg["use_kernel"] and seg["mesh"] is None:
+        return _jitted(_kernel_lane_calls, ("geometry", "window"))(
+            q, packed, seg["kv_lens"], tables, kv_pool, at,
+            geometry=seg["kernel_geometry"], window=window)
+    attend = _kv_call(kv_pool, at, seg, compute_dtype, window)
+    if packed is None:
+        # (``qpos``: the rows the causal mask runs on, where they are not
+        # the positions RoPE turns: EVA windows)
+        return attend(q, tables, seg.get("q_lens"), seg.get("kv_lens"),
+                      seg.get("qpos", pos))
+    return _lane_calls(q, seg, tables, attend)
 
 
 def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
@@ -277,42 +380,29 @@ def _mla_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx, seg,
         qa = jnp.concatenate(
             [jnp.einsum("bmhn,hnc->bmhc", q[..., :nope],
                         qmat(p["w_uk"], compute_dtype)), qr], axis=-1)
+
+        def attend(qq, tables, q_lens, kv_lens, qpos):    # one call
+            if seg["use_kernel"]:
+                from tpulab.ops.ragged_attention import (
+                    ragged_latent_attention)
+                return ragged_latent_attention(
+                    qq, kv_pool, layer, tables, q_lens, kv_lens,
+                    v_width=spec.kv_lora_rank, sm_scale=scale)
+            return _gather_attend_latent(
+                qq, kv_pool[layer, :, 0], tables, qpos, spec.kv_lora_rank,
+                scale, compute_dtype)
         packed = seg.get("rows")
         if packed is not None and (packed[2].size * spec.n_heads * qa.shape[-1]
                                    * qa.dtype.itemsize > SPREAD_LIMIT_BYTES):
             # a wide round: the chunk rows a lane at a time, the decode
             # rows [M, M + B) as the (B, 1) call they are
-            def attend(qq, tables, q_lens, kv_lens, qpos):
-                if seg["use_kernel"]:
-                    from tpulab.ops.ragged_attention import (
-                        ragged_latent_attention)
-                    return ragged_latent_attention(
-                        qq, kv_pool, layer, tables, q_lens, kv_lens,
-                        v_width=spec.kv_lora_rank, sm_scale=scale)
-                return _gather_attend_latent(
-                    qq, kv_pool[layer, :, 0], tables, qpos,
-                    spec.kv_lora_rank, scale, compute_dtype)
-            cut = packed[2].shape[1]
-            lat = jnp.concatenate([
-                _chunk_lanes(qa[0], seg, attend),
-                attend(qa[0, cut:, None], seg["tables"], packed[4],
-                       seg["kv_lens"], seg["kv_lens"][:, None] - 1)[:, 0]])[
-                           None]
+            lat = _lane_calls(qa, seg, seg["tables"], attend,
+                              spec.kv_lora_rank)
         else:
-            outs = []
-            for qq, q_lens, qpos in _segment_calls(qa, pos, seg):
-                if seg["use_kernel"]:
-                    from tpulab.ops.ragged_attention import (
-                        ragged_latent_attention)
-                    outs.append(ragged_latent_attention(
-                        qq, kv_pool, layer, seg["tables"], q_lens,
-                        seg["kv_lens"], v_width=spec.kv_lora_rank,
-                        sm_scale=scale))
-                else:
-                    outs.append(_gather_attend_latent(
-                        qq, kv_pool[layer, :, 0], seg["tables"], qpos,
-                        spec.kv_lora_rank, scale, compute_dtype))
-            lat = _segment_rows(outs, seg)                   # (b, m, H, C)
+            lat = _segment_rows([
+                attend(qq, seg["tables"], q_lens, seg.get("kv_lens"), qpos)
+                for qq, q_lens, qpos in _segment_calls(qa, pos, seg)],
+                seg)                                         # (b, m, H, C)
         attn = jnp.einsum("bmhc,hcv->bmhv", lat.astype(compute_dtype),
                           qmat(p["w_uv"], compute_dtype))
         return attn.reshape(b, m, -1), kv_pool
@@ -790,8 +880,7 @@ def _gated_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx,
     RMSNorm over each head of q and k and RoPE over the first
     ``spec.rotary_dim`` columns of a head: ``(attn * sigmoid(gate) (B, M, H
     * D), kv_pool)`` on ``"kv"`` pages.  The walk over the pages is the
-    dense decoder's (:func:`_layer_block`), written out here so that its
-    own path keeps the frames it has."""
+    dense decoder's (:func:`_kv_walk`)."""
     import jax
     import jax.numpy as jnp
     from tpulab.models.transformer import _rmsnorm, apply_rope, qmat
@@ -816,21 +905,8 @@ def _gated_attention(spec, p, layer, h, pos, kv_pool, page_idx, slot_idx,
     kv_pool = _scatter_kv(kv_pool, at, page_idx, slot_idx,
                           knew.reshape(page_idx.shape + tail),
                           vnew.reshape(page_idx.shape + tail))
-    outs = []
-    for qq, q_lens, qpos in _segment_calls(q, pos, seg):
-        if not seg["use_kernel"]:
-            outs.append(_gather_attend(
-                qq, kv_pool[at, :, 0], kv_pool[at, :, 1], seg["tables"],
-                qpos, compute_dtype).reshape(qq.shape))
-            continue
-        from tpulab.ops import ragged_attention as ra
-        from tpulab.tpu.platform import pallas_interpret
-        gk, nk = seg["kernel_geometry"] or (None, None)
-        outs.append(ra._ragged_attn(
-            qq, kv_pool, jnp.asarray(at, jnp.int32).reshape(1),
-            seg["tables"], q_lens, seg["kv_lens"], pallas_interpret(),
-            g_pages=gk, nbuf=nk))
-    attn = _segment_rows(outs, seg).astype(compute_dtype).reshape(b, m, hq, d)
+    attn = _kv_walk(q, pos, kv_pool, at, seg["tables"], seg,
+                    compute_dtype).astype(compute_dtype)
     with jax.named_scope("attn_gate"):
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
             compute_dtype)
@@ -1013,9 +1089,12 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     (T,), qpos (B, M), chunk_lens (B,), decode_lens (B,))`` holds the row
     behind each slot of the (B, M) form, the slot behind each row, and
     ``q_lens`` split by segment kind.  The attention is called once a
-    kind (:func:`_segment_calls`): a lane that holds a chunk pays M query
-    rows, a decoding lane one, an idle lane none, where one call at (B,
-    M) made every lane pay M (PR 33: 65 of a round's 79 ms at 8 lanes).
+    chunk LANE and once for the decode rows (:func:`_kv_walk`; the latent
+    and the sparse attention once a segment kind, :func:`_segment_calls`):
+    a lane that holds a chunk pays M query rows, a decoding lane one, an
+    idle lane none, where one call at (B, M) made every lane pay M (PR 33:
+    65 of a round's 79 ms at 8 lanes) and the (B, M) form of the queries
+    alone a fifth of a round at 32 lanes (PR 59).
     Returns ``(x, kv_pool, stats)``: ``stats`` is the expert layer's
     ``(E + 2,)`` int32 counters
     (:func:`tpulab.parallel.moe.routing_stats`) or None on a dense layer.
@@ -1073,13 +1152,18 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     ``"shortcut"`` layer's pair travels: the state is a function of the
     token, handed on inside the program and never stored.
 
-    Kept short, the K/V kernel called from here and the rest in functions
-    of their own: on the v5e host, tracing a kernel body costs more with
-    every Python frame between the step function and the ``pallas_call``
-    (PR 28, my chip runs: a kernel's trace took 0.63 s a program with the
-    parent's frames, 0.87-0.97 s behind one more, 1.42 s behind four more
-    and a helper inside the kernel; the dense cell's set-up grew 10 %,
-    96 -> 106 s, until the count was the parent's again).
+    Kept short, the mixers and the K/V walk (:func:`_kv_walk`) in
+    functions of their own.  What the host spends tracing and lowering
+    these lines it spends once a layer a program, and that is ``setup_s``:
+    on the v5e host, tracing a kernel body cost more with every Python
+    frame between the step function and the ``pallas_call`` (PR 28, my
+    chip runs: a kernel's trace took 0.63 s a program with the parent's
+    frames, 0.87-0.97 s behind one more, 1.42 s behind four more and a
+    helper inside the kernel; the dense cell's set-up grew 10 %, 96 -> 106
+    s, until the count was the parent's again; PR 35 found the cause in
+    CPython's frame chunks, ``core/threads.on_one_frame_chunk``), and a
+    loop written out in every layer is traced and lowered in every layer
+    (PR 59: :func:`_kernel_lane_calls`).
     """
     from contextlib import nullcontext
 
@@ -1169,34 +1253,8 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         kv_pool = _scatter_kv(kv_pool, at, page_idx, slot_idx,
                               knew.reshape(page_idx.shape + tail),
                               vnew.reshape(page_idx.shape + tail))
-        outs = []
-        for qq, q_lens, qpos in _segment_calls(q, pos, seg):
-            if not seg["use_kernel"]:
-                # XLA fallback: gather pages densely then mask
-                outs.append(_gather_attend(
-                    qq, kv_pool[at, :, 0], kv_pool[at, :, 1], tables,
-                    qpos, compute_dtype, window).reshape(qq.shape))
-                continue
-            # pallas ragged kernel: walks block tables page-by-page, no
-            # dense gather materialization; fused pages = 1 DMA/page;
-            # under a mesh the walk shards on the KV-heads dim via
-            # shard_map (tpulab.ops.ragged_attention)
-            from tpulab.ops import ragged_attention as ra
-            gk, nk = seg["kernel_geometry"] or (None, None)
-            if seg["mesh"] is None:
-                # the jitted entry itself, not ``ragged_paged_attention``
-                # around it: one Python frame fewer above the kernel
-                # (the docstring says what a frame costs)
-                from tpulab.tpu.platform import pallas_interpret
-                outs.append(ra._ragged_attn(
-                    qq, kv_pool, jnp.asarray(at, jnp.int32).reshape(1),
-                    tables, q_lens, seg["kv_lens"],
-                    pallas_interpret(), g_pages=gk, nbuf=nk, window=window))
-            else:
-                outs.append(ra.ragged_paged_attention(
-                    qq, kv_pool, at, tables, q_lens, seg["kv_lens"],
-                    mesh=seg["mesh"], g_pages=gk, nbuf=nk, window=window))
-        attn = _segment_rows(outs, seg).astype(compute_dtype)
+        attn = _kv_walk(q, pos, kv_pool, at, tables, seg, compute_dtype,
+                        window).astype(compute_dtype)
         attn = attn.reshape(b, m, -1)
         if other is not None:
             kv_pool = (other, kv_pool) if window else (kv_pool, other)
@@ -1748,9 +1806,11 @@ def paged_mixed_step(params, kv_pool, packed, carry, lanes: int,
     sits at global position ``kv_lens[b] - q_lens[b] + j``.  Embedding,
     norms, projections, RoPE, the row scatter into the lane's pages,
     ``wo``, the FFN or the routed experts and their counters run on the T
-    rows; the attention is called once a segment kind, the chunk rows in
-    the ``(B, M)`` form of :func:`paged_ragged_forward` and the decode
-    rows at ``(B, 1)`` (:func:`_segment_calls`), so a round costs what its
+    rows; the attention is called once a chunk lane on that lane's ``M``
+    rows and once for the decode rows at ``(B, 1)`` (:func:`_kv_walk`; the
+    latent and sparse attention once a segment kind, the chunk rows in the
+    ``(B, M)`` form of :func:`paged_ragged_forward`:
+    :func:`_segment_calls`), so a round costs what its
     tokens cost, not lanes x the longest chunk.  ``M`` (from the shapes,
     ``T - lanes``) is the ONE number the program is keyed by.
 
