@@ -524,8 +524,9 @@ class PagedKVPool:
 
 def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
     """``((shape, dtype) of ssm, (shape, dtype) of conv)``: the ONE
-    definition of what a model's Mamba, Gated DeltaNet or CCA layers
-    (``spec.state_kind``) keep a lane.
+    definition of what a model's Mamba-1, Gated DeltaNet, CCA or Mamba-2
+    layers (``spec.state_kind``: ``"mamba"``, ``"gdn"``, ``"cca"``,
+    ``"mamba2"``, the four kinds) keep a lane.  Kind ``"mamba"``:
 
     ``ssm``   ``(mamba layers, lanes, d_state, d_inner)`` float32: the SSM
               state ``h`` (the published kernel accumulates in float32; in
@@ -548,8 +549,18 @@ def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
     is: the last ``cca_taps[0] - 1`` rows of ``c = [q~ ; k~]`` (what the
     depthwise taps reach back to), the last ``cca_taps[1] - 1`` rows of the
     depthwise convolution's output ``a`` (the grouped taps'), and ``h W_v2``
-    of the lane's last token (the value's shifted half)."""
+    of the lane's last token (the value's shifted half).
+
+    Kind ``"mamba2"`` keeps the pair again: ``ssm`` is a head's matrix
+    ``(layers, lanes, heads, head_dim, state)`` float32 (the state's width
+    minor: a head's ``(head_dim, state)`` whole tiles), ``conv`` the tail of
+    the convolution over the ``[x | B | C]`` channels together."""
     n_layers = len(spec.state_layers)
+    if spec.state_kind == "mamba2":
+        return (((n_layers, lanes, spec.m2_heads, spec.m2_head_dim,
+                  spec.m2_state), np.dtype(np.float32)),
+                ((n_layers, spec.d_conv - 1, lanes, spec.m2_conv_dim),
+                 np.dtype(dtype)))
     if spec.state_kind == "cca":
         c = (spec.n_heads + spec.n_kv_heads) * spec.head_dim
         return tuple(((n_layers, kept, lanes, width), np.dtype(dtype))
@@ -569,8 +580,8 @@ def lane_state_shapes(spec, lanes: int, dtype) -> tuple:
 
 
 class LaneStateStore:
-    """Per-lane recurrent state of a model's Mamba or Gated DeltaNet layers,
-    beside the page store: ``arrays = (ssm, conv)``, shaped by
+    """Per-lane recurrent state of a model's Mamba (-1 or -2) or Gated
+    DeltaNet layers, beside the page store: ``arrays = (ssm, conv)``, shaped by
     :func:`lane_state_shapes` (a CCA model's three tails: ``arrays = (c
     tail, a tail, shifted value)``).
 

@@ -458,6 +458,13 @@ class ContinuousBatcher:
         #: segments the device started from zeros (a first chunk at
         #: position 0: admissions and re-prefills after preemption)
         self.zero_starts = 0
+        #: the chunked form of a Mamba-2 model's rounds (``debug_state()
+        #: ["ssd"]``), from the lengths a round commits: the chunks its
+        #: program computed (the round's width in whole chunks) and the
+        #: passes (a lane's part of one chunk) that carried a state through
+        #: them, a state layer each
+        self._ssd = (dict(chunks=0, passes=0)
+                     if plan.state_kind == "mamba2" else None)
         #: what the request released last held (``debug_state()
         #: ["last_release"]``): a slot and pages keep their contents until
         #: another request takes them, so a check can read them there
@@ -1680,6 +1687,22 @@ class ContinuousBatcher:
                           # what the live lanes hold right now
                           "summary_rows_live": summary_rows,
                           "raw_rows_live": raw_rows}
+        if self._ssd is not None:
+            # the chunked form's fill is rows / (chunks x chunk) of the
+            # round half: a round's prompt tokens and a lane's segment
+            # boundaries do not fall on whole chunks.  Every count is times
+            # the state layers; a decode block runs the one-token form alone
+            layers = len(self.model_spec.state_layers)
+            out["ssd"] = {
+                "chunk": self.model_spec.m2_chunk,
+                "decode": {"chunks": 0, "passes": 0, "rows": 0,
+                           "one_token_rows":
+                               self.lane_work["decode"]["rows"] * layers},
+                "round": {"chunks": self._ssd["chunks"] * layers,
+                          "passes": self._ssd["passes"] * layers,
+                          "rows": self.mixed_prompt_tokens * layers,
+                          "one_token_rows":
+                              self.mixed_decode_rows * layers}}
         if self.state is not None:
             # a slot is one lane's state in one layer of the store; a
             # dispatch HOLDS every slot and a pass of a lane through the
@@ -2562,6 +2585,14 @@ class ContinuousBatcher:
         self.mixed_attn_rows += ((len(toks) - b) * len(segs)
                                  + len(decode_parts))
         self.round_chunk_lanes += len(segs)
+        if self._ssd is not None:
+            q = min(self.model_spec.m2_chunk, len(toks) - b)
+            self._ssd["chunks"] += (len(toks) - b) // q
+            row = 0
+            for lane, _req in segs:     # packed in this order, end to end
+                self._ssd["passes"] += ((row + chunks[lane] - 1) // q
+                                        - row // q + 1)
+                row += chunks[lane]
         self.mixed_decode_rows += len(decode_parts)
         self._note_walk(req for _, req in decode_parts)
         if chain is not None:

@@ -604,9 +604,12 @@ def _segment_conv(x, w, conv, at, seg, live=None, fresh=None):
 
 
 def _mamba_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
-    """The Mamba-1 mixer of one layer (Jamba's: RMSNorm on dt, B and C) over
-    the lane state ``state = (ssm, conv)``, whose layer ``at`` it reads and
-    writes: ``(out, state)``, ``out`` shaped like ``h``.
+    """The Mamba-1 mixer of one layer (Jamba's: a state a CHANNEL, ``(d_state,
+    d_inner)``, ``dt`` through a low-rank projection, RMSNorm on dt, B and C;
+    the Mamba-2 mixer, a state a HEAD under one decay, is
+    :func:`_mamba2_mixer`) over the lane state ``state = (ssm, conv)``, whose
+    layer ``at`` it reads and writes: ``(out, state)``, ``out`` shaped like
+    ``h``.
 
     One rule for both forms: a segment that starts at position 0 starts
     from zeros, whatever the lane's slot holds; any other segment starts
@@ -770,6 +773,101 @@ def _gdn_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
         out = (o.reshape(n, hv * dv).astype(compute_dtype)
                @ qmat(p["out_proj"], compute_dtype))
     return out.reshape(h.shape), (ssm, conv)
+
+
+def _mamba2_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
+    """The Mamba-2 mixer of one layer (Nemotron-H's: a scalar decay a head,
+    ``B`` and ``C`` shared by a group of heads, a gated group norm; Mamba-1
+    is :func:`_mamba_mixer`) over the lane state ``state = (ssm, conv)``,
+    whose layer ``at`` it reads and writes: ``(out, state)``, ``out`` shaped
+    like ``h``.  ``ssm[at]`` is ``(lanes, heads, head_dim, state)`` float32,
+    ``conv[at]`` the last ``d_conv - 1`` inputs of the convolution over the
+    ``[x | B | C]`` channels together.
+
+    :func:`_mamba_mixer`'s one rule, in a function of its own: a segment at
+    position 0 starts from zeros whatever the slot holds, any other from
+    the slot; the slot is written from the segment's last valid row; rows
+    without a token and dead or idle lanes write nothing.
+
+    A decode step (``h (B, 1, D)``) is the one-token recurrence on the
+    store (:func:`tpulab.ops.ssd.one_token_ssd`).  A packed round
+    (``seg["row_seg"]``: ``h (1, T, D)``) takes the convolution's taps
+    across segment starts by :func:`_segment_conv`; its chunk rows ``[0,
+    M)`` run the chunked form (:func:`tpulab.ops.ssd.chunk_ssd`: whole
+    chunks of ``spec.m2_chunk`` rows as matrix products, the state carried
+    in float32 from chunk to chunk and, in the slot, from round to round)
+    and its decode rows ``[M, M + B)``, one a lane, the one-token form
+    (different lanes, so in either order).  The chunked form is XLA in
+    either plan; the one-token form is the kernel ``ssd_step`` with the
+    kernels (``seg["use_kernel"]``: it moves the slots of the lanes that
+    hold a row and no other, once each way), else its XLA definition."""
+    import jax
+    import jax.numpy as jnp
+    from tpulab.models.transformer import qmat
+    from tpulab.ops import ssd
+
+    f32 = jnp.float32
+    ssm, conv = state
+    nh, hd, g, ns = (spec.m2_heads, spec.m2_head_dim, spec.m2_groups,
+                     spec.m2_state)
+    din, cd = nh * hd, spec.m2_conv_dim
+    rows = seg.get("row_seg")
+    if rows is None and h.shape[1] != 1:
+        raise NotImplementedError(
+            "Mamba-2 layers run in a decode step or a packed round "
+            "(paged_mixed_step), not in the padded (B, M) form")
+    n = h.shape[0] * h.shape[1]
+    with jax.named_scope("mamba2_proj"):
+        zxd = (h @ qmat(p["in_proj"], compute_dtype)).reshape(n, -1)
+        z, xbc = zxd[:, :din], zxd[:, din:din + cd]
+        dt = jax.nn.softplus(zxd[:, din + cd:].astype(f32)
+                             + p["dt_bias"].astype(f32))
+    live = fresh = None
+    with jax.named_scope("mamba2_conv"):
+        if rows is None:
+            live, fresh = valid[:, 0], pos[:, 0] == 0
+        acc, conv = _segment_conv(xbc, p["conv_w"].astype(f32), conv, at,
+                                  seg, live, fresh)
+        u = jax.nn.silu(acc + p["conv_b"].astype(f32))
+    with jax.named_scope("mamba2_ssd"):
+        x = u[:, :din].reshape(n, nh, hd)
+        b = u[:, din:din + g * ns].reshape(n, g, ns)
+        c = u[:, din + g * ns:].reshape(n, g, ns)
+        a, d = -jnp.exp(p["a_log"].astype(f32)), p["d"].astype(f32)
+        if rows is None:
+            y, ssm = ssd.one_token_ssd(x, dt, a, b, c, d, ssm, at, live,
+                                       fresh, use_kernel=seg["use_kernel"])
+        else:
+            from tpulab.ops.selective_scan import ROW_ZERO, row_flags
+            row_lane, row_off = rows
+            flags = row_flags(row_lane, row_off, seg["q_lens"],
+                              seg["kv_lens"])
+            m = n - seg["q_lens"].shape[0]         # the chunk rows
+            y, ssm = ssd.chunk_ssd(
+                x[:m], dt[:m], a, b[:m], c[:m], d, ssm,
+                jnp.asarray(at, jnp.int32), row_lane[:m], flags[:m],
+                chunk=spec.m2_chunk)
+            y_dec, ssm = ssd.one_token_ssd(
+                x[m:], dt[m:], a, b[m:], c[m:], d, ssm, at,
+                row_lane[m:] >= 0, (flags[m:] & ROW_ZERO) != 0,
+                use_kernel=seg["use_kernel"])
+            y = jnp.concatenate([y, y_dec])
+    with jax.named_scope("mamba2_norm"):
+        # the gate BEFORE the norm, the norm over a group's channels
+        y = (y.reshape(n, din) * jax.nn.silu(z.astype(f32))).reshape(
+            n, g, din // g)
+        y = (y * jax.lax.rsqrt(jnp.square(y).mean(-1, keepdims=True)
+                               + spec.rms_eps)).reshape(n, din) * p[
+                                   "norm"]["scale"].astype(f32)
+    with jax.named_scope("mamba2_out"):
+        out = y.astype(compute_dtype) @ qmat(p["out_proj"], compute_dtype)
+    return out.reshape(h.shape), (ssm, conv)
+
+
+#: the mixers that keep a lane state and no pages, by ``ModelSpec.mixers``
+#: name, which is also the name of the layer's leaves
+_LANE_MIXERS = {"mamba": _mamba_mixer, "gdn": _gdn_mixer,
+                "mamba2": _mamba2_mixer}
 
 
 def _cca_qkv(spec, p, at, h, pos, valid, state, seg, compute_dtype):
@@ -999,6 +1097,9 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None,
     here) plus the shared expert where the model has one, scaled by its
     sigmoid gate where it has that (``spec.shared_gate``).  Returns ``(x,
     stats)``, ``stats`` the expert layer's ``(E + 2,)`` counters or None.
+    A layer of kind ``"none"`` (its mixer is all it is) returns ``x`` as it
+    came.  The experts' form is ``spec.expert_act``: SwiGLU, or ``relu2``
+    (``down(relu(up x)^2)``, the shared expert in the same form).
 
     A ``"shortcut"`` layer (LongCat-Flash) runs BOTH on the one normed
     input: its ``x`` is ``x + dense(h)`` and the expert block's output ``m``
@@ -1019,11 +1120,13 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None,
     import jax.numpy as jnp
     from tpulab.models.transformer import _dense_ffn, _rmsnorm
 
+    kind = spec.layer_kinds[layer]
+    if kind == "none":      # the layer is its mixer alone: no norm, no
+        return x, None      # residual and no product here
     streams = None
     if spec.hc_mult:
         streams, (x, *maps) = x, _mhc_pre(spec, p["hc_ffn"], x)
     h = _rmsnorm(x, p["ln2"]["scale"], spec.rms_eps)
-    kind = spec.layer_kinds[layer]
     if kind == "dense":
         if streams is not None:
             return _mhc_post(streams, *maps,
@@ -1037,7 +1140,7 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None,
     b, m = x.shape[:2]
     y, stats, *state = routed_ffn(
         p["moe"], h.reshape(b * m, -1), spec.top_k, compute_dtype,
-        router=spec.router, act="swiglu", scale=spec.routed_scale,
+        router=spec.router, act=spec.expert_act, scale=spec.routed_scale,
         norm=spec.norm_topk, valid=valid.reshape(-1),
         first=spec.expert_first, held=spec.experts_held or None,
         zero=spec.zero_experts,
@@ -1052,6 +1155,12 @@ def _ffn_block(spec, p, layer, x, valid, compute_dtype, shortcut=None,
             y = y + _dense_ffn(p["shared"], h, compute_dtype) * jax.nn.sigmoid(
                 (h @ qmat(p["shared"]["gate"], compute_dtype)).astype(
                     jnp.float32))
+    elif spec.n_shared and spec.expert_act == "relu2":
+        from tpulab.models.transformer import qmat
+        with jax.named_scope("moe_shared"):
+            y = y + jnp.square(jax.nn.relu(
+                h @ qmat(p["shared"]["w1"], compute_dtype))) @ qmat(
+                    p["shared"]["w2"], compute_dtype)
     elif spec.n_shared:
         with jax.named_scope("moe_shared"):
             y = y + _dense_ffn(p["shared"], h, compute_dtype)
@@ -1118,10 +1227,15 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
 
     The layer's mixer is attention over the pages (above; with an output
     gate and partial RoPE, :func:`_gated_attention`) or, by ``spec.mixers``,
-    a Mamba block (:func:`_mamba_mixer`) or a Gated DeltaNet block
-    (:func:`_gdn_mixer`) over the lane state; ``kv_pool`` is then the pair
-    ``(page store, lane state)`` for every layer of the model, and only
-    attention layers own a layer of the page store (``spec.store_layer``).
+    a Mamba-1 block (:func:`_mamba_mixer`), a Mamba-2 block
+    (:func:`_mamba2_mixer`) or a Gated DeltaNet block (:func:`_gdn_mixer`)
+    over the lane state; ``kv_pool`` is then the pair ``(page store, lane
+    state)`` for every layer of the model, and only attention layers own a
+    layer of the page store (``spec.store_layer``).  A layer may also be ONE
+    sublayer (Nemotron-H's): a mixer alone (``spec.layer_kinds`` ``"none"``:
+    :func:`_ffn_block` hands ``x`` back) or a feed-forward part alone
+    (``spec.mixers`` ``"none"``: nothing above this line runs), one norm and
+    one residual either way.
 
     A ``"shortcut"`` layer (``spec.layer_kinds``; LongCat-Flash's
     shortcut-connected expert block) returns its ``x`` as the pair ``(x,
@@ -1172,6 +1286,10 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
     from tpulab.models.transformer import (_rmsnorm, apply_rope, qmat,
                                            split_qkv)
 
+    if spec.mixers[layer] == "none":
+        # the layer is its feed-forward part alone (ONE norm, ONE residual)
+        x, stats = _ffn_block(spec, p, layer, x, valid, compute_dtype)
+        return x, kv_pool, stats
     shortcut = streams = routed = None
     if layer and spec.layer_kinds[layer - 1] == "shortcut":
         x, shortcut = x
@@ -1181,28 +1299,20 @@ def _layer_block(spec, p, layer, x, pos, valid, kv_pool, page_idx, slot_idx,
         streams, (x, *maps) = x, _mhc_pre(spec, p["hc_attn"], x)
     h = _rmsnorm(x, p["ln1"]["scale"], spec.rms_eps)
     state = None
-    if spec.mamba_layers:
-        # a hybrid's store is the pair (pages, lane state): a Mamba layer
-        # reads and writes the second alone, an attention layer the first
+    if spec.state_kind:
+        # a hybrid's store is the pair (pages, lane state): a layer whose
+        # mixer keeps a lane state (Mamba-1, Gated DeltaNet, Mamba-2) reads
+        # and writes the second alone, an attention layer the first (a CCA
+        # layer both, below)
         kv_pool, state = kv_pool
-        if spec.mixers[layer] == "mamba":
-            mixed, state = _mamba_mixer(
-                spec, p["mamba"], spec.store_layer(layer), h, pos, valid,
-                state, seg, compute_dtype)
+        name = spec.mixers[layer]
+        if name in _LANE_MIXERS:
+            mixed, state = _LANE_MIXERS[name](
+                spec, p[name], spec.store_layer(layer), h, pos, valid, state,
+                seg, compute_dtype)
             x, stats = _ffn_block(spec, p, layer, x + mixed, valid,
                                   compute_dtype)
             return x, (kv_pool, state), stats
-    elif spec.gdn_k_heads:
-        kv_pool, state = kv_pool
-        if spec.mixers[layer] == "gdn":
-            mixed, state = _gdn_mixer(
-                spec, p["gdn"], spec.store_layer(layer), h, pos, valid,
-                state, seg, compute_dtype)
-            x, stats = _ffn_block(spec, p, layer, x + mixed, valid,
-                                  compute_dtype)
-            return x, (kv_pool, state), stats
-    elif spec.cca_taps:
-        kv_pool, state = kv_pool
     if spec.attention == "mla":
         attn, kv_pool = _mla_attention(spec, p, layer, h, pos, kv_pool,
                                        page_idx, slot_idx, seg,
@@ -2242,7 +2352,9 @@ class StepPrograms:
         trace time (``tpulab.ops.grouped_matmul.traced_products``), shared
         by the process's engines of one width like the memo's programs."""
         from tpulab.ops.grouped_matmul import traced_products
-        widths = ((spec.d_model, 2 * spec.moe_ff), (spec.moe_ff, spec.d_model))
+        f = spec.moe_ff_served      # relu2: ONE matrix in, padded to lanes
+        widths = ((spec.d_model, f if spec.expert_act == "relu2" else 2 * f),
+                  (f, spec.d_model))
         return [p for p in traced_products() if (p["k"], p["n"]) in widths]
 
     def _sized(self, program, k: int):

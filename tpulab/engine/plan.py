@@ -48,7 +48,7 @@ class EnginePlan:
     mesh: Optional[Any]
     #: model-axis shards of the page payloads (1 on one device)
     n_shards: int
-    #: the kind of per-lane state ("mamba", "gdn", "cca"; None without
+    #: the kind of per-lane state ("mamba", "gdn", "cca", "mamba2"; None without
     #: layers that keep one); the page store then holds the attention layers
     #: (a CCA layer owns a layer of both)
     state_kind: Optional[str]
@@ -109,12 +109,17 @@ class EnginePlan:
         scans its segments in one and a Gated DeltaNet layer's one-token
         rule is one too (a decode step, a round's decode rows); a Mamba
         layer's decode step is XLA in either plan, and a CCA layer's tails
-        are XLA in both programs (its K/V walk is the page store's)."""
+        are XLA in both programs (its K/V walk is the page store's).  A
+        Mamba-2 layer's one-token rule is a kernel with the kernels (a
+        decode step, a round's decode rows) and its chunked form XLA in
+        either plan (:mod:`tpulab.ops.ssd`)."""
         if not self.state_kind:
             return None
         if self.state_kind == "cca":
             return {"decode": "xla", "round": "xla"}
         form = "kernel" if self.use_kernel else "xla"
+        if self.state_kind == "mamba2":
+            return {"decode": form, "round": "xla"}
         return {"decode": form if self.state_kind == "gdn" else "xla",
                 "round": form}
 
@@ -172,6 +177,11 @@ def kernel_error(plan: EnginePlan, cap: int):
         err = (scan_geometry_error(spec.d_inner, spec.d_state)
                if plan.state_kind == "mamba" else
                rule_geometry_error(spec.gdn_k_dim, spec.gdn_v_dim))
+        if err:
+            return err
+    if plan.state_kind == "mamba2":
+        from tpulab.ops.ssd import step_geometry_error
+        err = step_geometry_error(spec.m2_head_dim, spec.m2_state)
         if err:
             return err
     if plan.eva_window:
@@ -248,9 +258,9 @@ def plan_engine(*, spec, n_heads: int, n_layers: int,
         if bad:
             kinds = sorted({f"{spec.cache_entry} pages"}
                            | {f"{k} layers" for k in spec.mixers
-                              if k != "attention"}
+                              if k not in ("attention", "none")}
                            | {f"{k} FFNs" for k in spec.layer_kinds
-                              if k != "dense"}
+                              if k not in ("dense", "none")}
                            | ({"EVA windows (compacted pages)"} if eva
                               else set())
                            | ({"window layers (a page table a layer kind: "

@@ -15,14 +15,20 @@ kind the *cache-entry kind* the page store holds:
               layer: what a learned indexer scores a query against to
               choose the ``index_topk`` keys the query attends to.
 
-A layer's *mixer* is attention, a Mamba selective state-space block or a
-Gated DeltaNet linear-attention block (``mixers``).  A Mamba or Gated
-DeltaNet layer leaves no pages: it keeps a per-lane recurrent state of fixed
-size in the engine's lane-state store (``state_kind``: ``"mamba"``, the SSM
-state ``d_state x d_inner`` in float32; ``"gdn"``, a matrix ``gdn_k_dim x
-gdn_v_dim`` in float32 a value head; both with the last ``d_conv - 1``
-inputs of the layer's causal convolution), so only attention layers own a
-layer of the page store (``store_layer``).
+A layer's *mixer* is attention, a Mamba-1 selective state-space block
+(``"mamba"``: Jamba's, a state a CHANNEL), a Mamba-2 block (``"mamba2"``:
+Nemotron-H's, a state a HEAD under a scalar decay), a Gated DeltaNet
+linear-attention block (``"gdn"``) or nothing at all (``"none"``: the layer
+is its feed-forward part alone) (``mixers``).  A Mamba or Gated DeltaNet
+layer leaves no pages: it keeps a per-lane recurrent state of fixed size in
+the engine's lane-state store (``state_kind``: ``"mamba"``, the SSM state
+``d_state x d_inner`` in float32; ``"gdn"``, a matrix ``gdn_k_dim x
+gdn_v_dim`` in float32 a value head; ``"mamba2"``, a matrix ``m2_head_dim x
+m2_state`` in float32 a head; each with the last ``d_conv - 1`` inputs of
+the layer's causal convolution), so only attention layers own a layer of
+the page store (``store_layer``).  A layer's feed-forward part is likewise a
+dense FFN, an expert block or nothing (``layer_kinds`` ``"none"``: the layer
+is its mixer alone, ONE norm and ONE residual).
 
 The dense decoder the engine has always served is :func:`dense_spec` with
 today's constants (epsilon 1e-6, ``head_dim = d_model // n_heads``); the
@@ -59,7 +65,8 @@ in the layout the layer block reads:
 RoPE is the engine's rotate-half convention over the ``qk_rope`` columns.
 The multi-token-prediction layer of the published model is not built.
 
-``jamba`` (AI21-Jamba2-3B): Mamba-1 mixers with RMSNorm on dt, B and C, a
+``jamba`` (AI21-Jamba2-3B): Mamba-1 mixers (mixer ``"mamba"``; Mamba-2 is
+``nemotron_h``'s, at the end of this text) with RMSNorm on dt, B and C, a
 full-attention GQA mixer without positional encoding every
 ``attn_layer_period`` layers, a dense SwiGLU FFN on every layer
 (``num_experts`` 1), a tied output head.  :func:`jamba_spec` reads the
@@ -296,6 +303,44 @@ reads the published keys.  A layer has ``wqkv`` = ``[q | k | v]``, ``q_norm``
 / ``k_norm`` ``{"scale": (head_dim,)}``, ``wo`` and the ``moe`` leaves
 without ``bias`` and no ``shared``, whatever its kind.  The prediction head
 the model's description names has no key in its config and is not built.
+
+``nemotron_h`` (NVIDIA-Nemotron-3-Nano-30B-A3B): every layer is ONE sublayer
+by the letter of ``hybrid_override_pattern``: ``M`` a Mamba-2 mixer, ``*``
+GQA attention without positional rotation, ``E`` an expert block; ``x <- x +
+f(RMSNorm(x))``, one norm (``ln1`` of a mixer layer, ``ln2`` of an expert
+layer) and one residual a layer, an untied head.  ``M*`` stand side by side
+in the pattern, so the letters cannot be folded into (mixer, FFN) pairs:
+``mixers`` is ``"none"`` on an ``E`` layer and ``layer_kinds`` ``"none"`` on
+the others.  Mamba-2: ``[z | xBC | dt] = h W_in``; ``xBC <- silu(conv(xBC) +
+b)`` depthwise causal over ``[x | B | C]`` together; ``dt = softplus(dt +
+dt_bias)`` and ``A = -exp(A_log)`` a head; head ``j`` uses the ``B`` and
+``C`` of group ``j // (heads / groups)``; ``S_j <- exp(dt_j A_j) S_j + dt_j
+x_j (x) B_g``, ``y_j = S_j C_g + D_j x_j`` on a float32 state ``(head_dim,
+state)`` a head; ``y <- RMSNorm_group(y * silu(z))`` over a group's channels
+(gate BEFORE the norm), then ``out_proj``.  Experts: the ``"sigmoid_bias"``
+router, ``down(relu(up x)^2)`` (``expert_act`` ``"relu2"``: ``w1`` / ``w2``,
+no gate) and a shared expert of the same form.  :func:`nemotron_h_spec`
+reads the published keys; ``first`` / ``held`` give the share of the routed
+experts held here.  A Mamba-2 layer has, under ``mamba2``:
+
+=============  ==========================================================
+``in_proj``    ``(d_model, 2 * d_inner + 2 * groups * state + heads)``,
+               columns ``[z | x | B | C | dt]``
+``conv_w``     ``(d_conv, d_inner + 2 * groups * state)`` over ``[x | B |
+               C]``, tap ``k`` weighing the input ``d_conv - 1 - k`` tokens
+               back; ``conv_b`` a channel
+``dt_bias`` ``a_log`` ``d``   ``(heads,)``
+``norm``       ``{"scale": (d_inner,)}``: the gated group norm's weight
+``out_proj``   ``(d_inner, d_model)``
+=============  ==========================================================
+
+An expert layer has ``moe`` (``router (d_model, E)``, ``bias (E,)``, ``w1
+(held, d_model, F)``, ``w2 (held, F, d_model)``) and ``shared`` ``w1 w2``.
+The published expert width 1,856 is 14.5 x 128 lanes: the SERVED experts are
+padded to whole lanes (``moe_ff_pad`` zero columns of ``w1`` and zero rows
+of ``w2``, :func:`nemotron_h_layout`: ``relu(0)^2 = 0``, so the padded layer
+gives the published layer's numbers), which is what lets both expert
+products run the grouped kernel.
 """
 
 from __future__ import annotations
@@ -322,6 +367,7 @@ class ModelSpec:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     layer_kinds: Tuple[str, ...] = ()       # "dense" | "moe" | "shortcut"
+                                            # | "none" (a mixer ALONE)
     n_experts: int = 0                      # the router's columns
     top_k: int = 0
     moe_ff: int = 0
@@ -332,6 +378,8 @@ class ModelSpec:
     rope_theta: Optional[float] = None
     mixers: Tuple[str, ...] = ()            # "attention" | "mamba" | "gdn"
                                             # | "cca" (set from cca_taps)
+                                            # | "mamba2" | "none" (an FFN
+                                            #   ALONE)
     d_inner: int = 0                        # mamba, all four
     d_state: int = 0
     d_conv: int = 0                         # mamba and gdn
@@ -377,6 +425,17 @@ class ModelSpec:
                                             # every layer full attention
     rope_factor: float = 1.0                # YaRN's factor on cos and sin of
                                             # the FULL layers (window: none)
+    m2_heads: int = 0                       # Mamba-2, all five (+ d_conv):
+    m2_head_dim: int = 0                    # heads and a head's channels,
+    m2_groups: int = 0                      # groups that share B and C,
+    m2_state: int = 0                       # a state's width (B's, C's),
+    m2_chunk: int = 0                       # rows a chunk of the SSD form
+    expert_act: str = "swiglu"              # | "relu2": down(relu(up x)^2),
+                                            # TWO matrices an expert
+    moe_ff_pad: int = 0                     # zero columns of w1 / rows of w2
+                                            # the SERVED experts carry past
+                                            # the published moe_ff (whole
+                                            # lanes for the grouped product)
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla"):
@@ -434,9 +493,9 @@ class ModelSpec:
                                  "on K/V pages only")
         kinds = self.layer_kinds or ("dense",) * self.n_layers
         if len(kinds) != self.n_layers or set(kinds) - {"dense", "moe",
-                                                        "shortcut"}:
+                                                        "shortcut", "none"}:
             raise ValueError(f"layer_kinds {kinds} does not name a dense, "
-                             f"moe or shortcut FFN for each of "
+                             f"moe or shortcut FFN (or none) for each of "
                              f"{self.n_layers} layers")
         object.__setattr__(self, "layer_kinds", tuple(kinds))
         if "shortcut" in kinds:
@@ -451,10 +510,57 @@ class ModelSpec:
                     "and every mixer is attention")
         mixers = self.mixers or ("attention",) * self.n_layers
         if (len(mixers) != self.n_layers
-                or set(mixers) - {"attention", "mamba", "gdn", "cca"}):
+                or set(mixers) - {"attention", "mamba", "gdn", "cca",
+                                  "mamba2", "none"}):
             raise ValueError(f"mixers {mixers} does not name attention, "
-                             f"mamba, gdn or cca for each of {self.n_layers} "
-                             "layers")
+                             f"mamba, gdn, cca, mamba2 or none for each of "
+                             f"{self.n_layers} layers")
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert activation {self.expert_act!r}")
+        if self.moe_ff_pad and (self.expert_act != "relu2"
+                                or self.moe_ff_pad < 0):
+            raise ValueError("moe_ff_pad pads relu2 experts alone: "
+                             "relu(0)^2 = 0 makes a zero column exact")
+        lone = "none" in mixers or "none" in kinds
+        if lone or "mamba2" in mixers:
+            # layers of ONE sublayer and the Mamba-2 state come together
+            # (nemotron_h): what else keeps rows, a state or another
+            # residual a layer is refused by name
+            beside = {
+                "latent attention": self.attention != "gqa",
+                "an indexer": bool(self.index_topk),
+                "EVA windows": bool(self.eva_window),
+                "an output gate": self.attn_gate,
+                "partial RoPE": bool(self.rotary_dim),
+                "hyper-connections": bool(self.hc_mult),
+                "a shortcut layer": "shortcut" in kinds,
+                "residual scaling": self.res_scale,
+                "an MLP router": self.router == "mlp",
+                "CCA": bool(self.cca_taps),
+                "window layers": bool(self.window or self.attn_kinds),
+                "Mamba-1 or Gated DeltaNet layers":
+                    bool(set(mixers) & {"mamba", "gdn"})}
+            for other, there in beside.items():
+                if there:
+                    raise ValueError(
+                        f"Mamba-2 layers and layers of one sublayer beside "
+                        f"{other} are not implemented: a Mamba-2 state "
+                        "beside plain GQA pages, dense and expert FFNs")
+            if any(m == "none" and k == "none"
+                   for m, k in zip(mixers, kinds)):
+                raise ValueError("a layer with neither a mixer nor an FFN")
+            if "mamba2" not in mixers or "attention" not in mixers:
+                raise ValueError(
+                    "layers of one sublayer are served for a model with "
+                    "Mamba-2 layers beside GQA attention layers on K/V "
+                    "pages (a lane state of ONE kind)")
+            if min(self.m2_heads, self.m2_head_dim, self.m2_groups,
+                   self.m2_state, self.m2_chunk, self.d_conv - 1) < 1 or (
+                       self.m2_heads % self.m2_groups):
+                raise ValueError(
+                    "a spec with Mamba-2 layers gives m2_heads (a multiple "
+                    "of m2_groups), m2_head_dim, m2_groups, m2_state, "
+                    "m2_chunk and d_conv (>= 2)")
         if "gdn" in mixers:
             if (self.attention != "gqa" or "attention" not in mixers
                     or "mamba" in mixers):
@@ -641,7 +747,19 @@ class ModelSpec:
     def moe_layers(self) -> Tuple[int, ...]:
         """The layers that run an expert block (``"moe"``, ``"shortcut"``)."""
         return tuple(i for i, k in enumerate(self.layer_kinds)
-                     if k != "dense")
+                     if k not in ("dense", "none"))
+
+    @property
+    def moe_ff_served(self) -> int:
+        """The width of the experts as SERVED: the published ``moe_ff`` and
+        the zero columns behind it (:func:`nemotron_h_layout`)."""
+        return self.moe_ff + self.moe_ff_pad
+
+    @property
+    def m2_conv_dim(self) -> int:
+        """Channels of a Mamba-2 layer's convolution: ``[x | B | C]``."""
+        return (self.m2_heads * self.m2_head_dim
+                + 2 * self.m2_groups * self.m2_state)
 
     @property
     def mamba_layers(self) -> Tuple[int, ...]:
@@ -650,8 +768,8 @@ class ModelSpec:
     @property
     def state_kind(self) -> Optional[str]:
         """The kind of per-lane state the model's layers keep beside the
-        pages: ``"mamba"``, ``"gdn"``, ``"cca"`` or None."""
-        for kind in ("mamba", "gdn", "cca"):
+        pages: ``"mamba"``, ``"gdn"``, ``"cca"``, ``"mamba2"`` or None."""
+        for kind in ("mamba", "gdn", "cca", "mamba2"):
             if kind in self.mixers:
                 return kind
         return None
@@ -659,7 +777,8 @@ class ModelSpec:
     @property
     def state_layers(self) -> Tuple[int, ...]:
         """The layers that own a layer of the lane-state store."""
-        return tuple(i for i, k in enumerate(self.mixers) if k != "attention")
+        return tuple(i for i, k in enumerate(self.mixers)
+                     if k not in ("attention", "none"))
 
     @property
     def attention_layers(self) -> Tuple[int, ...]:
@@ -1127,6 +1246,94 @@ def mellum_spec(config: Dict[str, Any]) -> ModelSpec:
         rope_scaling=scaling, rope_factor=factor)
 
 
+def nemotron_h_spec(config: Dict[str, Any], first: int = 0,
+                    held: Optional[int] = None) -> ModelSpec:
+    """From the published ``config.json`` keys (``model_type``
+    ``nemotron_h``).  Layer ``i`` is what letter ``i`` of
+    ``hybrid_override_pattern`` says, ONE sublayer: ``M`` Mamba-2, ``*``
+    attention (no positional rotation: ``rope_theta`` and
+    ``partial_rotary_factor`` are read by nothing), ``E`` the expert block.
+    ``d_inner`` is ``mamba_num_heads x mamba_head_dim`` (``expand`` is read
+    by nothing).  ``first`` / ``held``: the contiguous share of the
+    ``n_routed_experts`` routed experts this device holds (all of them by
+    default); the router keeps every column.  The served experts are padded
+    to whole lanes (``moe_ff_pad``).  Refuses what the layer block does not
+    compute."""
+    n_layers = int(config["num_hidden_layers"])
+    # (a configuration cut in depth keeps the published pattern whole: the
+    # layers served are its first ``num_hidden_layers`` letters)
+    pattern = str(config["hybrid_override_pattern"])[:n_layers]
+    if len(pattern) != n_layers or set(pattern) - set("ME*"):
+        raise ValueError(
+            f"hybrid_override_pattern letters "
+            f"{sorted(set(pattern) - set('ME*'))} are not implemented (M "
+            f"Mamba-2, E experts, * attention, one for each of {n_layers} "
+            "layers; '-' dense MLP layers are not)")
+    if int(config.get("n_group", 1)) != 1 or int(config.get("topk_group",
+                                                            1)) != 1:
+        raise ValueError("group-limited routing (n_group/topk_group > 1) is "
+                         "not implemented")
+    for key in ("attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias",
+                "tie_word_embeddings", "residual_in_fp32"):
+        if config.get(key):
+            raise ValueError(f"{key} true is not implemented")
+    if not config.get("use_conv_bias", True):
+        raise ValueError("use_conv_bias false is not implemented")
+    if config.get("sliding_window") is not None:
+        raise ValueError("sliding_window is not implemented")
+    if config.get("mlp_hidden_act", "relu2") != "relu2" or config.get(
+            "mamba_hidden_act", "silu") != "silu":
+        raise ValueError("mlp_hidden_act relu2 and mamba_hidden_act silu "
+                         "alone are implemented")
+    moe_ff = int(config["moe_intermediate_size"])
+    shared = int(config["moe_shared_expert_intermediate_size"]) * int(
+        config.get("n_shared_experts", 1))
+    if shared % moe_ff:
+        raise ValueError(f"the shared experts' width {shared} is not a "
+                         f"multiple of moe_intermediate_size {moe_ff}")
+    n_experts = int(config["n_routed_experts"])
+    return ModelSpec(
+        n_layers=n_layers, d_model=int(config["hidden_size"]),
+        n_heads=int(config["num_attention_heads"]),
+        n_kv_heads=int(config["num_key_value_heads"]),
+        head_dim=int(config["head_dim"]), rope_theta=None,
+        rms_eps=float(config["layer_norm_epsilon"]),
+        mixers=tuple({"M": "mamba2", "*": "attention", "E": "none"}[c]
+                     for c in pattern),
+        layer_kinds=tuple("moe" if c == "E" else "none" for c in pattern),
+        d_conv=int(config["conv_kernel"]),
+        m2_heads=int(config["mamba_num_heads"]),
+        m2_head_dim=int(config["mamba_head_dim"]),
+        m2_groups=int(config["n_groups"]),
+        m2_state=int(config["ssm_state_size"]),
+        m2_chunk=int(config["chunk_size"]),
+        n_experts=n_experts, top_k=int(config["num_experts_per_tok"]),
+        moe_ff=moe_ff, moe_ff_pad=-moe_ff % 128, n_shared=shared // moe_ff,
+        expert_act="relu2", router="sigmoid_bias",
+        routed_scale=float(config["routed_scaling_factor"]),
+        norm_topk=bool(config["norm_topk_prob"]),
+        experts_held=n_experts if held is None else int(held),
+        expert_first=int(first))
+
+
+def nemotron_h_layout(w1, w2, spec: ModelSpec):
+    """The published ``up_proj`` / ``down_proj`` of the experts held,
+    transposed (``w1 (E, d_model, moe_ff)``, ``w2 (E, moe_ff, d_model)``),
+    as the served ``(w1, w2)``: ``moe_ff_pad`` zero columns behind ``w1``'s
+    and as many zero rows behind ``w2``'s, so that the width is whole
+    128-lane tiles and both products run the grouped kernel.  Exact: a zero
+    column gives ``relu(0)^2 = 0``, which a zero row of ``w2`` meets."""
+    pad = spec.moe_ff_pad
+    if not pad:
+        return w1, w2
+    if isinstance(w1, np.ndarray):
+        lib = np
+    else:
+        import jax.numpy as lib
+    return (lib.pad(w1, ((0, 0), (0, 0), (0, pad))),
+            lib.pad(w2, ((0, 0), (0, pad), (0, 0))))
+
+
 def mla_scales(config: Dict[str, Any]) -> Tuple[float, float]:
     """``(query factor, latent factor)`` that a config puts on latent
     attention and the program folds into its matrices at load time:
@@ -1245,7 +1452,12 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
     ``dt_bias = softplus^-1(dt)`` with ``dt`` log-uniform in [1e-3, 1e-1],
     the convolution uniform within ``d_conv ** -0.5``.  A Gated DeltaNet
     layer's for the same reason: ``a_log = log(U(0, 16))``, ``dt_bias`` and
-    the convolution as Mamba's.  The EVA scorers ``eva_mu`` / ``eva_phi``
+    the convolution as Mamba's.  A Mamba-2 layer's by ITS published
+    initialiser: ``a_log = log(U(1, 16))`` a head, ``d = 1``, ``dt_bias =
+    softplus^-1(dt)`` with ``dt`` log-uniform in [1e-3, 1e-1] floored at
+    1e-4, the convolution's weight and bias uniform within ``d_conv **
+    -0.5``; ``relu2`` experts are drawn at the published width and padded
+    (:func:`nemotron_h_layout`).  The EVA scorers ``eva_mu`` / ``eva_phi``
     are a unit normal cut at two deviations (the published
     initialisation).  The hyper-connections' leaves are
     :func:`init_hyper_connection`'s; a CCA layer's convolutions, its key
@@ -1287,7 +1499,26 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
         params["mtp_heads"] = w(d, (spec.pred_heads - 1) * vocab)
     for i, kind in enumerate(spec.layer_kinds):
         p = {"ln1": norm(d), "ln2": norm(d)}
-        if spec.mixers[i] == "mamba":
+        if kind == "none":              # ONE norm a layer: the mixer's
+            del p["ln2"]
+        if spec.mixers[i] == "none":    # ... or the FFN's, and no mixer
+            del p["ln1"]
+        elif spec.mixers[i] == "mamba2":
+            nh, din, cd = (spec.m2_heads, spec.m2_heads * spec.m2_head_dim,
+                           spec.m2_conv_dim)
+            bound = spec.d_conv ** -0.5
+            dt = jnp.maximum(jnp.exp(uniform(np.log(1e-3), np.log(1e-1),
+                                             nh)), 1e-4)
+            p["mamba2"] = {
+                "in_proj": w(d, din + cd + nh),
+                "conv_w": uniform(-bound, bound, spec.d_conv, cd),
+                "conv_b": uniform(-bound, bound, cd),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "a_log": jnp.log(uniform(1.0, 16.0, nh)),
+                "d": jnp.ones((nh,), jnp.float32),
+                "norm": norm(din),
+                "out_proj": w(din, d)}
+        elif spec.mixers[i] == "mamba":
             di, n, r = spec.d_inner, spec.d_state, spec.dt_rank
             bound = spec.d_conv ** -0.5
             dt = jnp.exp(uniform(np.log(1e-3), np.log(1e-1), di))
@@ -1357,9 +1588,18 @@ def init_params(spec: ModelSpec, vocab: int, d_ff: int, seed: int = 0,
                     "k_norm": dict(norm(spec.index_dim), bias=jnp.zeros(
                         (spec.index_dim,), jnp.float32)),
                     "ww": w(d, spec.index_heads)}
-        if kind != "moe":
+        if kind not in ("moe", "none"):
             p.update(w1=w(d, d_ff), w3=w(d, d_ff), w2=w(d_ff, d))
-        if kind != "dense":
+        if kind == "moe" and spec.expert_act == "relu2":
+            # two matrices an expert, the served width padded with zeros
+            f, fs = spec.moe_ff, spec.n_shared * spec.moe_ff
+            held = spec.experts_held or spec.ffn_experts
+            w1, w2 = nemotron_h_layout(w(held, d, f), w(held, f, d), spec)
+            p["moe"] = {"router": w(d, spec.n_experts),
+                        "bias": w(spec.n_experts), "w1": w1, "w2": w2}
+            if fs:
+                p["shared"] = {"w1": w(d, fs), "w2": w(fs, d)}
+        elif kind not in ("dense", "none"):
             f, fs = spec.moe_ff, spec.n_shared * spec.moe_ff
             held = spec.experts_held or spec.ffn_experts
             if spec.router == "mlp":

@@ -147,7 +147,8 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
     F, D)`` are experts ``first .. first + E_here`` of the layer
     (``first`` may be traced).  ``act="swiglu"``: ``w_in`` is ``[gate |
     up]`` and the hidden is ``silu(gate) * up``; ``"gelu"``: ``gelu(x
-    w_in)``.  Returns (N, D) float32: the weighted sum of each row's
+    w_in)``; ``"relu2"``: ``relu(x w_in)^2`` (no gate: two matrices an
+    expert).  Returns (N, D) float32: the weighted sum of each row's
     chosen experts that live here."""
     from tpulab.ops.grouped_matmul import grouped_product
     with jax.named_scope("moe_experts"):
@@ -168,6 +169,8 @@ def expert_ffn(x, idx, weights, w_in, w_out, act: str = "swiglu",
             h = jax.nn.silu(h[:, :f]) * h[:, f:]
         elif act == "gelu":
             h = jax.nn.gelu(h)
+        elif act == "relu2":
+            h = jnp.square(jax.nn.relu(h))
         else:
             raise ValueError(f"unknown expert activation {act!r}")
         y = grouped_product(h, w_out.astype(compute_dtype), sizes)
@@ -187,7 +190,7 @@ def routed_ffn(params: Dict[str, Any], x, top_k: int,
     what :func:`route` hands on, ``prev`` and ``eps`` being its arguments.
     ``params``: ``router (D, E)`` (the ``"mlp"`` router's leaves), ``bias
     (E,)`` for the routers that choose by it, and the experts as
-    ``w13``/``w2`` (SwiGLU) or ``w1``/``w2`` (GELU).  ``zero``: the router's
+    ``w13``/``w2`` (SwiGLU) or ``w1``/``w2`` (GELU, relu2).  ``zero``: the router's
     last ``zero`` columns are identity experts, the ``E - zero`` before
     them FFN experts.  ``first`` / ``held``: the share of the FFN experts
     whose weights ``params`` holds (all of them by default); the rows are
