@@ -34,7 +34,8 @@ from tpulab.ops.selective_scan import row_flags
 from tpulab.parallel.moe import routed_ffn
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-GOLDEN = os.path.join(ROOT, "tests", "data", "step_programs_pr60.json")
+GOLDEN = os.path.join(ROOT, "tests", "data", "step_programs_pr61.json")
+GOLDEN_PR60 = os.path.join(ROOT, "tests", "data", "step_programs_pr60.json")
 GOLDEN_PR59 = os.path.join(ROOT, "tests", "data", "step_programs_pr59.json")
 VOCAB, PAGE = 97, 8
 CONFIG = {
@@ -477,6 +478,153 @@ def test_a_reused_lane_and_a_preempted_request_are_the_references(
         assert err["logprob_err_max"] < 2e-4 and err["argmax_gap"] == 0
 
 
+# ------------------------------------------- ONE in-projection a layer ----
+
+def _three_slices_of_one_product(spec, p, h, compute_dtype):
+    """THE DEFINITION of :func:`paged_steps._mamba2_in_proj`, as the mixer
+    had it before PR 61: one product with the served leaf, cut in the
+    published order ``[z | xBC | dt]``."""
+    from tpulab.models.transformer import qmat
+    din, cd = spec.m2_heads * spec.m2_head_dim, spec.m2_conv_dim
+    zxd = (h @ qmat(p["in_proj"], compute_dtype)).reshape(
+        h.shape[0] * h.shape[1], -1)
+    return zxd[:, :din], zxd[:, din:din + cd], zxd[:, din + cd:]
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernel-interpret"])
+@pytest.mark.parametrize("program", ["round", "block"])
+def test_the_in_projection_is_the_one_products_three_slices(
+        model, program, use_kernel, monkeypatch):
+    """The tiny hybrid's mixed round (a chunk that crosses a chunk boundary
+    of the recurrence and starts mid-sequence, a fresh chunk, two decode
+    rows and an idle lane) and its K = 2 decode block on stores filled with
+    random numbers, through the tree's in-projection and through its
+    definition above: the logits, the picks and EVERY leaf of both stores
+    (pages, states, convolution tails) bit for bit.  What
+    ``_mamba2_in_proj`` does to keep XLA from computing the product once a
+    reader changes no number: the same product of the same operands, the
+    same columns to the same readers."""
+    from functools import partial
+
+    from helpers_steps import decode_block, mixed_step
+    from tpulab.engine import paged_steps
+    from tpulab.engine.kv_pool import LaneStateStore, PagedKVPool
+    spec, params = model
+    lanes, mp = 5, 64 // PAGE
+    rng = np.random.default_rng(17)
+
+    def junk(a):
+        return jnp.asarray(rng.normal(0, 0.5, a.shape), a.dtype)
+    pages = junk(PagedKVPool(
+        n_pages=1 + lanes * mp, page_size=PAGE,
+        n_layers=len(spec.attention_layers), n_heads=spec.n_kv_heads,
+        head_dim=spec.head_dim, dtype=jnp.float32).kv)
+    state = tuple(junk(a) for a in LaneStateStore(spec, lanes,
+                                                  jnp.float32).arrays)
+    tables = 1 + np.arange(lanes * mp).reshape(lanes, mp)
+    kw = dict(lanes=lanes, max_pages=mp, n_heads=spec.n_heads,
+              n_layers=spec.n_layers, spec=spec, compute_dtype=jnp.float32,
+              use_kernel=use_kernel)
+    ctx = np.asarray([9, 0, 21, 7, 0])
+
+    def run(form):
+        with monkeypatch.context() as patch:
+            if form == "definition":
+                patch.setattr(paged_steps, "_mamba2_in_proj",
+                              _three_slices_of_one_product)
+            if program == "round":
+                fn = jax.jit(partial(paged_steps.paged_mixed_step, **kw))
+                toks, row_lane, row_off, q_lens = paged_steps.pack_round(
+                    lanes, {2: np.arange(11) % VOCAB, 1: np.arange(4) + 5},
+                    {0: 3, 3: 8})
+                kv_lens = np.where(q_lens > 0, ctx + q_lens, 0)
+                picks, lps, last, kv, _moe = mixed_step(
+                    fn, params, (pages, state), tables, toks, row_lane,
+                    row_off, q_lens, kv_lens, spec=spec)
+                out = [picks, lps, np.asarray(last)[q_lens > 0]]
+            else:
+                fn = jax.jit(partial(paged_steps.paged_decode_block, k=2,
+                                     **kw))
+                live = np.asarray([1, 0, 1, 1, 0], bool)
+                picks, lps, emitted, _carry, kv, _moe = decode_block(
+                    fn, params, (pages, state), tables,
+                    (ctx + 1, np.asarray([3, 0, 8, 1, 0]), live,
+                     np.full(lanes, 2)), 2, spec=spec)
+                assert emitted[live].all()
+                out = [picks[live], lps[live]]
+        return out + [np.asarray(a) for a in jax.tree.leaves(kv)]
+
+    got, want = run("tree"), run("definition")
+    assert len(got) == len(want) >= 5
+    assert np.isfinite(got[1]).all()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_each_in_proj_leaf_is_read_by_one_product_and_never_copied(
+        one_chip, monkeypatch):
+    """The round and the K = 2 block at Nemotron-3-Nano's widths, pattern
+    ``MM*`` (8 lanes, 128 prompt rows: what compiles here in well under a
+    minute), compiled for the described v5e with the kernels Mosaic's: each
+    program holds ONE product of the in-projection's width a Mamba-2 layer,
+    and no ``copy`` and no fusion that writes a ``bf16[2688, ...]`` array: the
+    55 MB weight is read where it lies, whole, once (a form that cut the
+    leaf by columns could cost a copy of it a layer in every decode step).
+
+    This guards the WEIGHT COPY, not the clones: a shallow program has
+    none.  XLA computed the product once a reader in the 52-layer round
+    alone (``tools/xla_clones.py`` on the chip's by-operation table is the
+    judge of that: PERF.md section 6, PR 61)."""
+    from test_tools import _xla_clones
+    from tpulab.tpu import platform
+    tool = _xla_clones()
+    monkeypatch.setattr(platform, "pallas_interpret", lambda: False)
+    with open(os.path.join(ROOT, "perf", "configs",
+                           "nemotron3-nano-ep8.json"), encoding="utf-8") as f:
+        published = json.load(f)
+    spec = nemotron_h_spec(dict(published, num_hidden_layers=3,
+                                hybrid_override_pattern="MM*"))
+    assert (spec.d_model, spec.m2_conv_dim) == (2688, 6144)
+    params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                          init_params(spec, 512, 0))
+    assert params["layer1"]["mamba2"]["in_proj"].shape == (2688, 10304)
+    cb = ContinuousBatcher(params, spec.n_heads, spec.n_layers, spec=spec,
+                           lanes=8, max_len=2048, page_size=16, n_pages=256,
+                           compute_dtype=jnp.bfloat16, use_kernel=True)
+    try:
+        fields = cb.programs.fields
+        size = lambda kind: sum(
+            int(np.prod([n for n in shape if n >= 0])) for _n, _d, shape
+            in fields[kind])
+        there = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+        buffers = {
+            "round": (cb.programs.mixed, jnp.zeros(
+                (size("round") - 3 + 3 * (128 + cb.lanes),), jnp.int32)),
+            "block": (cb.programs.block(2), jnp.zeros((size("block"),),
+                                                      jnp.int32))}
+        for name, (program, buffer) in buffers.items():
+            text = program.lower(there(cb.params), there(cb._kv_state),
+                                 there(buffer),
+                                 there(cb._no_carry)).compile().as_text()
+            assert "tpu_custom_call" in text and "ssd_step" in text
+            compiled = tool.Program.from_text(name, text.splitlines())
+            products = [i for i, _ in compiled.insts
+                        if i.shape.endswith(",10304]")
+                        and compiled.holds_product(i)]
+            assert len(products) == 2, (name, products)
+            assert not compiled.clones(), name
+            moved = [i for i, _ in compiled.insts
+                     if i.shape in ("bf16[2688,10304]", "bf16[2688,4096]",
+                                    "bf16[2688,6144]", "bf16[2688,64]")
+                     and i.opcode in ("copy", "fusion", "slice",
+                                      "dynamic-slice", "transpose")]
+            assert not moved, (name, moved)
+    finally:
+        cb.shutdown()
+
+
 # ------------------------------------------------- the lowered programs ----
 
 def _hashes(texts):
@@ -487,9 +635,11 @@ def test_a_mixer_only_layer_lowers_no_ffn_and_the_programs_are_pinned():
     """A pattern of ``M*`` pairs alone lowers with no expert scope, no FFN
     product and no second norm anywhere: a layer of kind ``"none"`` spends
     nothing on the absent half.  The tiny hybrid's tick, round and K = 2
-    block are held to ``step_programs_pr60.json``, whose ten older kinds are
-    PR 59's file letter for letter (``tests/test_step_programs.py`` holds
-    the programs to that)."""
+    block are held to ``step_programs_pr61.json``: PR 61 put the Mamba-2
+    in-projection's three column blocks behind a barrier, which every
+    program of this kind runs, so all three differ from PR 60's file; the
+    ten older kinds are PR 60's file letter for letter, which repeats PR
+    59's (``tests/test_step_programs.py`` holds the programs to that)."""
     from test_step_programs import _lowered
     small = dict(lanes=2, max_len=64, page_size=8)
     lone = nemotron_h_spec(dict(CONFIG, hybrid_override_pattern="M*M*",
@@ -507,10 +657,15 @@ def test_a_mixer_only_layer_lowers_no_ffn_and_the_programs_are_pinned():
         assert scope in scoped, scope
     with open(GOLDEN, encoding="utf-8") as f:
         golden = json.load(f)["programs"]
+    with open(GOLDEN_PR60, encoding="utf-8") as f:
+        pr60 = json.load(f)["programs"]
     with open(GOLDEN_PR59, encoding="utf-8") as f:
-        before = json.load(f)["programs"]
+        pr59 = json.load(f)["programs"]
     assert _hashes(texts) == golden["nemotron"], _hashes(texts)
-    assert {k: v for k, v in golden.items() if k != "nemotron"} == before
+    assert all(a != b for a, b in zip(golden["nemotron"], pr60["nemotron"]))
+    assert sum(t.count("optimization_barrier") for t in texts) == 3 * 3
+    older = {k: v for k, v in golden.items() if k != "nemotron"}
+    assert older == {k: v for k, v in pr60.items() if k != "nemotron"} == pr59
 
 
 if __name__ == "__main__":
@@ -518,7 +673,7 @@ if __name__ == "__main__":
     from tpulab.tpu.platform import force_cpu
     force_cpu(8)
     from test_step_programs import _lowered
-    with open(GOLDEN_PR59, encoding="utf-8") as f:
+    with open(GOLDEN_PR60, encoding="utf-8") as f:
         golden = {"programs": json.load(f)["programs"]}
     golden["programs"]["nemotron"] = _hashes(_lowered(
         nemotron_h_spec(CONFIG), VOCAB, 0,

@@ -205,3 +205,86 @@ def test_gen_inference_pb2_schema_drift_and_roundtrip():
     assert fr.status.code == pb.NOT_FOUND
     assert pb.NOT_FOUND == 7
     assert pb.FetchKVResponse().kv_shipment == b""
+
+
+# ------------------------------------------------ tools/xla_clones.py ----
+
+def _xla_clones():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "xla_clones", f"{REPO}/tools/xla_clones.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_xla_clones_names_a_layers_four_products_and_their_parameter():
+    """A fixture cut from the by-operation table of PR 60's traced run
+    (``chiprun_out/byop60_3160000001.json``, 23 rounds): layer 46's four
+    clones of the in-projection and two operations that are no clones.  Two
+    of the four read a prefetched copy of the weight (``%custom-call.N``):
+    their siblings name the parameter for them."""
+    tool = _xla_clones()
+    programs = tool.load(f"{REPO}/tests/data/byop_clones_pr61.json")
+    assert [p.name for p in programs] == ["jit_paged_mixed_step"]
+    assert len(programs[0].insts) == 6
+    (group,) = programs[0].clones()
+    assert group["names"] == ["fusion.2262.remat", "fusion.2262.remat5",
+                              "fusion.2262.remat6", "fusion.2262.remat4"]
+    assert group["shape"] == "bf16[544,10304]" and group["product"]
+    assert group["parameter"] == "params__layerN____mamba2____in_proj__"
+    assert set(group["reads"]) == {"params__layer46____mamba2____in_proj__.1"}
+    # 14,380.7 us over 23 runs
+    assert abs(group["ms"] - 0.62525) < 1e-4
+    out = subprocess.run(
+        [sys.executable, f"{REPO}/tools/xla_clones.py",
+         f"{REPO}/tests/data/byop_clones_pr61.json"],
+        capture_output=True, text=True, timeout=60, env=ENV)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "4 cloned instructions, 0.63 ms a run over 23 runs" in out.stdout
+    assert "PRODUCT" in out.stdout and "in_proj" in out.stdout
+
+
+def test_xla_clones_reads_a_compiled_programs_text(tmp_path):
+    """The other input: ``compiled.as_text()``, whose operands carry no
+    shapes: a parameter is what the text declares one, a product what the
+    fused computation holds, a clone of an element-wise fusion is none."""
+    tool = _xla_clones()
+    text = """HloModule jit_step, is_scheduled=true
+
+%fused_computation.7 (param_0.1: bf16[64,96], param_1.2: bf16[8,64]) -> bf16[8,96] {
+  %param_1.2 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(1)
+  %param_0.1 = bf16[64,96]{0,1:T(8,128)(2,1)} parameter(0)
+  ROOT %convolution.3 = bf16[8,96]{1,0:T(8,128)(2,1)} convolution(%param_1.2, %param_0.1), dim_labels=bf_io->bf
+}
+
+%fused_computation.9 (param_0.4: bf16[8,96]) -> bf16[8,32] {
+  %param_0.4 = bf16[8,96]{1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %slice.1 = bf16[8,32]{1,0:T(8,128)(2,1)} slice(%param_0.4), slice={[0:8], [0:32]}
+}
+
+ENTRY %main.1 (params__layer3____mamba2____in_proj__.1: bf16[64,96], x.1: bf16[8,64]) -> (bf16[8,32], bf16[8,32]) {
+  %params__layer3____mamba2____in_proj__.1 = bf16[64,96]{0,1:T(8,128)(2,1)} parameter(0)
+  %x.1 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(1)
+  %fusion.5 = bf16[8,96]{1,0:T(8,128)(2,1)S(1)} fusion(%params__layer3____mamba2____in_proj__.1, %x.1), kind=kOutput, calls=%fused_computation.7
+  %fusion.6 = bf16[8,32]{1,0:T(8,128)(2,1)} fusion(%fusion.5), kind=kLoop, calls=%fused_computation.9
+  %fusion.5.remat = bf16[8,96]{1,0:T(8,128)(2,1)S(1)} fusion(%params__layer3____mamba2____in_proj__.1, %x.1), kind=kOutput, calls=%fused_computation.7
+  %fusion.6.remat.1 = bf16[8,32]{1,0:T(8,128)(2,1)} fusion(%fusion.5.remat), kind=kLoop, calls=%fused_computation.9
+  ROOT %tuple.1 = (bf16[8,32]{1,0}, bf16[8,32]{1,0}) tuple(%fusion.6, %fusion.6.remat.1)
+}
+"""
+    path = tmp_path / "step.txt"
+    path.write_text(text)
+    (program,) = tool.load(str(path), "step")
+    assert program.parameters >= {"x.1",
+                                  "params__layer3____mamba2____in_proj__.1"}
+    by_shape = {g["shape"]: g for g in program.clones()}
+    assert set(by_shape) == {"bf16[8,96]", "bf16[8,32]"}
+    assert by_shape["bf16[8,96]"]["product"]
+    assert by_shape["bf16[8,96]"]["parameter"] == \
+        "params__layerN____mamba2____in_proj__"
+    assert by_shape["bf16[8,96]"]["ms"] is None
+    assert not by_shape["bf16[8,32]"]["product"]
+    assert by_shape["bf16[8,32]"]["parameter"] is None
+    assert "2 cloned instructions (compiled text: no times)" in tool.report(
+        [program])

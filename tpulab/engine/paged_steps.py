@@ -775,6 +775,30 @@ def _gdn_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     return out.reshape(h.shape), (ssm, conv)
 
 
+def _mamba2_in_proj(spec, p, h, compute_dtype):
+    """A Mamba-2 layer's in-projection of the rows ``h``: ``(z, xBC, dt)``,
+    ``(rows, d_inner)``, ``(rows, conv_dim)`` and ``(rows, heads)``, the
+    column blocks of ONE product with the served leaf ``in_proj`` in the
+    published order ``[z | xBC | dt]``.
+
+    The three are read far apart (``xBC`` by the convolution and the tail's
+    write, ``dt`` by the recurrence, ``z`` by the gate behind it), and in the
+    52-layer round XLA would rather compute the whole product again for each
+    reader than keep 11 MB of it: four products a layer, each at the bf16
+    peak (PR 61: 13.9 of a 64.5 ms round; no shallower program shows it).
+    The barrier makes the three blocks values of their own, so the product
+    has ONE reader, beside it, and is computed once; what it costs is the
+    blocks written and read once more."""
+    import jax
+    from tpulab.models.transformer import qmat
+
+    din, cd = spec.m2_heads * spec.m2_head_dim, spec.m2_conv_dim
+    zxd = (h @ qmat(p["in_proj"], compute_dtype)).reshape(
+        h.shape[0] * h.shape[1], -1)
+    return jax.lax.optimization_barrier(
+        (zxd[:, :din], zxd[:, din:din + cd], zxd[:, din + cd:]))
+
+
 def _mamba2_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     """The Mamba-2 mixer of one layer (Nemotron-H's: a scalar decay a head,
     ``B`` and ``C`` shared by a group of heads, a gated group norm; Mamba-1
@@ -810,7 +834,7 @@ def _mamba2_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
     ssm, conv = state
     nh, hd, g, ns = (spec.m2_heads, spec.m2_head_dim, spec.m2_groups,
                      spec.m2_state)
-    din, cd = nh * hd, spec.m2_conv_dim
+    din = nh * hd
     rows = seg.get("row_seg")
     if rows is None and h.shape[1] != 1:
         raise NotImplementedError(
@@ -818,10 +842,8 @@ def _mamba2_mixer(spec, p, at, h, pos, valid, state, seg, compute_dtype):
             "(paged_mixed_step), not in the padded (B, M) form")
     n = h.shape[0] * h.shape[1]
     with jax.named_scope("mamba2_proj"):
-        zxd = (h @ qmat(p["in_proj"], compute_dtype)).reshape(n, -1)
-        z, xbc = zxd[:, :din], zxd[:, din:din + cd]
-        dt = jax.nn.softplus(zxd[:, din + cd:].astype(f32)
-                             + p["dt_bias"].astype(f32))
+        z, xbc, dt = _mamba2_in_proj(spec, p, h, compute_dtype)
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
     live = fresh = None
     with jax.named_scope("mamba2_conv"):
         if rows is None:
